@@ -11,7 +11,7 @@ RUN mkdir -p /app/models
 
 # Persistent XLA compile cache: restarts of the same container (or a
 # mounted volume — helm compileCache.*) skip jit warmup recompiles.
-ENV LFKT_COMPILE_CACHE_DIR=/xla-cache
+ENV JAX_COMPILATION_CACHE_DIR=/xla-cache
 RUN mkdir -p /xla-cache
 
 # Exactly one worker: the model is loaded once per process (reference

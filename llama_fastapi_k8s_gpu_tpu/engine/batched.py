@@ -75,6 +75,31 @@ _batched_first_sample = timed_jit("batched_first_sample",
                                   site="engine.batched")
 
 
+def _refuse_fused_on_a_tpu_mesh(params: dict, dp: int, tp: int) -> None:
+    """Found on four v5e chips (PR 22): a program that spans devices cannot
+    hold a fused K-quant matmul.  The kernels reach the partitioner through
+    ``custom_partitioning``, whose callback jax 0.9.0 never registers with
+    the TPU plugin (``make_tpu_client`` skips the plugin callbacks), so XLA
+    meets the raw call and stops with "Custom emitter for
+    CustomSPMDPartitioning not found" — at the warm-up compile, after the
+    whole load.  Say so here instead.  The CPU backend partitions them fine
+    (tests/test_parallel.py), which is how this went unseen."""
+    from ..parallel.mesh import _fused_key
+
+    if dp * tp == 1 or jax.default_backend() != "tpu":
+        return
+    fused = sorted(name for name, leaf in params["layers"].items()
+                   if isinstance(leaf, dict) and _fused_key(leaf))
+    if fused:
+        raise RuntimeError(
+            f"a {dp}x{tp} TPU mesh cannot serve fused K-quant weights "
+            f"({', '.join(fused)}): jax {jax.__version__} does not register "
+            "custom_partitioning with the TPU plugin, so the fused matmuls "
+            "do not compile in a program that spans devices.  Serve "
+            "LFKT_WEIGHT_FORMAT=int8 (or bf16) on a mesh, or one chip per "
+            "process with LFKT_MESH_TP=1")
+
+
 class MeshEngine(Engine):
     """An :class:`Engine` that serves batches of requests over a device mesh.
 
@@ -89,18 +114,32 @@ class MeshEngine(Engine):
 
     def __init__(self, model_path: str | None, *, dp: int | None = None,
                  tp: int = 1, batch_size: int | None = None, **kw):
-        super().__init__(model_path, **kw)
         avail = max(1, len(jax.devices()) // tp)
         if dp is None:
             if batch_size is None:
                 dp = avail
             else:  # largest device count the batch shards evenly over
                 dp = max(d for d in range(1, avail + 1) if batch_size % d == 0)
+        if dp * tp > 1:
+            # The flash prefill kernel is a bare pallas_call with no
+            # partitioning rule: JAX refuses to lower it into a program
+            # that spans devices ("Mosaic kernels cannot be automatically
+            # partitioned").  A mesh of more than one device therefore
+            # serves the XLA score-matrix attention; asking for the kernel
+            # by name is refused here instead of at the warm-up compile.
+            if kw.get("attn_impl", "auto") == "pallas":
+                raise ValueError(
+                    f"attn_impl='pallas' cannot serve a {dp}x{tp} device "
+                    "mesh: the flash kernel has no partitioning rule; use "
+                    "attn_impl='auto' (resolves to 'xla' on a mesh) or 'xla'")
+            kw["attn_impl"] = "xla"
+        super().__init__(model_path, **kw)
         self.mesh = make_mesh(dp=dp, tp=tp)
         self.batch_size = batch_size or dp
         if self.batch_size % dp:
             raise ValueError(
                 f"batch_size {self.batch_size} must be divisible by dp={dp}")
+        _refuse_fused_on_a_tpu_mesh(self.params, dp, tp)
         self.params = shard_params(self.params, self.mesh)
         state = init_batched_state(self.cfg, self.batch_size)
         self._bstate = jax.device_put(

@@ -226,7 +226,7 @@ def run_lint(package_dir: str | None = None, repo_root: str | None = None,
     ref_roots: list[str] = []
     if repo_root:
         for name in ("tests", "tools", "bench.py", "bench_server.py",
-                     "__graft_entry__.py"):
+                     "chip_smoke.py"):
             p = os.path.join(repo_root, name)
             if os.path.exists(p):
                 ref_roots.append(p)

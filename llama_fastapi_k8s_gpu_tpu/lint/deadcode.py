@@ -4,8 +4,8 @@ Dead code in a serving repo is not free: it keeps compiling, keeps
 importing, shows up in grep results as if load-bearing, and silently
 drifts out of date with the invariants the live code maintains.  This
 checker indexes every ``Name``/``Attribute`` reference across the package
-AND its consumers (tests/, tools/, bench.py, bench_server.py, the graft
-entrypoint) and flags:
+AND its consumers (tests/, tools/, bench.py, bench_server.py,
+chip_smoke.py) and flags:
 
 - DEAD001 — a module-level function (public or private) with no reference
   anywhere beyond its own definition.  Import statements and ``__all__``

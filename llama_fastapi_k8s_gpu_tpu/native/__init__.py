@@ -19,7 +19,6 @@ import ctypes
 import hashlib
 import logging
 import os
-import shutil
 import subprocess
 import tempfile
 import threading
@@ -38,6 +37,7 @@ _CXXFLAGS = ["-O3", "-march=native", "-ffp-contract=off", "-fPIC", "-shared",
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_attempted = False
+_loaded_path: str | None = None
 
 
 def _enabled() -> bool:
@@ -46,10 +46,10 @@ def _enabled() -> bool:
     return env_bool("LFKT_NATIVE", default=True)
 
 
-def _cache_dirs() -> list[str]:
-    here = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-    xdg = os.environ.get("XDG_CACHE_HOME") or os.path.join(os.path.expanduser("~"), ".cache")
-    return [here, os.path.join(xdg, "lfkt_native"), os.path.join(tempfile.gettempdir(), "lfkt_native")]
+#: the one place a built library is kept: git-ignored, inside the package,
+#: and named by the hash of its source, flags and host (``_load``), so a
+#: library built from other source is never picked up in its place
+_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
 
 
 def _build(so_path: str) -> bool:
@@ -135,39 +135,34 @@ def _bind(so_path: str) -> ctypes.CDLL | None:
 
 
 def _load() -> ctypes.CDLL | None:
+    global _loaded_path
     with open(_SRC, "rb") as f:
         payload = f.read() + " ".join(_CXXFLAGS).encode() + _host_tag().encode()
     tag = hashlib.sha256(payload).hexdigest()[:16]
     name = f"gguf_dequant-{tag}.so"
 
-    for d in _cache_dirs():
-        so_path = os.path.join(d, name)
-        if os.path.exists(so_path):
-            lib = _bind(so_path)
-            if lib is not None:
-                return lib
-
-    # Compile exactly once, into a tmpdir we know is writable.  A compile
-    # failure is a property of the toolchain, not the cache dir — don't
-    # retry it per directory.
-    build_dir = tempfile.mkdtemp(prefix="lfkt_build_")
-    built = os.path.join(build_dir, name)
-    if not _build(built):
-        return None
-
-    for d in _cache_dirs():  # promote into a persistent cache for next start
-        so_path = os.path.join(d, name)
+    so_path = os.path.join(_BUILD_DIR, name)
+    if not os.path.exists(so_path):
+        # compile next to the target so the final rename is atomic; where
+        # the package directory is read-only, serve from a temporary build
         try:
-            os.makedirs(d, exist_ok=True)
-            tmp = os.path.join(d, f"{name}.tmp.{os.getpid()}")
-            shutil.copyfile(built, tmp)
-            os.replace(tmp, so_path)
+            os.makedirs(_BUILD_DIR, exist_ok=True)
         except OSError:
-            continue
-        lib = _bind(so_path)
-        if lib is not None:
-            return lib
-    return _bind(built)  # all caches unwritable: serve from the tmp build
+            pass
+        if not os.access(_BUILD_DIR, os.W_OK):
+            so_path = os.path.join(tempfile.mkdtemp(prefix="lfkt_build_"), name)
+        if not _build(so_path):
+            return None
+    lib = _bind(so_path)
+    if lib is not None:
+        _loaded_path = so_path
+    return lib
+
+
+def loaded_path() -> str | None:
+    """Path of the library this process loaded (None: not loaded — the
+    numpy codecs serve).  Never triggers the build itself."""
+    return _loaded_path
 
 
 def get_lib() -> ctypes.CDLL | None:  # lfkt: blocks-under[_lock] -- one-time lazy native build/dlopen: concurrent callers must block until the handle exists, then every call is a cached read

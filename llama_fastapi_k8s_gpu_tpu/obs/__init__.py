@@ -9,7 +9,7 @@ logging (:mod:`.logctx`).  Stdlib only; nothing here is importable from a
 jit trace, and everything is strictly zero-cost for sampled-out requests
 (LFKT_TRACE_SAMPLE=0 → ``Tracer.start`` returns None before any lock).
 
-Span taxonomy, metric catalog, sampling and the debug endpoints:
+Span classes, metric catalog, sampling and the debug endpoints:
 docs/OBSERVABILITY.md.  Slow-request triage flow (tools/trace_report.py
 waterfalls): docs/RUNBOOK.md "Triaging a slow request".
 """

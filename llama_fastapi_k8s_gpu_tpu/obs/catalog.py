@@ -221,7 +221,7 @@ METRICS: dict[str, Metric] = _register(
     Metric("lane_idle_seconds", GAUGE,
            "cumulative idle lane-seconds while other lanes decoded "
            "(monotonic; the admission controller's raw loss signal)"),
-    # -- resilience / error taxonomy (docs/RUNBOOK.md) ---------------------
+    # -- resilience / error classes (docs/RUNBOOK.md) ---------------------
     Metric("engine_unavailable_total", COUNTER,
            "503s from watchdog trips / recovery in progress"),
     Metric("engine_errors_total", COUNTER, "engine-side request failures"),

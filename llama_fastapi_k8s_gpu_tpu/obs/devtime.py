@@ -14,8 +14,7 @@ Two registration forms, one registry:
 
 - :func:`timed_jit` wraps a HOST jit entry point.  Every call increments
   the program's dispatch count; a call that grew the underlying jit cache
-  (``fn._cache_size()``, with a signature-set fallback on jax versions
-  without it) is a compile event: the program records the static-shape
+  (``fn._cache_size()``) is a compile event: the program records the static-shape
   signature and the call's wall time (first-dispatch wall ≈ compile wall,
   the standard attribution), and the event is exported to the
   ``xla_compiles_total`` / ``xla_compile_seconds`` /
@@ -85,20 +84,11 @@ def _describe_leaf(leaf) -> str:
     return f"{type(leaf).__name__}#{h & 0xFFFFFFFF:08x}"
 
 
-#: Fastest plausible jit compile wall.  The no-cache-probe fallback only
-#: computes a dispatch signature when the call's wall reaches this floor:
-#: trace+lower+LLVM is milliseconds even for `lambda x: x`, while a
-#: steady-state cache-hit dispatch stays well under it.
-_FALLBACK_COMPILE_FLOOR_S = 1e-3
-
-
 def _signature(args: tuple, kwargs: dict) -> str:
     """Static-shape signature of one dispatch — the (shapes, dtypes,
     statics) key a jit cache distinguishes programs by, rendered as a
-    stable string.  Computed only on compile-scale calls: with a cache
-    probe that means actual compile events (rare); without one, only
-    calls whose wall clears _FALLBACK_COMPILE_FLOOR_S — so the lazy jax
-    import and the O(leaves) tree walk never ride a steady-state
+    stable string.  Computed only on actual compile events (rare), so the
+    lazy jax import and the O(leaves) tree walk never ride a steady-state
     (sub-millisecond) dispatch."""
     import jax
 
@@ -266,19 +256,14 @@ class DevtimeRegistry:
         with self._lock:
             self._program(name, ENTRY, None).dispatches += n
 
-    def record_compile(self, name: str, signature: str, wall_s: float,
-                       new_only: bool = False) -> None:
-        """Record one compile event.  ``new_only`` is the fallback path for
-        jit callables without a cache-size probe: only an unseen signature
-        counts as a compile.  Storm side effects (log + trace fan-in) fire
-        outside the lock."""
+    def record_compile(self, name: str, signature: str, wall_s: float) -> None:
+        """Record one compile event.  Storm side effects (log + trace
+        fan-in) fire outside the lock."""
         storm = None
         with self._lock:
             p = self._program(name, ENTRY, None)
             sig_h = hash(signature)
             known = sig_h in p.sig_seen
-            if new_only and known:
-                return
             if known:
                 entry = p.signatures.get(signature)
                 if entry is not None:     # display entry may be evicted
@@ -411,35 +396,20 @@ class _TimedJit:
         self._name = name
         self._fn = fn
         self.__wrapped__ = fn
-        # jax's PjitFunction exposes its compiled-variant count; older
-        # versions fall back to registry signature-set membership
-        self._probe = getattr(fn, "_cache_size", None)
+        # jax's PjitFunction exposes its compiled-variant count
+        self._probe = fn._cache_size
 
     def __call__(self, *args, **kwargs):
         reg = self._reg
         if not reg._armed:          # disarmed: forward untouched, allocate
             return self._fn(*args, **kwargs)   # nothing (poisoned-reg test)
         probe = self._probe
-        before = probe() if probe is not None else -1
+        before = probe()
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         dt = time.perf_counter() - t0
-        if probe is not None:
-            if probe() > before:
-                reg.record_compile(self._name, _signature(args, kwargs), dt)
-        elif dt >= _FALLBACK_COMPILE_FLOOR_S:
-            # No cache probe (old jax): signature-set membership detects
-            # compiles, but walking a ~300-leaf params tree per decode
-            # chunk is exactly the overhead this tool attributes.  A jit
-            # compile is never sub-millisecond, so a call that returns
-            # under the floor cannot have compiled and skips the walk;
-            # the first dispatch of any new signature pays compile wall
-            # and always clears it.  Membership lives in the REGISTRY
-            # ledger (new_only), not wrapper-private state, so reset()
-            # zeroes it with everything else; the lock it costs is one
-            # record_dispatch already pays on every call.
-            reg.record_compile(self._name, _signature(args, kwargs), dt,
-                               new_only=True)
+        if probe() > before:
+            reg.record_compile(self._name, _signature(args, kwargs), dt)
         reg.record_dispatch(self._name)
         return out
 

@@ -63,8 +63,8 @@ def _attn_kernel(
     k_ref,              # (1, U*BK, hd) — bf16, or int8 when quantized
     v_ref,              # (1, U*BK, hd)
     # quantized only (absent otherwise): per-token f32 scale blocks
-    #   ks_ref          # (1, U*BK)
-    #   vs_ref          # (1, U*BK)
+    #   ks_ref          # (1, 1, U*BK)
+    #   vs_ref          # (1, 1, U*BK)
     # outputs
     *rest,              # o_ref (1, BQ, hd), then scratch:
     # m_ref,            # (BQ, 128) f32  running max (lane-replicated)
@@ -142,7 +142,7 @@ def _attn_kernel(
                 preferred_element_type=jnp.float32,
             ) * sm_scale                           # (BQ, BK)
             if quantized:
-                scores = scores * ks_ref[:, lo:lo + block_k]  # (1, BK) bcast
+                scores = scores * ks_ref[0, :, lo:lo + block_k]  # (1, BK) bcast
 
             if masked:
                 row = qb * block_q + jax.lax.broadcasted_iota(
@@ -168,7 +168,7 @@ def _attn_kernel(
                 # same trick on V: p·(q·s) == (p·s)·q — fold the value
                 # scales into the (BQ, BK) probability tile, contract the
                 # raw int8
-                p = p * vs_ref[:, lo:lo + block_k]
+                p = p * vs_ref[0, :, lo:lo + block_k]
                 v = v.astype(q_ref.dtype)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -295,11 +295,15 @@ def flash_attention(
     ]
     operands = [qg, kk, vv]
     if quantized:
+        # scales ride as (n_kv, 1, n_ctx): Mosaic wants a block's last two
+        # dims (8, 128)-aligned or equal to the array's, and a (1, bkf)
+        # block of a 2-D (n_kv, n_ctx) array is neither
         in_specs += [
-            pl.BlockSpec((1, bkf), lambda h, qb, kb, *_: (h, kb)),
-            pl.BlockSpec((1, bkf), lambda h, qb, kb, *_: (h, kb)),
+            pl.BlockSpec((1, 1, bkf), lambda h, qb, kb, *_: (h, 0, kb)),
+            pl.BlockSpec((1, 1, bkf), lambda h, qb, kb, *_: (h, 0, kb)),
         ]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        operands += [s.astype(jnp.float32).reshape(n_kv, 1, n_ctx)
+                     for s in (k_scale, v_scale)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -81,15 +81,17 @@ def quantize_kv_pallas(x: jax.Array, interpret: bool = False):
         in_specs=[pl.BlockSpec((1, S, hd), lambda h: (h, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, S, hd), lambda h: (h, 0, 0)),
-            pl.BlockSpec((1, S), lambda h: (h, 0)),
+            # (n_kv, 1, S): a (1, S) block of a 2-D (n_kv, S) array is
+            # neither (8, 128)-aligned nor the array's own last two dims
+            pl.BlockSpec((1, 1, S), lambda h: (h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_kv, S, hd), jnp.int8),
-            jax.ShapeDtypeStruct((n_kv, S), jnp.float32),
+            jax.ShapeDtypeStruct((n_kv, 1, S), jnp.float32),
         ],
         interpret=interpret,
     )(x)
-    return q, s
+    return q, s.reshape(n_kv, S)
 
 
 def quantize_kv(x: jax.Array):

@@ -39,7 +39,6 @@ from ...gguf.quants import unpack_scale_min_k4
 from .qmatmul import (
     augment_x,
     batched_rows,
-    def_partition_compat,
     _env_variant,
     _interpret,
     _lane_repeat,
@@ -362,8 +361,7 @@ def _q5k_pre_2d_partitioned(interpret: bool):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, t n l -> b n",
@@ -461,8 +459,7 @@ def _q5k_2d_partitioned(interpret: bool, variant: str = "cur"):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, n p, t n l -> b n",

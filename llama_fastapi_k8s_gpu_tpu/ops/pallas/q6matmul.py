@@ -52,7 +52,6 @@ from ...obs.devtime import register_program
 from ...gguf.quants import _garbage_tolerant
 from .qmatmul import (
     batched_rows,
-    def_partition_compat,
     _env_variant,
     _interpret,
     _lane_repeat,
@@ -459,8 +458,7 @@ def _q6k_pre_2d_partitioned(interpret: bool):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, t n l -> b n",
@@ -525,8 +523,7 @@ def _q6k_2d_partitioned(interpret: bool, variant: str = "cur"):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, n p, t n l -> b n",

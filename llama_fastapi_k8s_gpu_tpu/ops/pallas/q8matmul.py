@@ -39,7 +39,6 @@ from ...obs.devtime import register_program
 from ...gguf.quants import _garbage_tolerant
 from .qmatmul import (
     batched_rows,
-    def_partition_compat,
     _interpret,
     _lane_repeat,
     permute_x,
@@ -174,8 +173,7 @@ def _q8_2d_partitioned(interpret: bool):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, t n l -> b n",

@@ -327,7 +327,7 @@ def _q4k_accum(o_ref, part):
 
 def _pick_tn(n: int, interpret: bool, prefs: tuple = (512, 256, 128)) -> int:
     """Largest N tile that divides ``n``.  512 measured fastest for the
-    Q4_K kernel (docs/bench/qmatmul_v2_microbench_2026-07-29.json); the
+    Q4_K kernel on an earlier machine (not re-measured); the
     Q6_K kernel passes smaller ``prefs`` because its wider f32
     intermediates would crowd the ~16 MB VMEM at TN=512."""
     for c in prefs + ((64, 32, 16, 8) if interpret else ()):
@@ -445,8 +445,7 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
             mesh, P(_spec_axis(arg_shapes[0].sharding, 0),
                     _spec_axis(arg_shapes[1].sharding, 0)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         # shardy factor rule: rows (b) and output (n) propagate; K factors
@@ -549,20 +548,6 @@ def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
     return call(idx, xpa, qs, sm)
 
 
-def def_partition_compat(fn, **kwargs) -> None:
-    """``fn.def_partition`` with the newer ``sharding_rule`` (Shardy) kwarg
-    when this jax supports it, dropping it otherwise.  Every caller also
-    passes the GSPMD callbacks (``partition`` /
-    ``infer_sharding_from_operands``), so older-jax behavior is identical —
-    without this the whole fused-kernel family raises TypeError at first
-    trace on jax builds that predate the kwarg."""
-    try:
-        fn.def_partition(**kwargs)
-    except TypeError:
-        kwargs.pop("sharding_rule", None)
-        fn.def_partition(**kwargs)
-
-
 def rows_vmappable(fn, xpa_pos: int):
     """Give a fused matmul a vmap rule: batching over the activation
     operand is just more rows for the kernel (weights are shared across
@@ -634,8 +619,7 @@ def stacked_partitioned(raw_fn, sharding_rule: str, interpret: bool):
             mesh, P(_spec_axis(arg_shapes[1].sharding, 0),
                     _spec_axis(arg_shapes[2].sharding, 1)))
 
-    def_partition_compat(
-        fn,
+    fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=sharding_rule,

@@ -40,15 +40,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
-try:                                  # newer jax: top-level export
-    from jax import shard_map
-except ImportError:                   # older jax: the experimental home, with
-    # check_vma spelled check_rep — shim the one call-site kwarg we use
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig

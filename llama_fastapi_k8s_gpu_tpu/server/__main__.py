@@ -1,14 +1,13 @@
 """``python -m llama_fastapi_k8s_gpu_tpu.server`` — run the service.
 
-Uses uvicorn when available (the production image installs it, mirroring the
-reference's gunicorn+UvicornWorker, reference docker/Dockerfile.app:12);
-otherwise falls back to the in-tree dependency-free ``httpd``.  Either way
-there is exactly one worker process: the model is loaded once per process, so
+Serves through the in-tree dependency-free ``httpd`` (the reference runs
+gunicorn+UvicornWorker, reference docker/Dockerfile.app:12).  There is
+exactly one worker process: the model is loaded once per process, so
 ``-w 1`` is load-bearing (SURVEY.md §1 L4).
 """
 
 def main():
-    from ..utils.config import env_bool, force_cpu_if_requested, knob
+    from ..utils.config import env_bool, knob
 
     # The reference scales with `gunicorn -w N` (reference
     # docker/Dockerfile.app:12).  On TPU that is the wrong axis: a chip
@@ -47,8 +46,8 @@ def main():
     # fleet router (serving/fleet/; docs/RUNBOOK.md "Running a replica
     # fleet"): the THIRD process role after serving and disagg tiers —
     # a prefix-affinity proxy over the replica fleet.  Checked BEFORE any
-    # model machinery (even the CPU pin): a router pod has no engine, no
-    # jax, no uvicorn — it is a placement process.
+    # model machinery: a router pod has no engine and no jax — it is a
+    # placement process.
     fleet_role = knob("LFKT_FLEET_ROLE", default="off")
     if fleet_role == "router":
         import logging
@@ -66,35 +65,12 @@ def main():
             f"got {fleet_role!r}: replicas stay role=off; only the "
             "router process changes type (docs/RUNBOOK.md 'Running a "
             "replica fleet')")
-    force_cpu_if_requested()   # site-hook defense (one copy: utils/config)
-    try:
-        import uvicorn
-    except ImportError:
-        from .app import app
-        from .httpd import run
+    from ..utils.jaxcache import setup_compile_cache
+    from .app import app
+    from .httpd import run
 
-        run(app, host, port)
-        return
-    # the same graceful-drain budget the in-tree httpd honors: without it
-    # uvicorn's SIGTERM handling applies no bounded drain and the
-    # documented LFKT_DRAIN_SECONDS knob would be a no-op in the
-    # production (uvicorn-installed) image.  The kwarg exists since
-    # uvicorn 0.20 (requirements.txt floats); degrade rather than refuse
-    # to serve on an older pin.
-    import inspect
-    import math
-
-    from ..utils.config import get_settings
-
-    drain = get_settings().drain_seconds
-    kw = {}
-    if "timeout_graceful_shutdown" in inspect.signature(
-            uvicorn.Config).parameters:
-        # uvicorn takes whole seconds; never truncate a small budget to an
-        # immediate-cancel 0
-        kw["timeout_graceful_shutdown"] = max(1, math.ceil(drain))
-    uvicorn.run("llama_fastapi_k8s_gpu_tpu.server.app:app",
-                host=host, port=port, workers=1, **kw)
+    setup_compile_cache()
+    run(app, host, port)
 
 
 if __name__ == "__main__":

@@ -42,6 +42,7 @@ from .asgikit import (
 
 import uuid
 
+from .. import native as _native
 from ..obs import flightrec as _flightrec
 from ..obs import memledger as _memledger
 from ..obs.devtime import DEVTIME
@@ -51,6 +52,7 @@ from ..obs.trace import TRACER, Tracer
 from ..serving.fleet.affinity import AFFINITY_KEY_HEADER, PRIOR_OWNER_HEADER
 from ..utils.config import Settings, get_settings
 from ..utils.faults import FAULTS
+from ..utils.jaxcache import compile_cache_stats
 from ..utils.health import (
     READY,
     STARTING,
@@ -1372,7 +1374,7 @@ def create_app(engine=None, settings: Settings | None = None,
                 status_code=500, detail=f"Internal server error: {str(e)}"))
 
     def _resilience_info() -> dict:
-        """Error-taxonomy + watchdog block for /health: the state machine,
+        """Error-class + watchdog block for /health: the state machine,
         the trip/recovery counters, and the last engine error."""
         st = app.state
         info: dict = {"health": st.health.snapshot()}
@@ -1459,6 +1461,10 @@ def create_app(engine=None, settings: Settings | None = None,
                 # clamped to the real divisor; 0 after any degrade, with
                 # the reason in /debug/compiles)
                 "decode_layer_unroll": _effective_unroll(cfg),
+                # how the weights got here: per-phase load/warm-up seconds
+                # and the native packer library (None = numpy codecs)
+                "load_phases": getattr(eng, "load_phases", None),
+                "native_lib": _native.loaded_path(),
             }
             # paged KV pool occupancy (LFKT_KV_PAGED): pages used/free/
             # pinned, the spill tier, and the hit/eviction counters —
@@ -1511,7 +1517,7 @@ def create_app(engine=None, settings: Settings | None = None,
         m = app.state.metrics
         if hasattr(app.state, "queue"):
             m.set_gauge("queue_depth", app.state.queue.qsize())
-        # health/resilience gauges (error taxonomy counters — timeouts,
+        # health/resilience gauges (error classes counters — timeouts,
         # 503s, watchdog trips/recoveries — are inc'd at their sites)
         m.set_gauge("health_state", STATE_CODES[app.state.health.state])
         hb = getattr(app.state.engine, "heartbeat", None)
@@ -1645,8 +1651,11 @@ def create_app(engine=None, settings: Settings | None = None,
         """The devtime program registry (obs/devtime.py): every registered
         jit program with its compile count, dispatch count, and the
         static-shape signatures it compiled — the "what is this pod
-        recompiling" answer (docs/RUNBOOK.md recompile-storm runbook)."""
-        return DEVTIME.snapshot()
+        recompiling" answer (docs/RUNBOOK.md recompile-storm runbook) —
+        plus where the persistent compile cache lives and how often it
+        hit (utils/jaxcache.py)."""
+        return {**DEVTIME.snapshot(),
+                "persistent_cache": compile_cache_stats()}
 
     @app.get("/debug/slo")
     async def debug_slo():

@@ -179,6 +179,97 @@ def synth_bpe_vocab(n_merges: int = 280_000, seed: int = 0,
     return tokens, merges, types
 
 
+def rand_q4k_blocks(rng, n_elem: int) -> np.ndarray:
+    """Valid random Q4_K block bytes (layout per gguf/quants.py: f16 d |
+    f16 dmin | 12B packed scale/min | 128B nibbles), zero-mean: every
+    sub-block's 6-bit min equals its scale and ``dmin = 7.5 d``, so a
+    weight is ``d * sc * (q - 7.5)`` with q uniform in 0..15 and a std of
+    about ``dim ** -0.5`` at dim 4096.  Activations then stay in the range
+    a trained model's do, and logits can be compared across formats."""
+    nb = n_elem // 256
+    blk = np.empty((nb, 144), dtype=np.uint8)
+    blk[:, 0:2] = np.full(nb, 1.5e-4, np.float16).view(np.uint8).reshape(nb, 2)
+    blk[:, 2:4] = np.full(nb, 7.5 * 1.5e-4, np.float16).view(np.uint8).reshape(nb, 2)
+    sc = rng.integers(0, 64, (nb, 4), dtype=np.uint8)   # sub-blocks 0-3
+    lo = rng.integers(0, 16, (nb, 4), dtype=np.uint8)   # sub-blocks 4-7
+    blk[:, 4:8] = sc                                    # scales 0-3
+    blk[:, 8:12] = sc                                   # mins 0-3
+    blk[:, 12:16] = lo | (lo << 4)                      # scale | min << 4
+    blk[:, 16:144] = rng.integers(0, 256, (nb, 128), dtype=np.uint8)
+    return blk.reshape(-1)
+
+
+def rand_q6k_blocks(rng, n_elem: int) -> np.ndarray:
+    """Valid random Q6_K block bytes (128B ql | 64B qh | 16×i8 scales |
+    f16 d): ``d * sc * (q - 32)``, zero-mean, std about ``dim ** -0.5``."""
+    nb = n_elem // 256
+    blk = np.empty((nb, 210), dtype=np.uint8)
+    blk[:, 0:192] = rng.integers(0, 256, (nb, 192), dtype=np.uint8)
+    blk[:, 192:208] = rng.integers(1, 4, (nb, 16), dtype=np.uint8)
+    blk[:, 208:210] = np.full(nb, 4e-4, np.float16).view(np.uint8).reshape(nb, 2)
+    return blk.reshape(-1)
+
+
+def write_llama3_8b_q4km_gguf(path: str, n_layers: int | None = None,
+                              seed: int = 0) -> ModelConfig:
+    """Write a Llama-3-8B GGUF at full width with the tensor mix of
+    llama.cpp's Q4_K_M files (Q4_K attn/ffn, Q6_K attn_v + ffn_down +
+    output, F16 embeddings), random weights from ``seed``, and a
+    Llama-3-scale BPE vocabulary.  ``n_layers`` cuts depth only.  Pure
+    numpy: safe in a process that never touches a device."""
+    import dataclasses
+
+    from .models.config import LLAMA3_8B
+
+    cfg = dataclasses.replace(LLAMA3_8B, n_layers=n_layers or LLAMA3_8B.n_layers)
+    rng = np.random.default_rng(seed)
+    tokens, merges, types = synth_bpe_vocab(n_merges=280_000)
+    # pad/trim to the exact 8B vocab so tensor shapes are authentic
+    specials = tokens[-7:]
+    need = cfg.vocab_size - len(specials)
+    body = tokens[:-7]
+    body = (body + [f"<pad{i}>" for i in range(need - len(body))])[:need]
+    tokens = body + specials
+    types = [1] * need + [3] * len(specials)
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, merges=merges,
+                          name="llama3-8b-synthetic-q4km", n_ctx=8192)
+    kv_dim = cfg.n_kv_heads * cfg.head_dim
+
+    def raw(name, shape, kind):
+        # `shape` is numpy order (out, in); GGUF tensor shapes are
+        # innermost-first, which is what add_raw_tensor stores verbatim
+        n = int(np.prod(shape))
+        if kind == GGMLType.Q4_K:
+            data = rand_q4k_blocks(rng, n)
+        elif kind == GGMLType.Q6_K:
+            data = rand_q6k_blocks(rng, n)
+        else:  # F16
+            data = (rng.standard_normal(n, dtype=np.float32)
+                    * cfg.dim ** -0.5).astype(np.float16).view(np.uint8)
+        w.add_raw_tensor(name, tuple(reversed(shape)), kind, data)
+
+    def f32(name, shape):
+        w.add_tensor(name, np.ones(shape, np.float32), GGMLType.F32)
+
+    raw("token_embd.weight", (cfg.vocab_size, cfg.dim), GGMLType.F16)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        f32(p + "attn_norm.weight", (cfg.dim,))
+        raw(p + "attn_q.weight", (cfg.dim, cfg.dim), GGMLType.Q4_K)
+        raw(p + "attn_k.weight", (kv_dim, cfg.dim), GGMLType.Q4_K)
+        raw(p + "attn_v.weight", (kv_dim, cfg.dim), GGMLType.Q6_K)
+        raw(p + "attn_output.weight", (cfg.dim, cfg.dim), GGMLType.Q4_K)
+        f32(p + "ffn_norm.weight", (cfg.dim,))
+        raw(p + "ffn_gate.weight", (cfg.ffn_dim, cfg.dim), GGMLType.Q4_K)
+        raw(p + "ffn_up.weight", (cfg.ffn_dim, cfg.dim), GGMLType.Q4_K)
+        raw(p + "ffn_down.weight", (cfg.dim, cfg.ffn_dim), GGMLType.Q6_K)
+    f32("output_norm.weight", (cfg.dim,))
+    raw("output.weight", (cfg.vocab_size, cfg.dim), GGMLType.Q6_K)
+    w.write()
+    return cfg
+
+
 def spm_byte_vocab() -> tuple[list[str], list[int], list[float]]:
     """Minimal SentencePiece-style vocab: specials + full byte fallback."""
     tokens = ["<unk>", "<s>", "</s>", "▁"]

@@ -18,7 +18,7 @@ shared vocabulary for the in-process resilience layer:
   watchdog samples (engine/watchdog.py).  Engines never import the
   watchdog; the heartbeat is the entire interface between them.
 - :class:`EngineUnavailable` / :class:`DeadlineExceeded` — the error
-  taxonomy the server maps to 503 / 408 (server/app.py), distinct from
+  classification the server maps to 503 / 408 (server/app.py), distinct from
   the generic engine-bug 500.
 """
 

@@ -136,3 +136,17 @@ def test_long_prompt_neighbor_does_not_truncate_short(mesh_engine):
     assert crowd["usage"]["completion_tokens"] == solo["usage"]["completion_tokens"]
     assert crowd["choices"][0]["message"]["content"] == \
         solo["choices"][0]["message"]["content"]
+
+
+def test_a_mesh_serves_xla_attention_and_refuses_flash_by_name(mesh_engine,
+                                                              tmp_path):
+    """The flash kernel has no partitioning rule, and JAX refuses to lower
+    a bare Mosaic kernel into a program that spans devices: a mesh of more
+    than one device resolves ``auto`` to ``xla`` and refuses ``pallas`` at
+    construction, not at the warm-up compile (found on four chips, PR 22)."""
+    assert mesh_engine.cfg.attn_impl == "xla"
+    path = str(tmp_path / "m.gguf")
+    write_tiny_llama_gguf(path)
+    with pytest.raises(ValueError, match="no partitioning rule"):
+        MeshEngine(path, dp=2, tp=2, batch_size=4, n_ctx=128,
+                   attn_impl="pallas")

@@ -1,6 +1,6 @@
-"""The driver's bench entry points must always produce one valid JSON line
-on the tiny CPU preset — these are the scripts the round is graded on, so a
-regression here is worse than a failing feature test."""
+"""The bench entry points must produce one valid JSON line on the tiny CPU
+preset, and must refuse — no result line, non-zero exit — to run a full-size
+preset where JAX finds no TPU."""
 
 from __future__ import annotations
 
@@ -27,80 +27,15 @@ def _run(script, extra_env=None, timeout=900):
     return parsed, out
 
 
-def test_maybe_seed_compile_cache(tmp_path):
-    """The cache-seed restore that protects the driver's post-restart
-    bench window: extracts only at the default repo-local location, only
-    ``.lfkt_xla_cache/`` members (``./``-normalized), never clobbers a
-    live cache, and degrades (False) on a bad seed instead of raising."""
-    import tarfile
-
-    sys.path.insert(0, REPO)
-    from bench import maybe_seed_compile_cache
-
-    def make_repo(seed_builder):
-        repo = tmp_path / f"repo{make_repo.n}"
-        make_repo.n += 1
-        (repo / "tools").mkdir(parents=True)
-        seed_builder(str(repo / "tools" / "xla_cache_seed.tgz"))
-        return str(repo)
-
-    make_repo.n = 0
-
-    def plain_seed(path, prefix="", stray=False):
-        src = tmp_path / f"src{make_repo.n}"
-        (src / ".lfkt_xla_cache").mkdir(parents=True)
-        (src / ".lfkt_xla_cache" / "entry1").write_text("x")
-        if stray:
-            (src / "stray.txt").write_text("evil")
-        with tarfile.open(path, "w:gz") as tf:
-            tf.add(src / ".lfkt_xla_cache",
-                   arcname=prefix + ".lfkt_xla_cache")
-            if stray:
-                tf.add(src / "stray.txt", arcname="stray.txt")
-
-    # happy path
-    repo = make_repo(plain_seed)
-    cache = os.path.join(repo, ".lfkt_xla_cache")
-    assert maybe_seed_compile_cache(repo, cache) is True
-    assert os.path.exists(os.path.join(cache, "entry1"))
-
-    # './'-prefixed member names still restore
-    repo = make_repo(lambda p: plain_seed(p, prefix="./"))
-    cache = os.path.join(repo, ".lfkt_xla_cache")
-    assert maybe_seed_compile_cache(repo, cache) is True
-    assert os.path.exists(os.path.join(cache, "entry1"))
-
-    # a live cache is never clobbered
-    repo = make_repo(plain_seed)
-    cache = os.path.join(repo, ".lfkt_xla_cache")
-    os.makedirs(cache)
-    with open(os.path.join(cache, "live"), "w") as f:
-        f.write("keep")
-    assert maybe_seed_compile_cache(repo, cache) is False
-    assert not os.path.exists(os.path.join(cache, "entry1"))
-
-    # a custom cache location is never seeded
-    repo = make_repo(plain_seed)
-    assert maybe_seed_compile_cache(repo, str(tmp_path / "elsewhere")) is False
-
-    # stray members outside .lfkt_xla_cache/ are not extracted
-    repo = make_repo(lambda p: plain_seed(p, stray=True))
-    cache = os.path.join(repo, ".lfkt_xla_cache")
-    assert maybe_seed_compile_cache(repo, cache) is True
-    assert not os.path.exists(os.path.join(repo, "stray.txt"))
-
-    # a seed with no cache members degrades cleanly
-    def bad_seed(path):
-        src = tmp_path / f"bad{make_repo.n}"
-        src.mkdir()
-        (src / "junk").write_text("j")
-        with tarfile.open(path, "w:gz") as tf:
-            tf.add(src / "junk", arcname="junk")
-
-    repo = make_repo(bad_seed)
-    cache = os.path.join(repo, ".lfkt_xla_cache")
-    assert maybe_seed_compile_cache(repo, cache) is False
-    assert not os.path.isdir(cache)
+def test_bench_full_size_preset_refuses_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", LFKT_BENCH_PRESET="llama3-8b")
+    for script in ("bench.py", "bench_server.py"):
+        out = subprocess.run(
+            [sys.executable, os.path.join(REPO, script)],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert out.returncode != 0, script
+        assert '"metric"' not in out.stdout, out.stdout[-500:]
+        assert "found 'cpu'" in out.stderr, out.stderr[-500:]
 
 
 def test_bench_tiny_smoke():

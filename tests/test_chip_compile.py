@@ -1,0 +1,263 @@
+"""Ask the TPU's compiler, without a chip: every Pallas kernel of the
+serving path is COMPILED (``interpret=False`` passed explicitly) for a
+described ``v5e:2x2`` device at the Llama-3-8B shapes.  Interpret mode — all
+the rest of tier-1 — cannot see a slice that is not aligned to the tiling or
+a kernel that wants more fast memory than it may use; this file can
+(/opt/skills/guides/on-chip-measurement §2, rehearsal 3).
+
+Rules of this file (they are what keeps the suite countable under
+``pytest -n 6 --dist loadfile``): the topology is described inside a
+module-scoped fixture, never while a module is imported; parametrize takes
+literal tuples only; every compile runs in the test's own process; all of
+it lives in this ONE file.  A compile that passes is not a chip run."""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep it out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    """jit → lower → compile ``fn`` on the described chip; returns the
+    compiled program's text."""
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    args = jax.tree.map(place, shapes)
+    return jax.jit(fn, keep_unused=True).lower(*args).compile().as_text()
+
+
+def S(*shape, dtype=bf16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _planes(fmt, n, k, layers=None):
+    kt = k // 2048
+    lead = () if layers is None else (layers,)
+    sm = S(*lead, kt, n, 128)
+    if fmt == "q4k":
+        return {"qs": S(*lead, n, k // 2, dtype=i8), "sm": sm}
+    if fmt == "q6k":
+        return {"q4": S(*lead, n, k // 2, dtype=i8),
+                "q2": S(*lead, n, k // 4, dtype=i8), "sm6": sm}
+    if fmt == "q5k":
+        return {"q5s": S(*lead, n, k // 2, dtype=i8),
+                "q5h": S(*lead, n, k // 8, dtype=i8), "sm5": sm}
+    return {"q8": S(*lead, n, k, dtype=i8), "sm8": sm}
+
+
+def _matmuls(fmt):
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as P
+
+    return {"q4k": (P.q4k_matmul, P.q4k_matmul_stacked),
+            "q5k": (P.q5k_matmul, P.q5k_matmul_stacked),
+            "q6k": (P.q6k_matmul, P.q6k_matmul_stacked),
+            "q8": (P.q8_matmul, P.q8_matmul_stacked)}[fmt]
+
+
+# the Q4_K_M tensor mix of Llama-3-8B (Q4_K and Q6_K) at every linear shape
+# of a layer, the Q6_K output head, and the Q5_K / Q8_0 kernels of BASELINE
+# config #3 — (format, k_in, n_out)
+@pytest.mark.parametrize("fmt,k,n", [
+    ("q4k", 4096, 4096), ("q4k", 4096, 1024), ("q4k", 4096, 14336),
+    ("q4k", 14336, 4096),
+    ("q6k", 4096, 4096), ("q6k", 4096, 1024), ("q6k", 4096, 14336),
+    ("q6k", 14336, 4096), ("q6k", 4096, 128256),
+    ("q5k", 4096, 4096), ("q5k", 4096, 14336),
+    ("q8", 4096, 4096), ("q8", 4096, 14336),
+])
+def test_fused_matmul_compiles(one_chip, fmt, k, n):
+    """Unstacked and stacked, one decode row and a 512-row prefill bucket
+    (which walks ``batched_rows`` and the TN<=256 cap).  The compiled
+    program must still hold the kernel — this is also where
+    ``_lane_repeat``'s ``pltpu.repeat`` branch meets the compiler."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import q4k_compatible
+
+    assert q4k_compatible(n, k, for_tpu=True)
+    plain, stacked = _matmuls(fmt)
+    for rows in (1, 512):
+        txt = _compile(one_chip, lambda x, w: plain(x, w, interpret=False),
+                       S(rows, k), _planes(fmt, n, k))
+        assert "tpu_custom_call" in txt
+        if n == 128256:          # the head is never stacked
+            continue
+        txt = _compile(
+            one_chip, lambda x, w, i: stacked(x, w, i, interpret=False),
+            S(rows, k), _planes(fmt, n, k, layers=2), S(dtype=i32))
+        assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("seq,quantized", [
+    (128, False), (256, False), (512, False), (1024, False),
+    (128, True), (1024, True),
+])
+def test_flash_attention_compiles(one_chip, seq, quantized):
+    """head_dim 128, 32/8 heads, the n_ctx 1024 ring, at the prefill
+    buckets; ``quantized`` is the int8-KV variant."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention
+
+    kd = i8 if quantized else bf16
+    shapes = [S(seq, 32, 128), S(8, 1024, 128, dtype=kd),
+              S(8, 1024, 128, dtype=kd), S(dtype=i32)]
+    if quantized:
+        shapes += [S(8, 1024, dtype=f32), S(8, 1024, dtype=f32)]
+
+    def fn(q, k, v, pos, *scales):
+        ks, vs = scales or (None, None)
+        return flash_attention(q, k, v, pos, sm_scale=128 ** -0.5,
+                               k_scale=ks, v_scale=vs, interpret=False)
+
+    assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("seq", [1, 128, 1024])
+def test_kv_quantize_compiles(one_chip, seq):
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.kvquant import quantize_kv_pallas
+
+    txt = _compile(one_chip, lambda x: quantize_kv_pallas(x, interpret=False),
+                   S(8, seq, 128))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("gtype", ["Q4_K", "Q5_K", "Q6_K", "Q8_0"])
+def test_load_dequant_kernel_compiles(one_chip, gtype):
+    """The dequant kernels ``load_params`` runs on the device.  They parse
+    block headers on the host, so they are compiled over real (random)
+    block bytes of one 256-row tile; the placed dummy argument puts the
+    program on the described chip."""
+    from llama_fastapi_k8s_gpu_tpu.gguf.constants import GGML_BLOCK_SIZES, GGMLType
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.dequant import _DEVICE_DEQUANT
+
+    gt = GGMLType[gtype]
+    block_elems, block_bytes = GGML_BLOCK_SIZES[gt]
+    n = 256 * 1024                      # >= one (256, 128) tile per format
+    buf = np.random.default_rng(0).integers(
+        0, 256, n // block_elems * block_bytes, dtype=np.uint8)
+
+    def fn(_placed):
+        return _DEVICE_DEQUANT[gt](buf, n, bf16, False)
+
+    with np.errstate(all="ignore"):     # random header bytes: inf/nan scales
+        assert "tpu_custom_call" in _compile(one_chip, fn, S(1, dtype=f32))
+
+
+def test_decode_loop_at_8b_geometry_is_refused(one_chip):
+    """``ops/pallas/decode_loop.py`` (off by default) does not compile for
+    the chip at the 8B geometry: the lowering refuses the (1, N) block of
+    its 2-D per-layer scale planes (not (8, 128)-aligned), and behind that
+    stands one layer's whole int8 weight set, ~218 MB, against 128 MiB of
+    VMEM.  Pinned as found and not repaired here — the engine degrades to
+    per-layer decode when the knob asks for the loop (engine/engine.py
+    probe).  If this starts to compile, the kernel was re-tiled: flip the
+    assertion."""
+    from llama_fastapi_k8s_gpu_tpu.models.config import LLAMA3_8B
+    from llama_fastapi_k8s_gpu_tpu.models.params import LOOP_LINEARS
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.decode_loop import decode_loop_step
+
+    cfg = dataclasses.replace(LLAMA3_8B, n_layers=2, decode_layer_unroll=1)
+    kv = cfg.n_kv_heads * cfg.head_dim
+    dims = {"wq": (cfg.dim, cfg.dim), "wk": (kv, cfg.dim), "wv": (kv, cfg.dim),
+            "wo": (cfg.dim, cfg.dim), "w_gate": (cfg.ffn_dim, cfg.dim),
+            "w_up": (cfg.ffn_dim, cfg.dim), "w_down": (cfg.dim, cfg.ffn_dim)}
+    layers = {nm: {"q": S(2, *dims[nm], dtype=i8), "s": S(2, dims[nm][0], dtype=f32)}
+              for nm in LOOP_LINEARS}
+    layers["attn_norm"] = S(2, cfg.dim, dtype=f32)
+    layers["ffn_norm"] = S(2, cfg.dim, dtype=f32)
+    cache = {"k": S(2, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim),
+             "v": S(2, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)}
+    fmts = dict.fromkeys(LOOP_LINEARS, "int8")
+
+    def fn(layers, cache, h, pos, layer0):
+        return decode_loop_step(layers, cache, h, pos, layer0, cfg, fmts,
+                                unroll=1, interpret=False)
+
+    with pytest.raises(Exception) as refusal:
+        _compile(one_chip, fn, layers, cache, S(1, cfg.dim), S(dtype=i32),
+                 S(dtype=i32))
+    print(f"decode_loop at 8B refused: {str(refusal.value)[:300]}")
+
+
+def _placed_on_four(topo):
+    """``placed(shape, dtype, *spec)``: shapes sharded over a dp=1, tp=4
+    mesh of the described devices."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(dp=1, tp=4, devices=topo.devices)
+    return lambda shape, dtype, *spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+
+def test_fused_matmul_on_a_tpu_mesh_is_refused(topo):
+    """Pinned as found on four real chips (PR 22): jax 0.9.0 never
+    registers ``custom_partitioning`` with the TPU plugin, so a fused
+    matmul whose weights are sharded over a mesh reaches XLA as a raw
+    ``CustomSPMDPartitioning`` call and is refused.  ``MeshEngine`` says so
+    at construction.  When this starts to compile, tensor parallelism over
+    fused weights is back: flip the assertion and drop that refusal."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q4k_matmul
+
+    placed = _placed_on_four(topo)
+    w = {"qs": placed((4096, 2048), i8, "tp", None),
+         "sm": placed((2, 4096, 128), bf16, None, "tp", None)}
+    with pytest.raises(Exception, match="CustomSPMDPartitioning"):
+        jax.jit(lambda x, w: q4k_matmul(x, w, interpret=False)).lower(
+            placed((8, 4096), bf16), w).compile()
+
+
+def test_flash_attention_on_a_tpu_mesh_is_refused(topo):
+    """A bare ``pallas_call`` cannot be lowered into a program that spans
+    devices; the mesh engines therefore serve XLA attention."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention
+
+    placed = _placed_on_four(topo)
+
+    def fn(q, k, v, pos):
+        return flash_attention(q, k, v, pos, sm_scale=128 ** -0.5,
+                               interpret=False)
+
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(fn).lower(placed((256, 32, 128), bf16, None, "tp", None),
+                          placed((8, 1024, 128), bf16, "tp", None, None),
+                          placed((8, 1024, 128), bf16, "tp", None, None),
+                          placed((), i32)).compile()

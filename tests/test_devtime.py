@@ -21,7 +21,6 @@ Four layers:
 from __future__ import annotations
 
 import logging
-import time
 
 import jax
 import jax.numpy as jnp
@@ -80,36 +79,23 @@ def test_wrapper_output_and_kwargs_pass_through(reg):
     assert float(out[0]) == 6.0
 
 
-def test_signature_fallback_without_cache_probe(reg):
-    calls = []
-
-    def plain(x):          # no _cache_size attr: the fallback path
-        calls.append(x.shape)
-        time.sleep(0.002)  # compile-scale wall: clears the fallback floor
-        return x
-
-    f = reg.timed_jit("fallback", plain)
-    f(jnp.ones(2))
-    f(jnp.ones(2))
-    f(jnp.ones(5))
-    c = reg.counters()["fallback"]
-    assert c["dispatches"] == 3
-    assert c["compiles"] == 2          # one per distinct signature
-    assert len(calls) == 3
+def test_timed_jit_refuses_a_callable_that_is_not_jitted(reg):
+    """The wrapper counts compiles from the jit cache's own size; a plain
+    function has none, and wrapping one is a programming error."""
+    with pytest.raises(AttributeError):
+        reg.timed_jit("plain", lambda x: x)
 
 
-def test_fallback_fast_dispatch_skips_signature_walk(reg, monkeypatch):
-    """Sub-floor calls on the no-probe path must never pay the O(leaves)
-    signature walk — the cost the review flagged on old-jax decode."""
+def test_warm_dispatch_skips_signature_walk(reg, monkeypatch):
+    """A dispatch that did not grow the jit cache must never pay the
+    O(leaves) signature walk."""
+    f = reg.timed_jit("warm", jax.jit(lambda x: x))
+    f(jnp.ones(2))                           # the one compile
     monkeypatch.setattr(devtime, "_signature",
-                        lambda *a: pytest.fail("signature on fast path"))
-    # generous floor: a preempted lambda on a loaded box must still skip
-    monkeypatch.setattr(devtime, "_FALLBACK_COMPILE_FLOOR_S", 10.0)
-    f = reg.timed_jit("fastpath", lambda x: x)   # plain fn, µs calls
+                        lambda *a: pytest.fail("signature on warm path"))
     f(jnp.ones(2))
-    f(jnp.ones(5))
-    c = reg.counters()["fastpath"]
-    assert c["dispatches"] == 2 and c["compiles"] == 0
+    c = reg.counters()["warm"]
+    assert c["dispatches"] == 2 and c["compiles"] == 1
 
 
 def test_signature_describes_arrays_and_statics():
@@ -187,19 +173,14 @@ def test_fresh_consumer_charges_no_drop_for_prehistory(reg):
     assert reg.events_dropped == 7
 
 
-def test_reset_rearms_fallback_compile_detection(reg):
-    """reset() must zero EVERY ledger including fallback signature
-    membership: on the no-cache-probe path a signature seen before the
-    reset is a compile again after it, not permanently suppressed."""
-    def plain(x):          # no _cache_size attr: the fallback path
-        time.sleep(0.002)  # compile-scale wall: clears the fallback floor
-        return x
-
-    f = reg.timed_jit("rf", plain)
+def test_reset_zeroes_the_signature_ledger(reg):
+    """reset() must zero EVERY ledger including signature membership: a
+    signature seen before the reset is new again after it."""
+    f = reg.timed_jit("rf", jax.jit(lambda x: x))
     f(jnp.ones(2))
     assert reg.counters()["rf"]["compiles"] == 1
     reg.reset()
-    f(jnp.ones(2))         # same signature, post-reset
+    f(jnp.ones(5))         # a fresh compile, post-reset
     assert reg.counters()["rf"]["compiles"] == 1
     assert reg.counters()["rf"]["signatures"] == 1
 
@@ -332,22 +313,31 @@ def test_disarmed_engine_decode_path_is_poison_proof(monkeypatch, model_path):
 # ---------------------------------------------------------------------------
 
 def test_storm_detected_on_real_engine_tail_chunk_churn(model_path):
-    """Decode tail chunks (max_tokens % decode_chunk) mint new n_steps
-    static signatures for the decode_chunk program.  With the budget
-    pinned to 1, the second distinct tail is a storm — detected at the
-    compile itself, i.e. within the very request that churned."""
-    eng = Engine(model_path, n_ctx=128, decode_chunk=8, max_gen_tokens=32,
-                 prefill_buckets=(32, 64, 128), prefix_cache=False)
+    """``n_steps`` is a static argument of the decode_chunk program.  A
+    BUDGET tail (max_tokens % decode_chunk) no longer mints a signature —
+    the engine dispatches a full chunk and drops the surplus on the host
+    (PR 22: on the chip that compile cost the first request its timeout).
+    The RING's own end still shortens the last chunk, by n_prompt mod
+    decode_chunk: with the budget pinned to 1, the second distinct ring
+    tail is a storm — detected at the compile itself, i.e. within the very
+    request that churned."""
+    eng = Engine(model_path, n_ctx=64, decode_chunk=8, max_gen_tokens=64,
+                 prefill_buckets=(32, 64), prefix_cache=False)
     old_budget = DEVTIME.budget
     DEVTIME.reset()
     DEVTIME.configure(budget=1)
     try:
-        # full chunks only: one n_steps signature for decode_chunk
-        eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=8)
+        # budget tails of 0, 3 and 5: one n_steps signature all the same
+        for n in (8, 3, 5):
+            eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=n)
         assert DEVTIME.storms() == []
-        # tail chunks 3 and 5: two MORE n_steps signatures -> storm
-        eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=3)
-        eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=5)
+        # generate to the end of the ring from two prompt lengths (52 and
+        # 53 tokens of the 64: ring tails of 2 and 1 steps): two MORE
+        # n_steps signatures -> storm
+        for extra in ("!" * 15, "!" * 16):
+            eng.create_chat_completion(
+                [{"role": "user", "content": MSGS[0]["content"] + extra}],
+                temperature=0.0)
         storms = {s["program"] for s in DEVTIME.storms()}
         assert "decode_chunk" in storms, DEVTIME.snapshot()["programs"]
         assert DEVTIME.storms_total >= 1

@@ -660,7 +660,7 @@ def test_lint_report_baseline_ratchet(tmp_path):
 
 
 def test_ci_gate_aggregates_lint_and_manifest():
-    """tools/ci_gate.py (POST_SUITE_CHECKLIST step 1): one entry point,
+    """tools/ci_gate.py: one entry point,
     both repo gates, --json machine shape, exit 0 on a clean tree.
 
     The three pytest-subset checks are --skip'd here: they re-spawn

@@ -400,7 +400,7 @@ def test_serial_engine_span_tree(model_path):
     engine_span = names["engine"][0]
     assert engine_span["attrs"]["engine"] == "Engine"
     assert engine_span["attrs"]["completion_tokens"] >= 1
-    # streaming rides the same taxonomy
+    # streaming rides the same classes
     tr2 = t.start()
     list(eng.create_chat_completion(MSGS, stream=True, temperature=0.0,
                                     max_tokens=8, trace=tr2))

@@ -47,11 +47,7 @@ def test_all_template_values_exist():
 def test_chart_env_vars_are_read_by_config():
     cfg_src = open(os.path.join(
         REPO, "llama_fastapi_k8s_gpu_tpu", "utils", "config.py")).read()
-    # LFKT_COMPILE_CACHE_DIR is honored by utils/jaxcache.py (called from
-    # Engine init), not the Settings loader
-    cache_src = open(os.path.join(
-        REPO, "llama_fastapi_k8s_gpu_tpu", "utils", "jaxcache.py")).read()
-    known = set(re.findall(r'"(LFKT_[A-Z0-9_]+)"', cfg_src + cache_src))
+    known = set(re.findall(r'"(LFKT_[A-Z0-9_]+)"', cfg_src))
     dep = open(os.path.join(REPO, "helm", "templates", "deployment.yaml")).read()
     used = set(re.findall(r"name: (LFKT_[A-Z0-9_]+)", dep))
     assert used, "deployment should set LFKT_* env vars"

@@ -12,7 +12,7 @@ pin, per engine flavor:
   the warmed programs only (this pin found and now guards two real
   holes: the sharded engines' chunk-2 donated-state resharding compile,
   fixed by the two-chunk warmup, and the serial tail-chunk compile,
-  exercised deliberately below);
+  fixed by always dispatching full chunks — pinned below);
 - **each request dispatches exactly D per program** — an extra dispatch
   per decode chunk is the launch/DMA overhead the kernel-looping roadmap
   item exists to eliminate; it must never sneak in unmeasured.
@@ -78,7 +78,12 @@ eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
 a = snap()
 out["serial_req"] = delta(w, a)
 eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
-out["serial_req2"] = delta(a, snap())
+b = snap()
+out["serial_req2"] = delta(a, b)
+# a budget that leaves a tail: 1 + 4 + 4 + 2 of a third, full chunk
+r = eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=11)
+out["serial_tail"] = delta(b, snap())
+out["serial_tail_tokens"] = {"completion": (r["usage"]["completion_tokens"], 0)}
 
 # -- mesh-batched ---------------------------------------------------------
 DEVTIME.reset()
@@ -179,6 +184,16 @@ def test_serial_request_dispatch_budget(pins):
             "decode_chunk": (0, 2)}
     assert pins["serial_req"] == want
     assert pins["serial_req2"] == want
+
+
+def test_serial_budget_tail_compiles_nothing(pins):
+    """A budget that is not 1 + k * decode_chunk ends in a FULL chunk whose
+    surplus is dropped on the host: ``n_steps`` is static, and a shorter
+    tail was a program warm-up never compiled (found on the chip: ~19 s of
+    compile inside the first 512-token request, PR 22)."""
+    assert pins["serial_tail"] == {
+        "prefill": (0, 1), "first_sample": (0, 1), "decode_chunk": (0, 3)}
+    assert pins["serial_tail_tokens"]["completion"][0] == 11
 
 
 def test_mesh_request_dispatch_budget(pins):

@@ -483,7 +483,7 @@ def test_continuous_watchdog_full_lifecycle(tmp_path):
             fut.result(timeout=60)
         assert isinstance(eng.failure(), FaultError)
 
-        # submissions during the outage get the 503-mapped taxonomy error
+        # submissions during the outage get the 503-mapped error class
         with pytest.raises(EngineUnavailable):
             eng.submit(MSGS, max_tokens=4)
 
@@ -523,7 +523,7 @@ def test_continuous_recover_refused_after_deliberate_shutdown(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# server integration: taxonomy mapping + probe routes
+# server integration: error-class mapping + probe routes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.anyio

@@ -1,9 +1,8 @@
 """Attribute the in-model fused-Q4_K decode gap (run ALONE on the chip).
 
 BENCH_r03 interim runs put the full-model fused-Q4_K decode at ~53.5 tok/s
-(18.7 ms/token) while the int8 path does 80.6 (12.4 ms) — yet the per-op
-microbench (docs/bench/qmatmul_v2_microbench_2026-07-29.json) has the fused
-kernel beating int8 at every 8B shape.  This script times, with the same
+(18.7 ms/token) while the int8 path does 80.6 (12.4 ms) — yet a per-op
+microbench had the fused kernel beating int8 at every 8B shape.  This script times, with the same
 hoist-proof scan harness, the pieces that differ between the two paths:
 
 - chained per-layer matmul stacks (the 7 linears of a Llama layer, output

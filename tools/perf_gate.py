@@ -25,8 +25,7 @@ the q4km baseline), a ``device`` mismatch refuses the comparison, and
 when both sides carry a provenance stamp (utils/provenance.py) a
 knob-fingerprint mismatch is reported (fatal with ``--strict-knobs``).
 
-Wired into tools/POST_SUITE_CHECKLIST.md: run it on every fresh artifact
-BEFORE banking; smoke-tested in tier-1 against a planted regression
+Run it on every fresh artifact BEFORE banking; smoke-tested in tier-1 against a planted regression
 (tests/test_bench_entrypoints.py).
 """
 
