@@ -37,7 +37,7 @@ from ..models.llama import init_cache
 from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
-from ..obs.trace import annotate_all_inflight
+from ..obs.trace import annotate_all_inflight, phase, rid
 from ..parallel.batched import (
     batched_generate_chunk_perlane_jit,
     batched_spec_verify_perlane_jit,
@@ -215,7 +215,7 @@ class _Slot:
                  "sink", "abandoned", "dec", "n_emitted", "sent_bytes",
                  "held", "cid", "created", "finished", "pending_first",
                  "reused", "deadline", "abort", "trace", "pspan", "dspan",
-                 "t_chunk")
+                 "t_chunk", "fspan", "fwave")
 
     def __init__(self, item: _Item, budget, n_prompt, ids):
         self.future = item.future
@@ -225,6 +225,8 @@ class _Slot:
         self.abort = item.abort
         self.trace = item.trace   # span sinks (None when sampled out)
         self.pspan = None         # the admission's "prefill" span
+        self.fspan = None         # its open "first_token" child
+        self.fwave = 0            # chunks dispatched when fspan opened
         self.dspan = None         # this slot's lane-occupancy "decode" span
         self.t_chunk = 0.0        # previous harvest time (chunk-span starts)
         self.finished = False   # set when resolved; the pipelined loop may
@@ -276,7 +278,8 @@ class ContinuousEngine(MeshEngine):
     _THREAD_CONFINED = (
         "_bstate", "_lane_st", "_scratch_cache", "_adm", "_lane_claims",
         "_prefix_stats", "_spec_stats", "_stats", "_loop_error",
-        "_adm_budget", "_lane_idle_s", "_mem_hot_prev",
+        "_adm_budget", "_lane_idle_s", "_mem_hot_prev", "_totals",
+        "_slices_queued",
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
@@ -305,6 +308,13 @@ class ContinuousEngine(MeshEngine):
         #: cumulative idle lane-seconds (free lanes × wave wall), exported
         #: as scheduler_lane_idle_seconds / the lane_idle_seconds gauge
         self._lane_idle_s = 0.0
+        #: the wave, counted where it happens: cumulative since start,
+        #: one plain add per wave (or per slice) on the scheduler thread,
+        #: exported through scheduler_stats() as scheduler_<key> gauges
+        self._totals = self._zero_totals()
+        #: prefill slices queued on the device since the last decode chunk
+        #: was dispatched: the next chunk's span names them as its wait
+        self._slices_queued = 0
         self._adm: dict | None = None   # in-flight chunked admission
         # -- lane-prefix reuse (default ON since round 6; the admission
         # -- controller closed the interference gap that kept it off) ------
@@ -373,6 +383,18 @@ class ContinuousEngine(MeshEngine):
         self._thread = threading.Thread(
             target=self._loop, name="lfkt-scheduler", daemon=True)
         self._thread.start()
+
+    @staticmethod
+    def _zero_totals() -> dict:
+        """The per-wave counters (docs/OBSERVABILITY.md "The wave").  A
+        wave is one loop pass that dispatched a decode chunk: the chunk,
+        the admission slices queued behind it, and the fetch + harvest of
+        the chunk before it.  ``lane_live_seconds`` + the older
+        ``lane_idle_seconds`` is ``batch_size`` x ``wave_seconds``."""
+        return {"waves": 0, "wave_seconds": 0.0, "lane_live_seconds": 0.0,
+                "fetch_wait_seconds": 0.0, "admit_seconds": 0.0,
+                "admit_slices": 0, "admit_tokens": 0,
+                "harvest_seconds": 0.0, "chunks_dispatched": 0}
 
     # ------------------------------------------------------------------
     def submit(self, messages: Sequence[dict], *, temperature: float = 0.2,
@@ -549,6 +571,8 @@ class ContinuousEngine(MeshEngine):
         self._items.clear()
         self._lane_claims = [None] * self.batch_size
         self._lane_idle_s = 0.0
+        self._totals = self._zero_totals()
+        self._slices_queued = 0
         if self._adm_ctl is not None:
             # fresh controller: post-recovery traffic should not inherit
             # the pre-crash EMAs (a wedged device reads as max pressure)
@@ -735,7 +759,10 @@ class ContinuousEngine(MeshEngine):
             pspan = item.trace.span("prefill", t0=t0)
         lease = None
         try:
-            ids = self.tokenize_messages(item.messages)
+            with phase("tokenize", rid=rid(item.trace)):
+                ids = self.tokenize_messages(item.messages)
+            if pspan is not None:
+                pspan.child("tokenize", t0=t0).set(n_prompt=len(ids)).end()
             if len(ids) >= self.cfg.n_ctx:
                 raise ValueError(
                     f"Requested tokens ({len(ids)}) exceed context window "
@@ -804,7 +831,7 @@ class ContinuousEngine(MeshEngine):
                 "st": sampling_tensors(item.sp),
                 "seed": item.seed if item.seed is not None else self._next_seed(),
                 "t0": t0, "offset": reuse, "reused": reuse, "logits": None,
-                "span": pspan, "lease": lease,
+                "span": pspan, "lease": lease, "t_slice": t0,
             }
         except Exception as e:  # noqa: BLE001 — per-request isolation
             self._note_error(e)
@@ -858,9 +885,9 @@ class ContinuousEngine(MeshEngine):
         Keeps the logits of the slice containing the last real token.
 
         The dispatch is async — its host wall (observed into the
-        ``prefill_slice_seconds`` histogram and the span's per-slice
-        event) is slice prep + device enqueue, overlapping the previous
-        slice's / decode chunk's compute; a long wall here means the
+        ``prefill_slice_seconds`` histogram and the request's
+        ``prefill_slice`` span) is slice prep + device enqueue, overlapping
+        the previous slice's / decode chunk's compute; a long wall means the
         device queue pushed back (the interference signal the admission
         controller is closing)."""
         t_s = time.time()
@@ -876,18 +903,23 @@ class ContinuousEngine(MeshEngine):
         C = min(self._prefill_chunk, adm["bucket"] - off)
         sl = jnp.asarray(adm["padded"][off:off + C])
         li = min(max(adm["n_prompt"] - 1 - off, 0), C - 1)
-        logits, cache = prefill_chunk_jit(
-            self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
-            self._scratch_cache)
+        with phase("admit_slice", rid=rid(adm["item"].trace), offset=off,
+                   tokens=C):
+            logits, cache = prefill_chunk_jit(
+                self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
+                self._scratch_cache)
         self._scratch_cache = cache
         if off <= adm["n_prompt"] - 1 < off + C:
             adm["logits"] = logits
         adm["offset"] = off + C
-        dt = time.time() - t_s
-        self._observe_slice(dt)
-        if adm.get("span") is not None:
-            adm["span"].event("prefill_slice", offset=off, tokens=C,
-                              host_s=round(dt, 6))
+        t_e = adm["t_slice"] = time.time()
+        self._observe_slice(t_e - t_s)
+        tot = self._totals
+        tot["admit_slices"] += 1
+        tot["admit_tokens"] += C
+        self._slices_queued += 1
+        self._slice_span(adm.get("span"), t_s, t_e, off, C,
+                         wave=tot["chunks_dispatched"])
 
     def _finish_admission(self, adm: dict, lane: int, slots: list) -> None:
         """Prefill complete: sample the first token, write the lane, install.
@@ -921,12 +953,18 @@ class ContinuousEngine(MeshEngine):
             slot.sp = item.sp
             slot.t_admit = adm["t0"]
             slot.pspan = adm.get("span")
+            deferred = any(s is not None for s in slots)
+            if slot.pspan is not None:
+                # last slice dispatched -> first token on the host
+                slot.fspan = slot.pspan.child(
+                    "first_token", t0=adm["t_slice"]).set(deferred=deferred)
+                slot.fwave = self._totals["chunks_dispatched"]
             slot.reused = adm.get("reused", 0)
             if slot.reused:     # count only realized reuse (lane written)
                 self._prefix_stats[f"{self._reuse_stat}_hits"] += 1
                 self._prefix_stats[
                     f"{self._reuse_stat}_reused_tokens"] += slot.reused
-            if any(s is not None for s in slots):
+            if deferred:
                 try:
                     token.copy_to_host_async()
                 except Exception:  # noqa: BLE001 — optional fast path
@@ -963,6 +1001,10 @@ class ContinuousEngine(MeshEngine):
         close, and the tokens=1 note must not clobber the per-chunk token
         counts recorded since — so the span reference is consumed here."""
         if slot.pspan is not None:
+            if slot.fspan is not None:
+                slot.fspan.set(waves=self._totals["chunks_dispatched"]
+                               - slot.fwave).end()
+                slot.fspan = None
             if slot.ttft_s is not None:
                 slot.pspan.set(ttft_s=round(slot.ttft_s, 6))
             slot.pspan.end()
@@ -1195,6 +1237,7 @@ class ContinuousEngine(MeshEngine):
         Returns True if any progress was made."""
         budget = self._adm_budget
         progressed = False
+        t0 = time.time()
         while budget > 0:
             spent = self._admit_step(slots)
             if spent is None:
@@ -1203,6 +1246,7 @@ class ContinuousEngine(MeshEngine):
             budget -= spent
             if self._adm is not None and self._adm_ctl is None:
                 break   # static mode: long admission yields after one slice
+        self._totals["admit_seconds"] += time.time() - t0
         return progressed
 
     def _note_mem_pressure(self) -> None:
@@ -1229,10 +1273,11 @@ class ContinuousEngine(MeshEngine):
     def scheduler_stats(self) -> dict:
         """Point-in-time scheduler occupancy for ``/metrics`` (lanes_live,
         pending queue depth, whether an admission prefill is in flight,
-        the live admission budget and its controller EMAs, cumulative
-        lane-idle seconds) — the observability the lane model adds over
-        the reference's single queue-depth number.  Written once per loop
-        iteration; reads are a dict swap, no lock needed."""
+        the live admission budget and its controller EMAs) and the
+        wave's cumulative counters (``_zero_totals`` + lane-idle seconds)
+        — the observability the lane model adds over the reference's
+        single queue-depth number.  Written once per loop iteration;
+        reads are a dict swap, no lock needed."""
         out = {"batch_size": self.batch_size, **self._stats}
         if self._lane_prefix or self._kv_paged:
             out.update(self._prefix_stats)
@@ -1241,7 +1286,8 @@ class ContinuousEngine(MeshEngine):
         return out
 
     def _harvest(self, pre: list, chunk: "np.ndarray", slots: list,
-                 counts: "np.ndarray | None" = None) -> None:
+                 counts: "np.ndarray | None" = None,
+                 wave: int = 0, admit_slices: int = 0) -> None:
         """Fold one fetched decode chunk into its lanes' slots.
 
         ``pre`` is the lane snapshot taken when the chunk was DISPATCHED —
@@ -1256,7 +1302,11 @@ class ContinuousEngine(MeshEngine):
 
         ``counts`` (spec-verify rounds): lane ``l`` emitted only
         ``chunk[:counts[l], l]`` — rows beyond that are samples conditioned
-        on rejected draft tokens and must be discarded."""
+        on rejected draft tokens and must be discarded.
+
+        ``wave`` / ``admit_slices`` (the chunk's dispatch number and the
+        prefill slices queued on the device ahead of it) ride on each
+        traced lane's ``decode_chunk`` span: a long chunk names its cause."""
         stop_ids = self.tokenizer.stop_ids
         now = time.time()
         for lane in range(len(pre)):
@@ -1311,7 +1361,8 @@ class ContinuousEngine(MeshEngine):
                     break
             if slot.dspan is not None:
                 slot.dspan.child("decode_chunk", t0=slot.t_chunk).set(
-                    tokens=len(slot.gens),
+                    tokens=len(slot.gens), wave=wave,
+                    admit_slices=admit_slices,
                     kind="verify" if counts is not None else "chunk").end(now)
                 slot.t_chunk = now
                 slot.trace.note(tokens=len(slot.gens))
@@ -1322,6 +1373,7 @@ class ContinuousEngine(MeshEngine):
                 if self._emit_stream(slot, done=False) == "stop":
                     self._finish_slot(slot, "stop")
                     self._free_lane(lane, slot, slots)
+        self._totals["harvest_seconds"] += time.time() - now
 
     def _spec_drafts(self, slots: list) -> "tuple | None":
         """(drafts (B, D) int32, hit_lanes) — zero rows for lanes with no
@@ -1417,48 +1469,67 @@ class ContinuousEngine(MeshEngine):
                     got = self._spec_drafts(slots)
                     if got is not None and pending is not None:
                         self._harvest(pending[0], np.asarray(pending[1]),
-                                      slots)
+                                      slots, wave=pending[2],
+                                      admit_slices=pending[3])
                         pending = None
                         got = self._spec_drafts(slots)  # histories advanced
                     while not self._stop and got is not None:
                         self._spec_round(slots, got)
                         got = self._spec_drafts(slots)
 
-                if any(s is not None for s in slots):
-                    pre = list(slots)   # lanes live in THIS chunk
-                    FAULTS.fire("decode_step")
-                    self._bstate, toks = batched_generate_chunk_perlane_jit(
-                        self.params, self.cfg, self._bstate, self._lane_st,
-                        n_steps=self.decode_chunk, top_k=self._max_top_k)
-                    self._spec_stats["chunk_steps"] += 1
-                    dispatched = (pre, toks)
-                else:
-                    dispatched = None
+                tot = self._totals
+                # lfkt.wave in a capture: this pass's device work, from the
+                # chunk's dispatch to the previous chunk's harvest
+                with phase("wave", wave=tot["chunks_dispatched"] + 1,
+                           lanes_live=sum(s is not None for s in slots)):
+                    if any(s is not None for s in slots):
+                        pre = list(slots)   # lanes live in THIS chunk
+                        FAULTS.fire("decode_step")
+                        tot["chunks_dispatched"] += 1
+                        wave = tot["chunks_dispatched"]
+                        with phase("dispatch_chunk", wave=wave,
+                                   lanes_live=sum(s is not None for s in pre)):
+                            self._bstate, toks = \
+                                batched_generate_chunk_perlane_jit(
+                                    self.params, self.cfg, self._bstate,
+                                    self._lane_st, n_steps=self.decode_chunk,
+                                    top_k=self._max_top_k)
+                        self._spec_stats["chunk_steps"] += 1
+                        # (lanes, tokens, dispatch number, slices queued on
+                        # the device ahead of this chunk)
+                        dispatched = (pre, toks, wave, self._slices_queued)
+                        self._slices_queued = 0
+                    else:
+                        dispatched = None
 
-                # ---- overlap: admission prefills run while the chunk
-                # executes, up to the per-iteration token budget (several
-                # complete short admissions, or one slice of a long one);
-                # each lane write queues after the dispatched chunks, and an
-                # admitted request's tokens start with the chunk dispatched
-                # NEXT iteration (pre[] snapshots who gets each chunk's
-                # rows).  Chunked prefill bounds the per-iteration stall to
-                # the budget even for full-bucket prompts.
-                self._admit_round(slots)
+                    # ---- overlap: admission prefills run while the chunk
+                    # executes, up to the per-iteration token budget (several
+                    # complete short admissions, or one slice of a long one);
+                    # each lane write queues after the dispatched chunks, and an
+                    # admitted request's tokens start with the chunk dispatched
+                    # NEXT iteration (pre[] snapshots who gets each chunk's
+                    # rows).  Chunked prefill bounds the per-iteration stall to
+                    # the budget even for full-bucket prompts.
+                    self._admit_round(slots)
 
-                # ---- harvest the PREVIOUS chunk (fetch blocks only until
-                # that chunk is done; the one dispatched above keeps the
-                # device busy meanwhile).  The fetch's blocking time IS the
-                # decode-pressure signal: a long wait means the device was
-                # still decoding when the host came back (admission slices
-                # queued this wave delay the NEXT chunk, surfacing here one
-                # wave later); a near-zero wait means the device sat idle —
-                # the admission controller converts that slack into budget.
-                fetch_wait = 0.0
-                if pending is not None:
-                    t_f = time.time()
-                    chunk_np = np.asarray(pending[1])
-                    fetch_wait = time.time() - t_f
-                    self._harvest(pending[0], chunk_np, slots)
+                    # ---- harvest the PREVIOUS chunk (fetch blocks only until
+                    # that chunk is done; the one dispatched above keeps the
+                    # device busy meanwhile).  The fetch's blocking time IS the
+                    # decode-pressure signal: a long wait means the device was
+                    # still decoding when the host came back (admission slices
+                    # queued this wave delay the NEXT chunk, surfacing here one
+                    # wave later); a near-zero wait means the device sat idle —
+                    # the admission controller converts that slack into budget.
+                    fetch_wait = 0.0
+                    if pending is not None:
+                        t_f = time.time()
+                        with phase("fetch", wave=pending[2]):
+                            chunk_np = np.asarray(pending[1])
+                        fetch_wait = time.time() - t_f
+                        with phase("harvest", wave=pending[2]):
+                            self._harvest(pending[0], chunk_np, slots,
+                                          wave=pending[2],
+                                          admit_slices=pending[3])
                 now = time.time()
                 wave_s = max(now - t_prev_wave, 0.0)
                 t_prev_wave = now
@@ -1468,6 +1539,10 @@ class ContinuousEngine(MeshEngine):
                     # idle lane-seconds: free lanes while others decode are
                     # lost throughput (the admission controller's raw signal)
                     self._lane_idle_s += (B - live_wave) * wave_s
+                    tot["waves"] += 1
+                    tot["wave_seconds"] += wave_s
+                    tot["lane_live_seconds"] += live_wave * wave_s
+                    tot["fetch_wait_seconds"] += fetch_wait
                     if self._adm_ctl is not None:
                         # HBM headroom joins the wave signals (lfkt-mem):
                         # disarmed/stat-less, pressure() is one attribute
@@ -1488,6 +1563,7 @@ class ContinuousEngine(MeshEngine):
                     "adm_budget_tokens": self._adm_budget,
                     "lane_idle_seconds": round(self._lane_idle_s, 3),
                     "mem_pressure": int(mem_hot),
+                    **tot,
                 }
                 if self._adm_ctl is not None:
                     stats.update(self._adm_ctl.stats())

@@ -44,10 +44,10 @@ from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
 from ..obs.memledger import register_component, tree_nbytes
+from ..obs.trace import arm_phases, phase, rid
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
 from ..utils.jaxcache import setup_compile_cache
-from ..utils.tracing import maybe_profile
 
 logger = logging.getLogger(__name__)
 
@@ -262,6 +262,7 @@ class Engine:
         #: "lfkt_timings" so callers never need this shared field.
         self.last_timings: dict | None = None
         setup_compile_cache()
+        arm_phases()   # lfkt.* phases emit iff /debug/profile can be armed
 
         #: coarse wall-clock attribution of model load (tokenizer build,
         #: fused-kernel compile probes, weight prep+transfer) — surfaced
@@ -710,6 +711,16 @@ class Engine:
             except Exception:  # noqa: BLE001 — telemetry must never fail serving
                 pass
 
+    @staticmethod
+    def _slice_span(pspan, t_s: float, t_e: float, offset: int, tokens: int,
+                    **attrs) -> None:
+        """One prefill program's host dispatch (start -> return of the jit
+        call) as a ``prefill_slice`` child of the traced ``prefill`` span;
+        nothing for a request that is sampled out."""
+        if pspan is not None:
+            pspan.child("prefill_slice", t0=t_s).set(
+                offset=offset, tokens=tokens, **attrs).end(t_e)
+
     def _prefill_padded(self, ids: list, n_prompt: int, bucket: int,
                         cache, pspan=None):  # lfkt: holds[_lock]
         """Bucket prefill, monolithic or sliced: returns (logits, cache).
@@ -730,9 +741,16 @@ class Engine:
         tests/test_prefill_pipeline.py on every engine flavor.
         """
         if not self._slices_prefill(bucket):
+            t_s = time.time()
             padded = ids + [0] * (bucket - n_prompt)
-            return self._prefill_call(
-                jnp.asarray(padded, jnp.int32), jnp.int32(n_prompt), cache)
+            with phase("prefill_slice", rid=rid(pspan), offset=0,
+                       tokens=bucket):
+                out = self._prefill_call(
+                    jnp.asarray(padded, jnp.int32), jnp.int32(n_prompt),
+                    cache)
+            # the one-program prompt: one slice
+            self._slice_span(pspan, t_s, time.time(), 0, bucket)
+            return out
         C = self._prefill_chunk
         padded_np = np.zeros((bucket,), np.int32)
         padded_np[:n_prompt] = ids
@@ -745,21 +763,20 @@ class Engine:
             n = min(C, bucket - off)
             sl = jnp.asarray(padded_np[off:off + n])
             li = min(max(last - off, 0), n - 1)
-            lg, cache = prefill_chunk_jit(
-                self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
-                cache)
-            if off <= last < off + n:
-                logits = lg
-            inflight.append(lg)
-            if len(inflight) > self._prefill_overlap:
-                # double-buffer bound: wait for the OLDEST slice so at most
-                # `overlap` slices are queued un-synced on the device
-                jax.block_until_ready(inflight.popleft())
-            dt = time.time() - t_s
-            self._observe_slice(dt)
-            if pspan is not None:
-                pspan.event("prefill_slice", offset=off, tokens=n,
-                            host_s=round(dt, 6))
+            with phase("prefill_slice", rid=rid(pspan), offset=off, tokens=n):
+                lg, cache = prefill_chunk_jit(
+                    self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
+                    cache)
+                if off <= last < off + n:
+                    logits = lg
+                inflight.append(lg)
+                if len(inflight) > self._prefill_overlap:
+                    # double-buffer bound: wait for the OLDEST slice so at
+                    # most `overlap` slices are queued un-synced on the device
+                    jax.block_until_ready(inflight.popleft())
+            t_e = time.time()
+            self._observe_slice(t_e - t_s)
+            self._slice_span(pspan, t_s, t_e, off, n)
             off += n
         return logits, cache
 
@@ -904,8 +921,12 @@ class Engine:
         t0 = time.time()
         self.heartbeat.beat()
         FAULTS.fire("prefill")
-        ids = pre_ids if pre_ids is not None \
-            else self.tokenize_messages(messages)
+        if pre_ids is not None:
+            ids, t_tok = pre_ids, None
+        else:
+            with phase("tokenize", rid=rid(espan)):
+                ids = self.tokenize_messages(messages)
+            t_tok = time.time()
         n_prompt = len(ids)
         if n_prompt >= self.cfg.n_ctx:
             raise ValueError(
@@ -929,6 +950,9 @@ class Engine:
         pspan = None
         if espan is not None:
             pspan = espan.child("prefill", t0=t0)
+            if t_tok is not None:
+                pspan.child("tokenize", t0=t0).set(n_prompt=n_prompt).end(
+                    t_tok)
         if self._kv_paged and not explicit_seed:
             # paged mode: the shared radix index replaces the single-claim
             # reuse above (restores matched pages into the ring and pins
@@ -944,13 +968,22 @@ class Engine:
             suffix = ids[reuse:]
             s = len(suffix)
             sbucket = self._bucket_for(s)
-            logits, cache = prefill_chunk_jit(
-                self.params, self.cfg,
-                jnp.asarray(suffix + [0] * (sbucket - s), jnp.int32),
-                jnp.int32(reuse), jnp.int32(s - 1), self._cache)
+            t_s = time.time()
+            with phase("prefill_slice", rid=rid(pspan), offset=reuse,
+                       tokens=sbucket):
+                logits, cache = prefill_chunk_jit(
+                    self.params, self.cfg,
+                    jnp.asarray(suffix + [0] * (sbucket - s), jnp.int32),
+                    jnp.int32(reuse), jnp.int32(s - 1), self._cache)
+            # the suffix pass after a prefix reuse
+            self._slice_span(pspan, t_s, time.time(), reuse, sbucket)
         else:
             logits, cache = self._prefill_padded(
                 ids, n_prompt, bucket, self._cache, pspan=pspan)
+        # last slice dispatched -> first token on the host (the lane
+        # engine may defer this fetch behind decode waves; here never)
+        fspan = pspan.child("first_token").set(deferred=False, waves=0) \
+            if pspan is not None else None
         window, wpos = seed_window(ids)
         key = jax.random.PRNGKey(seed)
         token, window, wpos, key = sample_jit(
@@ -966,6 +999,7 @@ class Engine:
         first = int(token)  # device sync: first token is now materialized
         ttft_s = time.time() - t0
         if pspan is not None:
+            fspan.end()
             pspan.set(ttft_s=round(ttft_s, 6))
             pspan.end()
         return {
@@ -1462,6 +1496,7 @@ class Engine:
             yield ready, False, finish
         espan = ctx.get("span")   # None when untraced: no span allocation,
         #                           no trace lock, anywhere in this loop
+        req = rid(espan)
         while not done:
             if self._deadline_hit(ctx):
                 finish = "deadline"   # caller timed out/disconnected: free
@@ -1469,23 +1504,24 @@ class Engine:
             self.heartbeat.beat()
             FAULTS.fire("decode_step")
             cspan = espan.child("decode_chunk") if espan is not None else None
-            # dispatch the NEXT chunk before touching the host copy of the
-            # current one (speculating that no stop token appears)
-            pos += n_cur
-            n_nxt = self._next_steps(len(gen) + n_cur, pos, budget)
-            nxt = None
-            if n_nxt > 0:
-                ctx["state"], nxt = self._decode_chunk_call(
-                    ctx["state"], ctx["st"], n_nxt, ctx["sp"].top_k)
+            with phase("decode_chunk", rid=req):   # dispatch + fetch
+                # dispatch the NEXT chunk before touching the host copy of
+                # the current one (speculating that no stop token appears)
+                pos += n_cur
+                n_nxt = self._next_steps(len(gen) + n_cur, pos, budget)
+                nxt = None
+                if n_nxt > 0:
+                    ctx["state"], nxt = self._decode_chunk_call(
+                        ctx["state"], ctx["st"], n_nxt, ctx["sp"].top_k)
 
-            for t in np.asarray(pending).tolist():   # host sync, overlapped
-                if len(gen) >= budget:   # surplus of the budget's last chunk
-                    break
-                if t in stop_ids:
-                    finish = "stop"
-                    done = True
-                    break
-                gen.append(t)
+                for t in np.asarray(pending).tolist():   # host sync
+                    if len(gen) >= budget:   # surplus of the last chunk
+                        break
+                    if t in stop_ids:
+                        finish = "stop"
+                        done = True
+                        break
+                    gen.append(t)
             pending, n_cur = nxt, n_nxt
             if pending is None:
                 done = True
@@ -1494,7 +1530,8 @@ class Engine:
                 cspan.end()
                 ctx["trace"].note(tokens=len(gen))
 
-            ready, finish, done = em.step(gen, done, finish)
+            with phase("emit", rid=req):
+                ready, finish, done = em.step(gen, done, finish)
             if ready:
                 yield ready, False, finish
 
@@ -1519,7 +1556,7 @@ class Engine:
         pre_ids = None
         if self._disagg is not None and seed is None:
             pre_ids = self._remote_prefill(messages, deadline, trace)
-        with self._lock, maybe_profile("generate"):
+        with self._lock:
             self.heartbeat.enter()
             try:
                 return self._generate_locked(messages, sp, max_tokens, stops,

@@ -112,14 +112,16 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
         # convert fuses into the dot's operand read — HBM moves int8),
         # then scale each key column once.  No dequantized ring is ever
         # materialized.
-        scores = jnp.einsum(
-            "ngsh,nch->ngsc", qg, kk.astype(qg.dtype),
-            preferred_element_type=jnp.float32,
-        ) * (hd ** -0.5) * cks[:, None, None, :]
+        with jax.named_scope("attn_scores"):
+            scores = jnp.einsum(
+                "ngsh,nch->ngsc", qg, kk.astype(qg.dtype),
+                preferred_element_type=jnp.float32,
+            ) * (hd ** -0.5) * cks[:, None, None, :]
     else:
-        scores = jnp.einsum(
-            "ngsh,nch->ngsc", qg, kk, preferred_element_type=jnp.float32
-        ) * (hd ** -0.5)  # (n_kv, group, S, n_ctx)
+        with jax.named_scope("attn_scores"):
+            scores = jnp.einsum(
+                "ngsh,nch->ngsc", qg, kk, preferred_element_type=jnp.float32
+            ) * (hd ** -0.5)  # (n_kv, group, S, n_ctx)
 
     key_pos = jnp.arange(cfg.n_ctx)
     q_pos = positions  # (S,)
@@ -132,10 +134,12 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
         # scales into the (tiny) probability matrix, contract int8
         probs = (jax.nn.softmax(scores, axis=-1)
                  * cvs[:, None, None, :]).astype(qg.dtype)
-        ctx = jnp.einsum("ngsc,nch->ngsh", probs, vv.astype(qg.dtype))
+        with jax.named_scope("attn_pv"):
+            ctx = jnp.einsum("ngsc,nch->ngsh", probs, vv.astype(qg.dtype))
     else:
         probs = jax.nn.softmax(scores, axis=-1).astype(vv.dtype)
-        ctx = jnp.einsum("ngsc,nch->ngsh", probs, vv)  # (n_kv, group, S, hd)
+        with jax.named_scope("attn_pv"):
+            ctx = jnp.einsum("ngsc,nch->ngsh", probs, vv)  # (n_kv, group, S, hd)
     return ctx.transpose(2, 0, 1, 3).reshape(S, cfg.n_heads * hd).astype(out_dtype)
 
 
@@ -159,10 +163,16 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     quant = cfg.kv_dtype == "int8"
 
     def lin(x, name):
-        return linear_at(x, layers[name], i)
+        # the projection's own name rides in the HLO's op_name metadata
+        with jax.named_scope(name):
+            return linear_at(x, layers[name], i)
 
     def at_layer(leaf):
         return jax.lax.dynamic_index_in_dim(leaf, i, axis=0, keepdims=False)
+
+    def ring_write(leaf, new, at):
+        with jax.named_scope("kv_write"):
+            return jax.lax.dynamic_update_slice(leaf, new[None], at)
 
     hn = rms_norm(h, layers["attn_norm"][i], cfg.rms_eps)
     q = lin(hn, "wq").reshape(S, cfg.n_heads, hd)
@@ -179,14 +189,10 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         kq, ks = quantize_kv(k.transpose(1, 0, 2))     # (n_kv, S, hd)
         vq, vs = quantize_kv(v.transpose(1, 0, 2))
         cache = {
-            "k_q": jax.lax.dynamic_update_slice(
-                cache["k_q"], kq[None], (i, 0, pos_offset, 0)),
-            "v_q": jax.lax.dynamic_update_slice(
-                cache["v_q"], vq[None], (i, 0, pos_offset, 0)),
-            "k_s": jax.lax.dynamic_update_slice(
-                cache["k_s"], ks[None], (i, 0, pos_offset)),
-            "v_s": jax.lax.dynamic_update_slice(
-                cache["v_s"], vs[None], (i, 0, pos_offset)),
+            "k_q": ring_write(cache["k_q"], kq, (i, 0, pos_offset, 0)),
+            "v_q": ring_write(cache["v_q"], vq, (i, 0, pos_offset, 0)),
+            "k_s": ring_write(cache["k_s"], ks, (i, 0, pos_offset)),
+            "v_s": ring_write(cache["v_s"], vs, (i, 0, pos_offset)),
         }
         ck, cv = at_layer(cache["k_q"]), at_layer(cache["v_q"])
         cks, cvs = at_layer(cache["k_s"]), at_layer(cache["v_s"])
@@ -195,10 +201,8 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         kh = k.astype(cache["k"].dtype).transpose(1, 0, 2)   # (n_kv, S, hd)
         vh = v.astype(cache["v"].dtype).transpose(1, 0, 2)
         cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], kh[None], (i, 0, pos_offset, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], vh[None], (i, 0, pos_offset, 0)),
+            "k": ring_write(cache["k"], kh, (i, 0, pos_offset, 0)),
+            "v": ring_write(cache["v"], vh, (i, 0, pos_offset, 0)),
         }
         ck, cv = at_layer(cache["k"]), at_layer(cache["v"])
         cks = cvs = None
@@ -335,13 +339,15 @@ def forward(
     out_w = params["output"]
     if return_all:
         hn = rms_norm(h, params["out_norm"], cfg.rms_eps)
-        logits = linear(hn, out_w).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = linear(hn, out_w).astype(jnp.float32)
         return logits, new_cache
     if last_idx is None:
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
     hn = rms_norm(h_last, params["out_norm"], cfg.rms_eps)
-    logits = linear(hn, out_w).astype(jnp.float32)[0]
+    with jax.named_scope("head"):
+        logits = linear(hn, out_w).astype(jnp.float32)[0]
     return logits, new_cache
 
 

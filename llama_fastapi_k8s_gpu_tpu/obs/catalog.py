@@ -308,9 +308,16 @@ METRICS: dict[str, Metric] = _register(
            labels=("slo", "window", "scope")),
     # -- runtime-synthesized families --------------------------------------
     Metric("scheduler_", GAUGE,
-           "continuous-scheduler occupancy family "
-           "(ContinuousEngine.scheduler_stats: lanes_live, pending, "
-           "admission_inflight, spec_*, lane_prefix_* / radix_prefix_*)",
+           "continuous-scheduler family (ContinuousEngine.scheduler_stats). "
+           "Point in time: lanes_live, pending, admission_inflight, "
+           "adm_*, mem_pressure, batch_size. Cumulative since start, one "
+           "add per wave (a wave = one decode chunk dispatched, the "
+           "admission slices behind it, the previous chunk's fetch + "
+           "harvest): waves, wave_seconds, lane_live_seconds + "
+           "lane_idle_seconds (= batch_size x wave_seconds), "
+           "fetch_wait_seconds, admit_seconds, admit_slices, admit_tokens, "
+           "harvest_seconds, chunks_dispatched, spec_*, lane_prefix_* / "
+           "radix_prefix_*",
            prefix=True),
 )
 

@@ -25,10 +25,17 @@ Design constraints:
   ingested (the incoming trace id becomes this trace's id, the incoming
   span id its remembered parent) and a valid ``traceparent`` for the
   request's root span is exported for response propagation.
+- **One clock with the device trace**: :func:`phase` puts the program's
+  own phases (a scheduler wave, a prefill slice, a tokenizer call) into a
+  ``/debug/profile`` capture as ``lfkt.<name>`` host events, so an idle
+  gap of the device is read against what the program says it was doing.
+  Armed only while the profiler can be (``LFKT_PROFILE_DIR``); otherwise
+  every call returns one shared no-op object.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import uuid
@@ -389,6 +396,55 @@ class Tracer:
                 "started_total": self.started_total,
                 "sampled_out_total": self.sampled_out_total,
             }
+
+
+# -- phases: the program's own names inside the profiler's trace ----------
+
+#: the disarmed :func:`phase`: one shared, reusable context manager that
+#: does nothing (no allocation, no lock, no jax import)
+_NO_PHASE = contextlib.nullcontext()
+#: ``jax.profiler.TraceAnnotation`` while the profiler can be armed, else
+#: None.  Resolved by :func:`arm_phases` when an engine is built — never
+#: per call: ``phase`` reads this one module attribute (GIL-atomic).
+_ANNOTATION = None
+
+
+def arm_phases() -> bool:
+    """Resolve, once per engine construction, whether :func:`phase` emits:
+    it does iff ``LFKT_PROFILE_DIR`` is set (the knob that arms
+    ``GET /debug/profile`` — a phase outside a capture costs the
+    annotation's enter/exit and records nothing)."""
+    global _ANNOTATION
+    from ..utils.tracing import profile_dir
+
+    if profile_dir():
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    else:
+        _ANNOTATION = None
+    return _ANNOTATION is not None
+
+
+def rid(node: "Span | Trace | None") -> str:
+    """The trace id a phase carries as its ``rid`` stat; "" for a request
+    that is sampled out (the phase still names the work)."""
+    if node is None:
+        return ""
+    return (node if isinstance(node, Trace) else node._trace).trace_id
+
+
+def phase(name: str, **attrs):
+    """Context manager naming one phase of the program on the calling
+    thread inside a profiler capture: a host event ``lfkt.<name>`` whose
+    stats are ``attrs`` (a request's trace id rides as ``rid``, so a gap
+    in the device trace leads to ``/debug/traces/{rid}``).  Sites are per
+    wave, per slice or per request — never per token.  Disarmed, the
+    shared no-op."""
+    ann = _ANNOTATION
+    if ann is None:
+        return _NO_PHASE
+    return ann("lfkt." + name, **attrs)
 
 
 #: every live Tracer, for the process-level event fan-in; weak so a

@@ -44,6 +44,7 @@ from .qmatmul import (
     _lane_repeat,
     permute_x,
     _pick_tn,
+    kernel_name,
     plain_pallas_call,
     q4k_compatible,
     rows_vmappable,
@@ -327,6 +328,7 @@ def _q5k_pre_2d_raw(xpa: jax.Array, q5p: jax.Array, sm: jax.Array,
         functools.partial(_q5k_pre_kernel, interpret=interpret),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q5k_pre", B),
     )(xpa, q5p, sm)
 
 
@@ -383,6 +385,7 @@ def _q5k_pre_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q5p: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q5k_pre", B),
     )
     return call(idx, xpa, q5p, sm)
 
@@ -423,6 +426,7 @@ def _q5k_2d_raw(xpa: jax.Array, q5s: jax.Array, q5h: jax.Array,
                           variant=variant),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q5k", B),
     )(xpa, q5s, q5h, sm)
 
 
@@ -483,6 +487,7 @@ def _q5k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q5s: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q5k", B),
     )
     return call(idx, xpa, q5s, q5h, sm)
 

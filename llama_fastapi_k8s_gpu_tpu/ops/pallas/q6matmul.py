@@ -56,6 +56,7 @@ from .qmatmul import (
     _interpret,
     _lane_repeat,
     _pick_tn,
+    kernel_name,
     plain_pallas_call,
     _q4k_accum,
     q4k_compatible,
@@ -410,6 +411,7 @@ def _q6k_2d_raw(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
                           variant=variant),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q6k", B),
     )(xpa, q4, q2, sm)
 
 
@@ -424,6 +426,7 @@ def _q6k_pre_2d_raw(xpa: jax.Array, q6p: jax.Array, sm: jax.Array,
         functools.partial(_q6k_pre_kernel, interpret=interpret),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q6k_pre", B),
     )(xpa, q6p, sm)
 
 
@@ -480,6 +483,7 @@ def _q6k_pre_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q6p: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q6k_pre", B),
     )
     return call(idx, xpa, q6p, sm)
 
@@ -547,6 +551,7 @@ def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q6k", B),
     )
     return call(idx, xpa, q4, q2, sm)
 

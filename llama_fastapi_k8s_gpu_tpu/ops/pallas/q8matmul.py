@@ -43,6 +43,7 @@ from .qmatmul import (
     _lane_repeat,
     permute_x,
     _pick_tn,
+    kernel_name,
     plain_pallas_call,
     q4k_compatible,
     rows_vmappable,
@@ -138,6 +139,7 @@ def _q8_2d_raw(xp: jax.Array, q8: jax.Array, sm: jax.Array,
         functools.partial(_q8_matmul_kernel, interpret=interpret),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q8_0", B),
     )(xp, q8, sm)
 
 
@@ -194,6 +196,7 @@ def _q8_2d_stacked_raw(idx: jax.Array, xp: jax.Array, q8: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q8_0", B),
     )
     return call(idx, xp, q8, sm)
 

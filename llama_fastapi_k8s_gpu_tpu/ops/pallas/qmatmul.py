@@ -368,10 +368,21 @@ def _q4k_specs(B: int, TN: int):
     )
 
 
+def kernel_name(family: str, rows: int) -> str:
+    """A fused matmul's name as a profile shows it (the Pallas ``name=``
+    becomes the HLO instruction's name): the quant family — ``q4k``,
+    ``q6k``, ``q6k_pre``, ``q5k``, ``q8_0`` — and the row regime, split
+    where :func:`_tn_prefs_for` splits the tiling: ``fewrow`` for the up
+    to 128 activation rows of a decode step, ``manyrow`` for a prefill
+    slice.  ``benchmarks/layer_metrics/q4k_busy_share.py`` and its
+    siblings find the kernels by these names."""
+    return f"{family}_matmul_{'manyrow' if rows > 128 else 'fewrow'}"
+
+
 def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
-                      interpret: bool):
+                      interpret: bool, name: str):
     """pl.pallas_call from the same (block_shape, index_map) pairs
-    :func:`stacked_pallas_call` consumes."""
+    :func:`stacked_pallas_call` consumes; ``name``: :func:`kernel_name`."""
     o_block, o_map = out_spec
     return pl.pallas_call(
         kernel,
@@ -380,6 +391,7 @@ def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
         out_specs=pl.BlockSpec(o_block, o_map),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )
 
 
@@ -395,6 +407,7 @@ def _q4k_2d_raw(xpa: jax.Array, qs: jax.Array, sm: jax.Array,
                           variant=variant),
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+        kernel_name("q4k", B),
     )(xpa, qs, sm)
 
 
@@ -492,7 +505,7 @@ class _NoLead:
 
 
 def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
-                        interpret: bool):
+                        interpret: bool, name: str):
     """Build ``fn(idx, xpa, *stacked_planes)`` running ``kernel`` (an
     unstacked fused kernel ``(xpa_ref, *plane_refs, o_ref)``) against layer
     ``idx[0]`` of weight planes stacked as (L, ...) arrays.
@@ -501,7 +514,7 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
     the activations, then the weight planes; weight specs get the layer dim
     prepended and their index_maps extended with the prefetched scalar.
     Interpret mode (CPU tests) runs the same code path — pallas emulates
-    scalar prefetch."""
+    scalar prefetch.  ``name``: :func:`kernel_name`."""
     from jax.experimental.pallas import tpu as pltpu
 
     (x_block, x_map), *w_specs = in_specs
@@ -525,7 +538,8 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
         kernel(xpa_ref, *(_NoLead(r) for r in rest[:-1]), rest[-1])
 
     return pl.pallas_call(
-        wrapped, grid_spec=gs, out_shape=out_shape, interpret=interpret)
+        wrapped, grid_spec=gs, out_shape=out_shape, interpret=interpret,
+        name=name)
 
 
 def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
@@ -544,6 +558,7 @@ def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
+        name=kernel_name("q4k", B),
     )
     return call(idx, xpa, qs, sm)
 

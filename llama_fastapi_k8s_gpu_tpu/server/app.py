@@ -1036,6 +1036,7 @@ def create_app(engine=None, settings: Settings | None = None,
             # after the handler returns), so the stream span AND the trace
             # itself are closed here, in the generator's finally
             sspan = trace.span("stream") if trace is not None else None
+            first_content = True    # not written yet (tracing only)
             n_events = 0
             try:
                 while True:
@@ -1061,6 +1062,8 @@ def create_app(engine=None, settings: Settings | None = None,
                                + json.dumps({"error": str(chunk)}) + "\n\n")
                         return
                     n_events += 1
+                    if sspan is not None and first_content:
+                        first_content = _mark_first_content(sspan, chunk)
                     yield "data: " + json.dumps(chunk) + "\n\n"
             finally:
                 # runs on timeout, error, AND client disconnect (the ASGI
@@ -1271,6 +1274,7 @@ def create_app(engine=None, settings: Settings | None = None,
 
         async def sse():
             sspan = trace.span("stream") if trace is not None else None
+            first_content = True    # not written yet (tracing only)
             n_events = 0
             last = None
             try:
@@ -1312,6 +1316,8 @@ def create_app(engine=None, settings: Settings | None = None,
                         return
                     last = chunk
                     n_events += 1
+                    if sspan is not None and first_content:
+                        first_content = _mark_first_content(sspan, chunk)
                     yield "data: " + json.dumps(chunk) + "\n\n"
             finally:
                 if not rd["future"].done():
@@ -1465,6 +1471,8 @@ def create_app(engine=None, settings: Settings | None = None,
                 # and the native packer library (None = numpy codecs)
                 "load_phases": getattr(eng, "load_phases", None),
                 "native_lib": _native.loaded_path(),
+                # the device as JAX reports it + peak device memory
+                **_device_info(),
             }
             # paged KV pool occupancy (LFKT_KV_PAGED): pages used/free/
             # pinned, the spill tier, and the hit/eviction counters —
@@ -1820,6 +1828,33 @@ def create_app(engine=None, settings: Settings | None = None,
         return response
 
     return app
+
+
+def _mark_first_content(sspan, chunk) -> bool:
+    """Stamp the traced ``stream`` span with ``first_content`` at the write
+    of the first chunk that carries text — where the client's clock for
+    the time to first token stops.  Returns whether it is still to come."""
+    try:
+        if not chunk["choices"][0]["delta"].get("content"):
+            return True
+    except (KeyError, IndexError, TypeError, AttributeError):
+        return True
+    sspan.event("first_content")
+    return False
+
+
+def _device_info() -> dict:
+    """What runs this engine, as JAX reports it: platform, device kind and
+    count, and the peak device memory of the process so far (``/health``
+    ``engine``; None where the backend keeps no memory statistics)."""
+    import jax
+
+    devs = jax.local_devices()
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs]
+    peaks = [int(p) for p in peaks if p is not None]
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "peak_bytes_in_use": max(peaks) if peaks else None}
 
 
 def _effective_unroll(cfg):

@@ -475,8 +475,10 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_WORKERS", int, "must stay 1: one model per process",
          default=1),
     Knob("LFKT_PROFILE_DIR", str,
-         "capture XProf traces per generation (utils/tracing.py) and via "
-         "GET /debug/profile", serving=True, default=""),
+         "arms GET /debug/profile (bounded XProf capture into this "
+         "directory, utils/tracing.py) and the lfkt.* phase annotations "
+         "inside it (obs/trace.py phase); nothing else is profiled",
+         serving=True, default=""),
     # -- lfkt-perf (obs/devtime.py + obs/slo.py; docs/SLO.md) --------------
     Knob("LFKT_DEVTIME", bool,
          "per-program compile/dispatch attribution (obs/devtime.py; "

@@ -1,17 +1,20 @@
-"""Profiling hooks: per-phase wall timers and optional XProf trace capture.
+"""Profiling hook: the bounded on-demand XProf capture.
 
 The reference's sole instrument is a request-timing log middleware (reference
 api.py:179-194).  SURVEY.md §5 "Tracing / profiling" calls for per-phase
 timers (queue wait, prefill/TTFT, decode tokens/sec — implemented in
 engine/engine.py and server/app.py against utils/metrics.py) plus optional
-``jax.profiler`` capture; this module provides the capture: set
-``LFKT_PROFILE_DIR`` and every generation records a TensorBoard/XProf trace
-there (device kernels + host dispatch), zero overhead when unset.
+``jax.profiler`` capture; this module provides the capture.
+``LFKT_PROFILE_DIR`` has ONE meaning: it arms ``GET /debug/profile``
+(:func:`capture_profile`), and with it the ``lfkt.*`` phase annotations that
+put the program's own phases inside such a capture (obs/trace.py ``phase``).
+No generation is ever profiled on its own: JAX allows one profile at a time,
+so a per-generation trace would refuse or cut short the operator's capture.
+Unset, nothing here runs.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import threading
@@ -87,36 +90,3 @@ def profile_dir() -> str | None:
     from .config import knob
 
     return knob("LFKT_PROFILE_DIR") or None
-
-
-@contextlib.contextmanager
-def maybe_profile(tag: str = "generate"):
-    """jax.profiler trace scope when LFKT_PROFILE_DIR is set; no-op otherwise.
-
-    Profiler start/stop failures are swallowed (profiling must never break
-    serving); exceptions raised by the profiled body itself propagate
-    unchanged.
-    """
-    d = profile_dir()
-    if not d:
-        yield
-        return
-
-    trace = None
-    try:
-        import jax
-
-        os.makedirs(d, exist_ok=True)
-        trace = jax.profiler.trace(d)
-        trace.__enter__()
-    except Exception as e:  # noqa: BLE001 — capture is best-effort
-        logger.warning("profiler capture unavailable (%s); continuing", e)
-        trace = None
-    try:
-        yield
-    finally:
-        if trace is not None:
-            try:
-                trace.__exit__(None, None, None)
-            except Exception as e:  # noqa: BLE001
-                logger.warning("profiler teardown failed (%s); trace dropped", e)

@@ -152,8 +152,9 @@ def test_serial_prefix_reuse_composes_with_slicing(model_path):
 
 
 def test_slice_events_on_prefill_span(model_path):
-    """A traced sliced prefill carries one prefill_slice event per slice,
-    each with offset/tokens/host_s — the waterfall's overlap rendering
+    """A traced sliced prefill carries one prefill_slice span per slice
+    (the host dispatch: start -> return of the jit call), each with
+    offset/tokens — the waterfall's overlap rendering
     (tools/trace_report.py) keys off these attrs."""
     from llama_fastapi_k8s_gpu_tpu.obs.trace import Tracer
 
@@ -174,9 +175,11 @@ def test_slice_events_on_prefill_span(model_path):
             prefill = s
         stack.extend(s["children"])
     assert prefill is not None
-    events = [e for e in prefill["events"] if e["name"] == "prefill_slice"]
-    assert len(events) >= 2                      # multi-slice prompt
-    offs = [e["offset"] for e in events]
+    assert not [e for e in prefill["events"] if e["name"] == "prefill_slice"]
+    slices = [c for c in prefill["children"] if c["name"] == "prefill_slice"]
+    assert len(slices) >= 2                      # multi-slice prompt
+    offs = [c["attrs"]["offset"] for c in slices]
     assert offs == sorted(offs)
-    for e in events:
-        assert e["tokens"] > 0 and e["host_s"] >= 0.0
+    for c in slices:
+        assert c["attrs"]["tokens"] > 0 and c["duration_s"] >= 0.0
+        assert prefill["start"] <= c["start"] <= c["end"] <= prefill["end"]

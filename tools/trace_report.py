@@ -115,11 +115,17 @@ def render_trace(trace: dict) -> str:
                 phase_seconds.get(span["name"], 0.0) + dur)
         lo = min(int(start / total * WIDTH), WIDTH - 1)
         hi = max(min(int(end / total * WIDTH + 0.999), WIDTH), lo + 1)
-        bar = " " * lo + "█" * (hi - lo) + " " * (WIDTH - hi)
-        name = (" " * (depth * INDENT) + span["name"])[:NAME_COL]
-        extra = ""
-        if span.get("attrs", {}).get("tokens") is not None:
-            extra = f"  t={span['attrs']['tokens']}"
+        glyph, label, extra = "█", span["name"], ""
+        if span["name"] == "prefill_slice":
+            # one slice's host dispatch (▒, offset-labeled): the slices
+            # tile the prefill span and what lies between them is the
+            # admission waiting — the round-6 overlap picture
+            glyph, label = "▒", f"slice@{attrs.get('offset', '?')}"
+            extra = f"  n={attrs.get('tokens', '?')}"
+        elif attrs.get("tokens") is not None:
+            extra = f"  t={attrs['tokens']}"
+        bar = " " * lo + glyph * (hi - lo) + " " * (WIDTH - hi)
+        name = (" " * (depth * INDENT) + label)[:NAME_COL]
         lines.append(f"{name:<{NAME_COL}} {_fmt_ms(start)} "
                      f"{_fmt_ms(dur)} |{bar}|{open_marker}{extra}")
         def duration_bar(at, host_s, glyph, label, suffix):
@@ -137,13 +143,6 @@ def render_trace(trace: dict) -> str:
         for ev in span.get("events", ()):
             at = ev["at"] - t0
             host_s = ev.get("host_s")
-            if ev["name"] == "prefill_slice" and host_s is not None:
-                # overlapped-prefill slice (▒): the overlap picture the
-                # round-6 pipeline exists for
-                duration_bar(at, host_s, "▒",
-                             f"slice@{ev.get('offset', '?')}",
-                             f"n={ev.get('tokens', '?')}")
-                continue
             if ev["name"] in ("kv_restore", "kv_spill", "kv_spill_restore") \
                     and host_s is not None:
                 # paged-KV page movement (░, parallel/kvpool.py): the
