@@ -12,9 +12,10 @@ only the Python tracer's frames of the host plane; this reader keeps the
 that was open at the gap's middle, on any thread.
 
 The arithmetic works on plain ``(name, start_s, duration_s)`` lists; only
-``load`` touches the file.  A program without phases (the parent of the PR
-that added them) gives an empty list, and every reader here then returns
-None.
+``load`` touches the file.  With a capture of the device there is always a
+number: a capture without a phase has all its idle seconds unnamed, one
+without an idle second has none unnamed.  Only a run without a capture (an
+unsound one: no trace file, no operation on a device) gives None.
 """
 
 from __future__ import annotations
@@ -77,35 +78,35 @@ def idle_by_phase(gaps, phases) -> dict[str, float]:
 def of_run(run: dict) -> dict[str, float] | None:
     """``idle_by_phase`` of a traced run's mid-window capture, computed once
     and kept in ``run["notes"]["idle_by_phase"]`` (the diagnostics line
-    prints it).  None when there is no capture, no device operation in it,
-    or no phase: the program of this checkout has none."""
+    prints it).  The capture is the file ``xplane.capture_of`` finds, the
+    one the run's reduction read.  None when there is no capture or no
+    device operation in it; ``{}`` where its gaps sum to nothing."""
     notes = run["notes"]
     if "idle_by_phase" in notes:
         return notes["idle_by_phase"]
-    where = (run.get("profile_call", {}).get("doc") or {}).get("dir")
-    path = xplane.newest_trace(where) if where else None
-    if not path:
+    path = xplane.capture_of(run)
+    profile = run.get("profile")
+    if not path or not profile:
         return None
     phases = load(path)
     counts: dict[str, int] = {}
     for name, _, _ in phases:
         counts[name] = counts.get(name, 0) + 1
     notes["phases_in_capture"] = counts
-    profile = run.get("profile")
-    if not phases or not profile:
-        return None
     notes["idle_by_phase"] = idle_by_phase(profile["gaps"], phases)
     return notes["idle_by_phase"]
 
 
 def idle_share(run: dict, only: str | None = None) -> float | None:
     """Per cent of the idle seconds (of the capture's 50 longest gaps) that
-    lie inside some phase, or inside the phase ``only``."""
+    lie inside some phase, or inside the phase ``only``.  Where the capture
+    holds no idle second nothing is unnamed and nothing is in ``only``: 100
+    for the whole, 0 for one phase.  None only without a capture."""
     by = of_run(run)
     if by is None:
         return None
     total = sum(by.values())
     if not total:
-        return None
+        return 0.0 if only else 100.0
     named = by.get(only, 0.0) if only else total - by.get(UNNAMED, 0.0)
     return 100.0 * named / total

@@ -1,16 +1,23 @@
 """What a decode step must read and what a prefill must compute, from a
-configuration file's shapes alone.  These are the algorithm's needs, not
-what the program happens to do: weights in the types the file stores them
-in (one pass per step, shared by all lanes), keys and values of the live
-context only, the causal half of attention.  Roofline shares divide these
-by the chip's peaks (``peaks.json``) and by measured device time."""
+configuration file's shapes.  These are the algorithm's needs, not what the
+program happens to do: weights in the types the file stores them in (one
+pass per step, shared by all lanes), keys and values of the live context
+only, the causal half of attention.  Roofline shares divide these by the
+chip's peaks (``peaks.json``) and by measured device time.
+
+Which tensors a step reads is the block's to say: ``decode_step_bytes``,
+``decode_step_flops`` and ``prefill_flops`` are those of the
+configuration's block file (``ggufgen.block_of``), which may read the
+traced ``run`` for what shapes alone do not give (the experts a routed step
+really read).  The sums over a tensor plan that any block can use are
+here."""
 
 from __future__ import annotations
 
 import json
 import os
 
-from ggufgen import tensor_nbytes, tensor_plan
+from ggufgen import block_of, tensor_nbytes, tensor_plan
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -25,7 +32,8 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _dims(cfg: dict) -> tuple[int, int, int]:
+def dims(cfg: dict) -> tuple[int, int, int]:
+    """(width of one head, of all query heads, of all key or value heads)."""
     head_dim = cfg.get("head_dim") or \
         cfg["hidden_size"] // cfg["num_attention_heads"]
     return (head_dim, cfg["num_attention_heads"] * head_dim,
@@ -48,17 +56,8 @@ def weight_bytes_per_step(cfg: dict) -> int:
 
 def kv_bytes_per_token(cfg: dict, kv_bytes: int = 2) -> int:
     """Key and value bytes one context position holds, all layers."""
-    _, _, kv_dim = _dims(cfg)
+    _, _, kv_dim = dims(cfg)
     return 2 * cfg["num_hidden_layers"] * kv_dim * kv_bytes
-
-
-def decode_step_bytes(cfg: dict, lanes: int, context_tokens: float,
-                      kv_bytes: int = 2) -> float:
-    """HBM bytes one decode step needs: one pass over the weights, the live
-    context's keys and values of each lane, one embedding row a lane."""
-    return (weight_bytes_per_step(cfg)
-            + lanes * context_tokens * kv_bytes_per_token(cfg, kv_bytes)
-            + lanes * cfg["hidden_size"] * 2)
 
 
 def linear_params(cfg: dict) -> int:
@@ -70,21 +69,21 @@ def linear_params(cfg: dict) -> int:
     return total
 
 
-def decode_step_flops(cfg: dict, lanes: int, context_tokens: float) -> float:
-    _, q_dim, _ = _dims(cfg)
-    attn = 4 * q_dim * context_tokens * cfg["num_hidden_layers"]
-    return lanes * (2 * linear_params(cfg) + attn)
+def decode_step_bytes(cfg: dict, lanes: int, context_tokens: float,
+                      kv_bytes: int = 2, run: dict | None = None) -> float:
+    """HBM bytes one decode step needs, by the configuration's block."""
+    return block_of(cfg).decode_step_bytes(cfg, lanes, context_tokens,
+                                           kv_bytes, run)
 
 
-def prefill_flops(cfg: dict, n_tokens: int) -> float:
-    """FLOPs of one prompt of ``n_tokens``: two per weight and token in the
-    layers, the head for the last position only, and causal attention
-    (QK^T and PV over half the square)."""
-    _, q_dim, _ = _dims(cfg)
-    head = cfg["vocab_size"] * cfg["hidden_size"]
-    layers = linear_params(cfg) - head
-    attn = 2 * q_dim * n_tokens * n_tokens * cfg["num_hidden_layers"]
-    return 2.0 * layers * n_tokens + 2.0 * head + attn
+def decode_step_flops(cfg: dict, lanes: int, context_tokens: float,
+                      run: dict | None = None) -> float:
+    return block_of(cfg).decode_step_flops(cfg, lanes, context_tokens, run)
+
+
+def prefill_flops(cfg: dict, n_tokens: int, run: dict | None = None) -> float:
+    """FLOPs of one prompt of ``n_tokens``, by the configuration's block."""
+    return block_of(cfg).prefill_flops(cfg, n_tokens, run)
 
 
 def roofline_seconds(flops: float, nbytes: float, peak: dict

@@ -10,10 +10,16 @@ and zero-mean, with a standard deviation of about ``hidden_size ** -0.5``.
 The data is written tensor by tensor as it is made (nothing the size of
 the file is held in memory) from 64-bit draws, which numpy makes about
 eight times faster than 8-bit ones.
+
+What is shared by every file is here: header, vocabulary, random valid
+blocks, alignment.  What one block of layers has and another lacks (its
+tensors, its hyperparameter keys) is in ``blocks/<block>.py``, found by the
+configuration file's ``block`` key (``block_of``).
 """
 
 from __future__ import annotations
 
+import importlib.util
 import os
 import struct
 
@@ -31,6 +37,10 @@ GGML = {
     "F32": (0, 1, 4), "F16": (1, 1, 2), "Q8_0": (8, 32, 34),
     "Q4_K": (12, 256, 144), "Q5_K": (13, 256, 176), "Q6_K": (14, 256, 210),
 }
+
+#: where a block's file is
+BLOCK_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "blocks")
+_blocks: dict[str, object] = {}       # loaded block files, by path
 
 SPACE = "▁"
 _LETTERS = "abcdefghijklmnopqrstuvwxyz"
@@ -191,58 +201,69 @@ def _kv_arr(key, etype, items):
     return _kv(key, _ARR, head + body)
 
 
+def block_of(cfg: dict):
+    """The module ``blocks/<block>.py`` of the configuration's ``block``
+    (absent: ``dense``): the one place that knows that block's tensors,
+    metadata keys and costs.  An unknown block is an error, never a
+    default."""
+    name = cfg.get("block", "dense")
+    path = os.path.join(BLOCK_DIR, f"{name}.py")
+    if not os.path.exists(path):
+        raise KeyError(f"no block {name!r}: no {name}.py in {BLOCK_DIR}")
+    if path not in _blocks:
+        spec = importlib.util.spec_from_file_location(
+            "block_" + name.replace("-", "_").replace(".", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _blocks[path] = mod
+    return _blocks[path]
+
+
 def tensor_plan(cfg: dict) -> list[tuple[str, tuple[int, ...], str]]:
-    """(name, numpy-order shape, ggml type) of every tensor of the dense
-    GQA + SwiGLU block the configuration describes, in file order."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    head_dim = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    q_dim = cfg["num_attention_heads"] * head_dim
-    kv_dim = cfg["num_key_value_heads"] * head_dim
-    v = cfg["vocab_size"]
-    tt = cfg["gguf"]["tensor_types"]
-    plan = [("token_embd.weight", (v, d), tt["token_embd"])]
-    for i in range(cfg["num_hidden_layers"]):
-        p = f"blk.{i}."
-        plan += [
-            (p + "attn_norm.weight", (d,), "F32"),
-            (p + "attn_q.weight", (q_dim, d), tt["attn_q"]),
-            (p + "attn_k.weight", (kv_dim, d), tt["attn_k"]),
-            (p + "attn_v.weight", (kv_dim, d), tt["attn_v"]),
-            (p + "attn_output.weight", (d, q_dim), tt["attn_output"]),
-            (p + "ffn_norm.weight", (d,), "F32"),
-            (p + "ffn_gate.weight", (f, d), tt["ffn_gate"]),
-            (p + "ffn_up.weight", (f, d), tt["ffn_up"]),
-            (p + "ffn_down.weight", (d, f), tt["ffn_down"]),
-        ]
-    plan += [("output_norm.weight", (d,), "F32"),
-             ("output.weight", (v, d), tt["output"])]
-    return plan
+    """(name, numpy-order shape of any rank, ggml type) of every tensor of
+    the configuration's block, in file order."""
+    return block_of(cfg).tensor_plan(cfg)
+
+
+def transformer_metadata(cfg: dict, arch: str) -> list[tuple[str, str, object]]:
+    """The hyperparameter keys of a decoder of attention + feed-forward
+    layers as (key, ``u32`` | ``f32`` | ``str`` | ``bool``, value): what a
+    block's ``metadata`` starts from where it is such a decoder."""
+    head_dim = cfg.get("head_dim") or \
+        cfg["hidden_size"] // cfg["num_attention_heads"]
+    meta = [
+        (f"{arch}.block_count", "u32", cfg["num_hidden_layers"]),
+        (f"{arch}.context_length", "u32", cfg["max_position_embeddings"]),
+        (f"{arch}.embedding_length", "u32", cfg["hidden_size"]),
+        (f"{arch}.feed_forward_length", "u32", cfg["intermediate_size"]),
+        (f"{arch}.attention.head_count", "u32", cfg["num_attention_heads"]),
+        (f"{arch}.attention.head_count_kv", "u32", cfg["num_key_value_heads"]),
+        (f"{arch}.rope.dimension_count", "u32", head_dim),
+        (f"{arch}.attention.layer_norm_rms_epsilon", "f32", cfg["rms_norm_eps"]),
+        (f"{arch}.rope.freq_base", "f32", cfg["rope_theta"]),
+        (f"{arch}.vocab_size", "u32", cfg["vocab_size"]),
+    ]
+    if cfg.get("sliding_window"):
+        meta.append((f"{arch}.attention.sliding_window", "u32",
+                     cfg["sliding_window"]))
+    return meta
+
+
+_KV = {"u32": _kv_u32, "f32": _kv_f32, "str": _kv_str, "bool": _kv_bool}
 
 
 def write_gguf(cfg: dict, path: str) -> int:
     """Write the configuration's GGUF file to ``path`` (through a temporary
     name beside it, renamed when whole).  Returns its size in bytes."""
     arch = cfg["gguf"].get("architecture", "llama")
+    block = block_of(cfg)
     tokens, types, scores = synth_spm_vocab(cfg["vocab_size"])
-    head_dim = cfg.get("head_dim") or \
-        cfg["hidden_size"] // cfg["num_attention_heads"]
     meta = [
         _kv_str("general.architecture", arch),
         _kv_str("general.name", cfg["name"]),
-        _kv_u32(f"{arch}.block_count", cfg["num_hidden_layers"]),
-        _kv_u32(f"{arch}.context_length", cfg["max_position_embeddings"]),
-        _kv_u32(f"{arch}.embedding_length", cfg["hidden_size"]),
-        _kv_u32(f"{arch}.feed_forward_length", cfg["intermediate_size"]),
-        _kv_u32(f"{arch}.attention.head_count", cfg["num_attention_heads"]),
-        _kv_u32(f"{arch}.attention.head_count_kv", cfg["num_key_value_heads"]),
-        _kv_u32(f"{arch}.rope.dimension_count", head_dim),
-        _kv_f32(f"{arch}.attention.layer_norm_rms_epsilon", cfg["rms_norm_eps"]),
-        _kv_f32(f"{arch}.rope.freq_base", cfg["rope_theta"]),
-        _kv_u32(f"{arch}.vocab_size", cfg["vocab_size"]),
     ]
-    if cfg.get("sliding_window"):
-        meta.append(_kv_u32(f"{arch}.attention.sliding_window",
-                            cfg["sliding_window"]))
+    meta += [_KV[kind](key, value)
+             for key, kind, value in block.metadata(cfg, arch)]
     meta += [
         _kv_str("tokenizer.ggml.model", "llama"),
         _kv_arr("tokenizer.ggml.tokens", _STR, tokens),
@@ -253,7 +274,7 @@ def write_gguf(cfg: dict, path: str) -> int:
         _kv_bool("tokenizer.ggml.add_bos_token", True),
         _kv_str("tokenizer.chat_template", MISTRAL_TEMPLATE),
     ]
-    plan = tensor_plan(cfg)
+    plan = block.tensor_plan(cfg)
     infos, offset = [], 0
     for name, shape, kind in plan:
         n = int(np.prod(shape))

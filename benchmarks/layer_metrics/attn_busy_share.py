@@ -1,7 +1,7 @@
 """kernels: self time of the attention kernels (``kernels/attn.json``) over
 device busy time, in the mid-window capture.  device_trace."""
-from xplane import group_busy_share
+from opshare import group_share
 
 
 def read(run):
-    return group_busy_share(run.get("profile"), "attn")
+    return group_share(run, "attn_busy_share", "attn")

@@ -1,7 +1,8 @@
 """kernels: the least time a decode step could take on this chip over the
 device time it took.  Least time: the bytes a step must read (one pass
 over the weights as the file stores them, the live context's keys and
-values in every lane, from ``costs.py``) over the chip's HBM bandwidth, or
+values in every lane, as the configuration's block counts them through
+``costs.py``, which hands it this ``run``) over the chip's HBM bandwidth, or
 its FLOPs over the bf16 peak where that is larger: at these sizes HBM
 bounds it.  Device time per step: the median duration of the decode
 programs on the trace's ``XLA Modules`` line (``kernels/decode_program
@@ -32,8 +33,8 @@ def read(run):
         + (sum(outs) / len(outs) / 2 if outs else 0)
     peak = costs.peaks(run["device"]["kind"])
     least, bound = costs.roofline_seconds(
-        costs.decode_step_flops(cfg, lanes, context),
-        costs.decode_step_bytes(cfg, lanes, context), peak)
+        costs.decode_step_flops(cfg, lanes, context, run=run),
+        costs.decode_step_bytes(cfg, lanes, context, run=run), peak)
     run["notes"]["decode_step_roofline"] = {
         "bound": bound, "least_ms": least * 1e3, "device_step_ms": step_s * 1e3,
         "lanes": lanes, "context_tokens": context}

@@ -4,7 +4,9 @@ line (``jit_prefill_chunk_jit``, one slice; ``jit_prefill_jit``, a prompt
 of one slice or less) over the median ``tokens`` of the ``prefill_slice``
 spans.  Beside ``prefill_ms_per_ktok`` (the host's span, waiting included)
 it says whether prefill is a slow program or a waiting one.  None where
-the capture holds no prefill.  device_trace."""
+the capture holds no prefill: there is no honest number then, so the
+metric is declared (its ``workloads`` list) only for cells whose every 3 s
+capture holds one, which leaves out one stream of chat.  device_trace."""
 import re
 
 from metrics import percentile

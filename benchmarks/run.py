@@ -4,17 +4,19 @@
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``BENCHMARK.json`` (or, for the CPU rehearsal, in
-``benchmarks/rehearsal/cells.json``), writes or finds the configuration's
-GGUF file, starts ``python -m llama_fastapi_k8s_gpu_tpu.server`` on it as a
+``benchmarks/rehearsal/cells.json`` or a file of its own,
+``benchmarks/rehearsal/cells/<name>.json``), writes or finds the
+configuration's GGUF file, starts ``python -m llama_fastapi_k8s_gpu_tpu.server`` on it as a
 child (this parent never touches JAX's devices: the child holds the chip),
 waits for READY, warms up, offers the cell's traffic to
 ``/v1/chat/completions`` with ``stream: true`` over loopback for
 ``--seconds``, reads the program's debug surfaces, stops the server and
 prints the result as the last line of standard output.
 
-Nothing here names a model, a cell or a length: a configuration, a traffic
-mix, a kernel name group and a per-layer metric are each a file of their
-own, found by the name ``BENCHMARK.json`` gives.
+Nothing here names a model, a block, a cell or a length: a configuration,
+its block of layers, a traffic mix, a kernel name group and a per-layer
+metric are each a file of their own, found by the name ``BENCHMARK.json``
+or the configuration file gives.
 
 Exit codes: 0 a result was printed; 2 bad arguments or files; 3 no result
 (no accelerator, too few chips, the server failed).
@@ -85,9 +87,13 @@ def find_cell(name: str) -> dict:
     else:
         cells = load_json(os.path.join(HERE, "rehearsal", "cells.json"))
         cell = next((w for w in cells["workloads"] if w["name"] == name), None)
-        if cell is None:
-            raise SystemExit(f"no cell {name!r} in BENCHMARK.json or in "
-                             "benchmarks/rehearsal/cells.json")
+        own = os.path.join(HERE, "rehearsal", "cells", name + ".json")
+        if cell is None and os.path.exists(own):
+            cell = load_json(own)
+        if cell is None or cell.get("name") != name:
+            raise SystemExit(f"no cell {name!r} in BENCHMARK.json, in "
+                             "benchmarks/rehearsal/cells.json or as "
+                             "benchmarks/rehearsal/cells/<name>.json")
         rehearsal = True
         cfg_path = os.path.join(HERE, "rehearsal", cell["config"] + ".json")
         mix_path = os.path.join(HERE, "rehearsal", cell["traffic"] + ".json")
@@ -136,8 +142,10 @@ def ensure_gguf(cfg: dict) -> str:
     os.makedirs(CACHE, exist_ok=True)
     shape_keys = sorted(k for k, v in cfg.items()
                         if isinstance(v, (int, float)) or v is None)
-    key = json.dumps([[k, cfg[k]] for k in shape_keys] + [cfg["gguf"]],
-                     sort_keys=True)
+    # a configuration of the dense block names none, and keeps its file
+    named = [["block", cfg["block"]]] if "block" in cfg else []
+    key = json.dumps([[k, cfg[k]] for k in shape_keys] + named
+                     + [cfg["gguf"]], sort_keys=True)
     digest = hashlib.sha256(key.encode()).hexdigest()[:12]
     path = os.path.join(CACHE, f"{cfg['name']}-{digest}.gguf")
     if not os.path.exists(path):
@@ -243,15 +251,24 @@ def sampler(server, samples: list, hz: float = 5.0):
     return job
 
 
-def profiler(server, out: dict, seconds: float):
+def profiler(server, out: dict, seconds: float, wait: float = 120.0):
+    """Ask the program for one capture of ``seconds`` at 40 % of the window.
+    The capture lands in the directory this run set (``LFKT_PROFILE_DIR``)
+    and is read from there; the answer is only noted, so one that comes
+    late or not at all costs no metric."""
     async def job(t0, t1):
         await asyncio.sleep(max(0.0, t0 + 0.4 * (t1 - t0) - time.time()))
+        out["asked_s"] = seconds
         out["t_send"] = time.time()
-        status, text = await asyncio.to_thread(
-            server.get, f"/debug/profile?seconds={seconds}", 120.0)
+        try:
+            status, text = await asyncio.to_thread(
+                server.get, f"/debug/profile?seconds={seconds}", wait)
+            out["doc"] = json.loads(text) if status == 200 \
+                else {"error": text[:200]}
+            out["status"] = status
+        except (OSError, ValueError) as e:
+            out["error"] = f"{type(e).__name__}: {e}"[:200]
         out["t_recv"] = time.time()
-        out["status"] = status
-        out["doc"] = json.loads(text) if status == 200 else {"error": text[:200]}
     return job
 
 
@@ -302,17 +319,29 @@ async def measure(server, cellinfo, args) -> dict:
 # the result
 # ---------------------------------------------------------------------------
 
-def reduced_capture(profile_dir: str, run: dict) -> dict | None:
+def reduced_capture(run: dict) -> dict | None:
     """The mid-window capture, reduced; its window is the capture's own
-    ``time.sleep``."""
-    path = xplane.newest_trace(profile_dir)
+    ``time.sleep`` of the seconds this run asked for."""
+    path = xplane.capture_of(run)
     if not path:
         return None
     trace = xplane.load(path)
-    asked = (run["profile_call"].get("doc") or {}).get("seconds")
+    asked = run["profile_call"].get("asked_s")
     return xplane.reduce(
         trace, run["kernel_groups"],
         xplane.capture_window(trace["host"], asked) if asked else None)
+
+
+def why_left_out(run: dict, device: dict) -> str:
+    """Why a traced run prints fewer metrics than its cell declares.  A
+    sound one on the chip prints them all."""
+    if device.get("platform") != "tpu":
+        return f"platform {device.get('platform')!r}: no device to trace"
+    if not run.get("capture_path"):
+        return "no capture file under " + str(run.get("profile_dir"))
+    if run.get("profile") is None:
+        return "no operation ran on the device inside the capture"
+    return "the reader found nothing to read in this run"
 
 
 def breakdown(profile: dict | None) -> dict | None:
@@ -404,13 +433,15 @@ def main(argv=None) -> int:
 
     run.update(config=cfg, mix=cellinfo["mix"], cell=cell, health=health,
                device=device, ready_s=ready_s, e2e=e2e,
-               kernel_groups=kernel_groups(), notes={})
+               kernel_groups=kernel_groups(), notes={},
+               profile_dir=env.get("LFKT_PROFILE_DIR"))
     profile = None
     if args.trace:
-        profile = run["profile"] = reduced_capture(
-            env["LFKT_PROFILE_DIR"], run)
-        if device.get("platform") == "tpu" and profile is None:
-            log("no operation ran on the device inside the traced window")
+        profile = run["profile"] = reduced_capture(run)
+        call = run["profile_call"]
+        run["notes"]["capture"] = {
+            "asked_s": call.get("asked_s"), "file": run["capture_path"],
+            "answer": call.get("error") or call.get("doc") or "none"}
         values = {m["name"]: reader(run) for m in cellinfo["per_layer"]
                   if (reader := layer_metric_reader(m["name"]))}
     else:
@@ -418,6 +449,13 @@ def main(argv=None) -> int:
     declared = cellinfo["per_layer"] if args.trace else cellinfo["end_to_end"]
     out_metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in declared if values.get(m["name"]) is not None}
+    if len(out_metrics) < len(declared):
+        run["notes"]["left_out"] = {
+            "metrics": [m["name"] for m in declared
+                        if m["name"] not in out_metrics],
+            "why": why_left_out(run, device) if args.trace
+            else "the window holds no sample of it"}
+        log(f"left out: {run['notes']['left_out']}")
 
     correct, why = is_correct(run, cellinfo, health, device, failed, attempted)
     dev_out = {"platform": device.get("platform"), "kind": device.get("kind"),

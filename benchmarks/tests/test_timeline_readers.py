@@ -1,7 +1,9 @@
 """The readers of PR 24, on hand-made span trees, ``/metrics`` texts and
 event lists, and on a recorded slice of the chip that holds the program's
-``lfkt.`` phases (``data/``).  Every reader gives None, and does not
-raise, for a program that has none of what it reads: the parent commit."""
+``lfkt.`` phases (``data/``).  A reader of spans or counters gives None,
+and does not raise, for a program that has none of what it reads (the
+parent commit of PR 24); a reader of the capture gives a number whenever
+there is a capture (``test_every_metric.py``)."""
 
 import importlib.util
 import json
@@ -179,10 +181,14 @@ def test_idle_shares_from_a_runs_notes():
     assert reader("idle_in_tokenize_share")(run) == pytest.approx(60.0)
 
 
-def test_no_capture_or_no_phase_is_none(tmp_path):
+def test_no_capture_is_none(tmp_path):
     assert annotations.of_run(run_of()) is None
-    run = run_of(profile_call={"doc": {"dir": str(tmp_path)}})
+    # a directory was set and holds no trace file: the answer's word that
+    # it does counts for nothing
+    run = run_of(profile_dir=str(tmp_path), profile={"gaps": [(0.1, 0.2)]},
+                 profile_call={"doc": {"ok": True, "dir": str(tmp_path)}})
     assert annotations.of_run(run) is None and run["notes"] == {}
+    assert reader("idle_attributed_share")(run) is None
 
 
 RECORDED = os.path.join(HERE, "data", "solar.doc-1.lfkt.v5e.xplane.pb")
@@ -255,12 +261,16 @@ def test_named_kernels_split_the_qmatmul_group():
         reader("attn_busy_share")(run) - 100 * 0.10 / busy)
 
 
-def test_kernels_without_names_are_none():
+def test_kernels_without_names_are_nothing_of_busy_time():
     unnamed = {"%closed_call.114 = f32[1,4096]{1,0} custom-call(s8[48,4096,"
                "7168]{2,1,0} %w), custom_call_target=\"tpu_custom_call\"": 1.0}
     profile = {"busy_s": 1.0, "ops": unnamed, "groups": {}}
-    assert opshare.busy_share(profile, r"^%q4k_matmul") is None
-    assert opshare.busy_share(None, r"^%q4k_matmul") is None
+    run = {"profile": profile}
+    assert opshare.busy_share(run, "q4k_busy_share", r"^%q4k_matmul") == 0.0
+    assert run["notes"] == {"no_match": ["q4k_busy_share"]}   # and says so
+    assert opshare.busy_share(run, "all", r"custom-call") == 100.0
+    assert run["notes"] == {"no_match": ["q4k_busy_share"]}
+    assert opshare.busy_share({}, "q4k_busy_share", r"^%q4k_matmul") is None
 
 
 def test_prefill_device_time_over_the_slices_tokens():
@@ -293,7 +303,10 @@ def test_new_readers_give_none_on_the_parents_run(name):
                  profile={"busy_s": 1.5, "window_s": 3.0, "ops": unnamed,
                           "groups": {}, "gaps": [(0.1, 0.2)], "modules": [],
                           "host": {}})
-    want_number = {"pending_wait_p50_ms"}      # the span was always there
+    # the span was always there; a share of a capture's busy time is 0.0
+    # where nothing matches; the idle shares have no trace file to read
+    want_number = {"pending_wait_p50_ms", "q4k_busy_share", "q6k_busy_share",
+                   "decode_attn_busy_share"}
     got = reader(name)(run)
     assert (got is not None) == (name in want_number)
 
@@ -305,8 +318,10 @@ def test_every_new_metric_is_declared_where_the_issue_says():
     only_lanes = {"pending_wait_p50_ms", "admit_wait_p50_ms",
                   "lane_occupancy_share", "admit_slices_per_wave",
                   "fetch_wait_share", "decode_attn_busy_share"}
+    # PR 27: a 3 s capture of one stream of chat may hold no prefill
+    with_prefill = {"prefill_device_ms_per_ktok": ["solar.doc-1"] + lanes}
     for name in NEW:
-        assert layer[name].get("workloads") == (
-            lanes if name in only_lanes else None), name
+        assert layer[name].get("workloads") == with_prefill.get(
+            name, lanes if name in only_lanes else None), name
     # appended: what was there keeps its place
     assert list(layer)[-len(NEW):] == NEW

@@ -23,6 +23,18 @@ def newest_trace(profile_dir: str) -> str | None:
     return max(paths, key=os.path.getmtime) if paths else None
 
 
+def capture_of(run: dict) -> str | None:
+    """The trace file of a traced run's capture: the newest under the
+    directory the run itself set for it (``run["profile_dir"]``), looked up
+    once and kept in ``run["capture_path"]``.  Every reader of the capture
+    finds it here, never through the answer of ``/debug/profile``, which
+    may come late or not at all while the file is on disk."""
+    if "capture_path" not in run:
+        where = run.get("profile_dir")
+        run["capture_path"] = newest_trace(where) if where else None
+    return run["capture_path"]
+
+
 def load(path: str) -> dict:
     """{"devices": {plane name: {line name: [(name, start_s, dur_s)]}},
     "host": {thread name: [Python frames as (name, start_s, dur_s)]}} of one
