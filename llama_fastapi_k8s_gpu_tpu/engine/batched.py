@@ -355,9 +355,10 @@ class MeshEngine(Engine):
             if n_steps <= 0:
                 break                                 # capacity: "length"
             t_chunk = time.time()
-            state, toks = batched_generate_chunk_jit(
+            state, out = batched_generate_chunk_jit(
                 self.params, self.cfg, state, st,
                 n_steps=n_steps, top_k=sp.top_k)
+            toks = self._take_expert_stats(out)
             chunk = np.asarray(toks)                  # (n_steps, B) host sync
             for b in range(B):
                 if done[b]:

@@ -1489,11 +1489,16 @@ class ContinuousEngine(MeshEngine):
                         wave = tot["chunks_dispatched"]
                         with phase("dispatch_chunk", wave=wave,
                                    lanes_live=sum(s is not None for s in pre)):
-                            self._bstate, toks = \
+                            # a routed block is told which lanes hold a
+                            # request: the others' rows reach no expert
+                            live = np.array([s is not None for s in pre]) \
+                                if self.cfg.n_experts else None
+                            self._bstate, out = \
                                 batched_generate_chunk_perlane_jit(
                                     self.params, self.cfg, self._bstate,
                                     self._lane_st, n_steps=self.decode_chunk,
-                                    top_k=self._max_top_k)
+                                    top_k=self._max_top_k, live=live)
+                            toks = self._take_expert_stats(out)
                         self._spec_stats["chunk_steps"] += 1
                         # (lanes, tokens, dispatch number, slices queued on
                         # the device ahead of this chunk)

@@ -38,6 +38,7 @@ from ..models.generate import (
     prefill_chunk_jit,
     prefill_jit,
     sample_jit,
+    split_chunk_out,
 )
 from ..models.llama import init_cache
 from ..models.params import load_params, synth_params
@@ -47,6 +48,7 @@ from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import arm_phases, phase, rid
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
+from .expert_counters import ExpertCounters
 from ..utils.jaxcache import setup_compile_cache
 
 logger = logging.getLogger(__name__)
@@ -248,6 +250,7 @@ class Engine:
                 "continuous scheduler; %s serves vanilla decode "
                 "(see _spec_enabled)", type(self).__name__)
         self._lock = threading.Lock()
+        self._expert_counters: ExpertCounters | None = None
         self._base_seed = seed
         # request counter: shared by the serial path (caller thread) and the
         # continuous scheduler thread; _next_seed() is the only writer and
@@ -288,26 +291,35 @@ class Engine:
                 # tensor.  On CPU
                 # (tests) the interpret-mode kernels are slow, so big
                 # models requantize to int8 instead.
-                n_lin = self.cfg.n_layers * (
-                    4 * self.cfg.dim * self.cfg.dim
-                    + 3 * self.cfg.dim * self.cfg.ffn_dim
-                )
-                if n_lin * 2 <= 4e9:
+                if self.cfg.n_linear_weights * 2 <= 4e9:
                     weight_format = "bf16"
                 elif jax.default_backend() == "tpu":
                     weight_format = "q4k"
                 else:
                     weight_format = "int8"
             fused_types = None
+            fused_experts = True
             if weight_format == "q4k":
                 present = {t.ggml_type for t in gf.tensors.values()}
                 _pt = time.time()
                 weight_format, fused_types = self._probe_fused_format(present)
+                if self.cfg.n_experts and weight_format == "q4k":
+                    # the grouped expert kernels: probed only for a file
+                    # that has experts (a dense pod's start pays nothing)
+                    from ..ops.pallas.probe import probe_fused_experts
+
+                    err = probe_fused_experts()
+                    if err is not None:
+                        fused_experts = False
+                        logger.error(
+                            "grouped expert kernels failed their compile "
+                            "probe; experts load dequantized: %s", err)
                 self.load_phases["probes_s"] = round(time.time() - _pt, 1)
             _pt = time.time()
             sub: dict = {}
             self.params = load_params(gf, self.cfg, weight_format,
-                                      fused_types=fused_types, phases_out=sub)
+                                      fused_types=fused_types, phases_out=sub,
+                                      fused_experts=fused_experts)
             self.load_phases["params_s"] = round(time.time() - _pt, 1)
             self.load_phases.update(
                 {f"params_{k}_s": round(v, 1) for k, v in sub.items()})
@@ -781,8 +793,28 @@ class Engine:
         return logits, cache
 
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int):
-        return generate_chunk_jit(self.params, self.cfg, state, st,
-                                  n_steps=n_steps, top_k=top_k)
+        state, out = generate_chunk_jit(self.params, self.cfg, state, st,
+                                        n_steps=n_steps, top_k=top_k)
+        return state, self._take_expert_stats(out)
+
+    def _take_expert_stats(self, chunk_out):
+        """A decode chunk's tokens; a routed block's counters go to
+        :attr:`expert_counters` on the way (still on the device)."""
+        tokens, stats = split_chunk_out(chunk_out)
+        if stats is not None:
+            self.expert_counters.push(stats)
+        return tokens
+
+    @property
+    def expert_counters(self):
+        """The routed layers' cumulative counters
+        (engine/expert_counters.py), None for a dense block.  Made on
+        first use: the configuration is final only after ``__init__``."""
+        if not self.cfg.n_experts:
+            return None
+        if self._expert_counters is None:
+            self._expert_counters = ExpertCounters(self.cfg.n_experts)
+        return self._expert_counters
 
     def _next_seed(self) -> int:
         with self._id_lock:

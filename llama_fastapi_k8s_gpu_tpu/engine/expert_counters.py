@@ -1,0 +1,50 @@
+"""The routed layers' counters, host side.
+
+A decode chunk of a routed block returns, beside its tokens, one small
+int32 vector (models/llama.py ``expert_stats_len``): the (layer, step) pairs
+it ran, the distinct experts read summed over them, and the rows each
+expert took.  The engines hand that DEVICE array here at dispatch
+(:meth:`push`: a list append, nothing fetched, nothing waited for) and the
+totals are folded when somebody reads them: a ``/metrics`` scrape folds the
+chunks that have finished (:meth:`snapshot`), a test folds them all
+(``block=True``).  With nobody reading, nothing on the decode path pays
+more than the append.
+"""
+
+from __future__ import annotations
+
+import threading
+
+import numpy as np
+
+_MAX_PENDING = 64   # older chunks have long finished: folding them is free
+
+
+class ExpertCounters:
+    def __init__(self, n_experts: int):
+        self._lock = threading.Lock()
+        self._pending: list = []
+        self._total = np.zeros(2 + n_experts, np.int64)
+
+    def push(self, stats) -> None:
+        """One dispatched chunk's counter vector (a device array)."""
+        with self._lock:
+            self._pending.append(stats)
+            if len(self._pending) > _MAX_PENDING:
+                self._total += np.asarray(self._pending.pop(0))
+
+    def snapshot(self, block: bool = False) -> dict:
+        """Cumulative counters of the chunks that have finished (all
+        dispatched chunks with ``block``): ``layer_steps``, ``experts_read``
+        and ``picks`` (a list, one count per expert)."""
+        with self._lock:
+            keep = []
+            for s in self._pending:
+                if block or s.is_ready():
+                    self._total += np.asarray(s)
+                else:
+                    keep.append(s)
+            self._pending = keep
+            t = self._total
+            return {"layer_steps": int(t[0]), "experts_read": int(t[1]),
+                    "picks": t[2:].tolist()}

@@ -102,5 +102,6 @@ class SPEngine(Engine):
                           self.mesh)
 
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int):
-        return sp_generate_chunk(self.params, self.cfg, state, st, self.mesh,
-                                 n_steps, top_k)
+        state, out = sp_generate_chunk(self.params, self.cfg, state, st,
+                                       self.mesh, n_steps, top_k)
+        return state, self._take_expert_stats(out)

@@ -105,6 +105,24 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 }
 
 
+#: ``general.architecture`` values whose block of layers the program
+#: computes (models/llama.py): ``llama``/``mistral`` are the dense block
+#: (GQA attention + SwiGLU), ``olmoe`` the routed block (QK-norm over the
+#: whole projection, a float32 router over ``<arch>.expert_count`` SwiGLU
+#: experts stacked in 3-D ``ffn_*_exps`` tensors, ``expert_used_count`` of
+#: them per token, their probabilities unnormalised).  A file of any other
+#: architecture is refused by name at load (gguf/reader.py).
+SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe")
+
+#: Of those, the architectures whose rotary embedding pairs dimension i
+#: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
+#: their converter leaves Q/K as Hugging Face stores them.  The others'
+#: converter permutes Q/K rows so that the pairs are (2i, 2i+1) (ggml's
+#: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
+#: the whole projection.
+NEOX_ROPE_ARCHITECTURES = ("olmoe",)
+
+
 def align_up(n: int, alignment: int) -> int:
     return (n + alignment - 1) // alignment * alignment
 

@@ -21,6 +21,7 @@ from .constants import (
     GGUF_SCALAR_FMT as _SCALAR_FMT,
     GGMLType,
     GGUFValueType,
+    SERVED_ARCHITECTURES,
     align_up,
     tensor_nbytes,
 )
@@ -164,6 +165,19 @@ class GGUFFile:
     @property
     def architecture(self) -> str:
         return self.metadata.get("general.architecture", "llama")
+
+    def require_served(self) -> str:
+        """The file's architecture, if the program computes its block of
+        layers; a :class:`ValueError` naming it otherwise.  Every hparam of
+        an unknown architecture would still read (``<arch>.<key>``), so
+        without this the file loads as the dense block and fails later on
+        a tensor it lacks."""
+        arch = self.architecture
+        if arch not in SERVED_ARCHITECTURES:
+            raise ValueError(
+                f"{self.path}: general.architecture {arch!r} is not served "
+                f"(served: {', '.join(SERVED_ARCHITECTURES)})")
+        return arch
 
     def hparam(self, key: str, default=None):
         """Look up ``<arch>.<key>`` with a plain-key fallback."""

@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 
 from ..gguf import GGUFFile
+from ..gguf.constants import NEOX_ROPE_ARCHITECTURES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,13 +50,37 @@ class ModelConfig:
     # A-B) is just ``dataclasses.replace`` — the knob is part of every
     # compiled program's static signature.
     decode_layer_unroll: int = 0
+    # The feed-forward kind (models/llama.py ``_layer``): ``n_experts == 0``
+    # is the dense SwiGLU of width ``ffn_dim``; otherwise a float32 router
+    # picks ``n_experts_used`` of ``n_experts`` SwiGLU experts of width
+    # ``ffn_dim`` per token (no shared expert, no capacity limit), their
+    # softmax-over-all probabilities renormalised over the picked ones only
+    # when ``norm_topk_prob``.
+    n_experts: int = 0
+    n_experts_used: int = 0
+    norm_topk_prob: bool = False
+    # RMSNorm of Q and K over the WHOLE projection width, before the split
+    # into heads and before RoPE (OLMoE; weights ``attn_{q,k}_norm``)
+    qk_norm: bool = False
+    # RoPE pairs dimension i with i + head_dim/2 (rotate-half, ggml NEOX)
+    # instead of 2i with 2i+1: by the file's architecture
+    # (gguf/constants.py NEOX_ROPE_ARCHITECTURES)
+    rope_neox: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    @property
+    def n_linear_weights(self) -> int:
+        """About how many weights the layers' matrices hold, every expert
+        included: what the ``weight_format="auto"`` size test weighs."""
+        ffn = 3 * self.dim * self.ffn_dim * max(self.n_experts, 1)
+        return self.n_layers * (4 * self.dim * self.dim + ffn)
+
     @classmethod
     def from_gguf(cls, gf: GGUFFile, n_ctx: int | None = None) -> "ModelConfig":
+        arch = gf.require_served()
         h = gf.hparam
         n_heads = int(h("attention.head_count"))
         vocab = h("vocab_size")
@@ -75,6 +100,13 @@ class ModelConfig:
             rms_eps=float(h("attention.layer_norm_rms_epsilon", 1e-5)),
             sliding_window=window,
             tie_embeddings="output.weight" not in gf.tensors,
+            n_experts=int(h("expert_count", 0) or 0),
+            n_experts_used=int(h("expert_used_count", 0) or 0),
+            # llama.cpp's olmoe graph: build_moe_ffn(..., norm_w=false) and
+            # build_norm over the whole Qcur/Kcur
+            norm_topk_prob=False,
+            qk_norm=arch == "olmoe",
+            rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
         )
 
 

@@ -83,9 +83,9 @@ def generate_chunk(params, cfg: ModelConfig, state: dict, st: dict,
     context for sequence-parallel decode)."""
 
     def step(carry, _):
-        logits, cache = forward(
-            params, cfg, carry["token"][None], carry["pos"], carry["cache"]
-        )
+        logits, cache, *stats = forward(
+            params, cfg, carry["token"][None], carry["pos"], carry["cache"],
+            with_stats=bool(cfg.n_experts))
         key, sub = jax.random.split(carry["key"])
         token = sample_chain(logits, carry["window"], sub, st, top_k=top_k)
         window = carry["window"].at[carry["wpos"] % PENALTY_WINDOW].set(token)
@@ -97,9 +97,23 @@ def generate_chunk(params, cfg: ModelConfig, state: dict, st: dict,
             "wpos": carry["wpos"] + 1,
             "key": key,
         }
-        return new_carry, token
+        return new_carry, (token, *stats)
 
-    return jax.lax.scan(step, state, None, length=n_steps)
+    state, ys = jax.lax.scan(step, state, None, length=n_steps)
+    return state, chunk_out(*ys)
+
+
+def chunk_out(tokens, stats=None):
+    """What a decode chunk hands the host beside its state: the sampled
+    tokens, and for a routed block (``cfg.n_experts``) the pair (tokens,
+    the chunk's expert counters summed over its steps: llama.py
+    ``expert_stats_len``).  :func:`split_chunk_out` takes it apart."""
+    return tokens if stats is None else (tokens, jnp.sum(stats, axis=0))
+
+
+def split_chunk_out(out):
+    """(tokens, expert counters or None) of a decode chunk's output."""
+    return out if isinstance(out, tuple) else (out, None)
 
 
 @functools.partial(
@@ -112,7 +126,8 @@ def generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
     """Run ``n_steps`` decode+sample steps on device.
 
     state["token"] is the most recently sampled (not yet decoded) token.
-    Returns (new_state, tokens (n_steps,)) — the tokens sampled this chunk.
+    Returns (new_state, tokens (n_steps,)) — the tokens sampled this chunk
+    (for a routed block, with its counters: :func:`chunk_out`).
     """
     return generate_chunk(params, cfg, state, st, n_steps, top_k)
 

@@ -13,6 +13,10 @@ preallocated, donated KV cache.  Design choices are TPU-first:
 - sliding-window masking (Mistral) is the same mask with one extra term;
 - matmuls go through ``ops.linear`` so bf16 / int8 / (later) fused-Q4_K
   weights are interchangeable without touching the graph.
+- the feed-forward kind is the configuration's: dense SwiGLU, or
+  (``cfg.n_experts``) a float32 router over SwiGLU experts whose products
+  are computed for the picked experts only (ops/pallas/experts.py); so is
+  the RMSNorm of Q and K (``cfg.qk_norm``).  One layer body, not a copy.
 
 RoPE is the *interleaved* (ggml "NORM") variant: GGUF conversion permutes
 Q/K weights to this convention, so parity with llama.cpp requires it.
@@ -48,6 +52,29 @@ def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Ar
     o1 = x1 * cos - x2 * sin
     o2 = x1 * sin + x2 * cos
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """x: (S, H, hd); rotate pairs (i, i + hd/2) by pos * theta^(-2i/hd):
+    "rotate-half", ggml's NEOX mode, what Hugging Face computes on
+    unpermuted Q/K."""
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (S, half)
+    cos = jnp.cos(ang)[:, None, :]  # (S, 1, half)
+    sin = jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The rotary embedding in the pairing the file's architecture stores
+    its Q/K for (``cfg.rope_neox``, from ``gguf.constants
+    NEOX_ROPE_ARCHITECTURES``)."""
+    fn = rope_half if cfg.rope_neox else rope_interleaved
+    return fn(x, positions, cfg.rope_theta)
 
 
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
@@ -143,12 +170,37 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
     return ctx.transpose(2, 0, 1, 3).reshape(S, cfg.n_heads * hd).astype(out_dtype)
 
 
+def route(hn, w_router, cfg: ModelConfig):
+    """The router of a routed feed-forward: ``softmax(W_r · hn)`` over ALL
+    experts in float32, the ``n_experts_used`` largest picked, their
+    probabilities as they are unless ``cfg.norm_topk_prob``.  hn (S, dim),
+    w_router (E, dim) f32 -> (picks (S, k) int32, weights (S, k) f32)."""
+    logits = jnp.einsum("sd,ed->se", hn.astype(jnp.float32), w_router,
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, picks = jax.lax.top_k(probs, cfg.n_experts_used)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return picks.astype(jnp.int32), weights
+
+
+def expert_stats_len(cfg: ModelConfig) -> int:
+    """Length of the routed layers' counter vector (:func:`forward`
+    ``with_stats``): [(layer, step) pairs, distinct experts read summed
+    over them, rows each expert took...]."""
+    return 2 + cfg.n_experts
+
+
 def _layer(h, layers, i, cache, positions, pos_offset,
-           cfg: ModelConfig):
+           cfg: ModelConfig, live=None):
     """One transformer block over S tokens against layer ``i`` of the
     stacked weights. ``cache``: the FULL stacked cache pytree, head-major
     (L, n_kv, n_ctx, hd) value leaves (+ (L, n_kv, n_ctx) scale leaves
-    under ``kv_dtype=int8``).
+    under ``kv_dtype=int8``).  Returns (h, cache, routed): None for the
+    dense feed-forward, else (rows each expert took (E,) int32, the
+    router's picks (S, k) int32).  ``live`` (scalar bool
+    or None): False marks a lane that holds no request, whose rows then
+    reach no expert (its output is not read).
 
     The weights stay STACKED (L, ...) and are addressed per layer with
     :func:`ops.linear.linear_at` — scanning them as xs would materialize a
@@ -175,11 +227,15 @@ def _layer(h, layers, i, cache, positions, pos_offset,
             return jax.lax.dynamic_update_slice(leaf, new[None], at)
 
     hn = rms_norm(h, layers["attn_norm"][i], cfg.rms_eps)
-    q = lin(hn, "wq").reshape(S, cfg.n_heads, hd)
-    k = lin(hn, "wk").reshape(S, n_kv, hd)
+    q, k = lin(hn, "wq"), lin(hn, "wk")
+    if cfg.qk_norm:   # over the whole projection, before heads and RoPE
+        q = rms_norm(q, layers["attn_q_norm"][i], cfg.rms_eps)
+        k = rms_norm(k, layers["attn_k_norm"][i], cfg.rms_eps)
+    q = q.reshape(S, cfg.n_heads, hd)
+    k = k.reshape(S, n_kv, hd)
     v = lin(hn, "wv").reshape(S, n_kv, hd)
-    q = rope_interleaved(q, positions, cfg.rope_theta)
-    k = rope_interleaved(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg)
+    k = rope(k, positions, cfg)
 
     if quant:
         # quantize ONLY the S new tokens' head-major slab (kvquant.py: int8
@@ -243,9 +299,21 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     h = h + lin(ctx, "wo")
 
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
+    if cfg.n_experts:
+        from ..ops.pallas.experts import routed_experts
+
+        with jax.named_scope("router"):
+            picks, weights = route(hn, layers["w_router"][i], cfg)
+        if live is not None:
+            picks = jnp.where(live, picks, cfg.n_experts)
+        with jax.named_scope("experts"):
+            out, count = routed_experts(
+                hn, picks, weights, layers["w_gate_exps"],
+                layers["w_up_exps"], layers["w_down_exps"], i)
+        return h + out, cache, (count, picks)
     gated = jax.nn.silu(lin(hn, "w_gate").astype(jnp.float32)).astype(h.dtype)
     h = h + lin(gated * lin(hn, "w_up"), "w_down")
-    return h, cache
+    return h, cache, None
 
 
 def _loop_unroll(params: dict, cfg: ModelConfig, S: int):
@@ -291,10 +359,16 @@ def forward(
     cache: dict,
     last_idx: jax.Array | None = None,  # scalar int32: position of last real token
     return_all: bool = False,
+    live: jax.Array | None = None,
+    with_stats: bool = False,
+    with_picks: bool = False,
 ):
     """Run S tokens through the stack. Returns (logits, new_cache):
     logits (vocab,) at ``last_idx`` (default S-1), or (S, vocab) if
-    ``return_all``."""
+    ``return_all``.  ``live``: see :func:`_layer`.  Of a routed block,
+    ``with_stats`` appends the counter vector of :func:`expert_stats_len`
+    and ``with_picks`` the routers' picks (L, S, k) int32 (what the
+    comparison with the reference counts mismatches on)."""
     S = tokens.shape[0]
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
@@ -319,6 +393,9 @@ def forward(
     # probe-degrade flag are all static, so the per-layer path below
     # compiles exactly as before whenever the loop is off or ineligible.
     K, loop_fmts = _loop_unroll(params, cfg, S)
+    routed = [jnp.zeros(expert_stats_len(cfg), jnp.int32),
+              jnp.zeros((cfg.n_layers, S, cfg.n_experts_used), jnp.int32)] \
+        if cfg.n_experts else []
     if K:
         from ..ops.pallas.decode_loop import forward_layers_looped
 
@@ -331,24 +408,36 @@ def forward(
         # MB/token at n_ctx 1024, ~2 GB at 8192 — measured as most of the
         # 8k decode gap)
         def body(i, carry):
-            return _layer(carry[0], params["layers"], jnp.int32(i), carry[1],
-                          positions, pos_offset, cfg)
+            h, cache, out = _layer(
+                carry[0], params["layers"], jnp.int32(i), carry[1],
+                positions, pos_offset, cfg, live)
+            if out is None:
+                return h, cache
+            count, picks = out
+            read = jnp.sum(count > 0, dtype=jnp.int32)
+            stats = carry[2] + jnp.concatenate(
+                [jnp.stack([jnp.int32(1), read]), count])
+            return h, cache, stats, jax.lax.dynamic_update_slice(
+                carry[3], picks[None], (i, 0, 0))
 
-        h, new_cache = jax.lax.fori_loop(0, cfg.n_layers, body, (h, cache))
+        h, new_cache, *routed = jax.lax.fori_loop(
+            0, cfg.n_layers, body, (h, cache, *routed))
 
     out_w = params["output"]
+    tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))
+                 if want)
     if return_all:
         hn = rms_norm(h, params["out_norm"], cfg.rms_eps)
         with jax.named_scope("head"):
             logits = linear(hn, out_w).astype(jnp.float32)
-        return logits, new_cache
+        return (logits, new_cache, *tail)
     if last_idx is None:
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
     hn = rms_norm(h_last, params["out_norm"], cfg.rms_eps)
     with jax.named_scope("head"):
         logits = linear(hn, out_w).astype(jnp.float32)[0]
-    return logits, new_cache
+    return (logits, new_cache, *tail)
 
 
 def prefill(params, cfg: ModelConfig, tokens, length, cache):

@@ -22,7 +22,13 @@ Formats (``ops.linear``):
 GGUF tensor names follow llama.cpp's convention: ``token_embd.weight``,
 ``blk.{i}.attn_{q,k,v,output}.weight``, ``blk.{i}.ffn_{gate,up,down}.weight``,
 ``blk.{i}.{attn,ffn}_norm.weight``, ``output_norm.weight``, ``output.weight``
-(absent when embeddings are tied).
+(absent when embeddings are tied).  A routed block (``cfg.n_experts``; the
+``olmoe`` architecture) has, in place of the three ``ffn_*`` matrices, the
+F32 router ``blk.{i}.ffn_gate_inp.weight`` (E, dim) and the 3-D
+``blk.{i}.ffn_{gate,up,down}_exps.weight`` (E, out, in), which stay fused
+K-quant planes with a leading (layer, expert) pair of axes
+(ops/pallas/experts.py); with ``cfg.qk_norm`` also
+``blk.{i}.attn_{q,k}_norm.weight``.
 """
 
 from __future__ import annotations
@@ -76,7 +82,8 @@ def _stack(dicts: list[dict], free: bool = False) -> dict:
 def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 on_device: bool | None = None,
                 fused_types: frozenset | None = None,
-                phases_out: dict | None = None) -> dict:
+                phases_out: dict | None = None,
+                fused_experts: bool = True) -> dict:
     """Dequantize all tensors from ``gf`` into a stacked param pytree.
 
     ``on_device=True`` (default on TPU) routes quantized tensors through the
@@ -87,6 +94,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     under ``fmt="q4k"`` (default: Q4_K, Q5_K, Q6_K and Q8_0).  The engine passes
     the set of types whose compile probes passed, so a Mosaic regression
     in ONE kernel degrades only that format's tensors to int8.
+    ``fused_experts=False`` (the grouped expert kernels failed their probe)
+    loads a routed block's experts dequantized.
     """
     if on_device is None:
         on_device = jax.default_backend() == "tpu"
@@ -111,21 +120,30 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         fusable = tuple(fused_types) if fused_types is not None \
             else (GGMLType.Q4_K, GGMLType.Q5_K, GGMLType.Q6_K, GGMLType.Q8_0)
         k_rank = {GGMLType.Q4_K: 0, GGMLType.Q5_K: 1, GGMLType.Q6_K: 2}
-        names = ["attn_q", "attn_k", "attn_v", "attn_output",
-                 "ffn_gate", "ffn_up", "ffn_down"]
+        from ..ops.pallas.experts import experts_compatible
+
+        names = ["attn_q", "attn_k", "attn_v", "attn_output"]
+        if not cfg.n_experts:
+            names += ["ffn_gate", "ffn_up", "ffn_down"]
+        elif fused_experts:
+            names += ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"]
         ok: dict[str, object] = {}
         for n in names:
             ts = [gf[f"blk.{i}.{n}.weight"] for i in range(cfg.n_layers)]
-            if not all(q4k_compatible(*reversed(t.shape)) for t in ts):
+            # an expert stack (E, out, in): the grouped kernels' two types
+            fits, allowed = (experts_compatible, [
+                t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
+                if n.endswith("_exps") else (q4k_compatible, fusable)
+            if not all(fits(*reversed(t.shape[:2])) for t in ts):
                 continue
             types = {t.ggml_type for t in ts}
             if len(types) == 1:
                 t0 = ts[0].ggml_type
-                if t0 in fusable:
+                if t0 in allowed:
                     ok[n] = t0
             elif types <= set(k_rank):
                 target = max(types, key=k_rank.get)
-                if target in fusable:
+                if target in allowed:
                     ok[n] = target
         t = gf.tensors.get("output.weight")
         if t is not None and t.ggml_type in fusable \
@@ -176,6 +194,25 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     def norm(name: str):
         return jnp.asarray(gf[name].astype_f32(), dtype=jnp.float32)
 
+    def experts(name: str) -> dict:
+        """A 3-D expert tensor (E, out, in): fused planes with a leading
+        expert axis where its name fuses, else dequantized bf16."""
+        t = gf[name]
+        target = fused_names.get(name.split(".")[-2])
+        if target is not None:
+            from ..gguf.quants import quantize
+            from ..ops.pallas.experts import prep_experts
+
+            k_in, n_out, n_exp = t.shape
+            raw = np.asarray(t.raw()) if t.ggml_type == target \
+                else quantize(t.astype_f32(), target)   # K-quant promotion
+            w = prep_experts(raw, n_exp, n_out, k_in, target)
+            if w is not None:
+                return w
+        if on_device:
+            return {"w": _tensor_to_device(t, jnp.bfloat16)}
+        return {"w": jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)}
+
     # LFKT_LOAD_OVERLAP=1: enqueue each layer's host→device transfer the
     # moment its planes are packed, so the (async) transfers stream while
     # the C++ packers prep the NEXT layers, instead of serializing all
@@ -200,10 +237,18 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             "wv": lin(p + "attn_v.weight"),
             "wo": lin(p + "attn_output.weight"),
             "ffn_norm": norm(p + "ffn_norm.weight"),
-            "w_gate": lin(p + "ffn_gate.weight"),
-            "w_up": lin(p + "ffn_up.weight"),
-            "w_down": lin(p + "ffn_down.weight"),
         }
+        if cfg.qk_norm:
+            layer["attn_q_norm"] = norm(p + "attn_q_norm.weight")
+            layer["attn_k_norm"] = norm(p + "attn_k_norm.weight")
+        if cfg.n_experts:
+            layer["w_router"] = norm(p + "ffn_gate_inp.weight")
+            for key in ("gate", "up", "down"):
+                layer[f"w_{key}_exps"] = experts(
+                    p + f"ffn_{key}_exps.weight")
+        else:
+            for key in ("gate", "up", "down"):
+                layer[f"w_{key}"] = lin(p + f"ffn_{key}.weight")
         if overlap:
             layer = jax.tree.map(jax.device_put, layer)
         layers.append(layer)

@@ -306,6 +306,18 @@ METRICS: dict[str, Metric] = _register(
            "scope=pod on replica scrapes, scope=fleet when the router "
            "evaluates the catalog over federated histograms",
            labels=("slo", "window", "scope")),
+    # -- routed layers (engine/expert_counters.py; a file with experts) ----
+    Metric("expert_layer_steps_total", GAUGE,
+           "routed feed-forward layers run by decode chunks: (layer, step) "
+           "pairs, cumulative; counted on the device, folded at scrape"),
+    Metric("experts_read_total", GAUGE,
+           "distinct experts whose weights a decode step's live lanes made "
+           "the grouped matmuls read, summed over the (layer, step) pairs; "
+           "over expert_layer_steps_total = experts read per layer-step"),
+    Metric("expert_picks_total", GAUGE,
+           "(token, pick) rows each expert took in decode chunks, "
+           "cumulative; the largest over their sum is the most-loaded "
+           "expert's share", labels=("expert",)),
     # -- runtime-synthesized families --------------------------------------
     Metric("scheduler_", GAUGE,
            "continuous-scheduler family (ContinuousEngine.scheduler_stats). "

@@ -15,7 +15,7 @@ MLP — so a decode step goes from O(L × ops) launches to O(L/K)
 
 Bit-exactness contract: the kernel body executes the SAME source the
 per-layer path executes — :func:`models.llama.rms_norm` /
-:func:`~models.llama.rope_interleaved` / :func:`~models.llama.
+:func:`~models.llama.rope` / :func:`~models.llama.
 xla_attention`, :func:`ops.linear.linear` on the per-layer weight dicts,
 :func:`~.kvquant.quantize_kv_xla`, and the same ``dynamic_update_slice``
 ring write — traced per layer in the same order, on the same dtypes.  On
@@ -151,7 +151,7 @@ def _loop_kernel(s_ref, h_ref, *rest, cfg, fmts, out_count: int):
     and the ``h`` scratch.  All math below is the per-layer path's own
     source (models/llama.py, ops/linear.py, kvquant.py), which is the
     whole bit-exactness argument."""
-    from ...models.llama import rms_norm, rope_interleaved, xla_attention
+    from ...models.llama import rms_norm, rope, xla_attention
     from ...ops.linear import linear
     from .kvquant import quantize_kv_xla
 
@@ -197,8 +197,8 @@ def _loop_kernel(s_ref, h_ref, *rest, cfg, fmts, out_count: int):
     q = lin(hn, "wq").reshape(1, cfg.n_heads, hd)
     k = lin(hn, "wk").reshape(1, n_kv, hd)
     v = lin(hn, "wv").reshape(1, n_kv, hd)
-    q = rope_interleaved(q, positions, cfg.rope_theta)
-    k = rope_interleaved(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg)
+    k = rope(k, positions, cfg)
 
     if quant:
         # the XLA quantize formulation, not quantize_kv_pallas: a

@@ -1,0 +1,475 @@
+"""Routed experts over fused K-quant planes: the grouped matmul.
+
+A routed feed-forward layer (models/llama.py ``_layer``, ``cfg.n_experts``)
+sends each token to ``k`` of ``E`` SwiGLU experts.  Its weights are the
+GGUF file's 3-D ``ffn_{gate,up,down}_exps`` tensors, kept as the SAME fused
+Q4_K / Q6_K planes the dense matmuls read (ops/pallas/qmatmul.py,
+q6matmul.py) with an (L, E) pair of leading axes, and computed by the SAME
+kernel bodies.  What is new is the grid around them:
+
+- the grid is (N tile, expert slot, K tile); the layer, the number of
+  slots in use and each slot's expert ride a prefetched scalar vector, and
+  the weight BlockSpecs address ``planes[layer, expert]`` through it, as
+  ``qmatmul.stacked_pallas_call`` addresses ``planes[layer]``.  Slots past
+  the last one in use repeat its block indices (no DMA) and skip the body,
+  so a step reads and multiplies the experts its rows picked and no others;
+- FEW rows (a decode step: lanes x k (token, pick) rows, at most
+  ``FEW_ROWS``): a slot is a distinct expert; every slot sees ALL the rows
+  (one resident block) and adds its product into the one output block for
+  the rows that picked its expert, zero for the rest
+  (:func:`grouped_matmul_few`).  No sort, no gather, no padding: the MXU
+  does a few times the work of the picked rows alone, which is not what
+  bounds these kernels;
+- MANY rows (a prefill slice): the rows are sorted by expert and laid out
+  in tiles of ``TM_MANY`` rows, each expert's rows padded up to whole
+  tiles, so a slot is a row tile of one expert (:func:`plan_groups`,
+  :func:`grouped_matmul_many`).  The split is the one ``qmatmul
+  .kernel_name`` makes for the dense kernels.
+
+The kernels take K in tiles of 2048.  An expert matrix whose K is a
+divisor of 2048 (OLMoE's down projection: K = 1024) is *folded*: ``f =
+2048 // K`` consecutive output rows are read as one row of ``f * K``
+(:func:`prep_experts` hands the packers the same bytes under that shape),
+and each activation row is offered ``f`` times, shifted into segment ``j``
+of the wider row, so that copy ``j`` yields outputs ``f * n + j``.  The
+weight bytes read stay the file's; the MXU does ``f`` times the few-row
+work, which is not what bounds these kernels.
+
+:func:`routed_experts` is the whole layer after the router: gather, gate
+and up, SwiGLU, down, the weighted sum over a token's picks.  It carries a
+``vmap`` rule that turns a batch of lanes into more rows of one call
+(weights are shared), so the lane engines' vmapped decode step
+(parallel/batched.py) groups the picks of all lanes together.  A pick equal
+to ``E`` is no pick: the lane engine marks a lane that holds no request so,
+and its rows reach no expert.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+
+from ...gguf.constants import GGMLType
+from ...obs.devtime import register_program
+from . import q6matmul as _q6
+from . import qmatmul as _q4
+from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
+
+FEW_ROWS = 128   # (token, pick) rows up to which every slot sees all rows
+TM_MANY = 128    # rows per tile of a prefill slice
+
+
+def fold_factor(k_in: int) -> int:
+    """Output rows read as one kernel row (module docstring)."""
+    return TK // k_in if k_in < TK and TK % k_in == 0 else 1
+
+
+def experts_compatible(n_out: int, k_in: int,
+                       for_tpu: bool | None = None) -> bool:
+    """Whether an (E, n_out, k_in) expert tensor can use the fused path."""
+    f = fold_factor(k_in)
+    return n_out % f == 0 and _q4.q4k_compatible(n_out // f, k_in * f, for_tpu)
+
+
+# ---------------------------------------------------------------------------
+# the two families: which planes, which kernel, which activation layout
+# ---------------------------------------------------------------------------
+
+class _Family:
+    def __init__(self, name, gtype, prep, planes, widths, kernel, tka,
+                 tn_prefs, permute, augment, variants):
+        self.name = name                # q4k | q6k
+        self.gtype, self.prep = gtype, prep   # ggml type, the dense packer
+        self.planes = planes            # plane keys, scale plane last
+        self.widths = widths            # value planes' bytes per K tile
+        self.kernel, self.tka, self.tn_prefs = kernel, tka, tn_prefs
+        self.permute, self.augment = permute, augment
+        self.variants = variants        # (env knob, allowed)
+
+    def variant(self) -> str:
+        v = _env_variant(*self.variants)
+        # `pre` is a Q6_K *layout*; split planes run the split default
+        return "cur" if v == "pre" else v
+
+
+FAMILIES = {
+    "q4k": _Family("q4k", GGMLType.Q4_K, _q4.prep_q4k, ("qs", "sm"),
+                   (TK // 2,), _q4._q4k_matmul_kernel, _q4.TKA, _q4._TN_PREFS_Q4K, _q4.permute_x, _q4.augment_x,
+                   ("LFKT_Q4K_KERNEL", _q4.Q4K_VARIANTS)),
+    "q6k": _Family("q6k", GGMLType.Q6_K, _q6.prep_q6k, ("q4", "q2", "sm6"),
+                   (TK // 2, TK // 4), _q6._q6k_matmul_kernel, _q6.TKA6, _q6._TN_PREFS_Q6K,
+                   _q6.permute_x6, _q6.augment_x6,
+                   ("LFKT_Q6K_KERNEL", _q6.Q6K_VARIANTS)),
+}
+
+
+def family_of(w: dict) -> str | None:
+    """The family of an expert weight dict, None for the dense fallback."""
+    return next((f.name for f in FAMILIES.values() if f.planes[0] in w), None)
+
+
+def prep_experts(raw: np.ndarray, n_experts: int, n_out: int, k_in: int,
+                 ggml_type) -> dict | None:
+    """Raw block bytes of an (E, n_out, k_in) expert tensor -> its fused
+    planes with a leading expert axis: value planes (E, N', K'/x), the scale
+    plane (E, K'/2048, N', 128), N' = n_out / f and K' = f * k_in.  The
+    dense packers do the work (the C++ ones where built): the bytes of E x
+    n_out rows of k_in are also those of E x N' rows of K'.  None where the
+    type has no expert kernel or the packer chose a layout it lacks (the
+    Q6_K ``pre`` layout): the caller loads the tensor dequantized."""
+    fam = next((f for f in FAMILIES.values() if f.gtype == ggml_type), None)
+    if fam is None or not experts_compatible(n_out, k_in):
+        return None
+    f = fold_factor(k_in)
+    w = fam.prep(raw, n_experts * n_out // f, k_in * f)
+    if set(w) != set(fam.planes):
+        return None
+    out = {}
+    for key, plane in w.items():
+        if key == fam.planes[-1]:                    # (kt, E*N', 128)
+            kt = plane.shape[0]
+            plane = plane.reshape(kt, n_experts, n_out // f, 128)
+            out[key] = jnp.transpose(plane, (1, 0, 2, 3))
+        else:                                        # (E*N', K'/x)
+            out[key] = plane.reshape(n_experts, n_out // f, -1)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# rows -> slots
+# ---------------------------------------------------------------------------
+
+def experts_in_use(row_expert: jax.Array, n_experts: int, n_slots: int):
+    """(rows per expert (E,), the distinct experts in rising order padded to
+    ``n_slots`` by repeating the last (so that an idle slot moves no block),
+    how many there are).  ``row_expert`` (R,) in [0, E]; E = no expert."""
+    i32 = jnp.int32
+    count = jnp.zeros(n_experts + 1, i32).at[row_expert].add(1)[:n_experts]
+    used = count > 0
+    n_used = jnp.sum(used, dtype=i32)
+    slot = jnp.where(used, jnp.cumsum(used) - 1, n_slots)
+    experts = jnp.zeros(n_slots, i32).at[slot].set(
+        jnp.arange(n_experts, dtype=i32), mode="drop")
+    t = jnp.arange(n_slots, dtype=i32)
+    experts = jnp.where(t < n_used, experts,
+                        experts[jnp.maximum(n_used - 1, 0)])
+    return count, experts, n_used
+
+
+def n_tiles(n_rows: int, n_experts: int, n_tokens: int, tm: int) -> int:
+    """Row tiles that always suffice: every expert in use wastes under one
+    tile, and holds at most one row per token (a token's picks differ)."""
+    return min(n_rows // tm + min(n_experts, n_rows),
+               n_experts * -(-n_tokens // tm))
+
+
+def plan_groups(row_expert: jax.Array, n_experts: int, n_tokens: int,
+                tm: int) -> dict:
+    """Lay R rows out by expert in T tiles of ``tm`` rows.  ``row_expert``
+    (R,) int32 in [0, E]; E = the row reaches no expert.  Returns
+
+    - ``src`` (T*tm,): the row that fills each padded slot, R for none;
+    - ``pos`` (R,): each row's padded slot, T*tm for a row with no expert;
+    - ``tile_expert`` (T,): each tile's expert (tiles past ``n_used``
+      repeat the last one in use), ``n_used``: tiles that hold rows;
+    - ``count`` (E,): rows per expert."""
+    E, R = n_experts, row_expert.shape[0]
+    T = n_tiles(R, E, n_tokens, tm)
+    P = T * tm
+    i32 = jnp.int32
+    count = jnp.zeros(E + 1, i32).at[row_expert].add(1)[:E]
+    tiles = (count + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    n_used = tile_end[-1]
+    slot0 = (tile_end - tiles) * tm          # an expert's first padded slot
+    rank0 = jnp.cumsum(count) - count        # its first row once sorted
+    order = jnp.argsort(row_expert, stable=True).astype(i32)
+    e_sorted = row_expert[order]
+    e_safe = jnp.minimum(e_sorted, E - 1)
+    dest = jnp.where(e_sorted < E,
+                     slot0[e_safe] + jnp.arange(R, dtype=i32) - rank0[e_safe],
+                     P)
+    pos = jnp.zeros(R, i32).at[order].set(dest)
+    src = jnp.full(P, R, i32).at[dest].set(order, mode="drop")
+    t = jnp.arange(T, dtype=i32)
+    te = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"), E - 1)
+    te = jnp.where(t < n_used, te, te[jnp.maximum(n_used - 1, 0)])
+    return {"src": src, "pos": pos, "tile_expert": te.astype(i32),
+            "n_used": n_used.astype(i32), "count": count}
+
+
+def _fold_rows(x: jax.Array, f: int, group: int) -> jax.Array:
+    """(P, K) -> (P*f, f*K): within each group of ``group`` rows, the rows
+    ``f`` times over, copy ``j`` shifted into segment ``j`` of the wider
+    row (module docstring)."""
+    if f == 1:
+        return x
+    P, K = x.shape
+    xt = x.reshape(P // group, group, K)
+    return jnp.concatenate(
+        [jnp.pad(xt, ((0, 0), (0, 0), (j * K, (f - 1 - j) * K)))
+         for j in range(f)], axis=1).reshape(P * f, f * K)
+
+
+def _unfold_rows(out: jax.Array, f: int, group: int) -> jax.Array:
+    """(P*f, N/f) -> (P, N): copy ``j``'s column ``n`` is output ``f*n+j``."""
+    if f == 1:
+        return out
+    G = out.shape[0] // (f * group)
+    return out.reshape(G, f, group, -1).transpose(0, 2, 3, 1).reshape(
+        G * group, -1)
+
+
+def _activations(x: jax.Array, fam: _Family) -> jax.Array:
+    """Rows -> the kernel's permuted, augmented bf16 rows."""
+    return fam.augment(fam.permute(x).astype(jnp.bfloat16))
+
+
+# ---------------------------------------------------------------------------
+# the grouped calls
+# ---------------------------------------------------------------------------
+
+class _NoLead2:
+    """Ref adapter hiding the length-1 (layer, expert) axes of an expert
+    plane block (``qmatmul._NoLead`` hides one)."""
+
+    __slots__ = ("_ref",)
+
+    def __init__(self, ref):
+        self._ref = ref
+
+    @property
+    def shape(self):
+        return self._ref.shape[2:]
+
+    def __getitem__(self, idx):
+        return self._ref[idx].reshape(self._ref.shape[2:])
+
+
+def expert_kernel_name(family: str, few: bool) -> str:
+    """``q4k_expert_matmul_fewrow`` ...: the name a profile shows
+    (benchmarks/kernels/expert_matmul.json finds the kernels by it)."""
+    return f"{family}_expert_matmul_{'fewrow' if few else 'manyrow'}"
+
+
+def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
+                  extra_in: tuple, interpret: bool, variant: str):
+    """The pallas_call both regimes share.  Grid (N tile, slot, K tile);
+    ``meta`` = [layer, slots in use, expert of slot 0..T-1].  ``few``: the
+    activation and output blocks are the whole (rows, ...) arrays, and
+    ``extra_in`` = the rows' experts (rows, 1); else they are slot ``t``'s
+    ``rows`` rows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    kt = xpa.shape[1] // fam.tka
+    T = meta.shape[0] - 2
+    N = planes[0].shape[2]
+    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(rows, fam.tn_prefs))
+
+    def rtile(t, m):         # a slot past the last in use stays on it
+        return 0 if few else jnp.minimum(t, jnp.maximum(m[1] - 1, 0))
+
+    def ktile(t, k, m):
+        return jnp.where(t < m[1], k, kt - 1)
+
+    specs = [pl.BlockSpec((rows, fam.tka),
+                          lambda n, t, k, m: (rtile(t, m), ktile(t, k, m)))]
+    specs += [pl.BlockSpec((rows, 1), lambda n, t, k, m: (0, 0))
+              for _ in extra_in]
+    specs += [pl.BlockSpec((1, 1, TN, w),
+                           lambda n, t, k, m: (m[0], m[2 + t], n,
+                                               ktile(t, k, m)))
+              for w in fam.widths]
+    specs.append(pl.BlockSpec((1, 1, 1, TN, 128),
+                              lambda n, t, k, m: (m[0], m[2 + t],
+                                                  ktile(t, k, m), n, 0)))
+
+    def body(meta_ref, x_ref, *rest):
+        t, k = pl.program_id(1), pl.program_id(2)
+        o_ref = rest[-1]
+        plane_refs = [_NoLead2(r) for r in rest[len(extra_in):-1]]
+        if few:
+            # every slot adds into the one block: its rows' share of it
+            mine = (rest[0][...] == meta_ref[2 + t]).astype(jnp.float32)
+
+            @pl.when((t == 0) & (k == 0))
+            def _():
+                o_ref[...] = jnp.zeros_like(o_ref)
+
+            def accum(o_ref, part):
+                o_ref[...] += part * mine
+        else:
+            def accum(o_ref, part):
+                o_ref[...] = jnp.where(k == 0, part, o_ref[...] + part)
+
+        @pl.when(t < meta_ref[1])
+        def _():
+            fam.kernel(x_ref, *plane_refs, o_ref, interpret=interpret,
+                       variant=variant, accum=accum)
+
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(N // TN, T, kt), in_specs=specs,
+            out_specs=pl.BlockSpec((rows, TN),
+                                   lambda n, t, k, m: (rtile(t, m), n))),
+        out_shape=jax.ShapeDtypeStruct((xpa.shape[0], N), jnp.float32),
+        interpret=interpret, name=expert_kernel_name(fam.name, few),
+    )(meta, xpa, *extra_in, *planes)
+
+
+def grouped_matmul_few(fam: _Family, meta, x, row_expert, planes, f: int,
+                       interpret: bool, variant: str) -> jax.Array:
+    """x (R, K) rows against ``planes[meta[0], row_expert[r]]`` -> (R, N)
+    f32, zero for a row without an expert; R <= FEW_ROWS."""
+    R = x.shape[0]
+    pad = -(R * f) % 16                    # bf16 packs 16 rows a tile
+    xf = _fold_rows(x, f, R)               # copy j of row r at j*R + r
+    xf = jnp.pad(xf, ((0, pad), (0, 0)))
+    re = jnp.pad(jnp.tile(row_expert, f), (0, pad),
+                 constant_values=jnp.iinfo(jnp.int32).max)
+    out = _grouped_call(fam, meta, _activations(xf, fam), planes,
+                        xf.shape[0], True, (re[:, None],), interpret,
+                        variant)
+    return _unfold_rows(out[:R * f], f, R)
+
+
+def grouped_matmul_many(fam: _Family, meta, xp, planes, f: int,
+                        interpret: bool, variant: str) -> jax.Array:
+    """xp (T*TM_MANY, K), rows in the padded layout of :func:`plan_groups`,
+    against ``planes[meta[0], tile's expert]`` -> (T*TM_MANY, N) f32."""
+    out = _grouped_call(fam, meta, _activations(
+        _fold_rows(xp, f, TM_MANY), fam), planes, TM_MANY * f, False, (),
+        interpret, variant)
+    return _unfold_rows(out, f, TM_MANY)
+
+
+# ---------------------------------------------------------------------------
+# the layer after the router
+# ---------------------------------------------------------------------------
+
+def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
+                picks, weights, *planes):
+    """x (M, D), picks (M, k) int32 in [0, E] (E: none), weights (M, k) f32
+    -> (y (M, D) in x.dtype, rows per expert (E,) int32)."""
+    gate, up, down = (FAMILIES[f] for f in fams)
+    n_g, n_u = len(gate.planes), len(up.planes)
+    pg, pu, pd = planes[:n_g], planes[n_g:n_g + n_u], planes[n_g + n_u:]
+    M, D = x.shape
+    k = picks.shape[1]
+    R = M * k
+    E = pg[0].shape[1]
+    layer = jnp.asarray(idx, jnp.int32).reshape(1)
+    row_expert = picks.reshape(R)
+    few = R <= FEW_ROWS
+    if few:
+        count, experts, n_used = experts_in_use(row_expert, E, min(E, R))
+        xr = jnp.repeat(x, k, axis=0)                  # row (m, j) = x[m]
+
+        def call(fam, variant, rows, w):
+            return grouped_matmul_few(fam, meta, rows, row_expert, w,
+                                      fold_factor(rows.shape[1]), interpret,
+                                      variant)
+    else:
+        plan = plan_groups(row_expert, E, M, TM_MANY)
+        count, experts, n_used = (plan["count"], plan["tile_expert"],
+                                  plan["n_used"])
+        # each padded slot's token (row // k), or the zero row M
+        token = jnp.where(plan["src"] < R, plan["src"] // k, M)
+        xr = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[token]
+
+        def call(fam, variant, rows, w):
+            return grouped_matmul_many(fam, meta, rows, w,
+                                       fold_factor(rows.shape[1]), interpret,
+                                       variant)
+    meta = jnp.concatenate([layer, n_used[None], experts])
+    g = call(gate, variants[0], xr, pg)
+    u = call(up, variants[1], xr, pu)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    out = call(down, variants[2], h, pd)
+    if not few:                 # back from the padded layout to the rows
+        P = out.shape[0]
+        rows = out[jnp.minimum(plan["pos"], P - 1)]
+        out = jnp.where((plan["pos"] < P)[:, None], rows, 0.0)
+    y = jnp.sum(out.reshape(M, k, D) * weights[:, :, None], axis=1)
+    return y.astype(x.dtype), count
+
+
+@functools.lru_cache(maxsize=8)
+def _routed_fn(fams: tuple | None, interpret: bool = False,
+               variants: tuple = ()):
+    """The jitted layer with its vmap rule: lanes become rows, of the
+    grouped kernels (``fams``: the three matrices' families) or of the
+    dequantized fallback (None)."""
+    from jax.custom_batching import custom_vmap
+
+    raw = functools.partial(_routed_raw, fams, interpret, variants) \
+        if fams else _routed_dense
+
+    @custom_vmap
+    def fn(idx, x, picks, weights, *planes):
+        return raw(idx, x, picks, weights, *planes)
+
+    @fn.def_vmap
+    def _rule(axis_size, in_batched, idx, x, picks, weights, *planes):
+        if in_batched[0] or any(in_batched[4:]):
+            raise NotImplementedError(
+                "routed experts vmap: only the activations, picks and their "
+                "weights may carry the batch axis (weights are shared)")
+        x, picks, weights = (
+            a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+            for a, b in zip((x, picks, weights), in_batched[1:4]))
+        nb, M = x.shape[:2]
+        y, count = fn(idx, x.reshape(nb * M, -1), picks.reshape(nb * M, -1),
+                      weights.reshape(nb * M, -1), *planes)
+        return (y.reshape(nb, M, -1), count), (True, False)
+
+    return jax.jit(fn)
+
+
+def routed_experts(x: jax.Array, picks: jax.Array, weights: jax.Array,
+                   w_gate: dict, w_up: dict, w_down: dict, idx,
+                   interpret: bool | None = None):
+    """The routed feed-forward of layer ``idx`` after its router: ``sum_j
+    weights[m, j] * down_e(silu(gate_e(x[m])) * up_e(x[m]))`` with ``e =
+    picks[m, j]`` (``E``: no expert, contributes nothing), over expert
+    planes stacked (L, E, ...).  Returns (y (M, D), rows each expert took
+    (E,) int32: an expert is read iff its count is not 0)."""
+    fams = tuple(family_of(w) for w in (w_gate, w_up, w_down))
+    if None in fams:
+        fn, planes = _routed_fn(None), [w["w"] for w in (w_gate, w_up, w_down)]
+    else:
+        fn = _routed_fn(fams, _interpret(interpret),
+                        tuple(FAMILIES[f].variant() for f in fams))
+        planes = [w[key] for w, f in zip((w_gate, w_up, w_down), fams)
+                  for key in FAMILIES[f].planes]
+    return fn(jnp.asarray(idx, jnp.int32), x, picks, weights, *planes)
+
+
+def _routed_dense(idx, x, picks, weights, w_gate, w_up, w_down):
+    """The same layer over dequantized experts (L, E, N, K): every expert's
+    product, the picked ones kept.  For files whose expert tensors have no
+    fused kernel (and the CPU tests' bf16 loads); E times the work, so
+    nothing to serve a large model with."""
+    E = w_gate.shape[1]
+    wg, wu, wd = (jax.lax.dynamic_index_in_dim(w, idx, 0, keepdims=False)
+                  for w in (w_gate, w_up, w_down))
+    f32 = jnp.float32
+    g = jnp.einsum("md,efd->mef", x, wg, preferred_element_type=f32)
+    u = jnp.einsum("md,efd->mef", x, wu, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    out = jnp.einsum("mef,edf->med", h, wd, preferred_element_type=f32)
+    onehot = jax.nn.one_hot(picks, E, dtype=f32)          # pick E: all zero
+    share = jnp.einsum("mk,mke->me", weights.astype(f32), onehot)
+    y = jnp.einsum("me,med->md", share, out)
+    count = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)
+    return y.astype(x.dtype), count
+
+
+# devtime inventory (lfkt-lint PERF001): trace-inner, as the dense builders
+register_program("_grouped_call", site="ops.pallas.experts")
+register_program("_routed_fn", site="ops.pallas.experts")

@@ -20,8 +20,8 @@ import functools
 import numpy as np
 
 __all__ = ["probe_fused_q4k", "probe_fused_q5k", "probe_fused_q6k",
-           "probe_fused_q8", "probe_flash_attention", "probe_kv_quant",
-           "probe_decode_loop"]
+           "probe_fused_q8", "probe_fused_experts", "probe_flash_attention",
+           "probe_kv_quant", "probe_decode_loop"]
 
 
 def _err(e: BaseException) -> str:
@@ -128,6 +128,43 @@ def probe_fused_q8() -> str | None:
         float(q8_matmul(x, w).sum())
         ws = {k: jnp.stack([v, v]) for k, v in w.items()}
         float(q8_matmul_stacked(x, ws, 1).sum())
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)
+
+
+@functools.lru_cache(maxsize=1)
+def probe_fused_experts() -> str | None:
+    """Compile + run the grouped expert matmuls (ops/pallas/experts.py) at
+    the serving tile geometry, in both row regimes: Q4_K gate/up (1024,
+    2048), Q6_K down (2048, 1024: folded), two experts.  Called only for a
+    file that has experts, so a dense pod's start pays nothing for it."""
+    try:
+        import jax.numpy as jnp
+
+        from ...gguf.constants import GGMLType
+        from ...gguf.quants import quant_q4_k, quant_q6_k
+        from . import use_interpret
+        from .experts import prep_experts, routed_experts
+
+        rng = np.random.default_rng(0)
+        e = 2
+        d, f = (256, 256) if use_interpret() else (2048, 1024)
+
+        def planes(quant, gtype, n_out, k_in):
+            raw = quant(rng.standard_normal(e * n_out * k_in).astype(
+                np.float32) * 0.02)
+            w = prep_experts(np.asarray(raw), e, n_out, k_in, gtype)
+            return {k: v[None] for k, v in w.items()}      # one layer
+
+        gate = planes(quant_q4_k, GGMLType.Q4_K, f, d)
+        down = planes(quant_q6_k, GGMLType.Q6_K, d, f)
+        for rows in (1, 80):        # few-row and many-row tiles
+            x = jnp.ones((rows, d), jnp.bfloat16)
+            picks = jnp.tile(jnp.arange(e, dtype=jnp.int32), (rows, 1))
+            wts = jnp.full((rows, e), 0.5, jnp.float32)
+            y, _ = routed_experts(x, picks, wts, gate, gate, down, 0)
+            float(y.astype(jnp.float32).sum())
         return None
     except Exception as e:  # noqa: BLE001
         return _err(e)

@@ -241,7 +241,8 @@ def dequant_ref6(w: dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
-                       variant="cur"):
+                       variant="cur", accum=_q4k_accum):
+    # ``accum``: as in qmatmul._q4k_matmul_kernel
     TN = q4_ref.shape[0]
     v4 = q4_ref[...].astype(jnp.float32)              # (TN, TK/2)
     h = jnp.floor(v4 * 0.0625)
@@ -252,7 +253,8 @@ def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     corr = jnp.concatenate([sm * -32.0, sm * 8.0], axis=1).astype(jnp.bfloat16)
 
     if variant == "vbf32":
-        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret)
+        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret,
+                        accum)
         return
 
     l = v4 - h * 16.0
@@ -288,10 +290,11 @@ def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     part += jax.lax.dot_general(
         xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    _q4k_accum(o_ref, part)
+    accum(o_ref, part)
 
 
-def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
+def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret,
+                    accum=_q4k_accum):
     """Activation-side recombination with f32 planes (Q6_K analogue of the
     Q4_K ``vbf32`` variant, ops/pallas/qmatmul.py).
 
@@ -341,7 +344,7 @@ def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
     part += dot(x2 - 4.0 * x1, f2 * eff_q)
     part += dot(x3 - 4.0 * x2, c3 * eff_q)
     part += dot(xpa[:, TK:], corr)
-    _q4k_accum(o_ref, part)
+    accum(o_ref, part)
 
 
 def _q6k_pre_kernel(xpa_ref, q6p_ref, sm_ref, o_ref, *, interpret):

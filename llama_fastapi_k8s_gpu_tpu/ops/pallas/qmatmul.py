@@ -235,9 +235,12 @@ def _lane_repeat(v, times: int, interpret: bool):
 
 
 def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
-                       variant="cur"):
+                       variant="cur", accum=None):
     # xpa (B, TKA) bf16 permuted+augmented; qs (TN, TK/2) int8;
-    # sm (1, TN, 128) bf16
+    # sm (1, TN, 128) bf16.  ``accum(o_ref, part)`` folds a k-tile's partial
+    # product into the output block: :func:`_q4k_accum` on the (n, k) grids
+    # here, the grouped expert grid's own (ops/pallas/experts.py)
+    accum = accum or _q4k_accum
     TN = qs_ref.shape[0]
     v = qs_ref[...].astype(jnp.float32)
     sm = sm_ref[...].reshape(TN, 128)
@@ -274,7 +277,7 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
         part += jax.lax.dot_general(
             xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        _q4k_accum(o_ref, part)
+        accum(o_ref, part)
         return
 
     if variant == "resplit":
@@ -302,7 +305,7 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
         part += jax.lax.dot_general(
             xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        _q4k_accum(o_ref, part)
+        accum(o_ref, part)
         return
 
     part = jax.lax.dot_general(
@@ -314,7 +317,7 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
     part += jax.lax.dot_general(
         xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    _q4k_accum(o_ref, part)
+    accum(o_ref, part)
 
 
 def _q4k_accum(o_ref, part):

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
+from ..models.generate import chunk_out
 from ..models.llama import forward, init_cache, prefill
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
@@ -80,21 +81,25 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
 
     def one_step(carry, _):
         def single(token, pos, cache, window, wpos, key):
-            logits, cache = forward(params, cfg, token[None], pos, cache)
+            logits, cache, *stats = forward(
+                params, cfg, token[None], pos, cache,
+                with_stats=bool(cfg.n_experts))
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)
-            return tok, pos + 1, cache, window, wpos + 1, key
+            return (tok, pos + 1, cache, window, wpos + 1, key, *stats)
 
-        tok, pos, cache, window, wpos, key = jax.vmap(single)(
+        tok, pos, cache, window, wpos, key, *stats = jax.vmap(single)(
             carry["token"], carry["pos"], carry["cache"],
             carry["window"], carry["wpos"], carry["key"],
         )
         new_carry = {"cache": cache, "pos": pos, "token": tok,
                      "window": window, "wpos": wpos, "key": key}
-        return new_carry, tok
+        # the counters are of the step, the same in every lane
+        return new_carry, (tok, *(s[0] for s in stats))
 
-    return jax.lax.scan(one_step, state, None, length=n_steps)
+    state, ys = jax.lax.scan(one_step, state, None, length=n_steps)
+    return state, chunk_out(*ys)
 
 
 batched_generate_chunk_jit = timed_jit(
@@ -109,30 +114,35 @@ batched_generate_chunk_jit = timed_jit(
 )
 def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
                                        lane_st: dict, n_steps: int,
-                                       top_k: int = 40):
+                                       top_k: int = 40, live=None):
     """Like :func:`batched_generate_chunk_jit` but with **per-lane** sampling
     knobs (``lane_st`` leaves have a leading B dim) — the continuous
     scheduler admits requests with different temperatures/penalties into
     neighboring lanes.  (top_k stays a shared static: ``lax.top_k`` needs a
-    static k; see ContinuousEngine.submit.)"""
+    static k; see ContinuousEngine.submit.)  ``live`` (B,) bool, given for
+    a routed block only: the lanes that hold a request; the others' rows
+    reach no expert, so a step reads what its live lanes picked."""
 
     def one_step(carry, _):
-        def single(token, pos, cache, window, wpos, key, st):
-            logits, cache = forward(params, cfg, token[None], pos, cache)
+        def single(token, pos, cache, window, wpos, key, st, live):
+            logits, cache, *stats = forward(
+                params, cfg, token[None], pos, cache, live=live,
+                with_stats=bool(cfg.n_experts))
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)
-            return tok, pos + 1, cache, window, wpos + 1, key
+            return (tok, pos + 1, cache, window, wpos + 1, key, *stats)
 
-        tok, pos, cache, window, wpos, key = jax.vmap(single)(
+        tok, pos, cache, window, wpos, key, *stats = jax.vmap(single)(
             carry["token"], carry["pos"], carry["cache"],
-            carry["window"], carry["wpos"], carry["key"], lane_st,
+            carry["window"], carry["wpos"], carry["key"], lane_st, live,
         )
         new_carry = {"cache": cache, "pos": pos, "token": tok,
                      "window": window, "wpos": wpos, "key": key}
-        return new_carry, tok
+        return new_carry, (tok, *(s[0] for s in stats))
 
-    return jax.lax.scan(one_step, state, None, length=n_steps)
+    state, ys = jax.lax.scan(one_step, state, None, length=n_steps)
+    return state, chunk_out(*ys)
 
 
 batched_generate_chunk_perlane_jit = timed_jit(

@@ -100,6 +100,11 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
     for name, leaf in layers.items():
         if name in ("attn_norm", "ffn_norm"):
             layer_shard[name] = _ns(mesh, None, None)
+        elif not isinstance(leaf, dict) or name.endswith("_exps"):
+            # the routed block's router and QK-norm vectors, and its expert
+            # planes: replicated (the grouped expert matmul has no
+            # partitioning rule, so experts do not span a tp mesh)
+            layer_shard[name] = jax.tree.map(lambda _: _ns(mesh), leaf)
         elif name in ("wq", "wk", "wv", "w_gate", "w_up"):
             layer_shard[name] = _match_linear(col, leaf)
         else:  # wo, w_down

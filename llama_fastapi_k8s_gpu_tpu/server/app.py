@@ -1576,6 +1576,16 @@ def create_app(engine=None, settings: Settings | None = None,
                             snap["adm_budget_tokens"])
             if "lane_idle_seconds" in snap:
                 m.set_gauge("lane_idle_seconds", snap["lane_idle_seconds"])
+        # routed layers (a file with experts): cumulative counters of the
+        # decode chunks that have finished, folded here and not on the
+        # decode path (engine/expert_counters.py)
+        ec = getattr(app.state.engine, "expert_counters", None)
+        if ec is not None:
+            snap = ec.snapshot()
+            m.set_gauge("expert_layer_steps_total", snap["layer_steps"])
+            m.set_gauge("experts_read_total", snap["experts_read"])
+            for e, n in enumerate(snap["picks"]):
+                m.set_gauge("expert_picks_total", n, expert=str(e))
         # lfkt-mem: live HBM attribution gauges (obs/memledger.py) — one
         # series per (component, model), residual = ground truth minus the
         # attributed sum, headroom only where the backend reports limits.

@@ -48,22 +48,26 @@ def write_llama_gguf_meta(
     name: str = "tiny-llama-test",
     n_ctx: int | None = None,
     chat_template: str | None = LLAMA3_CHAT_TEMPLATE,
+    arch: str = "llama",
 ) -> None:
-    """The llama-architecture GGUF metadata block (hparams + BPE tokenizer)
-    shared by the tiny test fixture and the full-size cold-start bench."""
-    w.add_metadata("general.architecture", "llama")
+    """The GGUF metadata block (hparams under ``<arch>.*`` + BPE tokenizer)
+    shared by the tiny test fixtures and the full-size cold-start bench."""
+    w.add_metadata("general.architecture", arch)
     w.add_metadata("general.name", name)
-    w.add_metadata("llama.block_count", cfg.n_layers)
-    w.add_metadata("llama.context_length", n_ctx or cfg.n_ctx)
-    w.add_metadata("llama.embedding_length", cfg.dim)
-    w.add_metadata("llama.feed_forward_length", cfg.ffn_dim)
-    w.add_metadata("llama.attention.head_count", cfg.n_heads)
-    w.add_metadata("llama.attention.head_count_kv", cfg.n_kv_heads)
-    w.add_metadata("llama.attention.layer_norm_rms_epsilon", cfg.rms_eps)
-    w.add_metadata("llama.rope.freq_base", cfg.rope_theta)
-    w.add_metadata("llama.vocab_size", cfg.vocab_size)
+    w.add_metadata(f"{arch}.block_count", cfg.n_layers)
+    w.add_metadata(f"{arch}.context_length", n_ctx or cfg.n_ctx)
+    w.add_metadata(f"{arch}.embedding_length", cfg.dim)
+    w.add_metadata(f"{arch}.feed_forward_length", cfg.ffn_dim)
+    w.add_metadata(f"{arch}.attention.head_count", cfg.n_heads)
+    w.add_metadata(f"{arch}.attention.head_count_kv", cfg.n_kv_heads)
+    w.add_metadata(f"{arch}.attention.layer_norm_rms_epsilon", cfg.rms_eps)
+    w.add_metadata(f"{arch}.rope.freq_base", cfg.rope_theta)
+    w.add_metadata(f"{arch}.vocab_size", cfg.vocab_size)
     if cfg.sliding_window:
-        w.add_metadata("llama.attention.sliding_window", cfg.sliding_window)
+        w.add_metadata(f"{arch}.attention.sliding_window", cfg.sliding_window)
+    if cfg.n_experts:
+        w.add_metadata(f"{arch}.expert_count", cfg.n_experts)
+        w.add_metadata(f"{arch}.expert_used_count", cfg.n_experts_used)
     w.add_metadata("tokenizer.ggml.model", "gpt2")
     w.add_metadata("tokenizer.ggml.pre", "llama-bpe")
     w.add_metadata("tokenizer.ggml.tokens", tokens)
@@ -116,6 +120,70 @@ def write_tiny_llama_gguf(
         t(p + "ffn_down.weight", (cfg.dim, cfg.ffn_dim), ffn_quant)
     t("output_norm.weight", (cfg.dim,), GGMLType.F32)
     t("output.weight", (cfg.vocab_size, cfg.dim), GGMLType.F16)
+    w.write()
+    return cfg
+
+
+TINY_OLMOE_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=2, n_heads=4, n_kv_heads=4,
+    ffn_dim=256, n_ctx=128, rope_theta=10000.0,
+    n_experts=8, n_experts_used=2, qk_norm=True, rope_neox=True,
+)
+
+#: llama.cpp's Q4_K_M mix on an ``olmoe`` file, as the benchmark writes it
+OLMOE_Q4KM_MIX = {
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "ffn_gate_exps": GGMLType.Q4_K, "ffn_up_exps": GGMLType.Q4_K,
+    "ffn_down_exps": GGMLType.Q6_K, "output": GGMLType.Q6_K,
+}
+
+
+def write_tiny_olmoe_gguf(path: str, cfg: ModelConfig = TINY_OLMOE_CFG,
+                          seed: int = 0, mix: dict | None = None,
+                          router_scale: float = 4.0) -> ModelConfig:
+    """Write a random-weight ``olmoe`` GGUF (the routed block: QK-norm, an
+    F32 router, 3-D expert tensors) with the byte-level tokenizer of
+    :func:`write_tiny_llama_gguf`.  ``mix`` maps tensor names to ggml types
+    (default :data:`OLMOE_Q4KM_MIX`; embeddings F16, router and norms F32).
+    The router's weights are ``router_scale`` times the others', so that
+    picks rarely sit on a near-tie that rounding could flip."""
+    tokens, types = byte_vocab_with_specials()
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens)})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**OLMOE_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-olmoe-test",
+                          arch="olmoe")
+    kv_dim = cfg.n_kv_heads * cfg.head_dim
+    E, F, D = cfg.n_experts, cfg.ffn_dim, cfg.dim
+
+    def t(name, shape, gtype, mul=1.0):
+        w.add_tensor(name, rng.standard_normal(shape).astype(np.float32)
+                     * scale * mul, gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight", D)
+        t(p + "attn_q.weight", (D, D), mix["attn_q"])
+        t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"])
+        t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+        t(p + "attn_output.weight", (D, D), mix["attn_output"])
+        norm(p + "attn_q_norm.weight", D)
+        norm(p + "attn_k_norm.weight", kv_dim)
+        norm(p + "ffn_norm.weight", D)
+        t(p + "ffn_gate_inp.weight", (E, D), GGMLType.F32, router_scale)
+        t(p + "ffn_gate_exps.weight", (E, F, D), mix["ffn_gate_exps"])
+        t(p + "ffn_up_exps.weight", (E, F, D), mix["ffn_up_exps"])
+        t(p + "ffn_down_exps.weight", (E, D, F), mix["ffn_down_exps"])
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size, D), mix["output"])
     w.write()
     return cfg
 

@@ -125,6 +125,38 @@ def test_fused_matmul_compiles(one_chip, fmt, k, n):
         assert "tpu_custom_call" in txt
 
 
+# the routed layer of OLMoE-1B-7B at its published widths (64 experts of
+# width 1024 on a hidden size of 2048, 8 per token, 16 layers): Q4_K gate
+# and up, Q6_K down (K = 1024: folded) — (tokens, regime of the row tiles)
+@pytest.mark.parametrize("tokens,regime", [
+    (1, "fewrow"),       # one stream's decode step: 8 rows
+    (8, "fewrow"),       # the 8-lane decode step: 64 rows, one tile an expert
+    (512, "manyrow"),    # a prefill slice: 4096 rows
+])
+def test_routed_experts_compile_at_olmoe_widths(one_chip, tokens, regime):
+    """Router picks in, the layer's output and the experts' row counts out;
+    both grouped kernels are in the program under their own names, which
+    ``benchmarks/kernels/expert_matmul.json`` reads a profile by."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
+        experts_compatible, routed_experts)
+
+    L, E, D, F, k = 16, 64, 2048, 1024, 8
+    assert experts_compatible(F, D, for_tpu=True)
+    assert experts_compatible(D, F, for_tpu=True)
+    gate = {"qs": S(L, E, F, D // 2, dtype=i8), "sm": S(L, E, 1, F, 128)}
+    down = {"q4": S(L, E, D // 2, F, dtype=i8),        # 2 rows read as one
+            "q2": S(L, E, D // 2, F // 2, dtype=i8),
+            "sm6": S(L, E, 1, D // 2, 128)}
+    txt = _compile(
+        one_chip,
+        lambda x, p, w, g, u, d, i: routed_experts(x, p, w, g, u, d, i,
+                                                   interpret=False),
+        S(tokens, D), S(tokens, k, dtype=i32), S(tokens, k, dtype=f32),
+        gate, dict(gate), down, S(dtype=i32))
+    assert f"q4k_expert_matmul_{regime}" in txt
+    assert f"q6k_expert_matmul_{regime}" in txt
+
+
 @pytest.mark.parametrize("seq,quantized", [
     (128, False), (256, False), (512, False), (1024, False),
     (128, True), (1024, True),
