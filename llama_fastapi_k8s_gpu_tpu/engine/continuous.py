@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import prefill_chunk_jit, sample_jit
-from ..models.llama import init_cache
+from ..models.llama import decode_chunk_slots, init_cache
 from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
@@ -283,8 +283,9 @@ class ContinuousEngine(MeshEngine):
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
+    # (ring_slots: the scheduler thread alone adds, /metrics reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread")
+                      "_thread", "ring_slots")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
@@ -1309,6 +1310,8 @@ class ContinuousEngine(MeshEngine):
         traced lane's ``decode_chunk`` span: a long chunk names its cause."""
         stop_ids = self.tokenizer.stop_ids
         now = time.time()
+        if counts is None:
+            self._note_ring_read(pre, len(chunk))
         for lane in range(len(pre)):
             slot = pre[lane]
             if slot is None or slot.finished:
@@ -1374,6 +1377,25 @@ class ContinuousEngine(MeshEngine):
                     self._finish_slot(slot, "stop")
                     self._free_lane(lane, slot, slots)
         self._totals["harvest_seconds"] += time.time() - now
+
+    def _note_ring_read(self, pre: list, n_steps: int) -> None:
+        """Count one decode chunk's attention read against what it needed
+        (models/llama.py ``decode_attention``): per step, summed over the
+        lanes whose rows are still wanted, the ring slots the read covered
+        (every lane reads up to the largest position among the lanes the
+        chunk was dispatched as live, ``pre``) and the slots at or below
+        the lane's own position.  From the positions the host holds before
+        the chunk's tokens are folded in (prompt + generated so far: the
+        slot of the chunk's first step); nothing is fetched."""
+        at = [None if s is None else s.n_prompt + max(len(s.gens) - 1, 0)
+              for s in pre]
+        bound = max((p for p in at if p is not None), default=0)
+        for slot, p in zip(pre, at):
+            if slot is None or slot.finished:
+                continue
+            read, live = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound)
+            self.ring_slots["read"] += read
+            self.ring_slots["live"] += live
 
     def _spec_drafts(self, slots: list) -> "tuple | None":
         """(drafts (B, D) int32, hit_lanes) — zero rows for lanes with no
@@ -1489,10 +1511,12 @@ class ContinuousEngine(MeshEngine):
                         wave = tot["chunks_dispatched"]
                         with phase("dispatch_chunk", wave=wave,
                                    lanes_live=sum(s is not None for s in pre)):
-                            # a routed block is told which lanes hold a
-                            # request: the others' rows reach no expert
-                            live = np.array([s is not None for s in pre]) \
-                                if self.cfg.n_experts else None
+                            # the step is told which lanes hold a
+                            # request: its attention reads the ring up to
+                            # the largest LIVE position (a freed lane's
+                            # walks on), and a routed block's other rows
+                            # reach no expert
+                            live = np.array([s is not None for s in pre])
                             self._bstate, out = \
                                 batched_generate_chunk_perlane_jit(
                                     self.params, self.cfg, self._bstate,

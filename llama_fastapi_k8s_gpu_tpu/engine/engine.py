@@ -40,7 +40,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models.llama import init_cache
+from ..models.llama import decode_chunk_slots, init_cache
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
@@ -251,6 +251,12 @@ class Engine:
                 "(see _spec_enabled)", type(self).__name__)
         self._lock = threading.Lock()
         self._expert_counters: ExpertCounters | None = None
+        # decode attention's read against what it needed (models/llama.py
+        # decode_attention): ring slots covered / at or below the position,
+        # summed over this engine's decode steps (a lane engine: and over
+        # its live lanes, ContinuousEngine._note_ring_read); /metrics
+        # ring_slots_*_total
+        self.ring_slots = {"read": 0, "live": 0}
         self._base_seed = seed
         # request counter: shared by the serial path (caller thread) and the
         # continuous scheduler thread; _next_seed() is the only writer and
@@ -792,9 +798,16 @@ class Engine:
             off += n
         return logits, cache
 
-    def _decode_chunk_call(self, state, st, n_steps: int, top_k: int):
+    def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
+                           pos: int):
+        """Dispatch one decode chunk; ``pos``: the host-tracked position of
+        its first step (what the ``ring_slots`` counters are computed
+        from: nothing is fetched)."""
         state, out = generate_chunk_jit(self.params, self.cfg, state, st,
                                         n_steps=n_steps, top_k=top_k)
+        read, live = decode_chunk_slots(pos, n_steps, self.cfg.n_ctx)
+        self.ring_slots["read"] += read
+        self.ring_slots["live"] += live
         return state, self._take_expert_stats(out)
 
     def _take_expert_stats(self, chunk_out):
@@ -1448,7 +1461,7 @@ class Engine:
                 if n <= 0:
                     break
                 ctx["state"], t = self._decode_chunk_call(
-                    ctx["state"], ctx["st"], n, ctx["sp"].top_k)
+                    ctx["state"], ctx["st"], n, ctx["sp"].top_k, pos)
                 toks = np.asarray(t).tolist()[:budget - len(gen)]
                 pos += n
                 stats["fallback_steps"] += 1
@@ -1515,7 +1528,7 @@ class Engine:
         pending = None
         if n_cur > 0:
             ctx["state"], pending = self._decode_chunk_call(
-                ctx["state"], ctx["st"], n_cur, ctx["sp"].top_k)
+                ctx["state"], ctx["st"], n_cur, ctx["sp"].top_k, pos)
 
         done = pending is None
         # Emit the first sampled token's text NOW — chunk 1 is already
@@ -1544,7 +1557,7 @@ class Engine:
                 nxt = None
                 if n_nxt > 0:
                     ctx["state"], nxt = self._decode_chunk_call(
-                        ctx["state"], ctx["st"], n_nxt, ctx["sp"].top_k)
+                        ctx["state"], ctx["st"], n_nxt, ctx["sp"].top_k, pos)
 
                 for t in np.asarray(pending).tolist():   # host sync
                     if len(gen) >= budget:   # surplus of the last chunk

@@ -101,7 +101,9 @@ class SPEngine(Engine):
         return sp_prefill(self.params, self.cfg, tokens, length, cache,
                           self.mesh)
 
-    def _decode_chunk_call(self, state, st, n_steps: int, top_k: int):
+    def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
+                           pos: int):
+        # ring attention reads its whole shard: no ring_slots counters
         state, out = sp_generate_chunk(self.params, self.cfg, state, st,
                                        self.mesh, n_steps, top_k)
         return state, self._take_expert_stats(out)

@@ -7,9 +7,11 @@ preallocated, donated KV cache.  Design choices are TPU-first:
 
 - layers are *stacked* and iterated with ``lax.scan`` so XLA compiles one
   layer body regardless of depth (compile time ∝ 1, not n_layers);
-- K/V are written with ``dynamic_update_slice`` and attention masks the full
-  ``n_ctx`` ring, so prefill and decode share one code path with static
-  shapes (prompt lengths are bucketed by the engine to bound recompiles);
+- K/V are written with ``dynamic_update_slice`` into a ring of static
+  shape; a prefill masks the full ``n_ctx`` ring (prompt lengths are
+  bucketed by the engine to bound recompiles), a decode step reads it in
+  blocks up to the newest live slot, under a TRACED bound: every position
+  runs the one compiled decode program (:func:`decode_attention`);
 - sliding-window masking (Mistral) is the same mask with one extra term;
 - matmuls go through ``ops.linear`` so bf16 / int8 / (later) fused-Q4_K
   weights are interchangeable without touching the graph.
@@ -118,13 +120,10 @@ def cache_nbytes(cfg: ModelConfig) -> int:
 
 def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
                   out_dtype):
-    """The XLA score-matrix attention over a full head-major ring — the
-    decode path (S=1 always lands here) and the small-prompt prefill path.
-
-    Extracted from :func:`_layer` so the layer-looped decode kernel
-    (ops/pallas/decode_loop.py) runs the SAME code: bit-exactness of the
-    looped path is then a property of shared source, not of two
-    implementations agreeing.  ``cks``/``cvs`` are the int8 cache's
+    """The XLA score-matrix attention over a full head-major ring: the
+    small-prompt prefill path and speculation's verify (S > 1; a decode
+    step reads the live part only: :func:`decode_attention`).
+    ``cks``/``cvs`` are the int8 cache's
     per-head per-token scales (None for bf16): scores are linear in K and
     probs·V is linear in V, so both scale sets fold OUTSIDE the int8
     contractions and no dequantized ring is ever materialized."""
@@ -152,7 +151,8 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
 
     key_pos = jnp.arange(cfg.n_ctx)
     q_pos = positions  # (S,)
-    mask = key_pos[None, :] <= q_pos[:, None]  # causal over the whole ring
+    # causal over the whole ring (S > 1 only: a decode step is decode_attention)
+    mask = key_pos[None, :] <= q_pos[:, None]
     if cfg.sliding_window:
         mask &= key_pos[None, :] > q_pos[:, None] - cfg.sliding_window
     scores = jnp.where(mask[None, None, :, :], scores, -jnp.inf)
@@ -168,6 +168,136 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
         with jax.named_scope("attn_pv"):
             ctx = jnp.einsum("ngsc,nch->ngsh", probs, vv)  # (n_kv, group, S, hd)
     return ctx.transpose(2, 0, 1, 3).reshape(S, cfg.n_heads * hd).astype(out_dtype)
+
+
+#: ring slots :func:`decode_attention` reads at a time.  Measured on the
+#: chip (PERF.md section 6, PR 31; 8 lanes x 32 layers, the read alone): at chat
+#: lengths 128, 256 and 512 take the same time (an iteration costs 3-4 us
+#: beside its bytes, which is what finer blocks would save in slots read
+#: past the bound), 1024 reads too far past it (+14 %); with the ring full
+#: 512 costs 12 % over one pass over the whole ring, 256 26 %, 128 41 %.
+DECODE_KV_BLOCK = 512
+
+
+def decode_read_slots(bound, n_ctx: int):
+    """(blocks, ring slots) :func:`decode_attention` covers when the newest
+    live position is ``bound``.  Integer arithmetic on a host int (the
+    engines' ``ring_slots_*`` counters) or on a traced scalar (the loop's
+    trip count) alike."""
+    block = min(DECODE_KV_BLOCK, n_ctx)
+    least = min if isinstance(bound, int) else jnp.minimum
+    # ceil((bound + 1) / block), and no more blocks than the ring holds
+    n_blocks = least((bound + block) // block, -(-n_ctx // block))
+    return n_blocks, least(n_blocks * block, n_ctx)
+
+
+def decode_chunk_slots(pos: int, n_steps: int, n_ctx: int,
+                       bound: int | None = None) -> tuple[int, int]:
+    """(ring slots read, ring slots live) of ONE sequence over ``n_steps``
+    decode steps from position ``pos``: a step at position p has p + 1
+    live slots (at or below it) and reads ``decode_read_slots`` of the
+    step's bound, which starts at ``bound`` (the largest live lane's
+    position; default the sequence's own) and walks with the steps.  Host
+    arithmetic for the engines' ``ring_slots_*`` counters: no device
+    fetch."""
+    bound = pos if bound is None else bound
+    read = live = 0
+    for t in range(n_steps):
+        read += decode_read_slots(bound + t, n_ctx)[1]
+        live += min(pos + t + 1, n_ctx)
+    return read, live
+
+
+def decode_attention(q, cache, i, pos, bound, cfg: ModelConfig, out_dtype):
+    """A decode step's attention (S = 1) over the LIVE part of layer
+    ``i``'s ring: K/V are read in blocks up to slot ``bound`` and no
+    further, with a running max and sum (the flash recurrence in plain
+    XLA), instead of all ``n_ctx`` slots behind a mask.  Slots past the
+    position have probability exactly 0 either way, so this is
+    :func:`xla_attention`'s mathematics at its precision (bf16 K/V, f32
+    scores, max and sum, bf16 probabilities into a f32-accumulated PV):
+    what differs is that a probability is rounded to bf16 BEFORE the
+    division by the sum and not after, and the order of the f32 sums.
+
+    ``q`` (1, n_heads, hd).  ``cache``: the STACKED leaves (L, n_kv, n_ctx,
+    hd) (+ int8 scales), sliced in place at (i, head, block): handing
+    ``at_layer(cache)`` into a loop can materialise the layer's ring.
+    ``pos``: this sequence's position, the causal bound of its mask.
+    ``bound``: the position the READ goes up to, >= ``pos`` of every
+    sequence whose output is used.  Under ``vmap`` over lanes ``pos`` is
+    per lane and ``bound`` must be unbatched (the largest live lane's
+    position, parallel/batched.py): the trip count then stays a scalar and
+    the loop a real loop.  A lane beyond ``bound`` (one that holds no
+    request) reads too little; its output is never used.
+
+    A LANE'S RESULT DOES NOT DEPEND ON ``bound``, so not on what the other
+    lanes hold: a block that lies wholly beyond ``pos`` has every score at
+    -inf, leaves the running max as it was, and adds probabilities of
+    exactly 0.0 under a rescale of exactly exp(0) = 1.0, so max, sum and
+    accumulator come out bit for bit as they went in; the block that holds
+    ``pos`` is masked by ``pos`` alone.  The same lane with the same ring
+    gives bitwise the same output under any bound >= its position
+    (tests/test_decode_lanes.py), which is what lets a greedy probe repeat
+    its text whatever its neighbours served in between.
+
+    The read covers ``decode_read_slots(bound, n_ctx)`` slots; a last
+    block that would overhang the ring is read shifted back, the overlap
+    masked.  The sliding window is the same mask as in
+    :func:`xla_attention`; int8 rings fold their scales as it does."""
+    n_kv, group, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
+    n_ctx = cfg.n_ctx
+    T = min(DECODE_KV_BLOCK, n_ctx)
+    quant = cfg.kv_dtype == "int8"
+    kname, vname = ("k_q", "v_q") if quant else ("k", "v")
+    qg = q.reshape(n_kv, group, 1, hd)
+    i = jnp.asarray(i, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    n_blocks, _ = decode_read_slots(jnp.asarray(bound, jnp.int32), n_ctx)
+
+    def block(j, carry):
+        m, l, acc = carry
+        lo = j * T
+        at = jnp.minimum(lo, n_ctx - T)
+        kb = jax.lax.dynamic_slice(
+            cache[kname], (i, 0, at, 0), (1, n_kv, T, hd))[0]
+        vb = jax.lax.dynamic_slice(
+            cache[vname], (i, 0, at, 0), (1, n_kv, T, hd))[0]
+        with jax.named_scope("attn_scores"):
+            s = jnp.einsum("ngsh,nch->ngsc", qg, kb.astype(qg.dtype),
+                           preferred_element_type=jnp.float32
+                           ) * (hd ** -0.5)           # (n_kv, group, 1, T)
+        if quant:
+            ksb = jax.lax.dynamic_slice(
+                cache["k_s"], (i, 0, at), (1, n_kv, T))[0]
+            vsb = jax.lax.dynamic_slice(
+                cache["v_s"], (i, 0, at), (1, n_kv, T))[0]
+            s = s * ksb[:, None, None, :]
+        key_pos = at + jnp.arange(T)
+        mask = (key_pos >= lo) & (key_pos <= pos)
+        if cfg.sliding_window:
+            mask &= key_pos > pos - cfg.sliding_window
+        s = jnp.where(mask[None, None, None, :], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        if quant:
+            p = p * vsb[:, None, None, :]
+        with jax.named_scope("attn_pv"):
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "ngsc,nch->ngsh", p.astype(qg.dtype), vb.astype(qg.dtype),
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    # a finite floor, not -inf: a block that holds no slot of this sequence
+    # (all masked) must leave exp(m - m_new) = 1, not exp(-inf + inf)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((n_kv, group, 1), -1e30, jnp.float32),
+        jnp.zeros((n_kv, group, 1), jnp.float32),
+        jnp.zeros((n_kv, group, 1, hd), jnp.float32)))
+    ctx = acc / jnp.where(l > 0, l, 1.0)[..., None]   # (n_kv, group, 1, hd)
+    return ctx.transpose(2, 0, 1, 3).reshape(
+        1, cfg.n_heads * hd).astype(out_dtype)
 
 
 def route(hn, w_router, cfg: ModelConfig):
@@ -192,7 +322,7 @@ def expert_stats_len(cfg: ModelConfig) -> int:
 
 
 def _layer(h, layers, i, cache, positions, pos_offset,
-           cfg: ModelConfig, live=None):
+           cfg: ModelConfig, live=None, kv_bound=None):
     """One transformer block over S tokens against layer ``i`` of the
     stacked weights. ``cache``: the FULL stacked cache pytree, head-major
     (L, n_kv, n_ctx, hd) value leaves (+ (L, n_kv, n_ctx) scale leaves
@@ -200,7 +330,8 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     dense feed-forward, else (rows each expert took (E,) int32, the
     router's picks (S, k) int32).  ``live`` (scalar bool
     or None): False marks a lane that holds no request, whose rows then
-    reach no expert (its output is not read).
+    reach no expert (its output is not read).  ``kv_bound``: see
+    :func:`forward`.
 
     The weights stay STACKED (L, ...) and are addressed per layer with
     :func:`ops.linear.linear_at` — scanning them as xs would materialize a
@@ -294,6 +425,11 @@ def _layer(h, layers, i, cache, positions, pos_offset,
             v_scale=cvs,
             interpret=use_interpret(),
         ).reshape(S, cfg.n_heads * hd).astype(h.dtype)
+    elif S == 1:
+        # a decode step reads the live part of the ring, not n_ctx slots
+        ctx = decode_attention(
+            q, cache, i, pos_offset,
+            pos_offset if kv_bound is None else kv_bound, cfg, h.dtype)
     else:
         ctx = xla_attention(q, ck, cv, cks, cvs, positions, cfg, h.dtype)
     h = h + lin(ctx, "wo")
@@ -362,13 +498,18 @@ def forward(
     live: jax.Array | None = None,
     with_stats: bool = False,
     with_picks: bool = False,
+    kv_bound: jax.Array | None = None,
 ):
     """Run S tokens through the stack. Returns (logits, new_cache):
     logits (vocab,) at ``last_idx`` (default S-1), or (S, vocab) if
     ``return_all``.  ``live``: see :func:`_layer`.  Of a routed block,
     ``with_stats`` appends the counter vector of :func:`expert_stats_len`
     and ``with_picks`` the routers' picks (L, S, k) int32 (what the
-    comparison with the reference counts mismatches on)."""
+    comparison with the reference counts mismatches on).  ``kv_bound``
+    (scalar int32, a decode step only): the ring slot a decode step's
+    attention reads up to (:func:`decode_attention`), default this
+    sequence's own position; lanes ``vmap``ped over one step share the
+    largest live lane's, as an UNBATCHED value."""
     S = tokens.shape[0]
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
@@ -410,7 +551,7 @@ def forward(
         def body(i, carry):
             h, cache, out = _layer(
                 carry[0], params["layers"], jnp.int32(i), carry[1],
-                positions, pos_offset, cfg, live)
+                positions, pos_offset, cfg, live, kv_bound)
             if out is None:
                 return h, cache
             count, picks = out

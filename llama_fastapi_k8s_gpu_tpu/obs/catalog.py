@@ -318,6 +318,19 @@ METRICS: dict[str, Metric] = _register(
            "(token, pick) rows each expert took in decode chunks, "
            "cumulative; the largest over their sum is the most-loaded "
            "expert's share", labels=("expert",)),
+    # -- decode attention's read of the KV ring (models/llama.py) ----------
+    Metric("ring_slots_read_total", GAUGE,
+           "KV ring slots the decode steps' attention covered (whole blocks "
+           "up to the position; on a lane engine up to the largest LIVE "
+           "lane's position, one bound for all lanes; models/llama.py "
+           "decode_attention), summed over decode steps and over the lanes "
+           "that hold a request, cumulative; from host-tracked positions "
+           "(a lane engine adds at each chunk's harvest), nothing fetched"),
+    Metric("ring_slots_live_total", GAUGE,
+           "KV ring slots at or below the sequence's own position, summed "
+           "over the same steps and lanes; over ring_slots_read_total = the "
+           "share of the read that was needed (a whole-ring read of 4096 "
+           "at chat lengths: 0.11)"),
     # -- runtime-synthesized families --------------------------------------
     Metric("scheduler_", GAUGE,
            "continuous-scheduler family (ContinuousEngine.scheduler_stats). "

@@ -16,7 +16,7 @@ MLP — so a decode step goes from O(L × ops) launches to O(L/K)
 Bit-exactness contract: the kernel body executes the SAME source the
 per-layer path executes — :func:`models.llama.rms_norm` /
 :func:`~models.llama.rope` / :func:`~models.llama.
-xla_attention`, :func:`ops.linear.linear` on the per-layer weight dicts,
+decode_attention`, :func:`ops.linear.linear` on the per-layer weight dicts,
 :func:`~.kvquant.quantize_kv_xla`, and the same ``dynamic_update_slice``
 ring write — traced per layer in the same order, on the same dtypes.  On
 the CPU dev-gate (interpret mode) the looped greedy decode is therefore
@@ -151,7 +151,7 @@ def _loop_kernel(s_ref, h_ref, *rest, cfg, fmts, out_count: int):
     and the ``h`` scratch.  All math below is the per-layer path's own
     source (models/llama.py, ops/linear.py, kvquant.py), which is the
     whole bit-exactness argument."""
-    from ...models.llama import rms_norm, rope, xla_attention
+    from ...models.llama import decode_attention, rms_norm, rope
     from ...ops.linear import linear
     from .kvquant import quantize_kv_xla
 
@@ -224,7 +224,12 @@ def _loop_kernel(s_ref, h_ref, *rest, cfg, fmts, out_count: int):
         cache_outs[1][...] = cv[None]
         cks = cvs = None
 
-    ctx = xla_attention(q, ck, cv, cks, cvs, positions, cfg, h.dtype)
+    # the per-layer path's own read of the live part of the ring, on this
+    # layer's ring as a stack of one
+    ring = {"k_q": ck, "v_q": cv, "k_s": cks, "v_s": cvs} if quant \
+        else {"k": ck, "v": cv}
+    ctx = decode_attention(q, {n: a[None] for n, a in ring.items()},
+                           0, pos, pos, cfg, h.dtype)
     h = h + lin(ctx, "wo")
 
     hn = rms_norm(h, ffn_norm[0], cfg.rms_eps)

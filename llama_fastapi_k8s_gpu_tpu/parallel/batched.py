@@ -45,6 +45,15 @@ def init_batched_state(cfg: ModelConfig, batch: int, seed: int = 0) -> dict:
     }
 
 
+def live_bound(pos: jax.Array, live: jax.Array | None = None) -> jax.Array:
+    """The ring slot a decode step's attention reads up to, ONE scalar for
+    all lanes (models/llama.py ``decode_attention``): the largest position
+    among the lanes that hold a request (``live`` (B,) bool; None: all).  A
+    freed lane keeps stepping and its position walks on; it must not drag
+    the read to ``n_ctx``."""
+    return jnp.max(pos if live is None else jnp.where(live, pos, 0))
+
+
 def state_nbytes(state: dict | None) -> int:
     """Resident HBM bytes of a batched generation state (cache lanes +
     decode bookkeeping) — the memory ledger's ``kv_lanes`` row
@@ -80,10 +89,12 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
     of sampling knobs.  Returns (state, tokens (n_steps, B))."""
 
     def one_step(carry, _):
+        bound = live_bound(carry["pos"])
+
         def single(token, pos, cache, window, wpos, key):
             logits, cache, *stats = forward(
                 params, cfg, token[None], pos, cache,
-                with_stats=bool(cfg.n_experts))
+                with_stats=bool(cfg.n_experts), kv_bound=bound)
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)
@@ -119,15 +130,18 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     knobs (``lane_st`` leaves have a leading B dim) — the continuous
     scheduler admits requests with different temperatures/penalties into
     neighboring lanes.  (top_k stays a shared static: ``lax.top_k`` needs a
-    static k; see ContinuousEngine.submit.)  ``live`` (B,) bool, given for
-    a routed block only: the lanes that hold a request; the others' rows
-    reach no expert, so a step reads what its live lanes picked."""
+    static k; see ContinuousEngine.submit.)  ``live`` (B,) bool: the lanes
+    that hold a request (None: all).  A step's attention reads the ring up
+    to :func:`live_bound`; of a routed block the others' rows also reach no
+    expert, so a step reads what its live lanes picked."""
 
     def one_step(carry, _):
+        bound = live_bound(carry["pos"], live)
+
         def single(token, pos, cache, window, wpos, key, st, live):
             logits, cache, *stats = forward(
                 params, cfg, token[None], pos, cache, live=live,
-                with_stats=bool(cfg.n_experts))
+                with_stats=bool(cfg.n_experts), kv_bound=bound)
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)

@@ -1576,6 +1576,12 @@ def create_app(engine=None, settings: Settings | None = None,
                             snap["adm_budget_tokens"])
             if "lane_idle_seconds" in snap:
                 m.set_gauge("lane_idle_seconds", snap["lane_idle_seconds"])
+        # the decode steps' read of the KV ring against what was live
+        # (Engine.ring_slots; the lane engine adds at each chunk's harvest)
+        ring = getattr(app.state.engine, "ring_slots", None)
+        if ring is not None:
+            m.set_gauge("ring_slots_read_total", ring["read"])
+            m.set_gauge("ring_slots_live_total", ring["live"])
         # routed layers (a file with experts): cumulative counters of the
         # decode chunks that have finished, folded here and not on the
         # decode path (engine/expert_counters.py)

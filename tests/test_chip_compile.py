@@ -211,6 +211,88 @@ def test_load_dequant_kernel_compiles(one_chip, gtype):
         assert "tpu_custom_call" in _compile(one_chip, fn, S(1, dtype=f32))
 
 
+def _int8_params(cfg):
+    """Shapes of ``load_params``'s tree with int8 linears (plain XLA dots:
+    the fused kernels have their own tests above; what is compiled here
+    is the decode step around them at a cell's ring and lane count)."""
+    L, D, F, V = cfg.n_layers, cfg.dim, cfg.ffn_dim, cfg.vocab_size
+    qd, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+
+    def lin(o, i):
+        return {"q": S(L, o, i, dtype=i8), "s": S(L, o, dtype=f32)}
+
+    layers = {"attn_norm": S(L, D, dtype=f32), "ffn_norm": S(L, D, dtype=f32),
+              "wq": lin(qd, D), "wk": lin(kv, D), "wv": lin(kv, D),
+              "wo": lin(D, qd), "w_gate": lin(F, D), "w_up": lin(F, D),
+              "w_down": lin(D, F)}
+    if cfg.qk_norm:
+        layers["attn_q_norm"] = S(L, qd, dtype=f32)
+        layers["attn_k_norm"] = S(L, kv, dtype=f32)
+    return {"tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+            "output": {"q": S(V, D, dtype=i8), "s": S(V, dtype=f32)},
+            "layers": layers}
+
+
+# the three configurations of BENCHMARK.json, n_ctx 4096, bf16 KV:
+# (name, layers, dim, heads, kv heads, ffn, vocab, qk_norm, lanes)
+@pytest.mark.parametrize("name,L,D,H,KV,F,V,qk_norm,lanes", [
+    ("solar-serial", 48, 4096, 32, 8, 14336, 32000, False, 0),
+    ("mistral-8lane", 32, 4096, 32, 8, 14336, 32000, False, 8),
+    # OLMoE's attention (16 MHA heads, QK-norm, rotate-half) and ring; its
+    # routed feed-forward has its own test above, a dense one stands in
+    ("olmoe-8lane", 16, 2048, 16, 16, 1024, 50304, True, 8),
+])
+def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
+                                              V, qk_norm, lanes):
+    """The decode chunk program of each cell (serial ``generate_chunk_jit``,
+    lane ``batched_generate_chunk_perlane_jit`` with ``live``) compiles for
+    the chip with the block read of ``decode_attention`` inside, and the
+    compiler has put NO ring-sized copy, slice or transpose beside it
+    (the loop slices the STACKED leaf in place; a form that hands a
+    layer's ring into the loop may be given a copy of it in every layer)
+    and needs no ring-sized scratch."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state)
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    cfg = ModelConfig(vocab_size=V, dim=D, n_layers=L, n_heads=H,
+                      n_kv_heads=KV, ffn_dim=F, n_ctx=4096, qk_norm=qk_norm,
+                      rope_neox=qk_norm)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(_int8_params(cfg))
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    ring_op = re.compile(
+        r"= bf16\[(\d+,)*4096,128\]\S* (copy|dynamic-slice|transpose)\(")
+    found = [ln.strip()[:160] for ln in compiled.as_text().splitlines()
+             if ring_op.search(ln)]
+    assert not found, found[:3]
+    one_layer_ring = max(lanes, 1) * KV * 4096 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_ring
+
+
 def test_decode_loop_at_8b_geometry_is_refused(one_chip):
     """``ops/pallas/decode_loop.py`` (off by default) does not compile for
     the chip at the 8B geometry: the lowering refuses the (1, N) block of

@@ -485,6 +485,34 @@ def test_scheduler_stats_surface(cengine):
     assert cengine.scheduler_stats()["pending"] == 0
 
 
+def test_ring_slot_counters_rise_with_the_lanes_positions(cengine):
+    """``ring_slots`` (/metrics ``ring_slots_*_total``): per decode step, summed over the lanes that
+    hold a request, the slots the attention read covered and the slots at
+    or below the lane's position (n_ctx 128 is one block here, so a step
+    reads 128 a lane).  Computed at harvest from host-held positions."""
+    def totals():
+        return cengine.ring_slots["read"], cengine.ring_slots["live"]
+
+    deadline = time.time() + 10
+    while time.time() < deadline and cengine.scheduler_stats()["lanes_live"]:
+        time.sleep(0.05)
+    read0, live0 = totals()
+    out = cengine.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
+    n_prompt = out["usage"]["prompt_tokens"]
+    assert out["usage"]["completion_tokens"] == 9
+    time.sleep(0.5)         # the pipelined in-flight chunk lands
+    read, live = totals()
+    read, live = read - read0, live - live0
+    # the first token comes from prefill; tokens 2..9 take two chunks of 4
+    # steps at positions n_prompt .. n_prompt + 7, and the lane stays live
+    # for the chunk that was in flight when it finished (rows discarded)
+    steps = read // 128
+    assert read == steps * 128 and steps in (8, 12)
+    assert live >= sum(n_prompt + t + 1 for t in range(8))
+    assert live <= sum(n_prompt + t + 1 for t in range(12))
+    assert 0 < live < read
+
+
 def test_outputs_independent_of_adm_budget(tmp_path):
     """The admission budget changes WHEN requests are admitted, never WHAT
     they produce: a wave of greedy requests must yield identical text at

@@ -1,0 +1,426 @@
+"""A lane's decode step does not depend on what the other lanes hold, and
+the bounded read of the ring (models/llama.py ``decode_attention``) stays
+the model: logits against the whole-ring read it replaced, and prefill +
+64 decode steps through the cache against the benchmark's plain float32
+references (``benchmarks/reference.py``, ``reference_routed.py``).
+
+Why the independence is a property and not luck: lanes ``vmap``ped over
+one step share the loop's trip count (``parallel/batched.py live_bound``:
+the largest LIVE position), so a short lane also runs the blocks a longer
+neighbour needs.  Such a block lies wholly beyond the short lane's
+position: every score is -inf, the running max is unchanged, ``exp(m -
+m_new)`` is exactly 1.0 and every probability exactly 0.0, so sum and
+accumulator come out bit for bit as they went in.  The block that holds
+the lane's own position is read under the lane's own mask whatever the
+bound.  Hence BITWISE equality below, not a tolerance.
+
+The block is shrunk to 16 slots (``DECODE_KV_BLOCK``, read at trace time)
+so that a ring of 128 slots holds eight blocks.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llama_fastapi_k8s_gpu_tpu.models import llama
+from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
+from llama_fastapi_k8s_gpu_tpu.parallel.batched import live_bound
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+BLOCK, N_CTX = 16, 128
+
+# The program multiplies in bfloat16 and keeps activations in bfloat16
+# between layers; over two layers that reaches about 1 % of the logits'
+# norm against a float32 reference (benchmarks/tests/test_reference.py and
+# tests/test_olmoe.py hold the same limit and show that a missing term or a
+# lower precision lands far outside it).
+REFERENCE = 3e-2
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(llama, "DECODE_KV_BLOCK", BLOCK)
+
+
+def _cfg(heads=(4, 2), window=0, kv_dtype="bf16"):
+    return ModelConfig(vocab_size=64, dim=16 * heads[0], n_layers=3,
+                       n_heads=heads[0], n_kv_heads=heads[1], ffn_dim=96,
+                       n_ctx=N_CTX, kv_dtype=kv_dtype, sliding_window=window)
+
+
+def _random_cache(cfg, seed):
+    """A ring with EVERY slot filled: what lies past a position, or in
+    another lane, must not matter."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
+    return {"k": jax.random.normal(ks[0], shape, jnp.bfloat16),
+            "v": jax.random.normal(ks[1], shape, jnp.bfloat16)}
+
+
+def whole_ring(q, cache, i, pos, bound, cfg, out_dtype):
+    """``xla_attention`` over layer ``i``'s whole ring, under
+    ``decode_attention``'s signature: the S = 1 read this PR replaced."""
+    at = {n: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+          for n, a in cache.items()}
+    return llama.xla_attention(q, at["k"], at["v"], None, None,
+                               jnp.asarray(pos, jnp.int32)[None], cfg,
+                               out_dtype)
+
+
+def bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# ---------------------------------------------------------------------------
+# logits: the bounded read against the whole-ring read
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("pos", [0, BLOCK - 1, BLOCK, BLOCK + 1, N_CTX - 1])
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
+def test_logits_match_the_whole_ring_read(monkeypatch, window, heads, pos):
+    """One decode step through the whole stack at the block's edges, every
+    layer's ring random.  The two reads differ by the order of float32 sums
+    (1e-6 relative in the float32 state) and by where a probability is
+    rounded to bf16: after the cast, at most one bf16 step (2^-8 relative)
+    of an attention output.  What one such step is worth in THIS model's
+    logits is measured beside it: the whole-ring read with its output
+    scaled by 1 + 2^-8 moves them by 0.8-2.1 % of their norm over these
+    cases, the bounded read by 0-2.3 %.  The limit is the program's own
+    distance from a float32 reference (``REFERENCE``, 3 %): a read that
+    dropped or added one live slot of 17 is tens of per cent off."""
+    cfg = _cfg(heads, window)
+    params = synth_params(cfg, seed=2)
+    cache = _random_cache(cfg, seed=pos)
+
+    def logits(read):
+        monkeypatch.setattr(llama, "decode_attention", read)
+        return np.asarray(llama.decode_step(
+            params, cfg, jnp.int32(7), jnp.int32(pos), cache)[0], np.float32)
+
+    def one_step_off(q, cache, i, pos, bound, cfg, out_dtype):
+        out = whole_ring(q, cache, i, pos, bound, cfg, jnp.float32)
+        return (out * (1 + 2.0 ** -8)).astype(out_dtype)
+
+    def a_slot_short(q, cache, i, pos, bound, cfg, out_dtype):
+        return whole_ring(q, cache, i, jnp.maximum(pos - 1, 0), bound, cfg,
+                          out_dtype)
+
+    got = logits(llama.decode_attention)
+    want = logits(whole_ring)
+    assert got.shape == want.shape == (cfg.vocab_size,)
+    assert np.isfinite(got).all()
+    assert rel(got, want) < REFERENCE
+    assert rel(got, want) < 3 * rel(logits(one_step_off), want) + 1e-6
+    if pos:
+        assert rel(logits(a_slot_short), want) > 2 * REFERENCE
+
+
+# ---------------------------------------------------------------------------
+# a lane's logits are bitwise the same whatever the other lanes hold
+# ---------------------------------------------------------------------------
+
+def _lane_step(params, cfg):
+    """The lane program's step (parallel/batched.py ``one_step``), with the
+    logits kept: ``vmap`` of ``forward`` over per-lane rings under ONE
+    bound, the largest live position."""
+    @jax.jit
+    def step(toks, poss, caches, live):
+        bound = live_bound(poss, live)
+        return jax.vmap(lambda t, p, c, lv: forward_lane(t, p, c, lv, bound))(
+            toks, poss, caches, live)
+
+    def forward_lane(t, p, c, lv, bound):
+        logits, cache, *_ = llama.forward(params, cfg, t[None], p, c, live=lv,
+                                          kv_bound=bound)
+        return logits, cache
+
+    return step
+
+
+OTHERS = {
+    # the other three lanes: (live, positions, rings)
+    "empty": (False, (0, 0, 0), "zeros"),
+    "full": (True, (N_CTX - 2, N_CTX - 3, N_CTX - 1), "random"),
+    "dead_with_stale_positions": (False, (N_CTX - 2, 77, N_CTX - 1), "random"),
+    # a freed lane keeps stepping: its position walks past the ring's end
+    "dead_beyond_the_ring": (False, (N_CTX + 40, 5000, N_CTX), "random"),
+    "mixed": ((True, False, True), (3, 120, 60), "random"),
+}
+
+
+@pytest.mark.parametrize("others", [k for k in OTHERS if k != "empty"])
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("pos", [5, BLOCK, 70])
+def test_a_lanes_logits_do_not_depend_on_the_other_lanes(heads, pos, others):
+    """Lane 1 holds the same ring, token and position throughout; lanes 0, 2
+    and 3 are empty, full to the ring's end, dead with stale positions, dead
+    with positions past the ring, or a mix.  Lane 1's logits and the K/V it
+    writes are BITWISE those of the run with the other lanes empty."""
+    cfg = _cfg(heads)
+    params = synth_params(cfg, seed=1)
+    step = _lane_step(params, cfg)
+    mine = _random_cache(cfg, seed=11)
+
+    def run(name):
+        live, poss, rings = OTHERS[name]
+        live = (live,) * 3 if isinstance(live, bool) else live
+        lanes = [jax.tree.map(jnp.zeros_like, mine) if rings == "zeros"
+                 else _random_cache(cfg, seed=20 + n) for n in range(3)]
+        lanes.insert(1, mine)
+        caches = jax.tree.map(lambda *a: jnp.stack(a), *lanes)
+        logits, new = step(
+            jnp.asarray([9, 7, 11, 13], jnp.int32),
+            jnp.asarray([poss[0], pos, poss[1], poss[2]], jnp.int32), caches,
+            jnp.asarray([live[0], True, live[1], live[2]]))
+        return logits, jax.tree.map(lambda a: a[1], new)
+
+    want, want_cache = run("empty")
+    got, got_cache = run(others)
+    assert np.array_equal(bits(got[1]), bits(want[1]))
+    for name in ("k", "v"):
+        assert np.array_equal(bits(got_cache[name]), bits(want_cache[name]))
+    assert np.isfinite(np.asarray(got[1])).all()
+    # and the test can tell: another token in lane 1 moves its logits
+    assert not np.array_equal(bits(got[1]), bits(got[0]))
+
+
+def test_an_all_masked_block_leaves_the_state_bit_for_bit():
+    """The recurrence itself: blocks beyond the position (a later bound)
+    change nothing, also under a sliding window whose FIRST blocks hold no
+    live slot (running max at its finite floor: no NaN)."""
+    for window in (0, 24):
+        cfg = _cfg(window=window)
+        cache = _random_cache(cfg, seed=3)
+        q = jax.random.normal(jax.random.PRNGKey(4),
+                              (1, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+        outs = [llama.decode_attention(q, cache, 1, 70, bound, cfg,
+                                       jnp.float32)
+                for bound in (70, 79, 80, N_CTX - 1, 5000)]
+        assert np.isfinite(np.asarray(outs[0])).all()
+        for out in outs[1:]:
+            assert np.array_equal(bits(out), bits(outs[0]))
+
+
+# ---------------------------------------------------------------------------
+# prefill + 64 decode steps through the cache, against the references
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def bench():
+    """``benchmarks/`` is not a package: its files import each other by
+    bare name."""
+    sys.path.insert(0, BENCH)
+    try:
+        import reference
+        import reference_routed
+        yield types.SimpleNamespace(dense=reference, routed=reference_routed)
+    finally:
+        sys.path.remove(BENCH)
+
+
+@pytest.fixture(scope="module")
+def dense(tmp_path_factory, bench):
+    """(params, cfg, reference logits of a sequence) on one tiny GGUF file:
+    2 layers, 4 / 2 heads (GQA), n_ctx 128."""
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
+    from llama_fastapi_k8s_gpu_tpu.models.params import load_params
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
+
+    path = str(tmp_path_factory.mktemp("dense") / "tiny.gguf")
+    write_tiny_llama_gguf(path, seed=5)
+    gf = GGUFFile(path)
+    cfg = ModelConfig.from_gguf(gf, n_ctx=N_CTX)
+    hp, w = bench.dense.load_weights(path)
+    return (load_params(gf, cfg, fmt="bf16"), cfg,
+            lambda tokens: np.asarray(bench.dense.forward(hp, w, tokens)))
+
+
+def _prefill(params, cfg, tokens, n, **kw):
+    padded = np.zeros(32, np.int32)
+    padded[:n] = tokens[:n]
+    return llama.forward(params, cfg, jnp.asarray(padded), jnp.int32(0),
+                         llama.init_cache(cfg), last_idx=jnp.int32(n - 1),
+                         **kw)
+
+
+def test_serial_prefill_then_64_decode_steps_agree_with_the_reference(dense):
+    """The serial engine's two programs: a padded bucket prefill of 20
+    tokens, then positions 20..83 one at a time against the ring, across
+    the block edges at 32, 48, 64 and 80."""
+    params, cfg, reference = dense
+    tokens = np.random.default_rng(1).integers(0, 256, size=84)
+    want = reference(tokens)
+    logits, cache = _prefill(params, cfg, tokens, 20)
+    assert rel(logits, want[19]) < REFERENCE
+    step = jax.jit(lambda t, p, c: llama.decode_step(params, cfg, t, p, c))
+    for pos in range(20, 84):
+        logits, cache = step(jnp.int32(tokens[pos]), jnp.int32(pos), cache)
+        assert rel(logits, want[pos]) < REFERENCE, pos
+
+
+def test_lanes_of_different_lengths_join_and_leave_over_64_steps(dense):
+    """The lane engine's step over three lanes for 64 steps.  Lane 0 (from
+    position 10) leaves after step 40 and, as a freed lane does, keeps
+    stepping with its position walking on; lane 1 (from 30) stays; lane 2
+    holds a dead request's stale ring at position 100 until a new request
+    of 5 tokens joins it at step 8.  Every live lane's logits are the
+    reference's at its own position, at every step."""
+    params, cfg, reference = dense
+    rng = np.random.default_rng(9)
+    starts, steps = (10, 30, 5), 64
+    leave_0, join_2 = 40, 8
+    seqs = [rng.integers(0, 256, size=s + steps) for s in starts]
+    fresh = [_prefill(params, cfg, s, n)[1] for s, n in zip(seqs, starts)]
+    stale = _random_cache(cfg, seed=99)
+    caches = jax.tree.map(lambda *a: jnp.stack(a), fresh[0], fresh[1], stale)
+    step = _lane_step(params, cfg)
+
+    pos = [starts[0], starts[1], 100]
+    got = {lane: [] for lane in range(3)}
+    for t in range(steps):
+        if t == join_2:             # the admission's lane write
+            caches = jax.tree.map(lambda a, b: a.at[2].set(b), caches,
+                                  fresh[2])
+            pos[2] = starts[2]
+        live = np.array([t <= leave_0, True, t >= join_2])
+        toks = [int(s[min(p, len(s) - 1)]) if lv else 1
+                for s, p, lv in zip(seqs, pos, live)]
+        logits, caches = step(jnp.asarray(toks, jnp.int32),
+                              jnp.asarray(pos, jnp.int32), caches,
+                              jnp.asarray(live))
+        for lane in range(3):
+            if live[lane]:
+                got[lane].append(np.asarray(logits[lane]))
+            pos[lane] += 1          # every lane steps, live or not
+    assert [len(got[lane]) for lane in range(3)] == [41, 64, 56]
+    for lane, n in enumerate(starts):
+        want = reference(seqs[lane])[n:n + len(got[lane])]
+        for t, (a, b) in enumerate(zip(got[lane], want)):
+            assert rel(a, b) < REFERENCE, (lane, t)
+
+
+def test_routed_prefill_then_64_decode_steps_agree_with_the_reference(
+        tmp_path, bench):
+    """The routed block (tiny ``olmoe`` file: 16 / 16 heads' kind, MHA,
+    QK-norm, rotate-half) through the same read, against
+    ``reference_routed.py`` sent the program's own picks (near-ties in a
+    random router are not the subject here: tests/test_olmoe.py)."""
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
+    from llama_fastapi_k8s_gpu_tpu.models.params import load_params
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_olmoe_gguf
+
+    path = str(tmp_path / "tiny.gguf")
+    write_tiny_olmoe_gguf(path, seed=3)
+    gf = GGUFFile(path)
+    cfg = ModelConfig.from_gguf(gf, n_ctx=N_CTX)
+    params = load_params(gf, cfg, fmt="bf16")
+    tokens = np.random.default_rng(5).integers(0, 256, size=84)
+    logits, cache, pk = _prefill(params, cfg, tokens, 20, with_picks=True)
+    got, picks = [], [np.asarray(pk)[:, :20]]
+    step = jax.jit(lambda t, p, c: llama.forward(
+        params, cfg, t[None], p, c, with_picks=True))
+    for pos in range(20, 84):
+        logits, cache, pk = step(jnp.int32(tokens[pos]), jnp.int32(pos), cache)
+        got.append(np.asarray(logits))
+        picks.append(np.asarray(pk))
+    hp, tensors = bench.routed.open_model(path)
+    want = np.asarray(bench.routed.forward(
+        hp, tensors, tokens,
+        use_picks=list(np.concatenate(picks, 1)))[0])
+    # the limit as tests/test_olmoe.py reads it, over the stacked positions
+    # (1.7 % here, and 1.7 % with the whole-ring read); one position alone
+    # of this tiny routed model reaches 3.6 % (the whole-ring read: 4.6 %)
+    assert rel(np.stack(got), want[20:]) < REFERENCE
+    for pos, row in zip(range(20, 84), got):
+        assert rel(row, want[pos]) < 2 * REFERENCE, pos
+
+
+# ---------------------------------------------------------------------------
+# the served path: a greedy probe's text over rounds of traffic
+# ---------------------------------------------------------------------------
+
+def test_a_greedy_probes_text_is_unchanged_by_the_other_lanes_traffic(
+        tmp_path):
+    """What ``benchmarks/run.py`` demands of a window, on the lane engine:
+    two temperature-0 probes on an idle engine, then traffic that fills the
+    other lanes to different lengths (long prompts, long outputs, lanes
+    freed with their positions left where they stopped), then the probes
+    again, five rounds; and once with the traffic still running.  The text
+    is identical every time."""
+    from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
+
+    path = str(tmp_path / "tiny.gguf")
+    write_tiny_llama_gguf(path)
+    eng = ContinuousEngine(path, batch_size=4, n_ctx=N_CTX, decode_chunk=4,
+                           max_gen_tokens=64, prefill_buckets=(32, 64, 128))
+    probes = [[{"role": "user", "content": "Say something."}],
+              [{"role": "user", "content": "Count to three, slowly."}]]
+
+    def probe():
+        return [eng.create_chat_completion(m, temperature=0.0, max_tokens=12)
+                ["choices"][0]["message"]["content"] for m in probes]
+
+    def traffic(round_no):
+        return [eng.submit(
+            [{"role": "user", "content": f"round {round_no} " * (2 + 3 * i)}],
+            temperature=0.8, seed=round_no * 10 + i,
+            max_tokens=(8, 60, 24)[i]) for i in range(3)]
+
+    try:
+        first = probe()
+        assert all(first)
+        for round_no in range(5):
+            futs = traffic(round_no)
+            if round_no == 2:
+                assert probe() == first     # neighbours mid-flight
+            for f in futs:
+                f.result(timeout=300)
+            assert probe() == first, round_no
+        assert 0 < eng.ring_slots["live"] <= eng.ring_slots["read"]
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the counters on a schedule the test knows
+# ---------------------------------------------------------------------------
+
+def test_the_lane_counters_on_a_known_schedule():
+    """``ContinuousEngine._note_ring_read`` on one chunk of 4 steps over 4
+    lanes: lane 0 at slot 14, lane 1 at 30 (the bound), lane 2 empty, lane
+    3 finished (its rows are discarded: not counted, and since the chunk
+    was dispatched with it live, its position 50 set the bound).  Blocks
+    of 16: bounds 50..53 read 4 blocks = 64 slots a lane-step."""
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+
+    def slot(n_prompt, n_gens, finished=False):
+        return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
+                                     finished=finished)
+
+    eng = types.SimpleNamespace(cfg=types.SimpleNamespace(n_ctx=N_CTX),
+                                ring_slots={"read": 0, "live": 0})
+    pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
+    ContinuousEngine._note_ring_read(eng, pre, 4)
+    assert eng.ring_slots == {
+        "read": 2 * 4 * 64,
+        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34)}
+    # without the finished lane the bound is lane 1's: 30, 31 read 2
+    # blocks, 32, 33 read 3
+    ContinuousEngine._note_ring_read(eng, pre[:3], 4)
+    assert eng.ring_slots["read"] == 2 * 4 * 64 + 2 * (32 + 32 + 48 + 48)

@@ -236,7 +236,10 @@ def test_per_layer_decode_step_launch_pin():
     assert audit["loop_trips"] == [8]
     assert audit["in_loop"] == 8 * 9
     assert audit["outside"] == 1          # the output head
-    assert audit["while_loops"] == 0      # trip counts are all static
+    # the ONE loop whose trip count is not static: the decode attention's
+    # read of the ring in blocks up to the newest live slot (its 2
+    # contractions are in the 9 above, counted once: a floor)
+    assert audit["while_loops"] == 1
 
 
 def test_looped_decode_step_launch_pin():

@@ -178,6 +178,26 @@ async def test_metrics_flattens_nested_scheduler_stats():
         await app.router.shutdown()
 
 
+@pytest.mark.anyio
+@pytest.mark.parametrize("lanes", [False, True], ids=["serial", "lane"])
+async def test_metrics_exports_the_ring_slot_counters(lanes):
+    """``ring_slots_read_total`` / ``ring_slots_live_total`` under ONE name
+    for the serial and the lane engine (``Engine.ring_slots``; a lane
+    engine also has ``scheduler_stats``)."""
+    engine = FakeEngine()
+    engine.ring_slots = {"read": 1536, "live": 1100}
+    if lanes:
+        engine.scheduler_stats = lambda: {"lanes_live": 2}
+    app, transport = make_client(engine)
+    async with transport:
+        await app.router.startup()
+        async with await lifespan_client(app, transport) as client:
+            m = await client.get("/metrics")
+            assert "\nring_slots_read_total 1536" in m.text
+            assert "\nring_slots_live_total 1100" in m.text
+        await app.router.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # pure-function behavior parity (reference api.py:30-46, 127-147)
 # ---------------------------------------------------------------------------
