@@ -471,138 +471,6 @@ def ttft_sweep_main() -> None:
             emit_result(line)
 
 
-def decode_unroll_sweep_main() -> None:
-    """``python bench.py --decode-unroll-sweep`` (env:
-    LFKT_BENCH_UNROLL_SWEEP=1): the layer-looped decode A/B grid
-    (ISSUE 12 / ROADMAP item 2) — ``LFKT_BENCH_UNROLLS`` (default
-    ``0,4,8,-1``) values of ``decode_layer_unroll``, one JSON line per
-    point: steady-state decode step time (the HBM-roofline adjudication
-    number), tok/s, and the deterministic per-step launch audit
-    (obs/launches.py) so every banked line carries its own proof of the
-    launch-count collapse.
-
-    Weight format defaults to ``int8`` (env LFKT_BENCH_FMT): the fused
-    K-quant layouts gate off the looped kernel (their block planes need a
-    per-layer restack — docs/PERF.md round 8), so the sweep adjudicates
-    launch overhead on the int8 path the kernel actually serves.  Each
-    point is a ``dataclasses.replace`` of the same config — the knob is a
-    ModelConfig field precisely so this sweep can retrace in-process
-    instead of spawning one child per K."""
-    import dataclasses
-    import statistics
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from llama_fastapi_k8s_gpu_tpu.models.config import LLAMA3_8B, ModelConfig
-    from llama_fastapi_k8s_gpu_tpu.models.generate import (
-        generate_chunk_jit,
-        init_state,
-        prefill_jit,
-        sample_jit,
-    )
-    from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
-    from llama_fastapi_k8s_gpu_tpu.obs.launches import decode_step_launches
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.decode_loop import (
-        effective_unroll,
-    )
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.probe import probe_decode_loop
-    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
-        SamplingParams,
-        sampling_tensors,
-        seed_window,
-    )
-
-    preset = os.environ.get("LFKT_BENCH_PRESET", "llama3-8b")
-    wfmt = os.environ.get("LFKT_BENCH_FMT", "int8")
-    tiny = preset == "tiny"
-    if tiny:
-        cfg0 = ModelConfig(vocab_size=512, dim=128, n_layers=2, n_heads=8,
-                           n_kv_heads=4, ffn_dim=256, n_ctx=256)
-        n_decode, unrolls_def = 32, "0,2,-1"
-    else:
-        cfg0 = LLAMA3_8B
-        n_decode, unrolls_def = 256, "0,4,8,-1"
-    cfg0 = dataclasses.replace(cfg0, kv_dtype=os.environ.get(
-        "LFKT_KV_DTYPE", "bf16"))
-    unrolls = [int(u) for u in os.environ.get(
-        "LFKT_BENCH_UNROLLS", unrolls_def).split(",") if u.strip()]
-    chunk = 8
-
-    dev = start_device(preset)
-
-    fallbacks = {}
-    if wfmt not in ("bf16", "int8"):
-        # the fused layouts cannot serve the looped kernel — an explicit
-        # LFKT_BENCH_FMT=q4km run degrades loudly rather than silently
-        # measuring the per-layer path at every K
-        fallbacks["fmt_fallback"] = (
-            f"{wfmt} gates off the looped kernel; sweeping int8 instead")
-        wfmt = "int8"
-    params = synth_params(cfg0, fmt=wfmt)
-    sp = SamplingParams()
-    st = sampling_tensors(sp)
-
-    err = probe_decode_loop(quantized=cfg0.kv_dtype == "int8",
-                            int8_weights=wfmt == "int8",
-                            n_kv=cfg0.n_kv_heads, head_dim=cfg0.head_dim,
-                            n_ctx=cfg0.n_ctx, n_heads=cfg0.n_heads,
-                            ffn_dim=cfg0.ffn_dim)
-    if err is not None:
-        fallbacks["loop_fallback"] = f"decode-loop probe: {err}"[:300]
-        unrolls = [0]
-
-    prompt = list(range(1, 17))
-
-    def one_rate(cfg) -> float:
-        """tokens/sec over ``n_decode`` steady-state decode tokens."""
-        state = init_state(cfg)
-        logits, state["cache"] = prefill_jit(
-            params, cfg, jnp.asarray(prompt, jnp.int32),
-            jnp.int32(len(prompt)), state["cache"])
-        window, wpos = seed_window(prompt)
-        tok, window, wpos, key = sample_jit(
-            logits, window, wpos, state["key"], st, cfg)
-        state.update(pos=jnp.int32(len(prompt)), token=tok,
-                     window=window, wpos=wpos, key=key)
-        state, toks = generate_chunk_jit(params, cfg, state, st,
-                                         n_steps=chunk)   # warm / compile
-        int(np.asarray(toks)[-1])
-        t0 = time.time()
-        for _ in range(n_decode // chunk):
-            state, toks = generate_chunk_jit(params, cfg, state, st,
-                                             n_steps=chunk)
-        int(np.asarray(toks)[-1])   # host fetch: the only reliable sync
-        return (n_decode // chunk) * chunk / (time.time() - t0)
-
-    for K in unrolls:
-        cfg = dataclasses.replace(cfg0, decode_layer_unroll=K)
-        eff = effective_unroll(cfg)
-        audit = decode_step_launches(params, cfg)
-        rates = sorted(one_rate(cfg) for _ in range(3))
-        rate = rates[1]
-        ktag = "kall" if K == -1 else f"k{K}"
-        line = {
-            "metric": (f"decode_step_ms[decode-unroll,{preset},{wfmt},"
-                       f"kv-{cfg.kv_dtype},{ktag}]"),
-            "value": round(1000.0 / rate, 3),
-            "unit": "ms",
-            "vs_baseline": 0.0,   # informational grid; no A10G analogue
-            "tokens_per_sec": round(rate, 2),
-            "decode_layer_unroll": K,
-            "effective_unroll": eff,
-            "launches_per_step": audit["total"],
-            "launches_in_loop": audit["in_loop"],
-            "decode_chunk": chunk,
-            "n_decode_tokens": n_decode,
-            "samples_tok_s": [round(r, 2) for r in rates],
-            "device": str(dev),
-        }
-        line.update(fallbacks)
-        emit_result(line)
-
-
 def replay_main() -> None:
     """``python bench.py --multiturn-replay`` (env: LFKT_BENCH_REPLAY=1):
     the block-paged radix prefix cache's payoff measurement —
@@ -715,9 +583,6 @@ def child_main() -> None:
         return
     if os.environ.get("LFKT_BENCH_TTFT_SWEEP") == "1":
         ttft_sweep_main()
-        return
-    if os.environ.get("LFKT_BENCH_UNROLL_SWEEP") == "1":
-        decode_unroll_sweep_main()
         return
     if os.environ.get("LFKT_BENCH_REPLAY") == "1":
         replay_main()
@@ -929,8 +794,6 @@ def child_main() -> None:
 def main() -> None:
     if "--ttft-sweep" in sys.argv[1:]:
         os.environ["LFKT_BENCH_TTFT_SWEEP"] = "1"
-    if "--decode-unroll-sweep" in sys.argv[1:]:
-        os.environ["LFKT_BENCH_UNROLL_SWEEP"] = "1"
     if "--multiturn-replay" in sys.argv[1:]:
         os.environ["LFKT_BENCH_REPLAY"] = "1"
     child_main()

@@ -64,8 +64,6 @@ def main() -> None:
     n_req = int(os.environ.get("LFKT_BENCH_N_REQ", "12"))
     max_tokens = int(os.environ.get("LFKT_BENCH_MAX_TOKENS", "48"))
     port = int(os.environ.get("LFKT_BENCH_PORT", "8017"))
-    spec_decode = os.environ.get("LFKT_SPEC_DECODE", "off")
-    spec_draft = int(os.environ.get("LFKT_SPEC_DRAFT", "8"))
     fullctx = os.environ.get("LFKT_BENCH_FULLCTX") == "1"
     multiturn = os.environ.get("LFKT_BENCH_MULTITURN") == "1"
     # mixed-model arm (docs/MULTIMODEL.md): serve TWO models from one
@@ -399,15 +397,14 @@ def main() -> None:
             adm_controller=settings.adm_controller,
             adm_ema_alpha=settings.adm_ema_alpha,
             prefill_overlap=settings.prefill_overlap,
-            spec_decode=spec_decode, spec_draft=spec_draft,
             # the lane-prefix A/B knobs (VERDICT r4 #8).  The admission
             # slice size matters to the A/B too: reuse is chunk-aligned,
             # so a 256-token slice needs 256 shared tokens before the
             # first claim pays.
             lane_prefix_cache=lane_prefix,
             prefill_chunk=settings.prefill_chunk)
-        # report the engine's REALIZED setting, not the env request: spec
-        # decode silently excludes lane-prefix reuse (continuous.py), and a
+        # report the engine's REALIZED setting, not the env request: the
+        # paged pool replaces lane-prefix reuse (continuous.py), and a
         # ',laneprefix'-labeled artifact with reuse actually off would be a
         # mislabeled A/B arm in the evidence ledger
         lane_prefix = bool(getattr(eng, "_lane_prefix", False))
@@ -428,7 +425,6 @@ def main() -> None:
                 adm_controller=settings.adm_controller,
                 adm_ema_alpha=settings.adm_ema_alpha,
                 prefill_overlap=settings.prefill_overlap,
-                spec_decode=spec_decode, spec_draft=spec_draft,
                 lane_prefix_cache=lane_prefix,
                 prefill_chunk=settings.prefill_chunk)
             eng = ModelRegistry({"alpha": eng, "beta": eng_b}, "alpha")
@@ -451,8 +447,6 @@ def main() -> None:
                                 max_gen_tokens=max_tokens,
                                 attn_impl=cfg.attn_impl,
                                 decode_chunk=settings.decode_chunk,
-                                spec_decode=spec_decode,
-                                spec_draft=spec_draft,
                                 prefix_cache=multiturn,
                                 prefill_chunk=settings.prefill_chunk,
                                 prefill_overlap=settings.prefill_overlap,
@@ -1064,7 +1058,6 @@ def main() -> None:
     result = {
         "metric": (f"server_ttft_ms_p50[/response,{preset},{wfmt}"
                    + (",fullctx" if fullctx else "")
-                   + (",spec" if spec_decode == "lookup" else "")
                    + (",laneprefix" if lane_prefix and batch > 1 else "")
                    + (",admstatic" if batch > 1
                       and not settings.adm_controller else "")
@@ -1104,15 +1097,6 @@ def main() -> None:
         # live budget + EMAs say WHY an arm's agg_tok_s moved
         result["scheduler_stats"] = eng.scheduler_stats()
         result["adm_controller"] = settings.adm_controller
-    if spec_decode == "lookup":
-        # acceptance telemetry: accepted/drafted is THE pays-or-not number
-        if batch > 1:
-            result["spec"] = eng.scheduler_stats().get("spec")
-        else:
-            # serial engine: scrape the spec counters the app exports
-            result["spec"] = read_metrics_counters(
-                ("spec_verify_steps_total", "spec_drafted_tokens_total",
-                 "spec_accepted_tokens_total", "spec_fallback_steps_total"))
     emit_result(result)
     os._exit(0)  # daemon server thread: skip graceful asyncio teardown
 

@@ -522,12 +522,30 @@ def child_write(args) -> None:
            "write_s": round(time.time() - t0, 1)})
 
 
+def measure_dispatch_rtt_s(n: int = 7) -> float:
+    """Median wall time of a minimal jitted dispatch + host fetch: what every
+    host round trip to the chip costs before any work.  Two warm executions
+    (the compile, and the first run) are discarded first."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x + 1)
+    x = jnp.zeros((), jnp.int32)
+    for _ in range(2):
+        int(f(x))
+    ts = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        int(f(x))
+        ts.append(time.perf_counter() - t0)
+    return sorted(ts)[n // 2]
+
+
 def child_device(args) -> None:
     import importlib.metadata as md
 
     import jax
 
-    from llama_fastapi_k8s_gpu_tpu.engine.spec_auto import measure_dispatch_rtt_s
     from llama_fastapi_k8s_gpu_tpu.ops.pallas import use_interpret
     from llama_fastapi_k8s_gpu_tpu.utils.jaxcache import setup_compile_cache
 

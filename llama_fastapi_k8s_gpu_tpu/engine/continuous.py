@@ -38,15 +38,11 @@ from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import annotate_all_inflight, phase, rid
-from ..parallel.batched import (
-    batched_generate_chunk_perlane_jit,
-    batched_spec_verify_perlane_jit,
-)
+from ..parallel.batched import batched_generate_chunk_perlane_jit
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, EngineUnavailable
 from .batched import MeshEngine
-from .engine import Engine
 
 logger = logging.getLogger(__name__)
 
@@ -258,8 +254,6 @@ class ContinuousEngine(MeshEngine):
     ``create_chat_completions`` facades, which route through the scheduler.
     """
 
-    _SPEC_LANES = True   # serves spec_decode="lookup" via batched verify
-
     # -- thread discipline (machine-checked: lfkt-lint LOCK001-004, see
     # docs/RUNBOOK.md "Lock discipline annotations") ----------------------
     # The scheduler thread OWNS the device state: unlike MeshEngine (whose
@@ -277,7 +271,7 @@ class ContinuousEngine(MeshEngine):
     _THREAD_ENTRIES = ("_loop",)
     _THREAD_CONFINED = (
         "_bstate", "_lane_st", "_scratch_cache", "_adm", "_lane_claims",
-        "_prefix_stats", "_spec_stats", "_stats", "_loop_error",
+        "_prefix_stats", "_stats", "_loop_error",
         "_adm_budget", "_lane_idle_s", "_mem_hot_prev", "_totals",
         "_slices_queued",
     )
@@ -326,12 +320,11 @@ class ContinuousEngine(MeshEngine):
         # prefill — directly attacking the scheduler's admission-prefill
         # bottleneck.  Reuse is chunk-aligned so the compiled slice-shape
         # set stays closed, skipped for explicit-seed requests (the serial
-        # engine's reproducibility contract), and disabled under spec
-        # decode (verify rounds leave rejected drafts in lanes).  Claims
-        # are capped at n_ctx-1: a freed lane keeps garbage-decoding in
-        # the shared batched program, but those writes land at positions
-        # past the claim (clamping to slot n_ctx-1 once pos overruns).
-        self._lane_prefix = bool(lane_prefix_cache) and not self._spec_draft
+        # engine's reproducibility contract).  Claims are capped at
+        # n_ctx-1: a freed lane keeps garbage-decoding in the shared
+        # batched program, but those writes land at positions past the
+        # claim (clamping to slot n_ctx-1 once pos overruns).
+        self._lane_prefix = bool(lane_prefix_cache)
         # paged mode (LFKT_KV_PAGED) folds the lane claims behind the
         # shared radix tree: one prefix-reuse implementation per mode (the
         # per-lane claim path remains the dense-ring default).  An
@@ -368,12 +361,6 @@ class ContinuousEngine(MeshEngine):
         # effectively min(requested, ceiling)
         self._max_top_k = max(max_top_k, SamplingParams().top_k)
         self._req_counter = 0                # monotonic request id (abandon key)
-        # per-lane speculative decoding (VERDICT r3 #7): prompt-lookup
-        # drafts per lane, ONE batched verify for all lanes.  Inherits
-        # Engine's spec_decode/spec_draft kwargs; _SPEC_LANES suppresses
-        # the serial-only warning.
-        self._spec_stats = {"verify_steps": 0, "drafted": 0, "accepted": 0,
-                            "chunk_steps": 0}
         self._stats = {"lanes_live": 0, "pending": 0, "admission_inflight": 0}
         self._items: dict[int, _Item] = {}   # live request id → item (abandon)
         self._pending: queue_mod.Queue = queue_mod.Queue()
@@ -623,12 +610,6 @@ class ContinuousEngine(MeshEngine):
             f.result()
         list(self.submit_stream(msgs, max_tokens=self.decode_chunk + 1,
                                 temperature=0.0))
-        if self._spec_draft:
-            # compile the batched verify: a repeated-word prompt whose
-            # n-gram lookup is guaranteed to hit
-            self.submit([{"role": "user", "content": "hi hi hi hi hi hi"}],
-                        max_tokens=self._spec_draft + self.decode_chunk + 2,
-                        temperature=0.0).result()
         # every slice shape a bucket walk can produce, compiled against a
         # throwaway cache (jit program caches are global, so the scheduler
         # thread hits them warm; its own scratch cache is never touched)
@@ -1282,12 +1263,9 @@ class ContinuousEngine(MeshEngine):
         out = {"batch_size": self.batch_size, **self._stats}
         if self._lane_prefix or self._kv_paged:
             out.update(self._prefix_stats)
-        if self._spec_draft:
-            out["spec"] = dict(self._spec_stats)
         return out
 
     def _harvest(self, pre: list, chunk: "np.ndarray", slots: list,
-                 counts: "np.ndarray | None" = None,
                  wave: int = 0, admit_slices: int = 0) -> None:
         """Fold one fetched decode chunk into its lanes' slots.
 
@@ -1301,17 +1279,12 @@ class ContinuousEngine(MeshEngine):
         discarded generation delays nobody), an occupied lane would hold up
         waiting requests.
 
-        ``counts`` (spec-verify rounds): lane ``l`` emitted only
-        ``chunk[:counts[l], l]`` — rows beyond that are samples conditioned
-        on rejected draft tokens and must be discarded.
-
         ``wave`` / ``admit_slices`` (the chunk's dispatch number and the
         prefill slices queued on the device ahead of it) ride on each
         traced lane's ``decode_chunk`` span: a long chunk names its cause."""
         stop_ids = self.tokenizer.stop_ids
         now = time.time()
-        if counts is None:
-            self._note_ring_read(pre, len(chunk))
+        self._note_ring_read(pre, len(chunk))
         for lane in range(len(pre)):
             slot = pre[lane]
             if slot is None or slot.finished:
@@ -1351,10 +1324,7 @@ class ContinuousEngine(MeshEngine):
                 if slot.finished:
                     continue
             finish = None
-            col = chunk[:, lane]
-            if counts is not None:
-                col = col[: int(counts[lane])]
-            for t in col.tolist():
+            for t in chunk[:, lane].tolist():
                 if t in stop_ids:
                     finish = "stop"
                     break
@@ -1365,8 +1335,7 @@ class ContinuousEngine(MeshEngine):
             if slot.dspan is not None:
                 slot.dspan.child("decode_chunk", t0=slot.t_chunk).set(
                     tokens=len(slot.gens), wave=wave,
-                    admit_slices=admit_slices,
-                    kind="verify" if counts is not None else "chunk").end(now)
+                    admit_slices=admit_slices, kind="chunk").end(now)
                 slot.t_chunk = now
                 slot.trace.note(tokens=len(slot.gens))
             if finish is not None:
@@ -1396,57 +1365,6 @@ class ContinuousEngine(MeshEngine):
             read, live = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound)
             self.ring_slots["read"] += read
             self.ring_slots["live"] += live
-
-    def _spec_drafts(self, slots: list) -> "tuple | None":
-        """(drafts (B, D) int32, hit_lanes) — zero rows for lanes with no
-        n-gram hit, no capacity, or no slot (they advance by one true
-        sample).  None when NO lane has a hit: the plain pipelined chunk
-        path is strictly better then (a zero-draft verify emits 1 token
-        per weight pass AND forfeits the one-chunk-deep pipeline)."""
-        D = self._spec_draft
-        drafts = np.zeros((self.batch_size, D), np.int32)
-        hits = []
-        for lane, slot in enumerate(slots):
-            if slot is None or slot.finished:
-                continue
-            # cache capacity: the batched verify writes D+1 K/V slots at
-            # EVERY live lane's pos (zero-draft lanes included).  A lane
-            # past this bound would have its dynamic_update_slice start
-            # clamped, overwriting real earlier cache slots with K/V
-            # RoPE'd for later positions — so one such lane vetoes spec
-            # rounds entirely (the chunk path serves it safely).  +2
-            # margin covers a pending_first lane's un-materialized token.
-            pos = slot.n_prompt + len(slot.gens)
-            if pos + D + 2 >= self.cfg.n_ctx:
-                return None
-            if slot.pending_first:
-                continue
-            if slot.budget - len(slot.gens) <= 1:
-                continue
-            d = Engine._lookup_draft(list(slot.ids) + slot.gens, D)
-            if d is not None:
-                drafts[lane] = d
-                hits.append(lane)
-        return (drafts, hits) if hits else None
-
-    def _spec_round(self, slots: list, got: tuple) -> None:
-        """One batched verify step for every live lane (pipeline already
-        flushed by the caller; ``got`` = the precomputed drafts): dispatch,
-        overlap admissions, then fetch per-lane emitted prefixes.
-        Telemetry mirrors the serial engine's acceptance counters
-        (accepted/drafted is THE pays-or-not number)."""
-        drafts, hits = got
-        pre = list(slots)
-        self._bstate, toks, cnts = batched_spec_verify_perlane_jit(
-            self.params, self.cfg, self._bstate, self._lane_st,
-            jnp.asarray(drafts), top_k=self._max_top_k)
-        self._admit_round(slots)         # overlap admissions with the verify
-        cnts = np.asarray(cnts)
-        self._harvest(pre, np.asarray(toks).T, slots, counts=cnts)
-        self._spec_stats["verify_steps"] += 1
-        self._spec_stats["drafted"] += self._spec_draft * len(hits)
-        self._spec_stats["accepted"] += int(
-            sum(max(0, int(cnts[l]) - 1) for l in hits))
 
     def _loop(self):
         B = self.batch_size
@@ -1481,24 +1399,6 @@ class ContinuousEngine(MeshEngine):
                 # request finished in the previous chunk decodes one extra
                 # chunk before being freed (its rows are discarded), and an
                 # admission lands one chunk later.
-                # ---- speculative rounds (spec_decode="lookup"): when any
-                # live lane's history has an n-gram hit, flush the pipeline
-                # (drafts need current host-side history), then run batched
-                # verify steps — NOT pipelined: the next drafts depend on
-                # this round's accepted tokens, so each verify pays the
-                # dispatch round-trip in exchange for multi-token steps.
-                if self._spec_draft and any(s is not None for s in slots):
-                    got = self._spec_drafts(slots)
-                    if got is not None and pending is not None:
-                        self._harvest(pending[0], np.asarray(pending[1]),
-                                      slots, wave=pending[2],
-                                      admit_slices=pending[3])
-                        pending = None
-                        got = self._spec_drafts(slots)  # histories advanced
-                    while not self._stop and got is not None:
-                        self._spec_round(slots, got)
-                        got = self._spec_drafts(slots)
-
                 tot = self._totals
                 # lfkt.wave in a capture: this pass's device work, from the
                 # chunk's dispatch to the previous chunk's harvest
@@ -1523,7 +1423,6 @@ class ContinuousEngine(MeshEngine):
                                     self._lane_st, n_steps=self.decode_chunk,
                                     top_k=self._max_top_k, live=live)
                             toks = self._take_expert_stats(out)
-                        self._spec_stats["chunk_steps"] += 1
                         # (lanes, tokens, dispatch number, slices queued on
                         # the device ahead of this chunk)
                         dispatched = (pre, toks, wave, self._slices_queued)

@@ -73,11 +73,10 @@ def _ledger_ring_bytes(eng: "Engine") -> int:
 
 
 class _TextEmitter:
-    """Incremental text emission shared by the pipelined (:meth:`Engine._run`)
-    and speculative (:meth:`Engine._run_spec`) decode loops: append-only
-    token list → (ready_text, stop_hit) increments via an incremental UTF-8
-    decoder with stop-string prefix holdback, plus the final flush.
-    Extracted so the two loops cannot drift."""
+    """Incremental text emission of the decode loop (:meth:`Engine._run`):
+    append-only token list → (ready_text, stop_hit) increments via an
+    incremental UTF-8 decoder with stop-string prefix holdback, plus the
+    final flush."""
 
     def __init__(self, engine: "Engine", stops):
         self._eng = engine
@@ -112,9 +111,9 @@ class _TextEmitter:
     def step(self, gen: list, done: bool, finish: str) -> tuple[str, str, bool]:
         """One emission step with the callers' shared hit convention applied:
         returns (ready_text, finish, done) — a stop hit forces
-        ``("", "stop", True)``.  Extracted so the four call sites (the
-        loop tails and the first-token early emits in :meth:`Engine._run`
-        and :meth:`Engine._run_spec`) cannot drift."""
+        ``("", "stop", True)``.  Extracted so the call sites (the loop tail
+        and the first-token early emit in :meth:`Engine._run`) cannot
+        drift."""
         ready, hit = self.process(gen, live=not done)
         if hit:
             return "", "stop", True
@@ -159,13 +158,6 @@ class Engine:
     #: which must be unsharded — engine/sp.py overrides to False.
     _KV_PAGED = True
 
-    #: whether this engine can arm layer-looped decode
-    #: (LFKT_DECODE_LAYER_UNROLL, ops/pallas/decode_loop.py): the
-    #: sp-sharded ring's attention crosses chips per layer, which one
-    #: fused kernel cannot — engine/sp.py overrides to False and the
-    #: knob degrades with attribution.
-    _DECODE_LOOP = True
-
     def __init__(
         self,
         model_path: str | None,
@@ -180,8 +172,6 @@ class Engine:
         attn_impl: str = "auto",  # auto | xla | pallas (prefill flash kernel)
         kv_dtype: str | None = None,  # bf16 | int8 KV cache; None keeps the
         #                               cfg's value (docs/KV_CACHE.md)
-        spec_decode: str = "off",  # off | lookup (prompt-lookup speculation)
-        spec_draft: int = 8,
         prefix_cache: bool = True,  # reuse the previous request's KV prefix
         prefix_min: int = 32,       # shortest common prefix worth reusing
         prefill_chunk: int = 256,   # prefill slice size: the continuous
@@ -195,11 +185,6 @@ class Engine:
         kv_page_tokens: int = 128,  # token slots per pool page
         kv_pool_pages: int = 0,     # pool size in pages (0 = auto)
         kv_spill_pages: int = 0,    # host-RAM spill tier capacity (0 = off)
-        decode_layer_unroll: int | None = None,  # layers fused per decode
-        #                             launch (ops/pallas/decode_loop.py):
-        #                             0 = per-layer chain, -1 = all layers
-        #                             in ONE launch, K = K per launch;
-        #                             None reads LFKT_DECODE_LAYER_UNROLL
         *,
         kv_pool=None,               # adopt a shared KVPool (multi-model
         #                             registry, docs/MULTIMODEL.md) instead
@@ -227,28 +212,10 @@ class Engine:
         #: an error ring for burst detection.  Engines never import the
         #: watchdog — this object is the entire interface.
         self.heartbeat = Heartbeat()
-        if spec_decode not in ("off", "lookup", "auto"):
-            raise ValueError(
-                f"spec_decode must be off|lookup|auto, got {spec_decode!r}")
         # validated BEFORE the weight load: a typo'd LFKT_KV_DTYPE must
         # fail in milliseconds, not after a multi-GB load per crash loop
         if kv_dtype is not None and kv_dtype not in ("bf16", "int8"):
             raise ValueError(f"kv_dtype must be bf16|int8, got {kv_dtype!r}")
-        if spec_decode != "off" and not 1 <= spec_draft < n_ctx - 1:
-            raise ValueError(
-                f"spec_draft must be in [1, n_ctx-2], got {spec_draft}")
-        # "auto" resolves AFTER params load (the decision needs the model's
-        # per-token HBM bytes + a measured dispatch RTT) — engine/spec_auto.py
-        self._spec_request = spec_decode
-        self._spec_draft_request = spec_draft
-        self.spec_auto_decision: dict | None = None
-        self._spec_draft = spec_draft if spec_decode == "lookup" else 0
-        if self._spec_draft and type(self) is not Engine \
-                and not getattr(self, "_SPEC_LANES", False):
-            logger.warning(
-                "spec_decode='lookup' is served by the serial Engine and the "
-                "continuous scheduler; %s serves vanilla decode "
-                "(see _spec_enabled)", type(self).__name__)
         self._lock = threading.Lock()
         self._expert_counters: ExpertCounters | None = None
         # decode attention's read against what it needed (models/llama.py
@@ -377,80 +344,6 @@ class Engine:
                 attn_impl = "xla"
         if attn_impl != self.cfg.attn_impl:
             self.cfg = dataclasses.replace(self.cfg, attn_impl=attn_impl)
-        # -- layer-looped decode (ROADMAP item 2; ops/pallas/decode_loop.py)
-        # Resolve the knob, validate the weight plan, and compile-probe the
-        # looped kernel at THIS engine's ring geometry NOW: every refusal
-        # degrades to the per-layer path with attribution (the degrade
-        # ledger at /debug/compiles) instead of crash-looping warmup, and
-        # warmup then compiles whichever decode program was chosen.
-        if decode_layer_unroll is None:
-            from ..utils.config import knob
-            decode_layer_unroll = int(knob("LFKT_DECODE_LAYER_UNROLL"))
-        decode_layer_unroll = int(decode_layer_unroll)
-        if decode_layer_unroll < -1:
-            raise ValueError(
-                f"decode_layer_unroll must be >= -1 (0 = off, -1 = all "
-                f"layers per launch), got {decode_layer_unroll}")
-        if decode_layer_unroll:
-            from ..obs.devtime import DEVTIME
-            if not self._DECODE_LOOP:
-                msg = (f"{type(self).__name__} serves ring attention "
-                       "(sp-sharded KV): layer-looped decode gates off — "
-                       "serving per-layer decode")
-                logger.warning(msg)
-                DEVTIME.record_degrade("decode_loop", msg)
-                decode_layer_unroll = 0
-        if decode_layer_unroll:
-            from ..models.params import decode_loop_plan
-            from ..ops.pallas.probe import probe_decode_loop
-
-            fmts, reason = decode_loop_plan(self.params, self.cfg)
-            if reason is not None:
-                logger.warning("layer-looped decode unavailable (%s); "
-                               "serving per-layer decode", reason)
-                DEVTIME.record_degrade("decode_loop", reason)
-                decode_layer_unroll = 0
-            else:
-                err = probe_decode_loop(
-                    quantized=self.cfg.kv_dtype == "int8",
-                    int8_weights=fmts["wq"] == "int8",
-                    n_kv=self.cfg.n_kv_heads, head_dim=self.cfg.head_dim,
-                    n_ctx=self.cfg.n_ctx,
-                    sliding_window=self.cfg.sliding_window,
-                    n_heads=self.cfg.n_heads, ffn_dim=self.cfg.ffn_dim)
-                if err is not None:
-                    # pin the per-layer path for THIS kernel geometry,
-                    # process-wide: direct forward() callers must not
-                    # re-arm a lowering that already failed here, while
-                    # a co-resident registry model with a different
-                    # geometry (its own probe verdict) keeps looping
-                    from ..ops.pallas.decode_loop import (
-                        disable_decode_loop,
-                        loop_geometry,
-                    )
-
-                    disable_decode_loop(err, loop_geometry(self.cfg, fmts))
-                    logger.error(
-                        "layer-looped decode kernel failed its compile "
-                        "probe; serving per-layer decode: %s", err)
-                    DEVTIME.record_degrade("decode_loop", err)
-                    decode_layer_unroll = 0
-        if decode_layer_unroll != self.cfg.decode_layer_unroll:
-            self.cfg = dataclasses.replace(
-                self.cfg, decode_layer_unroll=decode_layer_unroll)
-        if self._spec_request == "auto":
-            from .spec_auto import resolve_auto
-
-            mode, self.spec_auto_decision = resolve_auto(self.params)
-            self._spec_draft = (self._spec_draft_request
-                                if mode == "lookup" else 0)
-            logger.info("spec_decode=auto resolved to %r: %s", mode,
-                        self.spec_auto_decision)
-            if self._spec_draft and type(self) is not Engine \
-                    and not getattr(self, "_SPEC_LANES", False):
-                logger.warning(
-                    "spec_decode=auto resolved to lookup, but %s serves "
-                    "vanilla decode (see _spec_enabled)", type(self).__name__)
         self.prefill_buckets = sorted(b for b in prefill_buckets if b <= self.cfg.n_ctx)
         if not self.prefill_buckets or self.prefill_buckets[-1] < self.cfg.n_ctx:
             self.prefill_buckets.append(self.cfg.n_ctx)
@@ -464,14 +357,8 @@ class Engine:
         # and, when the next prompt shares that prefix, prefills only the
         # suffix via prefill_chunk_jit — multi-turn TTFT then scales with
         # the NEW turn's length, not the whole history.  The mesh/SP/lane
-        # engines manage caches differently and keep full prefill, and the
-        # speculative engine keeps it too: verify steps leave rejected
-        # drafts in re-claimable slots, and reuse would break spec's
-        # same-seed determinism contract (a cached and an uncached eval of
-        # the same prompt differ by bf16 KV rounding, so sampled tokens can
-        # diverge — see tests/test_spec_decode.py).
-        self._prefix_cache = (bool(prefix_cache) and type(self) is Engine
-                              and not self._spec_draft)
+        # engines manage caches differently and keep full prefill.
+        self._prefix_cache = bool(prefix_cache) and type(self) is Engine
         self._prefix_min = max(1, int(prefix_min))
         #: token ids whose KV occupy ring slots [0, len) — only ever read
         #: and written under self._lock (the single-generator invariant)
@@ -482,11 +369,8 @@ class Engine:
         # serial single-claim above (and the continuous engine's lane
         # claims) with the process-wide radix index — shared system
         # prompts prefill once per process, multi-turn requests resume
-        # from their last committed page.  Spec decode keeps the same
-        # exclusion as every reuse path (verify rounds leave rejected
-        # drafts in cache slots, and reuse would break spec's same-seed
-        # determinism contract).
-        paged = bool(kv_paged) and not self._spec_draft
+        # from their last committed page.
+        paged = bool(kv_paged)
         if paged and not self._KV_PAGED:
             logger.warning(
                 "LFKT_KV_PAGED=1 requested but %s shards the ring's n_ctx "
@@ -658,11 +542,7 @@ class Engine:
 
     def warmup(self):  # lfkt: blocks-under[_lock] -- warmup compiles and syncs under the engine lock by design: a request must never race a half-warmed cache
         """Compile every (bucket, chunk) shape so no request pays a cold
-        compile — the TPU analogue of the reference's eager model load.
-        With speculation enabled this drives BOTH decode paths: a
-        repeated-word prompt whose n-gram lookup hits (compiles
-        ``spec_verify_jit``) and a unique-word prompt whose lookup misses
-        (compiles the plain chunk fallback)."""
+        compile — the TPU analogue of the reference's eager model load."""
         t0 = time.time()
         msgs = [{"role": "user", "content": "hi hi hi hi hi hi hi hi"}]
         # TWO full decode chunks, not one: on the sharded engines the
@@ -673,10 +553,6 @@ class Engine:
         self.create_chat_completion(msgs,
                                     max_tokens=2 * self.decode_chunk + 1,
                                     temperature=0.0)
-        if self._spec_enabled():
-            self.create_chat_completion(
-                [{"role": "user", "content": "alpha bravo charlie delta"}],
-                max_tokens=2 * self.decode_chunk + 1, temperature=0.0)
         with self._lock:   # uncontended at warmup; the ring-write invariant
             #                (writes to _cache only under _lock) stays intact
             for b in self.prefill_buckets[1:]:
@@ -1278,10 +1154,7 @@ class Engine:
             # ring slots [0, n_prompt + n - 1) now hold prompt + all
             # generated tokens except the last sampled one (its KV write
             # happens only when it is fed — which a finished request never
-            # does); pipelined overshoot writes land past this.  (The spec
-            # path never claims: _prefix_cache is off when _spec_draft > 0,
-            # because verify steps leave rejected drafts in re-claimable
-            # slots.)
+            # does); pipelined overshoot writes land past this.
             keep = ctx["n_prompt"] + max(n - 1, 0)
             self._prefix_ids = (ctx["prompt_ids"] + ctx["ids"])[:keep]
         timings = {
@@ -1298,8 +1171,6 @@ class Engine:
             # first token came out of prefill; the decode phase produced n-1
             "tokens_per_sec": (n - 1) / decode_s if n > 1 and decode_s > 0 else 0.0,
         }
-        if "spec" in ctx:      # speculative decode: acceptance telemetry
-            timings["spec"] = ctx["spec"]
         self._record_timings(timings)
         espan = ctx.get("span")
         if espan is not None:
@@ -1355,157 +1226,22 @@ class Engine:
             return 0
         return max(0, min(self.decode_chunk, self.cfg.n_ctx - pos - 1))
 
-    # -- speculative decoding (prompt-lookup drafts) --------------------
-
-    def _spec_enabled(self) -> bool:
-        """Lookup speculation calls ``spec_verify_jit`` on ``self.params``
-        directly, which is only valid for the plain serial engine — mesh/
-        continuous/sequence-parallel engines hold sharded params and route
-        their device calls differently, so they serve vanilla decode even
-        if constructed with ``spec_decode="lookup"``."""
-        return self._spec_draft > 0 and type(self) is Engine
-
-    @staticmethod
-    def _lookup_draft(history: list, D: int, max_ngram: int = 3):
-        """Prompt-lookup draft: find the most recent earlier occurrence of
-        the last n-gram (n = max_ngram..1) in ``history`` and propose its
-        continuation, zero-padded to exactly ``D`` tokens (static verify
-        shape).  Returns None when no n-gram recurs — the caller falls back
-        to plain decode.  The same heuristic as llama.cpp's lookup-decoding
-        example: free drafts from the prompt's own repetitions (chat
-        history re-sent every turn, code identifiers, quoted spans)."""
-        n_hist = len(history)
-        for n in range(max_ngram, 0, -1):
-            if n_hist < n + 1:
-                continue
-            pat = history[-n:]
-            for j in range(n_hist - n - 1, -1, -1):
-                if history[j:j + n] == pat:
-                    cont = history[j + n:j + n + D]
-                    if cont:
-                        return cont + [0] * (D - len(cont))
-        return None
-
-    def _run_spec(self, ctx, max_tokens, stops):
-        """Speculative variant of :meth:`_run` (LFKT_SPEC_DECODE=lookup).
-
-        Each iteration drafts up to ``spec_draft`` next tokens from n-gram
-        repetition in prompt+generation, verifies them in ONE forward
-        (models/generate.spec_verify_jit) and emits the agreeing prefix +
-        one true sample — so a hit advances several tokens for one weight
-        read, and a miss costs one (wider) decode step.  Greedy output is
-        identical to the vanilla path; sampled output is equal in
-        distribution (same PRNG folds/window/conditioning, logits modulo
-        batched-forward float reordering — see spec_verify_jit).
-
-        NOT pipelined, unlike :meth:`_run`: the draft for step k+1 needs
-        step k's accepted tokens on the host, so dispatch is sequential —
-        speculation trades the overlapped round-trip for multi-token steps.
-        """
-        from ..models.generate import spec_verify_jit
-
-        stop_ids = self.tokenizer.stop_ids
-        budget = self._token_budget(max_tokens, ctx["n_prompt"])
-        gen: list[int] = []
-        em = _TextEmitter(self, stops)
-        finish = "length"
-        first = ctx["first"]
-        if budget <= 0:
-            yield "", True, "length"
-            return
-        if first in stop_ids:
-            yield "", True, "stop"
-            return
-        gen.append(first)
-        history = list(ctx["prompt_ids"]) + gen
-        pos = ctx["n_prompt"]
-        D = self._spec_draft
-        done = len(gen) >= budget
-        # acceptance telemetry → lfkt_timings["spec"] (scraped to /metrics):
-        # accepted/drafted is THE number that says whether speculation pays
-        # on this workload
-        stats = ctx.setdefault(
-            "spec", {"verify_steps": 0, "drafted": 0, "accepted": 0,
-                     "fallback_steps": 0})
-        # First-token early emit, as in _run: don't make the first text
-        # increment wait for the first verify/decode round trip.
-        ready, finish, done = em.step(gen, done, finish)
-        if ready:
-            yield ready, False, finish
-        espan = ctx.get("span")   # None when untraced: the loop below then
-        #                           allocates no span objects and takes no
-        #                           trace locks (tests/test_obs.py pins it)
-        while not done:
-            if self._deadline_hit(ctx):
-                finish = "deadline"
-                break
-            self.heartbeat.beat()
-            FAULTS.fire("decode_step")
-            cspan = espan.child("decode_chunk") if espan is not None else None
-            remaining = budget - len(gen)
-            capacity = self.cfg.n_ctx - pos - 1   # cache slots left to write
-            draft = (self._lookup_draft(history, D)
-                     if remaining > 1 and capacity > D else None)
-            if draft is not None:
-                ctx["state"], toks, cnt = spec_verify_jit(
-                    self.params, self.cfg, ctx["state"], ctx["st"],
-                    jnp.asarray(draft, jnp.int32), top_k=ctx["sp"].top_k)
-                cnt = int(cnt)                    # host sync
-                toks = np.asarray(toks)[:min(cnt, remaining)].tolist()
-                pos += cnt
-                stats["verify_steps"] += 1
-                stats["drafted"] += D
-                stats["accepted"] += cnt - 1      # beyond the always-free one
-            else:
-                n = self._next_steps(len(gen), pos, budget)
-                if n <= 0:
-                    break
-                ctx["state"], t = self._decode_chunk_call(
-                    ctx["state"], ctx["st"], n, ctx["sp"].top_k, pos)
-                toks = np.asarray(t).tolist()[:budget - len(gen)]
-                pos += n
-                stats["fallback_steps"] += 1
-            for t in toks:
-                if t in stop_ids:
-                    finish = "stop"
-                    done = True
-                    break
-                gen.append(t)
-                history.append(t)
-            if not done and len(gen) >= budget:
-                done = True
-            if cspan is not None:
-                cspan.set(tokens=len(gen),
-                          kind="verify" if draft is not None else "chunk")
-                cspan.end()
-                ctx["trace"].note(tokens=len(gen))
-
-            ready, finish, done = em.step(gen, done, finish)
-            if ready:
-                yield ready, False, finish
-
-        ctx["ids"] = gen
-        tail, finish = em.final(gen, finish)
-        yield tail, True, finish
-
     def _run(self, ctx, max_tokens, stops):
         """Generate tokens; yields (new_text, done, finish_reason) increments.
 
         Decode is **pipelined**: chunk k+1 is dispatched to the device before
         chunk k's tokens are fetched to the host, so the host↔device
         round-trip overlaps with compute.
-        If a stop lands mid-chunk the speculative chunk's cache writes are
-        harmless — attention masks by position and every request re-prefills
-        and reseeds the sampler window, so stale slots are never read.
+        If a stop lands mid-chunk, the cache writes of the chunk dispatched
+        ahead are harmless — attention masks by position and every request
+        re-prefills and reseeds the sampler window, so stale slots are never
+        read.
 
         Text increments are produced by an incremental UTF-8 decoder over
         the (append-only) token byte stream, so the streamed concatenation
         is byte-identical to the one-shot decode even when a multi-byte
         character spans a chunk boundary.
         """
-        if self._spec_enabled():
-            yield from self._run_spec(ctx, max_tokens, stops)
-            return
         stop_ids = self.tokenizer.stop_ids
         budget = self._token_budget(max_tokens, ctx["n_prompt"])
         gen: list[int] = []
@@ -1551,7 +1287,7 @@ class Engine:
             cspan = espan.child("decode_chunk") if espan is not None else None
             with phase("decode_chunk", rid=req):   # dispatch + fetch
                 # dispatch the NEXT chunk before touching the host copy of
-                # the current one (speculating that no stop token appears)
+                # the current one (betting that no stop token appears)
                 pos += n_cur
                 n_nxt = self._next_steps(len(gen) + n_cur, pos, budget)
                 nxt = None

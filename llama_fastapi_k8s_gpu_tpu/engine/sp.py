@@ -48,12 +48,6 @@ class SPEngine(Engine):
     #: dense sharded ring; greedy output is identical either way).
     _KV_PAGED = False
 
-    #: layer-looped decode (LFKT_DECODE_LAYER_UNROLL) gates off: each
-    #: layer's decode attention is a cross-chip sharded-LSE collective
-    #: (parallel/ring.py), which a single fused kernel cannot express —
-    #: Engine.__init__ degrades with attribution and serves per-layer.
-    _DECODE_LOOP = False
-
     def __init__(self, model_path: str | None, *, sp: int = 2, tp: int = 1,
                  n_ctx: int = 4096, **kw):
         if sp < 2:

@@ -41,15 +41,6 @@ class ModelConfig:
     # the attention consumers dequantize in-register).  Static so the cache
     # pytree STRUCTURE is fixed at trace time (docs/KV_CACHE.md).
     kv_dtype: str = "bf16"
-    # Layer-looped decode (ops/pallas/decode_loop.py; LFKT_DECODE_LAYER_
-    # UNROLL): layers fused per Pallas launch on the single-token decode
-    # step — 0 = off (the per-layer kernel chain), -1 = all layers in one
-    # launch, K>0 = K layers per launch (clamped to a divisor of
-    # n_layers).  A ModelConfig field rather than a process-lifetime env
-    # read so a jit retrace (and therefore an in-process bench sweep /
-    # A-B) is just ``dataclasses.replace`` — the knob is part of every
-    # compiled program's static signature.
-    decode_layer_unroll: int = 0
     # The feed-forward kind (models/llama.py ``_layer``): ``n_experts == 0``
     # is the dense SwiGLU of width ``ffn_dim``; otherwise a float32 router
     # picks ``n_experts_used`` of ``n_experts`` SwiGLU experts of width

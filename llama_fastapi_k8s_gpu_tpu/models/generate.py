@@ -134,84 +134,3 @@ def generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
 
 generate_chunk_jit = timed_jit("decode_chunk", generate_chunk_jit,
                                site="models.generate")
-
-
-def spec_verify(params, cfg: ModelConfig, state: dict, st: dict,
-                draft, top_k: int = 40):
-    """Speculative-decoding verify step (prompt-lookup drafts, engine.py).
-
-    Feeds ``[state["token"], draft...]`` — D+1 tokens — through ONE forward
-    at positions pos..pos+D (a short prefill continuation: the MXU sees a
-    batched matmul instead of D+1 matvecs, and HBM weight traffic is paid
-    once for up to D+1 tokens), then replays the sampling chain
-    sequentially over the returned logits.  Position ``i``'s sample is
-    *emitted* iff every earlier sample matched its draft token, so the
-    emitted prefix — including the first mismatch, which IS the true
-    sample — is distributed exactly as sequential decoding, for any
-    sampler: each emitted token consumes the same PRNG fold, penalty
-    window, and conditioning as the vanilla path.  (The batched forward's
-    logits differ from the sequential ones only by float reduction order,
-    so greedy outputs are identical — pinned by tests/test_spec_decode.py
-    — and sampled outputs are equal in distribution up to those ULPs, the
-    property every batched-verify speculative decoder shares, llama.cpp's
-    included.)  Rejected positions leave stale K/V in
-    cache slots beyond the new ``pos``; the attention mask is
-    position-based (models/llama.py), so they are never read and get
-    overwritten as decode advances.
-
-    Returns (new_state, tokens (D+1,), count): ``tokens[:count]`` are the
-    emitted tokens (1 ≤ count ≤ D+1); the llama.cpp analogue is the
-    tree-less speculative loop of its lookup-decoding example.
-    """
-    import dataclasses
-
-    D = draft.shape[0]
-    seq = jnp.concatenate([state["token"][None], draft])
-    if cfg.attn_impl == "pallas":
-        # the flash prefill kernel is tuned (and startup-probed) for
-        # bucket-sized S; a D+1-token block would hit it with unaligned
-        # tiny tiles.  The XLA score-matrix path is cheap at S ≈ 9.
-        cfg = dataclasses.replace(cfg, attn_impl="xla")
-    logits, cache = forward(params, cfg, seq, state["pos"], state["cache"],
-                            return_all=True)
-    # pad the draft so position D (no guess to match) never extends `alive`
-    dpad = jnp.concatenate([draft, jnp.int32(-1)[None]])
-
-    def step(carry, xs):
-        lg, d_i = xs
-        nk, sub = jax.random.split(carry["key"])
-        s = sample_chain(lg, carry["window"], sub, st, top_k=top_k)
-        emit = carry["alive"]
-        win2 = carry["window"].at[carry["wpos"] % PENALTY_WINDOW].set(s)
-        new_carry = {
-            "key": jnp.where(emit, nk, carry["key"]),
-            "window": jnp.where(emit, win2, carry["window"]),
-            "wpos": jnp.where(emit, carry["wpos"] + 1, carry["wpos"]),
-            "alive": jnp.logical_and(carry["alive"], s == d_i),
-            "last": jnp.where(emit, s, carry["last"]),
-            "count": carry["count"] + emit.astype(jnp.int32),
-        }
-        return new_carry, s
-
-    init = {
-        "key": state["key"], "window": state["window"], "wpos": state["wpos"],
-        "alive": jnp.bool_(True), "last": state["token"],
-        "count": jnp.int32(0),
-    }
-    fin, toks = jax.lax.scan(step, init, (logits, dpad))
-    new_state = {
-        "cache": cache,
-        "pos": state["pos"] + fin["count"],
-        "token": fin["last"],
-        "window": fin["window"],
-        "wpos": fin["wpos"],
-        "key": fin["key"],
-    }
-    return new_state, toks, fin["count"]
-
-
-spec_verify_jit = timed_jit("spec_verify", functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "top_k"),
-    donate_argnames=("state",),
-)(spec_verify), site="models.generate")

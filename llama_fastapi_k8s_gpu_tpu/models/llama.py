@@ -121,8 +121,8 @@ def cache_nbytes(cfg: ModelConfig) -> int:
 def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
                   out_dtype):
     """The XLA score-matrix attention over a full head-major ring: the
-    small-prompt prefill path and speculation's verify (S > 1; a decode
-    step reads the live part only: :func:`decode_attention`).
+    small-prompt prefill path (S > 1; a decode step reads the live part
+    only: :func:`decode_attention`).
     ``cks``/``cvs`` are the int8 cache's
     per-head per-token scales (None for bf16): scores are linear in K and
     probs·V is linear in V, so both scale sets fold OUTSIDE the int8
@@ -452,41 +452,6 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     return h, cache, None
 
 
-def _loop_unroll(params: dict, cfg: ModelConfig, S: int):
-    """(effective layers-per-launch, weight plan) for this trace — (0,
-    None) selects the per-layer path.  All inputs are trace-time static;
-    every ineligible armed configuration is attributed once (log + the
-    /debug/compiles degrade ledger) via
-    :func:`..ops.pallas.decode_loop.note_degrade` so a pod that silently
-    serves per-layer decode can always explain why."""
-    if not cfg.decode_layer_unroll or S != 1:
-        return 0, None   # off, or a prefill/verify trace: not a decode step
-    from ..ops.pallas.decode_loop import (
-        decode_loop_disabled,
-        effective_unroll,
-        loop_geometry,
-        note_degrade,
-    )
-
-    if cfg.attn_impl == "ring":
-        # sp-sharded rings gate off: the ring collectives cross chips,
-        # which a single fused kernel cannot (docs/RUNBOOK.md)
-        note_degrade("decode_loop",
-                     "attn_impl=ring (sequence-parallel) serves per-layer")
-        return 0, None
-    from .params import decode_loop_plan
-
-    fmts, reason = decode_loop_plan(params, cfg)
-    if reason is not None:
-        note_degrade("decode_loop", reason)
-        return 0, None
-    reason = decode_loop_disabled(loop_geometry(cfg, fmts))
-    if reason is not None:
-        note_degrade("decode_loop", reason)
-        return 0, None
-    return effective_unroll(cfg), fmts
-
-
 def forward(
     params: dict,
     cfg: ModelConfig,
@@ -526,43 +491,29 @@ def forward(
                 f"stacked leaf {name} has {leaf.shape[0]} layers but "
                 f"cfg.n_layers={cfg.n_layers}")
 
-    # Layer-looped decode (ROADMAP item 2; "Kernel Looping", PAPERS.md):
-    # with ``cfg.decode_layer_unroll`` armed, a single-token decode step
-    # runs K layers per Pallas launch instead of the per-layer kernel
-    # chain — O(L/K) launches per step instead of O(L × ops).  Trace-time
-    # selection: S, the config knob, the weight-plan eligibility and the
-    # probe-degrade flag are all static, so the per-layer path below
-    # compiles exactly as before whenever the loop is off or ineligible.
-    K, loop_fmts = _loop_unroll(params, cfg, S)
     routed = [jnp.zeros(expert_stats_len(cfg), jnp.int32),
               jnp.zeros((cfg.n_layers, S, cfg.n_experts_used), jnp.int32)] \
         if cfg.n_experts else []
-    if K:
-        from ..ops.pallas.decode_loop import forward_layers_looped
+    # fori_loop (not scan with cache xs/ys): the stacked cache rides the
+    # carry and each layer writes only its S new token slots in place —
+    # scan's ys-restack rewrites the entire ring every call (~256
+    # MB/token at n_ctx 1024, ~2 GB at 8192 — measured as most of the
+    # 8k decode gap)
+    def body(i, carry):
+        h, cache, out = _layer(
+            carry[0], params["layers"], jnp.int32(i), carry[1],
+            positions, pos_offset, cfg, live, kv_bound)
+        if out is None:
+            return h, cache
+        count, picks = out
+        read = jnp.sum(count > 0, dtype=jnp.int32)
+        stats = carry[2] + jnp.concatenate(
+            [jnp.stack([jnp.int32(1), read]), count])
+        return h, cache, stats, jax.lax.dynamic_update_slice(
+            carry[3], picks[None], (i, 0, 0))
 
-        h, new_cache = forward_layers_looped(
-            params["layers"], cfg, h, pos_offset, cache, K, loop_fmts)
-    else:
-        # fori_loop (not scan with cache xs/ys): the stacked cache rides the
-        # carry and each layer writes only its S new token slots in place —
-        # scan's ys-restack rewrites the entire ring every call (~256
-        # MB/token at n_ctx 1024, ~2 GB at 8192 — measured as most of the
-        # 8k decode gap)
-        def body(i, carry):
-            h, cache, out = _layer(
-                carry[0], params["layers"], jnp.int32(i), carry[1],
-                positions, pos_offset, cfg, live, kv_bound)
-            if out is None:
-                return h, cache
-            count, picks = out
-            read = jnp.sum(count > 0, dtype=jnp.int32)
-            stats = carry[2] + jnp.concatenate(
-                [jnp.stack([jnp.int32(1), read]), count])
-            return h, cache, stats, jax.lax.dynamic_update_slice(
-                carry[3], picks[None], (i, 0, 0))
-
-        h, new_cache, *routed = jax.lax.fori_loop(
-            0, cfg.n_layers, body, (h, cache, *routed))
+    h, new_cache, *routed = jax.lax.fori_loop(
+        0, cfg.n_layers, body, (h, cache, *routed))
 
     out_w = params["output"]
     tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))

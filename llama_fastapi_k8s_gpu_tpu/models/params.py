@@ -281,58 +281,6 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     }
 
 
-#: the stacked linear names a transformer layer serves, in the operand
-#: order the layer-looped decode kernel consumes them
-LOOP_LINEARS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-
-
-def decode_loop_plan(params: dict, cfg: ModelConfig):
-    """Layer-major weight plan for the looped decode kernel
-    (ops/pallas/decode_loop.py): per-linear format tags, or a refusal.
-
-    Returns ``(fmts, None)`` — ``fmts`` maps each :data:`LOOP_LINEARS`
-    name to ``"bf16"`` (a ``{"w"}`` plane) or ``"int8"`` (``{"q","s"}``)
-    — or ``(None, reason)`` when the loaded weights cannot serve the
-    looped kernel.  This is the load-path side of the kernel-looping
-    transform: the in-kernel per-layer BlockSpec indexing needs every
-    plane stacked **layer-major** with one uniform layout per name.
-    bf16/int8 loads already satisfy that (``_stack`` put the layer axis
-    first at load time), so the transform is a structural validation +
-    flattening rather than a byte-moving restack.  The fused K-quant
-    layouts (Q4_K/Q5_K/Q6_K/Q8_0 multi-plane dicts) are exactly the
-    formats that WOULD need a real per-layer restack of their block
-    planes — they refuse here and the caller degrades to the per-layer
-    path with attribution (the chip-session follow-up, docs/PERF.md
-    round 8).
-
-    Trace-time only: a dict-shape walk, no device work — callers run it
-    while jit traces a decode step.
-    """
-    layers = params.get("layers")
-    if not isinstance(layers, dict):
-        return None, "params carry no stacked layer tree"
-    fmts: dict[str, str] = {}
-    for name in LOOP_LINEARS:
-        w = layers.get(name)
-        if not isinstance(w, dict):
-            return None, f"stacked linear {name!r} missing from params"
-        if "w" in w:
-            fmts[name] = "bf16"
-        elif "q" in w and "s" in w:
-            fmts[name] = "int8"
-        else:
-            return None, (
-                f"linear {name!r} is a fused quantized layout "
-                f"(keys {sorted(w)}): the in-kernel fused K-quant matmul "
-                "needs its block planes restacked per layer — serve "
-                "per-layer decode (docs/RUNBOOK.md 'Tuning layer-looped "
-                "decode')")
-    for nm in ("attn_norm", "ffn_norm"):
-        if nm not in layers:
-            return None, f"stacked norm {nm!r} missing from params"
-    return fmts, None
-
-
 def synth_params(cfg: ModelConfig, fmt: str = "bf16", seed: int = 0,
                  scale: float | None = None) -> dict:
     """Random-weight params with the exact structure of :func:`load_params`.

@@ -100,14 +100,7 @@ METRICS: dict[str, Metric] = _register(
     Metric("batch_occupancy", HISTOGRAM,
            "requests coalesced per batched cycle",
            buckets=OCCUPANCY_BUCKETS),
-    # -- speculative decoding / prefix reuse -------------------------------
-    Metric("spec_drafted_tokens_total", COUNTER,
-           "speculative tokens drafted"),
-    Metric("spec_accepted_tokens_total", COUNTER,
-           "speculative tokens accepted"),
-    Metric("spec_verify_steps_total", COUNTER, "speculative verify steps"),
-    Metric("spec_fallback_steps_total", COUNTER,
-           "plain decode steps taken on lookup miss"),
+    # -- prefix reuse ------------------------------------------------------
     Metric("prefix_cache_hits_total", COUNTER,
            "requests served with prompt-prefix KV reuse"),
     Metric("prefix_cache_reused_tokens_total", COUNTER,
@@ -341,7 +334,7 @@ METRICS: dict[str, Metric] = _register(
            "harvest): waves, wave_seconds, lane_live_seconds + "
            "lane_idle_seconds (= batch_size x wave_seconds), "
            "fetch_wait_seconds, admit_seconds, admit_slices, admit_tokens, "
-           "harvest_seconds, chunks_dispatched, spec_*, lane_prefix_* / "
+           "harvest_seconds, chunks_dispatched, lane_prefix_* / "
            "radix_prefix_*",
            prefix=True),
 )

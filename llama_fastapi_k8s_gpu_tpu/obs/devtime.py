@@ -246,8 +246,8 @@ class DevtimeRegistry:
                                    "count": 1, "at": time.time()}
 
     def degrades(self) -> list[dict]:
-        """The degrade ledger (insertion order) — /debug/compiles and the
-        decode-loop tests read it."""
+        """The degrade ledger (insertion order), as /debug/compiles
+        shows it."""
         with self._lock:
             return [dict(v) for v in self._degrades.values()]
 

@@ -12,17 +12,18 @@ Mosaic programs XLA cannot fuse away; elementwise ops fuse into their
 consumers and are not launches) weighted by the runtime trip count of
 every enclosing ``scan`` (``fori_loop`` over layers lowers to one).
 
-That turns the kernel-looping claim (ISSUE 12 / ROADMAP item 2) into a
-CPU-pinnable fact: the per-layer path traces L × chain launch primitives
-inside its layer loop, the looped path ceil(L/K) ``pallas_call``s — the
-launch-count collapse is proven in tier-1 (tests/test_perf_pins.py)
+That makes the step's launch count a CPU-pinnable fact: the layer loop
+traces L × chain launch primitives, and a new dot on the decode path (or
+a lost loop) changes the integer in tier-1 (tests/test_perf_pins.py)
 without a chip.
 
 Caveats, stated rather than hidden: a ``while`` body's trip count is not
 static — its launches are counted ONCE and the audit marks
 ``while_loops`` so a reader knows the total is a floor; branch
-(``cond``) arms are counted at the maximum over arms.  Neither occurs in
-the decode step today.
+(``cond``) arms are counted at the maximum over arms.  The decode step
+has one ``while``: the decode attention's block loop over the live part
+of the ring (models/llama.py ``decode_attention``), whose two
+contractions are therefore counted once per layer.
 """
 
 from __future__ import annotations
@@ -100,7 +101,7 @@ def count_launches(fn, *args) -> dict:
          "loop_trips": scan trip counts encountered (outermost first),
          "by_prim":    {primitive: weighted count},
          "while_loops": bodies counted once because their trip count is
-                        not static (0 for the decode step)}
+                        not static (1 for the decode step)}
     """
     import jax
 
@@ -114,9 +115,7 @@ def count_launches(fn, *args) -> dict:
 def decode_step_launches(params, cfg) -> dict:
     """Launch audit of ONE single-token decode step under ``cfg`` —
     :func:`models.llama.decode_step` traced at shape level (no device
-    work, no allocation of a real ring).  The number the kernel-looping
-    pins compare: per-layer ``cfg`` vs ``dataclasses.replace(cfg,
-    decode_layer_unroll=K)``."""
+    work, no allocation of a real ring)."""
     import jax
     import jax.numpy as jnp
 

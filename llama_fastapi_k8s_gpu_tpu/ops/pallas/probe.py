@@ -21,7 +21,7 @@ import numpy as np
 
 __all__ = ["probe_fused_q4k", "probe_fused_q5k", "probe_fused_q6k",
            "probe_fused_q8", "probe_fused_experts", "probe_flash_attention",
-           "probe_kv_quant", "probe_decode_loop"]
+           "probe_kv_quant"]
 
 
 def _err(e: BaseException) -> str:
@@ -221,86 +221,6 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
             float(y.astype(jnp.float32).sum())
         return None
     except Exception as e:  # noqa: BLE001
-        return _err(e)
-
-
-@functools.lru_cache(maxsize=8)
-def probe_decode_loop(quantized: bool = False, int8_weights: bool = False,
-                      n_kv: int = 2, head_dim: int = 64,
-                      n_ctx: int = 128, sliding_window: int = 0,
-                      n_heads: int | None = None,
-                      ffn_dim: int | None = None) -> str | None:
-    """Compile + run the layer-looped decode kernel
-    (ops/pallas/decode_loop.py) at the ENGINE'S full geometry.
-
-    Unlike the matmul probes (tiny shapes: only tile-dependent Mosaic
-    regressions vary with size), the looped kernel's VMEM residency
-    scales with the serving shape — one layer's WHOLE weight set
-    (``dim``/``ffn_dim`` planes) plus its full ``(n_kv, n_ctx, hd)``
-    ring block live in VMEM per grid step — so a smaller-than-serving
-    probe would pass while warmup's real program fails.  The engine
-    therefore threads every residency-bearing dimension
-    (``decode_loop.loop_geometry``); only ``n_layers`` is synthetic
-    (2: the layer count changes the grid, never the per-step shape).
-
-    Beyond compiling, the probe verifies the partial-grid aliasing
-    contract the kernel leans on: with a 2-layer stack launched one
-    layer at a time (``unroll=1, layer0=1``), layer 0's ring bytes must
-    ride the input/output alias untouched.  A backend where unwritten
-    aliased blocks do not retain input bytes corrupts every layer
-    outside the launch window — that must degrade the pod, not corrupt
-    decode."""
-    try:
-        import dataclasses
-
-        import jax
-        import jax.numpy as jnp
-
-        from . import use_interpret
-        from ...models.config import ModelConfig
-        from ...models.llama import init_cache
-        from ...models.params import decode_loop_plan, synth_params
-        from .decode_loop import decode_loop_step
-
-        if n_heads is None:
-            n_heads = 2 * n_kv
-        dim = n_heads * head_dim
-        cfg = ModelConfig(
-            vocab_size=64, dim=dim, n_layers=2, n_heads=n_heads,
-            n_kv_heads=n_kv, ffn_dim=ffn_dim or 2 * dim, n_ctx=n_ctx,
-            sliding_window=sliding_window,
-            kv_dtype="int8" if quantized else "bf16",
-            decode_layer_unroll=1)
-        params = synth_params(cfg, fmt="int8" if int8_weights else "bf16")
-        fmts, reason = decode_loop_plan(params, cfg)
-        if reason is not None:
-            return reason
-        cache = init_cache(cfg)
-        # plant a sentinel in layer 0's ring so retention is checkable
-        leaf = "k_q" if quantized else "k"
-        sentinel = jnp.ones_like(cache[leaf][0, :, :1])
-        cache[leaf] = cache[leaf].at[0, :, :1].set(sentinel)
-        h = jnp.ones((1, cfg.dim), jnp.bfloat16)
-        itp = use_interpret()
-        # eager pallas_call (no enclosing jit): the kernel is trace-inner
-        # in serving, and the probe wants exactly its Mosaic lowering
-        h2, cache2 = decode_loop_step(
-            params["layers"], cache, h, jnp.int32(3), jnp.int32(1),
-            cfg, fmts, unroll=1, interpret=itp)
-        h2.block_until_ready()
-        kept = jax.device_get(cache2[leaf][0, :, :1])
-        if not (kept == jax.device_get(sentinel)).all():
-            return ("aliased cache layers outside the launch window did "
-                    "not retain their bytes — in-place layer-loop update "
-                    "unsupported on this backend")
-        # the grouped launch (unroll=2) is a different grid/program shape
-        h3, _ = decode_loop_step(
-            params["layers"], cache2, h, jnp.int32(4), jnp.int32(0),
-            dataclasses.replace(cfg, decode_layer_unroll=2), fmts,
-            unroll=2, interpret=itp)
-        float(h3.astype(jnp.float32).sum())
-        return None
-    except Exception as e:  # noqa: BLE001 — any failure means "don't use it"
         return _err(e)
 
 

@@ -162,41 +162,3 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
 batched_generate_chunk_perlane_jit = timed_jit(
     "lane_decode_chunk", batched_generate_chunk_perlane_jit,
     site="parallel.batched")
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "top_k"),
-    donate_argnames=("state",),
-)
-def batched_spec_verify_perlane_jit(params, cfg: ModelConfig, state: dict,
-                                    lane_st: dict, drafts, top_k: int = 40):
-    """Per-lane speculative verify: ``drafts`` (B, D) int32, one prompt-
-    lookup draft per lane (zeros for lanes with no n-gram hit — they still
-    advance by their one true sample).  ONE batched forward verifies every
-    lane's draft, so the weight read is amortized over B lanes × up to D+1
-    tokens.  Returns (state, toks (B, D+1), counts (B,)): lane ``l`` emits
-    ``toks[l, :counts[l]]``.  Per-lane sampler replay is exactly
-    models/generate.spec_verify vmapped — distributionally identical to
-    sequential decoding per lane."""
-    from ..models.generate import spec_verify
-
-    def single(token, pos, cache, window, wpos, key, st, draft):
-        s = {"token": token, "pos": pos, "cache": cache,
-             "window": window, "wpos": wpos, "key": key}
-        ns, toks, cnt = spec_verify(params, cfg, s, st, draft, top_k=top_k)
-        return (ns["token"], ns["pos"], ns["cache"], ns["window"],
-                ns["wpos"], ns["key"], toks, cnt)
-
-    tok, pos, cache, window, wpos, key, toks, cnt = jax.vmap(single)(
-        state["token"], state["pos"], state["cache"],
-        state["window"], state["wpos"], state["key"], lane_st, drafts,
-    )
-    new_state = {"cache": cache, "pos": pos, "token": tok,
-                 "window": window, "wpos": wpos, "key": key}
-    return new_state, toks, cnt
-
-
-batched_spec_verify_perlane_jit = timed_jit(
-    "lane_spec_verify", batched_spec_verify_perlane_jit,
-    site="parallel.batched")

@@ -340,12 +340,6 @@ def create_app(engine=None, settings: Settings | None = None,
             if timings["tokens_per_sec"]:
                 m.observe("engine_decode_tokens_per_sec",
                           timings["tokens_per_sec"], model=model)
-            spec = timings.get("spec")
-            if spec:   # speculative decode: acceptance is THE payoff number
-                m.inc("spec_drafted_tokens_total", spec["drafted"])
-                m.inc("spec_accepted_tokens_total", spec["accepted"])
-                m.inc("spec_verify_steps_total", spec["verify_steps"])
-                m.inc("spec_fallback_steps_total", spec["fallback_steps"])
             reused = timings.get("prefix_reused_tokens", 0)
             if reused:  # prompt-prefix KV reuse: prompt tokens NOT re-prefilled
                 m.inc("prefix_cache_hits_total")
@@ -1462,11 +1456,6 @@ def create_app(engine=None, settings: Settings | None = None,
                 # capacity win, verifiable per pod (docs/KV_CACHE.md)
                 "kv_dtype": getattr(cfg, "kv_dtype", None),
                 "kv_cache_bytes": getattr(eng, "kv_cache_bytes", None),
-                # layer-looped decode (ops/pallas/decode_loop.py): the
-                # EFFECTIVE layers-per-launch this pod serves (-1/K are
-                # clamped to the real divisor; 0 after any degrade, with
-                # the reason in /debug/compiles)
-                "decode_layer_unroll": _effective_unroll(cfg),
                 # how the weights got here: per-phase load/warm-up seconds
                 # and the native packer library (None = numpy codecs)
                 "load_phases": getattr(eng, "load_phases", None),
@@ -1490,10 +1479,6 @@ def create_app(engine=None, settings: Settings | None = None,
                 engine_info["models"] = models_fn()
                 engine_info["default_model"] = getattr(
                     eng, "default_model", None)
-            # spec_decode="auto": the measured-RTT decision and its inputs
-            # (engine/spec_auto.py) — operators verify the resolution here
-            if getattr(eng, "spec_auto_decision", None) is not None:
-                engine_info["spec_auto"] = eng.spec_auto_decision
         doc = {
             "status": "ok",
             "state": st.health.state,
@@ -1562,7 +1547,7 @@ def create_app(engine=None, settings: Settings | None = None,
         if stats is not None:
             snap = stats()
             for k, v in snap.items():
-                if isinstance(v, dict):   # nested stats (e.g. spec): flatten
+                if isinstance(v, dict):   # nested stats: flatten
                     for kk, vv in v.items():  # — a dict-valued gauge renders
                         m.set_gauge(f"scheduler_{k}_{kk}", vv)  # invalid lines
                 else:
@@ -1873,20 +1858,6 @@ def _device_info() -> dict:
             "peak_bytes_in_use": max(peaks) if peaks else None}
 
 
-def _effective_unroll(cfg):
-    """The decode layers-per-launch ``cfg`` actually serves — the
-    ``-1`` / nearest-divisor clamp applied (ops/pallas/decode_loop.py)
-    — or None for engines whose config predates the field (fakes)."""
-    if getattr(cfg, "decode_layer_unroll", None) is None:
-        return None
-    from ..ops.pallas.decode_loop import effective_unroll
-
-    try:
-        return effective_unroll(cfg)
-    except (AttributeError, TypeError, ValueError):
-        return 0
-
-
 def _base_engine_kwargs(settings: Settings) -> dict:
     """Engine-constructor kwargs shared by the single-model factory and
     every registry entry (which then applies its manifest overrides)."""
@@ -1898,9 +1869,6 @@ def _base_engine_kwargs(settings: Settings) -> dict:
         max_gen_tokens=settings.max_gen_tokens,
         attn_impl=settings.attn_impl,
         kv_dtype=settings.kv_dtype,
-        decode_layer_unroll=settings.decode_layer_unroll,
-        spec_decode=settings.spec_decode,
-        spec_draft=settings.spec_draft,
         prefix_cache=settings.prefix_cache,
         prefill_chunk=settings.prefill_chunk,
         prefill_overlap=settings.prefill_overlap,

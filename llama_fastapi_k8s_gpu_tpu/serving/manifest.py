@@ -39,13 +39,8 @@ OVERRIDE_KEYS: dict[str, type] = {
     "weight_format": str,
     "kv_dtype": str,
     "attn_impl": str,
-    # per-model layer-looping: co-resident models differ in depth/ring
-    # geometry, so one may loop while another's probe degrades it
-    "decode_layer_unroll": int,
     "decode_chunk": int,
     "max_gen_tokens": int,
-    "spec_decode": str,
-    "spec_draft": int,
 }
 
 _NAME_RE = re.compile(r"^[A-Za-z0-9._-]+$")

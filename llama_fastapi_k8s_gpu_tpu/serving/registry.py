@@ -680,7 +680,7 @@ class ModelRegistry:
             budget += stats.get("adm_budget_tokens", 0)
             idle += stats.get("lane_idle_seconds", 0.0)
             for k, v in stats.items():
-                if isinstance(v, dict):        # nested (spec): one level
+                if isinstance(v, dict):        # nested: one level
                     for kk, vv in v.items():
                         out[f"{name}_{k}_{kk}"] = vv
                 else:

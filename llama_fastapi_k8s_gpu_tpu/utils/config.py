@@ -99,14 +99,6 @@ class Settings:
 
     # TPU-native knobs (no reference equivalent).
     max_gen_tokens: int = 512
-    # layer-looped decode (ops/pallas/decode_loop.py; ROADMAP item 2):
-    # transformer layers fused per Pallas launch on the single-token
-    # decode step.  0 = off (the per-layer kernel chain), -1 = ALL layers
-    # in one launch, K > 0 = K layers per launch (clamped to a divisor of
-    # n_layers).  Engines compile-probe the looped kernel at their ring
-    # geometry and degrade to per-layer decode with attribution on any
-    # refusal (docs/RUNBOOK.md "Tuning layer-looped decode").
-    decode_layer_unroll: int = 0
     decode_chunk: int = 8           # device-side tokens per host round-trip.
     # Measured trade-off (docs/bench 2026-07-30): single-stream decode
     # rises mildly with chunk size (+~1% at 1k ctx, +4.7% at 8k for 32 vs
@@ -122,13 +114,6 @@ class Settings:
     #                                 (values int8 + per-head per-token f32
     #                                 scales) and streams int8 through the
     #                                 attention reads; docs/KV_CACHE.md
-    spec_decode: str = "off"        # off | lookup | auto — prompt-lookup
-    #                                 speculation; "auto" measures the
-    #                                 deployment's dispatch RTT at startup
-    #                                 and enables lookup iff its breakeven
-    #                                 acceptance < LFKT_SPEC_AUTO_ACCEPT
-    #                                 (engine/spec_auto.py)
-    spec_draft: int = 8             # draft tokens per verify step
     # serial-engine prompt-prefix KV reuse (llama.cpp's prompt-cache
     # analogue): when consecutive prompts share a token prefix — the
     # reference workload re-sends persona + full history every turn —
@@ -139,7 +124,7 @@ class Settings:
     # prefill only the suffix slices (chunk-aligned).  ON by default since
     # the admission controller closed the admission/decode interference
     # gap (round 6); explicit-seed requests still bypass it (the
-    # reproducibility contract) and spec decode still excludes it.
+    # reproducibility contract).
     lane_prefix_cache: bool = True
     # block-paged KV pool + shared radix-tree prefix cache
     # (parallel/kvpool.py; docs/RUNBOOK.md "Sizing the KV page pool"):
@@ -369,16 +354,11 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_REPEAT_PENALTY", float, "repetition penalty"),
     # -- TPU-native engine knobs -------------------------------------------
     Knob("LFKT_MAX_GEN_TOKENS", int, "default completion budget"),
-    Knob("LFKT_DECODE_LAYER_UNROLL", int,
-         "layers fused per decode-step Pallas launch (0 = per-layer, "
-         "-1 = all layers; ops/pallas/decode_loop.py)", serving=True),
     Knob("LFKT_DECODE_CHUNK", int, "device tokens per host round-trip"),
     Knob("LFKT_PREFILL_BUCKETS", str, "padded prompt shapes (csv)"),
     Knob("LFKT_WEIGHT_FORMAT", str, "auto|bf16|int8|q4k"),
     Knob("LFKT_ATTN_IMPL", str, "auto|xla|pallas"),
     Knob("LFKT_KV_DTYPE", str, "bf16|int8 KV cache (docs/KV_CACHE.md)"),
-    Knob("LFKT_SPEC_DECODE", str, "off|lookup|auto speculation"),
-    Knob("LFKT_SPEC_DRAFT", int, "draft tokens per verify step"),
     Knob("LFKT_PREFIX_CACHE", bool, "serial-engine prompt-prefix KV reuse"),
     Knob("LFKT_LANE_PREFIX_CACHE", bool, "lane-claim admission KV reuse"),
     Knob("LFKT_KV_PAGED", bool,
@@ -539,11 +519,6 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_LOAD_OVERLAP", bool,
          "overlap per-layer host→device transfer with dequant",
          default=True),
-    Knob("LFKT_HBM_GBPS", float,
-         "assumed HBM bandwidth for spec_decode=auto breakeven",
-         default=819.0),
-    Knob("LFKT_SPEC_AUTO_ACCEPT", float,
-         "assumed lookup acceptance for spec_decode=auto", default=1.0),
     Knob("LFKT_FAULTS", str,
          "fault-injection arming spec (utils/faults.py; drills only)",
          default=""),

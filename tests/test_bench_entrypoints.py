@@ -69,36 +69,6 @@ def test_bench_ttft_sweep_tiny_smoke():
         assert len(p["samples_ms"]) == 5
 
 
-def test_bench_decode_unroll_sweep_tiny_smoke():
-    """--decode-unroll-sweep (ISSUE 12): one JSON line per K, each with
-    the per-step launch audit stamped on — the banked artifact carries
-    its own proof of the launch-count collapse."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", LFKT_BENCH_PRESET="tiny",
-               LFKT_BENCH_UNROLL_SWEEP="1")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--decode-unroll-sweep"],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [json.loads(ln) for ln in out.stdout.splitlines() if ln.strip()]
-    points = [p for p in lines if "decode-unroll" in p.get("metric", "")]
-    assert len(points) == 3, out.stdout      # tiny grid: K in {0, 2, -1}
-    assert [p["decode_layer_unroll"] for p in points] == [0, 2, -1]
-    per_layer, k2, kall = points
-    assert per_layer["launches_per_step"] == 2 * 9 + 1   # L=2 × chain + head
-    # the collapse, visible in the artifact itself: one launch per group
-    # (+ the output head), for both the K=2 and whole-stack points
-    assert k2["launches_per_step"] == 2
-    assert kall["launches_per_step"] == 2
-    assert kall["effective_unroll"] == 2                 # -1 → L
-    for p in points:
-        assert p["value"] > 0 and p["unit"] == "ms"
-        assert p["tokens_per_sec"] > 0
-        assert len(p["samples_tok_s"]) == 3
-        # the tiny preset serves int8 weights (fused layouts gate off)
-        assert ",int8," in p["metric"]
-
-
 def test_bench_multiturn_replay_tiny_smoke():
     """--multiturn-replay (LFKT_BENCH_REPLAY=1): the paged radix-cache
     replay must emit one valid JSON line whose hit ratio is REAL (> 0) —

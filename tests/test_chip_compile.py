@@ -13,7 +13,6 @@ it lives in this ONE file.  A compile that passes is not a chip run."""
 
 from __future__ import annotations
 
-import dataclasses
 import os
 
 import jax
@@ -291,42 +290,6 @@ def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
     assert not found, found[:3]
     one_layer_ring = max(lanes, 1) * KV * 4096 * 128 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_ring
-
-
-def test_decode_loop_at_8b_geometry_is_refused(one_chip):
-    """``ops/pallas/decode_loop.py`` (off by default) does not compile for
-    the chip at the 8B geometry: the lowering refuses the (1, N) block of
-    its 2-D per-layer scale planes (not (8, 128)-aligned), and behind that
-    stands one layer's whole int8 weight set, ~218 MB, against 128 MiB of
-    VMEM.  Pinned as found and not repaired here — the engine degrades to
-    per-layer decode when the knob asks for the loop (engine/engine.py
-    probe).  If this starts to compile, the kernel was re-tiled: flip the
-    assertion."""
-    from llama_fastapi_k8s_gpu_tpu.models.config import LLAMA3_8B
-    from llama_fastapi_k8s_gpu_tpu.models.params import LOOP_LINEARS
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.decode_loop import decode_loop_step
-
-    cfg = dataclasses.replace(LLAMA3_8B, n_layers=2, decode_layer_unroll=1)
-    kv = cfg.n_kv_heads * cfg.head_dim
-    dims = {"wq": (cfg.dim, cfg.dim), "wk": (kv, cfg.dim), "wv": (kv, cfg.dim),
-            "wo": (cfg.dim, cfg.dim), "w_gate": (cfg.ffn_dim, cfg.dim),
-            "w_up": (cfg.ffn_dim, cfg.dim), "w_down": (cfg.dim, cfg.ffn_dim)}
-    layers = {nm: {"q": S(2, *dims[nm], dtype=i8), "s": S(2, dims[nm][0], dtype=f32)}
-              for nm in LOOP_LINEARS}
-    layers["attn_norm"] = S(2, cfg.dim, dtype=f32)
-    layers["ffn_norm"] = S(2, cfg.dim, dtype=f32)
-    cache = {"k": S(2, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim),
-             "v": S(2, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)}
-    fmts = dict.fromkeys(LOOP_LINEARS, "int8")
-
-    def fn(layers, cache, h, pos, layer0):
-        return decode_loop_step(layers, cache, h, pos, layer0, cfg, fmts,
-                                unroll=1, interpret=False)
-
-    with pytest.raises(Exception) as refusal:
-        _compile(one_chip, fn, layers, cache, S(1, cfg.dim), S(dtype=i32),
-                 S(dtype=i32))
-    print(f"decode_loop at 8B refused: {str(refusal.value)[:300]}")
 
 
 def _placed_on_four(topo):

@@ -713,21 +713,6 @@ def test_lane_prefix_cache_defaults_on(tmp_path):
         eng.shutdown()
 
 
-def test_lane_prefix_spec_decode_still_excluded(tmp_path):
-    """The default flip must not arm reuse under spec decode (verify
-    rounds leave rejected drafts in lanes — the documented exclusion)."""
-    path = str(tmp_path / "tiny-lp-spec.gguf")
-    write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
-                           decode_chunk=4, max_gen_tokens=16,
-                           prefill_buckets=(32, 64, 128),
-                           spec_decode="lookup", spec_draft=4)
-    try:
-        assert eng._lane_prefix is False
-    finally:
-        eng.shutdown()
-
-
 def test_scratch_none_recovers(cengine):
     """A failed lane snapshot leaves _scratch_cache = None (the reuse path
     frees the old scratch BEFORE the copy so HBM never holds two rings —

@@ -1,8 +1,9 @@
 """A lane's decode step does not depend on what the other lanes hold, and
 the bounded read of the ring (models/llama.py ``decode_attention``) stays
-the model: logits against the whole-ring read it replaced, and prefill +
-64 decode steps through the cache against the benchmark's plain float32
-references (``benchmarks/reference.py``, ``reference_routed.py``).
+the model: logits against the whole-ring read it replaced, and the routed
+block's prefill + 64 decode steps through the cache against the benchmark's
+plain float32 reference (``benchmarks/reference_routed.py``; the dense
+block's matrix is tests/test_dense_reference.py).
 
 Why the independence is a property and not luck: lanes ``vmap``ped over
 one step share the loop's trip count (``parallel/batched.py live_bound``:
@@ -20,6 +21,7 @@ so that a ring of 128 slots holds eight blocks.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 import types
@@ -221,33 +223,15 @@ def test_an_all_masked_block_leaves_the_state_bit_for_bit():
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def bench():
+def reference_routed():
     """``benchmarks/`` is not a package: its files import each other by
     bare name."""
     sys.path.insert(0, BENCH)
     try:
-        import reference
         import reference_routed
-        yield types.SimpleNamespace(dense=reference, routed=reference_routed)
+        yield reference_routed
     finally:
         sys.path.remove(BENCH)
-
-
-@pytest.fixture(scope="module")
-def dense(tmp_path_factory, bench):
-    """(params, cfg, reference logits of a sequence) on one tiny GGUF file:
-    2 layers, 4 / 2 heads (GQA), n_ctx 128."""
-    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
-    from llama_fastapi_k8s_gpu_tpu.models.params import load_params
-    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
-
-    path = str(tmp_path_factory.mktemp("dense") / "tiny.gguf")
-    write_tiny_llama_gguf(path, seed=5)
-    gf = GGUFFile(path)
-    cfg = ModelConfig.from_gguf(gf, n_ctx=N_CTX)
-    hp, w = bench.dense.load_weights(path)
-    return (load_params(gf, cfg, fmt="bf16"), cfg,
-            lambda tokens: np.asarray(bench.dense.forward(hp, w, tokens)))
 
 
 def _prefill(params, cfg, tokens, n, **kw):
@@ -258,68 +242,14 @@ def _prefill(params, cfg, tokens, n, **kw):
                          **kw)
 
 
-def test_serial_prefill_then_64_decode_steps_agree_with_the_reference(dense):
-    """The serial engine's two programs: a padded bucket prefill of 20
-    tokens, then positions 20..83 one at a time against the ring, across
-    the block edges at 32, 48, 64 and 80."""
-    params, cfg, reference = dense
-    tokens = np.random.default_rng(1).integers(0, 256, size=84)
-    want = reference(tokens)
-    logits, cache = _prefill(params, cfg, tokens, 20)
-    assert rel(logits, want[19]) < REFERENCE
-    step = jax.jit(lambda t, p, c: llama.decode_step(params, cfg, t, p, c))
-    for pos in range(20, 84):
-        logits, cache = step(jnp.int32(tokens[pos]), jnp.int32(pos), cache)
-        assert rel(logits, want[pos]) < REFERENCE, pos
-
-
-def test_lanes_of_different_lengths_join_and_leave_over_64_steps(dense):
-    """The lane engine's step over three lanes for 64 steps.  Lane 0 (from
-    position 10) leaves after step 40 and, as a freed lane does, keeps
-    stepping with its position walking on; lane 1 (from 30) stays; lane 2
-    holds a dead request's stale ring at position 100 until a new request
-    of 5 tokens joins it at step 8.  Every live lane's logits are the
-    reference's at its own position, at every step."""
-    params, cfg, reference = dense
-    rng = np.random.default_rng(9)
-    starts, steps = (10, 30, 5), 64
-    leave_0, join_2 = 40, 8
-    seqs = [rng.integers(0, 256, size=s + steps) for s in starts]
-    fresh = [_prefill(params, cfg, s, n)[1] for s, n in zip(seqs, starts)]
-    stale = _random_cache(cfg, seed=99)
-    caches = jax.tree.map(lambda *a: jnp.stack(a), fresh[0], fresh[1], stale)
-    step = _lane_step(params, cfg)
-
-    pos = [starts[0], starts[1], 100]
-    got = {lane: [] for lane in range(3)}
-    for t in range(steps):
-        if t == join_2:             # the admission's lane write
-            caches = jax.tree.map(lambda a, b: a.at[2].set(b), caches,
-                                  fresh[2])
-            pos[2] = starts[2]
-        live = np.array([t <= leave_0, True, t >= join_2])
-        toks = [int(s[min(p, len(s) - 1)]) if lv else 1
-                for s, p, lv in zip(seqs, pos, live)]
-        logits, caches = step(jnp.asarray(toks, jnp.int32),
-                              jnp.asarray(pos, jnp.int32), caches,
-                              jnp.asarray(live))
-        for lane in range(3):
-            if live[lane]:
-                got[lane].append(np.asarray(logits[lane]))
-            pos[lane] += 1          # every lane steps, live or not
-    assert [len(got[lane]) for lane in range(3)] == [41, 64, 56]
-    for lane, n in enumerate(starts):
-        want = reference(seqs[lane])[n:n + len(got[lane])]
-        for t, (a, b) in enumerate(zip(got[lane], want)):
-            assert rel(a, b) < REFERENCE, (lane, t)
-
-
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_routed_prefill_then_64_decode_steps_agree_with_the_reference(
-        tmp_path, bench):
+        tmp_path, reference_routed, kv):
     """The routed block (tiny ``olmoe`` file: 16 / 16 heads' kind, MHA,
-    QK-norm, rotate-half) through the same read, against
-    ``reference_routed.py`` sent the program's own picks (near-ties in a
-    random router are not the subject here: tests/test_olmoe.py)."""
+    QK-norm, rotate-half) through the same read, on a bf16 and on an int8
+    ring, against ``reference_routed.py`` sent the program's own picks
+    (near-ties in a random router are not the subject here:
+    tests/test_olmoe.py)."""
     from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
     from llama_fastapi_k8s_gpu_tpu.models.params import load_params
     from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_olmoe_gguf
@@ -327,7 +257,8 @@ def test_routed_prefill_then_64_decode_steps_agree_with_the_reference(
     path = str(tmp_path / "tiny.gguf")
     write_tiny_olmoe_gguf(path, seed=3)
     gf = GGUFFile(path)
-    cfg = ModelConfig.from_gguf(gf, n_ctx=N_CTX)
+    cfg = dataclasses.replace(ModelConfig.from_gguf(gf, n_ctx=N_CTX),
+                              kv_dtype=kv)
     params = load_params(gf, cfg, fmt="bf16")
     tokens = np.random.default_rng(5).integers(0, 256, size=84)
     logits, cache, pk = _prefill(params, cfg, tokens, 20, with_picks=True)
@@ -338,13 +269,14 @@ def test_routed_prefill_then_64_decode_steps_agree_with_the_reference(
         logits, cache, pk = step(jnp.int32(tokens[pos]), jnp.int32(pos), cache)
         got.append(np.asarray(logits))
         picks.append(np.asarray(pk))
-    hp, tensors = bench.routed.open_model(path)
-    want = np.asarray(bench.routed.forward(
+    hp, tensors = reference_routed.open_model(path)
+    want = np.asarray(reference_routed.forward(
         hp, tensors, tokens,
         use_picks=list(np.concatenate(picks, 1)))[0])
     # the limit as tests/test_olmoe.py reads it, over the stacked positions
-    # (1.7 % here, and 1.7 % with the whole-ring read); one position alone
-    # of this tiny routed model reaches 3.6 % (the whole-ring read: 4.6 %)
+    # (1.7 % here, and 1.7 % with the whole-ring read; 2.5 % on the int8
+    # ring); one position alone of this tiny routed model reaches 3.6 %
+    # (the whole-ring read: 4.6 %; the int8 ring: 5.6 %)
     assert rel(np.stack(got), want[20:]) < REFERENCE
     for pos, row in zip(range(20, 84), got):
         assert rel(row, want[pos]) < 2 * REFERENCE, pos

@@ -202,7 +202,7 @@ def test_package_entry_points_are_registered():
 
     names = {p["name"] for p in DEVTIME.snapshot()["programs"]}
     for want in ("prefill", "prefill_chunk", "decode_chunk", "first_sample",
-                 "spec_verify", "batched_prefill", "batched_decode_chunk",
+                 "batched_prefill", "batched_decode_chunk",
                  "lane_decode_chunk", "lane_write", "kvpool_store",
                  "kvpool_restore", "kvpool_upload", "kvpool_lane_store",
                  "flash_attention", "quantize_kv_pallas"):

@@ -734,9 +734,16 @@ def test_two_process_affinity_and_fault_drill(tmp_path):
         _wait_proc_ready(proc1, p1, deadline)
         _wait_proc_ready(proc2, p2, deadline)
 
-        table = _table([p1, p2]).start()
+        # the prober's re-probe backoff doubles while a replica stays down
+        # (0.3 s -> backoff_max, 30 s by default), and a restart under a
+        # loaded box outlasts that doubling: step (d) then waited 30 s for
+        # a re-probe that was due up to 30 s away.  The drill bounds the
+        # backoff well inside its wait, and gives a probe of a replica
+        # that shares six busy cores more than 2 s before it counts as dead.
+        prober = dict(backoff_max=2.0, probe_timeout=5.0)
+        table = _table([p1, p2], **prober).start()
         rs = _serve_router(FleetRouter(table, policy="affinity"), rp_aff)
-        table_rr = _table([p1, p2]).start()
+        table_rr = _table([p1, p2], **prober).start()
         rs_rr = _serve_router(FleetRouter(table_rr, policy="roundrobin"),
                               rp_rr)
 

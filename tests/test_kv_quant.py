@@ -8,7 +8,6 @@ Pallas compile is required anywhere.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +15,7 @@ import numpy as np
 import pytest
 
 from llama_fastapi_k8s_gpu_tpu.models import ModelConfig, init_cache
-from llama_fastapi_k8s_gpu_tpu.models.llama import cache_nbytes, forward, prefill
+from llama_fastapi_k8s_gpu_tpu.models.llama import cache_nbytes, forward
 from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
 from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention
 from llama_fastapi_k8s_gpu_tpu.ops.pallas.kvquant import (
@@ -30,16 +29,6 @@ from llama_fastapi_k8s_gpu_tpu.ops.pallas.kvquant import (
 CFG = ModelConfig(vocab_size=64, dim=128, n_layers=2, n_heads=4,
                   n_kv_heads=2, ffn_dim=128, n_ctx=160)
 CFG8 = dataclasses.replace(CFG, kv_dtype="int8")
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _prefill_jit(params, cfg, tokens, length, cache):
-    return prefill(params, cfg, tokens, length, cache)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _step_jit(params, cfg, token, pos, cache):
-    return forward(params, cfg, token[None], pos, cache)
 
 
 # ---------------------------------------------------------------------------
@@ -177,52 +166,6 @@ def test_int8_logits_close_to_bf16():
                     return_all=True)
     err = float(jnp.max(jnp.abs(l8 - lb)))
     assert err < 0.15, err
-
-
-def _peaked_params(cfg, seed: int, damp: float = 0.25):
-    """Random params reshaped so greedy decode is margin-robust: the output
-    head is a PERMUTATION of the embedding rows (scaled up), so logits are
-    diagonal-dominant — greedy walks a nontrivial token cycle with top-2
-    margins far above KV-quantization noise — and the post-attention
-    projections are damped so the embedding signal dominates the residual
-    stream.  A fully random tiny model has bf16-ULP top-2 margins, where
-    token-for-token parity over 64 steps is a coin flip for ANY cache
-    perturbation; this construction still runs the full attention + int8
-    ring read/write path every step."""
-    params = synth_params(cfg, fmt="bf16", seed=seed)
-    rng = np.random.default_rng(seed + 1)
-    perm = rng.permutation(cfg.vocab_size)
-    emb = np.asarray(params["tok_emb"], np.float32)
-    params["output"] = {"w": jnp.asarray(emb[perm] * 4.0, jnp.bfloat16)}
-    for name in ("wo", "w_down"):
-        params["layers"][name] = {"w": params["layers"][name]["w"] * damp}
-    return params
-
-
-@pytest.mark.parametrize("seed", [1, 2])
-def test_int8_greedy_decode_matches_bf16_for_64_steps(seed):
-    """ISSUE acceptance: LFKT_KV_DTYPE=int8 greedy decode matches bf16
-    token-for-token for ≥ 64 steps on the tiny test model."""
-    params = _peaked_params(CFG, seed)
-    tokens = jnp.arange(1, 17, dtype=jnp.int32) % CFG.vocab_size
-
-    def greedy(cfg, steps=72):
-        cache = init_cache(cfg)
-        lg, cache = _prefill_jit(params, cfg, tokens, jnp.int32(16), cache)
-        t = int(jnp.argmax(lg))
-        out, pos = [t], 16
-        for _ in range(steps):
-            lg, cache = _step_jit(params, cfg, jnp.int32(t), jnp.int32(pos),
-                                  cache)
-            t = int(jnp.argmax(lg))
-            out.append(t)
-            pos += 1
-        return out
-
-    a, b = greedy(CFG), greedy(CFG8)
-    assert len(a) >= 65
-    assert a == b, f"diverged at step {next(i for i, (x, y) in enumerate(zip(a, b)) if x != y)}"
-    assert len(set(a)) > 8, "degenerate greedy cycle — test model too weak"
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,9 @@ def test_manifest_grammar_roundtrip():
 @pytest.mark.parametrize("bad", [
     "noequals",                      # no path
     "a=x.gguf:bogus=1",              # unknown override key
+    "a=x.gguf:spec_decode=lookup",   # overrides of what PR 32 removed
+    "a=x.gguf:spec_draft=4",
+    "a=x.gguf:decode_layer_unroll=4",
     "a=x.gguf:n_ctx=abc",            # uncastable override
     "a=x.gguf,a=y.gguf",             # duplicate alias
     "bad name=x.gguf",               # illegal alias chars
@@ -91,6 +94,8 @@ def test_manifest_grammar_rejects(bad):
     with pytest.raises(ValueError) as ei:
         parse_manifest(bad)
     assert "LFKT_MODELS" in str(ei.value)
+    # an override's refusal names it
+    assert bad.partition(":")[2].partition("=")[0] in str(ei.value)
 
 
 def test_default_model_must_be_in_manifest():

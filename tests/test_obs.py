@@ -161,7 +161,7 @@ def test_runtime_catalog_enforcement():
         m.observe("queue_wait_seconds", 0.1, route="/x")   # stray label
     # declared prefix family admits runtime-synthesized names
     m.set_gauge("scheduler_lanes_live", 2)
-    m.set_gauge("scheduler_spec_drafted", 5)
+    m.set_gauge("scheduler_lane_prefix_hits", 5)
     assert "scheduler_lanes_live 2" in m.render()
 
 

@@ -14,8 +14,8 @@ pin, per engine flavor:
   fixed by the two-chunk warmup, and the serial tail-chunk compile,
   fixed by always dispatching full chunks — pinned below);
 - **each request dispatches exactly D per program** — an extra dispatch
-  per decode chunk is the launch/DMA overhead the kernel-looping roadmap
-  item exists to eliminate; it must never sneak in unmeasured.
+  per decode chunk is launch/DMA overhead; it must never sneak in
+  unmeasured.
 
 The pins run in ONE fresh subprocess: jit caches are process-global, so
 a suite that already warmed the module-level entry points would satisfy
@@ -209,21 +209,18 @@ def test_sp_request_dispatch_budget(pins):
 
 
 # ---------------------------------------------------------------------------
-# per-decode-step KERNEL-LAUNCH pins (ISSUE 12): the layer-loop collapse
-# proven deterministically on CPU, via the jaxpr launch audit
-# (obs/launches.py) — launch primitives weighted by layer-loop trip count
+# per-decode-step KERNEL-LAUNCH pin: counted deterministically on CPU, via
+# the jaxpr launch audit (obs/launches.py) — launch primitives weighted by
+# layer-loop trip count
 # ---------------------------------------------------------------------------
 
-def _launch_audit(unroll: int, kv_dtype: str = "bf16"):
-    import dataclasses
-
+def _launch_audit():
     from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
     from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
     from llama_fastapi_k8s_gpu_tpu.obs.launches import decode_step_launches
 
     cfg = ModelConfig(vocab_size=64, dim=64, n_layers=8, n_heads=4,
-                      n_kv_heads=2, ffn_dim=96, n_ctx=32, kv_dtype=kv_dtype,
-                      decode_layer_unroll=unroll)
+                      n_kv_heads=2, ffn_dim=96, n_ctx=32)
     return decode_step_launches(synth_params(cfg), cfg)
 
 
@@ -232,7 +229,7 @@ def test_per_layer_decode_step_launch_pin():
     # primitives per layer, × L=8 in the layer loop, + the output head.
     # A new dot on the decode path (or a lost loop) changes these exact
     # integers and fails here, on CPU, before any chip session pays for it.
-    audit = _launch_audit(0)
+    audit = _launch_audit()
     assert audit["loop_trips"] == [8]
     assert audit["in_loop"] == 8 * 9
     assert audit["outside"] == 1          # the output head
@@ -240,30 +237,6 @@ def test_per_layer_decode_step_launch_pin():
     # read of the ring in blocks up to the newest live slot (its 2
     # contractions are in the 9 above, counted once: a floor)
     assert audit["while_loops"] == 1
-
-
-def test_looped_decode_step_launch_pin():
-    import math
-
-    base = _launch_audit(0)
-    for K in (4, 8, -1):
-        audit = _launch_audit(K)
-        eff = 8 if K == -1 else K
-        in_step = audit["total"] - base["outside"]   # minus the output head
-        # THE acceptance criterion: K layers per launch → ≤ ceil(L/K)
-        # kernel launches per decode step (one pallas_call per group)
-        assert in_step <= math.ceil(8 / eff), (K, audit)
-        assert audit["total"] * 3 <= base["total"], (K, audit, base)
-    # and the collapse is attributed to the looped kernel, not to dots
-    a4 = _launch_audit(4)
-    assert a4["by_prim"].get("pallas_call") == 2
-    assert "dot_general" not in a4["by_prim"]        # none left in-loop
-
-
-def test_looped_launch_pin_int8_kv():
-    # the int8-KV fused-dequant reads stay inside the loop: same collapse
-    audit = _launch_audit(4, kv_dtype="int8")
-    assert audit["total"] - 1 <= 2, audit
 
 
 def test_continuous_request_budget(pins):

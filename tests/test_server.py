@@ -155,20 +155,20 @@ async def test_health_and_metrics_and_items():
 
 @pytest.mark.anyio
 async def test_metrics_flattens_nested_scheduler_stats():
-    """Dict-valued scheduler stats (spec telemetry) must flatten into one
+    """Dict-valued scheduler stats must flatten into one
     gauge per leaf — a dict rendered verbatim is an invalid exposition
     line every Prometheus scraper (and bench parser) drops."""
     engine = FakeEngine()
     engine.scheduler_stats = lambda: {
-        "lanes_live": 1, "spec": {"drafted": 5, "accepted": 3}}
+        "lanes_live": 1, "prefix": {"hits": 5, "reused_tokens": 3}}
     app, transport = make_client(engine)
     async with transport:
         await app.router.startup()
         async with await lifespan_client(app, transport) as client:
             m = await client.get("/metrics")
             assert "scheduler_lanes_live 1" in m.text
-            assert "scheduler_spec_drafted 5" in m.text
-            assert "scheduler_spec_accepted 3" in m.text
+            assert "scheduler_prefix_hits 5" in m.text
+            assert "scheduler_prefix_reused_tokens 3" in m.text
             # no dict-valued gauge rendered verbatim (histogram bucket
             # labels are the only legal brace-bearing lines)
             for line in m.text.splitlines():

@@ -69,16 +69,6 @@ CHECKS: list[tuple[str, list[str]]] = [
     ("disagg-wire-schema", [sys.executable, "-m",
                             "llama_fastapi_k8s_gpu_tpu.serving.disagg.wire",
                             "--check-golden"]),
-    # layer-looped decode bit-exactness (ISSUE 12): the serial-engine
-    # greedy-parity subset of tests/test_decode_loop.py, standalone —
-    # greedy output with LFKT_DECODE_LAYER_UNROLL armed must stay
-    # bit-identical to the per-layer path (bf16/int8 KV, dense/paged).
-    # `env JAX_PLATFORMS=cpu`: this gate must never touch a chip.
-    ("decode-loop-parity", ["env", "JAX_PLATFORMS=cpu", sys.executable,
-                            "-m", "pytest", "-q", "-p", "no:cacheprovider",
-                            os.path.join(ROOT, "tests",
-                                         "test_decode_loop.py"),
-                            "-k", "serial_parity"]),
     # fleet-tier byte-exactness (ISSUE 14): greedy output proxied through
     # the prefix-affinity router must be BYTE-identical to direct-to-
     # replica serving — the router relays raw backend bytes, and this
