@@ -222,7 +222,8 @@ class _Slot:
         self.trace = item.trace   # span sinks (None when sampled out)
         self.pspan = None         # the admission's "prefill" span
         self.fspan = None         # its open "first_token" child
-        self.fwave = 0            # chunks dispatched when fspan opened
+        self.fwave = 0            # chunks dispatched when fspan opened (the
+        #                           lane's first chunk is the next one)
         self.dspan = None         # this slot's lane-occupancy "decode" span
         self.t_chunk = 0.0        # previous harvest time (chunk-span starts)
         self.finished = False   # set when resolved; the pipelined loop may
@@ -273,7 +274,7 @@ class ContinuousEngine(MeshEngine):
         "_bstate", "_lane_st", "_scratch_cache", "_adm", "_lane_claims",
         "_prefix_stats", "_stats", "_loop_error",
         "_adm_budget", "_lane_idle_s", "_mem_hot_prev", "_totals",
-        "_slices_queued",
+        "_slices_queued", "_wave_at_pass",
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
@@ -308,8 +309,13 @@ class ContinuousEngine(MeshEngine):
         #: exported through scheduler_stats() as scheduler_<key> gauges
         self._totals = self._zero_totals()
         #: prefill slices queued on the device since the last decode chunk
-        #: was dispatched: the next chunk's span names them as its wait
+        #: was dispatched (the admission round runs ahead of its pass's
+        #: chunk): that chunk's span names them as its wait
         self._slices_queued = 0
+        #: ``chunks_dispatched`` as the current loop pass began: what an
+        #: admission finished in the pass compares it with (chunks that
+        #: left in the same pass without its lane)
+        self._wave_at_pass = 0
         self._adm: dict | None = None   # in-flight chunked admission
         # -- lane-prefix reuse (default ON since round 6; the admission
         # -- controller closed the interference gap that kept it off) ------
@@ -375,14 +381,20 @@ class ContinuousEngine(MeshEngine):
     @staticmethod
     def _zero_totals() -> dict:
         """The per-wave counters (docs/OBSERVABILITY.md "The wave").  A
-        wave is one loop pass that dispatched a decode chunk: the chunk,
-        the admission slices queued behind it, and the fetch + harvest of
-        the chunk before it.  ``lane_live_seconds`` + the older
-        ``lane_idle_seconds`` is ``batch_size`` x ``wave_seconds``."""
+        wave is one loop pass that dispatched a decode chunk: the
+        admission slices queued ahead of it, the chunk, and the fetch +
+        harvest of the chunk before it.  ``lane_live_seconds`` + the older
+        ``lane_idle_seconds`` is ``batch_size`` x ``wave_seconds``.
+        ``admits_beside_live`` counts admissions finished while other
+        lanes decode (the deferred first token); ``admit_chunks_behind``
+        sums, over them, the decode chunks the loop pass that finished the
+        admission had already dispatched without its lane: 0 since the
+        round runs ahead of the chunk, 1 each in the order before."""
         return {"waves": 0, "wave_seconds": 0.0, "lane_live_seconds": 0.0,
                 "fetch_wait_seconds": 0.0, "admit_seconds": 0.0,
                 "admit_slices": 0, "admit_tokens": 0,
-                "harvest_seconds": 0.0, "chunks_dispatched": 0}
+                "harvest_seconds": 0.0, "chunks_dispatched": 0,
+                "admits_beside_live": 0, "admit_chunks_behind": 0}
 
     # ------------------------------------------------------------------
     def submit(self, messages: Sequence[dict], *, temperature: float = 0.2,
@@ -561,6 +573,7 @@ class ContinuousEngine(MeshEngine):
         self._lane_idle_s = 0.0
         self._totals = self._zero_totals()
         self._slices_queued = 0
+        self._wave_at_pass = 0
         if self._adm_ctl is not None:
             # fresh controller: post-recovery traffic should not inherit
             # the pre-crash EMAs (a wedged device reads as max pressure)
@@ -900,8 +913,10 @@ class ContinuousEngine(MeshEngine):
         tot["admit_slices"] += 1
         tot["admit_tokens"] += C
         self._slices_queued += 1
+        # wave: the decode chunk this slice is queued ahead of (the next
+        # one dispatched), the number that chunk's decode_chunk spans carry
         self._slice_span(adm.get("span"), t_s, t_e, off, C,
-                         wave=tot["chunks_dispatched"])
+                         wave=tot["chunks_dispatched"] + 1)
 
     def _finish_admission(self, adm: dict, lane: int, slots: list) -> None:
         """Prefill complete: sample the first token, write the lane, install.
@@ -911,9 +926,15 @@ class ContinuousEngine(MeshEngine):
         blocking ``int(token)`` here drains the whole queued device
         pipeline through the dispatch round-trip on every admission, which
         under churn serializes the loop and starves live lanes (measured:
-        batch-4 aggregate throughput below a single lane's).  With no live
-        lanes nothing is starved, so the synchronous path keeps the
-        tightest TTFT for unloaded traffic."""
+        batch-4 aggregate throughput below a single lane's).  The round
+        runs AHEAD of its pass's decode chunk, so a blocking fetch beside
+        live lanes would also stand in front of a chunk not yet
+        dispatched: ``deferred`` is true whenever a lane holds a request.
+        The lane is then live in the chunk dispatched right after the
+        round, and its first token is handed over with that chunk's rows.
+        With no live lanes nothing is starved (a chunk still in flight
+        then carries only freed lanes' discarded rows), so the synchronous
+        path keeps the tightest TTFT for unloaded traffic."""
         item = adm["item"]
         try:
             ids, n_prompt, st = adm["ids"], adm["n_prompt"], adm["st"]
@@ -947,6 +968,10 @@ class ContinuousEngine(MeshEngine):
                 self._prefix_stats[
                     f"{self._reuse_stat}_reused_tokens"] += slot.reused
             if deferred:
+                tot = self._totals
+                tot["admits_beside_live"] += 1
+                tot["admit_chunks_behind"] += \
+                    tot["chunks_dispatched"] - self._wave_at_pass
                 try:
                     token.copy_to_host_async()
                 except Exception:  # noqa: BLE001 — optional fast path
@@ -994,10 +1019,11 @@ class ContinuousEngine(MeshEngine):
             slot.trace.note(tokens=1)
 
     def _materialize_first(self, lane: int, slot: _Slot, slots: list) -> None:
-        """Deferred-admission bookkeeping, run at the slot's first harvest
-        (its sample landed before the chunk just fetched, so this fetch
-        does not wait on new device work): first-token value, TTFT, stream
-        open, first stop/budget checks."""
+        """Deferred-admission bookkeeping, run at the slot's first harvest:
+        the harvest of the chunk dispatched right after the round that
+        admitted it (its sample was queued ahead of that chunk, so this
+        fetch does not wait on new device work): first-token value, TTFT,
+        stream open, first stop/budget checks."""
         slot.pending_first = False
         try:
             slot.first_token = int(slot.first_token)
@@ -1273,14 +1299,19 @@ class ContinuousEngine(MeshEngine):
         with the pipelined loop that is one iteration ago, so a lane's slot
         may have finished (budget/stop found in the previous chunk) while
         this chunk was already in flight on the device; those rows are
-        discarded (``slot.finished``).  Abandoned requests (client timeout /
-        disconnect) free their lane here instead of decoding to budget:
-        unlike the reference's serial engine (api.py:97-100, where a
-        discarded generation delays nobody), an occupied lane would hold up
-        waiting requests.
+        discarded (``slot.finished``).  A lane admitted by the round that
+        ran ahead of the chunk's dispatch is in ``pre`` with its first
+        token still on the device (``pending_first``): token 1 and the
+        chunk's rows are folded in together here.  Abandoned requests
+        (client timeout / disconnect) free their lane here instead of
+        decoding to budget: unlike the reference's serial engine
+        (api.py:97-100, where a discarded generation delays nobody), an
+        occupied lane would hold up waiting requests.
 
         ``wave`` / ``admit_slices`` (the chunk's dispatch number and the
-        prefill slices queued on the device ahead of it) ride on each
+        prefill slices queued on the device between the chunk before and
+        it: those of its own pass's admission round, whose
+        ``prefill_slice`` spans carry the same ``wave``) ride on each
         traced lane's ``decode_chunk`` span: a long chunk names its cause."""
         stop_ids = self.tokenizer.stop_ids
         now = time.time()
@@ -1317,9 +1348,10 @@ class ContinuousEngine(MeshEngine):
                 self._free_lane(lane, slot, slots)
                 continue
             if slot.pending_first:
-                # deferred admission: its sample was queued before the chunk
-                # just fetched — materialize the first token now, then fold
-                # in this chunk's rows (its tokens 2..n for this lane)
+                # deferred admission: its sample was queued ahead of the
+                # chunk just fetched, its lane's first — materialize the
+                # first token now, then fold in this chunk's rows (its
+                # tokens 2..n for this lane)
                 self._materialize_first(lane, slot, slots)
                 if slot.finished:
                     continue
@@ -1388,24 +1420,44 @@ class ContinuousEngine(MeshEngine):
                         continue
                     t_prev_wave = time.time()   # lanes just filled: new wave
 
-                # ---- one decode chunk for every live lane (per-lane sampling
-                # knobs incl. traced top_k ride in self._lane_st; the static
-                # k is the engine-wide ceiling).  Dispatch is async AND
-                # pipelined one chunk deep: this chunk queues on the device
-                # BEFORE the previous chunk's tokens are fetched, so the
-                # host round-trip (dispatch latency) overlaps device
-                # compute instead of
+                # ---- one pass: admission, then one decode chunk for every
+                # live lane, then the PREVIOUS chunk's fetch + harvest.
+                # Dispatch is async AND pipelined one chunk deep: this
+                # pass's chunk queues on the device BEFORE the previous
+                # chunk's tokens are fetched, so the host round-trip
+                # (dispatch latency) overlaps device compute instead of
                 # serializing with it.  Cost of the pipeline: a lane whose
                 # request finished in the previous chunk decodes one extra
-                # chunk before being freed (its rows are discarded), and an
-                # admission lands one chunk later.
+                # chunk before being freed (its rows are discarded).
+                # Admission goes AHEAD of the chunk: while the device still
+                # runs the previous chunk, the host tokenizes and queues the
+                # round's slices, first-token samples and lane writes, and
+                # the chunk dispatched right after carries every lane the
+                # round filled.  Behind the chunk, each admission would wait
+                # one whole wave more for its first token, behind a chunk
+                # that left a millisecond earlier without its lane.
                 tot = self._totals
-                # lfkt.wave in a capture: this pass's device work, from the
-                # chunk's dispatch to the previous chunk's harvest
+                self._wave_at_pass = tot["chunks_dispatched"]
+                # lfkt.wave in a capture: this pass's work, from the
+                # admission round to the previous chunk's harvest
+                # (lanes_live: lanes holding a request as the pass begins)
                 with phase("wave", wave=tot["chunks_dispatched"] + 1,
                            lanes_live=sum(s is not None for s in slots)):
+                    # ---- admission prefills, up to the per-pass token
+                    # budget (several complete short admissions, or slices
+                    # of a long one); they queue behind the chunk in flight
+                    # and ahead of the one dispatched below.  Chunked
+                    # prefill bounds what a pass puts in front of the live
+                    # lanes' next chunk to the budget even for full-bucket
+                    # prompts.
+                    self._admit_round(slots)
+
                     if any(s is not None for s in slots):
-                        pre = list(slots)   # lanes live in THIS chunk
+                        # lanes live in THIS chunk, the round's admissions
+                        # among them (pending_first: their first token is
+                        # read at this chunk's harvest, one pass on); pre[]
+                        # snapshots who gets the chunk's rows
+                        pre = list(slots)
                         FAULTS.fire("decode_step")
                         tot["chunks_dispatched"] += 1
                         wave = tot["chunks_dispatched"]
@@ -1424,30 +1476,22 @@ class ContinuousEngine(MeshEngine):
                                     top_k=self._max_top_k, live=live)
                             toks = self._take_expert_stats(out)
                         # (lanes, tokens, dispatch number, slices queued on
-                        # the device ahead of this chunk)
+                        # the device between the chunk before and this one:
+                        # this pass's round, or an idle engine's admissions)
                         dispatched = (pre, toks, wave, self._slices_queued)
                         self._slices_queued = 0
                     else:
                         dispatched = None
-
-                    # ---- overlap: admission prefills run while the chunk
-                    # executes, up to the per-iteration token budget (several
-                    # complete short admissions, or one slice of a long one);
-                    # each lane write queues after the dispatched chunks, and an
-                    # admitted request's tokens start with the chunk dispatched
-                    # NEXT iteration (pre[] snapshots who gets each chunk's
-                    # rows).  Chunked prefill bounds the per-iteration stall to
-                    # the budget even for full-bucket prompts.
-                    self._admit_round(slots)
 
                     # ---- harvest the PREVIOUS chunk (fetch blocks only until
                     # that chunk is done; the one dispatched above keeps the
                     # device busy meanwhile).  The fetch's blocking time IS the
                     # decode-pressure signal: a long wait means the device was
                     # still decoding when the host came back (admission slices
-                    # queued this wave delay the NEXT chunk, surfacing here one
-                    # wave later); a near-zero wait means the device sat idle —
-                    # the admission controller converts that slack into budget.
+                    # queued this wave delay THIS wave's chunk, surfacing here
+                    # one wave later); a near-zero wait means the device sat
+                    # idle — the admission controller converts that slack into
+                    # budget.
                     fetch_wait = 0.0
                     if pending is not None:
                         t_f = time.time()

@@ -329,12 +329,16 @@ METRICS: dict[str, Metric] = _register(
            "continuous-scheduler family (ContinuousEngine.scheduler_stats). "
            "Point in time: lanes_live, pending, admission_inflight, "
            "adm_*, mem_pressure, batch_size. Cumulative since start, one "
-           "add per wave (a wave = one decode chunk dispatched, the "
-           "admission slices behind it, the previous chunk's fetch + "
-           "harvest): waves, wave_seconds, lane_live_seconds + "
+           "add per wave (a wave = the admission slices queued ahead of "
+           "one decode chunk, that chunk's dispatch, the previous chunk's "
+           "fetch + harvest): waves, wave_seconds, lane_live_seconds + "
            "lane_idle_seconds (= batch_size x wave_seconds), "
            "fetch_wait_seconds, admit_seconds, admit_slices, admit_tokens, "
-           "harvest_seconds, chunks_dispatched, lane_prefix_* / "
+           "harvest_seconds, chunks_dispatched, admits_beside_live "
+           "(admissions finished while other lanes decode) and "
+           "admit_chunks_behind (summed over them: decode chunks the pass "
+           "that finished the admission had dispatched without its lane; "
+           "0 while the round runs ahead of the chunk), lane_prefix_* / "
            "radix_prefix_*",
            prefix=True),
 )

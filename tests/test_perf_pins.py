@@ -164,12 +164,16 @@ def test_sp_warmup_compile_budget(pins):
 
 
 def test_continuous_warmup_compile_budget(pins):
-    # prefill_chunk: 4 admission/suffix slice shapes; lane_write: 2 cache1
-    # bucket shapes; lane_decode_chunk: the sharding pair; lane_cache_copy:
-    # the lane-prefix snapshot program
+    # prefill_chunk: 4 admission/suffix slice shapes; lane_decode_chunk: the
+    # sharding pair; lane_cache_copy: the lane-prefix snapshot program;
+    # lane_write: one shape under the three shardings the batched state has
+    # at start-up (as placed at construction; as a lane write returns that,
+    # when the first pass admits a second request ahead of the engine's
+    # first chunk, ISSUE 33; as a decode chunk returns it: the only one
+    # after the first chunk, so none of them can first occur later)
     assert _compiles(pins["cont_warmup"]) == {
         "prefill_chunk": 4, "first_sample": 1, "lane_decode_chunk": 2,
-        "lane_write": 2, "lane_cache_copy": 1}
+        "lane_write": 3, "lane_cache_copy": 1}
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,9 @@ phases inside a profiler capture.
 2. **The wave** — ``scheduler_stats()`` integrates the wave where it
    happens: live + idle lane-seconds is lanes x wave seconds, and
    ``admit_slices`` is the ``prefill_slice`` spans recorded.
+   A request admitted beside live lanes rides the decode chunk of the pass
+   that admits it (ISSUE 33): the round runs ahead of the chunk's dispatch,
+   ``admit_chunks_behind`` stays 0, and its text is the one it has alone.
 3. **Phases** — ``phase()`` is one shared no-op while the profiler cannot
    be armed (poisoned ``TraceAnnotation``) and emits ``lfkt.<name>`` with
    its attrs while it can (a recording annotation class: no profiler).
@@ -281,6 +284,160 @@ async def test_wave_counters_are_scheduler_gauges(lanes):
     assert "wave_seconds" in family.help
 
 
+# -- an admitted request rides the chunk of the pass that admits it --------
+
+RIDE_SYS = ("You are a meticulous assistant who answers carefully. " * 3).strip()
+RIDE_VARIANTS = {
+    "plain": dict(lane_prefix_cache=False),
+    "lane_reuse": dict(lane_prefix_cache=True),
+    "paged": dict(kv_paged=True, kv_page_tokens=16, kv_pool_pages=64,
+                  kv_spill_pages=16, prefix_min=16),
+}
+
+
+class _NeverStop:
+    """The engine's tokenizer without its stop ids: the tiny model's greedy
+    text ends after some 16 tokens, and the neighbour lane has to decode to
+    its budget so that it is live for as long as the test admits beside it.
+    Both engines of a comparison wear it, so the texts compared are cut by
+    ``max_tokens`` alone, alike."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    @property
+    def stop_ids(self):
+        return set()
+
+
+def _ride_engine(model_path, variant):
+    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=512,
+                           decode_chunk=4, max_gen_tokens=440,
+                           prefill_buckets=(64, 128, 256, 512),
+                           prefill_chunk=16, **RIDE_VARIANTS[variant])
+    eng.tokenizer = _NeverStop(eng.tokenizer)
+    return eng
+
+
+def _ride_turns(reply=None):
+    msgs = [{"role": "system", "content": RIDE_SYS},
+            {"role": "user", "content": "Tell me something interesting."}]
+    if reply is not None:
+        msgs += [{"role": "assistant", "content": reply},
+                 {"role": "user", "content": "And another one please."}]
+    return msgs
+
+
+def _two_turns(eng, tracer=None):
+    """The conversation's two turns, one after the other -> (results,
+    trace documents); the second turn re-sends the first's reply."""
+    outs, docs, reply = [], [], None
+    for _ in range(2):
+        tr = tracer.start() if tracer is not None else None
+        out = eng.create_chat_completion(_ride_turns(reply), temperature=0.0,
+                                         max_tokens=8, trace=tr)
+        reply = out["choices"][0]["message"]["content"]
+        outs.append(out)
+        if tr is not None:
+            tracer.finish(tr)
+            docs.append(tr.to_dict()["root"])
+    return outs, docs
+
+
+@pytest.mark.parametrize("variant", list(RIDE_VARIANTS))
+def test_admitted_beside_a_live_lane_rides_the_chunk_of_its_pass(
+        model_path, variant):
+    """With one lane decoding, a request admitted beside it is live in the
+    decode chunk dispatched by the pass that finished its admission: the
+    round runs AHEAD of the chunk (``admit_chunks_behind`` 0 over the
+    admissions beside live lanes), the first ``decode_chunk.wave`` of its
+    ``decode`` span is the ``wave`` of its last ``prefill_slice`` (a slice
+    carries the number of the chunk it is queued ahead of), that chunk's
+    ``admit_slices`` counts the slices that carry its number, and the
+    greedy texts are those of the same two turns on an engine that serves
+    nothing else.  Several-slice prompts; the second turn reuses the
+    first's KV where the variant has a prefix cache (lane claims, paged
+    pool)."""
+    alone = _ride_engine(model_path, variant)
+    try:
+        want, _ = _two_turns(alone)
+    finally:
+        alone.shutdown()
+    eng = _ride_engine(model_path, variant)
+    try:
+        tracer = Tracer(sample=1.0, ring=8)
+        tr_n = tracer.start()
+        neighbour = eng.create_chat_completion(
+            MSGS, stream=True, temperature=0.0, max_tokens=440, trace=tr_n)
+        next(neighbour)                 # holds a lane and decodes to budget
+        got, docs = _two_turns(eng, tracer)
+        stats = eng.scheduler_stats()
+        assert stats["lanes_live"] >= 1     # the neighbour outlived both
+        neighbour.close()
+    finally:
+        eng.shutdown()
+    tracer.finish(tr_n)
+    numbered = [s["attrs"]["wave"] for d in docs + [tr_n.to_dict()["root"]]
+                for s in _named(d, "prefill_slice")]
+    assert [o["choices"][0]["message"]["content"] for o in got] == \
+        [o["choices"][0]["message"]["content"] for o in want]
+    assert [o["usage"] for o in got] == [o["usage"] for o in want]
+    reused = [o["lfkt_timings"]["prefix_reused_tokens"] for o in got]
+    assert reused == [o["lfkt_timings"]["prefix_reused_tokens"] for o in want]
+    assert reused[0] == 0 and (reused[1] > 0) is (variant != "plain")
+    assert stats["admits_beside_live"] == 2
+    assert stats["admit_chunks_behind"] == 0
+    for doc in docs:
+        first, = _named(doc, "first_token")
+        assert first["attrs"]["deferred"] is True
+        slices = _named(doc, "prefill_slice")
+        chunks = _named(doc, "decode_chunk")
+        assert doc is not docs[0] or len(slices) >= 2
+        wave = chunks[0]["attrs"]["wave"]
+        assert slices[-1]["attrs"]["wave"] == wave
+        # every slice queued since the chunk before carries this chunk's
+        # number (the neighbour's too, where both were admitted into the
+        # engine's first pass)
+        assert chunks[0]["attrs"]["admit_slices"] == numbered.count(wave)
+
+
+def test_chunks_behind_reads_one_in_the_order_before(model_path):
+    """What the counter measures, shown on the order the loop had before
+    ISSUE 33 (chunk first, admission round after), rebuilt here by moving
+    the round behind the chunk's dispatch: every admission beside a live
+    lane then sits behind one chunk that left in its own pass without its
+    lane."""
+    eng = _ride_engine(model_path, "plain")
+    try:
+        seen = {}
+        admit_round, take = eng._admit_round, eng._take_expert_stats
+
+        def later(slots):               # the loop's call: ahead of the chunk
+            seen["slots"] = slots
+            return False
+
+        def after_dispatch(out):        # ... moved to just behind it
+            toks = take(out)
+            admit_round(seen["slots"])
+            return toks
+
+        eng._admit_round, eng._take_expert_stats = later, after_dispatch
+        neighbour = eng.create_chat_completion(
+            MSGS, stream=True, temperature=0.0, max_tokens=440)
+        next(neighbour)
+        outs, _ = _two_turns(eng)
+        stats = eng.scheduler_stats()
+        neighbour.close()
+    finally:
+        eng.shutdown()
+    assert all(o["usage"]["completion_tokens"] == 8 for o in outs)
+    assert stats["admits_beside_live"] == 2
+    assert stats["admit_chunks_behind"] == 2
+
+
 # ---------------------------------------------------------------------------
 # 3. phases
 # ---------------------------------------------------------------------------
@@ -372,6 +529,37 @@ def test_phase_on_emits_the_lane_loop(recorder, lanes):
     assert {a["rid"] for a in slices} == {doc["trace_id"]}
     assert all(a["tokens"] == 16 for a in slices)
     assert seen["lfkt.tokenize"][0][0]["rid"] == doc["trace_id"]
+
+
+def test_phase_order_puts_the_admission_round_ahead_of_the_chunk(
+        monkeypatch, model_path):
+    """Inside one ``lfkt.wave`` the admission slices go out before the
+    decode chunk (ISSUE 33), and the wave then fetches the chunk before."""
+    eng = _ride_engine(model_path, "plain")
+    _Recorder.seen = []         # armed after the engine: building one disarms
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _Recorder)
+    try:
+        neighbour = eng.create_chat_completion(
+            MSGS, stream=True, temperature=0.0, max_tokens=440)
+        next(neighbour)                 # a lane decodes: waves run
+        eng.create_chat_completion(LONG, temperature=0.0, max_tokens=4)
+        neighbour.close()
+    finally:
+        eng.shutdown()
+    waves, inside = [], None
+    for name, _, depth in _Recorder.seen:
+        if name == "lfkt.wave":
+            inside = []
+            waves.append(inside)
+        elif depth == 1 and inside is not None and name in (
+                "lfkt.admit_slice", "lfkt.dispatch_chunk", "lfkt.fetch"):
+            inside.append(name.removeprefix("lfkt."))
+    mixed = [w for w in waves if "admit_slice" in w and "dispatch_chunk" in w]
+    assert mixed, waves
+    for w in mixed:
+        assert w.index("dispatch_chunk") > max(
+            i for i, n in enumerate(w) if n == "admit_slice"), w
+        assert "fetch" not in w or w.index("fetch") > w.index("dispatch_chunk")
 
 
 def test_phase_on_emits_the_serial_engine(recorder, serial):
