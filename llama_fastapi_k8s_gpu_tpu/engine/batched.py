@@ -112,6 +112,12 @@ class MeshEngine(Engine):
     # (lfkt-lint LOCK001; docs/RUNBOOK.md "Lock discipline annotations")
     _GUARDED_BY = {"_bstate": "_lock"}
 
+    #: whether prompts enter in slices of ``prefill_chunk`` (the continuous
+    #: scheduler) and not as one vmapped pass over a whole bucket (the
+    #: cycle scheduler here), which a cache whose window restarts cannot
+    #: take
+    _SLICED_ADMISSION = False
+
     def __init__(self, model_path: str | None, *, dp: int | None = None,
                  tp: int = 1, batch_size: int | None = None, **kw):
         avail = max(1, len(jax.devices()) // tp)
@@ -133,6 +139,7 @@ class MeshEngine(Engine):
                     "mesh: the flash kernel has no partitioning rule; use "
                     "attn_impl='auto' (resolves to 'xla' on a mesh) or 'xla'")
             kw["attn_impl"] = "xla"
+        self._mesh_shape = (dp, tp)    # read by _refuse_for_window_cache
         super().__init__(model_path, **kw)
         self.mesh = make_mesh(dp=dp, tp=tp)
         self.batch_size = batch_size or dp
@@ -148,6 +155,21 @@ class MeshEngine(Engine):
         # serving allocation — attribute it (provider reads the live
         # reference, so watchdog re-inits stay correct automatically)
         register_component("kv_lanes", self, _ledger_lane_bytes)
+
+    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
+        super()._refuse_for_window_cache(kv_paged)
+        dp, tp = self._mesh_shape
+        if tp > 1:
+            raise ValueError(
+                f"LFKT_MESH_TP={tp} cannot serve architecture 'evabyte': "
+                "parallel/mesh.py shards a ring's KV heads, and has no "
+                "layout for its window + summary cache")
+        if not self._SLICED_ADMISSION:
+            raise ValueError(
+                "LFKT_SCHEDULER=cycle cannot serve architecture 'evabyte': "
+                "it prefills a whole prompt in one vmapped pass, and a "
+                "pass must lie inside one attention window; use the "
+                "continuous scheduler")
 
     def _recover_locked(self) -> None:  # lfkt: holds[_lock]
         """Watchdog recovery: a crash mid-cycle may have poisoned the donated

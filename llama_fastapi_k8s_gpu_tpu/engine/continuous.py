@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.generate import prefill_chunk_jit, sample_jit
-from ..models.llama import decode_chunk_slots, init_cache
+from ..models.llama import init_cache
 from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
@@ -270,6 +270,7 @@ class ContinuousEngine(MeshEngine):
         "_req_counter": "_id_lock",
     }
     _THREAD_ENTRIES = ("_loop",)
+    _SLICED_ADMISSION = True
     _THREAD_CONFINED = (
         "_bstate", "_lane_st", "_scratch_cache", "_adm", "_lane_claims",
         "_prefix_stats", "_stats", "_loop_error",
@@ -278,9 +279,10 @@ class ContinuousEngine(MeshEngine):
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
-    # (ring_slots: the scheduler thread alone adds, /metrics reads an int)
+    # (ring_slots, eva_counts: the scheduler thread alone adds, /metrics
+    # reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread", "ring_slots")
+                      "_thread", "ring_slots", "eva_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
@@ -330,7 +332,12 @@ class ContinuousEngine(MeshEngine):
         # n_ctx-1: a freed lane keeps garbage-decoding in the shared
         # batched program, but those writes land at positions past the
         # claim (clamping to slot n_ctx-1 once pos overruns).
-        self._lane_prefix = bool(lane_prefix_cache)
+        # (Off for the window + summary cache, as the serial engine's: a
+        # claim is by token position and a window restarts; such a lane's
+        # walking position stays inside both stores by itself, slot
+        # ``pos mod W``, and closes no window past the last closable one.)
+        self._lane_prefix = bool(lane_prefix_cache) \
+            and not self.cfg.eva_window
         # paged mode (LFKT_KV_PAGED) folds the lane claims behind the
         # shared radix tree: one prefix-reuse implementation per mode (the
         # per-lane claim path remains the dense-ring default).  An
@@ -813,6 +820,7 @@ class ContinuousEngine(MeshEngine):
                 # mid-prefill (or failing later) must not inflate /metrics
             if pspan is not None:
                 pspan.set(n_prompt=len(ids), bucket=bucket, reused=reuse)
+            self._note_prefill_windows(len(ids), pspan)
             # host-side slice prep happens ONCE, here, while lanes decode:
             # one int32 array for the padded prompt; every slice dispatch
             # then takes a zero-copy view instead of re-converting a list
@@ -1385,18 +1393,17 @@ class ContinuousEngine(MeshEngine):
         lanes whose rows are still wanted, the ring slots the read covered
         (every lane reads up to the largest position among the lanes the
         chunk was dispatched as live, ``pre``) and the slots at or below
-        the lane's own position.  From the positions the host holds before
-        the chunk's tokens are folded in (prompt + generated so far: the
-        slot of the chunk's first step); nothing is fetched."""
+        the lane's own position (on a cache that is no ring its own two
+        stores: ``Engine._note_cache_read`` counts either kind).  From the
+        positions the host holds before the chunk's tokens are folded in
+        (prompt + generated so far: the slot of the chunk's first step);
+        nothing is fetched."""
         at = [None if s is None else s.n_prompt + max(len(s.gens) - 1, 0)
               for s in pre]
-        bound = max((p for p in at if p is not None), default=0)
-        for slot, p in zip(pre, at):
-            if slot is None or slot.finished:
-                continue
-            read, live = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound)
-            self.ring_slots["read"] += read
-            self.ring_slots["live"] += live
+        wanted = [p for slot, p in zip(pre, at)
+                  if slot is not None and not slot.finished]
+        self._note_cache_read(wanted, n_steps,
+                              [p for p in at if p is not None])
 
     def _loop(self):
         B = self.batch_size

@@ -40,6 +40,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
+from ..models import eva
 from ..models.llama import decode_chunk_slots, init_cache
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
@@ -224,6 +225,11 @@ class Engine:
         # its live lanes, ContinuousEngine._note_ring_read); /metrics
         # ring_slots_*_total
         self.ring_slots = {"read": 0, "live": 0}
+        # the same for the window + summary cache (models/eva.py
+        # chunk_counts; ring_slots stays 0 there): /metrics eva_*_total
+        self.eva_counts = {"lane_steps": 0, "window_read": 0,
+                           "window_live": 0, "summaries_read": 0,
+                           "summaries_live": 0, "windows_closed": 0}
         self._base_seed = seed
         # request counter: shared by the serial path (caller thread) and the
         # continuous scheduler thread; _next_seed() is the only writer and
@@ -306,6 +312,9 @@ class Engine:
             )
         if kv_dtype is not None and kv_dtype != self.cfg.kv_dtype:
             self.cfg = dataclasses.replace(self.cfg, kv_dtype=kv_dtype)
+        if self.cfg.eva_window:
+            self._refuse_for_window_cache(bool(kv_paged))
+            attn_impl = "xla"   # its attention is models/eva.py's own
         if self.cfg.kv_dtype == "int8":
             # compile-probe the KV write-quantize kernel NOW: a Mosaic
             # failure degrades writes to the identical XLA formulation
@@ -358,7 +367,10 @@ class Engine:
         # suffix via prefill_chunk_jit — multi-turn TTFT then scales with
         # the NEW turn's length, not the whole history.  The mesh/SP/lane
         # engines manage caches differently and keep full prefill.
-        self._prefix_cache = bool(prefix_cache) and type(self) is Engine
+        # (off for the window + summary cache: reuse is by token position,
+        # and a window restarts: a property of the cache, /health says so)
+        self._prefix_cache = bool(prefix_cache) and type(self) is Engine \
+            and not self.cfg.eva_window
         self._prefix_min = max(1, int(prefix_min))
         #: token ids whose KV occupy ring slots [0, len) — only ever read
         #: and written under self._lock (the single-generator invariant)
@@ -434,6 +446,43 @@ class Engine:
         # (The pool registers itself; subclasses add their own surfaces.)
         register_component("weights", self, _ledger_weight_bytes)
         register_component("kv_ring", self, _ledger_ring_bytes)
+
+    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
+        """What cannot serve the window + summary cache of ``evabyte``
+        (models/eva.py), refused at start by name, never degraded to:
+        an int8 cache, the paged pool (pages are runs of ring slots), and
+        a prefill slice that could lie astride a window or split a chunk.
+        Subclasses add the meshes that shard what this cache lacks."""
+        W, C = self.cfg.eva_window, self.cfg.eva_chunk
+        if self.cfg.kv_dtype == "int8":
+            raise ValueError(
+                "LFKT_KV_DTYPE=int8 cannot serve architecture 'evabyte': "
+                "its window + summary cache is bf16 only")
+        if kv_paged:
+            raise ValueError(
+                "LFKT_KV_PAGED=1 cannot serve architecture 'evabyte': the "
+                "pool pages runs of ring slots by token position, and its "
+                "cache is a window that restarts plus chunk summaries")
+        if W % self._prefill_chunk or self._prefill_chunk % C:
+            raise ValueError(
+                f"LFKT_PREFILL_CHUNK={self._prefill_chunk} cannot serve "
+                f"architecture 'evabyte': a prefill slice must divide its "
+                f"attention window ({W}) and be a multiple of its chunk "
+                f"({C}), so that no slice lies astride a window")
+
+    @property
+    def cache_kind(self) -> dict | None:
+        """The /health ``engine.cache`` block of a cache that is no ring
+        (None for the ring: its /health is what it was): the kind's sizes,
+        and the reuse it does without as a property, not a degrade."""
+        if not self.cfg.eva_window:
+            return None
+        return {"kind": "window+summaries", "window": self.cfg.eva_window,
+                "chunk": self.cfg.eva_chunk,
+                "summaries": eva.n_summaries(self.cfg),
+                "prefix_reuse": "off: reuse is by token position and a "
+                                "window restarts",
+                "kv_paged": "refused at start"}
 
     # ------------------------------------------------------------------
     @property
@@ -592,6 +641,8 @@ class Engine:
         """Whether a ``bucket``-sized prompt prefills as overlapped slices
         (vs one monolithic program).  Buckets at or under the slice size
         gain nothing from slicing and keep the single-program path."""
+        if self.cfg.eva_window:    # a pass lies inside one window: always
+            return bucket > self._prefill_chunk
         return (self._SLICE_PREFILL and self._prefill_overlap > 0
                 and bucket > self._prefill_chunk)
 
@@ -681,10 +732,47 @@ class Engine:
         from: nothing is fetched)."""
         state, out = generate_chunk_jit(self.params, self.cfg, state, st,
                                         n_steps=n_steps, top_k=top_k)
-        read, live = decode_chunk_slots(pos, n_steps, self.cfg.n_ctx)
-        self.ring_slots["read"] += read
-        self.ring_slots["live"] += live
+        self._note_cache_read([pos], n_steps)
         return state, self._take_expert_stats(out)
+
+    def _note_cache_read(self, wanted: list, n_steps: int,
+                         live: list | None = None) -> None:
+        """Count one decode chunk's attention read against what it needed,
+        per step and summed over the sequences at positions ``wanted``
+        (their first step's), every one reading up to the bound of the
+        positions ``live`` (the lanes a chunk was dispatched as live;
+        default ``wanted``).  A ring counts its slots (``ring_slots``:
+        models/llama.py ``decode_chunk_slots``), a window + summary cache
+        its two stores and the windows closed (``eva_counts``: models/eva.py
+        ``chunk_counts``).  Host arithmetic, nothing fetched: the one
+        place the engines tell the cache kinds apart when they count."""
+        if self.cfg.eva_window:
+            for k, v in eva.chunk_counts(wanted, n_steps, self.cfg,
+                                         live).items():
+                self.eva_counts[k] += v
+            return
+        bound = max(wanted if live is None else live, default=0)
+        for p in wanted:
+            read, lv = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound)
+            self.ring_slots["read"] += read
+            self.ring_slots["live"] += lv
+
+    def cache_read_gauges(self) -> dict:
+        """The counters of :meth:`_note_cache_read` under their /metrics
+        names: ``ring_slots_*`` for every engine (0 on a cache that is no
+        ring), ``eva_*`` for a window + summary cache alone."""
+        out = {"ring_slots_read_total": self.ring_slots["read"],
+               "ring_slots_live_total": self.ring_slots["live"]}
+        if self.cfg.eva_window:
+            c = self.eva_counts
+            out.update(
+                eva_lane_steps_total=c["lane_steps"],
+                eva_window_slots_read_total=c["window_read"],
+                eva_window_slots_live_total=c["window_live"],
+                eva_summaries_read_total=c["summaries_read"],
+                eva_summaries_live_total=c["summaries_live"],
+                eva_windows_closed_total=c["windows_closed"])
+        return out
 
     def _take_expert_stats(self, chunk_out):
         """A decode chunk's tokens; a routed block's counters go to
@@ -881,6 +969,7 @@ class Engine:
             reuse = self._paged_reuse(ids, n_prompt, bucket, pspan)
         if pspan is not None:
             pspan.set(n_prompt=n_prompt, bucket=bucket, reused=reuse)
+        self._note_prefill_windows(n_prompt, pspan)
         # claim nothing while this request is in flight: an exception past
         # this point must not leave a stale prefix claim over a cache whose
         # contents are indeterminate
@@ -929,6 +1018,15 @@ class Engine:
             "reused": reuse, "ttft_s": ttft_s, "span": espan,
             "bucket": bucket,
         }
+
+    def _note_prefill_windows(self, n_prompt: int, pspan=None) -> None:
+        """The windows a prompt's prefill closes (the window + summary
+        cache alone): counted, and on the traced ``prefill`` span."""
+        if self.cfg.eva_window:
+            n = eva.windows_closed_by_prefill(n_prompt, self.cfg)
+            self.eva_counts["windows_closed"] += n
+            if pspan is not None:
+                pspan.set(windows_closed=n)
 
     def _prefix_reuse_len(self, ids: list, n_prompt: int, bucket: int) -> int:
         """Longest usable common prefix of ``ids`` vs the KV resident in the

@@ -48,6 +48,12 @@ class SPEngine(Engine):
     #: dense sharded ring; greedy output is identical either way).
     _KV_PAGED = False
 
+    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
+        raise ValueError(
+            "LFKT_MESH_SP > 1 cannot serve architecture 'evabyte': the sp "
+            "ring shards the n_ctx slots of a KV ring, and its cache is a "
+            "window plus chunk summaries")
+
     def __init__(self, model_path: str | None, *, sp: int = 2, tp: int = 1,
                  n_ctx: int = 4096, **kw):
         if sp < 2:

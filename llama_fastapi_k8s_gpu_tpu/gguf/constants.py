@@ -110,9 +110,17 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: (GQA attention + SwiGLU), ``olmoe`` the routed block (QK-norm over the
 #: whole projection, a float32 router over ``<arch>.expert_count`` SwiGLU
 #: experts stacked in 3-D ``ffn_*_exps`` tensors, ``expert_used_count`` of
-#: them per token, their probabilities unnormalised).  A file of any other
-#: architecture is refused by name at load (gguf/reader.py).
-SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe")
+#: them per token, their probabilities unnormalised); ``evabyte`` is the dense
+#: block over the second cache kind (models/eva.py): multi-head attention
+#: over an exact window of ``<arch>.attention.window_size`` positions plus
+#: one summary per ``<arch>.attention.chunk_size`` positions of every
+#: earlier window, pooled with the two F32 tensors ``blk.N.attn_eva_phi`` /
+#: ``attn_eva_mu`` (n_heads, head_dim) (this repo's names: llama.cpp has
+#: none), a float32 residual stream, and an output matrix of ``vocab_size *
+#: <arch>.prediction_heads`` rows.  A unit-offset norm gain is stored as
+#: applied (1 + g), as llama.cpp's converters store them.  A file of any
+#: other architecture is refused by name at load (gguf/reader.py).
+SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
@@ -120,7 +128,7 @@ SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe")
 #: converter permutes Q/K rows so that the pairs are (2i, 2i+1) (ggml's
 #: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
 #: the whole projection.
-NEOX_ROPE_ARCHITECTURES = ("olmoe",)
+NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte")
 
 
 def align_up(n: int, alignment: int) -> int:

@@ -57,6 +57,19 @@ class ModelConfig:
     # instead of 2i with 2i+1: by the file's architecture
     # (gguf/constants.py NEOX_ROPE_ARCHITECTURES)
     rope_neox: bool = False
+    # The cache KIND (models/eva.py, docs/KV_CACHE.md): ``eva_window == 0``
+    # is the ring of ``n_ctx`` slots; otherwise an exact window of
+    # ``eva_window`` positions (blocked, not sliding) plus one summary per
+    # ``eva_chunk`` positions of every earlier window (``evabyte``).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # The output matrix has ``vocab_size * n_pred_heads`` rows; head 0 (the
+    # first ``vocab_size``) is the next token, the one a step samples from
+    n_pred_heads: int = 1
+    # The precision the configuration states (``fp32_skip_add``,
+    # ``fp32_logits``): the residual stream and the logits are float32,
+    # matmul inputs stay bf16
+    fp32_residual: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -79,12 +92,30 @@ class ModelConfig:
             vocab = len(gf.metadata["tokenizer.ggml.tokens"])
         window = int(h("attention.sliding_window", 0) or 0)
         train_ctx = int(h("context_length", 4096))
+        n_kv_heads = int(h("attention.head_count_kv", n_heads))
+        eva = {}
+        if arch == "evabyte":
+            eva = dict(
+                eva_window=int(h("attention.window_size")),
+                eva_chunk=int(h("attention.chunk_size")),
+                n_pred_heads=int(h("prediction_heads", 1)),
+                fp32_residual=True)
+            W, C = eva["eva_window"], eva["eva_chunk"]
+            if C < 1 or W % C:
+                raise ValueError(
+                    f"evabyte: attention.window_size {W} is no multiple of "
+                    f"attention.chunk_size {C}")
+            if n_kv_heads != n_heads:
+                raise ValueError(
+                    f"evabyte: {n_kv_heads} KV heads for {n_heads} heads: "
+                    "the chunk summaries are per head (phi, mu), so the "
+                    "block is multi-head only")
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
             n_layers=int(h("block_count")),
             n_heads=n_heads,
-            n_kv_heads=int(h("attention.head_count_kv", n_heads)),
+            n_kv_heads=n_kv_heads,
             ffn_dim=int(h("feed_forward_length")),
             n_ctx=int(n_ctx if n_ctx is not None else min(train_ctx, 4096)),
             rope_theta=float(h("rope.freq_base", 10000.0)),
@@ -98,6 +129,7 @@ class ModelConfig:
             norm_topk_prob=False,
             qk_norm=arch == "olmoe",
             rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
+            **eva,
         )
 
 

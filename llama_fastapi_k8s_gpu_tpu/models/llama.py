@@ -18,7 +18,10 @@ preallocated, donated KV cache.  Design choices are TPU-first:
 - the feed-forward kind is the configuration's: dense SwiGLU, or
   (``cfg.n_experts``) a float32 router over SwiGLU experts whose products
   are computed for the picked experts only (ops/pallas/experts.py); so is
-  the RMSNorm of Q and K (``cfg.qk_norm``).  One layer body, not a copy.
+  the RMSNorm of Q and K (``cfg.qk_norm``), the cache kind
+  (``cfg.eva_window``: an exact window plus chunk summaries in place of
+  the ring, models/eva.py) and the float32 residual stream
+  (``cfg.fp32_residual``).  One layer body, not a copy.
 
 RoPE is the *interleaved* (ggml "NORM") variant: GGUF conversion permutes
 Q/K weights to this convention, so parity with llama.cpp requires it.
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 
 from ..ops import linear
 from ..ops.linear import linear_at
+from . import eva
 from .config import ModelConfig
 
 
@@ -93,7 +97,12 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     layout (docs/KV_CACHE.md): int8 value rings ``k_q``/``v_q`` of the same
     shape plus per-head, per-token symmetric f32 scales ``k_s``/``v_s``
     (L, n_kv, n_ctx) — HBM per token-head drops 2·hd → hd + 4 bytes, and
-    attention reads stream int8."""
+    attention reads stream int8.
+
+    ``cfg.eva_window`` is the other cache KIND (models/eva.py): two window
+    leaves of ``eva_window`` slots and two summary leaves."""
+    if cfg.eva_window:
+        return eva.init_cache(cfg, dtype)
     shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
     if cfg.kv_dtype == "int8":
         sshape = shape[:-1]
@@ -113,6 +122,8 @@ def cache_nbytes(cfg: ModelConfig) -> int:
     per lane) — the /health ``kv_cache_bytes`` figure and the lane-headroom
     math in docs/KV_CACHE.md, computed from shapes so callers never need a
     live cache."""
+    if cfg.eva_window:
+        return eva.cache_nbytes(cfg)
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
         + (4 if cfg.kv_dtype == "int8" else 0)
     return 2 * cfg.n_layers * cfg.n_kv_heads * cfg.n_ctx * per_tok_head
@@ -321,6 +332,54 @@ def expert_stats_len(cfg: ModelConfig) -> int:
     return 2 + cfg.n_experts
 
 
+def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
+                    kv_bound, cfg: ModelConfig, dtype):
+    """One layer's attention over a RING cache, after the write: ``ck`` /
+    ``cv`` the layer's ring (``cks`` / ``cvs`` its int8 scales or None),
+    by ``cfg.attn_impl`` and the pass's length.  (S, n_heads * head_dim)
+    in ``dtype``."""
+    S, hd, quant = q.shape[0], cfg.head_dim, cks is not None
+    if cfg.attn_impl == "ring":
+        # sequence-parallel: KV sharded over the sp mesh axis (parallel/ring.py)
+        from ..parallel.ring import ring_attention, sharded_decode_attention
+
+        if quant:
+            # the ring collectives pass K/V chunks chip-to-chip, so this
+            # path materializes the layer's ring in bf16 (elementwise →
+            # stays sp-sharded); only XLA/flash get the fused-scale reads
+            from ..ops.pallas.kvquant import dequantize_kv
+
+            ck = dequantize_kv(ck, cks, dtype)
+            cv = dequantize_kv(cv, cvs, dtype)
+        attn = ring_attention if S > 1 else sharded_decode_attention
+        ctx = attn(
+            q, ck, cv, pos_offset,
+            sm_scale=hd ** -0.5,
+            sliding_window=cfg.sliding_window,
+        ).reshape(S, cfg.n_heads * hd).astype(dtype)
+    elif cfg.attn_impl == "pallas" and S > 1:
+        # blockwise flash kernel: streams K/V, never materializes scores;
+        # int8 caches ride the fused-dequant path (scales folded in-kernel)
+        from ..ops.pallas import flash_attention, use_interpret
+
+        ctx = flash_attention(
+            q, ck, cv, pos_offset,
+            sm_scale=hd ** -0.5,
+            sliding_window=cfg.sliding_window,
+            k_scale=cks,
+            v_scale=cvs,
+            interpret=use_interpret(),
+        ).reshape(S, cfg.n_heads * hd).astype(dtype)
+    elif S == 1:
+        # a decode step reads the live part of the ring, not n_ctx slots
+        ctx = decode_attention(
+            q, cache, i, pos_offset,
+            pos_offset if kv_bound is None else kv_bound, cfg, dtype)
+    else:
+        ctx = xla_attention(q, ck, cv, cks, cvs, positions, cfg, dtype)
+    return ctx
+
+
 def _layer(h, layers, i, cache, positions, pos_offset,
            cfg: ModelConfig, live=None, kv_bound=None):
     """One transformer block over S tokens against layer ``i`` of the
@@ -331,7 +390,9 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     router's picks (S, k) int32).  ``live`` (scalar bool
     or None): False marks a lane that holds no request, whose rows then
     reach no expert (its output is not read).  ``kv_bound``: see
-    :func:`forward`.
+    :func:`forward`.  Under ``cfg.eva_window`` the leaves are the window
+    and summary leaves of models/eva.py, the S tokens lie inside ONE
+    window, and ``kv_bound`` is the triple of ``eva.live_bounds``.
 
     The weights stay STACKED (L, ...) and are addressed per layer with
     :func:`ops.linear.linear_at` — scanning them as xs would materialize a
@@ -357,7 +418,12 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         with jax.named_scope("kv_write"):
             return jax.lax.dynamic_update_slice(leaf, new[None], at)
 
-    hn = rms_norm(h, layers["attn_norm"][i], cfg.rms_eps)
+    def normed(x, name):
+        # a float32 residual stream feeds the matmuls bf16 all the same
+        xn = rms_norm(x, layers[name][i], cfg.rms_eps)
+        return xn.astype(jnp.bfloat16) if cfg.fp32_residual else xn
+
+    hn = normed(h, "attn_norm")
     q, k = lin(hn, "wq"), lin(hn, "wk")
     if cfg.qk_norm:   # over the whole projection, before heads and RoPE
         q = rms_norm(q, layers["attn_q_norm"][i], cfg.rms_eps)
@@ -368,7 +434,13 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     q = rope(q, positions, cfg)
     k = rope(k, positions, cfg)
 
-    if quant:
+    if cfg.eva_window:
+        # the other cache kind: its write, its attention and its window
+        # close are one step (models/eva.py)
+        ctx, cache = eva.attend(
+            q, k, v, cache, i, positions, kv_bound,
+            layers["eva_phi"][i], layers["eva_mu"][i], cfg, hn.dtype)
+    elif quant:
         # quantize ONLY the S new tokens' head-major slab (kvquant.py: int8
         # values + per-head per-token f32 scales), then write both planes
         from ..ops.pallas.kvquant import quantize_kv
@@ -394,47 +466,12 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         ck, cv = at_layer(cache["k"]), at_layer(cache["v"])
         cks = cvs = None
 
-    if cfg.attn_impl == "ring":
-        # sequence-parallel: KV sharded over the sp mesh axis (parallel/ring.py)
-        from ..parallel.ring import ring_attention, sharded_decode_attention
-
-        if quant:
-            # the ring collectives pass K/V chunks chip-to-chip, so this
-            # path materializes the layer's ring in bf16 (elementwise →
-            # stays sp-sharded); only XLA/flash get the fused-scale reads
-            from ..ops.pallas.kvquant import dequantize_kv
-
-            ck = dequantize_kv(ck, cks, h.dtype)
-            cv = dequantize_kv(cv, cvs, h.dtype)
-        attn = ring_attention if S > 1 else sharded_decode_attention
-        ctx = attn(
-            q, ck, cv, pos_offset,
-            sm_scale=hd ** -0.5,
-            sliding_window=cfg.sliding_window,
-        ).reshape(S, cfg.n_heads * hd).astype(h.dtype)
-    elif cfg.attn_impl == "pallas" and S > 1:
-        # blockwise flash kernel: streams K/V, never materializes scores;
-        # int8 caches ride the fused-dequant path (scales folded in-kernel)
-        from ..ops.pallas import flash_attention, use_interpret
-
-        ctx = flash_attention(
-            q, ck, cv, pos_offset,
-            sm_scale=hd ** -0.5,
-            sliding_window=cfg.sliding_window,
-            k_scale=cks,
-            v_scale=cvs,
-            interpret=use_interpret(),
-        ).reshape(S, cfg.n_heads * hd).astype(h.dtype)
-    elif S == 1:
-        # a decode step reads the live part of the ring, not n_ctx slots
-        ctx = decode_attention(
-            q, cache, i, pos_offset,
-            pos_offset if kv_bound is None else kv_bound, cfg, h.dtype)
-    else:
-        ctx = xla_attention(q, ck, cv, cks, cvs, positions, cfg, h.dtype)
+    if not cfg.eva_window:     # the other kind attended above, with its write
+        ctx = _ring_attention(q, ck, cv, cks, cvs, cache, i, positions,
+                              pos_offset, kv_bound, cfg, h.dtype)
     h = h + lin(ctx, "wo")
 
-    hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
+    hn = normed(h, "ffn_norm")
     if cfg.n_experts:
         from ..ops.pallas.experts import routed_experts
 
@@ -447,7 +484,7 @@ def _layer(h, layers, i, cache, positions, pos_offset,
                 hn, picks, weights, layers["w_gate_exps"],
                 layers["w_up_exps"], layers["w_down_exps"], i)
         return h + out, cache, (count, picks)
-    gated = jax.nn.silu(lin(hn, "w_gate").astype(jnp.float32)).astype(h.dtype)
+    gated = jax.nn.silu(lin(hn, "w_gate").astype(jnp.float32)).astype(hn.dtype)
     h = h + lin(gated * lin(hn, "w_up"), "w_down")
     return h, cache, None
 
@@ -464,6 +501,7 @@ def forward(
     with_stats: bool = False,
     with_picks: bool = False,
     kv_bound: jax.Array | None = None,
+    all_heads: bool = False,
 ):
     """Run S tokens through the stack. Returns (logits, new_cache):
     logits (vocab,) at ``last_idx`` (default S-1), or (S, vocab) if
@@ -474,9 +512,18 @@ def forward(
     (scalar int32, a decode step only): the ring slot a decode step's
     attention reads up to (:func:`decode_attention`), default this
     sequence's own position; lanes ``vmap``ped over one step share the
-    largest live lane's, as an UNBATCHED value."""
+    largest live lane's, as an UNBATCHED value; under ``cfg.eva_window``
+    the triple of ``eva.live_bounds``.  ``all_heads``: the logits of every
+    prediction head (``vocab_size * n_pred_heads`` rows) and not head 0's
+    alone."""
     S = tokens.shape[0]
-    h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
+    if cfg.eva_window and S > cfg.eva_window:
+        raise ValueError(
+            f"architecture 'evabyte': {S} positions in one pass, its window "
+            f"holds {cfg.eva_window}: a prompt is prefilled in slices that "
+            "lie inside one window (LFKT_PREFILL_CHUNK)")
+    h = jnp.take(params["tok_emb"], tokens, axis=0).astype(
+        jnp.float32 if cfg.fp32_residual else jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
 
     # trace-time layer-count check over EVERY stacked leaf: looping over ids
@@ -518,18 +565,29 @@ def forward(
     out_w = params["output"]
     tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))
                  if want)
-    if return_all:
-        hn = rms_norm(h, params["out_norm"], cfg.rms_eps)
+
+    def head(x):
+        hn = rms_norm(x, params["out_norm"], cfg.rms_eps)
         with jax.named_scope("head"):
-            logits = linear(hn, out_w).astype(jnp.float32)
-        return (logits, new_cache, *tail)
+            if cfg.fp32_residual and "w" in out_w:
+                # float32 logits: bf16 inputs, the sums and the result f32
+                logits = jax.lax.dot_general(
+                    hn.astype(jnp.bfloat16), out_w["w"],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                logits = linear(hn.astype(jnp.bfloat16), out_w
+                                ).astype(jnp.float32)
+        if cfg.n_pred_heads > 1 and not all_heads:
+            logits = logits[:, :cfg.vocab_size]   # head 0: the next token
+        return logits
+
+    if return_all:
+        return (head(h), new_cache, *tail)
     if last_idx is None:
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
-    hn = rms_norm(h_last, params["out_norm"], cfg.rms_eps)
-    with jax.named_scope("head"):
-        logits = linear(hn, out_w).astype(jnp.float32)[0]
-    return (logits, new_cache, *tail)
+    return (head(h_last)[0], new_cache, *tail)
 
 
 def prefill(params, cfg: ModelConfig, tokens, length, cache):

@@ -13,7 +13,9 @@ Formats (``ops.linear``):
 - ``q4k`` — fused serving: Q4_K / Q5_K / Q6_K / Q8_0 tensors stay in
   (nearly) their GGUF bit layouts in HBM (~5 / 6 / 7 / 9 bit/weight) and
   are dequantized in-VMEM by their fused Pallas matmuls (ops/pallas/
-  q*matmul.py); anything else falls back to int8.  The v5e serving
+  q*matmul.py; a K that their 2048-wide tile does not divide is filled up
+  with zero blocks where that adds at most a quarter: ``ops.linear.
+  padded_k``); anything else falls back to int8.  The v5e serving
   format: lowest decode HBM traffic at file fidelity.  Because per-layer
   tensors are stacked for ``lax.scan``, the choice is per tensor *name*:
   a name fuses only if every layer's tensor of that name shares one
@@ -28,7 +30,10 @@ F32 router ``blk.{i}.ffn_gate_inp.weight`` (E, dim) and the 3-D
 ``blk.{i}.ffn_{gate,up,down}_exps.weight`` (E, out, in), which stay fused
 K-quant planes with a leading (layer, expert) pair of axes
 (ops/pallas/experts.py); with ``cfg.qk_norm`` also
-``blk.{i}.attn_{q,k}_norm.weight``.
+``blk.{i}.attn_{q,k}_norm.weight``.  The window + summary cache kind
+(``cfg.eva_window``; ``evabyte``) adds the two F32 pooling vectors
+``blk.{i}.attn_eva_{phi,mu}.weight`` (n_heads, head_dim), and its float32
+logits keep a float output matrix bf16 instead of requantizing it.
 """
 
 from __future__ import annotations
@@ -41,6 +46,7 @@ import numpy as np
 
 from ..gguf import GGUFFile
 from ..ops import make_linear_bf16, make_linear_int8, make_linear_int8_device
+from ..ops.linear import padded_k
 from .config import ModelConfig
 
 logger = logging.getLogger(__name__)
@@ -117,6 +123,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         from ..gguf.constants import GGMLType
         from ..ops.pallas.qmatmul import q4k_compatible
 
+        def fits_padded(n_out, k_in):
+            return q4k_compatible(n_out, padded_k(k_in))
+
         fusable = tuple(fused_types) if fused_types is not None \
             else (GGMLType.Q4_K, GGMLType.Q5_K, GGMLType.Q6_K, GGMLType.Q8_0)
         k_rank = {GGMLType.Q4_K: 0, GGMLType.Q5_K: 1, GGMLType.Q6_K: 2}
@@ -133,7 +142,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             # an expert stack (E, out, in): the grouped kernels' two types
             fits, allowed = (experts_compatible, [
                 t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
-                if n.endswith("_exps") else (q4k_compatible, fusable)
+                if n.endswith("_exps") else (fits_padded, fusable)
             if not all(fits(*reversed(t.shape[:2])) for t in ts):
                 continue
             types = {t.ggml_type for t in ts}
@@ -147,7 +156,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     ok[n] = target
         t = gf.tensors.get("output.weight")
         if t is not None and t.ggml_type in fusable \
-                and q4k_compatible(*reversed(t.shape)):
+                and fits_padded(*reversed(t.shape)):
             ok["output"] = t.ggml_type
         return ok
 
@@ -171,6 +180,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
 
             t = gf[name]
             target = fused_names[short]
+            n_out, k_in = tuple(reversed(t.shape))
+            k_pad = padded_k(k_in)
             if t.ggml_type != target:
                 # K-quant promotion (mixed-type name): dequantize and
                 # requantize onto the name's chosen finer grid
@@ -178,12 +189,21 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
 
                 maker = {GGMLType.Q5_K: make_linear_q5k,
                          GGMLType.Q6_K: make_linear_q6k}[target]
-                return maker(t.astype_f32())
-            n_out, k_in = tuple(reversed(t.shape))
+                return maker(np.pad(t.astype_f32(),
+                                    ((0, 0), (0, k_pad - k_in))))
             prep = {GGMLType.Q4_K: prep_q4k, GGMLType.Q5_K: prep_q5k,
                     GGMLType.Q6_K: prep_q6k,
                     GGMLType.Q8_0: prep_q8_0}[target]
-            return prep(np.asarray(t.raw()), n_out, k_in)
+            raw = np.asarray(t.raw())
+            if k_pad != k_in:
+                # a row's last K tile filled up with all-zero blocks (scale
+                # 0: every weight of them is 0), so that the file's own
+                # blocks serve the fused kernel (``linear`` pads the
+                # activations with zeros to match)
+                raw = raw.reshape(n_out, -1)
+                raw = np.pad(raw, ((0, 0), (
+                    0, raw.shape[1] * (k_pad - k_in) // k_in))).reshape(-1)
+            return prep(raw, n_out, k_pad)
         if on_device:
             w = _tensor_to_device(gf[name])
             if base_fmt == "int8":
@@ -241,6 +261,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         if cfg.qk_norm:
             layer["attn_q_norm"] = norm(p + "attn_q_norm.weight")
             layer["attn_k_norm"] = norm(p + "attn_k_norm.weight")
+        if cfg.eva_window:
+            layer["eva_phi"] = norm(p + "attn_eva_phi.weight")
+            layer["eva_mu"] = norm(p + "attn_eva_mu.weight")
         if cfg.n_experts:
             layer["w_router"] = norm(p + "ffn_gate_inp.weight")
             for key in ("gate", "up", "down"):
@@ -261,6 +284,12 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         emb = jnp.asarray(gf["token_embd.weight"].astype_f32(), dtype=jnp.bfloat16)
     if cfg.tie_embeddings or "output.weight" not in gf.tensors:
         output = {"w": emb}
+    elif cfg.fp32_residual and gf["output.weight"].ggml_type.name in (
+            "F32", "F16", "BF16"):
+        # float32 logits from a float head: bf16 inputs, nothing requantized
+        t = gf["output.weight"]
+        output = {"w": _tensor_to_device(t, jnp.bfloat16) if on_device
+                  else jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)}
     else:
         output = lin("output.weight")
     t0 = _time.time()
@@ -317,11 +346,16 @@ def synth_params(cfg: ModelConfig, fmt: str = "bf16", seed: int = 0,
             "w_up": lin(cfg.ffn_dim, cfg.dim),
             "w_down": lin(cfg.dim, cfg.ffn_dim),
         })
+        if cfg.eva_window:
+            for name in ("eva_phi", "eva_mu"):
+                layers[-1][name] = jnp.asarray(rng.standard_normal(
+                    (cfg.n_heads, cfg.head_dim), dtype=np.float32))
     emb = jnp.asarray(
         rng.standard_normal((cfg.vocab_size, cfg.dim), dtype=np.float32) * scale,
         dtype=jnp.bfloat16,
     )
-    output = {"w": emb} if cfg.tie_embeddings else lin(cfg.vocab_size, cfg.dim)
+    output = {"w": emb} if cfg.tie_embeddings \
+        else lin(cfg.vocab_size * cfg.n_pred_heads, cfg.dim)
     return {
         "tok_emb": emb,
         "layers": _stack(layers),

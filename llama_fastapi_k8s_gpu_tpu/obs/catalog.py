@@ -324,6 +324,33 @@ METRICS: dict[str, Metric] = _register(
            "over the same steps and lanes; over ring_slots_read_total = the "
            "share of the read that was needed (a whole-ring read of 4096 "
            "at chat lengths: 0.11)"),
+    # -- the window + summary cache's read (models/eva.py; ``evabyte``) -----
+    Metric("eva_lane_steps_total", GAUGE,
+           "decode steps summed over the lanes that hold a request (a "
+           "serial engine: its steps): what the eva_* sums below are over, "
+           "so that their ratio to it is entries per lane and step"),
+    Metric("eva_window_slots_read_total", GAUGE,
+           "window slots the decode steps' attention covered (whole blocks "
+           "up to the fill, position mod window; on a lane engine up to "
+           "the largest LIVE lane's fill; models/eva.py decode_attention), "
+           "summed over decode steps and over the lanes that hold a "
+           "request, cumulative; from host-tracked positions, nothing "
+           "fetched; exported by a file of that cache kind only"),
+    Metric("eva_window_slots_live_total", GAUGE,
+           "window slots at or below the sequence's own fill, over the "
+           "same steps and lanes"),
+    Metric("eva_summaries_read_total", GAUGE,
+           "chunk summaries the decode steps' attention covered (one "
+           "closed window's at a time, up to the live lane with most "
+           "closed windows), over the same steps and lanes"),
+    Metric("eva_summaries_live_total", GAUGE,
+           "chunk summaries of the windows before the sequence's own, over "
+           "the same steps and lanes; (window + summaries) live over read "
+           "= the share of the read that was needed"),
+    Metric("eva_windows_closed_total", GAUGE,
+           "windows turned into their chunk summaries: whole windows of a "
+           "prompt at its prefill, and a decode step that writes a "
+           "window's last position; cumulative"),
     # -- runtime-synthesized families --------------------------------------
     Metric("scheduler_", GAUGE,
            "continuous-scheduler family (ContinuousEngine.scheduler_stats). "

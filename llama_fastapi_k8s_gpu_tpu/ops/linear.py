@@ -101,6 +101,23 @@ def make_linear_q5k(w: np.ndarray) -> dict:
     return prep_q5k(quant_q5_k(w.reshape(-1)), n_out, k_in)
 
 
+def padded_k(k_in: int) -> int:
+    """The K a fused layout of a ``k_in``-wide matrix is stored at: the next
+    multiple of the kernels' K tile where that adds at most a quarter
+    (11008 -> 12288: the loader fills the last tile up with zero blocks and
+    :func:`linear` the activations with zeros), else ``k_in`` itself (so
+    narrow a matrix is no fused kernel's shape)."""
+    from .pallas.qmatmul import TK
+
+    k_pad = -(-k_in // TK) * TK
+    return k_pad if 4 * (k_pad - k_in) <= k_in else k_in
+
+
+def _pad_k(x: jax.Array) -> jax.Array:
+    pad = padded_k(x.shape[-1]) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
 def _fused_fns(w: dict):
     """(matmul, matmul_stacked) for a fused-layout weight dict, or None.
     The single dispatch point shared by :func:`linear` and
@@ -128,7 +145,7 @@ def linear(x: jax.Array, w: dict) -> jax.Array:
     """x: (..., in) bf16 → (..., out) bf16."""
     fns = _fused_fns(w)
     if fns is not None:
-        return fns[0](x, w)
+        return fns[0](_pad_k(x), w)
     if "w" in w:
         return jax.lax.dot_general(
             x, w["w"],
@@ -162,5 +179,5 @@ def linear_at(x: jax.Array, w: dict, idx) -> jax.Array:
     it was never the bottleneck."""
     fns = _fused_fns(w)
     if fns is not None:
-        return fns[1](x, w, idx)
+        return fns[1](_pad_k(x), w, idx)
     return linear(x, jax.tree_util.tree_map(lambda a: a[idx], w))

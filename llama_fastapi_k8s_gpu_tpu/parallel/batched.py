@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
+from ..models import eva
 from ..models.generate import chunk_out
 from ..models.llama import forward, init_cache, prefill
 from ..obs.devtime import timed_jit
@@ -52,6 +53,16 @@ def live_bound(pos: jax.Array, live: jax.Array | None = None) -> jax.Array:
     freed lane keeps stepping and its position walks on; it must not drag
     the read to ``n_ctx``."""
     return jnp.max(pos if live is None else jnp.where(live, pos, 0))
+
+
+def step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
+    """What ``forward`` takes as a lane step's ``kv_bound``, by the cache
+    kind: :func:`live_bound`, or for the window + summary cache the three
+    scalars of ``models/eva.py live_bounds`` (one bound becomes two, and
+    whether any live lane closes a window in this step)."""
+    if cfg.eva_window:
+        return eva.live_bounds(pos, live, cfg)
+    return live_bound(pos, live)
 
 
 def state_nbytes(state: dict | None) -> int:
@@ -89,7 +100,7 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
     of sampling knobs.  Returns (state, tokens (n_steps, B))."""
 
     def one_step(carry, _):
-        bound = live_bound(carry["pos"])
+        bound = step_bound(cfg, carry["pos"])
 
         def single(token, pos, cache, window, wpos, key):
             logits, cache, *stats = forward(
@@ -136,7 +147,7 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     expert, so a step reads what its live lanes picked."""
 
     def one_step(carry, _):
-        bound = live_bound(carry["pos"], live)
+        bound = step_bound(cfg, carry["pos"], live)
 
         def single(token, pos, cache, window, wpos, key, st, live):
             logits, cache, *stats = forward(

@@ -136,6 +136,8 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, batched: bool = False):
     spec and the (L, n_kv, ctx) scale planes get it minus the hd axis."""
     lead = ("dp",) if batched else ()
     s4 = _ns(mesh, *lead, None, "tp", None, None)
+    if cfg.eva_window:   # window + summary leaves (models/eva.py), tp = 1
+        return {name: s4 for name in ("k", "v", "sk", "sv")}
     if cfg.kv_dtype == "int8":
         s3 = _ns(mesh, *lead, None, "tp", None)
         return {"k_q": s4, "v_q": s4, "k_s": s3, "v_s": s3}

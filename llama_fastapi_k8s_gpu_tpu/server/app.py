@@ -1470,6 +1470,12 @@ def create_app(engine=None, settings: Settings | None = None,
             occ = getattr(eng, "kv_pool_occupancy", None)
             if callable(occ):
                 engine_info["kv_pool"] = occ()
+            # a cache that is no ring (models/eva.py): its sizes, and the
+            # reuse it does without as a property of the cache; absent on
+            # a ring, whose /health is what it was
+            kind = getattr(eng, "cache_kind", None)
+            if kind:
+                engine_info["cache"] = kind
             # multi-model registry: one row per served model (name, quant,
             # weight bytes, load state — docs/MULTIMODEL.md) next to the
             # kv_pool block; absent on single-model pods, whose /health is
@@ -1563,10 +1569,11 @@ def create_app(engine=None, settings: Settings | None = None,
                 m.set_gauge("lane_idle_seconds", snap["lane_idle_seconds"])
         # the decode steps' read of the KV ring against what was live
         # (Engine.ring_slots; the lane engine adds at each chunk's harvest)
-        ring = getattr(app.state.engine, "ring_slots", None)
-        if ring is not None:
-            m.set_gauge("ring_slots_read_total", ring["read"])
-            m.set_gauge("ring_slots_live_total", ring["live"])
+        # and the same for a cache that is no ring, each kind under its own
+        # names (Engine.cache_read_gauges)
+        reads = getattr(app.state.engine, "cache_read_gauges", None)
+        for name, value in (reads() if reads is not None else {}).items():
+            m.set_gauge(name, value)
         # routed layers (a file with experts): cumulative counters of the
         # decode chunks that have finished, folded here and not on the
         # decode path (engine/expert_counters.py)

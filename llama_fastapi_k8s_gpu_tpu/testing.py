@@ -338,6 +338,119 @@ def write_llama3_8b_q4km_gguf(path: str, n_layers: int | None = None,
     return cfg
 
 
+TINY_EVABYTE_CFG = ModelConfig(
+    vocab_size=64 + 256, dim=128, n_layers=3, n_heads=4, n_kv_heads=4,
+    ffn_dim=192, n_ctx=320, rope_theta=100000.0, rope_neox=True,
+    eva_window=64, eva_chunk=4, n_pred_heads=2, fp32_residual=True,
+)
+
+#: the type mix of the benchmark's ``evabyte`` file (llama.cpp's Q4_K_M
+#: recipe; the embeddings and the prediction heads stay F16)
+EVABYTE_Q4KM_MIX = {
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+}
+
+EVABYTE_CONTROL = 64      # control tokens before the 256 byte tokens
+
+
+def evabyte_vocab(n_control: int = EVABYTE_CONTROL
+                  ) -> tuple[list[str], list[int]]:
+    """``n_control`` control tokens (``<pad>``, ``<s>``, ``</s>``, then
+    placeholders), then one BYTE token per byte value: the ``bytes``
+    vocabulary of tokenizer/bytes.py."""
+    named = ["<pad>", "<s>", "</s>"]
+    tokens = named + [f"<unused_{i}>" for i in range(len(named), n_control)]
+    tokens += [f"<0x{b:02X}>" for b in range(256)]
+    types = [int(TokenType.CONTROL)] * n_control + [int(TokenType.BYTE)] * 256
+    return tokens, types
+
+
+MISTRAL_CHAT_TEMPLATE = (
+    "{{bos_token}}{% for m in messages %}{% if m['role'] == 'user' %}"
+    "[INST] {{m['content']}} [/INST]{% else %}{{m['content']}}</s>"
+    "{% endif %}{% endfor %}")
+
+
+def write_tiny_evabyte_gguf(path: str, cfg: ModelConfig = TINY_EVABYTE_CFG,
+                            seed: int = 0, quant: GGMLType = GGMLType.F16,
+                            mix: dict | None = None,
+                            pool_scale: float = 8.0,
+                            embed_scale: float = 1.0) -> ModelConfig:
+    """Write a random-weight ``evabyte`` GGUF: the dense block over the
+    window + summary cache (``<arch>.attention.window_size`` /
+    ``chunk_size``), the two F32 pooling vectors a layer
+    (``attn_eva_phi`` / ``attn_eva_mu``, (n_heads, head_dim)),
+    ``<arch>.prediction_heads`` heads in one F16 output matrix, and the
+    ``bytes`` vocabulary.  Every matrix is ``quant`` unless ``mix`` names
+    its type (:data:`EVABYTE_Q4KM_MIX`).  The pooling vectors are
+    ``pool_scale`` times a unit normal's ``head_dim ** -0.5``, so that the
+    weights inside a chunk are far from uniform and ``mu`` is no rounding
+    error: a program that drops either shows.  ``embed_scale`` multiplies
+    the embedding table: a residual stream that is large beside what a
+    layer adds to it is where a bfloat16 stream loses the additions, which
+    is what the float32 one (``fp32_skip_add``) is for."""
+    tokens, types = evabyte_vocab(cfg.vocab_size - 256)
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    arch = "evabyte"
+    w = GGUFWriter(path)
+    w.add_metadata("general.architecture", arch)
+    w.add_metadata("general.name", "tiny-evabyte-test")
+    w.add_metadata(f"{arch}.block_count", cfg.n_layers)
+    w.add_metadata(f"{arch}.context_length", cfg.n_ctx)
+    w.add_metadata(f"{arch}.embedding_length", cfg.dim)
+    w.add_metadata(f"{arch}.feed_forward_length", cfg.ffn_dim)
+    w.add_metadata(f"{arch}.attention.head_count", cfg.n_heads)
+    w.add_metadata(f"{arch}.attention.head_count_kv", cfg.n_kv_heads)
+    w.add_metadata(f"{arch}.attention.layer_norm_rms_epsilon", cfg.rms_eps)
+    w.add_metadata(f"{arch}.rope.freq_base", cfg.rope_theta)
+    w.add_metadata(f"{arch}.vocab_size", cfg.vocab_size)
+    w.add_metadata(f"{arch}.attention.window_size", cfg.eva_window)
+    w.add_metadata(f"{arch}.attention.chunk_size", cfg.eva_chunk)
+    w.add_metadata(f"{arch}.prediction_heads", cfg.n_pred_heads)
+    w.add_metadata("tokenizer.ggml.model", "bytes")
+    w.add_metadata("tokenizer.ggml.tokens", tokens)
+    w.add_metadata("tokenizer.ggml.token_type", types)
+    w.add_metadata("tokenizer.ggml.bos_token_id", tokens.index("<s>"))
+    w.add_metadata("tokenizer.ggml.eos_token_id", tokens.index("</s>"))
+    w.add_metadata("tokenizer.chat_template", MISTRAL_CHAT_TEMPLATE)
+    mix = mix or {}
+    D, F, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
+
+    def t(name, shape, gtype=None, mul=scale):
+        short = name.split(".")[-2]
+        w.add_tensor(name, rng.standard_normal(shape).astype(np.float32)
+                     * mul, mix.get(short, quant) if gtype is None else gtype)
+
+    def norm(name, n):   # (1 + g), stored as applied; near one, not one
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16,
+      scale * embed_scale)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight", D)
+        t(p + "attn_q.weight", (D, D))
+        t(p + "attn_k.weight", (D, D))
+        t(p + "attn_v.weight", (D, D))
+        t(p + "attn_output.weight", (D, D))
+        for name in ("attn_eva_phi", "attn_eva_mu"):
+            t(p + name + ".weight", (cfg.n_heads, hd), GGMLType.F32,
+              pool_scale * hd ** -0.5)
+        norm(p + "ffn_norm.weight", D)
+        t(p + "ffn_gate.weight", (F, D))
+        t(p + "ffn_up.weight", (F, D))
+        t(p + "ffn_down.weight", (D, F))
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size * cfg.n_pred_heads, D), GGMLType.F16)
+    w.write()
+    return cfg
+
+
 def spm_byte_vocab() -> tuple[list[str], list[int], list[float]]:
     """Minimal SentencePiece-style vocab: specials + full byte fallback."""
     tokens = ["<unk>", "<s>", "</s>", "▁"]
@@ -387,12 +500,7 @@ def write_tiny_mistral_gguf(
     w.add_metadata("tokenizer.ggml.scores", scores)
     w.add_metadata("tokenizer.ggml.bos_token_id", 1)
     w.add_metadata("tokenizer.ggml.eos_token_id", 2)
-    w.add_metadata(
-        "tokenizer.chat_template",
-        "{{bos_token}}{% for m in messages %}{% if m['role'] == 'user' %}"
-        "[INST] {{m['content']}} [/INST]{% else %}{{m['content']}}</s>"
-        "{% endif %}{% endfor %}",
-    )
+    w.add_metadata("tokenizer.chat_template", MISTRAL_CHAT_TEMPLATE)
 
     kv_dim = cfg.n_kv_heads * cfg.head_dim
 

@@ -4,7 +4,8 @@ The reference delegates tokenization entirely to the native engine
 (``llm.create_chat_completion(messages=...)``, reference api.py:55-63); the
 TPU framework implements the two tokenizer families GGUF models carry:
 byte-level BPE ("gpt2" model key — Llama-3) and SentencePiece-style
-("llama" model key — Mistral/Llama-2).  Vocabulary, merges, scores and
+("llama" model key — Mistral/Llama-2), and a vocabulary with no pieces at
+all ("bytes", this repo's own key — EvaByte: tokenizer/bytes.py).  Vocabulary, merges, scores and
 special-token metadata all come from GGUF KV pairs, never from network.
 """
 

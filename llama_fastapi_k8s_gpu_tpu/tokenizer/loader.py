@@ -6,6 +6,7 @@ from __future__ import annotations
 from ..gguf import GGUFFile
 from .base import Tokenizer
 from .bpe import BPETokenizer
+from .bytes import ByteTokenizer
 from .spm import SPMTokenizer
 
 
@@ -38,4 +39,7 @@ def tokenizer_from_gguf(gf: GGUFFile) -> Tokenizer:
             add_bos=add_bos,
             add_space_prefix=bool(md.get("tokenizer.ggml.add_space_prefix", True)),
         )
+    if model == "bytes":
+        return ByteTokenizer(tokens=tokens, token_types=token_types,
+                             bos_id=bos_id, eos_id=eos_id, add_bos=add_bos)
     raise NotImplementedError(f"tokenizer model {model!r}")

@@ -102,6 +102,10 @@ def _matmuls(fmt):
     ("q6k", 14336, 4096), ("q6k", 4096, 128256),
     ("q5k", 4096, 4096), ("q5k", 4096, 14336),
     ("q8", 4096, 4096), ("q8", 4096, 14336),
+    # EvaByte's feed-forward width: gate and up, and ffn_down's K = 11008
+    # as it is stored, its last tile filled up to 12288 (ops/linear.py
+    # padded_k)
+    ("q4k", 4096, 11008), ("q6k", 12288, 4096),
 ])
 def test_fused_matmul_compiles(one_chip, fmt, k, n):
     """Unstacked and stacked, one decode row and a 512-row prefill bucket
@@ -290,6 +294,85 @@ def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
     assert not found, found[:3]
     one_layer_ring = max(lanes, 1) * KV * 4096 * 128 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_ring
+
+
+# BENCHMARK.json's evabyte configuration at its published widths, n_ctx
+# 16384: (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("evabyte-serial", 0),
+                                        ("evabyte-4lane", 4)])
+def test_evabyte_step_reads_window_and_summaries_in_blocks(one_chip, name,
+                                                           lanes):
+    """The decode chunk and the prefill slice of the ``evabyte`` block
+    (models/eva.py: 32 MHA heads, window 2048, chunk 16, float32 residual,
+    8 prediction heads) compile for the chip at 16384 positions.  In the
+    decode chunk the compiler has put no window- or summary-leaf-sized
+    copy or transpose (the block reads and the window close slice the
+    STACKED leaves in place; the close's own read of one layer's window
+    is a dynamic-slice inside its branch), and its scratch stays under
+    three layers' windows: the close converts one window to float32."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    L, H, hd, W = 32, 32, 128, 2048
+    cfg = ModelConfig(vocab_size=320, dim=4096, n_layers=L, n_heads=H,
+                      n_kv_heads=H, ffn_dim=11008, n_ctx=16384,
+                      rope_theta=1e5, rope_neox=True, eva_window=W,
+                      eva_chunk=16, n_pred_heads=8, fp32_residual=True)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = _int8_params(cfg)
+    params["layers"]["eva_phi"] = S(L, H, hd, dtype=f32)
+    params["layers"]["eva_mu"] = S(L, H, hd, dtype=f32)
+    params["output"] = {"w": S(320 * 8, 4096)}
+    params = place(params)
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    assert state["cache"]["sk"].shape[-2:] == (896, hd)
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*(2048|896),128\]\S* "
+        r"(copy|transpose)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in compiled.as_text().splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        # a copy INSIDE a fusion is a layout of what the fusion reads
+        # (the close's slice), not a buffer of its own
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:160])
+    assert not found, found[:3]
+    one_layer_window = max(lanes, 1) * H * W * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * one_layer_window
+    if not lanes:       # the admission slice into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        sliced = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile()
+        assert sliced.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
 
 
 def _placed_on_four(topo):

@@ -340,13 +340,16 @@ def test_the_lane_counters_on_a_known_schedule():
     was dispatched with it live, its position 50 set the bound).  Blocks
     of 16: bounds 50..53 read 4 blocks = 64 slots a lane-step."""
     from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
 
     def slot(n_prompt, n_gens, finished=False):
         return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
                                      finished=finished)
 
-    eng = types.SimpleNamespace(cfg=types.SimpleNamespace(n_ctx=N_CTX),
-                                ring_slots={"read": 0, "live": 0})
+    eng = types.SimpleNamespace(
+        cfg=types.SimpleNamespace(n_ctx=N_CTX, eva_window=0),
+        ring_slots={"read": 0, "live": 0})
+    eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
     pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
     ContinuousEngine._note_ring_read(eng, pre, 4)
     assert eng.ring_slots == {

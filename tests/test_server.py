@@ -182,10 +182,11 @@ async def test_metrics_flattens_nested_scheduler_stats():
 @pytest.mark.parametrize("lanes", [False, True], ids=["serial", "lane"])
 async def test_metrics_exports_the_ring_slot_counters(lanes):
     """``ring_slots_read_total`` / ``ring_slots_live_total`` under ONE name
-    for the serial and the lane engine (``Engine.ring_slots``; a lane
-    engine also has ``scheduler_stats``)."""
+    for the serial and the lane engine (``Engine.cache_read_gauges``; a
+    lane engine also has ``scheduler_stats``)."""
     engine = FakeEngine()
-    engine.ring_slots = {"read": 1536, "live": 1100}
+    engine.cache_read_gauges = lambda: {"ring_slots_read_total": 1536,
+                                        "ring_slots_live_total": 1100}
     if lanes:
         engine.scheduler_stats = lambda: {"lanes_live": 2}
     app, transport = make_client(engine)
