@@ -1391,7 +1391,8 @@ class ContinuousEngine(MeshEngine):
         """Count one decode chunk's attention read against what it needed
         (models/llama.py ``decode_attention``): per step, summed over the
         lanes whose rows are still wanted, the ring slots the read covered
-        (every lane reads up to the largest position among the lanes the
+        (under the decode kernel the lane's own blocks; under the XLA loop
+        every lane reads up to the largest position among the lanes the
         chunk was dispatched as live, ``pre``) and the slots at or below
         the lane's own position (on a cache that is no ring its own two
         stores: ``Engine._note_cache_read`` counts either kind).  From the

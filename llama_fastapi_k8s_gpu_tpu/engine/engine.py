@@ -41,7 +41,8 @@ from ..models.generate import (
     split_chunk_out,
 )
 from ..models import eva
-from ..models.llama import decode_chunk_slots, init_cache
+from ..models.llama import (
+    decode_chunk_slots, decode_kernel_block, init_cache)
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
@@ -739,9 +740,12 @@ class Engine:
                          live: list | None = None) -> None:
         """Count one decode chunk's attention read against what it needed,
         per step and summed over the sequences at positions ``wanted``
-        (their first step's), every one reading up to the bound of the
-        positions ``live`` (the lanes a chunk was dispatched as live;
-        default ``wanted``).  A ring counts its slots (``ring_slots``:
+        (their first step's).  Under the XLA loop every one reads up to the
+        bound of the positions ``live`` (the lanes a chunk was dispatched
+        as live; default ``wanted``); under the decode kernel
+        (``decode_kernel_block``) each reads its OWN blocks, and a lane
+        that is not wanted reads nothing, so the sum is all the chunk
+        read.  A ring counts its slots (``ring_slots``:
         models/llama.py ``decode_chunk_slots``), a window + summary cache
         its two stores and the windows closed (``eva_counts``: models/eva.py
         ``chunk_counts``).  Host arithmetic, nothing fetched: the one
@@ -751,9 +755,12 @@ class Engine:
                                          live).items():
                 self.eva_counts[k] += v
             return
-        bound = max(wanted if live is None else live, default=0)
+        block = decode_kernel_block(self.cfg)
+        bound = None if block else max(
+            wanted if live is None else live, default=0)
         for p in wanted:
-            read, lv = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound)
+            read, lv = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound,
+                                          block)
             self.ring_slots["read"] += read
             self.ring_slots["live"] += lv
 

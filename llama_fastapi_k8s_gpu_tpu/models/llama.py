@@ -11,7 +11,10 @@ preallocated, donated KV cache.  Design choices are TPU-first:
   shape; a prefill masks the full ``n_ctx`` ring (prompt lengths are
   bucketed by the engine to bound recompiles), a decode step reads it in
   blocks up to the newest live slot, under a TRACED bound: every position
-  runs the one compiled decode program (:func:`decode_attention`);
+  runs the one compiled decode program (a kernel whose bound is each
+  lane's own, ops/pallas/attention.py ``flash_attention_decode``, or the
+  XLA loop of :func:`decode_attention` under one bound for all lanes:
+  :func:`decode_kernel_block` says which);
 - sliding-window masking (Mistral) is the same mask with one extra term;
 - matmuls go through ``ops.linear`` so bf16 / int8 / (later) fused-Q4_K
   weights are interchangeable without touching the graph.
@@ -181,7 +184,9 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
     return ctx.transpose(2, 0, 1, 3).reshape(S, cfg.n_heads * hd).astype(out_dtype)
 
 
-#: ring slots :func:`decode_attention` reads at a time.  Measured on the
+#: ring slots the XLA loop of :func:`decode_attention` reads at a time
+#: (the path of int8 rings, mesh and sequence-parallel engines and the CPU;
+#: the kernel's block is ``DECODE_KERNEL_BLOCK``).  Measured on the
 #: chip (PERF.md section 6, PR 31; 8 lanes x 32 layers, the read alone): at chat
 #: lengths 128, 256 and 512 take the same time (an iteration costs 3-4 us
 #: beside its bytes, which is what finer blocks would save in slots read
@@ -189,13 +194,40 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
 #: 512 costs 12 % over one pass over the whole ring, 256 26 %, 128 41 %.
 DECODE_KV_BLOCK = 512
 
+#: ring slots the decode KERNEL copies at a time
+#: (ops/pallas/attention.py ``flash_attention_decode``): (least, most, slots
+#: x KV heads a copy).  There a block's fixed cost is a copy's issue and a
+#: wait, not a loop iteration of two fusions, so finer blocks than the
+#: loop's pay, down to about 512 KB a copy (2048 head-slots of 128 bf16):
+#: 8 KV heads read fastest in blocks of 256, 16 in blocks of 128 (PERF.md
+#: section 6, PR 37, has what was tried).
+DECODE_KERNEL_BLOCK = (128, 512, 2048)
 
-def decode_read_slots(bound, n_ctx: int):
-    """(blocks, ring slots) :func:`decode_attention` covers when the newest
-    live position is ``bound``.  Integer arithmetic on a host int (the
-    engines' ``ring_slots_*`` counters) or on a traced scalar (the loop's
-    trip count) alike."""
-    block = min(DECODE_KV_BLOCK, n_ctx)
+
+def decode_kernel_block(cfg: ModelConfig) -> int:
+    """The block of the decode kernel where a decode step's attention runs
+    it, else 0 (the XLA loop of :func:`decode_attention`).  Decided by what
+    the configuration shows, no setting: the kernel serves a bf16 ring
+    under ``attn_impl == "pallas"`` (a TPU, ``head_dim % 128 == 0``, the
+    flash probe passed: engine/engine.py; a mesh engine resolves to
+    ``xla``, sequence parallelism to ``ring``) whose slots its block
+    divides; the block is a power of two by the KV heads a copy spans
+    (``DECODE_KERNEL_BLOCK``)."""
+    if cfg.attn_impl != "pallas" or cfg.eva_window or cfg.kv_dtype == "int8":
+        return 0
+    least, most, head_slots = DECODE_KERNEL_BLOCK
+    per_head = max(head_slots // cfg.n_kv_heads, 1)
+    block = min(max(1 << (per_head.bit_length() - 1), least), most, cfg.n_ctx)
+    return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
+
+
+def decode_read_slots(bound, n_ctx: int, block: int = 0):
+    """(blocks, ring slots) a decode step's attention covers when the
+    newest position it reads up to is ``bound``, in blocks of ``block``
+    (0: the XLA loop's ``DECODE_KV_BLOCK``).  Integer arithmetic on a
+    host int (the engines' ``ring_slots_*`` counters) or on a traced scalar
+    (the loop's trip count) alike."""
+    block = min(block or DECODE_KV_BLOCK, n_ctx)
     least = min if isinstance(bound, int) else jnp.minimum
     # ceil((bound + 1) / block), and no more blocks than the ring holds
     n_blocks = least((bound + block) // block, -(-n_ctx // block))
@@ -203,25 +235,32 @@ def decode_read_slots(bound, n_ctx: int):
 
 
 def decode_chunk_slots(pos: int, n_steps: int, n_ctx: int,
-                       bound: int | None = None) -> tuple[int, int]:
+                       bound: int | None = None,
+                       block: int = 0) -> tuple[int, int]:
     """(ring slots read, ring slots live) of ONE sequence over ``n_steps``
     decode steps from position ``pos``: a step at position p has p + 1
     live slots (at or below it) and reads ``decode_read_slots`` of the
-    step's bound, which starts at ``bound`` (the largest live lane's
-    position; default the sequence's own) and walks with the steps.  Host
-    arithmetic for the engines' ``ring_slots_*`` counters: no device
-    fetch."""
+    step's bound, which starts at ``bound`` and walks with the steps.  The
+    XLA loop's bound is the largest live lane's position and its block
+    ``DECODE_KV_BLOCK``; the kernel's is the sequence's own position (the
+    default) and ``block`` its ``decode_kernel_block``: ``ceil((pos + t +
+    1) / block) * block`` slots a step (a sliding window's skipped blocks
+    are not taken off, on either side).  Host arithmetic for the engines'
+    ``ring_slots_*`` counters: no device fetch."""
     bound = pos if bound is None else bound
     read = live = 0
     for t in range(n_steps):
-        read += decode_read_slots(bound + t, n_ctx)[1]
+        read += decode_read_slots(bound + t, n_ctx, block)[1]
         live += min(pos + t + 1, n_ctx)
     return read, live
 
 
 def decode_attention(q, cache, i, pos, bound, cfg: ModelConfig, out_dtype):
     """A decode step's attention (S = 1) over the LIVE part of layer
-    ``i``'s ring: K/V are read in blocks up to slot ``bound`` and no
+    ``i``'s ring, as a loop in plain XLA: the path where the decode kernel
+    does not run (:func:`decode_kernel_block`: int8 rings, mesh engines,
+    the CPU) and the reference tier-1 holds that kernel to.  K/V are read
+    in blocks up to slot ``bound`` and no
     further, with a running max and sum (the flash recurrence in plain
     XLA), instead of all ``n_ctx`` slots behind a mask.  Slots past the
     position have probability exactly 0 either way, so this is
@@ -333,7 +372,7 @@ def expert_stats_len(cfg: ModelConfig) -> int:
 
 
 def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
-                    kv_bound, cfg: ModelConfig, dtype):
+                    kv_bound, live, cfg: ModelConfig, dtype):
     """One layer's attention over a RING cache, after the write: ``ck`` /
     ``cv`` the layer's ring (``cks`` / ``cvs`` its int8 scales or None),
     by ``cfg.attn_impl`` and the pass's length.  (S, n_heads * head_dim)
@@ -370,8 +409,22 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
             v_scale=cvs,
             interpret=use_interpret(),
         ).reshape(S, cfg.n_heads * hd).astype(dtype)
+    elif S == 1 and (block := decode_kernel_block(cfg)):
+        # a decode step reads the live part of the ring, not n_ctx slots:
+        # the kernel, bounded by this sequence's own position, and nothing
+        # at all for a lane that holds no request
+        from ..ops.pallas import flash_attention_decode, use_interpret
+
+        ctx = flash_attention_decode(
+            q[0], cache["k"], cache["v"], i, pos_offset,
+            True if live is None else live,
+            sm_scale=hd ** -0.5,
+            block_k=block,
+            sliding_window=cfg.sliding_window,
+            interpret=use_interpret(),
+        )[None].astype(dtype)
     elif S == 1:
-        # a decode step reads the live part of the ring, not n_ctx slots
+        # the same read as a loop in plain XLA, under one bound for all lanes
         ctx = decode_attention(
             q, cache, i, pos_offset,
             pos_offset if kv_bound is None else kv_bound, cfg, dtype)
@@ -389,7 +442,8 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     dense feed-forward, else (rows each expert took (E,) int32, the
     router's picks (S, k) int32).  ``live`` (scalar bool
     or None): False marks a lane that holds no request, whose rows then
-    reach no expert (its output is not read).  ``kv_bound``: see
+    reach no expert and, where the decode kernel serves the ring, read no
+    slot of it (its output is not read).  ``kv_bound``: see
     :func:`forward`.  Under ``cfg.eva_window`` the leaves are the window
     and summary leaves of models/eva.py, the S tokens lie inside ONE
     window, and ``kv_bound`` is the triple of ``eva.live_bounds``.
@@ -468,7 +522,7 @@ def _layer(h, layers, i, cache, positions, pos_offset,
 
     if not cfg.eva_window:     # the other kind attended above, with its write
         ctx = _ring_attention(q, ck, cv, cks, cvs, cache, i, positions,
-                              pos_offset, kv_bound, cfg, h.dtype)
+                              pos_offset, kv_bound, live, cfg, h.dtype)
     h = h + lin(ctx, "wo")
 
     hn = normed(h, "ffn_norm")
@@ -509,10 +563,12 @@ def forward(
     ``with_stats`` appends the counter vector of :func:`expert_stats_len`
     and ``with_picks`` the routers' picks (L, S, k) int32 (what the
     comparison with the reference counts mismatches on).  ``kv_bound``
-    (scalar int32, a decode step only): the ring slot a decode step's
-    attention reads up to (:func:`decode_attention`), default this
-    sequence's own position; lanes ``vmap``ped over one step share the
-    largest live lane's, as an UNBATCHED value; under ``cfg.eva_window``
+    (scalar int32, a decode step only): the ring slot the XLA loop of a
+    decode step's attention reads up to (:func:`decode_attention`),
+    default this sequence's own position; lanes ``vmap``ped over one step
+    share the largest live lane's, as an UNBATCHED value (the decode
+    kernel takes none: it reads up to this sequence's own position, and
+    nothing where ``live`` is False); under ``cfg.eva_window``
     the triple of ``eva.live_bounds``.  ``all_heads``: the logits of every
     prediction head (``vocab_size * n_pred_heads`` rows) and not head 0's
     alone."""

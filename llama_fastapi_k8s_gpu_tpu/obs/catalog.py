@@ -314,11 +314,13 @@ METRICS: dict[str, Metric] = _register(
     # -- decode attention's read of the KV ring (models/llama.py) ----------
     Metric("ring_slots_read_total", GAUGE,
            "KV ring slots the decode steps' attention covered (whole blocks "
-           "up to the position; on a lane engine up to the largest LIVE "
+           "up to the position: under the decode kernel each lane's own, "
+           "under the XLA loop of a lane engine up to the largest LIVE "
            "lane's position, one bound for all lanes; models/llama.py "
-           "decode_attention), summed over decode steps and over the lanes "
-           "that hold a request, cumulative; from host-tracked positions "
-           "(a lane engine adds at each chunk's harvest), nothing fetched"),
+           "decode_kernel_block), summed over decode steps and over the "
+           "lanes that hold a request, cumulative; from host-tracked "
+           "positions (a lane engine adds at each chunk's harvest), nothing "
+           "fetched"),
     Metric("ring_slots_live_total", GAUGE,
            "KV ring slots at or below the sequence's own position, summed "
            "over the same steps and lanes; over ring_slots_read_total = the "

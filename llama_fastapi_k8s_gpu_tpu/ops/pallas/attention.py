@@ -325,8 +325,255 @@ def flash_attention(
     return out.reshape(n_kv, group, S, hd).transpose(2, 0, 1, 3).reshape(S, n_heads, hd)
 
 
+# ---------------------------------------------------------------------------
+# the decode step (S = 1): every lane walks its own blocks of the ring
+# ---------------------------------------------------------------------------
+
+#: rows a lane's queries of one KV head are padded to: the bf16 tile's
+#: sublanes, so the (rows, hd) x (hd, T) score product is one aligned MXU
+#: pass whatever the group (4 in GQA 32/8, 1 in MHA)
+_DECODE_ROWS = 16
+
+
+def _decode_kernel(
+    # scalar prefetch
+    i_ref,              # (1,) int32: the layer
+    pos_ref,            # (B,) int32: each lane's position
+    live_ref,           # (B,) int32: 0 = the lane holds no request
+    # inputs
+    q_ref,              # (1, n_kv, ROWS, hd): this lane's queries
+    k_hbm,              # (B, L, n_kv, n_ctx, hd) in HBM, read in place
+    v_hbm,
+    # output
+    o_ref,              # (1, n_kv, ROWS, hd)
+    # scratch
+    kbuf,               # (2, n_kv, T, hd): two slots, copy against compute
+    vbuf,
+    sem,                # DMA semaphores (2 = k|v, 2 slots)
+    m_ref,              # (n_kv, ROWS, 128) f32 running max (lane-replicated)
+    l_ref,              # (n_kv, ROWS, 128) f32 running sum
+    acc_ref,            # (n_kv, ROWS, hd) f32 running weighted sum
+    slot_ref,           # SMEM (1,): the slot the next block to consume is in
+    nxt_ref,            # SMEM (B + 1,): first lane >= c that reads anything
+    *,
+    block_k: int,
+    n_ctx: int,
+    sliding_window: int,
+    sm_scale: float,
+):
+    """One grid step is one LANE: a loop over that lane's own blocks, from
+    the sliding window's first to the one that holds its position, with a
+    DYNAMIC trip count; a lane that holds no request runs no iteration and
+    starts no copy.  The copy of a block's K and V (all KV heads at once)
+    is started one block ahead, across the lanes too: a lane's last
+    iteration starts the next reading lane's first block, so only the very
+    first copy of a call is waited for in full."""
+    T = block_k
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    layer = i_ref[0]
+
+    def span(lane):
+        """[lo, hi): the blocks ``lane`` reads."""
+        p = jnp.minimum(pos_ref[lane], n_ctx - 1)
+        hi = jnp.where(live_ref[lane] != 0, p // T + 1, 0)
+        if not sliding_window:
+            return 0, hi
+        return jnp.minimum(jnp.maximum(p - sliding_window + 1, 0) // T, hi), hi
+
+    def copies(lane, j, slot):
+        at = pl.multiple_of(j * T, T)
+        return [pltpu.make_async_copy(
+            ring.at[lane, layer, :, pl.ds(at, T), :], buf.at[slot],
+            sem.at[n, slot])
+            for n, (ring, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def start(lane, j, slot):
+        for c in copies(lane, j, slot):
+            c.start()
+
+    def start_first(lane, slot):
+        start(lane, span(lane)[0], slot)
+
+    @pl.when(b == 0)
+    def _first():
+        nxt_ref[B] = B
+
+        def scan(t, _):
+            c = B - 1 - t
+            lo, hi = span(c)
+            nxt_ref[c] = jnp.where(hi > lo, c, nxt_ref[c + 1])
+            return 0
+
+        jax.lax.fori_loop(0, B, scan, 0)
+        slot_ref[0] = 0
+
+        @pl.when(nxt_ref[0] < B)
+        def _():
+            start_first(nxt_ref[0], 0)
+
+    # a finite floor, not -inf (models/llama.py decode_attention): a block
+    # of a window's first, wholly masked slots must leave exp(m - m_new) = 1
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    lo, hi = span(b)
+    pos = pos_ref[b]
+
+    def block(j, _):
+        slot = slot_ref[0]
+
+        @pl.when(j + 1 < hi)
+        def _():
+            start(b, j + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(j + 1 >= hi, nxt_ref[b + 1] < B))
+        def _():
+            start_first(nxt_ref[b + 1], 1 - slot)
+
+        for c in copies(b, j, slot):
+            c.wait()
+        q = q_ref[0]                                   # (n_kv, ROWS, hd)
+        k = kbuf[slot]                                 # (n_kv, T, hd)
+        v = vbuf[slot]
+        s = jnp.einsum("ngh,nth->ngt", q, k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        key_pos = j * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        mask = key_pos <= pos
+        if sliding_window:
+            mask &= key_pos > pos - sliding_window
+        s = jnp.where(mask, s, -jnp.inf)
+        m = m_ref[:, :, :1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l_ref[:, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "ngt,nth->ngh", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        slot_ref[0] = 1 - slot
+        return 0
+
+    jax.lax.fori_loop(lo, hi, block, 0)
+    l = l_ref[:, :, :1]
+    o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def _decode_lanes(q, k, v, i, pos, live, *, block_k: int, sm_scale: float,
+                  sliding_window: int, interpret: bool):
+    """q (B, n_heads, hd), k / v (B, L, n_kv, n_ctx, hd), i scalar, pos and
+    live (B,) -> (B, n_heads * hd) in q.dtype: ONE kernel over the lanes."""
+    B, n_heads, hd = q.shape
+    _, _, n_kv, n_ctx, _ = k.shape
+    group = n_heads // n_kv
+    if n_ctx % block_k:
+        raise ValueError(f"the ring's {n_ctx} slots are no multiple of the "
+                         f"decode kernel's block of {block_k}")
+    rows = max(_DECODE_ROWS, group)
+    qg = jnp.pad(q.reshape(B, n_kv, group, hd),
+                 ((0, 0), (0, 0), (0, rows - group), (0, 0)))
+    lane_block = pl.BlockSpec((1, n_kv, rows, hd), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_k=block_k, n_ctx=n_ctx,
+                          sliding_window=sliding_window, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[lane_block,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=lane_block,
+            scratch_shapes=[
+                pltpu.VMEM((2, n_kv, block_k, hd), k.dtype),
+                pltpu.VMEM((2, n_kv, block_k, hd), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SMEM((B + 1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, rows, hd), q.dtype),
+        # lanes in order: the slot parity and the copy started ahead are
+        # carried from one lane to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="flash_attention_decode",
+    )(jnp.asarray(i, jnp.int32).reshape(1), pos.astype(jnp.int32),
+      live.astype(jnp.int32), qg, k, v)
+    return out[:, :, :group, :].reshape(B, n_heads * hd)
+
+
+@functools.lru_cache(maxsize=8)
+def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
+                      interpret: bool):
+    """The per-sequence call with its vmap rule: lanes ``vmap``ped over one
+    step become ONE kernel over (B lanes), as the fused matmuls' rows do
+    (qmatmul.py ``rows_vmappable``); without the rule ``vmap`` would batch
+    the kernel's grid and every lane would run the longest lane's trips."""
+    from jax.custom_batching import custom_vmap
+
+    lanes = functools.partial(
+        _decode_lanes, block_k=block_k, sm_scale=sm_scale,
+        sliding_window=sliding_window, interpret=interpret)
+
+    @custom_vmap
+    def one(q, k, v, i, pos, live):
+        return lanes(q[None], k[None], v[None], i, pos[None], live[None])[0]
+
+    @one.def_vmap
+    def _rule(axis_size, in_batched, q, k, v, i, pos, live):  # noqa: ANN001
+        qb, kb, vb, ib, pb, lb = in_batched
+        if ib:
+            raise NotImplementedError(
+                "decode attention vmap: the layer index is one for all lanes")
+
+        def per_lane(x, batched):
+            return x if batched else jnp.broadcast_to(
+                x, (axis_size, *x.shape))
+
+        return lanes(per_lane(q, qb), per_lane(k, kb), per_lane(v, vb), i,
+                     per_lane(pos, pb), per_lane(live, lb)), True
+
+    return one
+
+
+def flash_attention_decode(
+    q: jax.Array,          # (n_heads, hd): ONE sequence's query
+    k: jax.Array,          # (L, n_kv, n_ctx, hd): the STACKED bf16 ring,
+    v: jax.Array,          #   left in HBM and read in place at layer i
+    i: jax.Array,          # scalar int32: the layer
+    pos: jax.Array,        # scalar int32: this sequence's position
+    live: jax.Array,       # scalar bool: False = reads nothing, returns 0
+    *,
+    sm_scale: float,
+    block_k: int,
+    sliding_window: int = 0,
+    interpret: bool = False,
+) -> jax.Array:
+    """A decode step's attention (S = 1) over the live part of layer
+    ``i``'s ring: the flash recurrence of ``models/llama.py
+    decode_attention`` (f32 scores, max and sum; bf16 probabilities into an
+    f32 accumulator; the division last) as one kernel whose read is bounded
+    PER LANE: ``ceil((pos + 1) / block_k)`` blocks of ``block_k`` slots,
+    fewer under a sliding window, none where ``live`` is False.  Returns
+    (n_heads * hd,) in q.dtype.  Under ``vmap`` over lanes (everything but
+    ``i`` batched) it is still one kernel (:func:`_decode_vmappable`), and
+    a lane's output does not depend on its neighbours: each grid step
+    reads that lane's scalars, queries and blocks alone."""
+    return _decode_vmappable(int(block_k), float(sm_scale),
+                             int(sliding_window), bool(interpret))(
+        q, k, v, jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(live, jnp.bool_))
+
+
 # devtime inventory (lfkt-lint PERF001): flash attention is a TRACE-INNER
 # dispatch site — it runs inside the prefill/decode entry programs, so its
 # compile wall is attributed to whichever host program traced it
 # (obs/devtime.py; /debug/compiles shows it under kind="inner")
 register_program("flash_attention", site="ops.pallas.attention")
+register_program("_decode_lanes", site="ops.pallas.attention")

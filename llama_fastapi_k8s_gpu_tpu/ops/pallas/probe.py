@@ -173,7 +173,9 @@ def probe_fused_experts() -> str | None:
 @functools.lru_cache(maxsize=2)
 def probe_flash_attention(quantized: bool = False) -> str | None:
     """Compile + run the flash prefill kernel at the Llama-3-8B head
-    layout (32 q heads / 8 kv heads / head_dim 128) on a short sequence.
+    layout (32 q heads / 8 kv heads / head_dim 128) on a short sequence,
+    and with it the decode step's kernel (bf16 rings: ``attn_impl=pallas``
+    serves both, so both degrade together).
     ``quantized=True`` probes the int8-cache fused-dequant variant
     (kv_dtype=int8 engines call both: the two lower to different Mosaic
     programs and must degrade independently)."""
@@ -198,6 +200,23 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
             y = flash_attention(q, k, v, jnp.int32(0), sm_scale=HD ** -0.5,
                                 interpret=itp)
         float(y.astype(jnp.float32).sum())
+        if not quantized:
+            # the decode step's kernel over a bf16 ring (manual copies, a
+            # dynamic trip count: another Mosaic program), two blocks deep;
+            # ONE program, so a start pays one compile (or a cache hit) and
+            # not a dispatch per pad, slice and sum around the kernel
+            import jax
+
+            from .attention import flash_attention_decode
+
+            def decode(q):
+                ring = jnp.ones((2, KV, CTX, HD), jnp.bfloat16)
+                return flash_attention_decode(
+                    q[0], ring, ring, jnp.int32(1), jnp.int32(CTX - 1), True,
+                    sm_scale=HD ** -0.5, block_k=CTX // 2, interpret=itp,
+                ).astype(jnp.float32).sum()
+
+            float(jax.jit(decode)(q))
         if _env_kv_unroll() > 1:
             # the multi-KV-block inner loop (LFKT_FLASH_KV_UNROLL > 1) is a
             # structurally different Mosaic program (fused K/V fetch +
@@ -242,3 +261,10 @@ def probe_kv_quant() -> str | None:
         return None
     except Exception as e:  # noqa: BLE001
         return _err(e)
+
+
+# devtime inventory (lfkt-lint PERF001): the decode kernel's probe is one
+# small jit, compiled before any engine program exists
+from ...obs.devtime import register_program  # noqa: E402
+
+register_program("probe_flash_attention", site="ops.pallas.probe")

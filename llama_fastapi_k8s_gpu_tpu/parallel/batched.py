@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..models.config import ModelConfig
 from ..models import eva
 from ..models.generate import chunk_out
-from ..models.llama import forward, init_cache, prefill
+from ..models.llama import decode_kernel_block, forward, init_cache, prefill
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 
@@ -47,21 +47,29 @@ def init_batched_state(cfg: ModelConfig, batch: int, seed: int = 0) -> dict:
 
 
 def live_bound(pos: jax.Array, live: jax.Array | None = None) -> jax.Array:
-    """The ring slot a decode step's attention reads up to, ONE scalar for
-    all lanes (models/llama.py ``decode_attention``): the largest position
-    among the lanes that hold a request (``live`` (B,) bool; None: all).  A
-    freed lane keeps stepping and its position walks on; it must not drag
-    the read to ``n_ctx``."""
+    """The ring slot the XLA loop of a decode step's attention reads up
+    to, ONE scalar for all lanes (models/llama.py ``decode_attention``):
+    the largest position among the lanes that hold a request (``live``
+    (B,) bool; None: all).  A freed lane keeps stepping and its position
+    walks on; it must not drag the read to ``n_ctx``."""
     return jnp.max(pos if live is None else jnp.where(live, pos, 0))
 
 
 def step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
     """What ``forward`` takes as a lane step's ``kv_bound``, by the cache
-    kind: :func:`live_bound`, or for the window + summary cache the three
-    scalars of ``models/eva.py live_bounds`` (one bound becomes two, and
-    whether any live lane closes a window in this step)."""
+    kind and the read that serves it.  A ring read by the decode kernel
+    (``decode_kernel_block``) needs none: the kernel's reach is PER LANE,
+    the lane's own ``pos`` where its ``live`` says it holds a request and
+    nothing where not, and ``forward`` has both per lane already.  A ring
+    read by the XLA loop takes :func:`live_bound`, the one scalar that is
+    the largest such reach (the loop's trip count).  The window + summary
+    cache takes the three scalars of ``models/eva.py live_bounds`` (one
+    bound becomes two, and whether any live lane closes a window in this
+    step)."""
     if cfg.eva_window:
         return eva.live_bounds(pos, live, cfg)
+    if decode_kernel_block(cfg):
+        return None
     return live_bound(pos, live)
 
 
@@ -142,9 +150,10 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     scheduler admits requests with different temperatures/penalties into
     neighboring lanes.  (top_k stays a shared static: ``lax.top_k`` needs a
     static k; see ContinuousEngine.submit.)  ``live`` (B,) bool: the lanes
-    that hold a request (None: all).  A step's attention reads the ring up
-    to :func:`live_bound`; of a routed block the others' rows also reach no
-    expert, so a step reads what its live lanes picked."""
+    that hold a request (None: all).  A step's attention reads the ring as
+    :func:`step_bound` says (a lane that holds none reads nothing, or up
+    to the live lanes' bound); of a routed block the others' rows also
+    reach no expert, so a step reads what its live lanes picked."""
 
     def one_step(carry, _):
         bound = step_bound(cfg, carry["pos"], live)

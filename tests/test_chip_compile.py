@@ -183,6 +183,45 @@ def test_flash_attention_compiles(one_chip, seq, quantized):
     assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
 
 
+@pytest.mark.parametrize("lanes,layers,heads,window", [
+    (8, 32, (32, 8), 0),        # mistral-7b on 8 lanes: blocks of 256
+    (8, 16, (16, 16), 0),       # olmoe-1b-7b on 8 lanes: blocks of 128
+    (1, 48, (32, 8), 0),        # solar-10.7b, the serial engine
+    (8, 32, (32, 8), 4096),     # a sliding window
+    (16, 32, (32, 8), 0),       # 16 lanes: 8.6 GB of rings
+])
+def test_decode_attention_kernel_compiles(one_chip, lanes, layers, heads,
+                                          window):
+    """The decode step's kernel over the STACKED bf16 ring of 4096 slots,
+    lanes ``vmap``ped: one Mosaic call whose ring operands are read in
+    place (the program makes no copy of a ring), under the profile's name."""
+    from llama_fastapi_k8s_gpu_tpu.models import llama
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention_decode
+
+    n_heads, n_kv = heads
+    cfg = ModelConfig(vocab_size=64, dim=128 * n_heads, n_layers=layers,
+                      n_heads=n_heads, n_kv_heads=n_kv, ffn_dim=64,
+                      n_ctx=4096, sliding_window=window, attn_impl="pallas")
+    block = llama.decode_kernel_block(cfg)
+    assert block == (256 if n_kv == 8 else 128)
+    ring = S(lanes, layers, n_kv, 4096, 128)
+
+    def fn(q, k, v, i, pos, live):
+        return jax.vmap(lambda q, k, v, p, lv: flash_attention_decode(
+            q, k, v, i, p, lv, sm_scale=128 ** -0.5, block_k=block,
+            sliding_window=window, interpret=False))(q, k, v, pos, live)
+
+    txt = _compile(one_chip, fn, S(lanes, n_heads, 128), ring, ring,
+                   S(dtype=i32), S(lanes, dtype=i32),
+                   S(lanes, dtype=jnp.bool_))
+    assert txt.count("tpu_custom_call") == 1
+    assert "flash_attention_decode" in txt
+    ring_shape = f"bf16[{lanes},{layers},{n_kv},4096,128]"
+    assert not [ln for ln in txt.splitlines()
+                if " copy(" in ln and ring_shape in ln.split(" copy(")[0]]
+
+
 @pytest.mark.parametrize("seq", [1, 128, 1024])
 def test_kv_quantize_compiles(one_chip, seq):
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.kvquant import quantize_kv_pallas

@@ -11,6 +11,11 @@ tests allow 2^-7 * max|V|.  Greedy tokens over 64 steps are identical.
 
 The block is shrunk to 16 slots (``DECODE_KV_BLOCK``, read at trace time)
 so that a ring of 100 slots holds six blocks and a seventh that overhangs.
+
+The decode KERNEL (ops/pallas/attention.py ``flash_attention_decode``,
+interpret mode here) is held to that loop: same recurrence, a bound per
+lane, nothing read for a lane that holds no request.  Its ring has 128
+slots, eight blocks of ``DECODE_KERNEL_BLOCK`` = 16.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ BLOCK, N_CTX, LAYERS = 16, 100, 3
 @pytest.fixture(autouse=True)
 def small_blocks(monkeypatch):
     monkeypatch.setattr(llama, "DECODE_KV_BLOCK", BLOCK)
+    monkeypatch.setattr(llama, "DECODE_KERNEL_BLOCK", (BLOCK, BLOCK, 2048))
 
 
 def _cfg(kv_dtype="bf16", window=0, heads=(4, 2), n_ctx=N_CTX):
@@ -229,6 +235,182 @@ def test_a_pick_differs_only_at_a_tie(monkeypatch, seed, heads):
     want, want_rows = _decode_64(params, cfg)
     monkeypatch.setattr(llama, "decode_attention", got_mod)
     got, got_rows = _decode_64(params, cfg, forced=want)
+    for n, (a, b) in enumerate(zip(want_rows, got_rows)):
+        step_size = 2.0 ** -7 * float(np.max(np.abs(a)))
+        assert float(np.max(np.abs(a - b))) <= 3 * step_size, n
+        if want[n] != got[n]:
+            best = np.sort(a)[::-1]
+            assert best[0] - best[1] <= step_size, (n, best[:2])
+
+
+# ---------------------------------------------------------------------------
+# the decode kernel against the XLA loop
+# ---------------------------------------------------------------------------
+
+K_CTX = 128          # eight blocks of 16
+
+
+def _kernel(cfg, q, cache, i, pos, live=True):
+    """``flash_attention_decode`` as ``_ring_attention`` calls it."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention_decode
+
+    return flash_attention_decode(
+        q[0], cache["k"], cache["v"], i, pos, live,
+        sm_scale=cfg.head_dim ** -0.5, block_k=llama.decode_kernel_block(cfg),
+        sliding_window=cfg.sliding_window, interpret=True)[None]
+
+
+def _kernel_cfg(window=0, heads=(4, 2)):
+    return dataclasses.replace(_cfg(window=window, heads=heads, n_ctx=K_CTX),
+                               attn_impl="pallas")
+
+
+@pytest.mark.parametrize("pos", [0, BLOCK - 1, BLOCK, BLOCK + 1, 70,
+                                 K_CTX - 1])
+@pytest.mark.parametrize("heads", [(32, 8), (16, 16)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
+def test_kernel_matches_the_xla_loop(window, heads, pos):
+    """Same recurrence, so what differs is the order of f32 sums and that
+    the kernel returns bf16 (one bf16 step of the output, 2^-9 of it)."""
+    cfg = _kernel_cfg(window, heads)
+    cache, q, vmax = _ring(cfg)
+    want = llama.decode_attention(q, cache, 1, pos, pos, cfg, jnp.float32)
+    got = _kernel(cfg, q, cache, 1, pos)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) \
+        <= 2.0 ** -7 * vmax
+
+
+@pytest.mark.parametrize("cfg_kw,block", [
+    (dict(attn_impl="pallas"), 16),
+    (dict(attn_impl="xla"), 0),                  # CPU, a mesh engine
+    (dict(attn_impl="ring"), 0),                 # sequence parallel
+    (dict(attn_impl="pallas", kv_dtype="int8"), 0),
+    (dict(attn_impl="pallas", n_ctx=100), 0),    # no whole blocks
+    (dict(attn_impl="pallas", n_ctx=8), 0),      # under a bf16 tile
+    (dict(attn_impl="pallas", eva_window=64, eva_chunk=4), 0)])
+def test_which_read_serves_a_configuration(cfg_kw, block):
+    cfg = dataclasses.replace(_cfg(n_ctx=K_CTX), **cfg_kw)
+    assert llama.decode_kernel_block(cfg) == block
+
+
+@pytest.mark.parametrize("n_kv,n_ctx,block", [
+    (8, 4096, 256), (16, 4096, 128), (32, 4096, 128), (4, 4096, 512),
+    (1, 4096, 512), (8, 128, 128), (8, 1024, 256), (8, 3000, 0),
+    (12, 4096, 128)])
+def test_the_kernels_block_by_the_kv_heads(monkeypatch, n_kv, n_ctx, block):
+    """About 2048 head-slots a copy, a power of two between 128 and 512
+    that divides the ring; else the loop."""
+    monkeypatch.setattr(llama, "DECODE_KERNEL_BLOCK", (128, 512, 2048))
+    cfg = ModelConfig(vocab_size=64, dim=128 * 96, n_layers=2, n_heads=96,
+                      n_kv_heads=n_kv, ffn_dim=64, n_ctx=n_ctx,
+                      attn_impl="pallas")
+    assert llama.decode_kernel_block(cfg) == block
+
+
+def _lanes(cfg, n=4):
+    rings = [_ring(cfg, seed=s) for s in range(n)]
+    caches = jax.tree.map(lambda *a: jnp.stack(a), *[r[0] for r in rings])
+    return caches, jnp.stack([r[1] for r in rings])
+
+
+def _poison(caches, lane, from_slot):
+    """NaN in ``lane``'s ring from ``from_slot`` on: a read that touches
+    it shows in the output (0 * NaN in the PV product)."""
+    return jax.tree.map(
+        lambda a: a.at[lane, :, :, from_slot:].set(jnp.nan), caches)
+
+
+def test_lanes_walk_their_own_blocks_and_a_dead_lane_reads_nothing():
+    """``vmap`` over 4 lanes at very different positions, one of them dead
+    between live ones.  Each live lane's output is its own single-sequence
+    output bit for bit; the ring of every lane is NaN from the end of the
+    lane's own last block on, and ALL of the dead lane's ring is, so a
+    read past a lane's own reach, or any read of the dead lane, would
+    show; the dead lane's output is exactly 0."""
+    cfg = _kernel_cfg()
+    caches, qs = _lanes(cfg)
+    pos = jnp.asarray([3, 40, 97, 17], jnp.int32)
+    live = jnp.asarray([True, True, False, True])
+    poisoned = caches
+    for lane, reach in ((0, 16), (1, 48), (2, 0), (3, 32)):
+        poisoned = _poison(poisoned, lane, reach)
+
+    def lanes(caches, pos):
+        return jax.vmap(lambda q, c, p, lv: _kernel(cfg, q, c, 1, p, lv))(
+            qs, caches, pos, live)
+
+    got = lanes(poisoned, pos)
+    assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+    assert not bool(jnp.any(got[2]))
+    # the dead lane's position does not matter either
+    far = lanes(poisoned, pos.at[2].set(5000))
+    for lane in (0, 1, 3):
+        alone = _kernel(cfg, qs[lane],
+                        jax.tree.map(lambda a: a[lane], caches), 1, pos[lane])
+        assert jnp.array_equal(got[lane], alone)
+        assert jnp.array_equal(far[lane], alone)
+    # and the test can tell: one slot further and the poison is read
+    bad = lanes(poisoned, pos.at[0].set(16))
+    assert not bool(jnp.all(jnp.isfinite(bad[0].astype(jnp.float32))))
+
+
+def test_a_sliding_window_starts_at_its_first_block():
+    """Position 100 under a window of 24 attends slots 77..100: blocks 4,
+    5 and 6.  Blocks 0-3 and 7 are NaN and the output is the loop's."""
+    cfg = _kernel_cfg(window=24)
+    cache, q, vmax = _ring(cfg)
+    want = llama.decode_attention(q, cache, 1, 100, 100, cfg, jnp.float32)
+    holes = jax.tree.map(
+        lambda a: a.at[:, :, :64].set(jnp.nan).at[:, :, 112:].set(jnp.nan),
+        cache)
+    got = _kernel(cfg, q, holes, 1, 100).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(got - want))) <= 2.0 ** -7 * vmax
+
+
+@pytest.mark.parametrize("lanes", [2, 8])
+def test_the_vmapped_call_is_one_kernel(lanes):
+    """Lanes reach the kernel as the fused matmuls' rows do: one
+    ``pallas_call`` over (B lanes), not a call a lane and not a batched
+    grid (under which every lane would run the longest lane's trips)."""
+    cfg = _kernel_cfg()
+    caches, qs = _lanes(cfg, lanes)
+    pos = jnp.arange(lanes, dtype=jnp.int32) * 9
+    live = jnp.ones(lanes, bool)
+    jaxpr = str(jax.make_jaxpr(jax.vmap(
+        lambda q, c, p, lv: _kernel(cfg, q, c, 1, p, lv)))(
+            qs, caches, pos, live))
+    assert jaxpr.count("pallas_call") == 1
+    assert "name=flash_attention_decode" in jaxpr
+    assert f"grid=({lanes},)" in jaxpr
+
+
+def test_chunk_slots_of_the_kernel_are_per_lane():
+    """Each sequence by its own position, in the kernel's blocks:
+    ``ceil((pos + t + 1) / T) * T`` a step, whatever the other lanes."""
+    assert llama.decode_chunk_slots(14, 4, K_CTX, block=16) == (
+        16 + 16 + 32 + 32, 15 + 16 + 17 + 18)
+    assert llama.decode_chunk_slots(40, 2, K_CTX, block=8) == (
+        48 + 48, 41 + 42)
+    # past the ring's end the read is the ring
+    assert llama.decode_chunk_slots(126, 3, K_CTX, block=16) == (
+        3 * 128, 127 + 128 + 128)
+
+
+@pytest.mark.parametrize("seed,heads", [(4, (4, 1)), (4, (4, 4)),
+                                        (0, (4, 1)), (3, (4, 4))])
+def test_kernel_picks_differ_from_the_loops_only_at_a_tie(seed, heads):
+    """Through ``forward``, fed the loop's own greedy tokens over 64 steps:
+    the kernel picks the same token at every step but those where the
+    loop's best two logits are within one bf16 step of each other (the
+    statement ``test_a_pick_differs_only_at_a_tie`` makes of the loop
+    against the whole-ring read), and its logits stay within three."""
+    cfg = _cfg(heads=heads, n_ctx=K_CTX)
+    params = synth_params(cfg, seed=seed)
+    want, want_rows = _decode_64(params, cfg)
+    got, got_rows = _decode_64(
+        params, dataclasses.replace(cfg, attn_impl="pallas"), forced=want)
+    assert len(set(want)) > 4
     for n, (a, b) in enumerate(zip(want_rows, got_rows)):
         step_size = 2.0 ** -7 * float(np.max(np.abs(a)))
         assert float(np.max(np.abs(a - b))) <= 3 * step_size, n

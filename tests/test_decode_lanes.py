@@ -34,7 +34,7 @@ import pytest
 from llama_fastapi_k8s_gpu_tpu.models import llama
 from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
 from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
-from llama_fastapi_k8s_gpu_tpu.parallel.batched import live_bound
+from llama_fastapi_k8s_gpu_tpu.parallel.batched import live_bound, step_bound
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
@@ -51,12 +51,16 @@ REFERENCE = 3e-2
 @pytest.fixture(autouse=True)
 def small_blocks(monkeypatch):
     monkeypatch.setattr(llama, "DECODE_KV_BLOCK", BLOCK)
+    monkeypatch.setattr(llama, "DECODE_KERNEL_BLOCK", (BLOCK, BLOCK, 2048))
 
 
-def _cfg(heads=(4, 2), window=0, kv_dtype="bf16"):
+def _cfg(heads=(4, 2), window=0, kv_dtype="bf16", attn_impl="xla"):
+    """``attn_impl="pallas"``: the decode kernel serves the ring (interpret
+    mode here), one bound a lane; ``xla``: the loop under one bound."""
     return ModelConfig(vocab_size=64, dim=16 * heads[0], n_layers=3,
                        n_heads=heads[0], n_kv_heads=heads[1], ffn_dim=96,
-                       n_ctx=N_CTX, kv_dtype=kv_dtype, sliding_window=window)
+                       n_ctx=N_CTX, kv_dtype=kv_dtype, sliding_window=window,
+                       attn_impl=attn_impl)
 
 
 def _random_cache(cfg, seed):
@@ -132,17 +136,52 @@ def test_logits_match_the_whole_ring_read(monkeypatch, window, heads, pos):
         assert rel(logits(a_slot_short), want) > 2 * REFERENCE
 
 
+@pytest.mark.parametrize("pos", [0, BLOCK - 1, BLOCK, BLOCK + 1, 70,
+                                 N_CTX - 1])
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
+def test_the_kernels_logits_match_the_whole_ring_read(monkeypatch, window,
+                                                      heads, pos):
+    """The same step with the decode kernel serving the ring
+    (``attn_impl="pallas"``): held to the whole-ring read by the measure
+    the loop is held to above (three bf16 steps of the attention output's
+    worth in these logits; mid-ring, at 70, the loop itself reads 3.2 %),
+    and to the loop by one such step (in interpret mode they are equal)."""
+    cfg = _cfg(heads, window)
+    params = synth_params(cfg, seed=2)
+    cache = _random_cache(cfg, seed=pos)
+
+    def logits(cfg):
+        return np.asarray(llama.decode_step(
+            params, cfg, jnp.int32(7), jnp.int32(pos), cache)[0], np.float32)
+
+    def one_step_off(q, cache, i, pos, bound, cfg, out_dtype):
+        out = whole_ring(q, cache, i, pos, bound, cfg, jnp.float32)
+        return (out * (1 + 2.0 ** -8)).astype(out_dtype)
+
+    got = logits(dataclasses.replace(cfg, attn_impl="pallas"))
+    loop = logits(cfg)
+    monkeypatch.setattr(llama, "decode_attention", whole_ring)
+    want = logits(cfg)
+    monkeypatch.setattr(llama, "decode_attention", one_step_off)
+    step = rel(logits(cfg), want)
+    assert np.isfinite(got).all()
+    assert rel(got, want) < 3 * step + 1e-6
+    assert rel(got, loop) < step + 1e-6
+
+
 # ---------------------------------------------------------------------------
 # a lane's logits are bitwise the same whatever the other lanes hold
 # ---------------------------------------------------------------------------
 
 def _lane_step(params, cfg):
     """The lane program's step (parallel/batched.py ``one_step``), with the
-    logits kept: ``vmap`` of ``forward`` over per-lane rings under ONE
-    bound, the largest live position."""
+    logits kept: ``vmap`` of ``forward`` over per-lane rings under what
+    ``step_bound`` hands it: ONE bound, the largest live position, or
+    none where the kernel bounds each lane by itself."""
     @jax.jit
     def step(toks, poss, caches, live):
-        bound = live_bound(poss, live)
+        bound = step_bound(cfg, poss, live)
         return jax.vmap(lambda t, p, c, lv: forward_lane(t, p, c, lv, bound))(
             toks, poss, caches, live)
 
@@ -168,12 +207,16 @@ OTHERS = {
 @pytest.mark.parametrize("others", [k for k in OTHERS if k != "empty"])
 @pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
 @pytest.mark.parametrize("pos", [5, BLOCK, 70])
-def test_a_lanes_logits_do_not_depend_on_the_other_lanes(heads, pos, others):
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"],
+                         ids=["loop", "kernel"])
+def test_a_lanes_logits_do_not_depend_on_the_other_lanes(attn_impl, heads,
+                                                         pos, others):
     """Lane 1 holds the same ring, token and position throughout; lanes 0, 2
     and 3 are empty, full to the ring's end, dead with stale positions, dead
     with positions past the ring, or a mix.  Lane 1's logits and the K/V it
-    writes are BITWISE those of the run with the other lanes empty."""
-    cfg = _cfg(heads)
+    writes are BITWISE those of the run with the other lanes empty, under
+    the loop's common bound and under the kernel's bound per lane."""
+    cfg = _cfg(heads, attn_impl=attn_impl)
     params = synth_params(cfg, seed=1)
     step = _lane_step(params, cfg)
     mine = _random_cache(cfg, seed=11)
@@ -346,9 +389,8 @@ def test_the_lane_counters_on_a_known_schedule():
         return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
                                      finished=finished)
 
-    eng = types.SimpleNamespace(
-        cfg=types.SimpleNamespace(n_ctx=N_CTX, eva_window=0),
-        ring_slots={"read": 0, "live": 0})
+    eng = types.SimpleNamespace(cfg=_cfg(),      # the XLA loop serves it
+                                ring_slots={"read": 0, "live": 0})
     eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
     pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
     ContinuousEngine._note_ring_read(eng, pre, 4)
@@ -359,3 +401,76 @@ def test_the_lane_counters_on_a_known_schedule():
     # blocks, 32, 33 read 3
     ContinuousEngine._note_ring_read(eng, pre[:3], 4)
     assert eng.ring_slots["read"] == 2 * 4 * 64 + 2 * (32 + 32 + 48 + 48)
+
+
+def test_the_lane_counters_under_the_kernel_are_per_lane():
+    """The same schedule where the decode kernel serves the ring
+    (``attn_impl="pallas"``, blocks of 16): a wanted lane reads its OWN
+    blocks, whatever the finished lane at 50 and the longer neighbour
+    hold: lane 0 at 14..17 reads 16 + 16 + 32 + 32, lane 1 at 30..33
+    reads 32 + 32 + 48 + 48."""
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
+
+    def slot(n_prompt, n_gens, finished=False):
+        return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
+                                     finished=finished)
+
+    eng = types.SimpleNamespace(cfg=_cfg(attn_impl="pallas"),
+                                ring_slots={"read": 0, "live": 0})
+    eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
+    pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
+    ContinuousEngine._note_ring_read(eng, pre, 4)
+    assert eng.ring_slots == {
+        "read": (16 + 16 + 32 + 32) + (32 + 32 + 48 + 48),
+        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34)}
+
+
+def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
+    """``attn_impl="pallas"`` through the engines themselves (interpret
+    mode): the serial ``Engine`` is the kernel at one lane, the lane engine
+    the kernel over its lanes with freed lanes skipped.  A greedy probe's
+    text is the same before, during and after other lanes' traffic, and
+    the counters count whole blocks of 16 with most of the read live (the
+    loop's common bound, in blocks of 16 too, read well under that)."""
+    from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
+
+    path = str(tmp_path / "tiny.gguf")
+    write_tiny_llama_gguf(path)
+    kw = dict(n_ctx=N_CTX, decode_chunk=4, max_gen_tokens=64,
+              prefill_buckets=(32, 64, 128), attn_impl="pallas")
+    probe_msgs = [{"role": "user", "content": "Say something."}]
+    serial = Engine(path, prefix_cache=False, **kw)
+    assert serial.cfg.attn_impl == "pallas"
+    assert llama.decode_kernel_block(serial.cfg) == BLOCK
+    text = serial.create_chat_completion(
+        probe_msgs, temperature=0.0, max_tokens=12)[
+            "choices"][0]["message"]["content"]
+    assert text
+    assert serial.ring_slots["read"] % BLOCK == 0
+    assert serial.ring_slots["live"] / serial.ring_slots["read"] > 0.6
+
+    eng = ContinuousEngine(path, batch_size=3, dp=1, **kw)   # no mesh
+
+    def probe():
+        return eng.create_chat_completion(
+            probe_msgs, temperature=0.0, max_tokens=12)[
+                "choices"][0]["message"]["content"]
+
+    try:
+        first = probe()
+        assert first
+        futs = [eng.submit(
+            [{"role": "user", "content": "more words " * (2 + 4 * i)}],
+            temperature=0.8, seed=i, max_tokens=(40, 10)[i])
+            for i in range(2)]
+        assert probe() == first             # neighbours mid-flight
+        for f in futs:
+            f.result(timeout=300)
+        assert probe() == first             # beside freed lanes
+        read, live = eng.ring_slots["read"], eng.ring_slots["live"]
+        assert read % BLOCK == 0 and 0 < live <= read
+        assert live / read > 0.6
+    finally:
+        eng.shutdown()
