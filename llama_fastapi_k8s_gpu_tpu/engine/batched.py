@@ -84,11 +84,12 @@ def _refuse_fused_on_a_tpu_mesh(params: dict, dp: int, tp: int) -> None:
     CustomSPMDPartitioning not found" — at the warm-up compile, after the
     whole load.  Say so here instead.  The CPU backend partitions them fine
     (tests/test_parallel.py), which is how this went unseen."""
+    from ..models.params import flat_layers
     from ..parallel.mesh import _fused_key
 
     if dp * tp == 1 or jax.default_backend() != "tpu":
         return
-    fused = sorted(name for name, leaf in params["layers"].items()
+    fused = sorted(name for name, leaf in flat_layers(params["layers"])
                    if isinstance(leaf, dict) and _fused_key(leaf))
     if fused:
         raise RuntimeError(
@@ -170,6 +171,22 @@ class MeshEngine(Engine):
                 "it prefills a whole prompt in one vmapped pass, and a "
                 "pass must lie inside one attention window; use the "
                 "continuous scheduler")
+
+    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
+        super()._refuse_for_state_cache(kv_paged)
+        dp, tp = self._mesh_shape
+        if tp > 1:
+            raise ValueError(
+                f"LFKT_MESH_TP={tp} cannot serve architecture "
+                "'minicpm-sala': parallel/mesh.py shards a ring's KV heads "
+                "and one stack of layers, and has no layout for two kinds "
+                "of layer or a state leaf")
+        if not self._SLICED_ADMISSION:
+            raise ValueError(
+                "LFKT_SCHEDULER=cycle cannot serve architecture "
+                "'minicpm-sala': it prefills a whole prompt in one vmapped "
+                "pass, and its sparse layers select per query in slices; "
+                "use the continuous scheduler")
 
     def _recover_locked(self) -> None:  # lfkt: holds[_lock]
         """Watchdog recovery: a crash mid-cycle may have poisoned the donated

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.config import RING
 from ..models.generate import prefill_chunk_jit, sample_jit
 from ..models.llama import init_cache
 from ..obs import memledger as _memledger
@@ -282,7 +283,7 @@ class ContinuousEngine(MeshEngine):
     # (ring_slots, eva_counts: the scheduler thread alone adds, /metrics
     # reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread", "ring_slots", "eva_counts")
+                      "_thread", "ring_slots", "eva_counts", "sala_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
@@ -337,7 +338,7 @@ class ContinuousEngine(MeshEngine):
         # walking position stays inside both stores by itself, slot
         # ``pos mod W``, and closes no window past the last closable one.)
         self._lane_prefix = bool(lane_prefix_cache) \
-            and not self.cfg.eva_window
+            and self.cfg.cache_kind == RING
         # paged mode (LFKT_KV_PAGED) folds the lane claims behind the
         # shared radix tree: one prefix-reuse implementation per mode (the
         # per-lane claim path remains the dense-ring default).  An

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gguf import GGUFFile
-from ..models.config import ModelConfig
+from ..models.config import RING, STATE_RING, ModelConfig
 from ..models.generate import (
     generate_chunk_jit,
     init_state,
@@ -40,12 +40,13 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models import eva
+from ..models import eva, sala
 from ..models.llama import (
     decode_chunk_slots, decode_kernel_block, init_cache)
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
+from ..tokenizer.chat_template import named_template
 from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import arm_phases, phase, rid
 from ..utils.faults import FAULTS
@@ -231,6 +232,12 @@ class Engine:
         self.eva_counts = {"lane_steps": 0, "window_read": 0,
                            "window_live": 0, "summaries_read": 0,
                            "summaries_live": 0, "windows_closed": 0}
+        # and for the state + ring cache (models/sala.py chunk_counts /
+        # prefill_counts; ring_slots counts its ring layers' dense reads):
+        # /metrics lin_state_* / sparse_*_total
+        self.sala_counts = {"state_updates": 0, "queries_dense": 0,
+                            "queries_sparse": 0, "blocks_read": 0,
+                            "blocks_visible": 0, "kc_written": 0}
         self._base_seed = seed
         # request counter: shared by the serial path (caller thread) and the
         # continuous scheduler thread; _next_seed() is the only writer and
@@ -252,6 +259,9 @@ class Engine:
         #: by the coldstart bench to direct startup-latency work; empty for
         #: in-memory (_parts) engines
         self.load_phases: dict = {}
+        #: whether the file's own chat template names a kind known here
+        #: (else detect_chat_template fell back, and /health says so)
+        self._template_named = True
         if _parts is not None:
             self.params, self.cfg, self.tokenizer, self.template_kind = _parts
             self.model_name = "in-memory"
@@ -303,9 +313,12 @@ class Engine:
             self.load_phases["params_s"] = round(time.time() - _pt, 1)
             self.load_phases.update(
                 {f"params_{k}_s": round(v, 1) for k, v in sub.items()})
+            template = gf.metadata.get("tokenizer.chat_template")
             self.template_kind = detect_chat_template(
-                gf.metadata.get("tokenizer.chat_template"), self.tokenizer
-            )
+                template, self.tokenizer)
+            # detect_chat_template falls back on "mistral" in silence: a
+            # file whose own template is not known says so in /health
+            self._template_named = named_template(template) is not None
             logger.info(
                 "loaded %s (%s, %d layers, fmt=%s) in %.1fs",
                 model_path, gf.architecture, self.cfg.n_layers, weight_format,
@@ -316,6 +329,8 @@ class Engine:
         if self.cfg.eva_window:
             self._refuse_for_window_cache(bool(kv_paged))
             attn_impl = "xla"   # its attention is models/eva.py's own
+        if self.cfg.cache_kind == STATE_RING:
+            self._refuse_for_state_cache(bool(kv_paged))
         if self.cfg.kv_dtype == "int8":
             # compile-probe the KV write-quantize kernel NOW: a Mosaic
             # failure degrades writes to the identical XLA formulation
@@ -352,6 +367,16 @@ class Engine:
                 logger.error("pallas flash attention failed its compile "
                              "probe; serving with attn_impl=xla: %s", err)
                 attn_impl = "xla"
+        if attn_impl == "pallas" and self.cfg.cache_kind == STATE_RING:
+            # the state's decode step is a kernel too (ops/pallas/
+            # linstate.py); it and the ring's kernels degrade together
+            from ..ops.pallas.probe import probe_lin_state
+
+            err = probe_lin_state()
+            if err is not None:
+                logger.error("pallas linear-state step failed its compile "
+                             "probe; serving with attn_impl=xla: %s", err)
+                attn_impl = "xla"
         if attn_impl != self.cfg.attn_impl:
             self.cfg = dataclasses.replace(self.cfg, attn_impl=attn_impl)
         self.prefill_buckets = sorted(b for b in prefill_buckets if b <= self.cfg.n_ctx)
@@ -371,7 +396,7 @@ class Engine:
         # (off for the window + summary cache: reuse is by token position,
         # and a window restarts: a property of the cache, /health says so)
         self._prefix_cache = bool(prefix_cache) and type(self) is Engine \
-            and not self.cfg.eva_window
+            and self.cfg.cache_kind == RING
         self._prefix_min = max(1, int(prefix_min))
         #: token ids whose KV occupy ring slots [0, len) — only ever read
         #: and written under self._lock (the single-generator invariant)
@@ -471,11 +496,51 @@ class Engine:
                 f"attention window ({W}) and be a multiple of its chunk "
                 f"({C}), so that no slice lies astride a window")
 
+    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
+        """The same for the state + ring cache of ``minicpm-sala``
+        (models/sala.py): an int8 cache, the paged pool (a state has no
+        pages and cannot be rolled back to one), a prefill slice that
+        could split a block of the sparse layers.  Subclasses add the
+        meshes."""
+        if self.cfg.kv_dtype == "int8":
+            raise ValueError(
+                "LFKT_KV_DTYPE=int8 cannot serve architecture "
+                "'minicpm-sala': its state + ring cache is float32 + bf16 "
+                "only")
+        if kv_paged:
+            raise ValueError(
+                "LFKT_KV_PAGED=1 cannot serve architecture 'minicpm-sala': "
+                "the pool pages runs of ring slots by token position, and "
+                "its linear layers keep a state that cannot be rolled back "
+                "to a shared prefix")
+        if self._prefill_chunk % self.cfg.sp_block:
+            raise ValueError(
+                f"LFKT_PREFILL_CHUNK={self._prefill_chunk} cannot serve "
+                f"architecture 'minicpm-sala': a prefill slice must be a "
+                f"multiple of its sparse layers' block "
+                f"({self.cfg.sp_block})")
+
     @property
     def cache_kind(self) -> dict | None:
         """The /health ``engine.cache`` block of a cache that is no ring
         (None for the ring: its /health is what it was): the kind's sizes,
         and the reuse it does without as a property, not a degrade."""
+        if self.cfg.cache_kind == STATE_RING:
+            cfg = self.cfg
+            return {
+                "kind": STATE_RING,
+                "linear_layers": cfg.n_layers_of(sala.LIN),
+                "sparse_layers": cfg.n_layers_of(sala.SP),
+                "state_bytes": sala.state_nbytes(cfg),
+                "compressed_keys": sala.n_kc(cfg),
+                "blocks_read_at_most": sala.n_select(cfg),
+                "dense_len": cfg.sp_dense_len,
+                "prefix_reuse": "off: a state cannot be rolled back to a "
+                                "shared prefix",
+                "kv_paged": "refused at start",
+                "chat_template": self.template_kind + (
+                    "" if self._template_named else
+                    " (fallback: the file names no template known here)")}
         if not self.cfg.eva_window:
             return None
         return {"kind": "window+summaries", "window": self.cfg.eva_window,
@@ -642,7 +707,9 @@ class Engine:
         """Whether a ``bucket``-sized prompt prefills as overlapped slices
         (vs one monolithic program).  Buckets at or under the slice size
         gain nothing from slicing and keep the single-program path."""
-        if self.cfg.eva_window:    # a pass lies inside one window: always
+        if self.cfg.cache_kind != RING:
+            # a pass lies inside one window, or is small enough for the
+            # sparse layers' per-query masks: always
             return bucket > self._prefill_chunk
         return (self._SLICE_PREFILL and self._prefill_overlap > 0
                 and bucket > self._prefill_chunk)
@@ -755,11 +822,22 @@ class Engine:
                                          live).items():
                 self.eva_counts[k] += v
             return
+        first_sparse = None
+        if self.cfg.cache_kind == STATE_RING:
+            for k, v in sala.chunk_counts(wanted, n_steps, self.cfg).items():
+                self.sala_counts[k] += v
+            # the ring counters speak for the ring layers' dense reads: the
+            # steps before dense_len
+            first_sparse = self.cfg.sp_dense_len - 1
+            live = [p for p in (wanted if live is None else live)
+                    if p < first_sparse]
         block = decode_kernel_block(self.cfg)
         bound = None if block else max(
             wanted if live is None else live, default=0)
         for p in wanted:
-            read, lv = decode_chunk_slots(p, n_steps, self.cfg.n_ctx, bound,
+            steps = n_steps if first_sparse is None \
+                else min(n_steps, max(first_sparse - p, 0))
+            read, lv = decode_chunk_slots(p, steps, self.cfg.n_ctx, bound,
                                           block)
             self.ring_slots["read"] += read
             self.ring_slots["live"] += lv
@@ -779,6 +857,15 @@ class Engine:
                 eva_summaries_read_total=c["summaries_read"],
                 eva_summaries_live_total=c["summaries_live"],
                 eva_windows_closed_total=c["windows_closed"])
+        if self.cfg.cache_kind == STATE_RING:
+            c = self.sala_counts
+            out.update({
+                "lin_state_updates_total": c["state_updates"],
+                'sparse_queries_total{branch="dense"}': c["queries_dense"],
+                'sparse_queries_total{branch="sparse"}': c["queries_sparse"],
+                "sparse_blocks_read_total": c["blocks_read"],
+                "sparse_blocks_visible_total": c["blocks_visible"],
+                "sparse_kc_written_total": c["kc_written"]})
         return out
 
     def _take_expert_stats(self, chunk_out):
@@ -1034,6 +1121,13 @@ class Engine:
             self.eva_counts["windows_closed"] += n
             if pspan is not None:
                 pspan.set(windows_closed=n)
+        if self.cfg.cache_kind == STATE_RING:
+            c = sala.prefill_counts(n_prompt, self.cfg)
+            for k in ("queries_dense", "queries_sparse", "kc_written"):
+                self.sala_counts[k] += c[k]
+            if pspan is not None:
+                pspan.set(kc_closed=c["kc_closed"],
+                          sparse_positions=c["sparse_positions"])
 
     def _prefix_reuse_len(self, ids: list, n_prompt: int, bucket: int) -> int:
         """Longest usable common prefix of ``ids`` vs the KV resident in the

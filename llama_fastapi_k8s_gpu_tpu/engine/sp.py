@@ -54,6 +54,12 @@ class SPEngine(Engine):
             "ring shards the n_ctx slots of a KV ring, and its cache is a "
             "window plus chunk summaries")
 
+    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
+        raise ValueError(
+            "LFKT_MESH_SP > 1 cannot serve architecture 'minicpm-sala': the "
+            "sp ring shards the n_ctx slots of a KV ring, and its linear "
+            "layers keep a state per sequence, not slots")
+
     def __init__(self, model_path: str | None, *, sp: int = 2, tp: int = 1,
                  n_ctx: int = 4096, **kw):
         if sp < 2:

@@ -118,9 +118,21 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``attn_eva_mu`` (n_heads, head_dim) (this repo's names: llama.cpp has
 #: none), a float32 residual stream, and an output matrix of ``vocab_size *
 #: <arch>.prediction_heads`` rows.  A unit-offset norm gain is stored as
-#: applied (1 + g), as llama.cpp's converters store them.  A file of any
+#: applied (1 + g), as llama.cpp's converters store them.
+#: ``minicpm-sala`` (this repo's name: llama.cpp has none) is a stack of TWO
+#: layer kinds in the order ``<arch>.mixer_types`` gives (a comma-separated
+#: string of ``minicpm4`` | ``lightning-attn``, one per layer;
+#: models/sala.py): linear-attention layers that keep a decaying float32
+#: state per head (``blk.N.attn_{q,k,v,output,gate}``, ``attn_{q,k}_norm``
+#: and ``attn_out_norm`` of head width; ``<arch>.lightning.head_count``),
+#: and block-sparse attention layers on a ring of ``attention.head_count_kv``
+#: heads with no rotation (the same names without ``attn_out_norm``; the
+#: ``<arch>.sparse.*`` constants); the MiniCPM family's three scalars under
+#: llama.cpp's keys for it, ``<arch>.embedding_scale``, ``residual_scale``
+#: (scale_depth / sqrt(layers), as applied) and ``logit_scale`` (dim_model_base
+#: / hidden_size, as applied to the final norm's output).  A file of any
 #: other architecture is refused by name at load (gguf/reader.py).
-SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte")
+SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
@@ -128,7 +140,7 @@ SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte")
 #: converter permutes Q/K rows so that the pairs are (2i, 2i+1) (ggml's
 #: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
 #: the whole projection.
-NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte")
+NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte", "minicpm-sala")
 
 
 def align_up(n: int, alignment: int) -> int:

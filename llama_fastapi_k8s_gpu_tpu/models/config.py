@@ -15,6 +15,10 @@ from ..gguf import GGUFFile
 from ..gguf.constants import NEOX_ROPE_ARCHITECTURES
 
 
+#: the cache kinds' names (``ModelConfig.cache_kind``; docs/KV_CACHE.md)
+RING, WINDOW_SUMMARIES, STATE_RING = "ring", "window+summaries", "state+ring"
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int
@@ -70,10 +74,51 @@ class ModelConfig:
     # ``fp32_logits``): the residual stream and the logits are float32,
     # matmul inputs stay bf16
     fp32_residual: bool = False
+    # A stack of TWO layer kinds (models/sala.py; ``minicpm-sala``): the kind
+    # of each layer in the published order, ``"lin"`` (linear attention over
+    # a decaying float32 state per head) or ``"sp"`` (block-sparse attention
+    # on the ring of ``n_kv_heads`` heads); empty for every other file.
+    # ``n_heads`` / ``n_kv_heads`` are the sparse layers', ``lin_heads`` the
+    # linear layers' (of ``head_dim`` too).
+    mixers: tuple = ()
+    lin_heads: int = 0
+    # the family's three scalars, as applied: on the embedding, on every
+    # branch before it is added to the stream, on the final norm's output
+    emb_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    # the sparse layers' constants (positions): compressed keys are means of
+    # ``sp_kernel`` keys every ``sp_stride``; a query from ``sp_dense_len``
+    # on reads ``sp_init_blocks`` first blocks of ``sp_block``, the blocks
+    # that hold its last ``sp_window`` positions and the ``sp_topk`` others
+    # that score highest
+    sp_kernel: int = 0
+    sp_stride: int = 0
+    sp_block: int = 0
+    sp_topk: int = 0
+    sp_window: int = 0
+    sp_init_blocks: int = 0
+    sp_dense_len: int = 0
+    # float32 logits from a float head (bf16 inputs), as ``fp32_residual``
+    # has them, on a bf16 stream
+    fp32_logits: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def cache_kind(self) -> str:
+        """The NAME of the cache kind a sequence of this file holds
+        (docs/KV_CACHE.md "Cache kinds"), read here and nowhere else:
+        ``ring``, ``window+summaries`` (models/eva.py) or ``state+ring``
+        (models/sala.py)."""
+        if self.mixers:
+            return STATE_RING
+        return WINDOW_SUMMARIES if self.eva_window else RING
+
+    def n_layers_of(self, kind: str) -> int:
+        return sum(m == kind for m in self.mixers)
 
     @property
     def n_linear_weights(self) -> int:
@@ -110,6 +155,44 @@ class ModelConfig:
                     f"evabyte: {n_kv_heads} KV heads for {n_heads} heads: "
                     "the chunk summaries are per head (phi, mu), so the "
                     "block is multi-head only")
+        sala = {}
+        if arch == "minicpm-sala":
+            names = {"minicpm4": "sp", "lightning-attn": "lin"}
+            listed = str(h("mixer_types", "")).split(",")
+            if any(m not in names for m in listed) \
+                    or len(listed) != int(h("block_count")):
+                raise ValueError(
+                    f"minicpm-sala: mixer_types {listed!r} must name one of "
+                    f"{sorted(names)} for each of the {h('block_count')} "
+                    "layers")
+            sala = dict(
+                mixers=tuple(names[m] for m in listed),
+                lin_heads=int(h("lightning.head_count", n_heads)),
+                emb_scale=float(h("embedding_scale", 1.0)),
+                residual_scale=float(h("residual_scale", 1.0)),
+                logit_scale=float(h("logit_scale", 1.0)),
+                fp32_logits=True,
+                **{f"sp_{k}": int(h(f"sparse.{key}")) for k, key in (
+                    ("kernel", "kernel_size"), ("stride", "kernel_stride"),
+                    ("block", "block_size"), ("topk", "topk"),
+                    ("window", "window_size"), ("init_blocks", "init_blocks"),
+                    ("dense_len", "dense_len"))})
+            ctx = int(n_ctx if n_ctx is not None else min(train_ctx, 4096))
+            K, St, B = sala["sp_kernel"], sala["sp_stride"], sala["sp_block"]
+            if St < 1 or B % St or K % St or K > B + St:
+                raise ValueError(
+                    f"minicpm-sala: sparse.block_size {B} and kernel_size "
+                    f"{K} must be multiples of kernel_stride {St}, the "
+                    "kernel no wider than a block and a stride")
+            if ctx % B:
+                raise ValueError(
+                    f"minicpm-sala: n_ctx {ctx} is no multiple of "
+                    f"sparse.block_size {B} (LFKT_MAX_CONTEXT_TOKENS)")
+            if sala["lin_heads"] * (int(h("embedding_length")) // n_heads) \
+                    != int(h("embedding_length")):
+                raise ValueError(
+                    "minicpm-sala: lightning.head_count x head width must "
+                    "be the embedding length")
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -130,6 +213,7 @@ class ModelConfig:
             qk_norm=arch == "olmoe",
             rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
             **eva,
+            **sala,
         )
 
 

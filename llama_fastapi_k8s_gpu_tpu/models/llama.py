@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from ..ops import linear
 from ..ops.linear import linear_at
 from . import eva
-from .config import ModelConfig
+from .config import STATE_RING, ModelConfig
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -103,7 +103,14 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     attention reads stream int8.
 
     ``cfg.eva_window`` is the other cache KIND (models/eva.py): two window
-    leaves of ``eva_window`` slots and two summary leaves."""
+    leaves of ``eva_window`` slots and two summary leaves.  ``cfg.cache_kind
+    == "state+ring"`` is the third (models/sala.py): a ring and its
+    compressed keys for the sparse layers, a float32 state for the linear
+    ones, each as deep as its kind has layers."""
+    if cfg.cache_kind == STATE_RING:
+        from . import sala
+
+        return sala.init_cache(cfg, dtype)
     if cfg.eva_window:
         return eva.init_cache(cfg, dtype)
     shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
@@ -125,6 +132,10 @@ def cache_nbytes(cfg: ModelConfig) -> int:
     per lane) — the /health ``kv_cache_bytes`` figure and the lane-headroom
     math in docs/KV_CACHE.md, computed from shapes so callers never need a
     live cache."""
+    if cfg.cache_kind == STATE_RING:
+        from . import sala
+
+        return sala.cache_nbytes(cfg)
     if cfg.eva_window:
         return eva.cache_nbytes(cfg)
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
@@ -573,6 +584,12 @@ def forward(
     prediction head (``vocab_size * n_pred_heads`` rows) and not head 0's
     alone."""
     S = tokens.shape[0]
+    if cfg.cache_kind == STATE_RING:
+        # a stack of two layer kinds: its own loop, embedding and head
+        from . import sala
+
+        return sala.forward(params, cfg, tokens, pos_offset, cache, last_idx,
+                            return_all, live, with_picks, kv_bound)
     if cfg.eva_window and S > cfg.eva_window:
         raise ValueError(
             f"architecture 'evabyte': {S} positions in one pass, its window "

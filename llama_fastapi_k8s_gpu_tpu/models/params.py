@@ -54,6 +54,20 @@ logger = logging.getLogger(__name__)
 _LINEAR_MAKERS = {"bf16": make_linear_bf16, "int8": make_linear_int8}
 
 
+def flat_layers(layers: dict):
+    """(name, leaf) over the stacked layers' leaves, the leaves of a file
+    of several layer kinds (``{"lin": {...}, "sp": {...}}``, models/sala.py)
+    under ``<kind>.<name>``: what /health's ``weight_formats`` and the mesh
+    refusals walk."""
+    for name, leaf in layers.items():
+        if isinstance(leaf, dict) and any(
+                isinstance(v, dict) for v in leaf.values()):
+            for sub, subleaf in leaf.items():
+                yield f"{name}.{sub}", subleaf
+        else:
+            yield name, leaf
+
+
 def _tensor_to_device(t, dtype=jnp.float32) -> jax.Array:
     """Raw GGUF bytes → dequantized device array via the Pallas kernels
     (ops/pallas/dequant.py): the host ships quantized bytes, the chip
@@ -108,7 +122,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     base_fmt = "int8" if fmt == "q4k" else fmt
     make = _LINEAR_MAKERS[base_fmt]
 
-    def _fused_names() -> dict[str, object]:
+    def _fused_names(names=None, layer_ids=None) -> dict[str, object]:
         """Linear positions that can serve a fused kernel, mapped to the
         ONE GGML type the whole (L, ...) stack will use — stacked scan
         params need a single layout per name.
@@ -131,14 +145,17 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         k_rank = {GGMLType.Q4_K: 0, GGMLType.Q5_K: 1, GGMLType.Q6_K: 2}
         from ..ops.pallas.experts import experts_compatible
 
-        names = ["attn_q", "attn_k", "attn_v", "attn_output"]
-        if not cfg.n_experts:
-            names += ["ffn_gate", "ffn_up", "ffn_down"]
-        elif fused_experts:
-            names += ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"]
+        if names is None:
+            names = ["attn_q", "attn_k", "attn_v", "attn_output"]
+            if not cfg.n_experts:
+                names += ["ffn_gate", "ffn_up", "ffn_down"]
+            elif fused_experts:
+                names += ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"]
+        if layer_ids is None:
+            layer_ids = range(cfg.n_layers)
         ok: dict[str, object] = {}
         for n in names:
-            ts = [gf[f"blk.{i}.{n}.weight"] for i in range(cfg.n_layers)]
+            ts = [gf[f"blk.{i}.{n}.weight"] for i in layer_ids]
             # an expert stack (E, out, in): the grouped kernels' two types
             fits, allowed = (experts_compatible, [
                 t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
@@ -169,7 +186,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     # = host->device transfer of every packed plane)
     phase_s = {"prep": 0.0, "stack": 0.0}
 
-    def lin(name: str) -> dict:
+    def lin(name: str, fused_names: dict = fused_names) -> dict:
         short = name.split(".")[-2] if name.startswith("blk.") else name.split(".")[0]
         if short in fused_names:
             from ..gguf.constants import GGMLType
@@ -246,9 +263,37 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
 
     overlap = env_bool("LFKT_LOAD_OVERLAP", default=True)
 
+    def kinds_layers() -> dict:
+        """A file of two layer kinds (models/sala.py): {kind: its layers'
+        tensors, in the file's order within the kind}; a name fuses by the
+        types of ITS kind's layers."""
+        from .sala import LIN, SP
+
+        mats = {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
+                "wo": "attn_output", "wg": "attn_gate", "w_gate": "ffn_gate",
+                "w_up": "ffn_up", "w_down": "ffn_down"}
+        norms = ["attn_norm", "attn_q_norm", "attn_k_norm", "ffn_norm"]
+        out = {}
+        for kind in (LIN, SP):
+            ids = [i for i, m in enumerate(cfg.mixers) if m == kind]
+            fused = _fused_names(list(mats.values()), ids) \
+                if fmt == "q4k" else {}
+            out[kind] = []
+            for i in ids:
+                p = f"blk.{i}."
+                layer = {key: lin(p + name + ".weight", fused)
+                         for key, name in mats.items()}
+                for name in norms + (["attn_out_norm"] if kind == LIN else []):
+                    layer[name] = norm(p + name + ".weight")
+                if overlap:
+                    layer = jax.tree.map(jax.device_put, layer)
+                out[kind].append(layer)
+        return out
+
     layers = []
     t0 = _time.time()
-    for i in range(cfg.n_layers):
+    by_kind = kinds_layers() if cfg.mixers else None
+    for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."
         layer = {
             "attn_norm": norm(p + "attn_norm.weight"),
@@ -284,8 +329,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         emb = jnp.asarray(gf["token_embd.weight"].astype_f32(), dtype=jnp.bfloat16)
     if cfg.tie_embeddings or "output.weight" not in gf.tensors:
         output = {"w": emb}
-    elif cfg.fp32_residual and gf["output.weight"].ggml_type.name in (
-            "F32", "F16", "BF16"):
+    elif (cfg.fp32_residual or cfg.fp32_logits) \
+            and gf["output.weight"].ggml_type.name in ("F32", "F16", "BF16"):
         # float32 logits from a float head: bf16 inputs, nothing requantized
         t = gf["output.weight"]
         output = {"w": _tensor_to_device(t, jnp.bfloat16) if on_device
@@ -293,7 +338,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     else:
         output = lin("output.weight")
     t0 = _time.time()
-    stacked = _stack(layers, free=overlap)
+    stacked = _stack(layers, free=overlap) if by_kind is None else {
+        kind: _stack(ls, free=overlap) for kind, ls in by_kind.items()}
     jax.block_until_ready(stacked)
     phase_s["stack"] = _time.time() - t0
     logger.info("load_params phases: per-layer prep+transfer %.1fs, "

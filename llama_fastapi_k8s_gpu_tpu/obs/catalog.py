@@ -353,6 +353,34 @@ METRICS: dict[str, Metric] = _register(
            "windows turned into their chunk summaries: whole windows of a "
            "prompt at its prefill, and a decode step that writes a "
            "window's last position; cumulative"),
+    # -- the state + ring cache (models/sala.py; ``minicpm-sala``) ----------
+    Metric("lin_state_updates_total", GAUGE,
+           "updates of a linear-attention layer's state in decode steps: "
+           "one per step, linear layer and lane that holds a request (a "
+           "serial engine: its one sequence), cumulative; each reads and "
+           "writes heads x head_dim^2 float32; from host-tracked positions, "
+           "nothing fetched; exported by a file of that cache kind only"),
+    Metric("sparse_queries_total", GAUGE,
+           "queries of the block-sparse attention layers by the branch "
+           "they took (dense: plain causal attention on the ring, before "
+           "dense_len; sparse: scores, selection and a read of the selected "
+           "blocks): one per position of a prompt's prefill and per decode "
+           "step of a lane that holds a request, times the sparse layers; "
+           "cumulative", labels=("branch",)),
+    Metric("sparse_blocks_read_total", GAUGE,
+           "ring blocks the sparse branch's read covered in decode steps "
+           "(the first blocks, the window's, the picked ones), summed over "
+           "steps, lanes that hold a request, sparse layers and KV heads; "
+           "cumulative"),
+    Metric("sparse_blocks_visible_total", GAUGE,
+           "ring blocks a causal read would have covered, over the same "
+           "steps, lanes, layers and heads; read over visible = the share "
+           "of the ring the selection leaves to read"),
+    Metric("sparse_kc_written_total", GAUGE,
+           "compressed keys written (a mean over kernel_size keys, one "
+           "every kernel_stride positions, by the step or slice that "
+           "writes its last position), summed over the sparse layers; "
+           "prefill and decode; cumulative"),
     # -- runtime-synthesized families --------------------------------------
     Metric("scheduler_", GAUGE,
            "continuous-scheduler family (ContinuousEngine.scheduler_stats). "

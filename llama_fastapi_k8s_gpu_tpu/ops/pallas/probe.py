@@ -268,3 +268,32 @@ def probe_kv_quant() -> str | None:
 from ...obs.devtime import register_program  # noqa: E402
 
 register_program("probe_flash_attention", site="ops.pallas.probe")
+register_program("probe_lin_state", site="ops.pallas.probe")
+
+
+@functools.lru_cache(maxsize=1)
+def probe_lin_state() -> str | None:
+    """Compile + run the linear-attention layers' state step
+    (ops/pallas/linstate.py) over two lanes, one of them dead, at the
+    published head layout (32 heads of 128; 4 in interpret mode).  A
+    failure degrades a ``minicpm-sala`` file to ``attn_impl=xla``: the
+    plain XLA recurrence (``models/sala.py lin_step``), and with it the
+    ring's XLA read."""
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .linstate import lin_state_step
+
+        itp = use_interpret()
+        H, HD = (4 if itp else 32), 128
+        x = jnp.ones((2, H, HD), jnp.bfloat16)
+        state = jnp.zeros((2, 2, H, HD, HD), jnp.float32)
+        o, new = jax.jit(jax.vmap(lambda q, s, lv: lin_state_step(
+            q, q, q, s, jnp.int32(1), lv, jnp.full((H,), 0.5), interpret=itp)
+        ))(x, state, jnp.asarray([True, False]))
+        float(o.sum()) + float(new.sum())
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)

@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models.config import ModelConfig
+from ..models.config import STATE_RING, ModelConfig
 from ..models import eva
 from ..models.generate import chunk_out
 from ..models.llama import decode_kernel_block, forward, init_cache, prefill
@@ -66,6 +66,12 @@ def step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
     cache takes the three scalars of ``models/eva.py live_bounds`` (one
     bound becomes two, and whether any live lane closes a window in this
     step)."""
+    if cfg.cache_kind == STATE_RING:
+        # the sparse layers' two branches, each skipped where no live lane
+        # takes it (models/sala.py live_bounds)
+        from ..models import sala
+
+        return sala.live_bounds(pos, live, cfg)
     if cfg.eva_window:
         return eva.live_bounds(pos, live, cfg)
     if decode_kernel_block(cfg):

@@ -26,7 +26,7 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+from ..models.config import STATE_RING, ModelConfig
 
 
 def make_mesh(dp: int = 1, tp: int = 1, sp: int = 1, devices=None) -> Mesh:
@@ -100,7 +100,9 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
     for name, leaf in layers.items():
         if name in ("attn_norm", "ffn_norm"):
             layer_shard[name] = _ns(mesh, None, None)
-        elif not isinstance(leaf, dict) or name.endswith("_exps"):
+        elif not isinstance(leaf, dict) or name.endswith("_exps") \
+                or any(isinstance(v, dict) for v in leaf.values()):
+            # (and a layer kind's own stack, models/sala.py: tp = 1 there)
             # the routed block's router and QK-norm vectors, and its expert
             # planes: replicated (the grouped expert matmul has no
             # partitioning rule, so experts do not span a tp mesh)
@@ -136,6 +138,9 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, batched: bool = False):
     spec and the (L, n_kv, ctx) scale planes get it minus the hd axis."""
     lead = ("dp",) if batched else ()
     s4 = _ns(mesh, *lead, None, "tp", None, None)
+    if cfg.cache_kind == STATE_RING:   # models/sala.py, tp = 1
+        return {"k": s4, "v": s4, "kc": s4, "kw": s4,
+                "state": _ns(mesh, *lead, None, None, None, None)}
     if cfg.eva_window:   # window + summary leaves (models/eva.py), tp = 1
         return {name: s4 for name in ("k", "v", "sk", "sv")}
     if cfg.kv_dtype == "int8":

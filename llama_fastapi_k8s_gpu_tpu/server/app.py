@@ -1438,13 +1438,15 @@ def create_app(engine=None, settings: Settings | None = None,
             fmt = None
             params = getattr(eng, "params", None)
             if isinstance(params, dict) and "layers" in params:
+                from ..models.params import flat_layers
+
                 kinds = {"qs": "q4k-fused", "q5s": "q5k-fused",
                          "q5p": "q5k-fused-pre",
                          "q4": "q6k-fused", "q6p": "q6k-fused-pre",
                          "q8": "q8-fused", "q": "int8", "w": "bf16"}
                 fmt = {
                     name: next((v for k, v in kinds.items() if k in leaf), "?")
-                    for name, leaf in params["layers"].items()
+                    for name, leaf in flat_layers(params["layers"])
                     if isinstance(leaf, dict)
                 }
             engine_info = {
@@ -1573,7 +1575,11 @@ def create_app(engine=None, settings: Settings | None = None,
         # names (Engine.cache_read_gauges)
         reads = getattr(app.state.engine, "cache_read_gauges", None)
         for name, value in (reads() if reads is not None else {}).items():
-            m.set_gauge(name, value)
+            # a name may carry labels (sparse_queries_total{branch="dense"})
+            base, _, labels = name.partition("{")
+            m.set_gauge(base, value, **dict(
+                pair.split("=", 1) for pair in
+                labels.rstrip("}").replace('"', "").split(",") if pair))
         # routed layers (a file with experts): cumulative counters of the
         # decode chunks that have finished, folded here and not on the
         # decode path (engine/expert_counters.py)

@@ -77,9 +77,12 @@ def _quant_summary(engine) -> str | None:
     params = getattr(engine, "params", None)
     if not isinstance(params, dict) or "layers" not in params:
         return None
+    from ..models.params import flat_layers
+
     fmts = {
         next((v for k, v in _WEIGHT_KINDS.items() if k in leaf), "?")
-        for leaf in params["layers"].values() if isinstance(leaf, dict)
+        for _, leaf in flat_layers(params["layers"])
+        if isinstance(leaf, dict)
     }
     return "+".join(sorted(fmts)) if fmts else None
 

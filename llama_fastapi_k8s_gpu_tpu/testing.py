@@ -451,6 +451,122 @@ def write_tiny_evabyte_gguf(path: str, cfg: ModelConfig = TINY_EVABYTE_CFG,
     return cfg
 
 
+#: a tiny ``minicpm-sala`` stack: sparse layers adjacent and at both ends,
+#: 2 KV heads under 4 query heads, constants small enough that a sequence of
+#: a hundred positions crosses ``dense_len``, closes many compressed keys and
+#: leaves blocks unselected
+TINY_SALA_CFG = ModelConfig(
+    vocab_size=4 + 256, dim=128, n_layers=8, n_heads=4, n_kv_heads=2,
+    ffn_dim=192, n_ctx=256, rope_theta=10000.0, rms_eps=1e-6, rope_neox=True,
+    mixers=("sp", "lin", "lin", "sp", "sp", "lin", "lin", "sp"),
+    lin_heads=4, emb_scale=12.0, residual_scale=1.4 / 8 ** 0.5,
+    logit_scale=0.5, fp32_logits=True,
+    sp_kernel=4, sp_stride=2, sp_block=8, sp_topk=3, sp_window=16,
+    sp_init_blocks=1, sp_dense_len=48,
+)
+
+SALA_MIXER_NAMES = {"sp": "minicpm4", "lin": "lightning-attn"}
+
+#: the type mix of the benchmark's ``minicpm-sala`` file (llama.cpp's
+#: Q4_K_M recipe; the gate as the other square projections; embeddings and
+#: the head stay F16)
+SALA_Q4KM_MIX = {
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "attn_gate": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+}
+
+
+def write_tiny_sala_gguf(path: str, cfg: ModelConfig = TINY_SALA_CFG,
+                         seed: int = 0, quant: GGMLType = GGMLType.F16,
+                         mix: dict | None = None,
+                         qk_scale: float = 1.0) -> ModelConfig:
+    """Write a random-weight ``minicpm-sala`` GGUF (models/sala.py): the
+    mixer of each layer as ``<arch>.mixer_types``, the family's three
+    scalars, the sparse layers' constants, per kind its tensors
+    (``attn_gate`` in both, ``attn_out_norm`` in the linear layers; K and V
+    of ``n_kv_heads`` heads in the sparse ones), an F16 head and the
+    SentencePiece byte vocabulary.  Every matrix is ``quant`` unless
+    ``mix`` names its type (:data:`SALA_Q4KM_MIX`).  ``qk_scale``
+    multiplies the Q/K norm gains of the sparse layers: above 1 the
+    compressed-key scores are far from uniform, so that a selection is a
+    property of the weights and not of rounding."""
+    tokens, types, scores = spm_byte_vocab()
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    arch = "minicpm-sala"
+    w = GGUFWriter(path)
+    w.add_metadata("general.architecture", arch)
+    w.add_metadata("general.name", "tiny-sala-test")
+    w.add_metadata(f"{arch}.block_count", cfg.n_layers)
+    w.add_metadata(f"{arch}.context_length", cfg.n_ctx)
+    w.add_metadata(f"{arch}.embedding_length", cfg.dim)
+    w.add_metadata(f"{arch}.feed_forward_length", cfg.ffn_dim)
+    w.add_metadata(f"{arch}.attention.head_count", cfg.n_heads)
+    w.add_metadata(f"{arch}.attention.head_count_kv", cfg.n_kv_heads)
+    w.add_metadata(f"{arch}.attention.layer_norm_rms_epsilon", cfg.rms_eps)
+    w.add_metadata(f"{arch}.rope.freq_base", cfg.rope_theta)
+    w.add_metadata(f"{arch}.vocab_size", cfg.vocab_size)
+    w.add_metadata(f"{arch}.mixer_types",
+                   ",".join(SALA_MIXER_NAMES[m] for m in cfg.mixers))
+    w.add_metadata(f"{arch}.lightning.head_count", cfg.lin_heads)
+    w.add_metadata(f"{arch}.embedding_scale", cfg.emb_scale)
+    w.add_metadata(f"{arch}.residual_scale", cfg.residual_scale)
+    w.add_metadata(f"{arch}.logit_scale", cfg.logit_scale)
+    for key, field in (("kernel_size", "kernel"), ("kernel_stride", "stride"),
+                       ("block_size", "block"), ("topk", "topk"),
+                       ("window_size", "window"),
+                       ("init_blocks", "init_blocks"),
+                       ("dense_len", "dense_len")):
+        w.add_metadata(f"{arch}.sparse.{key}", getattr(cfg, f"sp_{field}"))
+    w.add_metadata("tokenizer.ggml.model", "llama")
+    w.add_metadata("tokenizer.ggml.tokens", tokens)
+    w.add_metadata("tokenizer.ggml.token_type", types)
+    w.add_metadata("tokenizer.ggml.scores", scores)
+    w.add_metadata("tokenizer.ggml.bos_token_id", 1)
+    w.add_metadata("tokenizer.ggml.eos_token_id", 2)
+    w.add_metadata("tokenizer.ggml.add_bos_token", True)
+    w.add_metadata("tokenizer.chat_template", MISTRAL_CHAT_TEMPLATE)
+    mix = mix or {}
+    D, F, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
+
+    def t(name, shape, gtype=None):
+        short = name.split(".")[-2]
+        w.add_tensor(name, rng.standard_normal(shape).astype(np.float32)
+                     * scale, mix.get(short, quant) if gtype is None
+                     else gtype)
+
+    def norm(name, n, mul=1.0):   # near one, not one
+        w.add_tensor(name, mul * (1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32)), GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i, kind in enumerate(cfg.mixers):
+        p = f"blk.{i}."
+        kv = D if kind == "lin" else cfg.n_kv_heads * hd
+        norm(p + "attn_norm.weight", D)
+        t(p + "attn_q.weight", (D, D))
+        t(p + "attn_k.weight", (kv, D))
+        t(p + "attn_v.weight", (kv, D))
+        t(p + "attn_output.weight", (D, D))
+        t(p + "attn_gate.weight", (D, D))
+        sharp = qk_scale if kind == "sp" else 1.0
+        norm(p + "attn_q_norm.weight", hd, sharp)
+        norm(p + "attn_k_norm.weight", hd, sharp)
+        if kind == "lin":
+            norm(p + "attn_out_norm.weight", hd)
+        norm(p + "ffn_norm.weight", D)
+        t(p + "ffn_gate.weight", (F, D))
+        t(p + "ffn_up.weight", (F, D))
+        t(p + "ffn_down.weight", (D, F))
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size, D), GGMLType.F16)
+    w.write()
+    return cfg
+
+
 def spm_byte_vocab() -> tuple[list[str], list[int], list[float]]:
     """Minimal SentencePiece-style vocab: specials + full byte fallback."""
     tokens = ["<unk>", "<s>", "</s>", "▁"]

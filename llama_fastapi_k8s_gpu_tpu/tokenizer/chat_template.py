@@ -18,14 +18,21 @@ from typing import Sequence
 from .base import Tokenizer
 
 
+def named_template(template: str | None) -> str | None:
+    """The kind a file's own ``tokenizer.chat_template`` names, or None
+    where it has none or one not known here (what
+    :func:`detect_chat_template` then falls back from)."""
+    for mark, kind in (("<|start_header_id|>", "llama3"),
+                       ("[INST]", "mistral"), ("<|im_start|>", "chatml")):
+        if template and mark in template:
+            return kind
+    return None
+
+
 def detect_chat_template(template: str | None, tokenizer: Tokenizer) -> str:
-    if template:
-        if "<|start_header_id|>" in template:
-            return "llama3"
-        if "[INST]" in template:
-            return "mistral"
-        if "<|im_start|>" in template:
-            return "chatml"
+    kind = named_template(template)
+    if kind:
+        return kind
     # fall back on vocab fingerprints
     if "<|start_header_id|>" in tokenizer.token_to_id:
         return "llama3"

@@ -414,6 +414,109 @@ def test_evabyte_step_reads_window_and_summaries_in_blocks(one_chip, name,
         assert sliced.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
 
 
+# BENCHMARK.json's minicpm-sala configuration at its published widths, n_ctx
+# 16384, the attention the chip resolves to: (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("sala-serial", 0),
+                                        ("sala-8lane", 8)])
+def test_sala_stack_compiles_with_no_ring_sized_copy(one_chip, name, lanes):
+    """The decode chunk and the prefill slice of the ``minicpm-sala`` stack
+    (models/sala.py: 24 linear-attention layers over a float32 state, 8
+    block-sparse layers on a 2-head ring of 16384 slots; nine runs of one
+    kind) compile for the chip with the ring's kernels inside (the decode
+    kernel at 2 KV heads and a group of 16, flash prefill at 16384 keys).
+    In the decode chunk the compiler has put NO ring-sized copy or
+    transpose (the compressed keys close from the small last-keys leaf: a
+    window read out of the ring at a lane's own position made it lay the
+    whole ring out anew in every sparse layer) and none of the state leaf
+    (the state's step is a kernel that updates the stacked leaf in place,
+    ops/pallas/linstate.py: the plain XLA step transposed the lanes' leaf
+    into and out of every chunk), and the scratch stays under half the
+    lanes' state (32 MB for one sequence): the lanes' compressed-key leaf
+    is laid out anew once into and once out of a chunk."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    order = "S" + "L" * 8 + "S" + "L" * 6 + "SS" + "L" * 4 + "S" + "L" * 6 \
+        + "SSS"
+    D, F, V, hd = 4096, 16384, 73448, 128
+    cfg = ModelConfig(
+        vocab_size=V, dim=D, n_layers=32, n_heads=32, n_kv_heads=2,
+        ffn_dim=F, n_ctx=16384, rope_theta=1e4, rms_eps=1e-6, rope_neox=True,
+        attn_impl="pallas",
+        mixers=tuple("sp" if c == "S" else "lin" for c in order),
+        lin_heads=32, emb_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        logit_scale=1 / 16, fp32_logits=True, sp_kernel=32, sp_stride=16,
+        sp_block=64, sp_topk=64, sp_window=2048, sp_init_blocks=1,
+        sp_dense_len=8192)
+
+    def kind(L, kv):
+        def lin(o, i):
+            return {"q": S(L, o, i, dtype=i8), "s": S(L, o, dtype=f32)}
+        return {"attn_norm": S(L, D, dtype=f32), "ffn_norm": S(L, D, dtype=f32),
+                "attn_q_norm": S(L, hd, dtype=f32),
+                "attn_k_norm": S(L, hd, dtype=f32),
+                "wq": lin(D, D), "wk": lin(kv, D), "wv": lin(kv, D),
+                "wo": lin(D, D), "wg": lin(D, D), "w_gate": lin(F, D),
+                "w_up": lin(F, D), "w_down": lin(D, F)}
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": {"w": S(V, D)},
+        "layers": {"lin": {**kind(24, D),
+                           "attn_out_norm": S(24, hd, dtype=f32)},
+                   "sp": kind(8, 2 * hd)}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("flash_attention_decode") >= 5    # one a sparse run
+    assert text.count("lin_state") >= 4            # one a linear run
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = (bf16\[(\d+,)*16384,128\]|"
+        r"f32\[(\d+,)*32,128,128\])\S* (copy|transpose)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    state_leaf = max(lanes, 1) * 24 * 32 * hd * hd * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < max(state_leaf // 2, 32 * 2 ** 20)
+    if not lanes:       # the admission slice into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        sliced = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile()
+        assert "flash_attention" in sliced.as_text()
+        assert sliced.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+
+
 def _placed_on_four(topo):
     """``placed(shape, dtype, *spec)``: shapes sharded over a dp=1, tp=4
     mesh of the described devices."""
