@@ -280,10 +280,11 @@ class ContinuousEngine(MeshEngine):
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
-    # (ring_slots, eva_counts: the scheduler thread alone adds, /metrics
-    # reads an int)
+    # (ring_slots, ring_rows_written, eva_counts: the scheduler thread
+    # alone adds, /metrics reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread", "ring_slots", "eva_counts", "sala_counts")
+                      "_thread", "ring_slots", "ring_rows_written",
+                      "eva_counts", "sala_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,

@@ -42,7 +42,7 @@ from ..models.generate import (
 )
 from ..models import eva, sala
 from ..models.llama import (
-    decode_chunk_slots, decode_kernel_block, init_cache)
+    decode_chunk_slots, decode_kernel_block, init_cache, ring_write_impl)
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
@@ -227,6 +227,11 @@ class Engine:
         # its live lanes, ContinuousEngine._note_ring_read); /metrics
         # ring_slots_*_total
         self.ring_slots = {"read": 0, "live": 0}
+        # K rows (and as many V rows) the decode kernel stored in the ring:
+        # lanes dispatched live x steps x layers (models/llama.py
+        # ring_write_impl; 0 where XLA writes); /metrics
+        # ring_rows_written_total
+        self.ring_rows_written = 0
         # the same for the window + summary cache (models/eva.py
         # chunk_counts; ring_slots stays 0 there): /metrics eva_*_total
         self.eva_counts = {"lane_steps": 0, "window_read": 0,
@@ -832,8 +837,13 @@ class Engine:
             live = [p for p in (wanted if live is None else live)
                     if p < first_sparse]
         block = decode_kernel_block(self.cfg)
-        bound = None if block else max(
-            wanted if live is None else live, default=0)
+        dispatched = wanted if live is None else live
+        bound = None if block else max(dispatched, default=0)
+        if ring_write_impl(self.cfg) == "kernel":
+            # every lane the chunk was dispatched with as live stored its
+            # row in every layer of every step; the others stored nothing
+            self.ring_rows_written += \
+                len(dispatched) * n_steps * self.cfg.n_layers
         for p in wanted:
             steps = n_steps if first_sparse is None \
                 else min(n_steps, max(first_sparse - p, 0))
@@ -847,7 +857,8 @@ class Engine:
         names: ``ring_slots_*`` for every engine (0 on a cache that is no
         ring), ``eva_*`` for a window + summary cache alone."""
         out = {"ring_slots_read_total": self.ring_slots["read"],
-               "ring_slots_live_total": self.ring_slots["live"]}
+               "ring_slots_live_total": self.ring_slots["live"],
+               "ring_rows_written_total": self.ring_rows_written}
         if self.cfg.eva_window:
             c = self.eva_counts
             out.update(

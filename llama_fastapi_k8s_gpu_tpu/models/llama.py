@@ -232,6 +232,20 @@ def decode_kernel_block(cfg: ModelConfig) -> int:
     return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
 
 
+def ring_write_impl(cfg: ModelConfig) -> str | None:
+    """Who stores a decode step's K and V row in a ring: ``kernel`` where
+    :func:`_layer` hands the row to the decode kernel
+    (:func:`decode_kernel_block` is not 0; a lane that holds no request
+    then stores nothing), else ``xla`` (``dynamic_update_slice``: int8
+    rings, mesh and sequence-parallel engines, the CPU, and the ring layers
+    of models/sala.py, which write before they call the kernel; prefill
+    slices on every path).  None on a cache that has no ring."""
+    if cfg.eva_window:
+        return None
+    kernel = decode_kernel_block(cfg) and cfg.cache_kind != STATE_RING
+    return "kernel" if kernel else "xla"
+
+
 def decode_read_slots(bound, n_ctx: int, block: int = 0):
     """(blocks, ring slots) a decode step's attention covers when the
     newest position it reads up to is ``bound``, in blocks of ``block``
@@ -382,6 +396,31 @@ def expert_stats_len(cfg: ModelConfig) -> int:
     return 2 + cfg.n_experts
 
 
+def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
+                   k_new=None, v_new=None):
+    """A decode step's attention through the decode kernel
+    (:func:`decode_kernel_block` is not 0): bounded by this sequence's own
+    position, and nothing at all for a lane that holds no request.  (1,
+    n_heads * head_dim) in ``dtype`` over a ring that holds the step's row;
+    given the row (``k_new``, ``v_new`` (n_kv, hd)) the kernel stores it
+    itself: (ctx, cache)."""
+    from ..ops.pallas import flash_attention_decode, use_interpret
+
+    out = flash_attention_decode(
+        q[0], cache["k"], cache["v"], i, pos,
+        True if live is None else live,
+        sm_scale=cfg.head_dim ** -0.5,
+        block_k=decode_kernel_block(cfg),
+        sliding_window=cfg.sliding_window,
+        interpret=use_interpret(),
+        k_new=k_new, v_new=v_new,
+    )
+    if k_new is None:
+        return out[None].astype(dtype)
+    ctx, k, v = out
+    return ctx[None].astype(dtype), {"k": k, "v": v}
+
+
 def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
                     kv_bound, live, cfg: ModelConfig, dtype):
     """One layer's attention over a RING cache, after the write: ``ck`` /
@@ -420,20 +459,11 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
             v_scale=cvs,
             interpret=use_interpret(),
         ).reshape(S, cfg.n_heads * hd).astype(dtype)
-    elif S == 1 and (block := decode_kernel_block(cfg)):
-        # a decode step reads the live part of the ring, not n_ctx slots:
-        # the kernel, bounded by this sequence's own position, and nothing
-        # at all for a lane that holds no request
-        from ..ops.pallas import flash_attention_decode, use_interpret
-
-        ctx = flash_attention_decode(
-            q[0], cache["k"], cache["v"], i, pos_offset,
-            True if live is None else live,
-            sm_scale=hd ** -0.5,
-            block_k=block,
-            sliding_window=cfg.sliding_window,
-            interpret=use_interpret(),
-        )[None].astype(dtype)
+    elif S == 1 and decode_kernel_block(cfg):
+        # a decode step reads the live part of the ring, not n_ctx slots
+        # (models/sala.py's ring layers, which write before they call;
+        # ``_layer`` hands the kernel the row to store)
+        ctx = _kernel_decode(q, cache, i, pos_offset, live, cfg, dtype)
     elif S == 1:
         # the same read as a loop in plain XLA, under one bound for all lanes
         ctx = decode_attention(
@@ -499,6 +529,7 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     q = rope(q, positions, cfg)
     k = rope(k, positions, cfg)
 
+    ctx = None
     if cfg.eva_window:
         # the other cache kind: its write, its attention and its window
         # close are one step (models/eva.py)
@@ -524,14 +555,21 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         # head-major write: transpose only the S new tokens, not the ring
         kh = k.astype(cache["k"].dtype).transpose(1, 0, 2)   # (n_kv, S, hd)
         vh = v.astype(cache["v"].dtype).transpose(1, 0, 2)
-        cache = {
-            "k": ring_write(cache["k"], kh, (i, 0, pos_offset, 0)),
-            "v": ring_write(cache["v"], vh, (i, 0, pos_offset, 0)),
-        }
-        ck, cv = at_layer(cache["k"]), at_layer(cache["v"])
         cks = cvs = None
+        if S == 1 and decode_kernel_block(cfg):
+            # the decode kernel stores the step's row itself, into the
+            # block it reads anyway, and nothing for a lane that holds no
+            # request: no update of the lanes' stacked leaf beside it
+            ctx, cache = _kernel_decode(q, cache, i, pos_offset, live, cfg,
+                                        h.dtype, kh[:, 0], vh[:, 0])
+        else:
+            cache = {
+                "k": ring_write(cache["k"], kh, (i, 0, pos_offset, 0)),
+                "v": ring_write(cache["v"], vh, (i, 0, pos_offset, 0)),
+            }
+            ck, cv = at_layer(cache["k"]), at_layer(cache["v"])
 
-    if not cfg.eva_window:     # the other kind attended above, with its write
+    if ctx is None:     # a ring, written above: attend by impl and length
         ctx = _ring_attention(q, ck, cv, cks, cvs, cache, i, positions,
                               pos_offset, kv_bound, live, cfg, h.dtype)
     h = h + lin(ctx, "wo")

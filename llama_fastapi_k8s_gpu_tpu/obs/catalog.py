@@ -326,6 +326,14 @@ METRICS: dict[str, Metric] = _register(
            "over the same steps and lanes; over ring_slots_read_total = the "
            "share of the read that was needed (a whole-ring read of 4096 "
            "at chat lengths: 0.11)"),
+    Metric("ring_rows_written_total", GAUGE,
+           "K rows (and as many V rows) the decode kernel stored in the KV "
+           "ring: lanes a decode chunk was dispatched with as live x its "
+           "steps x the layers, cumulative; a lane that holds no request "
+           "stores nothing.  0 where XLA writes the row (/health "
+           "engine.ring_write = xla: int8 rings, meshes, the CPU, a state "
+           "+ ring cache); host arithmetic at the chunk's harvest, nothing "
+           "fetched"),
     # -- the window + summary cache's read (models/eva.py; ``evabyte``) -----
     Metric("eva_lane_steps_total", GAUGE,
            "decode steps summed over the lanes that hold a request (a "

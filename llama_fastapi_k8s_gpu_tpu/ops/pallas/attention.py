@@ -334,6 +334,11 @@ def flash_attention(
 #: pass whatever the group (4 in GQA 32/8, 1 in MHA)
 _DECODE_ROWS = 16
 
+#: rows of the ring the kernel copies back around the row it stores: one
+#: bf16 tile's sublanes, what a block's slots are a multiple of
+#: (models/llama.py ``decode_kernel_block``)
+_ROW_TILE = 16
+
 
 def _decode_kernel(
     # scalar prefetch
@@ -342,24 +347,26 @@ def _decode_kernel(
     live_ref,           # (B,) int32: 0 = the lane holds no request
     # inputs
     q_ref,              # (1, n_kv, ROWS, hd): this lane's queries
-    k_hbm,              # (B, L, n_kv, n_ctx, hd) in HBM, read in place
-    v_hbm,
-    # output
-    o_ref,              # (1, n_kv, ROWS, hd)
+    *rest,
+    # store only:         kn_ref, vn_ref (1, n_kv, 1, hd): the step's rows
+    # k_hbm, v_hbm        (B, L, n_kv, n_ctx, hd) in HBM, read in place
+    # outputs
+    # o_ref               (1, n_kv, ROWS, hd)
+    # store only:         the rings again (aliased: the one buffer each)
     # scratch
-    kbuf,               # (2, n_kv, T, hd): two slots, copy against compute
-    vbuf,
-    sem,                # DMA semaphores (2 = k|v, 2 slots)
-    m_ref,              # (n_kv, ROWS, 128) f32 running max (lane-replicated)
-    l_ref,              # (n_kv, ROWS, 128) f32 running sum
-    acc_ref,            # (n_kv, ROWS, hd) f32 running weighted sum
-    slot_ref,           # SMEM (1,): the slot the next block to consume is in
-    nxt_ref,            # SMEM (B + 1,): first lane >= c that reads anything
-    *,
+    # kbuf, vbuf          (2, n_kv, T, hd): two slots, copy against compute
+    # sem                 DMA semaphores (2 = k|v, 2 slots)
+    # m_ref, l_ref        (n_kv, ROWS, 128) f32 running max and sum
+    #                     (lane-replicated)
+    # acc_ref             (n_kv, ROWS, hd) f32 running weighted sum
+    # slot_ref            SMEM (1,): the slot the next block to consume is in
+    # nxt_ref             SMEM (B + 1,): first lane >= c that reads anything
+    # store only:         wsem, DMA semaphores (2 = k|v) of the rows' tiles
     block_k: int,
     n_ctx: int,
     sliding_window: int,
     sm_scale: float,
+    store: bool = False,
 ):
     """One grid step is one LANE: a loop over that lane's own blocks, from
     the sliding window's first to the one that holds its position, with a
@@ -367,7 +374,25 @@ def _decode_kernel(
     starts no copy.  The copy of a block's K and V (all KV heads at once)
     is started one block ahead, across the lanes too: a lane's last
     iteration starts the next reading lane's first block, so only the very
-    first copy of a call is waited for in full."""
+    first copy of a call is waited for in full.
+
+    ``store``: the step's own K and V row (``kn_ref``, ``vn_ref``) is not
+    in the ring yet and the kernel puts it there.  The block that holds
+    the position is the last one a lane reads: once its copy has arrived
+    the row is set into it IN VMEM (a select over the bf16 tile of
+    ``_ROW_TILE`` rows that holds it: a single bf16 row is half a packed
+    sublane and no copy's unit), attention reads the block as it then
+    stands (the very values a write before the call would have left
+    there, in the same order), and the tile is copied back to the ring
+    while the block is computed on.  No read has to be ordered against
+    the write: the one block that holds the row is in VMEM before the row
+    is set.  A lane that holds no request stores nothing."""
+    if store:
+        kn_ref, vn_ref, _, _, o_ref, k_hbm, v_hbm, kbuf, vbuf, sem, m_ref, \
+            l_ref, acc_ref, slot_ref, nxt_ref, wsem = rest
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, \
+            slot_ref, nxt_ref = rest
     T = block_k
     b = pl.program_id(0)
     B = pl.num_programs(0)
@@ -394,6 +419,14 @@ def _decode_kernel(
 
     def start_first(lane, slot):
         start(lane, span(lane)[0], slot)
+
+    def tile_copies(slot, r, at):
+        """The tile of rows [r, r + _ROW_TILE) of this lane's block in
+        ``slot``, to the ring's slots from ``at`` on."""
+        return [pltpu.make_async_copy(
+            buf.at[slot, :, pl.ds(r, _ROW_TILE), :],
+            ring.at[b, layer, :, pl.ds(at, _ROW_TILE), :], wsem.at[n])
+            for n, (ring, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
 
     @pl.when(b == 0)
     def _first():
@@ -433,6 +466,22 @@ def _decode_kernel(
 
         for c in copies(b, j, slot):
             c.wait()
+        if store:
+            @pl.when(j == hi - 1)
+            def _():
+                # the slot ``dynamic_update_slice`` would write: clamped
+                at = jnp.minimum(pos, n_ctx - 1) - j * T
+                r = pl.multiple_of(at // _ROW_TILE * _ROW_TILE, _ROW_TILE)
+                for buf, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
+                    tile = buf[slot, :, pl.ds(r, _ROW_TILE), :]
+                    row = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+                    buf[slot, :, pl.ds(r, _ROW_TILE), :] = jnp.where(
+                        row == at - r,
+                        jnp.broadcast_to(new_ref[0], tile.shape), tile)
+                for c in tile_copies(slot, r,
+                                     pl.multiple_of(j * T + r, _ROW_TILE)):
+                    c.start()
+
         q = q_ref[0]                                   # (n_kv, ROWS, hd)
         k = kbuf[slot]                                 # (n_kv, T, hd)
         v = vbuf[slot]
@@ -457,34 +506,55 @@ def _decode_kernel(
         return 0
 
     jax.lax.fori_loop(lo, hi, block, 0)
+    if store:
+        # the tile's copy ran beside the last block's arithmetic; its slot
+        # is copied into again by the next lane's first iteration
+        @pl.when(hi > lo)
+        def _():
+            for c in tile_copies(0, 0, 0):
+                c.wait()
+
     l = l_ref[:, :, :1]
     o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def _decode_lanes(q, k, v, i, pos, live, *, block_k: int, sm_scale: float,
-                  sliding_window: int, interpret: bool):
+def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
+                  block_k: int, sm_scale: float, sliding_window: int,
+                  interpret: bool):
     """q (B, n_heads, hd), k / v (B, L, n_kv, n_ctx, hd), i scalar, pos and
-    live (B,) -> (B, n_heads * hd) in q.dtype: ONE kernel over the lanes."""
+    live (B,) -> (B, n_heads * hd) in q.dtype: ONE kernel over the lanes.
+    With the step's rows ``k_new`` / ``v_new`` (B, n_kv, hd) the kernel
+    stores them at (lane, i, :, pos, :) of the rings, which it then
+    returns beside the context as outputs aliased onto their inputs."""
     B, n_heads, hd = q.shape
     _, _, n_kv, n_ctx, _ = k.shape
     group = n_heads // n_kv
+    store = k_new is not None
     if n_ctx % block_k:
         raise ValueError(f"the ring's {n_ctx} slots are no multiple of the "
                          f"decode kernel's block of {block_k}")
+    if store and block_k % _ROW_TILE:
+        raise ValueError(f"the decode kernel's block of {block_k} slots is "
+                         f"no multiple of the {_ROW_TILE} rows it stores by")
     rows = max(_DECODE_ROWS, group)
     qg = jnp.pad(q.reshape(B, n_kv, group, hd),
                  ((0, 0), (0, 0), (0, rows - group), (0, 0)))
     lane_block = pl.BlockSpec((1, n_kv, rows, hd), lambda b, *_: (b, 0, 0, 0))
+    row_block = pl.BlockSpec((1, n_kv, 1, hd), lambda b, *_: (b, 0, 0, 0))
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    ctx_shape = jax.ShapeDtypeStruct((B, n_kv, rows, hd), q.dtype)
+    new = [x.reshape(B, n_kv, 1, hd) for x in (k_new, v_new)] if store else []
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k, n_ctx=n_ctx,
-                          sliding_window=sliding_window, sm_scale=sm_scale),
+                          sliding_window=sliding_window, sm_scale=sm_scale,
+                          store=store),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[lane_block,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=lane_block,
+            in_specs=[lane_block] + [row_block] * len(new)
+            + [in_place, in_place],
+            out_specs=[lane_block, in_place, in_place] if store
+            else lane_block,
             scratch_shapes=[
                 pltpu.VMEM((2, n_kv, block_k, hd), k.dtype),
                 pltpu.VMEM((2, n_kv, block_k, hd), v.dtype),
@@ -494,9 +564,14 @@ def _decode_lanes(q, k, v, i, pos, live, *, block_k: int, sm_scale: float,
                 pltpu.VMEM((n_kv, rows, hd), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.SMEM((B + 1,), jnp.int32),
-            ],
+            ] + ([pltpu.SemaphoreType.DMA((2,))] if store else []),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, rows, hd), q.dtype),
+        out_shape=[ctx_shape, jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)] if store
+        else ctx_shape,
+        # operands 6 and 7 (after the three prefetched scalars, the queries
+        # and the two rows): the rings, updated in place
+        input_output_aliases={6: 1, 7: 2} if store else {},
         # lanes in order: the slot parity and the copy started ahead are
         # carried from one lane to the next
         compiler_params=pltpu.CompilerParams(
@@ -504,8 +579,10 @@ def _decode_lanes(q, k, v, i, pos, live, *, block_k: int, sm_scale: float,
         interpret=interpret,
         name="flash_attention_decode",
     )(jnp.asarray(i, jnp.int32).reshape(1), pos.astype(jnp.int32),
-      live.astype(jnp.int32), qg, k, v)
-    return out[:, :, :group, :].reshape(B, n_heads * hd)
+      live.astype(jnp.int32), qg, *new, k, v)
+    ctx, *rings = out if store else (out,)
+    ctx = ctx[:, :, :group, :].reshape(B, n_heads * hd)
+    return (ctx, *rings) if store else ctx
 
 
 @functools.lru_cache(maxsize=8)
@@ -514,7 +591,9 @@ def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
     """The per-sequence call with its vmap rule: lanes ``vmap``ped over one
     step become ONE kernel over (B lanes), as the fused matmuls' rows do
     (qmatmul.py ``rows_vmappable``); without the rule ``vmap`` would batch
-    the kernel's grid and every lane would run the longest lane's trips."""
+    the kernel's grid and every lane would run the longest lane's trips.
+    ``rows``: nothing, or the step's K and V row (the rings are then
+    returned beside the context, all batched)."""
     from jax.custom_batching import custom_vmap
 
     lanes = functools.partial(
@@ -522,12 +601,14 @@ def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
         sliding_window=sliding_window, interpret=interpret)
 
     @custom_vmap
-    def one(q, k, v, i, pos, live):
-        return lanes(q[None], k[None], v[None], i, pos[None], live[None])[0]
+    def one(q, k, v, i, pos, live, *rows):
+        out = lanes(q[None], k[None], v[None], i, pos[None], live[None],
+                    *(r[None] for r in rows))
+        return jax.tree.map(lambda x: x[0], out)
 
     @one.def_vmap
-    def _rule(axis_size, in_batched, q, k, v, i, pos, live):  # noqa: ANN001
-        qb, kb, vb, ib, pb, lb = in_batched
+    def _rule(axis_size, in_batched, q, k, v, i, pos, live, *rows):  # noqa: ANN001
+        qb, kb, vb, ib, *rest_b = in_batched
         if ib:
             raise NotImplementedError(
                 "decode attention vmap: the layer index is one for all lanes")
@@ -536,8 +617,10 @@ def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
             return x if batched else jnp.broadcast_to(
                 x, (axis_size, *x.shape))
 
-        return lanes(per_lane(q, qb), per_lane(k, kb), per_lane(v, vb), i,
-                     per_lane(pos, pb), per_lane(live, lb)), True
+        out = lanes(per_lane(q, qb), per_lane(k, kb), per_lane(v, vb), i,
+                    *(per_lane(x, xb)
+                      for x, xb in zip((pos, live, *rows), rest_b)))
+        return out, jax.tree.map(lambda _: True, out)
 
     return one
 
@@ -554,7 +637,9 @@ def flash_attention_decode(
     block_k: int,
     sliding_window: int = 0,
     interpret: bool = False,
-) -> jax.Array:
+    k_new: jax.Array | None = None,   # (n_kv, hd): this step's K row and
+    v_new: jax.Array | None = None,   #   V row, not in the ring yet
+):
     """A decode step's attention (S = 1) over the live part of layer
     ``i``'s ring: the flash recurrence of ``models/llama.py
     decode_attention`` (f32 scores, max and sum; bf16 probabilities into an
@@ -564,11 +649,20 @@ def flash_attention_decode(
     (n_heads * hd,) in q.dtype.  Under ``vmap`` over lanes (everything but
     ``i`` batched) it is still one kernel (:func:`_decode_vmappable`), and
     a lane's output does not depend on its neighbours: each grid step
-    reads that lane's scalars, queries and blocks alone."""
+    reads that lane's scalars, queries and blocks alone.
+
+    With ``k_new`` / ``v_new`` the ring does not hold the step's own row
+    yet and the kernel stores it, at ``(i, :, pos, :)`` (clamped to the
+    ring's last slot, as ``dynamic_update_slice`` clamps), before it
+    attends: the result is ``(ctx, k, v)``, the rings the very buffers
+    that came in (aliased outputs) and, where ``live`` is False, untouched.
+    Without them the call reads a ring that was written before it."""
+    rows = () if k_new is None else (k_new.astype(k.dtype),
+                                     v_new.astype(v.dtype))
     return _decode_vmappable(int(block_k), float(sm_scale),
                              int(sliding_window), bool(interpret))(
         q, k, v, jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
-        jnp.asarray(live, jnp.bool_))
+        jnp.asarray(live, jnp.bool_), *rows)
 
 
 # devtime inventory (lfkt-lint PERF001): flash attention is a TRACE-INNER

@@ -211,10 +211,17 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
 
             def decode(q):
                 ring = jnp.ones((2, KV, CTX, HD), jnp.bfloat16)
-                return flash_attention_decode(
-                    q[0], ring, ring, jnp.int32(1), jnp.int32(CTX - 1), True,
-                    sm_scale=HD ** -0.5, block_k=CTX // 2, interpret=itp,
-                ).astype(jnp.float32).sum()
+                kw = dict(sm_scale=HD ** -0.5, block_k=CTX // 2,
+                          interpret=itp)
+                at = (jnp.int32(1), jnp.int32(CTX - 1), True)
+                # both forms: the one that stores the step's row (a dense
+                # stack's decode step) and the read-only one (a ring that
+                # was written before the call: models/sala.py)
+                row = jnp.ones((KV, HD), jnp.bfloat16)
+                ctx, k, v = flash_attention_decode(
+                    q[0], ring, ring, *at, k_new=row, v_new=row, **kw)
+                return (ctx + flash_attention_decode(q[0], k, v, *at, **kw)
+                        ).astype(jnp.float32).sum()
 
             float(jax.jit(decode)(q))
         if _env_kv_unroll() > 1:

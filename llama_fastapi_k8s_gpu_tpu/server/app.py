@@ -1453,6 +1453,9 @@ def create_app(engine=None, settings: Settings | None = None,
                 "model": getattr(eng, "model_name", None),
                 "n_ctx": getattr(cfg, "n_ctx", None),
                 "attn_impl": getattr(cfg, "attn_impl", None),
+                # who stores a decode step's K/V row in the ring: the
+                # decode kernel, or XLA (docs/KV_CACHE.md)
+                "ring_write": _ring_write(cfg),
                 "weight_formats": fmt,
                 # KV-cache dtype + resident HBM bytes: the kv_dtype=int8
                 # capacity win, verifiable per pod (docs/KV_CACHE.md)
@@ -1855,6 +1858,20 @@ def _mark_first_content(sspan, chunk) -> bool:
         return True
     sspan.event("first_content")
     return False
+
+
+def _ring_write(cfg) -> str | None:
+    """``/health`` ``engine.ring_write``: who stores a decode step's K and V
+    row in the ring, ``kernel`` or ``xla`` (models/llama.py
+    ``ring_write_impl``: the configuration decides, as it decides
+    ``attn_impl``); None without a model configuration or a ring."""
+    from ..models.config import ModelConfig
+
+    if not isinstance(cfg, ModelConfig):
+        return None
+    from ..models.llama import ring_write_impl
+
+    return ring_write_impl(cfg)
 
 
 def _device_info() -> dict:

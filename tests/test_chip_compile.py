@@ -222,6 +222,52 @@ def test_decode_attention_kernel_compiles(one_chip, lanes, layers, heads,
                 if " copy(" in ln and ring_shape in ln.split(" copy(")[0]]
 
 
+@pytest.mark.parametrize("lanes,layers,heads", [
+    (8, 32, (32, 8)),           # mistral-7b on 8 lanes: blocks of 256
+    (8, 16, (16, 16)),          # olmoe-1b-7b on 8 lanes: blocks of 128
+    (1, 48, (32, 8)),           # solar-10.7b, the serial engine
+])
+def test_decode_kernel_that_stores_the_row_compiles(one_chip, lanes, layers,
+                                                    heads):
+    """The decode kernel handed the step's K and V row (a select over one
+    bf16 tile of the block in VMEM, the tile copied back): still one Mosaic
+    call, its ring operands aliased onto its ring results and, the rings
+    donated, the program's ring arguments onto its ring results: no copy
+    of a ring anywhere."""
+    from llama_fastapi_k8s_gpu_tpu.models import llama
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention_decode
+
+    n_heads, n_kv = heads
+    block = llama.decode_kernel_block(ModelConfig(
+        vocab_size=64, dim=128 * n_heads, n_layers=layers, n_heads=n_heads,
+        n_kv_heads=n_kv, ffn_dim=64, n_ctx=4096, attn_impl="pallas"))
+    ring = S(lanes, layers, n_kv, 4096, 128)
+    row = S(lanes, n_kv, 128)
+
+    def fn(q, k, v, i, pos, live, kn, vn):
+        return jax.vmap(lambda q, k, v, p, lv, kn, vn: flash_attention_decode(
+            q, k, v, i, p, lv, sm_scale=128 ** -0.5, block_k=block,
+            interpret=False, k_new=kn, v_new=vn))(q, k, v, pos, live, kn, vn)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    txt = jax.jit(fn, donate_argnums=(1, 2)).lower(*map(place, (
+        S(lanes, n_heads, 128), ring, ring, S(dtype=i32),
+        S(lanes, dtype=i32), S(lanes, dtype=jnp.bool_), row, row))
+    ).compile().as_text()
+    assert txt.count("tpu_custom_call") == 1
+    assert "flash_attention_decode" in txt
+    head = txt.splitlines()[0]
+    assert "{1}: (1, {}, may-alias)" in head and "{2}: (2, {}, may-alias)" in head
+    call = next(ln for ln in txt.splitlines() if "tpu_custom_call" in ln)
+    assert "output_to_operand_aliasing={{1}: (6, {}), {2}: (7, {})}" in call
+    ring_shape = f"bf16[{lanes},{layers},{n_kv},4096,128]"
+    assert not [ln for ln in txt.splitlines()
+                if " copy(" in ln and ring_shape in ln.split(" copy(")[0]]
+
+
 @pytest.mark.parametrize("seq", [1, 128, 1024])
 def test_kv_quantize_compiles(one_chip, seq):
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.kvquant import quantize_kv_pallas
@@ -333,6 +379,70 @@ def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
     assert not found, found[:3]
     one_layer_ring = max(lanes, 1) * KV * 4096 * 128 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_ring
+
+
+@pytest.mark.parametrize("name,L,D,H,KV,F,V,qk_norm,lanes", [
+    ("solar-serial", 48, 4096, 32, 8, 14336, 32000, False, 0),
+    ("mistral-8lane", 32, 4096, 32, 8, 14336, 32000, False, 8),
+    ("olmoe-8lane", 16, 2048, 16, 16, 1024, 50304, True, 8),
+])
+def test_decode_step_leaves_the_ring_to_the_kernel(one_chip, monkeypatch,
+                                                   name, L, D, H, KV, F, V,
+                                                   qk_norm, lanes):
+    """The same decode chunk programs as a chip serves them
+    (``attn_impl="pallas"``, the decode kernel compiled by Mosaic: this
+    test says so in place of the backend): the kernel stores the step's
+    row itself, so the ONLY operation of the compiled program that takes
+    or gives a ring is the kernel's call: no ``dynamic-update-slice``, no
+    select fusion, no ``copy``, only the loops' tuples around it (the
+    serial engine's added lane axis is a bitcast)."""
+    import re
+    from collections import Counter
+
+    import llama_fastapi_k8s_gpu_tpu.ops.pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state)
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg = ModelConfig(vocab_size=V, dim=D, n_layers=L, n_heads=H,
+                      n_kv_heads=KV, ffn_dim=F, n_ctx=4096, qk_norm=qk_norm,
+                      rope_neox=qk_norm, attn_impl="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(_int8_params(cfg))
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ring = re.compile(r"bf16\[(\d+,)*4096,128\]")
+    op = re.compile(r"^\s*(ROOT )?%\S+ = .*? ([\w-]+)\(")
+    ops = Counter(op.match(ln).group(2) for ln in text.splitlines()
+                  if ring.search(ln) and op.match(ln))
+    assert ops["custom-call"] == 1, ops
+    assert set(ops) <= {"custom-call", "parameter", "tuple",
+                        "get-tuple-element", "while", "bitcast"}, ops
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_attention_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
 
 # BENCHMARK.json's evabyte configuration at its published widths, n_ctx

@@ -281,6 +281,163 @@ def test_kernel_matches_the_xla_loop(window, heads, pos):
         <= 2.0 ** -7 * vmax
 
 
+def _kernel_store(cfg, q, cache, i, pos, rows, live=True):
+    """``flash_attention_decode`` as ``_layer`` calls it: the step's K and
+    V row ride along and the kernel stores them.  (ctx, cache)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention_decode
+
+    ctx, k, v = flash_attention_decode(
+        q[0], cache["k"], cache["v"], i, pos, live,
+        sm_scale=cfg.head_dim ** -0.5, block_k=llama.decode_kernel_block(cfg),
+        sliding_window=cfg.sliding_window, interpret=True,
+        k_new=rows[0], v_new=rows[1])
+    return ctx[None], {"k": k, "v": v}
+
+
+def _rows(cfg, seed=9):
+    """One step's K and V row, head-major (n_kv, hd) bf16."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    shape = (cfg.n_kv_heads, cfg.head_dim)
+    return [jax.random.normal(k, shape, jnp.bfloat16) for k in ks]
+
+
+def _xla_write(cache, i, pos, rows):
+    """What ``_layer`` does on every other path: ``dynamic_update_slice``
+    of the stacked leaf at (i, 0, pos, 0)."""
+    return {n: jax.lax.dynamic_update_slice(
+        cache[n], r[None, :, None, :], (i, 0, pos, 0))
+        for n, r in zip(("k", "v"), rows)}
+
+
+def _same_bits(a, b):
+    return np.array_equal(np.asarray(a).view(np.uint16),
+                          np.asarray(b).view(np.uint16))
+
+
+@pytest.mark.parametrize("pos", [0, BLOCK - 1, BLOCK, BLOCK + 1, 70,
+                                 K_CTX - 1])
+@pytest.mark.parametrize("heads", [(32, 8), (16, 16)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
+def test_kernel_stores_the_row_and_matches_the_xla_loop(window, heads, pos):
+    """The kernel handed the step's row against ``dynamic_update_slice``
+    followed by the XLA loop: the context within the read-only kernel's
+    tolerance of the loop (and bit for bit the read-only kernel's over the
+    written ring: the row is set into the block before the block is read,
+    no sum is reordered), and the ring afterwards bit for bit the XLA
+    write's, every layer, head and slot of it."""
+    cfg = _kernel_cfg(window, heads)
+    cache, q, vmax = _ring(cfg)
+    rows = _rows(cfg)
+    written = _xla_write(cache, 1, pos, rows)
+    want = llama.decode_attention(q, written, 1, pos, pos, cfg, jnp.float32)
+    got, ring = _kernel_store(cfg, q, cache, 1, pos, rows)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) \
+        <= 2.0 ** -7 * max(vmax, float(jnp.max(jnp.abs(
+            rows[1].astype(jnp.float32)))))
+    assert _same_bits(got, _kernel(cfg, q, written, 1, pos))
+    for n in ("k", "v"):
+        assert _same_bits(ring[n], written[n]), n
+        assert not _same_bits(ring[n], cache[n])    # and a row was stored
+
+
+def test_the_stored_row_is_the_one_attended():
+    """The new row decides the output: with a key that dominates every
+    score the context is the new V row (bf16), whatever the ring held at
+    that slot before (NaN here: the stale slot is never read as it was)."""
+    cfg = _kernel_cfg()
+    cache, q, _ = _ring(cfg)
+    pos = 37
+    group = cfg.n_heads // cfg.n_kv_heads
+    stale = jax.tree.map(lambda a: a.at[1, :, pos].set(jnp.nan), cache)
+    qh = q[0].reshape(cfg.n_kv_heads, group, cfg.head_dim)
+    k_row = (100.0 * qh[:, 0]).astype(jnp.bfloat16)   # aligned with head 0's q
+    v_row = _rows(cfg)[1]
+    got, ring = _kernel_store(cfg, q, stale, 1, pos, [k_row, v_row])
+    got = got.reshape(cfg.n_kv_heads, group, cfg.head_dim)
+    assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+    assert _same_bits(got[:, 0], v_row)
+    assert _same_bits(ring["k"][1, :, pos], k_row)
+    assert _same_bits(ring["v"][1, :, pos], v_row)
+
+
+def test_a_position_past_the_ring_stores_at_its_last_slot():
+    """A freed lane's position walks on; where such a lane is still
+    dispatched as live the row lands where ``dynamic_update_slice`` clamps
+    it to: the ring's last slot, nothing else touched."""
+    cfg = _kernel_cfg()
+    cache, q, _ = _ring(cfg)
+    rows = _rows(cfg)
+    _, ring = _kernel_store(cfg, q, cache, 2, K_CTX + 40, rows)
+    written = _xla_write(cache, 2, K_CTX + 40, rows)
+    for n in ("k", "v"):
+        assert _same_bits(ring[n], written[n])
+        assert _same_bits(ring[n][2, :, K_CTX - 1], rows[n == "v"])
+
+
+def test_a_dead_lane_stores_nothing_and_lanes_store_their_own():
+    """``vmap`` over 4 lanes, one dead between live ones, positions in
+    different blocks.  A live lane's context AND ring are its own
+    single-sequence call's bit for bit (whatever its neighbours hold and
+    wherever the dead lane's position has walked to); the dead lane's ring
+    comes back bit for bit as it went in and its context is exactly 0."""
+    cfg = _kernel_cfg()
+    caches, qs = _lanes(cfg)
+    rows = [jnp.stack(r) for r in zip(*[_rows(cfg, seed=30 + n)
+                                        for n in range(4)])]
+    pos = jnp.asarray([3, 40, 97, 17], jnp.int32)
+    live = jnp.asarray([True, True, False, True])
+
+    def lanes(pos):
+        return jax.vmap(
+            lambda q, c, p, lv, kr, vr: _kernel_store(
+                cfg, q, c, 1, p, [kr, vr], lv))(
+                    qs, caches, pos, live, *rows)
+
+    got, rings = lanes(pos)
+    far, far_rings = lanes(pos.at[2].set(5000))
+    assert not bool(jnp.any(got[2])) and not bool(jnp.any(far[2]))
+    for n in ("k", "v"):
+        assert _same_bits(rings[n][2], caches[n][2])
+        assert _same_bits(far_rings[n][2], caches[n][2])
+    for lane in (0, 1, 3):
+        mine = jax.tree.map(lambda a: a[lane], caches)
+        alone, ring = _kernel_store(cfg, qs[lane], mine, 1, pos[lane],
+                                    [r[lane] for r in rows])
+        assert _same_bits(got[lane], alone) and _same_bits(far[lane], alone)
+        for n in ("k", "v"):
+            assert _same_bits(rings[n][lane], ring[n])
+            assert _same_bits(far_rings[n][lane], ring[n])
+            assert _same_bits(ring[n], _xla_write(
+                mine, 1, pos[lane], [r[lane] for r in rows])[n])
+
+
+@pytest.mark.parametrize("lanes", [1, 8])
+def test_the_call_with_rows_is_one_kernel_that_aliases_the_rings(lanes):
+    """Still ONE ``pallas_call`` over (B lanes), its ring operands (6 and 7,
+    after the three scalars, the queries and the two rows) aliased onto
+    its ring results; the call without rows is the read-only kernel it
+    was: no alias, one result."""
+    cfg = _kernel_cfg()
+    caches, qs = _lanes(cfg, lanes)
+    rows = [jnp.stack([r] * lanes) for r in _rows(cfg)]
+    pos = jnp.arange(lanes, dtype=jnp.int32) * 9
+    live = jnp.ones(lanes, bool)
+    stored = str(jax.make_jaxpr(jax.vmap(
+        lambda q, c, p, lv, kr, vr: _kernel_store(cfg, q, c, 1, p, [kr, vr],
+                                                  lv)))(
+            qs, caches, pos, live, *rows))
+    read = str(jax.make_jaxpr(jax.vmap(
+        lambda q, c, p, lv: _kernel(cfg, q, c, 1, p, lv)))(
+            qs, caches, pos, live))
+    for text in (stored, read):
+        assert text.count("pallas_call") == 1
+        assert "name=flash_attention_decode" in text
+        assert f"grid=({lanes},)" in text
+    assert "input_output_aliases=((6, 1), (7, 2))" in stored
+    assert "input_output_aliases=()" in read
+
+
 @pytest.mark.parametrize("cfg_kw,block", [
     (dict(attn_impl="pallas"), 16),
     (dict(attn_impl="xla"), 0),                  # CPU, a mesh engine

@@ -244,6 +244,98 @@ def test_a_lanes_logits_do_not_depend_on_the_other_lanes(attn_impl, heads,
     assert not np.array_equal(bits(got[1]), bits(got[0]))
 
 
+def _xla_write_then_kernel(monkeypatch):
+    """``_layer`` as it was before the kernel stored the row: the XLA
+    write of the stacked leaf, then the read-only kernel."""
+    real = llama._kernel_decode
+
+    def old(q, cache, i, pos, live, cfg, dtype, k_new=None, v_new=None):
+        if k_new is None:
+            return real(q, cache, i, pos, live, cfg, dtype)
+        cache = {n: jax.lax.dynamic_update_slice(
+            cache[n], r[None, :, None, :], (i, 0, pos, 0))
+            for n, r in (("k", k_new), ("v", v_new))}
+        return real(q, cache, i, pos, live, cfg, dtype), cache
+
+    monkeypatch.setattr(llama, "_kernel_decode", old)
+
+
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
+def test_64_greedy_steps_with_the_write_folded_serial(monkeypatch, heads,
+                                                      window):
+    """One sequence, a prefill of 8 and 64 greedy decode steps through
+    ``forward`` (the serial engine's program), across the block edges at
+    16..64: with the kernel storing the row the logits of every step and
+    the ring at the end are BITWISE those of the XLA write followed by the
+    read-only kernel (the row is set into the block in VMEM before the
+    block is read: the same values in the same order)."""
+    cfg = _cfg(heads, window, attn_impl="pallas")
+    params = synth_params(cfg, seed=4)
+
+    def run():
+        step = jax.jit(lambda t, p, c: llama.decode_step(params, cfg, t, p, c))
+        logits, cache = llama.prefill(
+            params, cfg, jnp.arange(8, dtype=jnp.int32), jnp.int32(8),
+            llama.init_cache(cfg))
+        rows = []
+        for pos in range(8, 72):
+            rows.append(bits(logits))
+            logits, cache = step(jnp.argmax(logits).astype(jnp.int32),
+                                 jnp.int32(pos), cache)
+        return rows, cache
+
+    got, got_cache = run()
+    _xla_write_then_kernel(monkeypatch)
+    want, want_cache = run()
+    assert len({int(np.argmax(r.view(np.float32))) for r in got}) > 4
+    for n, (a, b) in enumerate(zip(got, want)):
+        assert np.array_equal(a, b), n
+    for name in ("k", "v"):
+        assert np.array_equal(bits(got_cache[name]), bits(want_cache[name]))
+
+
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+def test_64_greedy_steps_with_the_write_folded_lanes(monkeypatch, heads):
+    """The lane program's step over 4 lanes at different positions, lane 2
+    dead with a random ring and a position that walks on: 64 greedy steps.
+    The live lanes' logits at every step and their rings at the end are
+    BITWISE those of the XLA write followed by the read-only kernel.  The
+    dead lane's ring comes back as it went in under the kernel, and
+    changed under the XLA write (the test can tell)."""
+    cfg = _cfg(heads, attn_impl="pallas")
+    params = synth_params(cfg, seed=4)
+    lanes = [_random_cache(cfg, seed=40 + n) for n in range(4)]
+    caches0 = jax.tree.map(lambda *a: jnp.stack(a), *lanes)
+    live = jnp.asarray([True, True, False, True])
+    start = np.array([3, 30, 50, 15])
+
+    def run():
+        step = _lane_step(params, cfg)
+        toks = jnp.asarray([9, 7, 11, 13], jnp.int32)
+        caches, rows = caches0, []
+        for t in range(64):
+            logits, caches = step(toks, jnp.asarray(start + t, jnp.int32),
+                                  caches, live)
+            rows.append(bits(logits))
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return rows, caches
+
+    got, got_caches = run()
+    _xla_write_then_kernel(monkeypatch)
+    want, want_caches = run()
+    for n, (a, b) in enumerate(zip(got, want)):
+        assert np.array_equal(a[[0, 1, 3]], b[[0, 1, 3]]), n
+    for name in ("k", "v"):
+        for lane in (0, 1, 3):
+            assert np.array_equal(bits(got_caches[name][lane]),
+                                  bits(want_caches[name][lane]))
+        assert np.array_equal(bits(got_caches[name][2]),
+                              bits(caches0[name][2]))
+        assert not np.array_equal(bits(want_caches[name][2]),
+                                  bits(caches0[name][2]))
+
+
 def test_an_all_masked_block_leaves_the_state_bit_for_bit():
     """The recurrence itself: blocks beyond the position (a later bound)
     change nothing, also under a sliding window whose FIRST blocks hold no
@@ -417,13 +509,50 @@ def test_the_lane_counters_under_the_kernel_are_per_lane():
                                      finished=finished)
 
     eng = types.SimpleNamespace(cfg=_cfg(attn_impl="pallas"),
-                                ring_slots={"read": 0, "live": 0})
+                                ring_slots={"read": 0, "live": 0},
+                                ring_rows_written=0)
     eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
     pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
     ContinuousEngine._note_ring_read(eng, pre, 4)
     assert eng.ring_slots == {
         "read": (16 + 16 + 32 + 32) + (32 + 32 + 48 + 48),
         "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34)}
+    # the kernel stored a K row for each of the THREE lanes the chunk was
+    # dispatched with as live (the finished one too: the device does not
+    # know yet), in 4 steps x 3 layers; the empty lane stored nothing
+    assert eng.ring_rows_written == 3 * 4 * 3
+
+
+@pytest.mark.parametrize("cfg_kw,who", [
+    (dict(attn_impl="pallas"), "kernel"),
+    (dict(attn_impl="xla"), "xla"),                     # the CPU, a mesh
+    (dict(attn_impl="ring"), "xla"),                    # sequence parallel
+    (dict(attn_impl="pallas", kv_dtype="int8"), "xla"),
+    (dict(attn_impl="pallas", mixers=("sp", "lin", "sp")), "xla"),
+    (dict(attn_impl="pallas", eva_window=64, eva_chunk=4), None)])
+def test_who_writes_a_decode_steps_row(cfg_kw, who):
+    """``/health`` ``engine.ring_write``: the kernel where ``_layer`` hands
+    it the row, XLA on every other ring (``models/sala.py`` writes before
+    it calls the kernel), nothing to say where there is no ring."""
+    from llama_fastapi_k8s_gpu_tpu.server.app import _ring_write
+
+    cfg = dataclasses.replace(_cfg(), **cfg_kw)
+    assert llama.ring_write_impl(cfg) == who
+    assert _ring_write(cfg) == who
+    assert _ring_write(None) is None
+
+
+def test_rows_written_count_only_where_the_kernel_writes():
+    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
+
+    for impl, rows in (("pallas", 2 * 5 * 3), ("xla", 0)):
+        eng = types.SimpleNamespace(cfg=_cfg(attn_impl=impl),
+                                    ring_slots={"read": 0, "live": 0},
+                                    ring_rows_written=0)
+        Engine._note_cache_read(eng, [7, 40], 5)
+        assert eng.ring_rows_written == rows
+        eng.eva_counts = eng.sala_counts = {}
+        assert Engine.cache_read_gauges(eng)["ring_rows_written_total"] == rows
 
 
 def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
@@ -450,6 +579,13 @@ def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
     assert text
     assert serial.ring_slots["read"] % BLOCK == 0
     assert serial.ring_slots["live"] / serial.ring_slots["read"] > 0.6
+    # the kernel stored every decode step's row itself: a K row a layer
+    layers = serial.cfg.n_layers
+    assert llama.ring_write_impl(serial.cfg) == "kernel"
+    assert serial.ring_rows_written > 0
+    assert serial.ring_rows_written % layers == 0
+    assert serial.cache_read_gauges()["ring_rows_written_total"] \
+        == serial.ring_rows_written
 
     eng = ContinuousEngine(path, batch_size=3, dp=1, **kw)   # no mesh
 
@@ -472,5 +608,7 @@ def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
         read, live = eng.ring_slots["read"], eng.ring_slots["live"]
         assert read % BLOCK == 0 and 0 < live <= read
         assert live / read > 0.6
+        assert eng.ring_rows_written > 0
+        assert eng.ring_rows_written % (layers * kw["decode_chunk"]) == 0
     finally:
         eng.shutdown()
