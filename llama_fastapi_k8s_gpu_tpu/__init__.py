@@ -30,4 +30,11 @@ in-tree, TPU-first:
 - ``utils``      — config, logging, metrics plumbing.
 """
 
+import time as _time
+
+#: when this package was first imported (``time.time()``): the start-up
+#: timeline's boundary between what a launcher paid and what the program's
+#: own imports cost (server/__main__.py, utils/startup.py)
+T_IMPORTED = _time.time()
+
 __version__ = "0.1.0"

@@ -142,16 +142,18 @@ class MeshEngine(Engine):
             kw["attn_impl"] = "xla"
         self._mesh_shape = (dp, tp)    # read by _refuse_for_window_cache
         super().__init__(model_path, **kw)
-        self.mesh = make_mesh(dp=dp, tp=tp)
-        self.batch_size = batch_size or dp
-        if self.batch_size % dp:
-            raise ValueError(
-                f"batch_size {self.batch_size} must be divisible by dp={dp}")
-        _refuse_fused_on_a_tpu_mesh(self.params, dp, tp)
-        self.params = shard_params(self.params, self.mesh)
-        state = init_batched_state(self.cfg, self.batch_size)
-        self._bstate = jax.device_put(
-            state, state_shardings(self.cfg, self.mesh, batched=True))
+        with self.startup.phase("lanes_alloc"):
+            self.mesh = make_mesh(dp=dp, tp=tp)
+            self.batch_size = batch_size or dp
+            if self.batch_size % dp:
+                raise ValueError(
+                    f"batch_size {self.batch_size} must be divisible by "
+                    f"dp={dp}")
+            _refuse_fused_on_a_tpu_mesh(self.params, dp, tp)
+            self.params = shard_params(self.params, self.mesh)
+            state = init_batched_state(self.cfg, self.batch_size)
+            self._bstate = jax.device_put(
+                state, state_shardings(self.cfg, self.mesh, batched=True))
         # lfkt-mem: the shared lane state is this engine family's biggest
         # serving allocation — attribute it (provider reads the live
         # reference, so watchdog re-inits stay correct automatically)
@@ -197,31 +199,33 @@ class MeshEngine(Engine):
             state, state_shardings(self.cfg, self.mesh, batched=True))
 
     # ------------------------------------------------------------------
-    def warmup(self):
+    def _warmup_steps(self, ph) -> str:
         """Compile every shape a request can hit: the batched prefill for
         every bucket + the batched decode chunk, AND the serial path (the
         server's /response/stream uses Engine's streaming generation)."""
-        t0 = time.time()
         msgs = [{"role": "user", "content": "hi"}]
         # TWO full decode chunks: chunk 2's donated state carries jit-chosen
         # shardings, a distinct compile the one-chunk warmup used to leave
         # for the first real request (devtime pin, tests/test_perf_pins.py)
-        self.create_chat_completions([msgs] * self.batch_size,
-                                     max_tokens=2 * self.decode_chunk + 1,
-                                     temperature=0.0)
+        with ph.child("batch_round"):
+            self.create_chat_completions([msgs] * self.batch_size,
+                                         max_tokens=2 * self.decode_chunk + 1,
+                                         temperature=0.0)
         with self._lock:   # uncontended at warmup; keeps the _bstate
             #                write invariant (writes only under _lock)
-            for bucket in self.prefill_buckets[1:]:
-                tokens = jnp.zeros((self.batch_size, bucket), jnp.int32)
-                lengths = jnp.ones((self.batch_size,), jnp.int32)
-                _, caches = batched_prefill_jit(
-                    self.params, self.cfg, tokens, lengths,
-                    self._bstate["cache"])
-                self._bstate["cache"] = caches
-        super().warmup()  # serial buckets + decode chunk (streaming path)
-        logger.info("mesh warmup done in %.1fs (dp=%d tp=%d batch=%d)",
-                    time.time() - t0, self.mesh.shape["dp"],
-                    self.mesh.shape["tp"], self.batch_size)
+            with ph.child("batch_buckets",
+                          n_buckets=len(self.prefill_buckets) - 1):
+                for bucket in self.prefill_buckets[1:]:
+                    tokens = jnp.zeros((self.batch_size, bucket), jnp.int32)
+                    lengths = jnp.ones((self.batch_size,), jnp.int32)
+                    _, caches = batched_prefill_jit(
+                        self.params, self.cfg, tokens, lengths,
+                        self._bstate["cache"])
+                    self._bstate["cache"] = caches
+        # serial buckets + decode chunk (streaming path)
+        serial = super()._warmup_steps(ph)
+        return (f"dp={self.mesh.shape['dp']} tp={self.mesh.shape['tp']} "
+                f"batch={self.batch_size}, {serial}")
 
     # ------------------------------------------------------------------
     def create_chat_completions(  # lfkt: blocks-under[_lock] -- the mesh engine serializes whole batches under its lock by design: drill sleeps and incident capture ride the generation path

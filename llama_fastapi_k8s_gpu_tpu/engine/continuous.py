@@ -361,6 +361,7 @@ class ContinuousEngine(MeshEngine):
             else "lane_prefix"
         self._prefix_stats = {f"{self._reuse_stat}_hits": 0,
                               f"{self._reuse_stat}_reused_tokens": 0}
+        sched = self.startup.phase("scheduler_start")
         self._scratch_cache = init_cache(self.cfg)
         # lfkt-mem: attribute the persistent prefill scratch (the lane
         # state rode MeshEngine's registration; the serial ring the base's)
@@ -386,6 +387,7 @@ class ContinuousEngine(MeshEngine):
         self._thread = threading.Thread(
             target=self._loop, name="lfkt-scheduler", daemon=True)
         self._thread.start()
+        sched.close()
 
     @staticmethod
     def _zero_totals() -> dict:
@@ -616,43 +618,48 @@ class ContinuousEngine(MeshEngine):
         self._wake.set()
         self._thread.join(timeout=10)
 
-    def warmup(self):
+    def _warmup_steps(self, ph) -> str:
         """Compile the scheduler's shapes: every admission prefill SLICE
         shape (the scheduler prefills via prefill_chunk_jit, not the serial
         engine's bucket-sized prefill_jit), first-token sampling, the lane
         write, and the batched decode chunk.  Streams ride the same lane
         programs, so one streamed request exercises (but doesn't extend)
         the compiled set."""
-        t0 = time.time()
         msgs = [{"role": "user", "content": "hi"}]
-        futs = [self.submit(msgs, max_tokens=self.decode_chunk + 1,
-                            temperature=0.0)
-                for _ in range(self.batch_size)]
-        for f in futs:
-            f.result()
-        list(self.submit_stream(msgs, max_tokens=self.decode_chunk + 1,
-                                temperature=0.0))
+        with ph.child("lanes_round"):
+            futs = [self.submit(msgs, max_tokens=self.decode_chunk + 1,
+                                temperature=0.0)
+                    for _ in range(self.batch_size)]
+            for f in futs:
+                f.result()
+        with ph.child("stream_round"):
+            list(self.submit_stream(msgs, max_tokens=self.decode_chunk + 1,
+                                    temperature=0.0))
         # every slice shape a bucket walk can produce, compiled against a
         # throwaway cache (jit program caches are global, so the scheduler
         # thread hits them warm; its own scratch cache is never touched)
-        cache = init_cache(self.cfg)
-        for b in self.prefill_buckets:
-            off = 0
-            while off < b:
-                C = min(self._prefill_chunk, b - off)
-                _, cache = prefill_chunk_jit(
-                    self.params, self.cfg, jnp.zeros((C,), jnp.int32),
-                    jnp.int32(off), jnp.int32(C - 1), cache)
-                off += C
+        with ph.child("slice_shapes") as slices:
+            cache = init_cache(self.cfg)
+            shapes = set()
+            for b in self.prefill_buckets:
+                off = 0
+                while off < b:
+                    C = min(self._prefill_chunk, b - off)
+                    _, cache = prefill_chunk_jit(
+                        self.params, self.cfg, jnp.zeros((C,), jnp.int32),
+                        jnp.int32(off), jnp.int32(C - 1), cache)
+                    off += C
+                    shapes.add(C)
+            slices.attrs["n_shapes"] = len(shapes)
         if self._lane_prefix:
             # compile the lane→scratch snapshot gather (one program; the
             # suffix slice shapes are already in the warmed set above)
-            jax.block_until_ready(_lane_cache_copy_jit(
-                self._bstate["cache"], jnp.int32(0)))
-        jax.block_until_ready(cache)
-        self.load_phases["warmup_s"] = round(time.time() - t0, 1)
-        logger.info("continuous warmup done in %.1fs (%d lanes)",
-                    self.load_phases["warmup_s"], self.batch_size)
+            with ph.child("lane_copy"):
+                jax.block_until_ready(_lane_cache_copy_jit(
+                    self._bstate["cache"], jnp.int32(0)))
+        with ph.child("drain"):   # the slices queued above, still running
+            jax.block_until_ready(cache)
+        return f"{self.batch_size} lanes"
 
     # ------------------------------------------------------------------
     # scheduler internals (all device work on the scheduler thread)

@@ -53,6 +53,7 @@ from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
 from .expert_counters import ExpertCounters
 from ..utils.jaxcache import setup_compile_cache
+from ..utils.startup import Phase, Timeline, legacy_load_phases
 
 logger = logging.getLogger(__name__)
 
@@ -259,11 +260,12 @@ class Engine:
         setup_compile_cache()
         arm_phases()   # lfkt.* phases emit iff /debug/profile can be armed
 
-        #: coarse wall-clock attribution of model load (tokenizer build,
-        #: fused-kernel compile probes, weight prep+transfer) — surfaced
-        #: by the coldstart bench to direct startup-latency work; empty for
-        #: in-memory (_parts) engines
-        self.load_phases: dict = {}
+        #: this engine's stretch of the start-up timeline (utils/startup.py):
+        #: ``gguf_open`` ... ``warmup``, each stamped where the work
+        #: happens; an in-memory (_parts) engine has no file phases.  The
+        #: app takes it into ``/health`` ``engine.startup`` at the READY
+        #: flip; ``load_phases`` is a view of it
+        self.startup = tl = Timeline()
         #: whether the file's own chat template names a kind known here
         #: (else detect_chat_template fell back, and /health says so)
         self._template_named = True
@@ -271,13 +273,12 @@ class Engine:
             self.params, self.cfg, self.tokenizer, self.template_kind = _parts
             self.model_name = "in-memory"
         else:
-            t0 = time.time()
-            gf = GGUFFile(model_path)
-            self.model_name = gf.metadata.get("general.name", model_path)
-            self.cfg = ModelConfig.from_gguf(gf, n_ctx=n_ctx)
-            _pt = time.time()
-            self.tokenizer = tokenizer_from_gguf(gf)
-            self.load_phases["tokenizer_s"] = round(time.time() - _pt, 1)
+            with tl.phase("gguf_open") as opened:
+                gf = GGUFFile(model_path)
+                self.model_name = gf.metadata.get("general.name", model_path)
+                self.cfg = ModelConfig.from_gguf(gf, n_ctx=n_ctx)
+            with tl.phase("tokenizer"):
+                self.tokenizer = tokenizer_from_gguf(gf)
             if weight_format == "auto":
                 # bf16 params ≈ 2 bytes/weight; small models keep exact
                 # bf16.  Large models on TPU serve "q4k": Q4_K/Q6_K tensors
@@ -296,28 +297,33 @@ class Engine:
             fused_experts = True
             if weight_format == "q4k":
                 present = {t.ggml_type for t in gf.tensors.values()}
-                _pt = time.time()
-                weight_format, fused_types = self._probe_fused_format(present)
-                if self.cfg.n_experts and weight_format == "q4k":
-                    # the grouped expert kernels: probed only for a file
-                    # that has experts (a dense pod's start pays nothing)
-                    from ..ops.pallas.probe import probe_fused_experts
+                with tl.phase("probes", meter="cache") as probes:
+                    weight_format, fused_types = self._probe_fused_format(
+                        present)
+                    if self.cfg.n_experts and weight_format == "q4k":
+                        # the grouped expert kernels: probed only for a
+                        # file that has experts (a dense pod's start pays
+                        # nothing)
+                        from ..ops.pallas.probe import probe_fused_experts
 
-                    err = probe_fused_experts()
-                    if err is not None:
-                        fused_experts = False
-                        logger.error(
-                            "grouped expert kernels failed their compile "
-                            "probe; experts load dequantized: %s", err)
-                self.load_phases["probes_s"] = round(time.time() - _pt, 1)
-            _pt = time.time()
-            sub: dict = {}
-            self.params = load_params(gf, self.cfg, weight_format,
-                                      fused_types=fused_types, phases_out=sub,
-                                      fused_experts=fused_experts)
-            self.load_phases["params_s"] = round(time.time() - _pt, 1)
-            self.load_phases.update(
-                {f"params_{k}_s": round(v, 1) for k, v in sub.items()})
+                        err = probe_fused_experts()
+                        if err is not None:
+                            fused_experts = False
+                            logger.error(
+                                "grouped expert kernels failed their "
+                                "compile probe; experts load dequantized: "
+                                "%s", err)
+                    probes.attrs.update(
+                        kernels=sorted(t.name for t in fused_types or ()),
+                        experts=bool(self.cfg.n_experts))
+            with tl.phase("params") as loading:
+                sub: dict = {}
+                self.params = load_params(gf, self.cfg, weight_format,
+                                          fused_types=fused_types,
+                                          phases_out=sub,
+                                          fused_experts=fused_experts)
+                loading.children = [Phase(k, t0, t1)
+                                    for k, (t0, t1) in sub.items()]
             template = gf.metadata.get("tokenizer.chat_template")
             self.template_kind = detect_chat_template(
                 template, self.tokenizer)
@@ -327,7 +333,7 @@ class Engine:
             logger.info(
                 "loaded %s (%s, %d layers, fmt=%s) in %.1fs",
                 model_path, gf.architecture, self.cfg.n_layers, weight_format,
-                time.time() - t0,
+                loading.t1 - opened.t0,
             )
         if kv_dtype is not None and kv_dtype != self.cfg.kv_dtype:
             self.cfg = dataclasses.replace(self.cfg, kv_dtype=kv_dtype)
@@ -336,6 +342,9 @@ class Engine:
             attn_impl = "xla"   # its attention is models/eva.py's own
         if self.cfg.cache_kind == STATE_RING:
             self._refuse_for_state_cache(bool(kv_paged))
+        # the compile probes of the attention side: one phase of the
+        # timeline when any of them ran
+        probing = Phase("attn_probes", meter="cache", kernels=[])
         if self.cfg.kv_dtype == "int8":
             # compile-probe the KV write-quantize kernel NOW: a Mosaic
             # failure degrades writes to the identical XLA formulation
@@ -343,6 +352,7 @@ class Engine:
             from ..ops.pallas.kvquant import force_xla_quant
             from ..ops.pallas.probe import probe_kv_quant
 
+            probing.attrs["kernels"].append("kv_quant")
             err = probe_kv_quant()
             if err is not None:
                 force_xla_quant(True)
@@ -366,6 +376,7 @@ class Engine:
             # a different Mosaic program — probe the one we'll run.
             from ..ops.pallas.probe import probe_flash_attention
 
+            probing.attrs["kernels"].append("flash_attention")
             err = probe_flash_attention(
                 quantized=self.cfg.kv_dtype == "int8")
             if err is not None:
@@ -377,16 +388,23 @@ class Engine:
             # linstate.py); it and the ring's kernels degrade together
             from ..ops.pallas.probe import probe_lin_state
 
+            probing.attrs["kernels"].append("lin_state")
             err = probe_lin_state()
             if err is not None:
                 logger.error("pallas linear-state step failed its compile "
                              "probe; serving with attn_impl=xla: %s", err)
                 attn_impl = "xla"
+        if probing.attrs["kernels"]:
+            probing.close()
+            tl.add(probing)
         if attn_impl != self.cfg.attn_impl:
             self.cfg = dataclasses.replace(self.cfg, attn_impl=attn_impl)
         self.prefill_buckets = sorted(b for b in prefill_buckets if b <= self.cfg.n_ctx)
         if not self.prefill_buckets or self.prefill_buckets[-1] < self.cfg.n_ctx:
             self.prefill_buckets.append(self.cfg.n_ctx)
+        # the serial ring here and the paged pool below; a subclass's lanes
+        # are a phase of their own (``lanes_alloc``, ``scheduler_start``)
+        alloc = tl.phase("cache_alloc")
         self._cache = init_cache(self.cfg)
         # -- prompt-prefix KV reuse (serial engine only) -------------------
         # The reference's engine re-evaluates the whole prompt every call;
@@ -477,6 +495,15 @@ class Engine:
         # (The pool registers itself; subclasses add their own surfaces.)
         register_component("weights", self, _ledger_weight_bytes)
         register_component("kv_ring", self, _ledger_ring_bytes)
+        alloc.close()
+
+    @property
+    def load_phases(self) -> dict:
+        """The six keys ``/health`` ``engine.load_phases`` has always had
+        (``tokenizer_s``, ``probes_s``, ``params_s``, ``params_prep_s``,
+        ``params_stack_s``, ``warmup_s``; 0.1 s), read off the timeline:
+        one source.  An in-memory engine has ``warmup_s`` alone."""
+        return legacy_load_phases(self.startup)
 
     def _refuse_for_window_cache(self, kv_paged: bool) -> None:
         """What cannot serve the window + summary cache of ``evabyte``
@@ -660,29 +687,45 @@ class Engine:
             return "int8", None
         return "q4k", frozenset(passed)
 
-    def warmup(self):  # lfkt: blocks-under[_lock] -- warmup compiles and syncs under the engine lock by design: a request must never race a half-warmed cache
-        """Compile every (bucket, chunk) shape so no request pays a cold
-        compile — the TPU analogue of the reference's eager model load."""
-        t0 = time.time()
+    def warmup(self):
+        """Compile every shape a request can hit so no request pays a cold
+        compile — the TPU analogue of the reference's eager model load —
+        as the ``warmup`` phase of the start-up timeline: a child per step
+        (:meth:`_warmup_steps`), and in ``attrs`` what compiled meanwhile
+        by the program's own ledgers (utils/startup.py CompileMeter)."""
+        with self.startup.phase("warmup", meter="programs") as ph:
+            what = self._warmup_steps(ph)
+        logger.info("warmup done in %.1fs (%s; %d programs compiled in "
+                    "%.1fs, %d of %d compile requests under the cache's "
+                    "floor)", ph.seconds, what,
+                    ph.attrs["programs_compiled"], ph.attrs["compile_s"],
+                    ph.attrs["compiled_uncached"], ph.attrs["cache_requests"])
+
+    def _warmup_steps(self, ph) -> str:  # lfkt: blocks-under[_lock] -- warmup compiles and syncs under the engine lock by design: a request must never race a half-warmed cache
+        """The serial engine's warm-up, every (bucket, chunk) shape; returns
+        what the log line says was warmed."""
         msgs = [{"role": "user", "content": "hi hi hi hi hi hi hi hi"}]
         # TWO full decode chunks, not one: on the sharded engines the
         # donated state returns from chunk 1 with jit-chosen shardings, so
         # the steady-state chunk-2 signature is a distinct compile — found
         # by the devtime compile pins (tests/test_perf_pins.py), which now
         # hold warmup to "compiles everything steady-state decode runs"
-        self.create_chat_completion(msgs,
-                                    max_tokens=2 * self.decode_chunk + 1,
-                                    temperature=0.0)
+        with ph.child("request"):
+            self.create_chat_completion(msgs,
+                                        max_tokens=2 * self.decode_chunk + 1,
+                                        temperature=0.0)
         with self._lock:   # uncontended at warmup; the ring-write invariant
             #                (writes to _cache only under _lock) stays intact
-            for b in self.prefill_buckets[1:]:
-                # compile the program(s) this bucket actually serves with:
-                # monolithic prefill for small buckets, the slice walk for
-                # buckets the overlapped path slices (_slices_prefill)
-                logits, cache = self._prefill_padded(
-                    [0] * (b - 1), b - 1, b, self._cache)
-                jax.block_until_ready(logits)
-                self._cache = cache
+            with ph.child("buckets", n_buckets=len(self.prefill_buckets) - 1):
+                for b in self.prefill_buckets[1:]:
+                    # compile the program(s) this bucket actually serves
+                    # with: monolithic prefill for small buckets, the slice
+                    # walk for buckets the overlapped path slices
+                    # (_slices_prefill)
+                    logits, cache = self._prefill_padded(
+                        [0] * (b - 1), b - 1, b, self._cache)
+                    jax.block_until_ready(logits)
+                    self._cache = cache
             if self._prefix_cache or self._kv_paged:
                 # compile the suffix pass for every bucket a reuse suffix can
                 # land in (all but the largest — _prefix_reuse_len only grants
@@ -693,15 +736,15 @@ class Engine:
                 # the garbage the raw bucket loop above wrote into the ring.
                 # (Pool page-copy programs are NOT part of this warmed set:
                 # they compile on first use — parallel/kvpool.py.)
-                for b in self.prefill_buckets[:-1]:
-                    logits, self._cache = prefill_chunk_jit(
-                        self.params, self.cfg, jnp.zeros((b,), jnp.int32),
-                        jnp.int32(0), jnp.int32(b - 1), self._cache)
-                    jax.block_until_ready(logits)
+                with ph.child("reuse_buckets",
+                              n_buckets=len(self.prefill_buckets) - 1):
+                    for b in self.prefill_buckets[:-1]:
+                        logits, self._cache = prefill_chunk_jit(
+                            self.params, self.cfg, jnp.zeros((b,), jnp.int32),
+                            jnp.int32(0), jnp.int32(b - 1), self._cache)
+                        jax.block_until_ready(logits)
                 self._prefix_ids = []
-        self.load_phases["warmup_s"] = round(time.time() - t0, 1)
-        logger.info("warmup done in %.1fs (%d prefill buckets)",
-                    self.load_phases["warmup_s"], len(self.prefill_buckets))
+        return f"{len(self.prefill_buckets)} prefill buckets"
 
     # -- jit call points (subclasses reroute these onto a mesh: engine/sp.py
     # runs them sequence-parallel; the vmap/batched engines bypass them) ----

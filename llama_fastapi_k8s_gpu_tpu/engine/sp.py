@@ -73,15 +73,18 @@ class SPEngine(Engine):
         super().__init__(model_path, n_ctx=n_ctx, attn_impl="xla", **kw)
         if self.cfg.n_ctx % sp:
             raise ValueError(f"n_ctx {self.cfg.n_ctx} must divide sp={sp}")
-        self.mesh = make_mesh(dp=1, tp=tp, sp=sp)
-        self.sp = sp
-        self.params = shard_params(self.params, self.mesh)
-        self.cfg = dataclasses.replace(self.cfg, attn_impl="ring")
-        # ring prefill shards the token dim: buckets round up to sp multiples
-        self.prefill_buckets = sorted(
-            {min(self.cfg.n_ctx, -(-b // sp) * sp) for b in self.prefill_buckets})
-        self._cache = jax.device_put(
-            init_cache(self.cfg), sp_state_shardings(self.cfg, self.mesh))
+        with self.startup.phase("sp_alloc"):
+            self.mesh = make_mesh(dp=1, tp=tp, sp=sp)
+            self.sp = sp
+            self.params = shard_params(self.params, self.mesh)
+            self.cfg = dataclasses.replace(self.cfg, attn_impl="ring")
+            # ring prefill shards the token dim: buckets round up to sp
+            # multiples
+            self.prefill_buckets = sorted(
+                {min(self.cfg.n_ctx, -(-b // sp) * sp)
+                 for b in self.prefill_buckets})
+            self._cache = jax.device_put(
+                init_cache(self.cfg), sp_state_shardings(self.cfg, self.mesh))
         logger.info("SPEngine: n_ctx=%d over sp=%d tp=%d (%d devices)",
                     self.cfg.n_ctx, sp, tp, sp * tp)
 

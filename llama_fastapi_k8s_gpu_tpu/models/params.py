@@ -115,7 +115,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     the set of types whose compile probes passed, so a Mosaic regression
     in ONE kernel degrades only that format's tensors to int8.
     ``fused_experts=False`` (the grouped expert kernels failed their probe)
-    loads a routed block's experts dequantized.
+    loads a routed block's experts dequantized.  ``phases_out`` receives
+    {"prep" | "head" | "stack": (start, end)} on ``time.time()``.
     """
     if on_device is None:
         on_device = jax.default_backend() == "tpu"
@@ -182,9 +183,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     import time as _time
 
     # coarse load-phase attribution, logged at the end: prep (host packers /
-    # codecs incl. the raw() mmap page-ins they trigger) vs stack (jnp.stack
-    # = host->device transfer of every packed plane)
-    phase_s = {"prep": 0.0, "stack": 0.0}
+    # codecs incl. the raw() mmap page-ins they trigger; with
+    # LFKT_LOAD_OVERLAP the transfers they queue), head (embeddings and the
+    # output head) and stack (jnp.stack, and the wait for every transfer
+    # still in flight), back to back on time.time()
 
     def lin(name: str, fused_names: dict = fused_names) -> dict:
         short = name.split(".")[-2] if name.startswith("blk.") else name.split(".")[0]
@@ -291,7 +293,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         return out
 
     layers = []
-    t0 = _time.time()
+    t_prep = _time.time()
     by_kind = kinds_layers() if cfg.mixers else None
     for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."
@@ -321,7 +323,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             layer = jax.tree.map(jax.device_put, layer)
         layers.append(layer)
         logger.debug("loaded layer %d/%d", i + 1, cfg.n_layers)
-    phase_s["prep"] = _time.time() - t0
+    t_head = _time.time()
 
     if on_device:
         emb = _tensor_to_device(gf["token_embd.weight"], jnp.bfloat16)
@@ -337,17 +339,19 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                   else jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)}
     else:
         output = lin("output.weight")
-    t0 = _time.time()
+    t_stack = _time.time()
     stacked = _stack(layers, free=overlap) if by_kind is None else {
         kind: _stack(ls, free=overlap) for kind, ls in by_kind.items()}
     jax.block_until_ready(stacked)
-    phase_s["stack"] = _time.time() - t0
+    t_end = _time.time()
     logger.info("load_params phases: per-layer prep+transfer %.1fs, "
-                "stack %.1fs", phase_s["prep"], phase_s["stack"])
+                "stack %.1fs", t_head - t_prep, t_end - t_stack)
     if phases_out is not None:
-        # caller-owned out-param (Engine.load_phases → coldstart bench JSON);
-        # no shared module state, so concurrent loads can't cross-report
-        phases_out.update(phase_s)
+        # caller-owned out-param (the children of the engine's ``params``
+        # phase, utils/startup.py); no shared module state, so concurrent
+        # loads can't cross-report
+        phases_out.update(prep=(t_prep, t_head), head=(t_head, t_stack),
+                          stack=(t_stack, t_end))
     return {
         "tok_emb": emb,
         "layers": stacked,

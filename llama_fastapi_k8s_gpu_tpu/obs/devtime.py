@@ -307,6 +307,14 @@ class DevtimeRegistry:
                            "storms": p.storms}
                     for name, p in self._programs.items()}
 
+    def compile_ledger(self) -> dict[str, tuple[int, float]]:
+        """{program: (compiles, compile seconds)} so far: two readings
+        around a phase give what compiled in it (utils/startup.py
+        CompileMeter: the warm-up's ``programs_compiled``, ``compile_s``)."""
+        with self._lock:
+            return {name: (p.compiles, p.compile_s)
+                    for name, p in self._programs.items()}
+
     def events_since(self, cursor: int) -> tuple[int, list[dict]]:
         """Compile events newer than ``cursor`` (bounded ring) + the new
         cursor — /metrics replays them into the xla_compile_seconds

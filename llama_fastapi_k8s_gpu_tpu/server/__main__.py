@@ -4,7 +4,15 @@ Serves through the in-tree dependency-free ``httpd`` (the reference runs
 gunicorn+UvicornWorker, reference docker/Dockerfile.app:12).  There is
 exactly one worker process: the model is loaded once per process, so
 ``-w 1`` is load-bearing (SURVEY.md §1 L4).
+
+The entry point also opens the start-up timeline (utils/startup.py): what
+the process pays before a byte of the model is read, each stretch stamped
+on ``time.time()`` and served with the rest at ``/health``
+``engine.startup``.
 """
+
+import time
+
 
 def main():
     from ..utils.config import env_bool, knob
@@ -65,11 +73,26 @@ def main():
             f"got {fleet_role!r}: replicas stay role=off; only the "
             "router process changes type (docs/RUNBOOK.md 'Running a "
             "replica fleet')")
+    import jax
+
+    from .. import T_IMPORTED
     from ..utils.jaxcache import setup_compile_cache
     from .app import app
     from .httpd import run
 
-    setup_compile_cache()
+    # before_main: the interpreter and whatever a launcher imported before
+    # this package (a launcher that touched JAX's devices paid the TPU
+    # attach THERE, and backend_init below reads next to nothing);
+    # imports: the package, the app module (server/__init__.py imports it
+    # ahead of this file) and JAX
+    tl = app.state.startup
+    if tl.source == "proc_stat":
+        tl.phase("before_main", tl.process_start_unix, T_IMPORTED)
+    tl.phase("imports", T_IMPORTED, time.time())
+    with tl.phase("backend_init") as ph:
+        ph.attrs["platform"] = jax.default_backend()
+    with tl.phase("compile_cache"):
+        setup_compile_cache()
     run(app, host, port)
 
 

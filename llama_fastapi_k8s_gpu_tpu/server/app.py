@@ -42,6 +42,7 @@ from .asgikit import (
 
 import uuid
 
+from .. import T_IMPORTED
 from .. import native as _native
 from ..obs import flightrec as _flightrec
 from ..obs import memledger as _memledger
@@ -62,6 +63,7 @@ from ..utils.health import (
     HealthMonitor,
 )
 from ..utils.metrics import Metrics
+from ..utils.startup import Timeline, process_start
 from .schemas import BotMessageRequest, ChatCompletionRequest
 
 logging.basicConfig(level=logging.INFO)
@@ -174,6 +176,12 @@ def create_app(engine=None, settings: Settings | None = None,
     #: into xla_compile_seconds exactly once per app (-1 = never read, so
     #: a ring that overflowed before this app existed charges no drop)
     app.state.devtime_cursor = -1
+    #: the start-up timeline (utils/startup.py): the entry point stamps
+    #: the process's own phases into it (server/__main__.py); the start-up
+    #: hook adds the engine's and its own at the READY flip and freezes
+    #: the document /health serves as engine.startup
+    app.state.startup = Timeline(*process_start(T_IMPORTED))
+    app.state.startup_doc = None
     app.state.ready = engine is not None
     #: pod health state machine (utils/health.py): STARTING until the
     #: engine is loaded; the watchdog moves it between READY/DEGRADED/DEAD
@@ -752,10 +760,19 @@ def create_app(engine=None, settings: Settings | None = None,
         # requests, so the bounded queue stays the back-pressure surface
         app.state.inflight = asyncio.Semaphore(max(1, settings.batch_size))
         app.state.health.transition(STARTING, "model loading")
+        tl = app.state.startup
+        t_hook = time.time()
         if app.state.engine is None:
             factory = engine_factory or _default_engine_factory(settings)
             loop = asyncio.get_running_loop()
             app.state.engine = await loop.run_in_executor(None, factory)
+            t_built = time.time()
+            if not isinstance(getattr(app.state.engine, "startup", None),
+                              Timeline):
+                # a registry, a fake: the factory's whole call (an engine
+                # stamps its own stretch, _default_engine_factory its import)
+                tl.phase("engine_load", t_hook, t_built)
+            t_hook = t_built
         engine = app.state.engine
         # which resilience kwargs this engine accepts (probed once; fakes
         # and out-of-tree engines may predate the deadline/abort contract)
@@ -827,8 +844,12 @@ def create_app(engine=None, settings: Settings | None = None,
                 build_migration, engine, settings,
                 metrics=app.state.metrics, health=app.state.health)
             await asyncio.to_thread(app.state.migration.warm_up)
+        tl.absorb(getattr(engine, "startup", None))
         app.state.ready = True
         app.state.health.transition(READY, "engine loaded")
+        tl.ready_unix = time.time()
+        tl.phase("app_start", t_hook, tl.ready_unix)
+        app.state.startup_doc = tl.doc()
         if settings.watchdog and getattr(engine, "heartbeat", None) is None \
                 and callable(getattr(engine, "models", None)):
             # multi-model registry: the engine watchdog is single-engine
@@ -1464,6 +1485,9 @@ def create_app(engine=None, settings: Settings | None = None,
                 # how the weights got here: per-phase load/warm-up seconds
                 # and the native packer library (None = numpy codecs)
                 "load_phases": getattr(eng, "load_phases", None),
+                # process start to the READY flip, phase by phase, on
+                # time.time() (docs/OBSERVABILITY.md "Start-up timeline")
+                "startup": st.startup_doc,
                 "native_lib": _native.loaded_path(),
                 # the device as JAX reports it + peak device memory
                 **_device_info(),
@@ -1976,8 +2000,10 @@ def _registry_factory(settings: Settings):
 
 def _default_engine_factory(settings: Settings):
     def factory():
+        t_import = time.time()
         from ..engine import ContinuousEngine, Engine, MeshEngine, SPEngine
 
+        t_imported = time.time()
         if settings.scheduler not in ("continuous", "cycle"):
             raise ValueError(
                 f"LFKT_SCHEDULER must be 'continuous' or 'cycle', "
@@ -2012,6 +2038,8 @@ def _default_engine_factory(settings: Settings):
                                  batch_size=settings.batch_size, **kw)
         else:
             eng = Engine(settings.model_path, **kw)
+        # the load thread's first import of engine/, models/, ops/pallas/
+        eng.startup.phase("engine_import", t_import, t_imported)
         eng.warmup()
         return eng
     return factory
