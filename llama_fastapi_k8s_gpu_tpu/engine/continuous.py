@@ -39,7 +39,8 @@ from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import annotate_all_inflight, phase, rid
-from ..parallel.batched import batched_generate_chunk_perlane_jit
+from ..parallel.batched import (
+    batched_generate_chunk_perlane_jit, init_lane_left, left_after)
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, EngineUnavailable
@@ -54,14 +55,21 @@ def _ledger_scratch_bytes(eng: "ContinuousEngine") -> int:
     return tree_nbytes(getattr(eng, "_scratch_cache", None))
 
 
-@functools.partial(jax.jit, donate_argnames=("state", "lane_st"))
-def _write_lane(state: dict, lane_st: dict, lane: jax.Array, cache1: dict,
-                pos, token, window, wpos, key, st: dict):
+@functools.partial(jax.jit, static_argnames=("stop_ids",),
+                   donate_argnames=("state", "lane_st"))
+def _write_lane(state: dict, lane_st: dict, lane_left: jax.Array,
+                lane: jax.Array, cache1: dict, pos, token, window, wpos, key,
+                st: dict, left, stop_ids: tuple = ()):
     """Install a freshly prefilled sequence into batch lane ``lane``.
     ``cache1`` is NOT donated — the scheduler reuses it as the next
     admission's prefill scratch (no per-request cache allocation).  Leaf-
     generic over the cache pytree ({k, v} bf16 or the int8 four-leaf
-    layout — models/llama.py init_cache)."""
+    layout — models/llama.py init_cache).  ``left``: the tokens the
+    request may decode after ``token``, its first.  It resets the lane's
+    entry of ``lane_left`` (parallel/batched.py ``init_lane_left``), so a
+    reused lane starts alive, unless its first token already ends it (a
+    stop id, or no budget beyond it): the chunk program then never steps
+    for it."""
     new_cache = jax.tree.map(
         lambda a, c: a.at[lane].set(c), state["cache"], cache1)
     new_state = {
@@ -74,7 +82,8 @@ def _write_lane(state: dict, lane_st: dict, lane: jax.Array, cache1: dict,
     }
     new_lane_st = jax.tree.map(
         lambda a, v: a.at[lane].set(v), lane_st, st)
-    return new_state, new_lane_st
+    return new_state, new_lane_st, lane_left.at[lane].set(
+        left_after(token, left, stop_ids))
 
 
 _write_lane = timed_jit("lane_write", _write_lane, site="engine.continuous")
@@ -273,7 +282,8 @@ class ContinuousEngine(MeshEngine):
     _THREAD_ENTRIES = ("_loop",)
     _SLICED_ADMISSION = True
     _THREAD_CONFINED = (
-        "_bstate", "_lane_st", "_scratch_cache", "_adm", "_lane_claims",
+        "_bstate", "_lane_st", "_lane_left", "_scratch_cache", "_adm",
+        "_lane_claims",
         "_prefix_stats", "_stats", "_loop_error",
         "_adm_budget", "_lane_idle_s", "_mem_hot_prev", "_totals",
         "_slices_queued", "_wave_at_pass",
@@ -372,6 +382,10 @@ class ContinuousEngine(MeshEngine):
         base_st = sampling_tensors(SamplingParams())
         self._lane_st = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (self.batch_size,)), base_st)
+        #: each lane's end as the chunk program tracks it: the tokens it
+        #: may still decode, 0 once it ended (parallel/batched.py
+        #: ``init_lane_left``); the device's copy of the harvest's rule
+        self._lane_left = init_lane_left(self.batch_size)
         # static top_k ceiling of the shared compiled decode program;
         # per-request k rides as a traced mask (sampling/sample.py) and is
         # effectively min(requested, ceiling)
@@ -389,6 +403,12 @@ class ContinuousEngine(MeshEngine):
         self._thread.start()
         sched.close()
 
+    @property
+    def _stop_ids(self) -> tuple:
+        """The tokenizer's stop ids as the lane programs take them (a
+        static argument): what the harvest tests a token against."""
+        return tuple(sorted(self.tokenizer.stop_ids))
+
     @staticmethod
     def _zero_totals() -> dict:
         """The per-wave counters (docs/OBSERVABILITY.md "The wave").  A
@@ -400,12 +420,22 @@ class ContinuousEngine(MeshEngine):
         lanes decode (the deferred first token); ``admit_chunks_behind``
         sums, over them, the decode chunks the loop pass that finished the
         admission had already dispatched without its lane: 0 since the
-        round runs ahead of the chunk, 1 each in the order before."""
+        round runs ahead of the chunk, 1 each in the order before.
+        ``steps_run`` / ``steps_skipped`` split the fetched chunks'
+        ``decode_chunk`` steps into those the chunk program ran and those
+        it left out because none of its lanes had anything left to decode
+        (parallel/batched.py ``batched_generate_chunk_perlane_jit``);
+        ``chunks_empty`` counts the chunks that ran none, one behind
+        every request that ends with no other lane alive.
+        ``end_disagreements``: lanes whose end the device and the harvest
+        saw at different tokens (0: they apply one rule)."""
         return {"waves": 0, "wave_seconds": 0.0, "lane_live_seconds": 0.0,
                 "fetch_wait_seconds": 0.0, "admit_seconds": 0.0,
                 "admit_slices": 0, "admit_tokens": 0,
                 "harvest_seconds": 0.0, "chunks_dispatched": 0,
-                "admits_beside_live": 0, "admit_chunks_behind": 0}
+                "admits_beside_live": 0, "admit_chunks_behind": 0,
+                "steps_run": 0, "steps_skipped": 0, "chunks_empty": 0,
+                "end_disagreements": 0}
 
     # ------------------------------------------------------------------
     def submit(self, messages: Sequence[dict], *, temperature: float = 0.2,
@@ -575,6 +605,7 @@ class ContinuousEngine(MeshEngine):
         base_st = sampling_tensors(SamplingParams())
         self._lane_st = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (self.batch_size,)), base_st)
+        self._lane_left = init_lane_left(self.batch_size)
         # re-init succeeded: clear the fault signature and restart
         self._loop_error = None
         self._stop = False
@@ -960,13 +991,15 @@ class ContinuousEngine(MeshEngine):
             token, window, wpos, key = sample_jit(
                 adm["logits"], window, wpos, jax.random.PRNGKey(adm["seed"]),
                 st, self.cfg, top_k=self._max_top_k)
-            self._bstate, self._lane_st = _write_lane(
-                self._bstate, self._lane_st, jnp.int32(lane),
-                self._scratch_cache, jnp.int32(n_prompt), token, window,
-                wpos, key, st)
-
             budget = min(self._token_budget(item.max_tokens, n_prompt),
                          max(0, self.cfg.n_ctx - 1 - n_prompt))
+            # the lane's end goes to the device with it: the budget less
+            # the first token, and whether that token already ends it
+            self._bstate, self._lane_st, self._lane_left = _write_lane(
+                self._bstate, self._lane_st, self._lane_left,
+                jnp.int32(lane), self._scratch_cache, jnp.int32(n_prompt),
+                token, window, wpos, key, st, jnp.int32(budget - 1),
+                stop_ids=self._stop_ids)
             slot = _Slot(item, budget, n_prompt, ids)
             slot.stops = item.stops
             slot.st = st
@@ -1314,9 +1347,23 @@ class ContinuousEngine(MeshEngine):
 
         ``pre`` is the lane snapshot taken when the chunk was DISPATCHED —
         with the pipelined loop that is one iteration ago, so a lane's slot
-        may have finished (budget/stop found in the previous chunk) while
-        this chunk was already in flight on the device; those rows are
-        discarded (``slot.finished``).  A lane admitted by the round that
+        may have finished (found in the previous chunk) while this chunk
+        was already queued on the device; its rows are discarded
+        (``slot.finished``).  An end the device saw too (a budget that ran
+        out, a stop id: parallel/batched.py
+        ``batched_generate_chunk_perlane_jit`` carries what each lane has
+        left) cost no step but beside lanes still alive: the rows of a lane
+        past its end and of the steps not run hold the pad -1, and a chunk
+        none of whose lanes was alive ran no step (``chunks_empty``).  The
+        rows with a token in them are the steps run (``steps_run``; the
+        rest ``steps_skipped``), and the read counters count those.  The
+        host's own test of stop id and budget stays and has to find a
+        lane's end where its pads begin (``end_disagreements`` counts the
+        lanes where it did not; the host's verdict stands, and a lane the
+        device alone ended is finished, so that it cannot wait for steps
+        that never come).  An end only the host sees (a stop STRING, a
+        deadline, an abandoned caller) leaves the lane alive on the device
+        for the one chunk already queued.  A lane admitted by the round that
         ran ahead of the chunk's dispatch is in ``pre`` with its first
         token still on the device (``pending_first``): token 1 and the
         chunk's rows are folded in together here.  Abandoned requests
@@ -1332,7 +1379,13 @@ class ContinuousEngine(MeshEngine):
         traced lane's ``decode_chunk`` span: a long chunk names its cause."""
         stop_ids = self.tokenizer.stop_ids
         now = time.time()
-        self._note_ring_read(pre, len(chunk))
+        # a step that ran has an alive lane's token (>= 0) in its row
+        run = int(np.count_nonzero(chunk.max(axis=1) >= 0))
+        tot = self._totals
+        tot["steps_run"] += run
+        tot["steps_skipped"] += len(chunk) - run
+        tot["chunks_empty"] += run == 0
+        self._note_ring_read(pre, run)
         for lane in range(len(pre)):
             slot = pre[lane]
             if slot is None or slot.finished:
@@ -1373,7 +1426,12 @@ class ContinuousEngine(MeshEngine):
                 if slot.finished:
                     continue
             finish = None
-            for t in chunk[:, lane].tolist():
+            rows = chunk[:, lane].tolist()
+            n = 0                   # rows of this lane folded in
+            for t in rows:
+                if t < 0:           # pad: the device ended the lane here
+                    break
+                n += 1
                 if t in stop_ids:
                     finish = "stop"
                     break
@@ -1381,6 +1439,15 @@ class ContinuousEngine(MeshEngine):
                 if len(slot.gens) >= slot.budget:
                     finish = "length"
                     break
+            # one rule on both sides: the lane's next row, where the chunk
+            # has one, is a pad exactly when the host ends the lane here
+            if n < len(rows) and (rows[n] < 0) == (finish is None):
+                tot["end_disagreements"] += 1
+                logger.error(
+                    "lane %d: the device and the harvest end the request "
+                    "at different tokens (host: %s after %d of rows %s)",
+                    lane, finish, n, rows)
+                finish = finish or "length"
             if slot.dspan is not None:
                 slot.dspan.child("decode_chunk", t0=slot.t_chunk).set(
                     tokens=len(slot.gens), wave=wave,
@@ -1443,9 +1510,20 @@ class ContinuousEngine(MeshEngine):
                 # pass's chunk queues on the device BEFORE the previous
                 # chunk's tokens are fetched, so the host round-trip
                 # (dispatch latency) overlaps device compute instead of
-                # serializing with it.  Cost of the pipeline: a lane whose
-                # request finished in the previous chunk decodes one extra
-                # chunk before being freed (its rows are discarded).
+                # serializing with it.  Cost of the pipeline: the host
+                # learns of a request's end one chunk late, when the next
+                # chunk is queued with its lane in it.  An end the device
+                # can see (the budget ran out, a stop id: the chunk program
+                # carries what each lane has ``left`` to decode) costs nothing
+                # where no other lane is alive: the chunk stops at the last
+                # token, the queued one runs no step, and its fetch below
+                # returns at once, so the next request does not wait in
+                # ``_pending`` behind a chunk for nobody.  Beside lanes
+                # still alive the ended lane steps on with them, its rows
+                # pads.  An end only the host sees (a stop STRING, a
+                # deadline, an abandoned caller) keeps the old price: the
+                # lane decodes one extra chunk before it is freed, its
+                # rows discarded.
                 # Admission goes AHEAD of the chunk: while the device still
                 # runs the previous chunk, the host tokenizes and queues the
                 # round's slices, first-token samples and lane writes, and
@@ -1486,11 +1564,13 @@ class ContinuousEngine(MeshEngine):
                             # walks on), and a routed block's other rows
                             # reach no expert
                             live = np.array([s is not None for s in pre])
-                            self._bstate, out = \
+                            self._bstate, self._lane_left, out = \
                                 batched_generate_chunk_perlane_jit(
                                     self.params, self.cfg, self._bstate,
-                                    self._lane_st, n_steps=self.decode_chunk,
-                                    top_k=self._max_top_k, live=live)
+                                    self._lane_st, self._lane_left,
+                                    n_steps=self.decode_chunk,
+                                    top_k=self._max_top_k, live=live,
+                                    stop_ids=self._stop_ids)
                             toks = self._take_expert_stats(out)
                         # (lanes, tokens, dispatch number, slices queued on
                         # the device between the chunk before and this one:

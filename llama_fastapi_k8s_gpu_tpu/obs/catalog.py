@@ -403,8 +403,14 @@ METRICS: dict[str, Metric] = _register(
            "(admissions finished while other lanes decode) and "
            "admit_chunks_behind (summed over them: decode chunks the pass "
            "that finished the admission had dispatched without its lane; "
-           "0 while the round runs ahead of the chunk), lane_prefix_* / "
-           "radix_prefix_*",
+           "0 while the round runs ahead of the chunk), steps_run / "
+           "steps_skipped (the fetched chunks' decode_chunk steps: those "
+           "the chunk program ran, and those it left out because none of "
+           "its lanes had anything left to decode), chunks_empty (chunks "
+           "that ran no step: one behind every request that ends with no "
+           "other lane alive) and end_disagreements (lanes whose end the "
+           "device and the harvest found at different tokens; 0), "
+           "lane_prefix_* / radix_prefix_*",
            prefix=True),
 )
 

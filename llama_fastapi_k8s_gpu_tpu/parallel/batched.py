@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from ..models.config import STATE_RING, ModelConfig
 from ..models import eva
 from ..models.generate import chunk_out
-from ..models.llama import decode_kernel_block, forward, init_cache, prefill
+from ..models.llama import (
+    decode_kernel_block, expert_stats_len, forward, init_cache, prefill)
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 
@@ -143,14 +144,37 @@ batched_generate_chunk_jit = timed_jit(
     site="parallel.batched")
 
 
+def init_lane_left(batch: int) -> jax.Array:
+    """What the lane engine's chunk program knows of its lanes' ENDS, kept
+    beside the batched state (whose leaves the other engines' programs
+    share): per lane the tokens it may still decode, (B,) int32.  0: the
+    lane has ended (its budget ran out or it sampled a stop id) or holds
+    no request, as every lane at the start; the scheduler's lane write
+    (engine/continuous.py ``_write_lane``) brings a lane to life with its
+    request's budget."""
+    return jnp.zeros(batch, jnp.int32)
+
+
+def left_after(token: jax.Array, left: jax.Array, stop_ids: tuple) -> jax.Array:
+    """What is left to a lane that has ``left`` tokens of budget after
+    sampling ``token``: nothing once the token is one of ``stop_ids`` (a
+    small static tuple).  The rule of the scheduler's harvest (``t in
+    stop_ids`` / ``len(gens) >= budget``), on the device."""
+    stop = jnp.zeros(jnp.shape(token), bool)
+    for s in stop_ids:
+        stop |= token == s
+    return jnp.where(stop, 0, left)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "n_steps", "top_k"),
+    static_argnames=("cfg", "n_steps", "top_k", "stop_ids"),
     donate_argnames=("state",),
 )
 def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
-                                       lane_st: dict, n_steps: int,
-                                       top_k: int = 40, live=None):
+                                       lane_st: dict, left: jax.Array,
+                                       n_steps: int, top_k: int = 40,
+                                       live=None, stop_ids: tuple = ()):
     """Like :func:`batched_generate_chunk_jit` but with **per-lane** sampling
     knobs (``lane_st`` leaves have a leading B dim) — the continuous
     scheduler admits requests with different temperatures/penalties into
@@ -159,9 +183,33 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     that hold a request (None: all).  A step's attention reads the ring as
     :func:`step_bound` says (a lane that holds none reads nothing, or up
     to the live lanes' bound); of a routed block the others' rows also
-    reach no expert, so a step reads what its live lanes picked."""
+    reach no expert, so a step reads what its live lanes picked.
 
-    def one_step(carry, _):
+    The chunk steps only while some lane has something left to decode:
+    ``left`` (:func:`init_lane_left`) carries each lane's end across
+    chunks, a lane is ALIVE while it is live and has ``left`` > 0, and
+    the loop runs while ``i < n_steps`` and any lane is alive.  Each step
+    takes one off the ``left`` of the lanes it stepped alive, all of it
+    where the lane sampled one of ``stop_ids``.  The host learns of a
+    lane's end one chunk late (the loop of engine/continuous.py is
+    pipelined one chunk deep); the chunk it has already dispatched for
+    that lane then runs NO step where no other lane is alive, and
+    returns the state as it got it.
+
+    Returns (state, left, tokens (n_steps, B)) (for a routed block the
+    tokens with the counters summed over the steps RUN:
+    models/generate.py ``chunk_out``).  A row of a lane that was not
+    alive in its step, and every row of a step not run, holds the pad -1:
+    the rows with a token in them are the steps run, read by the host
+    from the one array it fetches anyway.  The tokens of a lane while it
+    is alive are those of the ``scan`` form: the same sampling chain on
+    the same keys."""
+
+    def alive_of(left):
+        return left > 0 if live is None else live & (left > 0)
+
+    def one_step(loop):
+        i, carry, left, toks, *rows = loop
         bound = step_bound(cfg, carry["pos"], live)
 
         def single(token, pos, cache, window, wpos, key, st, live):
@@ -179,10 +227,27 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
         )
         new_carry = {"cache": cache, "pos": pos, "token": tok,
                      "window": window, "wpos": wpos, "key": key}
-        return new_carry, (tok, *(s[0] for s in stats))
+        alive = alive_of(left)
+        # the counters are of the step, the same in every lane
+        return (i + 1, new_carry,
+                jnp.where(alive, left_after(tok, left - 1, stop_ids), left),
+                toks.at[i].set(jnp.where(alive, tok, -1)),
+                *(r.at[i].set(s[0]) for r, s in zip(rows, stats)))
 
-    state, ys = jax.lax.scan(one_step, state, None, length=n_steps)
-    return state, chunk_out(*ys)
+    def more(loop):
+        return (loop[0] < n_steps) & jnp.any(alive_of(loop[2]))
+
+    # a while_loop, which a scan is already (the cache rides its carry),
+    # and not a cond around each step of a scan: a conditional that
+    # returns the cache from two branches is where a copy would appear
+    # (a step not run leaves its row of tokens pads, of counters zeros)
+    rows = [jnp.zeros((n_steps, expert_stats_len(cfg)), jnp.int32)] \
+        if cfg.n_experts else []
+    _, state, left, toks, *rows = jax.lax.while_loop(
+        more, one_step,
+        (jnp.int32(0), state, left,
+         jnp.full((n_steps, left.shape[0]), -1, jnp.int32), *rows))
+    return state, left, chunk_out(toks, *rows)
 
 
 batched_generate_chunk_perlane_jit = timed_jit(

@@ -345,7 +345,8 @@ def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
     from llama_fastapi_k8s_gpu_tpu.models.generate import (
         generate_chunk_jit, init_state)
     from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
-        batched_generate_chunk_perlane_jit, init_batched_state)
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
     from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
         SamplingParams, sampling_tensors)
 
@@ -363,9 +364,10 @@ def test_decode_step_reads_the_ring_in_blocks(one_chip, name, L, D, H, KV, F,
         state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
         st = place(jax.eval_shape(lambda: jax.tree.map(
             lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
         lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
-            params, cfg, state, st, n_steps=8, top_k=40,
-            live=place(S(lanes, dtype=jnp.bool_)))
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
     else:
         state = place(jax.eval_shape(lambda: init_state(cfg)))
         lowered = generate_chunk_jit.__wrapped__.lower(
@@ -404,7 +406,8 @@ def test_decode_step_leaves_the_ring_to_the_kernel(one_chip, monkeypatch,
     from llama_fastapi_k8s_gpu_tpu.models.generate import (
         generate_chunk_jit, init_state)
     from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
-        batched_generate_chunk_perlane_jit, init_batched_state)
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
     from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
         SamplingParams, sampling_tensors)
 
@@ -423,9 +426,10 @@ def test_decode_step_leaves_the_ring_to_the_kernel(one_chip, monkeypatch,
         state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
         st = place(jax.eval_shape(lambda: jax.tree.map(
             lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
         lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
-            params, cfg, state, st, n_steps=8, top_k=40,
-            live=place(S(lanes, dtype=jnp.bool_)))
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
     else:
         state = place(jax.eval_shape(lambda: init_state(cfg)))
         lowered = generate_chunk_jit.__wrapped__.lower(
@@ -466,7 +470,8 @@ def test_evabyte_step_reads_window_and_summaries_in_blocks(one_chip, name,
         generate_chunk_jit, init_state, prefill_chunk_jit)
     from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
     from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
-        batched_generate_chunk_perlane_jit, init_batched_state)
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
     from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
         SamplingParams, sampling_tensors)
 
@@ -490,9 +495,10 @@ def test_evabyte_step_reads_window_and_summaries_in_blocks(one_chip, name,
         state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
         st = place(jax.eval_shape(lambda: jax.tree.map(
             lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
         lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
-            params, cfg, state, st, n_steps=8, top_k=40,
-            live=place(S(lanes, dtype=jnp.bool_)))
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
     else:
         state = place(jax.eval_shape(lambda: init_state(cfg)))
         lowered = generate_chunk_jit.__wrapped__.lower(
@@ -550,7 +556,8 @@ def test_sala_stack_compiles_with_no_ring_sized_copy(one_chip, name, lanes):
         generate_chunk_jit, init_state, prefill_chunk_jit)
     from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
     from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
-        batched_generate_chunk_perlane_jit, init_batched_state)
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
     from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
         SamplingParams, sampling_tensors)
 
@@ -592,9 +599,10 @@ def test_sala_stack_compiles_with_no_ring_sized_copy(one_chip, name, lanes):
         state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
         st = place(jax.eval_shape(lambda: jax.tree.map(
             lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
         lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
-            params, cfg, state, st, n_steps=8, top_k=40,
-            live=place(S(lanes, dtype=jnp.bool_)))
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
     else:
         state = place(jax.eval_shape(lambda: init_state(cfg)))
         lowered = generate_chunk_jit.__wrapped__.lower(

@@ -8,8 +8,10 @@ and the server's no-barrier forwarding path.
 from __future__ import annotations
 
 import asyncio
+import functools
 import time
 
+import numpy as np
 import pytest
 
 from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine
@@ -504,12 +506,11 @@ def test_ring_slot_counters_rise_with_the_lanes_positions(cengine):
     read, live = totals()
     read, live = read - read0, live - live0
     # the first token comes from prefill; tokens 2..9 take two chunks of 4
-    # steps at positions n_prompt .. n_prompt + 7, and the lane stays live
-    # for the chunk that was in flight when it finished (rows discarded)
-    steps = read // 128
-    assert read == steps * 128 and steps in (8, 12)
-    assert live >= sum(n_prompt + t + 1 for t in range(8))
-    assert live <= sum(n_prompt + t + 1 for t in range(12))
+    # steps at positions n_prompt .. n_prompt + 7; the chunk that was
+    # queued when the lane ended runs no step (the device knows the
+    # lane's budget) and reads nothing
+    assert read == 8 * 128
+    assert live == sum(n_prompt + t + 1 for t in range(8))
     assert 0 < live < read
 
 
@@ -819,3 +820,405 @@ def test_mesh_stream_close_stops_decode_immediately(tmp_path):
     assert calls[0] == at_close
     outs = eng.create_chat_completions([MSGS], temperature=0.0, max_tokens=4)
     assert outs[0]["usage"]["completion_tokens"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# a lane's end on the device (PR 42): the chunk program carries what each
+# lane has left to decode and stops stepping when no lane has anything left
+# ---------------------------------------------------------------------------
+
+@functools.cache
+def _scan_chunk():
+    """The chunk program as it was before the lanes' ends went to the
+    device: a ``scan`` of ``n_steps`` whatever the lanes hold.  The
+    reference the ``while_loop`` form is held to, token for token; behind
+    the new signature (``left`` goes through untouched, no row is a pad)."""
+    import jax
+
+    from llama_fastapi_k8s_gpu_tpu.models.llama import forward
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import step_bound
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        PENALTY_WINDOW, sample_chain)
+
+    @functools.partial(jax.jit,
+                       static_argnames=("cfg", "n_steps", "top_k", "stop_ids"))
+    def scan_chunk(params, cfg, state, lane_st, left, n_steps, top_k=40,
+                   live=None, stop_ids=()):
+        def one_step(carry, _):
+            bound = step_bound(cfg, carry["pos"], live)
+
+            def single(token, pos, cache, window, wpos, key, st, live):
+                logits, cache = forward(params, cfg, token[None], pos, cache,
+                                        live=live, kv_bound=bound)
+                key, sub = jax.random.split(key)
+                tok = sample_chain(logits, window, sub, st, top_k=top_k)
+                window = window.at[wpos % PENALTY_WINDOW].set(tok)
+                return tok, pos + 1, cache, window, wpos + 1, key
+
+            tok, pos, cache, window, wpos, key = jax.vmap(single)(
+                carry["token"], carry["pos"], carry["cache"],
+                carry["window"], carry["wpos"], carry["key"], lane_st, live)
+            return {"cache": cache, "pos": pos, "token": tok,
+                    "window": window, "wpos": wpos, "key": key}, tok
+
+        state, toks = jax.lax.scan(one_step, state, None, length=n_steps)
+        return state, left, toks
+
+    return scan_chunk
+
+
+@pytest.fixture(scope="module")
+def lane_eng(tmp_path_factory):
+    """One plain lane engine (one device), with every finished slot's
+    tokens on record: ``eng.finished[response id] = (tokens, finish)``."""
+    path = str(tmp_path_factory.mktemp("ends") / "tiny.gguf")
+    write_tiny_llama_gguf(path)
+    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=4, n_ctx=128,
+                           decode_chunk=4, max_gen_tokens=32,
+                           prefill_buckets=(32, 64, 128))
+    eng.finished = {}
+    finish_slot = eng._finish_slot
+
+    def recording(slot, finish):
+        eng.finished[slot.cid] = (list(slot.gens), finish)
+        return finish_slot(slot, finish)
+
+    eng._finish_slot = recording
+    yield eng
+    eng.shutdown()
+
+
+def _quiet(eng):
+    """Wait until no lane is live and no chunk is in flight; the stats."""
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        st = eng.scheduler_stats()
+        if not st["lanes_live"] and not st["pending"] \
+                and st.get("chunks_dispatched", 0) == st.get("waves", 0):
+            time.sleep(0.2)     # the queued chunk's harvest, one pass on
+            # (an engine that has not run a pass yet shows no totals)
+            return {**eng._zero_totals(), **eng.scheduler_stats()}
+        time.sleep(0.02)
+    raise AssertionError("the engine did not drain")
+
+
+ENDS = ("budget", "stop_id", "stop_string", "abandoned", "queued")
+
+
+@pytest.fixture(scope="module")
+def mixed(lane_eng):
+    """A mixed batch (five requests on four lanes, each with its own seed:
+    three sampled, two greedy) whose requests end by budget, by a stop id,
+    by a stop string, by being abandoned and, queued behind them, in a
+    reused lane; run on the chunk program as it is and again on the
+    ``scan`` form: {"got" | "want": {end: what the caller received}}."""
+    from llama_fastapi_k8s_gpu_tpu.engine import continuous as cont
+
+    eng = lane_eng
+    msgs = {k: [{"role": "user", "content": f"{k} request " * (i + 1)}]
+            for i, k in enumerate(ENDS)}
+    # a stop id that the greedy answer samples as its sixth token or later
+    probe = eng.create_chat_completion(msgs["stop_id"], temperature=0.0,
+                                       max_tokens=14, seed=102)
+    gens = eng.finished[probe["id"]][0]
+    at = next(i for i in range(5, len(gens)) if gens[i] not in gens[:i])
+    text = eng.create_chat_completion(
+        msgs["stop_string"], temperature=0.0, max_tokens=14,
+        seed=103)["choices"][0]["message"]["content"]
+    stop_str = text[3:6]
+    assert len(stop_str) == 3
+
+    def batch():
+        it = eng.create_chat_completion(
+            msgs["abandoned"], stream=True, temperature=0.8, seed=104,
+            max_tokens=30)
+        futs = {
+            "budget": eng.submit(msgs["budget"], temperature=0.8, seed=101,
+                                 max_tokens=7),
+            "stop_id": eng.submit(msgs["stop_id"], temperature=0.0, seed=102,
+                                  max_tokens=14),
+            "stop_string": eng.submit(msgs["stop_string"], temperature=0.0,
+                                      seed=103, max_tokens=14,
+                                      stop=[stop_str]),
+            "queued": eng.submit(msgs["queued"], temperature=0.8, seed=105,
+                                 max_tokens=9),
+        }
+        heard = [next(it)["choices"][0]["delta"] for _ in range(3)]
+        it.close()
+        out = {"abandoned": "".join(d.get("content", "") for d in heard)}
+        for k, f in futs.items():
+            o = f.result(timeout=120)
+            out[k] = (o["choices"][0]["message"]["content"],
+                      o["choices"][0]["finish_reason"],
+                      o["usage"]["completion_tokens"])
+        _quiet(eng)
+        return out
+
+    tok, program = eng.tokenizer, cont.batched_generate_chunk_perlane_jit
+    tok.__class__ = type("OneStopId", (type(tok),), {
+        "stop_ids": property(lambda self: {gens[at]})})
+    try:
+        before = _quiet(eng)
+        got = batch()
+        after = _quiet(eng)
+        cont.batched_generate_chunk_perlane_jit = _scan_chunk()
+        want = batch()
+    finally:
+        cont.batched_generate_chunk_perlane_jit = program
+        tok.__class__ = type(tok).__mro__[1]
+    return {"got": got, "want": want, "stop_at": at, "stop_str": stop_str,
+            "disagreements": after["end_disagreements"]
+            - before["end_disagreements"]}
+
+
+@pytest.mark.parametrize("end", ENDS)
+def test_token_streams_are_the_scan_forms_whatever_ends_a_request(mixed, end):
+    """(a) What a caller receives does not depend on where the program
+    stops stepping: the same text, finish and count as on the ``scan``
+    form at the same seeds, for every kind of end; and the device and the
+    harvest found every end at the same token."""
+    got, want = mixed["got"][end], mixed["want"][end]
+    assert got == want
+    assert mixed["disagreements"] == 0
+    if end == "abandoned":
+        assert got                      # the caller heard text, then left
+        return
+    text, finish, n = got
+    assert (finish, n) == {
+        "budget": ("length", 7), "queued": ("length", 9),
+        "stop_id": ("stop", mixed["stop_at"]),
+        "stop_string": ("stop", n)}[end]
+    if end == "stop_string":
+        assert mixed["stop_str"] not in text and 1 <= n <= 14
+
+
+def test_one_caller_waits_behind_no_chunk(lane_eng):
+    """(b) One caller, three requests in a row: behind each request's end
+    the queued chunk runs no step (``chunks_empty``), the steps run are
+    the tokens decoded after each first token (plus the step that sampled
+    a stop id), and the next request's ``pending`` span is shorter than
+    one decode step: it does not wait behind a chunk for nobody."""
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import Tracer
+
+    eng, tracer = lane_eng, Tracer(sample=1.0)
+    before = _quiet(eng)
+    steps, pendings, step_s = 0, [], []
+    for i in range(3):
+        tr = tracer.start()
+        out = eng.create_chat_completion(
+            [{"role": "user", "content": f"in a row {i}"}], temperature=0.0,
+            max_tokens=10 + i, trace=tr)
+        tracer.finish(tr)
+        gens, finish = eng.finished[out["id"]]
+        steps += max(len(gens) - 1, 0) + (finish == "stop" and bool(gens))
+        pend = next(c for c in tr.to_dict()["root"]["children"]
+                    if c["name"] == "pending")
+        pendings.append(pend["end"] - pend["start"])
+        t = out["lfkt_timings"]
+        step_s.append(t["decode_s"] / max(t["completion_tokens"] - 1, 1))
+    after = _quiet(eng)
+    assert after["chunks_empty"] - before["chunks_empty"] >= 2
+    assert after["steps_run"] - before["steps_run"] == steps
+    run = after["steps_run"] - before["steps_run"]
+    skipped = after["steps_skipped"] - before["steps_skipped"]
+    assert run + skipped == 4 * (after["chunks_dispatched"]
+                                 - before["chunks_dispatched"])
+    assert after["end_disagreements"] == before["end_disagreements"]
+    assert pendings[1] < min(step_s), (pendings, step_s)
+
+
+def test_an_ended_lane_decodes_again_after_the_next_lane_write(lane_eng):
+    """(c) A lane the device has ended is alive again once ``_write_lane``
+    installs the next request in it: one caller's requests all take lane
+    0, and each decodes to its own budget."""
+    eng = lane_eng
+    before = _quiet(eng)
+    for n in (6, 9, 5):
+        out = eng.create_chat_completion(
+            [{"role": "user", "content": f"again {n}"}], temperature=0.0,
+            max_tokens=n)
+        gens, finish = eng.finished[out["id"]]
+        assert finish == "stop" or len(gens) == n
+        st = _quiet(eng)
+        assert int(np.asarray(eng._lane_left)[0]) == 0   # ended again
+    assert st["end_disagreements"] == before["end_disagreements"]
+
+
+def _lane_program_case(lanes=3, n_ctx=96):
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import init_batched_state
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    cfg = ModelConfig(vocab_size=64, dim=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, ffn_dim=96, n_ctx=n_ctx)
+    params = synth_params(cfg)
+    st = jax.tree.map(lambda a: jnp.broadcast_to(a, (lanes,)),
+                      sampling_tensors(SamplingParams(temperature=0.7)))
+
+    def state():
+        s = init_batched_state(cfg, lanes, seed=1)
+        ks = jax.random.split(jax.random.PRNGKey(7), 2)
+        s["cache"] = {k: jax.random.normal(kk, s["cache"][k].shape,
+                                           jnp.bfloat16)
+                      for k, kk in zip(("k", "v"), ks)}
+        s["pos"] = jnp.asarray([20, 31, 33][:lanes], jnp.int32)
+        s["token"] = jnp.asarray([5, 6, 7][:lanes], jnp.int32)
+        return s
+
+    return cfg, params, st, state
+
+
+@pytest.mark.parametrize("entry", ["budget_1", "first_token_a_stop_id",
+                                   "alive"])
+def test_a_lane_ended_at_entry_is_never_stepped(entry):
+    """(d) ``_write_lane`` with a budget of one token, or with a first
+    token that is a stop id, leaves the lane ended: the chunk program runs
+    no step for it (every row a pad, ``left`` stays 0).  With a budget and
+    an ordinary first token it decodes."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import _write_lane
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        PENALTY_WINDOW, SamplingParams, sampling_tensors)
+
+    cfg, params, st, state = _lane_program_case()
+    budget, stop_ids = {"budget_1": (1, (9,)), "alive": (6, (9,)),
+                        "first_token_a_stop_id": (6, (9, 5))}[entry]
+    s, st, left = _write_lane(
+        state(), st, init_lane_left(3), jnp.int32(1), init_cache(cfg),
+        jnp.int32(12), jnp.int32(5), jnp.full(PENALTY_WINDOW, -1, jnp.int32),
+        jnp.int32(0), jax.random.PRNGKey(3),
+        sampling_tensors(SamplingParams(temperature=0.0)),
+        jnp.int32(budget - 1), stop_ids=stop_ids)
+    want_left = 5 if entry == "alive" else 0
+    assert np.asarray(left).tolist() == [0, want_left, 0]
+    s, left, toks = batched_generate_chunk_perlane_jit(
+        params, cfg, s, st, left, n_steps=4, top_k=40,
+        live=np.array([True, True, True]), stop_ids=stop_ids)
+    toks = np.asarray(toks)
+    assert (toks[:, [0, 2]] == -1).all()
+    if entry == "alive":
+        col = toks[:, 1].tolist()
+        ran = col.index(9) + 1 if 9 in col else 4   # 9 is a stop id here
+        assert min(col[:ran]) >= 0 and set(col[ran:]) <= {-1}
+        assert int(left[1]) == (0 if 9 in col else 1)
+    else:
+        assert (toks == -1).all() and int(np.asarray(left).max()) == 0
+        assert int(s["pos"][1]) == 12           # not one step taken
+
+
+@pytest.mark.parametrize("lanes_left", [(0, 0, 0), (0, 9, 0), (2, 9, 0),
+                                        (3, 3, 3)],
+                         ids=["all_ended", "one_alive", "one_ends_inside",
+                              "all_end_inside"])
+def test_the_chunk_program_against_its_scan_form(lanes_left):
+    """(e) With every lane ended the program returns all-pad rows and
+    every state leaf as it got it; an alive lane's tokens are bit-equal to
+    the ``scan`` form's for as long as it is alive, the rows after its end
+    (and every row of a step not run) are pads, and the state of a chunk
+    that ran all its steps is the ``scan`` form's."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit)
+
+    cfg, params, st, state = _lane_program_case()
+    live = np.array([True, True, True])
+    left = jnp.asarray(lanes_left, jnp.int32)
+    entry = jax.tree.map(np.asarray, state())
+    _, _, want = _scan_chunk()(params, cfg, state(), st, left, n_steps=4,
+                               live=live)
+    s, left_out, got = batched_generate_chunk_perlane_jit(
+        params, cfg, state(), st, left, n_steps=4, top_k=40, live=live)
+    got, want = np.asarray(got), np.asarray(want)
+    run = min(4, max(lanes_left))
+    for lane, n in enumerate(lanes_left):
+        n = min(n, 4)
+        assert np.array_equal(got[:n, lane], want[:n, lane])
+        assert (got[n:, lane] == -1).all()
+    assert np.asarray(left_out).tolist() == [max(n - 4, 0)
+                                             for n in lanes_left]
+    assert int(s["pos"][0]) == 20 + run
+    if run == 0:
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), b), s, entry)
+    if run == 4:
+        s_scan, _, _ = _scan_chunk()(params, cfg, state(), st, left,
+                                     n_steps=4, live=live)
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), s, s_scan)
+
+
+def test_a_stop_id_ends_a_lane_on_the_device():
+    """(e) A lane that samples one of ``stop_ids`` is ended by that step:
+    its ``left`` drops to 0 and its later rows are pads, while the lane
+    beside it decodes on."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit)
+
+    cfg, params, st, state = _lane_program_case()
+    live = np.array([True, True, False])
+    left = jnp.asarray([9, 9, 9], jnp.int32)
+    _, _, free = batched_generate_chunk_perlane_jit(
+        params, cfg, state(), st, left, n_steps=4, top_k=40, live=live)
+    free = np.asarray(free)
+    assert (free[:, 2] == -1).all()         # a lane that holds no request
+    stop = int(free[1, 0])                  # lane 0's second token
+    _, left_out, got = batched_generate_chunk_perlane_jit(
+        params, cfg, state(), st, left, n_steps=4, top_k=40, live=live,
+        stop_ids=(stop,))
+    got = np.asarray(got)
+    first = int(np.argmax(free[:, 0] == stop))
+    assert np.array_equal(got[:first + 1, 0], free[:first + 1, 0])
+    assert (got[first + 1:, 0] == -1).all() and int(left_out[0]) == 0
+    if stop not in free[:, 1]:
+        assert np.array_equal(got[:, 1], free[:, 1])
+        assert int(left_out[1]) == 5
+
+
+@pytest.mark.parametrize("block", ["dense", "routed"])
+def test_the_per_step_counters_count_the_steps_run(block, lane_eng,
+                                                   tmp_path):
+    """(f) ``_note_ring_read`` (``ring_slots``) and a routed block's expert
+    statistics count the steps the chunk program ran, not ``n_steps``: one
+    request of 9 tokens takes two chunks of 4 steps, and the chunk queued
+    behind them runs none."""
+    if block == "dense":
+        eng = lane_eng
+    else:
+        from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_olmoe_gguf
+
+        path = str(tmp_path / "tiny-olmoe.gguf")
+        write_tiny_olmoe_gguf(path, seed=3)
+        eng = ContinuousEngine(path, weight_format="q4k", n_ctx=128,
+                               batch_size=3, decode_chunk=4)
+    try:
+        before = _quiet(eng)
+        read0 = eng.ring_slots["read"]
+        pairs0 = eng.expert_counters.snapshot(block=True)["layer_steps"] \
+            if block == "routed" else 0
+        out = eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
+        after = _quiet(eng)
+        n = out["usage"]["completion_tokens"]
+        run = after["steps_run"] - before["steps_run"]
+        assert run == (8 if n == 9 else run) and run < 12
+        # n_ctx 128 is one block of the read: a step reads 128 slots a lane
+        assert eng.ring_slots["read"] - read0 == 128 * run
+        if block == "routed":
+            snap = eng.expert_counters.snapshot(block=True)
+            assert snap["layer_steps"] - pairs0 == eng.cfg.n_layers * run
+    finally:
+        if block == "routed":
+            eng.shutdown()

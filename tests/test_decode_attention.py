@@ -178,8 +178,9 @@ def test_lane_program_ignores_a_dead_lanes_position():
             "v": jax.random.normal(ks[1], state["cache"]["v"].shape, jnp.bfloat16)}
         state["pos"] = jnp.asarray([20, dead_pos, 33], jnp.int32)
         state["token"] = jnp.asarray([5, 6, 7], jnp.int32)
-        state, toks = batched_generate_chunk_perlane_jit(
-            params, cfg, state, st, n_steps=4, top_k=40, live=live)
+        state, _, toks = batched_generate_chunk_perlane_jit(
+            params, cfg, state, st, jnp.full(3, 9, jnp.int32), n_steps=4,
+            top_k=40, live=live)
         return np.asarray(toks)
 
     near, far = run(2), run(90)
