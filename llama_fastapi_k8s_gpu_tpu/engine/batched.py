@@ -190,6 +190,22 @@ class MeshEngine(Engine):
                 "pass, and its sparse layers select per query in slices; "
                 "use the continuous scheduler")
 
+    def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
+        super()._refuse_for_latent_cache(kv_paged)
+        dp, tp = self._mesh_shape
+        if tp > 1:
+            raise ValueError(
+                f"LFKT_MESH_TP={tp} cannot serve architecture 'deepseek2': "
+                "parallel/mesh.py shards a ring's KV heads, and its latent "
+                "ring has one row for all heads; experts over a mesh are "
+                "ROADMAP B-I 5")
+        if not self._SLICED_ADMISSION:
+            raise ValueError(
+                "LFKT_SCHEDULER=cycle cannot serve architecture "
+                "'deepseek2': it prefills a whole prompt in one vmapped "
+                "pass, and latent attention scores a pass against blocks "
+                "of latents slice by slice; use the continuous scheduler")
+
     def _recover_locked(self) -> None:  # lfkt: holds[_lock]
         """Watchdog recovery: a crash mid-cycle may have poisoned the donated
         batched state, so rebuild it (sharded) along with the serial ring."""

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import RING
+from ..models.config import LATENT_RING, RING
 from ..models.generate import prefill_chunk_jit, sample_jit
 from ..models.llama import init_cache
 from ..obs import memledger as _memledger
@@ -348,8 +348,9 @@ class ContinuousEngine(MeshEngine):
         # claim is by token position and a window restarts; such a lane's
         # walking position stays inside both stores by itself, slot
         # ``pos mod W``, and closes no window past the last closable one.)
+        # (ON for the latent ring, which is positional as the ring is.)
         self._lane_prefix = bool(lane_prefix_cache) \
-            and self.cfg.cache_kind == RING
+            and self.cfg.cache_kind in (RING, LATENT_RING)
         # paged mode (LFKT_KV_PAGED) folds the lane claims behind the
         # shared radix tree: one prefix-reuse implementation per mode (the
         # per-lane claim path remains the dense-ring default).  An
@@ -744,8 +745,9 @@ class ContinuousEngine(MeshEngine):
 
     def _find_lane_reuse(self, ids: list, n_prompt: int):
         """(reuse_len, source_lane) — the longest chunk-aligned usable
-        claim prefix across freed lanes, or (0, None).  Chunk alignment
-        keeps every suffix slice shape inside the warmed compiled set."""
+        claim prefix across the lanes (a freed lane's conversation, a live
+        lane's prompt), or (0, None).  Chunk alignment keeps every suffix
+        slice shape inside the warmed compiled set."""
         best, src = 0, None
         cap = n_prompt - 1   # ≥1 real token must prefill (last-token logits)
         for lane, claim in enumerate(self._lane_claims):
@@ -860,7 +862,7 @@ class ContinuousEngine(MeshEngine):
                 # mid-prefill (or failing later) must not inflate /metrics
             if pspan is not None:
                 pspan.set(n_prompt=len(ids), bucket=bucket, reused=reuse)
-            self._note_prefill_windows(len(ids), pspan)
+            self._note_prefill_windows(len(ids), pspan, reuse)
             # host-side slice prep happens ONCE, here, while lanes decode:
             # one int32 array for the padded prompt; every slice dispatch
             # then takes a zero-copy view instead of re-converting a list
@@ -1000,6 +1002,15 @@ class ContinuousEngine(MeshEngine):
                 jnp.int32(lane), self._scratch_cache, jnp.int32(n_prompt),
                 token, window, wpos, key, st, jnp.int32(budget - 1),
                 stop_ids=self._stop_ids)
+            if self._lane_prefix:
+                # a LIVE lane is a claim too: its prompt's rows stay where
+                # they are while it decodes (it writes from n_prompt on),
+                # so requests that arrive together behind one system line
+                # ride the first one's prefill instead of each repeating it
+                # (16 callers at once, 8k shared: 2 s of prefill each, the
+                # last one past the server's timeout).  _free_lane extends
+                # the claim by what was generated, or drops it.
+                self._lane_claims[lane] = ids
             slot = _Slot(item, budget, n_prompt, ids)
             slot.stops = item.stops
             slot.st = st
@@ -1449,9 +1460,16 @@ class ContinuousEngine(MeshEngine):
                     lane, finish, n, rows)
                 finish = finish or "length"
             if slot.dspan is not None:
-                slot.dspan.child("decode_chunk", t0=slot.t_chunk).set(
+                cspan = slot.dspan.child(
+                    "decode_chunk", t0=slot.t_chunk).set(
                     tokens=len(slot.gens), wave=wave,
-                    admit_slices=admit_slices, kind="chunk").end(now)
+                    admit_slices=admit_slices, kind="chunk")
+                if self.cfg.cache_kind == LATENT_RING:
+                    # the lane's own live rows at the chunk's end: what
+                    # its attention needed of the read
+                    cspan.set(cache=LATENT_RING,
+                              latent_positions=slot.n_prompt + len(slot.gens))
+                cspan.end(now)
                 slot.t_chunk = now
                 slot.trace.note(tokens=len(slot.gens))
             if finish is not None:

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gguf import GGUFFile
-from ..models.config import RING, STATE_RING, ModelConfig
+from ..models.config import LATENT_RING, RING, STATE_RING, ModelConfig
 from ..models.generate import (
     generate_chunk_jit,
     init_state,
@@ -40,7 +40,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models import eva, sala
+from ..models import eva, mla, sala
 from ..models.llama import (
     decode_chunk_slots, decode_kernel_block, init_cache, ring_write_impl)
 from ..models.params import load_params, synth_params
@@ -342,6 +342,9 @@ class Engine:
             attn_impl = "xla"   # its attention is models/eva.py's own
         if self.cfg.cache_kind == STATE_RING:
             self._refuse_for_state_cache(bool(kv_paged))
+        if self.cfg.cache_kind == LATENT_RING:
+            self._refuse_for_latent_cache(bool(kv_paged))
+            attn_impl = "xla"   # its attention is models/mla.py's own loop
         # the compile probes of the attention side: one phase of the
         # timeline when any of them ran
         probing = Phase("attn_probes", meter="cache", kernels=[])
@@ -418,8 +421,9 @@ class Engine:
         # engines manage caches differently and keep full prefill.
         # (off for the window + summary cache: reuse is by token position,
         # and a window restarts: a property of the cache, /health says so)
+        # (ON for the latent ring, which is positional as the ring is)
         self._prefix_cache = bool(prefix_cache) and type(self) is Engine \
-            and self.cfg.cache_kind == RING
+            and self.cfg.cache_kind in (RING, LATENT_RING)
         self._prefix_min = max(1, int(prefix_min))
         #: token ids whose KV occupy ring slots [0, len) — only ever read
         #: and written under self._lock (the single-generator invariant)
@@ -552,11 +556,42 @@ class Engine:
                 f"multiple of its sparse layers' block "
                 f"({self.cfg.sp_block})")
 
+    def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
+        """The same for the latent ring of ``deepseek2`` (models/mla.py):
+        an int8 cache (the latent is read as it was normed) and the paged
+        pool (its page geometry is K and V heads: ROADMAP B-I 2).
+        Subclasses add the meshes."""
+        if self.cfg.kv_dtype == "int8":
+            raise ValueError(
+                "LFKT_KV_DTYPE=int8 cannot serve architecture 'deepseek2': "
+                "its latent ring is bf16 only")
+        if kv_paged:
+            raise ValueError(
+                "LFKT_KV_PAGED=1 cannot serve architecture 'deepseek2': a "
+                "pool page is a run of K and V slots per KV head, and its "
+                "cache is one latent row a position for all heads")
+
     @property
     def cache_kind(self) -> dict | None:
         """The /health ``engine.cache`` block of a cache that is no ring
         (None for the ring: its /health is what it was): the kind's sizes,
         and the reuse it does without as a property, not a degrade."""
+        if self.cfg.cache_kind == LATENT_RING:
+            cfg = self.cfg
+            reuse = getattr(self, "_lane_prefix", self._prefix_cache)
+            return {
+                "kind": LATENT_RING,
+                "latent": cfg.kv_lora_rank, "rotated_key": cfg.qk_rope_dim,
+                "bytes_per_position": 2 * cfg.n_layers * mla.lat_width(cfg),
+                "bytes_per_position_laid_out":
+                    2 * cfg.n_layers * mla.leaf_width(cfg),
+                "read": "absorbed, blocks of %d" % mla.LATENT_BLOCK,
+                "dense_layers": cfg.n_dense_layers,
+                "routed_layers": mla.n_moe_layers(cfg),
+                "experts_held": [cfg.experts_first, cfg.n_held],
+                "experts_routed": cfg.n_experts,
+                "prefix_reuse": "on" if reuse else "off",
+                "kv_paged": "refused at start"}
         if self.cfg.cache_kind == STATE_RING:
             cfg = self.cfg
             return {
@@ -911,6 +946,13 @@ class Engine:
                 eva_summaries_read_total=c["summaries_read"],
                 eva_summaries_live_total=c["summaries_live"],
                 eva_windows_closed_total=c["windows_closed"])
+        if self.cfg.cache_kind == LATENT_RING:
+            # the ring's own arithmetic (blocks of models/mla.py
+            # LATENT_BLOCK up to the largest live lane's position), under
+            # the kind's names: a latent is read once for all heads
+            out.update(
+                latent_positions_read_total=self.ring_slots["read"],
+                latent_positions_live_total=self.ring_slots["live"])
         if self.cfg.cache_kind == STATE_RING:
             c = self.sala_counts
             out.update({
@@ -938,7 +980,7 @@ class Engine:
         if not self.cfg.n_experts:
             return None
         if self._expert_counters is None:
-            self._expert_counters = ExpertCounters(self.cfg.n_experts)
+            self._expert_counters = ExpertCounters(self.cfg.n_held)
         return self._expert_counters
 
     def _next_seed(self) -> int:
@@ -1117,7 +1159,7 @@ class Engine:
             reuse = self._paged_reuse(ids, n_prompt, bucket, pspan)
         if pspan is not None:
             pspan.set(n_prompt=n_prompt, bucket=bucket, reused=reuse)
-        self._note_prefill_windows(n_prompt, pspan)
+        self._note_prefill_windows(n_prompt, pspan, reuse)
         # claim nothing while this request is in flight: an exception past
         # this point must not leave a stale prefix claim over a cache whose
         # contents are indeterminate
@@ -1167,9 +1209,16 @@ class Engine:
             "bucket": bucket,
         }
 
-    def _note_prefill_windows(self, n_prompt: int, pspan=None) -> None:
+    def _note_prefill_windows(self, n_prompt: int, pspan=None,
+                              reused: int = 0) -> None:
         """The windows a prompt's prefill closes (the window + summary
-        cache alone): counted, and on the traced ``prefill`` span."""
+        cache alone): counted, and on the traced ``prefill`` span; of a
+        latent ring the span names the kind and the cached rows its slices
+        read (``reused``: the prefix no slice computes)."""
+        if self.cfg.cache_kind == LATENT_RING and pspan is not None:
+            pspan.set(cache=LATENT_RING,
+                      latent_positions_read=mla.prefill_positions_read(
+                          n_prompt, reused, self._prefill_chunk, self.cfg))
         if self.cfg.eva_window:
             n = eva.windows_closed_by_prefill(n_prompt, self.cfg)
             self.eva_counts["windows_closed"] += n
@@ -1561,6 +1610,8 @@ class Engine:
                 done = True
             if cspan is not None:
                 cspan.set(tokens=len(gen))
+                if self.cfg.cache_kind == LATENT_RING:
+                    cspan.set(cache=LATENT_RING, latent_positions=pos)
                 cspan.end()
                 ctx["trace"].note(tokens=len(gen))
 

@@ -2,8 +2,9 @@
 
 A decode chunk of a routed block returns, beside its tokens, one small
 int32 vector (models/llama.py ``expert_stats_len``): the (layer, step) pairs
-it ran, the distinct experts read summed over them, and the rows each
-expert took.  The engines hand that DEVICE array here at dispatch
+it ran, the distinct experts read summed over them, the rows each expert
+HELD here took, and the picks the routers made over all experts (what
+left the chip is those less the rows taken: models/mla.py).  The engines hand that DEVICE array here at dispatch
 (:meth:`push`: a list append, nothing fetched, nothing waited for) and the
 totals are folded when somebody reads them: a ``/metrics`` scrape folds the
 chunks that have finished (:meth:`snapshot`), a test folds them all
@@ -21,10 +22,10 @@ _MAX_PENDING = 64   # older chunks have long finished: folding them is free
 
 
 class ExpertCounters:
-    def __init__(self, n_experts: int):
+    def __init__(self, n_held: int):
         self._lock = threading.Lock()
         self._pending: list = []
-        self._total = np.zeros(2 + n_experts, np.int64)
+        self._total = np.zeros(3 + n_held, np.int64)
 
     def push(self, stats) -> None:
         """One dispatched chunk's counter vector (a device array)."""
@@ -35,8 +36,9 @@ class ExpertCounters:
 
     def snapshot(self, block: bool = False) -> dict:
         """Cumulative counters of the chunks that have finished (all
-        dispatched chunks with ``block``): ``layer_steps``, ``experts_read``
-        and ``picks`` (a list, one count per expert)."""
+        dispatched chunks with ``block``): ``layer_steps``, ``experts_read``,
+        ``picks`` (a list, one count per held expert), ``picks_held`` (their
+        sum) and ``picks_total`` (over all the router's experts)."""
         with self._lock:
             keep = []
             for s in self._pending:
@@ -47,4 +49,5 @@ class ExpertCounters:
             self._pending = keep
             t = self._total
             return {"layer_steps": int(t[0]), "experts_read": int(t[1]),
-                    "picks": t[2:].tolist()}
+                    "picks": t[2:-1].tolist(), "picks_held": int(t[2:-1].sum()),
+                    "picks_total": int(t[-1])}

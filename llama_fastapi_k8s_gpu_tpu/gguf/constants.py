@@ -130,9 +130,34 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``<arch>.sparse.*`` constants); the MiniCPM family's three scalars under
 #: llama.cpp's keys for it, ``<arch>.embedding_scale``, ``residual_scale``
 #: (scale_depth / sqrt(layers), as applied) and ``logit_scale`` (dim_model_base
-#: / hidden_size, as applied to the final norm's output).  A file of any
-#: other architecture is refused by name at load (gguf/reader.py).
-SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala")
+#: / hidden_size, as applied to the final norm's output).
+#: ``deepseek2`` (llama.cpp's name for the DeepSeek-V2/V3 family;
+#: models/mla.py) is latent attention over the fourth cache kind and a
+#: feed-forward kind per layer.  Tensors: ``blk.N.attn_q_a`` (r_q, dim),
+#: ``attn_q_a_norm``, ``attn_q_b`` (heads x (d_nope + d_rope), r_q),
+#: ``attn_kv_a_mqa`` (r_kv + d_rope, dim), ``attn_kv_a_norm``, ``attn_kv_b``
+#: (heads x (d_nope + d_v), r_kv), ``attn_output`` (dim, heads x d_v); the
+#: first ``<arch>.leading_dense_block_count`` layers the dense
+#: ``ffn_{gate,up,down}``, the others the F32 router ``ffn_gate_inp``
+#: (experts, dim), its F32 choice bias ``exp_probs_b.bias`` (experts), the
+#: 3-D ``ffn_{gate,up,down}_exps`` and the shared ``ffn_{gate,up,down}_shexp``.
+#: Keys, llama.cpp's: ``attention.q_lora_rank``, ``attention.kv_lora_rank``,
+#: ``attention.key_length`` (d_nope + d_rope), ``attention.value_length``,
+#: ``rope.dimension_count`` (d_rope), ``leading_dense_block_count``,
+#: ``expert_feed_forward_length``, ``expert_count``, ``expert_used_count``,
+#: ``expert_shared_count``, ``expert_weights_scale``, ``expert_weights_norm``,
+#: ``expert_gating_func`` (1 softmax, 2 sigmoid), ``expert_group_count``,
+#: ``expert_group_used_count``, ``rope.scaling.{type,factor,
+#: original_context_length,yarn_log_multiplier}`` (the last 0.1 x
+#: mscale_all_dim); this repo's own: ``rope.scaling.yarn_beta_{fast,slow}``
+#: and ``expert_held_first`` / ``expert_held_count`` (the 3-D expert tensors
+#: hold that many experts from that one on, of the router's
+#: ``expert_count``: one chip's share of an expert-parallel layer; absent:
+#: all).  Q and K rotate on interleaved pairs (ggml's NORM mode).
+#: A file of any other architecture is refused by name at load
+#: (gguf/reader.py).
+SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
+                        "deepseek2")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):

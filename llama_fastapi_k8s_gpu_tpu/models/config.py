@@ -17,6 +17,7 @@ from ..gguf.constants import NEOX_ROPE_ARCHITECTURES
 
 #: the cache kinds' names (``ModelConfig.cache_kind``; docs/KV_CACHE.md)
 RING, WINDOW_SUMMARIES, STATE_RING = "ring", "window+summaries", "state+ring"
+LATENT_RING = "latent-ring"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +103,55 @@ class ModelConfig:
     # float32 logits from a float head (bf16 inputs), as ``fp32_residual``
     # has them, on a bf16 stream
     fp32_logits: bool = False
+    # Latent attention (models/mla.py; ``deepseek2``; 0: none): the query
+    # passes a normed latent of ``q_lora_rank``; a position's keys and
+    # values are one normed latent of ``kv_lora_rank`` that every head
+    # expands to ``qk_nope_dim`` key and ``v_head_dim`` value columns, plus
+    # ONE rotated key of ``qk_rope_dim`` shared by all heads.  The cache
+    # keeps the latent and the rotated key (``cache_kind`` latent-ring).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (factor 0: none) on the rotated part, as published: the inverse
+    # frequencies blended between plain and interpolated by a linear ramp
+    # between the correction dims of ``beta_fast`` / ``beta_slow`` at
+    # ``orig_ctx``; ``attn_mscale`` is what the softmax scale is multiplied
+    # by ((0.1 * mscale_all_dim * ln factor + 1) ** 2 where > 1; the
+    # cos/sin scale mscale/mscale_all_dim is 1 for every file served)
+    rope_yarn_factor: float = 0.0
+    rope_yarn_orig_ctx: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    attn_mscale: float = 1.0
+    # The feed-forward kind is the LAYER's: the first ``n_dense_layers`` are
+    # the dense SwiGLU of ``ffn_dim``, the others routed experts of
+    # ``expert_ffn_dim`` plus ``n_shared_experts`` shared ones (one matrix
+    # of their summed width) on every token.
+    n_dense_layers: int = 0
+    expert_ffn_dim: int = 0
+    n_shared_experts: int = 0
+    # The router of such a layer (``route_grouped``): ``expert_gating``
+    # softmax | sigmoid over all ``n_experts``; the choice on the scores
+    # plus a bias (``exp_probs_b``), inside the ``n_groups_used`` best of
+    # ``n_expert_groups`` groups (a group's score: its two largest);
+    # weights the unbiased scores, normalised if ``norm_topk_prob``, times
+    # ``expert_weights_scale``.
+    expert_gating: str = "softmax"
+    n_expert_groups: int = 1
+    n_groups_used: int = 1
+    expert_weights_scale: float = 1.0
+    # The experts HELD here, of the router's ``n_experts``: ``experts_held``
+    # from ``experts_first`` on (0: all).  A pick outside them adds nothing
+    # (expert parallelism's share of a layer, without its exchange).
+    experts_first: int = 0
+    experts_held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this process holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def head_dim(self) -> int:
@@ -111,10 +161,12 @@ class ModelConfig:
     def cache_kind(self) -> str:
         """The NAME of the cache kind a sequence of this file holds
         (docs/KV_CACHE.md "Cache kinds"), read here and nowhere else:
-        ``ring``, ``window+summaries`` (models/eva.py) or ``state+ring``
-        (models/sala.py)."""
+        ``ring``, ``window+summaries`` (models/eva.py), ``state+ring``
+        (models/sala.py) or ``latent-ring`` (models/mla.py)."""
         if self.mixers:
             return STATE_RING
+        if self.kv_lora_rank:
+            return LATENT_RING
         return WINDOW_SUMMARIES if self.eva_window else RING
 
     def n_layers_of(self, kind: str) -> int:
@@ -124,6 +176,12 @@ class ModelConfig:
     def n_linear_weights(self) -> int:
         """About how many weights the layers' matrices hold, every expert
         included: what the ``weight_format="auto"`` size test weighs."""
+        if self.kv_lora_rank:
+            routed = 3 * self.dim * self.expert_ffn_dim * (
+                self.n_held + self.n_shared_experts)
+            return self.n_layers * 4 * self.dim * self.dim \
+                + self.n_dense_layers * 3 * self.dim * self.ffn_dim \
+                + (self.n_layers - self.n_dense_layers) * routed
         ffn = 3 * self.dim * self.ffn_dim * max(self.n_experts, 1)
         return self.n_layers * (4 * self.dim * self.dim + ffn)
 
@@ -193,6 +251,9 @@ class ModelConfig:
                 raise ValueError(
                     "minicpm-sala: lightning.head_count x head width must "
                     "be the embedding length")
+        mla = {}
+        if arch == "deepseek2":
+            mla = _deepseek2_fields(h, n_heads)
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -208,13 +269,88 @@ class ModelConfig:
             n_experts=int(h("expert_count", 0) or 0),
             n_experts_used=int(h("expert_used_count", 0) or 0),
             # llama.cpp's olmoe graph: build_moe_ffn(..., norm_w=false) and
-            # build_norm over the whole Qcur/Kcur
-            norm_topk_prob=False,
+            # build_norm over the whole Qcur/Kcur; deepseek2 reads its key
+            norm_topk_prob=mla.pop("norm_topk_prob", False),
             qk_norm=arch == "olmoe",
             rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
             **eva,
             **sala,
+            **mla,
         )
+
+
+def _deepseek2_fields(h, n_heads: int) -> dict:
+    """The ``deepseek2`` keys (gguf/constants.py) as ``ModelConfig``
+    fields; a ValueError naming what the block here cannot compute."""
+    import math
+
+    def need(key):
+        v = h(key)
+        if v is None:
+            raise ValueError(f"deepseek2: the file lacks <arch>.{key}")
+        return v
+
+    r_q, r_kv = int(h("attention.q_lora_rank", 0) or 0), \
+        int(need("attention.kv_lora_rank"))
+    d_r = int(need("rope.dimension_count"))
+    d_qk, d_v = int(need("attention.key_length")), \
+        int(need("attention.value_length"))
+    if not r_q:
+        raise ValueError(
+            "deepseek2: attention.q_lora_rank is 0 (the lite files' plain "
+            "query projection): the block here has the query latent only")
+    if d_qk <= d_r or d_r % 2:
+        raise ValueError(
+            f"deepseek2: attention.key_length {d_qk} must exceed the even "
+            f"rope.dimension_count {d_r} (a head's key is its unrotated "
+            "part, then the shared rotated one)")
+    n_exp = int(h("expert_count", 0) or 0)
+    groups = int(h("expert_group_count", 1) or 1)
+    used_groups = int(h("expert_group_used_count", groups) or groups)
+    if n_exp % groups or not 1 <= used_groups <= groups:
+        raise ValueError(
+            f"deepseek2: {n_exp} experts in {groups} groups, "
+            f"{used_groups} used")
+    k = int(h("expert_used_count", 0) or 0)
+    if n_exp and (n_exp // groups < 2 or k > used_groups * (n_exp // groups)):
+        raise ValueError(
+            f"deepseek2: a group's score is its two largest, and "
+            f"{k} picks must fit {used_groups} groups of {n_exp // groups}")
+    gating = {1: "softmax", 2: "sigmoid"}.get(
+        int(h("expert_gating_func", 1) or 1))
+    if gating is None:
+        raise ValueError(
+            f"deepseek2: expert_gating_func {h('expert_gating_func')!r} "
+            "(1: softmax, 2: sigmoid)")
+    first = int(h("expert_held_first", 0) or 0)
+    held = int(h("expert_held_count", 0) or 0)
+    if held and not 0 <= first <= first + held <= n_exp:
+        raise ValueError(
+            f"deepseek2: experts held {first}..{first + held} of {n_exp}")
+    yarn = {}
+    if str(h("rope.scaling.type", "none")) == "yarn":
+        factor = float(need("rope.scaling.factor"))
+        # llama.cpp's key holds 0.1 * mscale_all_dim
+        log_mul = float(h("rope.scaling.yarn_log_multiplier", 0.0) or 0.0)
+        m = log_mul * math.log(factor) + 1.0 if factor > 1 else 1.0
+        yarn = dict(
+            rope_yarn_factor=factor,
+            rope_yarn_orig_ctx=int(need(
+                "rope.scaling.original_context_length")),
+            rope_yarn_beta_fast=float(h("rope.scaling.yarn_beta_fast", 32.0)),
+            rope_yarn_beta_slow=float(h("rope.scaling.yarn_beta_slow", 1.0)),
+            attn_mscale=m * m)
+    return dict(
+        q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_qk - d_r,
+        qk_rope_dim=d_r, v_head_dim=d_v,
+        n_dense_layers=int(h("leading_dense_block_count", 0) or 0),
+        expert_ffn_dim=int(h("expert_feed_forward_length", 0) or 0),
+        n_shared_experts=int(h("expert_shared_count", 0) or 0),
+        expert_gating=gating, n_expert_groups=groups,
+        n_groups_used=used_groups,
+        expert_weights_scale=float(h("expert_weights_scale", 1.0) or 1.0),
+        norm_topk_prob=bool(h("expert_weights_norm", False)),
+        experts_first=first, experts_held=held, **yarn)
 
 
 # Canonical full-size configs (for synthesis / benches; no network egress, so

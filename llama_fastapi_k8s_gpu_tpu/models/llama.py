@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from ..ops import linear
 from ..ops.linear import linear_at
 from . import eva
-from .config import STATE_RING, ModelConfig
+from .config import LATENT_RING, STATE_RING, ModelConfig
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -106,11 +106,17 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     leaves of ``eva_window`` slots and two summary leaves.  ``cfg.cache_kind
     == "state+ring"`` is the third (models/sala.py): a ring and its
     compressed keys for the sparse layers, a float32 state for the linear
-    ones, each as deep as its kind has layers."""
+    ones, each as deep as its kind has layers.  ``"latent-ring"`` is the
+    fourth (models/mla.py): one row a layer and position, the normed latent
+    and the rotated key all heads share."""
     if cfg.cache_kind == STATE_RING:
         from . import sala
 
         return sala.init_cache(cfg, dtype)
+    if cfg.cache_kind == LATENT_RING:
+        from . import mla
+
+        return mla.init_cache(cfg, dtype)
     if cfg.eva_window:
         return eva.init_cache(cfg, dtype)
     shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
@@ -136,6 +142,10 @@ def cache_nbytes(cfg: ModelConfig) -> int:
         from . import sala
 
         return sala.cache_nbytes(cfg)
+    if cfg.cache_kind == LATENT_RING:
+        from . import mla
+
+        return mla.cache_nbytes(cfg)
     if cfg.eva_window:
         return eva.cache_nbytes(cfg)
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
@@ -392,8 +402,10 @@ def route(hn, w_router, cfg: ModelConfig):
 def expert_stats_len(cfg: ModelConfig) -> int:
     """Length of the routed layers' counter vector (:func:`forward`
     ``with_stats``): [(layer, step) pairs, distinct experts read summed
-    over them, rows each expert took...]."""
-    return 2 + cfg.n_experts
+    over them, rows each HELD expert took..., the picks the live rows'
+    routers made over ALL experts (what was not held is the difference:
+    models/mla.py)]."""
+    return 3 + cfg.n_held
 
 
 def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
@@ -628,6 +640,12 @@ def forward(
 
         return sala.forward(params, cfg, tokens, pos_offset, cache, last_idx,
                             return_all, live, with_picks, kv_bound)
+    if cfg.cache_kind == LATENT_RING:
+        # latent attention, a feed-forward kind per layer: its own loops
+        from . import mla
+
+        return mla.forward(params, cfg, tokens, pos_offset, cache, last_idx,
+                           return_all, live, with_stats, with_picks, kv_bound)
     if cfg.eva_window and S > cfg.eva_window:
         raise ValueError(
             f"architecture 'evabyte': {S} positions in one pass, its window "
@@ -666,7 +684,8 @@ def forward(
         count, picks = out
         read = jnp.sum(count > 0, dtype=jnp.int32)
         stats = carry[2] + jnp.concatenate(
-            [jnp.stack([jnp.int32(1), read]), count])
+            [jnp.stack([jnp.int32(1), read]), count,
+             jnp.sum(count, dtype=jnp.int32)[None]])   # every pick is held
         return h, cache, stats, jax.lax.dynamic_update_slice(
             carry[3], picks[None], (i, 0, 0))
 

@@ -1,0 +1,448 @@
+"""The fourth cache KIND, and a feed-forward kind per LAYER
+(``general.architecture = "deepseek2"``: the DeepSeek-V2/V3 family's block;
+``cfg.kv_lora_rank``).
+
+- Latent attention (MLA).  ``c_q = RMSNorm(W_qa x)``; per head ``[q_n | q_r]
+  = W_qb c_q`` (``qk_nope_dim`` + ``qk_rope_dim``); ``[c_kv | k_r] = W_kva
+  x``, ``c = RMSNorm(c_kv)``; per head ``[k_n | v] = W_kvb c``; ``q_r`` and
+  the ONE ``k_r`` all heads share are rotated on interleaved pairs (YaRN
+  frequencies: :func:`rope_inv_freq`); scores ``(q_n . k_n + q_r . k_r) *
+  scale``, ``scale = (d_n + d_r)^-1/2 * cfg.attn_mscale``; causal softmax;
+  ``o = P v``; ``W_o``.  The cache keeps, per layer and position, the normed
+  latent ``c`` and the rotated ``k_r`` side by side: leaf ``lat`` (L, 1,
+  n_ctx, r_kv + d_r filled up to a multiple of 128: :func:`leaf_width`)
+  bf16, one row for all heads (1152 B at 512 + 64, 1280 as laid out,
+  against 49 152 B for 64 heads of K and V).  It is POSITIONAL, as a ring
+  is: a prefix of it is a prefix of the sequence, so the engines' prefix
+  reuse (the serial claim, the lanes' claims) serves it unchanged.
+- Every read is in the ABSORBED form (:func:`latent_attention`): ``W_kvb``'s
+  key half is folded into the query (``q_abs = q_n W_uk``, r_kv wide), the
+  scores are ``[q_abs | q_r] . [c | k_r]``, the weighted sum is over the
+  latents themselves and ``W_kvb``'s value half is applied after it.  A
+  block of latents is read ONCE for all heads, which is the mechanism's
+  point; the expanded form (:func:`expanded_attention`: K and V of every
+  head for every position) is what the reference computes and what
+  tests hold the absorbed form to.  A decode step and a prefill slice run
+  the same loop over blocks of ``LATENT_BLOCK`` positions up to a traced
+  bound, in plain XLA (flash recurrence: running max and sum).
+- The first ``cfg.n_dense_layers`` layers' feed-forward is the dense SwiGLU
+  of ``cfg.ffn_dim``; the others' a float32 router (:func:`route_grouped`)
+  over ``cfg.n_experts`` experts of ``cfg.expert_ffn_dim`` plus a shared
+  expert on every token.  The two kinds are two stacks of weights
+  (``params["layers"]["dense" | "moe"]``), each a ``fori_loop``.
+- The expert layer is told which experts it HOLDS (``cfg.experts_first``,
+  ``cfg.n_held``): the router scores all ``n_experts`` and picks as
+  published; a pick outside the held ones becomes the sentinel "no pick"
+  of ``ops/pallas/experts.py`` and adds nothing.  That is one chip's share
+  of an expert-parallel layer without its exchange.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.custom_batching import custom_vmap
+
+from ..ops.linear import linear, linear_at
+from .config import ModelConfig
+
+DENSE, MOE = "dense", "moe"
+HI = jax.lax.Precision.HIGHEST
+
+#: latent rows a block of :func:`latent_attention`'s loop reads (the XLA
+#: loop of ``models/llama.py decode_attention`` reads 512 ring slots a time)
+LATENT_BLOCK = 512
+
+
+def lat_width(cfg: ModelConfig) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_dim
+
+
+def leaf_width(cfg: ModelConfig) -> int:
+    """The ``lat`` leaf's last dimension: a row's ``lat_width`` filled up
+    with zeros to the tile's 128 lanes.  The chip lays a 576-wide row out
+    in 640 either way; with 576 stated the compiler chose to turn the
+    lanes' whole leaf (positions minor) on the way into every decode chunk
+    and back out (tests/test_chip_compile.py), with 640 it leaves it be."""
+    return -(-lat_width(cfg) // 128) * 128
+
+
+def n_moe_layers(cfg: ModelConfig) -> int:
+    return cfg.n_layers - cfg.n_dense_layers
+
+
+def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
+    return {"lat": jnp.zeros((cfg.n_layers, 1, cfg.n_ctx, leaf_width(cfg)),
+                             dtype)}
+
+
+def cache_nbytes(cfg: ModelConfig) -> int:
+    return cfg.n_layers * cfg.n_ctx * leaf_width(cfg) * 2
+
+
+def prefill_positions_read(n_prompt: int, reused: int, chunk: int,
+                           cfg: ModelConfig) -> int:
+    """Cached rows (a layer's) the slices of a prompt's prefill read: each
+    slice of ``chunk`` positions from ``reused`` on reads whole blocks up to
+    its own last position.  Host arithmetic for the ``prefill`` span."""
+    T, total = min(LATENT_BLOCK, cfg.n_ctx), 0
+    for off in range(reused, n_prompt, max(chunk, 1)):
+        total += min(-(-min(off + chunk, cfg.n_ctx) // T) * T, cfg.n_ctx)
+    return total
+
+
+def attn_scale(cfg: ModelConfig) -> float:
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * cfg.attn_mscale
+
+
+def rope_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """(d_r / 2,) float32 inverse frequencies of the rotated part.  Plain:
+    ``theta^(-2i/d_r)``.  YaRN as published (DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding``): blended with the same over
+    ``factor`` by a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations at the original context."""
+    d = cfg.qk_rope_dim
+    base = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if not cfg.rope_yarn_factor or cfg.rope_yarn_factor == 1.0:
+        return base.astype(np.float32)
+
+    def corr_dim(n_rot):
+        return d * math.log(cfg.rope_yarn_orig_ctx / (n_rot * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(corr_dim(cfg.rope_yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.rope_yarn_beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1: the plain frequency
+    return (base / cfg.rope_yarn_factor * (1.0 - keep) + base * keep
+            ).astype(np.float32)
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq) -> jax.Array:
+    """x (S, H, d_r): rotate pairs (2i, 2i+1) by ``pos * inv_freq[i]``
+    (ggml's NORM mode: the converter interleaves the rotated rows; the
+    published code de-interleaves and rotates halves, the same map)."""
+    ang = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)[None]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# attention over cached latents
+# ---------------------------------------------------------------------------
+
+def absorb_query(q_n, w_uk):
+    """q_n (S, H, d_n) x W_uk (H, d_n, r) -> (S, H, r): the key half of
+    ``W_kvb`` folded into the query.  (Head-major operands: the batch
+    dimension leads, which is the form every backend's dot takes.)"""
+    with jax.named_scope("mla_absorb_q"):
+        return jnp.einsum("hsn,hnr->hsr", q_n.transpose(1, 0, 2), w_uk,
+                          preferred_element_type=jnp.float32
+                          ).astype(q_n.dtype).transpose(1, 0, 2)
+
+
+def latent_attention(q_full, lat, i, positions, bound, cfg: ModelConfig):
+    """Causal attention of S queries over layer ``i``'s cached latents, in
+    the absorbed form.  ``q_full`` (S, H, r_kv + d_r) = [q_abs | q_r];
+    ``lat`` the stacked leaf (L, 1, n_ctx, r_kv + d_r), sliced in place at
+    (i, block); ``positions`` (S,) each query's own position, its causal
+    bound; ``bound`` (scalar) the position the read goes up to, >= every
+    position whose output is used (under ``vmap`` over lanes it must be
+    unbatched: the trip count stays a scalar).  Returns the weighted sum
+    of LATENTS, head-major (H, S, r_kv) float32, before ``W_uv``.  A query's result
+    does not depend on ``bound``: a block wholly beyond its position adds
+    probabilities of exactly 0 under a rescale of exactly 1
+    (``models/llama.py decode_attention`` has the argument)."""
+    S, H, W = q_full.shape
+    r, n_ctx = cfg.kv_lora_rank, cfg.n_ctx
+    T = min(LATENT_BLOCK, n_ctx)
+    scale = attn_scale(cfg)
+    i = jnp.asarray(i, jnp.int32)
+    n_blocks = jnp.minimum((jnp.asarray(bound, jnp.int32) + T) // T,
+                           -(-n_ctx // T))
+    qh = q_full.transpose(1, 0, 2)                       # (H, S, W)
+
+    def block(j, carry):
+        m, l, acc = carry
+        lo = j * T
+        at = jnp.minimum(lo, n_ctx - T)
+        lb = jax.lax.dynamic_slice(lat, (i, 0, at, 0), (1, 1, T, W))[0, 0]
+        with jax.named_scope("mla_scores"):
+            s = jnp.einsum("hsw,tw->hst", qh, lb,
+                           preferred_element_type=jnp.float32) * scale
+        key_pos = at + jnp.arange(T)
+        mask = (key_pos >= lo)[None, :] \
+            & (key_pos[None, :] <= positions[:, None])   # (S, T)
+        s = jnp.where(mask[None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        with jax.named_scope("mla_pv"):
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "hst,tr->hsr", p.astype(lb.dtype), lb[:, :r],
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    # a finite floor, not -inf: an all-masked block must leave the rescale 1
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((H, S), -1e30, jnp.float32),
+        jnp.zeros((H, S), jnp.float32),
+        jnp.zeros((H, S, r), jnp.float32)))
+    return acc / jnp.where(l > 0, l, 1.0)[..., None]     # (H, S, r)
+
+
+def expand_values(ctx_lat, w_uv, dtype):
+    """(H, S, r) weighted latents x W_uv (H, d_v, r) -> (S, H * d_v): the
+    value half of ``W_kvb`` applied after the weighted sum."""
+    with jax.named_scope("mla_expand_v"):
+        o = jnp.einsum("hsr,hvr->hsv", ctx_lat.astype(dtype), w_uv,
+                       preferred_element_type=jnp.float32)
+    return o.transpose(1, 0, 2).reshape(o.shape[1], -1).astype(dtype)
+
+
+def expanded_attention(q_n, q_r, rows, w_uk, w_uv, positions,
+                       cfg: ModelConfig):
+    """The same attention in the EXPANDED form, float32, over ``rows`` (T,
+    r_kv + d_r) cached latents at positions 0..T-1: every head's keys and
+    values for every position.  What the absorbed form must equal
+    (tests/test_mla.py); nothing serves through it."""
+    f32 = jnp.float32
+    r = cfg.kv_lora_rank
+    c = rows[:, :r].astype(f32)
+    k_r = rows[:, r:r + cfg.qk_rope_dim].astype(f32)
+    k_n = jnp.einsum("tr,hnr->thn", c, w_uk.astype(f32), precision=HI)
+    v = jnp.einsum("tr,hvr->thv", c, w_uv.astype(f32), precision=HI)
+    s = (jnp.einsum("shn,thn->hst", q_n.astype(f32), k_n, precision=HI)
+         + jnp.einsum("shd,td->hst", q_r.astype(f32), k_r, precision=HI)
+         ) * attn_scale(cfg)
+    mask = jnp.arange(rows.shape[0])[None, :] <= positions[:, None]
+    p = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("hst,thv->shv", p, v, precision=HI)
+    return o.reshape(o.shape[0], -1)
+
+
+# ---------------------------------------------------------------------------
+# the router
+# ---------------------------------------------------------------------------
+
+def route_grouped(hn, w_router, bias, cfg: ModelConfig):
+    """The router of a ``deepseek2`` routed layer, float32.  Scores
+    ``sigmoid`` (or ``softmax``) of ``W_r hn`` over ALL ``n_experts``; the
+    CHOICE on ``scores + bias``: a group's score is the sum of its two
+    largest, the ``n_groups_used`` best groups are kept, the
+    ``n_experts_used`` largest inside them picked; the weights are the
+    picked experts' UNBIASED scores, divided by their sum (+1e-20) where
+    ``norm_topk_prob``, times ``expert_weights_scale``.  hn (S, dim),
+    w_router (E, dim), bias (E,) -> (picks (S, k) int32 in [0, E), weights
+    (S, k) f32)."""
+    logits = jnp.einsum("sd,ed->se", hn.astype(jnp.float32), w_router,
+                        precision=HI)
+    scores = jax.nn.sigmoid(logits) if cfg.expert_gating == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    choice = scores + bias[None, :]
+    S, E = choice.shape
+    G = cfg.n_expert_groups
+    if G > 1 and cfg.n_groups_used < G:
+        grouped = choice.reshape(S, G, E // G)
+        gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)   # (S, G)
+        _, keep = jax.lax.top_k(gscore, cfg.n_groups_used)
+        kept = jnp.zeros((S, G), bool).at[
+            jnp.arange(S)[:, None], keep].set(True)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf
+                           ).reshape(S, E)
+    _, picks = jax.lax.top_k(choice, cfg.n_experts_used)
+    weights = jnp.take_along_axis(scores, picks, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return picks.astype(jnp.int32), weights * cfg.expert_weights_scale
+
+
+@custom_vmap
+def _sum_over_lanes(n):
+    """An int32 scalar that, under ``vmap`` over lanes, becomes its SUM over
+    them as an unbatched value: the counters of a step are the step's, the
+    same in every lane (as ``routed_experts``' rows per expert are)."""
+    return n
+
+
+@_sum_over_lanes.def_vmap
+def _sum_over_lanes_rule(axis_size, in_batched, n):
+    return _sum_over_lanes(jnp.sum(n) if in_batched[0] else n * axis_size), \
+        False
+
+
+def held_picks(picks, cfg: ModelConfig):
+    """The router's picks as indices into the HELD experts' planes:
+    ``pick - experts_first`` where this process holds the expert, else the
+    sentinel ``n_held`` ("no pick": ops/pallas/experts.py)."""
+    local = picks - cfg.experts_first
+    return jnp.where((local >= 0) & (local < cfg.n_held), local, cfg.n_held)
+
+
+# ---------------------------------------------------------------------------
+# the layers
+# ---------------------------------------------------------------------------
+
+def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, kv_bound):
+    """One layer's attention branch.  ``i``: the layer's number within its
+    kind's stack of weights, ``li``: its number in the whole stack (the
+    cache's).  Returns (h + branch, cache)."""
+    from .llama import rms_norm
+
+    S = h.shape[0]
+    H, r, d_n, d_r = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
+                      cfg.qk_rope_dim)
+
+    def lin(x, name):
+        with jax.named_scope(name):
+            return linear_at(x, layers[name], i)
+
+    hn = rms_norm(h, layers["attn_norm"][i], cfg.rms_eps)
+    c_q = rms_norm(lin(hn, "wq_a"), layers["q_a_norm"][i], cfg.rms_eps)
+    q = lin(c_q, "wq_b").reshape(S, H, d_n + d_r)
+    inv_freq = rope_inv_freq(cfg)
+    q_r = rope_pairs(q[..., d_n:], positions, inv_freq)
+    # the projection's rows are filled up to a kernel's N: the first
+    # r_kv + d_r are the file's
+    kv = lin(hn, "wkv_a")[:, :r + d_r]
+    c = rms_norm(kv[:, :r], layers["kv_a_norm"][i], cfg.rms_eps)
+    k_r = rope_pairs(kv[:, None, r:], positions, inv_freq)[:, 0]
+    fill = leaf_width(cfg) - r - d_r
+    rows = jnp.concatenate(
+        [c, k_r, jnp.zeros((S, fill), c.dtype)], axis=-1
+    ).astype(cache["lat"].dtype)
+    with jax.named_scope("kv_write"):
+        cache = {"lat": jax.lax.dynamic_update_slice(
+            cache["lat"], rows[None, None], (li, 0, pos_offset, 0))}
+    q_full = jnp.concatenate(
+        [absorb_query(q[..., :d_n], layers["w_uk"]["w"][i]), q_r,
+         jnp.zeros((S, H, fill), q_r.dtype)], axis=-1)
+    bound = pos_offset + S - 1 if kv_bound is None or S > 1 else kv_bound
+    with jax.named_scope("mla_attn"):
+        ctx = latent_attention(q_full, cache["lat"], li, positions, bound, cfg)
+    o = expand_values(ctx, layers["w_uv"]["w"][i], h.dtype)
+    return h + lin(o, "wo"), cache
+
+
+def _swiglu(hn, layers, i, gate, up, down):
+    def lin(x, name):
+        with jax.named_scope(name):
+            return linear_at(x, layers[name], i)
+
+    gated = jax.nn.silu(lin(hn, gate).astype(jnp.float32)).astype(hn.dtype)
+    return lin(gated * lin(hn, up), down)
+
+
+def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, kv_bound):
+    from .llama import rms_norm
+
+    h, cache = _attention(h, layers, i, i, cache, positions, pos_offset, cfg,
+                          kv_bound)
+    hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
+    return h + _swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache
+
+
+def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
+              kv_bound):
+    """Returns (h, cache, (rows each HELD expert took (n_held,), the
+    router's picks (S, k) over all experts, picks of live rows))."""
+    from ..ops.pallas.experts import routed_experts
+    from .llama import rms_norm
+
+    h, cache = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
+                          positions, pos_offset, cfg, kv_bound)
+    hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
+    with jax.named_scope("router"):
+        picks, weights = route_grouped(
+            hn, layers["w_router"][i], layers["router_bias"][i], cfg)
+    mine = held_picks(picks, cfg)
+    total = jnp.int32(picks.size)
+    if live is not None:
+        mine = jnp.where(live, mine, cfg.n_held)
+        total = jnp.where(live, total, 0)
+    total = _sum_over_lanes(total)
+    with jax.named_scope("experts"):
+        out, count = routed_experts(
+            hn, mine, weights, layers["w_gate_exps"], layers["w_up_exps"],
+            layers["w_down_exps"], i)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared_expert"):
+            out = out + _swiglu(hn, layers, i, "w_gate_sh", "w_up_sh",
+                                "w_down_sh")
+    return h + out, cache, (count, picks, total)
+
+
+def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
+            last_idx=None, return_all: bool = False, live=None,
+            with_stats: bool = False, with_picks: bool = False,
+            kv_bound=None):
+    """``models/llama.py forward`` for a ``deepseek2`` file: the leading
+    dense layers, then the routed ones, each kind a ``fori_loop`` over its
+    own stack of weights; the cache is one leaf over all layers.
+    ``with_stats`` / ``with_picks`` as there (the counter vector of
+    ``llama.expert_stats_len`` is over the HELD experts; the picks are the
+    router's, over all).  ``kv_bound``: a lane step's ``live_bound``."""
+    from .llama import expert_stats_len, rms_norm
+
+    S = tokens.shape[0]
+    n_moe = n_moe_layers(cfg)
+    for kind, n in ((DENSE, cfg.n_dense_layers), (MOE, n_moe)):
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                params["layers"].get(kind, {}))[0]:
+            if leaf.shape[0] != n:
+                raise ValueError(
+                    f"stacked leaf {kind}{jax.tree_util.keystr(path)} has "
+                    f"{leaf.shape[0]} layers but the file names {n} of "
+                    "that kind")
+    h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
+    positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
+
+    def dense_body(i, carry):
+        return dense_layer(carry[0], params["layers"][DENSE], jnp.int32(i),
+                           carry[1], positions, pos_offset, cfg, kv_bound)
+
+    def moe_body(i, carry):
+        h, cache, (count, picks, total) = moe_layer(
+            carry[0], params["layers"][MOE], jnp.int32(i), carry[1],
+            positions, pos_offset, cfg, live, kv_bound)
+        read = jnp.sum(count > 0, dtype=jnp.int32)
+        stats = carry[2] + jnp.concatenate(
+            [jnp.stack([jnp.int32(1), read]), count, total[None]])
+        return h, cache, stats, jax.lax.dynamic_update_slice(
+            carry[3], picks[None], (i, 0, 0))
+
+    carry = (h, cache)
+    if cfg.n_dense_layers:
+        carry = jax.lax.fori_loop(0, cfg.n_dense_layers, dense_body, carry)
+    routed = []
+    if n_moe:
+        h, new_cache, *routed = jax.lax.fori_loop(0, n_moe, moe_body, (
+            *carry, jnp.zeros(expert_stats_len(cfg), jnp.int32),
+            jnp.zeros((n_moe, S, cfg.n_experts_used), jnp.int32)))
+    else:
+        h, new_cache = carry
+    tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))
+                 if want)
+
+    def head(x):
+        hn = rms_norm(x, params["out_norm"], cfg.rms_eps)
+        with jax.named_scope("head"):
+            return linear(hn.astype(jnp.bfloat16), params["output"]
+                          ).astype(jnp.float32)
+
+    if return_all:
+        return (head(h), new_cache, *tail)
+    if last_idx is None:
+        last_idx = jnp.int32(S - 1)
+    h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
+    return (head(h_last)[0], new_cache, *tail)

@@ -123,7 +123,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     base_fmt = "int8" if fmt == "q4k" else fmt
     make = _LINEAR_MAKERS[base_fmt]
 
-    def _fused_names(names=None, layer_ids=None) -> dict[str, object]:
+    def _fused_names(names=None, layer_ids=None,
+                     rows: dict | None = None) -> dict[str, object]:
         """Linear positions that can serve a fused kernel, mapped to the
         ONE GGML type the whole (L, ...) stack will use — stacked scan
         params need a single layout per name.
@@ -134,7 +135,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         PROMOTED to the highest K-quant present: the minority layers are
         requantized onto the finer grid (16-element sub-block scales —
         strictly finer than the int8 per-row fallback this replaces) and
-        the whole name stays on the fused decode path at ≤0.88 B/weight."""
+        the whole name stays on the fused decode path at ≤0.88 B/weight.
+        ``rows``: {name: output rows it is LOADED with} where the loader
+        fills a matrix up with zero rows (:func:`padded_rows`)."""
         from ..gguf.constants import GGMLType
         from ..ops.pallas.qmatmul import q4k_compatible
 
@@ -158,10 +161,12 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         for n in names:
             ts = [gf[f"blk.{i}.{n}.weight"] for i in layer_ids]
             # an expert stack (E, out, in): the grouped kernels' two types
-            fits, allowed = (experts_compatible, [
-                t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
+            fits, allowed = (
+                lambda n_out, k_in: experts_compatible(n_out, padded_k(k_in)),
+                [t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
                 if n.endswith("_exps") else (fits_padded, fusable)
-            if not all(fits(*reversed(t.shape[:2])) for t in ts):
+            if not all(fits((rows or {}).get(n, t.shape[1]), t.shape[0])
+                       for t in ts):
                 continue
             types = {t.ggml_type for t in ts}
             if len(types) == 1:
@@ -178,7 +183,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             ok["output"] = t.ggml_type
         return ok
 
-    fused_names = _fused_names() if fmt == "q4k" else {}
+    # (a ``deepseek2`` file's layers fuse by kind, in ``latent_layers``:
+    # here its output head alone)
+    fused_names = _fused_names([] if cfg.kv_lora_rank else None) \
+        if fmt == "q4k" else {}
 
     import time as _time
 
@@ -188,7 +196,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     # output head) and stack (jnp.stack, and the wait for every transfer
     # still in flight), back to back on time.time()
 
-    def lin(name: str, fused_names: dict = fused_names) -> dict:
+    def lin(name: str, fused_names: dict = fused_names,
+            n_rows: int | None = None) -> dict:
+        """``n_rows``: output rows a FUSED layout is filled up to with
+        all-zero blocks (the caller slices the product)."""
         short = name.split(".")[-2] if name.startswith("blk.") else name.split(".")[0]
         if short in fused_names:
             from ..gguf.constants import GGMLType
@@ -214,15 +225,16 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     GGMLType.Q6_K: prep_q6k,
                     GGMLType.Q8_0: prep_q8_0}[target]
             raw = np.asarray(t.raw())
-            if k_pad != k_in:
+            n_fill = max(n_rows or n_out, n_out)
+            if k_pad != k_in or n_fill != n_out:
                 # a row's last K tile filled up with all-zero blocks (scale
                 # 0: every weight of them is 0), so that the file's own
                 # blocks serve the fused kernel (``linear`` pads the
-                # activations with zeros to match)
+                # activations with zeros to match); whole zero rows alike
                 raw = raw.reshape(n_out, -1)
-                raw = np.pad(raw, ((0, 0), (
+                raw = np.pad(raw, ((0, n_fill - n_out), (
                     0, raw.shape[1] * (k_pad - k_in) // k_in))).reshape(-1)
-            return prep(raw, n_out, k_pad)
+            return prep(raw, n_fill, k_pad)
         if on_device:
             w = _tensor_to_device(gf[name])
             if base_fmt == "int8":
@@ -233,7 +245,12 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     def norm(name: str):
         return jnp.asarray(gf[name].astype_f32(), dtype=jnp.float32)
 
-    def experts(name: str) -> dict:
+    def as_bf16(t) -> jax.Array:
+        """A tensor dequantized once and kept bf16 (never requantized)."""
+        return _tensor_to_device(t, jnp.bfloat16) if on_device \
+            else jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)
+
+    def experts(name: str, fused_names: dict = fused_names) -> dict:
         """A 3-D expert tensor (E, out, in): fused planes with a leading
         expert axis where its name fuses, else dequantized bf16."""
         t = gf[name]
@@ -245,12 +262,15 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             k_in, n_out, n_exp = t.shape
             raw = np.asarray(t.raw()) if t.ggml_type == target \
                 else quantize(t.astype_f32(), target)   # K-quant promotion
-            w = prep_experts(raw, n_exp, n_out, k_in, target)
+            k_pad = padded_k(k_in)
+            if k_pad != k_in:   # zero blocks fill a row's last K tile
+                raw = raw.reshape(n_exp * n_out, -1)
+                raw = np.pad(raw, ((0, 0), (
+                    0, raw.shape[1] * (k_pad - k_in) // k_in))).reshape(-1)
+            w = prep_experts(raw, n_exp, n_out, k_pad, target)
             if w is not None:
                 return w
-        if on_device:
-            return {"w": _tensor_to_device(t, jnp.bfloat16)}
-        return {"w": jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)}
+        return {"w": as_bf16(t)}
 
     # LFKT_LOAD_OVERLAP=1: enqueue each layer's host→device transfer the
     # moment its planes are packed, so the (async) transfers stream while
@@ -292,9 +312,67 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 out[kind].append(layer)
         return out
 
+    def latent_layers() -> dict:
+        """A ``deepseek2`` file (models/mla.py): {"dense": the leading
+        layers, "moe": the routed ones}.  Nothing is requantized: a matrix
+        no fused kernel takes (``attn_q_b``, K = r_q; ``attn_kv_b``, which
+        the absorbed form wants per head, W_uk and W_uv) is served bf16
+        under ``q4k``, never int8."""
+        from .mla import DENSE, MOE, lat_width
+
+        attn = {"wq_a": "attn_q_a", "wq_b": "attn_q_b",
+                "wkv_a": "attn_kv_a_mqa", "wo": "attn_output"}
+        ffn = {DENSE: {"w_gate": "ffn_gate", "w_up": "ffn_up",
+                       "w_down": "ffn_down"},
+               MOE: {"w_gate_sh": "ffn_gate_shexp", "w_up_sh": "ffn_up_shexp",
+                     "w_down_sh": "ffn_down_shexp"}}
+        # the latent projection's r_kv + d_r rows, filled up to a kernel's N
+        kv_rows = -(-lat_width(cfg) // 128) * 128
+        H, d_n = cfg.n_heads, cfg.qk_nope_dim
+
+        out = {}
+        for kind, ids in ((DENSE, range(cfg.n_dense_layers)),
+                          (MOE, range(cfg.n_dense_layers, cfg.n_layers))):
+            mats = {**attn, **ffn[kind]}
+            exps = ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"] \
+                if kind == MOE and fused_experts else []
+            fused = _fused_names(list(mats.values()) + exps, ids,
+                                 {"attn_kv_a_mqa": kv_rows}) \
+                if fmt == "q4k" and len(ids) else {}
+            out[kind] = []
+            for i in ids:
+                p = f"blk.{i}."
+                layer = {}
+                for key, name in mats.items():
+                    if fmt == "q4k" and name not in fused:
+                        layer[key] = {"w": as_bf16(gf[p + name + ".weight"])}
+                    else:
+                        layer[key] = lin(p + name + ".weight", fused,
+                                         kv_rows if key == "wkv_a" else None)
+                kv_b = as_bf16(gf[p + "attn_kv_b.weight"]).reshape(
+                    H, d_n + cfg.v_head_dim, cfg.kv_lora_rank)
+                layer["w_uk"] = {"w": kv_b[:, :d_n]}
+                layer["w_uv"] = {"w": kv_b[:, d_n:]}
+                for key, name in (("attn_norm", "attn_norm"),
+                                  ("q_a_norm", "attn_q_a_norm"),
+                                  ("kv_a_norm", "attn_kv_a_norm"),
+                                  ("ffn_norm", "ffn_norm")):
+                    layer[key] = norm(p + name + ".weight")
+                if kind == MOE:
+                    layer["w_router"] = norm(p + "ffn_gate_inp.weight")
+                    layer["router_bias"] = norm(p + "exp_probs_b.bias")
+                    for key in ("gate", "up", "down"):
+                        layer[f"w_{key}_exps"] = experts(
+                            p + f"ffn_{key}_exps.weight", fused)
+                if overlap:
+                    layer = jax.tree.map(jax.device_put, layer)
+                out[kind].append(layer)
+        return {k: v for k, v in out.items() if v}
+
     layers = []
     t_prep = _time.time()
-    by_kind = kinds_layers() if cfg.mixers else None
+    by_kind = kinds_layers() if cfg.mixers else \
+        latent_layers() if cfg.kv_lora_rank else None
     for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."
         layer = {
@@ -325,18 +403,13 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         logger.debug("loaded layer %d/%d", i + 1, cfg.n_layers)
     t_head = _time.time()
 
-    if on_device:
-        emb = _tensor_to_device(gf["token_embd.weight"], jnp.bfloat16)
-    else:
-        emb = jnp.asarray(gf["token_embd.weight"].astype_f32(), dtype=jnp.bfloat16)
+    emb = as_bf16(gf["token_embd.weight"])
     if cfg.tie_embeddings or "output.weight" not in gf.tensors:
         output = {"w": emb}
     elif (cfg.fp32_residual or cfg.fp32_logits) \
             and gf["output.weight"].ggml_type.name in ("F32", "F16", "BF16"):
         # float32 logits from a float head: bf16 inputs, nothing requantized
-        t = gf["output.weight"]
-        output = {"w": _tensor_to_device(t, jnp.bfloat16) if on_device
-                  else jnp.asarray(t.astype_f32(), dtype=jnp.bfloat16)}
+        output = {"w": as_bf16(gf["output.weight"])}
     else:
         output = lin("output.weight")
     t_stack = _time.time()

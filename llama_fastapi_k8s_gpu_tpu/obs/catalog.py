@@ -276,7 +276,8 @@ METRICS: dict[str, Metric] = _register(
     # devtime registry owns the count; /metrics copies it (same convention
     # as the tracer counters above)
     Metric("xla_compiles_total", GAUGE,
-           "jit compile events per program (devtime snapshot)",
+           "jit compile events per program: calls that grew the jit cache "
+           "and handed a lowering to the compiler (devtime snapshot)",
            labels=("program",)),
     Metric("jit_dispatches_total", GAUGE,
            "host dispatches per jit program (devtime snapshot)",
@@ -310,7 +311,15 @@ METRICS: dict[str, Metric] = _register(
     Metric("expert_picks_total", GAUGE,
            "(token, pick) rows each expert took in decode chunks, "
            "cumulative; the largest over their sum is the most-loaded "
-           "expert's share", labels=("expert",)),
+           "expert's share (over the experts HELD here, numbered from "
+           "the first held)", labels=("expert",)),
+    Metric("expert_picks_routed_total", GAUGE,
+           "picks the decode chunks' routers made for live rows over ALL "
+           "the router's experts, cumulative (picks_total)"),
+    Metric("expert_picks_held_total", GAUGE,
+           "of those, the picks of an expert held here (picks_held): the "
+           "rest left the chip in the deployment the file stands for; "
+           "equal to expert_picks_routed_total where every expert is held"),
     # -- decode attention's read of the KV ring (models/llama.py) ----------
     Metric("ring_slots_read_total", GAUGE,
            "KV ring slots the decode steps' attention covered (whole blocks "
@@ -361,6 +370,16 @@ METRICS: dict[str, Metric] = _register(
            "windows turned into their chunk summaries: whole windows of a "
            "prompt at its prefill, and a decode step that writes a "
            "window's last position; cumulative"),
+    # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
+    Metric("latent_positions_read_total", GAUGE,
+           "cached latent rows the decode steps' attention covered (whole "
+           "blocks up to the largest LIVE lane's position, summed over live "
+           "lanes and steps; a row is read once for all heads), cumulative"),
+    Metric("latent_positions_live_total", GAUGE,
+           "cached latent rows at or below the decoding positions in the "
+           "same steps (what the attention needed); over "
+           "latent_positions_read_total = the share of the read that was "
+           "live"),
     # -- the state + ring cache (models/sala.py; ``minicpm-sala``) ----------
     Metric("lin_state_updates_total", GAUGE,
            "updates of a linear-attention layer's state in decode steps: "

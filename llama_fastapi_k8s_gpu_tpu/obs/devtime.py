@@ -14,7 +14,9 @@ Two registration forms, one registry:
 
 - :func:`timed_jit` wraps a HOST jit entry point.  Every call increments
   the program's dispatch count; a call that grew the underlying jit cache
-  (``fn._cache_size()``) is a compile event: the program records the static-shape
+  (``fn._cache_size()``) AND handed a lowering to the compiler (JAX's own
+  ``backend_compile`` event fired on this thread inside the call) is a
+  compile event: the program records the static-shape
   signature and the call's wall time (first-dispatch wall ≈ compile wall,
   the standard attribution), and the event is exported to the
   ``xla_compiles_total`` / ``xla_compile_seconds`` /
@@ -47,6 +49,7 @@ CPU test long before it burns a chip session.
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -391,6 +394,34 @@ class DevtimeRegistry:
                 "programs": programs}
 
 
+class _Handed(threading.local):
+    """This thread's count of lowerings handed to the compiler (JAX's
+    ``backend_compile`` duration event: fired around the compile or the
+    persistent cache's answer, on the thread that called the jit)."""
+    n = 0
+
+
+_HANDED = _Handed()
+
+
+def _on_jax_event(event, *_a, **_k) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _HANDED.n += 1
+
+
+@functools.cache
+def _listen() -> None:
+    """Hear JAX's compile events, once a process.  The jit cache alone
+    over-counts: it keys on every argument's sharding OBJECT, and on one
+    device ``P()``, ``P(None, None)`` and ``P('dp', None)`` are one
+    placement under three names (an output takes the name of whichever
+    input it matches), so a state that passes through two programs grows
+    each one's cache by entries that lower and compile nothing."""
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+
+
 class _TimedJit:
     """The per-entry-point wrapper ``timed_jit`` returns.  Call-compatible
     with the wrapped jit function; donation, static args and sharding all
@@ -406,6 +437,7 @@ class _TimedJit:
         self.__wrapped__ = fn
         # jax's PjitFunction exposes its compiled-variant count
         self._probe = fn._cache_size
+        _listen()
 
     def __call__(self, *args, **kwargs):
         reg = self._reg
@@ -413,10 +445,11 @@ class _TimedJit:
             return self._fn(*args, **kwargs)   # nothing (poisoned-reg test)
         probe = self._probe
         before = probe()
+        handed = _HANDED.n
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         dt = time.perf_counter() - t0
-        if probe() > before:
+        if probe() > before and _HANDED.n > handed:
             reg.record_compile(self._name, _signature(args, kwargs), dt)
         reg.record_dispatch(self._name)
         return out

@@ -225,8 +225,11 @@ def _unfold_rows(out: jax.Array, f: int, group: int) -> jax.Array:
 
 
 def _activations(x: jax.Array, fam: _Family) -> jax.Array:
-    """Rows -> the kernel's permuted, augmented bf16 rows."""
-    return fam.augment(fam.permute(x).astype(jnp.bfloat16))
+    """Rows -> the kernel's permuted, augmented bf16 rows (zeros first
+    where the planes' K is ``ops.linear.padded_k`` of the rows')."""
+    from ..linear import _pad_k
+
+    return fam.augment(fam.permute(_pad_k(x)).astype(jnp.bfloat16))
 
 
 # ---------------------------------------------------------------------------

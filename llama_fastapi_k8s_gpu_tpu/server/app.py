@@ -1617,6 +1617,8 @@ def create_app(engine=None, settings: Settings | None = None,
             m.set_gauge("experts_read_total", snap["experts_read"])
             for e, n in enumerate(snap["picks"]):
                 m.set_gauge("expert_picks_total", n, expert=str(e))
+            m.set_gauge("expert_picks_routed_total", snap["picks_total"])
+            m.set_gauge("expert_picks_held_total", snap["picks_held"])
         # lfkt-mem: live HBM attribution gauges (obs/memledger.py) — one
         # series per (component, model), residual = ground truth minus the
         # attributed sum, headroom only where the backend reports limits.

@@ -8,6 +8,8 @@ the real reader/dequant/tokenizer/model path.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from .gguf import GGMLType, GGUFWriter
@@ -182,6 +184,146 @@ def write_tiny_olmoe_gguf(path: str, cfg: ModelConfig = TINY_OLMOE_CFG,
         t(p + "ffn_gate_exps.weight", (E, F, D), mix["ffn_gate_exps"])
         t(p + "ffn_up_exps.weight", (E, F, D), mix["ffn_up_exps"])
         t(p + "ffn_down_exps.weight", (E, D, F), mix["ffn_down_exps"])
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size, D), mix["output"])
+    w.write()
+    return cfg
+
+
+#: a tiny ``deepseek2`` file that keeps every ratio of the published block
+#: (models/mla.py): 3 groups of 4 experts, 2 groups used, top-3, one shared
+#: expert, 1 leading dense layer + 2 routed, d_nope / d_rope / d_v distinct,
+#: YaRN on
+TINY_MLA_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=3, n_heads=4, n_kv_heads=4,
+    ffn_dim=512, n_ctx=128, rope_theta=10000.0, rms_eps=1e-6,
+    q_lora_rank=64, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+    v_head_dim=24, rope_yarn_factor=4.0, rope_yarn_orig_ctx=32,
+    attn_mscale=(0.1 * math.log(4.0) + 1.0) ** 2,
+    n_dense_layers=1, expert_ffn_dim=256, n_shared_experts=1,
+    n_experts=12, n_experts_used=3, norm_topk_prob=True,
+    expert_gating="sigmoid", n_expert_groups=3, n_groups_used=2,
+    expert_weights_scale=2.5,
+)
+
+#: the Q4_K_M mix on a ``deepseek2`` file, as the benchmark writes it (the
+#: narrow-K matrices of the tiny file Q8_0: a K-quant block is 256 wide)
+MLA_Q4KM_MIX = {
+    "attn_q_a": GGMLType.Q4_K, "attn_q_b": GGMLType.Q8_0,
+    "attn_kv_a_mqa": GGMLType.Q4_K, "attn_kv_b": GGMLType.Q8_0,
+    "attn_output": GGMLType.Q8_0,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+    "ffn_gate_exps": GGMLType.Q4_K, "ffn_up_exps": GGMLType.Q4_K,
+    "ffn_down_exps": GGMLType.Q6_K,
+    "ffn_gate_shexp": GGMLType.Q4_K, "ffn_up_shexp": GGMLType.Q4_K,
+    "ffn_down_shexp": GGMLType.Q6_K, "output": GGMLType.Q6_K,
+}
+
+
+def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
+                        seed: int = 0, mix: dict | None = None,
+                        held: tuple[int, int] | None = None,
+                        router_scale: float = 4.0,
+                        bias_scale: float = 0.2) -> ModelConfig:
+    """Write a random-weight ``deepseek2`` GGUF (latent attention, leading
+    dense layers, a grouped sigmoid router with its choice bias, routed +
+    shared experts) with the byte-level tokenizer of
+    :func:`write_tiny_llama_gguf`.  ``held`` = (first, count): the 3-D
+    expert tensors hold those experts alone, of the SAME weights a file
+    with all of them has (the share of an expert-parallel layer), and the
+    file says so under ``expert_held_first`` / ``expert_held_count``.
+    ``bias_scale``: the choice bias's spread, large enough that dropping it
+    changes picks."""
+    tokens, types = byte_vocab_with_specials()
+    first, count = held or (0, 0)
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens),
+                         "experts_first": first, "experts_held": count})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**MLA_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    arch = "deepseek2"
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-mla-test",
+                          arch=arch)
+    for key, value in (
+            ("leading_dense_block_count", cfg.n_dense_layers),
+            ("expert_feed_forward_length", cfg.expert_ffn_dim),
+            ("expert_shared_count", cfg.n_shared_experts),
+            ("expert_weights_scale", float(cfg.expert_weights_scale)),
+            ("expert_weights_norm", bool(cfg.norm_topk_prob)),
+            ("expert_gating_func",
+             {"softmax": 1, "sigmoid": 2}[cfg.expert_gating]),
+            ("expert_group_count", cfg.n_expert_groups),
+            ("expert_group_used_count", cfg.n_groups_used),
+            ("attention.q_lora_rank", cfg.q_lora_rank),
+            ("attention.kv_lora_rank", cfg.kv_lora_rank),
+            ("attention.key_length", cfg.qk_nope_dim + cfg.qk_rope_dim),
+            ("attention.value_length", cfg.v_head_dim),
+            ("rope.dimension_count", cfg.qk_rope_dim)):
+        w.add_metadata(f"{arch}.{key}", value)
+    if cfg.rope_yarn_factor:
+        w.add_metadata(f"{arch}.rope.scaling.type", "yarn")
+        w.add_metadata(f"{arch}.rope.scaling.factor",
+                       float(cfg.rope_yarn_factor))
+        w.add_metadata(f"{arch}.rope.scaling.original_context_length",
+                       cfg.rope_yarn_orig_ctx)
+        w.add_metadata(
+            f"{arch}.rope.scaling.yarn_log_multiplier",
+            float((math.sqrt(cfg.attn_mscale) - 1.0)
+                  / math.log(cfg.rope_yarn_factor)))
+        w.add_metadata(f"{arch}.rope.scaling.yarn_beta_fast",
+                       float(cfg.rope_yarn_beta_fast))
+        w.add_metadata(f"{arch}.rope.scaling.yarn_beta_slow",
+                       float(cfg.rope_yarn_beta_slow))
+    if held:
+        w.add_metadata(f"{arch}.expert_held_first", first)
+        w.add_metadata(f"{arch}.expert_held_count", count)
+    D, H, E = cfg.dim, cfg.n_heads, cfg.n_experts
+    r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
+    d_n, d_r, d_v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    F, Fe = cfg.ffn_dim, cfg.expert_ffn_dim
+
+    def t(name, shape, gtype, mul=1.0, rows=None):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x if rows is None else x[rows], gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    mine = slice(first, first + count) if held else None
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight", D)
+        t(p + "attn_q_a.weight", (r_q, D), mix["attn_q_a"])
+        norm(p + "attn_q_a_norm.weight", r_q)
+        t(p + "attn_q_b.weight", (H * (d_n + d_r), r_q), mix["attn_q_b"],
+          (D / r_q) ** 0.5)
+        t(p + "attn_kv_a_mqa.weight", (r_kv + d_r, D), mix["attn_kv_a_mqa"])
+        norm(p + "attn_kv_a_norm.weight", r_kv)
+        t(p + "attn_kv_b.weight", (H * (d_n + d_v), r_kv), mix["attn_kv_b"],
+          (D / r_kv) ** 0.5)
+        t(p + "attn_output.weight", (D, H * d_v), mix["attn_output"])
+        norm(p + "ffn_norm.weight", D)
+        if i < cfg.n_dense_layers:
+            t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+            t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+            t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+            continue
+        t(p + "ffn_gate_inp.weight", (E, D), GGMLType.F32, router_scale)
+        w.add_tensor(p + "exp_probs_b.bias", bias_scale * rng.standard_normal(
+            E).astype(np.float32), GGMLType.F32)
+        t(p + "ffn_gate_exps.weight", (E, Fe, D), mix["ffn_gate_exps"],
+          rows=mine)
+        t(p + "ffn_up_exps.weight", (E, Fe, D), mix["ffn_up_exps"], rows=mine)
+        t(p + "ffn_down_exps.weight", (E, D, Fe), mix["ffn_down_exps"],
+          rows=mine)
+        sh = Fe * cfg.n_shared_experts
+        t(p + "ffn_gate_shexp.weight", (sh, D), mix["ffn_gate_shexp"])
+        t(p + "ffn_up_shexp.weight", (sh, D), mix["ffn_up_shexp"])
+        t(p + "ffn_down_shexp.weight", (D, sh), mix["ffn_down_shexp"])
     norm("output_norm.weight", D)
     t("output.weight", (cfg.vocab_size, D), mix["output"])
     w.write()

@@ -681,3 +681,123 @@ def test_flash_attention_on_a_tpu_mesh_is_refused(topo):
                           placed((8, 1024, 128), bf16, "tp", None, None),
                           placed((8, 1024, 128), bf16, "tp", None, None),
                           placed((), i32)).compile()
+
+
+# BENCHMARK.json's gigachat configuration at its published widths (hidden
+# 7168 filled up to K 8192, 64 heads, latents 1536 / 512 + 64, 1 dense + 6
+# routed layers holding 32 of 256 experts), n_ctx 16384: (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("gigachat-serial", 0),
+                                        ("gigachat-16lane", 16)])
+def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
+                                                       name, lanes):
+    """The decode chunk and the prefill slice of the ``deepseek2`` stack
+    (models/mla.py) compile for the chip: the fused planes at K 8192 (the
+    experts' gate and up among them: the grouped kernels are in the program
+    under their own names), the latent projection at 640 rows, the absorbed
+    attention's loop over blocks of the lanes' stacked latent leaf.  The
+    compiler has put NO copy or transpose of the latent leaf in the decode
+    chunk, and a slice's scratch stays under half a GB."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    D, DP, V, H = 7168, 8192, 128256, 64
+    r_q, r_kv, d_n, d_r, d_v, F, Fe, E = 1536, 512, 128, 64, 192, 18432, \
+        2048, 32
+    cfg = ModelConfig(
+        vocab_size=V, dim=D, n_layers=7, n_heads=H, n_kv_heads=H, ffn_dim=F,
+        n_ctx=16384, rope_theta=1e5, rms_eps=1e-6, attn_impl="xla",
+        q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_n, qk_rope_dim=d_r,
+        v_head_dim=d_v, rope_yarn_factor=64.0, rope_yarn_orig_ctx=4096,
+        attn_mscale=2.0048, n_dense_layers=1, expert_ffn_dim=Fe,
+        n_shared_experts=1, n_experts=256, n_experts_used=8,
+        norm_topk_prob=True, expert_gating="sigmoid", n_expert_groups=8,
+        n_groups_used=4, expert_weights_scale=2.5, experts_first=0,
+        experts_held=E)
+
+    def exps(fmt, n, k, L):
+        kt = k // 2048
+        if fmt == "q4k":
+            return {"qs": S(L, E, n, k // 2, dtype=i8),
+                    "sm": S(L, E, kt, n, 128)}
+        return {"q4": S(L, E, n, k // 2, dtype=i8),
+                "q2": S(L, E, n, k // 4, dtype=i8),
+                "sm6": S(L, E, kt, n, 128)}
+
+    def attn(L):
+        return {"attn_norm": S(L, D, dtype=f32), "ffn_norm": S(L, D, dtype=f32),
+                "q_a_norm": S(L, r_q, dtype=f32),
+                "kv_a_norm": S(L, r_kv, dtype=f32),
+                "wq_a": _planes("q4k", r_q, DP, L),
+                "wq_b": {"w": S(L, H * (d_n + d_r), r_q)},
+                "wkv_a": _planes("q4k", 640, DP, L),
+                "wo": _planes("q4k", D, H * d_v, L),
+                "w_uk": {"w": S(L, H, d_n, r_kv)},
+                "w_uv": {"w": S(L, H, d_v, r_kv)}}
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": _planes("q6k", V, DP),
+        "layers": {
+            "dense": {**attn(1), "w_gate": _planes("q4k", F, DP, 1),
+                      "w_up": _planes("q4k", F, DP, 1),
+                      "w_down": _planes("q6k", D, F, 1)},
+            "moe": {**attn(6), "w_router": S(6, 256, D, dtype=f32),
+                    "router_bias": S(6, 256, dtype=f32),
+                    "w_gate_sh": _planes("q4k", Fe, DP, 6),
+                    "w_up_sh": _planes("q4k", Fe, DP, 6),
+                    "w_down_sh": _planes("q6k", D, Fe, 6),
+                    "w_gate_exps": exps("q4k", Fe, DP, 6),
+                    "w_up_exps": exps("q4k", Fe, DP, 6),
+                    "w_down_exps": exps("q6k", D, Fe, 6)}}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "q4k_expert_matmul_fewrow" in text
+    assert "q6k_expert_matmul_fewrow" in text
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*16384,\d+\]\S* (copy|transpose)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    if not lanes:       # the admission slice into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        sliced = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile()
+        assert "q4k_expert_matmul_manyrow" in sliced.as_text()
+        assert sliced.memory_analysis().temp_size_in_bytes < 1024 * 2 ** 20

@@ -657,6 +657,31 @@ def test_lane_prefix_claim_bookkeeping_unit(lp_engine):
         lp_engine._lane_claims[:] = saved
 
 
+def test_lane_prefix_a_live_lanes_prompt_is_a_claim(lp_engine):
+    """Two requests behind one system line, sent together: the second is
+    admitted while the first still decodes, and rides the first's prompt
+    rows (a live lane writes from its prompt's end on, never below)."""
+    before = lp_engine.scheduler_stats()["lane_prefix_hits"]
+    saved = list(lp_engine._lane_claims)
+    lp_engine._lane_claims[:] = [None] * len(saved)      # a cold engine
+    long = "Answer with care and at length. " * 3
+    a = lp_engine.submit(_lp_multiturn() + [
+        {"role": "assistant", "content": long},
+        {"role": "user", "content": "first caller"}],
+        temperature=0.0, max_tokens=16)
+    b = lp_engine.submit(_lp_multiturn() + [
+        {"role": "assistant", "content": long},
+        {"role": "user", "content": "second caller, other words"}],
+        temperature=0.0, max_tokens=4)
+    out_b, out_a = b.result(timeout=300), a.result(timeout=300)
+    assert out_a["lfkt_timings"]["prefix_reused_tokens"] == 0
+    reused = out_b["lfkt_timings"]["prefix_reused_tokens"]
+    assert reused >= lp_engine._prefill_chunk
+    assert reused % lp_engine._prefill_chunk == 0
+    assert lp_engine.scheduler_stats()["lane_prefix_hits"] == before + 1
+    assert out_b["choices"][0]["message"]["content"]
+
+
 def test_lane_prefix_reuse_on_sharded_mesh(tmp_path):
     """The lane→scratch snapshot gather must compose with GSPMD when the
     batched cache is dp-sharded (the v5e-4 serving config)."""

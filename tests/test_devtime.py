@@ -86,6 +86,26 @@ def test_timed_jit_refuses_a_callable_that_is_not_jitted(reg):
         reg.timed_jit("plain", lambda x: x)
 
 
+def test_another_name_for_the_same_placement_is_no_compile(reg):
+    """The jit cache keys on the sharding OBJECT of every argument; on one
+    device ``P()`` and ``P(None, None)`` are one placement, and the second
+    entry lowers and compiles nothing: a dispatch, not a compile."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    f = reg.timed_jit("named", jax.jit(lambda x: x * 2))
+    x = jnp.ones((4, 4))
+    f(jax.device_put(x, NamedSharding(mesh, P())))
+    grown = f._fn._cache_size()
+    f(jax.device_put(x, NamedSharding(mesh, P(None, None))))
+    assert f._fn._cache_size() == grown + 1           # the cache grew,
+    c = reg.counters()["named"]
+    assert c["compiles"] == 1 and c["dispatches"] == 2   # nothing compiled
+    f(jnp.ones((8, 4)))                               # a new shape does
+    assert reg.counters()["named"]["compiles"] == 2
+
+
 def test_warm_dispatch_skips_signature_walk(reg, monkeypatch):
     """A dispatch that did not grow the jit cache must never pay the
     O(leaves) signature walk."""

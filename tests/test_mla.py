@@ -1,0 +1,742 @@
+"""The ``deepseek2`` block (models/mla.py) at a tiny size on the CPU, against
+the plain float32 reference (benchmarks/reference_mla.py): latent attention
+over the fourth cache kind (``latent-ring``), leading dense layers, the
+grouped sigmoid router with its choice bias, routed + shared experts, and
+an expert layer that is told which experts it holds.
+
+The tiny file (``testing.TINY_MLA_CFG``) keeps every ratio of the published
+block: 3 groups of 4 experts, 2 groups used, top-3, one shared expert, 1
+dense + 2 routed layers, d_nope 16 / d_rope 8 / d_v 24, YaRN on.
+
+LIMIT: the program (bf16 inputs to every product, float32 sums, a bf16
+stream and cache) against the float32 reference on the program's OWN picks
+reads 0.5-1.5 % of the logits' norm over blocks of 16 positions on three
+layers; every control below (another function: a dropped bias, shared
+expert, YaRN or routing scale) reads 8 % or more.  PICKS: rows whose set of
+picked experts differs from the reference's own: near-ties that bf16
+rounding orders the other way; the controls differ in tens of rows.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+
+LIMIT = 4e-2
+PICKS = 12           # rows of 2 layers x N_SEQ whose picks may differ
+N_CTX = 128
+SLICE = 16
+N_PROMPT = 40
+N_SEQ = 72
+
+
+@pytest.fixture(scope="module")
+def ref():
+    sys.path.insert(0, BENCH)
+    try:
+        import reference_mla
+        yield reference_mla
+    finally:
+        sys.path.remove(BENCH)
+
+
+@pytest.fixture(scope="module")
+def gguf_path(tmp_path_factory):
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_mla_gguf
+
+    path = str(tmp_path_factory.mktemp("mla") / "tiny.gguf")
+    write_tiny_mla_gguf(path, seed=3)
+    return path
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(5).integers(4, 260, size=N_SEQ)
+
+
+@pytest.fixture(scope="module")
+def model(ref, gguf_path):
+    return ref.open_model(gguf_path)
+
+
+def load(path, fmt="bf16", n_ctx=N_CTX):
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.params import load_params
+
+    gf = GGUFFile(path)
+    cfg = ModelConfig.from_gguf(gf, n_ctx=n_ctx)
+    return load_params(gf, cfg, fmt=fmt), cfg
+
+
+@pytest.fixture(scope="module")
+def loaded(gguf_path):
+    return load(gguf_path)
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def worst(got, want, step=16):
+    """The largest ``rel`` over blocks of ``step`` positions."""
+    return max(rel(got[a:a + step], want[a:a + step])
+               for a in range(0, len(got), step))
+
+
+def rows_that_differ(mine, theirs):
+    """Rows (layer, position) whose SET of picked experts differs."""
+    return int(np.sum(np.any(np.sort(mine, -1) != np.sort(theirs, -1), -1)))
+
+
+def programs(cfg):
+    """A prefill pass (``n`` real positions of the slice), one decode step,
+    one step of lanes (the body of ``parallel/batched.py``'s vmapped step,
+    its bound included); each returns the routers' picks too."""
+    import jax
+
+    from llama_fastapi_k8s_gpu_tpu.models.llama import forward
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import step_bound
+
+    @jax.jit
+    def pass_(params, tokens, off, n, cache):
+        return forward(params, cfg, tokens, off, cache, last_idx=n - 1,
+                       return_all=True, with_picks=True)
+
+    @jax.jit
+    def step(params, token, pos, cache):
+        return forward(params, cfg, token[None], pos, cache, with_picks=True)
+
+    @jax.jit
+    def lane_step(params, tokens, poss, caches, live):
+        bound = step_bound(cfg, poss, live)
+        return jax.vmap(lambda t, p, c, lv: forward(
+            params, cfg, t[None], p, c, live=lv, kv_bound=bound,
+            with_picks=True, with_stats=True))(tokens, poss, caches, live)
+    return pass_, step, lane_step
+
+
+def prefill(params, cfg, seq, n, size=SLICE, pass_=None, cache=None, start=0):
+    """Logits and picks of positions [start, n), and the cache, in passes of
+    ``size`` (the last one padded, as a bucket is)."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+
+    pass_ = pass_ or programs(cfg)[0]
+    cache = init_cache(cfg) if cache is None else cache
+    out, picks = [], []
+    for off in range(start, n, size):
+        part = np.full(size, 9, np.int32)
+        real = seq[off:min(off + size, n)]
+        part[:len(real)] = real
+        lg, cache, pk = pass_(params, jnp.asarray(part), jnp.int32(off),
+                              jnp.int32(len(real)), cache)
+        out.append(np.asarray(lg)[:len(real)])
+        picks.append(np.asarray(pk)[:, :len(real)])
+    return np.concatenate(out), np.concatenate(picks, axis=1), cache
+
+
+@pytest.fixture(scope="module")
+def served(loaded, tokens):
+    """The serial programs over the whole sequence, slices then steps
+    through the cache: (logits (S, V), picks (L_moe, S, k), the cache)."""
+    import jax.numpy as jnp
+
+    params, cfg = loaded
+    pass_, step, _ = programs(cfg)
+    logits, picks, cache = prefill(params, cfg, tokens, N_PROMPT, pass_=pass_)
+    dec, dpicks = [], []
+    for t in range(N_PROMPT, N_SEQ):
+        lg, cache, pk = step(params, jnp.int32(tokens[t]), jnp.int32(t),
+                             cache)
+        dec.append(np.asarray(lg))
+        dpicks.append(np.asarray(pk))
+    return (np.concatenate([logits, np.stack(dec)]),
+            np.concatenate([picks] + dpicks, axis=1), cache)
+
+
+@pytest.fixture(scope="module")
+def own(ref, model, tokens):
+    """The reference on its own picks: (logits, picks (L_moe, S, k))."""
+    logits, routes = ref.forward(*model, tokens)
+    return np.asarray(logits), np.stack([p for _, p in routes])
+
+
+# ---------------------------------------------------------------------------
+# the program against the reference
+# ---------------------------------------------------------------------------
+
+def test_slices_then_decode_through_the_latent_cache(ref, model, tokens,
+                                                     served, own):
+    logits, picks, _ = served
+    assert rows_that_differ(picks, own[1]) <= PICKS
+    want = np.asarray(ref.forward(*model, tokens, use_picks=picks)[0])
+    print("read", worst(logits[:N_PROMPT], want[:N_PROMPT]),
+          worst(logits[N_PROMPT:], want[N_PROMPT:]))
+    assert worst(logits[:N_PROMPT], want[:N_PROMPT]) < LIMIT
+    assert worst(logits[N_PROMPT:], want[N_PROMPT:]) < LIMIT
+
+
+@pytest.mark.parametrize("control", ["no_bias", "no_shared", "no_yarn",
+                                     "no_scale"])
+def test_another_function_fails_the_limit(ref, model, tokens, served, own,
+                                          control):
+    """Each control is a different function: its distance from the program
+    is past the limit, or (the bias, which moves the CHOICE alone) its own
+    picks differ from the program's in many rows."""
+    logits, picks, _ = served
+    got, routes = ref.forward(*model, tokens, **{control: True})
+    if control == "no_bias":
+        theirs = np.stack([p for _, p in routes])
+        assert rows_that_differ(picks, theirs) > 3 * PICKS
+    assert worst(logits, np.asarray(got)) > LIMIT
+
+
+def test_absorbed_is_expanded():
+    """The identity the decode path rests on: W_kvb's key half folded into
+    the query and its value half applied after the weighted sum of latents
+    equals attention over every head's expanded keys and values."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.testing import TINY_MLA_CFG
+
+    cfg = dataclasses.replace(TINY_MLA_CFG, n_ctx=64)
+    H, r, d_n, d_r, d_v = 4, 32, 16, 8, 24
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    S, T = 5, 37
+    q_n = jax.random.normal(keys[0], (S, H, d_n), jnp.float32)
+    q_r = jax.random.normal(keys[1], (S, H, d_r), jnp.float32)
+    rows = jax.random.normal(keys[2], (T, r + d_r), jnp.float32)
+    w_uk = jax.random.normal(keys[3], (H, d_n, r), jnp.float32) * r ** -0.5
+    w_uv = jax.random.normal(keys[4], (H, d_v, r), jnp.float32) * r ** -0.5
+    positions = jnp.arange(T - S, T, dtype=jnp.int32)
+    want = mla.expanded_attention(q_n, q_r, rows, w_uk, w_uv, positions, cfg)
+    W = mla.leaf_width(cfg)
+    lat = jnp.zeros((3, 1, 64, W), jnp.float32).at[1, 0, :T, :r + d_r].set(
+        rows)
+    with jax.default_matmul_precision("highest"):
+        q_full = jnp.concatenate([mla.absorb_query(q_n, w_uk), q_r,
+                                  jnp.zeros((S, H, W - r - d_r))], -1)
+        ctx = mla.latent_attention(q_full, lat, 1, positions, T - 1, cfg)
+        got = mla.expand_values(ctx, w_uv, jnp.float32)
+    assert rel(got, want) < 1e-5
+    # and the read does not depend on the bound it is given
+    far = mla.latent_attention(q_full, lat, 1, positions, 63, cfg)
+    assert np.array_equal(np.asarray(far), np.asarray(ctx))
+
+
+def test_yarn_frequencies_are_the_published_blend():
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        vocab_size=8, dim=64, n_layers=1, n_heads=1, n_kv_heads=1, ffn_dim=8,
+        n_ctx=8, rope_theta=1e5, kv_lora_rank=512, qk_rope_dim=64,
+        qk_nope_dim=128, rope_yarn_factor=64.0, rope_yarn_orig_ctx=4096)
+    f = mla.rope_inv_freq(cfg)
+    base = 1e5 ** (-np.arange(0, 64, 2) / 64)
+    # correction dims of 32 and 1 rotations at 4096: floor(8.4) and ceil(18.1)
+    assert np.allclose(f[:9], base[:9], rtol=1e-6)
+    assert np.allclose(f[19:], base[19:] / 64, rtol=1e-6)
+    assert np.all(np.diff(f) < 0)
+    mid = 13
+    ramp = (mid - 8) / (19 - 8)
+    assert np.isclose(f[mid], base[mid] / 64 * ramp + base[mid] * (1 - ramp),
+                      rtol=1e-6)
+
+
+def test_a_claimed_prefix_gives_the_logits_of_a_full_prefill(loaded, tokens):
+    """What lane-claim and serial prefix reuse rest on: suffix slices on a
+    COPY of a cache that holds the prefix give the full prefill's logits
+    (the latent ring is positional: the rows of a prefix are the rows)."""
+    import jax
+
+    params, cfg = loaded
+    full, _, _ = prefill(params, cfg, tokens, 64)
+    _, _, cache = prefill(params, cfg, tokens, 32)
+    # another sequence walks on in the source lane, past the claim
+    _, _, dirty = prefill(params, cfg, tokens[::-1], 64, cache=cache, start=32)
+    claimed = jax.tree.map(lambda a: a.copy(), dirty)
+    got, _, _ = prefill(params, cfg, tokens, 64, cache=claimed, start=32)
+    assert worst(got, full[32:]) < 1e-6
+
+
+def lanes_run(loaded, tokens):
+    """Three lanes at different positions; lane 2 dead, then taken."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+
+    params, cfg = loaded
+    pass_, _, lane_step = programs(cfg)
+    seq2 = np.roll(tokens, 7)
+    prompts = (33, 20, 11)
+    seqs = [tokens, tokens[3:], seq2]
+    caches = [prefill(params, cfg, s, n, pass_=pass_)[2]
+              for s, n in zip(seqs, prompts)]
+    garbage = jax.tree.map(lambda a: a + 1, init_cache(cfg))
+    stacked = jax.tree.map(lambda *a: jnp.stack(a), caches[0], caches[1],
+                           garbage)
+    pos = [prompts[0], prompts[1], N_CTX - 3]
+    live = [True, True, False]
+    got = {0: [], 1: [], 2: []}
+    stats = []
+    for t in range(24):
+        if t == 8:
+            stacked = jax.tree.map(lambda a, c: a.at[2].set(c), stacked,
+                                   caches[2])
+            pos[2], live[2] = prompts[2], True
+        if t == 16:
+            live[0] = False
+        toks = [seqs[i][p] if p < len(seqs[i]) else 0
+                for i, p in enumerate(pos)]
+        lg, stacked, st, pk = lane_step(
+            params, jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
+            stacked, jnp.asarray(live))
+        stats.append((np.asarray(st), sum(live)))
+        for lane in range(3):
+            if live[lane]:
+                got[lane].append((pos[lane], np.asarray(lg[lane]),
+                                  np.asarray(pk[lane])))
+        pos = [p + 1 for p in pos]
+    return {lane: (rows[0][0], np.stack([r[1] for r in rows]),
+                   np.concatenate([r[2] for r in rows], axis=1))
+            for lane, rows in got.items()}, seqs, stats
+
+
+def test_three_lanes_one_dead_then_taken(ref, model, loaded, tokens):
+    got, seqs, stats = lanes_run(loaded, tokens)
+    params, cfg = loaded
+    for lane, (first, logits, picks) in got.items():
+        n = first + len(logits)
+        use = np.concatenate(
+            [prefill(params, cfg, seqs[lane], first)[1], picks], axis=1)
+        want = np.asarray(ref.forward(*model, seqs[lane][:n],
+                                      use_picks=use)[0])
+        assert worst(logits, want[first:]) < LIMIT, lane
+    # the counters are the step's, the same in every lane: every live row's
+    # picks over all experts, all of them held here; a dead lane's reach none
+    for st, n_live in stats:
+        assert np.array_equal(st[0], st[1]) and np.array_equal(st[0], st[2])
+        layer_steps, total, held = st[0][0], st[0][-1], st[0][2:-1].sum()
+        assert layer_steps == 2
+        assert total == held == n_live * 2 * cfg.n_experts_used
+
+
+def test_a_lanes_logits_do_not_depend_on_the_other_lanes(loaded, tokens):
+    import jax
+    import jax.numpy as jnp
+
+    params, cfg = loaded
+    pass_, _, lane_step = programs(cfg)
+    near = prefill(params, cfg, tokens[5:], 10, pass_=pass_)[2]
+    far = prefill(params, cfg, tokens[9:], 60, pass_=pass_)[2]
+    mine = prefill(params, cfg, tokens, 30, pass_=pass_)[2]
+
+    def run(other, other_pos, other_live):
+        stacked = jax.tree.map(lambda *a: jnp.stack(a), mine, other)
+        out = []
+        for t in range(3):
+            lg, stacked, _, _ = lane_step(
+                params, jnp.asarray([tokens[30 + t], 7], jnp.int32),
+                jnp.asarray([30 + t, other_pos + t], jnp.int32),
+                stacked, jnp.asarray([True, other_live]))
+            out.append(np.asarray(lg[0]))
+        return np.stack(out)
+
+    base = run(near, 10, True)
+    for other, other_pos, other_live in (
+            (far, 60, True), (far, 60, False), (near, 10, False)):
+        assert np.array_equal(run(other, other_pos, other_live), base)
+
+
+# ---------------------------------------------------------------------------
+# the share
+# ---------------------------------------------------------------------------
+
+def test_the_shares_add_up_to_the_uncut_layer(ref, tmp_path, gguf_path,
+                                              model, tokens):
+    """One test ties the share to the model: the routed parts that the
+    three shares (first, count) give, plus what every chip computes alike
+    (attention, the shared expert) counted once, add up to what the UNCUT
+    reference gives for the whole layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_mla_gguf
+
+    hp, tensors = model
+    S = 24
+    x = np.asarray(ref.tensor(tensors, "token_embd.weight"))[tokens[:S]] * 8
+    outs, picks = [], None
+    for n, held in enumerate(((0, 4), (4, 4), (8, 4))):
+        path = str(tmp_path / f"share{n}.gguf")
+        write_tiny_mla_gguf(path, seed=3, held=held)
+        params, cfg = load(path)
+        assert (cfg.experts_first, cfg.n_held, cfg.n_experts) == (*held, 12)
+        assert params["layers"]["moe"]["w_gate_exps"]["w"].shape[1] == 4
+
+        def run(cfg):
+            return jax.jit(lambda h, c: mla.moe_layer(
+                h, params["layers"]["moe"], jnp.int32(0), c,
+                jnp.arange(S, dtype=jnp.int32), jnp.int32(0), cfg, None,
+                None))(jnp.asarray(x, jnp.bfloat16), init_cache(cfg))
+
+        h, _, (count, pk, total) = run(cfg)
+        assert int(total) == S * 3 and 0 < int(count.sum()) < S * 3
+        outs.append(np.asarray(h, np.float32))
+        picks = np.asarray(pk)
+        if n == 0:    # a share that holds nothing this router can pick
+            none = np.asarray(run(dataclasses.replace(
+                cfg, experts_first=cfg.n_experts))[0], np.float32)
+    got = sum(outs) - 2 * none
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.layer(
+            hp, ref.layer_weights(tensors, 1),
+            jnp.asarray(x, jnp.bfloat16).astype(jnp.float32), 1,
+            use_picks=picks)[0])
+    print("read", rel(got, want))
+    assert rel(got, want) < LIMIT
+    # and one share alone is far from it
+    assert rel(outs[0], want) > 5 * LIMIT
+
+
+def test_a_share_serves_and_counts_what_left(tmp_path, ref, tokens):
+    """A file that holds experts 4..7 of 12: the program and the reference
+    given the same share agree; the counters tell held from routed."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.llama import forward
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_mla_gguf
+
+    path = str(tmp_path / "share.gguf")
+    write_tiny_mla_gguf(path, seed=3, held=(4, 4))
+    params, cfg = load(path)
+    logits, picks, _ = prefill(params, cfg, tokens, 48)
+    want = np.asarray(ref.forward(*ref.open_model(path), tokens[:48],
+                                  use_picks=picks)[0])
+    assert worst(logits, want) < LIMIT
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+
+    _, _, stats = forward(params, cfg, jnp.asarray(tokens[:16], jnp.int32),
+                          jnp.int32(0), init_cache(cfg), with_stats=True)
+    stats = np.asarray(stats)
+    assert len(stats) == 3 + 4 and stats[0] == 2
+    assert stats[-1] == 2 * 16 * 3
+    held = int(np.sum((picks[:, :16] >= 4) & (picks[:, :16] < 8)))
+    assert stats[2:-1].sum() == held < stats[-1]
+
+
+# ---------------------------------------------------------------------------
+# the file, the loader, the refusals
+# ---------------------------------------------------------------------------
+
+def test_gguf_round_trip_of_the_keys_and_the_held_experts(tmp_path, loaded):
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
+    from llama_fastapi_k8s_gpu_tpu.models.config import (LATENT_RING,
+                                                         ModelConfig)
+    from llama_fastapi_k8s_gpu_tpu.testing import (TINY_MLA_CFG,
+                                                   write_tiny_mla_gguf)
+
+    params, cfg = loaded
+    assert cfg.cache_kind == LATENT_RING and not cfg.rope_neox
+    for f in dataclasses.fields(TINY_MLA_CFG):
+        if f.name in ("vocab_size", "rms_eps", "attn_mscale"):
+            continue
+        assert getattr(cfg, f.name) == getattr(TINY_MLA_CFG, f.name), f.name
+    assert abs(cfg.attn_mscale - TINY_MLA_CFG.attn_mscale) < 1e-6
+    assert cfg.n_held == 12 and cfg.experts_held == 0
+    assert set(params["layers"]) == {"dense", "moe"}
+    assert params["layers"]["moe"]["router_bias"].shape == (2, 12)
+    assert params["layers"]["dense"]["w_uk"]["w"].shape == (1, 4, 16, 32)
+    assert params["layers"]["moe"]["w_uv"]["w"].shape == (2, 4, 24, 32)
+    path = str(tmp_path / "held.gguf")
+    write_tiny_mla_gguf(path, held=(8, 4))
+    gf = GGUFFile(path)
+    assert gf.hparam("expert_held_first") == 8
+    assert gf.hparam("expert_held_count") == 4
+    assert gf.hparam("expert_count") == 12
+    assert tuple(gf["blk.1.ffn_gate_exps.weight"].shape) == (256, 256, 4)
+    held = ModelConfig.from_gguf(gf, n_ctx=N_CTX)
+    assert (held.experts_first, held.n_held, held.n_experts) == (8, 4, 12)
+
+
+def test_the_cache_is_one_row_a_position_for_all_heads(loaded):
+    from llama_fastapi_k8s_gpu_tpu.models.llama import (cache_nbytes,
+                                                        init_cache)
+
+    _, cfg = loaded
+    cache = init_cache(cfg)
+    assert {k: v.shape for k, v in cache.items()} \
+        == {"lat": (3, 1, N_CTX, 128)}        # 32 + 8, filled up to 128
+    assert cache_nbytes(cfg) == sum(v.nbytes for v in cache.values())
+
+
+def test_the_benchmark_files_mix_fuses_with_padded_k_and_rows(tmp_path, ref):
+    """The benchmark file's type mix at widths the fused kernels take only
+    PADDED, as the published ones are: hidden 1792 (7168 / 4: 0.875 K
+    tiles, filled up to 2048 with zero blocks in every plane whose K it is,
+    the experts' gate and up among them) and a latent projection of 128 +
+    64 = 192 rows filled up to 256.  ``attn_q_b`` and ``attn_kv_b`` (K = a
+    latent rank, no kernel's tile) are served bf16, never int8."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.params import flat_layers
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGMLType
+    from llama_fastapi_k8s_gpu_tpu.testing import (TINY_MLA_CFG,
+                                                   write_tiny_mla_gguf)
+
+    cfg = dataclasses.replace(
+        TINY_MLA_CFG, dim=1792, n_heads=2, n_kv_heads=2, n_layers=2,
+        q_lora_rank=256, kv_lora_rank=128, qk_nope_dim=32, qk_rope_dim=64,
+        v_head_dim=64, ffn_dim=2048, expert_ffn_dim=2048, n_experts=4,
+        n_expert_groups=2, n_groups_used=1, n_experts_used=2, n_ctx=64)
+    path = str(tmp_path / "wide.gguf")
+    write_tiny_mla_gguf(path, cfg, seed=1, held=(2, 2), mix={
+        "attn_q_b": GGMLType.Q4_K, "attn_kv_b": GGMLType.Q8_0,
+        "attn_output": GGMLType.Q8_0})
+    params, cfg = load(path, fmt="q4k", n_ctx=64)
+    kinds = {name: sorted(leaf) for name, leaf in
+             flat_layers(params["layers"]) if isinstance(leaf, dict)}
+    for name in ("dense.wq_a", "dense.wkv_a", "dense.w_gate", "dense.w_up",
+                 "moe.w_gate_sh", "moe.w_up_sh", "moe.w_gate_exps",
+                 "moe.w_up_exps"):
+        assert "qs" in kinds[name], name
+    for name in ("dense.w_down", "moe.w_down_sh", "moe.w_down_exps"):
+        assert {"q4", "q6p"} & set(kinds[name]), name
+    for name in ("dense.wq_b", "moe.wq_b", "moe.w_uk", "moe.w_uv", "moe.wo"):
+        assert kinds[name] == ["w"], name
+    assert params["layers"]["moe"]["wkv_a"]["qs"].shape[1:] == (256, 1024)
+    assert params["layers"]["moe"]["w_gate_exps"]["qs"].shape[1:] \
+        == (2, 2048, 1024)
+    seq = np.random.default_rng(2).integers(4, 260, size=20)
+    got, picks, cache = prefill(params, cfg, seq, 16, size=16)
+    step = programs(cfg)[1]
+    lg, _, pk = step(params, jnp.int32(seq[16]), jnp.int32(16), cache)
+    picks = np.concatenate([picks, np.asarray(pk)], axis=1)
+    exp = np.asarray(ref.forward(*ref.open_model(path), seq[:17],
+                                 use_picks=picks)[0])
+    print("read", rel(got, exp[:16]), rel(np.asarray(lg), exp[16]))
+    assert rel(got, exp[:16]) < 0.06
+    assert rel(np.asarray(lg), exp[16]) < 0.06
+
+
+def _file_with(tmp_path, drop=(), **meta):
+    """The tiny file with ``deepseek2.<key>`` values replaced."""
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFWriter
+    from llama_fastapi_k8s_gpu_tpu import testing
+
+    path = str(tmp_path / "odd.gguf")
+
+    class Odd(GGUFWriter):
+        def add_metadata(self, key, value):
+            short = key.removeprefix("deepseek2.")
+            super().add_metadata(key, meta.get(short, value))
+
+    orig = testing.GGUFWriter
+    testing.GGUFWriter = Odd
+    try:
+        testing.write_tiny_mla_gguf(path)
+    finally:
+        testing.GGUFWriter = orig
+    return path
+
+
+@pytest.mark.parametrize("meta, words", [
+    ({"attention.q_lora_rank": 0}, "q_lora_rank is 0"),
+    ({"expert_gating_func": 3}, "expert_gating_func 3"),
+    ({"expert_group_count": 5}, "12 experts in 5 groups"),
+    ({"expert_group_used_count": 1, "expert_used_count": 5},
+     "5 picks must fit 1 groups of 4"),
+])
+def test_a_file_the_block_cannot_compute_is_refused_by_name(tmp_path, meta,
+                                                            words):
+    from llama_fastapi_k8s_gpu_tpu.gguf import GGUFFile
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+
+    with pytest.raises(ValueError, match=words):
+        ModelConfig.from_gguf(GGUFFile(_file_with(tmp_path, **meta)),
+                              n_ctx=N_CTX)
+
+
+@pytest.mark.parametrize("kw, words", [
+    (dict(kv_dtype="int8"), "LFKT_KV_DTYPE=int8 cannot serve architecture "
+                            "'deepseek2'"),
+    (dict(kv_paged=True), "LFKT_KV_PAGED=1 cannot serve architecture "
+                          "'deepseek2'"),
+])
+def test_what_cannot_hold_the_cache_is_refused_by_name(gguf_path, kw, words):
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+
+    with pytest.raises(ValueError, match=words):
+        Engine(gguf_path, n_ctx=N_CTX, **kw)
+
+
+def test_meshes_refuse_the_architecture_by_name(gguf_path):
+    from llama_fastapi_k8s_gpu_tpu.engine.batched import MeshEngine
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+    from llama_fastapi_k8s_gpu_tpu.engine.sp import SPEngine
+
+    for make, words in (
+            (lambda: ContinuousEngine(gguf_path, n_ctx=N_CTX, tp=2,
+                                      batch_size=1, prefill_chunk=SLICE),
+             "LFKT_MESH_TP=2 cannot serve architecture 'deepseek2'"),
+            (lambda: MeshEngine(gguf_path, n_ctx=N_CTX, batch_size=2,
+                                prefill_chunk=SLICE),
+             "LFKT_SCHEDULER=cycle cannot serve architecture 'deepseek2'"),
+            (lambda: SPEngine(gguf_path, n_ctx=N_CTX, sp=2,
+                              prefill_chunk=SLICE),
+             "LFKT_MESH_SP > 1 cannot serve architecture 'deepseek2'")):
+        with pytest.raises(ValueError, match=words):
+            make()
+
+
+# ---------------------------------------------------------------------------
+# the engines
+# ---------------------------------------------------------------------------
+
+SYSTEM = "you are a careful assistant who answers in short plain sentences"
+MSGS = [{"role": "system", "content": SYSTEM},
+        {"role": "user", "content": "tell me about latents and rings"}]
+MSGS2 = [{"role": "system", "content": SYSTEM},
+         {"role": "user", "content": "and what does an expert hold here"}]
+
+
+@pytest.fixture(scope="module")
+def engine(gguf_path):
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+
+    return Engine(gguf_path, n_ctx=N_CTX * 2, prefill_chunk=SLICE,
+                  decode_chunk=4, prefix_min=8)
+
+
+def test_serial_engine_serves_reuses_a_prefix_and_counts(engine):
+    out = engine.create_chat_completion(MSGS, max_tokens=12, temperature=0.0)
+    assert out["usage"]["completion_tokens"] >= 1
+    kind = engine.cache_kind
+    assert kind["kind"] == "latent-ring" and kind["prefix_reuse"] == "on"
+    assert kind["latent"] == 32 and kind["rotated_key"] == 8
+    assert kind["bytes_per_position"] == 2 * 3 * 40
+    assert kind["bytes_per_position_laid_out"] == 2 * 3 * 128
+    assert kind["experts_held"] == [0, 12] and kind["experts_routed"] == 12
+    assert kind["kv_paged"] == "refused at start"
+    assert engine._prefix_cache and engine.cfg.attn_impl == "xla"
+    gauges = engine.cache_read_gauges()
+    assert 0 < gauges["latent_positions_live_total"] \
+        <= gauges["latent_positions_read_total"]
+    snap = engine.expert_counters.snapshot(block=True)
+    assert snap["picks_total"] == snap["picks_held"] == sum(snap["picks"]) > 0
+    # the same request again rides the prefix the ring still holds, and
+    # gives the same greedy text as the full prefill did
+    again = engine.create_chat_completion(MSGS, max_tokens=12,
+                                          temperature=0.0)
+    assert again["choices"][0]["message"] == out["choices"][0]["message"]
+    other = engine.create_chat_completion(MSGS2, max_tokens=4,
+                                          temperature=0.0)
+    assert other["usage"]["completion_tokens"] >= 1
+
+
+def test_lane_engine_serves_and_admits_through_a_lane_claim(gguf_path,
+                                                            engine):
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+
+    want = engine.create_chat_completion(MSGS, max_tokens=10, temperature=0.0)
+    eng = ContinuousEngine(gguf_path, n_ctx=N_CTX * 2, prefill_chunk=SLICE,
+                           decode_chunk=4, batch_size=3)
+    try:
+        assert eng._lane_prefix and eng.cache_kind["prefix_reuse"] == "on"
+        first = eng.submit(MSGS, max_tokens=10, temperature=0.0).result(
+            timeout=300)
+        assert first["usage"] == want["usage"]
+        futs = [eng.submit(m, max_tokens=10, temperature=0.0)
+                for m in (MSGS, MSGS2, MSGS, MSGS2, MSGS)]
+        outs = [f.result(timeout=300) for f in futs]
+        # a claim hit gives the text the full prefill gave on these lanes
+        for o in (outs[0], outs[2], outs[4]):
+            assert o["choices"][0]["message"] == first["choices"][0]["message"]
+        stats = eng.scheduler_stats()
+        assert stats["lane_prefix_hits"] >= 3
+        assert stats["lane_prefix_reused_tokens"] >= 3 * SLICE
+        snap = eng.expert_counters.snapshot(block=True)
+        assert 0 < snap["picks_held"] == snap["picks_total"]
+    finally:
+        eng.shutdown()
+
+
+def test_callers_that_arrive_together_ride_the_first_ones_prompt(gguf_path):
+    """A cold lane engine, three requests behind one system line at once
+    (the agent cell's warm-up at a small size): the first prefills, the
+    others are admitted beside it through its LIVE lane's claim, and each
+    gives the text a full prefill gives."""
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+
+    eng = ContinuousEngine(gguf_path, n_ctx=N_CTX * 2, prefill_chunk=SLICE,
+                           decode_chunk=4, batch_size=3)
+    try:
+        alone = [eng.submit(m, max_tokens=6, temperature=0.0, seed=5).result(
+            timeout=300) for m in (MSGS, MSGS2)]   # explicit seed: no reuse
+        assert eng.scheduler_stats()["lane_prefix_hits"] == 0
+        eng._lane_claims[:] = [None] * eng.batch_size
+        futs = [eng.submit(m, max_tokens=n, temperature=0.0)
+                for m, n in ((MSGS, 24), (MSGS2, 6), (MSGS, 6))]
+        outs = [f.result(timeout=300) for f in futs]
+        assert eng.scheduler_stats()["lane_prefix_hits"] == 2
+        assert outs[1]["choices"][0]["message"] \
+            == alone[1]["choices"][0]["message"]
+        assert outs[2]["choices"][0]["message"] \
+            == alone[0]["choices"][0]["message"]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.anyio
+async def test_v1_chat_completions_streams_and_health_names_the_kind(engine):
+    import json
+
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    app = create_app(engine=engine, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            r = await client.post("/v1/chat/completions", json={
+                "messages": MSGS, "max_tokens": 8, "temperature": 0.0,
+                "stream": True, "stream_options": {"include_usage": True}})
+            assert r.status_code == 200
+            events = [json.loads(ln[6:]) for ln in r.text.splitlines()
+                      if ln.startswith("data: {")]
+            usage = [e["usage"] for e in events if e.get("usage")][-1]
+            assert 1 <= usage["completion_tokens"] <= 8
+            eng = (await client.get("/health")).json()["engine"]
+            assert eng["cache"]["kind"] == "latent-ring"
+            assert eng["cache"]["prefix_reuse"] == "on"
+            assert set(eng["weight_formats"]) >= {
+                "dense.wq_a", "dense.w_gate", "moe.wkv_a", "moe.w_uk",
+                "moe.w_gate_exps", "moe.w_down_sh"}
+            d = (await client.get("/debug/compiles")).json()
+            assert not d.get("degrades")
+            m = (await client.get("/metrics")).text
+            assert "latent_positions_read_total" in m
+            assert "latent_positions_live_total" in m
+            assert "expert_picks_routed_total" in m
+            assert "expert_picks_held_total" in m
+        await app.router.shutdown()

@@ -582,7 +582,7 @@ def test_lanes_decode_at_once_while_lanes_join_and_leave(
             len(set(live_picks[:, layer].reshape(-1).tolist()))
             for layer in range(cfg.n_layers))
         np.testing.assert_array_equal(
-            stats[0][2:], np.bincount(live_picks.reshape(-1),
+            stats[0][2:-1], np.bincount(live_picks.reshape(-1),
                                       minlength=cfg.n_experts))
     assert [len(got[lane]) for lane in range(3)] == [4, 6, 4]
     for lane, n in enumerate(starts):
@@ -629,11 +629,13 @@ def test_expert_counters_fold_finished_chunks_when_read():
 
     c = ExpertCounters(3)
     assert c.snapshot() == {"layer_steps": 0, "experts_read": 0,
-                            "picks": [0, 0, 0]}
+                            "picks": [0, 0, 0], "picks_held": 0,
+                            "picks_total": 0}
     for _ in range(70):                 # past the pending bound: still exact
-        c.push(jnp.asarray([2, 3, 1, 0, 4], jnp.int32))
+        c.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
     assert c.snapshot(block=True) == {
-        "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280]}
+        "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280],
+        "picks_held": 350, "picks_total": 630}
 
 
 @pytest.mark.parametrize("engine", ["serial", "lanes"])

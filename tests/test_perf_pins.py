@@ -159,8 +159,12 @@ def test_mesh_warmup_compile_budget(pins):
 
 
 def test_sp_warmup_compile_budget(pins):
+    # sp_prefill: 2 lowerings reach the compiler.  (3 while the wrapper
+    # counted every entry of the jit cache: the third was a state under
+    # another NAME for the same placement, which lowered and compiled
+    # nothing: obs/devtime.py _listen.)
     assert _compiles(pins["sp_warmup"]) == {
-        "sp_prefill": 3, "first_sample": 1, "sp_decode_chunk": 2}
+        "sp_prefill": 2, "first_sample": 1, "sp_decode_chunk": 2}
 
 
 def test_continuous_warmup_compile_budget(pins):
