@@ -45,6 +45,7 @@ from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, EngineUnavailable
 from .batched import MeshEngine
+from .slices import next_slice
 
 logger = logging.getLogger(__name__)
 
@@ -667,22 +668,13 @@ class ContinuousEngine(MeshEngine):
         with ph.child("stream_round"):
             list(self.submit_stream(msgs, max_tokens=self.decode_chunk + 1,
                                     temperature=0.0))
-        # every slice shape a bucket walk can produce, compiled against a
-        # throwaway cache (jit program caches are global, so the scheduler
-        # thread hits them warm; its own scratch cache is never touched)
+        # every slice shape the plan can cut a prompt into (engine/
+        # slices.py), one call a shape, compiled against a throwaway cache
+        # (jit program caches are global, so the scheduler thread hits them
+        # warm; its own scratch cache is never touched)
         with ph.child("slice_shapes") as slices:
-            cache = init_cache(self.cfg)
-            shapes = set()
-            for b in self.prefill_buckets:
-                off = 0
-                while off < b:
-                    C = min(self._prefill_chunk, b - off)
-                    _, cache = prefill_chunk_jit(
-                        self.params, self.cfg, jnp.zeros((C,), jnp.int32),
-                        jnp.int32(off), jnp.int32(C - 1), cache)
-                    off += C
-                    shapes.add(C)
-            slices.attrs["n_shapes"] = len(shapes)
+            cache, slices.attrs["n_shapes"] = self._warm_slice_shapes(
+                self.prefill_buckets, init_cache(self.cfg))
         if self._lane_prefix:
             # compile the lane→scratch snapshot gather (one program; the
             # suffix slice shapes are already in the warmed set above)
@@ -779,8 +771,10 @@ class ContinuousEngine(MeshEngine):
         elif item.sink is not None:
             item.sink.put(exc if exc is not None else _STREAM_END)
 
-    def _begin_admission(self, item: _Item) -> dict | None:
-        """Guards + tokenize + machine setup (no device work yet)."""
+    def _begin_admission(self, item: _Item, alone: bool = False
+                         ) -> dict | None:
+        """Guards + tokenize + machine setup (no device work yet).
+        ``alone``: :meth:`_admit_step`'s."""
         if item.abandoned.is_set():
             self._resolve_skipped(item)
             return None
@@ -862,7 +856,7 @@ class ContinuousEngine(MeshEngine):
                 # mid-prefill (or failing later) must not inflate /metrics
             if pspan is not None:
                 pspan.set(n_prompt=len(ids), bucket=bucket, reused=reuse)
-            self._note_prefill_windows(len(ids), pspan, reuse)
+            self._note_prefill_windows(len(ids), pspan, reuse, alone)
             # host-side slice prep happens ONCE, here, while lanes decode:
             # one int32 array for the padded prompt; every slice dispatch
             # then takes a zero-copy view instead of re-converting a list
@@ -928,6 +922,9 @@ class ContinuousEngine(MeshEngine):
     def _dispatch_prefill_chunk(self, adm: dict) -> None:
         """Run ONE prompt slice through the model into the scratch cache.
         Keeps the logits of the slice containing the last real token.
+        ``adm["alone"]`` (nobody decodes behind the slice: what
+        :meth:`_admit_step` was called with) lets the plan cut it wide
+        (engine/slices.py).
 
         The dispatch is async — its host wall (observed into the
         ``prefill_slice_seconds`` histogram and the request's
@@ -945,7 +942,8 @@ class ContinuousEngine(MeshEngine):
             # are never attended.
             self._scratch_cache = init_cache(self.cfg)
         off = adm["offset"]
-        C = min(self._prefill_chunk, adm["bucket"] - off)
+        C = next_slice(off, adm["n_prompt"], adm["bucket"],
+                       self._prefill_chunk, self._wide_slice, adm["alone"])
         sl = jnp.asarray(adm["padded"][off:off + C])
         li = min(max(adm["n_prompt"] - 1 - off, 0), C - 1)
         with phase("admit_slice", rid=rid(adm["item"].trace), offset=off,
@@ -962,6 +960,7 @@ class ContinuousEngine(MeshEngine):
         tot = self._totals
         tot["admit_slices"] += 1
         tot["admit_tokens"] += C
+        self._count_slice(C)
         self._slices_queued += 1
         # wave: the decode chunk this slice is queued ahead of (the next
         # one dispatched), the number that chunk's decode_chunk spans carry
@@ -1236,13 +1235,15 @@ class ContinuousEngine(MeshEngine):
             # never fed/written)
             self._free_lane(lane, slot, slots)
 
-    def _admit_step(self, slots: list) -> int | None:
+    def _admit_step(self, slots: list, alone: bool = False) -> int | None:
         """One unit of admission progress: begin the next queued item (and
         dispatch its first prefill slice), or dispatch the in-flight
         admission's next slice — finishing it (sample + lane write) when the
         last slice lands.  Returns the number of prefill tokens dispatched
         (0 for bookkeeping-only progress), or None when there is nothing
-        to do."""
+        to do.  ``alone``: no lane holds a request and no chunk is in
+        flight (the idle branch of :meth:`_loop`), so nobody waits behind
+        the slice."""
         if self._adm is None:
             if not any(s is None for s in slots):
                 return None                     # no free lane to admit into
@@ -1250,7 +1251,7 @@ class ContinuousEngine(MeshEngine):
                 item = self._pending.get_nowait()
             except queue_mod.Empty:
                 return None
-            self._adm = self._begin_admission(item)
+            self._adm = self._begin_admission(item, alone)
             if self._adm is None:
                 return 0                        # item resolved/skipped: progress
         adm = self._adm
@@ -1262,6 +1263,7 @@ class ContinuousEngine(MeshEngine):
             self._adm = None
             return 0
         off_before = adm["offset"]
+        adm["alone"] = alone
         try:
             self._dispatch_prefill_chunk(adm)
         except Exception as e:  # noqa: BLE001 — per-request isolation: a
@@ -1512,7 +1514,7 @@ class ContinuousEngine(MeshEngine):
                     # drive the machine at full speed until a lane fills
                     progressed = False
                     while not any(s is not None for s in slots):
-                        if self._admit_step(slots) is None:
+                        if self._admit_step(slots, alone=True) is None:
                             break
                         progressed = True
                     if not any(s is not None for s in slots):

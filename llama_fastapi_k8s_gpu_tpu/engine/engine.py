@@ -52,6 +52,7 @@ from ..obs.trace import arm_phases, phase, rid
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
 from .expert_counters import ExpertCounters
+from .slices import plan_slices, slice_shapes, wide_width
 from ..utils.jaxcache import setup_compile_cache
 from ..utils.startup import Phase, Timeline, legacy_load_phases
 
@@ -244,6 +245,10 @@ class Engine:
         self.sala_counts = {"state_updates": 0, "queries_dense": 0,
                             "queries_sparse": 0, "blocks_read": 0,
                             "blocks_visible": 0, "kc_written": 0}
+        # prompt tokens prefilled (padding included), by the width of the
+        # program that took them: ``wide`` is more than the narrow width
+        # (engine/slices.py); /metrics prefill_slice_tokens_total{width=}
+        self.slice_tokens = {"narrow": 0, "wide": 0}
         self._base_seed = seed
         # request counter: shared by the serial path (caller thread) and the
         # continuous scheduler thread; _next_seed() is the only writer and
@@ -405,6 +410,11 @@ class Engine:
         self.prefill_buckets = sorted(b for b in prefill_buckets if b <= self.cfg.n_ctx)
         if not self.prefill_buckets or self.prefill_buckets[-1] < self.cfg.n_ctx:
             self.prefill_buckets.append(self.cfg.n_ctx)
+        # a prompt's slices where nobody decodes behind them (engine/
+        # slices.py): the wide width, or the narrow one where the block
+        # takes no wider slice
+        self._wide_slice = wide_width(self._prefill_chunk,
+                                      self.cfg.widest_slice)
         # the serial ring here and the paged pool below; a subclass's lanes
         # are a phase of their own (``lanes_alloc``, ``scheduler_start``)
         alloc = tl.phase("cache_alloc")
@@ -751,16 +761,24 @@ class Engine:
                                         temperature=0.0)
         with self._lock:   # uncontended at warmup; the ring-write invariant
             #                (writes to _cache only under _lock) stays intact
-            with ph.child("buckets", n_buckets=len(self.prefill_buckets) - 1):
+            with ph.child("buckets",
+                          n_buckets=len(self.prefill_buckets) - 1) as bk:
+                # compile the program(s) each bucket actually serves with:
+                # the monolithic prefill of a small bucket, and ONE call
+                # for every slice shape the plan can cut the sliced
+                # buckets into (_slices_prefill, engine/slices.py)
+                sliced = []
                 for b in self.prefill_buckets[1:]:
-                    # compile the program(s) this bucket actually serves
-                    # with: monolithic prefill for small buckets, the slice
-                    # walk for buckets the overlapped path slices
-                    # (_slices_prefill)
-                    logits, cache = self._prefill_padded(
+                    if self._slices_prefill(b):
+                        sliced.append(b)
+                        continue
+                    logits, self._cache = self._prefill_padded(
                         [0] * (b - 1), b - 1, b, self._cache)
                     jax.block_until_ready(logits)
-                    self._cache = cache
+                if sliced:
+                    self._cache, bk.attrs["n_shapes"] = \
+                        self._warm_slice_shapes(sliced, self._cache)
+                    jax.block_until_ready(self._cache)
             if self._prefix_cache or self._kv_paged:
                 # compile the suffix pass for every bucket a reuse suffix can
                 # land in (all but the largest — _prefix_reuse_len only grants
@@ -780,6 +798,18 @@ class Engine:
                         jax.block_until_ready(logits)
                 self._prefix_ids = []
         return f"{len(self.prefill_buckets)} prefill buckets"
+
+    def _warm_slice_shapes(self, buckets, cache):
+        """One ``prefill_chunk`` call for every slice shape the plan can
+        emit for ``buckets`` (engine/slices.py ``slice_shapes``): what both
+        engines' warm-ups compile, once a shape.  Returns (the cache the
+        calls were queued on, how many shapes)."""
+        shapes = slice_shapes(buckets, self._prefill_chunk, self._wide_slice)
+        for C in shapes:
+            _, cache = prefill_chunk_jit(
+                self.params, self.cfg, jnp.zeros((C,), jnp.int32),
+                jnp.int32(0), jnp.int32(C - 1), cache)
+        return cache, len(shapes)
 
     # -- jit call points (subclasses reroute these onto a mesh: engine/sp.py
     # runs them sequence-parallel; the vmap/batched engines bypass them) ----
@@ -806,6 +836,11 @@ class Engine:
                 m.observe("prefill_slice_seconds", dt)
             except Exception:  # noqa: BLE001 — telemetry must never fail serving
                 pass
+
+    def _count_slice(self, tokens: int) -> None:
+        """One prefill program's tokens into :attr:`slice_tokens`."""
+        self.slice_tokens[
+            "wide" if tokens > self._prefill_chunk else "narrow"] += tokens
 
     @staticmethod
     def _slice_span(pspan, t_s: float, t_e: float, offset: int, tokens: int,
@@ -846,17 +881,18 @@ class Engine:
                     cache)
             # the one-program prompt: one slice
             self._slice_span(pspan, t_s, time.time(), 0, bucket)
+            self._count_slice(bucket)
             return out
-        C = self._prefill_chunk
         padded_np = np.zeros((bucket,), np.int32)
         padded_np[:n_prompt] = ids
         logits = None
         inflight: deque = deque()
-        off = 0
         last = n_prompt - 1
-        while off <= last:
+        # nobody decodes behind a slice of this engine: wide first, narrow
+        # for the tail (engine/slices.py)
+        for off, n in plan_slices(0, n_prompt, bucket, self._prefill_chunk,
+                                  self._wide_slice):
             t_s = time.time()
-            n = min(C, bucket - off)
             sl = jnp.asarray(padded_np[off:off + n])
             li = min(max(last - off, 0), n - 1)
             with phase("prefill_slice", rid=rid(pspan), offset=off, tokens=n):
@@ -873,7 +909,7 @@ class Engine:
             t_e = time.time()
             self._observe_slice(t_e - t_s)
             self._slice_span(pspan, t_s, t_e, off, n)
-            off += n
+            self._count_slice(n)
         return logits, cache
 
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
@@ -933,10 +969,13 @@ class Engine:
     def cache_read_gauges(self) -> dict:
         """The counters of :meth:`_note_cache_read` under their /metrics
         names: ``ring_slots_*`` for every engine (0 on a cache that is no
-        ring), ``eva_*`` for a window + summary cache alone."""
+        ring), ``eva_*`` for a window + summary cache alone; beside them
+        the prompt tokens prefilled by slice width (:attr:`slice_tokens`)."""
         out = {"ring_slots_read_total": self.ring_slots["read"],
                "ring_slots_live_total": self.ring_slots["live"],
                "ring_rows_written_total": self.ring_rows_written}
+        out.update({f'prefill_slice_tokens_total{{width="{w}"}}': n
+                    for w, n in self.slice_tokens.items()})
         if self.cfg.eva_window:
             c = self.eva_counts
             out.update(
@@ -1177,6 +1216,7 @@ class Engine:
                     jnp.int32(reuse), jnp.int32(s - 1), self._cache)
             # the suffix pass after a prefix reuse
             self._slice_span(pspan, t_s, time.time(), reuse, sbucket)
+            self._count_slice(sbucket)
         else:
             logits, cache = self._prefill_padded(
                 ids, n_prompt, bucket, self._cache, pspan=pspan)
@@ -1210,15 +1250,19 @@ class Engine:
         }
 
     def _note_prefill_windows(self, n_prompt: int, pspan=None,
-                              reused: int = 0) -> None:
+                              reused: int = 0, alone: bool = True) -> None:
         """The windows a prompt's prefill closes (the window + summary
         cache alone): counted, and on the traced ``prefill`` span; of a
         latent ring the span names the kind and the cached rows its slices
-        read (``reused``: the prefix no slice computes)."""
+        read (``reused``: the prefix no slice computes; ``alone``: whether
+        nobody decodes behind them as the prompt is admitted, which is what
+        their widths are planned from)."""
         if self.cfg.cache_kind == LATENT_RING and pspan is not None:
             pspan.set(cache=LATENT_RING,
                       latent_positions_read=mla.prefill_positions_read(
-                          n_prompt, reused, self._prefill_chunk, self.cfg))
+                          plan_slices(reused, n_prompt, self.cfg.n_ctx,
+                                      self._prefill_chunk, self._wide_slice,
+                                      alone), self.cfg))
         if self.cfg.eva_window:
             n = eva.windows_closed_by_prefill(n_prompt, self.cfg)
             self.eva_counts["windows_closed"] += n

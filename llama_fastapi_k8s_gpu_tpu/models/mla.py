@@ -83,15 +83,14 @@ def cache_nbytes(cfg: ModelConfig) -> int:
     return cfg.n_layers * cfg.n_ctx * leaf_width(cfg) * 2
 
 
-def prefill_positions_read(n_prompt: int, reused: int, chunk: int,
-                           cfg: ModelConfig) -> int:
-    """Cached rows (a layer's) the slices of a prompt's prefill read: each
-    slice of ``chunk`` positions from ``reused`` on reads whole blocks up to
-    its own last position.  Host arithmetic for the ``prefill`` span."""
-    T, total = min(LATENT_BLOCK, cfg.n_ctx), 0
-    for off in range(reused, n_prompt, max(chunk, 1)):
-        total += min(-(-min(off + chunk, cfg.n_ctx) // T) * T, cfg.n_ctx)
-    return total
+def prefill_positions_read(slices, cfg: ModelConfig) -> int:
+    """Cached rows (a layer's) the slices of a prompt's prefill read:
+    ``slices`` [(offset, positions)] (engine/slices.py ``plan_slices``),
+    each reading whole blocks up to its own last position.  Host arithmetic
+    for the ``prefill`` span."""
+    T = min(LATENT_BLOCK, cfg.n_ctx)
+    return sum(min(-(-min(off + n, cfg.n_ctx) // T) * T, cfg.n_ctx)
+               for off, n in slices)
 
 
 def attn_scale(cfg: ModelConfig) -> float:

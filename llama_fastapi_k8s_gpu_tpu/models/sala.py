@@ -325,6 +325,34 @@ def lin_slice(q, k, v, s_in, slope, n_valid):
     return o, s_out
 
 
+#: positions of one piece of :func:`lin_pieces`: ``lin_slice`` is quadratic
+#: in its positions (scores C x C a head in float32), so a slice wider than
+#: this walks it piece by piece
+LIN_PIECE = 256
+
+
+def lin_pieces(q, k, v, s_in, slope, n_valid):
+    """:func:`lin_slice` over a wide slice as a scan over pieces of
+    :data:`LIN_PIECE` positions carrying the state: the same sum, piece for
+    piece what as many narrow slices compute.  A slice that is no whole
+    number of pieces (or one piece) goes to ``lin_slice`` whole."""
+    C = q.shape[0]
+    if C <= LIN_PIECE or C % LIN_PIECE:
+        return lin_slice(q, k, v, s_in, slope, n_valid)
+    n = C // LIN_PIECE
+
+    def piece(s, x):
+        qp, kp, vp, at = x
+        o, s = lin_slice(qp, kp, vp, s, slope,
+                         jnp.clip(n_valid - at, 0, LIN_PIECE))
+        return s, o
+
+    s_out, o = jax.lax.scan(piece, s_in, (
+        *(x.reshape(n, LIN_PIECE, *x.shape[1:]) for x in (q, k, v)),
+        jnp.arange(n, dtype=jnp.int32) * LIN_PIECE))
+    return o.reshape(C, *o.shape[2:]), s_out
+
+
 def lin_layer(h, w, i, cache, positions, pos_offset, n_valid,
               cfg: ModelConfig, live=None):
     """One linear-attention layer over S positions against layer ``i`` of
@@ -360,7 +388,7 @@ def lin_layer(h, w, i, cache, positions, pos_offset, n_valid,
             o = o[None]
         else:
             s_in = jnp.where(starts_sequence(pos_offset), 0.0, s_in)
-            o, s_out = lin_slice(q, k, v, s_in, slope, n_valid)
+            o, s_out = lin_pieces(q, k, v, s_in, slope, n_valid)
         with jax.named_scope("lin_state_write"):
             state = jax.lax.dynamic_update_slice(
                 cache["state"], s_out[None], (i, 0, 0, 0))

@@ -343,6 +343,16 @@ METRICS: dict[str, Metric] = _register(
            "engine.ring_write = xla: int8 rings, meshes, the CPU, a state "
            "+ ring cache); host arithmetic at the chunk's harvest, nothing "
            "fetched"),
+    # -- how a prompt was cut into prefill slices (engine/slices.py) --------
+    Metric("prefill_slice_tokens_total", GAUGE,
+           "prompt tokens prefilled (padding included), cumulative, by the "
+           "width of the program that took them: wide = more than the narrow "
+           "width (LFKT_PREFILL_CHUNK), which the plan cuts only where nobody "
+           "decodes behind the slice (the serial engine always; the lane "
+           "engine while no lane holds a request and no chunk is in flight); "
+           "over both widths = the share of the prompt that paid one weight "
+           "pass per wide slice and not per narrow one; host arithmetic at "
+           "each dispatch", labels=("width",)),
     # -- the window + summary cache's read (models/eva.py; ``evabyte``) -----
     Metric("eva_lane_steps_total", GAUGE,
            "decode steps summed over the lanes that hold a request (a "

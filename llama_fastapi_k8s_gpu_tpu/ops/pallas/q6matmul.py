@@ -57,6 +57,7 @@ from .qmatmul import (
     _lane_repeat,
     _pick_tn,
     kernel_name,
+    MANYROW_MAX,
     plain_pallas_call,
     _q4k_accum,
     q4k_compatible,
@@ -65,6 +66,7 @@ from .qmatmul import (
     stacked_pallas_call,
     stacked_partitioned,
     TK,
+    tn_prefs,
     _tn_prefs_for,
 )
 
@@ -407,7 +409,7 @@ def _q6k_2d_raw(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
     B, KA = xpa.shape
     K = (KA // TKA6) * TK
     N = q4.shape[0]
-    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(B, _TN_PREFS_Q6K))
+    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q6K))
     in_specs, out_spec = _q6k_specs(B, TN)
     return plain_pallas_call(
         functools.partial(_q6k_matmul_kernel, interpret=interpret,
@@ -535,7 +537,7 @@ def _q6k_2d_partitioned(interpret: bool, variant: str = "cur"):
         infer_sharding_from_operands=infer,
         sharding_rule="b k, n j, n p, t n l -> b n",
     )
-    return jax.jit(rows_vmappable(fn, xpa_pos=0))
+    return jax.jit(rows_vmappable(fn, xpa_pos=0, bound=MANYROW_MAX))
 
 
 def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
@@ -544,7 +546,7 @@ def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
     B, KA = xpa.shape
     K = (KA // TKA6) * TK
     N = q4.shape[1]
-    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(B, _TN_PREFS_Q6K))
+    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q6K))
     in_specs, out_spec = _q6k_specs(B, TN)
     call = stacked_pallas_call(
         functools.partial(_q6k_matmul_kernel, interpret=interpret,
@@ -563,7 +565,7 @@ def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
 def _q6k_2d_stacked_partitioned(interpret: bool, variant: str = "cur"):
     return stacked_partitioned(
         functools.partial(_q6k_2d_stacked_raw, variant=variant),
-        "i, b k, l n j, l n p, l t n m -> b n", interpret)
+        "i, b k, l n j, l n p, l t n m -> b n", interpret, MANYROW_MAX)
 
 
 def q6k_matmul_stacked(x: jax.Array, w: dict, idx,
@@ -587,7 +589,7 @@ def q6k_matmul_stacked(x: jax.Array, w: dict, idx,
         fn = _q6k_2d_stacked_partitioned(
             _interpret(interpret), "cur" if var == "pre" else var)
         y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws),
-                         xpa, w["q4"], w["q2"], w["sm6"])
+                         xpa, w["q4"], w["q2"], w["sm6"], bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
@@ -608,7 +610,8 @@ def q6k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
         var = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS)
         fn = _q6k_2d_partitioned(
             _interpret(interpret), "cur" if var == "pre" else var)
-        y = batched_rows(fn, xpa, w["q4"], w["q2"], w["sm6"])
+        y = batched_rows(fn, xpa, w["q4"], w["q2"], w["sm6"],
+                         bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 

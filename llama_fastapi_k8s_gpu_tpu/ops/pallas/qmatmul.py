@@ -342,18 +342,52 @@ def _pick_tn(n: int, interpret: bool, prefs: tuple = (512, 256, 128)) -> int:
 _TN_PREFS_Q4K = (512, 256, 128)  # 512 measured fastest for decode (docs/bench)
 
 
+#: rows of a call tiled as it always was: every decode step and every
+#: slice beside live lanes.  A call of more rows (up to :data:`MANYROW_MAX`)
+#: is still ONE row block, so each weight tile is read and dequantized once
+#: a call whatever its rows, under a wider N tile and a raised VMEM limit
+#: (docs/PERF.md "Rows of a fused matmul call")
+TM = 256
+#: the most rows of one call; a taller operand (a 2048-token bucket, lanes
+#: x a bucket under ``vmap``) is cut into calls of this many.  Measured on
+#: the chip: the MXU holds a 128 x 128 weight tile and streams the row
+#: block past it, so a taller block hides more of each tile's load, and a
+#: wider N tile re-reads the activations less often
+MANYROW_MAX = 1024
+#: N tiles of a call of more than :data:`TM` rows, both families
+MANYROW_TN = (512, 256, 128)
+#: scoped VMEM of such a call: at 1024 rows x TN 512 the activation block
+#: is 4.7 MB (twice, for the pipeline), the Q6_K dequant intermediates six
+#: float32 (512, 2048) planes
+MANYROW_VMEM = 96 * 2 ** 20
+
+
 def _tn_prefs_for(B: int, prefs: tuple) -> tuple:
-    """Cap TN at 256 for large row blocks, bounding the (B, TKA) activation
-    block plus dequant-intermediate VMEM footprint.  Artifact-free chip
-    measurement (docs/PERF.md "Measurement hygiene") shows prefill-size
-    row counts perform the same at 128-row/TN=512 and 256-row/TN=256 for
-    every fused format (~16.5 ms for the 8B (4096, 14336) shape at 512
-    rows); the cap keeps the larger 256-row chunks (half the kernel
-    calls) safely inside VMEM.  Decode (B ≤ 128) keeps the
-    measured-fastest TN=512."""
+    """Cap TN at 256 for the row blocks of up to :data:`TM` rows, bounding
+    the (B, TKA) activation block plus dequant-intermediate VMEM footprint
+    inside the 16 MB default (~4.3 MB activations + ~6 MB intermediates at
+    256 rows).  Decode (B ≤ 128) keeps the measured-fastest TN=512."""
     if B > 128:
         return tuple(t for t in prefs if t <= 256) or prefs[-1:]
     return prefs
+
+
+def tn_prefs(B: int, prefs: tuple) -> tuple:
+    """The N tiles of a Q4_K / Q6_K call of ``B`` rows: a call of more
+    than :data:`TM` rows takes :data:`MANYROW_TN` under its own VMEM limit
+    (:func:`_manyrow_kw`), any other what it always took."""
+    return MANYROW_TN if B > TM else _tn_prefs_for(B, prefs)
+
+
+def _manyrow_kw(B: int) -> dict:
+    """pallas_call keywords by the call's rows: none up to :data:`TM` (the
+    call is built as it always was), else the raised VMEM limit."""
+    if B <= TM:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=MANYROW_VMEM)}
 
 
 def _q4k_specs(B: int, TN: int):
@@ -395,6 +429,7 @@ def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
         out_shape=out_shape,
         interpret=interpret,
         name=name,
+        **_manyrow_kw(out_shape.shape[0]),
     )
 
 
@@ -403,7 +438,7 @@ def _q4k_2d_raw(xpa: jax.Array, qs: jax.Array, sm: jax.Array,
     B, KA = xpa.shape
     K = (KA // TKA) * TK
     N = qs.shape[0]
-    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(B, _TN_PREFS_Q4K))
+    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q4K))
     in_specs, out_spec = _q4k_specs(B, TN)
     return plain_pallas_call(
         functools.partial(_q4k_matmul_kernel, interpret=interpret,
@@ -468,7 +503,7 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
         # (k, j, t) stay unsplit by construction of the mesh.py shardings
         sharding_rule="b k, n j, t n l -> b n",
     )
-    return jax.jit(rows_vmappable(fn, xpa_pos=0))
+    return jax.jit(rows_vmappable(fn, xpa_pos=0, bound=MANYROW_MAX))
 
 
 # ---------------------------------------------------------------------------
@@ -542,7 +577,7 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
 
     return pl.pallas_call(
         wrapped, grid_spec=gs, out_shape=out_shape, interpret=interpret,
-        name=name)
+        name=name, **_manyrow_kw(out_shape.shape[0]))
 
 
 def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
@@ -551,7 +586,7 @@ def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
     B, KA = xpa.shape
     K = (KA // TKA) * TK
     N = qs.shape[1]
-    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(B, _TN_PREFS_Q4K))
+    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q4K))
     in_specs, out_spec = _q4k_specs(B, TN)
     call = stacked_pallas_call(
         functools.partial(_q4k_matmul_kernel, interpret=interpret,
@@ -566,14 +601,15 @@ def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
     return call(idx, xpa, qs, sm)
 
 
-def rows_vmappable(fn, xpa_pos: int):
+def rows_vmappable(fn, xpa_pos: int, bound: int = TM):
     """Give a fused matmul a vmap rule: batching over the activation
     operand is just more rows for the kernel (weights are shared across
     the batch).  ``custom_partitioning`` has no batching rule in JAX, so
     without this the vmapped engines (parallel/batched.py — the
     mesh-batched and continuous serving paths) raise
     ``NotImplementedError: Batching rule for 'custom_partitioning'`` the
-    first time they meet fused weights."""
+    first time they meet fused weights.  ``bound``: the rows ``fn`` takes
+    in one call (:func:`batched_rows`)."""
     from jax.custom_batching import custom_vmap
 
     @custom_vmap
@@ -590,24 +626,26 @@ def rows_vmappable(fn, xpa_pos: int):
         xpa = args[xpa_pos]
         nb, B, KA = xpa.shape
         # re-chunk the flattened rows: the caller's batched_rows bound was
-        # applied to the PER-LANE shape, so nb*B can exceed _MAX_B and blow
+        # applied to the PER-LANE shape, so nb*B can exceed it and blow
         # the kernel's activation/output VMEM blocks at large lane counts
         out = batched_rows(
             lambda xp: fn(*args[:xpa_pos], xp, *args[xpa_pos + 1:]),
-            xpa.reshape(nb * B, KA))
+            xpa.reshape(nb * B, KA), bound=bound)
         return out.reshape(nb, B, -1), True
 
     return wrapped
 
 
-def stacked_partitioned(raw_fn, sharding_rule: str, interpret: bool):
+def stacked_partitioned(raw_fn, sharding_rule: str, interpret: bool,
+                        bound: int = TM):
     """GSPMD rule shared by every stacked fused matmul — same contract as
     the unstacked kernels (partition over N and rows, never K) plus: the
     layer dim and the index scalar are never split.
 
     ``raw_fn(idx, xpa, *planes, interpret=...)`` is the stacked pallas
     call; plane shardings are derived from rank (value planes (L, N, K/x),
-    scale planes (L, kt, N, 128) — N is always at ``rank - 2``)."""
+    scale planes (L, kt, N, 128) — N is always at ``rank - 2``);
+    ``bound``: :func:`rows_vmappable`'s."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -642,14 +680,14 @@ def stacked_partitioned(raw_fn, sharding_rule: str, interpret: bool):
         infer_sharding_from_operands=infer,
         sharding_rule=sharding_rule,
     )
-    return jax.jit(rows_vmappable(fn, xpa_pos=1))
+    return jax.jit(rows_vmappable(fn, xpa_pos=1, bound=bound))
 
 
 @functools.lru_cache(maxsize=8)
 def _q4k_2d_stacked_partitioned(interpret: bool, variant: str = "cur"):
     return stacked_partitioned(
         functools.partial(_q4k_2d_stacked_raw, variant=variant),
-        "i, b k, l n j, l t n m -> b n", interpret)
+        "i, b k, l n j, l t n m -> b n", interpret, MANYROW_MAX)
 
 
 def q4k_matmul_stacked(x: jax.Array, w: dict, idx,
@@ -663,35 +701,30 @@ def q4k_matmul_stacked(x: jax.Array, w: dict, idx,
     fn = _q4k_2d_stacked_partitioned(
         _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS))
     i1 = jnp.asarray(idx, jnp.int32).reshape(1)
-    y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws), xpa, w["qs"], w["sm"])
+    y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws), xpa, w["qs"], w["sm"],
+                     bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
-_MAX_B = 256  # rows per kernel call: bounds the xpa/out VMEM blocks.
-              # Rows > 128 force TN <= 256 (_tn_prefs_for), keeping the
-              # budget at ~4.3 MB activations + ~6 MB dequant
-              # intermediates.  Chip-measured equal to 128-row/TN=512
-              # chunks for all four fused formats at prefill sizes
-              # (~16.5 ms for (4096, 14336) at 512 rows) with half the
-              # kernel calls.  Shared by every fused kernel via
-              # batched_rows().
-
-
-def batched_rows(fn, xpa: jax.Array, *weights) -> jax.Array:
-    """Run a fused 2D matmul over ``xpa`` (B, K') in row chunks of
-    ``_MAX_B`` so the activation/output VMEM blocks stay bounded for large
-    batch/sequence dims (prefill buckets).  Shared by all fused kernels
-    (Q4_K / Q5_K / Q6_K / Q8_0) — one place to tune the row bound."""
+def batched_rows(fn, xpa: jax.Array, *weights, bound: int = TM) -> jax.Array:
+    """Run a fused 2D matmul over ``xpa`` (B, K'): up to :data:`TM` rows as
+    the one call it always was; more, filled up to a multiple of
+    :data:`TM` with zero rows and cut into calls of ``bound`` rows, so the
+    activation/output VMEM blocks stay bounded for large batch/sequence
+    dims.  ``bound`` is :data:`MANYROW_MAX` for Q4_K and Q6_K, and one
+    :data:`TM` block for the kernels no cell of the benchmark runs (Q5_K,
+    Q8_0, the Q6_K ``pre`` layout: not measured at more rows), which
+    therefore dequantize every weight tile again for each 256 rows."""
     B = xpa.shape[0]
-    if B <= _MAX_B:
+    if B <= TM:
         return fn(xpa, *weights)
-    pad = (-B) % _MAX_B
+    pad = (-B) % TM
     if pad:
         xpa = jnp.concatenate(
             [xpa, jnp.zeros((pad, xpa.shape[1]), xpa.dtype)], axis=0)
     chunks = [
-        fn(xpa[i:i + _MAX_B], *weights)
-        for i in range(0, B + pad, _MAX_B)
+        fn(xpa[i:i + bound], *weights)
+        for i in range(0, B + pad, bound)
     ]
     return jnp.concatenate(chunks, axis=0)[:B]
 
@@ -705,7 +738,7 @@ def q4k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
         permute_x(x).reshape(-1, K).astype(jnp.bfloat16))
     fn = _q4k_2d_partitioned(
         _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS))
-    y = batched_rows(fn, xpa, w["qs"], w["sm"])
+    y = batched_rows(fn, xpa, w["qs"], w["sm"], bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 

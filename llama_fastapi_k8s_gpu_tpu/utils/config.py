@@ -367,8 +367,10 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_KV_POOL_PAGES", int, "KV pool arena size in pages (0 = auto)"),
     Knob("LFKT_KV_SPILL_PAGES", int,
          "host-RAM KV spill tier capacity in pages (0 = off)"),
-    Knob("LFKT_PREFILL_CHUNK", int, "prefill slice tokens (admission + "
-         "serial overlapped prefill)"),
+    Knob("LFKT_PREFILL_CHUNK", int, "prefill slice tokens beside live lanes "
+         "and of a prompt's tail (admission + serial overlapped prefill; "
+         "where nobody decodes behind it a prompt is cut 1024 wide first: "
+         "engine/slices.py)"),
     Knob("LFKT_PREFILL_OVERLAP", int,
          "overlapped-prefill depth (0 = monolithic bucket prefill)"),
     Knob("LFKT_ADM_BUDGET", int,

@@ -83,6 +83,15 @@ def _planes(fmt, n, k, layers=None):
     return {"q8": S(*lead, n, k, dtype=i8), "sm8": sm}
 
 
+def _slice_widths(cfg):
+    """The prefill slice widths an engine at the default narrow width cuts
+    this block's prompts into: narrow, and the wide one where the block
+    takes it (engine/slices.py)."""
+    from llama_fastapi_k8s_gpu_tpu.engine.slices import wide_width
+
+    return sorted({256, wide_width(256, cfg.widest_slice)})
+
+
 def _matmuls(fmt):
     from llama_fastapi_k8s_gpu_tpu.ops import pallas as P
 
@@ -108,15 +117,16 @@ def _matmuls(fmt):
     ("q4k", 4096, 11008), ("q6k", 12288, 4096),
 ])
 def test_fused_matmul_compiles(one_chip, fmt, k, n):
-    """Unstacked and stacked, one decode row and a 512-row prefill bucket
-    (which walks ``batched_rows`` and the TN<=256 cap).  The compiled
+    """Unstacked and stacked, one decode row, a 512-row prefill bucket and
+    a 1024-row wide slice (more than 256 rows: ONE call of one row block
+    under the N tile and the VMEM limit of its own).  The compiled
     program must still hold the kernel — this is also where
     ``_lane_repeat``'s ``pltpu.repeat`` branch meets the compiler."""
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import q4k_compatible
 
     assert q4k_compatible(n, k, for_tpu=True)
     plain, stacked = _matmuls(fmt)
-    for rows in (1, 512):
+    for rows in (1, 512, 1024):
         txt = _compile(one_chip, lambda x, w: plain(x, w, interpret=False),
                        S(rows, k), _planes(fmt, n, k))
         assert "tpu_custom_call" in txt
@@ -126,6 +136,62 @@ def test_fused_matmul_compiles(one_chip, fmt, k, n):
             one_chip, lambda x, w, i: stacked(x, w, i, interpret=False),
             S(rows, k), _planes(fmt, n, k, layers=2), S(dtype=i32))
         assert "tpu_custom_call" in txt
+
+
+# the dense block at the published widths of the two configurations with
+# long-prompt cells, in the Q4_K_M mix as the chip serves it (fused planes):
+# (name, layers, ffn width, vocabulary, n_ctx)
+@pytest.mark.parametrize("name,L,F,V,n_ctx", [
+    ("solar-serial", 48, 14336, 32000, 4096),
+    ("mistral-8lane", 32, 14336, 32000, 4096),
+])
+def test_wide_prefill_slice_of_the_dense_block_compiles(one_chip, monkeypatch,
+                                                        name, L, F, V, n_ctx):
+    """The prefill slice program of the dense block over FUSED Q4_K / Q6_K
+    planes compiles for the chip at the narrow width and at the wide one
+    (engine/slices.py): every fused matmul of the wide slice is ONE many-row
+    call of 1024 rows, none is cut into 256-row calls."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import prefill_chunk_jit
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+
+    # a whole-program compile on a CPU host would lower every kernel in
+    # interpret form: the chip's form is what is asked about
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    D, H, KV = 4096, 32, 8
+    cfg = ModelConfig(vocab_size=V, dim=D, n_layers=L, n_heads=H,
+                      n_kv_heads=KV, ffn_dim=F, n_ctx=n_ctx, rope_theta=1e4,
+                      attn_impl="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    kv = KV * (D // H)
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": _planes("q6k", V, D),
+        "layers": {
+            "attn_norm": S(L, D, dtype=f32), "ffn_norm": S(L, D, dtype=f32),
+            "wq": _planes("q4k", D, D, L), "wk": _planes("q4k", kv, D, L),
+            "wv": _planes("q6k", kv, D, L), "wo": _planes("q4k", D, D, L),
+            "w_gate": _planes("q4k", F, D, L), "w_up": _planes("q4k", F, D, L),
+            "w_down": _planes("q6k", D, F, L)}})
+    cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+    assert _slice_widths(cfg) == [256, 1024]
+    for rows in _slice_widths(cfg):
+        text = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile().as_text()
+        # the layer's seven matmuls, each one call whose row block is the
+        # whole slice (the head has one row)
+        calls = re.findall(r"%q[46]k_matmul_manyrow\S* = f32\[(\d+),\d+\]"
+                           r"\S* custom-call\(", text)
+        assert len(calls) >= 7 and set(calls) == {str(rows)}, (rows, calls)
+        assert "flash_attention" in text
 
 
 # the routed layer of OLMoE-1B-7B at its published widths (64 experts of
@@ -524,10 +590,13 @@ def test_evabyte_step_reads_window_and_summaries_in_blocks(one_chip, name,
         < 3 * one_layer_window
     if not lanes:       # the admission slice into the scratch cache
         cache = place(jax.eval_shape(lambda: init_cache(cfg)))
-        sliced = prefill_chunk_jit.__wrapped__.lower(
-            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
-            place(S(dtype=i32)), cache).compile()
-        assert sliced.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+        for rows in _slice_widths(cfg):     # this block keeps the narrow one
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 512 * 2 ** 20 * rows // 256
+        assert _slice_widths(cfg) == [256]
 
 
 # BENCHMARK.json's minicpm-sala configuration at its published widths, n_ctx
@@ -628,11 +697,14 @@ def test_sala_stack_compiles_with_no_ring_sized_copy(one_chip, name, lanes):
         < max(state_leaf // 2, 32 * 2 ** 20)
     if not lanes:       # the admission slice into the scratch cache
         cache = place(jax.eval_shape(lambda: init_cache(cfg)))
-        sliced = prefill_chunk_jit.__wrapped__.lower(
-            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
-            place(S(dtype=i32)), cache).compile()
-        assert "flash_attention" in sliced.as_text()
-        assert sliced.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            assert "flash_attention" in sliced.as_text()
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 512 * 2 ** 20 * rows // 256
+        assert _slice_widths(cfg) == [256, 1024]
 
 
 def _placed_on_four(topo):
@@ -796,8 +868,11 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
     if not lanes:       # the admission slice into the scratch cache
         cache = place(jax.eval_shape(lambda: init_cache(cfg)))
-        sliced = prefill_chunk_jit.__wrapped__.lower(
-            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
-            place(S(dtype=i32)), cache).compile()
-        assert "q4k_expert_matmul_manyrow" in sliced.as_text()
-        assert sliced.memory_analysis().temp_size_in_bytes < 1024 * 2 ** 20
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            assert "q4k_expert_matmul_manyrow" in sliced.as_text()
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 1024 * 2 ** 20 * rows // 256
+        assert _slice_widths(cfg) == [256, 1024]

@@ -551,7 +551,7 @@ def test_rows_written_count_only_where_the_kernel_writes():
                                     ring_rows_written=0)
         Engine._note_cache_read(eng, [7, 40], 5)
         assert eng.ring_rows_written == rows
-        eng.eva_counts = eng.sala_counts = {}
+        eng.eva_counts = eng.sala_counts = eng.slice_tokens = {}
         assert Engine.cache_read_gauges(eng)["ring_rows_written_total"] == rows
 
 

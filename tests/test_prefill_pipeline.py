@@ -183,3 +183,84 @@ def test_slice_events_on_prefill_span(model_path):
     for c in slices:
         assert c["attrs"]["tokens"] > 0 and c["duration_s"] >= 0.0
         assert prefill["start"] <= c["start"] <= c["end"] <= prefill["end"]
+
+
+# ---------------------------------------------------------------------------
+# wide slices where nobody decodes behind them (engine/slices.py)
+# ---------------------------------------------------------------------------
+
+#: prompts of a few wide slices and a narrow tail on every tiny file.  (The
+#: state + ring file's greedy text sits on near-ties: of eight such prompts
+#: three flip a token between the serial and the lane engine or between
+#: slices of 8 and of 16, on the parent too.  These two are among the five
+#: that read the same under all four; benchmarks/compare_sala.py holds the
+#: wide slices' numbers to the float32 reference.)
+LONG = [[{"role": "user",
+          "content": "the quick brown fox jumps over the lazy dog " * 4}],
+        [{"role": "user", "content": "red green blue yellow " * 8}]]
+
+#: cache kind -> (the tiny file's writer, engine keywords, wide width here)
+KINDS = {
+    "ring": ("write_tiny_llama_gguf",
+             dict(n_ctx=512, prefill_chunk=16, prefill_buckets=BUCKETS), 64),
+    "state+ring": ("write_tiny_sala_gguf", dict(n_ctx=512, prefill_chunk=8),
+                   32),
+    "window+summaries": ("write_tiny_evabyte_gguf",
+                         dict(n_ctx=1280, prefill_chunk=16), 64),
+    "latent-ring": ("write_tiny_mla_gguf",
+                    dict(n_ctx=512, prefill_chunk=16), 64),
+}
+
+
+@pytest.fixture(scope="module")
+def kind_paths(tmp_path_factory, model_path):
+    from llama_fastapi_k8s_gpu_tpu import testing
+
+    out = {"ring": model_path}
+    for kind, (writer, _, _) in KINDS.items():
+        if kind not in out:
+            out[kind] = str(tmp_path_factory.mktemp("kind") / "tiny.gguf")
+            getattr(testing, writer)(out[kind])
+    return out
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["serial", "lanes"])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_wide_slices_match_narrow_slices(kind_paths, monkeypatch, kind, lanes):
+    """Greedy identity, wide against narrow, through both engines on the
+    tiny file of each cache kind: where nobody decodes behind a slice the
+    plan cuts wide, and a request produces what the narrow slices gave.
+    (The one wide width is a constant, 1024: here it is set to four narrow
+    slices of the tiny file, and the state's piece to one.)"""
+    from llama_fastapi_k8s_gpu_tpu.engine import slices
+    from llama_fastapi_k8s_gpu_tpu.models import sala
+
+    _, kw, wide = KINDS[kind]
+    narrow = kw["prefill_chunk"]
+    monkeypatch.setattr(sala, "LIN_PIECE", narrow)
+
+    def run(width):
+        monkeypatch.setattr(slices, "WIDE_SLICE", width)
+        if lanes:
+            eng = ContinuousEngine(kind_paths[kind], batch_size=lanes,
+                                   decode_chunk=4, max_gen_tokens=16, **kw)
+        else:
+            eng = Engine(kind_paths[kind], decode_chunk=4, max_gen_tokens=16,
+                         prefix_cache=False, **kw)
+        try:
+            assert eng._wide_slice == max(width, narrow)
+            assert eng.cfg.cache_kind == kind
+            return _texts(eng, LONG + PROMPTS[:1]), dict(eng.slice_tokens)
+        finally:
+            if lanes:
+                eng.shutdown()
+
+    narrow_texts, narrow_tokens = run(0)
+    wide_texts, wide_tokens = run(wide)
+    assert narrow_tokens["wide"] == 0
+    # the long prompts went through wide slices (a lane's claim on the
+    # template's prefix starts the second one off the wide grid), their
+    # tails and the short prompt through narrow ones
+    assert wide_tokens["wide"] >= 2 * wide and wide_tokens["wide"] % wide == 0
+    assert wide_tokens["narrow"] > 0
+    assert wide_texts == narrow_texts

@@ -12,6 +12,12 @@ warm-up discarded (docs/PERF.md "Measurement hygiene"), then the mean of
 are part of every jit cache key (ops/pallas/qmatmul.py:_env_variant).
 
 Prints one JSON object (diagnostics, not the driver bench contract).
+
+``python tools/kernel_microbench.py rows`` is the ROW sweep of the fused
+matmuls a prefill slice runs (:func:`rows_sweep`): us a call and us per 256
+rows at 8 / 256 / 512 / 1024 / 2048 rows, as one many-row call and as the
+256-row calls a wider operand was cut into before (docs/PERF.md "Rows of a
+fused matmul call" holds its table).
 """
 
 from __future__ import annotations
@@ -97,6 +103,45 @@ def make_weight(fmt: str, wf: np.ndarray) -> dict:
     return jax.device_put(mk(wf))
 
 
+# the row sweep: (format, N, K) of the widest matmuls of the two dense
+# configurations with long-prompt cells (solar: ffn 14336, sala: ffn 16384)
+ROW_SHAPES = [("q4k", 14336, 4096), ("q6k", 4096, 14336),
+              ("q4k", 16384, 4096), ("q6k", 4096, 16384)]
+ROW_COUNTS = (8, 256, 512, 1024, 2048)
+ROW_ITERS = 200
+MXU_TFLOPS = 197.0  # v5e bf16 peak (spec)
+
+
+def rows_sweep(linear) -> list:
+    """us a call of ``rows`` rows, as ``linear`` serves it (``form``
+    ``call``) and cut into 256-row calls (``cut256``: what every call of
+    more rows was before the many-row kernel), beside the MXU's time for
+    the call's FLOPs and the few-row call (8 rows: the weight pass alone)."""
+    rng = np.random.default_rng(0)
+    rows = []
+    for fmt, n, k in ROW_SHAPES:
+        wf = rng.standard_normal((n, k)).astype(np.float32) * (k ** -0.5)
+        w = make_weight(fmt, wf)
+
+        def cut256(x, w):
+            return jnp.concatenate([linear(x[i:i + 256], w)
+                                    for i in range(0, x.shape[0], 256)])
+
+        for b in ROW_COUNTS:
+            for form, fn in (("call", linear), ("cut256", cut256)):
+                if form == "cut256" and b <= 256:
+                    continue
+                dt = timed_chain(fn, w, b, k, n, ROW_ITERS)
+                row = {"fmt": fmt, "n": n, "k": k, "rows": b, "form": form,
+                       "us": round(dt * 1e6, 1),
+                       "us_per_256": round(dt * 1e6 * 256 / max(b, 256), 1),
+                       "mxu_us": round(2.0 * b * n * k / MXU_TFLOPS / 1e6, 1)}
+                rows.append(row)
+                print(json.dumps(row), file=sys.stderr, flush=True)
+        del w
+    return rows
+
+
 def timed_chain(linear_fn, w, b: int, k: int, n: int, iters: int) -> float:
     """Mean per-matmul time over an ``iters``-step ON-DEVICE chain.
 
@@ -109,7 +154,8 @@ def timed_chain(linear_fn, w, b: int, k: int, n: int, iters: int) -> float:
     def chain(x):
         def body(x, _):
             y = linear_fn(x, w)                   # (B, N) bf16
-            r = jnp.sum(y, axis=1, keepdims=True).astype(jnp.bfloat16)
+            # (the first 128 columns: a many-row output is not read whole)
+            r = jnp.sum(y[:, :128], axis=1, keepdims=True).astype(jnp.bfloat16)
             return x + r * jnp.bfloat16(1e-8), ()
 
         x, _ = jax.lax.scan(body, x, None, length=iters)
@@ -133,6 +179,11 @@ def main() -> None:
     from llama_fastapi_k8s_gpu_tpu.ops.linear import linear
 
     dev = jax.devices()[0]
+    if sys.argv[1:] == ["rows"]:
+        print(json.dumps({"device": str(dev), "iters": ROW_ITERS,
+                          "mxu_tflops": MXU_TFLOPS,
+                          "rows": rows_sweep(linear)}), flush=True)
+        return
     out: dict = {"device": str(dev), "iters": ITERS, "hbm_gbps": HBM_GBPS}
     rows = []
     rng = np.random.default_rng(0)
