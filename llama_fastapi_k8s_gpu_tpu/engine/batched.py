@@ -190,6 +190,23 @@ class MeshEngine(Engine):
                 "pass, and its sparse layers select per query in slices; "
                 "use the continuous scheduler")
 
+    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
+        super()._refuse_for_hybrid_cache(kv_paged)
+        dp, tp = self._mesh_shape
+        if tp > 1:
+            raise ValueError(
+                f"LFKT_MESH_TP={tp} cannot serve architecture 'exaone-moe': "
+                "parallel/mesh.py shards one stack of layers and one ring, "
+                "and has no layout for two feed-forward kinds or a leaf "
+                "pair per attention kind; experts over a mesh are ROADMAP "
+                "B-I 5")
+        if not self._SLICED_ADMISSION:
+            raise ValueError(
+                "LFKT_SCHEDULER=cycle cannot serve architecture "
+                "'exaone-moe': it prefills a whole prompt in one vmapped "
+                "pass, and a window layer takes a prompt slice by slice "
+                "against its window slots; use the continuous scheduler")
+
     def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
         super()._refuse_for_latent_cache(kv_paged)
         dp, tp = self._mesh_shape

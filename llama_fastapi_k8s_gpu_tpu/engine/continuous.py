@@ -295,7 +295,7 @@ class ContinuousEngine(MeshEngine):
     # alone adds, /metrics reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
                       "_thread", "ring_slots", "ring_rows_written",
-                      "eva_counts", "sala_counts")
+                      "eva_counts", "sala_counts", "hybrid_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,

@@ -31,7 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gguf import GGUFFile
-from ..models.config import LATENT_RING, RING, STATE_RING, ModelConfig
+from ..models.config import (
+    GLOBAL, LATENT_RING, RING, STATE_RING, WINDOW, WINDOW_GLOBAL_RING,
+    ModelConfig)
 from ..models.generate import (
     generate_chunk_jit,
     init_state,
@@ -40,7 +42,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models import eva, mla, sala
+from ..models import eva, hybrid, mla, sala
 from ..models.llama import (
     decode_chunk_slots, decode_kernel_block, init_cache, ring_write_impl)
 from ..models.params import load_params, synth_params
@@ -245,6 +247,11 @@ class Engine:
         self.sala_counts = {"state_updates": 0, "queries_dense": 0,
                             "queries_sparse": 0, "blocks_read": 0,
                             "blocks_visible": 0, "kc_written": 0}
+        # and for the window + global cache (models/hybrid.py chunk_counts;
+        # ring_slots is their sum over kinds, in layer-slots): /metrics
+        # {window,global}_slots_*_total
+        self.hybrid_counts = {"window_read": 0, "window_live": 0,
+                              "global_read": 0, "global_live": 0}
         # prompt tokens prefilled (padding included), by the width of the
         # program that took them: ``wide`` is more than the narrow width
         # (engine/slices.py); /metrics prefill_slice_tokens_total{width=}
@@ -350,6 +357,8 @@ class Engine:
         if self.cfg.cache_kind == LATENT_RING:
             self._refuse_for_latent_cache(bool(kv_paged))
             attn_impl = "xla"   # its attention is models/mla.py's own loop
+        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
+            self._refuse_for_hybrid_cache(bool(kv_paged))
         # the compile probes of the attention side: one phase of the
         # timeline when any of them ran
         probing = Phase("attn_probes", meter="cache", kernels=[])
@@ -581,11 +590,44 @@ class Engine:
                 "pool page is a run of K and V slots per KV head, and its "
                 "cache is one latent row a position for all heads")
 
+    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
+        """The same for the window + global cache of ``exaone-moe``
+        (models/hybrid.py): an int8 cache (the decode kernel that serves
+        both leaf kinds reads bf16) and the paged pool (one page geometry,
+        runs of ``n_ctx`` slots by position: a window leaf wraps; a page
+        geometry per kind is ROADMAP B-I 2).  Subclasses add the meshes."""
+        if self.cfg.kv_dtype == "int8":
+            raise ValueError(
+                "LFKT_KV_DTYPE=int8 cannot serve architecture 'exaone-moe': "
+                "its window + global cache is bf16 only")
+        if kv_paged:
+            raise ValueError(
+                "LFKT_KV_PAGED=1 cannot serve architecture 'exaone-moe': a "
+                "pool page is a run of ring slots by token position, and "
+                "its window layers keep window slots that wrap")
+
     @property
     def cache_kind(self) -> dict | None:
         """The /health ``engine.cache`` block of a cache that is no ring
         (None for the ring: its /health is what it was): the kind's sizes,
         and the reuse it does without as a property, not a degrade."""
+        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
+            cfg = self.cfg
+            return {
+                "kind": WINDOW_GLOBAL_RING,
+                "window": cfg.sliding_window,
+                "window_slots": cfg.window_slots,
+                "window_layers": cfg.n_attn_layers(WINDOW),
+                "global_layers": cfg.n_attn_layers(GLOBAL),
+                "rotated": list(cfg.rope_kinds),
+                "bytes_per_lane": hybrid.cache_nbytes(cfg),
+                "dense_layers": cfg.n_dense_layers,
+                "routed_layers": hybrid.n_moe_layers(cfg),
+                "experts_held": [cfg.experts_first, cfg.n_held],
+                "experts_routed": cfg.n_experts,
+                "prefix_reuse": "off: a wrapped window cannot be rolled "
+                                "back to a prefix's end",
+                "kv_paged": "refused at start"}
         if self.cfg.cache_kind == LATENT_RING:
             cfg = self.cfg
             reuse = getattr(self, "_lane_prefix", self._prefix_cache)
@@ -941,6 +983,19 @@ class Engine:
                                          live).items():
                 self.eva_counts[k] += v
             return
+        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
+            dispatched = wanted if live is None else live
+            if ring_write_impl(self.cfg) == "kernel":
+                self.ring_rows_written += \
+                    len(dispatched) * n_steps * self.cfg.n_layers
+            c = hybrid.chunk_counts(wanted, n_steps, self.cfg,
+                                    max(dispatched, default=0))
+            for k, v in c.items():
+                self.hybrid_counts[k] += v
+            # the ring totals keep their meaning: the sum over kinds
+            self.ring_slots["read"] += c["window_read"] + c["global_read"]
+            self.ring_slots["live"] += c["window_live"] + c["global_live"]
+            return
         first_sparse = None
         if self.cfg.cache_kind == STATE_RING:
             for k, v in sala.chunk_counts(wanted, n_steps, self.cfg).items():
@@ -985,6 +1040,13 @@ class Engine:
                 eva_summaries_read_total=c["summaries_read"],
                 eva_summaries_live_total=c["summaries_live"],
                 eva_windows_closed_total=c["windows_closed"])
+        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
+            c = self.hybrid_counts
+            out.update(
+                window_slots_read_total=c["window_read"],
+                window_slots_live_total=c["window_live"],
+                global_slots_read_total=c["global_read"],
+                global_slots_live_total=c["global_live"])
         if self.cfg.cache_kind == LATENT_RING:
             # the ring's own arithmetic (blocks of models/mla.py
             # LATENT_BLOCK up to the largest live lane's position), under

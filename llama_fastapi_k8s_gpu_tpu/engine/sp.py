@@ -66,6 +66,12 @@ class SPEngine(Engine):
             "ring passes K and V chunks per head between chips, and its "
             "cache is one latent row a position for all heads")
 
+    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
+        raise ValueError(
+            "LFKT_MESH_SP > 1 cannot serve architecture 'exaone-moe': the sp "
+            "ring shards the n_ctx slots of a KV ring, and its window "
+            "layers keep window slots that wrap")
+
     def __init__(self, model_path: str | None, *, sp: int = 2, tp: int = 1,
                  n_ctx: int = 4096, **kw):
         if sp < 2:

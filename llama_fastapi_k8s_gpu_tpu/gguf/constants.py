@@ -154,10 +154,24 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: hold that many experts from that one on, of the router's
 #: ``expert_count``: one chip's share of an expert-parallel layer; absent:
 #: all).  Q and K rotate on interleaved pairs (ggml's NORM mode).
+#: ``exaone-moe`` (llama.cpp's name for the K-EXAONE family as remembered;
+#: models/hybrid.py) is an attention kind per layer over the fifth cache
+#: kind, and ``deepseek2``'s feed-forward kinds.  Tensors: ``blk.N.attn_q``
+#: (heads x key_length, dim), ``attn_k`` / ``attn_v`` (kv heads x
+#: key_length, dim), ``attn_q_norm`` / ``attn_k_norm`` (key_length: RMSNorm
+#: over EACH head), ``attn_output`` (dim, heads x key_length), ``attn_norm``,
+#: ``ffn_norm``; the feed-forward tensors and keys of ``deepseek2`` above
+#: (``leading_dense_block_count`` ... ``expert_group_used_count``,
+#: ``expert_held_first`` / ``expert_held_count``).  Its own keys:
+#: ``attention.key_length`` / ``value_length`` (a head's width, which is
+#: not ``embedding_length / head_count``), ``attention.sliding_window`` and
+#: ``attention.sliding_window_pattern`` (n: layer i is a window layer
+#: unless (i + 1) % n == 0, llama.cpp's ``set_swa_pattern``).  Window
+#: layers rotate Q and K (rotate-half), global layers do not.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
-                        "deepseek2")
+                        "deepseek2", "exaone-moe")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
@@ -165,7 +179,8 @@ SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
 #: converter permutes Q/K rows so that the pairs are (2i, 2i+1) (ggml's
 #: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
 #: the whole projection.
-NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte", "minicpm-sala")
+NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte", "minicpm-sala",
+                           "exaone-moe")
 
 
 def align_up(n: int, alignment: int) -> int:

@@ -18,6 +18,10 @@ from ..gguf.constants import NEOX_ROPE_ARCHITECTURES
 #: the cache kinds' names (``ModelConfig.cache_kind``; docs/KV_CACHE.md)
 RING, WINDOW_SUMMARIES, STATE_RING = "ring", "window+summaries", "state+ring"
 LATENT_RING = "latent-ring"
+WINDOW_GLOBAL_RING = "window+global-ring"
+
+#: the attention kinds of a layer (``ModelConfig.attn_kinds``)
+WINDOW, GLOBAL = "window", "global"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +151,21 @@ class ModelConfig:
     # (expert parallelism's share of a layer, without its exchange).
     experts_first: int = 0
     experts_held: int = 0
+    # The attention KIND is the layer's (models/hybrid.py; ``exaone-moe``):
+    # each layer in the file's order ``"window"`` (causal over the last
+    # ``sliding_window`` positions; its cache leaf holds WINDOW slots that
+    # wrap) or ``"global"`` (causal over all; a leaf of ``n_ctx`` slots);
+    # empty for every other file, whose one ``sliding_window`` is a mask
+    # term on a ring of ``n_ctx`` slots.  ``rope_kinds``: the kinds whose
+    # layers rotate Q and K (the others attend unrotated).
+    attn_kinds: tuple = ()
+    rope_kinds: tuple = (WINDOW, GLOBAL)
+    # a head's width where the file states it (``attention.key_length``;
+    # 0: ``dim // n_heads``) and RMSNorm of Q and K over EACH head's width
+    # (``attn_{q,k}_norm`` of ``head_dim``; ``qk_norm`` is over the whole
+    # projection)
+    head_width: int = 0
+    qk_norm_per_head: bool = False
 
     @property
     def n_held(self) -> int:
@@ -155,16 +174,29 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_width or self.dim // self.n_heads
+
+    def n_attn_layers(self, kind: str) -> int:
+        return sum(k == kind for k in self.attn_kinds)
+
+    @property
+    def window_slots(self) -> int:
+        """Slots a window layer's cache leaf holds: the window, filled up
+        to the 16 rows a bf16 tile stores by (what the decode kernel's
+        block is a multiple of); never ``n_ctx``."""
+        return -(-self.sliding_window // 16) * 16
 
     @property
     def cache_kind(self) -> str:
         """The NAME of the cache kind a sequence of this file holds
         (docs/KV_CACHE.md "Cache kinds"), read here and nowhere else:
         ``ring``, ``window+summaries`` (models/eva.py), ``state+ring``
-        (models/sala.py) or ``latent-ring`` (models/mla.py)."""
+        (models/sala.py), ``latent-ring`` (models/mla.py) or
+        ``window+global-ring`` (models/hybrid.py)."""
         if self.mixers:
             return STATE_RING
+        if self.attn_kinds:
+            return WINDOW_GLOBAL_RING
         if self.kv_lora_rank:
             return LATENT_RING
         return WINDOW_SUMMARIES if self.eva_window else RING
@@ -187,7 +219,7 @@ class ModelConfig:
     def n_linear_weights(self) -> int:
         """About how many weights the layers' matrices hold, every expert
         included: what the ``weight_format="auto"`` size test weighs."""
-        if self.kv_lora_rank:
+        if self.kv_lora_rank or self.attn_kinds:
             routed = 3 * self.dim * self.expert_ffn_dim * (
                 self.n_held + self.n_shared_experts)
             return self.n_layers * 4 * self.dim * self.dim \
@@ -265,6 +297,8 @@ class ModelConfig:
         mla = {}
         if arch == "deepseek2":
             mla = _deepseek2_fields(h, n_heads)
+        if arch == "exaone-moe":
+            mla = _exaone_moe_fields(h, n_heads, window)
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -283,6 +317,7 @@ class ModelConfig:
             # build_norm over the whole Qcur/Kcur; deepseek2 reads its key
             norm_topk_prob=mla.pop("norm_topk_prob", False),
             qk_norm=arch == "olmoe",
+            qk_norm_per_head=arch == "exaone-moe",
             rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
             **eva,
             **sala,
@@ -315,29 +350,6 @@ def _deepseek2_fields(h, n_heads: int) -> dict:
             f"deepseek2: attention.key_length {d_qk} must exceed the even "
             f"rope.dimension_count {d_r} (a head's key is its unrotated "
             "part, then the shared rotated one)")
-    n_exp = int(h("expert_count", 0) or 0)
-    groups = int(h("expert_group_count", 1) or 1)
-    used_groups = int(h("expert_group_used_count", groups) or groups)
-    if n_exp % groups or not 1 <= used_groups <= groups:
-        raise ValueError(
-            f"deepseek2: {n_exp} experts in {groups} groups, "
-            f"{used_groups} used")
-    k = int(h("expert_used_count", 0) or 0)
-    if n_exp and (n_exp // groups < 2 or k > used_groups * (n_exp // groups)):
-        raise ValueError(
-            f"deepseek2: a group's score is its two largest, and "
-            f"{k} picks must fit {used_groups} groups of {n_exp // groups}")
-    gating = {1: "softmax", 2: "sigmoid"}.get(
-        int(h("expert_gating_func", 1) or 1))
-    if gating is None:
-        raise ValueError(
-            f"deepseek2: expert_gating_func {h('expert_gating_func')!r} "
-            "(1: softmax, 2: sigmoid)")
-    first = int(h("expert_held_first", 0) or 0)
-    held = int(h("expert_held_count", 0) or 0)
-    if held and not 0 <= first <= first + held <= n_exp:
-        raise ValueError(
-            f"deepseek2: experts held {first}..{first + held} of {n_exp}")
     yarn = {}
     if str(h("rope.scaling.type", "none")) == "yarn":
         factor = float(need("rope.scaling.factor"))
@@ -353,7 +365,38 @@ def _deepseek2_fields(h, n_heads: int) -> dict:
             attn_mscale=m * m)
     return dict(
         q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_qk - d_r,
-        qk_rope_dim=d_r, v_head_dim=d_v,
+        qk_rope_dim=d_r, v_head_dim=d_v, **_routed_fields(h, "deepseek2"),
+        **yarn)
+
+
+def _routed_fields(h, arch: str) -> dict:
+    """The keys of a stack of leading dense layers, then routed ones with
+    a grouped router, shared experts and a HELD share of the experts
+    (``deepseek2``, ``exaone-moe``: gguf/constants.py; models/routed.py)."""
+    n_exp = int(h("expert_count", 0) or 0)
+    groups = int(h("expert_group_count", 1) or 1)
+    used_groups = int(h("expert_group_used_count", groups) or groups)
+    if n_exp % groups or not 1 <= used_groups <= groups:
+        raise ValueError(
+            f"{arch}: {n_exp} experts in {groups} groups, "
+            f"{used_groups} used")
+    k = int(h("expert_used_count", 0) or 0)
+    if n_exp and (n_exp // groups < 2 or k > used_groups * (n_exp // groups)):
+        raise ValueError(
+            f"{arch}: a group's score is its two largest, and "
+            f"{k} picks must fit {used_groups} groups of {n_exp // groups}")
+    gating = {1: "softmax", 2: "sigmoid"}.get(
+        int(h("expert_gating_func", 1) or 1))
+    if gating is None:
+        raise ValueError(
+            f"{arch}: expert_gating_func {h('expert_gating_func')!r} "
+            "(1: softmax, 2: sigmoid)")
+    first = int(h("expert_held_first", 0) or 0)
+    held = int(h("expert_held_count", 0) or 0)
+    if held and not 0 <= first <= first + held <= n_exp:
+        raise ValueError(
+            f"{arch}: experts held {first}..{first + held} of {n_exp}")
+    return dict(
         n_dense_layers=int(h("leading_dense_block_count", 0) or 0),
         expert_ffn_dim=int(h("expert_feed_forward_length", 0) or 0),
         n_shared_experts=int(h("expert_shared_count", 0) or 0),
@@ -361,7 +404,39 @@ def _deepseek2_fields(h, n_heads: int) -> dict:
         n_groups_used=used_groups,
         expert_weights_scale=float(h("expert_weights_scale", 1.0) or 1.0),
         norm_topk_prob=bool(h("expert_weights_norm", False)),
-        experts_first=first, experts_held=held, **yarn)
+        experts_first=first, experts_held=held)
+
+
+def _exaone_moe_fields(h, n_heads: int, window: int) -> dict:
+    """The ``exaone-moe`` keys (gguf/constants.py) as ``ModelConfig``
+    fields; a ValueError naming what the block here cannot compute."""
+    period = int(h("attention.sliding_window_pattern", 0) or 0)
+    n_layers = int(h("block_count"))
+    if period < 2 or window < 1:
+        raise ValueError(
+            f"exaone-moe: attention.sliding_window_pattern {period} and "
+            f"attention.sliding_window {window}: the block here is window "
+            "layers with every pattern-th layer global, window >= 1")
+    d_k = int(h("attention.key_length", 0) or 0)
+    if d_k != int(h("attention.value_length", d_k) or d_k):
+        raise ValueError(
+            "exaone-moe: attention.key_length and value_length differ")
+    n_kv = int(h("attention.head_count_kv", n_heads))
+    if n_heads % n_kv:
+        raise ValueError(
+            f"exaone-moe: {n_heads} heads on {n_kv} KV heads")
+    if str(h("rope.scaling.type", "none")) not in ("none", "linear") \
+            or float(h("rope.scaling.factor", 1.0) or 1.0) != 1.0:
+        raise ValueError(
+            "exaone-moe: rope.scaling is not served (rope scaling per "
+            "layer kind: ROADMAP B-I 1)")
+    # llama.cpp's ``set_swa_pattern(n)``: layer i is a window layer unless
+    # (i + 1) % n == 0; the family's hybrid rule rotates the window layers'
+    # Q and K and leaves the global layers' unrotated
+    kinds = tuple(GLOBAL if (i + 1) % period == 0 else WINDOW
+                  for i in range(n_layers))
+    return dict(attn_kinds=kinds, rope_kinds=(WINDOW,), head_width=d_k,
+                **_routed_fields(h, "exaone-moe"))
 
 
 # Canonical full-size configs (for synthesis / benches; no network egress, so

@@ -15,7 +15,9 @@ preallocated, donated KV cache.  Design choices are TPU-first:
   lane's own, ops/pallas/attention.py ``flash_attention_decode``, or the
   XLA loop of :func:`decode_attention` under one bound for all lanes:
   :func:`decode_kernel_block` says which);
-- sliding-window masking (Mistral) is the same mask with one extra term;
+- sliding-window masking (Mistral) is the same mask with one extra term
+  (a file whose window is a LAYER kind's keeps window slots for those
+  layers instead: models/hybrid.py);
 - matmuls go through ``ops.linear`` so bf16 / int8 / (later) fused-Q4_K
   weights are interchangeable without touching the graph.
 - the feed-forward kind is the configuration's: dense SwiGLU, or
@@ -38,7 +40,8 @@ import jax.numpy as jnp
 from ..ops import linear
 from ..ops.linear import linear_at
 from . import eva
-from .config import LATENT_RING, STATE_RING, ModelConfig
+from .config import (
+    LATENT_RING, STATE_RING, WINDOW_GLOBAL_RING, ModelConfig)
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -108,11 +111,17 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     compressed keys for the sparse layers, a float32 state for the linear
     ones, each as deep as its kind has layers.  ``"latent-ring"`` is the
     fourth (models/mla.py): one row a layer and position, the normed latent
-    and the rotated key all heads share."""
+    and the rotated key all heads share.  ``"window+global-ring"`` is the
+    fifth (models/hybrid.py): a leaf pair per attention kind, a window
+    layer's of window slots that wrap, a global layer's of ``n_ctx``."""
     if cfg.cache_kind == STATE_RING:
         from . import sala
 
         return sala.init_cache(cfg, dtype)
+    if cfg.cache_kind == WINDOW_GLOBAL_RING:
+        from . import hybrid
+
+        return hybrid.init_cache(cfg, dtype)
     if cfg.cache_kind == LATENT_RING:
         from . import mla
 
@@ -146,6 +155,10 @@ def cache_nbytes(cfg: ModelConfig) -> int:
         from . import mla
 
         return mla.cache_nbytes(cfg)
+    if cfg.cache_kind == WINDOW_GLOBAL_RING:
+        from . import hybrid
+
+        return hybrid.cache_nbytes(cfg)
     if cfg.eva_window:
         return eva.cache_nbytes(cfg)
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
@@ -249,7 +262,8 @@ def ring_write_impl(cfg: ModelConfig) -> str | None:
     then stores nothing), else ``xla`` (``dynamic_update_slice``: int8
     rings, mesh and sequence-parallel engines, the CPU, and the ring layers
     of models/sala.py, which write before they call the kernel; prefill
-    slices on every path).  None on a cache that has no ring."""
+    slices on every path; both leaf kinds of models/hybrid.py go the same
+    way).  None on a cache that has no ring."""
     if cfg.eva_window:
         return None
     kernel = decode_kernel_block(cfg) and cfg.cache_kind != STATE_RING
@@ -646,6 +660,13 @@ def forward(
 
         return mla.forward(params, cfg, tokens, pos_offset, cache, last_idx,
                            return_all, live, with_stats, with_picks, kv_bound)
+    if cfg.cache_kind == WINDOW_GLOBAL_RING:
+        # an attention kind per layer on leaves of its own size
+        from . import hybrid
+
+        return hybrid.forward(params, cfg, tokens, pos_offset, cache,
+                              last_idx, return_all, live, with_stats,
+                              with_picks, kv_bound)
     if cfg.eva_window and S > cfg.eva_window:
         raise ValueError(
             f"architecture 'evabyte': {S} positions in one pass, its window "

@@ -25,16 +25,11 @@
   tests hold the absorbed form to.  A decode step and a prefill slice run
   the same loop over blocks of ``LATENT_BLOCK`` positions up to a traced
   bound, in plain XLA (flash recurrence: running max and sum).
-- The first ``cfg.n_dense_layers`` layers' feed-forward is the dense SwiGLU
-  of ``cfg.ffn_dim``; the others' a float32 router (:func:`route_grouped`)
-  over ``cfg.n_experts`` experts of ``cfg.expert_ffn_dim`` plus a shared
-  expert on every token.  The two kinds are two stacks of weights
-  (``params["layers"]["dense" | "moe"]``), each a ``fori_loop``.
-- The expert layer is told which experts it HOLDS (``cfg.experts_first``,
-  ``cfg.n_held``): the router scores all ``n_experts`` and picks as
-  published; a pick outside the held ones becomes the sentinel "no pick"
-  of ``ops/pallas/experts.py`` and adds nothing.  That is one chip's share
-  of an expert-parallel layer without its exchange.
+- The feed-forward kind is the LAYER's (models/routed.py, shared with
+  models/hybrid.py): leading dense layers, then a float32 grouped router
+  over the experts HELD here plus a shared expert.  The two kinds are two
+  stacks of weights (``params["layers"]["dense" | "moe"]``), each a
+  ``fori_loop``.
 """
 
 from __future__ import annotations
@@ -44,13 +39,12 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.custom_batching import custom_vmap
 
 from ..ops.linear import linear, linear_at
 from .config import ModelConfig
-
-DENSE, MOE = "dense", "moe"
-HI = jax.lax.Precision.HIGHEST
+from .routed import (  # noqa: F401  (``mla.route_grouped``: the tests' name)
+    DENSE, HI, MOE, check_stacks, expert_branch, held_picks, moe_stats,
+    n_moe_layers, route_grouped, swiglu)
 
 #: latent rows a block of :func:`latent_attention`'s loop reads (the XLA
 #: loop of ``models/llama.py decode_attention`` reads 512 ring slots a time)
@@ -68,10 +62,6 @@ def leaf_width(cfg: ModelConfig) -> int:
     lanes' whole leaf (positions minor) on the way into every decode chunk
     and back out (tests/test_chip_compile.py), with 640 it leaves it be."""
     return -(-lat_width(cfg) // 128) * 128
-
-
-def n_moe_layers(cfg: ModelConfig) -> int:
-    return cfg.n_layers - cfg.n_dense_layers
 
 
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
@@ -231,64 +221,6 @@ def expanded_attention(q_n, q_r, rows, w_uk, w_uv, positions,
 
 
 # ---------------------------------------------------------------------------
-# the router
-# ---------------------------------------------------------------------------
-
-def route_grouped(hn, w_router, bias, cfg: ModelConfig):
-    """The router of a ``deepseek2`` routed layer, float32.  Scores
-    ``sigmoid`` (or ``softmax``) of ``W_r hn`` over ALL ``n_experts``; the
-    CHOICE on ``scores + bias``: a group's score is the sum of its two
-    largest, the ``n_groups_used`` best groups are kept, the
-    ``n_experts_used`` largest inside them picked; the weights are the
-    picked experts' UNBIASED scores, divided by their sum (+1e-20) where
-    ``norm_topk_prob``, times ``expert_weights_scale``.  hn (S, dim),
-    w_router (E, dim), bias (E,) -> (picks (S, k) int32 in [0, E), weights
-    (S, k) f32)."""
-    logits = jnp.einsum("sd,ed->se", hn.astype(jnp.float32), w_router,
-                        precision=HI)
-    scores = jax.nn.sigmoid(logits) if cfg.expert_gating == "sigmoid" \
-        else jax.nn.softmax(logits, axis=-1)
-    choice = scores + bias[None, :]
-    S, E = choice.shape
-    G = cfg.n_expert_groups
-    if G > 1 and cfg.n_groups_used < G:
-        grouped = choice.reshape(S, G, E // G)
-        gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)   # (S, G)
-        _, keep = jax.lax.top_k(gscore, cfg.n_groups_used)
-        kept = jnp.zeros((S, G), bool).at[
-            jnp.arange(S)[:, None], keep].set(True)
-        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf
-                           ).reshape(S, E)
-    _, picks = jax.lax.top_k(choice, cfg.n_experts_used)
-    weights = jnp.take_along_axis(scores, picks, axis=-1)
-    if cfg.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    return picks.astype(jnp.int32), weights * cfg.expert_weights_scale
-
-
-@custom_vmap
-def _sum_over_lanes(n):
-    """An int32 scalar that, under ``vmap`` over lanes, becomes its SUM over
-    them as an unbatched value: the counters of a step are the step's, the
-    same in every lane (as ``routed_experts``' rows per expert are)."""
-    return n
-
-
-@_sum_over_lanes.def_vmap
-def _sum_over_lanes_rule(axis_size, in_batched, n):
-    return _sum_over_lanes(jnp.sum(n) if in_batched[0] else n * axis_size), \
-        False
-
-
-def held_picks(picks, cfg: ModelConfig):
-    """The router's picks as indices into the HELD experts' planes:
-    ``pick - experts_first`` where this process holds the expert, else the
-    sentinel ``n_held`` ("no pick": ops/pallas/experts.py)."""
-    local = picks - cfg.experts_first
-    return jnp.where((local >= 0) & (local < cfg.n_held), local, cfg.n_held)
-
-
-# ---------------------------------------------------------------------------
 # the layers
 # ---------------------------------------------------------------------------
 
@@ -333,52 +265,26 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, kv_bound):
     return h + lin(o, "wo"), cache
 
 
-def _swiglu(hn, layers, i, gate, up, down):
-    def lin(x, name):
-        with jax.named_scope(name):
-            return linear_at(x, layers[name], i)
-
-    gated = jax.nn.silu(lin(hn, gate).astype(jnp.float32)).astype(hn.dtype)
-    return lin(gated * lin(hn, up), down)
-
-
 def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, kv_bound):
     from .llama import rms_norm
 
     h, cache = _attention(h, layers, i, i, cache, positions, pos_offset, cfg,
                           kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
-    return h + _swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache
+    return h + swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache
 
 
 def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
               kv_bound):
     """Returns (h, cache, (rows each HELD expert took (n_held,), the
     router's picks (S, k) over all experts, picks of live rows))."""
-    from ..ops.pallas.experts import routed_experts
     from .llama import rms_norm
 
     h, cache = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
                           positions, pos_offset, cfg, kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
-    with jax.named_scope("router"):
-        picks, weights = route_grouped(
-            hn, layers["w_router"][i], layers["router_bias"][i], cfg)
-    mine = held_picks(picks, cfg)
-    total = jnp.int32(picks.size)
-    if live is not None:
-        mine = jnp.where(live, mine, cfg.n_held)
-        total = jnp.where(live, total, 0)
-    total = _sum_over_lanes(total)
-    with jax.named_scope("experts"):
-        out, count = routed_experts(
-            hn, mine, weights, layers["w_gate_exps"], layers["w_up_exps"],
-            layers["w_down_exps"], i)
-    if cfg.n_shared_experts:
-        with jax.named_scope("shared_expert"):
-            out = out + _swiglu(hn, layers, i, "w_gate_sh", "w_up_sh",
-                                "w_down_sh")
-    return h + out, cache, (count, picks, total)
+    out, routed = expert_branch(hn, layers, i, cfg, live)
+    return h + out, cache, routed
 
 
 def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
@@ -395,14 +301,7 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
 
     S = tokens.shape[0]
     n_moe = n_moe_layers(cfg)
-    for kind, n in ((DENSE, cfg.n_dense_layers), (MOE, n_moe)):
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                params["layers"].get(kind, {}))[0]:
-            if leaf.shape[0] != n:
-                raise ValueError(
-                    f"stacked leaf {kind}{jax.tree_util.keystr(path)} has "
-                    f"{leaf.shape[0]} layers but the file names {n} of "
-                    "that kind")
+    check_stacks(params, cfg)
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
 
@@ -411,14 +310,10 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
                            carry[1], positions, pos_offset, cfg, kv_bound)
 
     def moe_body(i, carry):
-        h, cache, (count, picks, total) = moe_layer(
+        h, cache, routed = moe_layer(
             carry[0], params["layers"][MOE], jnp.int32(i), carry[1],
             positions, pos_offset, cfg, live, kv_bound)
-        read = jnp.sum(count > 0, dtype=jnp.int32)
-        stats = carry[2] + jnp.concatenate(
-            [jnp.stack([jnp.int32(1), read]), count, total[None]])
-        return h, cache, stats, jax.lax.dynamic_update_slice(
-            carry[3], picks[None], (i, 0, 0))
+        return (h, cache, *moe_stats(carry[2], carry[3], i, routed))
 
     carry = (h, cache)
     if cfg.n_dense_layers:

@@ -183,9 +183,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             ok["output"] = t.ggml_type
         return ok
 
-    # (a ``deepseek2`` file's layers fuse by kind, in ``latent_layers``:
-    # here its output head alone)
-    fused_names = _fused_names([] if cfg.kv_lora_rank else None) \
+    # (a ``deepseek2`` or ``exaone-moe`` file's layers fuse by kind, in
+    # ``ffn_kind_layers``: here its output head alone)
+    by_ffn_kind = bool(cfg.kv_lora_rank or cfg.attn_kinds)
+    fused_names = _fused_names([] if by_ffn_kind else None) \
         if fmt == "q4k" else {}
 
     import time as _time
@@ -312,22 +313,33 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 out[kind].append(layer)
         return out
 
-    def latent_layers() -> dict:
-        """A ``deepseek2`` file (models/mla.py): {"dense": the leading
-        layers, "moe": the routed ones}.  Nothing is requantized: a matrix
-        no fused kernel takes (``attn_q_b``, K = r_q; ``attn_kv_b``, which
-        the absorbed form wants per head, W_uk and W_uv) is served bf16
-        under ``q4k``, never int8."""
-        from .mla import DENSE, MOE, lat_width
+    def ffn_kind_layers() -> dict:
+        """A file whose feed-forward kind is the layer's (models/routed.py):
+        {"dense": the leading layers, "moe": the routed ones}, with the
+        attention of ``deepseek2`` (models/mla.py) or of ``exaone-moe``
+        (models/hybrid.py).  Nothing is requantized: a matrix no fused
+        kernel takes (``attn_q_b``, K = r_q; ``attn_kv_b``, which the
+        absorbed form wants per head, W_uk and W_uv) is served bf16 under
+        ``q4k``, never int8."""
+        from .mla import lat_width
+        from .routed import DENSE, MOE
 
+        latent = bool(cfg.kv_lora_rank)
         attn = {"wq_a": "attn_q_a", "wq_b": "attn_q_b",
-                "wkv_a": "attn_kv_a_mqa", "wo": "attn_output"}
+                "wkv_a": "attn_kv_a_mqa", "wo": "attn_output"} if latent \
+            else {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
+                  "wo": "attn_output"}
+        norms = (("attn_norm", "attn_norm"), ("q_a_norm", "attn_q_a_norm"),
+                 ("kv_a_norm", "attn_kv_a_norm"), ("ffn_norm", "ffn_norm")) \
+            if latent else (
+                ("attn_norm", "attn_norm"), ("attn_q_norm", "attn_q_norm"),
+                ("attn_k_norm", "attn_k_norm"), ("ffn_norm", "ffn_norm"))
         ffn = {DENSE: {"w_gate": "ffn_gate", "w_up": "ffn_up",
                        "w_down": "ffn_down"},
                MOE: {"w_gate_sh": "ffn_gate_shexp", "w_up_sh": "ffn_up_shexp",
                      "w_down_sh": "ffn_down_shexp"}}
         # the latent projection's r_kv + d_r rows, filled up to a kernel's N
-        kv_rows = -(-lat_width(cfg) // 128) * 128
+        kv_rows = -(-lat_width(cfg) // 128) * 128 if latent else None
         H, d_n = cfg.n_heads, cfg.qk_nope_dim
 
         out = {}
@@ -337,7 +349,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             exps = ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"] \
                 if kind == MOE and fused_experts else []
             fused = _fused_names(list(mats.values()) + exps, ids,
-                                 {"attn_kv_a_mqa": kv_rows}) \
+                                 {"attn_kv_a_mqa": kv_rows} if latent
+                                 else None) \
                 if fmt == "q4k" and len(ids) else {}
             out[kind] = []
             for i in ids:
@@ -349,14 +362,12 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     else:
                         layer[key] = lin(p + name + ".weight", fused,
                                          kv_rows if key == "wkv_a" else None)
-                kv_b = as_bf16(gf[p + "attn_kv_b.weight"]).reshape(
-                    H, d_n + cfg.v_head_dim, cfg.kv_lora_rank)
-                layer["w_uk"] = {"w": kv_b[:, :d_n]}
-                layer["w_uv"] = {"w": kv_b[:, d_n:]}
-                for key, name in (("attn_norm", "attn_norm"),
-                                  ("q_a_norm", "attn_q_a_norm"),
-                                  ("kv_a_norm", "attn_kv_a_norm"),
-                                  ("ffn_norm", "ffn_norm")):
+                if latent:
+                    kv_b = as_bf16(gf[p + "attn_kv_b.weight"]).reshape(
+                        H, d_n + cfg.v_head_dim, cfg.kv_lora_rank)
+                    layer["w_uk"] = {"w": kv_b[:, :d_n]}
+                    layer["w_uv"] = {"w": kv_b[:, d_n:]}
+                for key, name in norms:
                     layer[key] = norm(p + name + ".weight")
                 if kind == MOE:
                     layer["w_router"] = norm(p + "ffn_gate_inp.weight")
@@ -372,7 +383,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     layers = []
     t_prep = _time.time()
     by_kind = kinds_layers() if cfg.mixers else \
-        latent_layers() if cfg.kv_lora_rank else None
+        ffn_kind_layers() if by_ffn_kind else None
     for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."
         layer = {

@@ -380,6 +380,27 @@ METRICS: dict[str, Metric] = _register(
            "windows turned into their chunk summaries: whole windows of a "
            "prompt at its prefill, and a decode step that writes a "
            "window's last position; cumulative"),
+    # -- the window + global cache (models/hybrid.py; ``exaone-moe``) --------
+    Metric("window_slots_read_total", GAUGE,
+           "slots of the WINDOW layers' cache leaves the decode steps' "
+           "attention covered: the whole leaf (the window, filled up to 16 "
+           "rows) a step, lane that holds a request and window layer, "
+           "cumulative; in layer-slots, so that it adds to "
+           "global_slots_read_total (ring_slots_read_total is their sum); "
+           "from host-tracked positions, nothing fetched; exported by a "
+           "file of that cache kind only"),
+    Metric("window_slots_live_total", GAUGE,
+           "of those, the slots that held a position inside the window "
+           "(min(position + 1, window) a step, lane and window layer)"),
+    Metric("global_slots_read_total", GAUGE,
+           "slots of the GLOBAL layers' rings the decode steps' attention "
+           "covered (whole blocks up to the position, as "
+           "ring_slots_read_total counts a ring), summed over the global "
+           "layers too, cumulative"),
+    Metric("global_slots_live_total", GAUGE,
+           "of those, the slots at or below the sequence's own position; "
+           "(window + global live) over (window + global read) = the share "
+           "of the read that was needed"),
     # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
     Metric("latent_positions_read_total", GAUGE,
            "cached latent rows the decode steps' attention covered (whole "

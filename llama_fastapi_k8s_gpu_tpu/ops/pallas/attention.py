@@ -77,6 +77,7 @@ def _attn_kernel(
     sm_scale: float,
     sliding_window: int,
     quantized: bool = False,
+    key_floor: bool = False,   # pos_ref is (2,): [position, first real key]
 ):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -124,6 +125,10 @@ def _attn_kernel(
             interior = jnp.bool_(False)            # window edge → always mask
         else:
             interior = kmax <= q_min               # fully unmasked block
+        if key_floor:
+            # keys below the floor hold no position of this sequence
+            skip |= kmax < pos_ref[1]
+            interior &= kmin >= pos_ref[1]
 
         lo = u * block_k
 
@@ -153,6 +158,8 @@ def _attn_kernel(
                 mask = key_pos <= q_pos
                 if sliding_window:
                     mask &= key_pos > q_pos - sliding_window
+                if key_floor:
+                    mask &= key_pos >= pos_ref[1]
                 scores = jnp.where(mask, scores, DEFAULT_MASK_VALUE)
 
             m_prev = m_ref[:, :1]                  # (BQ, 1)
@@ -236,6 +243,8 @@ def flash_attention(
     k_scale: jax.Array | None = None,  # (n_kv, n_ctx) f32 — int8 cache only
     v_scale: jax.Array | None = None,
     interpret: bool = False,
+    first_key: jax.Array | None = None,  # scalar int32: keys below it are
+    #                                      masked (a window layer's slice)
 ) -> jax.Array:
     """Causal (+ sliding-window) attention of S queries over the KV ring.
 
@@ -255,6 +264,13 @@ def flash_attention(
     order), but with ``kv_unroll``× fewer grid launches to pay per-step
     block-DMA setup for.  Clamped so the fused block still divides
     ``n_ctx`` (tiny rings degrade gracefully to the plain grid).
+
+    ``first_key``: the keys are no ring of ``n_ctx`` positions but a run
+    that starts before the sequence does (models/hybrid.py: a window
+    layer's last window of cached rows, in position order, then the slice's
+    own; ``pos_offset`` is then q[0]'s row in that run): rows below
+    ``first_key`` hold no position and are masked.  The kernel is then
+    named ``flash_attention_window``.
     """
     S, n_heads, hd = q.shape
     n_kv, n_ctx, _ = k.shape
@@ -287,7 +303,12 @@ def flash_attention(
         sm_scale=sm_scale,
         sliding_window=sliding_window,
         quantized=quantized,
+        key_floor=first_key is not None,
     )
+    scalars = jnp.atleast_1d(pos_offset.astype(jnp.int32))
+    if first_key is not None:
+        scalars = jnp.concatenate(
+            [scalars, jnp.atleast_1d(jnp.asarray(first_key, jnp.int32))])
     in_specs = [
         pl.BlockSpec((1, bq, hd), lambda h, qb, kb, *_: (h, qb, 0)),
         pl.BlockSpec((1, bkf, hd), lambda h, qb, kb, *_: (h, kb, 0)),
@@ -319,7 +340,8 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((n_kv, gs, hd), q.dtype),
         interpret=interpret,
-    )(jnp.atleast_1d(pos_offset.astype(jnp.int32)), *operands)
+        **({} if first_key is None else {"name": "flash_attention_window"}),
+    )(scalars, *operands)
 
     # (n_kv, group, S, hd) → (S, n_heads, hd)
     return out.reshape(n_kv, group, S, hd).transpose(2, 0, 1, 3).reshape(S, n_heads, hd)
@@ -367,6 +389,7 @@ def _decode_kernel(
     sliding_window: int,
     sm_scale: float,
     store: bool = False,
+    wrap: bool = False,
 ):
     """One grid step is one LANE: a loop over that lane's own blocks, from
     the sliding window's first to the one that holds its position, with a
@@ -386,7 +409,14 @@ def _decode_kernel(
     there, in the same order), and the tile is copied back to the ring
     while the block is computed on.  No read has to be ordered against
     the write: the one block that holds the row is in VMEM before the row
-    is set.  A lane that holds no request stores nothing."""
+    is set.  A lane that holds no request stores nothing.
+
+    ``wrap``: the leaf is a WINDOW layer's (models/hybrid.py): ``n_ctx`` is
+    its slots, fewer than the positions a sequence walks; position p lives
+    in slot ``p % n_ctx``, the step's row is stored there (over the row of
+    position ``p - n_ctx``, which the window no longer holds), a lane reads
+    the blocks that hold a live position (all of them once it has wrapped)
+    and the mask is on the POSITION a slot holds, not on the slot."""
     if store:
         kn_ref, vn_ref, _, _, o_ref, k_hbm, v_hbm, kbuf, vbuf, sem, m_ref, \
             l_ref, acc_ref, slot_ref, nxt_ref, wsem = rest
@@ -400,9 +430,10 @@ def _decode_kernel(
 
     def span(lane):
         """[lo, hi): the blocks ``lane`` reads."""
+        # (a leaf that wraps: every block once the position passed its end)
         p = jnp.minimum(pos_ref[lane], n_ctx - 1)
         hi = jnp.where(live_ref[lane] != 0, p // T + 1, 0)
-        if not sliding_window:
+        if not sliding_window or wrap:
             return 0, hi
         return jnp.minimum(jnp.maximum(p - sliding_window + 1, 0) // T, hi), hi
 
@@ -452,6 +483,9 @@ def _decode_kernel(
     acc_ref[...] = jnp.zeros_like(acc_ref)
     lo, hi = span(b)
     pos = pos_ref[b]
+    # the block that holds the step's row: the last one read, or on a leaf
+    # that wraps the one of slot ``pos % n_ctx``
+    row_block = jax.lax.rem(pos, n_ctx) // T if wrap else hi - 1
 
     def block(j, _):
         slot = slot_ref[0]
@@ -467,10 +501,11 @@ def _decode_kernel(
         for c in copies(b, j, slot):
             c.wait()
         if store:
-            @pl.when(j == hi - 1)
+            @pl.when(j == row_block)
             def _():
                 # the slot ``dynamic_update_slice`` would write: clamped
-                at = jnp.minimum(pos, n_ctx - 1) - j * T
+                at = (jax.lax.rem(pos, n_ctx) if wrap
+                      else jnp.minimum(pos, n_ctx - 1)) - j * T
                 r = pl.multiple_of(at // _ROW_TILE * _ROW_TILE, _ROW_TILE)
                 for buf, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
                     tile = buf[slot, :, pl.ds(r, _ROW_TILE), :]
@@ -488,7 +523,13 @@ def _decode_kernel(
         s = jnp.einsum("ngh,nth->ngt", q, k,
                        preferred_element_type=jnp.float32) * sm_scale
         key_pos = j * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        mask = key_pos <= pos
+        if wrap:
+            # the newest position <= pos that lives in the slot (below 0:
+            # the slot holds none of this sequence yet)
+            key_pos = pos - jax.lax.rem(pos + n_ctx - key_pos, n_ctx)
+            mask = key_pos >= 0
+        else:
+            mask = key_pos <= pos
         if sliding_window:
             mask &= key_pos > pos - sliding_window
         s = jnp.where(mask, s, -jnp.inf)
@@ -503,10 +544,17 @@ def _decode_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         slot_ref[0] = 1 - slot
+        if store and wrap:
+            # the row's block need not be the last: its tile is back in
+            # the leaf before a later block's copy may land in its slot
+            @pl.when(j == row_block)
+            def _():
+                for c in tile_copies(0, 0, 0):
+                    c.wait()
         return 0
 
     jax.lax.fori_loop(lo, hi, block, 0)
-    if store:
+    if store and not wrap:
         # the tile's copy ran beside the last block's arithmetic; its slot
         # is copied into again by the next lane's first iteration
         @pl.when(hi > lo)
@@ -520,12 +568,14 @@ def _decode_kernel(
 
 def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
                   block_k: int, sm_scale: float, sliding_window: int,
-                  interpret: bool):
+                  interpret: bool, wrap: bool = False):
     """q (B, n_heads, hd), k / v (B, L, n_kv, n_ctx, hd), i scalar, pos and
     live (B,) -> (B, n_heads * hd) in q.dtype: ONE kernel over the lanes.
     With the step's rows ``k_new`` / ``v_new`` (B, n_kv, hd) the kernel
     stores them at (lane, i, :, pos, :) of the rings, which it then
-    returns beside the context as outputs aliased onto their inputs."""
+    returns beside the context as outputs aliased onto their inputs.
+    ``wrap``: k / v are a window layer's leaves, whose ``n_ctx`` slots
+    wrap; the kernel is then named ``flash_attention_decode_window``."""
     B, n_heads, hd = q.shape
     _, _, n_kv, n_ctx, _ = k.shape
     group = n_heads // n_kv
@@ -547,7 +597,7 @@ def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k, n_ctx=n_ctx,
                           sliding_window=sliding_window, sm_scale=sm_scale,
-                          store=store),
+                          store=store, wrap=wrap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
@@ -577,7 +627,8 @@ def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="flash_attention_decode",
+        name="flash_attention_decode_window" if wrap
+        else "flash_attention_decode",
     )(jnp.asarray(i, jnp.int32).reshape(1), pos.astype(jnp.int32),
       live.astype(jnp.int32), qg, *new, k, v)
     ctx, *rings = out if store else (out,)
@@ -587,7 +638,7 @@ def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
 
 @functools.lru_cache(maxsize=8)
 def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
-                      interpret: bool):
+                      interpret: bool, wrap: bool = False):
     """The per-sequence call with its vmap rule: lanes ``vmap``ped over one
     step become ONE kernel over (B lanes), as the fused matmuls' rows do
     (qmatmul.py ``rows_vmappable``); without the rule ``vmap`` would batch
@@ -598,7 +649,7 @@ def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
 
     lanes = functools.partial(
         _decode_lanes, block_k=block_k, sm_scale=sm_scale,
-        sliding_window=sliding_window, interpret=interpret)
+        sliding_window=sliding_window, interpret=interpret, wrap=wrap)
 
     @custom_vmap
     def one(q, k, v, i, pos, live, *rows):
@@ -639,6 +690,8 @@ def flash_attention_decode(
     interpret: bool = False,
     k_new: jax.Array | None = None,   # (n_kv, hd): this step's K row and
     v_new: jax.Array | None = None,   #   V row, not in the ring yet
+    wrap: bool = False,               # the leaf's slots wrap (a window
+    #                                   layer's: slot = position % slots)
 ):
     """A decode step's attention (S = 1) over the live part of layer
     ``i``'s ring: the flash recurrence of ``models/llama.py
@@ -656,11 +709,19 @@ def flash_attention_decode(
     ring's last slot, as ``dynamic_update_slice`` clamps), before it
     attends: the result is ``(ctx, k, v)``, the rings the very buffers
     that came in (aliased outputs) and, where ``live`` is False, untouched.
-    Without them the call reads a ring that was written before it."""
+    Without them the call reads a ring that was written before it.
+
+    ``wrap``: ``k`` / ``v`` hold ``sliding_window`` or a few more slots,
+    not ``n_ctx``: position p lives in slot ``p % slots``, the row is
+    stored there, and the mask is on positions (``_decode_kernel``)."""
+    if wrap and not 0 < sliding_window <= k.shape[2]:
+        raise ValueError(
+            f"a leaf of {k.shape[2]} slots that wrap holds no window of "
+            f"{sliding_window}")
     rows = () if k_new is None else (k_new.astype(k.dtype),
                                      v_new.astype(v.dtype))
     return _decode_vmappable(int(block_k), float(sm_scale),
-                             int(sliding_window), bool(interpret))(
+                             int(sliding_window), bool(interpret), bool(wrap))(
         q, k, v, jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
         jnp.asarray(live, jnp.bool_), *rows)
 

@@ -26,7 +26,8 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import LATENT_RING, STATE_RING, ModelConfig
+from ..models.config import (
+    LATENT_RING, STATE_RING, WINDOW_GLOBAL_RING, ModelConfig)
 
 
 def make_mesh(dp: int = 1, tp: int = 1, sp: int = 1, devices=None) -> Mesh:
@@ -141,6 +142,8 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, batched: bool = False):
     if cfg.cache_kind == STATE_RING:   # models/sala.py, tp = 1
         return {"k": s4, "v": s4, "kc": s4, "kw": s4,
                 "state": _ns(mesh, *lead, None, None, None, None)}
+    if cfg.cache_kind == WINDOW_GLOBAL_RING:   # models/hybrid.py, tp = 1
+        return {name: s4 for name in ("k", "v", "kw", "vw")}
     if cfg.cache_kind == LATENT_RING:   # models/mla.py, tp = 1
         return {"lat": _ns(mesh, *lead, None, None, None, None)}
     if cfg.eva_window:   # window + summary leaves (models/eva.py), tp = 1

@@ -330,6 +330,130 @@ def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
     return cfg
 
 
+#: a tiny ``exaone-moe`` file that keeps every ratio of the published
+#: block (models/hybrid.py): window window window global x 2, a window of
+#: 16 positions, 4 heads on 2 KV heads of 32 (heads x width is not the
+#: hidden size), per-head QK-norm, 1 leading dense layer + 7 routed of 8
+#: experts, top-3 in one group, sigmoid scores, one shared expert
+TINY_HYBRID_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=8, n_heads=4, n_kv_heads=2,
+    ffn_dim=512, n_ctx=128, rope_theta=10000.0, rms_eps=1e-5,
+    sliding_window=16, head_width=32, qk_norm_per_head=True, rope_neox=True,
+    attn_kinds=("window", "window", "window", "global") * 2,
+    rope_kinds=("window",),
+    n_dense_layers=1, expert_ffn_dim=256, n_shared_experts=1,
+    n_experts=8, n_experts_used=3, norm_topk_prob=True,
+    expert_gating="sigmoid", n_expert_groups=1, n_groups_used=1,
+    expert_weights_scale=2.5,
+)
+
+#: the Q4_K_M mix on an ``exaone-moe`` file, as the benchmark writes it
+#: (``attn_output`` of the tiny file Q8_0: its K is 128, a K-quant block
+#: is 256 wide)
+HYBRID_Q4KM_MIX = {
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q8_0,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+    "ffn_gate_exps": GGMLType.Q4_K, "ffn_up_exps": GGMLType.Q4_K,
+    "ffn_down_exps": GGMLType.Q6_K,
+    "ffn_gate_shexp": GGMLType.Q4_K, "ffn_up_shexp": GGMLType.Q4_K,
+    "ffn_down_shexp": GGMLType.Q6_K, "output": GGMLType.Q6_K,
+}
+
+
+def write_tiny_hybrid_gguf(path: str, cfg: ModelConfig = TINY_HYBRID_CFG,
+                           seed: int = 0, mix: dict | None = None,
+                           held: tuple[int, int] | None = None,
+                           router_scale: float = 4.0,
+                           bias_scale: float = 0.2) -> ModelConfig:
+    """Write a random-weight ``exaone-moe`` GGUF (window and global layers
+    by ``attention.sliding_window_pattern``, per-head QK-norm, leading dense
+    layers, a sigmoid router with its choice bias, routed + shared experts)
+    with the byte-level tokenizer of :func:`write_tiny_llama_gguf`.
+    ``held`` as :func:`write_tiny_mla_gguf` has it."""
+    tokens, types = byte_vocab_with_specials()
+    first, count = held or (0, 0)
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens),
+                         "experts_first": first, "experts_held": count})
+    period = cfg.attn_kinds.index("global") + 1
+    if cfg.attn_kinds != tuple(
+            "global" if (i + 1) % period == 0 else "window"
+            for i in range(cfg.n_layers)):
+        raise ValueError("the file states its layer kinds as a period")
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**HYBRID_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    arch = "exaone-moe"
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-hybrid-test",
+                          arch=arch)
+    for key, value in (
+            ("attention.key_length", cfg.head_dim),
+            ("attention.value_length", cfg.head_dim),
+            ("attention.sliding_window_pattern", period),
+            ("rope.dimension_count", cfg.head_dim),
+            ("leading_dense_block_count", cfg.n_dense_layers),
+            ("expert_feed_forward_length", cfg.expert_ffn_dim),
+            ("expert_shared_count", cfg.n_shared_experts),
+            ("expert_weights_scale", float(cfg.expert_weights_scale)),
+            ("expert_weights_norm", bool(cfg.norm_topk_prob)),
+            ("expert_gating_func",
+             {"softmax": 1, "sigmoid": 2}[cfg.expert_gating]),
+            ("expert_group_count", cfg.n_expert_groups),
+            ("expert_group_used_count", cfg.n_groups_used)):
+        w.add_metadata(f"{arch}.{key}", value)
+    if held:
+        w.add_metadata(f"{arch}.expert_held_first", first)
+        w.add_metadata(f"{arch}.expert_held_count", count)
+    D, E, hd = cfg.dim, cfg.n_experts, cfg.head_dim
+    q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    F, Fe = cfg.ffn_dim, cfg.expert_ffn_dim
+
+    def t(name, shape, gtype, mul=1.0, rows=None):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x if rows is None else x[rows], gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    mine = slice(first, first + count) if held else None
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight", D)
+        t(p + "attn_q.weight", (q_dim, D), mix["attn_q"])
+        t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"])
+        t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+        norm(p + "attn_q_norm.weight", hd)
+        norm(p + "attn_k_norm.weight", hd)
+        t(p + "attn_output.weight", (D, q_dim), mix["attn_output"],
+          (D / q_dim) ** 0.5)
+        norm(p + "ffn_norm.weight", D)
+        if i < cfg.n_dense_layers:
+            t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+            t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+            t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+            continue
+        t(p + "ffn_gate_inp.weight", (E, D), GGMLType.F32, router_scale)
+        w.add_tensor(p + "exp_probs_b.bias", bias_scale * rng.standard_normal(
+            E).astype(np.float32), GGMLType.F32)
+        t(p + "ffn_gate_exps.weight", (E, Fe, D), mix["ffn_gate_exps"],
+          rows=mine)
+        t(p + "ffn_up_exps.weight", (E, Fe, D), mix["ffn_up_exps"], rows=mine)
+        t(p + "ffn_down_exps.weight", (E, D, Fe), mix["ffn_down_exps"],
+          rows=mine)
+        sh = Fe * cfg.n_shared_experts
+        t(p + "ffn_gate_shexp.weight", (sh, D), mix["ffn_gate_shexp"])
+        t(p + "ffn_up_shexp.weight", (sh, D), mix["ffn_up_shexp"])
+        t(p + "ffn_down_shexp.weight", (D, sh), mix["ffn_down_shexp"])
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size, D), mix["output"])
+    w.write()
+    return cfg
+
+
 def synth_bpe_vocab(n_merges: int = 280_000, seed: int = 0,
                     ) -> tuple[list[str], list[str], list[int]]:
     """Deterministic Llama-3-*scale* BPE vocab: 256 byte tokens + specials +

@@ -876,3 +876,133 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
             assert sliced.memory_analysis().temp_size_in_bytes \
                 < 1024 * 2 ** 20 * rows // 256
         assert _slice_widths(cfg) == [256, 1024]
+
+
+# ``k-exaone-236b-a23b-q4km-ep8-16lane`` (benchmarks/configs: 12 layers of
+# kinds window window window global x 3, layer 0 dense + 11 routed holding 16
+# of 128 experts), n_ctx 16384: (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("kexaone-serial", 0),
+                                        ("kexaone-16lane", 16)])
+def test_window_global_stack_compiles_with_no_ring_sized_copy(
+        one_chip, monkeypatch, name, lanes):
+    """The decode chunk and the prefill slices of the ``exaone-moe`` stack
+    (models/hybrid.py) compile for the chip: the decode kernel on the
+    global rings and, under its own name, on the window leaves that wrap;
+    the flash kernel on a window layer's run of keys under its own name;
+    the fused planes at K 6144 / 8192 / 18432 / 2048 with nothing padded;
+    the grouped expert kernels.  The compiler has put NO copy or transpose
+    of a global ring in the decode chunk, whose step holds no XLA update of
+    the lanes' stacked leaves (the kernels store the rows)."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import hybrid
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import (
+        cache_nbytes, init_cache, ring_write_impl)
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    D, V, H, KV, hd = 6144, 153600, 64, 8, 128
+    F, Fe, E = 18432, 2048, 16
+    cfg = ModelConfig(
+        vocab_size=V, dim=D, n_layers=12, n_heads=H, n_kv_heads=KV,
+        ffn_dim=F, n_ctx=16384, rope_theta=1e6, rms_eps=1e-5,
+        attn_impl="pallas", sliding_window=128, head_width=hd,
+        qk_norm_per_head=True, rope_neox=True,
+        attn_kinds=("window", "window", "window", "global") * 3,
+        rope_kinds=("window",), n_dense_layers=1, expert_ffn_dim=Fe,
+        n_shared_experts=1, n_experts=128, n_experts_used=8,
+        norm_topk_prob=True, expert_gating="sigmoid",
+        expert_weights_scale=2.5, experts_first=0, experts_held=E)
+    assert (hybrid.window_block(cfg), cfg.window_slots) == (128, 128)
+    assert ring_write_impl(cfg) == "kernel"
+    assert cache_nbytes(cfg) == 4096 * (3 * 16384 + 9 * 128)
+
+    def exps(fmt, n, k, L):
+        kt = k // 2048
+        if fmt == "q4k":
+            return {"qs": S(L, E, n, k // 2, dtype=i8),
+                    "sm": S(L, E, kt, n, 128)}
+        return {"q4": S(L, E, n, k // 2, dtype=i8),
+                "q2": S(L, E, n, k // 4, dtype=i8),
+                "sm6": S(L, E, kt, n, 128)}
+
+    def attn(L):
+        return {"attn_norm": S(L, D, dtype=f32), "ffn_norm": S(L, D, dtype=f32),
+                "attn_q_norm": S(L, hd, dtype=f32),
+                "attn_k_norm": S(L, hd, dtype=f32),
+                "wq": _planes("q4k", H * hd, D, L),
+                "wk": _planes("q4k", KV * hd, D, L),
+                "wv": _planes("q6k", KV * hd, D, L),
+                "wo": _planes("q4k", D, H * hd, L)}
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": _planes("q6k", V, D),
+        "layers": {
+            "dense": {**attn(1), "w_gate": _planes("q4k", F, D, 1),
+                      "w_up": _planes("q4k", F, D, 1),
+                      "w_down": _planes("q6k", D, F, 1)},
+            "moe": {**attn(11), "w_router": S(11, 128, D, dtype=f32),
+                    "router_bias": S(11, 128, dtype=f32),
+                    "w_gate_sh": _planes("q4k", Fe, D, 11),
+                    "w_up_sh": _planes("q4k", Fe, D, 11),
+                    "w_down_sh": _planes("q6k", D, Fe, 11),
+                    "w_gate_exps": exps("q4k", Fe, D, 11),
+                    "w_up_exps": exps("q4k", Fe, D, 11),
+                    "w_down_exps": exps("q6k", D, Fe, 11)}}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_attention_decode_window" in text
+    assert re.search(r"flash_attention_decode[^_]", text)
+    assert "q4k_expert_matmul_fewrow" in text
+    assert "q6k_expert_matmul_fewrow" in text
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*16384,128\]\S* "
+        r"(copy|transpose|dynamic-update-slice)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    if not lanes:       # the admission slices into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert _slice_widths(cfg) == [256, 1024]
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            stext = sliced.as_text()
+            assert "flash_attention_window" in stext
+            assert "q4k_expert_matmul_manyrow" in stext
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 1024 * 2 ** 20 * rows // 256
