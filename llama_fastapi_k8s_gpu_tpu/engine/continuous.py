@@ -798,9 +798,10 @@ class ContinuousEngine(MeshEngine):
         lease = None
         try:
             with phase("tokenize", rid=rid(item.trace)):
-                ids = self.tokenize_messages(item.messages)
+                ids, memo = self._tokenize_counted(item.messages)
             if pspan is not None:
-                pspan.child("tokenize", t0=t0).set(n_prompt=len(ids)).end()
+                pspan.child("tokenize", t0=t0).set(
+                    n_prompt=len(ids), **memo).end()
             if len(ids) >= self.cfg.n_ctx:
                 raise ValueError(
                     f"Requested tokens ({len(ids)}) exceed context window "

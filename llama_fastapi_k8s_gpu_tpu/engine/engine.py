@@ -669,6 +669,17 @@ class Engine:
                                 "window restarts",
                 "kv_paged": "refused at start"}
 
+    @property
+    def tokenizer_fallback(self) -> str | None:
+        """The /health ``engine.tokenizer`` line of a SentencePiece
+        vocabulary that tokenizer/spm.py cannot cut at spaces (None
+        otherwise: /health is what it was), so that a pod whose prompts
+        pay the whole-text merge loop can be told from one whose do not."""
+        if getattr(self.tokenizer, "cuts_at_spaces", True):
+            return None
+        return ("whole-text merge loop: a vocabulary entry holds a space "
+                "after another character, so no piece is cut or remembered")
+
     # ------------------------------------------------------------------
     @property
     def kv_cache_bytes(self) -> int:
@@ -1025,12 +1036,17 @@ class Engine:
         """The counters of :meth:`_note_cache_read` under their /metrics
         names: ``ring_slots_*`` for every engine (0 on a cache that is no
         ring), ``eva_*`` for a window + summary cache alone; beside them
-        the prompt tokens prefilled by slice width (:attr:`slice_tokens`)."""
+        the prompt tokens prefilled by slice width (:attr:`slice_tokens`)
+        and the pieces a SentencePiece tokenizer encoded and remembered."""
         out = {"ring_slots_read_total": self.ring_slots["read"],
                "ring_slots_live_total": self.ring_slots["live"],
                "ring_rows_written_total": self.ring_rows_written}
         out.update({f'prefill_slice_tokens_total{{width="{w}"}}': n
                     for w, n in self.slice_tokens.items()})
+        counts = getattr(self.tokenizer, "piece_counts", None)
+        if counts is not None:
+            (out["tokenizer_pieces_total"],
+             out["tokenizer_memo_hits_total"]) = counts()
         if self.cfg.eva_window:
             c = self.eva_counts
             out.update(
@@ -1156,6 +1172,18 @@ class Engine:
     def tokenize_messages(self, messages: Sequence[dict]) -> list[int]:
         return apply_chat_template(self.tokenizer, messages, kind=self.template_kind)
 
+    def _tokenize_counted(self, messages) -> tuple[list[int], dict]:
+        """:meth:`tokenize_messages`, and what the tokenizer's memo of
+        pieces did for this call as the ``tokenize`` span's attributes
+        (tokenizer/spm.py; none for a tokenizer that keeps no memo)."""
+        counts = getattr(self.tokenizer, "piece_counts", None)
+        if counts is None:
+            return self.tokenize_messages(messages), {}
+        p0, h0 = counts(thread_only=True)
+        ids = self.tokenize_messages(messages)
+        p1, h1 = counts(thread_only=True)
+        return ids, {"pieces": p1 - p0, "memo_hits": h1 - h0}
+
     # ------------------------------------------------------------------
     def create_chat_completion(
         self,
@@ -1225,7 +1253,7 @@ class Engine:
             ids, t_tok = pre_ids, None
         else:
             with phase("tokenize", rid=rid(espan)):
-                ids = self.tokenize_messages(messages)
+                ids, memo = self._tokenize_counted(messages)
             t_tok = time.time()
         n_prompt = len(ids)
         if n_prompt >= self.cfg.n_ctx:
@@ -1251,8 +1279,8 @@ class Engine:
         if espan is not None:
             pspan = espan.child("prefill", t0=t0)
             if t_tok is not None:
-                pspan.child("tokenize", t0=t0).set(n_prompt=n_prompt).end(
-                    t_tok)
+                pspan.child("tokenize", t0=t0).set(
+                    n_prompt=n_prompt, **memo).end(t_tok)
         if self._kv_paged and not explicit_seed:
             # paged mode: the shared radix index replaces the single-claim
             # reuse above (restores matched pages into the ring and pins

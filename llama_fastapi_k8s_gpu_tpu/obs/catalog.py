@@ -353,6 +353,16 @@ METRICS: dict[str, Metric] = _register(
            "over both widths = the share of the prompt that paid one weight "
            "pass per wide slice and not per narrow one; host arithmetic at "
            "each dispatch", labels=("width",)),
+    # -- the tokenizer's memo of pieces (tokenizer/spm.py) -------------------
+    Metric("tokenizer_pieces_total", GAUGE,
+           "pieces of escaped text (a run of spaces and the word after it) "
+           "a SentencePiece tokenizer encoded, over every thread, "
+           "cumulative; 0 for a vocabulary that cannot be cut at spaces "
+           "(/health engine.tokenizer says so), absent for a tokenizer of "
+           "another family"),
+    Metric("tokenizer_memo_hits_total", GAUGE,
+           "of those, the pieces whose ids came from the memo and not from "
+           "the merge loop; over tokenizer_pieces_total = the hit share"),
     # -- the window + summary cache's read (models/eva.py; ``evabyte``) -----
     Metric("eva_lane_steps_total", GAUGE,
            "decode steps summed over the lanes that hold a request (a "

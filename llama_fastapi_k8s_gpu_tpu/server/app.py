@@ -1505,6 +1505,12 @@ def create_app(engine=None, settings: Settings | None = None,
             kind = getattr(eng, "cache_kind", None)
             if kind:
                 engine_info["cache"] = kind
+            # a vocabulary the tokenizer cannot cut at spaces pays the
+            # whole-text merge loop on every prompt (tokenizer/spm.py);
+            # absent where it can
+            fallback = getattr(eng, "tokenizer_fallback", None)
+            if fallback:
+                engine_info["tokenizer"] = fallback
             # multi-model registry: one row per served model (name, quant,
             # weight bytes, load state — docs/MULTIMODEL.md) next to the
             # kv_pool block; absent on single-model pods, whose /health is

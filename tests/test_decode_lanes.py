@@ -552,6 +552,7 @@ def test_rows_written_count_only_where_the_kernel_writes():
         Engine._note_cache_read(eng, [7, 40], 5)
         assert eng.ring_rows_written == rows
         eng.eva_counts = eng.sala_counts = eng.slice_tokens = {}
+        eng.tokenizer = None                # no memo of pieces to count
         assert Engine.cache_read_gauges(eng)["ring_rows_written_total"] == rows
 
 

@@ -308,3 +308,307 @@ def test_big_vocab_roundtrip(big_bpe):
     text = "the quick brown fox jumps over the lazy dog " * 40
     ids = big_bpe.encode(text, add_bos=False)
     assert big_bpe.decode(ids) == text
+
+
+# ---------------------------------------------------------------------------
+# the cut at spaces and the memo of pieces (ISSUE 46; tokenizer/spm.py)
+# ---------------------------------------------------------------------------
+
+def _spm_words():
+    """``benchmarks/vocabs/spm_words.py``, the table every SentencePiece
+    cell of the benchmark serves (``benchmarks/`` is no package)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "vocabs", "spm_words.py")
+    spec = importlib.util.spec_from_file_location("_spm_words", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _scored_vocab(seed=11):
+    """A hand-made scored vocabulary: letters, runs of spaces, whitespace
+    and pieces of random words under random scores with many ties, a few
+    byte tokens only (so that most unknown bytes are ``<unk>``), and no
+    entry with a space after another character."""
+    rng = np.random.default_rng(seed)
+    rows = [("<unk>", TokenType.UNKNOWN, 0.0), ("<s>", TokenType.CONTROL, 0.0),
+            ("</s>", TokenType.CONTROL, 0.0)]
+    rows += [(f"<0x{b:02X}>", TokenType.BYTE, 0.0) for b in (0xC3, 0xA9, 0x0A)]
+    seen = {t for t, _, _ in rows}
+    letters = "abcdefgh"
+    words = ["".join(letters[int(c)] for c in rng.integers(0, 8, int(n)))
+             for n in rng.integers(1, 7, 400)]
+    cand = list(letters) + ["▁", "▁▁", "▁▁▁▁", "\t", "\n\n", "é"]
+    for w in words:
+        a = int(rng.integers(0, len(w)))
+        b = int(rng.integers(a + 1, len(w) + 1))
+        cand += [w[a:b], "▁" + w[:b], "▁▁" + w[:1]]
+    for t in cand:
+        if t not in seen:
+            seen.add(t)
+            rows.append((t, TokenType.NORMAL, float(rng.integers(-3, 4))))
+    return ([t for t, _, _ in rows], [s for _, _, s in rows],
+            [int(ty) for _, ty, _ in rows])
+
+
+@pytest.fixture(scope="module", params=["words-32000", "words-153600",
+                                        "scored"])
+def spm_pair(request):
+    """(the tokenizer as built, the same vocabulary held to the whole-text
+    loop): ``cuts_at_spaces`` is what decides, so clearing it IS the
+    parent's ``_encode_fragment``."""
+    if request.param == "scored":
+        tokens, scores, types = _scored_vocab()
+    else:
+        tokens, types, scores = _spm_words().synth_spm_vocab(
+            int(request.param.split("-")[1]))
+    fast = SPMTokenizer(tokens, scores, types)
+    whole = SPMTokenizer(tokens, scores, types)
+    assert fast.cuts_at_spaces and whole.cuts_at_spaces
+    whole.cuts_at_spaces = False
+    return fast, whole
+
+
+def _random_mixes(n=200):
+    rng = np.random.default_rng(46)
+    atoms = ["abc", "zzz", "qrs", "hello", "a", "dcba", "fgh", "é", "€", "世界",
+             "\n", "\t", " ", "  ", "    ", "\n\n", "x" * 40, "A", ".", "▁"]
+    return ["".join(atoms[int(i)] + (" " if rng.random() < 0.6 else "")
+                    for i in rng.integers(0, len(atoms),
+                                          int(rng.integers(1, 60))))
+            for _ in range(n)]
+
+
+SPM_TEXTS = {
+    "runs-of-spaces": ["abc   def  ghi", "a    b", "   ", " "],
+    "leading-trailing": [" abc", "abc ", "  abc  def  ", " a"],
+    "newlines-tabs": ["abc\ndef", "abc \n def\t ghi", "\n\n abc\n", "\t"],
+    "empty": [""],
+    "non-ascii": ["café au lait", "世界 abc 世界", "é é é", "naïve € 5"],
+    "outside-vocab": ["ABC XYZ", "abc\x00def \x7f", "‽ abc ‽", "a▁b ▁ ▁▁c"],
+    "no-space-20kB": ["abcdefgh" * 2560, "  " + "héllo" * 4096],
+    "random-mixes": _random_mixes(),
+}
+
+
+@pytest.mark.parametrize("case", SPM_TEXTS)
+def test_spm_pieces_equal_whole_text(spm_pair, case):
+    """Cut at spaces and remembered == one heap over the whole text, id
+    for id, cold and from the memo; ``decode`` gives the text back where
+    every byte had a token and the text held no escape character."""
+    fast, whole = spm_pair
+    unk = fast.token_to_id["<unk>"]
+    for text in SPM_TEXTS[case]:
+        want = whole.encode(text, add_bos=False)
+        assert fast.encode(text, add_bos=False) == want, repr(text[:80])
+        assert fast.encode(text, add_bos=False) == want     # from the memo
+        if unk not in want and "▁" not in text:     # a literal ▁ is a space
+            assert fast.decode(want) == text
+    assert whole.piece_counts() == (0, 0) and not whole._memo
+
+
+def test_spm_entry_across_a_space_keeps_whole_text_loop():
+    """``a▁b`` scored above its parts: a merge crosses the cut, so the
+    tokenizer does not cut, says so, and ``a b`` is that one entry."""
+    rows = [("<unk>", 2, 0.0), ("<s>", 3, 0.0), ("</s>", 3, 0.0),
+            ("▁", 1, -1.0), ("a", 1, -1.0), ("b", 1, -1.0),
+            ("▁b", 1, 1.0), ("a▁b", 1, 5.0)]
+    tok = SPMTokenizer([t for t, _, _ in rows], [s for _, _, s in rows],
+                       [ty for _, ty, _ in rows], add_space_prefix=False)
+    assert not tok.cuts_at_spaces
+    ids = tok.encode("a b", add_bos=False)
+    assert [tok.id_to_piece(i) for i in ids] == ["a▁b"]
+    assert tok.piece_counts() == (0, 0) and not tok._memo
+    # ... where a cut would have given its parts
+    tok.cuts_at_spaces = True
+    assert [tok.id_to_piece(i) for i in tok.encode("a b", add_bos=False)] \
+        == ["a", "▁b"]
+
+
+def test_spm_fallback_is_named_in_health():
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+
+    class Stub:
+        tokenizer = make_spm()
+    assert Engine.tokenizer_fallback.fget(Stub) is None
+    Stub.tokenizer.cuts_at_spaces = False
+    assert "whole-text merge loop" in Engine.tokenizer_fallback.fget(Stub)
+    Stub.tokenizer = make_bpe()             # another family: nothing to say
+    assert Engine.tokenizer_fallback.fget(Stub) is None
+
+
+def test_spm_memo_is_bounded(monkeypatch):
+    from llama_fastapi_k8s_gpu_tpu.tokenizer import spm
+
+    tokens, types, scores = _spm_words().synth_spm_vocab(32000)
+    tok = SPMTokenizer(tokens, scores, types)
+    whole = SPMTokenizer(tokens, scores, types)
+    whole.cuts_at_spaces = False
+    assert spm.MEMO_CAP >= 2 * 26 ** 3      # the benchmark's words, with room
+    monkeypatch.setattr(spm, "MEMO_CAP", 50)
+    word = _spm_words().word
+    for start in range(0, 400, 37):
+        text = " ".join(word(i * 7) for i in range(start, start + 90))
+        assert tok.encode(text, add_bos=False) == whole.encode(
+            text, add_bos=False)
+        assert 0 < len(tok._memo) <= 50
+    # an over-long piece goes through the loop every time, and is not kept
+    long_piece = "ab" * spm.MEMO_PIECE_CHARS
+    before = tok.piece_counts()
+    for _ in range(2):
+        assert tok.encode(long_piece + " abc", add_bos=False) == whole.encode(
+            long_piece + " abc", add_bos=False)
+    after = tok.piece_counts()
+    assert after[0] - before[0] == 4 and after[1] - before[1] == 1
+    assert max(map(len, tok._memo)) <= spm.MEMO_PIECE_CHARS
+    assert "▁" + long_piece not in tok._memo and "▁abc" in tok._memo
+
+
+def test_spm_piece_counters_and_two_threads(monkeypatch):
+    import threading
+
+    tokens, types, scores = _spm_words().synth_spm_vocab(32000)
+    tok = SPMTokenizer(tokens, scores, types)
+    word = _spm_words().word
+    text = " ".join(word(i * 13) for i in range(300))      # 300 distinct
+    tok.encode(text + " " + text, add_bos=False)
+    assert tok.piece_counts() == (600, 300)     # the second half: the memo's
+    tok.encode(text, add_bos=False)
+    assert tok.piece_counts() == (900, 600)     # again: hits = pieces
+    assert tok.piece_counts(thread_only=True) == (900, 600)
+
+    # two threads at once, on a cold memo that is emptied as they go
+    from llama_fastapi_k8s_gpu_tpu.tokenizer import spm
+    texts = [" ".join(word(int(i)) for i in
+                      np.random.default_rng(s).integers(0, 26 ** 3, 2000))
+             for s in range(6)]
+    want = [tok.encode(t, add_bos=False) for t in texts]
+    cold = SPMTokenizer(tokens, scores, types)
+    got, own = {}, {}
+
+    def work(name, order):
+        got[name] = [cold.encode(texts[k], add_bos=False) for k in order]
+        own[name] = cold.piece_counts(thread_only=True)
+
+    monkeypatch.setattr(spm, "MEMO_CAP", 500)
+    threads = [threading.Thread(target=work, args=(n, o)) for n, o in
+               (("a", range(6)), ("b", range(5, -1, -1)))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert got["a"] == want and got["b"] == want[::-1]
+    assert own["a"][0] == own["b"][0] == 6 * 2000
+    assert cold.piece_counts() == tuple(
+        own["a"][k] + own["b"][k] for k in (0, 1))
+
+
+def test_spm_warm_11k_words_a_fraction_of_whole_text():
+    """The long-document prompt of the benchmark (11k words of the
+    ``spm_words`` table): with the memo warm the encode takes a small
+    fraction of the whole-text loop's time, both measured here, so a loaded
+    runner moves both."""
+    import time
+
+    tokens, types, scores = _spm_words().synth_spm_vocab(153600)
+    tok = SPMTokenizer(tokens, scores, types)
+    whole = SPMTokenizer(tokens, scores, types)
+    whole.cuts_at_spaces = False
+    word = _spm_words().word
+    rng = np.random.default_rng(5)
+    text = " ".join(word(int(i)) for i in rng.integers(0, 26 ** 3, 11000))
+
+    def best_of(t, n=3):
+        best, ids = float("inf"), None
+        for _ in range(n):
+            t0 = time.perf_counter()
+            ids = t.encode(text, add_bos=False)
+            best = min(best, time.perf_counter() - t0)
+        return best, ids
+
+    t_whole, want = best_of(whole)
+    t_cold0 = time.perf_counter()
+    assert tok.encode(text, add_bos=False) == want
+    t_cold = time.perf_counter() - t_cold0
+    t_warm, got = best_of(tok)
+    assert got == want and len(want) == 11000
+    # measured 4 ms against 120-160 ms; a cold memo 85 ms (a word twice in
+    # the prompt is merged once)
+    assert t_warm < 0.15 * t_whole, (t_warm, t_whole)
+    assert t_cold < 1.5 * t_whole, (t_cold, t_whole)
+
+
+@pytest.fixture(scope="module")
+def spm_engine(tmp_path_factory):
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_mistral_gguf
+
+    path = str(tmp_path_factory.mktemp("spm") / "tiny-mistral.gguf")
+    write_tiny_mistral_gguf(path)
+    return Engine(path, n_ctx=64, decode_chunk=4, max_gen_tokens=8,
+                  prefill_buckets=(32, 64))
+
+
+def _tokenize_span(eng, msgs):
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import Tracer
+
+    tracer = Tracer(sample=1.0, ring=4)
+    tr = tracer.start()
+    eng.create_chat_completion(msgs, temperature=0.0, max_tokens=2, trace=tr)
+    tracer.finish(tr)
+
+    def find(node):
+        if node["name"] == "tokenize":
+            return node
+        return next((f for c in node["children"] if (f := find(c))), None)
+    return find(tr.to_dict()["root"])["attrs"]
+
+
+@pytest.mark.anyio
+async def test_spm_span_attrs_and_metrics(spm_engine):
+    """The request's ``tokenize`` span says how many pieces its prompt was
+    and how many the memo answered; /metrics serves the sums; /health has
+    nothing to say of a vocabulary that cuts."""
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.obs.catalog import GAUGE, METRICS
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    eng = spm_engine
+    assert isinstance(eng.tokenizer, SPMTokenizer)
+    msgs = [{"role": "user", "content": "one two  three one"}]
+    p0, h0 = eng.tokenizer.piece_counts()
+    first = _tokenize_span(eng, msgs)
+    # [INST] one two  three one [/INST]: six pieces, "▁one" twice
+    assert first["n_prompt"] > first["pieces"] == 6
+    assert first["memo_hits"] == 1
+    again = _tokenize_span(eng, msgs)
+    assert again["pieces"] == again["memo_hits"] == 6
+    gauges = eng.cache_read_gauges()
+    assert gauges["tokenizer_pieces_total"] == p0 + 12
+    assert gauges["tokenizer_memo_hits_total"] == h0 + 7
+    assert METRICS["tokenizer_pieces_total"].mtype == GAUGE
+    assert METRICS["tokenizer_memo_hits_total"].mtype == GAUGE
+
+    app = create_app(engine=eng, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            m = (await client.get("/metrics")).text
+            assert f"\ntokenizer_pieces_total {p0 + 12}" in m
+            assert f"\ntokenizer_memo_hits_total {h0 + 7}" in m
+            h = (await client.get("/health")).json()
+            assert "tokenizer" not in h["engine"]
+            eng.tokenizer.cuts_at_spaces = False
+            try:
+                h = (await client.get("/health")).json()
+                assert "whole-text" in h["engine"]["tokenizer"]
+            finally:
+                eng.tokenizer.cuts_at_spaces = True
+        await app.router.shutdown()
