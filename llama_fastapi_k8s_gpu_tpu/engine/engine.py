@@ -354,9 +354,16 @@ class Engine:
             attn_impl = "xla"   # its attention is models/eva.py's own
         if self.cfg.cache_kind == STATE_RING:
             self._refuse_for_state_cache(bool(kv_paged))
+        latent_kernel = False
         if self.cfg.cache_kind == LATENT_RING:
             self._refuse_for_latent_cache(bool(kv_paged))
-            attn_impl = "xla"   # its attention is models/mla.py's own loop
+            # a decode step's read of the latent leaf is the decode kernel
+            # where the chip compiles it (``auto``: a TPU; probed below);
+            # ``attn_impl`` itself stays xla: a prefill slice's attention is
+            # models/mla.py's own loop, and the flash kernel serves nothing
+            latent_kernel = attn_impl == "pallas" or (
+                attn_impl == "auto" and jax.default_backend() == "tpu")
+            attn_impl = "xla"
         if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
             self._refuse_for_hybrid_cache(bool(kv_paged))
         # the compile probes of the attention side: one phase of the
@@ -375,6 +382,19 @@ class Engine:
                 force_xla_quant(True)
                 logger.error("pallas kv-quantize kernel failed its compile "
                              "probe; cache writes quantize via XLA: %s", err)
+        if latent_kernel:
+            # (ops/pallas/probe.py) a Mosaic failure degrades the step to
+            # the XLA loop, and says so
+            from ..ops.pallas.probe import probe_latent_decode
+
+            probing.attrs["kernels"].append("latent_decode")
+            err = probe_latent_decode()
+            if err is None:
+                self.cfg = dataclasses.replace(self.cfg, latent_kernel=True)
+            else:
+                logger.error("pallas latent decode kernel failed its compile "
+                             "probe; decode steps read the latent ring "
+                             "through the XLA loop: %s", err)
         if attn_impl == "auto":
             # the flash kernel wants lane-aligned heads; anything else (tiny
             # test models, CPU runs) stays on the XLA score-matrix path

@@ -113,6 +113,12 @@ class ModelConfig:
     # expands to ``qk_nope_dim`` key and ``v_head_dim`` value columns, plus
     # ONE rotated key of ``qk_rope_dim`` shared by all heads.  The cache
     # keeps the latent and the rotated key (``cache_kind`` latent-ring).
+    # ``latent_kernel``: a decode step reads the cache through the decode
+    # kernel (ops/pallas/attention.py ``latent_attention_decode``).  Set by
+    # the engine, never by a file or a user: a TPU whose compiler took the
+    # kernel's probe.  (``attn_impl`` stays ``xla`` for this kind: it names
+    # the prefill slices' attention, models/mla.py's own loop.)
+    latent_kernel: bool = False
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0

@@ -246,12 +246,25 @@ def decode_kernel_block(cfg: ModelConfig) -> int:
     flash probe passed: engine/engine.py; a mesh engine resolves to
     ``xla``, sequence parallelism to ``ring``) whose slots its block
     divides; the block is a power of two by the KV heads a copy spans
-    (``DECODE_KERNEL_BLOCK``)."""
-    if cfg.attn_impl != "pallas" or cfg.eva_window or cfg.kv_dtype == "int8":
-        return 0
-    least, most, head_slots = DECODE_KERNEL_BLOCK
-    per_head = max(head_slots // cfg.n_kv_heads, 1)
-    block = min(max(1 << (per_head.bit_length() - 1), least), most, cfg.n_ctx)
+    (``DECODE_KERNEL_BLOCK``).  A latent ring (models/mla.py) is read by
+    the same kernel on its one leaf where ``cfg.latent_kernel`` says so
+    (the engine's: a TPU, the kernel's own probe passed; ``attn_impl`` is
+    ``xla`` for that kind), in blocks of its own
+    (``mla.LATENT_KERNEL_BLOCK`` rows for all heads)."""
+    if cfg.cache_kind == LATENT_RING:
+        if not cfg.latent_kernel:
+            return 0
+        from .mla import LATENT_KERNEL_BLOCK
+
+        block = min(LATENT_KERNEL_BLOCK, cfg.n_ctx)
+    else:
+        if cfg.attn_impl != "pallas" or cfg.eva_window \
+                or cfg.kv_dtype == "int8":
+            return 0
+        least, most, head_slots = DECODE_KERNEL_BLOCK
+        per_head = max(head_slots // cfg.n_kv_heads, 1)
+        block = min(max(1 << (per_head.bit_length() - 1), least), most,
+                    cfg.n_ctx)
     return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
 
 

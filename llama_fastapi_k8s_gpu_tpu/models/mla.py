@@ -22,9 +22,22 @@
   block of latents is read ONCE for all heads, which is the mechanism's
   point; the expanded form (:func:`expanded_attention`: K and V of every
   head for every position) is what the reference computes and what
-  tests hold the absorbed form to.  A decode step and a prefill slice run
-  the same loop over blocks of ``LATENT_BLOCK`` positions up to a traced
-  bound, in plain XLA (flash recurrence: running max and sum).
+  tests hold the absorbed form to.  WHICH READ SERVES WHICH S: a prefill
+  slice (S > 1), and a decode step (S = 1) on the CPU or wherever
+  ``llama.decode_kernel_block`` says 0, run :func:`latent_attention`, a
+  loop in plain XLA over blocks of ``LATENT_BLOCK`` positions up to a
+  traced bound (flash recurrence: running max and sum; under the lane
+  engine's ``vmap`` ONE bound for all lanes), after an XLA
+  ``dynamic_update_slice`` of the pass's rows.  A decode step where
+  ``cfg.latent_kernel`` is set (the engine's: a TPU, the probe passed) is
+  ONE Pallas kernel over the lanes (ops/pallas/attention.py
+  ``latent_attention_decode``, the ring's decode kernel on one leaf): each
+  live lane's own blocks of ``LATENT_KERNEL_BLOCK`` rows read in place, a
+  block copied once for the scores (all 640 columns) and the weighted sum
+  (its first ``kv_lora_rank``), the step's row set into the block that
+  holds it and its 16-row tile copied back; a lane that holds no request
+  reads and stores nothing.  The same recurrence in the same order: tests
+  hold the kernel to the loop.
 - The feed-forward kind is the LAYER's (models/routed.py, shared with
   models/hybrid.py): leading dense layers, then a float32 grouped router
   over the experts HELD here plus a shared expert.  The two kinds are two
@@ -49,6 +62,16 @@ from .routed import (  # noqa: F401  (``mla.route_grouped``: the tests' name)
 #: latent rows a block of :func:`latent_attention`'s loop reads (the XLA
 #: loop of ``models/llama.py decode_attention`` reads 512 ring slots a time)
 LATENT_BLOCK = 512
+
+#: latent rows the decode KERNEL copies at a time
+#: (ops/pallas/attention.py ``latent_attention_decode``; ``models/llama.py
+#: decode_kernel_block`` says where it serves): 1.3 MB a copy at 640
+#: columns.  On the chip a block of 256 / 512 / 1024 rows costs 0.76 / 1.09
+#: / 1.84 us (0.4 us fixed, then 0.36 us a 256 rows, which is 89 % of the
+#: HBM's rate), so at 12 live lanes of context 8.7k a step's seven layers
+#: take 2.23 / 1.62 / 1.42 ms, with the half block a lane reads past its
+#: position counted in (PERF.md section 6, PR 47).
+LATENT_KERNEL_BLOCK = 1024
 
 
 def lat_width(cfg: ModelConfig) -> int:
@@ -224,11 +247,14 @@ def expanded_attention(q_n, q_r, rows, w_uk, w_uv, positions,
 # the layers
 # ---------------------------------------------------------------------------
 
-def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, kv_bound):
+def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
+               kv_bound):
     """One layer's attention branch.  ``i``: the layer's number within its
     kind's stack of weights, ``li``: its number in the whole stack (the
-    cache's).  Returns (h + branch, cache)."""
-    from .llama import rms_norm
+    cache's).  ``live`` (scalar bool or None): whether this sequence holds
+    a request; the decode kernel reads and stores nothing where not.
+    Returns (h + branch, cache)."""
+    from .llama import decode_kernel_block, rms_norm
 
     S = h.shape[0]
     H, r, d_n, d_r = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
@@ -252,24 +278,40 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, kv_bound):
     rows = jnp.concatenate(
         [c, k_r, jnp.zeros((S, fill), c.dtype)], axis=-1
     ).astype(cache["lat"].dtype)
-    with jax.named_scope("kv_write"):
-        cache = {"lat": jax.lax.dynamic_update_slice(
-            cache["lat"], rows[None, None], (li, 0, pos_offset, 0))}
     q_full = jnp.concatenate(
         [absorb_query(q[..., :d_n], layers["w_uk"]["w"][i]), q_r,
          jnp.zeros((S, H, fill), q_r.dtype)], axis=-1)
-    bound = pos_offset + S - 1 if kv_bound is None or S > 1 else kv_bound
-    with jax.named_scope("mla_attn"):
-        ctx = latent_attention(q_full, cache["lat"], li, positions, bound, cfg)
+    block = decode_kernel_block(cfg) if S == 1 else 0
+    if block:
+        # the decode kernel: this sequence's own blocks, read in place, and
+        # the step's row stored into the block it reads anyway
+        from ..ops.pallas import latent_attention_decode, use_interpret
+
+        with jax.named_scope("mla_attn"):
+            ctx, lat = latent_attention_decode(
+                q_full[0], cache["lat"], li, pos_offset,
+                True if live is None else live, rows[0],
+                sm_scale=attn_scale(cfg), block_k=block, v_width=r,
+                interpret=use_interpret())
+        cache, ctx = {"lat": lat}, ctx.reshape(H, 1, r)
+    else:
+        with jax.named_scope("kv_write"):
+            cache = {"lat": jax.lax.dynamic_update_slice(
+                cache["lat"], rows[None, None], (li, 0, pos_offset, 0))}
+        bound = pos_offset + S - 1 if kv_bound is None or S > 1 else kv_bound
+        with jax.named_scope("mla_attn"):
+            ctx = latent_attention(q_full, cache["lat"], li, positions, bound,
+                                   cfg)
     o = expand_values(ctx, layers["w_uv"]["w"][i], h.dtype)
     return h + lin(o, "wo"), cache
 
 
-def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, kv_bound):
+def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
+                kv_bound):
     from .llama import rms_norm
 
     h, cache = _attention(h, layers, i, i, cache, positions, pos_offset, cfg,
-                          kv_bound)
+                          live, kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
     return h + swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache
 
@@ -281,7 +323,7 @@ def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
     from .llama import rms_norm
 
     h, cache = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
-                          positions, pos_offset, cfg, kv_bound)
+                          positions, pos_offset, cfg, live, kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
     out, routed = expert_branch(hn, layers, i, cfg, live)
     return h + out, cache, routed
@@ -307,7 +349,8 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
 
     def dense_body(i, carry):
         return dense_layer(carry[0], params["layers"][DENSE], jnp.int32(i),
-                           carry[1], positions, pos_offset, cfg, kv_bound)
+                           carry[1], positions, pos_offset, cfg, live,
+                           kv_bound)
 
     def moe_body(i, carry):
         h, cache, routed = moe_layer(

@@ -414,8 +414,10 @@ METRICS: dict[str, Metric] = _register(
     # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
     Metric("latent_positions_read_total", GAUGE,
            "cached latent rows the decode steps' attention covered (whole "
-           "blocks up to the largest LIVE lane's position, summed over live "
-           "lanes and steps; a row is read once for all heads), cumulative"),
+           "blocks: under the decode kernel each lane's own, up to its "
+           "position; under the XLA loop up to the largest LIVE lane's "
+           "position; summed over live lanes and steps; a row is read once "
+           "for all heads), cumulative"),
     Metric("latent_positions_live_total", GAUGE,
            "cached latent rows at or below the decoding positions in the "
            "same steps (what the attention needed); over "

@@ -28,7 +28,8 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-from .attention import flash_attention, flash_attention_decode  # noqa: E402
+from .attention import (  # noqa: E402
+    flash_attention, flash_attention_decode, latent_attention_decode)
 from .dequant import (  # noqa: E402
     device_dequant,
     dequant_q4_k_device,
@@ -44,6 +45,7 @@ from .qmatmul import prep_q4k, q4k_matmul, q4k_matmul_stacked  # noqa: E402
 __all__ = [
     "flash_attention",
     "flash_attention_decode",
+    "latent_attention_decode",
     "device_dequant",
     "dequant_q4_k_device",
     "dequant_q5_k_device",

@@ -384,12 +384,15 @@ def _decode_kernel(
     # slot_ref            SMEM (1,): the slot the next block to consume is in
     # nxt_ref             SMEM (B + 1,): first lane >= c that reads anything
     # store only:         wsem, DMA semaphores (2 = k|v) of the rows' tiles
+    # (``v_width``: ONE ring, one row, one buffer, one semaphore each; o_ref
+    # and acc_ref ``v_width`` wide)
     block_k: int,
     n_ctx: int,
     sliding_window: int,
     sm_scale: float,
     store: bool = False,
     wrap: bool = False,
+    v_width: int = 0,
 ):
     """One grid step is one LANE: a loop over that lane's own blocks, from
     the sliding window's first to the one that holds its position, with a
@@ -416,13 +419,23 @@ def _decode_kernel(
     in slot ``p % n_ctx``, the step's row is stored there (over the row of
     position ``p - n_ctx``, which the window no longer holds), a lane reads
     the blocks that hold a live position (all of them once it has wrapped)
-    and the mask is on the POSITION a slot holds, not on the slot."""
+    and the mask is on the POSITION a slot holds, not on the slot.
+
+    ``v_width``: the leaf is a LATENT ring's (models/mla.py: one row a
+    position for all heads, ``n_kv`` 1): there is ONE ring, the keys are
+    its whole rows and the values the same rows' first ``v_width`` columns,
+    so a block is copied once and serves both products; the step's row is
+    one row."""
+    nr = 1 if v_width else 2          # rings, and all that is one a ring
     if store:
-        kn_ref, vn_ref, _, _, o_ref, k_hbm, v_hbm, kbuf, vbuf, sem, m_ref, \
-            l_ref, acc_ref, slot_ref, nxt_ref, wsem = rest
+        new_refs, o_ref, rest = rest[:nr], rest[2 * nr], rest[2 * nr + 1:]
     else:
-        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, \
-            slot_ref, nxt_ref = rest
+        new_refs, o_ref, rest = (), rest[nr], rest[:nr] + rest[nr + 1:]
+    pairs = tuple(zip(rest[:nr], rest[nr:2 * nr]))  # (ring in HBM, buffer)
+    # (``wsem``, the last: store only)
+    sem, m_ref, l_ref, acc_ref, slot_ref, nxt_ref = rest[2 * nr:2 * nr + 6]
+    wsem = rest[-1]
+    kbuf, vbuf = pairs[0][1], pairs[-1][1]
     T = block_k
     b = pl.program_id(0)
     B = pl.num_programs(0)
@@ -442,7 +455,7 @@ def _decode_kernel(
         return [pltpu.make_async_copy(
             ring.at[lane, layer, :, pl.ds(at, T), :], buf.at[slot],
             sem.at[n, slot])
-            for n, (ring, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+            for n, (ring, buf) in enumerate(pairs)]
 
     def start(lane, j, slot):
         for c in copies(lane, j, slot):
@@ -457,7 +470,7 @@ def _decode_kernel(
         return [pltpu.make_async_copy(
             buf.at[slot, :, pl.ds(r, _ROW_TILE), :],
             ring.at[b, layer, :, pl.ds(at, _ROW_TILE), :], wsem.at[n])
-            for n, (ring, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+            for n, (ring, buf) in enumerate(pairs)]
 
     @pl.when(b == 0)
     def _first():
@@ -507,7 +520,7 @@ def _decode_kernel(
                 at = (jax.lax.rem(pos, n_ctx) if wrap
                       else jnp.minimum(pos, n_ctx - 1)) - j * T
                 r = pl.multiple_of(at // _ROW_TILE * _ROW_TILE, _ROW_TILE)
-                for buf, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
+                for (_, buf), new_ref in zip(pairs, new_refs):
                     tile = buf[slot, :, pl.ds(r, _ROW_TILE), :]
                     row = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
                     buf[slot, :, pl.ds(r, _ROW_TILE), :] = jnp.where(
@@ -519,7 +532,7 @@ def _decode_kernel(
 
         q = q_ref[0]                                   # (n_kv, ROWS, hd)
         k = kbuf[slot]                                 # (n_kv, T, hd)
-        v = vbuf[slot]
+        v = vbuf[slot, :, :, :v_width] if v_width else vbuf[slot]
         s = jnp.einsum("ngh,nth->ngt", q, k,
                        preferred_element_type=jnp.float32) * sm_scale
         key_pos = j * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -566,101 +579,111 @@ def _decode_kernel(
     o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def _decode_lanes(q, k, v, i, pos, live, k_new=None, v_new=None, *,
-                  block_k: int, sm_scale: float, sliding_window: int,
-                  interpret: bool, wrap: bool = False):
-    """q (B, n_heads, hd), k / v (B, L, n_kv, n_ctx, hd), i scalar, pos and
-    live (B,) -> (B, n_heads * hd) in q.dtype: ONE kernel over the lanes.
-    With the step's rows ``k_new`` / ``v_new`` (B, n_kv, hd) the kernel
-    stores them at (lane, i, :, pos, :) of the rings, which it then
-    returns beside the context as outputs aliased onto their inputs.
-    ``wrap``: k / v are a window layer's leaves, whose ``n_ctx`` slots
-    wrap; the kernel is then named ``flash_attention_decode_window``."""
+def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
+                  sliding_window: int, interpret: bool, wrap: bool = False,
+                  v_width: int = 0):
+    """q (B, n_heads, hd), i scalar, pos and live (B,), then the rings k / v
+    (B, L, n_kv, n_ctx, hd) -> (B, n_heads * hd) in q.dtype: ONE kernel over
+    the lanes.  With the step's rows ``k_new`` / ``v_new`` (B, n_kv, hd)
+    after the rings the kernel stores them at (lane, i, :, pos, :) of the
+    rings, which it then returns beside the context as outputs aliased onto
+    their inputs.  ``wrap``: k / v are a window layer's leaves, whose
+    ``n_ctx`` slots wrap; the kernel is then named
+    ``flash_attention_decode_window``.  ``v_width``: ONE ring (and one row)
+    in place of two, a latent leaf (B, L, 1, n_ctx, hd) whose rows are the
+    keys and their first ``v_width`` columns the values: (B, n_heads *
+    v_width); the kernel is then named ``flash_attention_decode_latent``."""
+    n = 1 if v_width else 2
+    rings, rows = arrays[:n], arrays[n:]
     B, n_heads, hd = q.shape
-    _, _, n_kv, n_ctx, _ = k.shape
+    _, _, n_kv, n_ctx, _ = rings[0].shape
     group = n_heads // n_kv
-    store = k_new is not None
+    store = bool(rows)
     if n_ctx % block_k:
         raise ValueError(f"the ring's {n_ctx} slots are no multiple of the "
                          f"decode kernel's block of {block_k}")
     if store and block_k % _ROW_TILE:
         raise ValueError(f"the decode kernel's block of {block_k} slots is "
                          f"no multiple of the {_ROW_TILE} rows it stores by")
-    rows = max(_DECODE_ROWS, group)
+    n_rows = max(_DECODE_ROWS, group)
+    out_w = v_width or hd
     qg = jnp.pad(q.reshape(B, n_kv, group, hd),
-                 ((0, 0), (0, 0), (0, rows - group), (0, 0)))
-    lane_block = pl.BlockSpec((1, n_kv, rows, hd), lambda b, *_: (b, 0, 0, 0))
+                 ((0, 0), (0, 0), (0, n_rows - group), (0, 0)))
+    lane_block = pl.BlockSpec((1, n_kv, n_rows, hd),
+                              lambda b, *_: (b, 0, 0, 0))
+    out_block = pl.BlockSpec((1, n_kv, n_rows, out_w),
+                             lambda b, *_: (b, 0, 0, 0))
     row_block = pl.BlockSpec((1, n_kv, 1, hd), lambda b, *_: (b, 0, 0, 0))
     in_place = pl.BlockSpec(memory_space=pl.ANY)
-    ctx_shape = jax.ShapeDtypeStruct((B, n_kv, rows, hd), q.dtype)
-    new = [x.reshape(B, n_kv, 1, hd) for x in (k_new, v_new)] if store else []
+    ctx_shape = jax.ShapeDtypeStruct((B, n_kv, n_rows, out_w), q.dtype)
+    new = [x.reshape(B, n_kv, 1, hd) for x in rows]
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k, n_ctx=n_ctx,
                           sliding_window=sliding_window, sm_scale=sm_scale,
-                          store=store, wrap=wrap),
+                          store=store, wrap=wrap, v_width=v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[lane_block] + [row_block] * len(new)
-            + [in_place, in_place],
-            out_specs=[lane_block, in_place, in_place] if store
-            else lane_block,
+            in_specs=[lane_block] + [row_block] * len(new) + [in_place] * n,
+            out_specs=[out_block] + [in_place] * n if store else out_block,
             scratch_shapes=[
-                pltpu.VMEM((2, n_kv, block_k, hd), k.dtype),
-                pltpu.VMEM((2, n_kv, block_k, hd), v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
-                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
-                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+                pltpu.VMEM((2, n_kv, block_k, hd), r.dtype) for r in rings
+            ] + [
+                pltpu.SemaphoreType.DMA((n, 2)),
+                pltpu.VMEM((n_kv, n_rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, n_rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, n_rows, out_w), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.SMEM((B + 1,), jnp.int32),
-            ] + ([pltpu.SemaphoreType.DMA((2,))] if store else []),
+            ] + ([pltpu.SemaphoreType.DMA((n,))] if store else []),
         ),
-        out_shape=[ctx_shape, jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)] if store
-        else ctx_shape,
-        # operands 6 and 7 (after the three prefetched scalars, the queries
-        # and the two rows): the rings, updated in place
-        input_output_aliases={6: 1, 7: 2} if store else {},
+        out_shape=[ctx_shape] + [jax.ShapeDtypeStruct(r.shape, r.dtype)
+                                 for r in rings] if store else ctx_shape,
+        # the rings (after the three prefetched scalars, the queries and
+        # the rows), updated in place
+        input_output_aliases={4 + n + r: 1 + r for r in range(n)}
+        if store else {},
         # lanes in order: the slot parity and the copy started ahead are
         # carried from one lane to the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="flash_attention_decode_window" if wrap
-        else "flash_attention_decode",
+        name="flash_attention_decode" + (
+            "_latent" if v_width else "_window" if wrap else ""),
     )(jnp.asarray(i, jnp.int32).reshape(1), pos.astype(jnp.int32),
-      live.astype(jnp.int32), qg, *new, k, v)
+      live.astype(jnp.int32), qg, *new, *rings)
     ctx, *rings = out if store else (out,)
-    ctx = ctx[:, :, :group, :].reshape(B, n_heads * hd)
+    ctx = ctx[:, :, :group, :].reshape(B, n_heads * out_w)
     return (ctx, *rings) if store else ctx
 
 
 @functools.lru_cache(maxsize=8)
 def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
-                      interpret: bool, wrap: bool = False):
+                      interpret: bool, wrap: bool = False, v_width: int = 0):
     """The per-sequence call with its vmap rule: lanes ``vmap``ped over one
     step become ONE kernel over (B lanes), as the fused matmuls' rows do
     (qmatmul.py ``rows_vmappable``); without the rule ``vmap`` would batch
     the kernel's grid and every lane would run the longest lane's trips.
-    ``rows``: nothing, or the step's K and V row (the rings are then
-    returned beside the context, all batched)."""
+    ``rings``: the two rings or the one latent leaf; ``rows``: nothing, or
+    the step's row for each (the rings are then returned beside the
+    context, all batched)."""
     from jax.custom_batching import custom_vmap
 
     lanes = functools.partial(
         _decode_lanes, block_k=block_k, sm_scale=sm_scale,
-        sliding_window=sliding_window, interpret=interpret, wrap=wrap)
+        sliding_window=sliding_window, interpret=interpret, wrap=wrap,
+        v_width=v_width)
 
     @custom_vmap
-    def one(q, k, v, i, pos, live, *rows):
-        out = lanes(q[None], k[None], v[None], i, pos[None], live[None],
-                    *(r[None] for r in rows))
+    def one(q, rings, i, pos, live, rows):
+        q, rings, pos, live, rows = jax.tree.map(
+            lambda x: x[None], (q, rings, pos, live, rows))
+        out = lanes(q, i, pos, live, *rings, *rows)
         return jax.tree.map(lambda x: x[0], out)
 
     @one.def_vmap
-    def _rule(axis_size, in_batched, q, k, v, i, pos, live, *rows):  # noqa: ANN001
-        qb, kb, vb, ib, *rest_b = in_batched
-        if ib:
+    def _rule(axis_size, in_batched, q, rings, i, pos, live, rows):  # noqa: ANN001
+        if in_batched[2]:
             raise NotImplementedError(
                 "decode attention vmap: the layer index is one for all lanes")
 
@@ -668,9 +691,10 @@ def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
             return x if batched else jnp.broadcast_to(
                 x, (axis_size, *x.shape))
 
-        out = lanes(per_lane(q, qb), per_lane(k, kb), per_lane(v, vb), i,
-                    *(per_lane(x, xb)
-                      for x, xb in zip((pos, live, *rows), rest_b)))
+        qb, rb, _, *rest_b = in_batched
+        q, rings, pos, live, rows = jax.tree.map(
+            per_lane, (q, rings, pos, live, rows), (qb, rb, *rest_b))
+        out = lanes(q, i, pos, live, *rings, *rows)
         return out, jax.tree.map(lambda _: True, out)
 
     return one
@@ -722,8 +746,39 @@ def flash_attention_decode(
                                      v_new.astype(v.dtype))
     return _decode_vmappable(int(block_k), float(sm_scale),
                              int(sliding_window), bool(interpret), bool(wrap))(
-        q, k, v, jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
-        jnp.asarray(live, jnp.bool_), *rows)
+        q, (k, v), jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(live, jnp.bool_), rows)
+
+
+def latent_attention_decode(
+    q: jax.Array,          # (n_heads, w): ONE sequence's [q_abs | q_r | 0]
+    lat: jax.Array,        # (L, 1, n_ctx, w): the STACKED bf16 latent leaf,
+    #                        left in HBM and read in place at layer i
+    i: jax.Array,          # scalar int32: the layer (the leaf's)
+    pos: jax.Array,        # scalar int32: this sequence's position
+    live: jax.Array,       # scalar bool: False = reads and stores nothing
+    row: jax.Array,        # (w,): this step's [c | k_r | 0], not cached yet
+    *,
+    sm_scale: float,
+    block_k: int,
+    v_width: int,          # kv_lora_rank: a row's first columns, the values
+    interpret: bool = False,
+):
+    """A decode step's ABSORBED latent attention (``models/mla.py
+    latent_attention`` at S = 1) as :func:`flash_attention_decode`'s kernel
+    on one ring in place of two: the leaf is one KV "head" for all
+    ``n_heads`` query rows, a block of it is copied ONCE and is the keys
+    (all ``w`` columns) and the values (its first ``v_width``), and the
+    step's row is stored as that kernel stores a ring's (clamped alike).
+    The same recurrence in the same order, bounded per lane, nothing for a
+    lane that is not ``live``.  Returns (the weighted sum of LATENTS
+    (n_heads * v_width,) in q.dtype, before ``W_uv``; the leaf, the very
+    buffer that came in).  One kernel under ``vmap`` over lanes too."""
+    ctx, lat = _decode_vmappable(int(block_k), float(sm_scale), 0,
+                                 bool(interpret), False, int(v_width))(
+        q, (lat,), jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(live, jnp.bool_), (row.astype(lat.dtype)[None],))
+    return ctx, lat
 
 
 # devtime inventory (lfkt-lint PERF001): flash attention is a TRACE-INNER

@@ -251,6 +251,47 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
 
 
 @functools.lru_cache(maxsize=1)
+def probe_latent_decode() -> str | None:
+    """Compile + run the decode kernel on a latent leaf (one ring of rows
+    for all heads, the values a row's first columns: ops/pallas/
+    attention.py ``latent_attention_decode``) over two lanes, one of them
+    dead, two blocks deep, at the published widths and the block that
+    serves (64 heads, rows of 512 + 64 laid out as 640, blocks of
+    ``mla.LATENT_KERNEL_BLOCK``; 4 heads, 128 + 128 and 16 in interpret
+    mode).  A
+    failure leaves a ``deepseek2`` file's decode steps on the XLA loop of
+    ``models/mla.py latent_attention`` (``cfg.latent_kernel`` stays
+    False)."""
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .attention import latent_attention_decode
+
+        from ...models.mla import LATENT_KERNEL_BLOCK
+
+        itp = use_interpret()
+        H, W, R, CTX = (4, 256, 128, 32) if itp \
+            else (64, 640, 512, 2 * LATENT_KERNEL_BLOCK)
+
+        def decode(q, live):
+            lat = jnp.ones((2, 1, CTX, W), jnp.bfloat16)
+            ctx, lat = latent_attention_decode(
+                q, lat, jnp.int32(1), jnp.int32(CTX - 1), live, q[0],
+                sm_scale=W ** -0.5, block_k=CTX // 2, v_width=R,
+                interpret=itp)
+            return ctx.astype(jnp.float32).sum() + lat[1, 0, CTX - 1, 0]
+
+        out = jax.jit(jax.vmap(decode))(jnp.ones((2, H, W), jnp.bfloat16),
+                                        jnp.asarray([True, False]))
+        float(out.sum())
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)
+
+
+@functools.lru_cache(maxsize=1)
 def probe_kv_quant() -> str | None:
     """Compile + run the int8 KV-cache write-quantize kernel
     (ops/pallas/kvquant.py) at a decode-like shape.  A failure degrades
@@ -276,6 +317,7 @@ from ...obs.devtime import register_program  # noqa: E402
 
 register_program("probe_flash_attention", site="ops.pallas.probe")
 register_program("probe_lin_state", site="ops.pallas.probe")
+register_program("probe_latent_decode", site="ops.pallas.probe")
 
 
 @functools.lru_cache(maxsize=1)

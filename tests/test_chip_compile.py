@@ -758,17 +758,23 @@ def test_flash_attention_on_a_tpu_mesh_is_refused(topo):
 # BENCHMARK.json's gigachat configuration at its published widths (hidden
 # 7168 filled up to K 8192, 64 heads, latents 1536 / 512 + 64, 1 dense + 6
 # routed layers holding 32 of 256 experts), n_ctx 16384: (name, lanes)
-@pytest.mark.parametrize("name,lanes", [("gigachat-serial", 0),
-                                        ("gigachat-16lane", 16)])
+@pytest.mark.parametrize("name,lanes,read", [
+    ("gigachat-serial", 0, "loop"), ("gigachat-16lane", 16, "loop"),
+    ("gigachat-serial-kernel", 0, "kernel"),
+    ("gigachat-16lane-kernel", 16, "kernel")])
 def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
-                                                       name, lanes):
+                                                       name, lanes, read):
     """The decode chunk and the prefill slice of the ``deepseek2`` stack
     (models/mla.py) compile for the chip: the fused planes at K 8192 (the
     experts' gate and up among them: the grouped kernels are in the program
     under their own names), the latent projection at 640 rows, the absorbed
-    attention's loop over blocks of the lanes' stacked latent leaf.  The
-    compiler has put NO copy or transpose of the latent leaf in the decode
-    chunk, and a slice's scratch stays under half a GB."""
+    attention's loop over blocks of the lanes' stacked latent leaf
+    (``loop``) or the decode kernel on that leaf as it is (``kernel``: the
+    leaf in its own shape is the kernel's operand and aliased result, which
+    is how benchmarks/kernels/mla_attn.json finds it; no block of the
+    lanes' leaf is materialised and no XLA update writes the step's row).
+    The compiler has put NO copy or transpose of the latent leaf in the
+    decode chunk, and a slice's scratch stays under half a GB."""
     import re
 
     from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
@@ -790,6 +796,7 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
     cfg = ModelConfig(
         vocab_size=V, dim=D, n_layers=7, n_heads=H, n_kv_heads=H, ffn_dim=F,
         n_ctx=16384, rope_theta=1e5, rms_eps=1e-6, attn_impl="xla",
+        latent_kernel=read == "kernel",
         q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_n, qk_rope_dim=d_r,
         v_head_dim=d_v, rope_yarn_factor=64.0, rope_yarn_orig_ctx=4096,
         attn_mscale=2.0048, n_dense_layers=1, expert_ffn_dim=Fe,
@@ -866,6 +873,23 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
             found.append(ln.strip()[:120])
     assert not found, found[:4]
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    leaf = "bf16[%d,7,1,16384,640]" % (lanes or 1)
+    kernel = [ln for ln in text.splitlines()
+              if re.match(r"\s*(ROOT )?%flash_attention_decode_latent", ln)]
+    if read == "kernel":
+        from llama_fastapi_k8s_gpu_tpu.models.llama import (
+            decode_kernel_block, ring_write_impl)
+
+        assert decode_kernel_block(cfg) and ring_write_impl(cfg) == "kernel"
+        # the leaf un-reshaped: the kernel's aliased result (and the lanes'
+        # axis first under the lane engine)
+        assert kernel and all(leaf in ln.split(" custom-call(")[0]
+                              for ln in kernel), kernel[:2]
+        assert "bf16[16,512,640]" not in text
+        assert not re.search(
+            r"= bf16\[(\d+,)*16384,640\]\S* dynamic-update-slice\(", text)
+        return
+    assert not kernel
     if not lanes:       # the admission slice into the scratch cache
         cache = place(jax.eval_shape(lambda: init_cache(cfg)))
         for rows in _slice_widths(cfg):     # narrow, and the wide slice

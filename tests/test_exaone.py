@@ -30,10 +30,9 @@ import sys
 import numpy as np
 import pytest
 
+from tests.test_mla import lane_alone as _lane_alone
 from tests.test_mla import (
     lanes_run, load, prefill, programs, rel, rows_that_differ, worst)
-from tests.test_mla import (
-    test_a_lanes_logits_do_not_depend_on_the_other_lanes as _lane_alone)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")

@@ -236,6 +236,133 @@ def test_absorbed_is_expanded():
     assert np.array_equal(np.asarray(far), np.asarray(ctx))
 
 
+# ---------------------------------------------------------------------------
+# the decode kernel on the latent leaf (ops/pallas/attention.py
+# latent_attention_decode, interpret mode) against the XLA write + loop
+# ---------------------------------------------------------------------------
+
+KERNEL_CTX, KERNEL_BLOCK = 64, 16
+
+
+def _kernel_inputs(n_lanes):
+    """Lanes' stacked leaves (random: every slot holds something, so a
+    read past a position or a store beside it shows), each lane's q_n /
+    q_r and the step's row, W_uk / W_uv."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.testing import TINY_MLA_CFG
+
+    cfg = dataclasses.replace(TINY_MLA_CFG, n_ctx=KERNEL_CTX)
+    H, r, d_n, d_r, d_v = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
+                           cfg.qk_rope_dim, cfg.v_head_dim)
+    W = mla.leaf_width(cfg)
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+
+    def filled(key, shape):
+        x = jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+        return x.at[..., r + d_r:].set(0)
+
+    lat = filled(keys[0], (n_lanes, 3, 1, KERNEL_CTX, W))
+    rows = filled(keys[1], (n_lanes, W))
+    q_n = jax.random.normal(keys[2], (n_lanes, 1, H, d_n)).astype(jnp.bfloat16)
+    q_r = jax.random.normal(keys[3], (n_lanes, 1, H, d_r)).astype(jnp.bfloat16)
+    w_uk = (jax.random.normal(keys[4], (H, d_n, r)) * r ** -0.5
+            ).astype(jnp.bfloat16)
+    w_uv = (jax.random.normal(keys[5], (H, d_v, r)) * r ** -0.5
+            ).astype(jnp.bfloat16)
+    q_full = jax.vmap(lambda a, b: jnp.concatenate(
+        [mla.absorb_query(a, w_uk), b,
+         jnp.zeros((1, H, W - r - d_r), b.dtype)], -1))(q_n, q_r)
+    return cfg, lat, rows, q_n, q_r, q_full, w_uk, w_uv
+
+
+def _by_the_loop(cfg, q_full, lat, pos, row, bound, layer=1):
+    """What ``mla._attention`` does for S = 1 where the kernel does not
+    serve: the XLA write, then the loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+
+    lat = jax.lax.dynamic_update_slice(lat, row[None, None, None],
+                                       (layer, 0, pos, 0))
+    ctx = mla.latent_attention(q_full, lat, layer, pos[None], bound, cfg)
+    return ctx[:, 0].astype(jnp.bfloat16), lat
+
+
+def _by_the_kernel(cfg, q_full, lat, pos, live, row, layer=1):
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import latent_attention_decode
+
+    ctx, lat = latent_attention_decode(
+        q_full[0], lat, layer, pos, live, row, sm_scale=mla.attn_scale(cfg),
+        block_k=KERNEL_BLOCK, v_width=cfg.kv_lora_rank, interpret=True)
+    return ctx.reshape(cfg.n_heads, cfg.kv_lora_rank), lat
+
+
+@pytest.mark.parametrize("name,pos,live", [
+    ("lanes_at_their_own_positions", (37, 5, 50), (True, True, True)),
+    ("a_dead_lane", (37, 20, 63), (True, False, True)),
+    ("a_blocks_first_row", (16, 32, 48), (True, True, True)),
+    ("a_blocks_last_row", (15, 31, 47), (True, True, True)),
+    ("the_leafs_last_slot", (63, 0, 62), (True, True, True)),
+    ("past_the_leaf_is_clamped_alike", (64, 70, 63), (True, True, True)),
+    ("no_lane_lives", (37, 5, 50), (False, False, False)),
+])
+def test_the_decode_kernel_is_the_loop_per_lane(name, pos, live):
+    """Under ``vmap`` (one kernel over the lanes): a live lane's weighted
+    latents are the loop's and, expanded, the expanded form's; the leaf it
+    returns is the XLA write's bit for bit; a dead lane reads nothing,
+    stores nothing and returns 0."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+
+    cfg, lat, rows, q_n, q_r, q_full, w_uk, w_uv = _kernel_inputs(3)
+    pos, live = jnp.asarray(pos, jnp.int32), jnp.asarray(live)
+    want, want_lat = jax.vmap(
+        lambda q, c, p, row: _by_the_loop(cfg, q, c, p, row, jnp.max(pos))
+    )(q_full, lat, pos, rows)
+    got, got_lat = jax.jit(jax.vmap(
+        lambda q, c, p, lv, row: _by_the_kernel(cfg, q, c, p, lv, row)
+    ))(q_full, lat, pos, live, rows)
+    r, d_r = cfg.kv_lora_rank, cfg.qk_rope_dim
+    for lane in range(3):
+        if not bool(live[lane]):
+            assert not np.asarray(got[lane], np.float32).any()
+            assert np.array_equal(np.asarray(got_lat[lane], np.float32),
+                                  np.asarray(lat[lane], np.float32))
+            continue
+        assert np.array_equal(np.asarray(got_lat[lane], np.float32),
+                              np.asarray(want_lat[lane], np.float32))
+        assert rel(got[lane].astype(jnp.float32),
+                   want[lane].astype(jnp.float32)) < 1e-2, lane
+        n = min(int(pos[lane]), KERNEL_CTX - 1) + 1
+        if int(pos[lane]) >= KERNEL_CTX:
+            continue        # the expanded form has no clamped row
+        full = mla.expanded_attention(
+            q_n[lane], q_r[lane], got_lat[lane, 1, 0, :n, :r + d_r], w_uk,
+            w_uv, pos[lane][None], cfg)
+        mine = mla.expand_values(got[lane][:, None], w_uv, jnp.float32)
+        assert rel(mine, full) < 3e-2, lane
+
+
+def test_the_decode_kernel_serves_one_sequence_without_vmap():
+    """The serial engine's call: no lanes' axis, ``live`` a plain True."""
+    import jax.numpy as jnp
+
+    cfg, lat, rows, _, _, q_full, _, _ = _kernel_inputs(1)
+    pos = jnp.int32(41)
+    want, want_lat = _by_the_loop(cfg, q_full[0], lat[0], pos, rows[0], pos)
+    got, got_lat = _by_the_kernel(cfg, q_full[0], lat[0], pos, True, rows[0])
+    assert np.array_equal(np.asarray(got_lat, np.float32),
+                          np.asarray(want_lat, np.float32))
+    assert rel(got.astype(jnp.float32), want.astype(jnp.float32)) < 1e-2
+
+
 def test_yarn_frequencies_are_the_published_blend():
     from llama_fastapi_k8s_gpu_tpu.models import mla
     from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
@@ -272,14 +399,28 @@ def test_a_claimed_prefix_gives_the_logits_of_a_full_prefill(loaded, tokens):
     assert worst(got, full[32:]) < 1e-6
 
 
-def lanes_run(loaded, tokens):
+def with_kernel(cfg, monkeypatch):
+    """``cfg`` as an engine on a TPU leaves it: the decode kernel serves a
+    step (interpret mode here), in blocks of 16 so that a lane of these
+    tests walks several."""
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.llama import decode_kernel_block
+
+    monkeypatch.setattr(mla, "LATENT_KERNEL_BLOCK", 16)
+    cfg = dataclasses.replace(cfg, latent_kernel=True)
+    assert decode_kernel_block(cfg) == 16
+    return cfg
+
+
+def lanes_run(loaded, tokens, cfg=None):
     """Three lanes at different positions; lane 2 dead, then taken."""
     import jax
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
 
-    params, cfg = loaded
+    params = loaded[0]
+    cfg = cfg or loaded[1]
     pass_, _, lane_step = programs(cfg)
     seq2 = np.roll(tokens, 7)
     prompts = (33, 20, 11)
@@ -316,9 +457,13 @@ def lanes_run(loaded, tokens):
             for lane, rows in got.items()}, seqs, stats
 
 
-def test_three_lanes_one_dead_then_taken(ref, model, loaded, tokens):
-    got, seqs, stats = lanes_run(loaded, tokens)
+@pytest.mark.parametrize("read", ["loop", "kernel"])
+def test_three_lanes_one_dead_then_taken(ref, model, loaded, tokens, read,
+                                         monkeypatch):
     params, cfg = loaded
+    if read == "kernel":
+        cfg = with_kernel(cfg, monkeypatch)
+    got, seqs, stats = lanes_run(loaded, tokens, cfg)
     for lane, (first, logits, picks) in got.items():
         n = first + len(logits)
         use = np.concatenate(
@@ -335,7 +480,15 @@ def test_three_lanes_one_dead_then_taken(ref, model, loaded, tokens):
         assert total == held == n_live * 2 * cfg.n_experts_used
 
 
-def test_a_lanes_logits_do_not_depend_on_the_other_lanes(loaded, tokens):
+@pytest.mark.parametrize("read", ["loop", "kernel"])
+def test_a_lanes_logits_do_not_depend_on_the_other_lanes(loaded, tokens, read,
+                                                         monkeypatch):
+    params, cfg = loaded
+    lane_alone((params, with_kernel(cfg, monkeypatch)
+                if read == "kernel" else cfg), tokens)
+
+
+def lane_alone(loaded, tokens):
     import jax
     import jax.numpy as jnp
 
@@ -699,6 +852,70 @@ def test_callers_that_arrive_together_ride_the_first_ones_prompt(gguf_path):
             == alone[1]["choices"][0]["message"]
         assert outs[2]["choices"][0]["message"] \
             == alone[0]["choices"][0]["message"]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kernel,read,rows,who", [
+    # the kernel: each wanted lane its OWN blocks of 1024 (100..103 one,
+    # 1500..1503 two), the unwanted lane at 3000 nothing; a row stored a
+    # lane dispatched live, step and layer
+    (True, 4 * 1024 + 4 * 2048, 3 * 4 * 3, "kernel"),
+    # the loop: every wanted lane up to the largest lane's bound, in blocks
+    # of 512 (3000..3003: six of them); XLA writes the rows
+    (False, 2 * 4 * 3072, 0, "xla"),
+])
+def test_the_read_counters_follow_who_reads(kernel, read, rows, who):
+    """``Engine._note_cache_read`` on a ``latent-ring`` configuration, one
+    chunk of 4 steps: lanes at 100 and 1500 wanted, a third at 3000
+    dispatched live but finished.  ``/health`` ``engine.ring_write`` names
+    who reads and stores a step's row; ``engine.cache`` and ``attn_impl``
+    say what they said (the benchmark's ``expect_health`` holds both letter
+    for letter: they describe a prefill slice's read, the loop's)."""
+    import types
+
+    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.server.app import _ring_write
+    from llama_fastapi_k8s_gpu_tpu.testing import TINY_MLA_CFG
+
+    cfg = dataclasses.replace(TINY_MLA_CFG, n_ctx=4096, latent_kernel=kernel)
+    eng = types.SimpleNamespace(cfg=cfg, ring_slots={"read": 0, "live": 0},
+                                ring_rows_written=0, _prefix_cache=None)
+    Engine._note_cache_read(eng, [100, 1500], 4, live=[100, 1500, 3000])
+    assert eng.ring_slots == {
+        "read": read,
+        "live": sum(range(101, 105)) + sum(range(1501, 1505))}
+    assert eng.ring_rows_written == rows
+    assert _ring_write(cfg) == who and cfg.attn_impl == "xla"
+    assert Engine.cache_kind.fget(eng)["read"] == "absorbed, blocks of 512"
+
+
+def test_the_lane_engine_serves_through_the_kernel(gguf_path):
+    """``attn_impl="pallas"`` through the engine itself (what ``auto``
+    asks for on a TPU; interpret mode here): the probe passes, the
+    steps' rows are the kernel's, the counters count each lane's own
+    blocks, and a greedy request gives the same text twice (the second
+    through a lane claim on rows the kernel stored)."""
+    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
+
+    eng = ContinuousEngine(gguf_path, n_ctx=N_CTX * 2, prefill_chunk=SLICE,
+                           decode_chunk=4, batch_size=3, dp=1,  # no mesh
+                           attn_impl="pallas")
+    try:
+        assert eng.cfg.latent_kernel and eng.cfg.attn_impl == "xla"
+        first = eng.submit(MSGS, max_tokens=10, temperature=0.0).result(
+            timeout=300)
+        assert first["usage"]["completion_tokens"] >= 1
+        outs = [f.result(timeout=300) for f in [
+            eng.submit(m, max_tokens=10, temperature=0.0)
+            for m in (MSGS, MSGS2, MSGS)]]
+        for o in (outs[0], outs[2]):
+            assert o["choices"][0]["message"] == first["choices"][0]["message"]
+        assert eng.scheduler_stats()["lane_prefix_hits"] >= 1
+        gauges = eng.cache_read_gauges()
+        assert gauges["ring_rows_written_total"] > 0
+        assert 0 < gauges["latent_positions_live_total"] \
+            <= gauges["latent_positions_read_total"]
     finally:
         eng.shutdown()
 
