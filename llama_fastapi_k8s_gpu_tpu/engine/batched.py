@@ -113,11 +113,9 @@ class MeshEngine(Engine):
     # (lfkt-lint LOCK001; docs/RUNBOOK.md "Lock discipline annotations")
     _GUARDED_BY = {"_bstate": "_lock"}
 
-    #: whether prompts enter in slices of ``prefill_chunk`` (the continuous
-    #: scheduler) and not as one vmapped pass over a whole bucket (the
-    #: cycle scheduler here), which a cache whose window restarts cannot
-    #: take
-    _SLICED_ADMISSION = False
+    #: asked of the cache kind (Engine._refuse_unsupported) beside a mesh axis:
+    #: prompts enter as one vmapped pass over a whole bucket, not in slices
+    _asks = {"cycle": True}
 
     def __init__(self, model_path: str | None, *, dp: int | None = None,
                  tp: int = 1, batch_size: int | None = None, **kw):
@@ -140,7 +138,8 @@ class MeshEngine(Engine):
                     "mesh: the flash kernel has no partitioning rule; use "
                     "attn_impl='auto' (resolves to 'xla' on a mesh) or 'xla'")
             kw["attn_impl"] = "xla"
-        self._mesh_shape = (dp, tp)    # read by _refuse_for_window_cache
+        if tp > 1:
+            self._asks = {**self._asks, "tp": tp}
         super().__init__(model_path, **kw)
         with self.startup.phase("lanes_alloc"):
             self.mesh = make_mesh(dp=dp, tp=tp)
@@ -158,70 +157,6 @@ class MeshEngine(Engine):
         # serving allocation — attribute it (provider reads the live
         # reference, so watchdog re-inits stay correct automatically)
         register_component("kv_lanes", self, _ledger_lane_bytes)
-
-    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
-        super()._refuse_for_window_cache(kv_paged)
-        dp, tp = self._mesh_shape
-        if tp > 1:
-            raise ValueError(
-                f"LFKT_MESH_TP={tp} cannot serve architecture 'evabyte': "
-                "parallel/mesh.py shards a ring's KV heads, and has no "
-                "layout for its window + summary cache")
-        if not self._SLICED_ADMISSION:
-            raise ValueError(
-                "LFKT_SCHEDULER=cycle cannot serve architecture 'evabyte': "
-                "it prefills a whole prompt in one vmapped pass, and a "
-                "pass must lie inside one attention window; use the "
-                "continuous scheduler")
-
-    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
-        super()._refuse_for_state_cache(kv_paged)
-        dp, tp = self._mesh_shape
-        if tp > 1:
-            raise ValueError(
-                f"LFKT_MESH_TP={tp} cannot serve architecture "
-                "'minicpm-sala': parallel/mesh.py shards a ring's KV heads "
-                "and one stack of layers, and has no layout for two kinds "
-                "of layer or a state leaf")
-        if not self._SLICED_ADMISSION:
-            raise ValueError(
-                "LFKT_SCHEDULER=cycle cannot serve architecture "
-                "'minicpm-sala': it prefills a whole prompt in one vmapped "
-                "pass, and its sparse layers select per query in slices; "
-                "use the continuous scheduler")
-
-    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
-        super()._refuse_for_hybrid_cache(kv_paged)
-        dp, tp = self._mesh_shape
-        if tp > 1:
-            raise ValueError(
-                f"LFKT_MESH_TP={tp} cannot serve architecture 'exaone-moe': "
-                "parallel/mesh.py shards one stack of layers and one ring, "
-                "and has no layout for two feed-forward kinds or a leaf "
-                "pair per attention kind; experts over a mesh are ROADMAP "
-                "B-I 5")
-        if not self._SLICED_ADMISSION:
-            raise ValueError(
-                "LFKT_SCHEDULER=cycle cannot serve architecture "
-                "'exaone-moe': it prefills a whole prompt in one vmapped "
-                "pass, and a window layer takes a prompt slice by slice "
-                "against its window slots; use the continuous scheduler")
-
-    def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
-        super()._refuse_for_latent_cache(kv_paged)
-        dp, tp = self._mesh_shape
-        if tp > 1:
-            raise ValueError(
-                f"LFKT_MESH_TP={tp} cannot serve architecture 'deepseek2': "
-                "parallel/mesh.py shards a ring's KV heads, and its latent "
-                "ring has one row for all heads; experts over a mesh are "
-                "ROADMAP B-I 5")
-        if not self._SLICED_ADMISSION:
-            raise ValueError(
-                "LFKT_SCHEDULER=cycle cannot serve architecture "
-                "'deepseek2': it prefills a whole prompt in one vmapped "
-                "pass, and latent attention scores a pass against blocks "
-                "of latents slice by slice; use the continuous scheduler")
 
     def _recover_locked(self) -> None:  # lfkt: holds[_lock]
         """Watchdog recovery: a crash mid-cycle may have poisoned the donated

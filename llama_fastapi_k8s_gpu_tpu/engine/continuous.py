@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import LATENT_RING, RING
 from ..models.generate import prefill_chunk_jit, sample_jit
 from ..models.llama import init_cache
 from ..obs import memledger as _memledger
@@ -281,7 +280,7 @@ class ContinuousEngine(MeshEngine):
         "_req_counter": "_id_lock",
     }
     _THREAD_ENTRIES = ("_loop",)
-    _SLICED_ADMISSION = True
+    _asks = {}   # prompts enter in slices: every cache kind takes them
     _THREAD_CONFINED = (
         "_bstate", "_lane_st", "_lane_left", "_scratch_cache", "_adm",
         "_lane_claims",
@@ -291,11 +290,9 @@ class ContinuousEngine(MeshEngine):
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
-    # (ring_slots, ring_rows_written, eva_counts: the scheduler thread
-    # alone adds, /metrics reads an int)
+    # (cache_counts: the scheduler thread alone adds, /metrics reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread", "ring_slots", "ring_rows_written",
-                      "eva_counts", "sala_counts", "hybrid_counts")
+                      "_thread", "cache_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
@@ -345,13 +342,12 @@ class ContinuousEngine(MeshEngine):
         # n_ctx-1: a freed lane keeps garbage-decoding in the shared
         # batched program, but those writes land at positions past the
         # claim (clamping to slot n_ctx-1 once pos overruns).
-        # (Off for the window + summary cache, as the serial engine's: a
-        # claim is by token position and a window restarts; such a lane's
-        # walking position stays inside both stores by itself, slot
-        # ``pos mod W``, and closes no window past the last closable one.)
-        # (ON for the latent ring, which is positional as the ring is.)
+        # (Off for a cache that cannot be rolled back to a prefix, as the
+        # serial engine's: a claim is by token position and a window restarts;
+        # such a lane's walking position stays inside both stores by itself,
+        # slot ``pos mod W``, and closes no window past the last closable one.)
         self._lane_prefix = bool(lane_prefix_cache) \
-            and self.cfg.cache_kind in (RING, LATENT_RING)
+            and self.cache.rolls_back
         # paged mode (LFKT_KV_PAGED) folds the lane claims behind the
         # shared radix tree: one prefix-reuse implementation per mode (the
         # per-lane claim path remains the dense-ring default).  An
@@ -857,7 +853,7 @@ class ContinuousEngine(MeshEngine):
                 # mid-prefill (or failing later) must not inflate /metrics
             if pspan is not None:
                 pspan.set(n_prompt=len(ids), bucket=bucket, reused=reuse)
-            self._note_prefill_windows(len(ids), pspan, reuse, alone)
+            self._note_prefill(len(ids), pspan, reuse, alone)
             # host-side slice prep happens ONCE, here, while lanes decode:
             # one int32 array for the padded prompt; every slice dispatch
             # then takes a zero-copy view instead of re-converting a list
@@ -1466,12 +1462,11 @@ class ContinuousEngine(MeshEngine):
                 cspan = slot.dspan.child(
                     "decode_chunk", t0=slot.t_chunk).set(
                     tokens=len(slot.gens), wave=wave,
-                    admit_slices=admit_slices, kind="chunk")
-                if self.cfg.cache_kind == LATENT_RING:
+                    admit_slices=admit_slices, kind="chunk",
                     # the lane's own live rows at the chunk's end: what
                     # its attention needed of the read
-                    cspan.set(cache=LATENT_RING,
-                              latent_positions=slot.n_prompt + len(slot.gens))
+                    **self.cache.decode_span_attrs(
+                        slot.n_prompt + len(slot.gens)))
                 cspan.end(now)
                 slot.t_chunk = now
                 slot.trace.note(tokens=len(slot.gens))
@@ -1486,22 +1481,17 @@ class ContinuousEngine(MeshEngine):
 
     def _note_ring_read(self, pre: list, n_steps: int) -> None:
         """Count one decode chunk's attention read against what it needed
-        (models/llama.py ``decode_attention``): per step, summed over the
-        lanes whose rows are still wanted, the ring slots the read covered
-        (under the decode kernel the lane's own blocks; under the XLA loop
-        every lane reads up to the largest position among the lanes the
-        chunk was dispatched as live, ``pre``) and the slots at or below
-        the lane's own position (on a cache that is no ring its own two
-        stores: ``Engine._note_cache_read`` counts either kind).  From the
-        positions the host holds before the chunk's tokens are folded in
-        (prompt + generated so far: the slot of the chunk's first step);
-        nothing is fetched."""
+        (``CacheKind.note_decode``): summed over the lanes whose rows are
+        still wanted, under the bound of the lanes the chunk was dispatched
+        as live (``pre``).  From the positions the host holds before the
+        chunk's tokens are folded in (prompt + generated so far: the slot
+        of the chunk's first step); nothing is fetched."""
         at = [None if s is None else s.n_prompt + max(len(s.gens) - 1, 0)
               for s in pre]
         wanted = [p for slot, p in zip(pre, at)
                   if slot is not None and not slot.finished]
-        self._note_cache_read(wanted, n_steps,
-                              [p for p in at if p is not None])
+        self.cache.note_decode(self.cache_counts, self.cfg, wanted, n_steps,
+                               [p for p in at if p is not None])
 
     def _loop(self):
         B = self.batch_size

@@ -31,9 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gguf import GGUFFile
-from ..models.config import (
-    GLOBAL, LATENT_RING, RING, STATE_RING, WINDOW, WINDOW_GLOBAL_RING,
-    ModelConfig)
+from ..models.cache import FEATURES, cache_of
+from ..models.config import ModelConfig
 from ..models.generate import (
     generate_chunk_jit,
     init_state,
@@ -42,9 +41,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models import eva, hybrid, mla, sala
-from ..models.llama import (
-    decode_chunk_slots, decode_kernel_block, init_cache, ring_write_impl)
+from ..models.llama import init_cache
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
@@ -225,33 +222,6 @@ class Engine:
             raise ValueError(f"kv_dtype must be bf16|int8, got {kv_dtype!r}")
         self._lock = threading.Lock()
         self._expert_counters: ExpertCounters | None = None
-        # decode attention's read against what it needed (models/llama.py
-        # decode_attention): ring slots covered / at or below the position,
-        # summed over this engine's decode steps (a lane engine: and over
-        # its live lanes, ContinuousEngine._note_ring_read); /metrics
-        # ring_slots_*_total
-        self.ring_slots = {"read": 0, "live": 0}
-        # K rows (and as many V rows) the decode kernel stored in the ring:
-        # lanes dispatched live x steps x layers (models/llama.py
-        # ring_write_impl; 0 where XLA writes); /metrics
-        # ring_rows_written_total
-        self.ring_rows_written = 0
-        # the same for the window + summary cache (models/eva.py
-        # chunk_counts; ring_slots stays 0 there): /metrics eva_*_total
-        self.eva_counts = {"lane_steps": 0, "window_read": 0,
-                           "window_live": 0, "summaries_read": 0,
-                           "summaries_live": 0, "windows_closed": 0}
-        # and for the state + ring cache (models/sala.py chunk_counts /
-        # prefill_counts; ring_slots counts its ring layers' dense reads):
-        # /metrics lin_state_* / sparse_*_total
-        self.sala_counts = {"state_updates": 0, "queries_dense": 0,
-                            "queries_sparse": 0, "blocks_read": 0,
-                            "blocks_visible": 0, "kc_written": 0}
-        # and for the window + global cache (models/hybrid.py chunk_counts;
-        # ring_slots is their sum over kinds, in layer-slots): /metrics
-        # {window,global}_slots_*_total
-        self.hybrid_counts = {"window_read": 0, "window_live": 0,
-                              "global_read": 0, "global_live": 0}
         # prompt tokens prefilled (padding included), by the width of the
         # program that took them: ``wide`` is more than the narrow width
         # (engine/slices.py); /metrics prefill_slice_tokens_total{width=}
@@ -349,23 +319,18 @@ class Engine:
             )
         if kv_dtype is not None and kv_dtype != self.cfg.kv_dtype:
             self.cfg = dataclasses.replace(self.cfg, kv_dtype=kv_dtype)
-        if self.cfg.eva_window:
-            self._refuse_for_window_cache(bool(kv_paged))
-            attn_impl = "xla"   # its attention is models/eva.py's own
-        if self.cfg.cache_kind == STATE_RING:
-            self._refuse_for_state_cache(bool(kv_paged))
-        latent_kernel = False
-        if self.cfg.cache_kind == LATENT_RING:
-            self._refuse_for_latent_cache(bool(kv_paged))
-            # a decode step's read of the latent leaf is the decode kernel
-            # where the chip compiles it (``auto``: a TPU; probed below);
-            # ``attn_impl`` itself stays xla: a prefill slice's attention is
-            # models/mla.py's own loop, and the flash kernel serves nothing
-            latent_kernel = attn_impl == "pallas" or (
-                attn_impl == "auto" and jax.default_backend() == "tpu")
-            attn_impl = "xla"
-        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
-            self._refuse_for_hybrid_cache(bool(kv_paged))
+        #: the cache kind's object (models/cache.py), resolved ONCE: every
+        #: question about the kind is asked of it, none tests its name
+        self.cache = cache_of(self.cfg)
+        #: what the kind counts of its decode steps' and prefills' reads
+        #: (host arithmetic from tracked positions; a lane engine adds at
+        #: each chunk's harvest): /metrics via :meth:`cache_read_gauges`
+        self.cache_counts = self.cache.new_counts()
+        self._refuse_unsupported({
+            **self._asks, "slice": self._prefill_chunk,
+            "int8": self.cfg.kv_dtype == "int8", "paged": bool(kv_paged)})
+        asked_attn = attn_impl
+        attn_impl = self.cache.attn_impl(self.cfg, attn_impl)
         # the compile probes of the attention side: one phase of the
         # timeline when any of them ran
         probing = Phase("attn_probes", meter="cache", kernels=[])
@@ -382,19 +347,6 @@ class Engine:
                 force_xla_quant(True)
                 logger.error("pallas kv-quantize kernel failed its compile "
                              "probe; cache writes quantize via XLA: %s", err)
-        if latent_kernel:
-            # (ops/pallas/probe.py) a Mosaic failure degrades the step to
-            # the XLA loop, and says so
-            from ..ops.pallas.probe import probe_latent_decode
-
-            probing.attrs["kernels"].append("latent_decode")
-            err = probe_latent_decode()
-            if err is None:
-                self.cfg = dataclasses.replace(self.cfg, latent_kernel=True)
-            else:
-                logger.error("pallas latent decode kernel failed its compile "
-                             "probe; decode steps read the latent ring "
-                             "through the XLA loop: %s", err)
         if attn_impl == "auto":
             # the flash kernel wants lane-aligned heads; anything else (tiny
             # test models, CPU runs) stays on the XLA score-matrix path
@@ -420,17 +372,8 @@ class Engine:
                 logger.error("pallas flash attention failed its compile "
                              "probe; serving with attn_impl=xla: %s", err)
                 attn_impl = "xla"
-        if attn_impl == "pallas" and self.cfg.cache_kind == STATE_RING:
-            # the state's decode step is a kernel too (ops/pallas/
-            # linstate.py); it and the ring's kernels degrade together
-            from ..ops.pallas.probe import probe_lin_state
-
-            probing.attrs["kernels"].append("lin_state")
-            err = probe_lin_state()
-            if err is not None:
-                logger.error("pallas linear-state step failed its compile "
-                             "probe; serving with attn_impl=xla: %s", err)
-                attn_impl = "xla"
+        self.cfg, attn_impl = self.cache.probe_kernels(   # the kind's own
+            self.cfg, asked_attn, attn_impl, probing.attrs["kernels"])
         if probing.attrs["kernels"]:
             probing.close()
             tl.add(probing)
@@ -443,7 +386,7 @@ class Engine:
         # slices.py): the wide width, or the narrow one where the block
         # takes no wider slice
         self._wide_slice = wide_width(self._prefill_chunk,
-                                      self.cfg.widest_slice)
+                                      self.cache.widest_slice(self.cfg))
         # the serial ring here and the paged pool below; a subclass's lanes
         # are a phase of their own (``lanes_alloc``, ``scheduler_start``)
         alloc = tl.phase("cache_alloc")
@@ -458,11 +401,10 @@ class Engine:
         # suffix via prefill_chunk_jit — multi-turn TTFT then scales with
         # the NEW turn's length, not the whole history.  The mesh/SP/lane
         # engines manage caches differently and keep full prefill.
-        # (off for the window + summary cache: reuse is by token position,
-        # and a window restarts: a property of the cache, /health says so)
-        # (ON for the latent ring, which is positional as the ring is)
+        # (off for a cache that cannot be rolled back to a prefix: a
+        # property of the kind, its /health says so)
         self._prefix_cache = bool(prefix_cache) and type(self) is Engine \
-            and self.cfg.cache_kind in (RING, LATENT_RING)
+            and self.cache.rolls_back
         self._prefix_min = max(1, int(prefix_min))
         #: token ids whose KV occupy ring slots [0, len) — only ever read
         #: and written under self._lock (the single-generator invariant)
@@ -548,146 +490,30 @@ class Engine:
         one source.  An in-memory engine has ``warmup_s`` alone."""
         return legacy_load_phases(self.startup)
 
-    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
-        """What cannot serve the window + summary cache of ``evabyte``
-        (models/eva.py), refused at start by name, never degraded to:
-        an int8 cache, the paged pool (pages are runs of ring slots), and
-        a prefill slice that could lie astride a window or split a chunk.
-        Subclasses add the meshes that shard what this cache lacks."""
-        W, C = self.cfg.eva_window, self.cfg.eva_chunk
-        if self.cfg.kv_dtype == "int8":
-            raise ValueError(
-                "LFKT_KV_DTYPE=int8 cannot serve architecture 'evabyte': "
-                "its window + summary cache is bf16 only")
-        if kv_paged:
-            raise ValueError(
-                "LFKT_KV_PAGED=1 cannot serve architecture 'evabyte': the "
-                "pool pages runs of ring slots by token position, and its "
-                "cache is a window that restarts plus chunk summaries")
-        if W % self._prefill_chunk or self._prefill_chunk % C:
-            raise ValueError(
-                f"LFKT_PREFILL_CHUNK={self._prefill_chunk} cannot serve "
-                f"architecture 'evabyte': a prefill slice must divide its "
-                f"attention window ({W}) and be a multiple of its chunk "
-                f"({C}), so that no slice lies astride a window")
+    #: what a subclass asks of the cache kind beside int8, paging and its
+    #: slice ({feature of models/cache.py ``FEATURES``: the value asked for})
+    _asks: dict = {}
 
-    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
-        """The same for the state + ring cache of ``minicpm-sala``
-        (models/sala.py): an int8 cache, the paged pool (a state has no
-        pages and cannot be rolled back to one), a prefill slice that
-        could split a block of the sparse layers.  Subclasses add the
-        meshes."""
-        if self.cfg.kv_dtype == "int8":
-            raise ValueError(
-                "LFKT_KV_DTYPE=int8 cannot serve architecture "
-                "'minicpm-sala': its state + ring cache is float32 + bf16 "
-                "only")
-        if kv_paged:
-            raise ValueError(
-                "LFKT_KV_PAGED=1 cannot serve architecture 'minicpm-sala': "
-                "the pool pages runs of ring slots by token position, and "
-                "its linear layers keep a state that cannot be rolled back "
-                "to a shared prefix")
-        if self._prefill_chunk % self.cfg.sp_block:
-            raise ValueError(
-                f"LFKT_PREFILL_CHUNK={self._prefill_chunk} cannot serve "
-                f"architecture 'minicpm-sala': a prefill slice must be a "
-                f"multiple of its sparse layers' block "
-                f"({self.cfg.sp_block})")
-
-    def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
-        """The same for the latent ring of ``deepseek2`` (models/mla.py):
-        an int8 cache (the latent is read as it was normed) and the paged
-        pool (its page geometry is K and V heads: ROADMAP B-I 2).
-        Subclasses add the meshes."""
-        if self.cfg.kv_dtype == "int8":
-            raise ValueError(
-                "LFKT_KV_DTYPE=int8 cannot serve architecture 'deepseek2': "
-                "its latent ring is bf16 only")
-        if kv_paged:
-            raise ValueError(
-                "LFKT_KV_PAGED=1 cannot serve architecture 'deepseek2': a "
-                "pool page is a run of K and V slots per KV head, and its "
-                "cache is one latent row a position for all heads")
-
-    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
-        """The same for the window + global cache of ``exaone-moe``
-        (models/hybrid.py): an int8 cache (the decode kernel that serves
-        both leaf kinds reads bf16) and the paged pool (one page geometry,
-        runs of ``n_ctx`` slots by position: a window leaf wraps; a page
-        geometry per kind is ROADMAP B-I 2).  Subclasses add the meshes."""
-        if self.cfg.kv_dtype == "int8":
-            raise ValueError(
-                "LFKT_KV_DTYPE=int8 cannot serve architecture 'exaone-moe': "
-                "its window + global cache is bf16 only")
-        if kv_paged:
-            raise ValueError(
-                "LFKT_KV_PAGED=1 cannot serve architecture 'exaone-moe': a "
-                "pool page is a run of ring slots by token position, and "
-                "its window layers keep window slots that wrap")
+    def _refuse_unsupported(self, asks: dict) -> None:
+        """What was asked for that the cache kind cannot serve
+        (``CacheKind.supports``, ``slice_rule``) is refused at start by
+        name, never degraded to.  ``asks``: {feature: the value asked for,
+        or False}; checked in the order of ``FEATURES``."""
+        for feature, setting in FEATURES.items():
+            if not asks.get(feature):
+                continue
+            reason = self.cache.slice_rule(self.cfg, asks[feature]) \
+                if feature == "slice" else self.cache.supports[feature]
+            if reason not in (None, True):
+                raise ValueError(
+                    f"{setting.format(asks[feature])} cannot serve "
+                    f"architecture {self.cache.arch!r}: {reason}")
 
     @property
     def cache_kind(self) -> dict | None:
         """The /health ``engine.cache`` block of a cache that is no ring
-        (None for the ring: its /health is what it was): the kind's sizes,
-        and the reuse it does without as a property, not a degrade."""
-        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
-            cfg = self.cfg
-            return {
-                "kind": WINDOW_GLOBAL_RING,
-                "window": cfg.sliding_window,
-                "window_slots": cfg.window_slots,
-                "window_layers": cfg.n_attn_layers(WINDOW),
-                "global_layers": cfg.n_attn_layers(GLOBAL),
-                "rotated": list(cfg.rope_kinds),
-                "bytes_per_lane": hybrid.cache_nbytes(cfg),
-                "dense_layers": cfg.n_dense_layers,
-                "routed_layers": hybrid.n_moe_layers(cfg),
-                "experts_held": [cfg.experts_first, cfg.n_held],
-                "experts_routed": cfg.n_experts,
-                "prefix_reuse": "off: a wrapped window cannot be rolled "
-                                "back to a prefix's end",
-                "kv_paged": "refused at start"}
-        if self.cfg.cache_kind == LATENT_RING:
-            cfg = self.cfg
-            reuse = getattr(self, "_lane_prefix", self._prefix_cache)
-            return {
-                "kind": LATENT_RING,
-                "latent": cfg.kv_lora_rank, "rotated_key": cfg.qk_rope_dim,
-                "bytes_per_position": 2 * cfg.n_layers * mla.lat_width(cfg),
-                "bytes_per_position_laid_out":
-                    2 * cfg.n_layers * mla.leaf_width(cfg),
-                "read": "absorbed, blocks of %d" % mla.LATENT_BLOCK,
-                "dense_layers": cfg.n_dense_layers,
-                "routed_layers": mla.n_moe_layers(cfg),
-                "experts_held": [cfg.experts_first, cfg.n_held],
-                "experts_routed": cfg.n_experts,
-                "prefix_reuse": "on" if reuse else "off",
-                "kv_paged": "refused at start"}
-        if self.cfg.cache_kind == STATE_RING:
-            cfg = self.cfg
-            return {
-                "kind": STATE_RING,
-                "linear_layers": cfg.n_layers_of(sala.LIN),
-                "sparse_layers": cfg.n_layers_of(sala.SP),
-                "state_bytes": sala.state_nbytes(cfg),
-                "compressed_keys": sala.n_kc(cfg),
-                "blocks_read_at_most": sala.n_select(cfg),
-                "dense_len": cfg.sp_dense_len,
-                "prefix_reuse": "off: a state cannot be rolled back to a "
-                                "shared prefix",
-                "kv_paged": "refused at start",
-                "chat_template": self.template_kind + (
-                    "" if self._template_named else
-                    " (fallback: the file names no template known here)")}
-        if not self.cfg.eva_window:
-            return None
-        return {"kind": "window+summaries", "window": self.cfg.eva_window,
-                "chunk": self.cfg.eva_chunk,
-                "summaries": eva.n_summaries(self.cfg),
-                "prefix_reuse": "off: reuse is by token position and a "
-                                "window restarts",
-                "kv_paged": "refused at start"}
+        (``CacheKind.health``; None for the ring, whose /health is as it was)."""
+        return self.cache.health(self.cfg, self)
 
     @property
     def tokenizer_fallback(self) -> str | None:
@@ -893,12 +719,9 @@ class Engine:
         """Whether a ``bucket``-sized prompt prefills as overlapped slices
         (vs one monolithic program).  Buckets at or under the slice size
         gain nothing from slicing and keep the single-program path."""
-        if self.cfg.cache_kind != RING:
-            # a pass lies inside one window, or is small enough for the
-            # sparse layers' per-query masks: always
-            return bucket > self._prefill_chunk
-        return (self._SLICE_PREFILL and self._prefill_overlap > 0
-                and bucket > self._prefill_chunk)
+        return bucket > self._prefill_chunk and (
+            self.cache.always_slices
+            or (self._SLICE_PREFILL and self._prefill_overlap > 0))
 
     def _observe_slice(self, dt: float) -> None:
         """Feed one prefill-slice host wall time into the server's metrics
@@ -988,117 +811,25 @@ class Engine:
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
                            pos: int):
         """Dispatch one decode chunk; ``pos``: the host-tracked position of
-        its first step (what the ``ring_slots`` counters are computed
-        from: nothing is fetched)."""
+        its first step (what :attr:`cache_counts` are computed from:
+        nothing is fetched)."""
         state, out = generate_chunk_jit(self.params, self.cfg, state, st,
                                         n_steps=n_steps, top_k=top_k)
-        self._note_cache_read([pos], n_steps)
+        self.cache.note_decode(self.cache_counts, self.cfg, [pos], n_steps)
         return state, self._take_expert_stats(out)
 
-    def _note_cache_read(self, wanted: list, n_steps: int,
-                         live: list | None = None) -> None:
-        """Count one decode chunk's attention read against what it needed,
-        per step and summed over the sequences at positions ``wanted``
-        (their first step's).  Under the XLA loop every one reads up to the
-        bound of the positions ``live`` (the lanes a chunk was dispatched
-        as live; default ``wanted``); under the decode kernel
-        (``decode_kernel_block``) each reads its OWN blocks, and a lane
-        that is not wanted reads nothing, so the sum is all the chunk
-        read.  A ring counts its slots (``ring_slots``:
-        models/llama.py ``decode_chunk_slots``), a window + summary cache
-        its two stores and the windows closed (``eva_counts``: models/eva.py
-        ``chunk_counts``).  Host arithmetic, nothing fetched: the one
-        place the engines tell the cache kinds apart when they count."""
-        if self.cfg.eva_window:
-            for k, v in eva.chunk_counts(wanted, n_steps, self.cfg,
-                                         live).items():
-                self.eva_counts[k] += v
-            return
-        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
-            dispatched = wanted if live is None else live
-            if ring_write_impl(self.cfg) == "kernel":
-                self.ring_rows_written += \
-                    len(dispatched) * n_steps * self.cfg.n_layers
-            c = hybrid.chunk_counts(wanted, n_steps, self.cfg,
-                                    max(dispatched, default=0))
-            for k, v in c.items():
-                self.hybrid_counts[k] += v
-            # the ring totals keep their meaning: the sum over kinds
-            self.ring_slots["read"] += c["window_read"] + c["global_read"]
-            self.ring_slots["live"] += c["window_live"] + c["global_live"]
-            return
-        first_sparse = None
-        if self.cfg.cache_kind == STATE_RING:
-            for k, v in sala.chunk_counts(wanted, n_steps, self.cfg).items():
-                self.sala_counts[k] += v
-            # the ring counters speak for the ring layers' dense reads: the
-            # steps before dense_len
-            first_sparse = self.cfg.sp_dense_len - 1
-            live = [p for p in (wanted if live is None else live)
-                    if p < first_sparse]
-        block = decode_kernel_block(self.cfg)
-        dispatched = wanted if live is None else live
-        bound = None if block else max(dispatched, default=0)
-        if ring_write_impl(self.cfg) == "kernel":
-            # every lane the chunk was dispatched with as live stored its
-            # row in every layer of every step; the others stored nothing
-            self.ring_rows_written += \
-                len(dispatched) * n_steps * self.cfg.n_layers
-        for p in wanted:
-            steps = n_steps if first_sparse is None \
-                else min(n_steps, max(first_sparse - p, 0))
-            read, lv = decode_chunk_slots(p, steps, self.cfg.n_ctx, bound,
-                                          block)
-            self.ring_slots["read"] += read
-            self.ring_slots["live"] += lv
-
     def cache_read_gauges(self) -> dict:
-        """The counters of :meth:`_note_cache_read` under their /metrics
-        names: ``ring_slots_*`` for every engine (0 on a cache that is no
-        ring), ``eva_*`` for a window + summary cache alone; beside them
-        the prompt tokens prefilled by slice width (:attr:`slice_tokens`)
-        and the pieces a SentencePiece tokenizer encoded and remembered."""
-        out = {"ring_slots_read_total": self.ring_slots["read"],
-               "ring_slots_live_total": self.ring_slots["live"],
-               "ring_rows_written_total": self.ring_rows_written}
+        """:attr:`cache_counts` under their /metrics names (``ring_slots_*``
+        for every engine, 0 on a cache that is no ring, and the kind's own);
+        beside them the prompt tokens prefilled by slice width
+        (:attr:`slice_tokens`) and a SentencePiece tokenizer's pieces."""
+        out = self.cache.gauges(self.cache_counts)
         out.update({f'prefill_slice_tokens_total{{width="{w}"}}': n
                     for w, n in self.slice_tokens.items()})
         counts = getattr(self.tokenizer, "piece_counts", None)
         if counts is not None:
             (out["tokenizer_pieces_total"],
              out["tokenizer_memo_hits_total"]) = counts()
-        if self.cfg.eva_window:
-            c = self.eva_counts
-            out.update(
-                eva_lane_steps_total=c["lane_steps"],
-                eva_window_slots_read_total=c["window_read"],
-                eva_window_slots_live_total=c["window_live"],
-                eva_summaries_read_total=c["summaries_read"],
-                eva_summaries_live_total=c["summaries_live"],
-                eva_windows_closed_total=c["windows_closed"])
-        if self.cfg.cache_kind == WINDOW_GLOBAL_RING:
-            c = self.hybrid_counts
-            out.update(
-                window_slots_read_total=c["window_read"],
-                window_slots_live_total=c["window_live"],
-                global_slots_read_total=c["global_read"],
-                global_slots_live_total=c["global_live"])
-        if self.cfg.cache_kind == LATENT_RING:
-            # the ring's own arithmetic (blocks of models/mla.py
-            # LATENT_BLOCK up to the largest live lane's position), under
-            # the kind's names: a latent is read once for all heads
-            out.update(
-                latent_positions_read_total=self.ring_slots["read"],
-                latent_positions_live_total=self.ring_slots["live"])
-        if self.cfg.cache_kind == STATE_RING:
-            c = self.sala_counts
-            out.update({
-                "lin_state_updates_total": c["state_updates"],
-                'sparse_queries_total{branch="dense"}': c["queries_dense"],
-                'sparse_queries_total{branch="sparse"}': c["queries_sparse"],
-                "sparse_blocks_read_total": c["blocks_read"],
-                "sparse_blocks_visible_total": c["blocks_visible"],
-                "sparse_kc_written_total": c["kc_written"]})
         return out
 
     def _take_expert_stats(self, chunk_out):
@@ -1308,7 +1039,7 @@ class Engine:
             reuse = self._paged_reuse(ids, n_prompt, bucket, pspan)
         if pspan is not None:
             pspan.set(n_prompt=n_prompt, bucket=bucket, reused=reuse)
-        self._note_prefill_windows(n_prompt, pspan, reuse)
+        self._note_prefill(n_prompt, pspan, reuse)
         # claim nothing while this request is in flight: an exception past
         # this point must not leave a stale prefix claim over a cache whose
         # contents are indeterminate
@@ -1359,32 +1090,18 @@ class Engine:
             "bucket": bucket,
         }
 
-    def _note_prefill_windows(self, n_prompt: int, pspan=None,
-                              reused: int = 0, alone: bool = True) -> None:
-        """The windows a prompt's prefill closes (the window + summary
-        cache alone): counted, and on the traced ``prefill`` span; of a
-        latent ring the span names the kind and the cached rows its slices
-        read (``reused``: the prefix no slice computes; ``alone``: whether
-        nobody decodes behind them as the prompt is admitted, which is what
-        their widths are planned from)."""
-        if self.cfg.cache_kind == LATENT_RING and pspan is not None:
-            pspan.set(cache=LATENT_RING,
-                      latent_positions_read=mla.prefill_positions_read(
-                          plan_slices(reused, n_prompt, self.cfg.n_ctx,
-                                      self._prefill_chunk, self._wide_slice,
-                                      alone), self.cfg))
-        if self.cfg.eva_window:
-            n = eva.windows_closed_by_prefill(n_prompt, self.cfg)
-            self.eva_counts["windows_closed"] += n
-            if pspan is not None:
-                pspan.set(windows_closed=n)
-        if self.cfg.cache_kind == STATE_RING:
-            c = sala.prefill_counts(n_prompt, self.cfg)
-            for k in ("queries_dense", "queries_sparse", "kc_written"):
-                self.sala_counts[k] += c[k]
-            if pspan is not None:
-                pspan.set(kc_closed=c["kc_closed"],
-                          sparse_positions=c["sparse_positions"])
+    def _note_prefill(self, n_prompt: int, pspan=None, reused: int = 0,
+                      alone: bool = True) -> None:
+        """What a prompt's prefill does to the cache, by the kind: counted,
+        and on the traced ``prefill`` span.  ``reused``: the prefix no slice
+        computes; ``alone``: whether nobody decodes behind the slices."""
+        slices = None if pspan is None else plan_slices(
+            reused, n_prompt, self.cfg.n_ctx, self._prefill_chunk,
+            self._wide_slice, alone)
+        attrs = self.cache.note_prefill(self.cache_counts, self.cfg,
+                                        n_prompt, slices)
+        if pspan is not None:
+            pspan.set(**attrs)
 
     def _prefix_reuse_len(self, ids: list, n_prompt: int, bucket: int) -> int:
         """Longest usable common prefix of ``ids`` vs the KV resident in the
@@ -1763,9 +1480,8 @@ class Engine:
             if pending is None:
                 done = True
             if cspan is not None:
-                cspan.set(tokens=len(gen))
-                if self.cfg.cache_kind == LATENT_RING:
-                    cspan.set(cache=LATENT_RING, latent_positions=pos)
+                cspan.set(tokens=len(gen),
+                          **self.cache.decode_span_attrs(pos))
                 cspan.end()
                 ctx["trace"].note(tokens=len(gen))
 

@@ -48,29 +48,8 @@ class SPEngine(Engine):
     #: dense sharded ring; greedy output is identical either way).
     _KV_PAGED = False
 
-    def _refuse_for_window_cache(self, kv_paged: bool) -> None:
-        raise ValueError(
-            "LFKT_MESH_SP > 1 cannot serve architecture 'evabyte': the sp "
-            "ring shards the n_ctx slots of a KV ring, and its cache is a "
-            "window plus chunk summaries")
-
-    def _refuse_for_state_cache(self, kv_paged: bool) -> None:
-        raise ValueError(
-            "LFKT_MESH_SP > 1 cannot serve architecture 'minicpm-sala': the "
-            "sp ring shards the n_ctx slots of a KV ring, and its linear "
-            "layers keep a state per sequence, not slots")
-
-    def _refuse_for_latent_cache(self, kv_paged: bool) -> None:
-        raise ValueError(
-            "LFKT_MESH_SP > 1 cannot serve architecture 'deepseek2': the sp "
-            "ring passes K and V chunks per head between chips, and its "
-            "cache is one latent row a position for all heads")
-
-    def _refuse_for_hybrid_cache(self, kv_paged: bool) -> None:
-        raise ValueError(
-            "LFKT_MESH_SP > 1 cannot serve architecture 'exaone-moe': the sp "
-            "ring shards the n_ctx slots of a KV ring, and its window "
-            "layers keep window slots that wrap")
+    #: asked of the cache kind (Engine._refuse_unsupported): slots to shard
+    _asks = {"sp": True}
 
     def __init__(self, model_path: str | None, *, sp: int = 2, tp: int = 1,
                  n_ctx: int = 4096, **kw):
@@ -124,7 +103,7 @@ class SPEngine(Engine):
 
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
                            pos: int):
-        # ring attention reads its whole shard: no ring_slots counters
+        # ring attention reads its whole shard: nothing for cache_counts
         state, out = sp_generate_chunk(self.params, self.cfg, state, st,
                                        self.mesh, n_steps, top_k)
         return state, self._take_expert_stats(out)

@@ -195,10 +195,11 @@ class ModelConfig:
     @property
     def cache_kind(self) -> str:
         """The NAME of the cache kind a sequence of this file holds
-        (docs/KV_CACHE.md "Cache kinds"), read here and nowhere else:
+        (docs/KV_CACHE.md "Cache kinds"), decided here and nowhere else:
         ``ring``, ``window+summaries`` (models/eva.py), ``state+ring``
         (models/sala.py), ``latent-ring`` (models/mla.py) or
-        ``window+global-ring`` (models/hybrid.py)."""
+        ``window+global-ring`` (models/hybrid.py); models/cache.py
+        ``cache_of`` maps it to the kind's object, nothing else tests it."""
         if self.mixers:
             return STATE_RING
         if self.attn_kinds:
@@ -206,17 +207,6 @@ class ModelConfig:
         if self.kv_lora_rank:
             return LATENT_RING
         return WINDOW_SUMMARIES if self.eva_window else RING
-
-    @property
-    def widest_slice(self) -> int:
-        """The widest prefill slice this block takes where the plan is free
-        to cut wide (engine/slices.py); 0: any.  A block says so where a
-        slice's cost grows faster than its rows or its temporaries do not
-        fit: the window + summary cache's slice attention holds every
-        head's scores whole (``n_heads`` x S x (window + summaries)
-        float32: 386 MB at 1024 rows of the published widths, beside a
-        chip 83 % full), so it keeps the width it was sized at."""
-        return min(256, self.eva_window) if self.eva_window else 0
 
     def n_layers_of(self, kind: str) -> int:
         return sum(m == kind for m in self.mixers)

@@ -34,7 +34,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .config import ModelConfig
+from .cache import HEADS, CacheKind
+from .config import WINDOW_SUMMARIES, ModelConfig
 
 #: window slots a decode step reads at a time (``models/llama.py
 #: DECODE_KV_BLOCK``'s measurement: below 512 an iteration's fixed cost
@@ -324,6 +325,11 @@ def attend(q, k, v, cache: dict, i, positions, kv_bound, phi, mu,
     :func:`live_bounds` (None: this sequence's own).  Returns (ctx (S,
     n_heads * hd), cache)."""
     S, W = q.shape[0], cfg.eva_window
+    if S > W:
+        raise ValueError(
+            f"architecture 'evabyte': {S} positions in one pass, its window "
+            f"holds {W}: a prompt is prefilled in slices that lie inside "
+            "one window (LFKT_PREFILL_CHUNK)")
     first, last = positions[0], positions[S - 1]
     new = {}
     for name, x in (("k", k), ("v", v)):
@@ -344,3 +350,59 @@ def attend(q, k, v, cache: dict, i, positions, kv_bound, phi, mu,
     cache = close_window(cache, i, last // W, closing, any_closing, phi, mu,
                          cfg)
     return ctx, cache
+
+
+def _slice_rule(cfg: ModelConfig, chunk: int) -> str | None:
+    W, C = cfg.eva_window, cfg.eva_chunk
+    if W % chunk or chunk % C:
+        return (f"a prefill slice must divide its attention window ({W}) "
+                f"and be a multiple of its chunk ({C}), so that no slice "
+                "lies astride a window")
+
+
+def _health(cfg: ModelConfig, engine) -> dict:
+    return {"kind": WINDOW_SUMMARIES, "window": cfg.eva_window,
+            "chunk": cfg.eva_chunk, "summaries": n_summaries(cfg),
+            "prefix_reuse": "off: reuse is by token position and a "
+                            "window restarts",
+            "kv_paged": "refused at start"}
+
+
+def _note_prefill(counts, cfg: ModelConfig, n_prompt: int, slices) -> dict:
+    n = windows_closed_by_prefill(n_prompt, cfg)
+    counts["windows_closed"] += n
+    return {"windows_closed": n}
+
+
+CACHE = CacheKind(
+    name=WINDOW_SUMMARIES, arch="evabyte",
+    init=init_cache, nbytes=cache_nbytes,
+    step_bound=lambda cfg, pos, live: live_bounds(pos, live, cfg),
+    shardings=lambda cfg: dict.fromkeys(("k", "v", "sk", "sv"), HEADS),
+    supports={
+        "int8": "its window + summary cache is bf16 only",
+        "paged": "the pool pages runs of ring slots by token position, and "
+                 "its cache is a window that restarts plus chunk summaries",
+        "tp": "parallel/mesh.py shards a ring's KV heads, and has no layout "
+              "for its window + summary cache",
+        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
+              "cache is a window plus chunk summaries",
+        "cycle": "it prefills a whole prompt in one vmapped pass, and a "
+                 "pass must lie inside one attention window; use the "
+                 "continuous scheduler"},
+    slice_rule=_slice_rule,
+    # its attention is this file's own; no kernel, and no ring to write
+    attn_impl=lambda cfg, asked: "xla",
+    decode_kernel_block=lambda cfg: 0, kernel_writes=None,
+    # the slice attention holds every head's scores whole (386 MB at 1024
+    # rows of the published widths, beside a chip 83 % full)
+    widest_slice=lambda cfg: min(256, cfg.eva_window),
+    health=_health,
+    own_gauges={"eva_lane_steps_total": "lane_steps",
+                "eva_window_slots_read_total": "window_read",
+                "eva_window_slots_live_total": "window_live",
+                "eva_summaries_read_total": "summaries_read",
+                "eva_summaries_live_total": "summaries_live",
+                "eva_windows_closed_total": "windows_closed"},
+    note_decode=lambda counts, cfg, wanted, n_steps, live=None: counts.update(chunk_counts(wanted, n_steps, cfg, live)),
+    note_prefill=_note_prefill)

@@ -41,10 +41,11 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.linear import linear, linear_at
-from .config import GLOBAL, WINDOW, ModelConfig
+from .cache import HEADS, CacheKind
+from .config import GLOBAL, WINDOW, WINDOW_GLOBAL_RING, ModelConfig
 from .llama import (
-    _kernel_decode, _ring_attention, decode_kernel_block, decode_read_slots,
-    expert_stats_len, rms_norm, rope)
+    _kernel_decode, _ring_attention, decode_read_slots, expert_stats_len,
+    ring_kernel_block, ring_step_bound, rms_norm, rope)
 from .routed import (
     DENSE, MOE, check_stacks, expert_branch, moe_stats, n_moe_layers, swiglu)
 
@@ -57,9 +58,9 @@ def ring_view(cfg: ModelConfig) -> ModelConfig:
 
 def window_block(cfg: ModelConfig) -> int:
     """The decode kernel's block on a WINDOW leaf where the kernel serves
-    this file's decode steps (``decode_kernel_block``), else 0: the same
+    this file's decode steps (``ring_kernel_block``), else 0: the same
     rule over the leaf's slots."""
-    return decode_kernel_block(cfg) and decode_kernel_block(
+    return ring_kernel_block(cfg) and ring_kernel_block(
         dataclasses.replace(cfg, n_ctx=cfg.window_slots))
 
 
@@ -109,7 +110,7 @@ def chunk_counts(positions: list[int], n_steps: int, cfg: ModelConfig,
     lanes), a window layer its whole leaf against the window's live
     positions.  Host arithmetic for the engines' ``{window,global}_slots_*``
     counters."""
-    block = decode_kernel_block(cfg)
+    block = ring_kernel_block(cfg)
     n_w, n_g = cfg.n_attn_layers(WINDOW), cfg.n_attn_layers(GLOBAL)
     out = {"window_read": 0, "window_live": 0,
            "global_read": 0, "global_live": 0}
@@ -289,7 +290,7 @@ def _attention(h, layers, wi, ci, kind: str, cache, positions, pos_offset,
     # a global layer: models/llama.py's ring, on this kind's leaves
     gcfg = ring_view(cfg)
     ring = {"k": cache["k"], "v": cache["v"]}
-    if S == 1 and decode_kernel_block(gcfg):
+    if S == 1 and ring_kernel_block(gcfg):
         ctx, ring = _kernel_decode(q, ring, ci, pos_offset, live, gcfg,
                                    h.dtype, kh[:, 0], vh[:, 0])
     else:
@@ -370,3 +371,60 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
     return (head(h_last)[0], new_cache, *tail)
+
+
+def _health(cfg: ModelConfig, engine) -> dict:
+    return {
+        "kind": WINDOW_GLOBAL_RING,
+        "window": cfg.sliding_window,
+        "window_slots": cfg.window_slots,
+        "window_layers": cfg.n_attn_layers(WINDOW),
+        "global_layers": cfg.n_attn_layers(GLOBAL),
+        "rotated": list(cfg.rope_kinds),
+        "bytes_per_lane": cache_nbytes(cfg),
+        "dense_layers": cfg.n_dense_layers,
+        "routed_layers": n_moe_layers(cfg),
+        "experts_held": [cfg.experts_first, cfg.n_held],
+        "experts_routed": cfg.n_experts,
+        "prefix_reuse": "off: a wrapped window cannot be rolled "
+                        "back to a prefix's end",
+        "kv_paged": "refused at start"}
+
+
+def _note_decode(counts: dict, cfg: ModelConfig, wanted: list, n_steps: int,
+                 live: list | None = None) -> None:
+    dispatched = wanted if live is None else live
+    if ring_kernel_block(cfg):   # the kernels store the step's rows
+        counts["rows_written"] += len(dispatched) * n_steps * cfg.n_layers
+    c = chunk_counts(wanted, n_steps, cfg, max(dispatched, default=0))
+    counts.update(c)
+    # the ring totals keep their meaning: the sum over kinds
+    counts["read"] += c["window_read"] + c["global_read"]
+    counts["live"] += c["window_live"] + c["global_live"]
+
+
+CACHE = CacheKind(
+    name=WINDOW_GLOBAL_RING, arch="exaone-moe",
+    init=init_cache, nbytes=cache_nbytes, forward=forward,
+    # the global layers' XLA loop; the window layers read their whole leaf
+    step_bound=ring_step_bound,
+    shardings=lambda cfg: dict.fromkeys(("k", "v", "kw", "vw"), HEADS),
+    supports={
+        "int8": "its window + global cache is bf16 only",
+        "paged": "a pool page is a run of ring slots by token position, and "
+                 "its window layers keep window slots that wrap",
+        "tp": "parallel/mesh.py shards one stack of layers and one ring, "
+              "and has no layout for two feed-forward kinds or a leaf pair "
+              "per attention kind; experts over a mesh are ROADMAP B-I 5",
+        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
+              "window layers keep window slots that wrap",
+        "cycle": "it prefills a whole prompt in one vmapped pass, and a "
+                 "window layer takes a prompt slice by slice against its "
+                 "window slots; use the continuous scheduler"},
+    decode_kernel_block=ring_kernel_block,   # both leaf kinds
+    health=_health,
+    own_gauges={"window_slots_read_total": "window_read",
+                "window_slots_live_total": "window_live",
+                "global_slots_read_total": "global_read",
+                "global_slots_live_total": "global_live"},
+    note_decode=_note_decode)

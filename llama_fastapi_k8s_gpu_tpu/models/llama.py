@@ -23,10 +23,10 @@ preallocated, donated KV cache.  Design choices are TPU-first:
 - the feed-forward kind is the configuration's: dense SwiGLU, or
   (``cfg.n_experts``) a float32 router over SwiGLU experts whose products
   are computed for the picked experts only (ops/pallas/experts.py); so is
-  the RMSNorm of Q and K (``cfg.qk_norm``), the cache kind
-  (``cfg.eva_window``: an exact window plus chunk summaries in place of
-  the ring, models/eva.py) and the float32 residual stream
-  (``cfg.fp32_residual``).  One layer body, not a copy.
+  the RMSNorm of Q and K (``cfg.qk_norm``) and the float32 residual
+  stream (``cfg.fp32_residual``).  One layer body, not a copy.
+- the cache KIND is one object (models/cache.py ``cache_of``) that
+  :func:`init_cache`, :func:`forward` and the rest ask; the ring's: ``CACHE``.
 
 RoPE is the *interleaved* (ggml "NORM") variant: GGUF conversion permutes
 Q/K weights to this convention, so parity with llama.cpp requires it.
@@ -40,8 +40,8 @@ import jax.numpy as jnp
 from ..ops import linear
 from ..ops.linear import linear_at
 from . import eva
-from .config import (
-    LATENT_RING, STATE_RING, WINDOW_GLOBAL_RING, ModelConfig)
+from .cache import HEADS, CacheKind, cache_of
+from .config import RING, ModelConfig
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -90,6 +90,19 @@ def rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
+    """The cache leaves of ONE sequence, of the configuration's kind
+    (models/cache.py; docs/KV_CACHE.md "Cache kinds")."""
+    return cache_of(cfg).init(cfg, dtype)
+
+
+def cache_nbytes(cfg: ModelConfig) -> int:
+    """HBM bytes of ONE sequence's cache (a lane engine holds one a lane):
+    /health ``kv_cache_bytes`` and docs/KV_CACHE.md's lane-headroom math,
+    from shapes, so callers never need a live cache."""
+    return cache_of(cfg).nbytes(cfg)
+
+
+def _init_ring(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     """KV ring, HEAD-MAJOR: (L, n_kv, n_ctx, hd).  Head-major is the layout
     every attention consumer reads (XLA decode scores, the flash kernel's
     per-head blocks, ring chunks), so readers slice it directly; the
@@ -99,35 +112,8 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     transposed before its dynamic_update_slice — S ≤ bucket-size, not
     n_ctx.
 
-    ``cfg.kv_dtype == "int8"`` swaps the two bf16 leaves for the quantized
-    layout (docs/KV_CACHE.md): int8 value rings ``k_q``/``v_q`` of the same
-    shape plus per-head, per-token symmetric f32 scales ``k_s``/``v_s``
-    (L, n_kv, n_ctx) — HBM per token-head drops 2·hd → hd + 4 bytes, and
-    attention reads stream int8.
-
-    ``cfg.eva_window`` is the other cache KIND (models/eva.py): two window
-    leaves of ``eva_window`` slots and two summary leaves.  ``cfg.cache_kind
-    == "state+ring"`` is the third (models/sala.py): a ring and its
-    compressed keys for the sparse layers, a float32 state for the linear
-    ones, each as deep as its kind has layers.  ``"latent-ring"`` is the
-    fourth (models/mla.py): one row a layer and position, the normed latent
-    and the rotated key all heads share.  ``"window+global-ring"`` is the
-    fifth (models/hybrid.py): a leaf pair per attention kind, a window
-    layer's of window slots that wrap, a global layer's of ``n_ctx``."""
-    if cfg.cache_kind == STATE_RING:
-        from . import sala
-
-        return sala.init_cache(cfg, dtype)
-    if cfg.cache_kind == WINDOW_GLOBAL_RING:
-        from . import hybrid
-
-        return hybrid.init_cache(cfg, dtype)
-    if cfg.cache_kind == LATENT_RING:
-        from . import mla
-
-        return mla.init_cache(cfg, dtype)
-    if cfg.eva_window:
-        return eva.init_cache(cfg, dtype)
+    ``cfg.kv_dtype == "int8"``: the quantized layout of docs/KV_CACHE.md,
+    int8 ``k_q``/``v_q`` plus f32 scales ``k_s``/``v_s`` (L, n_kv, n_ctx)."""
     shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
     if cfg.kv_dtype == "int8":
         sshape = shape[:-1]
@@ -142,25 +128,7 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def cache_nbytes(cfg: ModelConfig) -> int:
-    """HBM bytes of ONE cache ring under ``cfg`` (batched engines hold one
-    per lane) — the /health ``kv_cache_bytes`` figure and the lane-headroom
-    math in docs/KV_CACHE.md, computed from shapes so callers never need a
-    live cache."""
-    if cfg.cache_kind == STATE_RING:
-        from . import sala
-
-        return sala.cache_nbytes(cfg)
-    if cfg.cache_kind == LATENT_RING:
-        from . import mla
-
-        return mla.cache_nbytes(cfg)
-    if cfg.cache_kind == WINDOW_GLOBAL_RING:
-        from . import hybrid
-
-        return hybrid.cache_nbytes(cfg)
-    if cfg.eva_window:
-        return eva.cache_nbytes(cfg)
+def _ring_nbytes(cfg: ModelConfig) -> int:
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
         + (4 if cfg.kv_dtype == "int8" else 0)
     return 2 * cfg.n_layers * cfg.n_kv_heads * cfg.n_ctx * per_tok_head
@@ -240,47 +208,41 @@ DECODE_KERNEL_BLOCK = (128, 512, 2048)
 
 def decode_kernel_block(cfg: ModelConfig) -> int:
     """The block of the decode kernel where a decode step's attention runs
-    it, else 0 (the XLA loop of :func:`decode_attention`).  Decided by what
-    the configuration shows, no setting: the kernel serves a bf16 ring
-    under ``attn_impl == "pallas"`` (a TPU, ``head_dim % 128 == 0``, the
-    flash probe passed: engine/engine.py; a mesh engine resolves to
-    ``xla``, sequence parallelism to ``ring``) whose slots its block
-    divides; the block is a power of two by the KV heads a copy spans
-    (``DECODE_KERNEL_BLOCK``).  A latent ring (models/mla.py) is read by
-    the same kernel on its one leaf where ``cfg.latent_kernel`` says so
-    (the engine's: a TPU, the kernel's own probe passed; ``attn_impl`` is
-    ``xla`` for that kind), in blocks of its own
-    (``mla.LATENT_KERNEL_BLOCK`` rows for all heads)."""
-    if cfg.cache_kind == LATENT_RING:
-        if not cfg.latent_kernel:
-            return 0
-        from .mla import LATENT_KERNEL_BLOCK
+    it, else 0, by the cache kind's rule (``CacheKind.decode_kernel_block``:
+    the ring's is :func:`ring_kernel_block`)."""
+    return cache_of(cfg).decode_kernel_block(cfg)
 
-        block = min(LATENT_KERNEL_BLOCK, cfg.n_ctx)
-    else:
-        if cfg.attn_impl != "pallas" or cfg.eva_window \
-                or cfg.kv_dtype == "int8":
-            return 0
-        least, most, head_slots = DECODE_KERNEL_BLOCK
-        per_head = max(head_slots // cfg.n_kv_heads, 1)
-        block = min(max(1 << (per_head.bit_length() - 1), least), most,
-                    cfg.n_ctx)
+
+def ring_kernel_block(cfg: ModelConfig) -> int:
+    """The block of the decode kernel where a decode step's attention on a
+    RING runs it, else 0 (the XLA loop of :func:`decode_attention`).
+    Decided by what the configuration shows, no setting: the kernel serves
+    a bf16 ring under ``attn_impl == "pallas"`` (a TPU, ``head_dim % 128 ==
+    0``, the flash probe passed: engine/engine.py; a mesh engine resolves
+    to ``xla``, sequence parallelism to ``ring``) whose slots its block
+    divides; the block is a power of two by the KV heads a copy spans
+    (``DECODE_KERNEL_BLOCK``)."""
+    if cfg.attn_impl != "pallas" or cfg.kv_dtype == "int8":
+        return 0
+    least, most, head_slots = DECODE_KERNEL_BLOCK
+    per_head = max(head_slots // cfg.n_kv_heads, 1)
+    block = min(max(1 << (per_head.bit_length() - 1), least), most,
+                cfg.n_ctx)
     return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
 
 
 def ring_write_impl(cfg: ModelConfig) -> str | None:
     """Who stores a decode step's K and V row in a ring: ``kernel`` where
-    :func:`_layer` hands the row to the decode kernel
-    (:func:`decode_kernel_block` is not 0; a lane that holds no request
-    then stores nothing), else ``xla`` (``dynamic_update_slice``: int8
-    rings, mesh and sequence-parallel engines, the CPU, and the ring layers
-    of models/sala.py, which write before they call the kernel; prefill
-    slices on every path; both leaf kinds of models/hybrid.py go the same
-    way).  None on a cache that has no ring."""
-    if cfg.eva_window:
+    the layer hands the row to the decode kernel (its block is not 0; a
+    lane that holds no request then stores nothing), else ``xla``
+    (``dynamic_update_slice``: int8 rings, meshes, the CPU, the ring layers
+    of models/sala.py; prefill slices on every path).  None on a cache
+    that has no ring."""
+    kind = cache_of(cfg)
+    if kind.kernel_writes is None:
         return None
-    kernel = decode_kernel_block(cfg) and cfg.cache_kind != STATE_RING
-    return "kernel" if kernel else "xla"
+    return "kernel" if kind.kernel_writes \
+        and kind.decode_kernel_block(cfg) else "xla"
 
 
 def decode_read_slots(bound, n_ctx: int, block: int = 0):
@@ -449,7 +411,7 @@ def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
         q[0], cache["k"], cache["v"], i, pos,
         True if live is None else live,
         sm_scale=cfg.head_dim ** -0.5,
-        block_k=decode_kernel_block(cfg),
+        block_k=ring_kernel_block(cfg),
         sliding_window=cfg.sliding_window,
         interpret=use_interpret(),
         k_new=k_new, v_new=v_new,
@@ -498,7 +460,7 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
             v_scale=cvs,
             interpret=use_interpret(),
         ).reshape(S, cfg.n_heads * hd).astype(dtype)
-    elif S == 1 and decode_kernel_block(cfg):
+    elif S == 1 and ring_kernel_block(cfg):
         # a decode step reads the live part of the ring, not n_ctx slots
         # (models/sala.py's ring layers, which write before they call;
         # ``_layer`` hands the kernel the row to store)
@@ -524,8 +486,8 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     or None): False marks a lane that holds no request, whose rows then
     reach no expert and, where the decode kernel serves the ring, read no
     slot of it (its output is not read).  ``kv_bound``: see
-    :func:`forward`.  Under ``cfg.eva_window`` the leaves are the window
-    and summary leaves of models/eva.py, the S tokens lie inside ONE
+    :func:`forward`.  On a window + summary cache the leaves are the
+    window and summary leaves of models/eva.py, the S tokens lie inside ONE
     window, and ``kv_bound`` is the triple of ``eva.live_bounds``.
 
     The weights stay STACKED (L, ...) and are addressed per layer with
@@ -595,7 +557,7 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         kh = k.astype(cache["k"].dtype).transpose(1, 0, 2)   # (n_kv, S, hd)
         vh = v.astype(cache["v"].dtype).transpose(1, 0, 2)
         cks = cvs = None
-        if S == 1 and decode_kernel_block(cfg):
+        if S == 1 and ring_kernel_block(cfg):
             # the decode kernel stores the step's row itself, into the
             # block it reads anyway, and nothing for a lane that holds no
             # request: no update of the lanes' stacked leaf beside it
@@ -656,35 +618,16 @@ def forward(
     default this sequence's own position; lanes ``vmap``ped over one step
     share the largest live lane's, as an UNBATCHED value (the decode
     kernel takes none: it reads up to this sequence's own position, and
-    nothing where ``live`` is False); under ``cfg.eva_window``
+    nothing where ``live`` is False); on a window + summary cache
     the triple of ``eva.live_bounds``.  ``all_heads``: the logits of every
     prediction head (``vocab_size * n_pred_heads`` rows) and not head 0's
     alone."""
+    own = cache_of(cfg).forward
+    if own is not None:   # a stack of the kind's own: its loops and head
+        return own(params, cfg, tokens, pos_offset, cache, last_idx,
+                   return_all, live, with_stats=with_stats,
+                   with_picks=with_picks, kv_bound=kv_bound)
     S = tokens.shape[0]
-    if cfg.cache_kind == STATE_RING:
-        # a stack of two layer kinds: its own loop, embedding and head
-        from . import sala
-
-        return sala.forward(params, cfg, tokens, pos_offset, cache, last_idx,
-                            return_all, live, with_picks, kv_bound)
-    if cfg.cache_kind == LATENT_RING:
-        # latent attention, a feed-forward kind per layer: its own loops
-        from . import mla
-
-        return mla.forward(params, cfg, tokens, pos_offset, cache, last_idx,
-                           return_all, live, with_stats, with_picks, kv_bound)
-    if cfg.cache_kind == WINDOW_GLOBAL_RING:
-        # an attention kind per layer on leaves of its own size
-        from . import hybrid
-
-        return hybrid.forward(params, cfg, tokens, pos_offset, cache,
-                              last_idx, return_all, live, with_stats,
-                              with_picks, kv_bound)
-    if cfg.eva_window and S > cfg.eva_window:
-        raise ValueError(
-            f"architecture 'evabyte': {S} positions in one pass, its window "
-            f"holds {cfg.eva_window}: a prompt is prefilled in slices that "
-            "lie inside one window (LFKT_PREFILL_CHUNK)")
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(
         jnp.float32 if cfg.fp32_residual else jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
@@ -763,3 +706,66 @@ def prefill(params, cfg: ModelConfig, tokens, length, cache):
 def decode_step(params, cfg: ModelConfig, token, pos, cache):
     """One autoregressive step: ``token`` at cache position ``pos``."""
     return forward(params, cfg, token[None], pos, cache)
+
+
+def live_bound(pos: jax.Array, live: jax.Array | None = None) -> jax.Array:
+    """The ring slot the XLA loop of a decode step's attention reads up
+    to, ONE scalar for all lanes (:func:`decode_attention`): the largest
+    position among the lanes that hold a request (``live`` (B,) bool; None:
+    all).  A freed lane's position walks on and must not drag the read."""
+    return jnp.max(pos if live is None else jnp.where(live, pos, 0))
+
+
+def ring_step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
+    """A lane step's ``kv_bound`` on a ring.  Read by the decode kernel it
+    needs none: the kernel's reach is PER LANE, the lane's own ``pos`` where
+    it is ``live`` and nothing where not, and ``forward`` has both already.
+    Read by the XLA loop it takes :func:`live_bound` (the trip count)."""
+    return None if cache_of(cfg).decode_kernel_block(cfg) \
+        else live_bound(pos, live)
+
+
+def ring_shardings(cfg: ModelConfig) -> dict:
+    """KV heads over ``tp``; an int8 ring's (L, n_kv, ctx) scale planes get
+    the value rings' axes minus the hd axis."""
+    if cfg.kv_dtype == "int8":
+        return {"k_q": HEADS, "v_q": HEADS, "k_s": HEADS[:-1],
+                "v_s": HEADS[:-1]}
+    return {"k": HEADS, "v": HEADS}
+
+
+def note_ring_decode(counts: dict, cfg: ModelConfig, wanted: list,
+                     n_steps: int, live: list | None = None, *,
+                     until: int | None = None) -> None:
+    """Count one decode chunk's read of a ring against what it needed, per
+    step and summed over the sequences at positions ``wanted`` (their
+    first step's): :func:`decode_chunk_slots`.  Under the XLA loop every
+    one reads up to the bound of the positions ``live`` (the lanes the
+    chunk was dispatched as live; default ``wanted``); under the decode
+    kernel each reads its OWN blocks and a lane not wanted reads nothing.
+    Where the kernel stores the step's row too, every lane dispatched live
+    stored its row in every layer of every step.  ``until``: the first
+    position whose step no longer reads the ring so (models/sala.py)."""
+    dispatched = wanted if live is None else live
+    if until is not None:
+        dispatched = [p for p in dispatched if p < until]
+    kind = cache_of(cfg)
+    block = kind.decode_kernel_block(cfg)
+    bound = None if block else max(dispatched, default=0)
+    if block and kind.kernel_writes:
+        counts["rows_written"] += len(dispatched) * n_steps * cfg.n_layers
+    for p in wanted:
+        steps = n_steps if until is None else min(n_steps, max(until - p, 0))
+        read, lv = decode_chunk_slots(p, steps, cfg.n_ctx, bound, block)
+        counts["read"] += read
+        counts["live"] += lv
+
+
+CACHE = CacheKind(
+    name=RING, arch="llama",
+    init=_init_ring, nbytes=_ring_nbytes,
+    step_bound=ring_step_bound, shardings=ring_shardings,
+    # the ring is what every feature was built on: it refuses nothing
+    supports=dict.fromkeys(("int8", "paged", "tp", "sp", "cycle"), True),
+    rolls_back=True, always_slices=False,
+    decode_kernel_block=ring_kernel_block, note_decode=note_ring_decode)

@@ -24,7 +24,7 @@
   head for every position) is what the reference computes and what
   tests hold the absorbed form to.  WHICH READ SERVES WHICH S: a prefill
   slice (S > 1), and a decode step (S = 1) on the CPU or wherever
-  ``llama.decode_kernel_block`` says 0, run :func:`latent_attention`, a
+  :func:`kernel_block` says 0, run :func:`latent_attention`, a
   loop in plain XLA over blocks of ``LATENT_BLOCK`` positions up to a
   traced bound (flash recurrence: running max and sum; under the lane
   engine's ``vmap`` ONE bound for all lanes), after an XLA
@@ -47,6 +47,8 @@
 
 from __future__ import annotations
 
+import dataclasses
+import logging
 import math
 
 import jax
@@ -54,24 +56,40 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.linear import linear, linear_at
-from .config import ModelConfig
+from .cache import WHOLE, CacheKind
+from .config import LATENT_RING, ModelConfig
+from .llama import (
+    expert_stats_len, note_ring_decode, ring_step_bound, rms_norm)
 from .routed import (  # noqa: F401  (``mla.route_grouped``: the tests' name)
     DENSE, HI, MOE, check_stacks, expert_branch, held_picks, moe_stats,
     n_moe_layers, route_grouped, swiglu)
+
+logger = logging.getLogger(__name__)
 
 #: latent rows a block of :func:`latent_attention`'s loop reads (the XLA
 #: loop of ``models/llama.py decode_attention`` reads 512 ring slots a time)
 LATENT_BLOCK = 512
 
 #: latent rows the decode KERNEL copies at a time
-#: (ops/pallas/attention.py ``latent_attention_decode``; ``models/llama.py
-#: decode_kernel_block`` says where it serves): 1.3 MB a copy at 640
+#: (ops/pallas/attention.py ``latent_attention_decode``;
+#: :func:`kernel_block` says where it serves): 1.3 MB a copy at 640
 #: columns.  On the chip a block of 256 / 512 / 1024 rows costs 0.76 / 1.09
 #: / 1.84 us (0.4 us fixed, then 0.36 us a 256 rows, which is 89 % of the
 #: HBM's rate), so at 12 live lanes of context 8.7k a step's seven layers
 #: take 2.23 / 1.62 / 1.42 ms, with the half block a lane reads past its
 #: position counted in (PERF.md section 6, PR 47).
 LATENT_KERNEL_BLOCK = 1024
+
+
+def kernel_block(cfg: ModelConfig) -> int:
+    """The decode kernel's block on the latent leaf where
+    ``cfg.latent_kernel`` says it serves (the engine's: a TPU, the kernel's
+    own probe passed; ``attn_impl`` is ``xla`` for this kind), else 0: rows
+    for all heads, dividing the leaf's."""
+    if not cfg.latent_kernel:
+        return 0
+    block = min(LATENT_KERNEL_BLOCK, cfg.n_ctx)
+    return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
 
 
 def lat_width(cfg: ModelConfig) -> int:
@@ -254,8 +272,6 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
     cache's).  ``live`` (scalar bool or None): whether this sequence holds
     a request; the decode kernel reads and stores nothing where not.
     Returns (h + branch, cache)."""
-    from .llama import decode_kernel_block, rms_norm
-
     S = h.shape[0]
     H, r, d_n, d_r = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
                       cfg.qk_rope_dim)
@@ -281,7 +297,7 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
     q_full = jnp.concatenate(
         [absorb_query(q[..., :d_n], layers["w_uk"]["w"][i]), q_r,
          jnp.zeros((S, H, fill), q_r.dtype)], axis=-1)
-    block = decode_kernel_block(cfg) if S == 1 else 0
+    block = kernel_block(cfg) if S == 1 else 0
     if block:
         # the decode kernel: this sequence's own blocks, read in place, and
         # the step's row stored into the block it reads anyway
@@ -308,8 +324,6 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
 
 def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
                 kv_bound):
-    from .llama import rms_norm
-
     h, cache = _attention(h, layers, i, i, cache, positions, pos_offset, cfg,
                           live, kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
@@ -320,8 +334,6 @@ def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
               kv_bound):
     """Returns (h, cache, (rows each HELD expert took (n_held,), the
     router's picks (S, k) over all experts, picks of live rows))."""
-    from .llama import rms_norm
-
     h, cache = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
                           positions, pos_offset, cfg, live, kv_bound)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
@@ -339,8 +351,6 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
     ``with_stats`` / ``with_picks`` as there (the counter vector of
     ``llama.expert_stats_len`` is over the HELD experts; the picks are the
     router's, over all).  ``kv_bound``: a lane step's ``live_bound``."""
-    from .llama import expert_stats_len, rms_norm
-
     S = tokens.shape[0]
     n_moe = n_moe_layers(cfg)
     check_stacks(params, cfg)
@@ -383,3 +393,79 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
     return (head(h_last)[0], new_cache, *tail)
+
+
+def _probe_kernels(cfg: ModelConfig, asked: str, attn_impl: str, probed):
+    """A decode step's read of the latent leaf is the decode kernel where
+    the chip compiles it (``auto``: a TPU); a Mosaic failure degrades the
+    step to the XLA loop, and says so (ops/pallas/probe.py)."""
+    if asked == "pallas" or (
+            asked == "auto" and jax.default_backend() == "tpu"):
+        from ..ops.pallas.probe import probe_latent_decode
+
+        probed.append("latent_decode")
+        err = probe_latent_decode()
+        if err is None:
+            cfg = dataclasses.replace(cfg, latent_kernel=True)
+        else:
+            logger.error("pallas latent decode kernel failed its compile "
+                         "probe; decode steps read the latent ring "
+                         "through the XLA loop: %s", err)
+    return cfg, attn_impl
+
+
+def _health(cfg: ModelConfig, engine) -> dict:
+    reuse = getattr(engine, "_lane_prefix", engine._prefix_cache)
+    return {
+        "kind": LATENT_RING,
+        "latent": cfg.kv_lora_rank, "rotated_key": cfg.qk_rope_dim,
+        "bytes_per_position": 2 * cfg.n_layers * lat_width(cfg),
+        "bytes_per_position_laid_out": 2 * cfg.n_layers * leaf_width(cfg),
+        "read": "absorbed, blocks of %d" % LATENT_BLOCK,
+        "dense_layers": cfg.n_dense_layers,
+        "routed_layers": n_moe_layers(cfg),
+        "experts_held": [cfg.experts_first, cfg.n_held],
+        "experts_routed": cfg.n_experts,
+        "prefix_reuse": "on" if reuse else "off",
+        "kv_paged": "refused at start"}
+
+
+def _note_prefill(counts, cfg: ModelConfig, n_prompt: int, slices) -> dict:
+    # the cached rows the prompt's slices read, from the reused prefix on
+    if slices is None:
+        return {}
+    return {"cache": LATENT_RING,
+            "latent_positions_read": prefill_positions_read(slices, cfg)}
+
+
+CACHE = CacheKind(
+    name=LATENT_RING, arch="deepseek2",
+    init=init_cache, nbytes=cache_nbytes, forward=forward,
+    step_bound=ring_step_bound,
+    shardings=lambda cfg: {"lat": WHOLE},
+    supports={
+        "int8": "its latent ring is bf16 only",
+        "paged": "a pool page is a run of K and V slots per KV head, and "
+                 "its cache is one latent row a position for all heads",
+        "tp": "parallel/mesh.py shards a ring's KV heads, and its latent "
+              "ring has one row for all heads; experts over a mesh are "
+              "ROADMAP B-I 5",
+        "sp": "the sp ring passes K and V chunks per head between chips, "
+              "and its cache is one latent row a position for all heads",
+        "cycle": "it prefills a whole prompt in one vmapped pass, and "
+                 "latent attention scores a pass against blocks of latents "
+                 "slice by slice; use the continuous scheduler"},
+    rolls_back=True,   # positional, as the ring is
+    # ``attn_impl`` stays xla: a prefill slice's attention is this file's
+    # own loop, and the flash kernel serves nothing
+    attn_impl=lambda cfg, asked: "xla",
+    probe_kernels=_probe_kernels,
+    decode_kernel_block=kernel_block,
+    health=_health,
+    # the ring's own arithmetic under the kind's names too: a latent is
+    # read once for all heads
+    own_gauges={"latent_positions_read_total": "read",
+                "latent_positions_live_total": "live"},
+    note_decode=note_ring_decode, note_prefill=_note_prefill,
+    decode_span_attrs=lambda pos: {"cache": LATENT_RING,
+                                   "latent_positions": pos})

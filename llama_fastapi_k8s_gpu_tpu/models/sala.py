@@ -59,13 +59,20 @@ output the lane does not take.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..ops.linear import linear, linear_at
-from .config import ModelConfig
+from .cache import HEADS, WHOLE, CacheKind
+from .config import STATE_RING, ModelConfig
+from .llama import (
+    _ring_attention, note_ring_decode, ring_kernel_block, rms_norm,
+    rope_half)
+
+logger = logging.getLogger(__name__)
 
 LIN, SP = "lin", "sp"
 HI = jax.lax.Precision.HIGHEST
@@ -162,12 +169,10 @@ def live_bounds(pos: jax.Array, live, cfg: ModelConfig):
     kernel serves it and bounds each lane by itself; whether any live lane
     is past ``dense_len``; whether any is before it).  A freed lane keeps
     stepping and must drag neither read along."""
-    from .llama import decode_kernel_block
-
     lv = jnp.ones(pos.shape, bool) if live is None else live
     sp = is_sparse(pos, cfg)
     dense = lv & ~sp
-    bound = None if decode_kernel_block(cfg) \
+    bound = None if ring_kernel_block(cfg) \
         else jnp.max(jnp.where(dense, pos, 0))
     return bound, jnp.any(lv & sp), jnp.any(dense)
 
@@ -199,7 +204,7 @@ def chunk_counts(positions: list[int], n_steps: int, cfg: ModelConfig) -> dict:
     layer), queries of the sparse layers by branch, blocks the sparse
     branch's read covered / a causal read would (per sparse layer and KV
     head), ``kc`` entries written.  Host arithmetic from tracked positions
-    for the counters of ``Engine.cache_read_gauges``: nothing fetched."""
+    for the counters of :data:`CACHE`: nothing fetched."""
     L_lin, L_sp = cfg.n_layers_of(LIN), cfg.n_layers_of(SP)
     out = {"state_updates": n_steps * len(positions) * L_lin,
            "queries_dense": 0, "queries_sparse": 0,
@@ -248,8 +253,6 @@ def _add_branch(h, out, cfg: ModelConfig):
 
 
 def _pre(h, w, i, cfg: ModelConfig):
-    from .llama import rms_norm
-
     hn = rms_norm(h, w["attn_norm"][i], cfg.rms_eps)
 
     def lin(x, name):
@@ -261,8 +264,6 @@ def _pre(h, w, i, cfg: ModelConfig):
 def _finish(h, hn, ctx, lin, w, i, cfg: ModelConfig):
     """Gate, output projection, branch, then the feed-forward branch.
     ``ctx`` (S, dim) float32."""
-    from .llama import rms_norm
-
     gate = jax.nn.sigmoid(lin(hn, "wg").astype(jnp.float32))
     h = _add_branch(h, lin((ctx * gate).astype(hn.dtype), "wo"), cfg)
     fn = rms_norm(h, w["ffn_norm"][i], cfg.rms_eps)
@@ -361,8 +362,6 @@ def lin_layer(h, w, i, cache, positions, pos_offset, n_valid,
     decode kernel) a decode step updates the stacked leaf in place
     (ops/pallas/linstate.py) and touches nothing of a lane whose ``live``
     is False; the plain XLA step serves meshes and the CPU."""
-    from .llama import rope_half
-
     S, H, hd = h.shape[0], cfg.lin_heads, cfg.head_dim
     hn, lin = _pre(h, w, i, cfg)
     q, k, v = (lin(hn, n).reshape(S, H, hd) for n in ("wq", "wk", "wv"))
@@ -672,8 +671,6 @@ def sp_layer(h, w, i, cache, positions, pos_offset, n_valid,
     sparse layers' stacked weights and of the ``k``/``v``/``kc`` leaves.
     Returns (h, cache, picks (n_kv, S, n_blocks) bool: the blocks each
     query read)."""
-    from .llama import _ring_attention
-
     S, n_kv, hd = h.shape[0], cfg.n_kv_heads, cfg.head_dim
     hn, lin = _pre(h, w, i, cfg)
     q = _rms(lin(hn, "wq").reshape(S, cfg.n_heads, hd),
@@ -738,14 +735,15 @@ def sp_layer(h, w, i, cache, positions, pos_offset, n_valid,
 
 def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
             last_idx=None, return_all: bool = False, live=None,
-            with_picks: bool = False, kv_bound=None):
+            with_picks: bool = False, kv_bound=None, with_stats=False):
     """``models/llama.py forward`` for a file of two layer kinds: the runs
     of :func:`runs` in order, each a ``fori_loop`` over its kind's stacked
     weights and cache leaves addressed by the layer's number within the
     kind (no per-layer copy of a fused plane, no restack of a cache).
     ``with_picks`` appends the blocks every query of every sparse layer
     read, (L_sp, n_kv, S, n_blocks) bool.  ``kv_bound``: a lane step's
-    :func:`live_bounds` (None: this sequence's own position decides)."""
+    :func:`live_bounds` (None: this sequence's own position decides).
+    ``with_stats`` is ``llama.forward``'s: no routed layer, nothing added."""
     S = tokens.shape[0]
     for kind in (LIN, SP):
         for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -787,8 +785,6 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
     out_w = params["output"]
 
     def head(x):
-        from .llama import rms_norm
-
         hn = (rms_norm(x, params["out_norm"], cfg.rms_eps).astype(jnp.float32)
               * cfg.logit_scale).astype(jnp.bfloat16)
         with jax.named_scope("head"):
@@ -805,3 +801,94 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
         last_idx = jnp.int32(S - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=0)
     return (head(h_last)[0], new_cache, *tail)
+
+
+def _slice_rule(cfg: ModelConfig, chunk: int) -> str | None:
+    if chunk % cfg.sp_block:
+        return ("a prefill slice must be a multiple of its sparse layers' "
+                f"block ({cfg.sp_block})")
+
+
+def _probe_kernels(cfg: ModelConfig, asked: str, attn_impl: str, probed):
+    """The state's decode step is a kernel too (ops/pallas/linstate.py);
+    it and the ring's kernels degrade together."""
+    if attn_impl == "pallas":
+        from ..ops.pallas.probe import probe_lin_state
+
+        probed.append("lin_state")
+        err = probe_lin_state()
+        if err is not None:
+            logger.error("pallas linear-state step failed its compile "
+                         "probe; serving with attn_impl=xla: %s", err)
+            attn_impl = "xla"
+    return cfg, attn_impl
+
+
+def _health(cfg: ModelConfig, engine) -> dict:
+    return {
+        "kind": STATE_RING,
+        "linear_layers": cfg.n_layers_of(LIN),
+        "sparse_layers": cfg.n_layers_of(SP),
+        "state_bytes": state_nbytes(cfg),
+        "compressed_keys": n_kc(cfg),
+        "blocks_read_at_most": n_select(cfg),
+        "dense_len": cfg.sp_dense_len,
+        "prefix_reuse": "off: a state cannot be rolled back to a "
+                        "shared prefix",
+        "kv_paged": "refused at start",
+        "chat_template": engine.template_kind + (
+            "" if engine._template_named else
+            " (fallback: the file names no template known here)")}
+
+
+def _note_decode(counts: dict, cfg: ModelConfig, wanted: list, n_steps: int,
+                 live: list | None = None) -> None:
+    counts.update(chunk_counts(wanted, n_steps, cfg))
+    # the ring counters: the ring layers' dense reads, before dense_len
+    note_ring_decode(counts, cfg, wanted, n_steps, live,
+                     until=cfg.sp_dense_len - 1)
+
+
+def _note_prefill(counts, cfg: ModelConfig, n_prompt: int, slices) -> dict:
+    c = prefill_counts(n_prompt, cfg)
+    for k in ("queries_dense", "queries_sparse", "kc_written"):
+        counts[k] += c[k]
+    return {"kc_closed": c["kc_closed"],
+            "sparse_positions": c["sparse_positions"]}
+
+
+CACHE = CacheKind(
+    name=STATE_RING, arch="minicpm-sala",
+    init=init_cache, nbytes=cache_nbytes,
+    forward=forward,   # two layer kinds: its own loop, embedding and head
+    # the sparse layers' two branches, each skipped where no live lane
+    # takes it
+    step_bound=lambda cfg, pos, live: live_bounds(pos, live, cfg),
+    shardings=lambda cfg: {**dict.fromkeys(("k", "v", "kc", "kw"), HEADS),
+                           "state": WHOLE},
+    supports={
+        "int8": "its state + ring cache is float32 + bf16 only",
+        "paged": "the pool pages runs of ring slots by token position, and "
+                 "its linear layers keep a state that cannot be rolled back "
+                 "to a shared prefix",
+        "tp": "parallel/mesh.py shards a ring's KV heads and one stack of "
+              "layers, and has no layout for two kinds of layer or a state "
+              "leaf",
+        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
+              "linear layers keep a state per sequence, not slots",
+        "cycle": "it prefills a whole prompt in one vmapped pass, and its "
+                 "sparse layers select per query in slices; use the "
+                 "continuous scheduler"},
+    slice_rule=_slice_rule,
+    probe_kernels=_probe_kernels,
+    # the ring layers write before they call the kernel
+    decode_kernel_block=ring_kernel_block, kernel_writes=False,
+    health=_health,
+    own_gauges={
+        "lin_state_updates_total": "state_updates",
+        'sparse_queries_total{branch="dense"}': "queries_dense",
+        'sparse_queries_total{branch="sparse"}': "queries_sparse",
+        "sparse_blocks_read_total": "blocks_read",
+        "sparse_blocks_visible_total": "blocks_visible",
+        "sparse_kc_written_total": "kc_written"},
+    note_decode=_note_decode, note_prefill=_note_prefill)

@@ -20,11 +20,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models.config import STATE_RING, ModelConfig
-from ..models import eva
+from ..models.cache import cache_of
+from ..models.config import ModelConfig
 from ..models.generate import chunk_out
-from ..models.llama import (
-    decode_kernel_block, expert_stats_len, forward, init_cache, prefill)
+from ..models.llama import (  # noqa: F401  (``live_bound``: the tests' name)
+    expert_stats_len, forward, init_cache, live_bound, prefill)
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 
@@ -47,37 +47,12 @@ def init_batched_state(cfg: ModelConfig, batch: int, seed: int = 0) -> dict:
     }
 
 
-def live_bound(pos: jax.Array, live: jax.Array | None = None) -> jax.Array:
-    """The ring slot the XLA loop of a decode step's attention reads up
-    to, ONE scalar for all lanes (models/llama.py ``decode_attention``):
-    the largest position among the lanes that hold a request (``live``
-    (B,) bool; None: all).  A freed lane keeps stepping and its position
-    walks on; it must not drag the read to ``n_ctx``."""
-    return jnp.max(pos if live is None else jnp.where(live, pos, 0))
-
-
 def step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
     """What ``forward`` takes as a lane step's ``kv_bound``, by the cache
-    kind and the read that serves it.  A ring read by the decode kernel
-    (``decode_kernel_block``) needs none: the kernel's reach is PER LANE,
-    the lane's own ``pos`` where its ``live`` says it holds a request and
-    nothing where not, and ``forward`` has both per lane already.  A ring
-    read by the XLA loop takes :func:`live_bound`, the one scalar that is
-    the largest such reach (the loop's trip count).  The window + summary
-    cache takes the three scalars of ``models/eva.py live_bounds`` (one
-    bound becomes two, and whether any live lane closes a window in this
-    step)."""
-    if cfg.cache_kind == STATE_RING:
-        # the sparse layers' two branches, each skipped where no live lane
-        # takes it (models/sala.py live_bounds)
-        from ..models import sala
-
-        return sala.live_bounds(pos, live, cfg)
-    if cfg.eva_window:
-        return eva.live_bounds(pos, live, cfg)
-    if decode_kernel_block(cfg):
-        return None
-    return live_bound(pos, live)
+    kind and the read that serves it (``CacheKind.step_bound``;
+    models/llama.py ``ring_step_bound`` for a ring, models/eva.py and
+    models/sala.py ``live_bounds`` for theirs)."""
+    return cache_of(cfg).step_bound(cfg, pos, live)
 
 
 def state_nbytes(state: dict | None) -> int:

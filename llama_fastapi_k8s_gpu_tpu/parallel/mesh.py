@@ -26,8 +26,8 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import (
-    LATENT_RING, STATE_RING, WINDOW_GLOBAL_RING, ModelConfig)
+from ..models.cache import cache_of
+from ..models.config import ModelConfig
 
 
 def make_mesh(dp: int = 1, tp: int = 1, sp: int = 1, devices=None) -> Mesh:
@@ -134,24 +134,13 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
 
 
 def cache_shardings(cfg: ModelConfig, mesh: Mesh, batched: bool = False):
-    """Head-major KV cache (L, n_kv, ctx, hd): kv-heads over tp; batch (if
-    any) over dp.  Under ``kv_dtype=int8`` the int8 value rings keep that
-    spec and the (L, n_kv, ctx) scale planes get it minus the hd axis."""
+    """The cache leaves' layout, by the cache kind
+    (``CacheKind.shardings``: a head-major (L, n_kv, ctx, hd) leaf's
+    kv-heads over tp, a leaf with no heads whole on every chip); batch (if
+    any) over dp."""
     lead = ("dp",) if batched else ()
-    s4 = _ns(mesh, *lead, None, "tp", None, None)
-    if cfg.cache_kind == STATE_RING:   # models/sala.py, tp = 1
-        return {"k": s4, "v": s4, "kc": s4, "kw": s4,
-                "state": _ns(mesh, *lead, None, None, None, None)}
-    if cfg.cache_kind == WINDOW_GLOBAL_RING:   # models/hybrid.py, tp = 1
-        return {name: s4 for name in ("k", "v", "kw", "vw")}
-    if cfg.cache_kind == LATENT_RING:   # models/mla.py, tp = 1
-        return {"lat": _ns(mesh, *lead, None, None, None, None)}
-    if cfg.eva_window:   # window + summary leaves (models/eva.py), tp = 1
-        return {name: s4 for name in ("k", "v", "sk", "sv")}
-    if cfg.kv_dtype == "int8":
-        s3 = _ns(mesh, *lead, None, "tp", None)
-        return {"k_q": s4, "v_q": s4, "k_s": s3, "v_s": s3}
-    return {"k": s4, "v": s4}
+    return {name: _ns(mesh, *lead, *axes)
+            for name, axes in cache_of(cfg).shardings(cfg).items()}
 
 
 def state_shardings(cfg: ModelConfig, mesh: Mesh, batched: bool = False) -> dict:

@@ -1499,9 +1499,9 @@ def create_app(engine=None, settings: Settings | None = None,
             occ = getattr(eng, "kv_pool_occupancy", None)
             if callable(occ):
                 engine_info["kv_pool"] = occ()
-            # a cache that is no ring (models/eva.py): its sizes, and the
-            # reuse it does without as a property of the cache; absent on
-            # a ring, whose /health is what it was
+            # a cache that is no ring (``CacheKind.health``): its sizes, and
+            # the reuse it does without as a property of the cache; absent
+            # on a ring, whose /health is what it was
             kind = getattr(eng, "cache_kind", None)
             if kind:
                 engine_info["cache"] = kind
@@ -1603,9 +1603,9 @@ def create_app(engine=None, settings: Settings | None = None,
             if "lane_idle_seconds" in snap:
                 m.set_gauge("lane_idle_seconds", snap["lane_idle_seconds"])
         # the decode steps' read of the KV ring against what was live
-        # (Engine.ring_slots; the lane engine adds at each chunk's harvest)
-        # and the same for a cache that is no ring, each kind under its own
-        # names (Engine.cache_read_gauges)
+        # (Engine.cache_counts; the lane engine adds at each chunk's
+        # harvest) and the same for a cache that is no ring, each kind under
+        # its own names (Engine.cache_read_gauges)
         reads = getattr(app.state.engine, "cache_read_gauges", None)
         for name, value in (reads() if reads is not None else {}).items():
             # a name may carry labels (sparse_queries_total{branch="dense"})

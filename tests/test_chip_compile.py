@@ -88,8 +88,9 @@ def _slice_widths(cfg):
     this block's prompts into: narrow, and the wide one where the block
     takes it (engine/slices.py)."""
     from llama_fastapi_k8s_gpu_tpu.engine.slices import wide_width
+    from llama_fastapi_k8s_gpu_tpu.models.cache import cache_of
 
-    return sorted({256, wide_width(256, cfg.widest_slice)})
+    return sorted({256, wide_width(256, cache_of(cfg).widest_slice(cfg))})
 
 
 def _matmuls(fmt):

@@ -488,12 +488,12 @@ def test_scheduler_stats_surface(cengine):
 
 
 def test_ring_slot_counters_rise_with_the_lanes_positions(cengine):
-    """``ring_slots`` (/metrics ``ring_slots_*_total``): per decode step, summed over the lanes that
+    """``cache_counts`` (/metrics ``ring_slots_*_total``): per decode step, summed over the lanes that
     hold a request, the slots the attention read covered and the slots at
     or below the lane's position (n_ctx 128 is one block here, so a step
     reads 128 a lane).  Computed at harvest from host-held positions."""
     def totals():
-        return cengine.ring_slots["read"], cengine.ring_slots["live"]
+        return cengine.cache_counts["read"], cengine.cache_counts["live"]
 
     deadline = time.time() + 10
     while time.time() < deadline and cengine.scheduler_stats()["lanes_live"]:
@@ -1216,7 +1216,7 @@ def test_a_stop_id_ends_a_lane_on_the_device():
 @pytest.mark.parametrize("block", ["dense", "routed"])
 def test_the_per_step_counters_count_the_steps_run(block, lane_eng,
                                                    tmp_path):
-    """(f) ``_note_ring_read`` (``ring_slots``) and a routed block's expert
+    """(f) ``_note_ring_read`` (``cache_counts``) and a routed block's expert
     statistics count the steps the chunk program ran, not ``n_steps``: one
     request of 9 tokens takes two chunks of 4 steps, and the chunk queued
     behind them runs none."""
@@ -1231,7 +1231,7 @@ def test_the_per_step_counters_count_the_steps_run(block, lane_eng,
                                batch_size=3, decode_chunk=4)
     try:
         before = _quiet(eng)
-        read0 = eng.ring_slots["read"]
+        read0 = eng.cache_counts["read"]
         pairs0 = eng.expert_counters.snapshot(block=True)["layer_steps"] \
             if block == "routed" else 0
         out = eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
@@ -1240,7 +1240,7 @@ def test_the_per_step_counters_count_the_steps_run(block, lane_eng,
         run = after["steps_run"] - before["steps_run"]
         assert run == (8 if n == 9 else run) and run < 12
         # n_ctx 128 is one block of the read: a step reads 128 slots a lane
-        assert eng.ring_slots["read"] - read0 == 128 * run
+        assert eng.cache_counts["read"] - read0 == 128 * run
         if block == "routed":
             snap = eng.expert_counters.snapshot(block=True)
             assert snap["layer_steps"] - pairs0 == eng.cfg.n_layers * run

@@ -633,7 +633,7 @@ out["serial_warmup"] = compiles()
 out["serial_reached"] = long_request(lambda seed, n: eng.create_chat_completion(
     MSGS, temperature=1.0, seed=seed, max_tokens=n)["usage"])
 out["serial_after"] = compiles()
-out["serial_ring"] = dict(eng.ring_slots)
+out["serial_ring"] = dict(eng.cache_counts)
 
 DEVTIME.reset()
 ceng = ContinuousEngine(path, batch_size=2, **KW)
@@ -643,7 +643,7 @@ out["lane_reached"] = long_request(lambda seed, n: ceng.submit(
     MSGS, temperature=1.0, seed=seed, max_tokens=n).result(timeout=300)["usage"])
 time.sleep(0.5)
 out["lane_after"] = compiles()
-out["lane_ring"] = dict(ceng.ring_slots)
+out["lane_ring"] = dict(ceng.cache_counts)
 ceng.shutdown()
 print("PINS " + json.dumps(out))
 """

@@ -459,7 +459,7 @@ def test_a_greedy_probes_text_is_unchanged_by_the_other_lanes_traffic(
             for f in futs:
                 f.result(timeout=300)
             assert probe() == first, round_no
-        assert 0 < eng.ring_slots["live"] <= eng.ring_slots["read"]
+        assert 0 < eng.cache_counts["live"] <= eng.cache_counts["read"]
     finally:
         eng.shutdown()
 
@@ -468,6 +468,16 @@ def test_a_greedy_probes_text_is_unchanged_by_the_other_lanes_traffic(
 # the counters on a schedule the test knows
 # ---------------------------------------------------------------------------
 
+def _counting(cfg):
+    """What the engines' counting needs of an engine: the configuration,
+    its cache kind's object and that kind's counters."""
+    from llama_fastapi_k8s_gpu_tpu.models.cache import cache_of
+
+    kind = cache_of(cfg)
+    return types.SimpleNamespace(cfg=cfg, cache=kind,
+                                 cache_counts=kind.new_counts())
+
+
 def test_the_lane_counters_on_a_known_schedule():
     """``ContinuousEngine._note_ring_read`` on one chunk of 4 steps over 4
     lanes: lane 0 at slot 14, lane 1 at 30 (the bound), lane 2 empty, lane
@@ -475,24 +485,22 @@ def test_the_lane_counters_on_a_known_schedule():
     was dispatched with it live, its position 50 set the bound).  Blocks
     of 16: bounds 50..53 read 4 blocks = 64 slots a lane-step."""
     from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
 
     def slot(n_prompt, n_gens, finished=False):
         return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
                                      finished=finished)
 
-    eng = types.SimpleNamespace(cfg=_cfg(),      # the XLA loop serves it
-                                ring_slots={"read": 0, "live": 0})
-    eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
+    eng = _counting(_cfg())                      # the XLA loop serves it
     pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
     ContinuousEngine._note_ring_read(eng, pre, 4)
-    assert eng.ring_slots == {
+    assert eng.cache_counts == {
         "read": 2 * 4 * 64,
-        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34)}
+        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34),
+        "rows_written": 0}
     # without the finished lane the bound is lane 1's: 30, 31 read 2
     # blocks, 32, 33 read 3
     ContinuousEngine._note_ring_read(eng, pre[:3], 4)
-    assert eng.ring_slots["read"] == 2 * 4 * 64 + 2 * (32 + 32 + 48 + 48)
+    assert eng.cache_counts["read"] == 2 * 4 * 64 + 2 * (32 + 32 + 48 + 48)
 
 
 def test_the_lane_counters_under_the_kernel_are_per_lane():
@@ -502,25 +510,21 @@ def test_the_lane_counters_under_the_kernel_are_per_lane():
     hold: lane 0 at 14..17 reads 16 + 16 + 32 + 32, lane 1 at 30..33
     reads 32 + 32 + 48 + 48."""
     from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
 
     def slot(n_prompt, n_gens, finished=False):
         return types.SimpleNamespace(n_prompt=n_prompt, gens=[0] * n_gens,
                                      finished=finished)
 
-    eng = types.SimpleNamespace(cfg=_cfg(attn_impl="pallas"),
-                                ring_slots={"read": 0, "live": 0},
-                                ring_rows_written=0)
-    eng._note_cache_read = types.MethodType(Engine._note_cache_read, eng)
+    eng = _counting(_cfg(attn_impl="pallas"))
     pre = [slot(10, 5), slot(30, 1), None, slot(41, 10, finished=True)]
     ContinuousEngine._note_ring_read(eng, pre, 4)
-    assert eng.ring_slots == {
-        "read": (16 + 16 + 32 + 32) + (32 + 32 + 48 + 48),
-        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34)}
     # the kernel stored a K row for each of the THREE lanes the chunk was
     # dispatched with as live (the finished one too: the device does not
     # know yet), in 4 steps x 3 layers; the empty lane stored nothing
-    assert eng.ring_rows_written == 3 * 4 * 3
+    assert eng.cache_counts == {
+        "read": (16 + 16 + 32 + 32) + (32 + 32 + 48 + 48),
+        "live": (15 + 16 + 17 + 18) + (31 + 32 + 33 + 34),
+        "rows_written": 3 * 4 * 3}
 
 
 @pytest.mark.parametrize("cfg_kw,who", [
@@ -546,12 +550,10 @@ def test_rows_written_count_only_where_the_kernel_writes():
     from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
 
     for impl, rows in (("pallas", 2 * 5 * 3), ("xla", 0)):
-        eng = types.SimpleNamespace(cfg=_cfg(attn_impl=impl),
-                                    ring_slots={"read": 0, "live": 0},
-                                    ring_rows_written=0)
-        Engine._note_cache_read(eng, [7, 40], 5)
-        assert eng.ring_rows_written == rows
-        eng.eva_counts = eng.sala_counts = eng.slice_tokens = {}
+        eng = _counting(_cfg(attn_impl=impl))
+        eng.cache.note_decode(eng.cache_counts, eng.cfg, [7, 40], 5)
+        assert eng.cache_counts["rows_written"] == rows
+        eng.slice_tokens = {}
         eng.tokenizer = None                # no memo of pieces to count
         assert Engine.cache_read_gauges(eng)["ring_rows_written_total"] == rows
 
@@ -578,15 +580,15 @@ def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
         probe_msgs, temperature=0.0, max_tokens=12)[
             "choices"][0]["message"]["content"]
     assert text
-    assert serial.ring_slots["read"] % BLOCK == 0
-    assert serial.ring_slots["live"] / serial.ring_slots["read"] > 0.6
+    assert serial.cache_counts["read"] % BLOCK == 0
+    assert serial.cache_counts["live"] / serial.cache_counts["read"] > 0.6
     # the kernel stored every decode step's row itself: a K row a layer
     layers = serial.cfg.n_layers
     assert llama.ring_write_impl(serial.cfg) == "kernel"
-    assert serial.ring_rows_written > 0
-    assert serial.ring_rows_written % layers == 0
+    assert serial.cache_counts["rows_written"] > 0
+    assert serial.cache_counts["rows_written"] % layers == 0
     assert serial.cache_read_gauges()["ring_rows_written_total"] \
-        == serial.ring_rows_written
+        == serial.cache_counts["rows_written"]
 
     eng = ContinuousEngine(path, batch_size=3, dp=1, **kw)   # no mesh
 
@@ -606,12 +608,12 @@ def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
         for f in futs:
             f.result(timeout=300)
         assert probe() == first             # beside freed lanes
-        read, live = eng.ring_slots["read"], eng.ring_slots["live"]
+        read, live = eng.cache_counts["read"], eng.cache_counts["live"]
         assert read % BLOCK == 0 and 0 < live <= read
         assert live / read > 0.6
-        assert eng.ring_rows_written > 0
+        assert eng.cache_counts["rows_written"] > 0
         # lanes dispatched live x steps RUN x layers (a chunk stops where
         # none of its lanes has anything left to decode)
-        assert eng.ring_rows_written % layers == 0
+        assert eng.cache_counts["rows_written"] % layers == 0
     finally:
         eng.shutdown()

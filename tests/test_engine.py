@@ -475,18 +475,18 @@ def test_explicit_seed_bypasses_prefix_reuse(prefix_model):
 
 
 def test_ring_slot_counters_follow_the_decode_positions(engine):
-    """``Engine.ring_slots`` (/metrics ``ring_slots_*_total``): every decode
+    """``Engine.cache_counts`` (/metrics ``ring_slots_*_total``): every decode
     step adds the slots its attention read covered (whole blocks up to the
     position; n_ctx 128 is one block here) and the slots at or below the
     position, from the host-tracked position of each chunk's first step."""
-    before = dict(engine.ring_slots)
+    before = dict(engine.cache_counts)
     out = engine.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
     n_prompt = out["usage"]["prompt_tokens"]
     assert out["usage"]["completion_tokens"] == 9
     # token 1 is sampled from prefill; tokens 2..9 are two chunks of 4
     # steps at positions n_prompt .. n_prompt + 7
-    assert engine.ring_slots["read"] - before["read"] == 8 * 128
-    assert engine.ring_slots["live"] - before["live"] == sum(
+    assert engine.cache_counts["read"] - before["read"] == 8 * 128
+    assert engine.cache_counts["live"] - before["live"] == sum(
         n_prompt + t + 1 for t in range(8))
 
 
@@ -495,4 +495,4 @@ def test_ring_slot_counters_are_in_the_catalog(engine):
 
     assert METRICS["ring_slots_read_total"].mtype == GAUGE
     assert METRICS["ring_slots_live_total"].mtype == GAUGE
-    assert set(engine.ring_slots) == {"read", "live"}
+    assert set(engine.cache_counts) == {"read", "live", "rows_written"}

@@ -515,11 +515,11 @@ def test_serial_engine_serves_and_counts(engine):
     """Prompt 100-odd bytes (one window closed in prefill), 100 decoded
     (another mid-decode): the counters are the host's arithmetic, the ring's
     stay 0, reuse finds nothing, and /health's block says why."""
-    before = dict(engine.eva_counts)
+    before = dict(engine.cache_counts)
     r = engine.create_chat_completion(MSG, max_tokens=100, temperature=0.0)
     n = r["usage"]["prompt_tokens"]
     assert 64 < n < 128 and r["usage"]["completion_tokens"] == 100
-    d = {k: engine.eva_counts[k] - before[k] for k in before}
+    d = {k: engine.cache_counts[k] - before[k] for k in before}
     steps = 100                       # 25 chunks of 4, the first token aside
     # window 0 by the prompt, then the steps that write 127 and 191
     assert d["windows_closed"] == 1 + sum(
@@ -529,7 +529,7 @@ def test_serial_engine_serves_and_counts(engine):
     assert d["window_live"] == sum((n + t) % 64 + 1 for t in range(steps))
     assert d["summaries_read"] == d["summaries_live"] \
         == sum((n + t) // 64 * 16 for t in range(steps))
-    assert engine.ring_slots == {"read": 0, "live": 0}
+    assert d["read"] == d["live"] == d["rows_written"] == 0
     assert engine._prefix_reuse_len(list(range(200)), 200, 256) == 0
     kind = engine.cache_kind
     assert kind["kind"] == "window+summaries" and kind["summaries"] == 64
@@ -574,11 +574,11 @@ def test_lane_engine_serves_and_frees_lanes(gguf_path):
         # (greedy on random weights may sample the end-of-text token)
         assert all(1 <= r["usage"]["completion_tokens"] <= 90 for r in out)
         assert sum(r["usage"]["completion_tokens"] for r in out) > 150
-        c = eng.eva_counts
+        c = eng.cache_counts
         assert c["windows_closed"] >= 3
         assert 0 < c["window_live"] <= c["window_read"]
         assert 0 < c["summaries_live"] <= c["summaries_read"]
-        assert eng.ring_slots == {"read": 0, "live": 0}
+        assert c["read"] == c["live"] == c["rows_written"] == 0
         assert eng._lane_prefix is False
         assert eng._find_lane_reuse(list(range(100)), 100) == (0, None)
     finally:

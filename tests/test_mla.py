@@ -866,7 +866,7 @@ def test_callers_that_arrive_together_ride_the_first_ones_prompt(gguf_path):
     (False, 2 * 4 * 3072, 0, "xla"),
 ])
 def test_the_read_counters_follow_who_reads(kernel, read, rows, who):
-    """``Engine._note_cache_read`` on a ``latent-ring`` configuration, one
+    """``CacheKind.note_decode`` on a ``latent-ring`` configuration, one
     chunk of 4 steps: lanes at 100 and 1500 wanted, a third at 3000
     dispatched live but finished.  ``/health`` ``engine.ring_write`` names
     who reads and stores a step's row; ``engine.cache`` and ``attn_impl``
@@ -875,17 +875,20 @@ def test_the_read_counters_follow_who_reads(kernel, read, rows, who):
     import types
 
     from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.models import mla
     from llama_fastapi_k8s_gpu_tpu.server.app import _ring_write
     from llama_fastapi_k8s_gpu_tpu.testing import TINY_MLA_CFG
 
     cfg = dataclasses.replace(TINY_MLA_CFG, n_ctx=4096, latent_kernel=kernel)
-    eng = types.SimpleNamespace(cfg=cfg, ring_slots={"read": 0, "live": 0},
-                                ring_rows_written=0, _prefix_cache=None)
-    Engine._note_cache_read(eng, [100, 1500], 4, live=[100, 1500, 3000])
-    assert eng.ring_slots == {
+    eng = types.SimpleNamespace(cfg=cfg, cache=mla.CACHE,
+                                cache_counts=mla.CACHE.new_counts(),
+                                _prefix_cache=None)
+    mla.CACHE.note_decode(eng.cache_counts, cfg, [100, 1500], 4,
+                          live=[100, 1500, 3000])
+    assert eng.cache_counts == {
         "read": read,
-        "live": sum(range(101, 105)) + sum(range(1501, 1505))}
-    assert eng.ring_rows_written == rows
+        "live": sum(range(101, 105)) + sum(range(1501, 1505)),
+        "rows_written": rows}
     assert _ring_write(cfg) == who and cfg.attn_impl == "xla"
     assert Engine.cache_kind.fget(eng)["read"] == "absorbed, blocks of 512"
 

@@ -49,6 +49,7 @@ Limits, with their reasons and the controls that fail them:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -352,6 +353,24 @@ def test_chunk_form_against_recurrence():
             assert rel(s_out, state) < SAME
 
 
+STATE_SLOPE = (0.8, 0.1, 1e-3, 1e-5)
+
+
+@functools.cache
+def _state_step_over_lanes():
+    """The kernel under ``vmap`` over lanes, layer 1 of the leaf: ONE
+    program for every case below (what is live is an operand), compiled
+    once a worker."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.linstate import lin_state_step
+
+    decay = jnp.exp(-jnp.asarray(STATE_SLOPE, jnp.float32))
+    return jax.jit(jax.vmap(lambda q, k, v, s, lv: lin_state_step(
+        q, k, v, s, jnp.int32(1), lv, decay, interpret=True)))
+
+
 @pytest.mark.parametrize("live", [
     (True, True, True, True), (True, False, True, True),
     (False, False, True, False), (False, False, False, False)])
@@ -360,22 +379,21 @@ def test_the_state_kernel_is_the_recurrence_and_skips_dead_lanes(live):
     chip in ``tests/test_chip_compile.py``) under ``vmap`` over lanes
     against ``lin_step``: a live lane's state and output are the
     recurrence's, a dead lane's state is bit for bit what it was and its
-    output 0, and no other layer of the leaf is touched."""
-    import jax
+    output 0, and no other layer of the leaf is touched.  The 0 is the
+    kernel's own store (``sala.lin_layer`` adds a dead lane's output to
+    its stream): interpret mode hands a kernel its outputs filled with
+    NaN, so an output block the kernel left alone would fail here."""
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.models import sala
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.linstate import lin_state_step
 
     rng = np.random.default_rng(0)
     B, L, H, hd = 4, 3, 4, 128
     q, k, v = (jnp.asarray(rng.standard_normal((B, H, hd)), jnp.bfloat16)
                for _ in range(3))
     state = jnp.asarray(rng.standard_normal((B, L, H, hd, hd)), jnp.float32)
-    slope = jnp.asarray([0.8, 0.1, 1e-3, 1e-5], jnp.float32)
-    o, new = jax.jit(jax.vmap(lambda q, k, v, s, lv: lin_state_step(
-        q, k, v, s, jnp.int32(1), lv, jnp.exp(-slope), interpret=True)))(
-            q, k, v, state, jnp.asarray(live))
+    slope = jnp.asarray(STATE_SLOPE, jnp.float32)
+    o, new = _state_step_over_lanes()(q, k, v, state, jnp.asarray(live))
     for b in range(B):
         if live[b]:
             want_o, want_s = sala.lin_step(q[b], k[b], v[b], state[b, 1],
@@ -711,7 +729,7 @@ def engine(gguf_path):
 
 
 def test_serial_engine_serves_counts_and_says_what_it_holds(engine):
-    before = dict(engine.sala_counts)
+    before = dict(engine.cache_counts)
     out = engine.create_chat_completion(MSGS, max_tokens=24, temperature=0.0)
     n_prompt = out["usage"]["prompt_tokens"]
     assert n_prompt > 48 and out["usage"]["completion_tokens"] >= 1
@@ -722,13 +740,13 @@ def test_serial_engine_serves_counts_and_says_what_it_holds(engine):
     assert kind["kv_paged"] == "refused at start"
     assert kind["chat_template"] == "mistral"
     assert not engine._prefix_cache
-    got = {k: engine.sala_counts[k] - before[k] for k in before}
+    got = {k: engine.cache_counts[k] - before[k] for k in before}
     assert got["queries_sparse"] > 0 and got["queries_dense"] == 4 * 47
     assert 0 < got["blocks_read"] < got["blocks_visible"]
     assert got["state_updates"] % 4 == 0 and got["kc_written"] > 0
     gauges = engine.cache_read_gauges()
     assert gauges['sparse_queries_total{branch="sparse"}'] \
-        == engine.sala_counts["queries_sparse"]
+        == engine.cache_counts["queries_sparse"]
     # the same request again: the state starts from nothing, so the same
     # greedy text (a state kept from the last request would change it)
     again = engine.create_chat_completion(MSGS, max_tokens=24,
@@ -749,9 +767,9 @@ def test_lane_engine_serves_and_takes_freed_lanes_again(gguf_path, engine):
         outs = [f.result(timeout=300) for f in futs]
         assert all(o["usage"]["completion_tokens"]
                    == want["usage"]["completion_tokens"] for o in outs)
-        assert eng.sala_counts["state_updates"] > 0
-        assert eng.sala_counts["blocks_read"] \
-            < eng.sala_counts["blocks_visible"]
+        assert eng.cache_counts["state_updates"] > 0
+        assert eng.cache_counts["blocks_read"] \
+            < eng.cache_counts["blocks_visible"]
     finally:
         eng.shutdown()
 
