@@ -168,10 +168,25 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``attention.sliding_window_pattern`` (n: layer i is a window layer
 #: unless (i + 1) % n == 0, llama.cpp's ``set_swa_pattern``).  Window
 #: layers rotate Q and K (rotate-half), global layers do not.
+#: ``lfm2moe`` (llama.cpp's name for the LFM2 mixture-of-experts family as
+#: remembered; models/lfm2.py) is a MIXER kind per layer over the sixth cache
+#: kind, a gated short convolution or GQA, and ``deepseek2``'s feed-forward
+#: kinds with no shared expert.  Tensors: a conv layer's
+#: ``blk.N.shortconv.in_proj`` (3 x dim, dim: the rows of b, c, x in that
+#: order), ``shortconv.conv`` (dim, l_cache: F32 depthwise taps, oldest
+#: first) and ``shortconv.out_proj`` (dim, dim); an attention layer's
+#: ``attn_{q,k,v,output}`` and ``attn_{q,k}_norm`` (a head's width); every
+#: layer's ``attn_norm`` (the family's ``operator_norm``) and ``ffn_norm``;
+#: the feed-forward tensors of ``deepseek2`` without the ``_shexp`` ones;
+#: ``token_embd_norm`` is the FINAL norm and there is no ``output.weight``
+#: (the head is the embedding).  Keys: ``shortconv.l_cache`` (taps),
+#: ``attention.head_count_kv`` as an ARRAY with one entry a layer, 0 in a
+#: conv layer; ``leading_dense_block_count`` ... ``expert_gating_func`` as
+#: above.  Q and K rotate on halves.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
-                        "deepseek2", "exaone-moe")
+                        "deepseek2", "exaone-moe", "lfm2moe")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
@@ -180,7 +195,7 @@ SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
 #: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
 #: the whole projection.
 NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte", "minicpm-sala",
-                           "exaone-moe")
+                           "exaone-moe", "lfm2moe")
 
 
 def align_up(n: int, alignment: int) -> int:

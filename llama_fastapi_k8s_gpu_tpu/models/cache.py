@@ -15,8 +15,8 @@ import types
 from typing import Callable, Mapping
 
 from .config import (
-    LATENT_RING, RING, STATE_RING, WINDOW_GLOBAL_RING, WINDOW_SUMMARIES,
-    ModelConfig)
+    CONV_RING, LATENT_RING, RING, STATE_RING, WINDOW_GLOBAL_RING,
+    WINDOW_SUMMARIES, ModelConfig)
 
 #: what an engine can ASK of a cache kind, in the order the asks are checked
 #: (a mesh with no place for the cache at all comes first), each with the
@@ -113,7 +113,8 @@ class CacheKind:
 
 #: the module that holds each kind's ``CACHE``
 _MODULES = {RING: "llama", WINDOW_SUMMARIES: "eva", STATE_RING: "sala",
-            LATENT_RING: "mla", WINDOW_GLOBAL_RING: "hybrid"}
+            LATENT_RING: "mla", WINDOW_GLOBAL_RING: "hybrid",
+            CONV_RING: "lfm2"}
 
 
 def cache_of(cfg: ModelConfig) -> CacheKind:

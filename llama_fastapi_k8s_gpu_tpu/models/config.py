@@ -19,9 +19,13 @@ from ..gguf.constants import NEOX_ROPE_ARCHITECTURES
 RING, WINDOW_SUMMARIES, STATE_RING = "ring", "window+summaries", "state+ring"
 LATENT_RING = "latent-ring"
 WINDOW_GLOBAL_RING = "window+global-ring"
+CONV_RING = "conv-state+ring"
 
 #: the attention kinds of a layer (``ModelConfig.attn_kinds``)
 WINDOW, GLOBAL = "window", "global"
+
+#: the mixer kinds of a ``lfm2moe`` layer (``ModelConfig.mixers``)
+CONV, ATTN = "conv", "attn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,8 +88,12 @@ class ModelConfig:
     # a decaying float32 state per head) or ``"sp"`` (block-sparse attention
     # on the ring of ``n_kv_heads`` heads); empty for every other file.
     # ``n_heads`` / ``n_kv_heads`` are the sparse layers', ``lin_heads`` the
-    # linear layers' (of ``head_dim`` too).
+    # linear layers' (of ``head_dim`` too).  A ``lfm2moe`` file
+    # (models/lfm2.py; ``conv_l_cache`` taps) names its layers ``"conv"`` (a
+    # gated short convolution, whose cache is the last ``conv_l_cache - 1``
+    # inputs of its taps) or ``"attn"`` (GQA on a ring) here too.
     mixers: tuple = ()
+    conv_l_cache: int = 0
     lin_heads: int = 0
     # the family's three scalars, as applied: on the embedding, on every
     # branch before it is added to the stream, on the final norm's output
@@ -152,6 +160,9 @@ class ModelConfig:
     n_expert_groups: int = 1
     n_groups_used: int = 1
     expert_weights_scale: float = 1.0
+    # what is added to the picked scores' sum before the weights are divided
+    # by it (``norm_topk_prob``): the file's architecture states it
+    expert_weights_eps: float = 1e-20
     # The experts HELD here, of the router's ``n_experts``: ``experts_held``
     # from ``experts_first`` on (0: all).  A pick outside them adds nothing
     # (expert parallelism's share of a layer, without its exchange).
@@ -172,6 +183,14 @@ class ModelConfig:
     # projection)
     head_width: int = 0
     qk_norm_per_head: bool = False
+    # the softmax scale where it is not ``head_dim ** -0.5`` (0: that): a
+    # ring whose rows hold several narrow heads side by side is read as
+    # heads of the row's width, at the narrow heads' scale (models/lfm2.py)
+    attn_scale: float = 0.0
+
+    @property
+    def sm_scale(self) -> float:
+        return self.attn_scale or self.head_dim ** -0.5
 
     @property
     def n_held(self) -> int:
@@ -198,8 +217,11 @@ class ModelConfig:
         (docs/KV_CACHE.md "Cache kinds"), decided here and nowhere else:
         ``ring``, ``window+summaries`` (models/eva.py), ``state+ring``
         (models/sala.py), ``latent-ring`` (models/mla.py) or
-        ``window+global-ring`` (models/hybrid.py); models/cache.py
-        ``cache_of`` maps it to the kind's object, nothing else tests it."""
+        ``window+global-ring`` (models/hybrid.py) or ``conv-state+ring``
+        (models/lfm2.py); models/cache.py ``cache_of`` maps it to the kind's
+        object, nothing else tests it."""
+        if self.conv_l_cache:
+            return CONV_RING
         if self.mixers:
             return STATE_RING
         if self.attn_kinds:
@@ -215,7 +237,7 @@ class ModelConfig:
     def n_linear_weights(self) -> int:
         """About how many weights the layers' matrices hold, every expert
         included: what the ``weight_format="auto"`` size test weighs."""
-        if self.kv_lora_rank or self.attn_kinds:
+        if self.kv_lora_rank or self.attn_kinds or self.conv_l_cache:
             routed = 3 * self.dim * self.expert_ffn_dim * (
                 self.n_held + self.n_shared_experts)
             return self.n_layers * 4 * self.dim * self.dim \
@@ -234,7 +256,12 @@ class ModelConfig:
             vocab = len(gf.metadata["tokenizer.ggml.tokens"])
         window = int(h("attention.sliding_window", 0) or 0)
         train_ctx = int(h("context_length", 4096))
-        n_kv_heads = int(h("attention.head_count_kv", n_heads))
+        n_kv_heads = h("attention.head_count_kv", n_heads)
+        mla = {}
+        if arch == "lfm2moe":   # one entry a layer, 0 in a conv layer
+            mla = _lfm2moe_fields(h, n_heads, n_kv_heads)
+            n_kv_heads = max(n_kv_heads)
+        n_kv_heads = int(n_kv_heads)
         eva = {}
         if arch == "evabyte":
             eva = dict(
@@ -290,7 +317,6 @@ class ModelConfig:
                 raise ValueError(
                     "minicpm-sala: lightning.head_count x head width must "
                     "be the embedding length")
-        mla = {}
         if arch == "deepseek2":
             mla = _deepseek2_fields(h, n_heads)
         if arch == "exaone-moe":
@@ -313,7 +339,7 @@ class ModelConfig:
             # build_norm over the whole Qcur/Kcur; deepseek2 reads its key
             norm_topk_prob=mla.pop("norm_topk_prob", False),
             qk_norm=arch == "olmoe",
-            qk_norm_per_head=arch == "exaone-moe",
+            qk_norm_per_head=arch in ("exaone-moe", "lfm2moe"),
             rope_neox=arch in NEOX_ROPE_ARCHITECTURES,
             **eva,
             **sala,
@@ -401,6 +427,39 @@ def _routed_fields(h, arch: str) -> dict:
         expert_weights_scale=float(h("expert_weights_scale", 1.0) or 1.0),
         norm_topk_prob=bool(h("expert_weights_norm", False)),
         experts_first=first, experts_held=held)
+
+
+def _lfm2moe_fields(h, n_heads: int, kv_heads) -> dict:
+    """The ``lfm2moe`` keys (gguf/constants.py) as ``ModelConfig`` fields; a
+    ValueError naming what the block here cannot compute."""
+    n_layers = int(h("block_count"))
+    taps = int(h("shortconv.l_cache", 0) or 0)
+    if not isinstance(kv_heads, (list, tuple)) or len(kv_heads) != n_layers:
+        raise ValueError(
+            "lfm2moe: attention.head_count_kv must be an array with one "
+            f"entry for each of the {n_layers} layers (0: a conv layer)")
+    counts = {int(n) for n in kv_heads} - {0}
+    if len(counts) != 1 or n_heads % max(counts):
+        raise ValueError(
+            f"lfm2moe: the attention layers' KV heads {sorted(counts)} must "
+            f"be one count that divides the {n_heads} heads")
+    if taps < 2:
+        raise ValueError(
+            f"lfm2moe: shortconv.l_cache {taps}: a conv layer has two taps "
+            "or more (its cache is the inputs of all but the newest)")
+    if str(h("rope.scaling.type", "none")) not in ("none", "linear") \
+            or float(h("rope.scaling.factor", 1.0) or 1.0) != 1.0:
+        raise ValueError("lfm2moe: rope.scaling is not served")
+    routed = _routed_fields(h, "lfm2moe")
+    if routed["n_shared_experts"] or routed["experts_held"]:
+        raise ValueError(
+            "lfm2moe: a shared expert or a held share of the experts is not "
+            "this architecture's")
+    return dict(mixers=tuple(ATTN if int(n) else CONV for n in kv_heads),
+                conv_l_cache=taps,
+                head_width=int(h("attention.key_length", 0) or 0),
+                # the family's router divides by the picked scores' sum + 1e-6
+                expert_weights_eps=1e-6, **routed)
 
 
 def _exaone_moe_fields(h, n_heads: int, window: int) -> dict:

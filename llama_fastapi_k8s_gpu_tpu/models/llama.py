@@ -158,12 +158,12 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
             scores = jnp.einsum(
                 "ngsh,nch->ngsc", qg, kk.astype(qg.dtype),
                 preferred_element_type=jnp.float32,
-            ) * (hd ** -0.5) * cks[:, None, None, :]
+            ) * cfg.sm_scale * cks[:, None, None, :]
     else:
         with jax.named_scope("attn_scores"):
             scores = jnp.einsum(
                 "ngsh,nch->ngsc", qg, kk, preferred_element_type=jnp.float32
-            ) * (hd ** -0.5)  # (n_kv, group, S, n_ctx)
+            ) * cfg.sm_scale  # (n_kv, group, S, n_ctx)
 
     key_pos = jnp.arange(cfg.n_ctx)
     q_pos = positions  # (S,)
@@ -339,7 +339,7 @@ def decode_attention(q, cache, i, pos, bound, cfg: ModelConfig, out_dtype):
         with jax.named_scope("attn_scores"):
             s = jnp.einsum("ngsh,nch->ngsc", qg, kb.astype(qg.dtype),
                            preferred_element_type=jnp.float32
-                           ) * (hd ** -0.5)           # (n_kv, group, 1, T)
+                           ) * cfg.sm_scale           # (n_kv, group, 1, T)
         if quant:
             ksb = jax.lax.dynamic_slice(
                 cache["k_s"], (i, 0, at), (1, n_kv, T))[0]
@@ -410,7 +410,7 @@ def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
     out = flash_attention_decode(
         q[0], cache["k"], cache["v"], i, pos,
         True if live is None else live,
-        sm_scale=cfg.head_dim ** -0.5,
+        sm_scale=cfg.sm_scale,
         block_k=ring_kernel_block(cfg),
         sliding_window=cfg.sliding_window,
         interpret=use_interpret(),
@@ -444,7 +444,7 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
         attn = ring_attention if S > 1 else sharded_decode_attention
         ctx = attn(
             q, ck, cv, pos_offset,
-            sm_scale=hd ** -0.5,
+            sm_scale=cfg.sm_scale,
             sliding_window=cfg.sliding_window,
         ).reshape(S, cfg.n_heads * hd).astype(dtype)
     elif cfg.attn_impl == "pallas" and S > 1:
@@ -454,7 +454,7 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
 
         ctx = flash_attention(
             q, ck, cv, pos_offset,
-            sm_scale=hd ** -0.5,
+            sm_scale=cfg.sm_scale,
             sliding_window=cfg.sliding_window,
             k_scale=cks,
             v_scale=cvs,

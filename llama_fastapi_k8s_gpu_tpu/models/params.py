@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gguf import GGUFFile
+from ..obs.devtime import timed_jit
 from ..ops import make_linear_bf16, make_linear_int8, make_linear_int8_device
 from ..ops.linear import padded_k
 from .config import ModelConfig
@@ -78,6 +79,19 @@ def _tensor_to_device(t, dtype=jnp.float32) -> jax.Array:
     return flat.reshape(tuple(reversed(t.shape)))
 
 
+#: a stacked leaf of this many bytes or more is stacked by ONE program.
+#: ``jnp.stack`` outside a program first writes every input again with its
+#: new leading axis, so beside the inputs and the result the device holds a
+#: third copy: 2.4 GB more for the 64 x 18 expert planes of an ``lfm2moe``
+#: file, whose load then peaks at 16.58 GB of a chip's 16.9 (PERF.md
+#: section 6, PR 49).  Smaller leaves keep the call that needs no compile.
+_ONE_PROGRAM_STACK_BYTES = 2 << 30
+
+_stack_in_one_program = timed_jit(
+    "load_stack", jax.jit(lambda *leaves: jnp.stack(leaves)),
+    site="models.params")
+
+
 def _stack(dicts: list[dict], free: bool = False) -> dict:
     """List of identically-keyed (possibly nested) dicts → dict of stacked
     arrays.  ``free=True`` drops each per-layer ref as soon as its stacked
@@ -91,7 +105,10 @@ def _stack(dicts: list[dict], free: bool = False) -> dict:
         if isinstance(vals[0], dict):
             out[key] = _stack(vals, free=free)
         else:
-            out[key] = jnp.stack(vals)
+            big = free and sum(v.nbytes for v in vals) \
+                >= _ONE_PROGRAM_STACK_BYTES
+            out[key] = _stack_in_one_program(*vals) if big \
+                else jnp.stack(vals)
             if free:
                 del vals
                 for d in dicts:
@@ -148,6 +165,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             else (GGMLType.Q4_K, GGMLType.Q5_K, GGMLType.Q6_K, GGMLType.Q8_0)
         k_rank = {GGMLType.Q4_K: 0, GGMLType.Q5_K: 1, GGMLType.Q6_K: 2}
         from ..ops.pallas.experts import experts_compatible
+        from ..ops.pallas.experts import padded_k as experts_padded_k
 
         if names is None:
             names = ["attn_q", "attn_k", "attn_v", "attn_output"]
@@ -162,7 +180,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             ts = [gf[f"blk.{i}.{n}.weight"] for i in layer_ids]
             # an expert stack (E, out, in): the grouped kernels' two types
             fits, allowed = (
-                lambda n_out, k_in: experts_compatible(n_out, padded_k(k_in)),
+                lambda n_out, k_in: experts_compatible(
+                    n_out, experts_padded_k(k_in)),
                 [t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
                 if n.endswith("_exps") else (fits_padded, fusable)
             if not all(fits((rows or {}).get(n, t.shape[1]), t.shape[0])
@@ -183,10 +202,12 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             ok["output"] = t.ggml_type
         return ok
 
-    # (a ``deepseek2`` or ``exaone-moe`` file's layers fuse by kind, in
-    # ``ffn_kind_layers``: here its output head alone)
+    # (a ``deepseek2``, ``exaone-moe`` or ``lfm2moe`` file's layers fuse by
+    # kind, in ``ffn_kind_layers`` / ``mixer_ffn_layers``: here its output
+    # head alone)
     by_ffn_kind = bool(cfg.kv_lora_rank or cfg.attn_kinds)
-    fused_names = _fused_names([] if by_ffn_kind else None) \
+    fused_names = _fused_names(
+        [] if by_ffn_kind or cfg.conv_l_cache else None) \
         if fmt == "q4k" else {}
 
     import time as _time
@@ -258,12 +279,13 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         target = fused_names.get(name.split(".")[-2])
         if target is not None:
             from ..gguf.quants import quantize
+            from ..ops.pallas.experts import padded_k as experts_padded_k
             from ..ops.pallas.experts import prep_experts
 
             k_in, n_out, n_exp = t.shape
             raw = np.asarray(t.raw()) if t.ggml_type == target \
                 else quantize(t.astype_f32(), target)   # K-quant promotion
-            k_pad = padded_k(k_in)
+            k_pad = experts_padded_k(k_in)
             if k_pad != k_in:   # zero blocks fill a row's last K tile
                 raw = raw.reshape(n_exp * n_out, -1)
                 raw = np.pad(raw, ((0, 0), (
@@ -380,9 +402,70 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 out[kind].append(layer)
         return {k: v for k, v in out.items() if v}
 
+    def mixer_ffn_layers() -> dict:
+        """A ``lfm2moe`` file (models/lfm2.py): FOUR stacks, a layer's
+        mixer tensors under its mixer kind (``conv`` | ``attn``, with the
+        layer's ``attn_norm``) and its feed-forward tensors under its
+        feed-forward kind (``dense`` | ``moe``, with its ``ffn_norm``); a
+        name fuses by the types of ITS kind's layers.  Nothing is
+        requantized: a matrix no fused kernel takes is served bf16."""
+        from .config import ATTN, CONV
+        from .routed import DENSE, MOE
+
+        mats = {
+            CONV: {"in_proj": "shortconv.in_proj",
+                   "out_proj": "shortconv.out_proj"},
+            ATTN: {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
+                   "wo": "attn_output"},
+            DENSE: {"w_gate": "ffn_gate", "w_up": "ffn_up",
+                    "w_down": "ffn_down"},
+            MOE: {}}
+        f32s = {
+            CONV: {"attn_norm": "attn_norm.weight",
+                   "conv": "shortconv.conv.weight"},
+            ATTN: {"attn_norm": "attn_norm.weight",
+                   "attn_q_norm": "attn_q_norm.weight",
+                   "attn_k_norm": "attn_k_norm.weight"},
+            DENSE: {"ffn_norm": "ffn_norm.weight"},
+            MOE: {"ffn_norm": "ffn_norm.weight",
+                  "w_router": "ffn_gate_inp.weight",
+                  "router_bias": "exp_probs_b.bias"}}
+        ids = {kind: [i for i, m in enumerate(cfg.mixers) if m == kind]
+               for kind in (CONV, ATTN)}
+        ids[DENSE] = list(range(cfg.n_dense_layers))
+        ids[MOE] = list(range(cfg.n_dense_layers, cfg.n_layers))
+        out = {}
+        for kind, mine in ids.items():
+            exps = ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"] \
+                if kind == MOE and fused_experts else []
+            # ``_fused_names`` reads a name's last dotted part but one
+            fused = _fused_names(list(mats[kind].values()) + exps, mine) \
+                if fmt == "q4k" and mine else {}
+            fused = {name.split(".")[-1]: t for name, t in fused.items()}
+            out[kind] = []
+            for i in mine:
+                p = f"blk.{i}."
+                layer = {}
+                for key, name in mats[kind].items():
+                    if fmt == "q4k" and name.split(".")[-1] not in fused:
+                        layer[key] = {"w": as_bf16(gf[p + name + ".weight"])}
+                    else:
+                        layer[key] = lin(p + name + ".weight", fused)
+                for key, name in f32s[kind].items():
+                    layer[key] = norm(p + name)
+                if kind == MOE:
+                    for key in ("gate", "up", "down"):
+                        layer[f"w_{key}_exps"] = experts(
+                            p + f"ffn_{key}_exps.weight", fused)
+                if overlap:
+                    layer = jax.tree.map(jax.device_put, layer)
+                out[kind].append(layer)
+        return {k: v for k, v in out.items() if v}
+
     layers = []
     t_prep = _time.time()
-    by_kind = kinds_layers() if cfg.mixers else \
+    by_kind = mixer_ffn_layers() if cfg.conv_l_cache else \
+        kinds_layers() if cfg.mixers else \
         ffn_kind_layers() if by_ffn_kind else None
     for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."
@@ -439,7 +522,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     return {
         "tok_emb": emb,
         "layers": stacked,
-        "out_norm": norm("output_norm.weight"),
+        # (``lfm2moe`` files name their FINAL norm ``token_embd_norm``)
+        "out_norm": norm("output_norm.weight" if "output_norm.weight"
+                         in gf.tensors else "token_embd_norm.weight"),
         "output": output,
     }
 

@@ -31,10 +31,13 @@ def n_moe_layers(cfg: ModelConfig) -> int:
     return cfg.n_layers - cfg.n_dense_layers
 
 
-def check_stacks(params: dict, cfg: ModelConfig) -> None:
+def check_stacks(params: dict, cfg: ModelConfig, more: tuple = ()) -> None:
     """Every stacked leaf of a kind is as deep as the file names layers of
-    that kind (a loop over layer ids would otherwise clamp its gathers)."""
-    for kind, n in ((DENSE, cfg.n_dense_layers), (MOE, n_moe_layers(cfg))):
+    that kind (a loop over layer ids would otherwise clamp its gathers).
+    ``more``: further (kind, layers) stacks beside the two feed-forward
+    kinds' (models/lfm2.py: its mixer kinds')."""
+    for kind, n in ((DENSE, cfg.n_dense_layers), (MOE, n_moe_layers(cfg)),
+                    *more):
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 params["layers"].get(kind, {}))[0]:
             if leaf.shape[0] != n:
@@ -50,7 +53,8 @@ def route_grouped(hn, w_router, bias, cfg: ModelConfig):
     CHOICE on ``scores + bias``: a group's score is the sum of its two
     largest, the ``n_groups_used`` best groups are kept, the
     ``n_experts_used`` largest inside them picked; the weights are the
-    picked experts' UNBIASED scores, divided by their sum (+1e-20) where
+    picked experts' UNBIASED scores, divided by their sum (+
+    ``expert_weights_eps``) where
     ``norm_topk_prob``, times ``expert_weights_scale``.  hn (S, dim),
     w_router (E, dim), bias (E,) -> (picks (S, k) int32 in [0, E), weights
     (S, k) f32)."""
@@ -72,7 +76,8 @@ def route_grouped(hn, w_router, bias, cfg: ModelConfig):
     _, picks = jax.lax.top_k(choice, cfg.n_experts_used)
     weights = jnp.take_along_axis(scores, picks, axis=-1)
     if cfg.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + cfg.expert_weights_eps)
     return picks.astype(jnp.int32), weights * cfg.expert_weights_scale
 
 
@@ -96,7 +101,6 @@ def held_picks(picks, cfg: ModelConfig):
     sentinel ``n_held`` ("no pick": ops/pallas/experts.py)."""
     local = picks - cfg.experts_first
     return jnp.where((local >= 0) & (local < cfg.n_held), local, cfg.n_held)
-
 
 
 def swiglu(hn, layers, i, gate, up, down):

@@ -411,6 +411,18 @@ METRICS: dict[str, Metric] = _register(
            "of those, the slots at or below the sequence's own position; "
            "(window + global live) over (window + global read) = the share "
            "of the read that was needed"),
+    # -- the conv-state + ring cache (models/lfm2.py; ``lfm2moe``) ----------
+    Metric("conv_state_updates_total", GAUGE,
+           "updates of a conv layer's carried rows (the last l_cache - 1 "
+           "inputs of its taps) in decode steps: one per step, conv layer "
+           "and lane that holds a request (a serial engine: its one "
+           "sequence), cumulative; a lane that holds none runs the step's "
+           "arithmetic and keeps its rows; from host-tracked positions, "
+           "nothing fetched; exported by a file of that cache kind only "
+           "(its ring_slots_* are summed over the attention layers)"),
+    Metric("conv_state_starts_total", GAUGE,
+           "prefills that began from zero rows: every prompt's, since the "
+           "carried rows cannot be rolled back to a prefix; cumulative"),
     # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
     Metric("latent_positions_read_total", GAUGE,
            "cached latent rows the decode steps' attention covered (whole "

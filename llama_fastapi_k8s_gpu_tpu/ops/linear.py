@@ -101,16 +101,18 @@ def make_linear_q5k(w: np.ndarray) -> dict:
     return prep_q5k(quant_q5_k(w.reshape(-1)), n_out, k_in)
 
 
-def padded_k(k_in: int) -> int:
+def padded_k(k_in: int, share: int = 4) -> int:
     """The K a fused layout of a ``k_in``-wide matrix is stored at: the next
     multiple of the kernels' K tile where that adds at most a quarter
     (11008 -> 12288: the loader fills the last tile up with zero blocks and
     :func:`linear` the activations with zeros), else ``k_in`` itself (so
-    narrow a matrix is no fused kernel's shape)."""
+    narrow a matrix is no fused kernel's shape).  ``share``: the most the
+    fill may add, as ``k_in / share`` (the grouped expert kernels take a
+    third: ops/pallas/experts.py ``padded_k``)."""
     from .pallas.qmatmul import TK
 
     k_pad = -(-k_in // TK) * TK
-    return k_pad if 4 * (k_pad - k_in) <= k_in else k_in
+    return k_pad if share * (k_pad - k_in) <= k_in else k_in
 
 
 def _pad_k(x: jax.Array) -> jax.Array:

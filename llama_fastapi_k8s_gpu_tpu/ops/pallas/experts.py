@@ -33,7 +33,15 @@ divisor of 2048 (OLMoE's down projection: K = 1024) is *folded*: ``f =
 and each activation row is offered ``f`` times, shifted into segment ``j``
 of the wider row, so that copy ``j`` yields outputs ``f * n + j``.  The
 weight bytes read stay the file's; the MXU does ``f`` times the few-row
-work, which is not what bounds these kernels.
+work, which is not what bounds these kernels at ``f`` 2.  A K that is no
+divisor of a tile is stored as the dense matrices' are, its last tile
+filled up with zero blocks, here where that adds at most a THIRD
+(:func:`padded_k`: LFM2's down projection, K = 1536 -> 2048; the dense
+matrices keep their quarter, so no standing file loads otherwise).  Folding FOUR rows of 1536 into three tiles
+was built and measured first (PERF.md section 6, PR 49): it keeps the
+file's bytes, but a row offered four times in a row four times as wide is
+sixteen times the activations, and a prefill slice spent more time laying
+them out than in the kernels.
 
 :func:`routed_experts` is the whole layer after the router: gather, gate
 and up, SwiGLU, down, the weighted sum over a token's picks.  It carries a
@@ -61,6 +69,14 @@ from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
 
 FEW_ROWS = 128   # (token, pick) rows up to which every slot sees all rows
 TM_MANY = 128    # rows per tile of a prefill slice
+
+
+def padded_k(k_in: int) -> int:
+    """The K an expert matrix of ``k_in`` is stored at (module docstring):
+    ``ops.linear.padded_k`` with a third for its quarter."""
+    from ..linear import padded_k as dense_padded_k
+
+    return dense_padded_k(k_in, share=3)
 
 
 def fold_factor(k_in: int) -> int:
@@ -226,10 +242,9 @@ def _unfold_rows(out: jax.Array, f: int, group: int) -> jax.Array:
 
 def _activations(x: jax.Array, fam: _Family) -> jax.Array:
     """Rows -> the kernel's permuted, augmented bf16 rows (zeros first
-    where the planes' K is ``ops.linear.padded_k`` of the rows')."""
-    from ..linear import _pad_k
-
-    return fam.augment(fam.permute(_pad_k(x)).astype(jnp.bfloat16))
+    where the planes' K is :func:`padded_k` of the rows')."""
+    x = jnp.pad(x, ((0, 0), (0, padded_k(x.shape[1]) - x.shape[1])))
+    return fam.augment(fam.permute(x).astype(jnp.bfloat16))
 
 
 # ---------------------------------------------------------------------------

@@ -454,6 +454,123 @@ def write_tiny_hybrid_gguf(path: str, cfg: ModelConfig = TINY_HYBRID_CFG,
     return cfg
 
 
+#: a tiny ``lfm2moe`` file (models/lfm2.py): two periods of conv conv attn
+#: conv, 4 heads on 2 KV heads of 64 (two side by side in a row of the
+#: ring), 3 taps, 2 leading dense layers + 6 routed of 8 experts, top-3,
+#: sigmoid scores + a choice bias, no shared expert, the head tied
+TINY_LFM2_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=8, n_heads=4, n_kv_heads=2,
+    ffn_dim=512, n_ctx=256, rope_theta=1e6, rms_eps=1e-5,
+    head_width=64, qk_norm_per_head=True, rope_neox=True,
+    mixers=("conv", "conv", "attn", "conv") * 2, conv_l_cache=3,
+    n_dense_layers=2, expert_ffn_dim=256, n_experts=8, n_experts_used=3,
+    norm_topk_prob=True, expert_gating="sigmoid", expert_weights_eps=1e-6,
+    tie_embeddings=True,
+)
+
+#: the Q4_K_M mix on a ``lfm2moe`` file, as the benchmark writes it
+LFM2_Q4KM_MIX = {
+    "shortconv.in_proj": GGMLType.Q4_K, "shortconv.out_proj": GGMLType.Q4_K,
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+    "ffn_gate_exps": GGMLType.Q4_K, "ffn_up_exps": GGMLType.Q4_K,
+    "ffn_down_exps": GGMLType.Q6_K,
+}
+
+
+def write_lfm2_meta(w: GGUFWriter, cfg: ModelConfig) -> None:
+    """The ``lfm2moe`` keys beside :func:`write_llama_gguf_meta`'s (whose
+    one KV-head count this replaces by the array, 0 in a conv layer)."""
+    arch = "lfm2moe"
+    key = f"{arch}.attention.head_count_kv"
+    w.metadata = [m for m in w.metadata if m[0] != key]
+    w.add_metadata(key, [cfg.n_kv_heads if m == "attn" else 0
+                         for m in cfg.mixers])
+    for key, value in (
+            ("shortconv.l_cache", cfg.conv_l_cache),
+            ("attention.key_length", cfg.head_dim),
+            ("attention.value_length", cfg.head_dim),
+            ("rope.dimension_count", cfg.head_dim),
+            ("leading_dense_block_count", cfg.n_dense_layers),
+            ("expert_feed_forward_length", cfg.expert_ffn_dim),
+            ("expert_weights_scale", float(cfg.expert_weights_scale)),
+            ("expert_weights_norm", bool(cfg.norm_topk_prob)),
+            ("expert_gating_func",
+             {"softmax": 1, "sigmoid": 2}[cfg.expert_gating])):
+        w.add_metadata(f"{arch}.{key}", value)
+
+
+def write_tiny_lfm2_gguf(path: str, cfg: ModelConfig = TINY_LFM2_CFG,
+                         seed: int = 0, mix: dict | None = None,
+                         router_scale: float = 4.0,
+                         bias_scale: float = 0.2) -> ModelConfig:
+    """Write a random-weight ``lfm2moe`` GGUF (conv and attention layers by
+    the per-layer KV-head array, F32 depthwise taps, per-head QK-norm,
+    leading dense layers, a sigmoid router with its choice bias, no shared
+    expert, no ``output.weight``) with the byte-level tokenizer of
+    :func:`write_tiny_llama_gguf`."""
+    tokens, types = byte_vocab_with_specials()
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens)})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**LFM2_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-lfm2-test",
+                          arch="lfm2moe")
+    write_lfm2_meta(w, cfg)
+    D, E, hd, L = cfg.dim, cfg.n_experts, cfg.head_dim, cfg.conv_l_cache
+    q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    F, Fe = cfg.ffn_dim, cfg.expert_ffn_dim
+
+    def t(name, shape, gtype, mul=1.0):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x, gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i, mixer in enumerate(cfg.mixers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight", D)
+        if mixer == "conv":
+            # b * x is a product of two unit-size projections: larger ones
+            # keep the branch the size of the others
+            t(p + "shortconv.in_proj.weight", (3 * D, D),
+              mix["shortconv.in_proj"], 2.0)
+            w.add_tensor(p + "shortconv.conv.weight", (
+                rng.standard_normal((D, L)) * L ** -0.5).astype(np.float32),
+                GGMLType.F32)
+            t(p + "shortconv.out_proj.weight", (D, D),
+              mix["shortconv.out_proj"])
+        else:
+            t(p + "attn_q.weight", (q_dim, D), mix["attn_q"])
+            t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"])
+            t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+            norm(p + "attn_q_norm.weight", hd)
+            norm(p + "attn_k_norm.weight", hd)
+            t(p + "attn_output.weight", (D, q_dim), mix["attn_output"],
+              (D / q_dim) ** 0.5)
+        norm(p + "ffn_norm.weight", D)
+        if i < cfg.n_dense_layers:
+            t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+            t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+            t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+            continue
+        t(p + "ffn_gate_inp.weight", (E, D), GGMLType.F32, router_scale)
+        w.add_tensor(p + "exp_probs_b.bias", bias_scale * rng.standard_normal(
+            E).astype(np.float32), GGMLType.F32)
+        t(p + "ffn_gate_exps.weight", (E, Fe, D), mix["ffn_gate_exps"])
+        t(p + "ffn_up_exps.weight", (E, Fe, D), mix["ffn_up_exps"])
+        t(p + "ffn_down_exps.weight", (E, D, Fe), mix["ffn_down_exps"])
+    norm("token_embd_norm.weight", D)
+    w.write()
+    return cfg
+
+
 def synth_bpe_vocab(n_merges: int = 280_000, seed: int = 0,
                     ) -> tuple[list[str], list[str], list[int]]:
     """Deterministic Llama-3-*scale* BPE vocab: 256 byte tokens + specials +

@@ -1031,3 +1031,128 @@ def test_window_global_stack_compiles_with_no_ring_sized_copy(
             assert "q4k_expert_matmul_manyrow" in stext
             assert sliced.memory_analysis().temp_size_in_bytes \
                 < 1024 * 2 ** 20 * rows // 256
+
+
+# LFM2-24B-A2B's first pipeline stage (benchmarks/configs/lfm2-24b-a2b-q4km-
+# l20-16lane.json: 20 layers conv conv attn conv x 5, 2 dense + 18 routed of
+# 64 experts all held), n_ctx 16384: (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("shortconv-serial", 0),
+                                        ("shortconv-16lane", 16)])
+def test_shortconv_stack_compiles_with_no_ring_sized_copy(
+        one_chip, monkeypatch, name, lanes):
+    """The decode chunk (0 and 16 lanes) and both prefill slice widths of
+    the ``lfm2moe`` stack (models/lfm2.py) compile for the chip: the decode
+    kernel (it stores the step's row) and the flash kernel on a ring whose
+    rows hold two heads of 64 side by side; the grouped expert kernels with
+    the down projection's K = 1536 filled up to 2048, few-row and many-row;
+    the dense down projection's K = 11776 filled up to 12288.  The compiler has put NO copy or transpose of a ring in the
+    decode chunk, whose step holds no XLA update of the lanes' stacked
+    rings (the kernel stores the rows)."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import lfm2
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import (
+        init_cache, ring_write_impl)
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+    from tests.test_lfm2 import published_cfg
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    import dataclasses
+
+    cfg = dataclasses.replace(published_cfg(), attn_impl="pallas")
+    D, V, F, Fe, E, hd = 2048, 65536, 11776, 1536, 64, 64
+    assert lfm2.CACHE.decode_kernel_block(cfg) == 512
+    assert ring_write_impl(cfg) == "kernel"
+
+    def exps(fmt, n, k, L):       # K as stored: experts.py ``padded_k``
+        k = -(-k // 2048) * 2048
+        kt = k // 2048
+        if fmt == "q4k":
+            return {"qs": S(L, E, n, k // 2, dtype=i8),
+                    "sm": S(L, E, kt, n, 128)}
+        return {"q4": S(L, E, n, k // 2, dtype=i8),
+                "q2": S(L, E, n, k // 4, dtype=i8),
+                "sm6": S(L, E, kt, n, 128)}
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    emb = S(V, D)
+    params = place({
+        "tok_emb": emb, "out_norm": S(D, dtype=f32), "output": {"w": emb},
+        "layers": {
+            "conv": {"attn_norm": S(15, D, dtype=f32),
+                     "conv": S(15, D, 3, dtype=f32),
+                     "in_proj": _planes("q4k", 3 * D, D, 15),
+                     "out_proj": _planes("q4k", D, D, 15)},
+            "attn": {"attn_norm": S(5, D, dtype=f32),
+                     "attn_q_norm": S(5, hd, dtype=f32),
+                     "attn_k_norm": S(5, hd, dtype=f32),
+                     "wq": _planes("q4k", D, D, 5),
+                     "wk": _planes("q4k", 8 * hd, D, 5),
+                     "wv": _planes("q6k", 8 * hd, D, 5),
+                     "wo": _planes("q4k", D, D, 5)},
+            "dense": {"ffn_norm": S(2, D, dtype=f32),
+                      "w_gate": _planes("q4k", F, D, 2),
+                      "w_up": _planes("q4k", F, D, 2),
+                      "w_down": _planes("q6k", D, 12288, 2)},
+            "moe": {"ffn_norm": S(18, D, dtype=f32),
+                    "w_router": S(18, E, D, dtype=f32),
+                    "router_bias": S(18, E, dtype=f32),
+                    "w_gate_exps": exps("q4k", Fe, D, 18),
+                    "w_up_exps": exps("q4k", Fe, D, 18),
+                    "w_down_exps": exps("q6k", D, Fe, 18)}}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert re.search(r"flash_attention_decode[^_]", text)
+    assert "q4k_expert_matmul_fewrow" in text
+    assert "q6k_expert_matmul_fewrow" in text
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*16384,128\]\S* "
+        r"(copy|transpose|dynamic-update-slice)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    if not lanes:       # the admission slices into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert _slice_widths(cfg) == [256, 1024]
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            stext = sliced.as_text()
+            assert "flash_attention" in stext
+            assert "q4k_expert_matmul_manyrow" in stext
+            assert "q6k_expert_matmul_manyrow" in stext
+            print("slice", rows, "temporaries",
+                  sliced.memory_analysis().temp_size_in_bytes)
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 1024 * 2 ** 20 * rows // 256
