@@ -516,6 +516,12 @@ class Engine:
         return self.cache.health(self.cfg, self)
 
     @property
+    def cache_engine_health(self) -> dict:
+        """Further keys of /health ``engine`` that the cache kind owns
+        (``CacheKind.engine_health``; none for most kinds)."""
+        return self.cache.engine_health(self.cfg)
+
+    @property
     def tokenizer_fallback(self) -> str | None:
         """The /health ``engine.tokenizer`` line of a SentencePiece
         vocabulary that tokenizer/spm.py cannot cut at spaces (None
@@ -734,9 +740,11 @@ class Engine:
                 pass
 
     def _count_slice(self, tokens: int) -> None:
-        """One prefill program's tokens into :attr:`slice_tokens`."""
+        """One prefill program's tokens into :attr:`slice_tokens`, and
+        into the cache kind's own counters."""
         self.slice_tokens[
             "wide" if tokens > self._prefill_chunk else "narrow"] += tokens
+        self.cache.note_slice(self.cache_counts, self.cfg, tokens)
 
     @staticmethod
     def _slice_span(pspan, t_s: float, t_e: float, offset: int, tokens: int,

@@ -89,6 +89,9 @@ class CacheKind:
     widest_slice: Callable = lambda cfg: 0
     #: (cfg, engine) -> the /health ``engine.cache`` block (the ring: none)
     health: Callable = lambda cfg, engine: None
+    #: (cfg) -> further keys of /health ``engine``, beside the ``cache``
+    #: block: which of the kind's own reads serves
+    engine_health: Callable = lambda cfg: {}
     #: the kind's OWN counters beside :data:`RING_GAUGES`: {/metrics name
     #: (obs/catalog.py): the key of :meth:`new_counts` it reads}
     own_gauges: Mapping = types.MappingProxyType({})
@@ -96,6 +99,9 @@ class CacheKind:
     #: attributes, counting what the prefill does; ``slices``: its plan
     #: [(offset, tokens)], None for an untraced request
     note_prefill: Callable = lambda counts, cfg, n_prompt, slices: {}
+    #: (counts, cfg, tokens): one dispatched prefill program of ``tokens``
+    #: rows into the counters
+    note_slice: Callable = lambda counts, cfg, tokens: None
     #: (live rows at the chunk's end) -> a ``decode_chunk`` span's attributes
     decode_span_attrs: Callable = lambda pos: {}
 

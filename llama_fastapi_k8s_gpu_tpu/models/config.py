@@ -124,9 +124,13 @@ class ModelConfig:
     # ``latent_kernel``: a decode step reads the cache through the decode
     # kernel (ops/pallas/attention.py ``latent_attention_decode``).  Set by
     # the engine, never by a file or a user: a TPU whose compiler took the
-    # kernel's probe.  (``attn_impl`` stays ``xla`` for this kind: it names
-    # the prefill slices' attention, models/mla.py's own loop.)
+    # kernel's probe.  ``latent_slice_kernel``: a prefill slice reads it
+    # through the slice kernel (``latent_attention_prefill``), set the same
+    # way behind that kernel's own probe.  (``attn_impl`` stays ``xla`` for
+    # this kind: it names the ring's flash kernel, which serves nothing
+    # here; /health ``engine.latent_slice_read`` names the slices' read.)
     latent_kernel: bool = False
+    latent_slice_kernel: bool = False
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0

@@ -22,13 +22,29 @@
   block of latents is read ONCE for all heads, which is the mechanism's
   point; the expanded form (:func:`expanded_attention`: K and V of every
   head for every position) is what the reference computes and what
-  tests hold the absorbed form to.  WHICH READ SERVES WHICH S: a prefill
-  slice (S > 1), and a decode step (S = 1) on the CPU or wherever
-  :func:`kernel_block` says 0, run :func:`latent_attention`, a
-  loop in plain XLA over blocks of ``LATENT_BLOCK`` positions up to a
-  traced bound (flash recurrence: running max and sum; under the lane
-  engine's ``vmap`` ONE bound for all lanes), after an XLA
-  ``dynamic_update_slice`` of the pass's rows.  A decode step where
+  tests hold the absorbed form to.  WHICH READ SERVES WHICH S, decided by
+  what the program observes (S, the backend, the kernels' probes) and by no
+  knob: :func:`latent_attention` is a loop in plain XLA over blocks of
+  ``LATENT_BLOCK`` positions up to a traced bound (flash recurrence:
+  running max and sum; under the lane engine's ``vmap`` ONE bound for all
+  lanes), after an XLA ``dynamic_update_slice`` of the pass's rows; it
+  serves on the CPU, after a failed probe, a decode step (S = 1) wherever
+  :func:`kernel_block` says 0 and a prefill slice (S > 1) wherever
+  :func:`slice_tile` says 0, and it is what tests hold the kernels to.  A
+  prefill slice where ``cfg.latent_slice_kernel`` is set (the engine's: a
+  TPU, the probe passed) and a tile fits its rows (every slice width of
+  engine/slices.py at the published 64 heads) is, after the same XLA
+  write, ONE Pallas kernel over the scratch leaf in place (ops/pallas/
+  attention.py ``latent_attention_prefill``): query tiles of 1024 rows
+  (one head's contiguous tokens, whole heads of a narrow slice) against
+  blocks of ``LATENT_SLICE_BLOCK`` rows, each block copied once for the
+  scores and the weighted sum, no block past a tile's last position
+  fetched, and the scores, probabilities and accumulator in VMEM from a
+  tile's first block to its last (the loop writes them to HBM three times
+  a block).  /health ``engine.
+  latent_slice_read`` (``kernel`` / ``xla``), the traced ``prefill``
+  span's ``latent_read`` and the gauges ``latent_slices_kernel_total`` /
+  ``latent_slices_loop_total`` say which served.  A decode step where
   ``cfg.latent_kernel`` is set (the engine's: a TPU, the probe passed) is
   ONE Pallas kernel over the lanes (ops/pallas/attention.py
   ``latent_attention_decode``, the ring's decode kernel on one leaf): each
@@ -80,6 +96,11 @@ LATENT_BLOCK = 512
 #: position counted in (PERF.md section 6, PR 47).
 LATENT_KERNEL_BLOCK = 1024
 
+#: latent rows a block of the prefill slices' KERNEL holds
+#: (ops/pallas/attention.py ``latent_attention_prefill``;
+#: :func:`slice_tile` says where it serves)
+LATENT_SLICE_BLOCK = 1024
+
 
 def kernel_block(cfg: ModelConfig) -> int:
     """The decode kernel's block on the latent leaf where
@@ -90,6 +111,20 @@ def kernel_block(cfg: ModelConfig) -> int:
         return 0
     block = min(LATENT_KERNEL_BLOCK, cfg.n_ctx)
     return block if cfg.n_ctx % block == 0 and block % 16 == 0 else 0
+
+
+def slice_tile(cfg: ModelConfig, S: int) -> int:
+    """Rows of a query tile of the prefill slices' kernel where it serves a
+    slice of ``S`` tokens, else 0 (the XLA loop serves):
+    ``cfg.latent_slice_kernel`` (the engine's: a TPU, the kernel's own
+    probe passed), S > 1, whole blocks in the leaf, and a tile that fits
+    the slice's ``n_heads * S`` rows."""
+    if not cfg.latent_slice_kernel or S < 2 \
+            or cfg.n_ctx % min(LATENT_SLICE_BLOCK, cfg.n_ctx):
+        return 0
+    from ..ops.pallas.attention import latent_prefill_tile
+
+    return latent_prefill_tile(cfg.n_heads, S)
 
 
 def lat_width(cfg: ModelConfig) -> int:
@@ -117,11 +152,15 @@ def cache_nbytes(cfg: ModelConfig) -> int:
 def prefill_positions_read(slices, cfg: ModelConfig) -> int:
     """Cached rows (a layer's) the slices of a prompt's prefill read:
     ``slices`` [(offset, positions)] (engine/slices.py ``plan_slices``),
-    each reading whole blocks up to its own last position.  Host arithmetic
-    for the ``prefill`` span."""
-    T = min(LATENT_BLOCK, cfg.n_ctx)
-    return sum(min(-(-min(off + n, cfg.n_ctx) // T) * T, cfg.n_ctx)
-               for off, n in slices)
+    each reading whole blocks, of the read that serves it
+    (:func:`slice_tile`), up to its own last position.  Host arithmetic for
+    the ``prefill`` span."""
+    def rows(off, n):
+        T = min(LATENT_SLICE_BLOCK if slice_tile(cfg, n) else LATENT_BLOCK,
+                cfg.n_ctx)
+        return min(-(-min(off + n, cfg.n_ctx) // T) * T, cfg.n_ctx)
+
+    return sum(rows(off, n) for off, n in slices)
 
 
 def attn_scale(cfg: ModelConfig) -> float:
@@ -314,10 +353,22 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
         with jax.named_scope("kv_write"):
             cache = {"lat": jax.lax.dynamic_update_slice(
                 cache["lat"], rows[None, None], (li, 0, pos_offset, 0))}
-        bound = pos_offset + S - 1 if kv_bound is None or S > 1 else kv_bound
         with jax.named_scope("mla_attn"):
-            ctx = latent_attention(q_full, cache["lat"], li, positions, bound,
-                                   cfg)
+            if slice_tile(cfg, S):
+                # the slice kernel: the scratch leaf in place, the scores
+                # in VMEM (``positions`` are ``pos_offset`` on)
+                from ..ops.pallas import (
+                    latent_attention_prefill, use_interpret)
+
+                ctx = latent_attention_prefill(
+                    q_full.transpose(1, 0, 2), cache["lat"], li, pos_offset,
+                    sm_scale=attn_scale(cfg), v_width=r,
+                    block_k=LATENT_SLICE_BLOCK, interpret=use_interpret())
+            else:
+                bound = pos_offset + S - 1 if kv_bound is None or S > 1 \
+                    else kv_bound
+                ctx = latent_attention(q_full, cache["lat"], li, positions,
+                                       bound, cfg)
     o = expand_values(ctx, layers["w_uv"]["w"][i], h.dtype)
     return h + lin(o, "wo"), cache
 
@@ -396,21 +447,30 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
 
 
 def _probe_kernels(cfg: ModelConfig, asked: str, attn_impl: str, probed):
-    """A decode step's read of the latent leaf is the decode kernel where
-    the chip compiles it (``auto``: a TPU); a Mosaic failure degrades the
-    step to the XLA loop, and says so (ops/pallas/probe.py)."""
+    """A decode step's and a prefill slice's read of the latent leaf are
+    their kernels where the chip compiles them (``auto``: a TPU), each
+    behind its own probe; a Mosaic failure degrades that read to the XLA
+    loop, and says so (ops/pallas/probe.py)."""
     if asked == "pallas" or (
             asked == "auto" and jax.default_backend() == "tpu"):
-        from ..ops.pallas.probe import probe_latent_decode
+        from ..obs.devtime import DEVTIME
+        from ..ops.pallas.probe import (
+            probe_latent_decode, probe_latent_prefill)
 
-        probed.append("latent_decode")
-        err = probe_latent_decode()
-        if err is None:
-            cfg = dataclasses.replace(cfg, latent_kernel=True)
-        else:
-            logger.error("pallas latent decode kernel failed its compile "
-                         "probe; decode steps read the latent ring "
-                         "through the XLA loop: %s", err)
+        for name, probe, flag, what in (
+                ("latent_decode", probe_latent_decode, "latent_kernel",
+                 "decode steps read"),
+                ("latent_prefill", probe_latent_prefill,
+                 "latent_slice_kernel", "prefill slices read")):
+            probed.append(name)
+            err = probe()
+            if err is None:
+                cfg = dataclasses.replace(cfg, **{flag: True})
+                continue
+            DEVTIME.record_degrade(probe.__name__, err)
+            logger.error("pallas %s kernel failed its compile probe; %s "
+                         "the latent ring through the XLA loop: %s",
+                         name.replace("_", " "), what, err)
     return cfg, attn_impl
 
 
@@ -430,12 +490,24 @@ def _health(cfg: ModelConfig, engine) -> dict:
         "kv_paged": "refused at start"}
 
 
+def slice_read(cfg: ModelConfig, S: int) -> str:
+    """Which read serves a prefill slice of ``S`` tokens."""
+    return "kernel" if slice_tile(cfg, S) else "loop"
+
+
 def _note_prefill(counts, cfg: ModelConfig, n_prompt: int, slices) -> dict:
-    # the cached rows the prompt's slices read, from the reused prefix on
+    # the cached rows the prompt's slices read, from the reused prefix on,
+    # and which read serves them
     if slices is None:
         return {}
     return {"cache": LATENT_RING,
+            "latent_read": "+".join(sorted(
+                {slice_read(cfg, n) for _, n in slices})),
             "latent_positions_read": prefill_positions_read(slices, cfg)}
+
+
+def _note_slice(counts, cfg: ModelConfig, tokens: int) -> None:
+    counts["slices_" + slice_read(cfg, tokens)] += 1
 
 
 CACHE = CacheKind(
@@ -457,15 +529,22 @@ CACHE = CacheKind(
                  "slice by slice; use the continuous scheduler"},
     rolls_back=True,   # positional, as the ring is
     # ``attn_impl`` stays xla: a prefill slice's attention is this file's
-    # own loop, and the flash kernel serves nothing
+    # own read (the loop, or the kernel of the same absorbed form on the
+    # latent leaf: ``engine_health`` says which), and the ring's flash
+    # kernel serves nothing
     attn_impl=lambda cfg, asked: "xla",
     probe_kernels=_probe_kernels,
     decode_kernel_block=kernel_block,
     health=_health,
+    engine_health=lambda cfg: {
+        "latent_slice_read": "kernel" if cfg.latent_slice_kernel else "xla"},
     # the ring's own arithmetic under the kind's names too: a latent is
-    # read once for all heads
+    # read once for all heads; and the slices by the read that served them
     own_gauges={"latent_positions_read_total": "read",
-                "latent_positions_live_total": "live"},
+                "latent_positions_live_total": "live",
+                "latent_slices_kernel_total": "slices_kernel",
+                "latent_slices_loop_total": "slices_loop"},
     note_decode=note_ring_decode, note_prefill=_note_prefill,
+    note_slice=_note_slice,
     decode_span_attrs=lambda pos: {"cache": LATENT_RING,
                                    "latent_positions": pos})

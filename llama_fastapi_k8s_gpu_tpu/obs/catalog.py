@@ -435,6 +435,19 @@ METRICS: dict[str, Metric] = _register(
            "same steps (what the attention needed); over "
            "latent_positions_read_total = the share of the read that was "
            "live"),
+    Metric("latent_slices_kernel_total", GAUGE,
+           "prefill programs (slices) whose attention over the cached "
+           "latents ran as the slice kernel (ops/pallas/attention.py "
+           "latent_attention_prefill: the scratch leaf in place, the "
+           "scores in VMEM; /health engine.latent_slice_read = kernel: a "
+           "TPU whose probe passed, a slice width that a tile fits), "
+           "counted at dispatch, cumulative"),
+    Metric("latent_slices_loop_total", GAUGE,
+           "prefill programs whose attention over the cached latents ran "
+           "as the plain XLA loop over blocks of 512 (models/mla.py "
+           "latent_attention: the CPU, a failed probe, a width no tile "
+           "fits); beside latent_slices_kernel_total the kernel's "
+           "engagement"),
     # -- the state + ring cache (models/sala.py; ``minicpm-sala``) ----------
     Metric("lin_state_updates_total", GAUGE,
            "updates of a linear-attention layer's state in decode steps: "

@@ -29,7 +29,8 @@ def use_interpret() -> bool:
 
 
 from .attention import (  # noqa: E402
-    flash_attention, flash_attention_decode, latent_attention_decode)
+    flash_attention, flash_attention_decode, latent_attention_decode,
+    latent_attention_prefill)
 from .dequant import (  # noqa: E402
     device_dequant,
     dequant_q4_k_device,
@@ -46,6 +47,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_decode",
     "latent_attention_decode",
+    "latent_attention_prefill",
     "device_dequant",
     "dequant_q4_k_device",
     "dequant_q5_k_device",

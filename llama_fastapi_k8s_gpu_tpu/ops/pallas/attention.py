@@ -781,9 +781,243 @@ def latent_attention_decode(
     return ctx, lat
 
 
+# ---------------------------------------------------------------------------
+# a prefill slice (S > 1) over a latent leaf: scores never leave VMEM
+# ---------------------------------------------------------------------------
+
+#: rows of a query tile and of a key block of :func:`latent_attention_prefill`,
+#: the keys one pass of its recurrence scores, and the row groups of a tile
+#: that a pass scores side by side.  From the sweep on the chip (PERF.md
+#: section 6, PR 50): at 64 heads x 1024 tokens against 11264 latents, ms a
+#: call, (512, 1024, 512, 1) 11.28, (512, 1024, 1024, 1) 11.05, (512, 2048,
+#: 512, 1) 11.38, (256, 1024, 512, 1) 12.97, (512, 1024, 256, 1) 14.37,
+#: (1024, 1024, 512, 1) 10.69, (512, 1024, 512, 2) 10.54, (1024, 1024, 512,
+#: 2) 10.32, (1024, 1024, 512, 4) 10.22; a key axis of the grid that ends at
+#: the slice's bound (a dynamic extent) 10.79 for the last, and 6.30 for
+#: 5.75 at 5632 latents: the static grid's empty steps cost less than the
+#: pipeline's restart at every tile
+LATENT_PREFILL_BLOCKS = (1024, 1024, 512, 4)
+
+
+def latent_prefill_tile(n_heads: int, seq_len: int,
+                        block_q: int = LATENT_PREFILL_BLOCKS[0]) -> int:
+    """Rows of a query tile of :func:`latent_attention_prefill` for a slice
+    of ``seq_len`` tokens, or 0 where no tile fits it: the largest power of
+    two up to ``block_q``, at least one bf16 tile's 16 sublanes, that
+    divides the ``n_heads * seq_len`` rows and either divides ``seq_len``
+    (a tile is contiguous tokens of ONE head) or is a multiple of it (whole
+    heads over all the slice's tokens)."""
+    b = block_q
+    while b >= _ROW_TILE:
+        if (n_heads * seq_len) % b == 0 and (
+                seq_len % b == 0 or b % seq_len == 0):
+            return b
+        b //= 2
+    return 0
+
+
+def _latent_tile_span(t, seq_len: int, block_q: int):
+    """(first, last) token of the slice that query tile ``t`` holds: rows
+    are h * S + s, so a tile that divides S is one head's contiguous
+    tokens, and one that is a multiple of S whole heads over all of them."""
+    if block_q < seq_len:
+        first = jax.lax.rem(t * block_q, seq_len)
+        return first, first + block_q - 1
+    return 0, seq_len - 1
+
+
+def _latent_prefill_kernel(
+    # scalar prefetch
+    i_ref,              # (1,) int32: the layer (the index maps')
+    pos_ref,            # (1,) int32: cache position of the slice's token 0
+    # inputs
+    q_ref,              # (BQ, w): rows h * S + s of [q_abs | q_r | 0]
+    lat_ref,            # (BK, w): one block of the layer's latents
+    # outputs
+    o_ref,              # (BQ, v_width)
+    # scratch
+    m_ref,              # (BQ, 128) f32 running max (lane-replicated)
+    l_ref,              # (BQ, 128) f32 running sum
+    acc_ref,            # (BQ, v_width) f32 running weighted sum
+    *,
+    seq_len: int,
+    block_q: int,
+    block_k: int,
+    sub_k: int,         # keys a pass of the recurrence scores
+    sm_scale: float,
+    v_width: int,
+    chains: int,        # independent row groups of a tile, scored together
+):
+    """``_attn_kernel``'s recurrence and block classification on ONE ring:
+    a block of latents is the keys (all its columns) and the values (its
+    first ``v_width``).  The key-block index map is clamped at the tile's
+    last block, so a grid step past it fetches nothing and, its keys lying
+    beyond every query of the tile, computes nothing."""
+    del i_ref
+    t = pl.program_id(0)
+    kb = pl.program_id(1)
+
+    @pl.when(kb == 0)
+    def _init():
+        # a finite floor and -inf masks, as models/mla.py latent_attention
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    t_min, t_max = _latent_tile_span(t, seq_len, block_q)
+    q_min = pos_ref[0] + t_min
+    q_max = pos_ref[0] + t_max
+
+    def _sub_block(u: int):
+        kmin = kb * block_k + u * sub_k
+        kmax = kmin + sub_k - 1
+        skip = kmin > q_max                 # wholly in the masked future
+        interior = kmax <= q_min            # wholly unmasked
+
+        def _body(masked: bool):
+            k = lat_ref[u * sub_k:(u + 1) * sub_k, :]       # (SK, w)
+            rq = block_q // chains
+            # every chain's scores first: chains are independent rows of
+            # the tile, so one's softmax can run beside another's products
+            scores = [jax.lax.dot_general(
+                q_ref[c * rq:(c + 1) * rq, :], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+                for c in range(chains)]
+            for c, s in enumerate(scores):
+                rows = slice(c * rq, (c + 1) * rq)
+                if masked:
+                    row = t * block_q + c * rq + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 0)
+                    q_pos = pos_ref[0] + jax.lax.rem(row, seq_len)
+                    key_pos = kmin + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 1)
+                    s = jnp.where(key_pos <= q_pos, s, -jnp.inf)
+                m_prev = m_ref[rows, :1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = l_ref[rows, :1] * alpha \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[rows, :] = acc_ref[rows, :] * alpha \
+                    + jax.lax.dot_general(
+                        p.astype(k.dtype), k[:, :v_width],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_ref[rows, :] = jnp.broadcast_to(m_new, (rq, 128))
+                l_ref[rows, :] = jnp.broadcast_to(l_new, (rq, 128))
+
+        @pl.when(jnp.logical_and(jnp.logical_not(skip), interior))
+        def _interior():
+            _body(masked=False)
+
+        @pl.when(jnp.logical_and(jnp.logical_not(skip),
+                                 jnp.logical_not(interior)))
+        def _edge():
+            _body(masked=True)
+
+    for u in range(block_k // sub_k):
+        _sub_block(u)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _finish():
+        l = l_ref[:, :1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("sm_scale", "v_width", "block_q", "block_k", "sub_k",
+                     "chains", "interpret"),
+)
+def latent_attention_prefill(
+    q: jax.Array,          # (n_heads, S, w): HEAD-MAJOR [q_abs | q_r | 0]
+    lat: jax.Array,        # (L, 1, n_ctx, w): the STACKED bf16 latent leaf,
+    #                        left in HBM and read in place at layer i, the
+    #                        slice's own rows already written
+    i: jax.Array,          # scalar int32: the layer (the leaf's)
+    pos_offset: jax.Array, # scalar int32: cache position of q[:, 0]
+    *,
+    sm_scale: float,
+    v_width: int,          # kv_lora_rank: a row's first columns, the values
+    block_q: int = LATENT_PREFILL_BLOCKS[0],
+    block_k: int = LATENT_PREFILL_BLOCKS[1],
+    sub_k: int = LATENT_PREFILL_BLOCKS[2],
+    chains: int = LATENT_PREFILL_BLOCKS[3],
+    interpret: bool = False,
+) -> jax.Array:
+    """A prefill slice's causal ABSORBED latent attention (``models/mla.py
+    latent_attention`` at S > 1) as ONE kernel: grid (query tiles, key
+    blocks), key blocks last, the scores, the probabilities and the
+    accumulator in VMEM from a tile's first block to its last.  The loop's
+    arithmetic (bf16 operands, float32 scores, max, sum and accumulator,
+    the division last), only where the intermediates live differs.
+
+    A query tile is ``block_q`` rows of the head-major query, row = h * S +
+    s (:func:`latent_prefill_tile`: one head's contiguous tokens, or whole
+    heads over all of them), so its causal bounds are tight.  A key block
+    is ``block_k`` rows of the leaf, copied ONCE for the scores (all ``w``
+    columns) and the weighted sum (its first ``v_width``) and scored
+    ``sub_k`` keys a pass, each pass skipped where it lies wholly beyond
+    the tile's last query and unmasked where wholly before its first; a
+    pass scores ``chains`` row groups of the tile side by side, so that one
+    group's softmax runs beside another's products.  The block index is
+    clamped at the block that holds the tile's last
+    position: the grid walks all of ``n_ctx``, but a step past that block
+    names the block already in VMEM, and no copy is started for it.
+
+    Returns the weighted sum of LATENTS (n_heads, S, v_width) in q.dtype,
+    before ``W_uv``.  One sequence: no ``vmap`` rule."""
+    H, S, W = q.shape
+    _, _, n_ctx, _ = lat.shape
+    bq = latent_prefill_tile(H, S, block_q)
+    bk = min(block_k, n_ctx)
+    sk = min(sub_k, bk)
+    if not bq or n_ctx % bk or bk % sk:
+        raise ValueError(
+            f"no tile of the latent prefill kernel fits {H} heads x {S} "
+            f"tokens against {n_ctx} positions in blocks of {block_k}")
+    n_kb = n_ctx // bk
+
+    def last_block(t, pos_ref):
+        return jnp.minimum((pos_ref[0] + _latent_tile_span(t, S, bq)[1])
+                           // bk, n_kb - 1)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_prefill_kernel, seq_len=S, block_q=bq, block_k=bk,
+            sub_k=sk, sm_scale=sm_scale, v_width=v_width,
+            chains=chains if bq % (16 * chains) == 0 else 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H * S // bq, n_kb),
+            in_specs=[
+                pl.BlockSpec((bq, W), lambda t, kb, i, pos: (t, 0)),
+                pl.BlockSpec((None, None, bk, W), lambda t, kb, i, pos: (
+                    i[0], 0, jnp.minimum(kb, last_block(t, pos)), 0)),
+            ],
+            out_specs=pl.BlockSpec((bq, v_width),
+                                   lambda t, kb, i, pos: (t, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, v_width), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H * S, v_width), q.dtype),
+        interpret=interpret,
+        name="flash_attention_prefill_latent",
+    )(jnp.asarray(i, jnp.int32).reshape(1),
+      jnp.asarray(pos_offset, jnp.int32).reshape(1),
+      q.reshape(H * S, W), lat)
+    return out.reshape(H, S, v_width)
+
+
 # devtime inventory (lfkt-lint PERF001): flash attention is a TRACE-INNER
 # dispatch site — it runs inside the prefill/decode entry programs, so its
 # compile wall is attributed to whichever host program traced it
 # (obs/devtime.py; /debug/compiles shows it under kind="inner")
 register_program("flash_attention", site="ops.pallas.attention")
 register_program("_decode_lanes", site="ops.pallas.attention")
+register_program("latent_attention_prefill", site="ops.pallas.attention")

@@ -292,6 +292,43 @@ def probe_latent_decode() -> str | None:
 
 
 @functools.lru_cache(maxsize=1)
+def probe_latent_prefill() -> str | None:
+    """Compile + run the prefill slices' kernel on a latent leaf
+    (ops/pallas/attention.py ``latent_attention_prefill``: the scores of a
+    slice in VMEM, the leaf read in place) on one narrow slice two blocks
+    deep, at the published widths and the tile and block that serve (64
+    heads x 256 tokens in tiles of 1024 rows, rows of 512 + 64 laid out as
+    640, blocks of ``mla.LATENT_SLICE_BLOCK``; 4 heads x 16 tokens, 128 +
+    128 and blocks of 16 in interpret mode).  A failure leaves a
+    ``deepseek2`` file's prefill slices on the XLA loop of ``models/mla.py
+    latent_attention`` (``cfg.latent_slice_kernel`` stays False)."""
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .attention import latent_attention_prefill
+
+        from ...models.mla import LATENT_SLICE_BLOCK
+
+        itp = use_interpret()
+        H, S, W, R, T = (4, 16, 256, 128, 16) if itp \
+            else (64, 256, 640, 512, LATENT_SLICE_BLOCK)
+
+        def slice_(q):
+            lat = jnp.ones((2, 1, 2 * T, W), jnp.bfloat16)
+            return latent_attention_prefill(
+                q, lat, jnp.int32(1), jnp.int32(2 * T - S),
+                sm_scale=W ** -0.5, v_width=R, block_k=T, interpret=itp
+            ).astype(jnp.float32).sum()
+
+        float(jax.jit(slice_)(jnp.ones((H, S, W), jnp.bfloat16)))
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)
+
+
+@functools.lru_cache(maxsize=1)
 def probe_kv_quant() -> str | None:
     """Compile + run the int8 KV-cache write-quantize kernel
     (ops/pallas/kvquant.py) at a decode-like shape.  A failure degrades
@@ -318,6 +355,7 @@ from ...obs.devtime import register_program  # noqa: E402
 register_program("probe_flash_attention", site="ops.pallas.probe")
 register_program("probe_lin_state", site="ops.pallas.probe")
 register_program("probe_latent_decode", site="ops.pallas.probe")
+register_program("probe_latent_prefill", site="ops.pallas.probe")
 
 
 @functools.lru_cache(maxsize=1)

@@ -1505,6 +1505,9 @@ def create_app(engine=None, settings: Settings | None = None,
             kind = getattr(eng, "cache_kind", None)
             if kind:
                 engine_info["cache"] = kind
+            # which of the kind's own reads serves (a latent ring's prefill
+            # slices: ``latent_slice_read``); no key for most kinds
+            engine_info.update(getattr(eng, "cache_engine_health", None) or {})
             # a vocabulary the tokenizer cannot cut at spaces pays the
             # whole-text merge loop on every prompt (tokenizer/spm.py);
             # absent where it can

@@ -756,55 +756,26 @@ def test_flash_attention_on_a_tpu_mesh_is_refused(topo):
                           placed((), i32)).compile()
 
 
-# BENCHMARK.json's gigachat configuration at its published widths (hidden
-# 7168 filled up to K 8192, 64 heads, latents 1536 / 512 + 64, 1 dense + 6
-# routed layers holding 32 of 256 experts), n_ctx 16384: (name, lanes)
-@pytest.mark.parametrize("name,lanes,read", [
-    ("gigachat-serial", 0, "loop"), ("gigachat-16lane", 16, "loop"),
-    ("gigachat-serial-kernel", 0, "kernel"),
-    ("gigachat-16lane-kernel", 16, "kernel")])
-def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
-                                                       name, lanes, read):
-    """The decode chunk and the prefill slice of the ``deepseek2`` stack
-    (models/mla.py) compile for the chip: the fused planes at K 8192 (the
-    experts' gate and up among them: the grouped kernels are in the program
-    under their own names), the latent projection at 640 rows, the absorbed
-    attention's loop over blocks of the lanes' stacked latent leaf
-    (``loop``) or the decode kernel on that leaf as it is (``kernel``: the
-    leaf in its own shape is the kernel's operand and aliased result, which
-    is how benchmarks/kernels/mla_attn.json finds it; no block of the
-    lanes' leaf is materialised and no XLA update writes the step's row).
-    The compiler has put NO copy or transpose of the latent leaf in the
-    decode chunk, and a slice's scratch stays under half a GB."""
-    import re
-
+def _gigachat(one_chip, **flags):
+    """BENCHMARK.json's gigachat configuration at its published widths
+    (hidden 7168 filled up to K 8192, 64 heads, latents 1536 / 512 + 64, 1
+    dense + 6 routed layers holding 32 of 256 experts), n_ctx 16384: (cfg,
+    the parameters' shapes on the described chip, ``place``)."""
     from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
-    from llama_fastapi_k8s_gpu_tpu.models.generate import (
-        generate_chunk_jit, init_state, prefill_chunk_jit)
-    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
-    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
-        batched_generate_chunk_perlane_jit, init_batched_state,
-        init_lane_left)
-    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
-        SamplingParams, sampling_tensors)
 
-    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
-
-    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
     D, DP, V, H = 7168, 8192, 128256, 64
     r_q, r_kv, d_n, d_r, d_v, F, Fe, E = 1536, 512, 128, 64, 192, 18432, \
         2048, 32
     cfg = ModelConfig(
         vocab_size=V, dim=D, n_layers=7, n_heads=H, n_kv_heads=H, ffn_dim=F,
         n_ctx=16384, rope_theta=1e5, rms_eps=1e-6, attn_impl="xla",
-        latent_kernel=read == "kernel",
         q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_n, qk_rope_dim=d_r,
         v_head_dim=d_v, rope_yarn_factor=64.0, rope_yarn_orig_ctx=4096,
         attn_mscale=2.0048, n_dense_layers=1, expert_ffn_dim=Fe,
         n_shared_experts=1, n_experts=256, n_experts_used=8,
         norm_topk_prob=True, expert_gating="sigmoid", n_expert_groups=8,
         n_groups_used=4, expert_weights_scale=2.5, experts_first=0,
-        experts_held=E)
+        experts_held=E, **flags)
 
     def exps(fmt, n, k, L):
         kt = k // 2048
@@ -845,6 +816,59 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
                     "w_gate_exps": exps("q4k", Fe, DP, 6),
                     "w_up_exps": exps("q4k", Fe, DP, 6),
                     "w_down_exps": exps("q6k", D, Fe, 6)}}})
+    return cfg, params, place
+
+
+def _leaf_copies(text):
+    """The copies and transposes of a leaf of 16384 positions that stand
+    outside a fusion in a compiled program's text."""
+    import re
+
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*16384,\d+\]\S* (copy|transpose)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    return found
+
+
+# (name, lanes)
+@pytest.mark.parametrize("name,lanes,read", [
+    ("gigachat-serial", 0, "loop"), ("gigachat-16lane", 16, "loop"),
+    ("gigachat-serial-kernel", 0, "kernel"),
+    ("gigachat-16lane-kernel", 16, "kernel")])
+def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
+                                                       name, lanes, read):
+    """The decode chunk and the prefill slice of the ``deepseek2`` stack
+    (models/mla.py) compile for the chip: the fused planes at K 8192 (the
+    experts' gate and up among them: the grouped kernels are in the program
+    under their own names), the latent projection at 640 rows, the absorbed
+    attention's loop over blocks of the lanes' stacked latent leaf
+    (``loop``) or the decode kernel on that leaf as it is (``kernel``: the
+    leaf in its own shape is the kernel's operand and aliased result, which
+    is how benchmarks/kernels/mla_attn.json finds it; no block of the
+    lanes' leaf is materialised and no XLA update writes the step's row).
+    The compiler has put NO copy or transpose of the latent leaf in the
+    decode chunk, and a slice's scratch stays under half a GB."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg, params, place = _gigachat(one_chip, latent_kernel=read == "kernel")
     st = sampling_tensors(SamplingParams())
     if lanes:
         state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
@@ -863,15 +887,7 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
     text = compiled.as_text()
     assert "q4k_expert_matmul_fewrow" in text
     assert "q6k_expert_matmul_fewrow" in text
-    leaf_op = re.compile(
-        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*16384,\d+\]\S* (copy|transpose)\(")
-    fused = re.compile(r"^%fused_computation")
-    found, in_fusion = [], False
-    for ln in text.splitlines():
-        if ln.startswith(("%", "ENTRY")):
-            in_fusion = bool(fused.match(ln))
-        if not in_fusion and leaf_op.search(ln):
-            found.append(ln.strip()[:120])
+    found = _leaf_copies(text)
     assert not found, found[:4]
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
     leaf = "bf16[%d,7,1,16384,640]" % (lanes or 1)
@@ -901,6 +917,63 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
             assert sliced.memory_analysis().temp_size_in_bytes \
                 < 1024 * 2 ** 20 * rows // 256
         assert _slice_widths(cfg) == [256, 1024]
+
+
+@pytest.mark.parametrize("rows", [128, 256, 1024])
+def test_latent_slice_program_holds_the_slice_kernel(one_chip, monkeypatch,
+                                                     rows):
+    """The admission slice into the scratch cache as a chip serves it
+    (``latent_slice_kernel``: the probe passed): a bucket's remainder, the
+    narrow and the wide slice of the ``gigachat`` shapes each hold the
+    slice kernel, ONE custom call a layer loop whose operand is the scratch
+    leaf in its own shape (which is how benchmarks/kernels/mla_attn.json
+    finds it) after the XLA write of the slice's rows; the compiler has put
+    no copy or transpose of the leaf around it, no block of the leaf is
+    sliced out, and no score or accumulator tensor (heads x rows x a block
+    of keys or the latent's 512, float32: the loop's ``mla_scores`` and
+    ``mla_pv``) stands in HBM."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.generate import prefill_chunk_jit
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg, params, place = _gigachat(one_chip, latent_kernel=True,
+                                   latent_slice_kernel=True)
+    assert mla.slice_tile(cfg, rows) == 1024
+    assert mla.slice_read(cfg, rows) == "kernel"
+    cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+    compiled = prefill_chunk_jit.__wrapped__.lower(
+        params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+        place(S(dtype=i32)), cache).compile()
+    text = compiled.as_text()
+    assert "q4k_expert_matmul_manyrow" in text
+    kernel = [ln for ln in text.splitlines()
+              if re.match(r"\s*(ROOT )?%flash_attention_prefill_latent", ln)]
+    # one call in the dense layers' loop, one in the routed layers'
+    assert len(kernel) == 2, kernel
+    operands = {m for ln in kernel
+                for m in re.findall(r"%([\w.-]+)", ln.split("custom-call(")[1]
+                                    .split(")")[0])}
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+?)[{ ]",
+                             text, re.M))
+    assert "bf16[7,1,16384,640]" in {shapes.get(o) for o in operands}, \
+        sorted((o, shapes.get(o)) for o in operands)
+    assert not _leaf_copies(text)
+    assert "bf16[512,640]" not in text            # the loop's block slice
+    assert re.search(
+        r"= bf16\[7,1,16384,640\]\S* dynamic-update-slice\(", text)
+    # (the absorbed query is heads x rows x 512 float32 on its way to
+    # bf16, as it was: the one such tensor, by its scope's name)
+    assert "mla_scores" not in text and "mla_pv" not in text
+    wide = [ln.strip()[:200] for ln in text.splitlines() if re.match(
+        r"\s*(ROOT )?%%\S+ = \(?f32\[64,%d,(512|1024)\]" % rows, ln)
+        and "mla_absorb_q" not in ln]
+    assert not wide, wide[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1024 * 2 ** 20 * rows // 256
 
 
 # ``k-exaone-236b-a23b-q4km-ep8-16lane`` (benchmarks/configs: 12 layers of

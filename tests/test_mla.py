@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import sys
 
 import numpy as np
@@ -362,6 +363,142 @@ def test_the_decode_kernel_serves_one_sequence_without_vmap():
                           np.asarray(want_lat, np.float32))
     assert rel(got.astype(jnp.float32), want.astype(jnp.float32)) < 1e-2
 
+# ---------------------------------------------------------------------------
+# the prefill slices' kernel on the latent leaf (ops/pallas/attention.py
+# latent_attention_prefill, interpret mode) against the XLA loop
+# ---------------------------------------------------------------------------
+
+SLICE_CTX = 2048
+# tiles of 256 rows against blocks of 512 keys scored 256 a pass: a wide
+# slice of the 4 heads is 16 tiles, and a tile walks up to 4 blocks
+SLICE_BLOCKS = dict(block_q=256, block_k=512, sub_k=256)
+
+
+@pytest.mark.parametrize("name,S,off", [
+    ("a_narrow_slice_at_offset_0", 256, 0),
+    ("a_wide_slice_at_offset_0", 1024, 0),
+    # tokens 300..555 of each head: the tile's bound crosses from block 0
+    # into block 1, and its first query lies inside a pass of 256 keys
+    ("a_bound_that_crosses_a_block_edge", 256, 300),
+    # two heads a tile, all of them over every token of the slice
+    ("the_narrow_last_slice_at_a_deep_offset", 128, 1800),
+    ("a_slice_that_ends_at_n_ctx", 256, SLICE_CTX - 256),
+    ("a_bucket_shorter_than_a_tile", 2, 37),
+])
+def test_the_slice_kernel_is_the_loop(name, S, off):
+    """A slice's weighted latents by the kernel are the loop's (bf16 out of
+    the same float32 recurrence) and, expanded, the expanded form's; where
+    no tile fits the slice's rows the branch keeps the loop, says so, and
+    the kernel refuses the shape by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import latent_attention_prefill
+    from llama_fastapi_k8s_gpu_tpu.testing import TINY_MLA_CFG
+
+    cfg = dataclasses.replace(TINY_MLA_CFG, n_ctx=SLICE_CTX,
+                              latent_slice_kernel=True)
+    H, r, d_n, d_r, d_v = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
+                           cfg.qk_rope_dim, cfg.v_head_dim)
+    W = mla.leaf_width(cfg)
+    keys = jax.random.split(jax.random.PRNGKey(S + off), 5)
+
+    def bf16(key, shape, scale=1.0):
+        return (jax.random.normal(key, shape) * scale).astype(jnp.bfloat16)
+
+    # every slot holds something: a read past a tile's bound would show
+    lat = bf16(keys[0], (3, 1, SLICE_CTX, W)).at[..., r + d_r:].set(0)
+    q_n, q_r = bf16(keys[1], (S, H, d_n)), bf16(keys[2], (S, H, d_r))
+    w_uk = bf16(keys[3], (H, d_n, r), r ** -0.5)
+    w_uv = bf16(keys[4], (H, d_v, r), r ** -0.5)
+    q_full = jnp.concatenate(
+        [mla.absorb_query(q_n, w_uk), q_r,
+         jnp.zeros((S, H, W - r - d_r), q_r.dtype)], -1)
+    pos = off + jnp.arange(S, dtype=jnp.int32)
+
+    def kernel():
+        return latent_attention_prefill(
+            q_full.transpose(1, 0, 2), lat, 1, jnp.int32(off),
+            sm_scale=mla.attn_scale(cfg), v_width=r, interpret=True,
+            **SLICE_BLOCKS)
+
+    if name == "a_bucket_shorter_than_a_tile":
+        assert mla.slice_tile(cfg, S) == 0
+        assert mla.slice_read(cfg, S) == "loop"
+        with pytest.raises(ValueError, match="no tile of the latent prefill"):
+            kernel()
+        return
+    assert mla.slice_tile(cfg, S) and mla.slice_read(cfg, S) == "kernel"
+    want = mla.latent_attention(q_full, lat, 1, pos, off + S - 1, cfg)
+    got = kernel()
+    assert got.shape == (H, S, r) and got.dtype == jnp.bfloat16
+    assert rel(got.astype(jnp.float32), want) < 1e-2
+    full = mla.expanded_attention(q_n, q_r, lat[1, 0, :off + S, :r + d_r],
+                                  w_uk, w_uv, pos, cfg)
+    assert rel(mla.expand_values(got, w_uv, jnp.float32), full) < 3e-2
+
+
+def test_the_read_of_a_slice_follows_s_the_backend_and_the_probe(
+        loaded, tokens, monkeypatch):
+    """Which read serves is decided by what the program observes: S (a
+    decode step the decode kernel, a slice the slice kernel where a tile
+    fits), the backend (``auto`` on the CPU probes nothing and keeps the
+    loop) and each kernel's own probe (a failed one keeps the loop for ITS
+    read alone, and the degrade ledger says so).  ``/health`` ``cache`` and
+    ``attn_impl`` say what they said whichever serves."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.llama import forward, init_cache
+    from llama_fastapi_k8s_gpu_tpu.obs.devtime import DEVTIME
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import probe
+
+    params, cfg = loaded
+    assert mla.CACHE.probe_kernels(cfg, "auto", "xla", []) == (cfg, "xla")
+    probed = []
+    both, impl = mla.CACHE.probe_kernels(cfg, "pallas", "xla", probed)
+    assert probed == ["latent_decode", "latent_prefill"] and impl == "xla"
+    assert both.latent_kernel and both.latent_slice_kernel
+    monkeypatch.setattr(probe, "probe_latent_prefill", lambda: "Mosaic: no")
+    before = len(DEVTIME.degrades())
+    one, _ = mla.CACHE.probe_kernels(cfg, "pallas", "xla", [])
+    assert one.latent_kernel and not one.latent_slice_kernel
+    assert [d["reason"] for d in DEVTIME.degrades()[before:]] == ["Mosaic: no"]
+
+    def kernels(c, S):
+        """The Pallas kernels in one pass of S tokens at position 32."""
+        jaxpr = jax.make_jaxpr(lambda t, cache: forward(
+            params, c, t, jnp.int32(32), cache))(
+            jnp.zeros(S, jnp.int32), init_cache(c))
+        return sorted(set(re.findall(r"flash_attention_\w+", str(jaxpr))))
+
+    assert kernels(cfg, SLICE) == kernels(cfg, 1) == []
+    assert kernels(both, SLICE) == ["flash_attention_prefill_latent"]
+    assert kernels(both, 1) == ["flash_attention_decode_latent"]
+    assert kernels(both, 2) == []            # 8 rows: no tile, the loop
+    assert kernels(one, SLICE) == []
+    assert kernels(one, 1) == ["flash_attention_decode_latent"]
+    for c, read in ((cfg, "xla"), (both, "kernel"), (one, "xla")):
+        eng = types.SimpleNamespace(cfg=c, cache=mla.CACHE, _prefix_cache=None)
+        assert Engine.cache_engine_health.fget(eng) == {
+            "latent_slice_read": read}
+        assert c.attn_impl == "xla"
+        assert Engine.cache_kind.fget(eng)["read"] == "absorbed, blocks of 512"
+    # the traced prefill span: which read, and rows in the read's own blocks
+    wide = dataclasses.replace(both, n_ctx=4096)
+    plan = [(0, 1024), (1024, 256), (1280, 2)]
+    assert mla.CACHE.note_prefill({}, wide, 1282, plan) == {
+        "cache": "latent-ring", "latent_read": "kernel+loop",
+        "latent_positions_read": 1024 + 2048 + 1536}
+    assert mla.CACHE.note_prefill({}, dataclasses.replace(
+        wide, latent_slice_kernel=False), 1282, plan)[
+        "latent_positions_read"] == 1024 + 1536 + 1536
+
 
 def test_yarn_frequencies_are_the_published_blend():
     from llama_fastapi_k8s_gpu_tpu.models import mla
@@ -401,14 +538,17 @@ def test_a_claimed_prefix_gives_the_logits_of_a_full_prefill(loaded, tokens):
 
 def with_kernel(cfg, monkeypatch):
     """``cfg`` as an engine on a TPU leaves it: the decode kernel serves a
-    step (interpret mode here), in blocks of 16 so that a lane of these
-    tests walks several."""
+    step and the slice kernel a prefill slice (interpret mode here), in
+    blocks of 16 so that a lane and a slice of these tests walk several."""
     from llama_fastapi_k8s_gpu_tpu.models import mla
     from llama_fastapi_k8s_gpu_tpu.models.llama import decode_kernel_block
 
     monkeypatch.setattr(mla, "LATENT_KERNEL_BLOCK", 16)
-    cfg = dataclasses.replace(cfg, latent_kernel=True)
+    monkeypatch.setattr(mla, "LATENT_SLICE_BLOCK", 16)
+    cfg = dataclasses.replace(cfg, latent_kernel=True,
+                              latent_slice_kernel=True)
     assert decode_kernel_block(cfg) == 16
+    assert mla.slice_tile(cfg, SLICE) == cfg.n_heads * SLICE
     return cfg
 
 
@@ -888,7 +1028,7 @@ def test_the_read_counters_follow_who_reads(kernel, read, rows, who):
     assert eng.cache_counts == {
         "read": read,
         "live": sum(range(101, 105)) + sum(range(1501, 1505)),
-        "rows_written": rows}
+        "rows_written": rows, "slices_kernel": 0, "slices_loop": 0}
     assert _ring_write(cfg) == who and cfg.attn_impl == "xla"
     assert Engine.cache_kind.fget(eng)["read"] == "absorbed, blocks of 512"
 
@@ -905,7 +1045,9 @@ def test_the_lane_engine_serves_through_the_kernel(gguf_path):
                            decode_chunk=4, batch_size=3, dp=1,  # no mesh
                            attn_impl="pallas")
     try:
-        assert eng.cfg.latent_kernel and eng.cfg.attn_impl == "xla"
+        assert eng.cfg.latent_kernel and eng.cfg.latent_slice_kernel
+        assert eng.cfg.attn_impl == "xla"
+        assert eng.cache_engine_health == {"latent_slice_read": "kernel"}
         first = eng.submit(MSGS, max_tokens=10, temperature=0.0).result(
             timeout=300)
         assert first["usage"]["completion_tokens"] >= 1
@@ -919,6 +1061,9 @@ def test_the_lane_engine_serves_through_the_kernel(gguf_path):
         assert gauges["ring_rows_written_total"] > 0
         assert 0 < gauges["latent_positions_live_total"] \
             <= gauges["latent_positions_read_total"]
+        # every slice is 16 tokens of 4 heads: one tile of the slice kernel
+        assert gauges["latent_slices_kernel_total"] > 0
+        assert gauges["latent_slices_loop_total"] == 0
     finally:
         eng.shutdown()
 
