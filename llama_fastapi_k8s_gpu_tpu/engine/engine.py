@@ -856,8 +856,24 @@ class Engine:
         if not self.cfg.n_experts:
             return None
         if self._expert_counters is None:
-            self._expert_counters = ExpertCounters(self.cfg.n_held)
+            self._expert_counters = ExpertCounters(self.cfg.n_held,
+                                                   self.expert_slots)
         return self._expert_counters
+
+    @property
+    def expert_slots(self) -> int:
+        """The slots of a decode step's grouped expert call (``T``: /health
+        ``engine.expert_slots``), which ends its grid at those in use; 0
+        where the experts serve dequantized and for a dense block."""
+        from ..models.params import flat_layers
+        from ..ops.pallas.experts import decode_slots, family_of
+
+        experts = [leaf for name, leaf in flat_layers(self.params["layers"])
+                   if name.endswith("_exps")]
+        if not experts or not all(family_of(leaf) for leaf in experts):
+            return 0
+        return decode_slots(self.cfg.n_held, getattr(self, "batch_size", 1),
+                            self.cfg.n_experts_used)
 
     def _next_seed(self) -> int:
         with self._id_lock:

@@ -10,6 +10,11 @@ totals are folded when somebody reads them: a ``/metrics`` scrape folds the
 chunks that have finished (:meth:`snapshot`), a test folds them all
 (``block=True``).  With nobody reading, nothing on the decode path pays
 more than the append.
+
+``slots_skipped`` is host arithmetic on those totals: a decode step's
+grouped expert call has ``n_slots`` slots whatever the router picked
+(ops/pallas/experts.py ``decode_slots``) and its grid ends at the slots in
+use, so ``layer_steps x n_slots - experts_read`` slots were never walked.
 """
 
 from __future__ import annotations
@@ -22,7 +27,8 @@ _MAX_PENDING = 64   # older chunks have long finished: folding them is free
 
 
 class ExpertCounters:
-    def __init__(self, n_held: int):
+    def __init__(self, n_held: int, n_slots: int = 0):
+        self.n_slots = n_slots      # 0: no grouped few-row call serves
         self._lock = threading.Lock()
         self._pending: list = []
         self._total = np.zeros(3 + n_held, np.int64)
@@ -38,7 +44,8 @@ class ExpertCounters:
         """Cumulative counters of the chunks that have finished (all
         dispatched chunks with ``block``): ``layer_steps``, ``experts_read``,
         ``picks`` (a list, one count per held expert), ``picks_held`` (their
-        sum) and ``picks_total`` (over all the router's experts)."""
+        sum), ``picks_total`` (over all the router's experts) and
+        ``slots_skipped`` (module docstring)."""
         with self._lock:
             keep = []
             for s in self._pending:
@@ -50,4 +57,6 @@ class ExpertCounters:
             t = self._total
             return {"layer_steps": int(t[0]), "experts_read": int(t[1]),
                     "picks": t[2:-1].tolist(), "picks_held": int(t[2:-1].sum()),
-                    "picks_total": int(t[-1])}
+                    "picks_total": int(t[-1]),
+                    "slots_skipped": int(t[0] * self.n_slots - t[1])
+                    if self.n_slots else 0}

@@ -308,6 +308,13 @@ METRICS: dict[str, Metric] = _register(
            "distinct experts whose weights a decode step's live lanes made "
            "the grouped matmuls read, summed over the (layer, step) pairs; "
            "over expert_layer_steps_total = experts read per layer-step"),
+    Metric("expert_slots_skipped_total", GAUGE,
+           "slots of the decode steps' grouped expert calls that held no "
+           "expert and were never walked (the call's grid ends at the "
+           "slots in use): expert_layer_steps_total x the slots of a call "
+           "(/health engine.expert_slots: the held experts or a step's "
+           "(token, pick) rows, the fewer) - experts_read_total; host "
+           "arithmetic at scrape; 0 where the experts serve dequantized"),
     Metric("expert_picks_total", GAUGE,
            "(token, pick) rows each expert took in decode chunks, "
            "cumulative; the largest over their sum is the most-loaded "

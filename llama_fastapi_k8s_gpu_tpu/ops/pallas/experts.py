@@ -7,24 +7,32 @@ Q4_K / Q6_K planes the dense matmuls read (ops/pallas/qmatmul.py,
 q6matmul.py) with an (L, E) pair of leading axes, and computed by the SAME
 kernel bodies.  What is new is the grid around them:
 
-- the grid is (N tile, expert slot, K tile); the layer, the number of
-  slots in use and each slot's expert ride a prefetched scalar vector, and
-  the weight BlockSpecs address ``planes[layer, expert]`` through it, as
-  ``qmatmul.stacked_pallas_call`` addresses ``planes[layer]``.  Slots past
-  the last one in use repeat its block indices (no DMA) and skip the body,
-  so a step reads and multiplies the experts its rows picked and no others;
+- the grid has an expert-slot axis beside the N and K tiles; the layer, the
+  number of slots in use and each slot's expert ride a prefetched scalar
+  vector, and the weight BlockSpecs address ``planes[layer, expert]``
+  through it, as ``qmatmul.stacked_pallas_call`` addresses
+  ``planes[layer]``.  The slot axis ENDS at the slots in use (a traced grid
+  bound: ``slot_extent`` of the prefetched count), so a step reads and
+  multiplies the experts its rows picked and takes no grid step for any
+  other: a step that skips its body still costs 0.1-0.2 us, and a
+  saturated decode step of LFM2 leaves 35 of 64 slots idle (PERF.md
+  section 6, PR 51);
 - FEW rows (a decode step: lanes x k (token, pick) rows, at most
   ``FEW_ROWS``): a slot is a distinct expert; every slot sees ALL the rows
   (one resident block) and adds its product into the one output block for
   the rows that picked its expert, zero for the rest
-  (:func:`grouped_matmul_few`).  No sort, no gather, no padding: the MXU
-  does a few times the work of the picked rows alone, which is not what
-  bounds these kernels;
+  (:func:`grouped_matmul_few`).  Grid (slot, N tile, K tile), the output
+  block all of N and resident for the whole call: the traced extent is
+  the outermost axis, so the pipeline never restarts inside the call.  No
+  sort, no gather, no padding: the MXU does a few times the work of the
+  picked rows alone, which is not what bounds these kernels;
 - MANY rows (a prefill slice): the rows are sorted by expert and laid out
   in tiles of ``TM_MANY`` rows, each expert's rows padded up to whole
   tiles, so a slot is a row tile of one expert (:func:`plan_groups`,
-  :func:`grouped_matmul_many`).  The split is the one ``qmatmul
-  .kernel_name`` makes for the dense kernels.
+  :func:`grouped_matmul_many`).  Grid (N tile, slot, K tile): the output
+  block moves with the slot, and consecutive tiles of one expert re-read
+  no weights.  The split is the one ``qmatmul.kernel_name`` makes for the
+  dense kernels.
 
 The kernels take K in tiles of 2048.  An expert matrix whose K is a
 divisor of 2048 (OLMoE's down projection: K = 1024) is *folded*: ``f =
@@ -69,6 +77,7 @@ from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
 
 FEW_ROWS = 128   # (token, pick) rows up to which every slot sees all rows
 TM_MANY = 128    # rows per tile of a prefill slice
+FEW_VMEM = 64 * 2 ** 20  # a few-row call's limit: its output block is all N
 
 
 def padded_k(k_in: int) -> int:
@@ -161,7 +170,7 @@ def prep_experts(raw: np.ndarray, n_experts: int, n_out: int, k_in: int,
 
 def experts_in_use(row_expert: jax.Array, n_experts: int, n_slots: int):
     """(rows per expert (E,), the distinct experts in rising order padded to
-    ``n_slots`` by repeating the last (so that an idle slot moves no block),
+    ``n_slots`` by repeating the last (the grid walks the first ``n_used``),
     how many there are).  ``row_expert`` (R,) in [0, E]; E = no expert."""
     i32 = jnp.int32
     count = jnp.zeros(n_experts + 1, i32).at[row_expert].add(1)[:n_experts]
@@ -174,6 +183,22 @@ def experts_in_use(row_expert: jax.Array, n_experts: int, n_slots: int):
     experts = jnp.where(t < n_used, experts,
                         experts[jnp.maximum(n_used - 1, 0)])
     return count, experts, n_used
+
+
+def slot_extent(n_used):
+    """How far the grid's slot axis runs: the slots in use, and one (which
+    skips its body: the output is still zero-filled) when there is none."""
+    return jnp.maximum(n_used, 1)
+
+
+def decode_slots(n_experts: int, n_tokens: int, k: int) -> int:
+    """The slots ``T`` of a few-row call of ``n_tokens`` tokens' picks: a
+    slot a distinct expert, so the held experts or the (token, pick) rows,
+    the fewer; 0 for more rows than :data:`FEW_ROWS` (the many-row plan's
+    slots are row tiles).  Of them a call walks those in use
+    (``expert_slots_skipped_total``: engine/expert_counters.py)."""
+    rows = n_tokens * k
+    return min(n_experts, rows) if rows <= FEW_ROWS else 0
 
 
 def n_tiles(n_rows: int, n_experts: int, n_tokens: int, tm: int) -> int:
@@ -276,55 +301,65 @@ def expert_kernel_name(family: str, few: bool) -> str:
 
 def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
                   extra_in: tuple, interpret: bool, variant: str):
-    """The pallas_call both regimes share.  Grid (N tile, slot, K tile);
-    ``meta`` = [layer, slots in use, expert of slot 0..T-1].  ``few``: the
-    activation and output blocks are the whole (rows, ...) arrays, and
-    ``extra_in`` = the rows' experts (rows, 1); else they are slot ``t``'s
-    ``rows`` rows."""
+    """The pallas_call both regimes share.  ``meta`` = [layer, slots in
+    use, expert of slot 0..T-1]; the slot axis of the grid runs to
+    :func:`slot_extent` of ``meta[1]``, not to T.  ``few``: grid (slot, N
+    tile, K tile), the activation block all the rows, the output ONE
+    resident block of all N that every slot adds into, and ``extra_in`` =
+    the rows' experts (rows, 1).  Else grid (N tile, slot, K tile), and the
+    activation and output blocks are slot ``t``'s ``rows`` rows."""
     from jax.experimental.pallas import tpu as pltpu
 
     kt = xpa.shape[1] // fam.tka
-    T = meta.shape[0] - 2
     N = planes[0].shape[2]
     TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(rows, fam.tn_prefs))
+    slots = slot_extent(meta[1])
+    if few:
+        grid = (slots, N // TN, kt)
+        out_spec = pl.BlockSpec((rows, N), lambda t, n, k, m: (0, 0))
+        kw = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=FEW_VMEM)}
+    else:
+        grid = (N // TN, slots, kt)
+        out_spec = pl.BlockSpec((rows, TN), lambda n, t, k, m: (t, n))
+        kw = {}
 
-    def rtile(t, m):         # a slot past the last in use stays on it
-        return 0 if few else jnp.minimum(t, jnp.maximum(m[1] - 1, 0))
-
-    def ktile(t, k, m):
-        return jnp.where(t < m[1], k, kt - 1)
+    def ax(f):               # the index maps below are written in (n, t, k, m)
+        return (lambda t, n, k, m: f(n, t, k, m)) if few else f
 
     specs = [pl.BlockSpec((rows, fam.tka),
-                          lambda n, t, k, m: (rtile(t, m), ktile(t, k, m)))]
-    specs += [pl.BlockSpec((rows, 1), lambda n, t, k, m: (0, 0))
+                          ax(lambda n, t, k, m: (0 if few else t, k)))]
+    specs += [pl.BlockSpec((rows, 1), ax(lambda n, t, k, m: (0, 0)))
               for _ in extra_in]
     specs += [pl.BlockSpec((1, 1, TN, w),
-                           lambda n, t, k, m: (m[0], m[2 + t], n,
-                                               ktile(t, k, m)))
+                           ax(lambda n, t, k, m: (m[0], m[2 + t], n, k)))
               for w in fam.widths]
     specs.append(pl.BlockSpec((1, 1, 1, TN, 128),
-                              lambda n, t, k, m: (m[0], m[2 + t],
-                                                  ktile(t, k, m), n, 0)))
+                              ax(lambda n, t, k, m: (m[0], m[2 + t], k, n,
+                                                     0))))
 
     def body(meta_ref, x_ref, *rest):
-        t, k = pl.program_id(1), pl.program_id(2)
+        t, n, k = (pl.program_id(i) for i in ((0, 1, 2) if few
+                                              else (1, 0, 2)))
         o_ref = rest[-1]
         plane_refs = [_NoLead2(r) for r in rest[len(extra_in):-1]]
         if few:
-            # every slot adds into the one block: its rows' share of it
+            # every slot adds into the one block: its rows' share of the
+            # N tile's columns
             mine = (rest[0][...] == meta_ref[2 + t]).astype(jnp.float32)
+            cols = pl.ds(pl.multiple_of(n * TN, TN), TN)
 
-            @pl.when((t == 0) & (k == 0))
+            @pl.when((t == 0) & (n == 0) & (k == 0))
             def _():
                 o_ref[...] = jnp.zeros_like(o_ref)
 
             def accum(o_ref, part):
-                o_ref[...] += part * mine
+                o_ref[:, cols] += part * mine
         else:
             def accum(o_ref, part):
                 o_ref[...] = jnp.where(k == 0, part, o_ref[...] + part)
 
-        @pl.when(t < meta_ref[1])
+        @pl.when(t < meta_ref[1])       # false only where no slot is in use
         def _():
             fam.kernel(x_ref, *plane_refs, o_ref, interpret=interpret,
                        variant=variant, accum=accum)
@@ -332,11 +367,10 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(N // TN, T, kt), in_specs=specs,
-            out_specs=pl.BlockSpec((rows, TN),
-                                   lambda n, t, k, m: (rtile(t, m), n))),
+            num_scalar_prefetch=1, grid=grid, in_specs=specs,
+            out_specs=out_spec),
         out_shape=jax.ShapeDtypeStruct((xpa.shape[0], N), jnp.float32),
-        interpret=interpret, name=expert_kernel_name(fam.name, few),
+        interpret=interpret, name=expert_kernel_name(fam.name, few), **kw,
     )(meta, xpa, *extra_in, *planes)
 
 
@@ -359,7 +393,8 @@ def grouped_matmul_few(fam: _Family, meta, x, row_expert, planes, f: int,
 def grouped_matmul_many(fam: _Family, meta, xp, planes, f: int,
                         interpret: bool, variant: str) -> jax.Array:
     """xp (T*TM_MANY, K), rows in the padded layout of :func:`plan_groups`,
-    against ``planes[meta[0], tile's expert]`` -> (T*TM_MANY, N) f32."""
+    against ``planes[meta[0], tile's expert]`` -> (T*TM_MANY, N) f32 (the
+    tiles past the last in use are not written: no row's ``pos`` is there)."""
     out = _grouped_call(fam, meta, _activations(
         _fold_rows(xp, f, TM_MANY), fam), planes, TM_MANY * f, False, (),
         interpret, variant)
@@ -385,7 +420,8 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
     row_expert = picks.reshape(R)
     few = R <= FEW_ROWS
     if few:
-        count, experts, n_used = experts_in_use(row_expert, E, min(E, R))
+        count, experts, n_used = experts_in_use(row_expert, E,
+                                                decode_slots(E, M, k))
         xr = jnp.repeat(x, k, axis=0)                  # row (m, j) = x[m]
 
         def call(fam, variant, rows, w):

@@ -1508,6 +1508,13 @@ def create_app(engine=None, settings: Settings | None = None,
             # which of the kind's own reads serves (a latent ring's prefill
             # slices: ``latent_slice_read``); no key for most kinds
             engine_info.update(getattr(eng, "cache_engine_health", None) or {})
+            # a routed file's grouped expert call: the slots of a decode
+            # step's grid, of which it walks those in use
+            # (expert_slots_skipped_total counts the rest); no key on a
+            # dense block
+            slots = getattr(eng, "expert_slots", 0)
+            if slots:
+                engine_info["expert_slots"] = slots
             # a vocabulary the tokenizer cannot cut at spaces pays the
             # whole-text merge loop on every prompt (tokenizer/spm.py);
             # absent where it can
@@ -1624,6 +1631,7 @@ def create_app(engine=None, settings: Settings | None = None,
             snap = ec.snapshot()
             m.set_gauge("expert_layer_steps_total", snap["layer_steps"])
             m.set_gauge("experts_read_total", snap["experts_read"])
+            m.set_gauge("expert_slots_skipped_total", snap["slots_skipped"])
             for e, n in enumerate(snap["picks"]):
                 m.set_gauge("expert_picks_total", n, expert=str(e))
             m.set_gauge("expert_picks_routed_total", snap["picks_total"])

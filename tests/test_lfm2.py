@@ -680,6 +680,8 @@ async def test_the_server_serves_the_file_and_names_the_kind(lane_engine):
             assert eng["cache"]["l_cache"] == 3
             assert eng["cache"]["heads_per_ring_row"] == 2
             assert eng["ring_write"] == "xla"
+            # the experts serve dequantized here: no grouped call, no slots
+            assert "expert_slots" not in eng and not lane_engine.expert_slots
             assert set(eng["weight_formats"]) >= {
                 "conv.in_proj", "conv.out_proj", "attn.wq", "attn.wo",
                 "dense.w_down", "moe.w_gate_exps", "moe.w_down_exps"}
@@ -689,6 +691,6 @@ async def test_the_server_serves_the_file_and_names_the_kind(lane_engine):
             for name in ("conv_state_updates_total",
                          "conv_state_starts_total", "ring_slots_read_total",
                          "experts_read_total", "expert_layer_steps_total",
-                         "expert_picks_total"):
+                         "expert_slots_skipped_total", "expert_picks_total"):
                 assert name in m, name
         await app.router.shutdown()

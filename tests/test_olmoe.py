@@ -274,11 +274,17 @@ def test_plan_groups_gives_every_row_a_slot_in_a_tile_of_its_expert(
 
 @pytest.mark.parametrize("rows,slots", [
     ([3, 3, 0, 7, 8, 8, 1], 4),        # 8 = no expert
-    ([8, 8, 8], 3), ([5], 1), ([0, 1, 2, 3, 4, 5, 6, 7], 8)])
+    ([8, 8, 8], 3), ([5], 1), ([0, 1, 2, 3, 4, 5, 6, 7], 8),
+    # the extent of the slot axis: none, one, a part and all of the slots
+    ([8] * 12, 8), ([2] * 12, 8), ([6, 8, 1, 6, 1, 8, 4, 4, 1], 8),
+    ([7, 6, 5, 4, 3, 2, 1, 0, 0, 7], 8)])
 def test_experts_in_use_lists_the_distinct_experts_once(rows, slots):
     """The few-row regime's slots: one per distinct expert, rising, idle
-    slots repeating the last so that they move no block."""
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import experts_in_use
+    slots repeating the last so that they move no block; the grid's slot
+    axis ends at ``n_used`` (one slot, which skips its body, when no row
+    has an expert), of the ``decode_slots`` a call of these rows has."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
+        FEW_ROWS, decode_slots, experts_in_use, slot_extent)
 
     E = 8
     count, experts, n_used = experts_in_use(
@@ -289,6 +295,9 @@ def test_experts_in_use_lists_the_distinct_experts_once(rows, slots):
     assert int(n_used) == len(distinct)
     want = distinct + [distinct[-1] if distinct else 0] * (slots - len(distinct))
     assert np.asarray(experts).tolist() == want
+    assert int(slot_extent(n_used)) == max(len(distinct), 1) <= slots
+    assert decode_slots(E, len(rows), 1) == min(E, len(rows)) >= slots
+    assert decode_slots(E, FEW_ROWS // 2 + 1, 2) == 0     # the many-row plan
 
 
 # ---------------------------------------------------------------------------
@@ -296,58 +305,129 @@ def test_experts_in_use_lists_the_distinct_experts_once(rows, slots):
 # ---------------------------------------------------------------------------
 
 def _expert_weights(E, N, K, gtype, rng, L=2):
+    """(fused planes, bf16 copies of their dequantized values) of L layers
+    of E experts (N, K); a K the K tile does not divide (1536) has its
+    last tile filled up with zero blocks, as the loader does."""
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.gguf import GGMLType, quants
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import prep_experts
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
+        padded_k, prep_experts)
 
     fused, plain = [], []
+    k_pad = padded_k(K)
     for _ in range(L):
         w = rng.standard_normal((E, N, K)).astype(np.float32) * K ** -0.5
         raw = np.asarray(quants.quantize(w, GGMLType[gtype]))
         plain.append(quants.dequantize(raw, GGMLType[gtype], w.size
                                        ).reshape(E, N, K))
-        fused.append(prep_experts(raw, E, N, K, GGMLType[gtype]))
+        raw = raw.reshape(E * N, -1)
+        raw = np.pad(raw, ((0, 0), (0, raw.shape[1] * (k_pad - K) // K)))
+        fused.append(prep_experts(raw.reshape(-1), E, N, k_pad,
+                                  GGMLType[gtype]))
     return ({key: jnp.stack([f[key] for f in fused]) for key in fused[0]},
             {"w": jnp.asarray(np.stack(plain), jnp.bfloat16)})
 
 
-@pytest.mark.parametrize("D,F,E,k,M", [
-    (256, 256, 8, 2, 5),        # fold 8 both ways, few rows
-    (256, 256, 8, 2, 80),       # many rows: 160 > 128
-    (2048, 1024, 4, 2, 3),      # OLMoE's widths: gate unfolded, down fold 2
-    (2048, 1024, 4, 3, 50)])
-def test_grouped_kernels_agree_with_the_dequantized_experts(D, F, E, k, M):
-    """Same rows, same weights, fused planes against bf16 copies of their
-    dequantized values; a token without a pick (``E``) gets nothing; under
-    ``vmap`` the lanes become rows of ONE call and the counts are the
-    step's, not a lane's."""
+def _picks(rng, M, k, E, used):
+    """(M, k) picks: ``used`` None = ``k`` distinct experts a token at
+    random, token 1 without any; else exactly ``used`` distinct experts
+    spread over the ``E`` (0: every pick ``E``, no expert at all)."""
+    if used is None:
+        picks = np.stack([rng.permutation(E)[:k] for _ in range(M)])
+        picks[1] = E
+    elif used == 0:
+        picks = np.full((M, k), E)
+    else:
+        pool = (np.arange(used) * E) // used
+        picks = pool[np.arange(M * k) % used].reshape(M, k)
+    return picks.astype(np.int32)
+
+
+@pytest.mark.parametrize("D,F,E,k,M,used", [
+    (256, 256, 8, 2, 5, None),      # fold 8 both ways, few rows
+    (256, 256, 8, 2, 80, None),     # many rows: 160 > 128
+    (2048, 1024, 4, 2, 3, None),    # OLMoE's widths: gate unfolded, down fold 2
+    (2048, 1024, 4, 3, 50, None),
+    # the slot axis ends at the slots in use: none (zeros), one, a part and
+    # all of T = min(E, rows); down folded (K 1024), zero-filled (K 1536:
+    # LFM2's) and plain (K 2048)
+    (256, 256, 8, 2, 5, 0),
+    (256, 1024, 4, 1, 6, 1),
+    (256, 1536, 4, 2, 3, 2),
+    (256, 2048, 4, 2, 3, 4)])
+def test_grouped_kernels_agree_with_the_dequantized_experts(D, F, E, k, M,
+                                                            used):
+    """Same rows, same weights, fused planes (Q4_K gate and up, Q6_K down)
+    against bf16 copies of their dequantized values; a token without a pick
+    (``E``) gets nothing; under ``vmap`` the lanes become rows of ONE call
+    and the counts are the step's, not a lane's."""
     import jax
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import routed_experts
 
     rng = np.random.default_rng(D + M)
-    g, gd = _expert_weights(E, F, D, "Q4_K", rng)
-    u, ud = _expert_weights(E, F, D, "Q4_K", rng)
-    d, dd = _expert_weights(E, D, F, "Q6_K", rng)
+    L = 2 if used is None else 1
+    g, gd = _expert_weights(E, F, D, "Q4_K", rng, L)
+    u, ud = _expert_weights(E, F, D, "Q4_K", rng, L)
+    d, dd = _expert_weights(E, D, F, "Q6_K", rng, L)
     x = jnp.asarray(rng.standard_normal((M, D)), jnp.bfloat16)
-    picks = np.stack([rng.permutation(E)[:k] for _ in range(M)]
-                     ).astype(np.int32)
-    picks[1] = E
+    picks = _picks(rng, M, k, E, used)
+    live = int((picks < E).sum())
     picks = jnp.asarray(picks)
     wts = jnp.asarray(rng.random((M, k)), jnp.float32)
-    y, count = routed_experts(x, picks, wts, g, u, d, 1)
-    y2, count2 = routed_experts(x, picks, wts, gd, ud, dd, 1)
-    assert rel(y, y2) < SAME
+    y, count = routed_experts(x, picks, wts, g, u, d, L - 1)
+    y2, count2 = routed_experts(x, picks, wts, gd, ud, dd, L - 1)
+    if live:
+        assert rel(y, y2) < SAME
+    else:
+        assert not np.asarray(y, np.float32).any()
     np.testing.assert_array_equal(count, count2)
-    assert int(np.asarray(count).sum()) == (M - 1) * k
-    assert not np.asarray(y[1], np.float32).any()
-    yv, cv = jax.vmap(lambda a, p, w: routed_experts(a, p, w, g, u, d, 1))(
+    assert int(np.asarray(count).sum()) == live
+    if used is None:
+        assert not np.asarray(y[1], np.float32).any()
+    else:
+        assert int((np.asarray(count) > 0).sum()) == used
+    yv, cv = jax.vmap(lambda a, p, w: routed_experts(a, p, w, g, u, d, L - 1))(
         x[:, None], picks[:, None], wts[:, None])
     np.testing.assert_allclose(np.asarray(yv[:, 0], np.float32),
                                np.asarray(y, np.float32), rtol=0, atol=0)
     np.testing.assert_array_equal(cv[0], count)
+
+
+@pytest.mark.parametrize("gtype,used", [("Q4_K", 3), ("Q6_K", 1),
+                                        ("Q6_K", 4)])
+def test_a_few_row_call_is_its_rows_through_the_dense_kernel_bit_for_bit(
+        gtype, used):
+    """The grouped call changes the GRID around the dense kernels' bodies,
+    not the arithmetic: each row's result is, bit for bit, that row of the
+    dense fused matmul of ITS expert's planes over the same row block (one
+    expert at a time), whatever the number of slots the grid walked."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    E, N, K, R, layer = 4, 128, 2048, 6, 0
+    rng = np.random.default_rng(used)
+    w, _ = _expert_weights(E, N, K, gtype, rng, L=1)
+    fam = X.FAMILIES[X.family_of(w)]
+    planes = [w[key] for key in fam.planes]
+    x = jnp.asarray(rng.standard_normal((R, K)), jnp.bfloat16)
+    row_expert = _picks(rng, R, 1, E, used).reshape(R)
+    row_expert[2] = E                                  # a row without one
+    _, slots, n_used = X.experts_in_use(row_expert, E, X.decode_slots(E, R, 1))
+    meta = jnp.concatenate([jnp.asarray([layer], jnp.int32), n_used[None],
+                            slots])
+    got = np.asarray(X.grouped_matmul_few(
+        fam, meta, x, jnp.asarray(row_expert), planes, 1, True, "cur"))
+    xpa = X._activations(jnp.pad(x, ((0, 16 - R), (0, 0))), fam)
+    dense = {"q4k": X._q4._q4k_2d_raw, "q6k": X._q6._q6k_2d_raw}[fam.name]
+    for r, e in enumerate(row_expert):
+        want = np.zeros(N, np.float32) if e == E else np.asarray(
+            dense(xpa, *(p[layer, e] for p in planes), True, "cur"))[r]
+        np.testing.assert_array_equal(got[r], want)
+    assert np.abs(got).sum() > 0
 
 
 def test_the_experts_probe_passes_in_interpret_mode():
@@ -627,15 +707,20 @@ def test_expert_counters_fold_finished_chunks_when_read():
     from llama_fastapi_k8s_gpu_tpu.engine.expert_counters import (
         ExpertCounters)
 
-    c = ExpertCounters(3)
+    c = ExpertCounters(3, n_slots=3)
     assert c.snapshot() == {"layer_steps": 0, "experts_read": 0,
                             "picks": [0, 0, 0], "picks_held": 0,
-                            "picks_total": 0}
+                            "picks_total": 0, "slots_skipped": 0}
     for _ in range(70):                 # past the pending bound: still exact
         c.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
     assert c.snapshot(block=True) == {
         "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280],
-        "picks_held": 350, "picks_total": 630}
+        "picks_held": 350, "picks_total": 630,
+        # 140 calls of 3 slots walked 210 of them
+        "slots_skipped": 140 * 3 - 210}
+    d = ExpertCounters(3)               # no grouped few-row call: no slots
+    d.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
+    assert d.snapshot(block=True)["slots_skipped"] == 0
 
 
 @pytest.mark.parametrize("engine", ["serial", "lanes"])
@@ -675,6 +760,44 @@ def test_the_engines_serve_the_file_and_count_their_decode_steps(
     assert k * steps <= snap["experts_read"] <= rows
     if engine == "serial":
         assert rows == k * steps == snap["experts_read"]
+    # the slots of a step's grouped call (/health engine.expert_slots) and
+    # those its grid never walked
+    assert eng.expert_slots == min(eng.cfg.n_held, lanes * k)
+    assert snap["slots_skipped"] == (steps * eng.expert_slots
+                                     - snap["experts_read"]) >= 0
+
+
+@pytest.mark.anyio
+async def test_health_names_the_slots_and_metrics_the_ones_skipped(gguf_path):
+    """A file served through the grouped kernels: /health ``engine`` has the
+    slots of a decode step's call, /metrics the gauge of those no grid
+    walked (host arithmetic on the device's counters)."""
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    import jax.numpy as jnp
+
+    eng = Engine(gguf_path, weight_format="q4k", n_ctx=128)
+    k, held = eng.cfg.n_experts_used, eng.cfg.n_held
+    assert eng.expert_slots == k               # one lane's picks
+    # a chunk of 6 (layer, step) pairs that read 2 experts each
+    eng.expert_counters.push(jnp.asarray(
+        [6, 12] + [0] * held + [6 * k], jnp.int32))
+    app = create_app(engine=eng, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            info = (await client.get("/health")).json()["engine"]
+            assert info["expert_slots"] == k
+            eng.expert_counters.snapshot(block=True)
+            m = (await client.get("/metrics")).text
+            assert f"expert_slots_skipped_total {6 * k - 12}" in m
+        await app.router.shutdown()
 
 
 def test_a_dense_file_has_no_expert_counters(tmp_path):
@@ -683,14 +806,15 @@ def test_a_dense_file_has_no_expert_counters(tmp_path):
 
     path = str(tmp_path / "d.gguf")
     write_tiny_llama_gguf(path)
-    assert Engine(path, n_ctx=64).expert_counters is None
+    eng = Engine(path, n_ctx=64)
+    assert eng.expert_counters is None and eng.expert_slots == 0
 
 
 def test_the_expert_metrics_are_in_the_catalog():
     from llama_fastapi_k8s_gpu_tpu.obs.catalog import GAUGE, METRICS
 
     for name in ("expert_layer_steps_total", "experts_read_total",
-                 "expert_picks_total"):
+                 "expert_picks_total", "expert_slots_skipped_total"):
         assert METRICS[name].mtype == GAUGE
     assert METRICS["expert_picks_total"].labels == ("expert",)
 

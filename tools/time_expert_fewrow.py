@@ -1,0 +1,223 @@
+"""Time the grouped expert call ALONE on the chip (ops/pallas/experts.py
+``_grouped_call``), at the four routed configurations' shapes, over the
+number of slots in use: the parent commit's grid (``--parent``: its
+``experts.py``, loaded beside this tree's under another name; it walks all T
+slots) and this tree's side by side, the same planes, rows and meta vector,
+the results compared bit for bit.  Without the parent's file it times this
+tree's alone.
+
+A call takes 50-700 us and a dispatch round trip about 1 ms, so a timing is
+the SLOPE of one jitted loop of calls over its trip count (the layer index
+walks the planes' leading axis, as a decode step's does): ``(t(3n) - t(n)) /
+2n``, each ``t`` the median of ``--reps`` runs.  One JSON line a timing; per
+(shape, slots in use) also the cost of one idle grid step, ``(parent - new) /
+((T - used) x N tiles x K tiles)``, which PERF.md section 6 (PR 51) sets
+beside the issue's reckoned 0.16-0.35 us.
+
+    git archive --prefix=.parent_check/ <parent> | tar x
+    chiprun -- python tools/time_expert_fewrow.py
+
+A device number: it refuses to run without a TPU."""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# (name, family, rows of the call, experts held = T, n_out, k_in): a decode
+# step's gate / up call (Q4_K) and down call (Q6_K) at 16 lanes (8: olmoe)
+FEW = (
+    ("lfm2.gate", "q4k", 64, 64, 1536, 2048),
+    ("lfm2.down", "q6k", 64, 64, 2048, 1536),       # K held at 2048
+    ("olmoe.gate", "q4k", 64, 64, 1024, 2048),
+    ("olmoe.down", "q6k", 64, 64, 2048, 1024),      # folded: 128 rows of 2048
+    ("gigachat.gate", "q4k", 128, 32, 2048, 7168),  # K held at 8192
+    ("gigachat.down", "q6k", 128, 32, 7168, 2048),
+    ("kexaone.gate", "q4k", 128, 16, 2048, 6144),
+    ("kexaone.down", "q6k", 128, 16, 6144, 2048),
+)
+USED = (1, 4, 7, 23, 29, 41)
+# a wide prefill slice (1024 tokens): (name, family, tokens, picks a token,
+# experts the router ranks, experts held, n_out, k_in)
+MANY = (
+    ("lfm2.gate", "q4k", 1024, 4, 64, 64, 1536, 2048),
+    ("lfm2.down", "q6k", 1024, 4, 64, 64, 2048, 1536),
+    ("gigachat.gate", "q4k", 1024, 8, 256, 32, 2048, 7168),
+    ("gigachat.down", "q6k", 1024, 8, 256, 32, 7168, 2048),
+)
+LAYERS = 3
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--parent", default=".parent_check/llama_fastapi_k8s_gpu_tpu"
+                    "/ops/pallas/experts.py",
+                    help="the parent commit's experts.py (its kernel bodies "
+                    "are this tree's: PR 51 changed the grid alone)")
+    ap.add_argument("--no-many", action="store_true",
+                    help="skip the wide-slice shapes")
+    ap.add_argument("--reps", type=int, default=8)
+    ap.add_argument("--calls", type=int, default=24,
+                    help="n: the loops run n and 3n calls")
+    ap.add_argument("--only", default="", help="substring of a shape's name")
+    ap.add_argument("--used", default=",".join(map(str, USED)),
+                    help="slots in use to time (T is always timed)")
+    ap.add_argument("--out", default="chiprun_out/time_expert_fewrow.jsonl")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    sides = {"new": X}
+    if os.path.exists(args.parent):
+        spec = importlib.util.spec_from_file_location(
+            X.__name__ + "_parent", args.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        sides = {"parent": parent, "new": X}
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(json.dumps({"error": f"platform {dev.platform!r}: a device "
+                          "number comes from a chip"}))
+        return 2
+    i32 = jnp.int32
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    lines = []
+
+    def say(**row):
+        row["device_kind"] = dev.device_kind
+        lines.append(row)
+        print(json.dumps(row), flush=True)
+
+    def planes_of(fam, E, n_out, k_in):
+        """Random planes (LAYERS, E, ...) in the family's layout: the
+        kernels' time does not depend on the values."""
+        f = X.fold_factor(k_in)
+        N, K = n_out // f, X.padded_k(k_in) * f
+        keys = jax.random.split(jax.random.PRNGKey(N + K), len(fam.planes))
+        out = [jax.random.randint(key, (LAYERS, E, N, w * (K // X.TK)), -128,
+                                  128, jnp.int8)
+               for key, w in zip(keys, fam.widths)]
+        out.append(jax.random.normal(keys[-1], (LAYERS, E, K // X.TK, N, 128),
+                                     jnp.bfloat16) * 0.01)
+        return out, f, N, K
+
+    def loop_of(call, n):
+        """n calls in one program, the layer walking the planes."""
+        def run(meta, *a):
+            def step(i, acc):       # a corner: the add costs nothing
+                return acc + call(meta.at[0].set(i % LAYERS), *a)[:8, :128]
+            return jax.lax.fori_loop(0, n, step, jnp.zeros((8, 128)))
+        return jax.jit(run)
+
+    def slope_us(call, *a):
+        """us a call: (t(3n) - t(n)) / 2n, and the single call's result."""
+        ts = []
+        for n in (args.calls, 3 * args.calls):
+            fn = loop_of(call, n)
+            fn(*a).block_until_ready()                # compiled
+            t = []
+            for _ in range(args.reps):
+                t0 = time.perf_counter()
+                fn(*a).block_until_ready()
+                t.append(time.perf_counter() - t0)
+            ts.append(float(np.median(t)))
+        return 1e6 * (ts[1] - ts[0]) / (2 * args.calls), \
+            np.asarray(jax.jit(call)(*a))
+
+    def run_sides(label, geometry, few, fam, rows, meta, xpa, extra_in,
+                  planes):
+        """``_grouped_call(fam, meta, xpa, planes, rows, few, extra_in,
+        interpret, variant)`` of each side on the same operands."""
+        got = {}
+        for side, mod in sides.items():
+            def call(meta, xpa, *rest, mod=mod):
+                return mod._grouped_call(
+                    fam, meta, xpa, rest[len(extra_in):], rows, few,
+                    rest[:len(extra_in)], False, "cur")
+            us, out = slope_us(call, meta, xpa, *extra_in, *planes)
+            row = dict(label, side=side, us=round(us, 2))
+            if "parent" in got:
+                # a many-row call leaves the tiles past the last in use
+                # unwritten: compare the rows of the tiles in use
+                live = label["used"] * rows if not few else out.shape[0]
+                row["same_bits"] = bool(
+                    (out[:live] == got["_out"][:live]).all())
+                if geometry["idle_steps"]:
+                    row["us_per_idle_step"] = round(
+                        (got["parent"] - us) / geometry["idle_steps"], 4)
+            got[side], got["_out"] = us, out
+            say(**row, **geometry)
+
+    # ---- few rows: a decode step's call over the slots in use
+    for name, famname, R, E, n_out, k_in in FEW:
+        if args.only not in name:
+            continue
+        fam = X.FAMILIES[famname]
+        planes, f, N, K = planes_of(fam, E, n_out, k_in)
+        T = min(E, R)
+        rows = R * f + (-(R * f) % 16)
+        TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(rows, fam.tn_prefs))
+        x = jax.random.normal(jax.random.PRNGKey(1), (R, k_in), jnp.bfloat16)
+        xf = jnp.pad(X._fold_rows(x, f, R), ((0, rows - R * f), (0, 0)))
+        xpa = X._activations(xf, fam)
+        for used in sorted({int(u) for u in args.used.split(",")
+                            if int(u) < T} | {T}):
+            # `used` experts spread over the held ones, the rows dealt round
+            experts = (np.arange(used) * E) // used
+            row_expert = jnp.asarray(experts[np.arange(R) % used], i32)
+            _, slots, n_used = X.experts_in_use(row_expert, E, T)
+            meta = jnp.concatenate([jnp.zeros(1, i32), n_used[None], slots])
+            re = jnp.pad(jnp.tile(row_expert, f), (0, rows - R * f),
+                         constant_values=jnp.iinfo(i32).max)[:, None]
+
+            run_sides(dict(regime="few", shape=name, rows=rows, N=N, K=K, T=T,
+                           used=used),
+                      dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK),
+                           idle_steps=(T - used) * (N // TN) * (K // X.TK)),
+                      True, fam, rows, meta, xpa, (re,), planes)
+        del planes
+
+    # ---- many rows: a wide slice's call, the tiles the plan lays out
+    for name, famname, M, k, E_all, E, n_out, k_in in MANY:
+        if args.only not in name or args.no_many:
+            continue
+        fam = X.FAMILIES[famname]
+        planes, f, N, K = planes_of(fam, E, n_out, k_in)
+        rng = np.random.default_rng(M + E)
+        picks = np.stack([rng.permutation(E_all)[:k] for _ in range(M)])
+        row_expert = jnp.asarray(np.minimum(picks, E).reshape(-1), i32)
+        plan = X.plan_groups(row_expert, E, M, X.TM_MANY)
+        T = int(plan["tile_expert"].shape[0])
+        used = int(plan["n_used"])
+        meta = jnp.concatenate([jnp.zeros(1, i32), plan["n_used"][None],
+                                plan["tile_expert"]])
+        xp = jax.random.normal(jax.random.PRNGKey(2), (T * X.TM_MANY, k_in),
+                               jnp.bfloat16)
+        xpa = X._activations(X._fold_rows(xp, f, X.TM_MANY), fam)
+        rows = X.TM_MANY * f
+        TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(rows, fam.tn_prefs))
+
+        run_sides(dict(regime="many", shape=name, rows=rows, N=N, K=K, T=T,
+                       used=used),
+                  dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK),
+                       idle_steps=(T - used) * (N // TN) * (K // X.TK)),
+                  False, fam, rows, meta, xpa, (), planes)
+        del planes
+
+    with open(args.out, "w") as fh:
+        fh.writelines(json.dumps(row) + "\n" for row in lines)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
