@@ -507,7 +507,7 @@ class Engine:
             if reason not in (None, True):
                 raise ValueError(
                     f"{setting.format(asks[feature])} cannot serve "
-                    f"architecture {self.cache.arch!r}: {reason}")
+                    f"architecture {self.cache.arch_of(self.cfg)!r}: {reason}")
 
     @property
     def cache_kind(self) -> dict | None:
@@ -856,8 +856,9 @@ class Engine:
         if not self.cfg.n_experts:
             return None
         if self._expert_counters is None:
-            self._expert_counters = ExpertCounters(self.cfg.n_held,
-                                                   self.expert_slots)
+            self._expert_counters = ExpertCounters(
+                self.cfg.n_held, self.expert_slots,
+                zero=bool(self.cfg.n_zero_experts))
         return self._expert_counters
 
     @property

@@ -4,7 +4,9 @@ A decode chunk of a routed block returns, beside its tokens, one small
 int32 vector (models/llama.py ``expert_stats_len``): the (layer, step) pairs
 it ran, the distinct experts read summed over them, the rows each expert
 HELD here took, and the picks the routers made over all experts (what
-left the chip is those less the rows taken: models/mla.py).  The engines hand that DEVICE array here at dispatch
+left the chip is those less the rows taken: models/mla.py) and, where the
+router has zero experts (models/routed.py), how many of them fell on one.
+The engines hand that DEVICE array here at dispatch
 (:meth:`push`: a list append, nothing fetched, nothing waited for) and the
 totals are folded when somebody reads them: a ``/metrics`` scrape folds the
 chunks that have finished (:meth:`snapshot`), a test folds them all
@@ -27,11 +29,13 @@ _MAX_PENDING = 64   # older chunks have long finished: folding them is free
 
 
 class ExpertCounters:
-    def __init__(self, n_held: int, n_slots: int = 0):
+    def __init__(self, n_held: int, n_slots: int = 0, zero: bool = False):
         self.n_slots = n_slots      # 0: no grouped few-row call serves
+        self.n_held = n_held
         self._lock = threading.Lock()
         self._pending: list = []
-        self._total = np.zeros(3 + n_held, np.int64)
+        # (``zero``: the router has zero experts, and the vector their count)
+        self._total = np.zeros(3 + n_held + zero, np.int64)
 
     def push(self, stats) -> None:
         """One dispatched chunk's counter vector (a device array)."""
@@ -44,8 +48,9 @@ class ExpertCounters:
         """Cumulative counters of the chunks that have finished (all
         dispatched chunks with ``block``): ``layer_steps``, ``experts_read``,
         ``picks`` (a list, one count per held expert), ``picks_held`` (their
-        sum), ``picks_total`` (over all the router's experts) and
-        ``slots_skipped`` (module docstring)."""
+        sum), ``picks_total`` (over all the router's outputs),
+        ``picks_zero`` (of those, the picks of a zero expert; 0 where the
+        router has none) and ``slots_skipped`` (module docstring)."""
         with self._lock:
             keep = []
             for s in self._pending:
@@ -55,8 +60,10 @@ class ExpertCounters:
                     keep.append(s)
             self._pending = keep
             t = self._total
+            picks = t[2:2 + self.n_held]
             return {"layer_steps": int(t[0]), "experts_read": int(t[1]),
-                    "picks": t[2:-1].tolist(), "picks_held": int(t[2:-1].sum()),
-                    "picks_total": int(t[-1]),
+                    "picks": picks.tolist(), "picks_held": int(picks.sum()),
+                    "picks_total": int(t[2 + self.n_held]),
+                    "picks_zero": int(t[3 + self.n_held:].sum()),
                     "slots_skipped": int(t[0] * self.n_slots - t[1])
                     if self.n_slots else 0}

@@ -183,10 +183,32 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``attention.head_count_kv`` as an ARRAY with one entry a layer, 0 in a
 #: conv layer; ``leading_dense_block_count`` ... ``expert_gating_func`` as
 #: above.  Q and K rotate on halves.
+#: ``longcat-flash`` (this repo's name for the LongCat-Flash family:
+#: llama.cpp's, if it has one, is not known here; models/mla.py) is
+#: ``deepseek2``'s latent attention TWICE a layer, a dense feed-forward
+#: after each, and one shortcut-connected expert branch a layer whose
+#: softmax router has outputs that are identity ("zero-compute") experts.
+#: Tensors: a sub-block ``s`` in (0, 1) of layer N has ``blk.N.s.attn_norm``,
+#: ``attn_q_a``, ``attn_q_a_norm``, ``attn_q_b``, ``attn_kv_a_mqa``,
+#: ``attn_kv_a_norm``, ``attn_kv_b``, ``attn_output`` (shapes as
+#: ``deepseek2``'s), ``ffn_norm`` and the dense ``ffn_{gate,up,down}``;
+#: the layer has the F32 router ``blk.N.ffn_gate_inp`` (expert_count +
+#: expert_zero_count rows: the zero experts' outputs come last), its F32
+#: choice bias ``blk.N.exp_probs_b.bias`` (as many; ABSENT reads as zeros:
+#: the published buffer starts there) and the 3-D ``ffn_{gate,up,down}_exps``.
+#: Keys: ``deepseek2``'s attention keys; ``expert_feed_forward_length``,
+#: ``expert_count`` (real experts), ``expert_used_count``,
+#: ``expert_weights_scale``, ``expert_weights_norm``, ``expert_gating_func``,
+#: ``expert_held_first`` / ``expert_held_count``; its own:
+#: ``expert_zero_count``, ``expert_zero_type`` (``identity``: anything else
+#: is refused by name), ``attention.scale_q_lora`` / ``scale_kv_lora``
+#: (bool: the query after ``attn_q_b`` times (embedding_length /
+#: q_lora_rank)^1/2, the normed latent times (embedding_length /
+#: kv_lora_rank)^1/2).  Q and K rotate on interleaved pairs; no rope scaling.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
-                        "deepseek2", "exaone-moe", "lfm2moe")
+                        "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):

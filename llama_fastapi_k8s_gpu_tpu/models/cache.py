@@ -104,6 +104,12 @@ class CacheKind:
     note_slice: Callable = lambda counts, cfg, tokens: None
     #: (live rows at the chunk's end) -> a ``decode_chunk`` span's attributes
     decode_span_attrs: Callable = lambda pos: {}
+    #: (cfg) -> the architecture a refusal names, where the kind serves
+    #: more than ``arch`` (the latent ring: ``longcat-flash`` too)
+    arch_for: Callable | None = None
+
+    def arch_of(self, cfg: ModelConfig) -> str:
+        return self.arch_for(cfg) if self.arch_for else self.arch
 
     def new_counts(self) -> collections.Counter:
         """The kind's counters at 0 (``Engine.cache_counts``; ``update``

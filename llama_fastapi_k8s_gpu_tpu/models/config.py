@@ -172,6 +172,23 @@ class ModelConfig:
     # (expert parallelism's share of a layer, without its exchange).
     experts_first: int = 0
     experts_held: int = 0
+    # Router outputs that are NOT experts (``longcat-flash``: identity
+    # "zero-compute" experts): the router scores ``n_experts +
+    # n_zero_experts`` outputs, and a pick ``e >= n_experts`` adds ``w_e``
+    # times the layer's own input, wherever the token lives: no weight, no
+    # exchange.
+    n_zero_experts: int = 0
+    # Attention sub-layers a layer (models/mla.py; ``longcat-flash``: 2).
+    # A layer of 2 is attention, dense feed-forward, attention, dense
+    # feed-forward, with ONE expert branch that reads the first sub-block's
+    # normed rows and joins after the second; the latent ring then holds
+    # ``n_layers * attn_sublayers`` leaves.
+    attn_sublayers: int = 1
+    # what the query (after ``W_qb``) and the normed latent (before it is
+    # cached) are multiplied by (``mla_scale_q_lora`` / ``mla_scale_kv_lora``:
+    # (dim / rank) ** 0.5; 1.0: not scaled)
+    q_latent_scale: float = 1.0
+    kv_latent_scale: float = 1.0
     # The attention KIND is the layer's (models/hybrid.py; ``exaone-moe``):
     # each layer in the file's order ``"window"`` (causal over the last
     # ``sliding_window`` positions; its cache leaf holds WINDOW slots that
@@ -204,6 +221,12 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.head_width or self.dim // self.n_heads
+
+    @property
+    def n_attn_sublayers(self) -> int:
+        """Attention sub-layers of the whole stack: the leaves of a latent
+        ring (``attn_sublayers`` a layer)."""
+        return self.n_layers * self.attn_sublayers
 
     def n_attn_layers(self, kind: str) -> int:
         return sum(k == kind for k in self.attn_kinds)
@@ -244,8 +267,10 @@ class ModelConfig:
         if self.kv_lora_rank or self.attn_kinds or self.conv_l_cache:
             routed = 3 * self.dim * self.expert_ffn_dim * (
                 self.n_held + self.n_shared_experts)
-            return self.n_layers * 4 * self.dim * self.dim \
-                + self.n_dense_layers * 3 * self.dim * self.ffn_dim \
+            n_dense = self.n_dense_layers if self.attn_sublayers == 1 \
+                else self.n_attn_sublayers    # a dense one a sub-layer
+            return self.n_attn_sublayers * 4 * self.dim * self.dim \
+                + n_dense * 3 * self.dim * self.ffn_dim \
                 + (self.n_layers - self.n_dense_layers) * routed
         ffn = 3 * self.dim * self.ffn_dim * max(self.n_experts, 1)
         return self.n_layers * (4 * self.dim * self.dim + ffn)
@@ -325,6 +350,8 @@ class ModelConfig:
             mla = _deepseek2_fields(h, n_heads)
         if arch == "exaone-moe":
             mla = _exaone_moe_fields(h, n_heads, window)
+        if arch == "longcat-flash":
+            mla = _longcat_fields(h, n_heads, int(h("embedding_length")))
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -351,15 +378,17 @@ class ModelConfig:
         )
 
 
-def _deepseek2_fields(h, n_heads: int) -> dict:
+def _deepseek2_fields(h, n_heads: int, arch: str = "deepseek2") -> dict:
     """The ``deepseek2`` keys (gguf/constants.py) as ``ModelConfig``
-    fields; a ValueError naming what the block here cannot compute."""
+    fields (``arch``: the architecture an error names: ``longcat-flash``
+    reads the same keys); a ValueError naming what the block here cannot
+    compute."""
     import math
 
     def need(key):
         v = h(key)
         if v is None:
-            raise ValueError(f"deepseek2: the file lacks <arch>.{key}")
+            raise ValueError(f"{arch}: the file lacks <arch>.{key}")
         return v
 
     r_q, r_kv = int(h("attention.q_lora_rank", 0) or 0), \
@@ -369,11 +398,11 @@ def _deepseek2_fields(h, n_heads: int) -> dict:
         int(need("attention.value_length"))
     if not r_q:
         raise ValueError(
-            "deepseek2: attention.q_lora_rank is 0 (the lite files' plain "
+            f"{arch}: attention.q_lora_rank is 0 (the lite files' plain "
             "query projection): the block here has the query latent only")
     if d_qk <= d_r or d_r % 2:
         raise ValueError(
-            f"deepseek2: attention.key_length {d_qk} must exceed the even "
+            f"{arch}: attention.key_length {d_qk} must exceed the even "
             f"rope.dimension_count {d_r} (a head's key is its unrotated "
             "part, then the shared rotated one)")
     yarn = {}
@@ -391,8 +420,36 @@ def _deepseek2_fields(h, n_heads: int) -> dict:
             attn_mscale=m * m)
     return dict(
         q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_qk - d_r,
-        qk_rope_dim=d_r, v_head_dim=d_v, **_routed_fields(h, "deepseek2"),
+        qk_rope_dim=d_r, v_head_dim=d_v, **_routed_fields(h, arch),
         **yarn)
+
+
+def _longcat_fields(h, n_heads: int, dim: int) -> dict:
+    """The ``longcat-flash`` keys (gguf/constants.py) as ``ModelConfig``
+    fields: ``deepseek2``'s attention keys and routed keys, two attention
+    sub-layers a layer, router outputs that are identity experts, and the
+    two ``mla_scale_*`` factors; a ValueError naming what the block here
+    cannot compute."""
+    arch = "longcat-flash"
+    fields = _deepseek2_fields(h, n_heads, arch)
+    kind = str(h("expert_zero_type", "identity"))
+    n_zero = int(h("expert_zero_count", 0) or 0)
+    if n_zero and kind != "identity":
+        raise ValueError(
+            f"{arch}: expert_zero_type {kind!r} is not served: a zero "
+            "expert here is the identity (a constant or learned-vector one "
+            "is ROADMAP B-I 4)")
+    if fields["n_dense_layers"] or fields["n_shared_experts"] \
+            or fields["n_expert_groups"] > 1 or "rope_yarn_factor" in fields:
+        raise ValueError(
+            f"{arch}: leading dense layers, a shared expert, router groups "
+            "or rope scaling are not this architecture's")
+    return dict(
+        fields, attn_sublayers=2, n_zero_experts=n_zero,
+        q_latent_scale=(dim / fields["q_lora_rank"]) ** 0.5
+        if h("attention.scale_q_lora", False) else 1.0,
+        kv_latent_scale=(dim / fields["kv_lora_rank"]) ** 0.5
+        if h("attention.scale_kv_lora", False) else 1.0)
 
 
 def _routed_fields(h, arch: str) -> dict:

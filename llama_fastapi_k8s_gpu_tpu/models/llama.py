@@ -393,8 +393,9 @@ def expert_stats_len(cfg: ModelConfig) -> int:
     ``with_stats``): [(layer, step) pairs, distinct experts read summed
     over them, rows each HELD expert took..., the picks the live rows'
     routers made over ALL experts (what was not held is the difference:
-    models/mla.py)]."""
-    return 3 + cfg.n_held
+    models/mla.py)[, of those the picks of a zero expert, where the router
+    has any: models/routed.py]]."""
+    return 3 + cfg.n_held + bool(cfg.n_zero_experts)
 
 
 def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
@@ -753,7 +754,8 @@ def note_ring_decode(counts: dict, cfg: ModelConfig, wanted: list,
     block = kind.decode_kernel_block(cfg)
     bound = None if block else max(dispatched, default=0)
     if block and kind.kernel_writes:
-        counts["rows_written"] += len(dispatched) * n_steps * cfg.n_layers
+        counts["rows_written"] += len(dispatched) * n_steps \
+            * cfg.n_attn_sublayers      # a leaf a layer but on a latent ring
     for p in wanted:
         steps = n_steps if until is None else min(n_steps, max(until - p, 0))
         read, lv = decode_chunk_slots(p, steps, cfg.n_ctx, bound, block)

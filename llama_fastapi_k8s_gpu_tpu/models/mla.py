@@ -1,6 +1,7 @@
 """The fourth cache KIND, and a feed-forward kind per LAYER
 (``general.architecture = "deepseek2"``: the DeepSeek-V2/V3 family's block;
-``cfg.kv_lora_rank``).
+``cfg.kv_lora_rank``), or TWO attention sub-layers a layer with a
+shortcut-connected expert branch (``"longcat-flash"``: the last point).
 
 - Latent attention (MLA).  ``c_q = RMSNorm(W_qa x)``; per head ``[q_n | q_r]
   = W_qb c_q`` (``qk_nope_dim`` + ``qk_rope_dim``); ``[c_kv | k_r] = W_kva
@@ -59,6 +60,19 @@
   over the experts HELD here plus a shared expert.  The two kinds are two
   stacks of weights (``params["layers"]["dense" | "moe"]``), each a
   ``fori_loop``.
+- A ``longcat-flash`` layer (``cfg.attn_sublayers == 2``;
+  :func:`shortcut_layer`) is two sub-blocks of this attention and a dense
+  SwiGLU each, and ONE expert branch that reads sub-block 0's normed rows
+  and joins after sub-block 1's feed-forward (a shortcut-connected
+  mixture of experts: in a deployment the branch's dispatch and combine
+  hide behind sub-block 1; on one chip the late join only frees the order
+  of operations).  Its router has outputs that are identity experts
+  (models/routed.py), its query and latent are scaled
+  (``cfg.q_latent_scale``, ``cfg.kv_latent_scale``; the latent BEFORE it is
+  cached, so every read of the cache is as above), and the latent ring has
+  a leaf an attention SUB-layer, ``2 l + s``.  Three stacks of weights:
+  ``params["layers"]["attn" | "ffn"]`` at depth ``2 L``, ``["moe"]`` at
+  depth ``L``; one ``fori_loop`` over the layers.
 """
 
 from __future__ import annotations
@@ -79,6 +93,10 @@ from .llama import (
 from .routed import (  # noqa: F401  (``mla.route_grouped``: the tests' name)
     DENSE, HI, MOE, check_stacks, expert_branch, held_picks, moe_stats,
     n_moe_layers, route_grouped, swiglu)
+
+#: the two stacks at depth ``2 L`` of a ``longcat-flash`` file (a third,
+#: ``MOE``, at depth ``L``): a sub-block's attention, its dense feed-forward
+ATTN, FFN = "attn", "ffn"
 
 logger = logging.getLogger(__name__)
 
@@ -141,12 +159,13 @@ def leaf_width(cfg: ModelConfig) -> int:
 
 
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
-    return {"lat": jnp.zeros((cfg.n_layers, 1, cfg.n_ctx, leaf_width(cfg)),
-                             dtype)}
+    # a leaf an attention SUB-layer (one a layer but in ``longcat-flash``)
+    return {"lat": jnp.zeros(
+        (cfg.n_attn_sublayers, 1, cfg.n_ctx, leaf_width(cfg)), dtype)}
 
 
 def cache_nbytes(cfg: ModelConfig) -> int:
-    return cfg.n_layers * cfg.n_ctx * leaf_width(cfg) * 2
+    return cfg.n_attn_sublayers * cfg.n_ctx * leaf_width(cfg) * 2
 
 
 def prefill_positions_read(slices, cfg: ModelConfig) -> int:
@@ -322,12 +341,18 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
     hn = rms_norm(h, layers["attn_norm"][i], cfg.rms_eps)
     c_q = rms_norm(lin(hn, "wq_a"), layers["q_a_norm"][i], cfg.rms_eps)
     q = lin(c_q, "wq_b").reshape(S, H, d_n + d_r)
+    if cfg.q_latent_scale != 1.0:       # both parts (``mla_scale_q_lora``)
+        q = (q.astype(jnp.float32) * cfg.q_latent_scale).astype(q.dtype)
     inv_freq = rope_inv_freq(cfg)
     q_r = rope_pairs(q[..., d_n:], positions, inv_freq)
     # the projection's rows are filled up to a kernel's N: the first
     # r_kv + d_r are the file's
     kv = lin(hn, "wkv_a")[:, :r + d_r]
     c = rms_norm(kv[:, :r], layers["kv_a_norm"][i], cfg.rms_eps)
+    if cfg.kv_latent_scale != 1.0:
+        # (``mla_scale_kv_lora``) before it is cached: every read, absorbed
+        # or expanded, finds the latent as ``W_kvb`` takes it; ``k_r`` is not
+        c = (c.astype(jnp.float32) * cfg.kv_latent_scale).astype(c.dtype)
     k_r = rope_pairs(kv[:, None, r:], positions, inv_freq)[:, 0]
     fill = leaf_width(cfg) - r - d_r
     rows = jnp.concatenate(
@@ -392,6 +417,33 @@ def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
     return h + out, cache, routed
 
 
+def shortcut_layer(h, layers, l, cache, positions, pos_offset, cfg, live,
+                   kv_bound):
+    """One ``longcat-flash`` layer ``l``: sub-block ``s`` is attention
+    (weights and cache leaf ``2 l + s``) then a dense SwiGLU; the ONE expert
+    branch reads sub-block 0's normed rows ``u`` and its result is carried
+    across sub-block 1 to the layer's end.  Returns as :func:`moe_layer`."""
+    def attn(h, cache, s):
+        i = 2 * l + s
+        with jax.named_scope(f"attn{s}"):
+            h, cache = _attention(h, layers[ATTN], i, i, cache, positions,
+                                  pos_offset, cfg, live, kv_bound)
+        return h, cache, rms_norm(h, layers[FFN]["ffn_norm"][i], cfg.rms_eps)
+
+    def ffn(u, s):
+        with jax.named_scope(f"ffn{s}"):
+            return swiglu(u, layers[FFN], 2 * l + s, "w_gate", "w_up",
+                          "w_down")
+
+    a, cache, u = attn(h, cache, 0)
+    m, routed = expert_branch(u, layers[MOE], l, cfg, live)
+    b = a + ffn(u, 0)
+    c, cache, u = attn(b, cache, 1)
+    f1 = ffn(u, 1)
+    with jax.named_scope("shortcut_join"):
+        return c + f1 + m, cache, routed
+
+
 def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
             last_idx=None, return_all: bool = False, live=None,
             with_stats: bool = False, with_picks: bool = False,
@@ -404,9 +456,15 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
     router's, over all).  ``kv_bound``: a lane step's ``live_bound``."""
     S = tokens.shape[0]
     n_moe = n_moe_layers(cfg)
-    check_stacks(params, cfg)
+    # a ``longcat-flash`` file: no dense layer, every layer a
+    # :func:`shortcut_layer` over all three stacks
+    shortcut = cfg.attn_sublayers == 2
+    check_stacks(params, cfg, ((ATTN, 2 * n_moe), (FFN, 2 * n_moe))
+                 if shortcut else ())
     h = jnp.take(params["tok_emb"], tokens, axis=0).astype(jnp.bfloat16)
     positions = pos_offset + jnp.arange(S, dtype=jnp.int32)
+    routed_layer, routed_stacks = (shortcut_layer, params["layers"]) \
+        if shortcut else (moe_layer, params["layers"][MOE])
 
     def dense_body(i, carry):
         return dense_layer(carry[0], params["layers"][DENSE], jnp.int32(i),
@@ -414,9 +472,9 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
                            kv_bound)
 
     def moe_body(i, carry):
-        h, cache, routed = moe_layer(
-            carry[0], params["layers"][MOE], jnp.int32(i), carry[1],
-            positions, pos_offset, cfg, live, kv_bound)
+        h, cache, routed = routed_layer(
+            carry[0], routed_stacks, jnp.int32(i), carry[1], positions,
+            pos_offset, cfg, live, kv_bound)
         return (h, cache, *moe_stats(carry[2], carry[3], i, routed))
 
     carry = (h, cache)
@@ -479,13 +537,19 @@ def _health(cfg: ModelConfig, engine) -> dict:
     return {
         "kind": LATENT_RING,
         "latent": cfg.kv_lora_rank, "rotated_key": cfg.qk_rope_dim,
-        "bytes_per_position": 2 * cfg.n_layers * lat_width(cfg),
-        "bytes_per_position_laid_out": 2 * cfg.n_layers * leaf_width(cfg),
+        "bytes_per_position": 2 * cfg.n_attn_sublayers * lat_width(cfg),
+        "bytes_per_position_laid_out":
+            2 * cfg.n_attn_sublayers * leaf_width(cfg),
         "read": "absorbed, blocks of %d" % LATENT_BLOCK,
         "dense_layers": cfg.n_dense_layers,
         "routed_layers": n_moe_layers(cfg),
         "experts_held": [cfg.experts_first, cfg.n_held],
         "experts_routed": cfg.n_experts,
+        # a ``longcat-flash`` file's: the leaves of the ring, and the
+        # router's outputs that are identity experts
+        **({"attn_sublayers": cfg.n_attn_sublayers,
+            "experts_zero": cfg.n_zero_experts}
+           if cfg.attn_sublayers > 1 else {}),
         "prefix_reuse": "on" if reuse else "off",
         "kv_paged": "refused at start"}
 
@@ -512,6 +576,8 @@ def _note_slice(counts, cfg: ModelConfig, tokens: int) -> None:
 
 CACHE = CacheKind(
     name=LATENT_RING, arch="deepseek2",
+    arch_for=lambda cfg: "longcat-flash" if cfg.attn_sublayers == 2
+    else "deepseek2",
     init=init_cache, nbytes=cache_nbytes, forward=forward,
     step_bound=ring_step_bound,
     shardings=lambda cfg: {"lat": WHOLE},

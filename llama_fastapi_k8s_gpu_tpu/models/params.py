@@ -175,6 +175,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 names += ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"]
         if layer_ids is None:
             layer_ids = range(cfg.n_layers)
+        # (an id may be ``"N.s"``: a sub-block of a ``longcat-flash`` layer)
         ok: dict[str, object] = {}
         for n in names:
             ts = [gf[f"blk.{i}.{n}.weight"] for i in layer_ids]
@@ -206,6 +207,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     # kind, in ``ffn_kind_layers`` / ``mixer_ffn_layers``: here its output
     # head alone)
     by_ffn_kind = bool(cfg.kv_lora_rank or cfg.attn_kinds)
+    latent_attn = {"wq_a": "attn_q_a", "wq_b": "attn_q_b",
+                   "wkv_a": "attn_kv_a_mqa", "wo": "attn_output"}
+    latent_norms = (("attn_norm", "attn_norm"), ("q_a_norm", "attn_q_a_norm"),
+                    ("kv_a_norm", "attn_kv_a_norm"))
     fused_names = _fused_names(
         [] if by_ffn_kind or cfg.conv_l_cache else None) \
         if fmt == "q4k" else {}
@@ -347,12 +352,10 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         from .routed import DENSE, MOE
 
         latent = bool(cfg.kv_lora_rank)
-        attn = {"wq_a": "attn_q_a", "wq_b": "attn_q_b",
-                "wkv_a": "attn_kv_a_mqa", "wo": "attn_output"} if latent \
+        attn = latent_attn if latent \
             else {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
                   "wo": "attn_output"}
-        norms = (("attn_norm", "attn_norm"), ("q_a_norm", "attn_q_a_norm"),
-                 ("kv_a_norm", "attn_kv_a_norm"), ("ffn_norm", "ffn_norm")) \
+        norms = (*latent_norms, ("ffn_norm", "ffn_norm")) \
             if latent else (
                 ("attn_norm", "attn_norm"), ("attn_q_norm", "attn_q_norm"),
                 ("attn_k_norm", "attn_k_norm"), ("ffn_norm", "ffn_norm"))
@@ -362,8 +365,6 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                      "w_down_sh": "ffn_down_shexp"}}
         # the latent projection's r_kv + d_r rows, filled up to a kernel's N
         kv_rows = -(-lat_width(cfg) // 128) * 128 if latent else None
-        H, d_n = cfg.n_heads, cfg.qk_nope_dim
-
         out = {}
         for kind, ids in ((DENSE, range(cfg.n_dense_layers)),
                           (MOE, range(cfg.n_dense_layers, cfg.n_layers))):
@@ -385,10 +386,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                         layer[key] = lin(p + name + ".weight", fused,
                                          kv_rows if key == "wkv_a" else None)
                 if latent:
-                    kv_b = as_bf16(gf[p + "attn_kv_b.weight"]).reshape(
-                        H, d_n + cfg.v_head_dim, cfg.kv_lora_rank)
-                    layer["w_uk"] = {"w": kv_b[:, :d_n]}
-                    layer["w_uv"] = {"w": kv_b[:, d_n:]}
+                    layer.update(absorbed_halves(p))
                 for key, name in norms:
                     layer[key] = norm(p + name + ".weight")
                 if kind == MOE:
@@ -401,6 +399,68 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     layer = jax.tree.map(jax.device_put, layer)
                 out[kind].append(layer)
         return {k: v for k, v in out.items() if v}
+
+    def absorbed_halves(p: str) -> dict:
+        """``attn_kv_b`` per head, as the absorbed form wants it: W_uk and
+        W_uv, bf16."""
+        d_n = cfg.qk_nope_dim
+        kv_b = as_bf16(gf[p + "attn_kv_b.weight"]).reshape(
+            cfg.n_heads, d_n + cfg.v_head_dim, cfg.kv_lora_rank)
+        return {"w_uk": {"w": kv_b[:, :d_n]}, "w_uv": {"w": kv_b[:, d_n:]}}
+
+    def shortcut_layers() -> dict:
+        """A ``longcat-flash`` file (models/mla.py ``shortcut_layer``):
+        THREE stacks, the sub-blocks' attentions and their dense
+        feed-forwards at depth ``2 L`` (sub-block ``s`` of layer ``l`` at
+        ``2 l + s``; tensors ``blk.l.s.*``) and the layers' expert branches
+        at depth ``L``.  As ``ffn_kind_layers``: nothing is requantized, a
+        matrix no fused kernel takes is served bf16.  An absent choice bias
+        (``exp_probs_b.bias``) loads as zeros."""
+        from .mla import ATTN, FFN, lat_width
+        from .routed import MOE
+
+        dense = {"w_gate": "ffn_gate", "w_up": "ffn_up", "w_down": "ffn_down"}
+        exps = ["ffn_gate_exps", "ffn_up_exps", "ffn_down_exps"]
+        kv_rows = -(-lat_width(cfg) // 128) * 128
+        subs = [f"{l}.{s}" for l in range(cfg.n_layers) for s in (0, 1)]
+        q4k = fmt == "q4k"
+        fused = _fused_names(
+            [*latent_attn.values(), *dense.values()], subs,
+            {"attn_kv_a_mqa": kv_rows}) if q4k else {}
+        fused_exps = _fused_names(exps, range(cfg.n_layers)) \
+            if q4k and fused_experts else {}
+
+        def mats(p, names):
+            return {key: {"w": as_bf16(gf[p + name + ".weight"])}
+                    if q4k and name not in fused
+                    else lin(p + name + ".weight", fused,
+                             kv_rows if key == "wkv_a" else None)
+                    for key, name in names.items()}
+
+        def put(layer):
+            return jax.tree.map(jax.device_put, layer) if overlap else layer
+
+        out = {ATTN: [], FFN: [], MOE: []}
+        for sub in subs:
+            p = f"blk.{sub}."
+            out[ATTN].append(put({
+                **mats(p, latent_attn), **absorbed_halves(p),
+                **{key: norm(p + name + ".weight")
+                   for key, name in latent_norms}}))
+            out[FFN].append(put({**mats(p, dense),
+                                 "ffn_norm": norm(p + "ffn_norm.weight")}))
+        n_out = cfg.n_experts + cfg.n_zero_experts
+        for l in range(cfg.n_layers):
+            p = f"blk.{l}."
+            out[MOE].append(put({
+                "w_router": norm(p + "ffn_gate_inp.weight"),
+                "router_bias": norm(p + "exp_probs_b.bias")
+                if p + "exp_probs_b.bias" in gf.tensors
+                else jnp.zeros(n_out, jnp.float32),
+                **{f"w_{key}_exps": experts(p + f"ffn_{key}_exps.weight",
+                                            fused_exps)
+                   for key in ("gate", "up", "down")}}))
+        return out
 
     def mixer_ffn_layers() -> dict:
         """A ``lfm2moe`` file (models/lfm2.py): FOUR stacks, a layer's
@@ -466,6 +526,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     t_prep = _time.time()
     by_kind = mixer_ffn_layers() if cfg.conv_l_cache else \
         kinds_layers() if cfg.mixers else \
+        shortcut_layers() if cfg.attn_sublayers == 2 else \
         ffn_kind_layers() if by_ffn_kind else None
     for i in range(cfg.n_layers if by_kind is None else 0):
         p = f"blk.{i}."

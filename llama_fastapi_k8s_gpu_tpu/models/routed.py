@@ -12,6 +12,12 @@ its experts: what the ``deepseek2`` block (models/mla.py) and the
   published; a pick outside the held ones becomes the sentinel "no pick"
   of ``ops/pallas/experts.py`` and adds nothing.  That is one chip's share
   of an expert-parallel layer without its exchange.
+- The router may have outputs that are NOT experts (``cfg.n_zero_experts``;
+  ``longcat-flash``'s identity experts): it scores ``n_experts +
+  n_zero_experts`` outputs, and a pick ``e >= n_experts`` adds its weight
+  times the layer's own input (:func:`expert_branch`), here, in full,
+  whatever share of the experts is held: it needs no weight and no
+  exchange.
 """
 
 from __future__ import annotations
@@ -49,7 +55,9 @@ def check_stacks(params: dict, cfg: ModelConfig, more: tuple = ()) -> None:
 
 def route_grouped(hn, w_router, bias, cfg: ModelConfig):
     """The router of a routed layer, float32.  Scores
-    ``sigmoid`` (or ``softmax``) of ``W_r hn`` over ALL ``n_experts``; the
+    ``sigmoid`` (or ``softmax``) of ``W_r hn`` over ALL ``n_experts`` (and
+    the ``n_zero_experts`` outputs after them: ``E`` below is their sum,
+    the router's rows); the
     CHOICE on ``scores + bias``: a group's score is the sum of its two
     largest, the ``n_groups_used`` best groups are kept, the
     ``n_experts_used`` largest inside them picked; the weights are the
@@ -98,9 +106,13 @@ def _sum_over_lanes_rule(axis_size, in_batched, n):
 def held_picks(picks, cfg: ModelConfig):
     """The router's picks as indices into the HELD experts' planes:
     ``pick - experts_first`` where this process holds the expert, else the
-    sentinel ``n_held`` ("no pick": ops/pallas/experts.py)."""
+    sentinel ``n_held`` ("no pick": ops/pallas/experts.py); a pick of a
+    zero expert (``>= n_experts``) is no held one's."""
     local = picks - cfg.experts_first
-    return jnp.where((local >= 0) & (local < cfg.n_held), local, cfg.n_held)
+    mine = (local >= 0) & (local < cfg.n_held)
+    if cfg.n_zero_experts:      # whatever range is held
+        mine &= picks < cfg.n_experts
+    return jnp.where(mine, local, cfg.n_held)
 
 
 def swiglu(hn, layers, i, gate, up, down):
@@ -114,9 +126,11 @@ def swiglu(hn, layers, i, gate, up, down):
 
 def expert_branch(hn, layers, i, cfg: ModelConfig, live):
     """A routed layer's feed-forward on the normed rows ``hn``: the router,
-    the held experts' products for their picks, the shared expert.  Returns
-    (out, (rows each HELD expert took (n_held,), the router's picks (S, k)
-    over all experts, picks of live rows))."""
+    the held experts' products for their picks, the shared expert, the
+    identity picks' ``(sum of their weights) * hn``.  Returns (out, (rows
+    each HELD expert took (n_held,), the router's picks (S, k) over all its
+    outputs, picks of live rows[, of those the picks of a zero expert:
+    only where the router has any])."""
     from ..ops.pallas.experts import routed_experts
 
     with jax.named_scope("router"):
@@ -136,16 +150,26 @@ def expert_branch(hn, layers, i, cfg: ModelConfig, live):
         with jax.named_scope("shared_expert"):
             out = out + swiglu(hn, layers, i, "w_gate_sh", "w_up_sh",
                                "w_down_sh")
-    return out, (count, picks, total)
+    if not cfg.n_zero_experts:
+        return out, (count, picks, total)
+    with jax.named_scope("zero_experts"):
+        is_zero = picks >= cfg.n_experts
+        w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)
+        out = (out.astype(jnp.float32) + w_zero[:, None]
+               * hn.astype(jnp.float32)).astype(out.dtype)
+        n_zero = jnp.sum(is_zero, dtype=jnp.int32)
+        if live is not None:
+            n_zero = jnp.where(live, n_zero, 0)
+    return out, (count, picks, total, _sum_over_lanes(n_zero))
 
 
 def moe_stats(stats, all_picks, i, routed):
     """A routed layer's :func:`expert_branch` counters folded into the
     forward's carry: the counter vector of ``llama.expert_stats_len`` and
     the picks of layer ``i`` of the routed stack."""
-    count, picks, total = routed
+    count, picks, *totals = routed      # the picks made[, the zero ones]
     read = jnp.sum(count > 0, dtype=jnp.int32)
     stats = stats + jnp.concatenate(
-        [jnp.stack([jnp.int32(1), read]), count, total[None]])
+        [jnp.stack([jnp.int32(1), read]), count, jnp.stack(totals)])
     return stats, jax.lax.dynamic_update_slice(
         all_picks, picks[None], (i, 0, 0))

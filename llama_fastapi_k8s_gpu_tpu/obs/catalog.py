@@ -327,6 +327,12 @@ METRICS: dict[str, Metric] = _register(
            "of those, the picks of an expert held here (picks_held): the "
            "rest left the chip in the deployment the file stands for; "
            "equal to expert_picks_routed_total where every expert is held"),
+    Metric("expert_picks_zero_total", GAUGE,
+           "of expert_picks_routed_total, the picks of a zero expert "
+           "(picks_zero: a router output that is no expert, the identity of "
+           "a longcat-flash file, computed where the token lives at no "
+           "weight read); 0 where the router has none; zero + held <= "
+           "routed, and routed - zero are the picks of a real expert"),
     # -- decode attention's read of the KV ring (models/llama.py) ----------
     Metric("ring_slots_read_total", GAUGE,
            "KV ring slots the decode steps' attention covered (whole blocks "

@@ -75,7 +75,10 @@ from . import q6matmul as _q6
 from . import qmatmul as _q4
 from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
 
-FEW_ROWS = 128   # (token, pick) rows up to which every slot sees all rows
+#: (token, pick) rows up to which every slot sees all rows: 16 lanes of 12
+#: picks (``longcat-flash``); no program of another served file has rows
+#: between 128, what it was, and this (a prefill slice is 128 tokens or more)
+FEW_ROWS = 192
 TM_MANY = 128    # rows per tile of a prefill slice
 FEW_VMEM = 64 * 2 ** 20  # a few-row call's limit: its output block is all N
 

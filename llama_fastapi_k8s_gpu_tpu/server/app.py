@@ -1636,6 +1636,7 @@ def create_app(engine=None, settings: Settings | None = None,
                 m.set_gauge("expert_picks_total", n, expert=str(e))
             m.set_gauge("expert_picks_routed_total", snap["picks_total"])
             m.set_gauge("expert_picks_held_total", snap["picks_held"])
+            m.set_gauge("expert_picks_zero_total", snap["picks_zero"])
         # lfkt-mem: live HBM attribution gauges (obs/memledger.py) — one
         # series per (component, model), residual = ground truth minus the
         # attributed sum, headroom only where the backend reports limits.

@@ -330,6 +330,111 @@ def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
     return cfg
 
 
+#: a tiny ``longcat-flash`` file that keeps every ratio of the published
+#: block (models/mla.py ``shortcut_layer``): 2 layers of two sub-blocks, a
+#: softmax router over 8 experts + 4 identity outputs, top-3, weights not
+#: normalised and times 3, both ``mla_scale_*`` factors on, no rope scaling
+TINY_LONGCAT_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=2, n_heads=4, n_kv_heads=4,
+    ffn_dim=512, n_ctx=128, rope_theta=10000.0, rms_eps=1e-5,
+    q_lora_rank=64, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+    v_head_dim=24, expert_ffn_dim=256, n_experts=8, n_experts_used=3,
+    n_zero_experts=4, expert_gating="softmax", expert_weights_scale=3.0,
+    attn_sublayers=2, q_latent_scale=2.0, kv_latent_scale=8.0 ** 0.5,
+)
+
+
+def write_tiny_longcat_gguf(path: str, cfg: ModelConfig = TINY_LONGCAT_CFG,
+                            seed: int = 0, mix: dict | None = None,
+                            held: tuple[int, int] | None = None,
+                            router_scale: float = 4.0,
+                            bias_scale: float | None = 0.05,
+                            zero_type: str = "identity") -> ModelConfig:
+    """Write a random-weight ``longcat-flash`` GGUF (gguf/constants.py) with
+    the byte-level tokenizer of :func:`write_tiny_llama_gguf`.  ``held`` as
+    :func:`write_tiny_mla_gguf`'s.  ``bias_scale``: the choice bias's spread
+    (beside softmax scores of about 1/12 it moves picks); None leaves the
+    tensor out of the file.  ``zero_type``: the file's
+    ``expert_zero_type``."""
+    tokens, types = byte_vocab_with_specials()
+    first, count = held or (0, 0)
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens),
+                         "experts_first": first, "experts_held": count})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**MLA_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    arch = "longcat-flash"
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-longcat-test",
+                          arch=arch)
+    for key, value in (
+            ("expert_feed_forward_length", cfg.expert_ffn_dim),
+            ("expert_weights_scale", float(cfg.expert_weights_scale)),
+            ("expert_weights_norm", bool(cfg.norm_topk_prob)),
+            ("expert_gating_func",
+             {"softmax": 1, "sigmoid": 2}[cfg.expert_gating]),
+            ("expert_zero_count", cfg.n_zero_experts),
+            ("expert_zero_type", zero_type),
+            ("attention.q_lora_rank", cfg.q_lora_rank),
+            ("attention.kv_lora_rank", cfg.kv_lora_rank),
+            ("attention.key_length", cfg.qk_nope_dim + cfg.qk_rope_dim),
+            ("attention.value_length", cfg.v_head_dim),
+            ("attention.scale_q_lora", cfg.q_latent_scale != 1.0),
+            ("attention.scale_kv_lora", cfg.kv_latent_scale != 1.0),
+            ("rope.dimension_count", cfg.qk_rope_dim)):
+        w.add_metadata(f"{arch}.{key}", value)
+    if held:
+        w.add_metadata(f"{arch}.expert_held_first", first)
+        w.add_metadata(f"{arch}.expert_held_count", count)
+    D, H, E = cfg.dim, cfg.n_heads, cfg.n_experts
+    r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
+    d_n, d_r, d_v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    F, Fe, n_out = cfg.ffn_dim, cfg.expert_ffn_dim, E + cfg.n_zero_experts
+
+    def t(name, shape, gtype, mul=1.0, rows=None):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x if rows is None else x[rows], gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name, 1.0 + 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    mine = slice(first, first + count) if held else None
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16)
+    for i in range(cfg.n_layers):
+        for s in (0, 1):
+            p = f"blk.{i}.{s}."
+            norm(p + "attn_norm.weight", D)
+            t(p + "attn_q_a.weight", (r_q, D), mix["attn_q_a"])
+            norm(p + "attn_q_a_norm.weight", r_q)
+            t(p + "attn_q_b.weight", (H * (d_n + d_r), r_q), mix["attn_q_b"])
+            t(p + "attn_kv_a_mqa.weight", (r_kv + d_r, D),
+              mix["attn_kv_a_mqa"])
+            norm(p + "attn_kv_a_norm.weight", r_kv)
+            t(p + "attn_kv_b.weight", (H * (d_n + d_v), r_kv),
+              mix["attn_kv_b"])
+            t(p + "attn_output.weight", (D, H * d_v), mix["attn_output"])
+            norm(p + "ffn_norm.weight", D)
+            t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+            t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+            t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+        p = f"blk.{i}."
+        t(p + "ffn_gate_inp.weight", (n_out, D), GGMLType.F32, router_scale)
+        bias = rng.standard_normal(n_out).astype(np.float32)  # drawn always
+        if bias_scale is not None:
+            w.add_tensor(p + "exp_probs_b.bias", bias_scale * bias,
+                         GGMLType.F32)
+        t(p + "ffn_gate_exps.weight", (E, Fe, D), mix["ffn_gate_exps"],
+          rows=mine)
+        t(p + "ffn_up_exps.weight", (E, Fe, D), mix["ffn_up_exps"], rows=mine)
+        t(p + "ffn_down_exps.weight", (E, D, Fe), mix["ffn_down_exps"],
+          rows=mine)
+    norm("output_norm.weight", D)
+    t("output.weight", (cfg.vocab_size, D), mix["output"])
+    w.write()
+    return cfg
+
+
 #: a tiny ``exaone-moe`` file that keeps every ratio of the published
 #: block (models/hybrid.py): window window window global x 2, a window of
 #: 16 positions, 4 heads on 2 KV heads of 32 (heads x width is not the

@@ -919,6 +919,141 @@ def test_latent_stack_compiles_with_no_ring_sized_copy(one_chip, monkeypatch,
         assert _slice_widths(cfg) == [256, 1024]
 
 
+def _longcat(one_chip, **flags):
+    """BENCHMARK.json's longcat configuration at its published widths
+    (hidden 6144, 64 heads, latents 1536 / 512 + 64, 4 double layers: 8
+    attention sub-layers and 8 dense feed-forwards of 12288, 4 expert
+    branches holding 64 of 512 experts beside 256 identity outputs, top-12),
+    n_ctx 16384: (cfg, the parameters' shapes on the described chip,
+    ``place``)."""
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+
+    D, V, H, L = 6144, 131072, 64, 4
+    r_q, r_kv, d_n, d_r, d_v, F, Fe, E = 1536, 512, 128, 64, 128, 12288, \
+        2048, 64
+    cfg = ModelConfig(
+        vocab_size=V, dim=D, n_layers=L, n_heads=H, n_kv_heads=H, ffn_dim=F,
+        n_ctx=16384, rope_theta=1e7, rms_eps=1e-5, attn_impl="xla",
+        q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_n, qk_rope_dim=d_r,
+        v_head_dim=d_v, expert_ffn_dim=Fe, n_experts=512, n_experts_used=12,
+        n_zero_experts=256, expert_gating="softmax",
+        expert_weights_scale=6.0, experts_first=0, experts_held=E,
+        attn_sublayers=2, q_latent_scale=2.0, kv_latent_scale=12.0 ** 0.5,
+        **flags)
+
+    def exps(fmt, n, k):
+        kt = k // 2048
+        if fmt == "q4k":
+            return {"qs": S(L, E, n, k // 2, dtype=i8),
+                    "sm": S(L, E, kt, n, 128)}
+        return {"q4": S(L, E, n, k // 2, dtype=i8),
+                "q2": S(L, E, n, k // 4, dtype=i8),
+                "sm6": S(L, E, kt, n, 128)}
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    L2 = 2 * L
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": _planes("q6k", V, D),
+        "layers": {
+            "attn": {"attn_norm": S(L2, D, dtype=f32),
+                     "q_a_norm": S(L2, r_q, dtype=f32),
+                     "kv_a_norm": S(L2, r_kv, dtype=f32),
+                     "wq_a": _planes("q4k", r_q, D, L2),
+                     "wq_b": {"w": S(L2, H * (d_n + d_r), r_q)},
+                     "wkv_a": _planes("q4k", 640, D, L2),
+                     "wo": _planes("q4k", D, H * d_v, L2),
+                     "w_uk": {"w": S(L2, H, d_n, r_kv)},
+                     "w_uv": {"w": S(L2, H, d_v, r_kv)}},
+            "ffn": {"ffn_norm": S(L2, D, dtype=f32),
+                    "w_gate": _planes("q4k", F, D, L2),
+                    "w_up": _planes("q4k", F, D, L2),
+                    "w_down": _planes("q6k", D, F, L2)},
+            "moe": {"w_router": S(L, 768, D, dtype=f32),
+                    "router_bias": S(L, 768, dtype=f32),
+                    "w_gate_exps": exps("q4k", Fe, D),
+                    "w_up_exps": exps("q4k", Fe, D),
+                    "w_down_exps": exps("q6k", D, Fe)}}})
+    return cfg, params, place
+
+
+# (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("longcat-serial", 0),
+                                        ("longcat-16lane", 16)])
+def test_shortcut_stack_compiles_with_no_ring_sized_copy(one_chip,
+                                                         monkeypatch, name,
+                                                         lanes):
+    """The decode chunk and the prefill slices of the ``longcat-flash``
+    stack (models/mla.py ``shortcut_layer``) compile for the chip as a chip
+    serves them (both latent kernels on): the fused planes at K 6144 / 12288
+    / 8192 / 2048 with nothing filled up, the latent projection at 640 rows,
+    the decode kernel on the 8-leaf latent ring as it is, and the FEW-row
+    grouped expert kernels on the lane step's 16 x 12 = 192 rows (the
+    many-row ones on a slice's).  The compiler has put NO copy or transpose
+    of the latent leaf in the decode chunk, and a slice's scratch stays
+    under a GB a 256 rows."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import decode_slots
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg, params, place = _longcat(one_chip, latent_kernel=True,
+                                  latent_slice_kernel=True)
+    assert decode_slots(cfg.n_held, 16, cfg.n_experts_used) == 64
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "q4k_expert_matmul_fewrow" in text
+    assert "q6k_expert_matmul_fewrow" in text
+    assert "expert_matmul_manyrow" not in text
+    found = _leaf_copies(text)
+    assert not found, found[:4]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    leaf = "bf16[%d,8,1,16384,640]" % (lanes or 1)
+    kernel = [ln for ln in text.splitlines()
+              if re.match(r"\s*(ROOT )?%flash_attention_decode_latent", ln)]
+    assert kernel and all(leaf in ln.split(" custom-call(")[0]
+                          for ln in kernel), kernel[:2]
+    assert not re.search(
+        r"= bf16\[(\d+,)*16384,640\]\S* dynamic-update-slice\(", text)
+    if not lanes:       # the admission slices into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert _slice_widths(cfg) == [256, 1024]
+        for rows in (128, 256, 1024):
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            assert "q4k_expert_matmul_manyrow" in sliced.as_text()
+            assert "flash_attention_prefill_latent" in sliced.as_text()
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 1024 * 2 ** 20 * max(rows, 256) // 256
+
+
 @pytest.mark.parametrize("rows", [128, 256, 1024])
 def test_latent_slice_program_holds_the_slice_kernel(one_chip, monkeypatch,
                                                      rows):

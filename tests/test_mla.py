@@ -469,6 +469,10 @@ def test_the_read_of_a_slice_follows_s_the_backend_and_the_probe(
     one, _ = mla.CACHE.probe_kernels(cfg, "pallas", "xla", [])
     assert one.latent_kernel and not one.latent_slice_kernel
     assert [d["reason"] for d in DEVTIME.degrades()[before:]] == ["Mosaic: no"]
+    # the ledger is the process's: a later test of this worker that holds
+    # /debug/compiles to "no degrades" must not find this fake one
+    with DEVTIME._lock:
+        DEVTIME._degrades.pop(("<lambda>", "Mosaic: no"))
 
     def kernels(c, S):
         """The Pallas kernels in one pass of S tokens at position 32."""

@@ -710,12 +710,13 @@ def test_expert_counters_fold_finished_chunks_when_read():
     c = ExpertCounters(3, n_slots=3)
     assert c.snapshot() == {"layer_steps": 0, "experts_read": 0,
                             "picks": [0, 0, 0], "picks_held": 0,
-                            "picks_total": 0, "slots_skipped": 0}
+                            "picks_total": 0, "picks_zero": 0,
+                            "slots_skipped": 0}
     for _ in range(70):                 # past the pending bound: still exact
         c.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
     assert c.snapshot(block=True) == {
         "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280],
-        "picks_held": 350, "picks_total": 630,
+        "picks_held": 350, "picks_total": 630, "picks_zero": 0,
         # 140 calls of 3 slots walked 210 of them
         "slots_skipped": 140 * 3 - 210}
     d = ExpertCounters(3)               # no grouped few-row call: no slots
