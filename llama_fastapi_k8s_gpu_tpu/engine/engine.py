@@ -858,23 +858,42 @@ class Engine:
         if self._expert_counters is None:
             self._expert_counters = ExpertCounters(
                 self.cfg.n_held, self.expert_slots,
-                zero=bool(self.cfg.n_zero_experts))
+                zero=bool(self.cfg.n_zero_experts), n_rows=self.expert_rows)
         return self._expert_counters
+
+    @property
+    def _grouped_step(self) -> tuple | None:
+        """(tokens, picks a token) of a decode step's grouped expert call;
+        None where the experts serve dequantized and for a dense block."""
+        from ..models.params import flat_layers
+        from ..ops.pallas.experts import family_of
+
+        experts = [leaf for name, leaf in flat_layers(self.params["layers"])
+                   if name.endswith("_exps")]
+        if not experts or not all(family_of(leaf) for leaf in experts):
+            return None
+        return getattr(self, "batch_size", 1), self.cfg.n_experts_used
 
     @property
     def expert_slots(self) -> int:
         """The slots of a decode step's grouped expert call (``T``: /health
         ``engine.expert_slots``), which ends its grid at those in use; 0
-        where the experts serve dequantized and for a dense block."""
-        from ..models.params import flat_layers
-        from ..ops.pallas.experts import decode_slots, family_of
+        without such a call."""
+        from ..ops.pallas.experts import decode_slots
 
-        experts = [leaf for name, leaf in flat_layers(self.params["layers"])
-                   if name.endswith("_exps")]
-        if not experts or not all(family_of(leaf) for leaf in experts):
-            return 0
-        return decode_slots(self.cfg.n_held, getattr(self, "batch_size", 1),
-                            self.cfg.n_experts_used)
+        step = self._grouped_step
+        return decode_slots(self.cfg.n_held, *step) if step else 0
+
+    @property
+    def expert_rows(self) -> int:
+        """The (token, pick) rows of a decode step's routed layer where it
+        is compacted to the rows that reach a held expert (/health
+        ``engine.expert_rows``); 0 where the layer is built as it always
+        was (64 rows or fewer) and without a grouped call."""
+        from ..ops.pallas.experts import compacted_rows
+
+        step = self._grouped_step
+        return compacted_rows(*step) if step else 0
 
     def _next_seed(self) -> int:
         with self._id_lock:

@@ -17,6 +17,12 @@ more than the append.
 grouped expert call has ``n_slots`` slots whatever the router picked
 (ops/pallas/experts.py ``decode_slots``) and its grid ends at the slots in
 use, so ``layer_steps x n_slots - experts_read`` slots were never walked.
+``rows_skipped`` is the same for the call's ROW axis: a step's layer of
+``n_rows`` (token, pick) rows that is compacted to the rows that reach a
+held expert (``compacted_rows``: more than 64 rows) multiplies those in a
+block of 64, so ``layer_steps x n_rows - picks_held`` rows were offered and
+never multiplied (a step with more than 64 such rows multiplies them all:
+not told apart here, and not seen where a share of the experts is held).
 """
 
 from __future__ import annotations
@@ -29,8 +35,10 @@ _MAX_PENDING = 64   # older chunks have long finished: folding them is free
 
 
 class ExpertCounters:
-    def __init__(self, n_held: int, n_slots: int = 0, zero: bool = False):
+    def __init__(self, n_held: int, n_slots: int = 0, zero: bool = False,
+                 n_rows: int = 0):
         self.n_slots = n_slots      # 0: no grouped few-row call serves
+        self.n_rows = n_rows        # 0: or it is built as it always was
         self.n_held = n_held
         self._lock = threading.Lock()
         self._pending: list = []
@@ -50,7 +58,8 @@ class ExpertCounters:
         ``picks`` (a list, one count per held expert), ``picks_held`` (their
         sum), ``picks_total`` (over all the router's outputs),
         ``picks_zero`` (of those, the picks of a zero expert; 0 where the
-        router has none) and ``slots_skipped`` (module docstring)."""
+        router has none), ``slots_skipped`` and ``rows_skipped`` (module
+        docstring)."""
         with self._lock:
             keep = []
             for s in self._pending:
@@ -61,9 +70,12 @@ class ExpertCounters:
             self._pending = keep
             t = self._total
             picks = t[2:2 + self.n_held]
+            held = int(picks.sum())
             return {"layer_steps": int(t[0]), "experts_read": int(t[1]),
-                    "picks": picks.tolist(), "picks_held": int(picks.sum()),
+                    "picks": picks.tolist(), "picks_held": held,
                     "picks_total": int(t[2 + self.n_held]),
                     "picks_zero": int(t[3 + self.n_held:].sum()),
                     "slots_skipped": int(t[0] * self.n_slots - t[1])
-                    if self.n_slots else 0}
+                    if self.n_slots else 0,
+                    "rows_skipped": int(t[0] * self.n_rows) - held
+                    if self.n_rows else 0}

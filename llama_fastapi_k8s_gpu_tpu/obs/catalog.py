@@ -315,6 +315,15 @@ METRICS: dict[str, Metric] = _register(
            "(/health engine.expert_slots: the held experts or a step's "
            "(token, pick) rows, the fewer) - experts_read_total; host "
            "arithmetic at scrape; 0 where the experts serve dequantized"),
+    Metric("expert_rows_skipped_total", GAUGE,
+           "(token, pick) rows the decode steps offered their grouped "
+           "expert calls that reached no expert held here and were never "
+           "multiplied (a layer of more than 64 rows sends the rows that "
+           "reach an expert through calls of 64): expert_layer_steps_total "
+           "x the rows of a step's layer (/health engine.expert_rows) - "
+           "expert_picks_held_total; host arithmetic at scrape; 0 where a "
+           "step's layer has 64 rows or fewer (it is built as it always "
+           "was) or the experts serve dequantized"),
     Metric("expert_picks_total", GAUGE,
            "(token, pick) rows each expert took in decode chunks, "
            "cumulative; the largest over their sum is the most-loaded "

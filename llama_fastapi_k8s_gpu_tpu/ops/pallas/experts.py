@@ -18,14 +18,27 @@ kernel bodies.  What is new is the grid around them:
   saturated decode step of LFM2 leaves 35 of 64 slots idle (PERF.md
   section 6, PR 51);
 - FEW rows (a decode step: lanes x k (token, pick) rows, at most
-  ``FEW_ROWS``): a slot is a distinct expert; every slot sees ALL the rows
-  (one resident block) and adds its product into the one output block for
-  the rows that picked its expert, zero for the rest
+  ``FEW_ROWS``): a slot is a distinct expert; the rows are ONE resident
+  block, and a slot adds its product into the one output block for the
+  rows that picked its expert, zero for the rest
   (:func:`grouped_matmul_few`).  Grid (slot, N tile, K tile), the output
   block all of N and resident for the whole call: the traced extent is
-  the outermost axis, so the pipeline never restarts inside the call.  No
-  sort, no gather, no padding: the MXU does a few times the work of the
-  picked rows alone, which is not what bounds these kernels;
+  the outermost axis, so the pipeline never restarts inside the call.
+  Every slot multiplies ALL the rows of the block, no sort, no padding:
+  the MXU does a few times the work of the picked rows alone, which hides
+  under the dequantization up to 64 rows.  It no longer does at 128 and
+  192, of which a layer that holds a share of its experts
+  (models/routed.py ``held_picks``) sends 9-12 to an expert here, and a
+  call whose K is several tiles fetches the whole row block again at every
+  grid step.  So a layer of more than ``ROW_GROUP`` (64) rows COMPACTS
+  them (:func:`_routed_raw`): the rows that reach an expert, in their
+  order (:func:`compact_rows`: a cumulative sum, one scatter, one gather of
+  the activations in place of their repeat), go through the SAME three
+  calls as a block of 64 rows, and one gather puts the down call's rows
+  back in their places; a step with more than 64 such rows makes the
+  calls of all the rows instead (one ``lax.cond``; both forms are in the
+  program).  A layer of 64 rows or fewer is built as it always was
+  (PERF.md section 6, PR 53);
 - MANY rows (a prefill slice): the rows are sorted by expert and laid out
   in tiles of ``TM_MANY`` rows, each expert's rows padded up to whole
   tiles, so a slot is a row tile of one expert (:func:`plan_groups`,
@@ -79,6 +92,11 @@ from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
 #: picks (``longcat-flash``); no program of another served file has rows
 #: between 128, what it was, and this (a prefill slice is 128 tokens or more)
 FEW_ROWS = 192
+#: rows of a few-row layer up to which it is built as it always was (every
+#: slot multiplies all the rows: at 64 the MXU's pass still hides under the
+#: dequantization); a layer of more rows sends those that reach an expert
+#: through calls of this many (module docstring)
+ROW_GROUP = 64
 TM_MANY = 128    # rows per tile of a prefill slice
 FEW_VMEM = 64 * 2 ** 20  # a few-row call's limit: its output block is all N
 
@@ -202,6 +220,32 @@ def decode_slots(n_experts: int, n_tokens: int, k: int) -> int:
     (``expert_slots_skipped_total``: engine/expert_counters.py)."""
     rows = n_tokens * k
     return min(n_experts, rows) if rows <= FEW_ROWS else 0
+
+
+def compacted_rows(n_tokens: int, k: int) -> int:
+    """The (token, pick) rows of a few-row layer that is compacted to the
+    rows that reach an expert (more than :data:`ROW_GROUP` of them); 0 for
+    a layer built as it always was and for the many-row plan
+    (``expert_rows_skipped_total``: engine/expert_counters.py)."""
+    rows = n_tokens * k
+    return rows if ROW_GROUP < rows <= FEW_ROWS else 0
+
+
+def compact_rows(row_expert: jax.Array, n_experts: int, n_places: int):
+    """The rows that reach an expert, in their order, laid out in
+    ``n_places`` places: (``place`` (R,): each row's place, ``n_places`` or
+    more for a row without an expert or past the last place; ``src``
+    (n_places,): the row at each place, R past the ``n_real`` in use;
+    ``n_real``, which may exceed the places).  ``row_expert`` (R,) in
+    [0, E]; E = no expert."""
+    i32 = jnp.int32
+    R = row_expert.shape[0]
+    real = row_expert < n_experts
+    n_real = jnp.sum(real, dtype=i32)
+    place = jnp.where(real, jnp.cumsum(real, dtype=i32) - 1, n_places)
+    src = jnp.full(n_places, R, i32).at[place].set(
+        jnp.arange(R, dtype=i32), mode="drop")
+    return place, src, n_real
 
 
 def n_tiles(n_rows: int, n_experts: int, n_tokens: int, tm: int) -> int:
@@ -422,15 +466,50 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
     layer = jnp.asarray(idx, jnp.int32).reshape(1)
     row_expert = picks.reshape(R)
     few = R <= FEW_ROWS
+
+    def products(call, xr):
+        """Rows -> their picked experts' SwiGLU, ``call`` a grouped matmul."""
+        g = call(gate, variants[0], xr, pg)
+        u = call(up, variants[1], xr, pu)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        return call(down, variants[2], h, pd)
+
+    def back(out, pos):         # from the places of a layout to the rows
+        P = out.shape[0]
+        rows = out[jnp.minimum(pos, P - 1)]
+        return jnp.where((pos < P)[:, None], rows, 0.0)
+
     if few:
         count, experts, n_used = experts_in_use(row_expert, E,
                                                 decode_slots(E, M, k))
-        xr = jnp.repeat(x, k, axis=0)                  # row (m, j) = x[m]
 
-        def call(fam, variant, rows, w):
-            return grouped_matmul_few(fam, meta, rows, row_expert, w,
-                                      fold_factor(rows.shape[1]), interpret,
-                                      variant)
+        def few_rows(xr, row_expert):
+            return products(
+                lambda fam, variant, rows, w: grouped_matmul_few(
+                    fam, meta, rows, row_expert, w,
+                    fold_factor(rows.shape[1]), interpret, variant), xr)
+
+        def as_it_was():
+            return few_rows(jnp.repeat(x, k, axis=0), row_expert)
+
+        if compacted_rows(M, k):
+            # the rows that reach an expert, in a call of ROW_GROUP rows,
+            # put back in their places (zero where a row had none); the
+            # call of all the rows where more of them do
+            meta = jnp.concatenate([layer, n_used[None], experts])
+            place, src, n_real = compact_rows(row_expert, E, ROW_GROUP)
+
+            def compacted():
+                token = jnp.where(src < R, src // k, M)   # M: the zero row
+                xc = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[token]
+                ec = jnp.concatenate([row_expert, jnp.full(1, E, jnp.int32)])
+                return back(few_rows(xc, ec[src]), place)
+
+            out = jax.lax.cond(n_real <= ROW_GROUP, compacted, as_it_was)
+        else:
+            xr = jnp.repeat(x, k, axis=0)              # row (m, j) = x[m]
+            meta = jnp.concatenate([layer, n_used[None], experts])
+            out = few_rows(xr, row_expert)
     else:
         plan = plan_groups(row_expert, E, M, TM_MANY)
         count, experts, n_used = (plan["count"], plan["tile_expert"],
@@ -438,20 +517,11 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
         # each padded slot's token (row // k), or the zero row M
         token = jnp.where(plan["src"] < R, plan["src"] // k, M)
         xr = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[token]
-
-        def call(fam, variant, rows, w):
-            return grouped_matmul_many(fam, meta, rows, w,
-                                       fold_factor(rows.shape[1]), interpret,
-                                       variant)
-    meta = jnp.concatenate([layer, n_used[None], experts])
-    g = call(gate, variants[0], xr, pg)
-    u = call(up, variants[1], xr, pu)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    out = call(down, variants[2], h, pd)
-    if not few:                 # back from the padded layout to the rows
-        P = out.shape[0]
-        rows = out[jnp.minimum(plan["pos"], P - 1)]
-        out = jnp.where((plan["pos"] < P)[:, None], rows, 0.0)
+        meta = jnp.concatenate([layer, n_used[None], experts])
+        out = back(products(
+            lambda fam, variant, rows, w: grouped_matmul_many(
+                fam, meta, rows, w, fold_factor(rows.shape[1]), interpret,
+                variant), xr), plan["pos"])
     y = jnp.sum(out.reshape(M, k, D) * weights[:, :, None], axis=1)
     return y.astype(x.dtype), count
 

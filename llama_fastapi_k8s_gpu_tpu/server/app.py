@@ -1515,6 +1515,12 @@ def create_app(engine=None, settings: Settings | None = None,
             slots = getattr(eng, "expert_slots", 0)
             if slots:
                 engine_info["expert_slots"] = slots
+            # and, where the layer is compacted to the rows that reach a held
+            # expert, the rows a step offers it (expert_rows_skipped_total
+            # counts those it never multiplied)
+            rows = getattr(eng, "expert_rows", 0)
+            if rows:
+                engine_info["expert_rows"] = rows
             # a vocabulary the tokenizer cannot cut at spaces pays the
             # whole-text merge loop on every prompt (tokenizer/spm.py);
             # absent where it can
@@ -1632,6 +1638,7 @@ def create_app(engine=None, settings: Settings | None = None,
             m.set_gauge("expert_layer_steps_total", snap["layer_steps"])
             m.set_gauge("experts_read_total", snap["experts_read"])
             m.set_gauge("expert_slots_skipped_total", snap["slots_skipped"])
+            m.set_gauge("expert_rows_skipped_total", snap["rows_skipped"])
             for e, n in enumerate(snap["picks"]):
                 m.set_gauge("expert_picks_total", n, expert=str(e))
             m.set_gauge("expert_picks_routed_total", snap["picks_total"])

@@ -227,6 +227,47 @@ def test_routed_experts_compile_at_olmoe_widths(one_chip, tokens, regime):
     assert f"q6k_expert_matmul_{regime}" in txt
 
 
+# a 16-lane decode step's routed layer where a share of the experts is held
+# (name, experts held, hidden size, expert width, picks a token): 128 and
+# 192 rows, of which a step sends the few that reach a held expert through
+# calls of 64 rows
+@pytest.mark.parametrize("name,E,D,F,k", [
+    ("gigachat", 32, 7168, 2048, 8), ("kexaone", 16, 6144, 2048, 8),
+    ("longcat", 64, 6144, 2048, 12)])
+def test_compacted_few_row_experts_compile_at_the_held_share_widths(
+        one_chip, name, E, D, F, k):
+    """Both forms of the layer are in the program under one conditional:
+    ONE Mosaic call a matrix on 64 rows and one on all the step's rows,
+    under the names the benchmark's readers find."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
+        ROW_GROUP, compacted_rows, padded_k, routed_experts)
+
+    L, lanes = 2, 16
+    rows = compacted_rows(lanes, k)
+    assert rows == lanes * k > ROW_GROUP
+    Dk = padded_k(D)                     # gigachat: K 7168 held at 8192
+    gate = {"qs": S(L, E, F, Dk // 2, dtype=i8),
+            "sm": S(L, E, Dk // 2048, F, 128)}
+    down = {"q4": S(L, E, D, F // 2, dtype=i8),
+            "q2": S(L, E, D, F // 4, dtype=i8), "sm6": S(L, E, 1, D, 128)}
+    txt = _compile(
+        one_chip,
+        lambda x, p, w, g, u, d, i: routed_experts(x, p, w, g, u, d, i,
+                                                   interpret=False),
+        S(lanes, D), S(lanes, k, dtype=i32), S(lanes, k, dtype=f32),
+        gate, dict(gate), down, S(dtype=i32))
+    calls = re.findall(r"%(q[46]k_expert_matmul_\w+?)(?:\.\d+)? = "
+                       r"(f32\[\d+,\d+\])\S* custom-call\(", txt)
+    assert sorted(calls) == sorted(
+        [("q4k_expert_matmul_fewrow", f"f32[{r},{F}]")
+         for r in (ROW_GROUP, rows)] * 2
+        + [("q6k_expert_matmul_fewrow", f"f32[{r},{D}]")
+           for r in (ROW_GROUP, rows)]), calls
+    assert " conditional(" in txt
+
+
 @pytest.mark.parametrize("seq,quantized", [
     (128, False), (256, False), (512, False), (1024, False),
     (128, True), (1024, True),

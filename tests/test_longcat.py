@@ -32,6 +32,7 @@ import pytest
 from tests.test_mla import (
     N_CTX, N_PROMPT, N_SEQ, SLICE, lane_alone, lanes_run, load, prefill,
     programs, rel, rows_that_differ, with_kernel, worst)
+from tests.test_olmoe import _as_it_was_built
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
@@ -271,6 +272,47 @@ def test_a_share_counts_what_left_and_what_is_free(loadeds, tokens):
     assert 0 < held and 0 < zero and held + zero < stats[-2]
 
 
+def test_a_step_of_many_lanes_compacts_its_rows_to_the_held_picks(paths,
+                                                                  monkeypatch):
+    """The lane engines' step (``vmap`` over lanes of ``expert_branch``) on
+    a share of the experts, through the grouped kernels: 24 lanes x 3 picks
+    are 72 rows of ONE call, compacted to the picks of experts 2..5 (an
+    identity pick, a pick of an expert held elsewhere and a dead lane's
+    rows reach none) in calls of 64 rows.  Bit for bit the branch with
+    every call built as it was before; the counters are the live lanes'."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.models.routed import MOE, expert_branch
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    params, cfg = load(paths["share"], fmt="q4k")
+    layers = params["layers"][MOE]
+    assert X.family_of(layers["w_gate_exps"]) == "q4k"
+    lanes, k = 24, cfg.n_experts_used
+    assert X.compacted_rows(lanes, k) == 72
+    hn = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (lanes, 1, cfg.dim)), jnp.bfloat16)
+    live = jnp.asarray(np.arange(lanes) % 7 != 3)      # dead between live
+
+    def step():
+        return jax.vmap(lambda h, on: expert_branch(
+            h, layers, jnp.int32(1), cfg, on))(hn, live)
+
+    out, (count, picks, total, zero) = step()
+    want, (want_count, *_) = _as_it_was_built(monkeypatch, step)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(count, want_count)
+    picks, on = np.asarray(picks)[:, 0], np.asarray(live)
+    held = (picks >= SHARE[0]) & (picks < SHARE[0] + SHARE[1]) & on[:, None]
+    assert 0 < held.sum() < on.sum() * k
+    np.testing.assert_array_equal(
+        count[0], np.bincount(picks[held] - SHARE[0], minlength=SHARE[1]))
+    assert int(total[0]) == on.sum() * k
+    assert int(zero[0]) == (picks[on] >= cfg.n_experts).sum() > 0
+
+
 def test_expert_counters_fold_the_zero_picks_where_the_router_has_any():
     import jax.numpy as jnp
 
@@ -283,7 +325,7 @@ def test_expert_counters_fold_the_zero_picks_where_the_router_has_any():
     assert c.snapshot(block=True) == {
         "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280],
         "picks_held": 350, "picks_total": 1680, "picks_zero": 560,
-        "slots_skipped": 140 * 3 - 210}
+        "slots_skipped": 140 * 3 - 210, "rows_skipped": 0}
 
 
 # ---------------------------------------------------------------------------

@@ -430,6 +430,238 @@ def test_a_few_row_call_is_its_rows_through_the_dense_kernel_bit_for_bit(
     assert np.abs(got).sum() > 0
 
 
+def _rows_with_an_expert(rng, R, E, n_real, used=None):
+    """(R,) row experts: ``n_real`` rows at random places reach an expert
+    (of ``used`` distinct ones dealt round, else any), the rest ``E``."""
+    row_expert = np.full(R, E, np.int32)
+    at = np.sort(rng.permutation(R)[:n_real])
+    row_expert[at] = rng.integers(0, E, n_real) if used is None else (
+        (np.arange(used) * E) // used)[np.arange(n_real) % used]
+    return row_expert
+
+
+# (type, rows of the layer's call, rows that reach an expert, distinct
+# experts (None: any), K): the rows in use up to a compacted call's 64 at
+# both row counts of the served files, one slot and every slot in use, a
+# folded K (f = 2)
+COMPACTED = [(g, R, n, None, 2048) for g in ("Q4_K", "Q6_K")
+             for R in (128, 192) for n in (0, 1, 63, 64)] + [
+    ("Q4_K", 128, 40, 1, 2048), ("Q6_K", 192, 64, 1, 2048),
+    ("Q4_K", 192, 64, 4, 2048), ("Q6_K", 128, 17, 4, 2048),
+    ("Q4_K", 128, 1, None, 1024), ("Q6_K", 128, 64, None, 1024),
+    ("Q6_K", 192, 63, 1, 1024), ("Q4_K", 192, 64, 4, 1024)]
+
+
+@pytest.mark.parametrize("gtype,R,n_real,used,K", COMPACTED)
+def test_a_compacted_call_is_the_call_of_all_rows_and_the_dense_kernel(
+        gtype, R, n_real, used, K):
+    """The layer's call of ``ROW_GROUP`` rows on the rows that reach an
+    expert (:func:`compact_rows`: in their order, the zero row past them):
+    put back in the rows' places it is, bit for bit, the call of all R rows
+    as it was built before (every slot multiplies all of them) and each
+    row's own row of the dense fused matmul of its expert's planes; the
+    places past the rows in use stay zero."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    E, N, layer, G = 4, 128 * (2048 // K), 0, 64
+    rng = np.random.default_rng(R + n_real)
+    w, _ = _expert_weights(E, N, K, gtype, rng, L=1)
+    fam = X.FAMILIES[X.family_of(w)]
+    f = X.fold_factor(K)
+    planes = [w[key] for key in fam.planes]
+    x = jnp.asarray(rng.standard_normal((R, K)), jnp.bfloat16)
+    row_expert = _rows_with_an_expert(rng, R, E, n_real, used)
+    assert X.ROW_GROUP == G and X.compacted_rows(R, 1) == R
+    assert not X.compacted_rows(G, 1)
+    assert not X.compacted_rows(X.FEW_ROWS + 1, 1)
+    _, slots, n_used = X.experts_in_use(row_expert, E, X.decode_slots(E, R, 1))
+    assert used is None or int(n_used) == used
+    meta = jnp.concatenate([jnp.asarray([layer], jnp.int32), n_used[None],
+                            slots])
+    want = np.asarray(X.grouped_matmul_few(
+        fam, meta, x, jnp.asarray(row_expert), planes, f, True, "cur"))
+    place, src, n = X.compact_rows(jnp.asarray(row_expert), E, G)
+    assert int(n) == n_real
+    np.testing.assert_array_equal(np.asarray(src)[:n_real],
+                                  np.flatnonzero(row_expert < E))
+    assert (np.asarray(src)[n_real:] == R).all()
+    got = np.asarray(X.grouped_matmul_few(
+        fam, meta, jnp.concatenate([x, jnp.zeros((1, K), x.dtype)])[src],
+        jnp.asarray(np.append(row_expert, E))[src], planes, f, True, "cur"))
+    assert got.shape[0] == G and not got[n_real:].any()
+    back = np.concatenate([got, np.zeros((1, got.shape[1]), got.dtype)])[
+        np.asarray(place)]
+    np.testing.assert_array_equal(back, want)
+    assert (np.abs(back).sum() > 0) == (n_real > 0)
+    if f == 1:      # the dense kernel on the same row block, an expert a time
+        xpa = X._activations(x, fam)
+        dense = {"q4k": X._q4._q4k_2d_raw, "q6k": X._q6._q6k_2d_raw}[fam.name]
+        for e in np.unique(row_expert[row_expert < E]):
+            rows = np.flatnonzero(row_expert == e)
+            np.testing.assert_array_equal(back[rows], np.asarray(dense(
+                xpa, *(p[layer, e] for p in planes), True, "cur"))[rows])
+
+
+def _as_it_was_built(monkeypatch, run):
+    """``run()`` with every few-row call built as it was before (the
+    threshold raised to all of them)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    monkeypatch.setattr(X, "ROW_GROUP", X.FEW_ROWS)
+    X._routed_fn.cache_clear()           # the jitted layer read the old one
+    try:
+        return run()
+    finally:
+        monkeypatch.undo()
+        X._routed_fn.cache_clear()
+
+
+@pytest.mark.parametrize("k,n_real,D,F", [
+    (k, n, 256, 256) for k in (8, 12) for n in (0, 1, 63, 64, 65, 16 * k)] + [
+    (8, 10, 2048, 1024), (12, 65, 2048, 1024)])
+def test_the_layer_of_more_than_64_rows_is_the_layer_as_it_was_built(
+        monkeypatch, k, n_real, D, F):
+    """16 tokens' picks (128 and 192 rows), of which ``n_real`` at random
+    places reach an expert: up to 64 the layer runs its three calls on a
+    block of 64 rows, with more the calls of all the rows; either way
+    result and counts are bit for bit the layer as it was built before."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import routed_experts
+
+    M, E = 16, 8
+    rng = np.random.default_rng(k + n_real)
+    g, _ = _expert_weights(E, F, D, "Q4_K", rng, 1)
+    u, _ = _expert_weights(E, F, D, "Q4_K", rng, 1)
+    d, _ = _expert_weights(E, D, F, "Q6_K", rng, 1)
+    x = jnp.asarray(rng.standard_normal((M, D)), jnp.bfloat16)
+    picks = jnp.asarray(_rows_with_an_expert(rng, M * k, E, n_real
+                                             ).reshape(M, k))
+    wts = jnp.asarray(rng.random((M, k)), jnp.float32)
+
+    def layer():
+        return routed_experts(x, picks, wts, g, u, d, 0)
+
+    y, count = layer()
+    want, want_count = _as_it_was_built(monkeypatch, layer)
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(count, want_count)
+    assert int(np.asarray(count).sum()) == n_real
+    assert np.asarray(y, np.float32).any() == (n_real > 0)
+
+
+@pytest.mark.parametrize("lanes,k,dead", [(16, 8, (5,)), (16, 12, (0, 9)),
+                                          (8, 8, (3,))])
+def test_lanes_with_a_dead_one_between_them_give_the_uncompacted_layer(
+        monkeypatch, lanes, k, dead):
+    """Under ``vmap`` the lanes' picks are the rows of ONE call: 128 and
+    192 rows are compacted (a dead lane's rows and the picks of an expert
+    held elsewhere reach none), 64 rows are not.  The layer's result and
+    counts are, bit for bit, those of the layer with every call built as
+    it was before; a dead lane gets nothing and a live lane what it gets
+    alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    D, F, E = 256, 256, 8
+    rng = np.random.default_rng(lanes + k)
+    g, _ = _expert_weights(E, F, D, "Q4_K", rng, 1)
+    u, _ = _expert_weights(E, F, D, "Q4_K", rng, 1)
+    d, _ = _expert_weights(E, D, F, "Q6_K", rng, 1)
+    x = jnp.asarray(rng.standard_normal((lanes, 1, D)), jnp.bfloat16)
+    # a router over 4 E experts of which the first E are held here
+    picks = np.stack([rng.permutation(4 * E)[:k] for _ in range(lanes)])
+    picks = np.minimum(picks, E).astype(np.int32)
+    picks[list(dead)] = E
+    picks = jnp.asarray(picks[:, None])
+    wts = jnp.asarray(rng.random((lanes, 1, k)), jnp.float32)
+
+    def layer():
+        return jax.vmap(lambda a, p, w: X.routed_experts(a, p, w, g, u, d, 0)
+                        )(x, picks, wts)
+
+    y, count = layer()
+    want, want_count = _as_it_was_built(monkeypatch, layer)
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(count, want_count)
+    for lane in range(lanes):
+        alone, _ = X.routed_experts(x[lane], picks[lane], wts[lane], g, u, d,
+                                    0)
+        assert lane not in dead or not np.asarray(y[lane], np.float32).any()
+        np.testing.assert_allclose(np.asarray(y[lane], np.float32),
+                                   np.asarray(alone, np.float32), rtol=1e-2,
+                                   atol=1e-4)
+
+
+def _few_row_calls(M, k):
+    """The layer's jaxpr at ``M`` tokens of ``k`` picks: ([(name, rows of
+    the activation operand, entries of the prefetched vector, dots in the
+    kernel) of each pallas_call], the primitives outside the kernels)."""
+    import jax
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    D, F, E = 2048, 1024, 4
+    S = jax.ShapeDtypeStruct
+    i8, bf16 = jnp.int8, jnp.bfloat16
+    gate = [S((1, E, F, D // 2), i8), S((1, E, 1, F, 128), bf16)]
+    down = [S((1, E, D // 2, F), i8), S((1, E, D // 2, F // 2), i8),
+            S((1, E, 1, D // 2, 128), bf16)]
+    jaxpr = jax.make_jaxpr(
+        lambda *a: X._routed_raw(("q4k", "q4k", "q6k"), True,
+                                 ("resplit", "resplit", "cur"), *a))(
+        S((), jnp.int32), S((M, D), bf16), S((M, k), jnp.int32),
+        S((M, k), jnp.float32), *gate, *gate, *down)
+
+    def walk(jp):               # every equation outside the kernels
+        for eqn in jp.eqns:
+            yield eqn
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from walk(sub)
+
+    def dots(jp):
+        return sum((e.primitive.name == "dot_general")
+                   + sum(dots(s) for s in jax.core.jaxprs_in_params(e.params))
+                   for e in jp.eqns)
+
+    eqns = list(walk(jaxpr.jaxpr))
+    # a call's operands: the grid's traced extent, the prefetched vector,
+    # the rows
+    return [(eqn.params["name"], eqn.invars[2].aval.shape[0],
+             eqn.invars[1].aval.shape[0], dots(eqn.params["jaxpr"]))
+            for eqn in eqns if eqn.primitive.name == "pallas_call"], \
+        [eqn.primitive.name for eqn in eqns]
+
+
+def test_a_call_of_64_rows_or_fewer_is_built_as_it_always_was():
+    """Statically, by the rows of the call: at 64 rows (OLMoE's and LFM2's
+    decode steps) no row is moved (the activations are repeated, not
+    gathered), nothing chooses between two forms, and the layer is three
+    calls on all its rows (the down call's K is folded: 128 rows); at 128
+    rows the layer holds both forms under one ``cond``: three calls on 64
+    rows, three on all of them.  Every call is the same kernel under the
+    same name with the same prefetched vector."""
+    T, few = 4, "expert_matmul_fewrow"
+    calls, outside = _few_row_calls(8, 8)
+    assert calls == [("q4k_" + few, 64, 2 + T, 3), ("q4k_" + few, 64, 2 + T, 3),
+                     ("q6k_" + few, 128, 2 + T, 2)]
+    assert "gather" not in outside and "cond" not in outside
+    assert "cumsum" in outside          # the slots in use
+    calls, outside = _few_row_calls(16, 8)
+    assert sorted(calls) == sorted(
+        [("q4k_" + few, rows, 2 + T, 3) for rows in (64, 128)] * 2
+        + [("q6k_" + few, 2 * rows, 2 + T, 2) for rows in (64, 128)])
+    assert "gather" in outside and outside.count("cond") == 1
+
+
 def test_the_experts_probe_passes_in_interpret_mode():
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.probe import probe_fused_experts
 
@@ -711,17 +943,36 @@ def test_expert_counters_fold_finished_chunks_when_read():
     assert c.snapshot() == {"layer_steps": 0, "experts_read": 0,
                             "picks": [0, 0, 0], "picks_held": 0,
                             "picks_total": 0, "picks_zero": 0,
-                            "slots_skipped": 0}
+                            "slots_skipped": 0, "rows_skipped": 0}
     for _ in range(70):                 # past the pending bound: still exact
         c.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
     assert c.snapshot(block=True) == {
         "layer_steps": 140, "experts_read": 210, "picks": [70, 0, 280],
         "picks_held": 350, "picks_total": 630, "picks_zero": 0,
         # 140 calls of 3 slots walked 210 of them
-        "slots_skipped": 140 * 3 - 210}
+        "slots_skipped": 140 * 3 - 210, "rows_skipped": 0}
     d = ExpertCounters(3)               # no grouped few-row call: no slots
     d.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
     assert d.snapshot(block=True)["slots_skipped"] == 0
+
+
+@pytest.mark.parametrize("n_rows,chunks,want", [
+    (0, 3, 0),            # 64 rows or fewer: the call is built as it was
+    (128, 0, 0), (128, 1, 2 * 128 - 5), (192, 70, 70 * (2 * 192 - 5))])
+def test_rows_skipped_is_the_rows_offered_less_the_picks_held(n_rows, chunks,
+                                                              want):
+    """A known schedule: each chunk runs 2 (layer, step) pairs whose calls
+    of ``n_rows`` rows held 1 + 0 + 4 picks of an expert here."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.engine.expert_counters import (
+        ExpertCounters)
+
+    c = ExpertCounters(3, n_slots=3, n_rows=n_rows)
+    for _ in range(chunks):
+        c.push(jnp.asarray([2, 3, 1, 0, 4, 9], jnp.int32))
+    snap = c.snapshot(block=True)
+    assert snap["rows_skipped"] == want and snap["picks_held"] == 5 * chunks
 
 
 @pytest.mark.parametrize("engine", ["serial", "lanes"])
@@ -766,6 +1017,8 @@ def test_the_engines_serve_the_file_and_count_their_decode_steps(
     assert eng.expert_slots == min(eng.cfg.n_held, lanes * k)
     assert snap["slots_skipped"] == (steps * eng.expert_slots
                                      - snap["experts_read"]) >= 0
+    # at most 3 lanes x k rows: the call is built as it always was
+    assert lanes * k <= 64 and eng.expert_rows == 0 == snap["rows_skipped"]
 
 
 @pytest.mark.anyio
@@ -794,10 +1047,21 @@ async def test_health_names_the_slots_and_metrics_the_ones_skipped(gguf_path):
         async with httpx.AsyncClient(transport=transport,
                                      base_url="http://test") as client:
             info = (await client.get("/health")).json()["engine"]
-            assert info["expert_slots"] == k
+            assert info["expert_slots"] == k and "expert_rows" not in info
             eng.expert_counters.snapshot(block=True)
             m = (await client.get("/metrics")).text
             assert f"expert_slots_skipped_total {6 * k - 12}" in m
+            assert "expert_rows_skipped_total 0" in m
+            # the same engine with the lanes of a 128-row step
+            eng.batch_size, eng._expert_counters = 128 // k, None
+            assert eng.expert_rows == 128
+            eng.expert_counters.push(jnp.asarray(
+                [6, 12] + [7] + [0] * (held - 1) + [6 * k], jnp.int32))
+            info = (await client.get("/health")).json()["engine"]
+            assert info["expert_rows"] == 128
+            eng.expert_counters.snapshot(block=True)
+            m = (await client.get("/metrics")).text
+            assert f"expert_rows_skipped_total {6 * 128 - 7}" in m
         await app.router.shutdown()
 
 
@@ -815,7 +1079,8 @@ def test_the_expert_metrics_are_in_the_catalog():
     from llama_fastapi_k8s_gpu_tpu.obs.catalog import GAUGE, METRICS
 
     for name in ("expert_layer_steps_total", "experts_read_total",
-                 "expert_picks_total", "expert_slots_skipped_total"):
+                 "expert_picks_total", "expert_slots_skipped_total",
+                 "expert_rows_skipped_total"):
         assert METRICS[name].mtype == GAUGE
     assert METRICS["expert_picks_total"].labels == ("expert",)
 

@@ -1,18 +1,21 @@
 """Time the grouped expert call ALONE on the chip (ops/pallas/experts.py
-``_grouped_call``), at the four routed configurations' shapes, over the
-number of slots in use: the parent commit's grid (``--parent``: its
-``experts.py``, loaded beside this tree's under another name; it walks all T
-slots) and this tree's side by side, the same planes, rows and meta vector,
-the results compared bit for bit.  Without the parent's file it times this
-tree's alone.
+``_grouped_call``), at the five routed configurations' shapes, over the
+number of slots in use and, for a call of more than ``ROW_GROUP`` rows, the
+rows that reach an expert: the parent commit's call (``--parent``: its
+``experts.py``, loaded beside this tree's under another name; every slot
+multiplies all the rows) and this tree's side by side (a call of
+``ROW_GROUP`` rows on the rows that reach an expert), the same planes, rows
+and slots, the results compared bit for bit.  Without the parent's file it
+times this tree's alone.  ``--layer`` times the whole layer after the
+router instead (``routed_experts``: the compaction, the choice between
+the two calls, the three products, the gather back and the weighted sum),
+which is what a decode step pays.
 
 A call takes 50-700 us and a dispatch round trip about 1 ms, so a timing is
 the SLOPE of one jitted loop of calls over its trip count (the layer index
 walks the planes' leading axis, as a decode step's does): ``(t(3n) - t(n)) /
-2n``, each ``t`` the median of ``--reps`` runs.  One JSON line a timing; per
-(shape, slots in use) also the cost of one idle grid step, ``(parent - new) /
-((T - used) x N tiles x K tiles)``, which PERF.md section 6 (PR 51) sets
-beside the issue's reckoned 0.16-0.35 us.
+2n``, each ``t`` the median of ``--reps`` runs.  One JSON line a timing
+(PERF.md section 6, PR 51 and PR 53, have the tables).
 
     git archive --prefix=.parent_check/ <parent> | tar x
     chiprun -- python tools/time_expert_fewrow.py
@@ -41,8 +44,23 @@ FEW = (
     ("gigachat.down", "q6k", 128, 32, 7168, 2048),
     ("kexaone.gate", "q4k", 128, 16, 2048, 6144),
     ("kexaone.down", "q6k", 128, 16, 6144, 2048),
+    ("longcat.gate", "q4k", 192, 64, 2048, 6144),
+    ("longcat.down", "q6k", 192, 64, 6144, 2048),
 )
 USED = (1, 4, 7, 23, 29, 41)
+# a call of more than ROW_GROUP rows: slots in use x rows that reach an
+# expert (no fewer than the slots, no more than ROW_GROUP: with more the
+# layer makes the parent's call)
+USED_COMPACTED = (1, 7)
+REAL = (1, 8, 12, 24, 64)
+# the layer after the router: (name, lanes, picks a token, experts held,
+# hidden size, expert width)
+LAYER = (
+    ("gigachat", 16, 8, 32, 7168, 2048),
+    ("kexaone", 16, 8, 16, 6144, 2048),
+    ("longcat", 16, 12, 64, 6144, 2048),
+    ("olmoe", 8, 8, 64, 2048, 1024),
+)
 # a wide prefill slice (1024 tokens): (name, family, tokens, picks a token,
 # experts the router ranks, experts held, n_out, k_in)
 MANY = (
@@ -65,9 +83,19 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=8)
     ap.add_argument("--calls", type=int, default=24,
                     help="n: the loops run n and 3n calls")
-    ap.add_argument("--only", default="", help="substring of a shape's name")
-    ap.add_argument("--used", default=",".join(map(str, USED)),
-                    help="slots in use to time (T is always timed)")
+    ap.add_argument("--only", default="",
+                    help="substrings of the shapes' names, comma-separated")
+    ap.add_argument("--used", default="",
+                    help="slots in use to time (T is always timed); default "
+                    f"{USED}, {USED_COMPACTED} for a call of more than "
+                    "ROW_GROUP rows")
+    ap.add_argument("--real", default=",".join(map(str, REAL)),
+                    help="rows that reach an expert, for such a call")
+    ap.add_argument("--group", type=int, default=0,
+                    help="this tree's ROW_GROUP for the run (what it keeps "
+                    "was chosen with this)")
+    ap.add_argument("--layer", action="store_true",
+                    help="time the whole layer after the router instead")
     ap.add_argument("--out", default="chiprun_out/time_expert_fewrow.jsonl")
     args = ap.parse_args()
 
@@ -76,6 +104,12 @@ def main() -> int:
     import numpy as np
 
     from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    if args.group:
+        X.ROW_GROUP = args.group
+
+    def wanted(name):
+        return any(part in name for part in args.only.split(","))
 
     sides = {"new": X}
     if os.path.exists(args.parent):
@@ -135,16 +169,26 @@ def main() -> int:
             np.asarray(jax.jit(call)(*a))
 
     def run_sides(label, geometry, few, fam, rows, meta, xpa, extra_in,
-                  planes):
+                  planes, new=None):
         """``_grouped_call(fam, meta, xpa, planes, rows, few, extra_in,
-        interpret, variant)`` of each side on the same operands."""
+        interpret, variant)`` of each side on the same operands; ``new``:
+        this tree's compacted operands (xpa, extra_in, each row's place)
+        where its call takes others."""
         got = {}
         for side, mod in sides.items():
+            place = None
+            a, ex = xpa, extra_in
+            if new and side == "new":
+                a, ex, place = new
+
             def call(meta, xpa, *rest, mod=mod):
                 return mod._grouped_call(
-                    fam, meta, xpa, rest[len(extra_in):], rows, few,
-                    rest[:len(extra_in)], False, "cur")
-            us, out = slope_us(call, meta, xpa, *extra_in, *planes)
+                    fam, meta, xpa, rest[len(extra_in):], xpa.shape[0]
+                    if few else rows, few, rest[:len(extra_in)], False,
+                    "cur")
+            us, out = slope_us(call, meta, a, *ex, *planes)
+            if place is not None:       # back from the places to the rows
+                out = np.concatenate([out, np.zeros_like(out[:1])])[place]
             row = dict(label, side=side, us=round(us, 2))
             if "parent" in got:
                 # a many-row call leaves the tiles past the last in use
@@ -152,44 +196,81 @@ def main() -> int:
                 live = label["used"] * rows if not few else out.shape[0]
                 row["same_bits"] = bool(
                     (out[:live] == got["_out"][:live]).all())
-                if geometry["idle_steps"]:
-                    row["us_per_idle_step"] = round(
-                        (got["parent"] - us) / geometry["idle_steps"], 4)
             got[side], got["_out"] = us, out
             say(**row, **geometry)
 
-    # ---- few rows: a decode step's call over the slots in use
+    def write() -> int:
+        with open(args.out, "w") as fh:
+            fh.writelines(json.dumps(row) + "\n" for row in lines)
+        return 0
+
+    if args.layer:
+        time_layers(wanted, X, sides, planes_of, slope_us, say)
+        return write()
+
+    # ---- few rows: a decode step's call over the slots in use (and the
+    # rows in use, where this tree compacts them)
     for name, famname, R, E, n_out, k_in in FEW:
-        if args.only not in name:
+        if not wanted(name):
             continue
         fam = X.FAMILIES[famname]
         planes, f, N, K = planes_of(fam, E, n_out, k_in)
         T = min(E, R)
+        compacted = R > X.ROW_GROUP
         rows = R * f + (-(R * f) % 16)
-        TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(rows, fam.tn_prefs))
         x = jax.random.normal(jax.random.PRNGKey(1), (R, k_in), jnp.bfloat16)
-        xf = jnp.pad(X._fold_rows(x, f, R), ((0, rows - R * f), (0, 0)))
-        xpa = X._activations(xf, fam)
-        for used in sorted({int(u) for u in args.used.split(",")
-                            if int(u) < T} | {T}):
-            # `used` experts spread over the held ones, the rows dealt round
-            experts = (np.arange(used) * E) // used
-            row_expert = jnp.asarray(experts[np.arange(R) % used], i32)
-            _, slots, n_used = X.experts_in_use(row_expert, E, T)
-            meta = jnp.concatenate([jnp.zeros(1, i32), n_used[None], slots])
-            re = jnp.pad(jnp.tile(row_expert, f), (0, rows - R * f),
+
+        def operands(x, row_expert, R=R, f=f, fam=fam):
+            """(activations, the rows' experts) as ``grouped_matmul_few``
+            hands them to the call."""
+            pad = -(R * f) % 16
+            xf = jnp.pad(X._fold_rows(x, f, R), ((0, pad), (0, 0)))
+            re = jnp.pad(jnp.tile(row_expert, f), (0, pad),
                          constant_values=jnp.iinfo(i32).max)[:, None]
+            return X._activations(xf, fam), re
 
-            run_sides(dict(regime="few", shape=name, rows=rows, N=N, K=K, T=T,
-                           used=used),
-                      dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK),
-                           idle_steps=(T - used) * (N // TN) * (K // X.TK)),
-                      True, fam, rows, meta, xpa, (re,), planes)
+        useds = [int(u) for u in args.used.split(",") if u] or (
+            USED_COMPACTED if compacted else USED)
+        reals = [int(r) for r in args.real.split(",")
+                 if int(r) <= X.ROW_GROUP] if compacted else [R]
+        for used in sorted({u for u in useds if u < T} | {T}):
+            for real in (r for r in reals if r >= used):
+                # `used` experts spread over the held ones, dealt round to
+                # `real` rows spread over the call's; no expert elsewhere
+                experts = (np.arange(used) * E) // used
+                at = (np.arange(real) * R) // real
+                row_expert = np.full(R, E, np.int32)
+                row_expert[at] = experts[np.arange(real) % used]
+                row_expert = jnp.asarray(row_expert)
+                _, slots, n_used = X.experts_in_use(row_expert, E, T)
+                meta = jnp.concatenate([jnp.zeros(1, i32), n_used[None],
+                                        slots])
+                xpa, re = operands(x, row_expert)
+                new = None
+                if compacted:
+                    places = X.ROW_GROUP
+                    place, src, _ = X.compact_rows(row_expert, E, places)
+                    zero = jnp.zeros((1, k_in), x.dtype)
+                    xc, rec = operands(
+                        jnp.concatenate([x, zero])[src],
+                        jnp.concatenate([row_expert, jnp.full(1, E, i32)]
+                                        )[src], R=places)
+                    # copy j of the row at place p sits at j * places + p
+                    place = np.asarray(place)
+                    back = np.concatenate(
+                        [np.where(place < places, j * places + place,
+                                  xc.shape[0]) for j in range(f)])
+                    new = (xc, (rec,), back)
+                TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(
+                    X.ROW_GROUP * f if compacted else rows, fam.tn_prefs))
+                run_sides(dict(regime="few", shape=name, rows=rows, N=N, K=K,
+                               T=T, used=used, real=real),
+                          dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK)),
+                          True, fam, rows, meta, xpa, (re,), planes, new)
         del planes
-
     # ---- many rows: a wide slice's call, the tiles the plan lays out
     for name, famname, M, k, E_all, E, n_out, k_in in MANY:
-        if args.only not in name or args.no_many:
+        if not wanted(name) or args.no_many:
             continue
         fam = X.FAMILIES[famname]
         planes, f, N, K = planes_of(fam, E, n_out, k_in)
@@ -209,14 +290,52 @@ def main() -> int:
 
         run_sides(dict(regime="many", shape=name, rows=rows, N=N, K=K, T=T,
                        used=used),
-                  dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK),
-                       idle_steps=(T - used) * (N // TN) * (K // X.TK)),
+                  dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK)),
                   False, fam, rows, meta, xpa, (), planes)
         del planes
 
-    with open(args.out, "w") as fh:
-        fh.writelines(json.dumps(row) + "\n" for row in lines)
-    return 0
+    return write()
+
+
+def time_layers(wanted, X, sides, planes_of, slope_us, say) -> None:
+    """``routed_experts`` of each side: the layer a decode step runs, at
+    the occupancies of the saturated cells (live lanes x the share of their
+    picks held here) and with every pick held."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    for name, lanes, k, E, D, F in LAYER:
+        if not wanted(name):
+            continue
+        w = []
+        for famname, n_out, k_in in (("q4k", F, D), ("q4k", F, D),
+                                     ("q6k", D, F)):
+            fam = X.FAMILIES[famname]
+            w.append(dict(zip(fam.planes, planes_of(fam, E, n_out, k_in)[0])))
+        x = jax.random.normal(jax.random.PRNGKey(3), (lanes, D), jnp.bfloat16)
+        wts = jax.random.uniform(jax.random.PRNGKey(4), (lanes, k))
+        R = lanes * k
+        for real in sorted({r for r in (1, 10, 24, 64, R) if r <= R}):
+            rng = np.random.default_rng(real)
+            picks = np.full(R, E, np.int32)
+            picks[rng.permutation(R)[:real]] = rng.integers(0, E, real)
+            picks = jnp.asarray(picks.reshape(lanes, k))
+            got = {}
+            for side, mod in sides.items():
+                def call(meta, x, picks, wts, g, u, d, mod=mod):
+                    return mod.routed_experts(x, picks, wts, g, u, d,
+                                              meta[0], interpret=False
+                                              )[0].astype(jnp.float32)
+                us, out = slope_us(call, jnp.zeros(1, jnp.int32), x, picks,
+                                   wts, *w)
+                row = dict(regime="layer", shape=name, rows=R, real=real,
+                           side=side, us=round(us, 2))
+                if "parent" in got:
+                    row["same_bits"] = bool((out == got["_out"]).all())
+                got[side], got["_out"] = us, out
+                say(**row)
+        del w
 
 
 if __name__ == "__main__":

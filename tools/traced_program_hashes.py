@@ -1,0 +1,113 @@
+"""Hashes of the TRACED programs of every call that ops/pallas/qmatmul.py,
+q6matmul.py and experts.py build, for telling which programs a change to
+those files moved: the dense fused matmuls (plain and stacked, both
+families, every row regime) and the routed layer after the router at the
+five routed configurations' widths (a serial step, the lane engines'
+vmapped step, prefill slices), each with the chip's kernels and in
+interpret mode.
+
+    python tools/traced_program_hashes.py <tree> <out.json>     # once a tree
+    git archive --prefix=.parent_check/ <parent> | tar x
+    python tools/traced_program_hashes.py .parent_check /tmp/parent.json
+    python tools/traced_program_hashes.py . /tmp/change.json    # then diff
+
+What is hashed is ``jax.make_jaxpr``'s text (the program with its kernels'
+bodies in full, the addresses of closures taken out), not ``lower().as_text()``
+for the chip: that holds each kernel as serialized MLIR WITH its source
+locations (file and line), so it differs between two trees whatever the
+code.  Needs no chip; a minute a tree."""
+
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+import re
+import sys
+
+
+def main(tree: str, out: str) -> int:
+    sys.path.insert(0, os.path.abspath(tree))
+    import jax
+    import jax.numpy as jnp
+
+    import llama_fastapi_k8s_gpu_tpu as pkg
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as P
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
+        fold_factor, padded_k, routed_experts)
+
+    assert os.path.realpath(pkg.__file__).startswith(
+        os.path.realpath(tree)), pkg.__file__
+    bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+    S = jax.ShapeDtypeStruct
+
+    def traced(fn, *args):
+        text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(*args)))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def planes(fmt, n, k, lead=()):
+        sm = S((*lead, k // 2048, n, 128), bf16)
+        if fmt == "q4k":
+            return {"qs": S((*lead, n, k // 2), i8), "sm": sm}
+        return {"q4": S((*lead, n, k // 2), i8),
+                "q2": S((*lead, n, k // 4), i8), "sm6": sm}
+
+    res = {}
+    dense = {"q4k": (P.q4k_matmul, P.q4k_matmul_stacked),
+             "q6k": (P.q6k_matmul, P.q6k_matmul_stacked)}
+    for fmt, (plain, stacked) in dense.items():
+        for k, n in ((4096, 4096), (4096, 14336), (14336, 4096),
+                     (12288, 4096), (4096, 32000 if fmt == "q6k" else 1024)):
+            for rows in (1, 8, 64, 128, 256, 512, 1024):
+                for interp in (False, True):
+                    if interp and (rows not in (1, 128) or k != 4096):
+                        continue
+                    tag = f"{fmt}.{k}x{n}.r{rows}." + ("interp" if interp
+                                                       else "tpu")
+                    res["dense." + tag] = traced(
+                        lambda x, w: plain(x, w, interpret=interp),
+                        S((rows, k), bf16), planes(fmt, n, k))
+                    res["stacked." + tag] = traced(
+                        lambda x, w, i: stacked(x, w, i, interpret=interp),
+                        S((rows, k), bf16), planes(fmt, n, k, (2,)),
+                        S((), i32))
+    # the routed layer: (name, experts held, D, F, picks a token, tokens)
+    for name, E, D, F, k, toks in (
+            ("olmoe", 64, 2048, 1024, 8, (1, 8, 128, 512, 1024)),
+            ("lfm2", 64, 2048, 1536, 4, (1, 16, 128, 256, 1024)),
+            ("gigachat", 32, 7168, 2048, 8, (1, 16, 128, 256, 1024)),
+            ("kexaone", 16, 6144, 2048, 8, (1, 16, 128, 256, 1024)),
+            ("longcat", 64, 6144, 2048, 12, (1, 16, 128, 256, 1024))):
+        def exps(fmt, n, kk):
+            f = fold_factor(kk)
+            return planes(fmt, n // f, padded_k(kk) * f, (2, E))
+
+        w = (exps("q4k", F, D), exps("q4k", F, D), exps("q6k", D, F),
+             S((), i32))
+        for t in toks:
+            for interp in (False, True):
+                if interp and t > 16:
+                    continue
+
+                def layer(x, p, wt, g, u, d, i):
+                    return routed_experts(x, p, wt, g, u, d, i,
+                                          interpret=interp)
+
+                key = f"routed.{name}.t{t}." + ("interp" if interp else "tpu")
+                res[key] = traced(layer, S((t, D), bf16), S((t, k), i32),
+                                  S((t, k), f32), *w)
+                if t in (8, 16):    # the lane engines: vmap over lanes
+                    res[key + ".vmap"] = traced(
+                        lambda x, p, wt, g, u, d, i: jax.vmap(
+                            lambda a, b, c: layer(a, b, c, g, u, d, i))(
+                                x, p, wt),
+                        S((t, 1, D), bf16), S((t, 1, k), i32),
+                        S((t, 1, k), f32), *w)
+    with open(out, "w") as fh:
+        json.dump(res, fh, indent=0, sort_keys=True)
+    print(len(res), "programs hashed ->", out)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(*sys.argv[1:3]))
