@@ -37,7 +37,8 @@ from ..models.llama import init_cache
 from ..obs import memledger as _memledger
 from ..obs.devtime import timed_jit
 from ..obs.memledger import register_component, tree_nbytes
-from ..obs.trace import annotate_all_inflight, phase, rid
+from ..obs.trace import (annotate_all_inflight, end_first_token, phase,
+                         rid)
 from ..parallel.batched import (
     batched_generate_chunk_perlane_jit, init_lane_left, left_after)
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
@@ -86,7 +87,9 @@ def _write_lane(state: dict, lane_st: dict, lane_left: jax.Array,
         left_after(token, left, stop_ids))
 
 
-_write_lane = timed_jit("lane_write", _write_lane, site="engine.continuous")
+# done stamp on ``lane_left``: the state and ``lane_st`` are donated onward
+_write_lane = timed_jit("lane_write", _write_lane, site="engine.continuous",
+                        leaf=2)
 
 
 @jax.jit
@@ -98,8 +101,9 @@ def _lane_cache_copy_jit(cache: dict, lane) -> dict:
     return jax.tree.map(lambda a: a[lane], cache)
 
 
+# no done stamp: its one result becomes the scratch the slices donate
 _lane_cache_copy_jit = timed_jit("lane_cache_copy", _lane_cache_copy_jit,
-                                 site="engine.continuous")
+                                 site="engine.continuous", leaf=None)
 
 
 _STREAM_END = object()   # scheduler→stream-consumer sentinel
@@ -986,18 +990,21 @@ class ContinuousEngine(MeshEngine):
             ids, n_prompt, st = adm["ids"], adm["n_prompt"], adm["st"]
             self._lane_claims[lane] = None   # lane overwritten below
             window, wpos = seed_window(ids)
-            token, window, wpos, key = sample_jit(
-                adm["logits"], window, wpos, jax.random.PRNGKey(adm["seed"]),
-                st, self.cfg, top_k=self._max_top_k)
             budget = min(self._token_budget(item.max_tokens, n_prompt),
                          max(0, self.cfg.n_ctx - 1 - n_prompt))
-            # the lane's end goes to the device with it: the budget less
-            # the first token, and whether that token already ends it
-            self._bstate, self._lane_st, self._lane_left = _write_lane(
-                self._bstate, self._lane_st, self._lane_left,
-                jnp.int32(lane), self._scratch_cache, jnp.int32(n_prompt),
-                token, window, wpos, key, st, jnp.int32(budget - 1),
-                stop_ids=self._stop_ids)
+            with phase("first_sample", rid=rid(item.trace), lane=lane):
+                token, window, wpos, key = sample_jit(
+                    adm["logits"], window, wpos,
+                    jax.random.PRNGKey(adm["seed"]), st, self.cfg,
+                    top_k=self._max_top_k)
+                # the lane's end goes to the device with it: the budget
+                # less the first token, and whether that token already
+                # ends it
+                self._bstate, self._lane_st, self._lane_left = _write_lane(
+                    self._bstate, self._lane_st, self._lane_left,
+                    jnp.int32(lane), self._scratch_cache,
+                    jnp.int32(n_prompt), token, window, wpos, key, st,
+                    jnp.int32(budget - 1), stop_ids=self._stop_ids)
             if self._lane_prefix:
                 # a LIVE lane is a claim too: its prompt's rows stay where
                 # they are while it decodes (it writes from n_prompt on),
@@ -1041,7 +1048,7 @@ class ContinuousEngine(MeshEngine):
                 return
             slot.first_token = int(token)   # host sync: prefill done = TTFT
             slot.ttft_s = time.time() - adm["t0"]
-            self._end_prefill_span(slot)
+            self._end_prefill_span(slot, token)
             if slot.sink is not None:       # stream: open the chunk stream
                 slot.sink.put(self._chunk(slot, {"role": "assistant"}))
             self._install(lane, slots, slot)
@@ -1059,15 +1066,19 @@ class ContinuousEngine(MeshEngine):
             # the pages become evictable again
             self._release_adm_lease(adm)
 
-    def _end_prefill_span(self, slot: _Slot) -> None:
+    def _end_prefill_span(self, slot: _Slot, token=None) -> None:
         """Close the admission's ``prefill`` span at TTFT.  Idempotent —
         the deadline/abandon path in _harvest re-runs it after a normal
         close, and the tokens=1 note must not clobber the per-chunk token
-        counts recorded since — so the span reference is consumed here."""
+        counts recorded since — so the span reference is consumed here.
+        ``token``: the first token's device array, where the caller has
+        just fetched it (``first_token`` then holds what the device ran
+        inside it: obs/trace.py ``end_first_token``)."""
         if slot.pspan is not None:
             if slot.fspan is not None:
-                slot.fspan.set(waves=self._totals["chunks_dispatched"]
-                               - slot.fwave).end()
+                end_first_token(
+                    slot.fspan, slot.pspan, token,
+                    waves=self._totals["chunks_dispatched"] - slot.fwave)
                 slot.fspan = None
             if slot.ttft_s is not None:
                 slot.pspan.set(ttft_s=round(slot.ttft_s, 6))
@@ -1082,8 +1093,9 @@ class ContinuousEngine(MeshEngine):
         fetch does not wait on new device work): first-token value, TTFT,
         stream open, first stop/budget checks."""
         slot.pending_first = False
+        token = slot.first_token            # the device array
         try:
-            slot.first_token = int(slot.first_token)
+            slot.first_token = int(token)
         except Exception as e:  # noqa: BLE001 — per-request isolation
             self._note_error(e)
             slot.finished = True
@@ -1095,7 +1107,7 @@ class ContinuousEngine(MeshEngine):
                 slot.future.set_exception(e)
             return
         slot.ttft_s = time.time() - slot.t_admit
-        self._end_prefill_span(slot)
+        self._end_prefill_span(slot, token)
         if slot.sink is not None:
             slot.sink.put(self._chunk(slot, {"role": "assistant"}))
         self._install(lane, slots, slot)

@@ -47,7 +47,7 @@ from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
 from ..tokenizer.chat_template import named_template
 from ..obs.memledger import register_component, tree_nbytes
-from ..obs.trace import arm_phases, phase, rid
+from ..obs.trace import arm_phases, end_first_token, phase, rid
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
 from .expert_counters import ExpertCounters
@@ -240,7 +240,9 @@ class Engine:
         #: "lfkt_timings" so callers never need this shared field.
         self.last_timings: dict | None = None
         setup_compile_cache()
-        arm_phases()   # lfkt.* phases emit iff /debug/profile can be armed
+        # lfkt.* phases emit iff /debug/profile can be armed, and keep a
+        # request's rid for the device-done stamps iff the tracer is
+        arm_phases()
 
         #: this engine's stretch of the start-up timeline (utils/startup.py):
         #: ``gguf_open`` ... ``warmup``, each stamped where the work
@@ -1111,8 +1113,9 @@ class Engine:
             if pspan is not None else None
         window, wpos = seed_window(ids)
         key = jax.random.PRNGKey(seed)
-        token, window, wpos, key = sample_jit(
-            logits, window, wpos, key, st, self.cfg, top_k=sp.top_k)
+        with phase("first_sample", rid=rid(pspan)):
+            token, window, wpos, key = sample_jit(
+                logits, window, wpos, key, st, self.cfg, top_k=sp.top_k)
         state = {
             "cache": cache,
             "pos": jnp.int32(n_prompt),
@@ -1124,7 +1127,8 @@ class Engine:
         first = int(token)  # device sync: first token is now materialized
         ttft_s = time.time() - t0
         if pspan is not None:
-            fspan.end()
+            # the fetch above was the wait: the sample's program is done
+            end_first_token(fspan, pspan, token)
             pspan.set(ttft_s=round(ttft_s, 6))
             pspan.end()
         return {

@@ -133,4 +133,4 @@ def generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
 
 
 generate_chunk_jit = timed_jit("decode_chunk", generate_chunk_jit,
-                               site="models.generate")
+                               site="models.generate", leaf=1)  # its rows

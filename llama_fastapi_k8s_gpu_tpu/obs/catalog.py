@@ -282,6 +282,18 @@ METRICS: dict[str, Metric] = _register(
     Metric("jit_dispatches_total", GAUGE,
            "host dispatches per jit program (devtime snapshot)",
            labels=("program",)),
+    Metric("jit_device_seconds_total", GAUGE,
+           "device seconds per jit program while the tracer is armed: the "
+           "sum of its intervals [max(previous done, its dispatch's "
+           "return), done], done stamped when one leaf of its result is "
+           "ready.  An UPPER bound: eager device work, transfers and "
+           "programs without a stamp lie in the next stamped interval; on "
+           "a mesh done is the slowest shard's (devtime snapshot)",
+           labels=("program",)),
+    Metric("jit_device_intervals_total", GAUGE,
+           "device intervals summed into jit_device_seconds_total per "
+           "program: its stamped dispatches (devtime snapshot)",
+           labels=("program",)),
     Metric("xla_recompile_storms_total", GAUGE,
            "signatures minted past LFKT_RECOMPILE_BUDGET "
            "(devtime snapshot; docs/RUNBOOK.md recompile-storm runbook)"),

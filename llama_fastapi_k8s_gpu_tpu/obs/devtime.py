@@ -41,6 +41,22 @@ is a plain attribute read and the call forwards untouched — no signature,
 no lock, no allocation (pinned by the poisoned-registry test in
 tests/test_devtime.py, the tracer's ``LFKT_TRACE_SAMPLE=0`` analogue).
 
+Device seconds by program (PR 54).  While the tracer is armed
+(``LFKT_TRACE_SAMPLE`` > 0) every dispatch of an entry program also hands
+one leaf of its result to the registry's single watcher thread, which
+waits for it and stamps the host clock: ``done``.  One chip runs its
+programs in the order they were enqueued, so a program's **interval** is
+``[max(previous done, its dispatch's return), done]``: summed per program
+(``device_s``, ``intervals``; ``jit_device_seconds_total`` /
+``jit_device_intervals_total``) and kept, the last :data:`MAX_INTERVALS`
+of them, in a ring the ``first_token`` span takes its ``device.<program>``
+children from (obs/trace.py ``end_first_token``).  An interval is an UPPER
+bound on its program: work that reaches the device outside the registry
+(an eager ``jnp`` call, a transfer, a program without a stamp) lies in the
+interval of the next stamped program, and on a mesh ``done`` is the
+slowest shard's.  With the tracer off the wrapper pays one attribute read
+and the watcher thread does not exist.
+
 Determinism dividend: because compile/dispatch counts are exact and
 device-independent, tier-1 pins them on CPU (tests/test_perf_pins.py) —
 a silent recompile or a stray extra dispatch per decode chunk fails a
@@ -55,6 +71,8 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from .trace import annotate_all_inflight, open_rid, phase
+
 logger = logging.getLogger(__name__)
 
 #: bounded compile-event ring: /metrics replays events it has not seen yet
@@ -67,6 +85,12 @@ MAX_EVENTS = 1024
 #: ~a word per mint, not a multi-KB string — negligible next to the
 #: compiled executable jax itself retains for every one of them.
 MAX_SIGNATURES_SHOWN = 64
+#: device intervals ``(program, rid, start, end, dispatch return)`` kept
+#: for the spans (newest last); the per-program sums are exact past it
+MAX_INTERVALS = 4096
+#: dispatches whose result the watcher has not seen ready yet; past it a
+#: dispatch costs a counted miss (a device that hangs must not grow a list)
+MAX_PENDING = 4096
 
 ENTRY = "entry"    # host-dispatched jit program (wrapped by timed_jit)
 INNER = "inner"    # trace-inner dispatch site (compiles inside its caller)
@@ -103,7 +127,8 @@ class _Program:
     """One registered program's ledger."""
 
     __slots__ = ("name", "kind", "site", "signatures", "sig_seen",
-                 "compiles", "dispatches", "compile_s", "storms")
+                 "compiles", "dispatches", "compile_s", "storms",
+                 "device_s", "intervals", "stamped")
 
     def __init__(self, name: str, kind: str, site: str | None):
         self.name = name
@@ -119,6 +144,23 @@ class _Program:
         self.dispatches = 0
         self.compile_s = 0.0
         self.storms = 0
+        #: sum and count of the program's device intervals (stamps armed)
+        self.device_s = 0.0
+        self.intervals = 0
+        #: False for an entry whose every result is donated onward: it
+        #: carries no stamp, its time lies in the next program's interval
+        self.stamped = True
+
+
+def _first_leaf(out, index: int):
+    """The array a dispatch's ``done`` is read from: the first leaf of
+    element ``index`` of a tuple result (of the result itself otherwise)."""
+    import jax
+
+    if isinstance(out, tuple):
+        out = out[index]
+    leaves = jax.tree_util.tree_leaves(out)
+    return leaves[0] if leaves else None
 
 
 class DevtimeRegistry:
@@ -132,17 +174,22 @@ class DevtimeRegistry:
     _GUARDED_BY = {"_programs": "_lock", "_events": "_lock",
                    "_seq": "_lock", "storms_total": "_lock",
                    "events_dropped": "_lock", "_floor": "_lock",
-                   "_degrades": "_lock"}
-    _SHARED_ATOMIC = ("_armed", "budget")
+                   "_degrades": "_lock", "_pending": "_lock",
+                   "_ring": "_lock", "_last_done": "_lock",
+                   "stamp_misses": "_lock", "_watcher": "_lock"}
+    _SHARED_ATOMIC = ("_armed", "_stamps", "budget")
 
-    def __init__(self, armed: bool | None = None, budget: int | None = None):
-        if armed is None or budget is None:
+    def __init__(self, armed: bool | None = None, budget: int | None = None,
+                 stamps: bool | None = None):
+        if armed is None or budget is None or stamps is None:
             from ..utils.config import knob
 
             if armed is None:
                 armed = bool(knob("LFKT_DEVTIME"))
             if budget is None:
                 budget = int(knob("LFKT_RECOMPILE_BUDGET"))
+            if stamps is None:      # the tracer's own knob: no second one
+                stamps = float(knob("LFKT_TRACE_SAMPLE")) > 0.0
         self._lock = threading.Lock()
         self._programs: dict[str, _Program] = {}
         self._events: deque[dict] = deque(maxlen=MAX_EVENTS)
@@ -163,18 +210,43 @@ class DevtimeRegistry:
         self._degrades: OrderedDict[tuple, dict] = OrderedDict()
         self.budget = max(1, int(budget))
         self._armed = bool(armed)
+        # -- device-done stamps (armed with the tracer) ---------------------
+        #: the clock of the stamps: the spans' (obs/trace.py), not the
+        #: compile walls' perf_counter
+        self.clock = time.time
+        #: dispatches in enqueue order whose result is not known ready:
+        #: [program, rid, dispatch return, leaf, the watcher's own stamp]
+        self._pending: deque[list] = deque()
+        self._ring: deque[tuple] = deque(maxlen=MAX_INTERVALS)
+        self._last_done = 0.0
+        #: dispatches that got no interval: a leaf already deleted or
+        #: donated, a result without an array, a full pending list
+        self.stamp_misses = 0
+        self._wake = threading.Condition(self._lock)
+        self._watcher: threading.Thread | None = None
+        #: the second hot-path bool: read only where ``_armed`` is true
+        self._stamps = bool(stamps)
 
     # -- configuration (tests + ops) ---------------------------------------
     def configure(self, armed: bool | None = None,
-                  budget: int | None = None) -> None:
+                  budget: int | None = None,
+                  stamps: bool | None = None) -> None:
         if armed is not None:
             self._armed = bool(armed)
         if budget is not None:
             self.budget = max(1, int(budget))
+        if stamps is not None:
+            self._stamps = bool(stamps)
 
     @property
     def armed(self) -> bool:
         return self._armed
+
+    @property
+    def stamps(self) -> bool:
+        """Whether a dispatch is stamped: the registry and the tracer are
+        both armed."""
+        return self._armed and self._stamps
 
     def reset(self) -> None:
         """Zero every ledger (tests).  The event sequence stays monotonic
@@ -183,8 +255,12 @@ class DevtimeRegistry:
             for p in self._programs.values():
                 p.signatures.clear()
                 p.sig_seen.clear()
-                p.compiles = p.dispatches = p.storms = 0
-                p.compile_s = 0.0
+                p.compiles = p.dispatches = p.storms = p.intervals = 0
+                p.compile_s = p.device_s = 0.0
+            self._pending.clear()
+            self._ring.clear()
+            self._last_done = 0.0
+            self.stamp_misses = 0
             self._events.clear()
             self._degrades.clear()
             self.storms_total = 0
@@ -210,13 +286,22 @@ class DevtimeRegistry:
             self._program(name, kind, site)
         return name
 
-    def timed_jit(self, name: str, fn, site: str | None = None):
+    def timed_jit(self, name: str, fn, site: str | None = None,
+                  leaf: int | None = 0):
         """Wrap a host jit entry point.  Re-wrapping under the same name
         (lru-cached factories minting one jit per mesh/config key) merges
-        into one program ledger — exactly what storm detection wants."""
+        into one program ledger — exactly what storm detection wants.
+
+        ``leaf``: which element of a tuple result the ``done`` stamp waits
+        on (its first array; default the first of all).  It must NOT be one
+        a later program takes by donation, or the wait finds it deleted: a
+        prefill has its logits, a chunk program its rows.  ``None``: every
+        result is donated onward, the program carries no stamp
+        (``/debug/compiles`` says so) and its time lies in the next
+        program's interval."""
         with self._lock:
-            self._program(name, ENTRY, site)
-        return _TimedJit(self, name, fn)
+            self._program(name, ENTRY, site).stamped = leaf is not None
+        return _TimedJit(self, name, fn, leaf)
 
     #: distinct (program, reason) degrade pairs retained; repeats past the
     #: bound still count into the OLDEST entry's overflow marker
@@ -295,19 +380,113 @@ class DevtimeRegistry:
                 "(docs/RUNBOOK.md 'Diagnosing a recompile storm')",
                 storm["program"], storm["signatures"], storm["budget"],
                 extra=storm)
-            from .trace import annotate_all_inflight
-
             annotate_all_inflight("recompile_storm", **storm)
+
+    # -- device-done stamps ------------------------------------------------
+    def _stamp(self, name: str, index: int, out) -> None:
+        """Hand one dispatch to the watcher (the serving thread's part of
+        a stamp: the dispatch's return time, the request open on this
+        thread, one leaf of the result).  Never raises: a result that
+        yields no leaf is a counted miss."""
+        t_ret = self.clock()
+        try:
+            leaf = _first_leaf(out, index)
+        except Exception:  # noqa: BLE001 — telemetry must never fail serving
+            leaf = None
+        with self._lock:
+            if leaf is None or len(self._pending) >= MAX_PENDING:
+                self.stamp_misses += 1
+                return
+            self._pending.append([name, open_rid(), t_ret, leaf, None])
+            if self._watcher is None or not self._watcher.is_alive():
+                self._watcher = threading.Thread(
+                    target=self._watch, name="lfkt-device-done", daemon=True)
+                self._watcher.start()
+            self._wake.notify()
+
+    def _watch(self) -> None:  # lfkt: blocks-under[_lock] -- Condition.wait on the registry's own lock RELEASES it while the watcher idles; the device wait itself runs off the lock
+        """The watcher thread: wait for the oldest pending result (GIL
+        released), stamp the clock, close its interval.  The wait stands
+        inside ``phase("device_done")``, so a ``/debug/profile`` capture
+        holds the stamp on the device trace's clock, its END beside the
+        end of the program on the ``XLA Modules`` line."""
+        while True:
+            with self._lock:
+                while not self._pending:
+                    self._wake.wait()
+                entry = self._pending[0]
+            try:
+                with phase("device_done", program=entry[0], rid=entry[1]):
+                    entry[3].block_until_ready()
+                    entry[4] = self.clock()
+            except Exception:  # noqa: BLE001 — a deleted or donated leaf
+                pass
+            with self._lock:
+                # fetched() may have closed it, reset() dropped it
+                if self._pending and self._pending[0] is entry:
+                    self._pending.popleft()
+                    if entry[4] is None:
+                        self.stamp_misses += 1
+                    else:
+                        self._close(entry, entry[4])
+            entry = None      # hold no result while waiting for the next
+
+    def _close(self, entry: list, t: float) -> None:  # lfkt: holds[_lock]
+        """One dispatch's interval: from the later of the previous ``done``
+        and its own return (a dispatch into an idle device) to ``t``."""
+        name, rid, t_ret, _, seen = entry
+        if seen is not None:       # the watcher saw it ready before ``t``
+            t = min(t, seen)
+        start = max(self._last_done, t_ret)
+        end = max(t, start)
+        p = self._program(name, ENTRY, None)
+        p.device_s += end - start
+        p.intervals += 1
+        self._ring.append((name, rid, start, end, t_ret))
+        self._last_done = end
+
+    def fetched(self, leaf) -> None:
+        """The calling thread has just read ``leaf`` on the host: its
+        program, and every one enqueued before it, is done NOW.  The fetch
+        was the wait, so this thread knows before the watcher does; it
+        closes those intervals itself, and a span that ends at the fetch
+        never closes ahead of its last child."""
+        if not self.stamps:
+            return
+        t = self.clock()
+        with self._lock:
+            for i, entry in enumerate(self._pending):
+                if entry[3] is leaf:
+                    break
+            else:
+                return
+            for _ in range(i + 1):
+                self._close(self._pending.popleft(), t)
+
+    def intervals_since(self, t0: float) -> list[tuple]:
+        """The ring's intervals that end at or after ``t0``, oldest first:
+        ``(program, rid, start, end, dispatch return)`` on :attr:`clock`."""
+        out = []
+        with self._lock:
+            for iv in reversed(self._ring):    # ends never decrease
+                if iv[3] < t0:
+                    break
+                out.append(iv)
+        out.reverse()
+        return out
 
     # -- consumers ---------------------------------------------------------
     def counters(self) -> dict[str, dict]:
-        """{program: {"compiles", "dispatches", "signatures", "storms"}} —
-        the cheap ledger for /metrics gauges and the tier-1 perf pins."""
+        """{program: {"compiles", "dispatches", "signatures", "storms",
+        "device_s", "intervals"}} — the cheap ledger for /metrics gauges
+        and the tier-1 perf pins."""
         with self._lock:
             return {name: {"compiles": p.compiles,
                            "dispatches": p.dispatches,
                            "signatures": len(p.sig_seen),
-                           "storms": p.storms}
+                           "storms": p.storms,
+                           "device_s": p.device_s,
+                           "intervals": p.intervals}
                     for name, p in self._programs.items()}
 
     def compile_ledger(self) -> dict[str, tuple[int, float]]:
@@ -368,15 +547,20 @@ class DevtimeRegistry:
         with self._lock:
             rows = [(p.name, p.kind, p.site, p.compiles, p.dispatches,
                      p.compile_s, len(p.sig_seen), p.storms,
-                     dict(p.signatures))
+                     dict(p.signatures), p.device_s, p.intervals, p.stamped)
                     for p in self._programs.values()]
             degrades = [dict(v) for v in self._degrades.values()]
             armed = self._armed
             storms_total = self.storms_total
             dropped = self.events_dropped
+            stamps = {"armed": armed and self._stamps,
+                      "misses": self.stamp_misses,
+                      "pending": len(self._pending),
+                      "ring": len(self._ring)}
         programs = []
         for name, kind, site, compiles, dispatches, compile_s, n_sigs, \
-                storms, signatures in sorted(rows):
+                storms, signatures, device_s, intervals, stamped \
+                in sorted(rows):
             sigs = [{"signature": s, **meta}
                     for s, meta in signatures.items()]
             programs.append({
@@ -385,11 +569,17 @@ class DevtimeRegistry:
                 "compile_seconds_total": round(compile_s, 6),
                 "signatures": n_sigs,
                 "storms": storms,
+                # an upper bound: unregistered device work and a program
+                # without a stamp lie in the NEXT stamped interval
+                "device_seconds_total": round(device_s, 6),
+                "intervals": intervals,
+                "stamped": stamped if kind == ENTRY else None,
                 "signature_list": sigs,   # ledger bounds retention
             })
         return {"armed": armed, "budget": self.budget,
                 "storms_total": storms_total,
                 "events_dropped": dropped,
+                "stamps": stamps,
                 "degrades": degrades,
                 "programs": programs}
 
@@ -428,12 +618,14 @@ class _TimedJit:
     pass through untouched (the wrapper never copies or inspects buffers
     beyond shape/dtype metadata, and only on compile events)."""
 
-    __slots__ = ("_reg", "_name", "_fn", "_probe", "__wrapped__")
+    __slots__ = ("_reg", "_name", "_fn", "_probe", "_leaf", "__wrapped__")
 
-    def __init__(self, reg: DevtimeRegistry, name: str, fn):
+    def __init__(self, reg: DevtimeRegistry, name: str, fn,
+                 leaf: int | None = 0):
         self._reg = reg
         self._name = name
         self._fn = fn
+        self._leaf = leaf
         self.__wrapped__ = fn
         # jax's PjitFunction exposes its compiled-variant count
         self._probe = fn._cache_size
@@ -452,6 +644,8 @@ class _TimedJit:
         if probe() > before and _HANDED.n > handed:
             reg.record_compile(self._name, _signature(args, kwargs), dt)
         reg.record_dispatch(self._name)
+        if reg._stamps and self._leaf is not None:   # the tracer is armed
+            reg._stamp(self._name, self._leaf, out)
         return out
 
 
@@ -460,10 +654,11 @@ class _TimedJit:
 DEVTIME = DevtimeRegistry()
 
 
-def timed_jit(name: str, fn, site: str | None = None):
+def timed_jit(name: str, fn, site: str | None = None,
+              leaf: int | None = 0):
     """Module-level convenience: wrap ``fn`` as program ``name`` on the
     process registry (the form every entry-point module uses)."""
-    return DEVTIME.timed_jit(name, fn, site=site)
+    return DEVTIME.timed_jit(name, fn, site=site, leaf=leaf)
 
 
 def register_program(name: str, kind: str = INNER,

@@ -29,13 +29,21 @@ Design constraints:
   own phases (a scheduler wave, a prefill slice, a tokenizer call) into a
   ``/debug/profile`` capture as ``lfkt.<name>`` host events, so an idle
   gap of the device is read against what the program says it was doing.
-  Armed only while the profiler can be (``LFKT_PROFILE_DIR``); otherwise
-  every call returns one shared no-op object.
+  The annotation is written only while the profiler can be
+  (``LFKT_PROFILE_DIR``); while the tracer is armed a phase that names a
+  request also keeps its ``rid`` on the thread, where the jit registry's
+  device-done stamp reads it (obs/devtime.py).  With both off every call
+  returns one shared no-op object.
+- **The device inside ``first_token``**: :func:`end_first_token` closes
+  the span with the registry's device intervals as children
+  (``device.<program>``, ``host_fetch``), so that the one span that holds
+  most of a long prompt's first token names what ran inside it.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 import uuid
@@ -49,12 +57,7 @@ MAX_NODES_PER_TRACE = 512
 _TRACEPARENT_VERSION = "00"
 
 
-def _new_trace_id() -> str:
-    return uuid.uuid4().hex                      # 32 lowercase hex chars
-
-
-def _new_span_id() -> str:
-    return uuid.uuid4().hex[:16]                 # 16 lowercase hex chars
+_SPAN_ID_MASK = (1 << 64) - 1
 
 
 def parse_traceparent(value: str | None) -> tuple[str, str] | None:
@@ -102,7 +105,7 @@ class Span:
 
     def __init__(self, name: str, trace: "Trace", t0: float | None = None):
         self.name = name
-        self.span_id = _new_span_id()
+        self.span_id = trace._new_span_id()
         self.t0 = time.time() if t0 is None else t0
         self.t1: float | None = None
         self.attrs: dict = {}
@@ -161,16 +164,24 @@ class Trace:
     snapshots (engine, lane, deadline, tokens so far)."""
 
     __slots__ = ("trace_id", "parent_span_id", "root", "meta",
-                 "_lock", "_nodes", "_dropped", "finished")
+                 "_lock", "_nodes", "_dropped", "finished",
+                 "_span_base", "_span_ids")
 
     def __init__(self, name: str = "request",
                  traceparent: str | None = None,
                  t0: float | None = None):
+        # ONE random read a trace (uuid4 reads the kernel's pool; a read per
+        # span was the longest idle gap of a capture, 0.0295 s: ledger,
+        # PR 53, `sala.longdoc-1`): its 128 bits are the trace id, its low
+        # 64 the base the trace's span ids count up from
+        seed = uuid.uuid4().int
+        self._span_base = seed & _SPAN_ID_MASK
+        self._span_ids = itertools.count(1)   # next() is atomic under the GIL
         ingested = parse_traceparent(traceparent)
         if ingested is not None:
             self.trace_id, self.parent_span_id = ingested
         else:
-            self.trace_id, self.parent_span_id = _new_trace_id(), None
+            self.trace_id, self.parent_span_id = f"{seed:032x}", None
         self._lock = threading.Lock()
         self._nodes = 1
         self._dropped = 0
@@ -179,6 +190,12 @@ class Trace:
         #: live request metadata, overwritten in place (cheap single-key
         #: stores) — NOT part of the span tree
         self.meta: dict = {}
+
+    def _new_span_id(self) -> str:
+        """16 lowercase hex characters, never zero (W3C), distinct within
+        the trace: the base plus a counter, no system call."""
+        n = (self._span_base + next(self._span_ids)) & _SPAN_ID_MASK
+        return f"{n or 1:016x}"
 
     # -- producer API -------------------------------------------------------
     def span(self, name: str, t0: float | None = None) -> Span:
@@ -407,14 +424,61 @@ _NO_PHASE = contextlib.nullcontext()
 #: None.  Resolved by :func:`arm_phases` when an engine is built — never
 #: per call: ``phase`` reads this one module attribute (GIL-atomic).
 _ANNOTATION = None
+#: whether a phase that names a request keeps its ``rid`` on the thread:
+#: while the tracer is armed (resolved with ``_ANNOTATION``)
+_KEEP_RID = False
 
 
-def arm_phases() -> bool:
-    """Resolve, once per engine construction, whether :func:`phase` emits:
-    it does iff ``LFKT_PROFILE_DIR`` is set (the knob that arms
-    ``GET /debug/profile`` — a phase outside a capture costs the
-    annotation's enter/exit and records nothing)."""
-    global _ANNOTATION
+class _OpenRid(threading.local):
+    """The ``rid`` of the innermost open phase of this thread that named
+    one; "" outside any (a decode chunk is every lane's)."""
+    rid = ""
+
+
+_OPEN = _OpenRid()
+
+
+def open_rid() -> str:
+    """The request the calling thread is working for, as its open phases
+    say (the jit registry reads it at each stamped dispatch)."""
+    return _OPEN.rid
+
+
+class _RidPhase:
+    """A phase that names a request, while the tracer is armed: keeps the
+    ``rid`` on the thread for the block, around the profiler's annotation
+    where there is one."""
+
+    __slots__ = ("_rid", "_ann", "_outer")
+
+    def __init__(self, rid: str, ann):
+        self._rid = rid
+        self._ann = ann
+
+    def __enter__(self):
+        self._outer = _OPEN.rid
+        _OPEN.rid = self._rid
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        _OPEN.rid = self._outer
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def arm_phases(tracing: bool | None = None) -> bool:
+    """Resolve, once per engine construction, what :func:`phase` does.  It
+    writes the annotation iff ``LFKT_PROFILE_DIR`` is set (the knob that
+    arms ``GET /debug/profile`` — a phase outside a capture costs the
+    annotation's enter/exit and records nothing): returns that.  It keeps
+    a request's ``rid`` on the thread iff the tracer is armed (``tracing``;
+    default: the process tracer, ``LFKT_TRACE_SAMPLE`` > 0), so that an
+    operator with spans and no profiler gets the same ``device.*``
+    children."""
+    global _ANNOTATION, _KEEP_RID
     from ..utils.tracing import profile_dir
 
     if profile_dir():
@@ -423,6 +487,7 @@ def arm_phases() -> bool:
         _ANNOTATION = TraceAnnotation
     else:
         _ANNOTATION = None
+    _KEEP_RID = TRACER._armed if tracing is None else bool(tracing)
     return _ANNOTATION is not None
 
 
@@ -439,12 +504,74 @@ def phase(name: str, **attrs):
     thread inside a profiler capture: a host event ``lfkt.<name>`` whose
     stats are ``attrs`` (a request's trace id rides as ``rid``, so a gap
     in the device trace leads to ``/debug/traces/{rid}``).  Sites are per
-    wave, per slice or per request — never per token.  Disarmed, the
-    shared no-op."""
+    wave, per slice or per request — never per token.  While the tracer
+    is armed a phase with a ``rid`` also keeps it on the thread
+    (:func:`open_rid`).  Disarmed, the shared no-op."""
     ann = _ANNOTATION
+    if _KEEP_RID and "rid" in attrs:
+        return _RidPhase(attrs["rid"], None if ann is None
+                         else ann("lfkt." + name, **attrs))
     if ann is None:
         return _NO_PHASE
     return ann("lfkt." + name, **attrs)
+
+
+# -- the device inside a first_token span ---------------------------------
+
+def end_first_token(fspan: Span, prefill: Span | None = None, token=None,
+                    **attrs) -> None:
+    """Close a request's ``first_token`` span (the return of its last
+    prefill slice's dispatch -> its first token on the host) with what the
+    device did inside it, from the jit registry's interval ring
+    (obs/devtime.py): a child ``device.<program>`` for every interval that
+    overlaps the span, clipped to it, in the device's order, with
+    ``seconds`` (the whole interval) and ``own`` (the dispatch was this
+    request's: its slices and its sample; absent where the dispatch named
+    no request, as a decode chunk of every lane); then ``host_fetch``,
+    from the last of them to the span's end: the transfer, the wake-up and
+    whatever the host did before it looked.  Beside live lanes the
+    children are the other lanes' chunk and slices the token sat behind.
+
+    ``token``: the first token's device array where the calling thread has
+    just fetched it.  The fetch was the wait, so the thread stamps that
+    program itself (``DEVTIME.fetched``) and the span never closes ahead
+    of its last child.  Every ``prefill_slice`` child of ``prefill`` (the
+    span's parent) also gets ``device_s`` / ``done_at`` of its own program
+    (matched by the return of its dispatch, which lies inside the slice's
+    span).
+
+    An interval is an upper bound on its program: eager device work (a
+    ``jnp.asarray``, a ``PRNGKey``), transfers and programs without a
+    stamp lie in the interval of the next stamped one."""
+    from .devtime import DEVTIME
+
+    mine = fspan._trace.trace_id
+    if token is not None:
+        DEVTIME.fetched(token)
+    t1 = time.time()
+    ivs = DEVTIME.intervals_since(
+        min(fspan.t0, prefill.t0) if prefill is not None else fspan.t0)
+    if prefill is not None:
+        own = [iv for iv in ivs if iv[1] == mine]
+        for s in prefill.children:
+            if s.name != "prefill_slice" or s.t1 is None:
+                continue
+            for _, _, start, end, t_ret in own:
+                if s.t0 <= t_ret <= s.t1:
+                    s.set(device_s=round(end - start, 6), done_at=end)
+                    break
+    last = None
+    for name, rid, start, end, _ in ivs:
+        if end < fspan.t0 or start > t1:
+            continue
+        last = min(end, t1)
+        kid = fspan.child("device." + name, t0=max(start, fspan.t0))
+        kid.end(last)
+        kid.set(seconds=round(end - start, 6),
+                **({"own": rid == mine} if rid else {}))
+    if last is not None:
+        fspan.child("host_fetch", t0=last).end(t1)
+    fspan.set(**attrs).end(t1)
 
 
 #: every live Tracer, for the process-level event fan-in; weak so a

@@ -116,7 +116,7 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
 
 batched_generate_chunk_jit = timed_jit(
     "batched_decode_chunk", batched_generate_chunk_jit,
-    site="parallel.batched")
+    site="parallel.batched", leaf=1)      # done stamp on its rows
 
 
 def init_lane_left(batch: int) -> jax.Array:
@@ -227,4 +227,4 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
 
 batched_generate_chunk_perlane_jit = timed_jit(
     "lane_decode_chunk", batched_generate_chunk_perlane_jit,
-    site="parallel.batched")
+    site="parallel.batched", leaf=1)      # done stamp on ``left``

@@ -120,8 +120,10 @@ def _store_pages_jit(arena: dict, ring: dict, page_ids, offset):
     return jax.tree.map(per_leaf, arena, ring)
 
 
+# the four page programs carry no done stamp (``leaf=None``): each returns
+# the arena or the ring alone, which the next program takes by donation
 _store_pages_jit = timed_jit("kvpool_store", _store_pages_jit,
-                             site="parallel.kvpool")
+                             site="parallel.kvpool", leaf=None)
 
 
 @functools.partial(jax.jit, donate_argnames=("arena",))
@@ -142,7 +144,7 @@ def _store_lane_pages_jit(arena: dict, bcache: dict, lane, page_ids, offset):
 
 
 _store_lane_pages_jit = timed_jit("kvpool_lane_store", _store_lane_pages_jit,
-                                  site="parallel.kvpool")
+                                  site="parallel.kvpool", leaf=None)
 
 
 @functools.partial(jax.jit, donate_argnames=("ring",))
@@ -160,7 +162,7 @@ def _restore_pages_jit(arena: dict, ring: dict, page_ids, offset):
 
 
 _restore_pages_jit = timed_jit("kvpool_restore", _restore_pages_jit,
-                               site="parallel.kvpool")
+                               site="parallel.kvpool", leaf=None)
 
 
 @functools.partial(jax.jit, donate_argnames=("arena",))
@@ -171,7 +173,7 @@ def _upload_pages_jit(arena: dict, pages: dict, page_ids):
 
 
 _upload_pages_jit = timed_jit("kvpool_upload", _upload_pages_jit,
-                              site="parallel.kvpool")
+                              site="parallel.kvpool", leaf=None)
 
 
 # ---------------------------------------------------------------------------

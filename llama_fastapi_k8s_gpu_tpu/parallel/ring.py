@@ -292,7 +292,7 @@ def _sp_chunk_fn(mesh: Mesh, axis_name: str, cfg: ModelConfig,
             return generate_chunk(params, cfg, state, st, n_steps, top_k)
 
     return timed_jit("sp_decode_chunk", jax.jit(fn, donate_argnames=("state",)),
-                     site="parallel.ring")
+                     site="parallel.ring", leaf=1)     # done stamp: its rows
 
 
 def sp_generate_chunk(params, cfg: ModelConfig, state: dict, st: dict,

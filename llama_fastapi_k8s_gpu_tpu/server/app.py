@@ -1686,6 +1686,11 @@ def create_app(engine=None, settings: Settings | None = None,
             m.set_gauge("xla_compiles_total", c["compiles"], program=prog)
             m.set_gauge("jit_dispatches_total", c["dispatches"],
                         program=prog)
+            if c["intervals"]:      # stamped while the tracer is armed
+                m.set_gauge("jit_device_seconds_total", c["device_s"],
+                            program=prog)
+                m.set_gauge("jit_device_intervals_total", c["intervals"],
+                            program=prog)
         m.set_gauge("xla_recompile_storms_total", DEVTIME.storms_total)
         cursor, events = DEVTIME.events_since(app.state.devtime_cursor)
         app.state.devtime_cursor = cursor

@@ -28,6 +28,7 @@ import json
 import logging
 import os
 import re
+import time
 
 import httpx
 import pytest
@@ -331,6 +332,217 @@ def test_node_cap_counts_drops():
     d = tr.to_dict()
     assert len(d["root"]["children"]) == MAX_NODES_PER_TRACE - 1
     assert d["dropped_nodes"] == 51
+
+
+def test_span_ids_cost_one_random_read_a_trace(monkeypatch):
+    """16 lowercase hex characters, never zero, distinct within the trace,
+    and no ``uuid4`` (a read of the kernel's pool) per span: one per
+    trace.  ``traceparent`` stays valid."""
+    import llama_fastapi_k8s_gpu_tpu.obs.trace as trace_mod
+
+    reads = []
+    real = trace_mod.uuid.uuid4
+
+    def counted():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(trace_mod.uuid, "uuid4", counted)
+    t = Tracer(sample=1.0, ring=2)
+    tr = t.start()
+    spans = [tr.root] + [tr.span(f"s{i}") for i in range(40)]
+    spans += [spans[1].child("kid"), spans[-1].child("kid")]
+    assert len(reads) == 1
+    ids = [sp.span_id for sp in spans]
+    assert len(set(ids)) == len(ids)
+    for i in ids:
+        assert len(i) == 16 and i == i.lower() and int(i, 16) != 0
+    assert parse_traceparent(tr.traceparent()) == (tr.trace_id,
+                                                   tr.root.span_id)
+    assert parse_traceparent(trace_mod.span_traceparent(spans[5])) == \
+        (tr.trace_id, spans[5].span_id)
+    # another trace counts up from another base
+    assert t.start().root.span_id != tr.root.span_id
+    # an ingested trace keeps the caller's trace id and still gets a base
+    tp = "00-" + "ab" * 16 + "-" + "12" * 8 + "-01"
+    tr3 = t.start(traceparent=tp)
+    assert tr3.trace_id == "ab" * 16 and int(tr3.root.span_id, 16) != 0
+
+
+@pytest.mark.parametrize("base", [0, (1 << 64) - 1, (1 << 64) - 3])
+def test_span_ids_wrap_and_are_never_zero(base):
+    import llama_fastapi_k8s_gpu_tpu.obs.trace as trace_mod
+
+    tr = trace_mod.Trace()
+    tr._span_base = base
+    ids = [tr._new_span_id() for _ in range(6)]
+    assert all(len(i) == 16 and int(i, 16) != 0 for i in ids)
+
+
+# -- the device inside first_token (PR 54): obs/trace.py end_first_token ----
+
+def _ring_of(monkeypatch, intervals):
+    """A private jit registry whose ring holds ``intervals``:
+    (program, rid, dispatch return, done) in the device's order."""
+    from llama_fastapi_k8s_gpu_tpu.obs import devtime
+
+    reg = devtime.DevtimeRegistry(armed=True, stamps=True)
+    with reg._lock:
+        for name, rid, t_ret, done in intervals:
+            reg._close([name, rid, t_ret, None, None], done)
+    monkeypatch.setattr(devtime, "DEVTIME", reg)
+    return reg
+
+
+def _kids(span):
+    return [(c.name, round(c.t0 - span.t0, 3), round(c.t1 - span.t0, 3),
+             c.attrs.get("own")) for c in span.children]
+
+
+def test_first_token_children_are_the_ring_clipped_to_the_span(monkeypatch):
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import end_first_token
+
+    tr = Tracer(sample=1.0, ring=2).start()
+    me, t0 = tr.trace_id, time.time() - 10.0
+    _ring_of(monkeypatch, [
+        ("lane_decode_chunk", "", t0 - 9.0, t0 - 5.0),   # before: not a child
+        ("prefill_chunk", me, t0 - 3.0, t0 + 1.0),       # clipped at the start
+        ("prefill_chunk", me, t0 - 2.9, t0 + 2.0),
+        ("prefill_chunk", "someone-else", t0 - 2.8, t0 + 2.5),
+        ("first_sample", me, t0 - 0.1, t0 + 2.6),
+        ("lane_decode_chunk", "", t0 + 2.7, t0 + 4.0),   # every lane's: no own
+    ])
+    prefill = tr.span("prefill", t0=t0 - 4.0)
+    fspan = prefill.child("first_token", t0=t0)
+    end_first_token(fspan, prefill, waves=2)
+    assert fspan.attrs == {"waves": 2}
+    kids = _kids(fspan)
+    assert kids[:5] == [
+        ("device.prefill_chunk", 0.0, 1.0, True),
+        ("device.prefill_chunk", 1.0, 2.0, True),
+        ("device.prefill_chunk", 2.0, 2.5, False),
+        ("device.first_sample", 2.5, 2.6, True),
+        ("device.lane_decode_chunk", 2.7, 4.0, None),
+    ]
+    assert kids[5][0] == "host_fetch" and kids[5][1] == 4.0
+    assert fspan.children[5].t1 == fspan.t1 and len(kids) == 6
+    # the whole interval rides as `seconds`, clipped or not
+    assert fspan.children[0].attrs["seconds"] == pytest.approx(4.0)
+    assert "own" not in fspan.children[4].attrs
+    # a child is a span like every other: name, start, end, its own id
+    d = fspan.to_dict()
+    assert all(c["span_id"] and c["end"] >= c["start"] >= d["start"]
+               for c in d["children"])
+
+
+def test_first_token_ends_after_an_interval_that_outlives_it(monkeypatch):
+    """An interval still open on the device when the token is on the host
+    is not in the ring yet; one that ended later than now is clipped."""
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import end_first_token
+
+    tr = Tracer(sample=1.0, ring=2).start()
+    t0 = time.time() - 1.0
+    _ring_of(monkeypatch, [("prefill_chunk", tr.trace_id, t0, t0 + 3600.0)])
+    fspan = tr.span("first_token", t0=t0)
+    end_first_token(fspan)
+    (kid, fetch) = fspan.children
+    assert kid.t1 == fetch.t0 == fetch.t1 == fspan.t1
+
+
+def test_first_token_without_a_stamp_is_the_bare_span(monkeypatch):
+    """The tracer armed on a registry that stamped nothing (LFKT_DEVTIME=0,
+    a private tracer beside ``LFKT_TRACE_SAMPLE=0``): no child is made up."""
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import end_first_token
+
+    _ring_of(monkeypatch, [])
+    tr = Tracer(sample=1.0, ring=2).start()
+    fspan = tr.span("first_token", t0=time.time() - 1.0)
+    end_first_token(fspan, None, None, deferred=False)
+    assert fspan.children == [] and fspan.t1 is not None
+    assert fspan.attrs == {"deferred": False}
+
+
+def test_each_slice_span_gets_its_own_programs_interval(monkeypatch):
+    """Matched by the return of the dispatch, which lies inside the
+    slice's span; slices that were done before ``first_token`` opened (one
+    a wave beside live lanes) are found as well."""
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import end_first_token
+
+    tr = Tracer(sample=1.0, ring=2).start()
+    me, t0 = tr.trace_id, time.time() - 10.0
+    prefill = tr.span("prefill", t0=t0)
+    s1 = prefill.child("prefill_slice", t0=t0 + 0.10)
+    s1.end(t0 + 0.12)
+    other = prefill.child("tokenize", t0=t0)
+    other.end(t0 + 0.1)
+    s2 = prefill.child("prefill_slice", t0=t0 + 2.00)
+    s2.end(t0 + 2.02)
+    _ring_of(monkeypatch, [
+        ("prefill_chunk", me, t0 + 0.11, t0 + 1.0),
+        ("lane_decode_chunk", "", t0 + 0.5, t0 + 1.8),
+        ("prefill_chunk", "someone-else", t0 + 2.01, t0 + 2.5),
+        ("prefill_chunk", me, t0 + 2.015, t0 + 3.0),
+    ])
+    fspan = prefill.child("first_token", t0=t0 + 2.02)
+    end_first_token(fspan, prefill)
+    assert s1.attrs == {"device_s": pytest.approx(0.89),
+                        "done_at": pytest.approx(t0 + 1.0)}
+    assert s2.attrs == {"device_s": pytest.approx(0.5),
+                        "done_at": pytest.approx(t0 + 3.0)}
+    assert other.attrs == {}
+    assert [k[0] for k in _kids(fspan)] == [
+        "device.prefill_chunk", "device.prefill_chunk", "host_fetch"]
+
+
+def test_first_token_children_stop_at_the_node_cap(monkeypatch):
+    from llama_fastapi_k8s_gpu_tpu.obs.trace import (MAX_NODES_PER_TRACE,
+                                                     end_first_token)
+
+    tr = Tracer(sample=1.0, ring=2).start()
+    t0 = time.time() - 100.0
+    _ring_of(monkeypatch, [("lane_decode_chunk", "", t0 + 0.1 * i,
+                            t0 + 0.1 * (i + 1))
+                           for i in range(MAX_NODES_PER_TRACE + 40)])
+    fspan = tr.span("first_token", t0=t0)
+    end_first_token(fspan)
+    d = tr.to_dict()
+    assert len(fspan.children) == MAX_NODES_PER_TRACE - 2   # root + itself
+    assert d["dropped_nodes"] == 42 + 1                     # + host_fetch
+    assert fspan.t1 is not None
+
+
+@pytest.mark.anyio
+async def test_metrics_and_debug_compiles_carry_the_device_seconds():
+    from llama_fastapi_k8s_gpu_tpu.obs.devtime import DEVTIME
+
+    DEVTIME.register_program("stamped_toy", kind="entry")
+    DEVTIME.register_program("unstamped_toy", kind="entry")
+    with DEVTIME._lock:
+        DEVTIME._close(["stamped_toy", "", 1.0, None, None], 1.0)
+        last = DEVTIME._last_done
+        DEVTIME._close(["stamped_toy", "", last + 1.0, None, None],
+                       last + 1.25)
+    app = create_app(engine=FakeEngine(reply="hey"))
+    metrics, compiles = await _serve(app, [("get", "/metrics", {}),
+                                           ("get", "/debug/compiles", {})])
+    text = metrics.text
+    types = validate_exposition(text)
+    assert types["jit_device_seconds_total"] == "gauge"
+    assert types["jit_device_intervals_total"] == "gauge"
+    secs = re.search(r'^jit_device_seconds_total\{program="stamped_toy"\} '
+                     r'(\S+)$', text, re.M)
+    assert float(secs.group(1)) == pytest.approx(0.25, abs=1e-6)
+    assert 'jit_device_intervals_total{program="stamped_toy"} 2' in text
+    # a program that was never stamped exports no zero series
+    assert 'program="unstamped_toy"} ' in text      # its dispatches
+    assert 'jit_device_seconds_total{program="unstamped_toy"}' not in text
+    doc = compiles.json()
+    row = {p["name"]: p for p in doc["programs"]}["stamped_toy"]
+    assert row["device_seconds_total"] == pytest.approx(0.25, abs=1e-6)
+    assert row["intervals"] == 2 and row["stamped"] is True
+    assert set(doc["stamps"]) == {"armed", "misses", "pending", "ring"}
+    for name in ("jit_device_seconds_total", "jit_device_intervals_total"):
+        assert name in METRICS and METRICS[name].labels == ("program",)
 
 
 # ---------------------------------------------------------------------------

@@ -266,8 +266,9 @@ async def test_debug_slo_and_compiles_schemas():
             "verdict"} <= set(doc["recompile"])
     comp = compiles.json()
     assert set(comp) == {"armed", "budget", "storms_total",
-                         "events_dropped", "degrades", "programs",
+                         "events_dropped", "stamps", "degrades", "programs",
                          "persistent_cache"}
+    assert set(comp["stamps"]) == {"armed", "misses", "pending", "ring"}
     assert set(comp["persistent_cache"]) == {"dir", "requests", "hits",
                                              "misses"}
     for d in comp["degrades"]:   # the kernel-degrade attribution ledger

@@ -35,7 +35,10 @@ LEGACY = ("tokenizer_s", "probes_s", "params_s", "params_prep_s",
 READERS = ("program_ready_s", "runtime_start_s", "probes_s",
            "warmup_compile_s", "setup_outside_ready_s", "startup_named_share")
 FILE_PHASES = ("gguf_open", "tokenizer", "probes", "params")
-KW = dict(n_ctx=128, decode_chunk=4, max_gen_tokens=16, prefill_buckets=(32,))
+#: n_ctx 144: a ring no other test file builds, so the jit caches (one a
+#: process, and a worker runs whatever files ``--dist load`` hands it) hold
+#: none of these engines' programs when the first of them warms up
+KW = dict(n_ctx=144, decode_chunk=4, max_gen_tokens=16, prefill_buckets=(32,))
 #: the warm-up's steps as the code has them, by engine kind
 STEPS = {"serial": ["request", "buckets", "reuse_buckets"],
          "lanes": ["lanes_round", "stream_round", "slice_shapes", "lane_copy",
@@ -256,6 +259,11 @@ def test_warmup_counts_what_the_jit_registry_counted(engines, kind):
     assert [t["compile_s"] for t in tops] == sorted(
         (t["compile_s"] for t in tops), reverse=True)
     assert all(compiled[t["name"]] == t["compiles"] > 0 for t in tops)
+    # the phase and the registry summed the same events: seconds where
+    # and only where something compiled, whatever an earlier test of this
+    # process left in the jit caches
+    assert (a["compile_s"] > 0) == (a["programs_compiled"] > 0) \
+        == bool(tops)
     if kind.endswith("file"):   # the first engine of its kind compiles
         assert a["programs_compiled"] >= 3 and a["compile_s"] > 0
 

@@ -177,7 +177,10 @@ def test_serial_one_program_prompt_is_one_slice(model_path):
     prefill, = _named(doc["root"], "prefill")
     kids = _assert_children_tile(prefill)
     one, = [c for c in kids if c["name"] == "prefill_slice"]
-    assert one["attrs"] == {"offset": 0, "tokens": prefill["attrs"]["bucket"]}
+    # (device_s / done_at: its program's device interval, tests/test_devtime.py)
+    assert {k: one["attrs"][k] for k in ("offset", "tokens")} == \
+        {"offset": 0, "tokens": prefill["attrs"]["bucket"]}
+    assert set(one["attrs"]) <= {"offset", "tokens", "device_s", "done_at"}
 
 
 @pytest.mark.anyio
@@ -471,12 +474,15 @@ def recorder(monkeypatch):
 
 
 def test_phase_off_is_the_shared_noop(monkeypatch, serial):
-    """LFKT_PROFILE_DIR unset: one shared object, and no annotation is ever
-    built — pinned by poisoning the class and serving a whole request."""
+    """LFKT_PROFILE_DIR unset and the tracer off: one shared object, and no
+    annotation is ever built — pinned by poisoning the class and serving a
+    whole request.  With the tracer armed a phase that names a request
+    keeps its rid on the thread and still builds no annotation."""
     import jax.profiler
 
     monkeypatch.delenv("LFKT_PROFILE_DIR", raising=False)
-    assert obs_trace.arm_phases() is False
+    monkeypatch.setattr(obs_trace, "_KEEP_RID", False)   # restored after
+    assert obs_trace.arm_phases(tracing=False) is False
 
     class Poisoned:
         def __init__(self, *a, **kw):
@@ -488,6 +494,18 @@ def test_phase_off_is_the_shared_noop(monkeypatch, serial):
     assert a is b is obs_trace._NO_PHASE
     with a:
         pass
+    out = serial.create_chat_completion(LONG, temperature=0.0, max_tokens=6)
+    assert out["usage"]["completion_tokens"] >= 1
+    assert obs_trace.arm_phases(tracing=True) is False
+    assert phase("wave", wave=1, lanes_live=2) is obs_trace._NO_PHASE
+    with phase("tokenize", rid="x"):
+        assert obs_trace.open_rid() == "x"
+        with phase("fetch", wave=3):            # names no request: keeps it
+            assert obs_trace.open_rid() == "x"
+        with phase("admit_slice", rid="y", offset=0, tokens=16):
+            assert obs_trace.open_rid() == "y"
+        assert obs_trace.open_rid() == "x"
+    assert obs_trace.open_rid() == ""
     out = serial.create_chat_completion(LONG, temperature=0.0, max_tokens=6)
     assert out["usage"]["completion_tokens"] >= 1
 
