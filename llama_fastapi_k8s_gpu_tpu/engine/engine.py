@@ -647,11 +647,15 @@ class Engine:
         by the program's own ledgers (utils/startup.py CompileMeter)."""
         with self.startup.phase("warmup", meter="programs") as ph:
             what = self._warmup_steps(ph)
+        a = ph.attrs
         logger.info("warmup done in %.1fs (%s; %d programs compiled in "
-                    "%.1fs, %d of %d compile requests under the cache's "
-                    "floor)", ph.seconds, what,
-                    ph.attrs["programs_compiled"], ph.attrs["compile_s"],
-                    ph.attrs["compiled_uncached"], ph.attrs["cache_requests"])
+                    "%.1fs: %d loaded from the executable store in %.1fs, "
+                    "%d built for it in %.1fs, %d files did not load; %d of "
+                    "%d compile requests under the cache's floor)",
+                    ph.seconds, what, a["programs_compiled"], a["compile_s"],
+                    a["programs_loaded"], a["load_s"], a["programs_built"],
+                    a["build_s"], a["load_failures"],
+                    a["compiled_uncached"], a["cache_requests"])
 
     def _warmup_steps(self, ph) -> str:  # lfkt: blocks-under[_lock] -- warmup compiles and syncs under the engine lock by design: a request must never race a half-warmed cache
         """The serial engine's warm-up, every (bucket, chunk) shape; returns

@@ -294,6 +294,27 @@ METRICS: dict[str, Metric] = _register(
            "device intervals summed into jit_device_seconds_total per "
            "program: its stamped dispatches (devtime snapshot)",
            labels=("program",)),
+    Metric("executables_loaded_total", GAUGE,
+           "first calls of a signature whose executable was loaded from "
+           "the executable store, no trace and no lowering "
+           "(utils/execstore.py; devtime snapshot)", labels=("program",)),
+    Metric("executables_built_total", GAUGE,
+           "first calls of a signature that built the executable and "
+           "wrote it to the executable store (devtime snapshot)",
+           labels=("program",)),
+    Metric("executable_load_seconds_total", GAUGE,
+           "wall of the first calls counted in executables_loaded_total: "
+           "the file's read, the load, the first dispatch "
+           "(devtime snapshot)", labels=("program",)),
+    Metric("executable_build_seconds_total", GAUGE,
+           "wall of the first calls counted in executables_built_total: "
+           "trace, lowering, compile or persistent-cache read, "
+           "serialisation, the first dispatch (devtime snapshot)",
+           labels=("program",)),
+    Metric("executable_load_failures_total", GAUGE,
+           "executable store files that did not load (truncated, another "
+           "runtime, a changed pickle): each deleted and its program "
+           "built again"),
     Metric("xla_recompile_storms_total", GAUGE,
            "signatures minted past LFKT_RECOMPILE_BUDGET "
            "(devtime snapshot; docs/RUNBOOK.md recompile-storm runbook)"),

@@ -57,6 +57,25 @@ interval of the next stamped program, and on a mesh ``done`` is the
 slowest shard's.  With the tracer off the wrapper pays one attribute read
 and the watcher thread does not exist.
 
+The executable store (PR 55; utils/execstore.py, the compile layer's).
+Where the persistent compile cache is on (utils/jaxcache.py
+``setup_compile_cache``), the first call of a signature computes a key
+WITHOUT tracing, and either loads the program's executable from ``<cache
+dir>/executables`` or builds it once (``fn.lower(...).compile()``), writes
+it there and dispatches through that ``Compiled`` from then on.  What
+dispatches a call is :class:`StoredJit`'s decision, made before this
+module looks at ``_armed``: the registry only records what the decision
+was.  A first-seen signature is a compile event either way (``compiles``,
+``compile_s``: the first call's wall, as before); ``loaded`` / ``load_s``
+and ``built`` / ``build_s`` say which of them came through the store and
+how.  **A start that loaded its programs ran no trace**: what only a trace
+writes reads zero there, and is no fault: the inner sites' registrations
+(``register_program`` at trace time: the ``programs`` list of
+``/debug/compiles`` holds the entry programs alone), the degrade entries a
+trace would add (the probes' own are recorded before any program, and are
+in the key), and JAX's ``requests`` / ``hits`` for the stored programs
+(utils/jaxcache.py ``cache_counts``).
+
 Determinism dividend: because compile/dispatch counts are exact and
 device-independent, tier-1 pins them on CPU (tests/test_perf_pins.py) —
 a silent recompile or a stray extra dispatch per decode chunk fails a
@@ -71,6 +90,8 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from ..utils.execstore import (BUILT, JIT, LOADED, NOT_KEPT, ExecStore,
+                               StoredJit)
 from .trace import annotate_all_inflight, open_rid, phase
 
 logger = logging.getLogger(__name__)
@@ -128,7 +149,8 @@ class _Program:
 
     __slots__ = ("name", "kind", "site", "signatures", "sig_seen",
                  "compiles", "dispatches", "compile_s", "storms",
-                 "device_s", "intervals", "stamped")
+                 "device_s", "intervals", "stamped",
+                 "loads", "load_s", "builds", "build_s")
 
     def __init__(self, name: str, kind: str, site: str | None):
         self.name = name
@@ -144,6 +166,10 @@ class _Program:
         self.dispatches = 0
         self.compile_s = 0.0
         self.storms = 0
+        #: of ``compiles`` / ``compile_s``: the first calls that loaded an
+        #: executable from the store, and those that built and wrote one
+        self.loads = self.builds = 0
+        self.load_s = self.build_s = 0.0
         #: sum and count of the program's device intervals (stamps armed)
         self.device_s = 0.0
         self.intervals = 0
@@ -177,10 +203,10 @@ class DevtimeRegistry:
                    "_degrades": "_lock", "_pending": "_lock",
                    "_ring": "_lock", "_last_done": "_lock",
                    "stamp_misses": "_lock", "_watcher": "_lock"}
-    _SHARED_ATOMIC = ("_armed", "_stamps", "budget")
+    _SHARED_ATOMIC = ("_armed", "_stamps", "budget", "_store")
 
     def __init__(self, armed: bool | None = None, budget: int | None = None,
-                 stamps: bool | None = None):
+                 stamps: bool | None = None, store: ExecStore | None = None):
         if armed is None or budget is None or stamps is None:
             from ..utils.config import knob
 
@@ -226,6 +252,8 @@ class DevtimeRegistry:
         self._watcher: threading.Thread | None = None
         #: the second hot-path bool: read only where ``_armed`` is true
         self._stamps = bool(stamps)
+        #: the executable store, or None: every program on the jit path
+        self._store = store
 
     # -- configuration (tests + ops) ---------------------------------------
     def configure(self, armed: bool | None = None,
@@ -237,6 +265,21 @@ class DevtimeRegistry:
             self.budget = max(1, int(budget))
         if stamps is not None:
             self._stamps = bool(stamps)
+
+    def use_store(self, path: str | None) -> ExecStore | None:
+        """Keep entry programs' executables under ``path`` from now on
+        (``None``: no store; utils/jaxcache.py calls this where the
+        persistent compile cache is on).  Signatures a wrapper already
+        dispatches keep the executable they have."""
+        if path is None:
+            self._store = None
+        elif self._store is None or self._store.path != path:
+            self._store = ExecStore(path)
+        return self._store
+
+    @property
+    def store(self) -> ExecStore | None:
+        return self._store
 
     @property
     def armed(self) -> bool:
@@ -257,6 +300,8 @@ class DevtimeRegistry:
                 p.sig_seen.clear()
                 p.compiles = p.dispatches = p.storms = p.intervals = 0
                 p.compile_s = p.device_s = 0.0
+                p.loads = p.builds = 0
+                p.load_s = p.build_s = 0.0
             self._pending.clear()
             self._ring.clear()
             self._last_done = 0.0
@@ -287,10 +332,15 @@ class DevtimeRegistry:
         return name
 
     def timed_jit(self, name: str, fn, site: str | None = None,
-                  leaf: int | None = 0):
+                  leaf: int | None = 0, key=None):
         """Wrap a host jit entry point.  Re-wrapping under the same name
         (lru-cached factories minting one jit per mesh/config key) merges
         into one program ledger — exactly what storm detection wants.
+
+        ``key``: what ``fn``'s closure holds that a trace reads, by value
+        (a factory's own arguments: mesh, axis, configuration), for the
+        executable store's key.  A jit over a closure that passes none is
+        never stored (utils/execstore.py).
 
         ``leaf``: which element of a tuple result the ``done`` stamp waits
         on (its first array; default the first of all).  It must NOT be one
@@ -301,7 +351,7 @@ class DevtimeRegistry:
         program's interval."""
         with self._lock:
             self._program(name, ENTRY, site).stamped = leaf is not None
-        return _TimedJit(self, name, fn, leaf)
+        return _TimedJit(self, name, fn, leaf, key)
 
     #: distinct (program, reason) degrade pairs retained; repeats past the
     #: bound still count into the OLDEST entry's overflow marker
@@ -344,9 +394,13 @@ class DevtimeRegistry:
         with self._lock:
             self._program(name, ENTRY, None).dispatches += n
 
-    def record_compile(self, name: str, signature: str, wall_s: float) -> None:
-        """Record one compile event.  Storm side effects (log + trace
-        fan-in) fire outside the lock."""
+    def record_compile(self, name: str, signature: str, wall_s: float,
+                       how: str | None = None) -> None:
+        """Record one compile event: the first call of a signature, whose
+        wall is ``wall_s``.  ``how``: :data:`LOADED` (the executable came
+        from the store), :data:`BUILT` (built and written there), None (the
+        jit path).  Storm side effects (log + trace fan-in) fire outside
+        the lock."""
         storm = None
         with self._lock:
             p = self._program(name, ENTRY, None)
@@ -364,10 +418,16 @@ class DevtimeRegistry:
                     p.signatures.popitem(last=False)
             p.compiles += 1
             p.compile_s += wall_s
+            if how == LOADED:
+                p.loads += 1
+                p.load_s += wall_s
+            elif how == BUILT:
+                p.builds += 1
+                p.build_s += wall_s
             self._seq += 1
             self._events.append({"seq": self._seq, "program": name,
                                  "wall_s": wall_s, "signature": signature,
-                                 "at": time.time()})
+                                 "how": how, "at": time.time()})
             if not known and len(p.sig_seen) > self.budget:
                 p.storms += 1
                 self.storms_total += 1
@@ -497,6 +557,26 @@ class DevtimeRegistry:
             return {name: (p.compiles, p.compile_s)
                     for name, p in self._programs.items()}
 
+    def store_ledger(self) -> dict[str, tuple[int, float, int, float]]:
+        """{program: (loads, load seconds, builds, build seconds)} so far:
+        the part of :meth:`compile_ledger` that went through the
+        executable store (CompileMeter: ``programs_loaded`` ...)."""
+        with self._lock:
+            return {name: (p.loads, p.load_s, p.builds, p.build_s)
+                    for name, p in self._programs.items()}
+
+    def store_totals(self) -> dict:
+        """``programs_loaded`` / ``load_s`` / ``programs_built`` /
+        ``build_s`` over every program, and the store's ``load_failures``
+        (0 without a store)."""
+        rows = self.store_ledger().values()
+        store = self._store
+        return {"programs_loaded": sum(r[0] for r in rows),
+                "load_s": round(sum(r[1] for r in rows), 3),
+                "programs_built": sum(r[2] for r in rows),
+                "build_s": round(sum(r[3] for r in rows), 3),
+                "load_failures": 0 if store is None else store.load_failures}
+
     def events_since(self, cursor: int) -> tuple[int, list[dict]]:
         """Compile events newer than ``cursor`` (bounded ring) + the new
         cursor — /metrics replays them into the xla_compile_seconds
@@ -547,7 +627,8 @@ class DevtimeRegistry:
         with self._lock:
             rows = [(p.name, p.kind, p.site, p.compiles, p.dispatches,
                      p.compile_s, len(p.sig_seen), p.storms,
-                     dict(p.signatures), p.device_s, p.intervals, p.stamped)
+                     dict(p.signatures), p.device_s, p.intervals, p.stamped,
+                     p.loads, p.load_s, p.builds, p.build_s)
                     for p in self._programs.values()]
             degrades = [dict(v) for v in self._degrades.values()]
             armed = self._armed
@@ -559,14 +640,18 @@ class DevtimeRegistry:
                       "ring": len(self._ring)}
         programs = []
         for name, kind, site, compiles, dispatches, compile_s, n_sigs, \
-                storms, signatures, device_s, intervals, stamped \
-                in sorted(rows):
+                storms, signatures, device_s, intervals, stamped, \
+                loads, load_s, builds, build_s in sorted(rows):
             sigs = [{"signature": s, **meta}
                     for s, meta in signatures.items()]
             programs.append({
                 "name": name, "kind": kind, "site": site,
                 "compiles": compiles, "dispatches": dispatches,
                 "compile_seconds_total": round(compile_s, 6),
+                # of the two above: first calls that loaded the executable
+                # from the store, and those that built and wrote it
+                "loaded": loads, "load_seconds_total": round(load_s, 6),
+                "built": builds, "build_seconds_total": round(build_s, 6),
                 "signatures": n_sigs,
                 "storms": storms,
                 # an upper bound: unregistered device work and a program
@@ -576,10 +661,13 @@ class DevtimeRegistry:
                 "stamped": stamped if kind == ENTRY else None,
                 "signature_list": sigs,   # ledger bounds retention
             })
+        store = self._store
         return {"armed": armed, "budget": self.budget,
                 "storms_total": storms_total,
                 "events_dropped": dropped,
                 "stamps": stamps,
+                "executable_store": None if store is None else {
+                    **store.stats(), **self.store_totals()},
                 "degrades": degrades,
                 "programs": programs}
 
@@ -616,12 +704,20 @@ class _TimedJit:
     """The per-entry-point wrapper ``timed_jit`` returns.  Call-compatible
     with the wrapped jit function; donation, static args and sharding all
     pass through untouched (the wrapper never copies or inspects buffers
-    beyond shape/dtype metadata, and only on compile events)."""
+    beyond shape/dtype metadata).
 
-    __slots__ = ("_reg", "_name", "_fn", "_probe", "_leaf", "__wrapped__")
+    WHAT dispatches a call is the compile layer's to say
+    (utils/execstore.py :class:`StoredJit`: the jit itself, or a
+    ``Compiled`` that was loaded from the executable store or built for
+    it), and it says so before this wrapper looks at ``_armed``: an armed
+    registry adds the counters, the compile events and the stamps, and
+    changes no dispatch."""
+
+    __slots__ = ("_reg", "_name", "_fn", "_probe", "_leaf", "__wrapped__",
+                 "_prog")
 
     def __init__(self, reg: DevtimeRegistry, name: str, fn,
-                 leaf: int | None = 0):
+                 leaf: int | None = 0, key=None):
         self._reg = reg
         self._name = name
         self._fn = fn
@@ -629,12 +725,28 @@ class _TimedJit:
         self.__wrapped__ = fn
         # jax's PjitFunction exposes its compiled-variant count
         self._probe = fn._cache_size
+        self._prog = StoredJit(name, fn, key)
         _listen()
 
     def __call__(self, *args, **kwargs):
         reg = self._reg
-        if not reg._armed:          # disarmed: forward untouched, allocate
-            return self._fn(*args, **kwargs)   # nothing (poisoned-reg test)
+        store, prog = reg._store, self._prog
+        if store is None or prog.on_jit:
+            run = JIT
+        else:
+            run = prog.find(args, kwargs)
+            if run is None:
+                return self._first_call(reg, store, args, kwargs)
+        if not reg._armed:          # disarmed: the same dispatch, and the
+            if run is JIT:          # registry untouched (poisoned-reg test)
+                return self._fn(*args, **kwargs)
+            return run(*prog.dynamic(args), **prog.dynamic_kw(kwargs))
+        if run is JIT:
+            return self._call_jit(reg, args, kwargs)
+        out = run(*prog.dynamic(args), **prog.dynamic_kw(kwargs))
+        return self._dispatched(reg, out)
+
+    def _call_jit(self, reg: DevtimeRegistry, args: tuple, kwargs: dict):
         probe = self._probe
         before = probe()
         handed = _HANDED.n
@@ -643,10 +755,36 @@ class _TimedJit:
         dt = time.perf_counter() - t0
         if probe() > before and _HANDED.n > handed:
             reg.record_compile(self._name, _signature(args, kwargs), dt)
+        return self._dispatched(reg, out)
+
+    def _dispatched(self, reg: DevtimeRegistry, out):
         reg.record_dispatch(self._name)
         if reg._stamps and self._leaf is not None:   # the tracer is armed
             reg._stamp(self._name, self._leaf, out)
         return out
+
+    def _first_call(self, reg: DevtimeRegistry, store: ExecStore,
+                    args: tuple, kwargs: dict):
+        """A signature the program has not seen: the store's layer loads
+        or builds it; a compile event either way, with ``how``."""
+        t0 = time.perf_counter()
+        prog, armed = self._prog, reg._armed
+        run, how = prog.first(store, args, kwargs, reg.degrades())
+        if run is JIT and how is None and armed:   # the jit's own first
+            return self._call_jit(reg, args, kwargs)   # call says what it did
+        if run is JIT:
+            # built here and not kept: the jit finds that executable in
+            # its own caches and hands the compiler nothing
+            out = self._fn(*args, **kwargs)
+        else:
+            out = run(*prog.dynamic(args), **prog.dynamic_kw(kwargs))
+        if not armed:
+            return out
+        if how is not None:
+            reg.record_compile(self._name, _signature(args, kwargs),
+                               time.perf_counter() - t0,
+                               how if how != NOT_KEPT else None)
+        return self._dispatched(reg, out)
 
 
 #: THE process-wide registry: entry points wrap themselves through it at
@@ -655,10 +793,10 @@ DEVTIME = DevtimeRegistry()
 
 
 def timed_jit(name: str, fn, site: str | None = None,
-              leaf: int | None = 0):
+              leaf: int | None = 0, key=None):
     """Module-level convenience: wrap ``fn`` as program ``name`` on the
     process registry (the form every entry-point module uses)."""
-    return DEVTIME.timed_jit(name, fn, site=site, leaf=leaf)
+    return DEVTIME.timed_jit(name, fn, site=site, leaf=leaf, key=key)
 
 
 def register_program(name: str, kind: str = INNER,

@@ -24,6 +24,33 @@ __all__ = ["probe_fused_q4k", "probe_fused_q5k", "probe_fused_q6k",
            "probe_kv_quant"]
 
 
+#: every verdict this process has reached: {"probe_x" or "probe_x(args)":
+#: None or the error}.  The executable store's key holds them
+#: (utils/execstore.py): a verdict decides which kernels a trace may use
+_VERDICTS: dict[str, str | None] = {}
+
+
+def verdicts() -> dict[str, str | None]:
+    """The verdicts so far (a copy); a probe that has not run is absent."""
+    return dict(_VERDICTS)
+
+
+def _once(fn):
+    """Run a probe once per process and argument tuple, and write its
+    verdict down for :func:`verdicts`."""
+    cached = functools.lru_cache(maxsize=None)(fn)
+
+    @functools.wraps(fn)
+    def probe(*args, **kwargs):
+        verdict = cached(*args, **kwargs)
+        asked = ",".join([*map(repr, args),
+                          *(f"{k}={v!r}" for k, v in sorted(kwargs.items()))])
+        _VERDICTS[fn.__name__ + (f"({asked})" if asked else "")] = verdict
+        return verdict
+
+    return probe
+
+
 def _err(e: BaseException) -> str:
     return f"{type(e).__name__}: {e}"[:400]
 
@@ -38,7 +65,7 @@ def _probe_n() -> int:
     return 8 if use_interpret() else 512
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_fused_q4k() -> str | None:
     """Compile + run the fused Q4_K matmul at the serving tile geometry."""
     try:
@@ -64,7 +91,7 @@ def probe_fused_q4k() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_fused_q5k() -> str | None:
     """Compile + run the fused Q5_K matmul at the serving tile geometry."""
     try:
@@ -87,7 +114,7 @@ def probe_fused_q5k() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_fused_q6k() -> str | None:
     """Compile + run the fused Q6_K matmul at the serving tile geometry."""
     try:
@@ -110,7 +137,7 @@ def probe_fused_q6k() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_fused_q8() -> str | None:
     """Compile + run the fused Q8_0 matmul at the serving tile geometry."""
     try:
@@ -133,7 +160,7 @@ def probe_fused_q8() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_fused_experts() -> str | None:
     """Compile + run the grouped expert matmuls (ops/pallas/experts.py) at
     the serving tile geometry, in both row regimes: Q4_K gate/up (1024,
@@ -170,7 +197,7 @@ def probe_fused_experts() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=2)
+@_once
 def probe_flash_attention(quantized: bool = False) -> str | None:
     """Compile + run the flash prefill kernel at the Llama-3-8B head
     layout (32 q heads / 8 kv heads / head_dim 128) on a short sequence,
@@ -250,7 +277,7 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_latent_decode() -> str | None:
     """Compile + run the decode kernel on a latent leaf (one ring of rows
     for all heads, the values a row's first columns: ops/pallas/
@@ -291,7 +318,7 @@ def probe_latent_decode() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_latent_prefill() -> str | None:
     """Compile + run the prefill slices' kernel on a latent leaf
     (ops/pallas/attention.py ``latent_attention_prefill``: the scores of a
@@ -328,7 +355,7 @@ def probe_latent_prefill() -> str | None:
         return _err(e)
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_kv_quant() -> str | None:
     """Compile + run the int8 KV-cache write-quantize kernel
     (ops/pallas/kvquant.py) at a decode-like shape.  A failure degrades
@@ -358,7 +385,7 @@ register_program("probe_latent_decode", site="ops.pallas.probe")
 register_program("probe_latent_prefill", site="ops.pallas.probe")
 
 
-@functools.lru_cache(maxsize=1)
+@_once
 def probe_lin_state() -> str | None:
     """Compile + run the linear-attention layers' state step
     (ops/pallas/linstate.py) over two lanes, one of them dead, at the

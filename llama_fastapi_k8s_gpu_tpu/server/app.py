@@ -22,6 +22,7 @@ Additions the reference advertises but lacks (SURVEY.md §2C): ``GET /health``
 from __future__ import annotations
 
 import asyncio
+import gc
 import inspect
 import logging
 import math
@@ -94,6 +95,22 @@ def _openai_http_error(e: HTTPException) -> JSONResponse:
         _openai_error_body(e.status_code, msg,
                            getattr(e, "openai_code", None)),
         e.status_code)
+
+
+def _settle_heap() -> None:
+    """One full collection as the start ends, so that none falls due among
+    the first requests.  CPython runs a full pass when the objects that
+    outlived two young passes exceed a quarter of the heap the LAST full
+    pass walked, and on a serving pod a pass holds the interpreter lock,
+    and the scheduler thread with it, for 0.36-0.85 s (about 145 000
+    objects at READY).  A start that TRACES its programs leaves the next
+    pass far away (seven automatic passes a start, the last over a heap
+    that still held a trace); one that LOADED them (PR 55: three passes)
+    reaches READY just short of the threshold, and the fourth fell 31 s
+    into the serving window: 0.99 s without an admission, 174 requests
+    where the parent serves 178 (PERF.md section 6, PR 55, call K).  Paid
+    here it is 0.4-0.8 s of the start, and the window meets none."""
+    gc.collect()
 
 
 def _accepts_kwarg(fn, name: str) -> bool:
@@ -845,6 +862,7 @@ def create_app(engine=None, settings: Settings | None = None,
                 metrics=app.state.metrics, health=app.state.health)
             await asyncio.to_thread(app.state.migration.warm_up)
         tl.absorb(getattr(engine, "startup", None))
+        _settle_heap()
         app.state.ready = True
         app.state.health.transition(READY, "engine loaded")
         tl.ready_unix = time.time()
@@ -1691,6 +1709,17 @@ def create_app(engine=None, settings: Settings | None = None,
                             program=prog)
                 m.set_gauge("jit_device_intervals_total", c["intervals"],
                             program=prog)
+        for prog, (n_l, s_l, n_b, s_b) in DEVTIME.store_ledger().items():
+            if n_l or n_b:      # the executable store had a part in it
+                m.set_gauge("executables_loaded_total", n_l, program=prog)
+                m.set_gauge("executable_load_seconds_total", s_l,
+                            program=prog)
+                m.set_gauge("executables_built_total", n_b, program=prog)
+                m.set_gauge("executable_build_seconds_total", s_b,
+                            program=prog)
+        store = DEVTIME.store
+        m.set_gauge("executable_load_failures_total",
+                    0 if store is None else store.load_failures)
         m.set_gauge("xla_recompile_storms_total", DEVTIME.storms_total)
         cursor, events = DEVTIME.events_since(app.state.devtime_cursor)
         app.state.devtime_cursor = cursor

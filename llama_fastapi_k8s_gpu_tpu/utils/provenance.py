@@ -65,16 +65,40 @@ VOLATILE_KNOBS = frozenset({
 })
 
 
+def _lfkt_env(skip: frozenset) -> list[tuple[str, str]]:
+    """The ``LFKT_*`` variables as set for this process, sorted, less
+    ``skip``."""
+    return sorted((k, v) for k, v in os.environ.items()
+                  if k.startswith("LFKT_") and k not in skip)
+
+
 def knob_fingerprint() -> dict:
     """The perf-relevant ``LFKT_*`` environment as set for this process,
     plus a short stable hash — two artifacts with equal ``knob_hash``
     were measured under byte-identical knob sets (modulo
     :data:`VOLATILE_KNOBS`)."""
-    knobs = {k: v for k, v in sorted(os.environ.items())
-             if k.startswith("LFKT_") and k not in VOLATILE_KNOBS}
+    knobs = dict(_lfkt_env(VOLATILE_KNOBS))
     digest = hashlib.sha256(
         json.dumps(knobs, sort_keys=True).encode()).hexdigest()[:12]
     return {"knobs": knobs, "knob_hash": digest}
+
+
+#: beside :data:`VOLATILE_KNOBS`, variables that cannot reach a TRACE
+#: because of what they name: the file a model is read from (its weights
+#: are arguments, its shapes are in the key) and what the tracer samples.
+#: Everything else is in the executable store's key (utils/execstore.py): a
+#: variable wrongly in the key costs a rebuild, one wrongly out of it a
+#: wrong answer, so a new knob is in the key until someone shows it
+#: belongs here.
+NOT_TRACED = VOLATILE_KNOBS | {
+    "LFKT_MODEL_NAME", "LFKT_TRACE_SAMPLE", "LFKT_TRACE_RING"}
+
+
+def trace_env() -> list[tuple[str, str]]:
+    """Every ``LFKT_*`` variable the environment holds, registered or not,
+    sorted, but :data:`NOT_TRACED`: what a trace in this process may read
+    through ``knob`` / ``_env_variant``."""
+    return _lfkt_env(NOT_TRACED)
 
 
 def mem_stats() -> dict:

@@ -187,15 +187,22 @@ class CompileMeter:
     entry programs' compile counts and first-dispatch walls) and JAX's
     persistent-cache events (``utils/jaxcache.py``).  A compile request
     that is neither a hit nor a miss was compiled anew and not written
-    back: under the cache's 0.5 s floor, paid again by every start."""
+    back: under the cache's 0.5 s floor, paid again by every start.  Of
+    the programs compiled, the executable store's part (utils/execstore.py):
+    ``programs_loaded`` / ``load_s`` read their executable from it,
+    ``programs_built`` / ``build_s`` built and wrote one (the rest, under
+    the floor, went through the jit as before); ``load_failures`` counts
+    files that did not load."""
 
     def __init__(self):
         from ..obs.devtime import DEVTIME
         from .jaxcache import compile_cache_stats
 
         self._ledger = DEVTIME.compile_ledger
+        self._store = DEVTIME.store_totals
         self._cache = compile_cache_stats
         self._ledger0 = self._ledger()
+        self._store0 = self._store()
         self._cache0 = self._cache()
 
     def cache(self) -> dict:
@@ -211,8 +218,9 @@ class CompileMeter:
     def read(self, seconds: float, top: int = 8) -> dict:
         """The attributes of a phase that lasted ``seconds``: the cache
         counters, ``programs_compiled`` and ``compile_s`` (the registry's
-        deltas), ``execute_s`` (the phase less ``compile_s``) and the
-        ``top`` programs by compile seconds."""
+        deltas: every first call of a signature and its wall),
+        ``execute_s`` (the phase less ``compile_s``), the store's part of
+        them and the ``top`` programs by compile seconds."""
         rows = []
         for name, (n, s) in self._ledger().items():
             n0, s0 = self._ledger0.get(name, (0, 0.0))
@@ -221,7 +229,10 @@ class CompileMeter:
                              "compile_s": round(s - s0, 3)})
         rows.sort(key=lambda r: -r["compile_s"])
         compile_s = round(sum(r["compile_s"] for r in rows), 3)
+        store = self._store()
         return {"programs_compiled": sum(r["compiles"] for r in rows),
                 "compile_s": compile_s,
                 "execute_s": round(seconds - compile_s, 3),
+                **{k: round(v - self._store0[k], 3)
+                   for k, v in store.items()},
                 **self.cache(), "top_programs": rows[:top]}

@@ -73,6 +73,12 @@ def test_timed_jit_counts_compiles_and_dispatches(reg):
     prog = next(p for p in snap["programs"] if p["name"] == "toy")
     assert prog["kind"] == "entry"
     assert prog["compile_seconds_total"] > 0
+    # no executable store (the tests keep out of the persistent cache):
+    # both compiles went through the jit (tests/test_execstore.py has the
+    # store's side)
+    assert (prog["loaded"], prog["built"]) == (0, 0)
+    assert prog["load_seconds_total"] == prog["build_seconds_total"] == 0
+    assert snap["executable_store"] is None
     sigs = [s["signature"] for s in prog["signature_list"]]
     assert any("[3]" in s for s in sigs) and any("[4]" in s for s in sigs)
 

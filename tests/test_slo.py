@@ -266,20 +266,23 @@ async def test_debug_slo_and_compiles_schemas():
             "verdict"} <= set(doc["recompile"])
     comp = compiles.json()
     assert set(comp) == {"armed", "budget", "storms_total",
-                         "events_dropped", "stamps", "degrades", "programs",
-                         "persistent_cache"}
+                         "events_dropped", "stamps", "executable_store",
+                         "degrades", "programs", "persistent_cache"}
+    assert comp["executable_store"] is None      # off where the cache is
     assert set(comp["stamps"]) == {"armed", "misses", "pending", "ring"}
     assert set(comp["persistent_cache"]) == {"dir", "requests", "hits",
                                              "misses"}
     for d in comp["degrades"]:   # the kernel-degrade attribution ledger
         assert {"program", "reason", "count"} <= set(d)
     for p in comp["programs"]:
-        assert {"name", "kind", "compiles", "dispatches",
+        assert {"name", "kind", "compiles", "dispatches", "loaded", "built",
+                "load_seconds_total", "build_seconds_total",
                 "signatures", "signature_list"} <= set(p)
     # the scrape carries the devtime + slo families
     text = metrics.text
     assert "slo_burn_rate{" in text
     assert "xla_recompile_storms_total" in text
+    assert "executable_load_failures_total 0" in text
 
 
 @pytest.mark.anyio
@@ -334,8 +337,10 @@ async def test_storm_visible_in_metrics_slo_and_inflight_trace():
                 task = asyncio.create_task(client.post("/response",
                                                        json=BODY))
                 await asyncio.sleep(0.15)          # request now in flight
-                DEVTIME.record_compile("stormy", "f32[1]", 0.2)
-                DEVTIME.record_compile("stormy", "f32[2]", 0.2)  # storm
+                # the first loaded from the executable store, the second
+                # built for it: compile events both (PR 55)
+                DEVTIME.record_compile("stormy", "f32[1]", 0.2, "loaded")
+                DEVTIME.record_compile("stormy", "f32[2]", 0.2, "built")
                 metrics = (await client.get("/metrics")).text
                 slo = (await client.get("/debug/slo")).json()
                 r = await task
@@ -343,6 +348,10 @@ async def test_storm_visible_in_metrics_slo_and_inflight_trace():
         assert r.status_code == 200
         assert "xla_recompile_storms_total 1" in metrics
         assert 'xla_compiles_total{program="stormy"} 2' in metrics
+        assert 'executables_loaded_total{program="stormy"} 1' in metrics
+        assert 'executables_built_total{program="stormy"} 1' in metrics
+        assert 'executable_load_seconds_total{program="stormy"} 0.2' \
+            in metrics
         assert 'xla_compile_seconds_count{program="stormy"} 2' in metrics
         assert slo["recompile"]["verdict"] == "storm"
         assert slo["recompile"]["storms"][0]["program"] == "stormy"

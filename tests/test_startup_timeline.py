@@ -254,6 +254,12 @@ def test_warmup_counts_what_the_jit_registry_counted(engines, kind):
                                            abs=0.002)
     assert a["compiled_uncached"] == a["cache_requests"] - a["cache_hits"] \
         - a["cache_misses"]
+    # the executable store's part of them (PR 55): the tests keep out of
+    # the persistent cache (conftest.py), so out of the store beside it
+    assert {k: a[k] for k in ("programs_loaded", "load_s", "programs_built",
+                              "build_s", "load_failures")} == {
+        "programs_loaded": 0, "load_s": 0, "programs_built": 0,
+        "build_s": 0, "load_failures": 0}
     tops = a["top_programs"]
     assert len(tops) <= 8
     assert [t["compile_s"] for t in tops] == sorted(
@@ -334,6 +340,32 @@ def served(engines):
         "fake": anyio.run(health_of, create_app(
             engine_factory=FakeEngine, settings=Settings())),
     }
+
+
+def test_the_start_ends_with_a_full_collection(monkeypatch):
+    """READY comes right after one full pass of the collector, so that
+    none falls due in the first requests (server/app.py ``_settle_heap``:
+    a start that loaded its programs stops a few thousand objects short of
+    the next pass's threshold)."""
+    import anyio
+    import gc
+
+    from llama_fastapi_k8s_gpu_tpu.server import app as app_module
+
+    seen = []
+
+    def full_pass(phase, info):
+        if phase == "stop" and info["generation"] == 2:
+            seen.append(app.state.ready)
+
+    app = create_app(engine_factory=FakeEngine, settings=Settings())
+    gc.callbacks.append(full_pass)
+    try:
+        anyio.run(health_of, app)
+    finally:
+        gc.callbacks.remove(full_pass)
+    assert False in seen            # a full pass before the READY flip
+    assert app_module._settle_heap.__doc__
 
 
 @pytest.mark.parametrize("field", FIELDS)
@@ -448,7 +480,12 @@ def canned(with_timeline: bool) -> dict:
             phases.append({"name": name, "start_s": round(at, 3),
                            "seconds": s})
             at += s
-        phases[-2]["attrs"] = {"compile_s": 5.25, "compiled_uncached": 92}
+        # a start that loaded its executables (PR 55): every first call
+        # of a signature is in compile_s, the store's part beside it
+        phases[-2]["attrs"] = {"programs_compiled": 11, "compile_s": 5.25,
+                               "programs_loaded": 5, "load_s": 4.4,
+                               "programs_built": 0, "build_s": 0.0,
+                               "load_failures": 0, "compiled_uncached": 92}
         engine["startup"] = {
             "process_start_unix": 1000.0, "ready_unix": 1000.0 + at + 0.5,
             "ready_s": round(at + 0.5, 3), "phases": phases,
