@@ -294,9 +294,10 @@ class ContinuousEngine(MeshEngine):
     )
     # cross-thread by design; individual operations are GIL-atomic
     # (dict/Queue/Event ops) or single reference stores
-    # (cache_counts: the scheduler thread alone adds, /metrics reads an int)
+    # (cache_counts, pass_counts: the scheduler thread alone adds, /metrics
+    # reads an int)
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
-                      "_thread", "cache_counts")
+                      "_thread", "cache_counts", "pass_counts")
 
     def __init__(self, model_path: str | None, *, max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
@@ -675,14 +676,18 @@ class ContinuousEngine(MeshEngine):
         with ph.child("slice_shapes") as slices:
             cache, slices.attrs["n_shapes"] = self._warm_slice_shapes(
                 self.prefill_buckets, init_cache(self.cfg))
+        with ph.child("drain"):   # the slices queued above, still running
+            jax.block_until_ready(cache)
+        # the throwaway ring goes BEFORE the snapshot below is made: a lane
+        # engine whose caches fill the chip (six of 2 GB beside the file:
+        # ``ouro``) has room for one spare cache at a time, not for two
+        del cache
         if self._lane_prefix:
             # compile the lane→scratch snapshot gather (one program; the
             # suffix slice shapes are already in the warmed set above)
             with ph.child("lane_copy"):
                 jax.block_until_ready(_lane_cache_copy_jit(
                     self._bstate["cache"], jnp.int32(0)))
-        with ph.child("drain"):   # the slices queued above, still running
-            jax.block_until_ready(cache)
         return f"{self.batch_size} lanes"
 
     # ------------------------------------------------------------------
@@ -1407,7 +1412,7 @@ class ContinuousEngine(MeshEngine):
         tot["steps_run"] += run
         tot["steps_skipped"] += len(chunk) - run
         tot["chunks_empty"] += run == 0
-        self._note_ring_read(pre, run)
+        self._count_lane_steps(run * self._note_ring_read(pre, run))
         for lane in range(len(pre)):
             slot = pre[lane]
             if slot is None or slot.finished:
@@ -1478,7 +1483,8 @@ class ContinuousEngine(MeshEngine):
                     # the lane's own live rows at the chunk's end: what
                     # its attention needed of the read
                     **self.cache.decode_span_attrs(
-                        slot.n_prompt + len(slot.gens)))
+                        slot.n_prompt + len(slot.gens)),
+                    **self.cache.span_attrs(self.cfg))
                 cspan.end(now)
                 slot.t_chunk = now
                 slot.trace.note(tokens=len(slot.gens))
@@ -1491,19 +1497,21 @@ class ContinuousEngine(MeshEngine):
                     self._free_lane(lane, slot, slots)
         self._totals["harvest_seconds"] += time.time() - now
 
-    def _note_ring_read(self, pre: list, n_steps: int) -> None:
+    def _note_ring_read(self, pre: list, n_steps: int) -> int:
         """Count one decode chunk's attention read against what it needed
         (``CacheKind.note_decode``): summed over the lanes whose rows are
         still wanted, under the bound of the lanes the chunk was dispatched
         as live (``pre``).  From the positions the host holds before the
         chunk's tokens are folded in (prompt + generated so far: the slot
-        of the chunk's first step); nothing is fetched."""
+        of the chunk's first step); nothing is fetched.  Returns the lanes
+        whose rows were wanted (what ``layer_passes_total`` counts by)."""
         at = [None if s is None else s.n_prompt + max(len(s.gens) - 1, 0)
               for s in pre]
         wanted = [p for slot, p in zip(pre, at)
                   if slot is not None and not slot.finished]
         self.cache.note_decode(self.cache_counts, self.cfg, wanted, n_steps,
                                [p for p in at if p is not None])
+        return len(wanted)
 
     def _loop(self):
         B = self.batch_size

@@ -18,6 +18,7 @@ streaming chunks (SURVEY.md §2B) — on a JAX/TPU runtime:
 from __future__ import annotations
 
 import codecs
+import collections
 import dataclasses
 import logging
 import threading
@@ -41,7 +42,7 @@ from ..models.generate import (
     sample_jit,
     split_chunk_out,
 )
-from ..models.llama import init_cache
+from ..models.llama import init_cache, layer_passes
 from ..models.params import load_params, synth_params
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..tokenizer import apply_chat_template, detect_chat_template, tokenizer_from_gguf
@@ -50,7 +51,7 @@ from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import arm_phases, end_first_token, phase, rid
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, Heartbeat
-from .expert_counters import ExpertCounters
+from .expert_counters import ExitMass, ExpertCounters
 from .slices import plan_slices, slice_shapes, wide_width
 from ..utils.jaxcache import setup_compile_cache
 from ..utils.startup import Phase, Timeline, legacy_load_phases
@@ -222,6 +223,7 @@ class Engine:
             raise ValueError(f"kv_dtype must be bf16|int8, got {kv_dtype!r}")
         self._lock = threading.Lock()
         self._expert_counters: ExpertCounters | None = None
+        self._exit_mass: ExitMass | None = None
         # prompt tokens prefilled (padding included), by the width of the
         # program that took them: ``wide`` is more than the narrow width
         # (engine/slices.py); /metrics prefill_slice_tokens_total{width=}
@@ -328,6 +330,11 @@ class Engine:
         #: (host arithmetic from tracked positions; a lane engine adds at
         #: each chunk's harvest): /metrics via :meth:`cache_read_gauges`
         self.cache_counts = self.cache.new_counts()
+        # layer applications the programs ran, host arithmetic beside the
+        # cache kind's own (``layer_passes_total``, ``decode_lane_steps_
+        # total``: the scheduler thread alone adds, /metrics reads an int)
+        self.pass_counts = collections.Counter(
+            lane_steps=0, decode=0, prefill=0)
         self._refuse_unsupported({
             **self._asks, "slice": self._prefill_chunk,
             "int8": self.cfg.kv_dtype == "int8", "paged": bool(kv_paged)})
@@ -751,6 +758,13 @@ class Engine:
         self.slice_tokens[
             "wide" if tokens > self._prefill_chunk else "narrow"] += tokens
         self.cache.note_slice(self.cache_counts, self.cfg, tokens)
+        self.pass_counts["prefill"] += tokens * layer_passes(self.cfg)
+
+    def _count_lane_steps(self, lane_steps: int) -> None:
+        """Decode steps of the lanes whose rows were wanted, and the layer
+        applications they took, into :attr:`pass_counts`."""
+        self.pass_counts["lane_steps"] += lane_steps
+        self.pass_counts["decode"] += lane_steps * layer_passes(self.cfg)
 
     @staticmethod
     def _slice_span(pspan, t_s: float, t_e: float, offset: int, tokens: int,
@@ -830,6 +844,7 @@ class Engine:
         state, out = generate_chunk_jit(self.params, self.cfg, state, st,
                                         n_steps=n_steps, top_k=top_k)
         self.cache.note_decode(self.cache_counts, self.cfg, [pos], n_steps)
+        self._count_lane_steps(n_steps)
         return state, self._take_expert_stats(out)
 
     def cache_read_gauges(self) -> dict:
@@ -844,15 +859,36 @@ class Engine:
         if counts is not None:
             (out["tokenizer_pieces_total"],
              out["tokenizer_memo_hits_total"]) = counts()
+        passes = getattr(self, "pass_counts", None)
+        if passes is not None:
+            out.update({
+                'layer_passes_total{phase="decode"}': passes["decode"],
+                'layer_passes_total{phase="prefill"}': passes["prefill"],
+                "decode_lane_steps_total": passes["lane_steps"]})
+        mass = getattr(self, "exit_mass", None)
+        if mass is not None:
+            out.update({f'ut_exit_mass_total{{pass="{t}"}}': p
+                        for t, p in enumerate(mass.snapshot())})
         return out
 
     def _take_expert_stats(self, chunk_out):
         """A decode chunk's tokens; a routed block's counters go to
-        :attr:`expert_counters` on the way (still on the device)."""
+        :attr:`expert_counters` on the way (still on the device), a looped
+        stack's exit masses to :attr:`exit_mass`."""
         tokens, stats = split_chunk_out(chunk_out)
         if stats is not None:
-            self.expert_counters.push(stats)
+            (self.expert_counters or self.exit_mass).push(stats)
         return tokens
+
+    @property
+    def exit_mass(self):
+        """The exit gate's cumulative mass a pass (engine/expert_counters.py
+        ``ExitMass``), None where layers run once."""
+        if self.cfg.ut_steps == 1:
+            return None
+        if self._exit_mass is None:
+            self._exit_mass = ExitMass(self.cfg.ut_steps)
+        return self._exit_mass
 
     @property
     def expert_counters(self):
@@ -1153,7 +1189,7 @@ class Engine:
         attrs = self.cache.note_prefill(self.cache_counts, self.cfg,
                                         n_prompt, slices)
         if pspan is not None:
-            pspan.set(**attrs)
+            pspan.set(**attrs, **self.cache.span_attrs(self.cfg))
 
     def _prefix_reuse_len(self, ids: list, n_prompt: int, bucket: int) -> int:
         """Longest usable common prefix of ``ids`` vs the KV resident in the
@@ -1533,7 +1569,8 @@ class Engine:
                 done = True
             if cspan is not None:
                 cspan.set(tokens=len(gen),
-                          **self.cache.decode_span_attrs(pos))
+                          **self.cache.decode_span_attrs(pos),
+                          **self.cache.span_attrs(self.cfg))
                 cspan.end()
                 ctx["trace"].note(tokens=len(gen))
 

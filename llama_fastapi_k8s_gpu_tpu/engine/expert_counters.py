@@ -23,6 +23,11 @@ held expert (``compacted_rows``: more than 64 rows) multiplies those in a
 block of 64, so ``layer_steps x n_rows - picks_held`` rows were offered and
 never multiplied (a step with more than 64 such rows multiplies them all:
 not told apart here, and not seen where a share of the experts is held).
+
+A stack whose layers run several times (``cfg.ut_steps`` > 1;
+models/llama.py ``exit_mass``) returns a float32 vector instead, the exit
+gate's mass a pass summed over the chunk's decoded tokens, and
+:class:`ExitMass` folds it the same way (``ut_exit_mass_total{pass=}``).
 """
 
 from __future__ import annotations
@@ -34,23 +39,53 @@ import numpy as np
 _MAX_PENDING = 64   # older chunks have long finished: folding them is free
 
 
-class ExpertCounters:
+class _Folded:
+    """Device vectors pushed at dispatch, summed when somebody reads."""
+
+    def __init__(self, n: int, dtype):
+        self._lock = threading.Lock()
+        self._pending: list = []
+        self._total = np.zeros(n, dtype)
+
+    def push(self, stats) -> None:
+        """One dispatched chunk's vector (a device array)."""
+        with self._lock:
+            self._pending.append(stats)
+            if len(self._pending) > _MAX_PENDING:
+                self._total += np.asarray(self._pending.pop(0))
+
+    def _fold(self, block: bool) -> np.ndarray:
+        """The total with the finished chunks (all with ``block``) folded
+        in; called under the lock."""
+        keep = []
+        for s in self._pending:
+            if block or s.is_ready():
+                self._total += np.asarray(s)
+            else:
+                keep.append(s)
+        self._pending = keep
+        return self._total
+
+
+class ExitMass(_Folded):
+    """The exit gate's mass a pass, summed over the decoded tokens."""
+
+    def __init__(self, ut_steps: int):
+        super().__init__(ut_steps, np.float64)
+
+    def snapshot(self, block: bool = False) -> list:
+        with self._lock:
+            return self._fold(block).tolist()
+
+
+class ExpertCounters(_Folded):
     def __init__(self, n_held: int, n_slots: int = 0, zero: bool = False,
                  n_rows: int = 0):
         self.n_slots = n_slots      # 0: no grouped few-row call serves
         self.n_rows = n_rows        # 0: or it is built as it always was
         self.n_held = n_held
-        self._lock = threading.Lock()
-        self._pending: list = []
         # (``zero``: the router has zero experts, and the vector their count)
-        self._total = np.zeros(3 + n_held + zero, np.int64)
-
-    def push(self, stats) -> None:
-        """One dispatched chunk's counter vector (a device array)."""
-        with self._lock:
-            self._pending.append(stats)
-            if len(self._pending) > _MAX_PENDING:
-                self._total += np.asarray(self._pending.pop(0))
+        super().__init__(3 + n_held + zero, np.int64)
 
     def snapshot(self, block: bool = False) -> dict:
         """Cumulative counters of the chunks that have finished (all
@@ -61,14 +96,7 @@ class ExpertCounters:
         router has none), ``slots_skipped`` and ``rows_skipped`` (module
         docstring)."""
         with self._lock:
-            keep = []
-            for s in self._pending:
-                if block or s.is_ready():
-                    self._total += np.asarray(s)
-                else:
-                    keep.append(s)
-            self._pending = keep
-            t = self._total
+            t = self._fold(block)
             picks = t[2:2 + self.n_held]
             held = int(picks.sum())
             return {"layer_steps": int(t[0]), "experts_read": int(t[1]),

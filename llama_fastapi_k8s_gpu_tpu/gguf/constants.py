@@ -205,10 +205,24 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: (bool: the query after ``attn_q_b`` times (embedding_length /
 #: q_lora_rank)^1/2, the normed latent times (embedding_length /
 #: kv_lora_rank)^1/2).  Q and K rotate on interleaved pairs; no rope scaling.
+#: ``ouro`` (this repo's name for the Ouro looped language models:
+#: llama.cpp's, if it has one, is not known here; models/llama.py) is the
+#: dense block whose ``block_count`` layers run ``<arch>.ut_steps`` passes a
+#: token: the same weights every pass, a cache leaf per (pass, layer), the
+#: final norm ``output_norm`` after EVERY pass.  Tensors: the dense block's,
+#: and ``blk.N.post_attention_norm`` / ``blk.N.post_ffw_norm`` (llama.cpp's
+#: names for a norm AFTER a sub-block: the output of attention and of the
+#: feed-forward is normed before it joins the stream), and the exit gate's
+#: F32 ``ut_exit_gate.weight`` (1, dim) and ``ut_exit_gate.bias`` (1).
+#: Keys: the dense block's; ``ut_steps`` (``total_ut_steps``),
+#: ``early_exit_threshold`` (under 1.0 refused by name:
+#: models/config.py), ``attention.key_length`` (a head's width).  Q and K
+#: rotate on halves.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
-                        "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash")
+                        "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash",
+                        "ouro")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):
@@ -217,7 +231,7 @@ SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
 #: NORM mode).  ``olmoe`` could not be permuted: its QK-norm weight spans
 #: the whole projection.
 NEOX_ROPE_ARCHITECTURES = ("olmoe", "evabyte", "minicpm-sala",
-                           "exaone-moe", "lfm2moe")
+                           "exaone-moe", "lfm2moe", "ouro")
 
 
 def align_up(n: int, alignment: int) -> int:

@@ -104,6 +104,9 @@ class CacheKind:
     note_slice: Callable = lambda counts, cfg, tokens: None
     #: (live rows at the chunk's end) -> a ``decode_chunk`` span's attributes
     decode_span_attrs: Callable = lambda pos: {}
+    #: (cfg) -> attributes that EVERY traced ``prefill`` and ``decode_chunk``
+    #: span of the kind carries (the ring: a looped stack's passes)
+    span_attrs: Callable = lambda cfg: {}
     #: (cfg) -> the architecture a refusal names, where the kind serves
     #: more than ``arch`` (the latent ring: ``longcat-flash`` too)
     arch_for: Callable | None = None

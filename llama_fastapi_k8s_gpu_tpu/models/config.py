@@ -208,6 +208,27 @@ class ModelConfig:
     # ring whose rows hold several narrow heads side by side is read as
     # heads of the row's width, at the narrow heads' scale (models/lfm2.py)
     attn_scale: float = 0.0
+    # Layers run several times (``ouro``; models/llama.py ``forward``): the
+    # SAME ``n_layers`` layers run ``ut_steps`` passes a token, the final
+    # norm after every pass, and pass ``t`` of layer ``l`` keeps its keys
+    # and values in a cache leaf of its own (``t * n_layers + l``), so the
+    # ring has ``cache_leaves`` leaves and the weights ``n_layers`` rows.
+    # ``sandwich_norm``: a second RMSNorm AFTER each sub-block, before its
+    # output joins the stream (``post_attention_norm`` / ``post_ffw_norm``).
+    # ``exit_threshold``: the cumulative exit mass at which a token would
+    # leave the loop; 1.0 (every token runs every pass) is the only one
+    # served (``from_gguf`` refuses another by name).
+    ut_steps: int = 1
+    sandwich_norm: bool = False
+    exit_threshold: float = 1.0
+
+    @property
+    def cache_leaves(self) -> int:
+        """Leaves (rows of the stacked cache's leading axis) a sequence's
+        ring holds: one an attention sub-layer and pass.  What everything
+        that sizes, shards, pages, reports or counts a cache asks, never
+        ``n_layers``."""
+        return self.n_layers * self.attn_sublayers * self.ut_steps
 
     @property
     def sm_scale(self) -> float:
@@ -352,6 +373,8 @@ class ModelConfig:
             mla = _exaone_moe_fields(h, n_heads, window)
         if arch == "longcat-flash":
             mla = _longcat_fields(h, n_heads, int(h("embedding_length")))
+        if arch == "ouro":
+            mla = _ouro_fields(h)
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -450,6 +473,24 @@ def _longcat_fields(h, n_heads: int, dim: int) -> dict:
         if h("attention.scale_q_lora", False) else 1.0,
         kv_latent_scale=(dim / fields["kv_lora_rank"]) ** 0.5
         if h("attention.scale_kv_lora", False) else 1.0)
+
+
+def _ouro_fields(h) -> dict:
+    """The ``ouro`` keys (gguf/constants.py) as ``ModelConfig`` fields; a
+    ValueError naming what neither engine can serve."""
+    steps = int(h("ut_steps", 1) or 1)
+    threshold = float(h("early_exit_threshold", 1.0))
+    if steps < 1:
+        raise ValueError(f"ouro: ut_steps {steps}: a token runs one pass or more")
+    if threshold < 1.0:
+        raise ValueError(
+            f"ouro: early_exit_threshold {threshold:g} is not served (1.0 "
+            "is): a token that leaves the loop early writes no keys and "
+            "values into the deeper passes' cache leaves, which every later "
+            "token's deeper passes attend to, and neither engine can serve "
+            "a leaf with holes yet (ROADMAP B-I 12)")
+    return dict(ut_steps=steps, sandwich_norm=True, exit_threshold=threshold,
+                head_width=int(h("attention.key_length", 0) or 0))
 
 
 def _routed_fields(h, arch: str) -> dict:

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 from .config import ModelConfig
-from .llama import forward, init_cache, prefill
+from .llama import forward, has_step_stats, init_cache, prefill
 
 
 def init_state(cfg: ModelConfig, cache=None, seed: int = 0) -> dict:
@@ -85,7 +85,7 @@ def generate_chunk(params, cfg: ModelConfig, state: dict, st: dict,
     def step(carry, _):
         logits, cache, *stats = forward(
             params, cfg, carry["token"][None], carry["pos"], carry["cache"],
-            with_stats=bool(cfg.n_experts))
+            with_stats=has_step_stats(cfg))
         key, sub = jax.random.split(carry["key"])
         token = sample_chain(logits, carry["window"], sub, st, top_k=top_k)
         window = carry["window"].at[carry["wpos"] % PENALTY_WINDOW].set(token)

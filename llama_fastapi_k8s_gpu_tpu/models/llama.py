@@ -103,7 +103,9 @@ def cache_nbytes(cfg: ModelConfig) -> int:
 
 
 def _init_ring(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
-    """KV ring, HEAD-MAJOR: (L, n_kv, n_ctx, hd).  Head-major is the layout
+    """KV ring, HEAD-MAJOR: (L, n_kv, n_ctx, hd), L = ``cfg.cache_leaves``
+    (a leaf a layer, and a layer AND pass where layers run several times:
+    ``cfg.ut_steps``).  Head-major is the layout
     every attention consumer reads (XLA decode scores, the flash kernel's
     per-head blocks, ring chunks), so readers slice it directly; the
     sequence-major alternative forced a full-ring transpose per layer per
@@ -114,7 +116,7 @@ def _init_ring(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
 
     ``cfg.kv_dtype == "int8"``: the quantized layout of docs/KV_CACHE.md,
     int8 ``k_q``/``v_q`` plus f32 scales ``k_s``/``v_s`` (L, n_kv, n_ctx)."""
-    shape = (cfg.n_layers, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
+    shape = (cfg.cache_leaves, cfg.n_kv_heads, cfg.n_ctx, cfg.head_dim)
     if cfg.kv_dtype == "int8":
         sshape = shape[:-1]
         return {
@@ -131,7 +133,7 @@ def _init_ring(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
 def _ring_nbytes(cfg: ModelConfig) -> int:
     per_tok_head = cfg.head_dim * (1 if cfg.kv_dtype == "int8" else 2) \
         + (4 if cfg.kv_dtype == "int8" else 0)
-    return 2 * cfg.n_layers * cfg.n_kv_heads * cfg.n_ctx * per_tok_head
+    return 2 * cfg.cache_leaves * cfg.n_kv_heads * cfg.n_ctx * per_tok_head
 
 
 def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
@@ -398,6 +400,50 @@ def expert_stats_len(cfg: ModelConfig) -> int:
     return 3 + cfg.n_held + bool(cfg.n_zero_experts)
 
 
+def layer_passes(cfg: ModelConfig) -> int:
+    """Layer applications a token takes: the bodies :func:`forward`'s one
+    loop runs (its trip count where layers run several times) and what the
+    engines' ``layer_passes_total`` counts by."""
+    return cfg.n_layers * cfg.ut_steps
+
+
+def has_step_stats(cfg: ModelConfig) -> bool:
+    """Whether a decode step's ``forward`` is asked ``with_stats`` (the
+    decode programs of models/generate.py and parallel/batched.py): a
+    routed block's counters, or the exit masses of a looped stack."""
+    return bool(cfg.n_experts) or cfg.ut_steps > 1
+
+
+def step_stats_zeros(cfg: ModelConfig, n_steps: int) -> jax.Array:
+    """A chunk's rows of step statistics at 0: ``expert_stats_len`` int32
+    counters a step, or ``ut_steps`` float32 exit masses."""
+    if cfg.ut_steps > 1:
+        return jnp.zeros((n_steps, cfg.ut_steps), jnp.float32)
+    return jnp.zeros((n_steps, expert_stats_len(cfg)), jnp.int32)
+
+
+def lanes_step_stats(cfg: ModelConfig, stats: jax.Array, alive) -> jax.Array:
+    """One step's statistics of B lanes ``vmap``ped side by side, as one
+    row: a routed block's counters are of the step and the same in every
+    lane; exit masses are a lane's own and summed over the lanes ``alive``
+    ((B,) bool or None: all), whose tokens are the ones decoded."""
+    if cfg.ut_steps == 1:
+        return stats[0]
+    if alive is not None:
+        stats = jnp.where(alive[:, None], stats, 0.0)
+    return jnp.sum(stats, axis=0)
+
+
+def exit_mass(lams: jax.Array) -> jax.Array:
+    """The exit rule's mass a pass from the gate's outputs ``lams`` (T,):
+    ``p_t = lam_t prod_{j<t} (1 - lam_j)``, the last pass takes what is
+    left.  Sums to 1; a token leaves at the first pass whose cumulative
+    mass reaches ``cfg.exit_threshold`` (at 1.0: the last)."""
+    stay = jnp.concatenate([jnp.ones(1, lams.dtype),
+                            jnp.cumprod(1.0 - lams[:-1])])
+    return stay * jnp.concatenate([lams[:-1], jnp.ones(1, lams.dtype)])
+
+
 def _kernel_decode(q, cache, i, pos, live, cfg: ModelConfig, dtype,
                    k_new=None, v_new=None):
     """A decode step's attention through the decode kernel
@@ -476,10 +522,15 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
     return ctx
 
 
-def _layer(h, layers, i, cache, positions, pos_offset,
+def _layer(h, layers, w, c, cache, positions, pos_offset,
            cfg: ModelConfig, live=None, kv_bound=None):
-    """One transformer block over S tokens against layer ``i`` of the
-    stacked weights. ``cache``: the FULL stacked cache pytree, head-major
+    """One transformer block over S tokens against row ``w`` of the
+    stacked weights and leaf ``c`` of the cache: the same index wherever a
+    layer of the file is a layer of the step, ``c = pass * n_layers + w``
+    where layers run several times (``cfg.ut_steps``: a pass has its own
+    keys and values, the weights have not).  ``cfg.sandwich_norm``: each
+    sub-block's output passes a second RMSNorm before it joins the stream.
+    ``cache``: the FULL stacked cache pytree, head-major
     (L, n_kv, n_ctx, hd) value leaves (+ (L, n_kv, n_ctx) scale leaves
     under ``kv_dtype=int8``).  Returns (h, cache, routed): None for the
     dense feed-forward, else (rows each expert took (E,) int32, the
@@ -496,7 +547,7 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     per-layer copy of every fused quantized plane before its pallas_call
     (+6.3 ms/token measured on 8B v5e decode, tools/decode_breakdown.py).
     The cache is updated the same way: only the S new token slots of layer
-    ``i`` are written (``dynamic_update_slice`` at (i, pos, 0, 0)); carrying
+    ``c`` are written (``dynamic_update_slice`` at (c, pos, 0, 0)); carrying
     per-layer caches through ``lax.scan`` xs/ys instead restacks the whole
     ring every step — ~256 MB/token at n_ctx 1024, ~2 GB at 8192."""
     S = h.shape[0]
@@ -506,10 +557,10 @@ def _layer(h, layers, i, cache, positions, pos_offset,
     def lin(x, name):
         # the projection's own name rides in the HLO's op_name metadata
         with jax.named_scope(name):
-            return linear_at(x, layers[name], i)
+            return linear_at(x, layers[name], w)
 
     def at_layer(leaf):
-        return jax.lax.dynamic_index_in_dim(leaf, i, axis=0, keepdims=False)
+        return jax.lax.dynamic_index_in_dim(leaf, c, axis=0, keepdims=False)
 
     def ring_write(leaf, new, at):
         with jax.named_scope("kv_write"):
@@ -517,14 +568,22 @@ def _layer(h, layers, i, cache, positions, pos_offset,
 
     def normed(x, name):
         # a float32 residual stream feeds the matmuls bf16 all the same
-        xn = rms_norm(x, layers[name][i], cfg.rms_eps)
+        xn = rms_norm(x, layers[name][w], cfg.rms_eps)
         return xn.astype(jnp.bfloat16) if cfg.fp32_residual else xn
+
+    def joins(x, name):
+        # what a sub-block adds to the stream: itself, or (sandwich norm)
+        # its RMSNorm under the scope of the norm's name
+        if not cfg.sandwich_norm:
+            return x
+        with jax.named_scope(name):
+            return rms_norm(x, layers[name][w], cfg.rms_eps)
 
     hn = normed(h, "attn_norm")
     q, k = lin(hn, "wq"), lin(hn, "wk")
     if cfg.qk_norm:   # over the whole projection, before heads and RoPE
-        q = rms_norm(q, layers["attn_q_norm"][i], cfg.rms_eps)
-        k = rms_norm(k, layers["attn_k_norm"][i], cfg.rms_eps)
+        q = rms_norm(q, layers["attn_q_norm"][w], cfg.rms_eps)
+        k = rms_norm(k, layers["attn_k_norm"][w], cfg.rms_eps)
     q = q.reshape(S, cfg.n_heads, hd)
     k = k.reshape(S, n_kv, hd)
     v = lin(hn, "wv").reshape(S, n_kv, hd)
@@ -536,8 +595,8 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         # the other cache kind: its write, its attention and its window
         # close are one step (models/eva.py)
         ctx, cache = eva.attend(
-            q, k, v, cache, i, positions, kv_bound,
-            layers["eva_phi"][i], layers["eva_mu"][i], cfg, hn.dtype)
+            q, k, v, cache, c, positions, kv_bound,
+            layers["eva_phi"][w], layers["eva_mu"][w], cfg, hn.dtype)
     elif quant:
         # quantize ONLY the S new tokens' head-major slab (kvquant.py: int8
         # values + per-head per-token f32 scales), then write both planes
@@ -546,10 +605,10 @@ def _layer(h, layers, i, cache, positions, pos_offset,
         kq, ks = quantize_kv(k.transpose(1, 0, 2))     # (n_kv, S, hd)
         vq, vs = quantize_kv(v.transpose(1, 0, 2))
         cache = {
-            "k_q": ring_write(cache["k_q"], kq, (i, 0, pos_offset, 0)),
-            "v_q": ring_write(cache["v_q"], vq, (i, 0, pos_offset, 0)),
-            "k_s": ring_write(cache["k_s"], ks, (i, 0, pos_offset)),
-            "v_s": ring_write(cache["v_s"], vs, (i, 0, pos_offset)),
+            "k_q": ring_write(cache["k_q"], kq, (c, 0, pos_offset, 0)),
+            "v_q": ring_write(cache["v_q"], vq, (c, 0, pos_offset, 0)),
+            "k_s": ring_write(cache["k_s"], ks, (c, 0, pos_offset)),
+            "v_s": ring_write(cache["v_s"], vs, (c, 0, pos_offset)),
         }
         ck, cv = at_layer(cache["k_q"]), at_layer(cache["v_q"])
         cks, cvs = at_layer(cache["k_s"]), at_layer(cache["v_s"])
@@ -562,35 +621,35 @@ def _layer(h, layers, i, cache, positions, pos_offset,
             # the decode kernel stores the step's row itself, into the
             # block it reads anyway, and nothing for a lane that holds no
             # request: no update of the lanes' stacked leaf beside it
-            ctx, cache = _kernel_decode(q, cache, i, pos_offset, live, cfg,
+            ctx, cache = _kernel_decode(q, cache, c, pos_offset, live, cfg,
                                         h.dtype, kh[:, 0], vh[:, 0])
         else:
             cache = {
-                "k": ring_write(cache["k"], kh, (i, 0, pos_offset, 0)),
-                "v": ring_write(cache["v"], vh, (i, 0, pos_offset, 0)),
+                "k": ring_write(cache["k"], kh, (c, 0, pos_offset, 0)),
+                "v": ring_write(cache["v"], vh, (c, 0, pos_offset, 0)),
             }
             ck, cv = at_layer(cache["k"]), at_layer(cache["v"])
 
     if ctx is None:     # a ring, written above: attend by impl and length
-        ctx = _ring_attention(q, ck, cv, cks, cvs, cache, i, positions,
+        ctx = _ring_attention(q, ck, cv, cks, cvs, cache, c, positions,
                               pos_offset, kv_bound, live, cfg, h.dtype)
-    h = h + lin(ctx, "wo")
+    h = h + joins(lin(ctx, "wo"), "post_attn_norm")
 
     hn = normed(h, "ffn_norm")
     if cfg.n_experts:
         from ..ops.pallas.experts import routed_experts
 
         with jax.named_scope("router"):
-            picks, weights = route(hn, layers["w_router"][i], cfg)
+            picks, weights = route(hn, layers["w_router"][w], cfg)
         if live is not None:
             picks = jnp.where(live, picks, cfg.n_experts)
         with jax.named_scope("experts"):
             out, count = routed_experts(
                 hn, picks, weights, layers["w_gate_exps"],
-                layers["w_up_exps"], layers["w_down_exps"], i)
+                layers["w_up_exps"], layers["w_down_exps"], w)
         return h + out, cache, (count, picks)
     gated = jax.nn.silu(lin(hn, "w_gate").astype(jnp.float32)).astype(hn.dtype)
-    h = h + lin(gated * lin(hn, "w_up"), "w_down")
+    h = h + joins(lin(gated * lin(hn, "w_up"), "w_down"), "post_ffn_norm")
     return h, cache, None
 
 
@@ -613,7 +672,10 @@ def forward(
     ``return_all``.  ``live``: see :func:`_layer`.  Of a routed block,
     ``with_stats`` appends the counter vector of :func:`expert_stats_len`
     and ``with_picks`` the routers' picks (L, S, k) int32 (what the
-    comparison with the reference counts mismatches on).  ``kv_bound``
+    comparison with the reference counts mismatches on); of a stack whose
+    layers run several times (``cfg.ut_steps`` > 1) ``with_stats`` appends
+    the exit gate's mass a pass (:func:`exit_mass`, (ut_steps,) float32) of
+    the row at ``last_idx``, zeros where ``live`` is False.  ``kv_bound``
     (scalar int32, a decode step only): the ring slot the XLA loop of a
     decode step's attention reads up to (:func:`decode_attention`),
     default this sequence's own position; lanes ``vmap``ped over one step
@@ -653,9 +715,44 @@ def forward(
     # scan's ys-restack rewrites the entire ring every call (~256
     # MB/token at n_ctx 1024, ~2 GB at 8192 — measured as most of the
     # 8k decode gap)
+    loop = cfg.ut_steps > 1
+    gate = loop and with_stats    # the exit gate is computed where it is read
+
+    def pass_end(h, t, lams=None):
+        """What ends pass ``t``: the FINAL norm (its output feeds the next
+        pass, and the head after the last), then the exit gate on the
+        normed rows into ``lams`` (T, S), where that is carried."""
+        with jax.named_scope("pass_norm"):
+            h = rms_norm(h, params["out_norm"], cfg.rms_eps)
+        if lams is None:
+            return (h,)
+        with jax.named_scope("exit_gate"):
+            lam = jax.nn.sigmoid(jnp.einsum(
+                "sd,d->s", h.astype(jnp.float32), params["exit_gate"]["w"],
+                precision=jax.lax.Precision.HIGHEST)
+                + params["exit_gate"]["b"])
+        return h, jax.lax.dynamic_update_slice(lams, lam[None], (t, 0))
+
+    def loop_body(i, carry):
+        # ONE body for every (pass, layer) pair: weights at i % n_layers,
+        # the cache leaf at i; the pass's end behind a conditional, so that
+        # the other n_layers - 1 bodies of a pass pay nothing for it
+        c = jnp.int32(i)
+        w = c % cfg.n_layers
+        with jax.named_scope("ut_pass"):
+            h, cache, _ = _layer(
+                carry[0], params["layers"], w, c, carry[1],
+                positions, pos_offset, cfg, live, kv_bound)
+        h, *lams = jax.lax.cond(
+            w == cfg.n_layers - 1,
+            lambda h, *lams: pass_end(h, c // cfg.n_layers, *lams),
+            lambda h, *lams: (h, *lams), h, *carry[2:])
+        return (h, cache, *lams)
+
     def body(i, carry):
+        at = jnp.int32(i)       # a layer of the file is a leaf of the cache
         h, cache, out = _layer(
-            carry[0], params["layers"], jnp.int32(i), carry[1],
+            carry[0], params["layers"], at, at, carry[1],
             positions, pos_offset, cfg, live, kv_bound)
         if out is None:
             return h, cache
@@ -667,15 +764,29 @@ def forward(
         return h, cache, stats, jax.lax.dynamic_update_slice(
             carry[3], picks[None], (i, 0, 0))
 
-    h, new_cache, *routed = jax.lax.fori_loop(
-        0, cfg.n_layers, body, (h, cache, *routed))
+    if loop:
+        if cfg.n_experts:
+            raise ValueError("layers that run several times (ut_steps "
+                             f"{cfg.ut_steps}) have the dense feed-forward")
+        lams = [jnp.zeros((cfg.ut_steps, S), jnp.float32)] if gate else []
+        h, new_cache, *lams = jax.lax.fori_loop(
+            0, layer_passes(cfg), loop_body, (h, cache, *lams))
+        if gate:
+            at = jnp.int32(S - 1) if last_idx is None else last_idx
+            mass = exit_mass(jax.lax.dynamic_slice_in_dim(
+                lams[0], at, 1, axis=1)[:, 0])
+            routed = [mass if live is None else jnp.where(live, mass, 0.0)]
+    else:
+        h, new_cache, *routed = jax.lax.fori_loop(
+            0, cfg.n_layers, body, (h, cache, *routed))
 
     out_w = params["output"]
     tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))
                  if want)
 
     def head(x):
-        hn = rms_norm(x, params["out_norm"], cfg.rms_eps)
+        # (a looped stack's rows left their last pass through the final norm)
+        hn = x if loop else rms_norm(x, params["out_norm"], cfg.rms_eps)
         with jax.named_scope("head"):
             if cfg.fp32_residual and "w" in out_w:
                 # float32 logits: bf16 inputs, the sums and the result f32
@@ -755,12 +866,30 @@ def note_ring_decode(counts: dict, cfg: ModelConfig, wanted: list,
     bound = None if block else max(dispatched, default=0)
     if block and kind.kernel_writes:
         counts["rows_written"] += len(dispatched) * n_steps \
-            * cfg.n_attn_sublayers      # a leaf a layer but on a latent ring
+            * cfg.cache_leaves      # a row a leaf: a layer, sub-layer, pass
     for p in wanted:
         steps = n_steps if until is None else min(n_steps, max(until - p, 0))
         read, lv = decode_chunk_slots(p, steps, cfg.n_ctx, bound, block)
         counts["read"] += read
         counts["live"] += lv
+
+
+def loop_attrs(cfg: ModelConfig) -> dict:
+    """What a looped stack (``cfg.ut_steps`` > 1) puts on its traced
+    ``prefill`` and ``decode_chunk`` spans: the passes, and the layer
+    applications a token takes; nothing on any other."""
+    if cfg.ut_steps == 1:
+        return {}
+    return {"ut_steps": cfg.ut_steps, "layer_passes": layer_passes(cfg)}
+
+
+def _engine_health(cfg: ModelConfig) -> dict:
+    """/health ``engine.loop`` of a looped stack; no key on any other."""
+    if cfg.ut_steps == 1:
+        return {}
+    return {"loop": {"ut_steps": cfg.ut_steps, "layers": cfg.n_layers,
+                     "cache_leaves": cfg.cache_leaves,
+                     "exit_threshold": cfg.exit_threshold}}
 
 
 CACHE = CacheKind(
@@ -770,4 +899,5 @@ CACHE = CacheKind(
     # the ring is what every feature was built on: it refuses nothing
     supports=dict.fromkeys(("int8", "paged", "tp", "sp", "cycle"), True),
     rolls_back=True, always_slices=False,
-    decode_kernel_block=ring_kernel_block, note_decode=note_ring_decode)
+    decode_kernel_block=ring_kernel_block, note_decode=note_ring_decode,
+    span_attrs=loop_attrs, engine_health=_engine_health)

@@ -161,11 +161,11 @@ def leaf_width(cfg: ModelConfig) -> int:
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     # a leaf an attention SUB-layer (one a layer but in ``longcat-flash``)
     return {"lat": jnp.zeros(
-        (cfg.n_attn_sublayers, 1, cfg.n_ctx, leaf_width(cfg)), dtype)}
+        (cfg.cache_leaves, 1, cfg.n_ctx, leaf_width(cfg)), dtype)}
 
 
 def cache_nbytes(cfg: ModelConfig) -> int:
-    return cfg.n_attn_sublayers * cfg.n_ctx * leaf_width(cfg) * 2
+    return cfg.cache_leaves * cfg.n_ctx * leaf_width(cfg) * 2
 
 
 def prefill_positions_read(slices, cfg: ModelConfig) -> int:
@@ -537,9 +537,9 @@ def _health(cfg: ModelConfig, engine) -> dict:
     return {
         "kind": LATENT_RING,
         "latent": cfg.kv_lora_rank, "rotated_key": cfg.qk_rope_dim,
-        "bytes_per_position": 2 * cfg.n_attn_sublayers * lat_width(cfg),
+        "bytes_per_position": 2 * cfg.cache_leaves * lat_width(cfg),
         "bytes_per_position_laid_out":
-            2 * cfg.n_attn_sublayers * leaf_width(cfg),
+            2 * cfg.cache_leaves * leaf_width(cfg),
         "read": "absorbed, blocks of %d" % LATENT_BLOCK,
         "dense_layers": cfg.n_dense_layers,
         "routed_layers": n_moe_layers(cfg),

@@ -544,6 +544,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         if cfg.eva_window:
             layer["eva_phi"] = norm(p + "attn_eva_phi.weight")
             layer["eva_mu"] = norm(p + "attn_eva_mu.weight")
+        if cfg.sandwich_norm:   # a norm after each sub-block too
+            layer["post_attn_norm"] = norm(p + "post_attention_norm.weight")
+            layer["post_ffn_norm"] = norm(p + "post_ffw_norm.weight")
         if cfg.n_experts:
             layer["w_router"] = norm(p + "ffn_gate_inp.weight")
             for key in ("gate", "up", "down"):
@@ -580,6 +583,11 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         # loads can't cross-report
         phases_out.update(prep=(t_prep, t_head), head=(t_head, t_stack),
                           stack=(t_stack, t_end))
+    # a looped stack's exit gate: one F32 row and its bias
+    gate = {"exit_gate": {
+        "w": norm("ut_exit_gate.weight").reshape(cfg.dim),
+        "b": norm("ut_exit_gate.bias").reshape(())}} \
+        if cfg.ut_steps > 1 else {}
     return {
         "tok_emb": emb,
         "layers": stacked,
@@ -587,6 +595,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         "out_norm": norm("output_norm.weight" if "output_norm.weight"
                          in gf.tensors else "token_embd_norm.weight"),
         "output": output,
+        **gate,
     }
 
 
@@ -630,15 +639,23 @@ def synth_params(cfg: ModelConfig, fmt: str = "bf16", seed: int = 0,
             for name in ("eva_phi", "eva_mu"):
                 layers[-1][name] = jnp.asarray(rng.standard_normal(
                     (cfg.n_heads, cfg.head_dim), dtype=np.float32))
+        if cfg.sandwich_norm:
+            for name in ("post_attn_norm", "post_ffn_norm"):
+                layers[-1][name] = jnp.ones(cfg.dim, jnp.float32)
     emb = jnp.asarray(
         rng.standard_normal((cfg.vocab_size, cfg.dim), dtype=np.float32) * scale,
         dtype=jnp.bfloat16,
     )
     output = {"w": emb} if cfg.tie_embeddings \
         else lin(cfg.vocab_size * cfg.n_pred_heads, cfg.dim)
+    gate = {"exit_gate": {
+        "w": jnp.asarray(rng.standard_normal(cfg.dim, dtype=np.float32)
+                         * scale),
+        "b": jnp.zeros((), jnp.float32)}} if cfg.ut_steps > 1 else {}
     return {
         "tok_emb": emb,
         "layers": _stack(layers),
         "out_norm": jnp.ones(cfg.dim, jnp.float32),
         "output": output,
+        **gate,
     }

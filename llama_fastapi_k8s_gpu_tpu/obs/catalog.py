@@ -398,6 +398,31 @@ METRICS: dict[str, Metric] = _register(
            "engine.ring_write = xla: int8 rings, meshes, the CPU, a state "
            "+ ring cache); host arithmetic at the chunk's harvest, nothing "
            "fetched"),
+    # -- layer applications (models/llama.py layer_passes; a ring) ----------
+    Metric("layer_passes_total", GAUGE,
+           "layer applications the programs ran, by phase: decode = lanes "
+           "whose rows were wanted x the chunk's steps x the bodies of "
+           "forward's one loop (n_layers, x ut_steps where layers run "
+           "several times: 192 at 48 x 4), prefill = tokens of the "
+           "dispatched slices x the same; cumulative, host arithmetic at "
+           "the chunk's harvest and the slice's dispatch, nothing fetched; "
+           "exported by a file whose cache is the ring",
+           labels=("phase",)),
+    Metric("decode_lane_steps_total", GAUGE,
+           "decode steps summed over the lanes whose rows were wanted (a "
+           "serial engine: its one sequence), cumulative: what "
+           "layer_passes_total{phase=\"decode\"} is divided by"),
+    Metric("ut_exit_mass_total", GAUGE,
+           "a looped stack's exit gate (ut_steps > 1): the exit rule's mass "
+           "p_t = lam_t prod_{j<t}(1 - lam_j) of pass t (the last pass "
+           "takes what is left), summed over the decoded tokens of the "
+           "lanes alive in their step; the passes' sum is the tokens "
+           "decoded.  Computed in the decode programs (forward with_stats) "
+           "and folded at a scrape from the chunks that have finished "
+           "(engine/expert_counters.py ExitMass); at the served threshold "
+           "1.0 it moves no token: what an exit rule under 1.0 would have "
+           "had to work with",
+           labels=("pass",)),
     # -- how a prompt was cut into prefill slices (engine/slices.py) --------
     Metric("prefill_slice_tokens_total", GAUGE,
            "prompt tokens prefilled (padding included), cumulative, by the "

@@ -24,7 +24,8 @@ from ..models.cache import cache_of
 from ..models.config import ModelConfig
 from ..models.generate import chunk_out
 from ..models.llama import (  # noqa: F401  (``live_bound``: the tests' name)
-    expert_stats_len, forward, init_cache, live_bound, prefill)
+    forward, has_step_stats, init_cache, lanes_step_stats, live_bound,
+    prefill, step_stats_zeros)
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 
@@ -95,7 +96,7 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
         def single(token, pos, cache, window, wpos, key):
             logits, cache, *stats = forward(
                 params, cfg, token[None], pos, cache,
-                with_stats=bool(cfg.n_experts), kv_bound=bound)
+                with_stats=has_step_stats(cfg), kv_bound=bound)
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)
@@ -108,7 +109,8 @@ def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
         new_carry = {"cache": cache, "pos": pos, "token": tok,
                      "window": window, "wpos": wpos, "key": key}
         # the counters are of the step, the same in every lane
-        return new_carry, (tok, *(s[0] for s in stats))
+        return new_carry, (tok, *(lanes_step_stats(cfg, s, None)
+                                  for s in stats))
 
     state, ys = jax.lax.scan(one_step, state, None, length=n_steps)
     return state, chunk_out(*ys)
@@ -190,7 +192,7 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
         def single(token, pos, cache, window, wpos, key, st, live):
             logits, cache, *stats = forward(
                 params, cfg, token[None], pos, cache, live=live,
-                with_stats=bool(cfg.n_experts), kv_bound=bound)
+                with_stats=has_step_stats(cfg), kv_bound=bound)
             key, sub = jax.random.split(key)
             tok = sample_chain(logits, window, sub, st, top_k=top_k)
             window = window.at[wpos % PENALTY_WINDOW].set(tok)
@@ -203,11 +205,13 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
         new_carry = {"cache": cache, "pos": pos, "token": tok,
                      "window": window, "wpos": wpos, "key": key}
         alive = alive_of(left)
-        # the counters are of the step, the same in every lane
+        # the counters are of the step, the same in every lane (a looped
+        # stack's exit masses: of the lanes alive in it)
         return (i + 1, new_carry,
                 jnp.where(alive, left_after(tok, left - 1, stop_ids), left),
                 toks.at[i].set(jnp.where(alive, tok, -1)),
-                *(r.at[i].set(s[0]) for r, s in zip(rows, stats)))
+                *(r.at[i].set(lanes_step_stats(cfg, s, alive))
+                  for r, s in zip(rows, stats)))
 
     def more(loop):
         return (loop[0] < n_steps) & jnp.any(alive_of(loop[2]))
@@ -216,8 +220,7 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     # and not a cond around each step of a scan: a conditional that
     # returns the cache from two branches is where a copy would appear
     # (a step not run leaves its row of tokens pads, of counters zeros)
-    rows = [jnp.zeros((n_steps, expert_stats_len(cfg)), jnp.int32)] \
-        if cfg.n_experts else []
+    rows = [step_stats_zeros(cfg, n_steps)] if has_step_stats(cfg) else []
     _, state, left, toks, *rows = jax.lax.while_loop(
         more, one_step,
         (jnp.int32(0), state, left,

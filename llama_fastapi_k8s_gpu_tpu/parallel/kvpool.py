@@ -213,6 +213,17 @@ class _Lease:
         self.page_ids = page_ids
 
 
+def page_geometry(cfg: ModelConfig, page_tokens: int) -> tuple:
+    """((page shape, dtype name), ...) of one page of ``page_tokens``
+    positions, a leaf of the cache each, in leaf order: (L, n_kv, T[, hd])
+    with L the cache's leaves (a layer, and a layer AND pass where layers
+    run several times).  From shapes: nothing is allocated."""
+    T = int(page_tokens)
+    return tuple(
+        (s.shape[:2] + (T,) + s.shape[3:], str(jnp.dtype(s.dtype)))
+        for s in jax.tree.leaves(jax.eval_shape(lambda: init_cache(cfg))))
+
+
 class KVPool:
     """The process-wide paged KV arena + radix prefix index.
 
@@ -259,20 +270,19 @@ class KVPool:
         self.spill_pages = max(0, int(spill_pages))
         self._sink_host = sink_host
         spec = jax.eval_shape(lambda: init_cache(cfg))
-        #: the paged arena: one leaf per cache leaf, page-major
-        #: (n_pages, L, n_kv, T[, hd]) — allocated once, updated in place
-        #: (the copy jits donate it)
-        self.arena = jax.tree.map(
-            lambda s: jnp.zeros((self.n_pages,) + s.shape[:2]
-                                + (T,) + s.shape[3:], s.dtype), spec)
-        self.page_nbytes = sum(
-            int(np.prod(s.shape[:2] + (T,) + s.shape[3:]))
-            * jnp.dtype(s.dtype).itemsize for s in jax.tree.leaves(spec))
         #: per-page-leaf geometry fingerprint: what another ModelConfig
         #: must reproduce to share this arena (see :meth:`compatible`)
-        self._page_spec = tuple(
-            (s.shape[:2] + (T,) + s.shape[3:], str(jnp.dtype(s.dtype)))
-            for s in jax.tree.leaves(spec))
+        self._page_spec = page_geometry(cfg, T)
+        #: the paged arena: one leaf per cache leaf, page-major
+        #: (n_pages, L, n_kv, T[, hd]), L the cache's leaves
+        #: (``cfg.cache_leaves``) — allocated once, updated in place (the
+        #: copy jits donate it)
+        self.arena = jax.tree.unflatten(jax.tree.structure(spec), [
+            jnp.zeros((self.n_pages,) + shape, dtype)
+            for shape, dtype in self._page_spec])
+        self.page_nbytes = sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for shape, dtype in self._page_spec)
         self._lock = threading.Lock()
         self._free: list[int] = list(range(self.n_pages))
         self._page_refs: dict[int, int] = {}
@@ -346,12 +356,7 @@ class KVPool:
         (docs/MULTIMODEL.md)."""
         if page_tokens is not None and int(page_tokens) != self.page_tokens:
             return False
-        T = self.page_tokens
-        spec = jax.eval_shape(lambda: init_cache(cfg))
-        theirs = tuple(
-            (s.shape[:2] + (T,) + s.shape[3:], str(jnp.dtype(s.dtype)))
-            for s in jax.tree.leaves(spec))
-        return theirs == self._page_spec
+        return page_geometry(cfg, self.page_tokens) == self._page_spec
 
     def page_spec(self) -> tuple:
         """The per-leaf page geometry fingerprint ((shape, dtype_str), ...)

@@ -130,6 +130,10 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
         "layers": layer_shard,
         "out_norm": _ns(mesh, None),
         "output": out_shard,
+        # a looped stack's exit gate (models/llama.py): whole on every chip
+        **({"exit_gate": jax.tree.map(lambda _: _ns(mesh),
+                                      params["exit_gate"])}
+           if "exit_gate" in params else {}),
     }
 
 

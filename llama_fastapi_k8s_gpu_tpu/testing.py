@@ -190,6 +190,86 @@ def write_tiny_olmoe_gguf(path: str, cfg: ModelConfig = TINY_OLMOE_CFG,
     return cfg
 
 
+#: a tiny ``ouro`` file (layers that run several times; models/llama.py):
+#: 2 layers x 3 passes (no 2 x 2 symmetry between the weight index and the
+#: cache leaf), multi-head as published, sandwich norms, the exit gate
+TINY_OURO_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=2, n_heads=4, n_kv_heads=4,
+    ffn_dim=512, n_ctx=256, rope_theta=1000000.0, rms_eps=1e-6,
+    rope_neox=True, ut_steps=3, sandwich_norm=True,
+)
+
+#: llama.cpp's Q4_K_M mix on an ``ouro`` file, as the benchmark writes it
+OURO_Q4KM_MIX = {
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K, "output": GGMLType.Q6_K,
+}
+
+
+def write_tiny_ouro_gguf(path: str, cfg: ModelConfig = TINY_OURO_CFG,
+                         seed: int = 0, mix: dict | None = None,
+                         exit_threshold: float | None = 1.0) -> ModelConfig:
+    """Write a random-weight ``ouro`` GGUF (the dense block run
+    ``cfg.ut_steps`` passes a token: ``post_attention_norm`` /
+    ``post_ffw_norm``, the F32 exit gate and its bias) with the byte-level
+    tokenizer of :func:`write_tiny_llama_gguf`.  ``mix`` maps tensor names
+    to ggml types (default :data:`OURO_Q4KM_MIX`; embeddings F16, norms and
+    the gate F32).  The norm gains are spread about one (0.6 to 1.4), each
+    norm's its own, so that a norm left out, or another layer's taken in its
+    place, moves the logits; Q/K rows are 1.5 times the others' and the
+    embeddings of unit variance, so that attention looks somewhere and the
+    stream carries the token (tests/test_dense_reference.py's file).
+    ``exit_threshold``: the file's key (None:
+    absent, which reads as 1.0)."""
+    tokens, types = byte_vocab_with_specials()
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens)})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**OURO_Q4KM_MIX, **(mix or {})}
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-ouro-test",
+                          arch="ouro")
+    w.add_metadata("ouro.ut_steps", cfg.ut_steps)
+    if exit_threshold is not None:
+        w.add_metadata("ouro.early_exit_threshold", float(exit_threshold))
+    w.add_metadata("ouro.attention.key_length", cfg.head_dim)
+    kv_dim = cfg.n_kv_heads * cfg.head_dim
+    F, D = cfg.ffn_dim, cfg.dim
+
+    def t(name, shape, gtype, mul=1.0):
+        w.add_tensor(name, rng.standard_normal(shape).astype(np.float32)
+                     * scale * mul, gtype)
+
+    def norm(name):
+        w.add_tensor(name, rng.uniform(0.6, 1.4, D).astype(np.float32),
+                     GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), GGMLType.F16, D ** 0.5)
+    for i in range(cfg.n_layers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm.weight")
+        t(p + "attn_q.weight", (D, D), mix["attn_q"], 1.5)
+        t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"], 1.5)
+        t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+        t(p + "attn_output.weight", (D, D), mix["attn_output"])
+        norm(p + "post_attention_norm.weight")
+        norm(p + "ffn_norm.weight")
+        t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+        t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+        t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+        norm(p + "post_ffw_norm.weight")
+    norm("output_norm.weight")
+    t("output.weight", (cfg.vocab_size, D), mix["output"])
+    # a gate whose outputs lie well inside (0, 1) and differ by pass
+    t("ut_exit_gate.weight", (1, D), GGMLType.F32, 2.0)
+    w.add_tensor("ut_exit_gate.bias", np.array([-0.5], np.float32),
+                 GGMLType.F32)
+    w.write()
+    return cfg
+
+
 #: a tiny ``deepseek2`` file that keeps every ratio of the published block
 #: (models/mla.py): 3 groups of 4 experts, 2 groups used, top-3, one shared
 #: expert, 1 leading dense layer + 2 routed, d_nope / d_rope / d_v distinct,

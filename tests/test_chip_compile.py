@@ -557,6 +557,134 @@ def test_decode_step_leaves_the_ring_to_the_kernel(one_chip, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
 
+def _ouro(one_chip):
+    """Ouro-2.6B at its published geometry (48 layers x 4 passes, 16 MHA
+    heads of 128, SwiGLU 5632, vocabulary 49152), ``n_ctx`` 1280: (cfg,
+    params with int8 linears as ``_int8_params`` has them, place)."""
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(vocab_size=49152, dim=2048, n_layers=48, n_heads=16,
+                      n_kv_heads=16, ffn_dim=5632, n_ctx=1280, rope_theta=1e6,
+                      rms_eps=1e-6, rope_neox=True, ut_steps=4,
+                      sandwich_norm=True, attn_impl="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = _int8_params(cfg)
+    params["layers"].update(post_attn_norm=S(48, 2048, dtype=f32),
+                            post_ffn_norm=S(48, 2048, dtype=f32))
+    params["exit_gate"] = {"w": S(2048, dtype=f32), "b": S(dtype=f32)}
+    return cfg, place(params), place
+
+
+@pytest.mark.parametrize("name,lanes", [("ouro-serial", 0),
+                                        ("ouro-4lane", 4)])
+def test_looped_stack_compiles_with_one_body_and_no_ring_sized_copy(
+        one_chip, monkeypatch, name, lanes):
+    """The decode chunk and the 256-row prefill slice of a stack whose 48
+    layers run 4 passes (models/llama.py ``forward``'s one loop of 192
+    bodies) compile for the chip as a chip serves them: ONE layer body (one
+    decode-kernel call, whatever the passes) on a ring of 192 leaves, the
+    pass's end behind one conditional, no operation but the kernel's call
+    that takes or gives a ring, and the exit masses in the chunk's result."""
+    import re
+    from collections import Counter
+
+    import llama_fastapi_k8s_gpu_tpu.ops.pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import cache_nbytes, init_cache
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg, params, place = _ouro(one_chip)
+    assert cfg.cache_leaves == 192 and cache_nbytes(cfg) == 1280 * 1536 * 1024
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    # the chunk hands the host its tokens AND the exit masses of its steps
+    out = jax.tree.leaves(lowered.out_info)
+    assert any(o.shape == (4,) and o.dtype == f32 for o in out), out
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ring = re.compile(r"bf16\[(\d+,)*192,16,1280,128\]")
+    op = re.compile(r"^\s*(ROOT )?%\S+ = .*? ([\w-]+)\(")
+    ops = Counter(op.match(ln).group(2) for ln in text.splitlines()
+                  if ring.search(ln) and op.match(ln))
+    assert ops["custom-call"] == 1, ops
+    assert set(ops) <= {"custom-call", "parameter", "tuple",
+                        "get-tuple-element", "while", "bitcast",
+                        "conditional"}, ops
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_attention_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+    if not lanes:       # the admission's slice into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        sliced = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(256, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile()
+        assert sliced.as_text().count("tpu_custom_call") == 1  # flash, once
+        assert "flash_attention" in sliced.as_text()
+        assert sliced.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_a_stack_whose_layers_run_once_has_nothing_of_the_loop():
+    """A ``mistral``-shaped configuration (``ut_steps`` 1) lowers a decode
+    step with none of the loop's scopes, no arithmetic on the layer counter
+    (the weights' row and the cache's leaf are the counter itself) and no
+    conditional; the same widths with ``ut_steps`` 2 have all of them.
+    Lowered, not compiled: no chip is described."""
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+    from llama_fastapi_k8s_gpu_tpu.models.llama import forward, init_cache
+    from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
+
+    scopes = ("ut_pass", "pass_norm", "exit_gate", "post_attn_norm",
+              "post_ffn_norm")
+    texts = {}
+    for steps in (1, 2):
+        cfg = ModelConfig(vocab_size=512, dim=256, n_layers=4, n_heads=4,
+                          n_kv_heads=2, ffn_dim=512, n_ctx=128,
+                          rope_theta=1e6, ut_steps=steps,
+                          sandwich_norm=steps > 1)
+        params = jax.eval_shape(lambda cfg=cfg: synth_params(cfg))
+        texts[steps] = jax.jit(
+            lambda p, t, pos, c, cfg=cfg: forward(
+                p, cfg, t, pos, c, with_stats=steps > 1)).lower(
+            params, S(1, dtype=i32), S(dtype=i32),
+            jax.eval_shape(lambda cfg=cfg: init_cache(cfg))
+        ).as_text(debug_info=True)
+    once, looped = texts[1], texts[2]
+    for scope in scopes:
+        assert scope not in once and scope in looped, scope
+    # (a step's block arithmetic on the POSITION has a remainder and a
+    # division of its own, the same in both: the loop adds one of each on
+    # the counter, and the one conditional)
+    count = {op: (once.count(op), looped.count(op)) for op in (
+        "stablehlo.remainder", "stablehlo.divide", "stablehlo.case")}
+    print(count)
+    assert count["stablehlo.case"] == (0, 1), count
+    assert count["stablehlo.remainder"][1] == \
+        count["stablehlo.remainder"][0] + 1, count
+    assert "stablehlo.if" not in once
+
+
 # BENCHMARK.json's evabyte configuration at its published widths, n_ctx
 # 16384: (name, lanes)
 @pytest.mark.parametrize("name,lanes", [("evabyte-serial", 0),

@@ -253,9 +253,9 @@ def test_the_residual_stream_is_float32(ref, tmp_path, tokens):
             jnp.float32 if c.fp32_residual else jnp.bfloat16)
         h0, cache = h, init_cache(c)
         for i in range(c.n_layers):
-            h, cache, _ = jax.jit(_layer, static_argnums=(6,))(
-                h, params["layers"], jnp.int32(i), cache, jnp.arange(64),
-                jnp.int32(0), c)
+            h, cache, _ = jax.jit(_layer, static_argnums=(7,))(
+                h, params["layers"], jnp.int32(i), jnp.int32(i), cache,
+                jnp.arange(64), jnp.int32(0), c)
         return np.asarray(h.astype(jnp.float32) - h0.astype(jnp.float32))
 
     assert rel(stream(cfg), added) < LIMIT

@@ -337,14 +337,36 @@ def test_continuous_wave_consults_ledger_and_annotates(ledger, model_path):
 # layer 4: acceptance — two-model paged reconciliation through the server
 # ---------------------------------------------------------------------------
 
+def _settled_truth(ledger, patience_s: float = 5.0) -> dict:
+    """The process-wide ground truth once it has stopped moving.  It counts
+    EVERY live array of the process, and under ``--dist load`` a worker runs
+    this test behind whatever files it was handed: an engine of theirs that
+    is still shutting down (a scheduler thread that holds its lanes' state
+    for a moment longer) frees tens of MB a moment later, which read as
+    NEGATIVE growth of this test's registry (PR 56: "the process grew
+    -51559544", on the parent too).  Two equal readings 0.2 s apart."""
+    import time
+
+    deadline = time.time() + patience_s
+    gc.collect()
+    truth = ledger.ground_truth()
+    while time.time() < deadline:
+        time.sleep(0.2)
+        gc.collect()
+        again = ledger.ground_truth()
+        if again["bytes"] == truth["bytes"]:
+            break
+        truth = again
+    return truth
+
+
 @pytest.mark.anyio
 async def test_two_model_paged_reconciliation_within_5pct(ledger, ggufs):
     """ISSUE 10 acceptance: CPU two-model registry, paging on — the
     /debug/memory component sum explains the registry's allocations to
     within 5% of jax.live_arrays() ground truth, and the residual line
     carries exactly the remainder (the pre-existing process bytes)."""
-    gc.collect()
-    before = ledger.ground_truth()
+    before = _settled_truth(ledger)
     assert before["source"] == "jax.live_arrays"
     pa, pb = ggufs
     specs = [ModelSpec("alpha", pa), ModelSpec("beta", pb)]

@@ -41,8 +41,8 @@ FILE_PHASES = ("gguf_open", "tokenizer", "probes", "params")
 KW = dict(n_ctx=144, decode_chunk=4, max_gen_tokens=16, prefill_buckets=(32,))
 #: the warm-up's steps as the code has them, by engine kind
 STEPS = {"serial": ["request", "buckets", "reuse_buckets"],
-         "lanes": ["lanes_round", "stream_round", "slice_shapes", "lane_copy",
-                   "drain"]}
+         "lanes": ["lanes_round", "stream_round", "slice_shapes", "drain",
+                   "lane_copy"]}
 
 
 def assert_sound(doc: dict) -> None:
