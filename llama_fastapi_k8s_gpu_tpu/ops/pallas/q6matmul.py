@@ -37,6 +37,13 @@ Layout contract (:func:`prep_q6k`):
 
 Shape requirements: ``K % 2048 == 0``, ``N % 128`` == 0 — same classes as
 the Q4_K kernel; ineligible tensors fall back to int8 (models/params.py).
+
+Two bodies build that plane.  The stacked calls (a layer's ``w_down`` /
+``wv``) and the grouped expert calls run :func:`_q6k_matmul_kernel`, the
+float form above.  The ONE unstacked call, the vocabulary head's
+(:func:`_q6k_2d_raw`), runs :func:`_q6k_head_kernel`: the same plane bit for
+bit from integer operations on the packed bytes, under a tiling of its own
+(a wide N tile, all of K a grid step, so the activations are fetched once).
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from ...gguf.constants import GGML_BLOCK_SIZES, GGMLType, QK_K
 from ...obs.devtime import register_program
@@ -58,6 +66,8 @@ from .qmatmul import (
     _pick_tn,
     kernel_name,
     MANYROW_MAX,
+    MANYROW_TN,
+    MANYROW_VMEM,
     plain_pallas_call,
     _q4k_accum,
     q4k_compatible,
@@ -66,6 +76,7 @@ from .qmatmul import (
     stacked_pallas_call,
     stacked_partitioned,
     TK,
+    TM,
     tn_prefs,
     _tn_prefs_for,
 )
@@ -404,20 +415,128 @@ def _q6k_specs(B: int, TN: int):
     )
 
 
+# ---------------------------------------------------------------------------
+# the head's call: the one unstacked split-layout matmul (``ops/linear.py
+# linear`` is called by the models' ``head`` functions alone)
+# ---------------------------------------------------------------------------
+
+#: the widest N tile of the head's call, as 128-row units: the tile is the
+#: largest ``128 * d`` with ``d`` up to this that divides N (128256 = 167 x
+#: 768, 153600 = 150 x 1024).  On the chip at 153600 x 6144, 16 rows, all
+#: of K a step: 1.17 / 1.14 / 1.12 ms at d = 4 / 8 / 16, and 1.62 at the
+#: stacked calls' (256, one K tile), whose 1800 grid steps cost 0.27 us each
+HEAD_TN_UNITS = 8
+#: the most weights of one grid step's block (N tile x K tiles x 2048)
+HEAD_W_BLOCK = 1024 * 4 * 2048
+#: scoped VMEM of the head's call
+HEAD_VMEM = 64 * 2 ** 20
+#: what ``/health`` ``engine.head_kernel`` calls :func:`_q6k_2d_raw` as it is
+#: built here (serving/registry.py ``head_kind``): a change of the body the
+#: unstacked call runs changes this name with it
+HEAD_KERNEL = "q6k-head"
+
+_NIB = 0x0F0F0F0F                # a nibble of each of a word's four bytes
+_CRUMB = 0x30303030              # a crumb, where ``q6 = nib | crumb << 4`` has it
+
+
+def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
+                     tiles, accumulate):
+    """:func:`_q6k_matmul_kernel`'s plane, bit for bit, at half its vector
+    operations: the packed bytes are taken apart as INTEGERS, four weight
+    rows a 32-bit word (the int8 planes bitcast in the kernel), and joined
+    to ``q6 = nib | crumb << 4`` before the scale, so that a weight pays one
+    conversion, one multiply by ``eff`` (exact: 6 bits by a bfloat16) and
+    the bfloat16 cast.  The high half's nibble keeps its bias of -8 (the
+    byte holds ``hi - 8``), whose +8 stays in the correction columns.
+
+    ``tiles`` K tiles a grid step: xpa (tiles, B, TKA6), which is the whole
+    of it, fetched once a call, where the step holds all of K; q4 (TN,
+    tiles * TK/2), q2 (TN, tiles * TK/4) int8; sm (tiles, TN, 128)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    Q = TK // 4
+
+    def signed(t, flip):
+        # a byte ``16 c + u``, u = (hi - 8) mod 16, to the int8 ``16 c + hi
+        # - 8``: u ^ 8 = hi, then - 8 without a borrow between the bytes
+        # (``flip``: bits to turn on the way, in the same operation)
+        return ((t ^ (0x08080808 | flip)) + 0x78787878) ^ -0x7F7F7F80
+
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    part = None
+    for j in range(tiles):
+        w4 = pltpu.bitcast(q4_ref[:, j * 2 * Q:(j + 1) * 2 * Q], jnp.int32)
+        w2 = pltpu.bitcast(q2_ref[:, j * Q:(j + 1) * Q], jnp.int32)
+        lo, hi = w4 & _NIB, (w4 >> 4) & _NIB          # hi: (hi - 8) mod 16
+        # (the crumb byte is stored less 128: its top bit, the fourth
+        # crumb's high one, arrives turned)
+        q6 = (lo[:, :Q] | ((w2 << 4) & _CRUMB),       # columns [0, 512)
+              lo[:, Q:] | ((w2 << 2) & _CRUMB),       # [512, 1024)
+              signed(hi[:, :Q] | (w2 & _CRUMB), 0),   # [1024, 1536)
+              signed(hi[:, Q:] | ((w2 >> 2) & _CRUMB), 0x20202020))
+        sm = sm_ref[j]                                # (TN, 128): eff = d·sc
+        eff = _lane_repeat(sm, Q // 128, interpret)
+        corr = jnp.concatenate([sm * -32.0, sm * 8.0],
+                               axis=1).astype(jnp.bfloat16)
+        xpa = xpa_ref[j]
+        # a dot a quarter: joined into one (TN, TK) plane first, the same
+        # work takes 1.34 ms where this takes 1.14 (153600 x 6144, on the
+        # chip): the float32 sums of a K tile are taken in another order
+        # than the stacked body's one dot takes them
+        p = dot(xpa[:, TK:], corr)
+        for c, q in enumerate(q6):
+            a = (pltpu.bitcast(q, jnp.int8).astype(jnp.float32) * eff
+                 ).astype(jnp.bfloat16)               # (TN, 512)
+            p += dot(xpa[:, c * Q:(c + 1) * Q], a)
+        part = p if part is None else part + p
+    if accumulate:
+        _q4k_accum(o_ref, part)
+    else:
+        o_ref[...] = part
+
+
+def _head_tiling(N: int, B: int, kt: int, interpret: bool):
+    """(N tile, K tiles a grid step) of the head's call.  The rows of a
+    decode step or of a slice beside live lanes: the widest ``128 * d``
+    that divides N (:data:`HEAD_TN_UNITS`) and as many K tiles a step as
+    divide the call's and fit :data:`HEAD_W_BLOCK`.  A many-row call keeps
+    its own tiles, a K tile a step."""
+    if B > TM:
+        return _pick_tn(N, interpret, prefs=MANYROW_TN), 1
+    tn = next((128 * d for d in range(HEAD_TN_UNITS, 0, -1)
+               if N % (128 * d) == 0), None) or _pick_tn(N, interpret, ())
+    tiles = max(t for t in range(1, kt + 1)
+                if kt % t == 0 and (t == 1 or tn * t * TK <= HEAD_W_BLOCK))
+    return tn, tiles
+
+
 def _q6k_2d_raw(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
-                interpret: bool, variant: str = "cur") -> jax.Array:
+                interpret: bool) -> jax.Array:
+    from jax.experimental.pallas import tpu as pltpu
+
     B, KA = xpa.shape
-    K = (KA // TKA6) * TK
+    kt = KA // TKA6
     N = q4.shape[0]
-    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q6K))
-    in_specs, out_spec = _q6k_specs(B, TN)
-    return plain_pallas_call(
-        functools.partial(_q6k_matmul_kernel, interpret=interpret,
-                          variant=variant),
-        (N // TN, K // TK), in_specs, out_spec,
-        jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
-        kernel_name("q6k", B),
-    )(xpa, q4, q2, sm)
+    TN, tiles = _head_tiling(N, B, kt, interpret)
+    return pl.pallas_call(
+        functools.partial(_q6k_head_kernel, interpret=interpret,
+                          tiles=tiles, accumulate=tiles < kt),
+        grid=(N // TN, kt // tiles),
+        in_specs=[
+            pl.BlockSpec((tiles, B, TKA6), lambda n, k: (k, 0, 0)),
+            pl.BlockSpec((TN, tiles * TK // 2), lambda n, k: (n, k)),
+            pl.BlockSpec((TN, tiles * TK // 4), lambda n, k: (n, k)),
+            pl.BlockSpec((tiles, TN, 128), lambda n, k: (k, n, 0)),
+        ],
+        out_specs=pl.BlockSpec((B, TN), lambda n, k: (0, n)),
+        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
+        interpret=interpret,
+        name=kernel_name("q6k", B),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=MANYROW_VMEM if B > TM else HEAD_VMEM),
+    )(jnp.transpose(xpa.reshape(B, kt, TKA6), (1, 0, 2)), q4, q2, sm)
 
 
 def _q6k_pre_2d_raw(xpa: jax.Array, q6p: jax.Array, sm: jax.Array,
@@ -499,8 +618,8 @@ def _q6k_pre_2d_stacked_partitioned(interpret: bool):
         _q6k_pre_2d_stacked_raw, "i, b k, l n j, l t n m -> b n", interpret)
 
 
-@functools.lru_cache(maxsize=8)
-def _q6k_2d_partitioned(interpret: bool, variant: str = "cur"):
+@functools.lru_cache(maxsize=4)
+def _q6k_2d_partitioned(interpret: bool):
     """GSPMD rule mirroring the Q4_K kernel's: partition over N (and rows),
     never over K; tp-sharded weights compute locally."""
     from jax.experimental.custom_partitioning import custom_partitioning
@@ -508,7 +627,7 @@ def _q6k_2d_partitioned(interpret: bool, variant: str = "cur"):
 
     @custom_partitioning
     def fn(xpa, q4, q2, sm):
-        return _q6k_2d_raw(xpa, q4, q2, sm, interpret, variant)
+        return _q6k_2d_raw(xpa, q4, q2, sm, interpret)
 
     def partition(mesh, arg_shapes, result_shape):
         xp_s, q4_s, q2_s, sm_s = (a.sharding for a in arg_shapes)
@@ -523,7 +642,7 @@ def _q6k_2d_partitioned(interpret: bool, variant: str = "cur"):
         result_sharding = NamedSharding(mesh, P(rows, n_ax))
 
         def lower(xpa, q4, q2, sm):
-            return _q6k_2d_raw(xpa, q4, q2, sm, interpret, variant)
+            return _q6k_2d_raw(xpa, q4, q2, sm, interpret)
 
         return mesh, lower, result_sharding, arg_shardings
 
@@ -604,12 +723,9 @@ def q6k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
         fn = _q6k_pre_2d_partitioned(_interpret(interpret))
         y = batched_rows(fn, xpa, w["q6p"], w["sm6"])
     else:
-        # `pre` is a layout variant: split-layout weights (e.g. prepped
-        # before the env flip) run the split default, never a silent
-        # mislabel
-        var = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS)
-        fn = _q6k_2d_partitioned(
-            _interpret(interpret), "cur" if var == "pre" else var)
+        # the split layout's unstacked call is the head's, whatever
+        # LFKT_Q6K_KERNEL says of the stacked bodies
+        fn = _q6k_2d_partitioned(_interpret(interpret))
         y = batched_rows(fn, xpa, w["q4"], w["q2"], w["sm6"],
                          bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
@@ -617,5 +733,6 @@ def q6k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
 
 # devtime inventory (lfkt-lint PERF001): trace-inner fused-matmul builders
 # (see ops/pallas/qmatmul.py for the attribution contract)
+register_program("_q6k_2d_raw", site="ops.pallas.q6matmul")
 register_program("_q6k_2d_partitioned", site="ops.pallas.q6matmul")
 register_program("_q6k_pre_2d_partitioned", site="ops.pallas.q6matmul")

@@ -1478,13 +1478,10 @@ def create_app(engine=None, settings: Settings | None = None,
             params = getattr(eng, "params", None)
             if isinstance(params, dict) and "layers" in params:
                 from ..models.params import flat_layers
+                from ..serving.registry import linear_kind
 
-                kinds = {"qs": "q4k-fused", "q5s": "q5k-fused",
-                         "q5p": "q5k-fused-pre",
-                         "q4": "q6k-fused", "q6p": "q6k-fused-pre",
-                         "q8": "q8-fused", "q": "int8", "w": "bf16"}
                 fmt = {
-                    name: next((v for k, v in kinds.items() if k in leaf), "?")
+                    name: linear_kind(leaf)
                     for name, leaf in flat_layers(params["layers"])
                     if isinstance(leaf, dict)
                 }
@@ -1492,6 +1489,8 @@ def create_app(engine=None, settings: Settings | None = None,
                 "model": getattr(eng, "model_name", None),
                 "n_ctx": getattr(cfg, "n_ctx", None),
                 "attn_impl": getattr(cfg, "attn_impl", None),
+                # what serves the vocabulary head (``_head_kernel``)
+                "head_kernel": _head_kernel(params),
                 # who stores a decode step's K/V row in the ring: the
                 # decode kernel, or XLA (docs/KV_CACHE.md)
                 "ring_write": _ring_write(cfg),
@@ -1943,6 +1942,17 @@ def _mark_first_content(sspan, chunk) -> bool:
         return True
     sspan.event("first_content")
     return False
+
+
+def _head_kernel(params) -> str | None:
+    """``/health`` ``engine.head_kernel``: what serves the vocabulary head
+    (serving/registry.py ``head_kind``: ``q6k-head`` for a Q6_K head in the
+    split layout, any other head what ``weight_formats`` would say of it, a
+    tied or float head ``bf16``); None without parameters."""
+    from ..serving.registry import head_kind
+
+    leaf = params.get("output") if isinstance(params, dict) else None
+    return head_kind(leaf) if isinstance(leaf, dict) else None
 
 
 def _ring_write(cfg) -> str | None:

@@ -63,11 +63,28 @@ class WeightBudgetError(RuntimeError):
 
 #: weight-group leaf key -> served layout; ORDER MATTERS (specific keys
 #: before the generic "q"/"w" fallbacks) — same map /health derives its
-#: per-group weight_formats from (server/app.py)
+#: per-group weight_formats and its head_kernel from (server/app.py)
 _WEIGHT_KINDS = {"qs": "q4k-fused", "q5s": "q5k-fused",
                  "q5p": "q5k-fused-pre", "q4": "q6k-fused",
                  "q6p": "q6k-fused-pre", "q8": "q8-fused",
                  "q": "int8", "w": "bf16"}
+
+
+def linear_kind(leaf: dict) -> str:
+    """The served layout of one linear's leaf dict (ops/linear.py)."""
+    return next((v for k, v in _WEIGHT_KINDS.items() if k in leaf), "?")
+
+
+def head_kind(leaf: dict) -> str:
+    """What serves a vocabulary head's leaf: the one unstacked linear of a
+    model.  The split Q6_K layout's unstacked call has a kernel of its own,
+    named where it is built (ops/pallas/q6matmul.py ``HEAD_KERNEL``); any
+    other head is served as a layer's linear of its layout is."""
+    if "q4" in leaf:
+        from ..ops.pallas.q6matmul import HEAD_KERNEL
+
+        return HEAD_KERNEL
+    return linear_kind(leaf)
 
 
 def _quant_summary(engine) -> str | None:
@@ -80,7 +97,7 @@ def _quant_summary(engine) -> str | None:
     from ..models.params import flat_layers
 
     fmts = {
-        next((v for k, v in _WEIGHT_KINDS.items() if k in leaf), "?")
+        linear_kind(leaf)
         for _, leaf in flat_layers(params["layers"])
         if isinstance(leaf, dict)
     }

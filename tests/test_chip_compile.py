@@ -116,6 +116,9 @@ def _matmuls(fmt):
     # as it is stored, its last tile filled up to 12288 (ops/linear.py
     # padded_k)
     ("q4k", 4096, 11008), ("q6k", 12288, 4096),
+    # the widest heads served (K-EXAONE's; GigaChat's, K 7168 filled up to
+    # 8192): the head's own call at three and four K tiles a grid step
+    ("q6k", 6144, 153600), ("q6k", 8192, 128256),
 ])
 def test_fused_matmul_compiles(one_chip, fmt, k, n):
     """Unstacked and stacked, one decode row, a 512-row prefill bucket and
@@ -131,11 +134,29 @@ def test_fused_matmul_compiles(one_chip, fmt, k, n):
         txt = _compile(one_chip, lambda x, w: plain(x, w, interpret=False),
                        S(rows, k), _planes(fmt, n, k))
         assert "tpu_custom_call" in txt
-        if n == 128256:          # the head is never stacked
+        if n >= 128256:          # the head is never stacked
             continue
         txt = _compile(
             one_chip, lambda x, w, i: stacked(x, w, i, interpret=False),
             S(rows, k), _planes(fmt, n, k, layers=2), S(dtype=i32))
+        assert "tpu_custom_call" in txt
+
+
+# the fused Q6_K heads of the benchmark's configurations (k_in, n_out):
+# K-EXAONE, GigaChat (K 7168 filled up to 8192), LongCat, Mistral / SOLAR,
+# OLMoE (50304 = 131 x 384: an N tile of 384) and Ouro (one K tile)
+@pytest.mark.parametrize("k,n", [
+    (6144, 153600), (8192, 128256), (6144, 131072), (4096, 32000),
+    (2048, 50304), (2048, 49152)])
+def test_head_call_compiles_at_the_rows_of_a_lane_step(one_chip, k, n):
+    """The head's own call (ops/pallas/q6matmul.py ``_q6k_2d_raw``) under
+    its wide tiling, all of K a grid step, at the rows of a decode step of
+    16, 128 and 256 lanes: the tallest call that takes that tiling."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6k_matmul
+
+    for rows in (16, 128, 256):
+        txt = _compile(one_chip, lambda x, w: q6k_matmul(x, w, interpret=False),
+                       S(rows, k), _planes("q6k", n, k))
         assert "tpu_custom_call" in txt
 
 

@@ -728,7 +728,9 @@ def test_a_steps_costs_count_the_experts_read_and_an_identity_pick_at_no_byte(
     assert block.latent_bytes_per_step(cfg, 1, 1) == 8 * 576 * 2
 
 
-def test_the_pick_share_readers_read_the_window_and_nothing_on_a_parent():
+def test_the_pick_share_readers_read_the_window_and_nothing_on_a_parent(bench):
+    # (``bench``: the readers import ``counters`` by its bare name; alone on
+    # a worker the test found no such module)
     run = {"config": {}, "samples": _samples(
         (10, 110), (30, 1430), (1000, 20200), (100, 1700), (300, 6700))}
     assert _reader("zero_pick_share")(run) == 100.0 * 6400 / 19200

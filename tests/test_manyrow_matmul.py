@@ -45,8 +45,9 @@ def _raw(fmt, stacked, xpa, w):
         fn = Q4._q4k_2d_stacked_raw if fmt == "q4k" else Q6._q6k_2d_stacked_raw
         return fn(jnp.ones((1,), jnp.int32), xpa, *planes, interpret=True,
                   variant=var)
-    fn = Q4._q4k_2d_raw if fmt == "q4k" else Q6._q6k_2d_raw
-    return fn(xpa, *planes, True, var)
+    if fmt == "q4k":
+        return Q4._q4k_2d_raw(xpa, *planes, True, var)
+    return Q6._q6k_2d_raw(xpa, *planes, True)   # the head's call: one body
 
 
 def _xpa(fmt, x):
@@ -105,7 +106,10 @@ def test_a_taller_operand_is_cut_into_many_row_calls(weights):
 # sha256[:16] of the lowered text (the Mosaic module inside, no source
 # locations) of a call of up to 256 rows at (N 512, K 2048), for the chip:
 # taken on the parent (bb5116b) before this change.  These are the programs
-# every decode step and every slice beside live lanes runs.
+# every decode step and every slice beside live lanes runs.  The four of
+# the unstacked Q6_K call are PR 57's own: that call is the vocabulary
+# head's alone and has a body and a tiling of its own since (the stacked
+# call's text, below them, is still bb5116b's).
 PARENT_HASHES = {
     ("q4k", False, 1): "e2ad44577405e3ba",
     ("q4k", False, 8): "9f7506a561ebb922",
@@ -115,10 +119,10 @@ PARENT_HASHES = {
     ("q4k", True, 8): "4226b3421b232848",
     ("q4k", True, 128): "f92acc431fcd0825",
     ("q4k", True, 256): "0b7afd2edcefc6a2",
-    ("q6k", False, 1): "aeb0892ac9a63b95",
-    ("q6k", False, 8): "0881baa8f0f94694",
-    ("q6k", False, 128): "83c7c460aee0ea0b",
-    ("q6k", False, 256): "4260c1f20a64d50b",
+    ("q6k", False, 1): "4a49cf0c29585929",
+    ("q6k", False, 8): "8e7130d8f20644f3",
+    ("q6k", False, 128): "a248344ab47d21b4",
+    ("q6k", False, 256): "d1f9a148ac6b7372",
     ("q6k", True, 1): "4d91cd89a119fde7",
     ("q6k", True, 8): "056c8d33a44e6532",
     ("q6k", True, 128): "b30ae869bbd31100",

@@ -422,12 +422,26 @@ def test_a_few_row_call_is_its_rows_through_the_dense_kernel_bit_for_bit(
     got = np.asarray(X.grouped_matmul_few(
         fam, meta, x, jnp.asarray(row_expert), planes, 1, True, "cur"))
     xpa = X._activations(jnp.pad(x, ((0, 16 - R), (0, 0))), fam)
-    dense = {"q4k": X._q4._q4k_2d_raw, "q6k": X._q6._q6k_2d_raw}[fam.name]
+    dense = _dense_call(X, fam)
     for r, e in enumerate(row_expert):
         want = np.zeros(N, np.float32) if e == E else np.asarray(
             dense(xpa, *(p[layer, e] for p in planes), True, "cur"))[r]
         np.testing.assert_array_equal(got[r], want)
     assert np.abs(got).sum() > 0
+
+
+def _dense_call(X, fam):
+    """``fn(xpa, *planes, interpret, variant)``: the dense fused matmul whose
+    body the grouped calls of ``fam`` run.  Q6_K: the STACKED call on a
+    stack of one (the unstacked call is the head's own: another body, whose
+    float32 sums are taken in another order)."""
+    import jax.numpy as jnp
+
+    if fam.name == "q4k":
+        return X._q4._q4k_2d_raw
+    return lambda xpa, *rest: X._q6._q6k_2d_stacked_raw(
+        jnp.zeros(1, jnp.int32), xpa, *(p[None] for p in rest[:-2]),
+        *rest[-2:])
 
 
 def _rows_with_an_expert(rng, R, E, n_real, used=None):
@@ -497,7 +511,7 @@ def test_a_compacted_call_is_the_call_of_all_rows_and_the_dense_kernel(
     assert (np.abs(back).sum() > 0) == (n_real > 0)
     if f == 1:      # the dense kernel on the same row block, an expert a time
         xpa = X._activations(x, fam)
-        dense = {"q4k": X._q4._q4k_2d_raw, "q6k": X._q6._q6k_2d_raw}[fam.name]
+        dense = _dense_call(X, fam)
         for e in np.unique(row_expert[row_expert < E]):
             rows = np.flatnonzero(row_expert == e)
             np.testing.assert_array_equal(back[rows], np.asarray(dense(

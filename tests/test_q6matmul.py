@@ -171,6 +171,17 @@ def test_q6k_params_shard_over_mesh():
     assert sharded["layers"]["wq"]["q4"].shape == params["layers"]["wq"]["q4"].shape
 
 
+def _stack_of_one(wd):
+    """The stacked call on a stack of one: the ``LFKT_Q6K_KERNEL`` variants
+    are bodies of the stacked calls (the unstacked call is the head's, one
+    body whatever the knob says)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import q6k_matmul_stacked
+
+    ws = {key: v[None] for key, v in wd.items()}
+    return lambda x, interpret=True: q6k_matmul_stacked(
+        x, ws, 0, interpret=interpret)
+
+
 def test_parfloor_variant_bit_identical(monkeypatch):
     """LFKT_Q6K_KERNEL=parfloor must produce BIT-identical output: its
     independent floors compute the same exact f32 integers as the serial
@@ -178,8 +189,7 @@ def test_parfloor_variant_bit_identical(monkeypatch):
     import numpy as np
 
     from llama_fastapi_k8s_gpu_tpu.gguf.quants import quant_q6_k
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6matmul as qm
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k, q6k_matmul
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k
 
     rng = np.random.default_rng(1)
     n, k = 64, 2048
@@ -190,10 +200,11 @@ def test_parfloor_variant_bit_identical(monkeypatch):
     # between calls re-traces without any cache_clear choreography.
     # Compare cur vs parfloor EXPLICITLY so the assertion is immune to
     # which of the two bit-identical variants leads the tuple default.
+    mm = _stack_of_one(wd)
     monkeypatch.setenv("LFKT_Q6K_KERNEL", "cur")
-    a = np.asarray(q6k_matmul(x, wd, interpret=True))
+    a = np.asarray(mm(x))
     monkeypatch.setenv("LFKT_Q6K_KERNEL", "parfloor")
-    b = np.asarray(q6k_matmul(x, wd, interpret=True))
+    b = np.asarray(mm(x))
     assert np.array_equal(a, b)
 
 
@@ -203,7 +214,7 @@ def test_vbf32_variant_beats_default_accuracy(monkeypatch):
     close to the f32 dequant_ref6 oracle as the bf16-plane default, and
     inside the default's own quantization tolerance."""
     from llama_fastapi_k8s_gpu_tpu.gguf.quants import quant_q6_k
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k, q6k_matmul
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k
 
     rng = np.random.default_rng(7)
     n, k = 64, 4096
@@ -212,10 +223,11 @@ def test_vbf32_variant_beats_default_accuracy(monkeypatch):
     x = jnp.asarray(rng.standard_normal((4, k)), jnp.float32)
     ref = np.asarray(
         permute_x6(x).astype(jnp.bfloat16).astype(jnp.float32) @ dequant_ref6(wd).T)
+    mm = _stack_of_one(wd)
     monkeypatch.delenv("LFKT_Q6K_KERNEL", raising=False)
-    cur = np.asarray(q6k_matmul(x, wd, interpret=True))
+    cur = np.asarray(mm(x))
     monkeypatch.setenv("LFKT_Q6K_KERNEL", "vbf32")
-    got = np.asarray(q6k_matmul(x, wd, interpret=True))
+    got = np.asarray(mm(x))
     err_cur = np.abs(cur - ref).max()
     err_vb = np.abs(got - ref).max()
     assert err_vb <= err_cur * 1.05, (err_vb, err_cur)
@@ -309,3 +321,166 @@ def test_pre_layout_shards_on_mesh(monkeypatch):
     # the ill-fitting head leaf must come back REPLICATED, not half-sharded
     head_spec = sharded["output"]["q6p"].sharding.spec
     assert all(a is None for a in head_spec), head_spec
+
+
+# ---------------------------------------------------------------------------
+# the head's call (PR 57): the unstacked split-layout matmul has a body and
+# a tiling of its own
+# ---------------------------------------------------------------------------
+
+def _random_planes(rng, n, k):
+    """Kernel-layout planes over every byte value (the kernels' arithmetic
+    is total: any int8 pair is some Q6_K weight, any bf16 a scale)."""
+    return {
+        "q4": jnp.asarray(rng.integers(-128, 128, (n, k // 2)), jnp.int8),
+        "q2": jnp.asarray(rng.integers(-128, 128, (n, k // 4)), jnp.int8),
+        "sm6": jnp.asarray(rng.standard_normal((k // 2048, n, 128)) * 1e-2,
+                           jnp.bfloat16)}
+
+
+def _stacked_body_call(xpa, w):
+    """The unstacked call as it was before PR 57: the stacked calls' body
+    (``_q6k_matmul_kernel``) under their tiling, on a stack of one."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6matmul as Q6
+
+    return Q6._q6k_2d_stacked_raw(
+        jnp.zeros(1, jnp.int32), xpa, w["q4"][None], w["q2"][None],
+        w["sm6"][None], interpret=True)
+
+
+@pytest.mark.parametrize("n", [256, 1280])    # 1280: 512 does not divide it
+@pytest.mark.parametrize("k", [2048, 6144, 8192])
+@pytest.mark.parametrize("rows", [1, 4, 16])
+def test_head_call_matches_oracle_and_the_stacked_bodys_plane(rows, k, n):
+    """The head's call against ``dequant_ref6`` at today's tolerance, and its
+    dequantized plane against the stacked body's BIT FOR BIT: a one-hot
+    activation row (no correction columns) reads one plane column out of
+    either kernel exactly, whatever order the float32 sums are taken in."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6matmul as Q6
+
+    rng = np.random.default_rng(rows * 7 + k + n)
+    w = _random_planes(rng, n, k)
+    x = jnp.asarray(rng.standard_normal((rows, k)), jnp.float32)
+    ref = (permute_x6(x).astype(jnp.bfloat16).astype(jnp.float32)
+           @ dequant_ref6(w).T)
+    got = q6k_matmul(x, w, interpret=True)
+    assert got.shape == (rows, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-2,
+                               atol=2e-2 * float(jnp.abs(ref).max()))
+
+    # 256 columns of every K tile, 64 of each quarter (the four quarters of
+    # a tile are taken apart by four different integer forms)
+    assert Q6._head_tiling(n, 256, k // 2048, True)[0] == {256: 256,
+                                                           1280: 640}[n]
+    for t in range(k // 2048):
+        cols = np.concatenate([q * 512 + rng.permutation(512)[:64]
+                               for q in range(4)])
+        onehot = np.zeros((256, k // 2048, Q6.TKA6), np.float32)
+        onehot[np.arange(256), t, cols] = 1.0
+        xpa = jnp.asarray(onehot.reshape(256, -1), jnp.bfloat16)
+        new = np.asarray(Q6._q6k_2d_raw(xpa, w["q4"], w["q2"], w["sm6"], True))
+        old = np.asarray(_stacked_body_call(xpa, w))
+        assert np.array_equal(new.view(np.uint32), old.view(np.uint32)), t
+        plane = np.asarray(dequant_ref6(w))[:, t * 2048 + cols].T
+        bias = np.asarray(w["sm6"][t].astype(jnp.float32))[
+            :, cols % 128].T * np.where(cols < 1024, 32.0, 24.0)[:, None]
+        # (the plane holds q6 - 0 in the low half and q6 - 8 in the high
+        # one; the -32 and +8 ride the correction columns, zero here)
+        np.testing.assert_allclose(new, plane + bias, rtol=1e-2, atol=1e-6)
+
+
+def test_head_call_ignores_the_variant_knob(monkeypatch):
+    """The unstacked split-layout call is one program whatever
+    ``LFKT_Q6K_KERNEL`` says of the stacked bodies."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6matmul as Q6
+
+    w = _random_planes(np.random.default_rng(5), 64, 2048)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((2, 2048)),
+                    jnp.bfloat16)
+    outs = []
+    for var in Q6.Q6K_VARIANTS:
+        monkeypatch.setenv("LFKT_Q6K_KERNEL", var)
+        outs.append(np.asarray(q6k_matmul(x, w, interpret=True)))
+    assert all(np.array_equal(o, outs[0]) for o in outs)
+    assert Q6.Q6K_VARIANTS == ("cur", "parfloor", "vbf32", "pre")
+
+
+# jax.make_jaxpr's text (kernel bodies in full) of the calls that keep the
+# body ``_q6k_matmul_kernel``, hashed on the parent (e1bd971) with
+# tools/traced_program_hashes.py: the stacked Q6_K call (a decode row, a
+# lane step's rows, a slice) and the routed layers, whose Q6_K down calls
+# are the grouped bodies (few rows, the lanes' vmap, a compacted call, many
+# rows, interpret mode)
+PARENT_TRACED = {
+    "stacked.q6k.4096x4096.r1.tpu": "fb7d69116df06667",
+    "stacked.q6k.14336x4096.r8.tpu": "b49126df7b5baa31",
+    "stacked.q6k.4096x4096.r512.tpu": "6d535e3614120b0a",
+    "routed.olmoe.t8.tpu": "681794971179b5d7",
+    "routed.lfm2.t16.tpu.vmap": "47d36bafe2293224",
+    "routed.longcat.t16.tpu": "572eab122c93cff3",
+    "routed.gigachat.t256.tpu": "e74f9f118bd93412",
+    "routed.kexaone.t1.interp": "b0eef6d7a0beffe8",
+}
+
+
+def test_stacked_and_grouped_q6k_calls_trace_to_the_text_they_had():
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "traced_program_hashes", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "traced_program_hashes.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.hashes(only=PARENT_TRACED.__contains__) == PARENT_TRACED
+
+
+@pytest.mark.parametrize("leaf,name", [
+    ("q6k", "q6k-head"), ("tied", "bf16"), ("int8", "int8"),
+    ("q4k", "q4k-fused"), (None, None)])
+def test_health_names_the_heads_kernel(leaf, name):
+    """``/health`` ``engine.head_kernel``: ``q6k-head`` for a Q6_K head in
+    the split layout, else what ``weight_formats`` would say of the head
+    (a tied embedding: ``bf16``)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.linear import (
+        make_linear_int8, make_linear_q4k)
+    from llama_fastapi_k8s_gpu_tpu.server.app import _head_kernel
+
+    wf = _rand_weights(np.random.default_rng(9), 16, 2048)
+    emb = jnp.asarray(wf, jnp.bfloat16)
+    out = {"q6k": lambda: make_linear_q6k(wf), "tied": lambda: {"w": emb},
+           "int8": lambda: make_linear_int8(wf),
+           "q4k": lambda: make_linear_q4k(wf), None: lambda: None}[leaf]()
+    params = {"tok_emb": emb, "layers": {}, "output": out}
+    assert _head_kernel(params) == name
+    assert _head_kernel(None) is None
+
+
+@pytest.mark.anyio
+async def test_health_serves_head_kernel_beside_attn_impl():
+    """Through the served ``/health``: one new key in ``engine``, none in
+    ``weight_formats`` (the configurations' ``expect_health`` holds that
+    dict letter for letter)."""
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.engine import FakeEngine
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    wf = _rand_weights(np.random.default_rng(10), 16, 2048)
+    stacked = {k: v[None] for k, v in make_linear_q6k(wf).items()}
+    eng = FakeEngine(reply="x")
+    eng.params = {"layers": {"w_down": stacked},
+                  "output": make_linear_q6k(wf)}
+    app = create_app(engine=eng, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            got = (await client.get("/health")).json()["engine"]
+        await app.router.shutdown()
+    assert got["head_kernel"] == "q6k-head"
+    assert got["weight_formats"] == {"w_down": "q6k-fused"}
+    assert list(got)[:4] == ["model", "n_ctx", "attn_impl", "head_kernel"]

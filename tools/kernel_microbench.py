@@ -5,6 +5,11 @@ shapes, against the int8 control and the HBM-bandwidth roofline, so kernel
 restructurings can be picked on data (VERDICT r3 #2: raise Q4_K from 57% of
 roofline toward the int8 path's 85%).
 
+(The Q6_K variants are bodies of the STACKED call since PR 57: that format's
+rows go through ``linear_at`` on a stack of one.  The unstacked Q6_K call
+is the vocabulary head's, one body whatever the knob says:
+``tools/time_head_call.py`` times it.)
+
 Method: each (fmt, variant, shape, B) cell times a jitted x -> x-chained
 matvec (output reduced back into the input row so nothing hoists), double
 warm-up discarded (docs/PERF.md "Measurement hygiene"), then the mean of
@@ -100,7 +105,10 @@ def make_weight(fmt: str, wf: np.ndarray) -> dict:
     mk = {"q4k": L.make_linear_q4k, "q5k": L.make_linear_q5k,
           "q6k": L.make_linear_q6k, "q8": L.make_linear_q8,
           "int8": L.make_linear_int8}[fmt]
-    return jax.device_put(mk(wf))
+    w = mk(wf)
+    if fmt == "q6k":                # the variants' bodies: the stacked call
+        w = {k: v[None] for k, v in w.items()}
+    return jax.device_put(w)
 
 
 # the row sweep: (format, N, K) of the widest matmuls of the two dense
@@ -176,7 +184,11 @@ def main() -> None:
     from llama_fastapi_k8s_gpu_tpu.utils.jaxcache import setup_compile_cache
 
     setup_compile_cache()
-    from llama_fastapi_k8s_gpu_tpu.ops.linear import linear
+    from llama_fastapi_k8s_gpu_tpu.ops.linear import linear as linear_, linear_at
+
+    def linear(x, w):
+        # (a Q6_K weight arrives as a stack of one: see make_weight)
+        return linear_at(x, w, 0) if "sm6" in w else linear_(x, w)
 
     dev = jax.devices()[0]
     if sys.argv[1:] == ["rows"]:

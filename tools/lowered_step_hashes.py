@@ -12,7 +12,8 @@ On the CPU the Pallas kernels lower in interpret form, as plain HLO with no
 source locations (``as_text()`` without debug info), so two trees whose
 programs are the same give the same text, whatever lines moved.  bf16 and
 int8 rings, the XLA loop and the decode kernel (``attn_impl`` ``pallas``),
-dense, routed, window + summaries.  Needs no chip; a minute a tree."""
+dense, routed, window + summaries, state + ring, conv-state + ring.  Needs
+no chip; a minute a tree."""
 
 from __future__ import annotations
 
@@ -50,7 +51,9 @@ def main(tree: str) -> int:
             ("llama", T.write_tiny_llama_gguf, "int8", 128, 32),
             ("mistral", T.write_tiny_mistral_gguf, "bf16", 128, 32),
             ("olmoe", T.write_tiny_olmoe_gguf, "q4k", 128, 32),
-            ("evabyte", T.write_tiny_evabyte_gguf, "bf16", 320, 64)):
+            ("evabyte", T.write_tiny_evabyte_gguf, "bf16", 320, 64),
+            ("sala", T.write_tiny_sala_gguf, "bf16", 256, 32),
+            ("lfm2", T.write_tiny_lfm2_gguf, "q4k", 128, 32)):
         path = os.path.join(tmp, f"{name}-{fmt}.gguf")
         writer(path)
         gf = GGUFFile(path)
@@ -58,7 +61,8 @@ def main(tree: str) -> int:
         params = load_params(gf, cfg, fmt=fmt)
         for kv, impl in (("bf16", "xla"), ("bf16", "pallas"),
                          ("int8", "xla")):
-            if name == "evabyte" and (kv, impl) != ("bf16", "xla"):
+            if name in ("evabyte", "sala", "lfm2") and (kv, impl) != (
+                    "bf16", "xla"):
                 continue
             c = dataclasses.replace(cfg, kv_dtype=kv, attn_impl=impl)
             texts = {

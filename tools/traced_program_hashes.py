@@ -26,8 +26,11 @@ import re
 import sys
 
 
-def main(tree: str, out: str) -> int:
-    sys.path.insert(0, os.path.abspath(tree))
+def hashes(tree: str | None = None, only=None) -> dict:
+    """{program: hash} of the package importable now, or of ``tree`` put
+    first on the path; ``only(key)``: the programs to trace (all)."""
+    if tree is not None:
+        sys.path.insert(0, os.path.abspath(tree))
     import jax
     import jax.numpy as jnp
 
@@ -36,7 +39,7 @@ def main(tree: str, out: str) -> int:
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
         fold_factor, padded_k, routed_experts)
 
-    assert os.path.realpath(pkg.__file__).startswith(
+    assert tree is None or os.path.realpath(pkg.__file__).startswith(
         os.path.realpath(tree)), pkg.__file__
     bf16, i8, f32, i32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
     S = jax.ShapeDtypeStruct
@@ -53,6 +56,11 @@ def main(tree: str, out: str) -> int:
                 "q2": S((*lead, n, k // 4), i8), "sm6": sm}
 
     res = {}
+
+    def put(key, thunk):
+        if only is None or only(key):
+            res[key] = thunk()
+
     dense = {"q4k": (P.q4k_matmul, P.q4k_matmul_stacked),
              "q6k": (P.q6k_matmul, P.q6k_matmul_stacked)}
     for fmt, (plain, stacked) in dense.items():
@@ -64,13 +72,13 @@ def main(tree: str, out: str) -> int:
                         continue
                     tag = f"{fmt}.{k}x{n}.r{rows}." + ("interp" if interp
                                                        else "tpu")
-                    res["dense." + tag] = traced(
+                    put("dense." + tag, lambda: traced(
                         lambda x, w: plain(x, w, interpret=interp),
-                        S((rows, k), bf16), planes(fmt, n, k))
-                    res["stacked." + tag] = traced(
+                        S((rows, k), bf16), planes(fmt, n, k)))
+                    put("stacked." + tag, lambda: traced(
                         lambda x, w, i: stacked(x, w, i, interpret=interp),
                         S((rows, k), bf16), planes(fmt, n, k, (2,)),
-                        S((), i32))
+                        S((), i32)))
     # the routed layer: (name, experts held, D, F, picks a token, tokens)
     for name, E, D, F, k, toks in (
             ("olmoe", 64, 2048, 1024, 8, (1, 8, 128, 512, 1024)),
@@ -94,15 +102,21 @@ def main(tree: str, out: str) -> int:
                                           interpret=interp)
 
                 key = f"routed.{name}.t{t}." + ("interp" if interp else "tpu")
-                res[key] = traced(layer, S((t, D), bf16), S((t, k), i32),
-                                  S((t, k), f32), *w)
+                put(key, lambda: traced(
+                    layer, S((t, D), bf16), S((t, k), i32), S((t, k), f32),
+                    *w))
                 if t in (8, 16):    # the lane engines: vmap over lanes
-                    res[key + ".vmap"] = traced(
+                    put(key + ".vmap", lambda: traced(
                         lambda x, p, wt, g, u, d, i: jax.vmap(
                             lambda a, b, c: layer(a, b, c, g, u, d, i))(
                                 x, p, wt),
                         S((t, 1, D), bf16), S((t, 1, k), i32),
-                        S((t, 1, k), f32), *w)
+                        S((t, 1, k), f32), *w))
+    return res
+
+
+def main(tree: str, out: str) -> int:
+    res = hashes(tree)
     with open(out, "w") as fh:
         json.dump(res, fh, indent=0, sort_keys=True)
     print(len(res), "programs hashed ->", out)
