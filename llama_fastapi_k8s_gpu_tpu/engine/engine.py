@@ -1183,9 +1183,10 @@ class Engine:
         """What a prompt's prefill does to the cache, by the kind: counted,
         and on the traced ``prefill`` span.  ``reused``: the prefix no slice
         computes; ``alone``: whether nobody decodes behind the slices."""
-        slices = None if pspan is None else plan_slices(
+        slices = plan_slices(
             reused, n_prompt, self.cfg.n_ctx, self._prefill_chunk,
-            self._wide_slice, alone)
+            self._wide_slice, alone) \
+            if pspan is not None or self.cache.counts_prefill else None
         attrs = self.cache.note_prefill(self.cache_counts, self.cfg,
                                         n_prompt, slices)
         if pspan is not None:

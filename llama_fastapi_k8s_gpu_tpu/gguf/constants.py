@@ -205,6 +205,21 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: (bool: the query after ``attn_q_b`` times (embedding_length /
 #: q_lora_rank)^1/2, the normed latent times (embedding_length /
 #: kv_lora_rank)^1/2).  Q and K rotate on interleaved pairs; no rope scaling.
+#: ``deepseek32`` (this repo's name for DeepSeek-V3.2's block, ``model_type
+#: deepseek_v32``: llama.cpp's, if it has one, is not known here;
+#: models/mla.py) is ``deepseek2`` with a learned INDEXER beside every
+#: layer's latent attention (DeepSeek Sparse Attention): every tensor and
+#: key of ``deepseek2`` above, and per layer ``blk.N.indexer_q_b``
+#: (indexer heads x key_length, r_q: from the SAME normed query latent as
+#: ``attn_q_b``), ``indexer_k`` (key_length, dim: ONE index key a position),
+#: ``indexer_k_norm.weight`` / ``indexer_k_norm.bias`` (a LayerNorm over the
+#: key, F32) and the F32 ``indexer_proj`` (indexer heads, dim: a signed
+#: weight a head and query).  Keys: ``attention.indexer.head_count``,
+#: ``attention.indexer.key_length``, ``attention.indexer.top_k`` (all three
+#: needed: a file without them is refused by name), and
+#: ``attention.indexer.layer_norm_epsilon`` (absent: 1e-6).  The main
+#: attention's Q and K rotate on interleaved pairs as ``deepseek2``'s; the
+#: indexer's first ``rope.dimension_count`` columns rotate on HALVES.
 #: ``ouro`` (this repo's name for the Ouro looped language models:
 #: llama.cpp's, if it has one, is not known here; models/llama.py) is the
 #: dense block whose ``block_count`` layers run ``<arch>.ut_steps`` passes a
@@ -222,7 +237,7 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
                         "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash",
-                        "ouro")
+                        "ouro", "deepseek32")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):

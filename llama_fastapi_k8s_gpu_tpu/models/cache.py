@@ -99,6 +99,9 @@ class CacheKind:
     #: attributes, counting what the prefill does; ``slices``: its plan
     #: [(offset, tokens)], None for an untraced request
     note_prefill: Callable = lambda counts, cfg, n_prompt, slices: {}
+    #: ``note_prefill`` COUNTS (not only names what a traced span shows), so
+    #: it is given the plan of an untraced request's slices too
+    counts_prefill: bool = False
     #: (counts, cfg, tokens): one dispatched prefill program of ``tokens``
     #: rows into the counters
     note_slice: Callable = lambda counts, cfg, tokens: None
@@ -110,6 +113,10 @@ class CacheKind:
     #: (cfg) -> the architecture a refusal names, where the kind serves
     #: more than ``arch`` (the latent ring: ``longcat-flash`` too)
     arch_for: Callable | None = None
+    #: (cfg) -> the object that answers for THIS configuration, where files
+    #: of the kind differ in what their cache holds (the latent ring: a
+    #: second leaf of index keys, with counters of its own); None: this one
+    variant: Callable | None = None
 
     def arch_of(self, cfg: ModelConfig) -> str:
         return self.arch_for(cfg) if self.arch_for else self.arch
@@ -136,5 +143,6 @@ def cache_of(cfg: ModelConfig) -> CacheKind:
     """The kind of cache a sequence of this configuration holds.  The
     module is imported on first use: the kinds' modules import
     models/llama.py, which calls this."""
-    return importlib.import_module(
+    kind = importlib.import_module(
         "." + _MODULES[cfg.cache_kind], __package__).CACHE
+    return kind.variant(cfg) if kind.variant else kind

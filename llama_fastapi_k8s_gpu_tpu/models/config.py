@@ -142,6 +142,17 @@ class ModelConfig:
     # ``orig_ctx``; ``attn_mscale`` is what the softmax scale is multiplied
     # by ((0.1 * mscale_all_dim * ln factor + 1) ** 2 where > 1; the
     # cos/sin scale mscale/mscale_all_dim is 1 for every file served)
+    # A learned indexer beside the latent attention (``deepseek32``:
+    # DeepSeek Sparse Attention; 0: none): ``index_heads`` query heads of
+    # ``index_dim`` from the SAME normed query latent score ONE cached index
+    # key a position (a second leaf of the latent ring, ``idx``), and a
+    # query attends the ``index_topk`` positions of largest score alone
+    # (all of them while it has no more).  ``index_norm_eps``: the
+    # LayerNorm (weight AND bias) over the index key.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_norm_eps: float = 1e-6
     rope_yarn_factor: float = 0.0
     rope_yarn_orig_ctx: int = 0
     rope_yarn_beta_fast: float = 32.0
@@ -369,6 +380,8 @@ class ModelConfig:
                     "be the embedding length")
         if arch == "deepseek2":
             mla = _deepseek2_fields(h, n_heads)
+        if arch == "deepseek32":
+            mla = _deepseek32_fields(h, n_heads)
         if arch == "exaone-moe":
             mla = _exaone_moe_fields(h, n_heads, window)
         if arch == "longcat-flash":
@@ -445,6 +458,29 @@ def _deepseek2_fields(h, n_heads: int, arch: str = "deepseek2") -> dict:
         q_lora_rank=r_q, kv_lora_rank=r_kv, qk_nope_dim=d_qk - d_r,
         qk_rope_dim=d_r, v_head_dim=d_v, **_routed_fields(h, arch),
         **yarn)
+
+
+def _deepseek32_fields(h, n_heads: int) -> dict:
+    """The ``deepseek32`` keys (gguf/constants.py) as ``ModelConfig``
+    fields: ``deepseek2``'s, and the indexer's three; a ValueError naming
+    what the block here cannot compute."""
+    arch = "deepseek32"
+    fields = _deepseek2_fields(h, n_heads, arch)
+    heads, width, topk = (int(h(f"attention.indexer.{key}", 0) or 0)
+                          for key in ("head_count", "key_length", "top_k"))
+    if min(heads, width, topk) < 1:
+        raise ValueError(
+            f"{arch}: the file lacks <arch>.attention.indexer.head_count / "
+            "key_length / top_k: without its indexer the file is a "
+            "deepseek2 one, and says so")
+    if width < fields["qk_rope_dim"]:
+        raise ValueError(
+            f"{arch}: attention.indexer.key_length {width} is narrower than "
+            f"rope.dimension_count {fields['qk_rope_dim']}: an index key's "
+            "first columns are its rotated part")
+    return dict(fields, index_heads=heads, index_dim=width, index_topk=topk,
+                index_norm_eps=float(h("attention.indexer.layer_norm_epsilon",
+                                       1e-6)))
 
 
 def _longcat_fields(h, n_heads: int, dim: int) -> dict:

@@ -1,7 +1,9 @@
 """The fourth cache KIND, and a feed-forward kind per LAYER
 (``general.architecture = "deepseek2"``: the DeepSeek-V2/V3 family's block;
 ``cfg.kv_lora_rank``), or TWO attention sub-layers a layer with a
-shortcut-connected expert branch (``"longcat-flash"``: the last point).
+shortcut-connected expert branch (``"longcat-flash"``), or a learned INDEXER
+beside every layer's attention that picks the positions a query attends
+(``"deepseek32"``: the last point).
 
 - Latent attention (MLA).  ``c_q = RMSNorm(W_qa x)``; per head ``[q_n | q_r]
   = W_qb c_q`` (``qk_nope_dim`` + ``qk_rope_dim``); ``[c_kv | k_r] = W_kva
@@ -33,8 +35,9 @@ shortcut-connected expert branch (``"longcat-flash"``: the last point).
   :func:`kernel_block` says 0 and a prefill slice (S > 1) wherever
   :func:`slice_tile` says 0, and it is what tests hold the kernels to.  A
   prefill slice where ``cfg.latent_slice_kernel`` is set (the engine's: a
-  TPU, the probe passed) and a tile fits its rows (every slice width of
-  engine/slices.py at the published 64 heads) is, after the same XLA
+  TPU, the probe passed) and a tile fits its rows (a tile is rows of the
+  head-major query, whatever the number of heads: every slice width of
+  engine/slices.py at 64 heads and at 128) is, after the same XLA
   write, ONE Pallas kernel over the scratch leaf in place (ops/pallas/
   attention.py ``latent_attention_prefill``): query tiles of 1024 rows
   (one head's contiguous tokens, whole heads of a narrow slice) against
@@ -73,6 +76,35 @@ shortcut-connected expert branch (``"longcat-flash"``: the last point).
   a leaf an attention SUB-layer, ``2 l + s``.  Three stacks of weights:
   ``params["layers"]["attn" | "ffn"]`` at depth ``2 L``, ``["moe"]`` at
   depth ``L``; one ``fori_loop`` over the layers.
+- A ``deepseek32`` layer (``cfg.index_topk``: DeepSeek Sparse Attention) has
+  a learned INDEXER beside this attention: ``qI_h = W_Iq c_q`` (``index_
+  heads`` of ``index_dim``, from the SAME normed query latent), ``kI =
+  LayerNorm(W_Ik x)`` (weight and bias; ONE vector a position), the first
+  ``qk_rope_dim`` columns of both rotated on HALVES with the attention's
+  YaRN frequencies, ``w = W_Iw x`` times ``index_heads^-1/2 index_dim^-1/2``
+  (signed, float32), ``I(t, s) = sum_h w_h(t) relu(qI_h(t) . kI(s))``; a
+  query attends the ``min(index_topk, t + 1)`` positions of largest ``I``
+  (of equal scores the lower position) and no other.  The ring holds a
+  SECOND leaf, ``idx`` (L, 1, n_ctx, index_dim filled up to 128) bf16,
+  written where ``lat`` is written: one row a position as well, so the
+  cache rolls back, is claimed and is copied as before, by whoever maps
+  over its leaves (:data:`INDEXED`, the kind's object for such a file).
+  Per pass and layer (:func:`_indexer`): the pass's index keys are written
+  by an XLA update, :func:`index_scores` scores every query against the
+  leaf in blocks up to the read's bound (a loop in plain XLA: bf16
+  operands, float32 products, relu, weights and sums; the per-head scores
+  of a block and a group of heads at a time, never all of them),
+  :func:`select_topk` finds each row's k-th largest score by a search on
+  its bits (no sort) and hands out the selection as a mask, and the
+  attention is the read that serves S as above WITH that mask: the XLA
+  loop's ``sel``, or a bias operand of the two kernels
+  (``flash_attention_decode_latent_select``, ``flash_attention_prefill_
+  latent_select``).  The selection is a MASK on the blocks read, not a
+  gather: a decode step fetches every live latent and scores it at all
+  heads (tools/time_dsa_select.py has both on the chip; PERF.md section 6,
+  PR 58); the counters ``latents_selected_total`` / ``latents_read_total``
+  say how far the fetch is from the selection.  While a query has no more
+  than ``index_topk`` positions the layer is the dense one exactly.
 """
 
 from __future__ import annotations
@@ -89,7 +121,8 @@ from ..ops.linear import linear, linear_at
 from .cache import WHOLE, CacheKind
 from .config import LATENT_RING, ModelConfig
 from .llama import (
-    expert_stats_len, note_ring_decode, ring_step_bound, rms_norm)
+    expert_stats_len, live_bound, note_ring_decode, ring_step_bound,
+    rms_norm)
 from .routed import (  # noqa: F401  (``mla.route_grouped``: the tests' name)
     DENSE, HI, MOE, check_stacks, expert_branch, held_picks, moe_stats,
     n_moe_layers, route_grouped, swiglu)
@@ -138,7 +171,10 @@ def slice_tile(cfg: ModelConfig, S: int) -> int:
     probe passed), S > 1, whole blocks in the leaf, and a tile that fits
     the slice's ``n_heads * S`` rows."""
     if not cfg.latent_slice_kernel or S < 2 \
-            or cfg.n_ctx % min(LATENT_SLICE_BLOCK, cfg.n_ctx):
+            or cfg.n_ctx % min(LATENT_SLICE_BLOCK, cfg.n_ctx) \
+            or (cfg.index_topk and S % 16):
+        # (a selection comes to the kernel as whole bf16 tiles of the
+        # slice's rows)
         return 0
     from ..ops.pallas.attention import latent_prefill_tile
 
@@ -158,14 +194,28 @@ def leaf_width(cfg: ModelConfig) -> int:
     return -(-lat_width(cfg) // 128) * 128
 
 
+def index_leaf_width(cfg: ModelConfig) -> int:
+    """The ``idx`` leaf's last dimension (0: the file has no indexer): an
+    index key filled up to the tile's 128 lanes, as :func:`leaf_width`."""
+    return -(-cfg.index_dim // 128) * 128 if cfg.index_topk else 0
+
+
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
-    # a leaf an attention SUB-layer (one a layer but in ``longcat-flash``)
-    return {"lat": jnp.zeros(
+    # a leaf an attention SUB-layer (one a layer but in ``longcat-flash``);
+    # a ``deepseek32`` file's ring has a SECOND leaf, the index keys: one
+    # row a position too, so a prefix of both is a prefix of the sequence
+    # and whatever copies, rolls back or claims the cache maps over the two
+    cache = {"lat": jnp.zeros(
         (cfg.cache_leaves, 1, cfg.n_ctx, leaf_width(cfg)), dtype)}
+    if cfg.index_topk:
+        cache["idx"] = jnp.zeros(
+            (cfg.cache_leaves, 1, cfg.n_ctx, index_leaf_width(cfg)), dtype)
+    return cache
 
 
 def cache_nbytes(cfg: ModelConfig) -> int:
-    return cfg.cache_leaves * cfg.n_ctx * leaf_width(cfg) * 2
+    return cfg.cache_leaves * cfg.n_ctx * (
+        leaf_width(cfg) + index_leaf_width(cfg)) * 2
 
 
 def prefill_positions_read(slices, cfg: ModelConfig) -> int:
@@ -238,7 +288,8 @@ def absorb_query(q_n, w_uk):
                           ).astype(q_n.dtype).transpose(1, 0, 2)
 
 
-def latent_attention(q_full, lat, i, positions, bound, cfg: ModelConfig):
+def latent_attention(q_full, lat, i, positions, bound, cfg: ModelConfig,
+                     sel=None):
     """Causal attention of S queries over layer ``i``'s cached latents, in
     the absorbed form.  ``q_full`` (S, H, r_kv + d_r) = [q_abs | q_r];
     ``lat`` the stacked leaf (L, 1, n_ctx, r_kv + d_r), sliced in place at
@@ -249,7 +300,10 @@ def latent_attention(q_full, lat, i, positions, bound, cfg: ModelConfig):
     of LATENTS, head-major (H, S, r_kv) float32, before ``W_uv``.  A query's result
     does not depend on ``bound``: a block wholly beyond its position adds
     probabilities of exactly 0 under a rescale of exactly 1
-    (``models/llama.py decode_attention`` has the argument)."""
+    (``models/llama.py decode_attention`` has the argument).  ``sel`` (S,
+    n_ctx) bool or None: the positions each query may attend beside the
+    causal bound (:func:`select_topk`: a ``deepseek32`` file's selection,
+    applied as a MASK on the blocks read)."""
     S, H, W = q_full.shape
     r, n_ctx = cfg.kv_lora_rank, cfg.n_ctx
     T = min(LATENT_BLOCK, n_ctx)
@@ -270,6 +324,8 @@ def latent_attention(q_full, lat, i, positions, bound, cfg: ModelConfig):
         key_pos = at + jnp.arange(T)
         mask = (key_pos >= lo)[None, :] \
             & (key_pos[None, :] <= positions[:, None])   # (S, T)
+        if sel is not None:
+            mask &= jax.lax.dynamic_slice(sel, (0, at), (S, T))
         s = jnp.where(mask[None], s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
@@ -320,16 +376,170 @@ def expanded_attention(q_n, q_r, rows, w_uk, w_uv, positions,
 
 
 # ---------------------------------------------------------------------------
+# the learned indexer of a ``deepseek32`` file (DeepSeek Sparse Attention)
+# ---------------------------------------------------------------------------
+
+#: index keys a block of :func:`index_scores`' loop scores
+INDEX_BLOCK = 1024
+
+#: (head, query) rows of per-head scores a pass of that loop holds at once:
+#: the per-head scores of a slice never exist whole (64 heads x 1024 rows x
+#: 16384 keys would be 4.3 GB float32), 8192 rows x 1024 keys are 32 MB
+INDEX_ROWS = 8192
+
+#: bits of the k-th largest score that one pass of :func:`select_topk`
+#: settles (2^bits - 1 counts a pass, 32 / bits passes)
+SELECT_BITS = 4
+
+
+def rope_halves(x: jax.Array, positions: jax.Array, inv_freq) -> jax.Array:
+    """x (S, H, d): the first ``2 * len(inv_freq)`` columns rotated on
+    HALVES (column i with i + d_r / 2: the indexer's layout, not the main
+    attention's interleaved pairs), the others left as they are."""
+    n = len(inv_freq)
+    ang = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)[None]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :n], xf[..., n:2 * n]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, xf[..., 2 * n:]],
+        axis=-1).astype(x.dtype)
+
+
+def layer_norm(x, weight, bias, eps: float):
+    """LayerNorm over the last axis in float32 (mean and variance), with
+    weight AND bias: the index key's norm."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps) * weight + bias
+            ).astype(x.dtype)
+
+
+def index_scores(q_i, w, idx, i, bound, cfg: ModelConfig):
+    """The indexer's scores of S queries against layer ``i``'s cached index
+    keys: ``I(t, s) = sum_h w_h(t) relu(q_h(t) . k(s))``.  ``q_i`` (S, Hi,
+    dI) bf16, ``w`` (S, Hi) float32 (the scale folded in), ``idx`` the
+    stacked leaf (L, 1, n_ctx, dI filled up), sliced in place at (i, block);
+    ``bound`` as :func:`latent_attention`'s.  Returns (S, n_ctx) float32,
+    ``-inf`` past the last block read.  A loop in plain XLA over blocks of
+    ``INDEX_BLOCK`` keys and, within a block, over groups of heads: bf16
+    operands, float32 products, relu, weights and sum, and never more than
+    ``INDEX_ROWS`` (head, query) rows of per-head scores at once."""
+    S, Hi, dI = q_i.shape
+    n_ctx = cfg.n_ctx
+    T = min(INDEX_BLOCK, n_ctx)
+    g = Hi
+    while g > 1 and (g * S > INDEX_ROWS or Hi % g):
+        g -= 1
+    i = jnp.asarray(i, jnp.int32)
+    n_blocks = jnp.minimum((jnp.asarray(bound, jnp.int32) + T) // T,
+                           -(-n_ctx // T))
+    qh = q_i.transpose(1, 0, 2)                          # (Hi, S, dI)
+    wh = w.T.astype(jnp.float32)                         # (Hi, S)
+
+    def block(j, out):
+        at = jnp.minimum(j * T, n_ctx - T)
+        kb = jax.lax.dynamic_slice(
+            idx, (i, 0, at, 0), (1, 1, T, idx.shape[-1]))[0, 0][:, :dI]
+
+        def heads(n, acc):
+            qg = jax.lax.dynamic_slice_in_dim(qh, n * g, g, axis=0)
+            wg = jax.lax.dynamic_slice_in_dim(wh, n * g, g, axis=0)
+            s = jnp.einsum("hsd,td->hst", qg, kb,
+                           preferred_element_type=jnp.float32)
+            return acc + jnp.sum(jnp.maximum(s, 0.0) * wg[..., None], axis=0)
+
+        with jax.named_scope("dsa_index_scores"):
+            acc = jnp.zeros((S, T), jnp.float32)
+            acc = heads(0, acc) if g == Hi else jax.lax.fori_loop(
+                0, Hi // g, heads, acc)
+        return jax.lax.dynamic_update_slice(out, acc, (0, at))
+
+    return jax.lax.fori_loop(0, n_blocks, block,
+                             jnp.full((S, n_ctx), -jnp.inf, jnp.float32))
+
+
+def select_topk(scores, positions, k: int):
+    """(S, n_ctx) bool: for each query the ``min(k, t + 1)`` positions ``s
+    <= t`` of largest score (``positions`` (S,): each query's ``t``).  The
+    tie rule: of equal scores the LOWER position is taken.  No sort: the
+    k-th largest value is found by a search on the scores' bits (the
+    float32 order is an integer order after a fold of the sign), ``SELECT_
+    BITS`` bits a pass, each pass a handful of counts over the row."""
+    S, n = scores.shape
+    causal = jnp.arange(n, dtype=jnp.int32)[None, :] <= positions[:, None]
+    with jax.named_scope("dsa_select"):
+        # (+ 0.0: a negative zero is a zero)
+        bits = jax.lax.bitcast_convert_type(scores + 0.0, jnp.int32)
+        key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        u = jax.lax.bitcast_convert_type(key, jnp.uint32) \
+            ^ jnp.uint32(0x80000000)
+        u = jnp.where(causal, u, jnp.uint32(0))
+        kk = jnp.minimum(jnp.int32(k), positions.astype(jnp.int32) + 1)
+        B = SELECT_BITS
+        steps = jnp.arange(1, 1 << B, dtype=jnp.uint32)
+
+        def settle(p, thr):
+            shift = (32 - B * (p + 1)).astype(jnp.uint32)
+            cand = thr[:, None] | (steps[None, :] << shift)       # (S, 2^B-1)
+            cnt = jnp.sum(u[:, None, :] >= cand[:, :, None], axis=-1,
+                          dtype=jnp.int32)
+            # the counts fall as the candidate rises: the largest that
+            # still has k at or above it
+            c = jnp.sum(cnt >= kk[:, None], axis=-1).astype(jnp.uint32)
+            return thr | (c << shift)
+
+        thr = jax.lax.fori_loop(0, 32 // B, settle,
+                                jnp.zeros((S,), jnp.uint32))
+        above = u > thr[:, None]
+        tie = (u == thr[:, None]) & causal
+        room = kk - jnp.sum(above, axis=-1, dtype=jnp.int32)
+        return above | (tie & (jnp.cumsum(tie, axis=-1, dtype=jnp.int32)
+                               <= room[:, None]))
+
+
+def _indexer(hn, c_q, layers, i, li, cache, positions, pos_offset, cfg,
+             kv_bound, lin):
+    """A ``deepseek32`` layer's indexer for the pass's S rows: the index
+    keys written to the ``idx`` leaf, then every query scored against the
+    leaf and its positions chosen.  Returns (idx leaf, the scores (S, n_ctx)
+    float32, the selection (S, n_ctx) bool)."""
+    S = hn.shape[0]
+    Hi, dI = cfg.index_heads, cfg.index_dim
+    inv_freq = rope_inv_freq(cfg)
+    q_i = rope_halves(lin(c_q, "idx_wq_b").reshape(S, Hi, dI), positions,
+                      inv_freq)
+    k_i = layer_norm(lin(hn, "idx_wk")[:, :dI], layers["idx_k_norm"][i],
+                     layers["idx_k_norm_b"][i], cfg.index_norm_eps)
+    k_i = rope_halves(k_i[:, None], positions, inv_freq)[:, 0]
+    with jax.named_scope("dsa_index_weights"):
+        w = jnp.einsum("sd,hd->sh", hn.astype(jnp.float32),
+                       layers["idx_proj"][i], precision=HI
+                       ) * (Hi ** -0.5 * dI ** -0.5)
+    idx = cache["idx"]
+    rows = jnp.pad(k_i, ((0, 0), (0, idx.shape[-1] - dI))).astype(idx.dtype)
+    with jax.named_scope("kv_write"):
+        idx = jax.lax.dynamic_update_slice(
+            idx, rows[None, None], (li, 0, pos_offset, 0))
+    bound = pos_offset + S - 1 if kv_bound is None or S > 1 else kv_bound
+    scores = index_scores(q_i, w, idx, li, bound, cfg)
+    return idx, scores, select_topk(scores, positions, cfg.index_topk)
+
+
+# ---------------------------------------------------------------------------
 # the layers
 # ---------------------------------------------------------------------------
 
 def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
-               kv_bound):
+               kv_bound, tap=None):
     """One layer's attention branch.  ``i``: the layer's number within its
     kind's stack of weights, ``li``: its number in the whole stack (the
     cache's).  ``live`` (scalar bool or None): whether this sequence holds
     a request; the decode kernel reads and stores nothing where not.
-    Returns (h + branch, cache)."""
+    ``tap`` (``forward(with_index=True)``): the indexer's scores and the
+    selection of every leaf so far, each (leaves, S, n_ctx), handed back
+    with this layer's set in.  Returns (h + branch, cache, tap)."""
     S = h.shape[0]
     H, r, d_n, d_r = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
                       cfg.qk_rope_dim)
@@ -361,6 +571,16 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
     q_full = jnp.concatenate(
         [absorb_query(q[..., :d_n], layers["w_uk"]["w"][i]), q_r,
          jnp.zeros((S, H, fill), q_r.dtype)], axis=-1)
+    sel = None
+    if cfg.index_topk:
+        # the positions each query may attend: the index keys are written
+        # and scored BEFORE the read, whichever read serves
+        idx, scores, sel = _indexer(hn, c_q, layers, i, li, cache, positions,
+                                    pos_offset, cfg, kv_bound, lin)
+        cache = {**cache, "idx": idx}
+        if tap is not None:
+            tap = tuple(jax.lax.dynamic_update_slice(t, x[None], (li, 0, 0))
+                        for t, x in zip(tap, (scores, sel)))
     block = kernel_block(cfg) if S == 1 else 0
     if block:
         # the decode kernel: this sequence's own blocks, read in place, and
@@ -372,11 +592,12 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
                 q_full[0], cache["lat"], li, pos_offset,
                 True if live is None else live, rows[0],
                 sm_scale=attn_scale(cfg), block_k=block, v_width=r,
-                interpret=use_interpret())
-        cache, ctx = {"lat": lat}, ctx.reshape(H, 1, r)
+                interpret=use_interpret(),
+                sel=None if sel is None else sel[0])
+        cache, ctx = {**cache, "lat": lat}, ctx.reshape(H, 1, r)
     else:
         with jax.named_scope("kv_write"):
-            cache = {"lat": jax.lax.dynamic_update_slice(
+            cache = {**cache, "lat": jax.lax.dynamic_update_slice(
                 cache["lat"], rows[None, None], (li, 0, pos_offset, 0))}
         with jax.named_scope("mla_attn"):
             if slice_tile(cfg, S):
@@ -388,33 +609,44 @@ def _attention(h, layers, i, li, cache, positions, pos_offset, cfg, live,
                 ctx = latent_attention_prefill(
                     q_full.transpose(1, 0, 2), cache["lat"], li, pos_offset,
                     sm_scale=attn_scale(cfg), v_width=r,
-                    block_k=LATENT_SLICE_BLOCK, interpret=use_interpret())
+                    block_k=LATENT_SLICE_BLOCK, interpret=use_interpret(),
+                    sel=sel)
             else:
                 bound = pos_offset + S - 1 if kv_bound is None or S > 1 \
                     else kv_bound
                 ctx = latent_attention(q_full, cache["lat"], li, positions,
-                                       bound, cfg)
+                                       bound, cfg, sel)
     o = expand_values(ctx, layers["w_uv"]["w"][i], h.dtype)
-    return h + lin(o, "wo"), cache
+    return h + lin(o, "wo"), cache, tap
+
+
+def _asked(tap) -> tuple:
+    """What a layer returns after everything else: the tap, where one was
+    handed in."""
+    return () if tap is None else (tap,)
 
 
 def dense_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
-                kv_bound):
-    h, cache = _attention(h, layers, i, i, cache, positions, pos_offset, cfg,
-                          live, kv_bound)
+                kv_bound, tap=None):
+    """Returns (h, cache), then :func:`_attention`'s ``tap`` where asked."""
+    h, cache, tap = _attention(h, layers, i, i, cache, positions, pos_offset,
+                               cfg, live, kv_bound, tap)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
-    return h + swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache
+    return (h + swiglu(hn, layers, i, "w_gate", "w_up", "w_down"), cache,
+            *_asked(tap))
 
 
 def moe_layer(h, layers, i, cache, positions, pos_offset, cfg, live,
-              kv_bound):
+              kv_bound, tap=None):
     """Returns (h, cache, (rows each HELD expert took (n_held,), the
-    router's picks (S, k) over all experts, picks of live rows))."""
-    h, cache = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
-                          positions, pos_offset, cfg, live, kv_bound)
+    router's picks (S, k) over all experts, picks of live rows)), then
+    :func:`_attention`'s ``tap`` where asked."""
+    h, cache, tap = _attention(h, layers, i, cfg.n_dense_layers + i, cache,
+                               positions, pos_offset, cfg, live, kv_bound,
+                               tap)
     hn = rms_norm(h, layers["ffn_norm"][i], cfg.rms_eps)
     out, routed = expert_branch(hn, layers, i, cfg, live)
-    return h + out, cache, routed
+    return (h + out, cache, routed, *_asked(tap))
 
 
 def shortcut_layer(h, layers, l, cache, positions, pos_offset, cfg, live,
@@ -426,8 +658,8 @@ def shortcut_layer(h, layers, l, cache, positions, pos_offset, cfg, live,
     def attn(h, cache, s):
         i = 2 * l + s
         with jax.named_scope(f"attn{s}"):
-            h, cache = _attention(h, layers[ATTN], i, i, cache, positions,
-                                  pos_offset, cfg, live, kv_bound)
+            h, cache, _ = _attention(h, layers[ATTN], i, i, cache, positions,
+                                     pos_offset, cfg, live, kv_bound)
         return h, cache, rms_norm(h, layers[FFN]["ffn_norm"][i], cfg.rms_eps)
 
     def ffn(u, s):
@@ -447,14 +679,25 @@ def shortcut_layer(h, layers, l, cache, positions, pos_offset, cfg, live,
 def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
             last_idx=None, return_all: bool = False, live=None,
             with_stats: bool = False, with_picks: bool = False,
-            kv_bound=None):
+            kv_bound=None, with_index: bool = False):
     """``models/llama.py forward`` for a ``deepseek2`` file: the leading
     dense layers, then the routed ones, each kind a ``fori_loop`` over its
     own stack of weights; the cache is one leaf over all layers.
     ``with_stats`` / ``with_picks`` as there (the counter vector of
     ``llama.expert_stats_len`` is over the HELD experts; the picks are the
-    router's, over all).  ``kv_bound``: a lane step's ``live_bound``."""
+    router's, over all).  ``kv_bound``: a lane step's ``live_bound``.
+    ``with_index`` (a ``deepseek32`` file; tests and benchmarks/
+    compare_dsa.py): the indexer's scores (leaves, S, n_ctx) float32 and
+    the selection (the same, bool) every layer's queries were served with,
+    after everything else."""
     S = tokens.shape[0]
+    asked = ()
+    if with_index:
+        if not cfg.index_topk:
+            raise ValueError("with_index: the file has no indexer")
+        shape = (cfg.cache_leaves, S, cfg.n_ctx)
+        asked = ((jnp.zeros(shape, jnp.float32),
+                  jnp.zeros(shape, jnp.bool_)),)
     n_moe = n_moe_layers(cfg)
     # a ``longcat-flash`` file: no dense layer, every layer a
     # :func:`shortcut_layer` over all three stacks
@@ -466,29 +709,34 @@ def forward(params: dict, cfg: ModelConfig, tokens, pos_offset, cache: dict,
     routed_layer, routed_stacks = (shortcut_layer, params["layers"]) \
         if shortcut else (moe_layer, params["layers"][MOE])
 
+    # a carry ends in the tap, where one was asked for
     def dense_body(i, carry):
         return dense_layer(carry[0], params["layers"][DENSE], jnp.int32(i),
                            carry[1], positions, pos_offset, cfg, live,
-                           kv_bound)
+                           kv_bound, *carry[2:])
 
     def moe_body(i, carry):
-        h, cache, routed = routed_layer(
+        h, cache, routed, *tap = routed_layer(
             carry[0], routed_stacks, jnp.int32(i), carry[1], positions,
-            pos_offset, cfg, live, kv_bound)
-        return (h, cache, *moe_stats(carry[2], carry[3], i, routed))
+            pos_offset, cfg, live, kv_bound, *carry[4:])
+        return (h, cache, *moe_stats(carry[2], carry[3], i, routed), *tap)
 
-    carry = (h, cache)
+    carry = (h, cache, *asked)
     if cfg.n_dense_layers:
         carry = jax.lax.fori_loop(0, cfg.n_dense_layers, dense_body, carry)
     routed = []
     if n_moe:
         h, new_cache, *routed = jax.lax.fori_loop(0, n_moe, moe_body, (
-            *carry, jnp.zeros(expert_stats_len(cfg), jnp.int32),
-            jnp.zeros((n_moe, S, cfg.n_experts_used), jnp.int32)))
+            *carry[:2], jnp.zeros(expert_stats_len(cfg), jnp.int32),
+            jnp.zeros((n_moe, S, cfg.n_experts_used), jnp.int32),
+            *carry[2:]))
+        routed, asked = routed[:2], routed[2:]
     else:
-        h, new_cache = carry
+        h, new_cache, *asked = carry
     tail = tuple(r for r, want in zip(routed, (with_stats, with_picks))
                  if want)
+    for tap in asked:
+        tail += tuple(tap)
 
     def head(x):
         hn = rms_norm(x, params["out_norm"], cfg.rms_eps)
@@ -515,11 +763,22 @@ def _probe_kernels(cfg: ModelConfig, asked: str, attn_impl: str, probed):
         from ..ops.pallas.probe import (
             probe_latent_decode, probe_latent_prefill)
 
-        for name, probe, flag, what in (
-                ("latent_decode", probe_latent_decode, "latent_kernel",
-                 "decode steps read"),
-                ("latent_prefill", probe_latent_prefill,
-                 "latent_slice_kernel", "prefill slices read")):
+        probes = (("latent_decode", probe_latent_decode, "latent_kernel",
+                   "decode steps read"),
+                  ("latent_prefill", probe_latent_prefill,
+                   "latent_slice_kernel", "prefill slices read"))
+        if cfg.index_topk:
+            # a ``deepseek32`` file's reads take the selection: the kernels
+            # WITH the bias operand are what must compile
+            from ..ops.pallas.probe import (
+                probe_latent_decode_select, probe_latent_prefill_select)
+
+            probes = (
+                ("latent_decode_select", probe_latent_decode_select,
+                 "latent_kernel", "decode steps read"),
+                ("latent_prefill_select", probe_latent_prefill_select,
+                 "latent_slice_kernel", "prefill slices read"))
+        for name, probe, flag, what in probes:
             probed.append(name)
             err = probe()
             if err is None:
@@ -613,4 +872,95 @@ CACHE = CacheKind(
     note_decode=note_ring_decode, note_prefill=_note_prefill,
     note_slice=_note_slice,
     decode_span_attrs=lambda pos: {"cache": LATENT_RING,
-                                   "latent_positions": pos})
+                                   "latent_positions": pos},
+    variant=lambda cfg: INDEXED if cfg.index_topk else CACHE)
+
+
+# ---------------------------------------------------------------------------
+# the same kind with the index-key leaf (``deepseek32``)
+# ---------------------------------------------------------------------------
+
+def _index_sums(cfg: ModelConfig, first: int, last: int) -> tuple[int, int]:
+    """Over the queries at positions ``first`` .. ``last - 1`` of one leaf:
+    (index keys scored: every position at or below the query; latents
+    selected: ``min(index_topk, position + 1)``).  Host arithmetic."""
+    def tri(n):
+        return n * (n + 1) // 2
+
+    first, last, k = min(first, cfg.n_ctx), min(last, cfg.n_ctx), \
+        cfg.index_topk
+    selected = tri(min(last, k)) - tri(min(first, k)) \
+        + k * (max(last, k) - max(first, k))
+    return tri(last) - tri(first), selected
+
+
+def _note_indexed_decode(counts: dict, cfg: ModelConfig, wanted: list,
+                         n_steps: int, live: list | None = None) -> None:
+    """The ring's counters, and per wanted lane, step and leaf: the index
+    keys its query scored, the latents the selection chose, and the latents
+    the attention FETCHED (the blocks the read walked: the selection is a
+    mask on them)."""
+    before = counts["read"]
+    note_ring_decode(counts, cfg, wanted, n_steps, live)
+    L = cfg.cache_leaves
+    counts["read_decode"] += (counts["read"] - before) * L
+    for p in wanted:
+        scored, selected = _index_sums(cfg, p, p + n_steps)
+        counts["scored_decode"] += scored * L
+        counts["selected_decode"] += selected * L
+
+
+def _note_indexed_prefill(counts, cfg: ModelConfig, n_prompt: int,
+                          slices) -> dict:
+    attrs = _note_prefill(counts, cfg, n_prompt, slices)
+    if slices is None:
+        return attrs
+    L = cfg.cache_leaves
+    scored, selected = _index_sums(cfg, slices[0][0], n_prompt)
+    counts["scored_prefill"] += scored * L
+    counts["selected_prefill"] += selected * L
+    # every row of a slice against the blocks the slice's read walks
+    counts["read_prefill"] += L * sum(
+        min(n, max(n_prompt - off, 0)) * prefill_positions_read([(off, n)],
+                                                                cfg)
+        for off, n in slices)
+    return {**attrs, "index_keys_scored": scored,
+            "latents_selected": selected, "select": "mask"}
+
+
+def _indexed_health(cfg: ModelConfig, engine) -> dict:
+    h = _health(cfg, engine)
+    both = lat_width(cfg) + cfg.index_dim
+    laid = leaf_width(cfg) + index_leaf_width(cfg)
+    return {**h, "index_key": cfg.index_dim, "index_heads": cfg.index_heads,
+            "index_topk": cfg.index_topk,
+            "bytes_per_position": 2 * cfg.cache_leaves * both,
+            "bytes_per_position_laid_out": 2 * cfg.cache_leaves * laid,
+            "read": h["read"] + ", the selection a mask"}
+
+
+def _phased(name: str, key: str) -> dict:
+    return {f'{name}{{phase="{phase}"}}': f"{key}_{phase}"
+            for phase in ("prefill", "decode")}
+
+
+#: a ``deepseek32`` file's latent ring: TWO leaves a layer of unlike width
+#: (the latents and the index keys), both one row a position, so it rolls
+#: back, is claimed and is copied as the one-leaf ring is (every engine
+#: maps over the leaves); its decode steps always take the lanes' bound
+#: (the indexer's scores are a loop in plain XLA, whichever read serves the
+#: attention), and it counts what was scored, selected and fetched
+INDEXED = dataclasses.replace(
+    CACHE, arch="deepseek32", arch_for=None, variant=None,
+    step_bound=lambda cfg, pos, live=None: live_bound(pos, live),
+    shardings=lambda cfg: {"lat": WHOLE, "idx": WHOLE},
+    health=_indexed_health,
+    own_gauges={**CACHE.own_gauges,
+                **_phased("index_keys_scored_total", "scored"),
+                **_phased("latents_selected_total", "selected"),
+                **_phased("latents_read_total", "read")},
+    note_decode=_note_indexed_decode, note_prefill=_note_indexed_prefill,
+    counts_prefill=True,
+    decode_span_attrs=lambda pos: {
+        "cache": LATENT_RING, "latent_positions": pos, "select": "mask"},
+    span_attrs=lambda cfg: {"index_topk": cfg.index_topk})

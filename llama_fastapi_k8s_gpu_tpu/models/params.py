@@ -365,6 +365,13 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                      "w_down_sh": "ffn_down_shexp"}}
         # the latent projection's r_kv + d_r rows, filled up to a kernel's N
         kv_rows = -(-lat_width(cfg) // 128) * 128 if latent else None
+        if cfg.index_topk:
+            # a ``deepseek32`` layer's indexer: its query projection from
+            # the query latent (K = r_q, as ``attn_q_b``'s) and its ONE key
+            # projection; its LayerNorm and the heads' F32 weights below
+            attn = {**attn, "idx_wq_b": "indexer_q_b", "idx_wk": "indexer_k"}
+            norms = (*norms, ("idx_k_norm", "indexer_k_norm"),
+                     ("idx_proj", "indexer_proj"))
         out = {}
         for kind, ids in ((DENSE, range(cfg.n_dense_layers)),
                           (MOE, range(cfg.n_dense_layers, cfg.n_layers))):
@@ -389,6 +396,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     layer.update(absorbed_halves(p))
                 for key, name in norms:
                     layer[key] = norm(p + name + ".weight")
+                if cfg.index_topk:
+                    layer["idx_k_norm_b"] = norm(p + "indexer_k_norm.bias")
                 if kind == MOE:
                     layer["w_router"] = norm(p + "ffn_gate_inp.weight")
                     layer["router_bias"] = norm(p + "exp_probs_b.bias")

@@ -528,6 +528,33 @@ METRICS: dict[str, Metric] = _register(
            "latent_attention: the CPU, a failed probe, a width no tile "
            "fits); beside latent_slices_kernel_total the kernel's "
            "engagement"),
+    # -- the latent ring's index-key leaf (models/mla.py; ``deepseek32``) -----
+    Metric("index_keys_scored_total", GAUGE,
+           "cached index keys the learned indexer scored, summed over "
+           "queries and layers: every position at or below the query "
+           "(label phase: prefill = a prompt's rows from the reused prefix "
+           "on, decode = the live lanes' steps); host arithmetic from "
+           "tracked positions, nothing fetched; exported by a deepseek32 "
+           "file only, cumulative", labels=("phase",)),
+    Metric("latents_selected_total", GAUGE,
+           "cached latent rows the selection chose, summed over queries and "
+           "layers: min(index_topk, position + 1) a query (label phase); "
+           "over index_keys_scored_total = the share of the live positions "
+           "a query attends.  Like it, what the ALGORITHM prescribes at the "
+           "tracked positions (host arithmetic), not a count taken on the "
+           "device: a property of the traffic's contexts under index_topk, "
+           "so of the two shares built on it only the one over "
+           "latents_read_total can move with the program, and only when "
+           "the READ changes", labels=("phase",)),
+    Metric("latents_read_total", GAUGE,
+           "cached latent rows the attention FETCHED for those queries, "
+           "whichever read served (label phase): the selection is a MASK "
+           "on the blocks the read walks, so a decode step fetches its "
+           "lane's whole blocks up to its position (the bound's under the "
+           "XLA loop) and a slice's every row the blocks up to the slice's "
+           "end; latents_selected_total over it = how sparse the read is "
+           "in fact (100 % would be a read of the selected rows alone)",
+           labels=("phase",)),
     # -- the state + ring cache (models/sala.py; ``minicpm-sala``) ----------
     Metric("lin_state_updates_total", GAUGE,
            "updates of a linear-attention layer's state in decode steps: "

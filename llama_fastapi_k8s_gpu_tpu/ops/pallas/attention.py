@@ -393,6 +393,7 @@ def _decode_kernel(
     store: bool = False,
     wrap: bool = False,
     v_width: int = 0,
+    select: bool = False,
 ):
     """One grid step is one LANE: a loop over that lane's own blocks, from
     the sliding window's first to the one that holds its position, with a
@@ -425,7 +426,15 @@ def _decode_kernel(
     position for all heads, ``n_kv`` 1): there is ONE ring, the keys are
     its whole rows and the values the same rows' first ``v_width`` columns,
     so a block is copied once and serves both products; the step's row is
-    one row."""
+    one row.
+
+    ``select``: the first of ``rest`` is this lane's (1, 1, n_ctx) float32
+    BIAS on the scores, 0 at a position the query may attend and ``-inf``
+    elsewhere (models/mla.py ``select_topk``: a ``deepseek32`` file's
+    selection, a mask on the blocks read)."""
+    bias_ref = None
+    if select:
+        bias_ref, rest = rest[0], rest[1:]
     nr = 1 if v_width else 2          # rings, and all that is one a ring
     if store:
         new_refs, o_ref, rest = rest[:nr], rest[2 * nr], rest[2 * nr + 1:]
@@ -546,6 +555,8 @@ def _decode_kernel(
         if sliding_window:
             mask &= key_pos > pos - sliding_window
         s = jnp.where(mask, s, -jnp.inf)
+        if select:
+            s = s + bias_ref[0, :, pl.ds(pl.multiple_of(j * T, T), T)][None]
         m = m_ref[:, :, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -581,7 +592,7 @@ def _decode_kernel(
 
 def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
                   sliding_window: int, interpret: bool, wrap: bool = False,
-                  v_width: int = 0):
+                  v_width: int = 0, select: bool = False):
     """q (B, n_heads, hd), i scalar, pos and live (B,), then the rings k / v
     (B, L, n_kv, n_ctx, hd) -> (B, n_heads * hd) in q.dtype: ONE kernel over
     the lanes.  With the step's rows ``k_new`` / ``v_new`` (B, n_kv, hd)
@@ -592,8 +603,14 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
     ``flash_attention_decode_window``.  ``v_width``: ONE ring (and one row)
     in place of two, a latent leaf (B, L, 1, n_ctx, hd) whose rows are the
     keys and their first ``v_width`` columns the values: (B, n_heads *
-    v_width); the kernel is then named ``flash_attention_decode_latent``."""
+    v_width); the kernel is then named ``flash_attention_decode_latent``.
+    ``select``: the LAST array is the lanes' bias on the scores (B, n_ctx)
+    float32 (0 | -inf: ``_decode_kernel``); the kernel is then named
+    ``flash_attention_decode_latent_select``."""
     n = 1 if v_width else 2
+    bias = ()
+    if select:
+        arrays, bias = arrays[:-1], (arrays[-1][:, None, :],)
     rings, rows = arrays[:n], arrays[n:]
     B, n_heads, hd = q.shape
     _, _, n_kv, n_ctx, _ = rings[0].shape
@@ -620,11 +637,14 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k, n_ctx=n_ctx,
                           sliding_window=sliding_window, sm_scale=sm_scale,
-                          store=store, wrap=wrap, v_width=v_width),
+                          store=store, wrap=wrap, v_width=v_width,
+                          select=select),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[lane_block] + [row_block] * len(new) + [in_place] * n,
+            in_specs=[lane_block] + [pl.BlockSpec(
+                (1, 1, n_ctx), lambda b, *_: (b, 0, 0))] * len(bias)
+            + [row_block] * len(new) + [in_place] * n,
             out_specs=[out_block] + [in_place] * n if store else out_block,
             scratch_shapes=[
                 pltpu.VMEM((2, n_kv, block_k, hd), r.dtype) for r in rings
@@ -641,7 +661,7 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
                                  for r in rings] if store else ctx_shape,
         # the rings (after the three prefetched scalars, the queries and
         # the rows), updated in place
-        input_output_aliases={4 + n + r: 1 + r for r in range(n)}
+        input_output_aliases={4 + len(bias) + n + r: 1 + r for r in range(n)}
         if store else {},
         # lanes in order: the slot parity and the copy started ahead are
         # carried from one lane to the next
@@ -649,9 +669,10 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="flash_attention_decode" + (
-            "_latent" if v_width else "_window" if wrap else ""),
+            "_latent" if v_width else "_window" if wrap else "") + (
+            "_select" if select else ""),
     )(jnp.asarray(i, jnp.int32).reshape(1), pos.astype(jnp.int32),
-      live.astype(jnp.int32), qg, *new, *rings)
+      live.astype(jnp.int32), qg, *bias, *new, *rings)
     ctx, *rings = out if store else (out,)
     ctx = ctx[:, :, :group, :].reshape(B, n_heads * out_w)
     return (ctx, *rings) if store else ctx
@@ -659,20 +680,22 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
 
 @functools.lru_cache(maxsize=8)
 def _decode_vmappable(block_k: int, sm_scale: float, sliding_window: int,
-                      interpret: bool, wrap: bool = False, v_width: int = 0):
+                      interpret: bool, wrap: bool = False, v_width: int = 0,
+                      select: bool = False):
     """The per-sequence call with its vmap rule: lanes ``vmap``ped over one
     step become ONE kernel over (B lanes), as the fused matmuls' rows do
     (qmatmul.py ``rows_vmappable``); without the rule ``vmap`` would batch
     the kernel's grid and every lane would run the longest lane's trips.
     ``rings``: the two rings or the one latent leaf; ``rows``: nothing, or
     the step's row for each (the rings are then returned beside the
-    context, all batched)."""
+    context, all batched).  ``select``: the LAST of ``rows`` is no row but
+    the sequence's bias on the scores (n_ctx,) (``_decode_lanes``)."""
     from jax.custom_batching import custom_vmap
 
     lanes = functools.partial(
         _decode_lanes, block_k=block_k, sm_scale=sm_scale,
         sliding_window=sliding_window, interpret=interpret, wrap=wrap,
-        v_width=v_width)
+        v_width=v_width, select=select)
 
     @custom_vmap
     def one(q, rings, i, pos, live, rows):
@@ -763,6 +786,8 @@ def latent_attention_decode(
     block_k: int,
     v_width: int,          # kv_lora_rank: a row's first columns, the values
     interpret: bool = False,
+    sel: jax.Array | None = None,   # (n_ctx,) bool: the positions the query
+    #                                 may attend beside the causal bound
 ):
     """A decode step's ABSORBED latent attention (``models/mla.py
     latent_attention`` at S = 1) as :func:`flash_attention_decode`'s kernel
@@ -773,11 +798,17 @@ def latent_attention_decode(
     The same recurrence in the same order, bounded per lane, nothing for a
     lane that is not ``live``.  Returns (the weighted sum of LATENTS
     (n_heads * v_width,) in q.dtype, before ``W_uv``; the leaf, the very
-    buffer that came in).  One kernel under ``vmap`` over lanes too."""
-    ctx, lat = _decode_vmappable(int(block_k), float(sm_scale), 0,
-                                 bool(interpret), False, int(v_width))(
+    buffer that came in).  One kernel under ``vmap`` over lanes too.
+    ``sel``: a selection (a ``deepseek32`` file's), applied as a mask on the
+    blocks read: a position outside it has probability exactly 0."""
+    rows = (row.astype(lat.dtype)[None],)
+    if sel is not None:
+        rows += (jnp.where(sel, 0.0, -jnp.inf).astype(jnp.float32),)
+    ctx, lat = _decode_vmappable(
+        int(block_k), float(sm_scale), 0, bool(interpret), False,
+        int(v_width), sel is not None)(
         q, (lat,), jnp.asarray(i, jnp.int32), jnp.asarray(pos, jnp.int32),
-        jnp.asarray(live, jnp.bool_), (row.astype(lat.dtype)[None],))
+        jnp.asarray(live, jnp.bool_), rows)
     return ctx, lat
 
 
@@ -787,7 +818,9 @@ def latent_attention_decode(
 
 #: rows of a query tile and of a key block of :func:`latent_attention_prefill`,
 #: the keys one pass of its recurrence scores, and the row groups of a tile
-#: that a pass scores side by side.  From the sweep on the chip (PERF.md
+#: that a pass scores side by side (a tile is rows of the head-major query:
+#: nothing here depends on the number of heads; the sweep was made at
+#: gigachat's 64).  From the sweep on the chip (PERF.md
 #: section 6, PR 50): at 64 heads x 1024 tokens against 11264 latents, ms a
 #: call, (512, 1024, 512, 1) 11.28, (512, 1024, 1024, 1) 11.05, (512, 2048,
 #: 512, 1) 11.38, (256, 1024, 512, 1) 12.97, (512, 1024, 256, 1) 14.37,
@@ -833,13 +866,15 @@ def _latent_prefill_kernel(
     # inputs
     q_ref,              # (BQ, w): rows h * S + s of [q_abs | q_r | 0]
     lat_ref,            # (BK, w): one block of the layer's latents
+    *rest,
+    # ``select``:         bias_ref (BQ, BK) bf16, the tile's rows against
+    #                     the block's keys: 0 | -inf on the scores
     # outputs
-    o_ref,              # (BQ, v_width)
+    # o_ref               (BQ, v_width)
     # scratch
-    m_ref,              # (BQ, 128) f32 running max (lane-replicated)
-    l_ref,              # (BQ, 128) f32 running sum
-    acc_ref,            # (BQ, v_width) f32 running weighted sum
-    *,
+    # m_ref               (BQ, 128) f32 running max (lane-replicated)
+    # l_ref               (BQ, 128) f32 running sum
+    # acc_ref             (BQ, v_width) f32 running weighted sum
     seq_len: int,
     block_q: int,
     block_k: int,
@@ -847,13 +882,21 @@ def _latent_prefill_kernel(
     sm_scale: float,
     v_width: int,
     chains: int,        # independent row groups of a tile, scored together
+    select: bool = False,
 ):
     """``_attn_kernel``'s recurrence and block classification on ONE ring:
     a block of latents is the keys (all its columns) and the values (its
     first ``v_width``).  The key-block index map is clamped at the tile's
     last block, so a grid step past it fetches nothing and, its keys lying
-    beyond every query of the tile, computes nothing."""
+    beyond every query of the tile, computes nothing.  ``select``: a bias
+    block comes with every key block (models/mla.py ``select_topk``: a
+    ``deepseek32`` file's selection as a mask), added to the scores of
+    every pass, the wholly causal ones too."""
     del i_ref
+    bias_ref = None
+    if select:
+        bias_ref, rest = rest[0], rest[1:]
+    o_ref, m_ref, l_ref, acc_ref = rest
     t = pl.program_id(0)
     kb = pl.program_id(1)
 
@@ -892,6 +935,9 @@ def _latent_prefill_kernel(
                     key_pos = kmin + jax.lax.broadcasted_iota(
                         jnp.int32, s.shape, 1)
                     s = jnp.where(key_pos <= q_pos, s, -jnp.inf)
+                if select:
+                    s = s + bias_ref[rows, u * sub_k:(u + 1) * sub_k
+                                     ].astype(jnp.float32)
                 m_prev = m_ref[rows, :1]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s, axis=-1, keepdims=True))
@@ -946,6 +992,8 @@ def latent_attention_prefill(
     sub_k: int = LATENT_PREFILL_BLOCKS[2],
     chains: int = LATENT_PREFILL_BLOCKS[3],
     interpret: bool = False,
+    sel: jax.Array | None = None,   # (S, n_ctx) bool: the positions each
+    #                                 query may attend beside the causal bound
 ) -> jax.Array:
     """A prefill slice's causal ABSORBED latent attention (``models/mla.py
     latent_attention`` at S > 1) as ONE kernel: grid (query tiles, key
@@ -967,6 +1015,12 @@ def latent_attention_prefill(
     position: the grid walks all of ``n_ctx``, but a step past that block
     names the block already in VMEM, and no copy is started for it.
 
+    ``sel``: a selection (a ``deepseek32`` file's) applied as a MASK: it
+    comes to the kernel as a bf16 bias (0 | -inf) whose rows are a TILE's
+    rows (a tile of whole heads: the slice's rows again and again), one
+    block a key block; the kernel is then named
+    ``flash_attention_prefill_latent_select``.
+
     Returns the weighted sum of LATENTS (n_heads, S, v_width) in q.dtype,
     before ``W_uv``.  One sequence: no ``vmap`` rule."""
     H, S, W = q.shape
@@ -984,11 +1038,22 @@ def latent_attention_prefill(
         return jnp.minimum((pos_ref[0] + _latent_tile_span(t, S, bq)[1])
                            // bk, n_kb - 1)
 
+    select = sel is not None
+    bias, call_kw = (), {}
+    if select:
+        bias = jnp.where(sel, 0.0, -jnp.inf).astype(jnp.bfloat16)
+        if bq > S:                  # whole heads: every head's rows
+            bias = jnp.tile(bias, (bq // S, 1))
+        bias = (bias,)
+        # the bias blocks' two buffers beside the kernel's own 11 MB
+        call_kw = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=48 << 20)}
+    per_head = max(S // bq, 1)      # tiles a head's tokens make
     out = pl.pallas_call(
         functools.partial(
             _latent_prefill_kernel, seq_len=S, block_q=bq, block_k=bk,
             sub_k=sk, sm_scale=sm_scale, v_width=v_width,
-            chains=chains if bq % (16 * chains) == 0 else 1),
+            chains=chains if bq % (16 * chains) == 0 else 1, select=select),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(H * S // bq, n_kb),
@@ -996,7 +1061,9 @@ def latent_attention_prefill(
                 pl.BlockSpec((bq, W), lambda t, kb, i, pos: (t, 0)),
                 pl.BlockSpec((None, None, bk, W), lambda t, kb, i, pos: (
                     i[0], 0, jnp.minimum(kb, last_block(t, pos)), 0)),
-            ],
+            ] + [pl.BlockSpec((bq, bk), lambda t, kb, i, pos: (
+                jax.lax.rem(t, per_head),
+                jnp.minimum(kb, last_block(t, pos))))] * select,
             out_specs=pl.BlockSpec((bq, v_width),
                                    lambda t, kb, i, pos: (t, 0)),
             scratch_shapes=[
@@ -1007,10 +1074,11 @@ def latent_attention_prefill(
         ),
         out_shape=jax.ShapeDtypeStruct((H * S, v_width), q.dtype),
         interpret=interpret,
-        name="flash_attention_prefill_latent",
+        name="flash_attention_prefill_latent" + ("_select" if select else ""),
+        **call_kw,
     )(jnp.asarray(i, jnp.int32).reshape(1),
       jnp.asarray(pos_offset, jnp.int32).reshape(1),
-      q.reshape(H * S, W), lat)
+      q.reshape(H * S, W), lat, *bias)
     return out.reshape(H, S, v_width)
 
 

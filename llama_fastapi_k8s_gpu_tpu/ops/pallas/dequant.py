@@ -141,7 +141,11 @@ def _interleave_lo_hi(lo: jax.Array, hi: jax.Array, nb: int) -> jax.Array:
     """lo/hi (nb, 128) — lane g*32+i is sub-block 2g (resp. 2g+1) element i
     → flat element order (sub-block-major)."""
     y = jnp.stack([lo.reshape(nb, 4, 32), hi.reshape(nb, 4, 32)], axis=2)
-    return y.reshape(nb * QK_K)
+    # flat in TWO steps: over the one reshape (nb, 4, 2, 32) -> (nb * 256,)
+    # the TPU's compiler takes 102 s at 49152 blocks (an 8192 x 1536 tensor)
+    # where it takes 0.15 s at 65536 or 147456; over these two, 0.6 s at
+    # each (PERF.md section 6, PR 58: 100 s of every uncached start)
+    return y.reshape(nb, QK_K).reshape(nb * QK_K)
 
 
 _K4_SPECS = dict(

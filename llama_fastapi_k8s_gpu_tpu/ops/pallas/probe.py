@@ -277,6 +277,37 @@ def probe_flash_attention(quantized: bool = False) -> str | None:
         return _err(e)
 
 
+def _latent_decode(heads: int, select: bool) -> str | None:
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .attention import latent_attention_decode
+
+        from ...models.mla import LATENT_KERNEL_BLOCK
+
+        itp = use_interpret()
+        H, W, R, CTX = (4, 256, 128, 32) if itp \
+            else (heads, 640, 512, 2 * LATENT_KERNEL_BLOCK)
+        sel = {"sel": jnp.arange(CTX) % 2 == 1} if select else {}
+
+        def decode(q, live):
+            lat = jnp.ones((2, 1, CTX, W), jnp.bfloat16)
+            ctx, lat = latent_attention_decode(
+                q, lat, jnp.int32(1), jnp.int32(CTX - 1), live, q[0],
+                sm_scale=W ** -0.5, block_k=CTX // 2, v_width=R,
+                interpret=itp, **sel)
+            return ctx.astype(jnp.float32).sum() + lat[1, 0, CTX - 1, 0]
+
+        out = jax.jit(jax.vmap(decode))(jnp.ones((2, H, W), jnp.bfloat16),
+                                        jnp.asarray([True, False]))
+        float(out.sum())
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)
+
+
 @_once
 def probe_latent_decode() -> str | None:
     """Compile + run the decode kernel on a latent leaf (one ring of rows
@@ -289,30 +320,41 @@ def probe_latent_decode() -> str | None:
     failure leaves a ``deepseek2`` file's decode steps on the XLA loop of
     ``models/mla.py latent_attention`` (``cfg.latent_kernel`` stays
     False)."""
+    return _latent_decode(64, False)
+
+
+@_once
+def probe_latent_decode_select() -> str | None:
+    """:func:`probe_latent_decode` for a ``deepseek32`` file: 128 heads, and
+    the kernel WITH a selection's bias operand (another Mosaic program:
+    ``flash_attention_decode_latent_select``)."""
+    return _latent_decode(128, True)
+
+
+def _latent_prefill(heads: int, select: bool) -> str | None:
     try:
         import jax
         import jax.numpy as jnp
 
         from . import use_interpret
-        from .attention import latent_attention_decode
+        from .attention import latent_attention_prefill
 
-        from ...models.mla import LATENT_KERNEL_BLOCK
+        from ...models.mla import LATENT_SLICE_BLOCK
 
         itp = use_interpret()
-        H, W, R, CTX = (4, 256, 128, 32) if itp \
-            else (64, 640, 512, 2 * LATENT_KERNEL_BLOCK)
+        H, S, W, R, T = (4, 16, 256, 128, 16) if itp \
+            else (heads, 256, 640, 512, LATENT_SLICE_BLOCK)
+        sel = {"sel": (jnp.arange(2 * T) % 2 == 1)[None, :].repeat(S, 0)} \
+            if select else {}
 
-        def decode(q, live):
-            lat = jnp.ones((2, 1, CTX, W), jnp.bfloat16)
-            ctx, lat = latent_attention_decode(
-                q, lat, jnp.int32(1), jnp.int32(CTX - 1), live, q[0],
-                sm_scale=W ** -0.5, block_k=CTX // 2, v_width=R,
-                interpret=itp)
-            return ctx.astype(jnp.float32).sum() + lat[1, 0, CTX - 1, 0]
+        def slice_(q):
+            lat = jnp.ones((2, 1, 2 * T, W), jnp.bfloat16)
+            return latent_attention_prefill(
+                q, lat, jnp.int32(1), jnp.int32(2 * T - S),
+                sm_scale=W ** -0.5, v_width=R, block_k=T, interpret=itp,
+                **sel).astype(jnp.float32).sum()
 
-        out = jax.jit(jax.vmap(decode))(jnp.ones((2, H, W), jnp.bfloat16),
-                                        jnp.asarray([True, False]))
-        float(out.sum())
+        float(jax.jit(slice_)(jnp.ones((H, S, W), jnp.bfloat16)))
         return None
     except Exception as e:  # noqa: BLE001
         return _err(e)
@@ -329,30 +371,15 @@ def probe_latent_prefill() -> str | None:
     128 and blocks of 16 in interpret mode).  A failure leaves a
     ``deepseek2`` file's prefill slices on the XLA loop of ``models/mla.py
     latent_attention`` (``cfg.latent_slice_kernel`` stays False)."""
-    try:
-        import jax
-        import jax.numpy as jnp
+    return _latent_prefill(64, False)
 
-        from . import use_interpret
-        from .attention import latent_attention_prefill
 
-        from ...models.mla import LATENT_SLICE_BLOCK
-
-        itp = use_interpret()
-        H, S, W, R, T = (4, 16, 256, 128, 16) if itp \
-            else (64, 256, 640, 512, LATENT_SLICE_BLOCK)
-
-        def slice_(q):
-            lat = jnp.ones((2, 1, 2 * T, W), jnp.bfloat16)
-            return latent_attention_prefill(
-                q, lat, jnp.int32(1), jnp.int32(2 * T - S),
-                sm_scale=W ** -0.5, v_width=R, block_k=T, interpret=itp
-            ).astype(jnp.float32).sum()
-
-        float(jax.jit(slice_)(jnp.ones((H, S, W), jnp.bfloat16)))
-        return None
-    except Exception as e:  # noqa: BLE001
-        return _err(e)
+@_once
+def probe_latent_prefill_select() -> str | None:
+    """:func:`probe_latent_prefill` for a ``deepseek32`` file: 128 heads,
+    and the kernel WITH a selection's bias operand
+    (``flash_attention_prefill_latent_select``)."""
+    return _latent_prefill(128, True)
 
 
 @_once
@@ -383,6 +410,8 @@ register_program("probe_flash_attention", site="ops.pallas.probe")
 register_program("probe_lin_state", site="ops.pallas.probe")
 register_program("probe_latent_decode", site="ops.pallas.probe")
 register_program("probe_latent_prefill", site="ops.pallas.probe")
+register_program("_latent_decode", site="ops.pallas.probe")
+register_program("_latent_prefill", site="ops.pallas.probe")
 
 
 @_once

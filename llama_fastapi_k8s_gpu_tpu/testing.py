@@ -8,6 +8,7 @@ the real reader/dequant/tokenizer/model path.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import numpy as np
@@ -301,6 +302,13 @@ MLA_Q4KM_MIX = {
 }
 
 
+#: the tiny ``deepseek2`` file with a learned indexer (``deepseek32``:
+#: models/mla.py): 4 indexer heads of 16 (the first 8 columns rotated), and
+#: a selection of 16 positions, which bites from the 17th token on
+TINY_DSA_CFG = dataclasses.replace(
+    TINY_MLA_CFG, index_heads=4, index_dim=16, index_topk=16)
+
+
 def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
                         seed: int = 0, mix: dict | None = None,
                         held: tuple[int, int] | None = None,
@@ -314,7 +322,9 @@ def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
     with all of them has (the share of an expert-parallel layer), and the
     file says so under ``expert_held_first`` / ``expert_held_count``.
     ``bias_scale``: the choice bias's spread, large enough that dropping it
-    changes picks."""
+    changes picks.  A ``cfg`` with an indexer (``index_topk``:
+    :data:`TINY_DSA_CFG`) writes a ``deepseek32`` file: the same tensors
+    from the same draws, then each layer's indexer tensors."""
     tokens, types = byte_vocab_with_specials()
     first, count = held or (0, 0)
     cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens),
@@ -323,9 +333,14 @@ def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
     scale = cfg.dim ** -0.5
     mix = {**MLA_Q4KM_MIX, **(mix or {})}
     w = GGUFWriter(path)
-    arch = "deepseek2"
+    arch = "deepseek32" if cfg.index_topk else "deepseek2"
     write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-mla-test",
                           arch=arch)
+    if cfg.index_topk:
+        for key, value in (("head_count", cfg.index_heads),
+                           ("key_length", cfg.index_dim),
+                           ("top_k", cfg.index_topk)):
+            w.add_metadata(f"{arch}.attention.indexer.{key}", value)
     for key, value in (
             ("leading_dense_block_count", cfg.n_dense_layers),
             ("expert_feed_forward_length", cfg.expert_ffn_dim),
@@ -406,6 +421,19 @@ def write_tiny_mla_gguf(path: str, cfg: ModelConfig = TINY_MLA_CFG,
         t(p + "ffn_down_shexp.weight", (D, sh), mix["ffn_down_shexp"])
     norm("output_norm.weight", D)
     t("output.weight", (cfg.vocab_size, D), mix["output"])
+    # (after everything else: the other tensors are a ``deepseek2`` file's
+    # of the same seed, draw for draw)
+    for i in range(cfg.n_layers if cfg.index_topk else 0):
+        p = f"blk.{i}."
+        Hi, dI = cfg.index_heads, cfg.index_dim
+        t(p + "indexer_q_b.weight", (Hi * dI, r_q), GGMLType.Q8_0,
+          (D / r_q) ** 0.5)
+        t(p + "indexer_k.weight", (dI, D), GGMLType.Q8_0)
+        norm(p + "indexer_k_norm.weight", dI)
+        w.add_tensor(p + "indexer_k_norm.bias", 0.1 * rng.standard_normal(
+            dI).astype(np.float32), GGMLType.F32)
+        # signed weights of order 1 a head: the scale is the program's
+        t(p + "indexer_proj.weight", (Hi, D), GGMLType.F32, 4.0)
     w.write()
     return cfg
 

@@ -13,6 +13,7 @@ it lives in this ONE file.  A compile that passes is not a chip run."""
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -1299,6 +1300,106 @@ def test_latent_slice_program_holds_the_slice_kernel(one_chip, monkeypatch,
     assert not wide, wide[:3]
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 1024 * 2 ** 20 * rows // 256
+
+
+def _dsv32(one_chip, **flags):
+    """BENCHMARK.json's ``deepseek-v3.2-exp`` configuration at its published
+    widths: ``_gigachat``'s shapes at 128 heads (v 128), 1 dense + 5 routed
+    layers, vocabulary 129280, and the indexer's tensors (64 heads of 128
+    from the query latent, one index key of 128, its LayerNorm, the F32
+    head weights), ``index_topk`` 2048."""
+    cfg, params, place = _gigachat(one_chip, **flags)
+    H, Hi, dI, r_q, D, V = 128, 64, 128, 1536, 7168, 129280
+    cfg = dataclasses.replace(
+        cfg, n_layers=6, n_heads=H, n_kv_heads=H, v_head_dim=128,
+        vocab_size=V, rope_theta=1e4, rope_yarn_factor=40.0,
+        index_heads=Hi, index_dim=dI, index_topk=2048)
+
+    def stack(tree, L):
+        t = jax.tree.map(lambda a: S(L, *a.shape[1:], dtype=a.dtype), tree)
+        return {**t, "wq_b": {"w": S(L, H * 192, r_q)},
+                "wo": _planes("q4k", D, H * 128, L),
+                "w_uk": {"w": S(L, H, 128, 512)},
+                "w_uv": {"w": S(L, H, 128, 512)},
+                "idx_wq_b": {"w": S(L, Hi * dI, r_q)},
+                "idx_wk": _planes("q6k", 128, 8192, L),
+                "idx_k_norm": S(L, dI, dtype=f32),
+                "idx_k_norm_b": S(L, dI, dtype=f32),
+                "idx_proj": S(L, Hi, D, dtype=f32)}
+
+    params = place({
+        "tok_emb": S(V, D), "out_norm": S(D, dtype=f32),
+        "output": _planes("q6k", V, 8192),
+        "layers": {"dense": stack(params["layers"]["dense"], 1),
+                   "moe": stack(params["layers"]["moe"], 5)}})
+    return cfg, params, place
+
+
+@pytest.mark.parametrize("name,lanes,rows", [
+    ("dsv32-16lane-step", 16, 0), ("dsv32-narrow-slice", 0, 256),
+    ("dsv32-wide-slice", 0, 1024)])
+def test_indexed_latent_stack_compiles_with_the_selection_in_the_kernels(
+        one_chip, monkeypatch, name, lanes, rows):
+    """The lanes' decode chunk and an admission slice of the ``deepseek32``
+    stack compile for the chip at 128 heads: the indexer's scores and the
+    threshold search as plain XLA over the index-key leaf in its own shape
+    (which is how benchmarks/dsa_roofline.py finds them), the attention as
+    the two latent kernels WITH the selection's bias operand under names
+    of their own, and the compiler has put no copy or transpose of either
+    leaf around them; no per-head score tensor of the slice (64 heads x
+    rows x a block of keys, float32) stands whole in HBM: a slice of any
+    width is scored a group of heads at a time, ``INDEX_ROWS`` (head, query)
+    rows at most."""
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+    from llama_fastapi_k8s_gpu_tpu.models.generate import prefill_chunk_jit
+    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg, params, place = _dsv32(one_chip, latent_kernel=True,
+                                latent_slice_kernel=True)
+    if lanes:
+        st = sampling_tensors(SamplingParams())
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        compiled = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,)).compile()
+        kernel, leaf = "flash_attention_decode_latent_select", \
+            "bf16[16,6,1,16384,128]"
+    else:
+        assert mla.slice_tile(cfg, rows) == 1024
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert set(cache) == {"lat", "idx"}
+        compiled = prefill_chunk_jit.__wrapped__.lower(
+            params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+            place(S(dtype=i32)), cache).compile()
+        kernel, leaf = "flash_attention_prefill_latent_select", \
+            "bf16[6,1,16384,128]"
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if re.match(r"\s*(ROOT )?%" + kernel, ln)]
+    # one call in the dense layers' loop, one in the routed layers'
+    assert len(calls) == 2, calls
+    assert leaf in text and not _leaf_copies(text)
+    # the per-head scores: (heads of a group, queries, a block of keys)
+    # float32, at any width of the slice
+    per_head = {(int(g), int(r)) for g, r in re.findall(
+        r"= f32\[(?:16,)?(\d+),(\d+),%d\]\S* convolution\(" % mla.INDEX_BLOCK,
+        text)}
+    assert per_head and all(g * r <= mla.INDEX_ROWS for g, r in per_head), \
+        per_head
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1024 * 2 ** 20 * max(rows, 256) // 256
 
 
 # ``k-exaone-236b-a23b-q4km-ep8-16lane`` (benchmarks/configs: 12 layers of

@@ -22,6 +22,7 @@ so that a ring of 128 slots holds eight blocks.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 import types
@@ -95,6 +96,35 @@ def rel(got, want):
 # logits: the bounded read against the whole-ring read
 # ---------------------------------------------------------------------------
 
+def one_step_off(q, cache, i, pos, bound, cfg, out_dtype):
+    out = whole_ring(q, cache, i, pos, bound, cfg, jnp.float32)
+    return (out * (1 + 2.0 ** -8)).astype(out_dtype)
+
+
+def a_slot_short(q, cache, i, pos, bound, cfg, out_dtype):
+    return whole_ring(q, cache, i, jnp.maximum(pos - 1, 0), bound, cfg,
+                      out_dtype)
+
+
+@functools.cache
+def _step_reading(cfg, read):
+    """``decode_step``'s logits as ONE program of ``cfg`` whose ring is read
+    by ``read`` (``llama.decode_attention`` while the program is traced;
+    None: the program's own).  One build a process: the position is an
+    operand, so every case of a (configuration, read) runs the program the
+    first case compiled."""
+    return jax.jit(lambda params, token, pos, cache: llama.decode_step(
+        params, cfg, token, pos, cache)[0])
+
+
+def step_logits(monkeypatch, params, cfg, pos, cache, read=None):
+    with monkeypatch.context() as patch:
+        if read is not None:
+            patch.setattr(llama, "decode_attention", read)
+        return np.asarray(_step_reading(cfg, read)(
+            params, jnp.int32(7), jnp.int32(pos), cache), np.float32)
+
+
 @pytest.mark.parametrize("pos", [0, BLOCK - 1, BLOCK, BLOCK + 1, N_CTX - 1])
 @pytest.mark.parametrize("heads", [(4, 2), (4, 4)], ids=["gqa", "mha"])
 @pytest.mark.parametrize("window", [0, 24], ids=["causal", "window24"])
@@ -113,20 +143,10 @@ def test_logits_match_the_whole_ring_read(monkeypatch, window, heads, pos):
     params = synth_params(cfg, seed=2)
     cache = _random_cache(cfg, seed=pos)
 
-    def logits(read):
-        monkeypatch.setattr(llama, "decode_attention", read)
-        return np.asarray(llama.decode_step(
-            params, cfg, jnp.int32(7), jnp.int32(pos), cache)[0], np.float32)
+    def logits(read=None):
+        return step_logits(monkeypatch, params, cfg, pos, cache, read)
 
-    def one_step_off(q, cache, i, pos, bound, cfg, out_dtype):
-        out = whole_ring(q, cache, i, pos, bound, cfg, jnp.float32)
-        return (out * (1 + 2.0 ** -8)).astype(out_dtype)
-
-    def a_slot_short(q, cache, i, pos, bound, cfg, out_dtype):
-        return whole_ring(q, cache, i, jnp.maximum(pos - 1, 0), bound, cfg,
-                          out_dtype)
-
-    got = logits(llama.decode_attention)
+    got = logits()
     want = logits(whole_ring)
     assert got.shape == want.shape == (cfg.vocab_size,)
     assert np.isfinite(got).all()
@@ -151,20 +171,13 @@ def test_the_kernels_logits_match_the_whole_ring_read(monkeypatch, window,
     params = synth_params(cfg, seed=2)
     cache = _random_cache(cfg, seed=pos)
 
-    def logits(cfg):
-        return np.asarray(llama.decode_step(
-            params, cfg, jnp.int32(7), jnp.int32(pos), cache)[0], np.float32)
-
-    def one_step_off(q, cache, i, pos, bound, cfg, out_dtype):
-        out = whole_ring(q, cache, i, pos, bound, cfg, jnp.float32)
-        return (out * (1 + 2.0 ** -8)).astype(out_dtype)
+    def logits(cfg, read=None):
+        return step_logits(monkeypatch, params, cfg, pos, cache, read)
 
     got = logits(dataclasses.replace(cfg, attn_impl="pallas"))
     loop = logits(cfg)
-    monkeypatch.setattr(llama, "decode_attention", whole_ring)
-    want = logits(cfg)
-    monkeypatch.setattr(llama, "decode_attention", one_step_off)
-    step = rel(logits(cfg), want)
+    want = logits(cfg, whole_ring)
+    step = rel(logits(cfg, one_step_off), want)
     assert np.isfinite(got).all()
     assert rel(got, want) < 3 * step + 1e-6
     assert rel(got, loop) < step + 1e-6
@@ -193,6 +206,13 @@ def _lane_step(params, cfg):
     return step
 
 
+@functools.cache
+def _lanes_program(cfg):
+    """``_lane_step`` of ``cfg`` on the weights of seed 1: one build a
+    process, whatever position and neighbours a case gives the lanes."""
+    return _lane_step(synth_params(cfg, seed=1), cfg)
+
+
 OTHERS = {
     # the other three lanes: (live, positions, rings)
     "empty": (False, (0, 0, 0), "zeros"),
@@ -217,8 +237,7 @@ def test_a_lanes_logits_do_not_depend_on_the_other_lanes(attn_impl, heads,
     writes are BITWISE those of the run with the other lanes empty, under
     the loop's common bound and under the kernel's bound per lane."""
     cfg = _cfg(heads, attn_impl=attn_impl)
-    params = synth_params(cfg, seed=1)
-    step = _lane_step(params, cfg)
+    step = _lanes_program(cfg)
     mine = _random_cache(cfg, seed=11)
 
     def run(name):

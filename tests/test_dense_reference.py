@@ -201,11 +201,22 @@ def matrix(fn):
     return fn
 
 
+_PREFILLS: dict = {}
+
+
 def prefill(params, cfg, tokens, n):
+    """A padded bucket of 32 through ``forward`` as ONE program: one build
+    a process for each configuration, read block and ``llama._layer`` (a
+    control that puts another layer in its place gets a program of its
+    own); the weights and the real length are operands."""
     padded = np.zeros(32, np.int32)
     padded[:n] = tokens[:n]
-    return llama.forward(params, cfg, jnp.asarray(padded), jnp.int32(0),
-                         llama.init_cache(cfg), last_idx=jnp.int32(n - 1))
+    key = (cfg, llama._layer, llama.DECODE_KV_BLOCK)
+    if key not in _PREFILLS:
+        _PREFILLS[key] = jax.jit(lambda params, padded, last: llama.forward(
+            params, cfg, padded, jnp.int32(0), llama.init_cache(cfg),
+            last_idx=last))
+    return _PREFILLS[key](params, jnp.asarray(padded), jnp.int32(n - 1))
 
 
 def stale_ring(cfg, seed):

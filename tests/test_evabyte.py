@@ -108,7 +108,18 @@ def rel(got, want):
 def programs(cfg):
     """The calls the tests make of the program's ``forward`` under ``cfg``:
     a prefill pass, one decode step, one step of lanes (the body of
-    ``parallel/batched.py``'s vmapped step, its bounds included)."""
+    ``parallel/batched.py``'s vmapped step, its bounds included).  One
+    build a process for each configuration: a second caller gets the
+    programs the first one compiled."""
+    if cfg not in _PROGRAMS:
+        _PROGRAMS[cfg] = _build_programs(cfg)
+    return _PROGRAMS[cfg]
+
+
+_PROGRAMS = {}
+
+
+def _build_programs(cfg):
     import jax
 
     from llama_fastapi_k8s_gpu_tpu.models.llama import forward

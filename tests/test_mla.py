@@ -98,10 +98,30 @@ def rows_that_differ(mine, theirs):
     return int(np.sum(np.any(np.sort(mine, -1) != np.sort(theirs, -1), -1)))
 
 
+_PROGRAMS = {}
+
+
+def traced_with(cfg):
+    """What a trace of ``cfg``'s programs reads: the configuration and the
+    block widths that ``with_kernel`` sets on models/mla.py."""
+    from llama_fastapi_k8s_gpu_tpu.models import mla
+
+    return (cfg, mla.LATENT_KERNEL_BLOCK, mla.LATENT_SLICE_BLOCK)
+
+
 def programs(cfg):
     """A prefill pass (``n`` real positions of the slice), one decode step,
     one step of lanes (the body of ``parallel/batched.py``'s vmapped step,
-    its bound included); each returns the routers' picks too."""
+    its bound included); each returns the routers' picks too.  One build a
+    process for each (configuration, block widths): a second caller gets
+    the programs the first one compiled."""
+    key = traced_with(cfg)
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = _build_programs(cfg)
+    return _PROGRAMS[key]
+
+
+def _build_programs(cfg):
     import jax
 
     from llama_fastapi_k8s_gpu_tpu.models.llama import forward

@@ -826,6 +826,7 @@ def test_prefill_then_decode_through_the_cache_agrees(
         loaded, ref, gguf_path, tokens, fmt):
     """The serial engine's two programs (models/generate.py): a padded
     bucket prefill, then one token at a time against the ring."""
+    import jax
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.models.llama import forward, init_cache
@@ -838,10 +839,14 @@ def test_prefill_then_decode_through_the_cache_agrees(
         params, cfg, jnp.asarray(padded), jnp.int32(0), init_cache(cfg),
         last_idx=jnp.int32(n - 1), with_picks=True)
     got, picks = [logits], [np.asarray(picks)[:, :n]]
+    # (the step as ONE program, as the engine runs it: called op by op,
+    # every step compiled the layers' loop anew)
+    step = jax.jit(lambda token, pos, cache: forward(
+        params, cfg, token, pos, cache, with_picks=True))
     for pos in range(n, len(tokens)):
-        logits, cache, pk = forward(
-            params, cfg, jnp.asarray(tokens[pos:pos + 1], jnp.int32),
-            jnp.int32(pos), cache, with_picks=True)
+        logits, cache, pk = step(
+            jnp.asarray(tokens[pos:pos + 1], jnp.int32), jnp.int32(pos),
+            cache)
         got.append(logits)
         picks.append(np.asarray(pk))
     want = reference_with(ref, gguf_path, tokens, np.concatenate(picks, 1))

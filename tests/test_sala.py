@@ -125,7 +125,22 @@ def programs(cfg):
     """The calls the tests make of the program's ``forward``: a prefill
     pass (``n`` real positions of the slice), one decode step, one step of
     lanes (the body of ``parallel/batched.py``'s vmapped step, its bounds
-    included); each returns the sparse layers' sets too."""
+    included); each returns the sparse layers' sets too.  One build a
+    process for each configuration (and ``starts_sequence``, which one
+    control replaces): a second caller gets the programs the first one
+    compiled."""
+    from llama_fastapi_k8s_gpu_tpu.models import sala
+
+    key = (cfg, sala.starts_sequence)
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = _build_programs(cfg)
+    return _PROGRAMS[key]
+
+
+_PROGRAMS = {}
+
+
+def _build_programs(cfg):
     import jax
 
     from llama_fastapi_k8s_gpu_tpu.models.llama import forward
