@@ -904,17 +904,36 @@ class Engine:
         return self._expert_counters
 
     @property
-    def _grouped_step(self) -> tuple | None:
-        """(tokens, picks a token) of a decode step's grouped expert call;
-        None where the experts serve dequantized and for a dense block."""
+    def _expert_families(self) -> tuple:
+        """The grouped-call families of the file's expert tensors, in the
+        layers' order; () where any serves dequantized and for a dense
+        block."""
         from ..models.params import flat_layers
         from ..ops.pallas.experts import family_of
 
-        experts = [leaf for name, leaf in flat_layers(self.params["layers"])
-                   if name.endswith("_exps")]
-        if not experts or not all(family_of(leaf) for leaf in experts):
+        fams = tuple(family_of(leaf) for name, leaf
+                     in flat_layers(self.params["layers"])
+                     if name.endswith("_exps"))
+        return fams if all(fams) else ()
+
+    @property
+    def _grouped_step(self) -> tuple | None:
+        """(tokens, picks a token) of a decode step's grouped expert call;
+        None where the experts serve dequantized and for a dense block."""
+        if not self._expert_families:
             return None
         return getattr(self, "batch_size", 1), self.cfg.n_experts_used
+
+    @property
+    def expert_kernel(self) -> str | None:
+        """The bodies the grouped expert calls run, by family (/health
+        ``engine.expert_kernel``: ``q4k-float+q6k-int`` on a Q4_K_M file;
+        named where they are chosen, ops/pallas/experts.py ``FAMILIES``);
+        None without such a call."""
+        from ..ops.pallas.experts import FAMILIES
+
+        return "+".join(FAMILIES[f].body for f in
+                        sorted(set(self._expert_families))) or None
 
     @property
     def expert_slots(self) -> int:

@@ -4,8 +4,14 @@ A routed feed-forward layer (models/llama.py ``_layer``, ``cfg.n_experts``)
 sends each token to ``k`` of ``E`` SwiGLU experts.  Its weights are the
 GGUF file's 3-D ``ffn_{gate,up,down}_exps`` tensors, kept as the SAME fused
 Q4_K / Q6_K planes the dense matmuls read (ops/pallas/qmatmul.py,
-q6matmul.py) with an (L, E) pair of leading axes, and computed by the SAME
-kernel bodies.  What is new is the grid around them:
+q6matmul.py) with an (L, E) pair of leading axes, and computed by dense
+kernels' bodies: a Q4_K plane by the stacked dense calls'
+(``qmatmul._q4k_matmul_kernel``), a Q6_K plane, since PR 59, by the
+vocabulary head's integer dequantization (``q6matmul._q6k_tile_product``:
+the stacked dense calls' bfloat16 plane bit for bit at half the vector
+work; the float32 sums of a K tile taken a quarter at a time, so a result
+differs from the stacked call's in its last bits and equals the unstacked
+call's).  What is new is the grid around them:
 
 - the grid has an expert-slot axis beside the N and K tiles; the layer, the
   number of slots in use and each slot's expert ride a prefetched scalar
@@ -126,30 +132,45 @@ def experts_compatible(n_out: int, k_in: int,
 # ---------------------------------------------------------------------------
 
 class _Family:
-    def __init__(self, name, gtype, prep, planes, widths, kernel, tka,
-                 tn_prefs, permute, augment, variants):
+    def __init__(self, name, gtype, prep, planes, widths, kernel, body, tka,
+                 tn, permute, augment, variants=None):
         self.name = name                # q4k | q6k
         self.gtype, self.prep = gtype, prep   # ggml type, the dense packer
         self.planes = planes            # plane keys, scale plane last
         self.widths = widths            # value planes' bytes per K tile
-        self.kernel, self.tka, self.tn_prefs = kernel, tka, tn_prefs
+        self.kernel, self.body = kernel, body     # body: its /health name
+        self.tka, self.tn = tka, tn     # tn(N, rows, interpret): the N tile
         self.permute, self.augment = permute, augment
-        self.variants = variants        # (env knob, allowed)
+        self.variants = variants        # (env knob, allowed); None: one body
 
-    def variant(self) -> str:
-        v = _env_variant(*self.variants)
-        # `pre` is a Q6_K *layout*; split planes run the split default
-        return "cur" if v == "pre" else v
+    def variant(self) -> str | None:
+        return _env_variant(*self.variants) if self.variants else None
+
+
+def _tn_q4k(N: int, rows: int, interpret: bool) -> int:
+    return _pick_tn(N, interpret,
+                    prefs=_tn_prefs_for(rows, _q4._TN_PREFS_Q4K))
+
+
+def _tn_q6k(N: int, rows: int, interpret: bool) -> int:
+    """The N tile of a grouped Q6_K call, whatever its rows: the head's
+    rule (``q6matmul.wide_tn``: 1024 at N 1024, 2048, 6144 and 7168).  A
+    grid step costs 0.3 us beside its bytes, and a many-row call fetches
+    its slot's activation block (590 KB at 128 rows) again at every (N
+    tile, slot) step, which at a tile of 256 is more bytes than the planes
+    (PERF.md section 6, PR 59: few rows -24 %, many rows -39 % from 256 to
+    1024 under the same body)."""
+    return _q6.wide_tn(N, interpret)
 
 
 FAMILIES = {
     "q4k": _Family("q4k", GGMLType.Q4_K, _q4.prep_q4k, ("qs", "sm"),
-                   (TK // 2,), _q4._q4k_matmul_kernel, _q4.TKA, _q4._TN_PREFS_Q4K, _q4.permute_x, _q4.augment_x,
+                   (TK // 2,), _q4._q4k_matmul_kernel, "q4k-float", _q4.TKA,
+                   _tn_q4k, _q4.permute_x, _q4.augment_x,
                    ("LFKT_Q4K_KERNEL", _q4.Q4K_VARIANTS)),
     "q6k": _Family("q6k", GGMLType.Q6_K, _q6.prep_q6k, ("q4", "q2", "sm6"),
-                   (TK // 2, TK // 4), _q6._q6k_matmul_kernel, _q6.TKA6, _q6._TN_PREFS_Q6K,
-                   _q6.permute_x6, _q6.augment_x6,
-                   ("LFKT_Q6K_KERNEL", _q6.Q6K_VARIANTS)),
+                   (TK // 2, TK // 4), _q6._q6k_expert_kernel, "q6k-int",
+                   _q6.TKA6, _tn_q6k, _q6.permute_x6, _q6.augment_x6),
 }
 
 
@@ -359,7 +380,7 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
 
     kt = xpa.shape[1] // fam.tka
     N = planes[0].shape[2]
-    TN = _pick_tn(N, interpret, prefs=_tn_prefs_for(rows, fam.tn_prefs))
+    TN = fam.tn(N, rows, interpret)
     slots = slot_extent(meta[1])
     if few:
         grid = (slots, N // TN, kt)
@@ -385,6 +406,8 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
                               ax(lambda n, t, k, m: (m[0], m[2 + t], k, n,
                                                      0))))
 
+    by_variant = {"variant": variant} if fam.variants else {}
+
     def body(meta_ref, x_ref, *rest):
         t, n, k = (pl.program_id(i) for i in ((0, 1, 2) if few
                                               else (1, 0, 2)))
@@ -409,7 +432,7 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
         @pl.when(t < meta_ref[1])       # false only where no slot is in use
         def _():
             fam.kernel(x_ref, *plane_refs, o_ref, interpret=interpret,
-                       variant=variant, accum=accum)
+                       accum=accum, **by_variant)
 
     return pl.pallas_call(
         body,

@@ -39,11 +39,13 @@ Shape requirements: ``K % 2048 == 0``, ``N % 128`` == 0 — same classes as
 the Q4_K kernel; ineligible tensors fall back to int8 (models/params.py).
 
 Two bodies build that plane.  The stacked calls (a layer's ``w_down`` /
-``wv``) and the grouped expert calls run :func:`_q6k_matmul_kernel`, the
-float form above.  The ONE unstacked call, the vocabulary head's
-(:func:`_q6k_2d_raw`), runs :func:`_q6k_head_kernel`: the same plane bit for
-bit from integer operations on the packed bytes, under a tiling of its own
-(a wide N tile, all of K a grid step, so the activations are fetched once).
+``wv``) run :func:`_q6k_matmul_kernel`, the float form above, and are what
+``LFKT_Q6K_KERNEL`` chooses among.  :func:`_q6k_tile_product` builds the
+same plane bit for bit from integer operations on the packed bytes; it
+serves the ONE unstacked call, the vocabulary head's (:func:`_q6k_2d_raw`,
+:func:`_q6k_head_kernel`: a wide N tile, all of K a grid step, so the
+activations are fetched once), and, since PR 59, the grouped expert calls
+(:func:`_q6k_expert_kernel` under ops/pallas/experts.py's grid and tile).
 """
 
 from __future__ import annotations
@@ -254,8 +256,7 @@ def dequant_ref6(w: dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
-                       variant="cur", accum=_q4k_accum):
-    # ``accum``: as in qmatmul._q4k_matmul_kernel
+                       variant="cur"):
     TN = q4_ref.shape[0]
     v4 = q4_ref[...].astype(jnp.float32)              # (TN, TK/2)
     h = jnp.floor(v4 * 0.0625)
@@ -266,8 +267,7 @@ def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     corr = jnp.concatenate([sm * -32.0, sm * 8.0], axis=1).astype(jnp.bfloat16)
 
     if variant == "vbf32":
-        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret,
-                        accum)
+        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret)
         return
 
     l = v4 - h * 16.0
@@ -303,11 +303,10 @@ def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     part += jax.lax.dot_general(
         xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    accum(o_ref, part)
+    _q4k_accum(o_ref, part)
 
 
-def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret,
-                    accum=_q4k_accum):
+def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
     """Activation-side recombination with f32 planes (Q6_K analogue of the
     Q4_K ``vbf32`` variant, ops/pallas/qmatmul.py).
 
@@ -357,7 +356,7 @@ def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret,
     part += dot(x2 - 4.0 * x1, f2 * eff_q)
     part += dot(x3 - 4.0 * x2, c3 * eff_q)
     part += dot(xpa[:, TK:], corr)
-    accum(o_ref, part)
+    _q4k_accum(o_ref, part)
 
 
 def _q6k_pre_kernel(xpa_ref, q6p_ref, sm_ref, o_ref, *, interpret):
@@ -439,19 +438,23 @@ _NIB = 0x0F0F0F0F                # a nibble of each of a word's four bytes
 _CRUMB = 0x30303030              # a crumb, where ``q6 = nib | crumb << 4`` has it
 
 
-def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
-                     tiles, accumulate):
-    """:func:`_q6k_matmul_kernel`'s plane, bit for bit, at half its vector
-    operations: the packed bytes are taken apart as INTEGERS, four weight
-    rows a 32-bit word (the int8 planes bitcast in the kernel), and joined
-    to ``q6 = nib | crumb << 4`` before the scale, so that a weight pays one
-    conversion, one multiply by ``eff`` (exact: 6 bits by a bfloat16) and
-    the bfloat16 cast.  The high half's nibble keeps its bias of -8 (the
-    byte holds ``hi - 8``), whose +8 stays in the correction columns.
+def _q6k_tile_product(q4, q2, sm, xpa, interpret):
+    """One K tile of a Q6_K product: :func:`_q6k_matmul_kernel`'s plane, bit
+    for bit, at half its vector operations.  The packed bytes are taken
+    apart as INTEGERS, four weight rows a 32-bit word (the int8 planes
+    bitcast in the kernel), and joined to ``q6 = nib | crumb << 4`` before
+    the scale, so that a weight pays one conversion, one multiply by ``eff``
+    (exact: 6 bits by a bfloat16) and the bfloat16 cast.  The high half's
+    nibble keeps its bias of -8 (the byte holds ``hi - 8``), whose +8 stays
+    in the correction columns.
 
-    ``tiles`` K tiles a grid step: xpa (tiles, B, TKA6), which is the whole
-    of it, fetched once a call, where the step holds all of K; q4 (TN,
-    tiles * TK/2), q2 (TN, tiles * TK/4) int8; sm (tiles, TN, 128)."""
+    The operands are read by the caller's thunks, in this order: ``q4()``
+    (TN, TK/2) and ``q2()`` (TN, TK/4) int8, ``sm()`` (TN, 128), ``xpa()``
+    (B, TKA6): thunks, so that each read stays where it was among the
+    operations and the head's program keeps its text
+    (tools/traced_program_hashes.py).  Returns the (B, TN) float32 product.  The bodies that run it:
+    the head's (:func:`_q6k_head_kernel`) and the grouped expert calls'
+    (:func:`_q6k_expert_kernel`)."""
     from jax.experimental.pallas import tpu as pltpu
 
     Q = TK // 4
@@ -465,36 +468,71 @@ def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     dot = functools.partial(
         jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
+    w4 = pltpu.bitcast(q4(), jnp.int32)
+    w2 = pltpu.bitcast(q2(), jnp.int32)
+    lo, hi = w4 & _NIB, (w4 >> 4) & _NIB          # hi: (hi - 8) mod 16
+    # (the crumb byte is stored less 128: its top bit, the fourth crumb's
+    # high one, arrives turned)
+    q6 = (lo[:, :Q] | ((w2 << 4) & _CRUMB),       # columns [0, 512)
+          lo[:, Q:] | ((w2 << 2) & _CRUMB),       # [512, 1024)
+          signed(hi[:, :Q] | (w2 & _CRUMB), 0),   # [1024, 1536)
+          signed(hi[:, Q:] | ((w2 >> 2) & _CRUMB), 0x20202020))
+    sm = sm()                                     # (TN, 128): eff = d·sc
+    eff = _lane_repeat(sm, Q // 128, interpret)
+    corr = jnp.concatenate([sm * -32.0, sm * 8.0],
+                           axis=1).astype(jnp.bfloat16)
+    xpa = xpa()
+    # a dot a quarter: joined into one (TN, TK) plane first, the same work
+    # takes 1.34 ms where this takes 1.14 (153600 x 6144, on the chip): the
+    # float32 sums of a K tile are taken in another order than the stacked
+    # body's one dot takes them
+    p = dot(xpa[:, TK:], corr)
+    for c, q in enumerate(q6):
+        a = (pltpu.bitcast(q, jnp.int8).astype(jnp.float32) * eff
+             ).astype(jnp.bfloat16)               # (TN, 512)
+        p += dot(xpa[:, c * Q:(c + 1) * Q], a)
+    return p
+
+
+def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
+                     tiles, accumulate):
+    """The head's body: :func:`_q6k_tile_product` over ``tiles`` K tiles a
+    grid step.  xpa (tiles, B, TKA6), which is the whole of it, fetched once
+    a call, where the step holds all of K; q4 (TN, tiles * TK/2), q2 (TN,
+    tiles * TK/4) int8; sm (tiles, TN, 128)."""
+    Q = TK // 4
     part = None
     for j in range(tiles):
-        w4 = pltpu.bitcast(q4_ref[:, j * 2 * Q:(j + 1) * 2 * Q], jnp.int32)
-        w2 = pltpu.bitcast(q2_ref[:, j * Q:(j + 1) * Q], jnp.int32)
-        lo, hi = w4 & _NIB, (w4 >> 4) & _NIB          # hi: (hi - 8) mod 16
-        # (the crumb byte is stored less 128: its top bit, the fourth
-        # crumb's high one, arrives turned)
-        q6 = (lo[:, :Q] | ((w2 << 4) & _CRUMB),       # columns [0, 512)
-              lo[:, Q:] | ((w2 << 2) & _CRUMB),       # [512, 1024)
-              signed(hi[:, :Q] | (w2 & _CRUMB), 0),   # [1024, 1536)
-              signed(hi[:, Q:] | ((w2 >> 2) & _CRUMB), 0x20202020))
-        sm = sm_ref[j]                                # (TN, 128): eff = d·sc
-        eff = _lane_repeat(sm, Q // 128, interpret)
-        corr = jnp.concatenate([sm * -32.0, sm * 8.0],
-                               axis=1).astype(jnp.bfloat16)
-        xpa = xpa_ref[j]
-        # a dot a quarter: joined into one (TN, TK) plane first, the same
-        # work takes 1.34 ms where this takes 1.14 (153600 x 6144, on the
-        # chip): the float32 sums of a K tile are taken in another order
-        # than the stacked body's one dot takes them
-        p = dot(xpa[:, TK:], corr)
-        for c, q in enumerate(q6):
-            a = (pltpu.bitcast(q, jnp.int8).astype(jnp.float32) * eff
-                 ).astype(jnp.bfloat16)               # (TN, 512)
-            p += dot(xpa[:, c * Q:(c + 1) * Q], a)
+        p = _q6k_tile_product(
+            lambda: q4_ref[:, j * 2 * Q:(j + 1) * 2 * Q],
+            lambda: q2_ref[:, j * Q:(j + 1) * Q],
+            lambda: sm_ref[j], lambda: xpa_ref[j], interpret)
         part = p if part is None else part + p
     if accumulate:
         _q4k_accum(o_ref, part)
     else:
         o_ref[...] = part
+
+
+def _q6k_expert_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
+                       accum):
+    """The grouped expert calls' body (ops/pallas/experts.py): one K tile a
+    grid step of :func:`_q6k_tile_product`, folded into the output block by
+    the grid's own ``accum``.  xpa (rows, TKA6); q4 (TN, TK/2), q2 (TN,
+    TK/4) int8; sm (1, TN, 128)."""
+    TN = q4_ref.shape[0]
+    accum(o_ref, _q6k_tile_product(
+        lambda: q4_ref[...], lambda: q2_ref[...],
+        lambda: sm_ref[...].reshape(TN, 128), lambda: xpa_ref[...],
+        interpret))
+
+
+def wide_tn(N: int, interpret: bool) -> int:
+    """The widest N tile ``128 * d``, ``d`` up to :data:`HEAD_TN_UNITS`,
+    that divides N: the head's few-row calls' and the grouped Q6_K expert
+    calls' (a narrower N, in interpret mode only: what divides it)."""
+    return next((128 * d for d in range(HEAD_TN_UNITS, 0, -1)
+                 if N % (128 * d) == 0), None) or _pick_tn(N, interpret, ())
 
 
 def _head_tiling(N: int, B: int, kt: int, interpret: bool):
@@ -505,8 +543,7 @@ def _head_tiling(N: int, B: int, kt: int, interpret: bool):
     its own tiles, a K tile a step."""
     if B > TM:
         return _pick_tn(N, interpret, prefs=MANYROW_TN), 1
-    tn = next((128 * d for d in range(HEAD_TN_UNITS, 0, -1)
-               if N % (128 * d) == 0), None) or _pick_tn(N, interpret, ())
+    tn = wide_tn(N, interpret)
     tiles = max(t for t in range(1, kt + 1)
                 if kt % t == 0 and (t == 1 or tn * t * TK <= HEAD_W_BLOCK))
     return tn, tiles

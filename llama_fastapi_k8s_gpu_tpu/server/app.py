@@ -1538,6 +1538,11 @@ def create_app(engine=None, settings: Settings | None = None,
             rows = getattr(eng, "expert_rows", 0)
             if rows:
                 engine_info["expert_rows"] = rows
+            # and the bodies those calls run, by family (a build's kernel
+            # read without a trace, as ``head_kernel`` is)
+            kernel = getattr(eng, "expert_kernel", None)
+            if kernel:
+                engine_info["expert_kernel"] = kernel
             # a vocabulary the tokenizer cannot cut at spaces pays the
             # whole-text merge loop on every prompt (tokenizer/spm.py);
             # absent where it can

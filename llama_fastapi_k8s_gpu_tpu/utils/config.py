@@ -531,8 +531,8 @@ KNOBS: dict[str, Knob] = _register(
          default=""),
     Knob("LFKT_Q5K_KERNEL", str, "fused Q5_K kernel variant (A/B)",
          default=""),
-    Knob("LFKT_Q6K_KERNEL", str, "fused Q6_K kernel variant (A/B)",
-         default=""),
+    Knob("LFKT_Q6K_KERNEL", str,
+         "fused Q6_K variant of the stacked dense calls (A/B)", default=""),
 )
 
 

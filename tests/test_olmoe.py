@@ -432,16 +432,13 @@ def test_a_few_row_call_is_its_rows_through_the_dense_kernel_bit_for_bit(
 
 def _dense_call(X, fam):
     """``fn(xpa, *planes, interpret, variant)``: the dense fused matmul whose
-    body the grouped calls of ``fam`` run.  Q6_K: the STACKED call on a
-    stack of one (the unstacked call is the head's own: another body, whose
-    float32 sums are taken in another order)."""
-    import jax.numpy as jnp
-
+    body the grouped calls of ``fam`` run.  Q6_K: the UNSTACKED call, the
+    head's (``q6matmul._q6k_tile_product`` since PR 59; the stacked calls'
+    float body takes a K tile's float32 sums in another order), which has
+    no variant."""
     if fam.name == "q4k":
         return X._q4._q4k_2d_raw
-    return lambda xpa, *rest: X._q6._q6k_2d_stacked_raw(
-        jnp.zeros(1, jnp.int32), xpa, *(p[None] for p in rest[:-2]),
-        *rest[-2:])
+    return lambda xpa, *rest: X._q6._q6k_2d_raw(xpa, *rest[:-1])
 
 
 def _rows_with_an_expert(rng, R, E, n_real, used=None):
@@ -516,6 +513,70 @@ def test_a_compacted_call_is_the_call_of_all_rows_and_the_dense_kernel(
             rows = np.flatnonzero(row_expert == e)
             np.testing.assert_array_equal(back[rows], np.asarray(dense(
                 xpa, *(p[layer, e] for p in planes), True, "cur"))[rows])
+
+
+@pytest.mark.parametrize("N", [1024, 1280, 2048])   # 1280: the tile is 640
+@pytest.mark.parametrize("f", [1, 2])
+def test_grouped_q6k_calls_read_the_stacked_bodys_plane_bit_for_bit(f, N):
+    """The dequantized plane of the grouped Q6_K calls (the head's integer
+    body since PR 59) against the stacked dense body's, BIT FOR BIT: a
+    one-hot row reads one plane column (and its two correction terms) out
+    of either kernel exactly, whatever order the float32 sums are taken
+    in.  256 columns of the K tile, 64 of each quarter (four integer forms
+    take the quarters apart), through the few-row and the many-row call,
+    every column from both experts, unfolded and folded (``f`` 2: a row of
+    1024 offered twice in a row of 2048)."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q6matmul as Q6
+
+    E, K, G = 2, 2048 // f, 128
+    rng = np.random.default_rng(N + f)
+    fam = X.FAMILIES["q6k"]
+    planes = [
+        jnp.asarray(rng.integers(-128, 128, (1, E, N, 1024)), jnp.int8),
+        jnp.asarray(rng.integers(-128, 128, (1, E, N, 512)), jnp.int8),
+        jnp.asarray(rng.standard_normal((1, E, 1, N, 128)) * 1e-2,
+                    jnp.bfloat16)]
+    assert fam.tn(N, G * f, True) == {1024: 1024, 1280: 640, 2048: 1024}[N]
+    # the elements of a row of 2048 that land in the chosen tile columns;
+    # folded, copy ``element // K`` of the row holds it
+    cols = np.concatenate([q * 512 + rng.permutation(512)[:64]
+                           for q in range(4)])
+    landed = np.asarray(Q6.permute_x6(jnp.arange(2048)[None]))[0][cols]
+    x = np.zeros((2 * G, K), np.float32)
+    x[np.arange(2 * G), landed % K] = 1.0
+    x = jnp.asarray(x, jnp.bfloat16)
+    hot = np.asarray(Q6.permute_x6(X._fold_rows(x, f, 2 * G)), np.float32)
+    assert set(cols) <= set(np.nonzero(hot)[1])
+
+    def stacked(rows, e):       # (G, K) rows -> (G, N * f), expert e's plane
+        out = Q6._q6k_2d_stacked_raw(
+            jnp.zeros(1, jnp.int32), X._activations(
+                X._fold_rows(rows, f, G), fam),
+            *(p[0, e][None] for p in planes), interpret=True)
+        return np.asarray(X._unfold_rows(out, f, G))
+
+    def same(got, want):
+        assert np.array_equal(np.asarray(got).view(np.uint32),
+                              want.view(np.uint32))
+        assert np.abs(want).sum() > 0
+
+    halves = [x[:G], x[G:]]
+    want = [[stacked(rows, e) for e in range(E)] for rows in halves]
+    meta = jnp.asarray([0, E, 0, 1], jnp.int32)
+    for rows, by_expert in zip(halves, want):
+        for swap in range(E):
+            row_expert = (np.arange(G) + swap) % E
+            same(X.grouped_matmul_few(fam, meta, rows, jnp.asarray(
+                row_expert, jnp.int32), planes, f, True, None),
+                np.stack(by_expert)[row_expert, np.arange(G)])
+    # many rows: four tiles, both halves through expert 0, then through 1
+    tiles = jnp.asarray([0, 4, 0, 0, 1, 1], jnp.int32)
+    same(X.grouped_matmul_many(fam, tiles, jnp.concatenate(halves * E),
+                               planes, f, True, None),
+         np.concatenate([want[h][e] for e in range(E) for h in range(2)]))
 
 
 def _as_it_was_built(monkeypatch, run):
@@ -630,7 +691,7 @@ def _few_row_calls(M, k):
             S((1, E, 1, D // 2, 128), bf16)]
     jaxpr = jax.make_jaxpr(
         lambda *a: X._routed_raw(("q4k", "q4k", "q6k"), True,
-                                 ("resplit", "resplit", "cur"), *a))(
+                                 ("resplit", "resplit", None), *a))(
         S((), jnp.int32), S((M, D), bf16), S((M, k), jnp.int32),
         S((M, k), jnp.float32), *gate, *gate, *down)
 
@@ -662,17 +723,18 @@ def test_a_call_of_64_rows_or_fewer_is_built_as_it_always_was():
     calls on all its rows (the down call's K is folded: 128 rows); at 128
     rows the layer holds both forms under one ``cond``: three calls on 64
     rows, three on all of them.  Every call is the same kernel under the
-    same name with the same prefetched vector."""
+    same name with the same prefetched vector (a Q6_K call holds five dots
+    since PR 59: one a quarter of the K tile and the correction columns')."""
     T, few = 4, "expert_matmul_fewrow"
     calls, outside = _few_row_calls(8, 8)
     assert calls == [("q4k_" + few, 64, 2 + T, 3), ("q4k_" + few, 64, 2 + T, 3),
-                     ("q6k_" + few, 128, 2 + T, 2)]
+                     ("q6k_" + few, 128, 2 + T, 5)]
     assert "gather" not in outside and "cond" not in outside
     assert "cumsum" in outside          # the slots in use
     calls, outside = _few_row_calls(16, 8)
     assert sorted(calls) == sorted(
         [("q4k_" + few, rows, 2 + T, 3) for rows in (64, 128)] * 2
-        + [("q6k_" + few, 2 * rows, 2 + T, 2) for rows in (64, 128)])
+        + [("q6k_" + few, 2 * rows, 2 + T, 5) for rows in (64, 128)])
     assert "gather" in outside and outside.count("cond") == 1
 
 
@@ -1082,6 +1144,46 @@ async def test_health_names_the_slots_and_metrics_the_ones_skipped(gguf_path):
             m = (await client.get("/metrics")).text
             assert f"expert_rows_skipped_total {6 * 128 - 7}" in m
         await app.router.shutdown()
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("routed", [True, False])
+async def test_health_names_the_bodies_of_the_grouped_calls(
+        gguf_path, tmp_path, routed):
+    """/health ``engine.expert_kernel``: the bodies the grouped expert
+    calls run, by family, beside ``expert_slots`` on a file served through
+    them; no key on a dense file (nor ``expert_slots``)."""
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    if routed:
+        eng = Engine(gguf_path, weight_format="q4k", n_ctx=128)
+    else:
+        write_tiny_llama_gguf(str(tmp_path / "d.gguf"))
+        eng = Engine(str(tmp_path / "d.gguf"), n_ctx=64)
+    app = create_app(engine=eng, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            info = (await client.get("/health")).json()["engine"]
+        await app.router.shutdown()
+    if routed:
+        assert [X.FAMILIES[f].body for f in ("q4k", "q6k")] == [
+            "q4k-float", "q6k-int"]
+        assert eng.expert_kernel == "q4k-float+q6k-int"
+        assert info["expert_kernel"] == eng.expert_kernel
+        keys = list(info)
+        assert keys.index("expert_kernel") == keys.index("expert_slots") + 1
+    else:
+        assert eng.expert_kernel is None
+        assert "expert_kernel" not in info and "expert_slots" not in info
 
 
 def test_a_dense_file_has_no_expert_counters(tmp_path):

@@ -405,16 +405,25 @@ def test_head_call_ignores_the_variant_knob(monkeypatch):
     assert Q6.Q6K_VARIANTS == ("cur", "parfloor", "vbf32", "pre")
 
 
-# jax.make_jaxpr's text (kernel bodies in full) of the calls that keep the
-# body ``_q6k_matmul_kernel``, hashed on the parent (e1bd971) with
-# tools/traced_program_hashes.py: the stacked Q6_K call (a decode row, a
-# lane step's rows, a slice) and the routed layers, whose Q6_K down calls
-# are the grouped bodies (few rows, the lanes' vmap, a compacted call, many
-# rows, interpret mode)
+# jax.make_jaxpr's text (kernel bodies in full) of the DENSE Q6_K calls,
+# hashed on the parent (5c73b4e) with tools/traced_program_hashes.py: the
+# stacked call, which keeps the body ``_q6k_matmul_kernel`` (a decode row, a
+# lane step's rows, a slice), and the head's call (a decode row, a lane
+# step's rows, a slice, interpret mode), whose body PR 59 took apart into
+# ``_q6k_tile_product`` for the grouped expert calls to share
 PARENT_TRACED = {
     "stacked.q6k.4096x4096.r1.tpu": "fb7d69116df06667",
     "stacked.q6k.14336x4096.r8.tpu": "b49126df7b5baa31",
     "stacked.q6k.4096x4096.r512.tpu": "6d535e3614120b0a",
+    "dense.q6k.4096x32000.r1.tpu": "97ef5a2f1f5029d3",
+    "dense.q6k.4096x32000.r8.tpu": "3cb7b282a86a227c",
+    "dense.q6k.4096x4096.r512.tpu": "6e6453ee43abfd43",
+    "dense.q6k.4096x4096.r1.interp": "4872229a7f481e76",
+}
+# the routed layers as the parent traced them (few rows, the lanes' vmap, a
+# compacted call, many rows, interpret mode): their Q6_K down calls held the
+# stacked calls' float body, and hold the head's since PR 59
+PARENT_ROUTED = {
     "routed.olmoe.t8.tpu": "681794971179b5d7",
     "routed.lfm2.t16.tpu.vmap": "47d36bafe2293224",
     "routed.longcat.t16.tpu": "572eab122c93cff3",
@@ -423,7 +432,7 @@ PARENT_TRACED = {
 }
 
 
-def test_stacked_and_grouped_q6k_calls_trace_to_the_text_they_had():
+def _traced_hashes(keys):
     import importlib.util
     import os
 
@@ -433,7 +442,19 @@ def test_stacked_and_grouped_q6k_calls_trace_to_the_text_they_had():
             "tools", "traced_program_hashes.py"))
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
-    assert tool.hashes(only=PARENT_TRACED.__contains__) == PARENT_TRACED
+    return tool.hashes(only=keys.__contains__)
+
+
+def test_stacked_and_grouped_q6k_calls_trace_to_the_text_they_had():
+    """The dense programs are the parent's: sharing the head's
+    dequantization with the grouped calls changed no dense call's text."""
+    assert _traced_hashes(PARENT_TRACED) == PARENT_TRACED
+
+
+def test_routed_layers_no_longer_trace_to_the_stacked_body():
+    got = _traced_hashes(PARENT_ROUTED)
+    assert got.keys() == PARENT_ROUTED.keys()
+    assert all(got[key] != PARENT_ROUTED[key] for key in got), got
 
 
 @pytest.mark.parametrize("leaf,name", [

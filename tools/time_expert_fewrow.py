@@ -2,14 +2,20 @@
 ``_grouped_call``), at the five routed configurations' shapes, over the
 number of slots in use and, for a call of more than ``ROW_GROUP`` rows, the
 rows that reach an expert: the parent commit's call (``--parent``: its
-``experts.py``, loaded beside this tree's under another name; every slot
-multiplies all the rows) and this tree's side by side (a call of
-``ROW_GROUP`` rows on the rows that reach an expert), the same planes, rows
-and slots, the results compared bit for bit.  Without the parent's file it
-times this tree's alone.  ``--layer`` times the whole layer after the
-router instead (``routed_experts``: the compaction, the choice between
-the two calls, the three products, the gather back and the weighted sum),
-which is what a decode step pays.
+``experts.py``, loaded beside this tree's under another name, with ITS
+families: the body and the N tile it hands the call) and this tree's side
+by side, the same planes, rows and slots.  A Q4_K call's results are
+compared bit for bit (``same_bits``).  A Q6_K call's are not equal since PR
+59: the parent runs the stacked dense calls' float body, this tree the
+head's integer one, which builds the same bfloat16 plane (tier-1 holds it
+bit for bit, tests/test_olmoe.py) and takes a K tile's float32 sums a
+quarter at a time; ``max_rel`` is the largest difference over the largest
+result.  ``--q6k-tn 256,1024`` times this tree's Q6_K body under those N
+tiles beside its own rule (the body at the old tile, the tile at the new
+body).  Without the parent's file it times this tree's alone.  ``--layer``
+times the whole layer after the router instead (``routed_experts``: the
+compaction, the choice between the two calls, the three products, the
+gather back and the weighted sum), which is what a decode step pays.
 
 A call takes 50-700 us and a dispatch round trip about 1 ms, so a timing is
 the SLOPE of one jitted loop of calls over its trip count (the layer index
@@ -25,6 +31,7 @@ A device number: it refuses to run without a TPU."""
 from __future__ import annotations
 
 import argparse
+import copy
 import importlib.util
 import json
 import os
@@ -68,6 +75,8 @@ MANY = (
     ("lfm2.down", "q6k", 1024, 4, 64, 64, 2048, 1536),
     ("gigachat.gate", "q4k", 1024, 8, 256, 32, 2048, 7168),
     ("gigachat.down", "q6k", 1024, 8, 256, 32, 7168, 2048),
+    ("olmoe.down", "q6k", 1024, 8, 64, 64, 2048, 1024),     # folded: 256 rows
+    ("longcat.down", "q6k", 256, 12, 768, 64, 6144, 2048),  # a held share
 )
 LAYERS = 3
 
@@ -76,10 +85,14 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=".parent_check/llama_fastapi_k8s_gpu_tpu"
                     "/ops/pallas/experts.py",
-                    help="the parent commit's experts.py (its kernel bodies "
-                    "are this tree's: PR 51 changed the grid alone)")
+                    help="the parent commit's experts.py")
+    ap.add_argument("--q6k-tn", default="",
+                    help="N tiles to time this tree's Q6_K body under, "
+                    "beside its own rule")
     ap.add_argument("--no-many", action="store_true",
                     help="skip the wide-slice shapes")
+    ap.add_argument("--no-few", action="store_true",
+                    help="skip the decode steps' shapes")
     ap.add_argument("--reps", type=int, default=8)
     ap.add_argument("--calls", type=int, default=24,
                     help="n: the loops run n and 3n calls")
@@ -94,6 +107,10 @@ def main() -> int:
     ap.add_argument("--group", type=int, default=0,
                     help="this tree's ROW_GROUP for the run (what it keeps "
                     "was chosen with this)")
+    ap.add_argument("--all-rows", action="store_true",
+                    help="a call of more than ROW_GROUP rows as the layer "
+                    "makes it when more than ROW_GROUP reach an expert: all "
+                    "the rows, every slot multiplies them")
     ap.add_argument("--layer", action="store_true",
                     help="time the whole layer after the router instead")
     ap.add_argument("--out", default="chiprun_out/time_expert_fewrow.jsonl")
@@ -113,10 +130,19 @@ def main() -> int:
 
     sides = {"new": X}
     if os.path.exists(args.parent):
-        spec = importlib.util.spec_from_file_location(
-            X.__name__ + "_parent", args.parent)
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
+        def load(name, path):
+            spec = importlib.util.spec_from_file_location(name, path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            return mod
+
+        # the parent's experts.py with the Q6_K body of ITS q6matmul.py
+        # (its other imports are this tree's)
+        parent = load(X.__name__ + "_parent", args.parent)
+        q6 = load(X._q6.__name__ + "_parent", os.path.join(
+            os.path.dirname(args.parent), "q6matmul.py"))
+        fam = parent.FAMILIES["q6k"]
+        fam.kernel = getattr(q6, fam.kernel.__name__)
         sides = {"parent": parent, "new": X}
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -168,36 +194,60 @@ def main() -> int:
         return 1e6 * (ts[1] - ts[0]) / (2 * args.calls), \
             np.asarray(jax.jit(call)(*a))
 
-    def run_sides(label, geometry, few, fam, rows, meta, xpa, extra_in,
-                  planes, new=None):
-        """``_grouped_call(fam, meta, xpa, planes, rows, few, extra_in,
-        interpret, variant)`` of each side on the same operands; ``new``:
-        this tree's compacted operands (xpa, extra_in, each row's place)
-        where its call takes others."""
-        got = {}
+    def families(famname, N, rows):
+        """(side, module, family, N tile) of each side to time: the parent
+        under its own families, this tree, and this tree's Q6_K body under
+        each ``--q6k-tn`` tile that divides N."""
+        out = []
         for side, mod in sides.items():
-            place = None
-            a, ex = xpa, extra_in
-            if new and side == "new":
-                a, ex, place = new
+            fam = mod.FAMILIES[famname]
+            # (a parent before PR 59 keeps a family's tiles as ``tn_prefs``)
+            tn = fam.tn(N, rows, False) if hasattr(fam, "tn") else \
+                mod._pick_tn(N, False, prefs=mod._tn_prefs_for(
+                    rows, fam.tn_prefs))
+            out.append((side, mod, fam, tn))
+        for tn in (int(t) for t in args.q6k_tn.split(",") if t):
+            if famname == "q6k" and N % tn == 0:
+                fam = copy.copy(X.FAMILIES[famname])
+                fam.tn = lambda N, rows, interpret, tn=tn: tn
+                out.append((f"new.tn{tn}", X, fam, tn))
+        return out
 
-            def call(meta, xpa, *rest, mod=mod):
+    def run_sides(label, few, famname, N, K, rows, meta, xpa, extra_in,
+                  planes, place=None):
+        """``_grouped_call(fam, meta, xpa, planes, rows, few, extra_in,
+        interpret, variant)`` of each side on the same operands; ``place``:
+        each row's place in a compacted call's result."""
+        got = {}
+        for side, mod, fam, tn in families(
+                famname, N, xpa.shape[0] if few else rows):
+
+            def call(meta, xpa, *rest, mod=mod, fam=fam):
                 return mod._grouped_call(
                     fam, meta, xpa, rest[len(extra_in):], xpa.shape[0]
                     if few else rows, few, rest[:len(extra_in)], False,
                     "cur")
-            us, out = slope_us(call, meta, a, *ex, *planes)
+            row = dict(label, side=side, TN=tn,
+                       steps_a_slot=(N // tn) * (K // X.TK))
+            try:
+                us, out = slope_us(call, meta, xpa, *extra_in, *planes)
+            except Exception as err:    # a tile the compiler refuses
+                say(**row, error=str(err)[-300:])
+                continue
             if place is not None:       # back from the places to the rows
                 out = np.concatenate([out, np.zeros_like(out[:1])])[place]
-            row = dict(label, side=side, us=round(us, 2))
-            if "parent" in got:
+            row["us"] = round(us, 2)
+            if "_out" in got:
                 # a many-row call leaves the tiles past the last in use
                 # unwritten: compare the rows of the tiles in use
                 live = label["used"] * rows if not few else out.shape[0]
-                row["same_bits"] = bool(
-                    (out[:live] == got["_out"][:live]).all())
-            got[side], got["_out"] = us, out
-            say(**row, **geometry)
+                want = got["_out"][:live]
+                row["same_bits"] = bool((out[:live] == want).all())
+                row["max_rel"] = float(np.abs(out[:live] - want).max()
+                                       / max(np.abs(want).max(), 1e-30))
+            else:
+                got["_out"] = out
+            say(**row)
 
     def write() -> int:
         with open(args.out, "w") as fh:
@@ -209,14 +259,15 @@ def main() -> int:
         return write()
 
     # ---- few rows: a decode step's call over the slots in use (and the
-    # rows in use, where this tree compacts them)
+    # rows in use, where the layer compacts them: both sides do since PR 53;
+    # --all-rows: the call of all the rows it falls back to)
     for name, famname, R, E, n_out, k_in in FEW:
-        if not wanted(name):
+        if not wanted(name) or args.no_few:
             continue
         fam = X.FAMILIES[famname]
         planes, f, N, K = planes_of(fam, E, n_out, k_in)
         T = min(E, R)
-        compacted = R > X.ROW_GROUP
+        compacted = R > X.ROW_GROUP and not args.all_rows
         rows = R * f + (-(R * f) % 16)
         x = jax.random.normal(jax.random.PRNGKey(1), (R, k_in), jnp.bfloat16)
 
@@ -246,12 +297,12 @@ def main() -> int:
                 meta = jnp.concatenate([jnp.zeros(1, i32), n_used[None],
                                         slots])
                 xpa, re = operands(x, row_expert)
-                new = None
+                back = None
                 if compacted:
                     places = X.ROW_GROUP
                     place, src, _ = X.compact_rows(row_expert, E, places)
                     zero = jnp.zeros((1, k_in), x.dtype)
-                    xc, rec = operands(
+                    xpa, re = operands(
                         jnp.concatenate([x, zero])[src],
                         jnp.concatenate([row_expert, jnp.full(1, E, i32)]
                                         )[src], R=places)
@@ -259,14 +310,11 @@ def main() -> int:
                     place = np.asarray(place)
                     back = np.concatenate(
                         [np.where(place < places, j * places + place,
-                                  xc.shape[0]) for j in range(f)])
-                    new = (xc, (rec,), back)
-                TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(
-                    X.ROW_GROUP * f if compacted else rows, fam.tn_prefs))
-                run_sides(dict(regime="few", shape=name, rows=rows, N=N, K=K,
-                               T=T, used=used, real=real),
-                          dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK)),
-                          True, fam, rows, meta, xpa, (re,), planes, new)
+                                  xpa.shape[0]) for j in range(f)])
+                run_sides(dict(regime="few", shape=name, rows=xpa.shape[0],
+                               N=N, K=K, T=T, used=used, real=real),
+                          True, famname, N, K, rows, meta, xpa, (re,),
+                          planes, back)
         del planes
     # ---- many rows: a wide slice's call, the tiles the plan lays out
     for name, famname, M, k, E_all, E, n_out, k_in in MANY:
@@ -286,12 +334,9 @@ def main() -> int:
                                jnp.bfloat16)
         xpa = X._activations(X._fold_rows(xp, f, X.TM_MANY), fam)
         rows = X.TM_MANY * f
-        TN = X._pick_tn(N, False, prefs=X._tn_prefs_for(rows, fam.tn_prefs))
-
         run_sides(dict(regime="many", shape=name, rows=rows, N=N, K=K, T=T,
                        used=used),
-                  dict(TN=TN, steps_a_slot=(N // TN) * (K // X.TK)),
-                  False, fam, rows, meta, xpa, (), planes)
+                  False, famname, N, K, rows, meta, xpa, (), planes)
         del planes
 
     return write()
