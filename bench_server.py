@@ -382,7 +382,7 @@ def main() -> None:
         eng = ContinuousEngine.from_parts(
             params, cfg, tok, template_kind="llama3",
             max_gen_tokens=max_tokens, attn_impl=cfg.attn_impl,
-            dp=1, batch_size=batch,
+            batch_size=batch,
             # honor the same LFKT_* scheduler knobs the production factory
             # does (server/app.py passes each from Settings) — a
             # directly-constructed engine otherwise pins constructor
@@ -419,7 +419,7 @@ def main() -> None:
             eng_b = ContinuousEngine.from_parts(
                 params, cfg, tok, template_kind="llama3",
                 max_gen_tokens=max_tokens, attn_impl=cfg.attn_impl,
-                dp=1, batch_size=batch,
+                batch_size=batch,
                 decode_chunk=settings.decode_chunk,
                 adm_budget=settings.adm_budget,
                 adm_controller=settings.adm_controller,

@@ -2,7 +2,6 @@
 """chip_smoke.py — the quickest proof that the serving path runs on the chip.
 
     python chip_smoke.py              # one TPU chip (what the driver runs)
-    python chip_smoke.py --chips 4    # the tensor-parallel path, four chips
 
 Drives Llama-3-8B Q4_K_M at full width and depth (random weights from a
 seed, written here as a GGUF file by the package's own writer) through the
@@ -249,8 +248,8 @@ def metric_value(metrics_text: str, name: str) -> float | None:
 
 
 def server_phase(phase: str, model_name: str, extra_env: dict,
-                 n_sequential: int, n_concurrent: int, deadline: float,
-                 want_attn: str = "pallas") -> dict:
+                 n_sequential: int, n_concurrent: int,
+                 deadline: float) -> dict:
     """Start the server through its normal entry point, wait until it is
     ready, send the requests, read back what it says about itself, SIGTERM
     it and wait for it to exit."""
@@ -350,8 +349,8 @@ def server_phase(phase: str, model_name: str, extra_env: dict,
     problems = []
     if out["health_state"] != "READY":
         problems.append(f"health state {out['health_state']!r}")
-    if out["attn_impl"] != want_attn:
-        problems.append(f"attn_impl {out['attn_impl']!r}, want {want_attn!r}")
+    if out["attn_impl"] != "pallas":
+        problems.append(f"attn_impl {out['attn_impl']!r}, want 'pallas'")
     fm = out["weight_formats"] or {}
     want = {"wq": "q4k-fused", "wk": "q4k-fused", "wv": "q6k-fused",
             "wo": "q4k-fused", "w_gate": "q4k-fused", "w_up": "q4k-fused",
@@ -403,17 +402,17 @@ def wait_writer(proc: subprocess.Popen, timeout: float) -> dict:
     return doc
 
 
-def device_phase(chips: int) -> dict:
+def device_phase() -> dict:
     """The device facts, from a child that held the chip.  The child itself
     fails a run without a TPU or in interpret mode."""
     dev = run_child("device", [], timeout=180)
-    if dev.get("count") != chips:
-        raise PhaseFailed("device", f"{dev.get('count')} device(s), want {chips}")
+    if dev.get("count") != 1:
+        raise PhaseFailed("device", f"{dev.get('count')} device(s), want 1")
     return dev
 
 
 def run_one_chip(args, deadline: float) -> dict:
-    dev = device_phase(1)
+    dev = device_phase()
     # the writers need no chip: they run beside the kernel phase
     w2 = start_writer(MODEL_2L, 2, args.seed)
     w32 = start_writer(MODEL, 32, args.seed)
@@ -428,9 +427,8 @@ def run_one_chip(args, deadline: float) -> dict:
     lanes = server_phase("server_lanes8", MODEL,
                          {"LFKT_BATCH_SIZE": "8"}, n_sequential=1,
                          n_concurrent=8, deadline=deadline)
-    # Reported, not required: the lane engine places its weights on a
-    # 1x1 mesh and vmaps its steps, so none of its programs is one the
-    # serial engine compiled.  What the cache saves shows from one RUN
+    # Reported, not required: the lane engine vmaps its steps, so none
+    # of its decode programs is one the serial engine compiled.  What the cache saves shows from one RUN
     # to the next, in both phases' hits and warm-up seconds.
     emit({"phase": "compile_cache", "ok": True,
           "dir": (lanes.get("persistent_cache") or {}).get("dir"),
@@ -438,41 +436,6 @@ def run_one_chip(args, deadline: float) -> dict:
           "second_phase": lanes.get("persistent_cache"),
           "first_phase_warmup_s": (first.get("load_phases") or {}).get("warmup_s"),
           "second_phase_warmup_s": (lanes.get("load_phases") or {}).get("warmup_s")})
-    return dev
-
-
-def run_four_chips(args, deadline: float) -> dict:
-    """The tensor-parallel path and what it is compared with, nothing else."""
-    dev = device_phase(4)
-    wait_writer(start_writer(MODEL, 32, args.seed), 600)
-    path = os.path.join(WORK, MODEL)
-    run_child("tp_compare", ["--path", path, "--seed", str(args.seed)],
-              timeout=900)
-    greedy = {"LFKT_TEMPERATURE": "0", "LFKT_MAX_GEN_TOKENS": "48",
-              "LFKT_BATCH_SIZE": "4"}
-    # a mesh serves XLA attention: the flash kernel has no partitioning rule
-    tp4 = server_phase("server_tp4", MODEL,
-                       {**greedy, "LFKT_MESH_TP": "4"}, n_sequential=1,
-                       n_concurrent=4, deadline=deadline, want_attn="xla")
-    # what it is compared with: the same requests at tp=1, on one of the
-    # four chips (libtpu's own variables hide the other three)
-    tp1 = server_phase("server_tp1", MODEL,
-                       {**greedy, "LFKT_MESH_TP": "1", "TPU_VISIBLE_CHIPS": "0",
-                        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
-                        "TPU_PROCESS_BOUNDS": "1,1,1"}, n_sequential=1,
-                       n_concurrent=4, deadline=deadline)
-    a, b = tp4["requests"][0]["reply_head"], tp1["requests"][0]["reply_head"]
-    same = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
-                min(len(a), len(b)))
-    ok = same > 0
-    emit({"phase": "tp4_vs_tp1_server", "ok": ok,
-          "greedy_reply_chars_agree_prefix": same,
-          "compared_chars": min(len(a), len(b)),
-          "note": "greedy replies to the same request; random weights give "
-                  "near-flat logits, so a late divergence is rounding"})
-    if not ok:
-        raise PhaseFailed("tp4_vs_tp1_server",
-                          "greedy replies differ from the first character")
     return dev
 
 
@@ -485,7 +448,7 @@ def parent_main(args) -> int:
     shutil.rmtree(WORK, ignore_errors=True)
     os.makedirs(WORK, exist_ok=True)
     try:
-        dev = (run_four_chips if args.chips == 4 else run_one_chip)(args, deadline)
+        dev = run_one_chip(args, deadline)
     except PhaseFailed as e:
         emit({"ok": False, "phase": e.phase, "error": e.error})
         return 1
@@ -745,115 +708,12 @@ def child_logits2(args) -> None:
            "error": None if ok else "served logits disagree with bf16+xla"})
 
 
-def child_tp_compare(args) -> None:
-    """Four chips, one process.  Load the file as the server's tp=4 factory
-    does and check (a) each device holds about a quarter of the weight
-    bytes, (b) the fused matmuls are still kernels under
-    ``custom_partitioning``, (c) prefill logits and greedy tokens agree
-    with the one-chip engine on the same file."""
-    import gc
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine
-    from llama_fastapi_k8s_gpu_tpu.models.generate import prefill_jit
-    from llama_fastapi_k8s_gpu_tpu.models.llama import init_cache
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import _fused_key
-    from llama_fastapi_k8s_gpu_tpu.server.app import _base_engine_kwargs
-    from llama_fastapi_k8s_gpu_tpu.utils.config import get_settings
-    from llama_fastapi_k8s_gpu_tpu.utils.jaxcache import setup_compile_cache
-
-    setup_compile_cache()
-    devs = _require_tpu(4)
-    kw = _base_engine_kwargs(get_settings())    # what the server would pass
-    rng = np.random.default_rng(args.seed)
-    ids = [int(t) for t in rng.integers(0, 128000, 200)]
-    padded = jnp.asarray(ids + [0] * (256 - len(ids)), jnp.int32)
-    msgs = [{"role": "user", "content": "tell me about the weather today"}]
-    n_tok = 32
-
-    def in_use():
-        return [int((d.memory_stats() or {}).get("bytes_in_use", 0))
-                for d in devs]
-
-    def arm(eng):
-        logits, _ = prefill_jit(eng.params, eng.cfg, padded,
-                                jnp.int32(len(ids)), init_cache(eng.cfg))
-        logits = np.asarray(jax.device_get(logits), np.float32)
-        r = eng.create_chat_completion(msgs, max_tokens=n_tok, temperature=0.0)
-        text = r["choices"][0]["message"]["content"]
-        return logits, eng.tokenizer.encode(text)
-
-    eng1 = Engine(args.path, **kw)                 # one chip: device 0
-    one_chip = in_use()
-    lg1, toks1 = arm(eng1)
-    del eng1
-    gc.collect()
-
-    eng4 = ContinuousEngine(args.path, tp=4, batch_size=4, **kw)
-    fused = [n for n, leaf in eng4.params["layers"].items()
-             if isinstance(leaf, dict) and _fused_key(leaf)]
-    def held(tree):
-        leaves = jax.tree.leaves(tree)
-        return (sum(x.nbytes for x in leaves),
-                [sum(s.data.nbytes for x in leaves
-                     for s in x.addressable_shards if s.device == d)
-                 for d in devs])
-
-    # the layer stack is what tensor parallelism shards; the embedding table
-    # is replicated by design, and so is the head when vocab / tp is not a
-    # multiple of the kernel's 128-row tile (128256 / 4 = 32064 is not)
-    weight_bytes, per_dev = held(eng4.params)
-    layer_bytes, per_dev_layers = held(eng4.params["layers"])
-    four_chips = in_use()
-    hlo = prefill_jit.__wrapped__.lower(
-        eng4.params, eng4.cfg, padded, jnp.int32(len(ids)),
-        init_cache(eng4.cfg)).compile().as_text()
-    lg4, toks4 = arm(eng4)
-    agree = next((i for i, (a, b) in enumerate(zip(toks1, toks4)) if a != b),
-                 min(len(toks1), len(toks4)))
-    rel = float(np.linalg.norm(lg4 - lg1) / np.linalg.norm(lg1))
-    share = [b / layer_bytes for b in per_dev_layers]
-    kernels = hlo.count("tpu_custom_call")
-    problems = []
-    if eng4.cfg.attn_impl != "xla":
-        problems.append(f"attn_impl {eng4.cfg.attn_impl!r} on a mesh")
-    if not all(0.22 <= s <= 0.28 for s in share):
-        problems.append(f"the layer stack is not spread over the chips: {share}")
-    if fused and not kernels:
-        problems.append("no tpu_custom_call in the tp=4 prefill program")
-    if not (np.isfinite(lg4).all() and rel <= LOGIT_TOL):
-        problems.append(f"tp=4 logits differ from one chip by {rel}")
-    if lg1.argmax() != lg4.argmax() or agree < 1:
-        problems.append("first greedy token differs")
-    final({"phase": "tp_compare", "ok": not problems,
-           "attn_impl_on_the_mesh": eng4.cfg.attn_impl,
-           "weight_format_setting": kw["weight_format"],
-           "fused_linears": fused,
-           "weight_bytes": weight_bytes, "per_device_weight_bytes": per_dev,
-           "layer_stack_bytes": layer_bytes,
-           "per_device_layer_stack_share": [round(s, 3) for s in share],
-           "bytes_in_use_one_chip_engine": one_chip,
-           "bytes_in_use_tp4_engine": four_chips,
-           "tpu_custom_calls_in_tp4_prefill": kernels,
-           "all_gathers_in_tp4_prefill": hlo.count("all-gather("),
-           "all_reduces_in_tp4_prefill": hlo.count("all-reduce("),
-           "logits_rel_l2_err": round(rel, 5), "tolerance": LOGIT_TOL,
-           "greedy_tokens_compared": min(len(toks1), len(toks4)),
-           "greedy_tokens_agree_prefix": agree,
-           "error": "; ".join(problems) or None})
-
-
 CHILDREN = {"write": child_write, "device": child_device,
-            "kernels": child_kernels, "logits2": child_logits2,
-            "tp_compare": child_tp_compare}
+            "kernels": child_kernels, "logits2": child_logits2}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
     ap.add_argument("--path", help=argparse.SUPPRESS)

@@ -22,8 +22,8 @@ in-tree, TPU-first:
 - ``engine``     — the drop-in replacement for ``llama_cpp.Llama``:
                    ``Engine.create_chat_completion`` with OpenAI-shaped
                    responses and streaming.
-- ``parallel``   — device meshes, tensor/data/sequence-parallel shardings via
-                   ``jax.sharding`` + XLA collectives over ICI.
+- ``parallel``   — the lane engine's vmapped decode programs and the
+                   block-paged KV pool, on the process's one device.
 - ``server``     — the FastAPI layer preserving the reference's externally
                    observable behavior (routes, admission queue, timeouts),
                    plus the advertised-but-missing ``/health`` and ``/metrics``.

@@ -1,6 +1,4 @@
-from .batched import MeshEngine  # noqa: F401
 from .continuous import ContinuousEngine  # noqa: F401
 from .engine import Engine  # noqa: F401
 from .fake import FakeEngine  # noqa: F401
-from .sp import SPEngine  # noqa: F401
 from .watchdog import Watchdog  # noqa: F401

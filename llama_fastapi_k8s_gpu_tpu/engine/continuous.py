@@ -1,15 +1,19 @@
-"""Continuous batching: slot-based scheduling over the mesh-batched engine.
+"""Continuous batching: slot-based scheduling over B lanes of one device.
 
-:class:`MeshEngine` coalesces requests into *cycles* — everyone admitted
-together, nobody new until the whole cycle drains.  This module removes the
-barrier: the batch's B lanes become **slots**; at every decode-chunk boundary
-finished lanes are freed and waiting requests are admitted into them
+The serial :class:`Engine` generates one request at a time.  This module
+steps B sequences as one vmapped program (parallel/batched.py) and makes
+the batch's B lanes **slots**: at every decode-chunk boundary finished
+lanes are freed and waiting requests are admitted into them
 (single-sequence prefill into a scratch cache, then a jit'd lane write into
 the batched state).  Decode keeps running for whatever lanes are live, so
 short requests exit early and long ones never block admission — the
 vLLM-style serving loop, TPU-native: static shapes throughout, one compiled
-program per (bucket | chunk | lane-write) shape, batch dim sharded over
-``dp`` and the model over ``tp``.
+program per (slice | chunk | lane-write) shape.  Decode efficiency is the
+point: a single-sequence decode matvec cannot saturate HBM; B lanes
+multiply decode throughput at nearly constant step latency (weights are
+read once per step regardless of B).  Weights, the serial ring, the
+scratch ring and the lane state all live plainly on the process's one
+device: there is no mesh.
 
 The reference's concurrency model (one generation at a time behind
 Queue(5)+Semaphore(1), reference api.py:110-116) is the degenerate B=1 case;
@@ -40,14 +44,23 @@ from ..obs.memledger import register_component, tree_nbytes
 from ..obs.trace import (annotate_all_inflight, end_first_token, phase,
                          rid)
 from ..parallel.batched import (
-    batched_generate_chunk_perlane_jit, init_lane_left, left_after)
+    batched_generate_chunk_perlane_jit, init_batched_state, init_lane_left,
+    left_after)
 from ..sampling.sample import SamplingParams, sampling_tensors, seed_window
 from ..utils.faults import FAULTS
 from ..utils.health import DeadlineExceeded, EngineUnavailable
-from .batched import MeshEngine
+from .engine import Engine
 from .slices import next_slice
 
 logger = logging.getLogger(__name__)
+
+
+def _ledger_lane_bytes(eng: "ContinuousEngine") -> int:
+    """Memory-ledger provider: the batched lane state's resident bytes,
+    cache lanes + decode bookkeeping (snapshot-time metadata read —
+    obs/memledger.py; ``.nbytes`` is shape metadata, safe even while a
+    donating chunk program holds the buffers in flight)."""
+    return tree_nbytes(getattr(eng, "_bstate", None))
 
 
 def _ledger_scratch_bytes(eng: "ContinuousEngine") -> int:
@@ -261,8 +274,9 @@ class _Slot:
         self.created = int(time.time())
 
 
-class ContinuousEngine(MeshEngine):
-    """MeshEngine + a background scheduler thread with per-lane admission.
+class ContinuousEngine(Engine):
+    """An :class:`Engine` with ``batch_size`` lanes and a background
+    scheduler thread with per-lane admission.
 
     Use :meth:`submit` (returns a ``concurrent.futures.Future`` resolving to
     the OpenAI-shaped dict) or the blocking ``create_chat_completion`` /
@@ -271,10 +285,10 @@ class ContinuousEngine(MeshEngine):
 
     # -- thread discipline (machine-checked: lfkt-lint LOCK001-004, see
     # docs/RUNBOOK.md "Lock discipline annotations") ----------------------
-    # The scheduler thread OWNS the device state: unlike MeshEngine (whose
-    # callers mutate _bstate under _lock), every serving-path write to the
-    # state below happens on the lfkt-scheduler thread, so the parent's
-    # lock mapping is replaced by thread confinement.  The only
+    # The scheduler thread OWNS the device state: unlike the serial engine
+    # (whose callers mutate the ring under _lock), every serving-path write
+    # to the state below happens on the lfkt-scheduler thread, so the
+    # parent's lock mapping is replaced by thread confinement.  The only
     # cross-thread writes are in recover(), which runs strictly after the
     # thread is proven dead (join + alive/_loop_error guards).
     _GUARDED_BY = {
@@ -284,7 +298,6 @@ class ContinuousEngine(MeshEngine):
         "_req_counter": "_id_lock",
     }
     _THREAD_ENTRIES = ("_loop",)
-    _asks = {}   # prompts enter in slices: every cache kind takes them
     _THREAD_CONFINED = (
         "_bstate", "_lane_st", "_lane_left", "_scratch_cache", "_adm",
         "_lane_claims",
@@ -299,13 +312,22 @@ class ContinuousEngine(MeshEngine):
     _SHARED_ATOMIC = ("_items", "_pending", "_wake", "_stop", "_shutdown",
                       "_thread", "cache_counts", "pass_counts")
 
-    def __init__(self, model_path: str | None, *, max_top_k: int = 64,
+    def __init__(self, model_path: str | None, *, batch_size: int = 1,
+                 max_top_k: int = 64,
                  prefill_chunk: int = 256, adm_budget: int = 512,
                  adm_controller: bool = True, adm_ema_alpha: float = 0.25,
                  lane_prefix_cache: bool = True, **kw):
         # the admission prompt-slice size doubles as the serial overlapped-
         # prefill slice size, so it lives on Engine (self._prefill_chunk)
         super().__init__(model_path, prefill_chunk=prefill_chunk, **kw)
+        #: the lanes: how many sequences one decode chunk steps
+        self.batch_size = int(batch_size)
+        with self.startup.phase("lanes_alloc"):
+            self._bstate = init_batched_state(self.cfg, self.batch_size)
+        # lfkt-mem: the shared lane state is this engine's biggest serving
+        # allocation — attribute it (the provider reads the live reference,
+        # so watchdog re-inits stay correct automatically)
+        register_component("kv_lanes", self, _ledger_lane_bytes)
         #: prefill-token budget per scheduler wave.  Static when the
         #: admission controller is off (LFKT_ADM_CONTROLLER=0): with short
         #: prompts several COMPLETE admissions fit one wave, and a long
@@ -377,7 +399,7 @@ class ContinuousEngine(MeshEngine):
         sched = self.startup.phase("scheduler_start")
         self._scratch_cache = init_cache(self.cfg)
         # lfkt-mem: attribute the persistent prefill scratch (the lane
-        # state rode MeshEngine's registration; the serial ring the base's)
+        # state is registered above; the serial ring by the base)
         register_component("kv_scratch", self, _ledger_scratch_bytes)
         #: previous wave's memory-pressure verdict: the rising edge emits
         #: ONE mem_pressure trace event + counter, not one per wave
@@ -603,7 +625,9 @@ class ContinuousEngine(MeshEngine):
         # probes and no scheduler thread, queueing every request into a
         # 408 (code-review r2 finding)
         with self._lock:
-            self._recover_locked()          # fresh serial ring + batched state
+            self._recover_locked()          # fresh serial ring (+ pool)
+        # a crash mid-chunk may have poisoned the donated lane state
+        self._bstate = init_batched_state(self.cfg, self.batch_size)
         self._scratch_cache = init_cache(self.cfg)
         base_st = sampling_tensors(SamplingParams())
         self._lane_st = jax.tree.map(

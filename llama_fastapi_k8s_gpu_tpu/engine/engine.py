@@ -152,17 +152,6 @@ class Engine:
         "last_timings": "_id_lock",
     }
 
-    #: sliced bucket prefill (prefill_chunk/prefill_overlap) runs the ring
-    #: through prefill_chunk_jit, which assumes an UNSHARDED n_ctx dim —
-    #: the sequence-parallel engine (engine/sp.py) overrides this to False
-    #: and keeps its rerouted monolithic ring prefill.
-    _SLICE_PREFILL = True
-
-    #: whether this engine can serve the block-paged KV pool
-    #: (LFKT_KV_PAGED): page restore/store slice the ring's n_ctx dim,
-    #: which must be unsharded — engine/sp.py overrides to False.
-    _KV_PAGED = True
-
     def __init__(
         self,
         model_path: str | None,
@@ -336,7 +325,7 @@ class Engine:
         self.pass_counts = collections.Counter(
             lane_steps=0, decode=0, prefill=0)
         self._refuse_unsupported({
-            **self._asks, "slice": self._prefill_chunk,
+            "slice": self._prefill_chunk,
             "int8": self.cfg.kv_dtype == "int8", "paged": bool(kv_paged)})
         asked_attn = attn_impl
         attn_impl = self.cache.attn_impl(self.cfg, attn_impl)
@@ -408,8 +397,8 @@ class Engine:
         # token ids' KV entries are resident in its ring after each request
         # and, when the next prompt shares that prefix, prefills only the
         # suffix via prefill_chunk_jit — multi-turn TTFT then scales with
-        # the NEW turn's length, not the whole history.  The mesh/SP/lane
-        # engines manage caches differently and keep full prefill.
+        # the NEW turn's length, not the whole history.  The lane engine
+        # reuses per lane instead (engine/continuous.py lane claims).
         # (off for a cache that cannot be rolled back to a prefix: a
         # property of the kind, its /health says so)
         self._prefix_cache = bool(prefix_cache) and type(self) is Engine \
@@ -426,12 +415,6 @@ class Engine:
         # prompts prefill once per process, multi-turn requests resume
         # from their last committed page.
         paged = bool(kv_paged)
-        if paged and not self._KV_PAGED:
-            logger.warning(
-                "LFKT_KV_PAGED=1 requested but %s shards the ring's n_ctx "
-                "dim; the paged pool needs it unsharded — serving with the "
-                "dense ring", type(self).__name__)
-            paged = False
         self._kv_paged = paged
         #: the in-flight request's pinned pool pages (exactly one live
         #: lease: the serial engines generate one request at a time).
@@ -452,9 +435,9 @@ class Engine:
                 # shared multi-model pool: N models partition one HBM page
                 # budget dynamically instead of each provisioning
                 # worst-case — but only an identical per-page cache
-                # geometry can share the arena.  Gate off with attribution
-                # (the SPEngine-paging idiom): this model serves from a
-                # private pool instead of failing the whole fleet.
+                # geometry can share the arena.  Gate off with attribution:
+                # this model serves from a private pool instead of failing
+                # the whole fleet.
                 logger.warning(
                     "model %r (n_layers=%d, n_kv_heads=%d, head_dim=%d, "
                     "kv_dtype=%s) cannot share the KV page arena: cache "
@@ -499,10 +482,6 @@ class Engine:
         one source.  An in-memory engine has ``warmup_s`` alone."""
         return legacy_load_phases(self.startup)
 
-    #: what a subclass asks of the cache kind beside int8, paging and its
-    #: slice ({feature of models/cache.py ``FEATURES``: the value asked for})
-    _asks: dict = {}
-
     def _refuse_unsupported(self, asks: dict) -> None:
         """What was asked for that the cache kind cannot serve
         (``CacheKind.supports``, ``slice_rule``) is refused at start by
@@ -545,7 +524,7 @@ class Engine:
     @property
     def kv_cache_bytes(self) -> int:
         """Logical HBM bytes of EVERY resident KV ring this engine holds:
-        the serial ring, the batched lane state (mesh/continuous), and the
+        the serial ring, the lane engine's batched state, and the
         continuous scheduler's persistent prefill scratch — summed from the
         live pytrees so the /health and /metrics figure matches what
         actually sits in HBM (docs/KV_CACHE.md lane-headroom math).
@@ -668,11 +647,9 @@ class Engine:
         """The serial engine's warm-up, every (bucket, chunk) shape; returns
         what the log line says was warmed."""
         msgs = [{"role": "user", "content": "hi hi hi hi hi hi hi hi"}]
-        # TWO full decode chunks, not one: on the sharded engines the
-        # donated state returns from chunk 1 with jit-chosen shardings, so
-        # the steady-state chunk-2 signature is a distinct compile — found
-        # by the devtime compile pins (tests/test_perf_pins.py), which now
-        # hold warmup to "compiles everything steady-state decode runs"
+        # TWO full decode chunks, not one: the devtime compile pins
+        # (tests/test_perf_pins.py) hold warmup to "compiles everything
+        # steady-state decode runs", the second chunk's signature included
         with ph.child("request"):
             self.create_chat_completion(msgs,
                                         max_tokens=2 * self.decode_chunk + 1,
@@ -729,18 +706,13 @@ class Engine:
                 jnp.int32(0), jnp.int32(C - 1), cache)
         return cache, len(shapes)
 
-    # -- jit call points (subclasses reroute these onto a mesh: engine/sp.py
-    # runs them sequence-parallel; the vmap/batched engines bypass them) ----
-    def _prefill_call(self, tokens, length, cache):
-        return prefill_jit(self.params, self.cfg, tokens, length, cache)
-
     def _slices_prefill(self, bucket: int) -> bool:
         """Whether a ``bucket``-sized prompt prefills as overlapped slices
         (vs one monolithic program).  Buckets at or under the slice size
         gain nothing from slicing and keep the single-program path."""
         return bucket > self._prefill_chunk and (
             self.cache.always_slices
-            or (self._SLICE_PREFILL and self._prefill_overlap > 0))
+            or self._prefill_overlap > 0)
 
     def _observe_slice(self, dt: float) -> None:
         """Feed one prefill-slice host wall time into the server's metrics
@@ -793,16 +765,16 @@ class Engine:
         write cache garbage that is never attended.
 
         Greedy-bit-identity with the monolithic program is pinned by
-        tests/test_prefill_pipeline.py on every engine flavor.
+        tests/test_prefill_pipeline.py on both engines.
         """
         if not self._slices_prefill(bucket):
             t_s = time.time()
             padded = ids + [0] * (bucket - n_prompt)
             with phase("prefill_slice", rid=rid(pspan), offset=0,
                        tokens=bucket):
-                out = self._prefill_call(
-                    jnp.asarray(padded, jnp.int32), jnp.int32(n_prompt),
-                    cache)
+                out = prefill_jit(
+                    self.params, self.cfg, jnp.asarray(padded, jnp.int32),
+                    jnp.int32(n_prompt), cache)
             # the one-program prompt: one slice
             self._slice_span(pspan, t_s, time.time(), 0, bucket)
             self._count_slice(bucket)
@@ -1089,8 +1061,7 @@ class Engine:
 
     def _trace_attrs(self) -> dict:
         """Engine-identity attributes stamped on a traced request's
-        ``engine`` span (subclasses extend — engine/sp.py adds the mesh
-        geometry)."""
+        ``engine`` span."""
         return {"engine": type(self).__name__, "model": self.model_name}
 
     # ------------------------------------------------------------------

@@ -22,7 +22,7 @@ The donor registry is built from the same surface PERF001 enumerates:
 ``jax.jit``/``functools.partial(jax.jit, ...)`` entry points with
 ``donate_argnames`` (decorator, assignment, and ``timed_jit``-wrapped
 forms), jit *factories* (a function returning a donating jit over a
-nested def — parallel/ring.py's ``_sp_*_fn`` pattern), plus one level of
+nested def; the package has none since PR 60), plus one level of
 interprocedural propagation: a function that forwards its own parameter
 into a donated position donates that parameter too (``KVPool.restore``'s
 ``ring``, ``Engine._prefill_padded``'s ``cache``).

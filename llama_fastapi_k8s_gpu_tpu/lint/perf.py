@@ -7,7 +7,7 @@ The lfkt-perf contract (obs/devtime.py, obs/slo.py):
   dispatch attribution can never silently lose a program.  A site counts
   as registered when (a) the jit-creating call is lexically inside a
   ``timed_jit(...)``/``register_program(...)`` call (the wrap-at-build
-  form: ``timed_jit("sp_prefill", jax.jit(fn))``), or (b) the decorated
+  form: ``timed_jit("name", jax.jit(fn))``), or (b) the decorated
   function's name — or the enclosing function's name, for call-expression
   sites — appears as an argument (string or name) of a registration call
   somewhere in the same module (the module-level forms:

@@ -18,22 +18,14 @@ from .config import (
     CONV_RING, LATENT_RING, RING, STATE_RING, WINDOW_GLOBAL_RING,
     WINDOW_SUMMARIES, ModelConfig)
 
-#: what an engine can ASK of a cache kind, in the order the asks are checked
-#: (a mesh with no place for the cache at all comes first), each with the
-#: setting as its operator wrote it; ``supports`` answers all but ``slice``
+#: what an engine can ASK of a cache kind, in the order the asks are
+#: checked, each with the setting as its operator wrote it; ``supports``
+#: answers all but ``slice``
 FEATURES = {
-    "sp": "LFKT_MESH_SP > 1",
     "int8": "LFKT_KV_DTYPE=int8",
     "paged": "LFKT_KV_PAGED=1",
     "slice": "LFKT_PREFILL_CHUNK={}",
-    "tp": "LFKT_MESH_TP={}",
-    "cycle": "LFKT_SCHEDULER=cycle",
 }
-
-#: a leaf's mesh axes (parallel/mesh.py puts a lane axis in front): a
-#: head-major (L, n_kv, slots, hd) leaf's KV heads over ``tp``, or all whole
-HEADS = (None, "tp", None, None)
-WHOLE = (None, None, None, None)
 
 #: the counters every kind keeps, a ring's (0 for good where there is none):
 #: slots its decode steps read / needed, K rows the decode kernel stored
@@ -53,9 +45,7 @@ class CacheKind:
     nbytes: Callable    # (cfg) -> their bytes
     #: (cfg, pos (B,), live (B,) bool | None) -> a lane step's ``kv_bound``
     step_bound: Callable
-    #: (cfg) -> {leaf: its mesh axes} (:data:`HEADS`, :data:`WHOLE`)
-    shardings: Callable
-    #: {``int8``, ``paged``, ``tp``, ``sp``, ``cycle``} -> True, or why not:
+    #: {``int8``, ``paged``} -> True, or why not:
     #: the end of "<SETTING> cannot serve architecture '<arch>': <reason>"
     supports: Mapping
     #: (cfg) -> the decode kernel's block where a decode step runs it, else 0

@@ -43,10 +43,7 @@ class ModelConfig:
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     # "xla" materializes (S, n_ctx) scores; "pallas" streams K/V through the
-    # blockwise flash kernel (ops/pallas/attention.py) on prefill paths;
-    # "ring" shards the sequence over the sp mesh axis — only valid through
-    # the parallel/ring.py entry points (sp_prefill / sp_decode_step), which
-    # establish the mesh context the ring ops need.
+    # blockwise flash kernel (ops/pallas/attention.py) on prefill paths
     attn_impl: str = "xla"
     # KV-cache storage dtype: "bf16" (the default two-leaf {k, v} ring) or
     # "int8" (four-leaf {k_q, v_q, k_s, v_s}: int8 values + per-head

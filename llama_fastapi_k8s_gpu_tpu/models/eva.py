@@ -34,7 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .cache import HEADS, CacheKind
+from .cache import CacheKind
 from .config import WINDOW_SUMMARIES, ModelConfig
 
 #: window slots a decode step reads at a time (``models/llama.py
@@ -378,18 +378,10 @@ CACHE = CacheKind(
     name=WINDOW_SUMMARIES, arch="evabyte",
     init=init_cache, nbytes=cache_nbytes,
     step_bound=lambda cfg, pos, live: live_bounds(pos, live, cfg),
-    shardings=lambda cfg: dict.fromkeys(("k", "v", "sk", "sv"), HEADS),
     supports={
         "int8": "its window + summary cache is bf16 only",
         "paged": "the pool pages runs of ring slots by token position, and "
-                 "its cache is a window that restarts plus chunk summaries",
-        "tp": "parallel/mesh.py shards a ring's KV heads, and has no layout "
-              "for its window + summary cache",
-        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
-              "cache is a window plus chunk summaries",
-        "cycle": "it prefills a whole prompt in one vmapped pass, and a "
-                 "pass must lie inside one attention window; use the "
-                 "continuous scheduler"},
+                 "its cache is a window that restarts plus chunk summaries"},
     slice_rule=_slice_rule,
     # its attention is this file's own; no kernel, and no ring to write
     attn_impl=lambda cfg, asked: "xla",

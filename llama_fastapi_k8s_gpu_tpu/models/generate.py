@@ -79,8 +79,7 @@ sample_jit = timed_jit("first_sample", sample_jit, site="models.generate")
 def generate_chunk(params, cfg: ModelConfig, state: dict, st: dict,
                    n_steps: int, top_k: int = 40):
     """Pure ``n_steps`` decode+sample scan (the body of
-    :func:`generate_chunk_jit`; parallel/ring.py re-jits it under a ring
-    context for sequence-parallel decode)."""
+    :func:`generate_chunk_jit`)."""
 
     def step(carry, _):
         logits, cache, *stats = forward(

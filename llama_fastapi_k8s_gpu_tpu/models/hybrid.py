@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.linear import linear, linear_at
-from .cache import HEADS, CacheKind
+from .cache import CacheKind
 from .config import GLOBAL, WINDOW, WINDOW_GLOBAL_RING, ModelConfig
 from .llama import (
     _kernel_decode, _ring_attention, decode_read_slots, expert_stats_len,
@@ -408,19 +408,10 @@ CACHE = CacheKind(
     init=init_cache, nbytes=cache_nbytes, forward=forward,
     # the global layers' XLA loop; the window layers read their whole leaf
     step_bound=ring_step_bound,
-    shardings=lambda cfg: dict.fromkeys(("k", "v", "kw", "vw"), HEADS),
     supports={
         "int8": "its window + global cache is bf16 only",
         "paged": "a pool page is a run of ring slots by token position, and "
-                 "its window layers keep window slots that wrap",
-        "tp": "parallel/mesh.py shards one stack of layers and one ring, "
-              "and has no layout for two feed-forward kinds or a leaf pair "
-              "per attention kind; experts over a mesh are ROADMAP B-I 5",
-        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
-              "window layers keep window slots that wrap",
-        "cycle": "it prefills a whole prompt in one vmapped pass, and a "
-                 "window layer takes a prompt slice by slice against its "
-                 "window slots; use the continuous scheduler"},
+                 "its window layers keep window slots that wrap"},
     decode_kernel_block=ring_kernel_block,   # both leaf kinds
     health=_health,
     own_gauges={"window_slots_read_total": "window_read",

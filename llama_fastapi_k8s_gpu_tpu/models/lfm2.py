@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.linear import linear, linear_at
-from .cache import HEADS, CacheKind
+from .cache import CacheKind
 from .config import ATTN, CONV, CONV_RING, ModelConfig
 from .llama import (
     _kernel_decode, _ring_attention, expert_stats_len, note_ring_decode,
@@ -367,21 +367,11 @@ CACHE = CacheKind(
     name=CONV_RING, arch="lfm2moe",
     init=init_cache, nbytes=cache_nbytes, forward=forward,
     step_bound=ring_step_bound,      # the attention layers' XLA loop
-    shardings=lambda cfg: {"conv": (None, None, None), "k": HEADS,
-                           "v": HEADS},
     supports={
         "int8": "its conv-state + ring cache is bf16 only",
         "paged": "a pool page is a run of ring slots by token position, and "
                  "its conv layers carry inputs that cannot be rolled back "
-                 "to a shared prefix",
-        "tp": "parallel/mesh.py shards one stack of layers and one ring, "
-              "and has no layout for two mixer kinds, two feed-forward "
-              "kinds or a conv leaf; experts over a mesh are ROADMAP B-I 5",
-        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
-              "conv layers carry a few rows per sequence, not slots",
-        "cycle": "it prefills a whole prompt in one vmapped pass padded to "
-                 "a bucket, and a conv layer must be told which rows are "
-                 "real; use the continuous scheduler"},
+                 "to a shared prefix"},
     attn_impl=_attn_impl,
     # a slice's XLA attention holds (heads, rows, n_ctx) float32 scores
     widest_slice=lambda cfg: 0 if cfg.attn_impl == "pallas" else 256,

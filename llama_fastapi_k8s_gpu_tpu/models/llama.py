@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from ..ops import linear
 from ..ops.linear import linear_at
 from . import eva
-from .cache import HEADS, CacheKind, cache_of
+from .cache import CacheKind, cache_of
 from .config import RING, ModelConfig
 
 
@@ -189,7 +189,7 @@ def xla_attention(q, kk, vv, cks, cvs, positions, cfg: ModelConfig,
 
 
 #: ring slots the XLA loop of :func:`decode_attention` reads at a time
-#: (the path of int8 rings, mesh and sequence-parallel engines and the CPU;
+#: (the path of int8 rings and the CPU;
 #: the kernel's block is ``DECODE_KERNEL_BLOCK``).  Measured on the
 #: chip (PERF.md section 6, PR 31; 8 lanes x 32 layers, the read alone): at chat
 #: lengths 128, 256 and 512 take the same time (an iteration costs 3-4 us
@@ -220,8 +220,7 @@ def ring_kernel_block(cfg: ModelConfig) -> int:
     RING runs it, else 0 (the XLA loop of :func:`decode_attention`).
     Decided by what the configuration shows, no setting: the kernel serves
     a bf16 ring under ``attn_impl == "pallas"`` (a TPU, ``head_dim % 128 ==
-    0``, the flash probe passed: engine/engine.py; a mesh engine resolves
-    to ``xla``, sequence parallelism to ``ring``) whose slots its block
+    0``, the flash probe passed: engine/engine.py) whose slots its block
     divides; the block is a power of two by the KV heads a copy spans
     (``DECODE_KERNEL_BLOCK``)."""
     if cfg.attn_impl != "pallas" or cfg.kv_dtype == "int8":
@@ -237,7 +236,7 @@ def ring_write_impl(cfg: ModelConfig) -> str | None:
     """Who stores a decode step's K and V row in a ring: ``kernel`` where
     the layer hands the row to the decode kernel (its block is not 0; a
     lane that holds no request then stores nothing), else ``xla``
-    (``dynamic_update_slice``: int8 rings, meshes, the CPU, the ring layers
+    (``dynamic_update_slice``: int8 rings, the CPU, the ring layers
     of models/sala.py; prefill slices on every path).  None on a cache
     that has no ring."""
     kind = cache_of(cfg)
@@ -284,8 +283,7 @@ def decode_chunk_slots(pos: int, n_steps: int, n_ctx: int,
 def decode_attention(q, cache, i, pos, bound, cfg: ModelConfig, out_dtype):
     """A decode step's attention (S = 1) over the LIVE part of layer
     ``i``'s ring, as a loop in plain XLA: the path where the decode kernel
-    does not run (:func:`decode_kernel_block`: int8 rings, mesh engines,
-    the CPU) and the reference tier-1 holds that kernel to.  K/V are read
+    does not run (:func:`decode_kernel_block`: int8 rings, the CPU) and the reference tier-1 holds that kernel to.  K/V are read
     in blocks up to slot ``bound`` and no
     further, with a running max and sum (the flash recurrence in plain
     XLA), instead of all ``n_ctx`` slots behind a mask.  Slots past the
@@ -476,25 +474,7 @@ def _ring_attention(q, ck, cv, cks, cvs, cache, i, positions, pos_offset,
     by ``cfg.attn_impl`` and the pass's length.  (S, n_heads * head_dim)
     in ``dtype``."""
     S, hd, quant = q.shape[0], cfg.head_dim, cks is not None
-    if cfg.attn_impl == "ring":
-        # sequence-parallel: KV sharded over the sp mesh axis (parallel/ring.py)
-        from ..parallel.ring import ring_attention, sharded_decode_attention
-
-        if quant:
-            # the ring collectives pass K/V chunks chip-to-chip, so this
-            # path materializes the layer's ring in bf16 (elementwise →
-            # stays sp-sharded); only XLA/flash get the fused-scale reads
-            from ..ops.pallas.kvquant import dequantize_kv
-
-            ck = dequantize_kv(ck, cks, dtype)
-            cv = dequantize_kv(cv, cvs, dtype)
-        attn = ring_attention if S > 1 else sharded_decode_attention
-        ctx = attn(
-            q, ck, cv, pos_offset,
-            sm_scale=cfg.sm_scale,
-            sliding_window=cfg.sliding_window,
-        ).reshape(S, cfg.n_heads * hd).astype(dtype)
-    elif cfg.attn_impl == "pallas" and S > 1:
+    if cfg.attn_impl == "pallas" and S > 1:
         # blockwise flash kernel: streams K/V, never materializes scores;
         # int8 caches ride the fused-dequant path (scales folded in-kernel)
         from ..ops.pallas import flash_attention, use_interpret
@@ -837,15 +817,6 @@ def ring_step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
         else live_bound(pos, live)
 
 
-def ring_shardings(cfg: ModelConfig) -> dict:
-    """KV heads over ``tp``; an int8 ring's (L, n_kv, ctx) scale planes get
-    the value rings' axes minus the hd axis."""
-    if cfg.kv_dtype == "int8":
-        return {"k_q": HEADS, "v_q": HEADS, "k_s": HEADS[:-1],
-                "v_s": HEADS[:-1]}
-    return {"k": HEADS, "v": HEADS}
-
-
 def note_ring_decode(counts: dict, cfg: ModelConfig, wanted: list,
                      n_steps: int, live: list | None = None, *,
                      until: int | None = None) -> None:
@@ -895,9 +866,9 @@ def _engine_health(cfg: ModelConfig) -> dict:
 CACHE = CacheKind(
     name=RING, arch="llama",
     init=_init_ring, nbytes=_ring_nbytes,
-    step_bound=ring_step_bound, shardings=ring_shardings,
+    step_bound=ring_step_bound,
     # the ring is what every feature was built on: it refuses nothing
-    supports=dict.fromkeys(("int8", "paged", "tp", "sp", "cycle"), True),
+    supports=dict.fromkeys(("int8", "paged"), True),
     rolls_back=True, always_slices=False,
     decode_kernel_block=ring_kernel_block, note_decode=note_ring_decode,
     span_attrs=loop_attrs, engine_health=_engine_health)

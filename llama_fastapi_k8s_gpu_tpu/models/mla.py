@@ -118,7 +118,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.linear import linear, linear_at
-from .cache import WHOLE, CacheKind
+from .cache import CacheKind
 from .config import LATENT_RING, ModelConfig
 from .llama import (
     expert_stats_len, live_bound, note_ring_decode, ring_step_bound,
@@ -839,19 +839,10 @@ CACHE = CacheKind(
     else "deepseek2",
     init=init_cache, nbytes=cache_nbytes, forward=forward,
     step_bound=ring_step_bound,
-    shardings=lambda cfg: {"lat": WHOLE},
     supports={
         "int8": "its latent ring is bf16 only",
         "paged": "a pool page is a run of K and V slots per KV head, and "
-                 "its cache is one latent row a position for all heads",
-        "tp": "parallel/mesh.py shards a ring's KV heads, and its latent "
-              "ring has one row for all heads; experts over a mesh are "
-              "ROADMAP B-I 5",
-        "sp": "the sp ring passes K and V chunks per head between chips, "
-              "and its cache is one latent row a position for all heads",
-        "cycle": "it prefills a whole prompt in one vmapped pass, and "
-                 "latent attention scores a pass against blocks of latents "
-                 "slice by slice; use the continuous scheduler"},
+                 "its cache is one latent row a position for all heads"},
     rolls_back=True,   # positional, as the ring is
     # ``attn_impl`` stays xla: a prefill slice's attention is this file's
     # own read (the loop, or the kernel of the same absorbed form on the
@@ -953,7 +944,6 @@ def _phased(name: str, key: str) -> dict:
 INDEXED = dataclasses.replace(
     CACHE, arch="deepseek32", arch_for=None, variant=None,
     step_bound=lambda cfg, pos, live=None: live_bound(pos, live),
-    shardings=lambda cfg: {"lat": WHOLE, "idx": WHOLE},
     health=_indexed_health,
     own_gauges={**CACHE.own_gauges,
                 **_phased("index_keys_scored_total", "scored"),

@@ -58,8 +58,7 @@ _LINEAR_MAKERS = {"bf16": make_linear_bf16, "int8": make_linear_int8}
 def flat_layers(layers: dict):
     """(name, leaf) over the stacked layers' leaves, the leaves of a file
     of several layer kinds (``{"lin": {...}, "sp": {...}}``, models/sala.py)
-    under ``<kind>.<name>``: what /health's ``weight_formats`` and the mesh
-    refusals walk."""
+    under ``<kind>.<name>``: what /health's ``weight_formats`` walks."""
     for name, leaf in layers.items():
         if isinstance(leaf, dict) and any(
                 isinstance(v, dict) for v in leaf.values()):

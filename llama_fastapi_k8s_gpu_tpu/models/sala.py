@@ -66,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.linear import linear, linear_at
-from .cache import HEADS, WHOLE, CacheKind
+from .cache import CacheKind
 from .config import STATE_RING, ModelConfig
 from .llama import (
     _ring_attention, note_ring_decode, ring_kernel_block, rms_norm,
@@ -361,7 +361,7 @@ def lin_layer(h, w, i, cache, positions, pos_offset, n_valid,
     the kernels serve (``cfg.attn_impl == "pallas"``: a TPU, as the ring's
     decode kernel) a decode step updates the stacked leaf in place
     (ops/pallas/linstate.py) and touches nothing of a lane whose ``live``
-    is False; the plain XLA step serves meshes and the CPU."""
+    is False; the plain XLA step serves the CPU."""
     S, H, hd = h.shape[0], cfg.lin_heads, cfg.head_dim
     hn, lin = _pre(h, w, i, cfg)
     q, k, v = (lin(hn, n).reshape(S, H, hd) for n in ("wq", "wk", "wv"))
@@ -864,21 +864,11 @@ CACHE = CacheKind(
     # the sparse layers' two branches, each skipped where no live lane
     # takes it
     step_bound=lambda cfg, pos, live: live_bounds(pos, live, cfg),
-    shardings=lambda cfg: {**dict.fromkeys(("k", "v", "kc", "kw"), HEADS),
-                           "state": WHOLE},
     supports={
         "int8": "its state + ring cache is float32 + bf16 only",
         "paged": "the pool pages runs of ring slots by token position, and "
                  "its linear layers keep a state that cannot be rolled back "
-                 "to a shared prefix",
-        "tp": "parallel/mesh.py shards a ring's KV heads and one stack of "
-              "layers, and has no layout for two kinds of layer or a state "
-              "leaf",
-        "sp": "the sp ring shards the n_ctx slots of a KV ring, and its "
-              "linear layers keep a state per sequence, not slots",
-        "cycle": "it prefills a whole prompt in one vmapped pass, and its "
-                 "sparse layers select per query in slices; use the "
-                 "continuous scheduler"},
+                 "to a shared prefix"},
     slice_rule=_slice_rule,
     probe_kernels=_probe_kernels,
     # the ring layers write before they call the kernel

@@ -30,8 +30,6 @@ LATENCY_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                    5.0, 10.0, 25.0, 60.0)
 #: decode throughput buckets (tokens/sec)
 RATE_BUCKETS = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 500.0)
-#: batch occupancy buckets (lanes filled per cycle)
-OCCUPANCY_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
 #: token-count buckets (prefix reuse lengths: one page up to a 32k prompt)
 TOKEN_BUCKETS = (64.0, 128.0, 256.0, 512.0, 1024.0, 2048.0, 4096.0,
                  8192.0, 16384.0, 32768.0)
@@ -94,12 +92,7 @@ METRICS: dict[str, Metric] = _register(
            "per-request decode throughput, by model",
            buckets=RATE_BUCKETS, labels=("model",)),
     Metric("generated_tokens_total", COUNTER, "completion tokens emitted"),
-    Metric("batched_generations_total", COUNTER,
-           "mesh-batched generation cycles"),
     Metric("streamed_generations_total", COUNTER, "SSE streams served"),
-    Metric("batch_occupancy", HISTOGRAM,
-           "requests coalesced per batched cycle",
-           buckets=OCCUPANCY_BUCKETS),
     # -- prefix reuse ------------------------------------------------------
     Metric("prefix_cache_hits_total", COUNTER,
            "requests served with prompt-prefix KV reuse"),
@@ -287,8 +280,8 @@ METRICS: dict[str, Metric] = _register(
            "sum of its intervals [max(previous done, its dispatch's "
            "return), done], done stamped when one leaf of its result is "
            "ready.  An UPPER bound: eager device work, transfers and "
-           "programs without a stamp lie in the next stamped interval; on "
-           "a mesh done is the slowest shard's (devtime snapshot)",
+           "programs without a stamp lie in the next stamped interval "
+           "(devtime snapshot)",
            labels=("program",)),
     Metric("jit_device_intervals_total", GAUGE,
            "device intervals summed into jit_device_seconds_total per "
@@ -395,7 +388,7 @@ METRICS: dict[str, Metric] = _register(
            "ring: lanes a decode chunk was dispatched with as live x its "
            "steps x the layers, cumulative; a lane that holds no request "
            "stores nothing.  0 where XLA writes the row (/health "
-           "engine.ring_write = xla: int8 rings, meshes, the CPU, a state "
+           "engine.ring_write = xla: int8 rings, the CPU, a state "
            "+ ring cache); host arithmetic at the chunk's harvest, nothing "
            "fetched"),
     # -- layer applications (models/llama.py layer_passes; a ring) ----------
@@ -639,8 +632,8 @@ MEM_COMPONENTS: dict[str, MemComponent] = {
                      "serial dense KV ring (Engine._cache; allocated on "
                      "every engine, serving or not)"),
         MemComponent("kv_lanes",
-                     "batched lane state: the mesh/continuous engines' "
-                     "shared decode pytree (parallel/batched.py)"),
+                     "batched lane state: the lane engine's shared "
+                     "decode pytree (parallel/batched.py)"),
         MemComponent("kv_scratch",
                      "the continuous scheduler's persistent prefill "
                      "scratch ring (engine/continuous.py)"),

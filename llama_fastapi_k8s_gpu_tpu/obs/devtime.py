@@ -53,8 +53,7 @@ of them, in a ring the ``first_token`` span takes its ``device.<program>``
 children from (obs/trace.py ``end_first_token``).  An interval is an UPPER
 bound on its program: work that reaches the device outside the registry
 (an eager ``jnp`` call, a transfer, a program without a stamp) lies in the
-interval of the next stamped program, and on a mesh ``done`` is the
-slowest shard's.  With the tracer off the wrapper pays one attribute read
+interval of the next stamped program.  With the tracer off the wrapper pays one attribute read
 and the watcher thread does not exist.
 
 The executable store (PR 55; utils/execstore.py, the compile layer's).
@@ -334,11 +333,11 @@ class DevtimeRegistry:
     def timed_jit(self, name: str, fn, site: str | None = None,
                   leaf: int | None = 0, key=None):
         """Wrap a host jit entry point.  Re-wrapping under the same name
-        (lru-cached factories minting one jit per mesh/config key) merges
+        (lru-cached factories minting one jit per config key) merges
         into one program ledger — exactly what storm detection wants.
 
         ``key``: what ``fn``'s closure holds that a trace reads, by value
-        (a factory's own arguments: mesh, axis, configuration), for the
+        (a factory's own arguments), for the
         executable store's key.  A jit over a closure that passes none is
         never stored (utils/execstore.py).
 

@@ -211,12 +211,10 @@ class MemLedger:
                 for (c, m), b in sorted(merged.items())]
 
     def _raw_device_stats(self):
-        """The real device probe, summed over the LOCAL mesh (separate so
-        tests can pin the latch semantics without faking a backend).
+        """The real device probe, summed over the local devices (separate
+        so tests can pin the latch semantics without faking a backend).
         Providers report physical bytes across every shard, so the
-        baseline must be the whole mesh's in-use/limit — one chip's
-        stats would make residual go negative by ~(N-1)/N on exactly the
-        multi-chip engines this ledger targets."""
+        baseline is every local device's in-use/limit."""
         try:
             import jax
 

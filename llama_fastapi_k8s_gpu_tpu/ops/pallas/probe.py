@@ -4,9 +4,9 @@ A Mosaic lowering failure (new libtpu, unexpected geometry) must degrade a
 pod to a slower path — not crash-loop it behind a misleading traceback.
 These probes compile each risky kernel once on a tiny shape at engine
 construction time, so the *caller* can pick the fallback (int8 weights /
-XLA attention) with correct attribution, for every engine variant (serial,
-mesh-batched, continuous, sequence-parallel — they all construct through
-``Engine.__init__``) and for the benches.
+XLA attention) with correct attribution, for both engines (serial and
+continuous — they construct through ``Engine.__init__``) and for the
+benches.
 
 Each probe returns ``None`` on success or a short error string; results are
 cached per process (the real warmup then reuses the compiled programs'

@@ -465,7 +465,7 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
     HBM purpose for exactly the format built to save bandwidth).
 
     Contract: partitioning is over the output dim N (and the row/batch dim
-    of ``xpa``); the contraction dim K is never split (mesh.py shards fused
+    of ``xpa``); the contraction dim K is never split (a caller shards fused
     weights on N for row-parallel layers too — gathering the small
     activations beats gathering weights)."""
     from jax.experimental.custom_partitioning import custom_partitioning
@@ -500,7 +500,7 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
         partition=partition,
         infer_sharding_from_operands=infer,
         # shardy factor rule: rows (b) and output (n) propagate; K factors
-        # (k, j, t) stay unsplit by construction of the mesh.py shardings
+        # (k, j, t) stay unsplit: a caller shards the planes over N alone
         sharding_rule="b k, n j, t n l -> b n",
     )
     return jax.jit(rows_vmappable(fn, xpa_pos=0, bound=MANYROW_MAX))

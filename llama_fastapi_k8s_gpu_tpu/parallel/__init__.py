@@ -1,8 +1,5 @@
-from .mesh import (  # noqa: F401
-    make_mesh,
-    param_shardings,
-    cache_shardings,
-    state_shardings,
-    shard_params,
+from .batched import (  # noqa: F401
+    batched_generate_chunk_perlane_jit,
+    init_batched_state,
+    init_lane_left,
 )
-from .batched import batched_prefill_jit, batched_generate_chunk_jit, init_batched_state  # noqa: F401

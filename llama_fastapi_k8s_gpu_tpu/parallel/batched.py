@@ -1,16 +1,16 @@
-"""Batched (data-parallel) prefill/decode over a device mesh.
+"""The lane engine's device programs: B sequences stepped as one.
 
-``vmap`` lifts the single-sequence model (models/llama.py) over a batch axis;
-NamedShardings place the batch on the ``dp`` mesh axis and the model on
-``tp``, so one jit'd program serves B concurrent sequences across the mesh —
-the TPU-native replacement for the reference's "4 independent single-GPU
-pods" data parallelism (SURVEY.md §2A), and the basis of the v5e-4
-"concurrent /response load" config in BASELINE.json.
+``vmap`` lifts the single-sequence model (models/llama.py) over a batch
+axis, so one jit'd program serves B concurrent sequences on the one device
+-- the TPU-native replacement for the reference's "4 independent single-GPU
+pods" data parallelism (SURVEY.md §2A).  The state lives where the serial
+engine's ring lives: plainly on the process's device, no mesh
+(engine/continuous.py allocates it).
 
-Every entry point here donates its ``state``/``caches`` pytree: callers
-own the rebind-from-result contract, machine-checked at every call site
-by lfkt-lint DON001-002 (the donor registry is scraped from these
-``donate_argnames`` declarations — docs/LINT.md).
+Every entry point here donates its ``state`` pytree: callers own the
+rebind-from-result contract, machine-checked at every call site by
+lfkt-lint DON001-002 (the donor registry is scraped from these
+``donate_argnames`` declarations -- docs/LINT.md).
 """
 
 from __future__ import annotations
@@ -25,7 +25,7 @@ from ..models.config import ModelConfig
 from ..models.generate import chunk_out
 from ..models.llama import (  # noqa: F401  (``live_bound``: the tests' name)
     forward, has_step_stats, init_cache, lanes_step_stats, live_bound,
-    prefill, step_stats_zeros)
+    step_stats_zeros)
 from ..obs.devtime import timed_jit
 from ..sampling.sample import PENALTY_WINDOW, sample_chain
 
@@ -56,75 +56,10 @@ def step_bound(cfg: ModelConfig, pos: jax.Array, live=None):
     return cache_of(cfg).step_bound(cfg, pos, live)
 
 
-def state_nbytes(state: dict | None) -> int:
-    """Resident HBM bytes of a batched generation state (cache lanes +
-    decode bookkeeping) — the memory ledger's ``kv_lanes`` row
-    (obs/memledger.py).  One reduction for the whole ledger: this is
-    ``tree_nbytes`` under the name that documents WHAT is being measured
-    (``.nbytes`` is shape metadata, safe even while the donating chunk
-    jits below hold the buffers in flight)."""
-    from ..obs.memledger import tree_nbytes
-
-    return tree_nbytes(state)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("caches",))
-def batched_prefill_jit(params, cfg: ModelConfig, tokens, lengths, caches):
-    """tokens (B, S) padded; lengths (B,). Returns (logits (B, V), caches)."""
-    return jax.vmap(
-        lambda t, l, c: prefill(params, cfg, t, l, c)
-    )(tokens, lengths, caches)
-
-
-batched_prefill_jit = timed_jit("batched_prefill", batched_prefill_jit,
-                                site="parallel.batched")
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "n_steps", "top_k"),
-    donate_argnames=("state",),
-)
-def batched_generate_chunk_jit(params, cfg: ModelConfig, state: dict, st: dict,
-                               n_steps: int, top_k: int = 40):
-    """B sequences × n_steps decode+sample steps on device, one shared set
-    of sampling knobs.  Returns (state, tokens (n_steps, B))."""
-
-    def one_step(carry, _):
-        bound = step_bound(cfg, carry["pos"])
-
-        def single(token, pos, cache, window, wpos, key):
-            logits, cache, *stats = forward(
-                params, cfg, token[None], pos, cache,
-                with_stats=has_step_stats(cfg), kv_bound=bound)
-            key, sub = jax.random.split(key)
-            tok = sample_chain(logits, window, sub, st, top_k=top_k)
-            window = window.at[wpos % PENALTY_WINDOW].set(tok)
-            return (tok, pos + 1, cache, window, wpos + 1, key, *stats)
-
-        tok, pos, cache, window, wpos, key, *stats = jax.vmap(single)(
-            carry["token"], carry["pos"], carry["cache"],
-            carry["window"], carry["wpos"], carry["key"],
-        )
-        new_carry = {"cache": cache, "pos": pos, "token": tok,
-                     "window": window, "wpos": wpos, "key": key}
-        # the counters are of the step, the same in every lane
-        return new_carry, (tok, *(lanes_step_stats(cfg, s, None)
-                                  for s in stats))
-
-    state, ys = jax.lax.scan(one_step, state, None, length=n_steps)
-    return state, chunk_out(*ys)
-
-
-batched_generate_chunk_jit = timed_jit(
-    "batched_decode_chunk", batched_generate_chunk_jit,
-    site="parallel.batched", leaf=1)      # done stamp on its rows
-
-
 def init_lane_left(batch: int) -> jax.Array:
     """What the lane engine's chunk program knows of its lanes' ENDS, kept
-    beside the batched state (whose leaves the other engines' programs
-    share): per lane the tokens it may still decode, (B,) int32.  0: the
+    beside the batched state: per lane the tokens it may still decode,
+    (B,) int32.  0: the
     lane has ended (its budget ran out or it sampled a stop id) or holds
     no request, as every lane at the start; the scheduler's lane write
     (engine/continuous.py ``_write_lane``) brings a lane to life with its
@@ -152,10 +87,11 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
                                        lane_st: dict, left: jax.Array,
                                        n_steps: int, top_k: int = 40,
                                        live=None, stop_ids: tuple = ()):
-    """Like :func:`batched_generate_chunk_jit` but with **per-lane** sampling
-    knobs (``lane_st`` leaves have a leading B dim) — the continuous
-    scheduler admits requests with different temperatures/penalties into
-    neighboring lanes.  (top_k stays a shared static: ``lax.top_k`` needs a
+    """B sequences × up to ``n_steps`` decode+sample steps on device with
+    **per-lane** sampling knobs (``lane_st`` leaves have a leading B dim) —
+    the continuous scheduler admits requests with different
+    temperatures/penalties into neighboring lanes.  (top_k stays a shared
+    static: ``lax.top_k`` needs a
     static k; see ContinuousEngine.submit.)  ``live`` (B,) bool: the lanes
     that hold a request (None: all).  A step's attention reads the ring as
     :func:`step_bound` says (a lane that holds none reads nothing, or up
@@ -179,8 +115,8 @@ def batched_generate_chunk_perlane_jit(params, cfg: ModelConfig, state: dict,
     alive in its step, and every row of a step not run, holds the pad -1:
     the rows with a token in them are the steps run, read by the host
     from the one array it fetches anyway.  The tokens of a lane while it
-    is alive are those of the ``scan`` form: the same sampling chain on
-    the same keys."""
+    is alive are those of the serial chunk's ``scan`` (models/generate.py):
+    the same sampling chain on the same keys."""
 
     def alive_of(left):
         return left > 0 if live is None else live & (left > 0)

@@ -233,31 +233,28 @@ def create_app(engine=None, settings: Settings | None = None,
             tr.span("queue", t0=rd["enqueued_at"]).end(now)
 
     async def consumer():
-        """Single drain task: strict FIFO, one generation *cycle* at a time
-        (reference api.py:80-107).  With ``batch_size > 1`` and a
-        batch-capable engine, a cycle coalesces up to batch_size queued
-        requests into one mesh-batched generation (engine/batched.py);
-        FIFO order is preserved."""
+        """Single drain task: strict FIFO (reference api.py:80-107).  A
+        lane engine (``submit``) takes each request as it comes and admits
+        it into a free lane; the serial engine generates one request at a
+        time."""
         queue = app.state.queue
         semaphore = app.state.semaphore
         while True:
-            batch = [await queue.get()]
-            continuous = hasattr(app.state.engine, "submit")
-            if continuous:
+            rd = await queue.get()
+            now = time.time()
+            app.state.metrics.observe(
+                "queue_wait_seconds", now - rd["enqueued_at"])
+            _queue_span(rd, now)
+            if rd["future"].cancelled():
+                logger.info("Future was cancelled before processing; skipping.")
+            elif hasattr(app.state.engine, "submit"):
                 # slot scheduler: forward without a barrier — the engine
                 # admits into free lanes at chunk boundaries.  In-flight
                 # count is capped at batch_size so the bounded queue is
                 # still the back-pressure surface (503 on overflow);
                 # without the cap the engine's pending queue would absorb
                 # unlimited work and 503 could never fire.
-                rd = batch[0]
-                now = time.time()
-                app.state.metrics.observe(
-                    "queue_wait_seconds", now - rd["enqueued_at"])
-                _queue_span(rd, now)
-                if rd["future"].cancelled():
-                    logger.info("Future was cancelled before processing; skipping.")
-                elif "stream_queue" in rd:
+                if "stream_queue" in rd:
                     # streams ride scheduler lanes concurrently with batched
                     # requests; each holds an inflight permit so the bounded
                     # queue (503) stays the back-pressure surface for them too
@@ -266,64 +263,7 @@ def create_app(engine=None, settings: Settings | None = None,
                 else:
                     await app.state.inflight.acquire()  # lfkt: transfers[inflight] -- permit released in _forward_to_scheduler's finally
                     _spawn(_forward_to_scheduler(rd))
-                queue.task_done()
-                continue
-            can_batch = (settings.batch_size > 1
-                         and hasattr(app.state.engine, "create_chat_completions"))
-            while can_batch and len(batch) < settings.batch_size:
-                try:
-                    batch.append(queue.get_nowait())
-                except asyncio.QueueEmpty:
-                    break
-            now = time.time()
-            live, streams = [], []
-            for rd in batch:
-                app.state.metrics.observe(
-                    "queue_wait_seconds", now - rd["enqueued_at"])
-                _queue_span(rd, now)
-                if rd["future"].cancelled():
-                    logger.info("Future was cancelled before processing; skipping.")
-                elif "stream_queue" in rd:
-                    streams.append(rd)
-                else:
-                    live.append(rd)
-            results: list[tuple] = []           # (request, response, error)
-            if can_batch and live:
-                # /v1 facade requests never coalesce into a mesh cycle:
-                # the batched path applies the /response truncation quirks
-                # and returns text, both wrong for the OpenAI contract —
-                # they take the per-request path below instead
-                batchable = [rd for rd in live if not rd.get("openai")]
-                solo = [rd for rd in live if rd.get("openai")]
-            else:
-                batchable, solo = [], live
-            if batchable:
-                # batch-of-one included: MeshEngine.warmup compiles only the
-                # batched shapes, so even solo requests must use them
-                try:
-                    responses = await _truncate_and_generate_batch(
-                        batchable, semaphore)
-                    results = [
-                        (rd, None, r) if isinstance(r, Exception) else (rd, r, None)
-                        for rd, r in zip(batchable, responses)
-                    ]
-                except Exception as e:  # noqa: BLE001 — one program, one failure
-                    results = [(rd, None, e) for rd in batchable]
-            for rd in solo:         # per-request isolation (reference semantics)
-                try:
-                    results.append((rd, await _truncate_and_generate(
-                        rd, semaphore), None))
-                except Exception as e:  # noqa: BLE001
-                    results.append((rd, None, e))
-            for rd, resp, err in results:
-                if rd["future"].cancelled():
-                    logger.info("Future cancelled during processing; "
-                                "%s dropped.", "error" if err else "result")
-                elif err is not None:
-                    rd["future"].set_exception(err)
-                else:
-                    rd["future"].set_result(resp)
-            for rd in streams:      # streaming requests, serial, in FIFO slot
+            elif "stream_queue" in rd:
                 try:
                     await _truncate_and_stream(rd, semaphore)
                 except Exception as e:  # noqa: BLE001 — never kill the consumer
@@ -332,8 +272,20 @@ def create_app(engine=None, settings: Settings | None = None,
                         rd["stream_queue"].put_nowait(e)
                     except Exception:  # noqa: BLE001
                         pass
-            for _ in batch:
-                queue.task_done()
+            else:       # per-request isolation (reference semantics)
+                try:
+                    resp, err = await _truncate_and_generate(
+                        rd, semaphore), None
+                except Exception as e:  # noqa: BLE001
+                    resp, err = None, e
+                if rd["future"].cancelled():
+                    logger.info("Future cancelled during processing; "
+                                "%s dropped.", "error" if err else "result")
+                elif err is not None:
+                    rd["future"].set_exception(err)
+                else:
+                    rd["future"].set_result(resp)
+            queue.task_done()
 
     def _model_label(obj=None) -> str:
         """Bounded-cardinality ``model`` label value: the per-request model
@@ -532,73 +484,6 @@ def create_app(engine=None, settings: Settings | None = None,
             except Exception as e:  # noqa: BLE001 — 500 semantics, api.py:76-78
                 m.inc("engine_errors_total")
                 logger.error("Error during message generation: %s", e)
-                raise HTTPException(
-                    status_code=500,
-                    detail=f"Error during message generation: {str(e)}",
-                ) from e
-
-    async def _truncate_and_generate_batch(rds, semaphore):
-        """Batched analogue of ``_truncate_and_generate`` over MeshEngine.
-        Returns one entry per request: the response text, or an exception for
-        that request alone (per-entry engine errors don't fail neighbors)."""
-        m = app.state.metrics
-        async with semaphore:
-            try:
-                batch_messages = [
-                    truncate_messages_to_fit_context(rd["messages"],
-                                                     settings.max_context_tokens)
-                    for rd in rds
-                ]
-                batch_kw = {}
-                if app.state.engine_kw.get("batch_deadlines"):
-                    # per-entry deadline/abort propagation: an entry whose
-                    # caller timed out or disconnected stops accumulating
-                    # within one decode chunk instead of pinning the cycle
-                    batch_kw["deadlines"] = [rd.get("deadline") for rd in rds]
-                    batch_kw["aborts"] = [rd["future"].cancelled for rd in rds]
-                if app.state.engine_kw.get("batch_traces"):
-                    batch_kw["traces"] = [rd.get("trace") for rd in rds]
-                t0 = time.time()
-                answers = await asyncio.to_thread(
-                    lambda: app.state.engine.create_chat_completions(
-                        batch_messages,
-                        temperature=settings.temperature,
-                        top_p=settings.top_p,
-                        frequency_penalty=settings.frequency_penalty,
-                        presence_penalty=settings.presence_penalty,
-                        **batch_kw,
-                    ))
-                m.observe("generation_seconds", time.time() - t0,
-                          model=_model_label(next(
-                              (a for a in answers if isinstance(a, dict)),
-                              None)))
-                m.inc("batched_generations_total")
-                m.observe("batch_occupancy", len(batch_messages))
-                _observe_engine_timings(
-                    m, next((a for a in answers
-                             if isinstance(a, dict) and "lfkt_timings" in a),
-                            None))
-                out = []
-                for answer in answers:
-                    if isinstance(answer, dict) and "error" in answer:
-                        out.append(HTTPException(
-                            status_code=500,
-                            detail="Error during message generation: "
-                                   f"{answer['error'].get('message', 'unknown')}"))
-                        continue
-                    try:
-                        out.append(_answer_to_text(answer, m))
-                    except HTTPException as e:
-                        out.append(e)
-                return out
-            except EngineUnavailable as e:
-                m.inc("engine_unavailable_total")
-                logger.error("Engine unavailable: %s", e)
-                raise HTTPException(
-                    status_code=503, detail=f"Engine unavailable: {e}") from e
-            except Exception as e:  # noqa: BLE001 — 500 semantics, api.py:76-78
-                m.inc("engine_errors_total")
-                logger.error("Error during batched generation: %s", e)
                 raise HTTPException(
                     status_code=500,
                     detail=f"Error during message generation: {str(e)}",
@@ -811,10 +696,6 @@ def create_app(engine=None, settings: Settings | None = None,
             "submit_trace": hasattr(engine, "submit") and _accepts_kwarg(
                 engine.submit, "trace"),
             "submit_model": hasattr(engine, "submit") and is_registry,
-            "batch_deadlines": hasattr(engine, "create_chat_completions")
-            and _accepts_kwarg(engine.create_chat_completions, "deadlines"),
-            "batch_traces": hasattr(engine, "create_chat_completions")
-            and _accepts_kwarg(engine.create_chat_completions, "traces"),
         }
         # engines observe prefill-slice timings straight into the app's
         # registry (obs/catalog.py prefill_slice_seconds); attribute
@@ -2020,52 +1901,13 @@ def _registry_factory(settings: Settings):
 
     specs = parse_manifest(settings.models)
     default = pick_default(specs, settings.default_model)
-    if settings.mesh_sp > 1 and settings.batch_size > 1:
-        # mirror the single-model factory's refusal exactly — a 1-entry
-        # manifest must not soften any serving-shape validation
-        raise ValueError(
-            "LFKT_MESH_SP > 1 serves sequence-parallel (serial); "
-            "set LFKT_BATCH_SIZE=1 or use dp/tp batching instead")
-    if len(specs) > 1 and settings.mesh_sp > 1:
-        raise ValueError(
-            "LFKT_MESH_SP > 1 gates off multi-model serving: the "
-            "sp-sharded ring serves one model per mesh (run one model "
-            "per pod, or drop to mesh_sp=1)")
-    if len(specs) > 1 and settings.batch_size > 1 \
-            and settings.scheduler != "continuous":
-        raise ValueError(
-            "LFKT_SCHEDULER=cycle gates off multi-model serving: a "
-            "mesh-batched cycle coalesces its whole batch into ONE "
-            "shared device program, which cannot interleave models — "
-            "use the continuous scheduler (docs/MULTIMODEL.md)")
 
     def build(spec, path, shared_pool):
-        from ..engine import ContinuousEngine, Engine, MeshEngine, SPEngine
-
         kw = _base_engine_kwargs(settings)
         kw.update(spec.overrides)
         kw["kv_pool"] = shared_pool
         kw["kv_namespace"] = spec.name
-        if settings.mesh_sp > 1:
-            return SPEngine(path, sp=settings.mesh_sp, tp=settings.mesh_tp,
-                            **kw)
-        if settings.batch_size > 1:
-            if settings.scheduler == "continuous":
-                kw.pop("prefill_chunk")
-                return ContinuousEngine(
-                    path, tp=settings.mesh_tp,
-                    batch_size=settings.batch_size,
-                    prefill_chunk=settings.prefill_chunk,
-                    adm_budget=settings.adm_budget,
-                    adm_controller=settings.adm_controller,
-                    adm_ema_alpha=settings.adm_ema_alpha,
-                    lane_prefix_cache=settings.lane_prefix_cache, **kw)
-            # cycle scheduler, single-entry manifest: the same
-            # MeshEngine the non-manifest factory builds — a 1-entry
-            # LFKT_MODELS migration must not silently swap schedulers
-            return MeshEngine(path, tp=settings.mesh_tp,
-                              batch_size=settings.batch_size, **kw)
-        return Engine(path, **kw)
+        return _build_engine(settings, path, kw)
 
     reg = ModelRegistry.from_specs(
         specs, build, default_model=default, model_dir=settings.model_dir,
@@ -2074,46 +1916,34 @@ def _registry_factory(settings: Settings):
     return reg
 
 
+def _build_engine(settings: Settings, path, kw: dict):
+    """The one place an engine class is chosen: the serial ``Engine`` for
+    one lane, ``ContinuousEngine`` for ``LFKT_BATCH_SIZE`` > 1.  ``kw``:
+    :func:`_base_engine_kwargs`, with a registry entry's overrides."""
+    from ..engine import ContinuousEngine, Engine
+
+    if settings.batch_size > 1:
+        return ContinuousEngine(
+            path, batch_size=settings.batch_size,
+            adm_budget=settings.adm_budget,
+            adm_controller=settings.adm_controller,
+            adm_ema_alpha=settings.adm_ema_alpha,
+            lane_prefix_cache=settings.lane_prefix_cache, **kw)
+    return Engine(path, **kw)
+
+
 def _default_engine_factory(settings: Settings):
     def factory():
         t_import = time.time()
-        from ..engine import ContinuousEngine, Engine, MeshEngine, SPEngine
+        from .. import engine  # noqa: F401  (the jax-heavy import, timed)
 
         t_imported = time.time()
-        if settings.scheduler not in ("continuous", "cycle"):
-            raise ValueError(
-                f"LFKT_SCHEDULER must be 'continuous' or 'cycle', "
-                f"got {settings.scheduler!r}")
         if settings.models:
             # multi-model manifest: the registry replaces the single
             # engine; empty LFKT_MODELS keeps this path byte-for-byte
             return _registry_factory(settings)
-        kw = _base_engine_kwargs(settings)
-        if settings.mesh_sp > 1:
-            # long-context serving: n_ctx sharded over the sp ring
-            if settings.batch_size > 1:
-                raise ValueError(
-                    "LFKT_MESH_SP > 1 serves sequence-parallel (serial); "
-                    "set LFKT_BATCH_SIZE=1 or use dp/tp batching instead")
-            eng = SPEngine(settings.model_path, sp=settings.mesh_sp,
-                           tp=settings.mesh_tp, **kw)
-        elif settings.batch_size > 1:
-            if settings.scheduler == "continuous":
-                ckw = dict(kw)
-                ckw.pop("prefill_chunk")   # named explicitly below
-                eng = ContinuousEngine(
-                    settings.model_path, tp=settings.mesh_tp,
-                    batch_size=settings.batch_size,
-                    prefill_chunk=settings.prefill_chunk,
-                    adm_budget=settings.adm_budget,
-                    adm_controller=settings.adm_controller,
-                    adm_ema_alpha=settings.adm_ema_alpha,
-                    lane_prefix_cache=settings.lane_prefix_cache, **ckw)
-            else:
-                eng = MeshEngine(settings.model_path, tp=settings.mesh_tp,
-                                 batch_size=settings.batch_size, **kw)
-        else:
-            eng = Engine(settings.model_path, **kw)
+        eng = _build_engine(settings, settings.model_path,
+                            _base_engine_kwargs(settings))
         # the load thread's first import of engine/, models/, ops/pallas/
         eng.startup.phase("engine_import", t_import, t_imported)
         eng.warmup()

@@ -22,11 +22,7 @@ ROADMAP item 5's subsystem (docs/MULTIMODEL.md).  The registry
   run between waves of model B — the co-resident-deployment shape of
   "Transformer-Lite" (PAPERS.md).
 
-Gating, with attribution (the SPEngine-paging idiom): the ``cycle``
-(mesh-batched) scheduler and the sequence-parallel engine coalesce
-requests into one shared device program, which cannot interleave
-models — the server factory refuses those combinations at startup.  The
-engine watchdog is likewise single-engine (one heartbeat, one recovery
+The engine watchdog is single-engine (one heartbeat, one recovery
 path) and does not run over a multi-model registry; per-engine scheduler
 failures still fail fast through ``EngineUnavailable`` on their own
 submit paths.
@@ -111,9 +107,9 @@ class ModelRegistry:
     engine (``create_chat_completion`` / ``submit`` / ``scheduler_stats``
     / ``kv_cache_bytes`` ...), plus ``model=`` routing and the
     ``models()`` descriptor that feeds ``GET /v1/models`` and the
-    /health ``models`` block.  ``submit``/``submit_stream``/
-    ``create_chat_completions`` are installed only when every engine
-    provides them, so the server's capability probes keep working.
+    /health ``models`` block.  ``submit``/``submit_stream`` are
+    installed only when every engine provides them, so the server's
+    capability probes keep working.
     """
 
     # -- lock discipline (lfkt-lint LOCK001-004): one mutex guards the
@@ -174,9 +170,6 @@ class ModelRegistry:
             self.submit = self._submit
         if all(hasattr(e, "submit_stream") for e in self._engines.values()):
             self.submit_stream = self._submit_stream
-        if all(hasattr(e, "create_chat_completions")
-               for e in self._engines.values()):
-            self.create_chat_completions = self._create_chat_completions
         if all(hasattr(e, "scheduler_stats")
                for e in self._engines.values()):
             self.scheduler_stats = self._scheduler_stats
@@ -368,14 +361,6 @@ class ModelRegistry:
             raise
         return self._tracked_iter(name, it)
 
-    def _create_chat_completions(self, batch_messages, *,
-                                 model: str | None = None, **kw):
-        name, eng = self._resolve_tracked(model)
-        try:
-            return eng.create_chat_completions(batch_messages, **kw)
-        finally:
-            self._track_exit(name)
-
     def abandon(self, fut) -> None:
         eng = getattr(fut, "_lfkt_engine", None)
         if eng is not None and hasattr(eng, "abandon"):
@@ -395,8 +380,7 @@ class ModelRegistry:
     #: facade capabilities every engine must share; an added engine
     #: missing one the registry installed at construction would silently
     #: break the server's capability probes mid-flight — refuse instead
-    _CAPABILITIES = ("submit", "submit_stream", "create_chat_completions",
-                     "scheduler_stats")
+    _CAPABILITIES = ("submit", "submit_stream", "scheduler_stats")
 
     def _emit_reload(self, action: str) -> None:
         m = self._metrics_sink

@@ -117,7 +117,7 @@ class Settings:
     # serial-engine prompt-prefix KV reuse (llama.cpp's prompt-cache
     # analogue): when consecutive prompts share a token prefix — the
     # reference workload re-sends persona + full history every turn —
-    # prefill only the suffix.  Mesh/SP/lane engines ignore it.
+    # prefill only the suffix.  The lane engine has its own (below).
     prefix_cache: bool = True
     # the continuous scheduler's analogue: admissions whose prompt shares
     # a freed lane's conversation history snapshot that lane's KV and
@@ -164,20 +164,10 @@ class Settings:
     # deadline-bearing admission always makes progress).
     adm_controller: bool = True
     adm_ema_alpha: float = 0.25     # EMA weight of the controller's signals
-    # >1 switches the server to mesh-batched serving — the v5e-4
-    # "concurrent /response load" config.  scheduler picks the flavor:
-    #   cycle      — MeshEngine: coalesce up to batch_size queued requests
-    #                per generation cycle (barrier between cycles)
-    #   continuous — ContinuousEngine: slot-based continuous batching;
-    #                free lanes admit new requests at every chunk boundary
+    # >1 switches the server from the serial Engine to ContinuousEngine
+    # with that many lanes: slot-based continuous batching on the one
+    # device; free lanes admit new requests at every chunk boundary
     batch_size: int = 1
-    scheduler: str = "continuous"
-    mesh_tp: int = 1                # tensor-parallel width across the mesh
-    # >1 serves with the sequence-parallel engine (engine/sp.py): the KV
-    # cache's n_ctx dim shards over an sp-axis ring (ring attention for
-    # prefill, sharded-LSE decode), scaling max context linearly with the
-    # ring size.  Serial serving (batch_size must stay 1).
-    mesh_sp: int = 1
     # -- disaggregated prefill/decode (serving/disagg/; docs/RUNBOOK.md
     # "Operating a split prefill/decode fleet") ----------------------------
     # role of this process in a split fleet: "off" (default — the single-
@@ -379,10 +369,7 @@ KNOBS: dict[str, Knob] = _register(
          "EMA admission controller for the per-wave prefill budget"),
     Knob("LFKT_ADM_EMA_ALPHA", float,
          "admission-controller EMA weight"),
-    Knob("LFKT_BATCH_SIZE", int, "serving lanes (mesh/continuous batching)"),
-    Knob("LFKT_SCHEDULER", str, "continuous|cycle batching flavor"),
-    Knob("LFKT_MESH_TP", int, "tensor-parallel width"),
-    Knob("LFKT_MESH_SP", int, "sequence-parallel ring size"),
+    Knob("LFKT_BATCH_SIZE", int, "serving lanes (>1: continuous batching)"),
     # -- disaggregated prefill/decode (serving/disagg/) --------------------
     Knob("LFKT_DISAGG_ROLE", str,
          "off|prefill|decode|both — split prefill/decode fleet role "

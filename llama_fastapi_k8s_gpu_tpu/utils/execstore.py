@@ -23,12 +23,10 @@ list for operators):
   arguments, ``keep_unused``, ``inline``, compiler options) and the
   ``key=`` a factory passes through ``timed_jit`` for what its closure
   holds.  A jit whose function closes over anything and passes no ``key=``
-  is never stored (parallel/ring.py's ``sp_*`` factories pass none: a
-  program over a mesh has never been loaded on one, see below);
+  is never stored;
 - the static arguments BY VALUE (``cfg: ModelConfig``, ``n_steps``,
-  ``top_k``, stop ids): dataclasses field by field, tuples, plain scalars,
-  a mesh.  A
-  static value of any other type makes the call unkeyable: it stays on the
+  ``top_k``, stop ids): dataclasses field by field, tuples, plain scalars.
+  A static value of any other type makes the call unkeyable: it stays on the
   jit path;
 - the dynamic arguments' tree structure and, leaf by leaf, dtype, shape,
   weak type and placement (:func:`placement`: which device holds which
@@ -55,10 +53,11 @@ that cannot be written turns the store off for the process with one log
 line.  Files of another source hash are pruned when a new one is written.
 
 **One device only** (utils/jaxcache.py opens the store where
-``jax.device_count() == 1``): every measured start is a one-chip start,
-and an executable over a mesh that is loaded onto the wrong device order
-is a wrong answer, not a slow one.  A four-chip run that shows built,
-loaded and jit replies equal byte for byte comes before that gate goes.
+``jax.device_count() == 1``): every measured start is a one-chip start
+and the tree has no program that spans devices.  An executable loaded
+onto the wrong device order would be a wrong answer, not a slow one: a
+four-chip run that shows built, loaded and jit replies equal byte for
+byte comes before that gate goes.
 
 **The files are pickles**: reading one runs what it says.  The cache
 volume must be writable by the serving user alone (docs/RUNBOOK.md).
@@ -132,9 +131,6 @@ def encode_static(v) -> str:
         return type(v).__qualname__ + "(" + ",".join(
             f"{f.name}={encode_static(getattr(v, f.name))}"
             for f in dataclasses.fields(v)) + ")"
-    mesh_ids = getattr(v, "device_ids", None)
-    if mesh_ids is not None and hasattr(v, "axis_names"):   # a jax Mesh
-        return f"{v!r}@{mesh_ids.ravel().tolist()}"
     raise Unkeyable(f"a static {type(v).__name__}")
 
 

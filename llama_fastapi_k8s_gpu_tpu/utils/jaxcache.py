@@ -23,8 +23,8 @@ so that a later start loads them instead of building every program's HLO
 to look them up.  It rides the same volume and the same variable with no
 setting of its own, and is on exactly where the persistent cache is
 (``jax_enable_compilation_cache``), the backend is not the CPU and the
-process drives ONE device (no start over a mesh has loaded an executable
-yet: utils/execstore.py).  JAX's cache lists only its own
+process drives ONE device (the tree has no program that spans devices:
+utils/execstore.py).  JAX's cache lists only its own
 ``*-cache`` / ``*-atime`` files at the top of the directory for its LRU
 (``jax/_src/lru_cache.py``), so it leaves the subdirectory alone.  To empty
 either: delete the directory (or just ``executables/``); the next start

@@ -100,7 +100,7 @@ def test_deadline_request_progresses_at_budget_floor(tmp_path):
 
     path = str(tmp_path / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=32,
                            prefill_buckets=(32, 64), prefill_chunk=16,
                            lane_prefix_cache=False)
@@ -134,7 +134,7 @@ def test_static_budget_mode_unchanged(tmp_path):
 
     path = str(tmp_path / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64), prefill_chunk=16,
                            adm_budget=48, adm_controller=False,
@@ -162,7 +162,7 @@ def test_static_mode_yields_after_one_slice_mid_prompt(tmp_path):
 
     path = str(tmp_path / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=8,
                            prefill_buckets=(32, 64), prefill_chunk=16,
                            adm_budget=64, adm_controller=False,

@@ -1,9 +1,10 @@
 """The cache kind as ONE object (models/cache.py): what each of the five
-kinds' ``CACHE`` says of its tiny preset, against what the engines, the
-mesh and ``/health`` said of it by hand at f321371 (before the object
-existed).  ``tests/cache_kinds_f321371.json`` holds that commit's own words,
-taken from its engines on the tiny files: every refusal's text, which of two
-refusals is named first, the ``/health`` ``engine.cache`` block and the
+kinds' ``CACHE`` says of its tiny preset, against what the engines and
+``/health`` said of it by hand at f321371 (before the object existed).
+``tests/cache_kinds_f321371.json`` holds that commit's own words, taken
+from its engines on the tiny files: every refusal's text (re-pinned at PR
+60 to the asks that are left: the mesh, sequence-parallel and ``cycle``
+refusals went with their engines), which of two refusals is named first, the ``/health`` ``engine.cache`` block and the
 ``/metrics`` names of a fresh engine's counters.
 
 No engine starts here and nothing is jitted: a kind is functions of the
@@ -26,6 +27,7 @@ import pytest
 from llama_fastapi_k8s_gpu_tpu import testing
 from llama_fastapi_k8s_gpu_tpu.engine.engine import Engine
 from llama_fastapi_k8s_gpu_tpu.models import llama
+from llama_fastapi_k8s_gpu_tpu.models import cache
 from llama_fastapi_k8s_gpu_tpu.models.cache import FEATURES, cache_of
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -41,7 +43,7 @@ WRITERS = {
     "window+global-ring": testing.write_tiny_hybrid_gguf,
 }
 KINDS = sorted(WRITERS)
-ASKS = ("int8", "paged", "tp", "sp", "cycle")
+ASKS = ("int8", "paged")
 
 
 @pytest.fixture(scope="module")
@@ -76,23 +78,13 @@ def test_the_leaves_weigh_what_nbytes_says(cfgs, kind):
     assert jax.tree.structure(same) == jax.tree.structure(leaves)
 
 
-@pytest.mark.parametrize("kind", KINDS)
-def test_a_mesh_layout_names_every_leaf(cfgs, kind):
-    cfg = cfgs[kind]
-    leaves = jax.eval_shape(lambda: cache_of(cfg).init(cfg, jnp.bfloat16))
-    axes = cache_of(cfg).shardings(cfg)
-    assert set(axes) == set(leaves)
-    assert all(len(axes[n]) == leaves[n].ndim for n in leaves)
-
-
 def test_an_int8_ring_lays_out_its_scales(cfgs):
     import dataclasses
 
     cfg = dataclasses.replace(cfgs["ring"], kv_dtype="int8")
     leaves = jax.eval_shape(lambda: cache_of(cfg).init(cfg, jnp.bfloat16))
-    axes = cache_of(cfg).shardings(cfg)
-    assert set(axes) == set(leaves) == {"k_q", "v_q", "k_s", "v_s"}
-    assert all(len(axes[n]) == leaves[n].ndim for n in leaves)
+    assert set(leaves) == {"k_q", "v_q", "k_s", "v_s"}
+    assert leaves["k_s"].shape == leaves["k_q"].shape[:-1]
     assert sum(a.size * a.dtype.itemsize for a in leaves.values()) \
         == cache_of(cfg).nbytes(cfg)
 
@@ -103,7 +95,7 @@ def test_what_a_kind_cannot_serve_is_refused_in_the_parents_words(
         cfgs, kind, ask):
     eng = _engine(cfgs[kind])
     want = PARENT[kind]["refusals"].get(ask)
-    asks = {ask: 2 if ask == "tp" else True}
+    asks = {ask: True}
     if want is None:          # the ring serves them all
         assert kind == "ring" and eng.cache.supports[ask] is True
         Engine._refuse_unsupported(eng, asks)
@@ -133,7 +125,7 @@ def test_the_slice_its_tests_serve_with_is_taken(cfgs, kind):
 def test_of_two_refusals_the_parents_first_is_named(cfgs, kind):
     eng = _engine(cfgs[kind])
     for pair, want in PARENT[kind]["named_first"].items():
-        asks = {ask: 2 if ask == "tp" else True for ask in pair.split("+")}
+        asks = dict.fromkeys(pair.split("+"), True)
         with pytest.raises(ValueError) as e:
             Engine._refuse_unsupported(eng, asks)
         assert str(e.value) == want, pair
@@ -197,6 +189,26 @@ def test_a_traced_request_says_what_the_kind_adds(cfgs, kind):
     assert cache.decode_span_attrs(41) == (
         {"cache": "latent-ring", "latent_positions": 41}
         if kind == "latent-ring" else {})
+
+
+@pytest.mark.parametrize("module", sorted(cache._MODULES.values()))
+def test_a_kind_answers_int8_and_paging_and_nothing_else(module):
+    """Every kind's module (the sixth, ``lfm2``, has no tiny preset above;
+    ``mla`` has a second object for the indexed variant): ``supports``
+    answers exactly the asks an engine can make beside its slice: no row
+    for a mesh, a ring sharded over chips or a second scheduler (PR 60)."""
+    import importlib
+
+    mod = importlib.import_module(
+        "llama_fastapi_k8s_gpu_tpu.models." + module)
+    kinds = [mod.CACHE] + [getattr(mod, n) for n in ("INDEXED",)
+                           if hasattr(mod, n)]
+    for kind in kinds:
+        assert set(kind.supports) == {"int8", "paged"} \
+            == set(FEATURES) - {"slice"}
+        assert all(v is True or (isinstance(v, str) and v)
+                   for v in kind.supports.values())
+        assert not hasattr(kind, "shardings")
 
 
 # ---------------------------------------------------------------------------

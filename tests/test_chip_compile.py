@@ -899,54 +899,6 @@ def test_sala_stack_compiles_with_no_ring_sized_copy(one_chip, name, lanes):
         assert _slice_widths(cfg) == [256, 1024]
 
 
-def _placed_on_four(topo):
-    """``placed(shape, dtype, *spec)``: shapes sharded over a dp=1, tp=4
-    mesh of the described devices."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import make_mesh
-
-    mesh = make_mesh(dp=1, tp=4, devices=topo.devices)
-    return lambda shape, dtype, *spec: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
-
-
-def test_fused_matmul_on_a_tpu_mesh_is_refused(topo):
-    """Pinned as found on four real chips (PR 22): jax 0.9.0 never
-    registers ``custom_partitioning`` with the TPU plugin, so a fused
-    matmul whose weights are sharded over a mesh reaches XLA as a raw
-    ``CustomSPMDPartitioning`` call and is refused.  ``MeshEngine`` says so
-    at construction.  When this starts to compile, tensor parallelism over
-    fused weights is back: flip the assertion and drop that refusal."""
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas import q4k_matmul
-
-    placed = _placed_on_four(topo)
-    w = {"qs": placed((4096, 2048), i8, "tp", None),
-         "sm": placed((2, 4096, 128), bf16, None, "tp", None)}
-    with pytest.raises(Exception, match="CustomSPMDPartitioning"):
-        jax.jit(lambda x, w: q4k_matmul(x, w, interpret=False)).lower(
-            placed((8, 4096), bf16), w).compile()
-
-
-def test_flash_attention_on_a_tpu_mesh_is_refused(topo):
-    """A bare ``pallas_call`` cannot be lowered into a program that spans
-    devices; the mesh engines therefore serve XLA attention."""
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas import flash_attention
-
-    placed = _placed_on_four(topo)
-
-    def fn(q, k, v, pos):
-        return flash_attention(q, k, v, pos, sm_scale=128 ** -0.5,
-                               interpret=False)
-
-    with pytest.raises(NotImplementedError,
-                       match="cannot be automatically partitioned"):
-        jax.jit(fn).lower(placed((256, 32, 128), bf16, None, "tp", None),
-                          placed((8, 1024, 128), bf16, "tp", None, None),
-                          placed((8, 1024, 128), bf16, "tp", None, None),
-                          placed((), i32)).compile()
-
-
 def _gigachat(one_chip, **flags):
     """BENCHMARK.json's gigachat configuration at its published widths
     (hidden 7168 filled up to K 8192, 64 heads, latents 1536 / 512 + 64, 1

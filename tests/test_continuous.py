@@ -1,4 +1,4 @@
-"""ContinuousEngine: slot-based continuous batching on the virtual mesh.
+"""ContinuousEngine: slot-based continuous batching over B lanes.
 
 Covers: greedy parity with the serial Engine, more requests than lanes
 (lane reuse), per-request error isolation, cancellation freeing a lane,
@@ -24,7 +24,7 @@ MSGS = [{"role": "user", "content": "Say something."}]
 def cengine(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("model") / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=2, tp=2, batch_size=4, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=4, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     yield eng
@@ -308,7 +308,7 @@ def test_shutdown_resolves_outstanding(tmp_path):
 
     path = str(tmp_path / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=64,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=64,
                            decode_chunk=2, max_gen_tokens=64,
                            prefill_buckets=(32, 64))
     futs = [eng.submit(MSGS, max_tokens=60) for _ in range(4)]
@@ -414,7 +414,7 @@ def test_chunked_prefill_bounds_stall_per_slice(tmp_path):
     # static one-slice budget (controller off): this test pins the
     # per-SLICE stall bound; the controller's budget-driven multi-slice
     # interleave is covered by tests/test_admission.py
-    eng = ContinuousEngine(path, dp=2, tp=2, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=24,
                            prefill_buckets=(64,), prefill_chunk=16,
                            adm_budget=16, adm_controller=False)
@@ -525,7 +525,7 @@ def test_outputs_independent_of_adm_budget(tmp_path):
                for i in range(8)]
 
     def run(budget):
-        eng = ContinuousEngine(path, dp=2, tp=2, batch_size=4, n_ctx=128,
+        eng = ContinuousEngine(path, batch_size=4, n_ctx=128,
                                decode_chunk=4, max_gen_tokens=16,
                                prefill_buckets=(32, 64, 128),
                                adm_budget=budget)
@@ -566,7 +566,7 @@ def _lp_multiturn(reply=None, new="And another one please."):
 def lp_engine(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("model") / "tiny-lp.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=512,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=512,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_chunk=16, lane_prefix_cache=True,
                            prefill_buckets=(64, 128, 256, 512))
@@ -682,12 +682,13 @@ def test_lane_prefix_a_live_lanes_prompt_is_a_claim(lp_engine):
     assert out_b["choices"][0]["message"]["content"]
 
 
-def test_lane_prefix_reuse_on_sharded_mesh(tmp_path):
-    """The lane→scratch snapshot gather must compose with GSPMD when the
-    batched cache is dp-sharded (the v5e-4 serving config)."""
-    path = str(tmp_path / "tiny-lp-mesh.gguf")
+def test_lane_prefix_reuse_on_a_fresh_four_lane_engine(tmp_path):
+    """The lane→scratch snapshot gather on the one device's lane state: a
+    conversation's second turn rides the first's rows on an engine that
+    has served nothing else."""
+    path = str(tmp_path / "tiny-lp-fresh.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=2, tp=2, batch_size=4, n_ctx=512,
+    eng = ContinuousEngine(path, batch_size=4, n_ctx=512,
                            decode_chunk=4, max_gen_tokens=12,
                            prefill_chunk=16, lane_prefix_cache=True,
                            prefill_buckets=(64, 128, 256, 512))
@@ -718,7 +719,7 @@ def test_lane_prefix_cache_defaults_on(tmp_path):
 
     path = str(tmp_path / "tiny-lp-default.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     serial = Engine(path, n_ctx=128, decode_chunk=4, max_gen_tokens=16,
@@ -819,34 +820,6 @@ def test_serial_stream_close_stops_decode_immediately(tmp_path):
     assert out["usage"]["completion_tokens"] >= 1
 
 
-def test_mesh_stream_close_stops_decode_immediately(tmp_path):
-    """MeshEngine streams ride the serial path: same close bound."""
-    from llama_fastapi_k8s_gpu_tpu.engine import MeshEngine
-
-    path = str(tmp_path / "tiny-mesh-close.gguf")
-    write_tiny_llama_gguf(path)
-    eng = MeshEngine(path, dp=2, tp=2, batch_size=2, n_ctx=128,
-                     decode_chunk=4, max_gen_tokens=100,
-                     prefill_buckets=(32, 64, 128))
-    calls = [0]
-    orig = eng._decode_chunk_call
-
-    def counting(*a, **kw):
-        calls[0] += 1
-        return orig(*a, **kw)
-
-    eng._decode_chunk_call = counting
-    it = eng.create_chat_completion(MSGS, stream=True, temperature=0.0,
-                                    max_tokens=100)
-    next(it)
-    next(it)
-    at_close = calls[0]
-    it.close()
-    assert calls[0] == at_close
-    outs = eng.create_chat_completions([MSGS], temperature=0.0, max_tokens=4)
-    assert outs[0]["usage"]["completion_tokens"] >= 1
-
-
 # ---------------------------------------------------------------------------
 # a lane's end on the device (PR 42): the chunk program carries what each
 # lane has left to decode and stops stepping when no lane has anything left
@@ -898,7 +871,7 @@ def lane_eng(tmp_path_factory):
     tokens on record: ``eng.finished[response id] = (tokens, finish)``."""
     path = str(tmp_path_factory.mktemp("ends") / "tiny.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=4, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=4, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=32,
                            prefill_buckets=(32, 64, 128))
     eng.finished = {}
@@ -1021,35 +994,46 @@ def test_one_caller_waits_behind_no_chunk(lane_eng):
     """(b) One caller, three requests in a row: behind each request's end
     the queued chunk runs no step (``chunks_empty``), the steps run are
     the tokens decoded after each first token (plus the step that sampled
-    a stop id), and the next request's ``pending`` span is shorter than
-    one decode step: it does not wait behind a chunk for nobody."""
+    a stop id), and EVERY later request's ``pending`` span is shorter
+    than one decode step: it does not wait behind a chunk for nobody.
+    The spans are a millisecond on the host's clock, and beside six busy
+    workers a pass of three has a later span over a step one time in six
+    on the parent's tree as on this one (PR 60, CHANGES.md): the three
+    requests run again, at most twice, and one whole pass has to be under
+    a step.  A wait behind a chunk is four steps long and in every pass."""
     from llama_fastapi_k8s_gpu_tpu.obs.trace import Tracer
 
     eng, tracer = lane_eng, Tracer(sample=1.0)
     before = _quiet(eng)
-    steps, pendings, step_s = 0, [], []
-    for i in range(3):
-        tr = tracer.start()
-        out = eng.create_chat_completion(
-            [{"role": "user", "content": f"in a row {i}"}], temperature=0.0,
-            max_tokens=10 + i, trace=tr)
-        tracer.finish(tr)
-        gens, finish = eng.finished[out["id"]]
-        steps += max(len(gens) - 1, 0) + (finish == "stop" and bool(gens))
-        pend = next(c for c in tr.to_dict()["root"]["children"]
-                    if c["name"] == "pending")
-        pendings.append(pend["end"] - pend["start"])
-        t = out["lfkt_timings"]
-        step_s.append(t["decode_s"] / max(t["completion_tokens"] - 1, 1))
+    steps, passes = 0, []
+    for _ in range(3):
+        pendings, step_s = [], []
+        for i in range(3):
+            tr = tracer.start()
+            out = eng.create_chat_completion(
+                [{"role": "user", "content": f"in a row {i}"}],
+                temperature=0.0, max_tokens=10 + i, trace=tr)
+            tracer.finish(tr)
+            gens, finish = eng.finished[out["id"]]
+            steps += max(len(gens) - 1, 0) + (finish == "stop" and bool(gens))
+            pend = next(c for c in tr.to_dict()["root"]["children"]
+                        if c["name"] == "pending")
+            pendings.append(pend["end"] - pend["start"])
+            t = out["lfkt_timings"]
+            step_s.append(t["decode_s"] / max(t["completion_tokens"] - 1, 1))
+        passes.append((pendings, step_s))
+        if max(pendings[1:]) < min(step_s):
+            break
     after = _quiet(eng)
-    assert after["chunks_empty"] - before["chunks_empty"] >= 2
+    assert after["chunks_empty"] - before["chunks_empty"] >= 2 * len(passes)
     assert after["steps_run"] - before["steps_run"] == steps
     run = after["steps_run"] - before["steps_run"]
     skipped = after["steps_skipped"] - before["steps_skipped"]
     assert run + skipped == 4 * (after["chunks_dispatched"]
                                  - before["chunks_dispatched"])
     assert after["end_disagreements"] == before["end_disagreements"]
-    assert pendings[1] < min(step_s), (pendings, step_s)
+    pendings, step_s = passes[-1]
+    assert max(pendings[1:]) < min(step_s), passes
 
 
 def test_an_ended_lane_decodes_again_after_the_next_lane_write(lane_eng):

@@ -672,12 +672,12 @@ def test_serial_decode_to_the_rings_end_compiles_nothing(pins):
 
 def test_lane_decode_to_the_rings_end_compiles_nothing(pins):
     """As above for the lane engine (tests/test_perf_pins.py holds the same
-    numbers and says why ``lane_write`` is 3: prefill_chunk 4, first_sample
-    1, lane_decode_chunk 2, lane_cache_copy 1): ``live`` is an array for
-    every block, so still one signature."""
+    numbers: prefill_chunk 3, and one each of first_sample,
+    lane_decode_chunk, lane_write, lane_cache_copy): ``live`` is an array
+    for every block, so still one signature."""
     assert pins["lane_warmup"] == {
-        "prefill_chunk": 4, "first_sample": 1, "lane_decode_chunk": 2,
-        "lane_write": 3, "lane_cache_copy": 1}
+        "prefill_chunk": 3, "first_sample": 1, "lane_decode_chunk": 1,
+        "lane_write": 1, "lane_cache_copy": 1}
     assert pins["lane_reached"] >= 120
     assert pins["lane_after"] == pins["lane_warmup"]
 

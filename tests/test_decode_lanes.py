@@ -548,7 +548,7 @@ def test_the_lane_counters_under_the_kernel_are_per_lane():
 
 @pytest.mark.parametrize("cfg_kw,who", [
     (dict(attn_impl="pallas"), "kernel"),
-    (dict(attn_impl="xla"), "xla"),                     # the CPU, a mesh
+    (dict(attn_impl="xla"), "xla"),                     # the CPU
     (dict(attn_impl="ring"), "xla"),                    # sequence parallel
     (dict(attn_impl="pallas", kv_dtype="int8"), "xla"),
     (dict(attn_impl="pallas", mixers=("sp", "lin", "sp")), "xla"),
@@ -609,7 +609,7 @@ def test_the_kernel_serves_both_engines_and_the_probes_text_holds(tmp_path):
     assert serial.cache_read_gauges()["ring_rows_written_total"] \
         == serial.cache_counts["rows_written"]
 
-    eng = ContinuousEngine(path, batch_size=3, dp=1, **kw)   # no mesh
+    eng = ContinuousEngine(path, batch_size=3, **kw)
 
     def probe():
         return eng.create_chat_completion(

@@ -233,11 +233,15 @@ def test_package_entry_points_are_registered():
 
     names = {p["name"] for p in DEVTIME.snapshot()["programs"]}
     for want in ("prefill", "prefill_chunk", "decode_chunk", "first_sample",
-                 "batched_prefill", "batched_decode_chunk",
                  "lane_decode_chunk", "lane_write", "kvpool_store",
                  "kvpool_restore", "kvpool_upload", "kvpool_lane_store",
                  "flash_attention", "quantize_kv_pallas"):
         assert want in names, (want, sorted(names))
+    # the cycle scheduler's and the sequence-parallel engine's programs
+    # left with them (PR 60)
+    assert not names & {"batched_prefill", "batched_decode_chunk",
+                        "batched_first_sample", "sp_prefill",
+                        "sp_decode_chunk"}
 
 
 # ---------------------------------------------------------------------------

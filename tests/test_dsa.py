@@ -40,8 +40,8 @@ import numpy as np
 import pytest
 
 from tests.test_mla import (
-    BENCH, LIMIT, N_CTX, N_PROMPT, N_SEQ, PICKS, SLICE, load, rel,
-    rows_that_differ, traced_with, with_kernel, worst)
+    BENCH, LIMIT, N_CTX, N_PROMPT, N_SEQ, PICKS, SLICE, load,
+    reference_rows, rel, rows_that_differ, traced_with, with_kernel, worst)
 
 SCORE = 6e-2
 SUMS = 1e-4
@@ -613,9 +613,9 @@ def test_lanes_at_their_own_positions_one_dead(ref, model, loaded, tokens,
         first, n = prompts[lane], prompts[lane] + 6
         picks = np.concatenate([pre[lane][1]] + [g[1] for g in got], axis=1)
         sel = np.concatenate([pre[lane][3]] + [g[2] for g in got], axis=1)
-        want = np.asarray(ref.forward(
-            *model, seqs[lane][:n], use_picks=picks,
-            use_sel=sel[:, :, :n])[0])
+        assert picks.shape[1] == n
+        want = reference_rows(ref, model, seqs[lane], picks,
+                              use_sel=sel[:, :, :n])
         assert worst(np.stack([g[0] for g in got]), want[first:]) < LIMIT, lane
         assert sel[:, -1].sum(-1).tolist() == [TOPK] * 3
 
@@ -836,7 +836,7 @@ def test_gguf_round_trip_of_the_indexers_keys_and_tensors(loaded):
     assert kind.nbytes(cfg) == 3 * N_CTX * (128 + 128) * 2
     assert mla.CACHE.nbytes(dataclasses.replace(
         TINY_MLA_CFG, n_ctx=N_CTX)) == 3 * N_CTX * 128 * 2
-    assert set(kind.shardings(cfg)) == set(kind.init(cfg)) == {"lat", "idx"}
+    assert set(kind.init(cfg)) == {"lat", "idx"}
     for stack, depth in (("dense", 1), ("moe", 2)):
         layer = params["layers"][stack]
         assert layer["idx_wq_b"]["w"].shape == (depth, 4 * 16, 64)

@@ -444,15 +444,18 @@ def test_prefix_reuse_never_spans_past_the_ring(prefix_model):
                                  eng._bucket_for(90)) == 89
 
 
-def test_prefix_cache_disabled_for_sharded_engines(prefix_model):
-    """Subclasses manage caches differently (lanes / mesh / sp ring); the
-    reuse path must stay off there even when the kwarg is passed."""
-    from llama_fastapi_k8s_gpu_tpu.engine import MeshEngine
+def test_prefix_cache_disabled_for_the_lane_engine(prefix_model):
+    """The lane engine reuses per lane (its own claims); the serial
+    ring's reuse path must stay off there even when the kwarg is passed."""
+    from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine
 
-    eng = MeshEngine(prefix_model, batch_size=2, n_ctx=128,
-                     decode_chunk=4, max_gen_tokens=8,
-                     prefill_buckets=(64, 128), prefix_cache=True)
-    assert eng._prefix_cache is False
+    eng = ContinuousEngine(prefix_model, batch_size=2, n_ctx=128,
+                           decode_chunk=4, max_gen_tokens=8,
+                           prefill_buckets=(64, 128), prefix_cache=True)
+    try:
+        assert eng._prefix_cache is False
+    finally:
+        eng.shutdown()
 
 
 def test_explicit_seed_bypasses_prefix_reuse(prefix_model):

@@ -471,24 +471,6 @@ def test_what_cannot_hold_the_cache_is_refused_by_name(gguf_path, kw, words):
     assert all(w in str(e.value) for w in words), str(e.value)
 
 
-def test_meshes_refuse_the_architecture_by_name(gguf_path):
-    from llama_fastapi_k8s_gpu_tpu.engine import (
-        ContinuousEngine, MeshEngine, SPEngine)
-
-    for build, word in (
-            (lambda: ContinuousEngine(gguf_path, n_ctx=N_CTX, tp=2,
-                                      batch_size=2, prefill_chunk=SLICE),
-             "LFKT_MESH_TP=2"),
-            (lambda: MeshEngine(gguf_path, n_ctx=N_CTX, batch_size=2,
-                                dp=1, prefill_chunk=SLICE),
-             "LFKT_SCHEDULER=cycle"),
-            (lambda: SPEngine(gguf_path, n_ctx=N_CTX, sp=2,
-                              prefill_chunk=SLICE), "LFKT_MESH_SP")):
-        with pytest.raises(ValueError) as e:
-            build()
-        assert word in str(e.value) and "evabyte" in str(e.value)
-
-
 def test_one_pass_holds_one_window_at_most(loaded, tokens):
     import jax.numpy as jnp
 

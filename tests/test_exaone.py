@@ -32,7 +32,8 @@ import pytest
 
 from tests.test_mla import lane_alone as _lane_alone
 from tests.test_mla import (
-    lanes_run, load, prefill, programs, rel, rows_that_differ, worst)
+    lanes_run, load, prefill, programs, reference_rows, rel,
+    rows_that_differ, worst)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmarks")
@@ -233,8 +234,8 @@ def test_three_lanes_one_dead_then_taken(ref, model, loaded, tokens, impl):
         n = first + len(logits)
         use = np.concatenate(
             [prefill(params, cfg, seqs[lane], first)[1], picks], axis=1)
-        want = np.asarray(ref.forward(*model, seqs[lane][:n],
-                                      use_picks=use)[0])
+        assert use.shape[1] == n
+        want = reference_rows(ref, model, seqs[lane], use, length=N_SEQ)
         assert worst(logits, want[first:]) < LIMIT, lane
     for st, n_live in stats:
         assert np.array_equal(st[0], st[1]) and np.array_equal(st[0], st[2])
@@ -430,27 +431,6 @@ def test_what_cannot_hold_the_cache_is_refused_by_name(gguf_path, kw, words):
 
     with pytest.raises(ValueError, match=words):
         Engine(gguf_path, n_ctx=N_CTX, **kw)
-
-
-@pytest.mark.parametrize("which, words", [
-    ("tp", "LFKT_MESH_TP=2 cannot serve architecture 'exaone-moe'"),
-    ("cycle", "LFKT_SCHEDULER=cycle cannot serve architecture 'exaone-moe'"),
-    ("sp", "LFKT_MESH_SP > 1 cannot serve architecture 'exaone-moe'"),
-])
-def test_meshes_refuse_the_architecture_by_name(gguf_path, which, words):
-    from llama_fastapi_k8s_gpu_tpu.engine.batched import MeshEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.sp import SPEngine
-
-    make = {
-        "tp": lambda: ContinuousEngine(gguf_path, n_ctx=N_CTX, tp=2,
-                                       batch_size=1, prefill_chunk=SLICE),
-        "cycle": lambda: MeshEngine(gguf_path, n_ctx=N_CTX, batch_size=2,
-                                    prefill_chunk=SLICE),
-        "sp": lambda: SPEngine(gguf_path, n_ctx=N_CTX, sp=2,
-                               prefill_chunk=SLICE)}[which]
-    with pytest.raises(ValueError, match=words):
-        make()
 
 
 # ---------------------------------------------------------------------------

@@ -415,9 +415,12 @@ def test_router_affinity_sticks_roundrobin_spreads():
     rr = FleetRouter(table2, policy="roundrobin")
     rs2 = _serve_router(rr, rp2)
     try:
-        # affinity: each conversation sticks to ONE replica...
+        # affinity: each conversation sticks to ONE replica...  (sixteen
+        # of them: the rank hashes the replicas' addresses and the ports
+        # are whatever was free; six all fall to one replica in one start
+        # of thirty)
         seen = {}
-        for conv in range(6):
+        for conv in range(16):
             answers = set()
             for _ in range(3):
                 _status, raw = _post(rp, _body(conv))
@@ -710,6 +713,12 @@ def _replay(router_port: int, convs: list, turns: int,
                                  "message": "Please tell me more."})
 
 
+# the drill takes 30 s alone and 37 s beside five busy workers (the driver's
+# run of PR 60's tree); 116 s, its longest on record, was PR 59's run on a
+# machine a third slower, which a limit under that would turn red for no fault
+DRILL_LIMIT_S = 180
+
+
 def test_two_process_affinity_and_fault_drill(tmp_path):
     """THE acceptance drill: 2 real replica processes behind the router.
 
@@ -729,10 +738,23 @@ def test_two_process_affinity_and_fault_drill(tmp_path):
     proc1 = _spawn_replica(p1, str(tmp_path))
     proc2 = _spawn_replica(p2, str(tmp_path))
     table = table_rr = rs = rs_rr = None
+    # the drill's own time limit (no pytest-timeout here): its replicas are
+    # killed when it is up, so that every wait below ends at once and a hang
+    # costs the run DRILL_LIMIT_S, not the 420 s + 300 s of its own waits
+    procs, outran = [proc1, proc2], threading.Event()
+
+    def _time_is_up():
+        outran.set()
+        for p in procs:
+            p.kill()
+
+    limit = threading.Timer(DRILL_LIMIT_S, _time_is_up)
+    limit.daemon = True
+    limit.start()
     try:
-        deadline = time.time() + 420
-        _wait_proc_ready(proc1, p1, deadline)
-        _wait_proc_ready(proc2, p2, deadline)
+        limit_at = time.time() + DRILL_LIMIT_S
+        _wait_proc_ready(proc1, p1, limit_at)
+        _wait_proc_ready(proc2, p2, limit_at)
 
         # the prober's re-probe backoff doubles while a replica stays down
         # (0.3 s -> backoff_max, 30 s by default), and a restart under a
@@ -825,8 +847,9 @@ def test_two_process_affinity_and_fault_drill(tmp_path):
         # (d) recovery: restart the victim on its port -> re-admission
         dead_port = int(owner.rsplit(":", 1)[1])
         revived = _spawn_replica(dead_port, str(tmp_path))
+        procs.append(revived)
         try:
-            _wait_proc_ready(revived, dead_port, time.time() + 420)
+            _wait_proc_ready(revived, dead_port, limit_at)
             deadline = time.time() + 30
             while _get_json(rp_aff, "/health")["healthy"] < 2 \
                     and time.time() < deadline:
@@ -838,7 +861,18 @@ def test_two_process_affinity_and_fault_drill(tmp_path):
                                        opener="[kill] welcome back"),
                                  timeout=300)
             assert status == 200
-            assert _metric_sum(survivor_port, "http_requests_total") > 0
+            # the survivor's /metrics, scraped while the other workers of
+            # the run compile beside it: one scrape outlasted its 30 s in
+            # the driver's runs (the drill itself takes 30 s alone, 116 s
+            # there), so a slow scrape is asked again, not failed
+            for attempt in range(3):
+                try:
+                    served = _metric_sum(survivor_port, "http_requests_total")
+                    break
+                except (TimeoutError, urllib.error.URLError, OSError):
+                    if attempt == 2:
+                        raise
+            assert served > 0
         finally:
             if revived.poll() is None:
                 revived.terminate()
@@ -846,7 +880,12 @@ def test_two_process_affinity_and_fault_drill(tmp_path):
                 revived.wait(timeout=30)
             except subprocess.TimeoutExpired:
                 revived.kill()
+    except BaseException:
+        if outran.is_set():
+            pytest.fail(f"the drill outran its own {DRILL_LIMIT_S} s")
+        raise
     finally:
+        limit.cancel()
         for closer in (rs, rs_rr):
             if closer is not None:
                 closer.stop()

@@ -342,7 +342,7 @@ async def test_fault_drill_one_bundle_readable_after_recovery(
     engine recovered in place."""
     path = str(tmp_path / "tiny-drill.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     health = HealthMonitor()

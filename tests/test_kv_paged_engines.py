@@ -3,8 +3,8 @@
 The load-bearing invariant mirrors the chunked-prefill rollout (PR 5):
 paging changes WHERE prefix KV comes from, never WHAT a greedy request
 produces.  With no cache hit the paged engines dispatch exactly the
-dense-ring programs, so greedy decode is bit-identical on all four
-engine flavors — pinned here against a dense serial reference.  On top
+dense-ring programs, so greedy decode is bit-identical on both
+engines — pinned here against a dense serial reference.  On top
 of that: radix reuse across turns and across conversations sharing a
 system prompt, cross-lane reuse on the continuous scheduler, explicit
 seeds bypassing reuse (the reproducibility contract), pool-exhaustion
@@ -18,8 +18,6 @@ import pytest
 from llama_fastapi_k8s_gpu_tpu.engine import (
     ContinuousEngine,
     Engine,
-    MeshEngine,
-    SPEngine,
 )
 from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
 from llama_fastapi_k8s_gpu_tpu.testing import TINY_CFG, write_tiny_llama_gguf
@@ -76,7 +74,7 @@ def _convo(turn2: str = "And another one."):
 
 
 # ---------------------------------------------------------------------------
-# paged-vs-dense greedy bit-parity, all four engines
+# paged-vs-dense greedy bit-parity, both engines
 # ---------------------------------------------------------------------------
 
 def test_serial_paged_matches_dense(model_path, dense_texts):
@@ -89,20 +87,8 @@ def test_serial_paged_matches_dense(model_path, dense_texts):
     assert stats["stored_pages"] > 0
 
 
-def test_mesh_paged_matches_dense(model_path, dense_texts):
-    """MeshEngine under paging: the serial (stream) path consults the
-    radix index; the batched-cycle path keeps its lane rings untouched —
-    both must stay greedy-identical to the dense serial reference."""
-    eng = MeshEngine(model_path, dp=2, tp=2, batch_size=2,
-                     **BASE_KW, **PAGED_KW)
-    assert _texts(eng) == dense_texts
-    got = [eng.create_chat_completions([p], temperature=0.0, max_tokens=8)[0]
-           ["choices"][0]["message"]["content"] for p in PROMPTS]
-    assert got == dense_texts
-
-
 def test_continuous_paged_matches_dense(model_path, dense_texts):
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2,
+    eng = ContinuousEngine(model_path, batch_size=2,
                            **BASE_KW, **PAGED_KW)
     try:
         assert eng._lane_prefix is False       # folded behind the radix
@@ -110,19 +96,6 @@ def test_continuous_paged_matches_dense(model_path, dense_texts):
     finally:
         eng.shutdown()
 
-
-def test_sp_paged_gates_off_and_matches(model_path, dense_texts):
-    """SPEngine shards the ring's n_ctx dim: paging must gate itself off
-    (with attribution) and serve the identical dense path."""
-    eng = SPEngine(model_path, sp=2, tp=1, prefix_cache=False,
-                   **BASE_KW, **PAGED_KW)
-    assert eng._kv_paged is False and eng._kvpool is None
-    assert _texts(eng) == dense_texts
-
-
-# ---------------------------------------------------------------------------
-# radix reuse behavior (serial)
-# ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
 def paged_serial(model_path):
@@ -179,6 +152,9 @@ def test_explicit_seed_bypasses_radix(paged_serial):
 
 def test_recover_resets_pool(paged_serial):
     eng = paged_serial
+    # pages of its own: under ``--dist load`` this case can be the first
+    # of the file that its worker runs, on a pool nothing has stored in
+    eng.create_chat_completion(_convo()[0], temperature=0.0, max_tokens=8)
     assert eng._kvpool.occupancy()["pages_used"] > 0
     assert eng.recover()
     occ = eng._kvpool.occupancy()
@@ -195,7 +171,7 @@ def test_continuous_cross_lane_reuse_and_exhaustion(model_path):
     a burst of distinct conversations completes normally — stores skip
     or evict, requests never fail (backpressure, not OOM)."""
     kw = dict(PAGED_KW, kv_pool_pages=4, kv_spill_pages=0)
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2,
+    eng = ContinuousEngine(model_path, batch_size=2,
                            **BASE_KW, **kw)
     try:
         msgs, turn2 = _convo()
@@ -298,7 +274,7 @@ def test_continuous_reuse_survives_poisoned_span(model_path):
     for the life of the process (``_begin_admission``'s cleanup releases
     its own ``lease`` local, still None while the helper is on the stack).
     The span set is now guarded: the hit proceeds, nothing stays pinned."""
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2,
+    eng = ContinuousEngine(model_path, batch_size=2,
                            **BASE_KW, **PAGED_KW)
     try:
         msgs, _ = _convo()

@@ -564,9 +564,7 @@ def test_a_file_the_block_cannot_compute_is_refused_by_name(gguf_path, meta,
 
 
 @pytest.mark.parametrize("feature, setting", [
-    ("int8", "LFKT_KV_DTYPE=int8"), ("paged", "LFKT_KV_PAGED=1"),
-    ("tp", "LFKT_MESH_TP=2"), ("sp", "LFKT_MESH_SP > 1"),
-    ("cycle", "LFKT_SCHEDULER=cycle")])
+    ("int8", "LFKT_KV_DTYPE=int8"), ("paged", "LFKT_KV_PAGED=1")])
 def test_what_the_kind_cannot_serve_is_refused_by_name(lane_engine, feature,
                                                        setting):
     """Every ask the kind refuses, through the engines' one refusal (the

@@ -30,8 +30,6 @@ from llama_fastapi_k8s_gpu_tpu.engine import (
     ContinuousEngine,
     Engine,
     FakeEngine,
-    MeshEngine,
-    SPEngine,
 )
 from llama_fastapi_k8s_gpu_tpu.engine.continuous import AdmissionController
 from llama_fastapi_k8s_gpu_tpu.obs.memledger import MemLedger
@@ -244,32 +242,29 @@ def _components(ledger):
     return {(r["component"], r["model"]) for r in ledger._rows()}
 
 
-def test_all_four_engines_register_surfaces(ledger, model_path):
+def test_both_engines_register_surfaces(ledger, model_path):
     eng = Engine(model_path, n_ctx=128, prefill_buckets=(32,))
     name = eng.model_name
     assert {("weights", name), ("kv_ring", name)} <= _components(ledger)
-
-    mesh = MeshEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=128,
-                      decode_chunk=4, prefill_buckets=(32,))
-    assert ("kv_lanes", name) in _components(ledger)
-
-    sp = SPEngine(model_path, sp=2, tp=2, n_ctx=128, decode_chunk=4,
-                  prefill_buckets=(32,))
-    # the sp engine's sharded ring reports its GLOBAL logical bytes
+    assert ("kv_lanes", name) not in _components(ledger)
     rows = {(r["component"], r["model"]): r["bytes"]
             for r in ledger._rows()}
     assert rows[("kv_ring", name)] > 0
 
-    cont = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    cont = ContinuousEngine(model_path, batch_size=2, n_ctx=128,
                             decode_chunk=4, max_gen_tokens=8,
                             prefill_buckets=(32, 64, 128))
     try:
         comps = _components(ledger)
         assert ("kv_scratch", name) in comps
         assert ("kv_lanes", name) in comps
+        rows = {(r["component"], r["model"]): r["bytes"]
+                for r in ledger._rows()}
+        # two lanes of the ring and their bookkeeping
+        assert rows[("kv_lanes", name)] > 2 * rows[("kv_scratch", name)]
     finally:
         cont.shutdown()
-    del eng, mesh, sp
+    del eng
 
 
 def test_paged_pool_registers_arena_rows(ledger, model_path):
@@ -304,7 +299,7 @@ def test_continuous_wave_consults_ledger_and_annotates(ledger, model_path):
     """The scheduler passes the ledger's verdict into the controller,
     publishes mem_pressure in scheduler_stats, bumps the cataloged
     counter, and stamps in-flight traces ONCE per rising edge."""
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     m = Metrics()
@@ -366,6 +361,17 @@ async def test_two_model_paged_reconciliation_within_5pct(ledger, ggufs):
     /debug/memory component sum explains the registry's allocations to
     within 5% of jax.live_arrays() ground truth, and the residual line
     carries exactly the remainder (the pre-existing process bytes)."""
+    # ``before`` is taken AFTER an app has started (ROADMAP C0): the
+    # start-up's own collection (server/app.py ``_settle_heap``) and its
+    # rebinding of the process-wide sinks free arrays that earlier tests of
+    # this worker still held through cycles (52 MB in the driver's runs),
+    # which then read as NEGATIVE growth of this test's registry.  A
+    # throwaway app over a fake engine pays for that first.
+    warm = create_app(engine=FakeEngine(reply="hi"))
+    async with httpx.ASGITransport(app=warm):
+        await warm.router.startup()
+        await warm.router.shutdown()
+    del warm
     before = _settled_truth(ledger)
     assert before["source"] == "jax.live_arrays"
     pa, pb = ggufs
@@ -536,7 +542,7 @@ def test_disarmed_decode_path_is_poison_proof(ledger, model_path,
     monkeypatch.setattr(ledger, "_device_stats", boom)
     monkeypatch.setattr(ledger, "_rows", boom)
     monkeypatch.setattr(ledger, "ground_truth", boom)
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     try:

@@ -233,21 +233,26 @@ def test_incompatible_geometry_degrades_to_private_pool(ggufs):
 
 def test_registry_factory_mirrors_single_model_semantics(ggufs):
     """A 1-entry LFKT_MODELS manifest must keep the single-model
-    factory's serving shape: cycle scheduler still builds a MeshEngine
-    (no silent scheduler swap), and sp×batch refuses identically."""
+    factory's serving shape: the lane engine for LFKT_BATCH_SIZE > 1, the
+    serial engine for 1 (one choice for both factories: server/app.py
+    ``_build_engine``)."""
     from llama_fastapi_k8s_gpu_tpu.server.app import _registry_factory
 
     pa, _ = ggufs
     reg = _registry_factory(Settings(
-        models=f"solo={pa}", scheduler="cycle", batch_size=2,
+        models=f"solo={pa}", batch_size=2,
         max_context_tokens=128, prefill_buckets="32"))
-    assert type(reg.resolve(None)).__name__ == "MeshEngine"
-    assert reg.model_names() == ["solo"]
+    try:
+        assert type(reg.resolve(None)).__name__ == "ContinuousEngine"
+        assert reg.resolve(None).batch_size == 2
+        assert reg.model_names() == ["solo"]
+    finally:
+        reg.resolve(None).shutdown()
 
-    with pytest.raises(ValueError) as ei:
-        _registry_factory(Settings(models=f"solo={pa}", mesh_sp=2,
-                                   batch_size=2))
-    assert "LFKT_BATCH_SIZE" in str(ei.value)
+    reg = _registry_factory(Settings(
+        models=f"solo={pa}", batch_size=1,
+        max_context_tokens=128, prefill_buckets="32"))
+    assert type(reg.resolve(None)).__name__ == "Engine"
 
 
 # ---------------------------------------------------------------------------

@@ -9,9 +9,8 @@ Four layers:
 2. **Tracer unit behavior** — deterministic sampling, ring eviction
    bounds, W3C ``traceparent`` ingest, idempotent finish, global-event
    fan-in, and the zero-cost guarantee for sampled-out requests.
-3. **Engine span trees** — every engine flavor (serial, mesh-batched,
-   continuous, sequence-parallel) produces a complete, monotonic,
-   nested span tree; concurrent load against a real
+3. **Engine span trees** — both engines (serial, continuous) produce a
+   complete, monotonic, nested span tree; concurrent load against a real
    :class:`ContinuousEngine` through the real server yields one complete
    tree per sampled request.
 4. **Server surface** — /debug endpoints, response headers, request-id
@@ -558,7 +557,7 @@ def model_path(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def cengine(model_path):
-    eng = ContinuousEngine(model_path, dp=2, tp=2, batch_size=4, n_ctx=128,
+    eng = ContinuousEngine(model_path, batch_size=4, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     yield eng
@@ -618,41 +617,6 @@ def test_serial_engine_span_tree(model_path):
                                     max_tokens=8, trace=tr2))
     t.finish(tr2)
     _assert_engine_tree(tr2.to_dict())
-
-
-def test_mesh_engine_span_tree(model_path):
-    from llama_fastapi_k8s_gpu_tpu.engine import MeshEngine
-
-    eng = MeshEngine(model_path, dp=2, tp=2, batch_size=2, n_ctx=128,
-                     decode_chunk=4, max_gen_tokens=16,
-                     prefill_buckets=(32, 64, 128))
-    t = Tracer(sample=1.0, ring=4)
-    traces = [t.start(), None]       # entry 1 sampled out: must not trace
-    outs = eng.create_chat_completions([MSGS, MSGS], temperature=0.0,
-                                       max_tokens=8, traces=traces)
-    t.finish(traces[0])
-    assert all(o["usage"]["completion_tokens"] >= 1 for o in outs)
-    d = traces[0].to_dict()
-    _assert_engine_tree(d)
-    assert d["meta"]["engine"] == "MeshEngine"
-    assert d["meta"]["lane"] == 0
-
-
-def test_sp_engine_span_tree(model_path):
-    from llama_fastapi_k8s_gpu_tpu.engine import SPEngine
-
-    eng = SPEngine(model_path, sp=2, tp=1, n_ctx=128, decode_chunk=4,
-                   max_gen_tokens=16, prefill_buckets=(32, 64, 128))
-    t = Tracer(sample=1.0, ring=4)
-    tr = t.start()
-    out = eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=8,
-                                     trace=tr)
-    t.finish(tr)
-    assert out["usage"]["completion_tokens"] >= 1
-    d = tr.to_dict()
-    _assert_engine_tree(d)
-    names = _spans_by_name(d["root"])
-    assert names["engine"][0]["attrs"]["sp"] == 2   # ring geometry stamped
 
 
 def test_continuous_engine_span_tree(cengine):

@@ -1206,22 +1206,6 @@ def test_the_expert_metrics_are_in_the_catalog():
     assert METRICS["expert_picks_total"].labels == ("expert",)
 
 
-def test_expert_leaves_are_replicated_on_a_mesh(loaded):
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import (
-        make_mesh, param_shardings)
-
-    params, _ = loaded["q4k"]
-    sh = param_shardings(params, make_mesh(1, 1, 1))
-    assert jax.tree.structure(sh) == jax.tree.structure(
-        jax.tree.map(lambda _: 0, params))
-    for name in ("w_router", "attn_q_norm"):
-        assert sh["layers"][name].spec == P()
-    assert all(s.spec == P() for s in sh["layers"]["w_down_exps"].values())
-
-
 # ---------------------------------------------------------------------------
 # the benchmark's files for the block (tier-1 collects tests/ only)
 # ---------------------------------------------------------------------------

@@ -15,8 +15,7 @@ import pytest
 from llama_fastapi_k8s_gpu_tpu.gguf.constants import GGMLType
 
 # jax-version compat: jax.tree.flatten_with_path landed after 0.4.37; the
-# tree_util spelling exists on every version this repo supports (the same
-# shim family as parallel/ring.py's shard_map fallback)
+# tree_util spelling exists on every version this repo supports
 _flatten_with_path = getattr(
     jax.tree, "flatten_with_path", None) or jax.tree_util.tree_flatten_with_path
 from llama_fastapi_k8s_gpu_tpu.gguf.quants import dequantize, quantize

@@ -3,15 +3,15 @@
 Chip time is scarce; compile counts and dispatch counts are not — they
 are exact, device-independent integers the devtime registry
 (obs/devtime.py) measures identically on the CPU backend.  These tests
-pin, per engine flavor:
+pin, for both engines:
 
 - **warmup compiles exactly K programs** (named, counted): a new jit
   entry point, a lost warmup shape, or a silent extra signature changes
   K and fails here — on CPU, long before a chip session pays for it;
 - **steady state compiles nothing**: after warmup, requests re-dispatch
   the warmed programs only (this pin found and now guards two real
-  holes: the sharded engines' chunk-2 donated-state resharding compile,
-  fixed by the two-chunk warmup, and the serial tail-chunk compile,
+  holes: a second decode chunk's donated-state compile, fixed by the
+  two-chunk warmup, and the serial tail-chunk compile,
   fixed by always dispatching full chunks — pinned below);
 - **each request dispatches exactly D per program** — an extra dispatch
   per decode chunk is launch/DMA overhead; it must never sneak in
@@ -20,7 +20,7 @@ pin, per engine flavor:
 The pins run in ONE fresh subprocess: jit caches are process-global, so
 a suite that already warmed the module-level entry points would satisfy
 any compile count vacuously.  Shapes: tiny GGUF, n_ctx=128, buckets
-(32, 64, 128), decode_chunk=4, 8 virtual CPU devices (conftest's mesh).
+(32, 64, 128), decode_chunk=4.
 """
 
 from __future__ import annotations
@@ -46,8 +46,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
 from llama_fastapi_k8s_gpu_tpu.obs.devtime import DEVTIME
-from llama_fastapi_k8s_gpu_tpu.engine import (
-    ContinuousEngine, Engine, MeshEngine, SPEngine)
+from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine
 
 path = tempfile.mktemp(suffix=".gguf")
 write_tiny_llama_gguf(path)
@@ -85,27 +84,9 @@ r = eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=11)
 out["serial_tail"] = delta(b, snap())
 out["serial_tail_tokens"] = {"completion": (r["usage"]["completion_tokens"], 0)}
 
-# -- mesh-batched ---------------------------------------------------------
-DEVTIME.reset()
-eng = MeshEngine(path, dp=2, tp=2, batch_size=2, **KW)
-eng.warmup()
-w = snap()
-out["mesh_warmup"] = w
-eng.create_chat_completions([MSGS, MSGS], temperature=0.0, max_tokens=9)
-out["mesh_req"] = delta(w, snap())
-
-# -- sequence-parallel ----------------------------------------------------
-DEVTIME.reset()
-eng = SPEngine(path, sp=2, tp=1, **KW)
-eng.warmup()
-w = snap()
-out["sp_warmup"] = w
-eng.create_chat_completion(MSGS, temperature=0.0, max_tokens=9)
-out["sp_req"] = delta(w, snap())
-
 # -- continuous ------------------------------------------------------------
 DEVTIME.reset()
-ceng = ContinuousEngine(path, dp=2, tp=2, batch_size=4, **KW)
+ceng = ContinuousEngine(path, batch_size=4, **KW)
 ceng.warmup()
 w = snap()
 out["cont_warmup"] = w
@@ -146,38 +127,15 @@ def test_serial_warmup_compile_budget(pins):
         "prefill": 2, "first_sample": 1, "decode_chunk": 1}
 
 
-def test_mesh_warmup_compile_budget(pins):
-    # batched_prefill: 3 buckets; batched_decode_chunk: 2 (chunk 1 against
-    # the device_put state sharding + chunk 2 against the donated jit
-    # output sharding — the hole the two-chunk warmup closes); plus the
-    # serial streaming path (prefill 3 incl. the 32-bucket 'hi' prompt,
-    # decode_chunk 2 for the same sharding pair)
-    assert _compiles(pins["mesh_warmup"]) == {
-        "batched_prefill": 3, "batched_first_sample": 1,
-        "batched_decode_chunk": 2,
-        "prefill": 3, "first_sample": 1, "decode_chunk": 2}
-
-
-def test_sp_warmup_compile_budget(pins):
-    # sp_prefill: 2 lowerings reach the compiler.  (3 while the wrapper
-    # counted every entry of the jit cache: the third was a state under
-    # another NAME for the same placement, which lowered and compiled
-    # nothing: obs/devtime.py _listen.)
-    assert _compiles(pins["sp_warmup"]) == {
-        "sp_prefill": 2, "first_sample": 1, "sp_decode_chunk": 2}
-
-
 def test_continuous_warmup_compile_budget(pins):
-    # prefill_chunk: 4 admission/suffix slice shapes; lane_decode_chunk: the
-    # sharding pair; lane_cache_copy: the lane-prefix snapshot program;
-    # lane_write: one shape under the three shardings the batched state has
-    # at start-up (as placed at construction; as a lane write returns that,
-    # when the first pass admits a second request ahead of the engine's
-    # first chunk, ISSUE 33; as a decode chunk returns it: the only one
-    # after the first chunk, so none of them can first occur later)
+    # prefill_chunk: 3 admission/suffix slice shapes; first_sample,
+    # lane_write, lane_decode_chunk and lane_cache_copy (the lane-prefix
+    # snapshot program): ONE each.  Every leaf of the process is placed
+    # plainly on the one device, so no program is built twice under two
+    # names of one placement
     assert _compiles(pins["cont_warmup"]) == {
-        "prefill_chunk": 4, "first_sample": 1, "lane_decode_chunk": 2,
-        "lane_write": 3, "lane_cache_copy": 1}
+        "prefill_chunk": 3, "first_sample": 1, "lane_decode_chunk": 1,
+        "lane_write": 1, "lane_cache_copy": 1}
 
 
 # ---------------------------------------------------------------------------
@@ -202,18 +160,6 @@ def test_serial_budget_tail_compiles_nothing(pins):
     assert pins["serial_tail"] == {
         "prefill": (0, 1), "first_sample": (0, 1), "decode_chunk": (0, 3)}
     assert pins["serial_tail_tokens"]["completion"][0] == 11
-
-
-def test_mesh_request_dispatch_budget(pins):
-    assert pins["mesh_req"] == {
-        "batched_prefill": (0, 1), "batched_first_sample": (0, 1),
-        "batched_decode_chunk": (0, 2)}
-
-
-def test_sp_request_dispatch_budget(pins):
-    assert pins["sp_req"] == {
-        "sp_prefill": (0, 1), "first_sample": (0, 1),
-        "sp_decode_chunk": (0, 2)}
 
 
 # ---------------------------------------------------------------------------

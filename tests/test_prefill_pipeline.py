@@ -5,8 +5,7 @@ pieces and double-buffers their dispatch (engine/engine.py
 ``_prefill_padded``; the continuous scheduler's admission machine in
 engine/continuous.py).  The load-bearing invariant: slicing changes WHEN
 device work is dispatched, never WHAT a greedy request produces — pinned
-here against the monolithic path on all four engine flavors (serial,
-mesh-batched, continuous, sequence-parallel).
+here against the monolithic path on both engines (serial, continuous).
 """
 
 from __future__ import annotations
@@ -16,8 +15,6 @@ import pytest
 from llama_fastapi_k8s_gpu_tpu.engine import (
     ContinuousEngine,
     Engine,
-    MeshEngine,
-    SPEngine,
 )
 from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
 from llama_fastapi_k8s_gpu_tpu.testing import TINY_CFG, write_tiny_llama_gguf
@@ -85,34 +82,11 @@ def test_serial_slicing_actually_engages(model_path):
     assert calls and calls[0][1] > eng._prefill_chunk
 
 
-def test_mesh_serial_path_chunked_matches_monolithic(model_path, mono_texts):
-    """MeshEngine's serial (stream) path rides Engine._start: sliced
-    prefill there must keep greedy parity too."""
-    eng = MeshEngine(model_path, dp=2, tp=2, batch_size=2, n_ctx=512,
-                     decode_chunk=4, max_gen_tokens=16,
-                     prefill_buckets=BUCKETS, prefix_cache=False,
-                     prefill_chunk=16, prefill_overlap=2)
-    assert _texts(eng) == mono_texts
-
-
-def test_mesh_batched_matches_monolithic(model_path, mono_texts):
-    """The batched prefill program stays monolithic; its outputs must agree
-    with the serial monolithic reference (and therefore with the sliced
-    path, by the test above)."""
-    eng = MeshEngine(model_path, dp=2, tp=2, batch_size=2, n_ctx=512,
-                     decode_chunk=4, max_gen_tokens=16,
-                     prefill_buckets=BUCKETS, prefix_cache=False,
-                     prefill_chunk=16, prefill_overlap=2)
-    got = [eng.create_chat_completions([p], temperature=0.0, max_tokens=8)[0]
-           ["choices"][0]["message"]["content"] for p in PROMPTS]
-    assert got == mono_texts
-
-
 def test_continuous_chunked_admission_matches_monolithic(model_path,
                                                          mono_texts):
     """The scheduler's chunked admission (with the admission controller ON,
     the default) is greedy-identical to serial monolithic prefill."""
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=512,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=512,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=BUCKETS, prefill_chunk=16,
                            lane_prefix_cache=False)
@@ -120,17 +94,6 @@ def test_continuous_chunked_admission_matches_monolithic(model_path,
         assert _texts(eng) == mono_texts
     finally:
         eng.shutdown()
-
-
-def test_sp_engine_matches_monolithic(model_path, mono_texts):
-    """SPEngine gates slicing off (_SLICE_PREFILL: its ring is sp-sharded
-    over n_ctx) — passing the pipeline knobs must be a no-op that keeps
-    serial parity."""
-    eng = SPEngine(model_path, sp=2, tp=1, n_ctx=512, decode_chunk=4,
-                   max_gen_tokens=16, prefill_buckets=BUCKETS,
-                   prefix_cache=False, prefill_chunk=16, prefill_overlap=2)
-    assert not eng._slices_prefill(128)
-    assert _texts(eng) == mono_texts
 
 
 def test_serial_prefix_reuse_composes_with_slicing(model_path):

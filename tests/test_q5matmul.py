@@ -191,31 +191,3 @@ def test_pre_layout_stacked_matches_plain(monkeypatch):
         plain = np.asarray(q5k_matmul(x, w, interpret=True))
         stacked = np.asarray(q5k_matmul_stacked(x, ws, i, interpret=True))
         np.testing.assert_array_equal(plain, stacked)
-
-
-def test_pre_layout_shards_on_mesh(monkeypatch):
-    """The q5p plane must ride the full shard_params path: tp over N when
-    the per-shard N keeps the kernel tiling, whole-leaf replication when
-    it would not (same contract as the q6p test in test_q6matmul.py)."""
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import (
-        make_mesh, param_shardings, shard_params,
-    )
-
-    rng = np.random.default_rng(23)
-    monkeypatch.setenv("LFKT_Q5K_KERNEL", "pre")
-    n, k = 256, 2048
-    w = prep_q5k(quant_q5_k(_rand_weights(rng, n, k).reshape(-1)), n, k)
-    ws = {key: jnp.stack([w[key], w[key]]) for key in w}
-    n_bad = 24                      # 24/tp=12, not a multiple of gran=8
-    w_bad = prep_q5k(
-        quant_q5_k(_rand_weights(rng, n_bad, k).reshape(-1)), n_bad, k)
-    params = {"tok_emb": jnp.zeros((8, 8)), "out_norm": jnp.zeros((8,)),
-              "layers": {"w_down": ws, "attn_norm": jnp.zeros((2, 8))},
-              "output": w_bad}
-    mesh = make_mesh(dp=2, tp=2, sp=2)
-    sh = param_shardings(params, mesh)
-    assert sh["layers"]["w_down"]["q5p"] is not None
-    sharded = shard_params(params, mesh)
-    assert sharded["layers"]["w_down"]["q5p"].shape == ws["q5p"].shape
-    head_spec = sharded["output"]["q5p"].sharding.spec
-    assert all(a is None for a in head_spec), head_spec

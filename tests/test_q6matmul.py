@@ -144,33 +144,6 @@ def test_load_params_q4km_fuses_both_types(tmp_path):
     assert np.abs(a - b).max() / denom < 0.08, np.abs(a - b).max() / denom
 
 
-def test_q6k_params_shard_over_mesh():
-    """param_shardings must cover {'q4','q2','sm6'} dicts."""
-    import numpy as np
-
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import make_mesh, shard_params
-
-    rng = np.random.default_rng(4)
-    w = make_linear_q6k(_rand_weights(rng, 256, 2048))
-    params = {
-        "tok_emb": jnp.zeros((64, 32), jnp.bfloat16),
-        "layers": {"attn_norm": jnp.ones((1, 32)),
-                   "wq": {k: v[None] for k, v in w.items()},
-                   "wk": {k: v[None] for k, v in w.items()},
-                   "wv": {k: v[None] for k, v in w.items()},
-                   "wo": {k: v[None] for k, v in w.items()},
-                   "ffn_norm": jnp.ones((1, 32)),
-                   "w_gate": {k: v[None] for k, v in w.items()},
-                   "w_up": {k: v[None] for k, v in w.items()},
-                   "w_down": {k: v[None] for k, v in w.items()}},
-        "out_norm": jnp.ones(32),
-        "output": {"w": jnp.zeros((64, 32), jnp.bfloat16)},
-    }
-    mesh = make_mesh(dp=2, tp=2, sp=2)
-    sharded = shard_params(params, mesh)
-    assert sharded["layers"]["wq"]["q4"].shape == params["layers"]["wq"]["q4"].shape
-
-
 def _stack_of_one(wd):
     """The stacked call on a stack of one: the ``LFKT_Q6K_KERNEL`` variants
     are bodies of the stacked calls (the unstacked call is the head's, one
@@ -289,38 +262,6 @@ def test_pre_layout_stacked_matches_plain(monkeypatch):
         plain = np.asarray(q6k_matmul(x, w, interpret=True))
         stacked = np.asarray(q6k_matmul_stacked(x, ws, i, interpret=True))
         np.testing.assert_array_equal(plain, stacked)
-
-
-def test_pre_layout_shards_on_mesh(monkeypatch):
-    """The q6p plane must ride the full shard_params path: tp over N when
-    the per-shard N keeps the kernel tiling, and — the fused-GROUP guard
-    (`_FUSED_MAIN_KEY`) — whole-leaf replication when it would not (the
-    Llama-3 output head's 128256/tp=4 = 32064 is not 128-aligned; in
-    interpret mode the granularity is 8, so N=24 over tp=2 → 12 models
-    the same violation)."""
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import (
-        make_mesh, param_shardings, shard_params,
-    )
-
-    rng = np.random.default_rng(13)
-    monkeypatch.setenv("LFKT_Q6K_KERNEL", "pre")
-    n, k = 256, 2048
-    w = prep_q6k(quant_q6_k(_rand_weights(rng, n, k).reshape(-1)), n, k)
-    ws = {key: jnp.stack([w[key], w[key]]) for key in w}
-    n_bad = 24                      # 24/tp=12, not a multiple of gran=8
-    w_bad = prep_q6k(
-        quant_q6_k(_rand_weights(rng, n_bad, k).reshape(-1)), n_bad, k)
-    params = {"tok_emb": jnp.zeros((8, 8)), "out_norm": jnp.zeros((8,)),
-              "layers": {"w_down": ws, "attn_norm": jnp.zeros((2, 8))},
-              "output": w_bad}
-    mesh = make_mesh(dp=2, tp=2, sp=2)
-    sh = param_shardings(params, mesh)
-    assert sh["layers"]["w_down"]["q6p"] is not None
-    sharded = shard_params(params, mesh)
-    assert sharded["layers"]["w_down"]["q6p"].shape == ws["q6p"].shape
-    # the ill-fitting head leaf must come back REPLICATED, not half-sharded
-    head_spec = sharded["output"]["q6p"].sharding.spec
-    assert all(a is None for a in head_spec), head_spec
 
 
 # ---------------------------------------------------------------------------

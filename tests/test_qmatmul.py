@@ -140,19 +140,43 @@ def test_load_params_q4k_mixed_formats(tmp_path):
     assert np.abs(a - b).max() / denom < 0.08, np.abs(a - b).max() / denom
 
 
-def test_q4k_params_shard_over_mesh():
-    """param_shardings must cover {'qs','sm'} dicts (v5e-4 path)."""
-    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
-    from llama_fastapi_k8s_gpu_tpu.models.params import synth_params
-    from llama_fastapi_k8s_gpu_tpu.parallel.mesh import make_mesh, shard_params
+# fused-kernel GSPMD rules (custom_partitioning in ops/pallas/q*matmul.py):
+# planes sharded over N on a plain ``jax.sharding.Mesh`` must compute
+# locally and match the unsharded result.  The package has no mesh of its
+# own; the wrappers stay because they are in every cell's traced program
+# (ROADMAP C3).
 
-    cfg = ModelConfig(vocab_size=256, dim=2048, n_layers=1, n_heads=16,
-                      n_kv_heads=8, ffn_dim=2048, n_ctx=32)
-    params = synth_params(cfg, fmt="q4k", seed=0)
-    assert "qs" in params["layers"]["wq"]
-    mesh = make_mesh(dp=2, tp=2, sp=2)
-    sharded = shard_params(params, mesh)
-    assert sharded["layers"]["wq"]["qs"].shape == params["layers"]["wq"]["qs"].shape
+@pytest.mark.parametrize("maker_name", ["q4k", "q5k", "q6k", "q8", "q6k-pre"])
+def test_fused_matmul_partitioned_matches_unsharded(maker_name, monkeypatch):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from llama_fastapi_k8s_gpu_tpu.ops import (
+        make_linear_q5k,
+        make_linear_q6k,
+        make_linear_q8,
+    )
+
+    if maker_name == "q6k-pre":
+        monkeypatch.setenv("LFKT_Q6K_KERNEL", "pre")
+    maker = {"q4k": make_linear_q4k, "q5k": make_linear_q5k,
+             "q6k": make_linear_q6k, "q6k-pre": make_linear_q6k,
+             "q8": make_linear_q8}[maker_name]
+    rng = np.random.default_rng(5)
+    wf = rng.standard_normal((256, 2048)).astype(np.float32) * 2048 ** -0.5
+    w = maker(wf)
+    x = jnp.asarray(rng.standard_normal((3, 2048)), jnp.bfloat16)
+    ref = np.asarray(linear(x, w).astype(jnp.float32))
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    # quantized planes (N, K/x) shard their output dim N; scale tables
+    # (kt, N, 128) shard N in the middle
+    ws = jax.device_put(w, {
+        k: NamedSharding(mesh, P("tp", None) if w[k].ndim == 2
+                         else P(None, "tp", None)) for k in w})
+    assert any(len(v.sharding.device_set) == 2 for v in ws.values())
+    got = jax.jit(linear)(x, ws)
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)), ref,
+                               rtol=2e-2, atol=2e-2 * np.abs(ref).max())
 
 
 def test_shipped_kernel_defaults_are_the_measured_configuration():

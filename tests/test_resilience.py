@@ -18,7 +18,7 @@ import time
 
 import pytest
 
-from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine, MeshEngine
+from llama_fastapi_k8s_gpu_tpu.engine import ContinuousEngine, Engine
 from llama_fastapi_k8s_gpu_tpu.engine.fake import FakeEngine
 from llama_fastapi_k8s_gpu_tpu.engine.watchdog import Watchdog
 from llama_fastapi_k8s_gpu_tpu.testing import write_tiny_llama_gguf
@@ -287,7 +287,7 @@ def test_failed_mid_recovery_does_not_go_zombie_ready(tmp_path):
     NOT declare an in-place recovery over a scheduler-less zombie."""
     path = str(tmp_path / "tiny-zombie.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=64,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=64,
                            decode_chunk=2, max_gen_tokens=8,
                            prefill_buckets=(32, 64))
     health = HealthMonitor()
@@ -375,50 +375,11 @@ def test_serial_engine_no_deadline_is_unchanged(serial_engine):
     assert a["choices"][0]["finish_reason"] == b["choices"][0]["finish_reason"]
 
 
-def test_mesh_engine_per_lane_deadline(tmp_path):
-    path = str(tmp_path / "tiny-mesh-res.gguf")
-    write_tiny_llama_gguf(path)
-    eng = MeshEngine(path, dp=2, tp=2, batch_size=2, n_ctx=128,
-                     decode_chunk=4, max_gen_tokens=64,
-                     prefill_buckets=(32, 64, 128))
-    outs = eng.create_chat_completions(
-        [MSGS, MSGS], temperature=0.0, max_tokens=24,
-        deadlines=[time.time(), None], aborts=[None, None])
-    # entry 0 expired immediately; entry 1 unaffected by its neighbor
-    assert outs[0]["choices"][0]["finish_reason"] == "deadline"
-    assert outs[0]["usage"]["completion_tokens"] <= 1 + eng.decode_chunk
-    assert outs[1]["usage"]["completion_tokens"] > \
-        outs[0]["usage"]["completion_tokens"]
-
-
-def test_mesh_engine_abort_frees_cycle(tmp_path):
-    path = str(tmp_path / "tiny-mesh-ab.gguf")
-    write_tiny_llama_gguf(path)
-    eng = MeshEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
-                     decode_chunk=4, max_gen_tokens=64,
-                     prefill_buckets=(32, 64, 128))
-    # both entries abort after a couple of chunks: the cycle must end long
-    # before the 60-token budget (one timed-out batch no longer pins the
-    # consumer for the full budget)
-    state = {"n": 0}
-
-    def abort():
-        state["n"] += 1
-        return state["n"] > 4
-
-    outs = eng.create_chat_completions(
-        [MSGS, MSGS], temperature=0.0, max_tokens=60,
-        aborts=[abort, abort])
-    for o in outs:
-        assert o["choices"][0]["finish_reason"] == "deadline"
-        assert o["usage"]["completion_tokens"] < 60
-
-
 @pytest.fixture(scope="module")
 def cont_engine(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("model") / "tiny-cont-res.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=32,
                            prefill_buckets=(32, 64, 128))
     yield eng
@@ -462,7 +423,7 @@ def test_continuous_deadline_mid_generation_frees_lane(cont_engine):
 def test_continuous_watchdog_full_lifecycle(tmp_path):
     path = str(tmp_path / "tiny-lifecycle.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=128,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=128,
                            decode_chunk=4, max_gen_tokens=16,
                            prefill_buckets=(32, 64, 128))
     health = HealthMonitor()
@@ -515,7 +476,7 @@ def test_continuous_watchdog_full_lifecycle(tmp_path):
 def test_continuous_recover_refused_after_deliberate_shutdown(tmp_path):
     path = str(tmp_path / "tiny-shut.gguf")
     write_tiny_llama_gguf(path)
-    eng = ContinuousEngine(path, dp=1, tp=1, batch_size=2, n_ctx=64,
+    eng = ContinuousEngine(path, batch_size=2, n_ctx=64,
                            decode_chunk=2, max_gen_tokens=8,
                            prefill_buckets=(32, 64))
     eng.shutdown()

@@ -421,6 +421,13 @@ def test_the_state_kernel_is_the_recurrence_and_skips_dead_lanes(live):
             assert np.array_equal(new[b, other], state[b, other])
 
 
+def _lane_step_parts(lane_step, args):
+    """(logits, the ``state`` leaf, the sets) of one lane step, on the host;
+    the rest of the step's cache is dropped with this frame."""
+    logits, cache, sets = lane_step(*args)
+    return np.asarray(logits), np.asarray(cache["state"]), np.asarray(sets)
+
+
 def test_the_stack_with_the_kernels_is_the_stack_without(loaded, tokens,
                                                          served):
     """``attn_impl="pallas"`` (what a TPU resolves to; interpret mode
@@ -437,12 +444,18 @@ def test_the_stack_with_the_kernels_is_the_stack_without(loaded, tokens,
     args = (params, jnp.asarray([tokens[72], tokens[24], 5], jnp.int32),
             jnp.asarray([72, 24, 24], jnp.int32), stacked,
             jnp.asarray([True, True, False]))
-    want, wcache, wsets = programs(cfg)[2](*args)
-    got, gcache, gsets = programs(kcfg)[2](*args)
+    # one stack after the other, the first one's device results freed
+    # before the second is compiled: the worker that held both died in the
+    # compile of the second (a segmentation fault inside
+    # ``backend_compile_and_load`` in the driver's PR 59 run)
+    want, wstate, wsets = (np.asarray(a) for a in _lane_step_parts(
+        programs(cfg)[2], args))
+    got, gstate, gsets = (np.asarray(a) for a in _lane_step_parts(
+        programs(kcfg)[2], args))
     assert rel(got[:2], want[:2]) < SAME * 1e3        # bf16 P in the kernel
     assert np.array_equal(gsets[:2], wsets[:2])
-    assert rel(gcache["state"][:2], wcache["state"][:2]) < SAME
-    assert np.array_equal(gcache["state"][2], stacked["state"][2])
+    assert rel(gstate[:2], wstate[:2]) < SAME
+    assert np.array_equal(gstate[2], np.asarray(stacked["state"][2]))
 
 
 # ---------------------------------------------------------------------------
@@ -706,26 +719,6 @@ def test_what_cannot_hold_the_cache_is_refused_by_name(gguf_path, kw, words):
 
     with pytest.raises(ValueError, match=words):
         Engine(gguf_path, n_ctx=N_CTX, **kw)
-
-
-def test_meshes_refuse_the_architecture_by_name(gguf_path):
-    from llama_fastapi_k8s_gpu_tpu.engine.batched import MeshEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.continuous import ContinuousEngine
-    from llama_fastapi_k8s_gpu_tpu.engine.sp import SPEngine
-
-    for make, words in (
-            (lambda: ContinuousEngine(gguf_path, n_ctx=N_CTX, tp=2,
-                                      batch_size=1, prefill_chunk=SLICE),
-             "LFKT_MESH_TP=2 cannot serve architecture 'minicpm-sala'"),
-            (lambda: MeshEngine(gguf_path, n_ctx=N_CTX, batch_size=2,
-                                prefill_chunk=SLICE),
-             "LFKT_SCHEDULER=cycle cannot serve architecture "
-             "'minicpm-sala'"),
-            (lambda: SPEngine(gguf_path, n_ctx=N_CTX, sp=2,
-                              prefill_chunk=SLICE),
-             "LFKT_MESH_SP > 1 cannot serve architecture 'minicpm-sala'")):
-        with pytest.raises(ValueError, match=words):
-            make()
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from llama_fastapi_k8s_gpu_tpu.ops.linear import (
     linear,
@@ -28,7 +28,6 @@ from llama_fastapi_k8s_gpu_tpu.ops.linear import (
     make_linear_q6k,
     make_linear_q8,
 )
-from llama_fastapi_k8s_gpu_tpu.parallel.mesh import make_mesh
 
 MAKERS = {
     "q4k": make_linear_q4k,
@@ -154,7 +153,7 @@ def test_stacked_partitioned_matches_unsharded(fmt):
     x = jnp.asarray(rng.standard_normal((3, k)), jnp.bfloat16)
     ref = np.asarray(linear(x, ws[1]).astype(jnp.float32))
 
-    mesh = make_mesh(dp=1, tp=2)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     sharded = {
         key: jax.device_put(v, NamedSharding(mesh, _PLANE_SPEC[key]))
         for key, v in stacked.items()

@@ -57,7 +57,7 @@ def model_path(tmp_path_factory):
 @pytest.fixture(scope="module")
 def lanes(model_path):
     """Two lanes, 16-token slices: a LONG prompt admits in several."""
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=256,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=256,
                            decode_chunk=4, max_gen_tokens=64,
                            prefill_buckets=(32, 64, 128), prefill_chunk=16,
                            lane_prefix_cache=False)
@@ -216,7 +216,7 @@ def test_wave_counters_close(model_path):
     """On a fresh engine: live + idle lane-seconds == lanes x wave seconds;
     every slice dispatched is a ``prefill_slice`` span of some request;
     decode chunks name their wave and the slices queued ahead of them."""
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=256,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=256,
                            decode_chunk=4, max_gen_tokens=64,
                            prefill_buckets=(32, 64, 128), prefill_chunk=16,
                            lane_prefix_cache=False)
@@ -317,7 +317,7 @@ class _NeverStop:
 
 
 def _ride_engine(model_path, variant):
-    eng = ContinuousEngine(model_path, dp=1, tp=1, batch_size=2, n_ctx=512,
+    eng = ContinuousEngine(model_path, batch_size=2, n_ctx=512,
                            decode_chunk=4, max_gen_tokens=440,
                            prefill_buckets=(64, 128, 256, 512),
                            prefill_chunk=16, **RIDE_VARIANTS[variant])
