@@ -899,7 +899,7 @@ class Engine:
     @property
     def expert_kernel(self) -> str | None:
         """The bodies the grouped expert calls run, by family (/health
-        ``engine.expert_kernel``: ``q4k-float+q6k-int`` on a Q4_K_M file;
+        ``engine.expert_kernel``: ``q4k-int+q6k-int`` on a Q4_K_M file;
         named where they are chosen, ops/pallas/experts.py ``FAMILIES``);
         None without such a call."""
         from ..ops.pallas.experts import FAMILIES

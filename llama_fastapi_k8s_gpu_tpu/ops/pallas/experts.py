@@ -4,14 +4,17 @@ A routed feed-forward layer (models/llama.py ``_layer``, ``cfg.n_experts``)
 sends each token to ``k`` of ``E`` SwiGLU experts.  Its weights are the
 GGUF file's 3-D ``ffn_{gate,up,down}_exps`` tensors, kept as the SAME fused
 Q4_K / Q6_K planes the dense matmuls read (ops/pallas/qmatmul.py,
-q6matmul.py) with an (L, E) pair of leading axes, and computed by dense
-kernels' bodies: a Q4_K plane by the stacked dense calls'
-(``qmatmul._q4k_matmul_kernel``), a Q6_K plane, since PR 59, by the
-vocabulary head's integer dequantization (``q6matmul._q6k_tile_product``:
-the stacked dense calls' bfloat16 plane bit for bit at half the vector
-work; the float32 sums of a K tile taken a quarter at a time, so a result
-differs from the stacked call's in its last bits and equals the unstacked
-call's).  What is new is the grid around them:
+q6matmul.py) with an (L, E) pair of leading axes, and computed by INTEGER
+dequantizations of the packed bytes that build the stacked dense calls'
+bfloat16 planes bit for bit at fewer vector operations: a Q6_K plane, since
+PR 59, by the vocabulary head's (``q6matmul._q6k_tile_product``: the float32
+sums of a K tile taken a quarter at a time, so a result differs from the
+stacked call's in its last bits and equals the unstacked call's), a Q4_K
+plane, since PR 61, by ``qmatmul._q4k_tile_product`` (the stacked body's
+three dots in their order: its results bit for bit).  Both under an N tile
+of their own (the head's rule: ``_Family.tn``), and a few-row Q4_K call
+takes all its K tiles in one grid step (:func:`_few_k_tiles`).  The grid
+around them:
 
 - the grid has an expert-slot axis beside the N and K tiles; the layer, the
   number of slots in use and each slot's expert ride a prefetched scalar
@@ -35,8 +38,8 @@ call's).  What is new is the grid around them:
   under the dequantization up to 64 rows.  It no longer does at 128 and
   192, of which a layer that holds a share of its experts
   (models/routed.py ``held_picks``) sends 9-12 to an expert here, and a
-  call whose K is several tiles fetches the whole row block again at every
-  grid step.  So a layer of more than ``ROW_GROUP`` (64) rows COMPACTS
+  call whose K is several grid steps fetches the whole row block again at
+  every one.  So a layer of more than ``ROW_GROUP`` (64) rows COMPACTS
   them (:func:`_routed_raw`): the rows that reach an expert, in their
   order (:func:`compact_rows`: a cumulative sum, one scatter, one gather of
   the activations in place of their repeat), go through the SAME three
@@ -92,7 +95,7 @@ from ...gguf.constants import GGMLType
 from ...obs.devtime import register_program
 from . import q6matmul as _q6
 from . import qmatmul as _q4
-from .qmatmul import TK, _env_variant, _interpret, _pick_tn, _tn_prefs_for
+from .qmatmul import TK, _interpret
 
 #: (token, pick) rows up to which every slot sees all rows: 16 lanes of 12
 #: picks (``longcat-flash``); no program of another served file has rows
@@ -133,44 +136,57 @@ def experts_compatible(n_out: int, k_in: int,
 
 class _Family:
     def __init__(self, name, gtype, prep, planes, widths, kernel, body, tka,
-                 tn, permute, augment, variants=None):
+                 permute, augment, few_k_tiles=False, many_vmem=None):
         self.name = name                # q4k | q6k
         self.gtype, self.prep = gtype, prep   # ggml type, the dense packer
         self.planes = planes            # plane keys, scale plane last
         self.widths = widths            # value planes' bytes per K tile
         self.kernel, self.body = kernel, body     # body: its /health name
-        self.tka, self.tn = tka, tn     # tn(N, rows, interpret): the N tile
+        self.tka = tka
         self.permute, self.augment = permute, augment
-        self.variants = variants        # (env knob, allowed); None: one body
+        # whether a few-row call's grid step holds several K tiles
+        # (:func:`_few_k_tiles`: the kernel then loops over them)
+        self.few_k_tiles = few_k_tiles
+        self.many_vmem = many_vmem      # a many-row call's limit; None: XLA's
 
-    def variant(self) -> str | None:
-        return _env_variant(*self.variants) if self.variants else None
-
-
-def _tn_q4k(N: int, rows: int, interpret: bool) -> int:
-    return _pick_tn(N, interpret,
-                    prefs=_tn_prefs_for(rows, _q4._TN_PREFS_Q4K))
-
-
-def _tn_q6k(N: int, rows: int, interpret: bool) -> int:
-    """The N tile of a grouped Q6_K call, whatever its rows: the head's
-    rule (``q6matmul.wide_tn``: 1024 at N 1024, 2048, 6144 and 7168).  A
-    grid step costs 0.3 us beside its bytes, and a many-row call fetches
-    its slot's activation block (590 KB at 128 rows) again at every (N
-    tile, slot) step, which at a tile of 256 is more bytes than the planes
-    (PERF.md section 6, PR 59: few rows -24 %, many rows -39 % from 256 to
-    1024 under the same body)."""
-    return _q6.wide_tn(N, interpret)
+    def tn(self, N: int, rows: int, interpret: bool) -> int:
+        """The N tile of a grouped call, whatever its rows: the head's rule
+        (``q6matmul.wide_tn``: 1024 at N 1024, 2048, 6144 and 7168, 768 at
+        LFM2's 1536).  A grid step costs 0.3 us beside its bytes, and a
+        many-row call fetches its slot's activation block (590 KB at 128
+        rows) again at every (N tile, slot) step, which at a tile of 256 is
+        more bytes than the planes (PERF.md section 6: PR 59, the Q6_K
+        calls from 256, few rows -24 %, many rows -39 % under one body;
+        PR 61, the Q4_K calls from 512 / 256)."""
+        return _q6.wide_tn(N, interpret)
 
 
+def _few_k_tiles(kt: int, tn: int) -> int:
+    """K tiles a grid step of a few-row call whose family takes several
+    (``few_k_tiles``): as many as divide the call's and fit the head's
+    weight block (``q6matmul.HEAD_W_BLOCK``: all four of ``gigachat``'s gate
+    at K 8192, all three of ``kexaone``'s and ``longcat``'s at 6144).  The
+    grid then takes a step an (expert, N tile) and the row block, whose
+    index no longer moves, is fetched once a call and not at every step."""
+    return max(t for t in range(1, kt + 1)
+               if kt % t == 0 and (t == 1 or tn * t * TK <= _q6.HEAD_W_BLOCK))
+
+
+# Each family's kernel is the integer dequantization of its packed bytes
+# under this module's grid; the stacked dense calls keep their float bodies
+# (and the ``LFKT_Q4K_KERNEL`` / ``LFKT_Q6K_KERNEL`` variants, which reach
+# no grouped call).  The Q4_K calls' float32 temporaries of a half plane are
+# (TN, 1024): 4 MB each at a tile of 1024, so the many-row call is given the
+# few-row call's scoped VMEM (the Q6_K calls take a quarter at a time and
+# fit XLA's own limit; their programs keep their text)
 FAMILIES = {
     "q4k": _Family("q4k", GGMLType.Q4_K, _q4.prep_q4k, ("qs", "sm"),
-                   (TK // 2,), _q4._q4k_matmul_kernel, "q4k-float", _q4.TKA,
-                   _tn_q4k, _q4.permute_x, _q4.augment_x,
-                   ("LFKT_Q4K_KERNEL", _q4.Q4K_VARIANTS)),
+                   (TK // 2,), _q4._q4k_expert_kernel, "q4k-int", _q4.TKA,
+                   _q4.permute_x, _q4.augment_x, few_k_tiles=True,
+                   many_vmem=FEW_VMEM),
     "q6k": _Family("q6k", GGMLType.Q6_K, _q6.prep_q6k, ("q4", "q2", "sm6"),
                    (TK // 2, TK // 4), _q6._q6k_expert_kernel, "q6k-int",
-                   _q6.TKA6, _tn_q6k, _q6.permute_x6, _q6.augment_x6),
+                   _q6.TKA6, _q6.permute_x6, _q6.augment_x6),
 }
 
 
@@ -358,7 +374,9 @@ class _NoLead2:
         return self._ref.shape[2:]
 
     def __getitem__(self, idx):
-        return self._ref[idx].reshape(self._ref.shape[2:])
+        if idx is Ellipsis:
+            return self._ref[idx].reshape(self._ref.shape[2:])
+        return self._ref[(0, 0) + (idx if isinstance(idx, tuple) else (idx,))]
 
 
 def expert_kernel_name(family: str, few: bool) -> str:
@@ -368,45 +386,46 @@ def expert_kernel_name(family: str, few: bool) -> str:
 
 
 def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
-                  extra_in: tuple, interpret: bool, variant: str):
+                  extra_in: tuple, interpret: bool):
     """The pallas_call both regimes share.  ``meta`` = [layer, slots in
     use, expert of slot 0..T-1]; the slot axis of the grid runs to
     :func:`slot_extent` of ``meta[1]``, not to T.  ``few``: grid (slot, N
-    tile, K tile), the activation block all the rows, the output ONE
+    tile, K step), the activation block all the rows, the output ONE
     resident block of all N that every slot adds into, and ``extra_in`` =
-    the rows' experts (rows, 1).  Else grid (N tile, slot, K tile), and the
-    activation and output blocks are slot ``t``'s ``rows`` rows."""
+    the rows' experts (rows, 1).  Else grid (N tile, slot, K step), and the
+    activation and output blocks are slot ``t``'s ``rows`` rows.  A K step
+    is one K tile, or :func:`_few_k_tiles` of them."""
     from jax.experimental.pallas import tpu as pltpu
 
     kt = xpa.shape[1] // fam.tka
     N = planes[0].shape[2]
     TN = fam.tn(N, rows, interpret)
+    tiles = _few_k_tiles(kt, TN) if few and fam.few_k_tiles else 1
     slots = slot_extent(meta[1])
     if few:
-        grid = (slots, N // TN, kt)
+        grid = (slots, N // TN, kt // tiles)
         out_spec = pl.BlockSpec((rows, N), lambda t, n, k, m: (0, 0))
-        kw = {"compiler_params": pltpu.CompilerParams(
-            vmem_limit_bytes=FEW_VMEM)}
+        vmem = FEW_VMEM
     else:
         grid = (N // TN, slots, kt)
         out_spec = pl.BlockSpec((rows, TN), lambda n, t, k, m: (t, n))
-        kw = {}
+        vmem = fam.many_vmem
+    kw = {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=vmem)} if vmem else {}
 
     def ax(f):               # the index maps below are written in (n, t, k, m)
         return (lambda t, n, k, m: f(n, t, k, m)) if few else f
 
-    specs = [pl.BlockSpec((rows, fam.tka),
+    specs = [pl.BlockSpec((rows, tiles * fam.tka),
                           ax(lambda n, t, k, m: (0 if few else t, k)))]
     specs += [pl.BlockSpec((rows, 1), ax(lambda n, t, k, m: (0, 0)))
               for _ in extra_in]
-    specs += [pl.BlockSpec((1, 1, TN, w),
+    specs += [pl.BlockSpec((1, 1, TN, tiles * w),
                            ax(lambda n, t, k, m: (m[0], m[2 + t], n, k)))
               for w in fam.widths]
-    specs.append(pl.BlockSpec((1, 1, 1, TN, 128),
+    specs.append(pl.BlockSpec((1, 1, tiles, TN, 128),
                               ax(lambda n, t, k, m: (m[0], m[2 + t], k, n,
                                                      0))))
-
-    by_variant = {"variant": variant} if fam.variants else {}
 
     def body(meta_ref, x_ref, *rest):
         t, n, k = (pl.program_id(i) for i in ((0, 1, 2) if few
@@ -432,7 +451,7 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
         @pl.when(t < meta_ref[1])       # false only where no slot is in use
         def _():
             fam.kernel(x_ref, *plane_refs, o_ref, interpret=interpret,
-                       accum=accum, **by_variant)
+                       accum=accum)
 
     return pl.pallas_call(
         body,
@@ -445,7 +464,7 @@ def _grouped_call(fam: _Family, meta, xpa, planes, rows: int, few: bool,
 
 
 def grouped_matmul_few(fam: _Family, meta, x, row_expert, planes, f: int,
-                       interpret: bool, variant: str) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """x (R, K) rows against ``planes[meta[0], row_expert[r]]`` -> (R, N)
     f32, zero for a row without an expert; R <= FEW_ROWS."""
     R = x.shape[0]
@@ -455,19 +474,18 @@ def grouped_matmul_few(fam: _Family, meta, x, row_expert, planes, f: int,
     re = jnp.pad(jnp.tile(row_expert, f), (0, pad),
                  constant_values=jnp.iinfo(jnp.int32).max)
     out = _grouped_call(fam, meta, _activations(xf, fam), planes,
-                        xf.shape[0], True, (re[:, None],), interpret,
-                        variant)
+                        xf.shape[0], True, (re[:, None],), interpret)
     return _unfold_rows(out[:R * f], f, R)
 
 
 def grouped_matmul_many(fam: _Family, meta, xp, planes, f: int,
-                        interpret: bool, variant: str) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """xp (T*TM_MANY, K), rows in the padded layout of :func:`plan_groups`,
     against ``planes[meta[0], tile's expert]`` -> (T*TM_MANY, N) f32 (the
     tiles past the last in use are not written: no row's ``pos`` is there)."""
     out = _grouped_call(fam, meta, _activations(
         _fold_rows(xp, f, TM_MANY), fam), planes, TM_MANY * f, False, (),
-        interpret, variant)
+        interpret)
     return _unfold_rows(out, f, TM_MANY)
 
 
@@ -475,8 +493,8 @@ def grouped_matmul_many(fam: _Family, meta, xp, planes, f: int,
 # the layer after the router
 # ---------------------------------------------------------------------------
 
-def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
-                picks, weights, *planes):
+def _routed_raw(fams: tuple, interpret: bool, idx, x, picks, weights,
+                *planes):
     """x (M, D), picks (M, k) int32 in [0, E] (E: none), weights (M, k) f32
     -> (y (M, D) in x.dtype, rows per expert (E,) int32)."""
     gate, up, down = (FAMILIES[f] for f in fams)
@@ -492,10 +510,10 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
 
     def products(call, xr):
         """Rows -> their picked experts' SwiGLU, ``call`` a grouped matmul."""
-        g = call(gate, variants[0], xr, pg)
-        u = call(up, variants[1], xr, pu)
+        g = call(gate, xr, pg)
+        u = call(up, xr, pu)
         h = (jax.nn.silu(g) * u).astype(x.dtype)
-        return call(down, variants[2], h, pd)
+        return call(down, h, pd)
 
     def back(out, pos):         # from the places of a layout to the rows
         P = out.shape[0]
@@ -508,9 +526,9 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
 
         def few_rows(xr, row_expert):
             return products(
-                lambda fam, variant, rows, w: grouped_matmul_few(
+                lambda fam, rows, w: grouped_matmul_few(
                     fam, meta, rows, row_expert, w,
-                    fold_factor(rows.shape[1]), interpret, variant), xr)
+                    fold_factor(rows.shape[1]), interpret), xr)
 
         def as_it_was():
             return few_rows(jnp.repeat(x, k, axis=0), row_expert)
@@ -542,22 +560,21 @@ def _routed_raw(fams: tuple, interpret: bool, variants: tuple, idx, x,
         xr = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[token]
         meta = jnp.concatenate([layer, n_used[None], experts])
         out = back(products(
-            lambda fam, variant, rows, w: grouped_matmul_many(
-                fam, meta, rows, w, fold_factor(rows.shape[1]), interpret,
-                variant), xr), plan["pos"])
+            lambda fam, rows, w: grouped_matmul_many(
+                fam, meta, rows, w, fold_factor(rows.shape[1]), interpret),
+            xr), plan["pos"])
     y = jnp.sum(out.reshape(M, k, D) * weights[:, :, None], axis=1)
     return y.astype(x.dtype), count
 
 
 @functools.lru_cache(maxsize=8)
-def _routed_fn(fams: tuple | None, interpret: bool = False,
-               variants: tuple = ()):
+def _routed_fn(fams: tuple | None, interpret: bool = False):
     """The jitted layer with its vmap rule: lanes become rows, of the
     grouped kernels (``fams``: the three matrices' families) or of the
     dequantized fallback (None)."""
     from jax.custom_batching import custom_vmap
 
-    raw = functools.partial(_routed_raw, fams, interpret, variants) \
+    raw = functools.partial(_routed_raw, fams, interpret) \
         if fams else _routed_dense
 
     @custom_vmap
@@ -593,8 +610,7 @@ def routed_experts(x: jax.Array, picks: jax.Array, weights: jax.Array,
     if None in fams:
         fn, planes = _routed_fn(None), [w["w"] for w in (w_gate, w_up, w_down)]
     else:
-        fn = _routed_fn(fams, _interpret(interpret),
-                        tuple(FAMILIES[f].variant() for f in fams))
+        fn = _routed_fn(fams, _interpret(interpret))
         planes = [w[key] for w, f in zip((w_gate, w_up, w_down), fams)
                   for key in FAMILIES[f].planes]
     return fn(jnp.asarray(idx, jnp.int32), x, picks, weights, *planes)

@@ -65,6 +65,7 @@ from .qmatmul import (
     _env_variant,
     _interpret,
     _lane_repeat,
+    _NIB,
     _pick_tn,
     kernel_name,
     MANYROW_MAX,
@@ -434,7 +435,6 @@ HEAD_VMEM = 64 * 2 ** 20
 #: unstacked call runs changes this name with it
 HEAD_KERNEL = "q6k-head"
 
-_NIB = 0x0F0F0F0F                # a nibble of each of a word's four bytes
 _CRUMB = 0x30303030              # a crumb, where ``q6 = nib | crumb << 4`` has it
 
 

@@ -59,6 +59,17 @@ with the per-sub-block sums by :func:`augment_x`.
 Shape requirements: ``K % 2048 == 0`` and ``N % 128 == 0`` (all Llama-3 /
 Mistral linear shapes qualify; loaders fall back to the int8 format
 otherwise — see models/params.py).
+
+Two bodies read these planes.  :func:`_q4k_matmul_kernel`, the float split
+above with its ``LFKT_Q4K_KERNEL`` variants, is the body of the dense calls
+(stacked and unstacked).  :func:`_q4k_expert_kernel` /
+:func:`_q4k_tile_product`, since PR 61 the body of the grouped expert calls
+(ops/pallas/experts.py), takes the same bytes apart as 32-bit INTEGERS of
+four weight rows (the widening that point 1 priced at 4x the registers is a
+bitcast there, and Mosaic's 32-bit bit operations are cheap): the same two
+bfloat16 planes bit for bit, the same three dots, at about 7.5 vector
+operations a packed byte for 9-10 and no ``floor``.  The planes in HBM and
+the packers are one and the same.
 """
 
 from __future__ import annotations
@@ -326,6 +337,71 @@ def _q4k_accum(o_ref, part):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += part
+
+
+_NIB = 0x0F0F0F0F                # a nibble of each of a word's four bytes
+
+
+def _q4k_int_planes(qs, sc_exp):
+    """The two bfloat16 planes of a K tile, (TN, TK/2) each (columns [0,
+    TK/2) and [TK/2, TK)): :func:`_q4k_matmul_kernel`'s ``a_lo`` / ``a_hi``
+    bit for bit (``cur``; ``resplit`` too, up to the sign of a zero under a
+    negative scale), built from INTEGER operations on the packed bytes, four
+    weight rows a 32-bit word.  The stored byte is ``16 (hi - 8) + lo`` in
+    two's complement, so its low nibble is ``lo`` and its high one ``(hi -
+    8) mod 16``, made the int8 ``hi - 8`` without a borrow between the
+    bytes (``q6matmul._q6k_tile_product``'s ``signed``); a weight then pays
+    one conversion, one multiply by ``sc_exp`` (exact: 4 bits by a
+    bfloat16) and the bfloat16 cast.  No ``floor``, and 7.5 vector
+    operations a packed byte where the float bodies take 9-10."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = pltpu.bitcast(qs, jnp.int32)              # (TN/4, TK/2)
+    lo = w & _NIB
+    u = (w >> 4) & _NIB                           # (hi - 8) mod 16
+    # u ^ 8 = hi, then - 8: + 120 cannot carry out of a byte, and turning
+    # the top bit takes the 128 off again
+    hi = ((u ^ 0x08080808) + 0x78787878) ^ -0x7F7F7F80
+    return tuple((pltpu.bitcast(q, jnp.int8).astype(jnp.float32) * sc_exp
+                  ).astype(jnp.bfloat16) for q in (lo, hi))
+
+
+def _q4k_tile_product(qs, sm, xpa, interpret):
+    """One K tile of a Q4_K product with the planes of
+    :func:`_q4k_int_planes`: ``qs`` (TN, TK/2) int8, ``sm`` (TN, 128), ``xpa``
+    (B, TKA) -> (B, TN) float32.  The ``[-mn | 8 sc]`` correction columns and
+    the THREE dots in their order (low half, high half, correction) are
+    :func:`_q4k_matmul_kernel`'s, so with equal planes the float32 product
+    equals that body's bit for bit."""
+    sc, mn = sm[:, :_SUBS], sm[:, _SUBS:]
+    sc_exp = _lane_repeat(jnp.concatenate([sc, sc], axis=1), TK // 256,
+                          interpret)
+    corr = jnp.concatenate([-mn, sc * 8.0], axis=1).astype(jnp.bfloat16)
+    a_lo, a_hi = _q4k_int_planes(qs, sc_exp)
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    part = dot(xpa[:, : TK // 2], a_lo)
+    part += dot(xpa[:, TK // 2: TK], a_hi)
+    part += dot(xpa[:, TK:], corr)
+    return part
+
+
+def _q4k_expert_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret, accum):
+    """The grouped expert calls' body (ops/pallas/experts.py):
+    :func:`_q4k_tile_product` over the K tiles of a grid step, their float32
+    products summed in the tiles' order (``p0 + p1 + ...``: what the grid's
+    ``accum`` makes of them a step at a time) and folded into the output
+    block by ``accum``.  xpa (rows, tiles * TKA); qs (TN, tiles * TK/2)
+    int8; sm (tiles, TN, 128)."""
+    H = TK // 2
+    part = None
+    for j in range(sm_ref.shape[0]):
+        p = _q4k_tile_product(
+            qs_ref[:, j * H:(j + 1) * H], sm_ref[j],
+            xpa_ref[:, j * TKA:(j + 1) * TKA], interpret)
+        part = p if part is None else part + p
+    accum(o_ref, part)
 
 
 def _pick_tn(n: int, interpret: bool, prefs: tuple = (512, 256, 128)) -> int:

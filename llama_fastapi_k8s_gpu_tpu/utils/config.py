@@ -514,8 +514,8 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_FLASH_KV_UNROLL", int,
          "flash-attention fused KV sub-blocks per grid step "
          "(ops/pallas/attention.py)", default=4),
-    Knob("LFKT_Q4K_KERNEL", str, "fused Q4_K kernel variant (A/B)",
-         default=""),
+    Knob("LFKT_Q4K_KERNEL", str,
+         "fused Q4_K variant of the dense calls (A/B)", default=""),
     Knob("LFKT_Q5K_KERNEL", str, "fused Q5_K kernel variant (A/B)",
          default=""),
     Knob("LFKT_Q6K_KERNEL", str,

@@ -42,6 +42,7 @@ Tolerances, with their reasons:
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -420,7 +421,7 @@ def test_a_few_row_call_is_its_rows_through_the_dense_kernel_bit_for_bit(
     meta = jnp.concatenate([jnp.asarray([layer], jnp.int32), n_used[None],
                             slots])
     got = np.asarray(X.grouped_matmul_few(
-        fam, meta, x, jnp.asarray(row_expert), planes, 1, True, "cur"))
+        fam, meta, x, jnp.asarray(row_expert), planes, 1, True))
     xpa = X._activations(jnp.pad(x, ((0, 16 - R), (0, 0))), fam)
     dense = _dense_call(X, fam)
     for r, e in enumerate(row_expert):
@@ -492,7 +493,7 @@ def test_a_compacted_call_is_the_call_of_all_rows_and_the_dense_kernel(
     meta = jnp.concatenate([jnp.asarray([layer], jnp.int32), n_used[None],
                             slots])
     want = np.asarray(X.grouped_matmul_few(
-        fam, meta, x, jnp.asarray(row_expert), planes, f, True, "cur"))
+        fam, meta, x, jnp.asarray(row_expert), planes, f, True))
     place, src, n = X.compact_rows(jnp.asarray(row_expert), E, G)
     assert int(n) == n_real
     np.testing.assert_array_equal(np.asarray(src)[:n_real],
@@ -500,7 +501,7 @@ def test_a_compacted_call_is_the_call_of_all_rows_and_the_dense_kernel(
     assert (np.asarray(src)[n_real:] == R).all()
     got = np.asarray(X.grouped_matmul_few(
         fam, meta, jnp.concatenate([x, jnp.zeros((1, K), x.dtype)])[src],
-        jnp.asarray(np.append(row_expert, E))[src], planes, f, True, "cur"))
+        jnp.asarray(np.append(row_expert, E))[src], planes, f, True))
     assert got.shape[0] == G and not got[n_real:].any()
     back = np.concatenate([got, np.zeros((1, got.shape[1]), got.dtype)])[
         np.asarray(place)]
@@ -570,13 +571,116 @@ def test_grouped_q6k_calls_read_the_stacked_bodys_plane_bit_for_bit(f, N):
         for swap in range(E):
             row_expert = (np.arange(G) + swap) % E
             same(X.grouped_matmul_few(fam, meta, rows, jnp.asarray(
-                row_expert, jnp.int32), planes, f, True, None),
+                row_expert, jnp.int32), planes, f, True),
                 np.stack(by_expert)[row_expert, np.arange(G)])
     # many rows: four tiles, both halves through expert 0, then through 1
     tiles = jnp.asarray([0, 4, 0, 0, 1, 1], jnp.int32)
     same(X.grouped_matmul_many(fam, tiles, jnp.concatenate(halves * E),
-                               planes, f, True, None),
+                               planes, f, True),
          np.concatenate([want[h][e] for e in range(E) for h in range(2)]))
+
+
+def _parents_q4k_family(X, variant):
+    """The ``q4k`` family as the grouped calls had it up to PR 60: the
+    stacked dense calls' float body (``qmatmul._q4k_matmul_kernel`` under
+    ``variant``), N tiles of 512 / 256 / 128 by the rows, a K tile a step."""
+    import copy
+
+    fam = copy.copy(X.FAMILIES["q4k"])
+    fam.kernel = functools.partial(X._q4._q4k_matmul_kernel, variant=variant)
+    fam.tn = lambda N, rows, interpret: X._q4._pick_tn(
+        N, interpret, prefs=X._q4._tn_prefs_for(rows, X._q4._TN_PREFS_Q4K))
+    fam.few_k_tiles, fam.many_vmem = False, None
+    return fam
+
+
+# the five served gate / up shapes cut down in N (the N tile's rule still
+# sees 1536 = 2 x 768 as 384 = 1 x 384), and a down projection's: (name, N,
+# K of the file, K tiles a few-row step)
+Q4K_SHAPES = [("lfm2", 384, 2048, 1), ("olmoe", 256, 2048, 1),
+              ("gigachat-filled", 256, 7168, 4), ("kexaone", 256, 6144, 3),
+              ("longcat", 384, 6144, 3), ("olmoe-down-folded", 256, 1024, 1)]
+
+
+@pytest.mark.parametrize("variant", ["resplit", "cur"])
+@pytest.mark.parametrize("name,N,K,tiles", Q4K_SHAPES,
+                         ids=[s[0] for s in Q4K_SHAPES])
+def test_grouped_q4k_calls_equal_the_float_bodys_bit_for_bit(name, N, K,
+                                                             tiles, variant):
+    """The grouped Q4_K calls' RESULTS (the integer body, the head's N tile
+    and, few rows, all the K tiles that fit a grid step, since PR 61)
+    against the same calls as PR 60 built them (:func:`_parents_q4k_family`),
+    bit for bit: equal planes (tests/test_qmatmul.py), the same three dots
+    a K tile, the tiles' products summed in the grid's order.  Few rows: 3
+    experts held and 2 in use (a slot count under the extent), a row without
+    an expert; many rows: the plan's tiles, a token without a pick."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    E, R = 3, 24
+    rng = np.random.default_rng(N + K)
+    w, _ = _expert_weights(E, N, K, "Q4_K", rng, L=1)
+    new, old = X.FAMILIES["q4k"], _parents_q4k_family(X, variant)
+    planes = [w[key] for key in new.planes]
+    f, kt = X.fold_factor(K), X.padded_k(K) * X.fold_factor(K) // X.TK
+    assert planes[0].shape == (1, E, N // f, kt * X.TK // 2)
+    assert X._few_k_tiles(kt, new.tn(N // f, R * f, True)) == tiles == kt
+
+    def same(got, want):
+        assert np.array_equal(np.asarray(got).view(np.uint32),
+                              np.asarray(want).view(np.uint32))
+        assert np.abs(np.asarray(want)).sum() > 0
+
+    x = jnp.asarray(rng.standard_normal((R, K)), jnp.bfloat16)
+    row_expert = np.asarray([0, 2])[rng.integers(0, 2, R)].astype(np.int32)
+    row_expert[5] = E
+    _, slots, n_used = X.experts_in_use(row_expert, E, X.decode_slots(E, R, 1))
+    assert int(n_used) == 2 < slots.shape[0]
+    meta = jnp.concatenate([jnp.zeros(1, jnp.int32), n_used[None], slots])
+    got = X.grouped_matmul_few(new, meta, x, jnp.asarray(row_expert), planes,
+                               f, True)
+    same(got, X.grouped_matmul_few(old, meta, x, jnp.asarray(row_expert),
+                                   planes, f, True))
+    assert not np.asarray(got[5]).any()
+    M, k = 100, 2                               # 200 rows: the many-row plan
+    picks = _picks(rng, M, k, E, None).reshape(-1)
+    plan = X.plan_groups(jnp.asarray(picks), E, M, X.TM_MANY)
+    meta = jnp.concatenate([jnp.zeros(1, jnp.int32), plan["n_used"][None],
+                            plan["tile_expert"]])
+    xr = jnp.asarray(rng.standard_normal((M * k + 1, K)), jnp.bfloat16
+                     ).at[M * k].set(0)[plan["src"]]
+    live = int(plan["n_used"]) * X.TM_MANY      # the tiles past are not written
+    same(X.grouped_matmul_many(new, meta, xr, planes, f, True)[:live],
+         X.grouped_matmul_many(old, meta, xr, planes, f, True)[:live])
+
+
+@pytest.mark.parametrize("N,tn", [(1024, 1024), (1536, 768), (2048, 1024),
+                                  (6144, 1024), (7168, 1024), (1280, 640),
+                                  (200, 8)])      # 200: interpret mode's own
+@pytest.mark.parametrize("family", ["q4k", "q6k"])
+def test_a_grouped_calls_n_tile_is_the_heads_whatever_its_rows(family, N, tn):
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    fam = X.FAMILIES[family]
+    assert {fam.tn(N, rows, True) for rows in (16, 64, 128, 192, 256)} == {tn}
+    if N % 128 == 0:
+        assert fam.tn(N, 64, False) == tn
+
+
+@pytest.mark.parametrize("kt,tn,tiles", [
+    (1, 1024, 1), (3, 1024, 3), (4, 1024, 4),     # 2048, 6144, 8192: all of K
+    (4, 768, 4), (8, 1024, 4), (5, 1024, 1), (6, 1024, 3), (8, 256, 8)])
+def test_a_few_row_q4k_step_holds_the_k_tiles_that_fit(kt, tn, tiles):
+    """:func:`_few_k_tiles`: the most K tiles that divide the call's and
+    fit the head's weight block; the Q4_K family's few-row calls alone (a
+    many-row call and every Q6_K call take a K tile a step)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
+
+    assert X._few_k_tiles(kt, tn) == tiles
+    assert X.FAMILIES["q4k"].few_k_tiles and not X.FAMILIES["q6k"].few_k_tiles
+    assert X.FAMILIES["q4k"].many_vmem == X.FEW_VMEM
+    assert X.FAMILIES["q6k"].many_vmem is None
 
 
 def _as_it_was_built(monkeypatch, run):
@@ -690,8 +794,7 @@ def _few_row_calls(M, k):
     down = [S((1, E, D // 2, F), i8), S((1, E, D // 2, F // 2), i8),
             S((1, E, 1, D // 2, 128), bf16)]
     jaxpr = jax.make_jaxpr(
-        lambda *a: X._routed_raw(("q4k", "q4k", "q6k"), True,
-                                 ("resplit", "resplit", None), *a))(
+        lambda *a: X._routed_raw(("q4k", "q4k", "q6k"), True, *a))(
         S((), jnp.int32), S((M, D), bf16), S((M, k), jnp.int32),
         S((M, k), jnp.float32), *gate, *gate, *down)
 
@@ -1176,8 +1279,8 @@ async def test_health_names_the_bodies_of_the_grouped_calls(
         await app.router.shutdown()
     if routed:
         assert [X.FAMILIES[f].body for f in ("q4k", "q6k")] == [
-            "q4k-float", "q6k-int"]
-        assert eng.expert_kernel == "q4k-float+q6k-int"
+            "q4k-int", "q6k-int"]
+        assert eng.expert_kernel == "q4k-int+q6k-int"
         assert info["expert_kernel"] == eng.expert_kernel
         keys = list(info)
         assert keys.index("expert_kernel") == keys.index("expert_slots") + 1

@@ -271,3 +271,170 @@ def test_vbf32_variant_beats_default_accuracy(monkeypatch):
     assert err_vb <= err_cur * 1.05, (err_vb, err_cur)
     np.testing.assert_allclose(got, ref, rtol=2e-2,
                                atol=2e-2 * float(np.abs(ref).max()))
+
+
+# ---------------------------------------------------------------------------
+# the grouped expert calls' integer body (PR 61): the float bodies' planes
+# ---------------------------------------------------------------------------
+
+#: effective scales (d·sc as bfloat16) the planes are compared under: zero,
+#: the smallest subnormal, one that makes subnormal products, ordinary ones,
+#: 2**120 (the largest whose 128-fold is finite in float32, up to which
+#: ``resplit``'s cancellation is exact) and the largest bfloat16 (whose
+#: products overflow to an infinity in ``cur`` and in the integer body alike,
+#: and to a NaN in ``resplit``); a negative one, which no file holds
+_SCALES = {"zero": 0.0, "subnormal": 9.1835e-41, "tiny": 2.0 ** -130,
+           "small": 0.0123, "one": 1.0, "odd": 0.037109375,
+           "resplit_max": 2.0 ** 120, "largest": 3.3895e38, "negative": -0.0123}
+
+
+class _Block:
+    """What a kernel body reads of a ref (``.shape``, ``[...]``), over an
+    array: the body then runs op by op outside a ``pallas_call``."""
+
+    def __init__(self, a):
+        self._a = a
+        self.shape = a.shape
+
+    def __getitem__(self, idx):
+        return self._a[idx]
+
+
+def _every_byte_under_every_scale():
+    """(qs (16, 1024) int8, sm (1, 16, 128) bfloat16, the scale's name by
+    packed column): row ``n``, byte column ``e * 64 + s`` holds the byte
+    ``16 n + e - 128`` under sub-block ``s``'s scale, the ``s % 9``-th of
+    :data:`_SCALES`, so every byte value meets every scale in both halves."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import qmatmul as qm
+
+    n, b = np.arange(16)[:, None], np.arange(qm.TK // 2)[None, :]
+    qs = (16 * n + b // 64 - 128).astype(np.int8)
+    names = [list(_SCALES)[s % len(_SCALES)] for s in range(64)]
+    sc = np.asarray([_SCALES[name] for name in names], np.float32)
+    mn = np.random.default_rng(0).random(64).astype(np.float32)
+    sm = jnp.asarray(np.tile(np.concatenate([sc, mn]), (1, 16, 1)),
+                     jnp.bfloat16)
+    assert sorted(set(qs.reshape(-1).tolist())) == list(range(-128, 128))
+    return jnp.asarray(qs), sm, np.asarray(names)[np.arange(1024) % 64]
+
+
+def _float_body_planes(qs, sm, variant, monkeypatch):
+    """``_q4k_matmul_kernel``'s ``a_lo`` / ``a_hi`` under ``variant``: the
+    weight operands of its first two dots, the body run op by op."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import qmatmul as qm
+
+    seen, dot = [], jax.lax.dot_general
+    with monkeypatch.context() as m:
+        m.setattr(jax.lax, "dot_general",
+                  lambda a, b, *r, **kw: (seen.append(b), dot(a, b, *r, **kw)
+                                          )[1])
+        qm._q4k_matmul_kernel(
+            _Block(jnp.zeros((16, qm.TKA), jnp.bfloat16)), _Block(qs),
+            _Block(sm), None, interpret=True, variant=variant,
+            accum=lambda o_ref, part: None)
+    assert len(seen) == 3 and seen[2].shape == (16, 128)
+    return [np.asarray(a) for a in seen[:2]]
+
+
+def _int_body_planes(qs, sm):
+    """``_q4k_int_planes`` in a ``pallas_call`` of its own (the bitcasts are
+    Mosaic's), under the scales as ``_q4k_tile_product`` spreads them."""
+    from jax.experimental import pallas as pl
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import qmatmul as qm
+
+    def kernel(qs_ref, sm_ref, lo_ref, hi_ref):
+        sc = sm_ref[0][:, :64]
+        lo_ref[...], hi_ref[...] = qm._q4k_int_planes(
+            qs_ref[...], qm._lane_repeat(jnp.concatenate([sc, sc], axis=1),
+                                         qm.TK // 256, True))
+
+    plane = jax.ShapeDtypeStruct(qs.shape, jnp.bfloat16)
+    return [np.asarray(a) for a in pl.pallas_call(
+        kernel, out_shape=[plane, plane], interpret=True)(qs, sm)]
+
+
+@pytest.mark.parametrize("variant", ["cur", "resplit"])
+@pytest.mark.parametrize("half", ["lo", "hi"])
+@pytest.mark.parametrize("scale", list(_SCALES))
+def test_the_integer_bodys_planes_are_the_float_bodys_bit_for_bit(
+        monkeypatch, variant, half, scale):
+    """Every byte value -128..127 under every scale of :data:`_SCALES`: the
+    bfloat16 plane ``_q4k_int_planes`` builds from integer operations on the
+    packed bytes is ``_q4k_matmul_kernel``'s, BIT for bit (``cur`` at every
+    scale; ``resplit`` wherever its own cancellation is exact: not past
+    2**120, where it makes a NaN of an overflow, and up to the sign of a
+    zero under a negative scale, which no file holds)."""
+    qs, sm, names = _every_byte_under_every_scale()
+    i = ["lo", "hi"].index(half)
+    want = _float_body_planes(qs, sm, variant, monkeypatch)[i][:, names == scale]
+    got = _int_body_planes(qs, sm)[i][:, names == scale]
+    assert got.shape == (16, 16 * list(names[:64]).count(scale))
+    digits = np.asarray(qs)[:, names == scale].astype(np.int32)
+    digits = digits & 15 if half == "lo" else digits >> 4
+    if scale not in ("subnormal", "tiny"):  # (a backend may flush those)
+        with np.errstate(over="ignore"):
+            exact = np.float32(jnp.bfloat16(_SCALES[scale])) * digits.astype(
+                np.float32)
+            assert np.array_equal(got.astype(np.float32), np.asarray(
+                jnp.asarray(exact, jnp.bfloat16), np.float32))
+    if variant == "resplit" and scale == "largest" and half == "lo":
+        assert np.isnan(want.astype(np.float32)).any()  # inf - inf
+        return
+    if variant == "resplit" and scale == "negative":
+        assert np.array_equal(got, want)                # -0.0 == 0.0
+        return
+    assert np.array_equal(got.view(np.uint16), want.view(np.uint16))
+
+
+# jax.make_jaxpr's text (kernel bodies in full), hashed on the parent
+# (4165a65, PR 60) with tools/traced_program_hashes.py: the dense Q4_K calls
+# (stacked and plain: a decode row, a lane step's rows, a slice, interpret
+# mode), which keep ``_q4k_matmul_kernel``, and the grouped Q6_K calls, which
+# share ``experts._grouped_call`` with the Q4_K ones and keep their text ...
+PARENT_KEPT = {
+    "stacked.q4k.4096x4096.r1.tpu": "757be23bc0c08802",
+    "stacked.q4k.4096x14336.r8.tpu": "e6d8d73c15097442",
+    "stacked.q4k.14336x4096.r512.tpu": "388f9ab71a8970ce",
+    "dense.q4k.4096x1024.r1.tpu": "14c905113773f740",
+    "stacked.q4k.4096x4096.r128.interp": "bcfef6776fda52df",
+    "grouped.q6k.lfm2.few": "fcaee95a3fa2677d",
+    "grouped.q6k.gigachat.few": "cfffd7c027efa916",
+    "grouped.q6k.olmoe.many": "7cf0bfd85f58cdb2",
+    "grouped.q6k.longcat.many": "fb783bd9fec9941f",
+}
+# ... and the grouped Q4_K calls, which held that float body and hold the
+# integer one under a tile of their own since PR 61
+PARENT_MOVED = {
+    "grouped.q4k.lfm2.few": "ac531b3e69997cfa",
+    "grouped.q4k.olmoe.few": "e258b6ed94a83a3e",
+    "grouped.q4k.gigachat.few": "b95faa0781ed9d3c",
+    "grouped.q4k.gigachat.many": "eaf88a777236eb0c",
+    "grouped.q4k.kexaone.many": "4eb31323dbcaba60",
+}
+
+
+@pytest.fixture(scope="module")
+def traced_hashes():
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "traced_program_hashes", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "traced_program_hashes.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool.hashes(only={**PARENT_KEPT, **PARENT_MOVED}.__contains__)
+
+
+@pytest.mark.parametrize("key", list(PARENT_KEPT))
+def test_dense_q4k_and_grouped_q6k_calls_trace_to_the_text_they_had(
+        traced_hashes, key):
+    assert traced_hashes[key] == PARENT_KEPT[key]
+
+
+@pytest.mark.parametrize("key", list(PARENT_MOVED))
+def test_grouped_q4k_calls_no_longer_trace_to_the_float_body(traced_hashes,
+                                                             key):
+    assert traced_hashes[key] != PARENT_MOVED[key]

@@ -4,15 +4,23 @@ number of slots in use and, for a call of more than ``ROW_GROUP`` rows, the
 rows that reach an expert: the parent commit's call (``--parent``: its
 ``experts.py``, loaded beside this tree's under another name, with ITS
 families: the body and the N tile it hands the call) and this tree's side
-by side, the same planes, rows and slots.  A Q4_K call's results are
-compared bit for bit (``same_bits``).  A Q6_K call's are not equal since PR
-59: the parent runs the stacked dense calls' float body, this tree the
-head's integer one, which builds the same bfloat16 plane (tier-1 holds it
-bit for bit, tests/test_olmoe.py) and takes a K tile's float32 sums a
+by side, the same planes, rows and slots.  A call's results are compared
+bit for bit with the first side's (``same_bits``).  A Q4_K call's are
+expected EQUAL to a parent's that runs the stacked dense calls' float body
+(PR 60 and before): the integer body of PR 61 builds that body's two
+bfloat16 planes bit for bit (tier-1, tests/test_qmatmul.py) and makes the
+same three dots in the same order, and the sums over a step's K tiles are
+taken in the tiles' order, which is the order the grid took them in.  A
+Q6_K call's against a parent before PR 59 are not equal: the head's integer
+body builds the float body's plane and takes a K tile's float32 sums a
 quarter at a time; ``max_rel`` is the largest difference over the largest
-result.  ``--q6k-tn 256,1024`` times this tree's Q6_K body under those N
-tiles beside its own rule (the body at the old tile, the tile at the new
-body).  Without the parent's file it times this tree's alone.  ``--layer``
+result.  ``--q6k-tn 256,1024`` / ``--q4k-tn 512,1024`` time this tree's
+Q6_K / Q4_K body under those N tiles, a K tile a grid step, beside its own
+rule (``new.tn512``: the body at the old tile), and ``--q4k-tn`` also the
+PARENT's Q4_K body under them (``parent.tn1024``: the tile at the old
+body).  The ``@8`` / ``@16`` / ``@32`` shapes are ``lfm2``'s gate call at
+fewer rows than any served step has: what the rows cost.  Without the
+parent's file it times this tree's alone.  ``--layer``
 times the whole layer after the router instead (``routed_experts``: the
 compaction, the choice between the two calls, the three products, the
 gather back and the weighted sum), which is what a decode step pays.
@@ -33,6 +41,7 @@ from __future__ import annotations
 import argparse
 import copy
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -44,6 +53,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # step's gate / up call (Q4_K) and down call (Q6_K) at 16 lanes (8: olmoe)
 FEW = (
     ("lfm2.gate", "q4k", 64, 64, 1536, 2048),
+    ("lfm2.gate@8", "q4k", 8, 64, 1536, 2048),      # a call of 16 rows
+    ("lfm2.gate@16", "q4k", 16, 64, 1536, 2048),
+    ("lfm2.gate@32", "q4k", 32, 64, 1536, 2048),
     ("lfm2.down", "q6k", 64, 64, 2048, 1536),       # K held at 2048
     ("olmoe.gate", "q4k", 64, 64, 1024, 2048),
     ("olmoe.down", "q6k", 64, 64, 2048, 1024),      # folded: 128 rows of 2048
@@ -89,6 +101,10 @@ def main() -> int:
     ap.add_argument("--q6k-tn", default="",
                     help="N tiles to time this tree's Q6_K body under, "
                     "beside its own rule")
+    ap.add_argument("--q4k-tn", default="",
+                    help="N tiles to time the Q4_K bodies under (this "
+                    "tree's, a K tile a step, and the parent's), beside "
+                    "their own rules")
     ap.add_argument("--no-many", action="store_true",
                     help="skip the wide-slice shapes")
     ap.add_argument("--no-few", action="store_true",
@@ -194,41 +210,53 @@ def main() -> int:
         return 1e6 * (ts[1] - ts[0]) / (2 * args.calls), \
             np.asarray(jax.jit(call)(*a))
 
-    def families(famname, N, rows):
+    def k_steps(fam, tn, K, few):
+        """Grid steps along K of a call of ``fam`` under the N tile ``tn``."""
+        kt = K // X.TK
+        return kt // X._few_k_tiles(kt, tn) if few and getattr(
+            fam, "few_k_tiles", False) else kt
+
+    def families(famname, N, K, rows, few):
         """(side, module, family, N tile) of each side to time: the parent
-        under its own families, this tree, and this tree's Q6_K body under
-        each ``--q6k-tn`` tile that divides N."""
-        out = []
-        for side, mod in sides.items():
-            fam = mod.FAMILIES[famname]
-            # (a parent before PR 59 keeps a family's tiles as ``tn_prefs``)
-            tn = fam.tn(N, rows, False) if hasattr(fam, "tn") else \
-                mod._pick_tn(N, False, prefs=mod._tn_prefs_for(
-                    rows, fam.tn_prefs))
-            out.append((side, mod, fam, tn))
-        for tn in (int(t) for t in args.q6k_tn.split(",") if t):
-            if famname == "q6k" and N % tn == 0:
-                fam = copy.copy(X.FAMILIES[famname])
+        under its own families, this tree, and this tree's body (a K tile a
+        grid step) and, for Q4_K, the parent's under each ``--q6k-tn`` /
+        ``--q4k-tn`` tile that divides N."""
+        out = [(side, mod, mod.FAMILIES[famname],
+                mod.FAMILIES[famname].tn(N, rows, False))
+               for side, mod in sides.items()]
+        tiles = {"q6k": args.q6k_tn, "q4k": args.q4k_tn}[famname]
+        for tn in (int(t) for t in tiles.split(",") if t):
+            for (side, mod), own in zip(sides.items(), list(out)):
+                if N % tn or (side, famname) == ("parent", "q6k") or (
+                        tn == own[3]
+                        and k_steps(own[2], tn, K, few) == K // X.TK):
+                    continue                # no such tile, or the side's own
+                fam = copy.copy(mod.FAMILIES[famname])
                 fam.tn = lambda N, rows, interpret, tn=tn: tn
-                out.append((f"new.tn{tn}", X, fam, tn))
+                fam.few_k_tiles = False
+                out.append((f"{side}.tn{tn}", mod, fam, tn))
         return out
 
     def run_sides(label, few, famname, N, K, rows, meta, xpa, extra_in,
                   planes, place=None):
         """``_grouped_call(fam, meta, xpa, planes, rows, few, extra_in,
-        interpret, variant)`` of each side on the same operands; ``place``:
-        each row's place in a compacted call's result."""
+        interpret)`` of each side on the same operands (and a ``variant``
+        for a parent that takes one: PR 60 and before); ``place``: each
+        row's place in a compacted call's result."""
         got = {}
         for side, mod, fam, tn in families(
-                famname, N, xpa.shape[0] if few else rows):
+                famname, N, K, xpa.shape[0] if few else rows, few):
 
-            def call(meta, xpa, *rest, mod=mod, fam=fam):
+            variant = ("resplit",) if "variant" in inspect.signature(
+                mod._grouped_call).parameters else ()
+
+            def call(meta, xpa, *rest, mod=mod, fam=fam, variant=variant):
                 return mod._grouped_call(
                     fam, meta, xpa, rest[len(extra_in):], xpa.shape[0]
                     if few else rows, few, rest[:len(extra_in)], False,
-                    "cur")
+                    *variant)
             row = dict(label, side=side, TN=tn,
-                       steps_a_slot=(N // tn) * (K // X.TK))
+                       steps_a_slot=(N // tn) * k_steps(fam, tn, K, few))
             try:
                 us, out = slope_us(call, meta, xpa, *extra_in, *planes)
             except Exception as err:    # a tile the compiler refuses

@@ -4,7 +4,8 @@ those files moved: the dense fused matmuls (plain and stacked, both
 families, every row regime) and the routed layer after the router at the
 five routed configurations' widths (a serial step, the lane engines'
 vmapped step, prefill slices), each with the chip's kernels and in
-interpret mode.
+interpret mode, and the grouped calls alone, a family each (``grouped.*``:
+a 16-lane decode step's few-row call and a 1024-token slice's many-row one).
 
     python tools/traced_program_hashes.py <tree> <out.json>     # once a tree
     git archive --prefix=.parent_check/ <parent> | tar x
@@ -20,6 +21,7 @@ code.  Needs no chip; a minute a tree."""
 from __future__ import annotations
 
 import hashlib
+import inspect
 import json
 import os
 import re
@@ -36,6 +38,7 @@ def hashes(tree: str | None = None, only=None) -> dict:
 
     import llama_fastapi_k8s_gpu_tpu as pkg
     from llama_fastapi_k8s_gpu_tpu.ops import pallas as P
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas import experts as X
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.experts import (
         fold_factor, padded_k, routed_experts)
 
@@ -112,6 +115,26 @@ def hashes(tree: str | None = None, only=None) -> dict:
                                 x, p, wt),
                         S((t, 1, D), bf16), S((t, 1, k), i32),
                         S((t, 1, k), f32), *w))
+        # the grouped calls alone, a family each: a layer's program holds
+        # both, so a change to one family's body or tile moves every
+        # ``routed.*`` hash and only these tell the families apart
+        for fmt, n, kk in (("q4k", F, D), ("q6k", D, F)):
+            fam, f = X.FAMILIES[fmt], fold_factor(kk)
+            w1 = list(planes(fmt, n // f, padded_k(kk) * f, (2, E)).values())
+            # (a tree up to PR 60 hands the call a Q4_K variant too)
+            variant = ("resplit",) if "variant" in inspect.signature(
+                X.grouped_matmul_few).parameters else ()
+            rows = min(16 * k, X.ROW_GROUP)         # a 16-lane decode step
+            T = min(E, rows)
+            put(f"grouped.{fmt}.{name}.few", lambda: traced(
+                lambda m, x, re, *w: X.grouped_matmul_few(
+                    fam, m, x, re, w, f, False, *variant),
+                S((2 + T,), i32), S((rows, kk), bf16), S((rows,), i32), *w1))
+            T = X.n_tiles(1024 * k, E, 1024, X.TM_MANY)
+            put(f"grouped.{fmt}.{name}.many", lambda: traced(
+                lambda m, x, *w: X.grouped_matmul_many(
+                    fam, m, x, w, f, False, *variant),
+                S((2 + T,), i32), S((T * X.TM_MANY, kk), bf16), *w1))
     return res
 
 
