@@ -976,10 +976,12 @@ class ContinuousEngine(Engine):
                        self._prefill_chunk, self._wide_slice, adm["alone"])
         sl = jnp.asarray(adm["padded"][off:off + C])
         li = min(max(adm["n_prompt"] - 1 - off, 0), C - 1)
+        # the program by whether the slice holds the prompt's last token
+        scfg = self._slice_cfgs[off <= adm["n_prompt"] - 1 < off + C]
         with phase("admit_slice", rid=rid(adm["item"].trace), offset=off,
                    tokens=C):
             logits, cache = prefill_chunk_jit(
-                self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
+                self.params, scfg, sl, jnp.int32(off), jnp.int32(li),
                 self._scratch_cache)
         self._scratch_cache = cache
         if off <= adm["n_prompt"] - 1 < off + C:
@@ -990,12 +992,13 @@ class ContinuousEngine(Engine):
         tot = self._totals
         tot["admit_slices"] += 1
         tot["admit_tokens"] += C
-        self._count_slice(C)
+        self._count_slice(C, scfg)
         self._slices_queued += 1
         # wave: the decode chunk this slice is queued ahead of (the next
         # one dispatched), the number that chunk's decode_chunk spans carry
         self._slice_span(adm.get("span"), t_s, t_e, off, C,
-                         wave=tot["chunks_dispatched"] + 1)
+                         wave=tot["chunks_dispatched"] + 1,
+                         **self._slice_attrs(scfg))
 
     def _finish_admission(self, adm: dict, lane: int, slots: list) -> None:
         """Prefill complete: sample the first token, write the lane, install.
@@ -1535,6 +1538,7 @@ class ContinuousEngine(Engine):
                   if slot is not None and not slot.finished]
         self.cache.note_decode(self.cache_counts, self.cfg, wanted, n_steps,
                                [p for p in at if p is not None])
+        self.cache.note_lanes(self.cache_counts, self.cfg, len(pre), n_steps)
         return len(wanted)
 
     def _loop(self):

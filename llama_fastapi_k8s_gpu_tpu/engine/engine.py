@@ -385,6 +385,12 @@ class Engine:
         # takes no wider slice
         self._wide_slice = wide_width(self._prefill_chunk,
                                       self.cache.widest_slice(self.cfg))
+        # the configuration a slice's program is built for, by whether the
+        # slice holds its prompt's last token (``CacheKind.slice_cfg``: a
+        # kind whose upper layers write no cache stops the others early);
+        # most kinds: this one configuration twice
+        self._slice_cfgs = {last: self.cache.slice_cfg(self.cfg, last)
+                            for last in (True, False)}
         # the serial ring here and the paged pool below; a subclass's lanes
         # are a phase of their own (``lanes_alloc``, ``scheduler_start``)
         alloc = tl.phase("cache_alloc")
@@ -700,11 +706,14 @@ class Engine:
         engines' warm-ups compile, once a shape.  Returns (the cache the
         calls were queued on, how many shapes)."""
         shapes = slice_shapes(buckets, self._prefill_chunk, self._wide_slice)
+        # (a kind that stops some slices early: a second program a shape)
+        cfgs = list(dict.fromkeys(self._slice_cfgs.values()))
         for C in shapes:
-            _, cache = prefill_chunk_jit(
-                self.params, self.cfg, jnp.zeros((C,), jnp.int32),
-                jnp.int32(0), jnp.int32(C - 1), cache)
-        return cache, len(shapes)
+            for scfg in cfgs:
+                _, cache = prefill_chunk_jit(
+                    self.params, scfg, jnp.zeros((C,), jnp.int32),
+                    jnp.int32(0), jnp.int32(C - 1), cache)
+        return cache, len(shapes) * len(cfgs)
 
     def _slices_prefill(self, bucket: int) -> bool:
         """Whether a ``bucket``-sized prompt prefills as overlapped slices
@@ -724,19 +733,30 @@ class Engine:
             except Exception:  # noqa: BLE001 — telemetry must never fail serving
                 pass
 
-    def _count_slice(self, tokens: int) -> None:
+    def _count_slice(self, tokens: int, scfg=None) -> None:
         """One prefill program's tokens into :attr:`slice_tokens`, and
-        into the cache kind's own counters."""
+        into the cache kind's own counters.  ``scfg``: the configuration
+        the program was built for (:attr:`_slice_cfgs`; default the
+        engine's)."""
         self.slice_tokens[
             "wide" if tokens > self._prefill_chunk else "narrow"] += tokens
-        self.cache.note_slice(self.cache_counts, self.cfg, tokens)
-        self.pass_counts["prefill"] += tokens * layer_passes(self.cfg)
+        run = self.cache.note_slice(self.cache_counts, scfg or self.cfg,
+                                    tokens)
+        # (a kind that runs some slices on part of the stack says how much)
+        self.pass_counts["prefill"] += tokens * layer_passes(self.cfg) \
+            if run is None else run
 
     def _count_lane_steps(self, lane_steps: int) -> None:
         """Decode steps of the lanes whose rows were wanted, and the layer
         applications they took, into :attr:`pass_counts`."""
         self.pass_counts["lane_steps"] += lane_steps
         self.pass_counts["decode"] += lane_steps * layer_passes(self.cfg)
+
+    def _slice_attrs(self, scfg) -> dict:
+        """What a traced ``prefill_slice`` span says of a program that is
+        not the whole stack's (``CacheKind.slice_cfg``); nothing of one
+        that is."""
+        return {"lower_only": True} if scfg.lower_only else {}
 
     @staticmethod
     def _slice_span(pspan, t_s: float, t_e: float, offset: int, tokens: int,
@@ -791,9 +811,10 @@ class Engine:
             t_s = time.time()
             sl = jnp.asarray(padded_np[off:off + n])
             li = min(max(last - off, 0), n - 1)
+            scfg = self._slice_cfgs[off <= last < off + n]
             with phase("prefill_slice", rid=rid(pspan), offset=off, tokens=n):
                 lg, cache = prefill_chunk_jit(
-                    self.params, self.cfg, sl, jnp.int32(off), jnp.int32(li),
+                    self.params, scfg, sl, jnp.int32(off), jnp.int32(li),
                     cache)
                 if off <= last < off + n:
                     logits = lg
@@ -804,8 +825,9 @@ class Engine:
                     jax.block_until_ready(inflight.popleft())
             t_e = time.time()
             self._observe_slice(t_e - t_s)
-            self._slice_span(pspan, t_s, t_e, off, n)
-            self._count_slice(n)
+            self._slice_span(pspan, t_s, t_e, off, n,
+                             **self._slice_attrs(scfg))
+            self._count_slice(n, scfg)
         return logits, cache
 
     def _decode_chunk_call(self, state, st, n_steps: int, top_k: int,
@@ -816,6 +838,7 @@ class Engine:
         state, out = generate_chunk_jit(self.params, self.cfg, state, st,
                                         n_steps=n_steps, top_k=top_k)
         self.cache.note_decode(self.cache_counts, self.cfg, [pos], n_steps)
+        self.cache.note_lanes(self.cache_counts, self.cfg, 1, n_steps)
         self._count_lane_steps(n_steps)
         return state, self._take_expert_stats(out)
 

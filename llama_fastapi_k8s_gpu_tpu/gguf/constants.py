@@ -233,11 +233,38 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``early_exit_threshold`` (under 1.0 refused by name:
 #: models/config.py), ``attention.key_length`` (a head's width).  Q and K
 #: rotate on halves.
+#: ``phi4flash`` (this repo's name for Phi-4-mini-flash-reasoning's block,
+#: ``model_type phi4flash``: llama.cpp's, if it has one, is not known here;
+#: models/phi4flash.py) is a MIXER kind per layer over the seventh cache
+#: kind and a dense SwiGLU in every layer; every norm is a LayerNorm
+#: (``*_norm.weight`` AND ``*_norm.bias``), nothing rotates, and there is
+#: no ``output.weight``: the head is ``token_embd``.  Key ``mixer_types``:
+#: a comma-joined kind a layer, ``ssm`` | ``window`` | ``full`` | ``gmu`` |
+#: ``cross``, in the order (ssm, window) pairs, ONE (ssm, full) pair,
+#: (gmu, cross) pairs.  Tensors: an ``ssm`` layer has llama.cpp's Mamba
+#: names ``blk.N.ssm_in`` (2 x inner, dim: the rows of x then z),
+#: ``ssm_conv1d.weight`` (inner, conv_kernel: F32 taps, oldest first) and
+#: ``ssm_conv1d.bias``, ``ssm_x`` (time_step_rank + 2 x state_size, inner:
+#: dt, B, C in that order), ``ssm_dt.weight`` (inner, time_step_rank) and
+#: ``ssm_dt.bias``, ``ssm_a`` (inner, state_size: A itself, negative, as
+#: llama.cpp's converter stores it), ``ssm_d`` (inner), ``ssm_out`` (dim,
+#: inner); a ``window`` / ``full`` layer ``attn_{q,k,v,output}`` with
+#: ``.bias``, the differential form's ``attn_lambda_{q1,k1,q2,k2}`` (a
+#: head's width, F32) and ``attn_sub_norm.weight`` (2 x a head's width: an
+#: RMSNorm); a ``gmu`` layer ``gmu_in`` (inner, dim) and ``gmu_out`` (dim,
+#: inner); a ``cross`` layer ``attn_q``, ``attn_output`` and the lambdas
+#: and ``attn_sub_norm`` alone.  Keys: llama.cpp's ``ssm.conv_kernel`` /
+#: ``ssm.inner_size`` / ``ssm.state_size`` / ``ssm.time_step_rank``,
+#: ``attention.sliding_window``, ``attention.key_length``; and
+#: ``ssm.values`` (absent: ``stored``): ``init_offsets`` says that
+#: ``ssm_a`` and ``ssm_dt.bias`` hold OFFSETS from Mamba's initialisation
+#: (models/params.py ``ssm_values``), which is how a file of random values
+#: gets the time scales a trained one has.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
                         "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash",
-                        "ouro", "deepseek32")
+                        "ouro", "deepseek32", "phi4flash")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):

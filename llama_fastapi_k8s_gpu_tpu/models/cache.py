@@ -15,8 +15,8 @@ import types
 from typing import Callable, Mapping
 
 from .config import (
-    CONV_RING, LATENT_RING, RING, STATE_RING, WINDOW_GLOBAL_RING,
-    WINDOW_SUMMARIES, ModelConfig)
+    CONV_RING, LATENT_RING, RING, SSM_WINDOW_SHARED, STATE_RING,
+    WINDOW_GLOBAL_RING, WINDOW_SUMMARIES, ModelConfig)
 
 #: what an engine can ASK of a cache kind, in the order the asks are
 #: checked, each with the setting as its operator wrote it; ``supports``
@@ -93,8 +93,18 @@ class CacheKind:
     #: it is given the plan of an untraced request's slices too
     counts_prefill: bool = False
     #: (counts, cfg, tokens): one dispatched prefill program of ``tokens``
-    #: rows into the counters
+    #: rows into the counters; ``cfg``: the one the program was built for
+    #: (``slice_cfg``).  Returns None, or the layer applications the
+    #: program ran where that is not ``tokens`` x the stack's
     note_slice: Callable = lambda counts, cfg, tokens: None
+    #: (cfg, holds_last) -> the configuration a prefill slice's program is
+    #: built for, by whether the slice holds its prompt's last token: a kind
+    #: whose upper layers write no cache runs the others on part of the
+    #: stack (a SECOND program a slice shape in the warm-up); most: ``cfg``
+    slice_cfg: Callable = lambda cfg, holds_last: cfg
+    #: (counts, cfg, lanes, n_steps): the lanes a decode chunk's program
+    #: stepped, whether they hold a request or not, into the counters
+    note_lanes: Callable = lambda counts, cfg, lanes, n_steps: None
     #: (live rows at the chunk's end) -> a ``decode_chunk`` span's attributes
     decode_span_attrs: Callable = lambda pos: {}
     #: (cfg) -> attributes that EVERY traced ``prefill`` and ``decode_chunk``
@@ -126,7 +136,7 @@ class CacheKind:
 #: the module that holds each kind's ``CACHE``
 _MODULES = {RING: "llama", WINDOW_SUMMARIES: "eva", STATE_RING: "sala",
             LATENT_RING: "mla", WINDOW_GLOBAL_RING: "hybrid",
-            CONV_RING: "lfm2"}
+            CONV_RING: "lfm2", SSM_WINDOW_SHARED: "phi4flash"}
 
 
 def cache_of(cfg: ModelConfig) -> CacheKind:

@@ -20,12 +20,18 @@ RING, WINDOW_SUMMARIES, STATE_RING = "ring", "window+summaries", "state+ring"
 LATENT_RING = "latent-ring"
 WINDOW_GLOBAL_RING = "window+global-ring"
 CONV_RING = "conv-state+ring"
+SSM_WINDOW_SHARED = "ssm-state+window+shared-ring"
 
 #: the attention kinds of a layer (``ModelConfig.attn_kinds``)
 WINDOW, GLOBAL = "window", "global"
 
 #: the mixer kinds of a ``lfm2moe`` layer (``ModelConfig.mixers``)
 CONV, ATTN = "conv", "attn"
+
+#: the mixer kinds of a ``phi4flash`` layer beside :data:`WINDOW`: a
+#: selective scan, full attention that WRITES the shared leaf, a gated
+#: memory unit, attention that only READS the shared leaf
+SSM, FULL, GMU, CROSS = "ssm", "full", "gmu", "cross"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,6 +235,27 @@ class ModelConfig:
     ut_steps: int = 1
     sandwich_norm: bool = False
     exit_threshold: float = 1.0
+    # A ``phi4flash`` file (models/phi4flash.py; ``ssm_d_state`` > 0):
+    # ``mixers`` names each layer ``"ssm"`` (Mamba-1: ``ssm_d_inner``
+    # channels of ``ssm_d_state`` float32 states each, ``ssm_d_conv``
+    # causal depthwise taps, a step size from ``ssm_dt_rank`` columns),
+    # ``"window"`` / ``"full"`` (differential attention; the ONE full layer
+    # writes the shared K/V leaf), ``"gmu"`` (a gate on the last ssm layer's
+    # scan output) or ``"cross"`` (a query on the shared leaf).  Every norm
+    # is a LayerNorm with a bias; nothing rotates.
+    ssm_d_inner: int = 0
+    ssm_d_state: int = 0
+    ssm_d_conv: int = 0
+    ssm_dt_rank: int = 0
+    # a prefill slice's scan runs the slice kernel (ops/pallas/ssmscan.py).
+    # Set by the engine, never by a file or a user: a TPU whose compiler
+    # took the kernel's probe
+    ssm_scan_kernel: bool = False
+    # The program of a prefill slice that holds NO prompt's last token: it
+    # stops after the layer that writes the shared leaf (nothing above it
+    # writes a cache, and no logits are read).  Set by the engines' slice
+    # plan (``CacheKind.slice_cfg``), never by a file.
+    lower_only: bool = False
 
     @property
     def cache_leaves(self) -> int:
@@ -274,8 +301,11 @@ class ModelConfig:
         ``ring``, ``window+summaries`` (models/eva.py), ``state+ring``
         (models/sala.py), ``latent-ring`` (models/mla.py) or
         ``window+global-ring`` (models/hybrid.py) or ``conv-state+ring``
-        (models/lfm2.py); models/cache.py ``cache_of`` maps it to the kind's
-        object, nothing else tests it."""
+        (models/lfm2.py) or ``ssm-state+window+shared-ring``
+        (models/phi4flash.py); models/cache.py ``cache_of`` maps it to the
+        kind's object, nothing else tests it."""
+        if self.ssm_d_state:
+            return SSM_WINDOW_SHARED
         if self.conv_l_cache:
             return CONV_RING
         if self.mixers:
@@ -293,6 +323,10 @@ class ModelConfig:
     def n_linear_weights(self) -> int:
         """About how many weights the layers' matrices hold, every expert
         included: what the ``weight_format="auto"`` size test weighs."""
+        if self.ssm_d_state:
+            return self.n_layers * 3 * self.dim * self.ffn_dim \
+                + self.n_layers_of(SSM) * 3 * self.dim * self.ssm_d_inner \
+                + (self.n_layers_of(WINDOW) + 1) * 3 * self.dim * self.dim
         if self.kv_lora_rank or self.attn_kinds or self.conv_l_cache:
             routed = 3 * self.dim * self.expert_ffn_dim * (
                 self.n_held + self.n_shared_experts)
@@ -385,6 +419,8 @@ class ModelConfig:
             mla = _longcat_fields(h, n_heads, int(h("embedding_length")))
         if arch == "ouro":
             mla = _ouro_fields(h)
+        if arch == "phi4flash":
+            mla = _phi4flash_fields(h, n_heads, n_kv_heads, window)
         return cls(
             vocab_size=int(vocab),
             dim=int(h("embedding_length")),
@@ -595,6 +631,47 @@ def _lfm2moe_fields(h, n_heads: int, kv_heads) -> dict:
                 head_width=int(h("attention.key_length", 0) or 0),
                 # the family's router divides by the picked scores' sum + 1e-6
                 expert_weights_eps=1e-6, **routed)
+
+
+def _phi4flash_fields(h, n_heads: int, n_kv: int, window: int) -> dict:
+    """The ``phi4flash`` keys (gguf/constants.py) as ``ModelConfig`` fields;
+    a ValueError naming what the block here cannot compute."""
+    arch = "phi4flash"
+    n_layers, dim = int(h("block_count")), int(h("embedding_length"))
+    listed = str(h("mixer_types", "")).split(",")
+    if len(listed) != n_layers or any(
+            m not in (SSM, WINDOW, FULL, GMU, CROSS) for m in listed):
+        raise ValueError(
+            f"{arch}: mixer_types {listed!r} must name one of ssm, window, "
+            f"full, gmu, cross for each of the {n_layers} layers")
+    # the stack this block walks: (ssm, window) pairs, ONE (ssm, full)
+    # pair, (gmu, cross) pairs
+    n_low = listed.index(FULL) - 1 if FULL in listed else -1
+    if n_low < 0 or n_low % 2 or (n_layers - n_low) % 2 or tuple(listed) != (
+            (SSM, WINDOW) * (n_low // 2) + (SSM, FULL)
+            + (GMU, CROSS) * ((n_layers - n_low - 2) // 2)):
+        raise ValueError(
+            f"{arch}: mixer_types {listed!r}: the block here is (ssm, "
+            "window) pairs, one (ssm, full) pair, then (gmu, cross) pairs")
+    d_inner, d_state, d_conv, dt_rank = (
+        int(h(f"ssm.{key}", 0) or 0) for key in
+        ("inner_size", "state_size", "conv_kernel", "time_step_rank"))
+    if min(d_inner, d_state, dt_rank) < 1 or d_conv < 2:
+        raise ValueError(
+            f"{arch}: the file lacks <arch>.ssm.inner_size / state_size / "
+            "time_step_rank, or ssm.conv_kernel is under 2 taps")
+    d_k = int(h("attention.key_length", 0) or 0) or dim // n_heads
+    if n_heads % 2 or n_kv % 2 or (n_heads // 2) % (n_kv // 2) \
+            or 2 * d_k != 128:
+        raise ValueError(
+            f"{arch}: {n_heads} heads on {n_kv} KV heads of {d_k}: "
+            "differential attention pairs even and odd heads, and the block "
+            "here lays a pair's two 64-wide keys side by side in one row")
+    if window < 1:
+        raise ValueError(f"{arch}: attention.sliding_window {window}")
+    return dict(mixers=tuple(listed), ssm_d_inner=d_inner,
+                ssm_d_state=d_state, ssm_d_conv=d_conv, ssm_dt_rank=dt_rank,
+                head_width=d_k)
 
 
 def _exaone_moe_fields(h, n_heads: int, window: int) -> dict:

@@ -146,7 +146,7 @@ def window_decode_attention(q, kw_l, vw_l, pos, cfg: ModelConfig, out_dtype):
     qg = q.reshape(n_kv, cfg.n_heads // n_kv, hd)
     with jax.named_scope("attn_scores"):
         s = jnp.einsum("ngh,nth->ngt", qg, kw_l.astype(qg.dtype),
-                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+                       preferred_element_type=jnp.float32) * cfg.sm_scale
     key_pos = slot_positions(jnp.asarray(pos, jnp.int32), kw_l.shape[1])
     mask = (key_pos >= 0) & (key_pos > pos - cfg.sliding_window)
     s = jnp.where(mask[None, None, :], s, -jnp.inf)
@@ -159,7 +159,8 @@ def window_decode_attention(q, kw_l, vw_l, pos, cfg: ModelConfig, out_dtype):
     return ctx.reshape(1, cfg.n_heads * hd).astype(out_dtype)
 
 
-def _run_attention_xla(q, kt, vt, q0: int, first_key, window: int, out_dtype):
+def _run_attention_xla(q, kt, vt, q0: int, first_key, window: int, out_dtype,
+                       sm_scale: float):
     """S queries over a run of keys in position order: query s is row
     ``q0 + s`` of the run, rows below ``first_key`` hold no position."""
     S, n_heads, hd = q.shape
@@ -167,7 +168,7 @@ def _run_attention_xla(q, kt, vt, q0: int, first_key, window: int, out_dtype):
     qg = q.reshape(S, n_kv, n_heads // n_kv, hd).transpose(1, 2, 0, 3)
     with jax.named_scope("attn_scores"):
         s = jnp.einsum("ngsh,nch->ngsc", qg, kt,
-                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+                       preferred_element_type=jnp.float32) * sm_scale
     c = jnp.arange(T)[None, :]
     row = (q0 + jnp.arange(S))[:, None]
     mask = (c <= row) & (c > row - window) & (c >= first_key)
@@ -200,11 +201,12 @@ def window_slice(q, kh, vh, cache, ci, pos_offset, n_valid, cfg: ModelConfig,
         from ..ops.pallas import flash_attention, use_interpret
 
         ctx = flash_attention(
-            q, kt, vt, jnp.int32(slots), sm_scale=cfg.head_dim ** -0.5,
+            q, kt, vt, jnp.int32(slots), sm_scale=cfg.sm_scale,
             sliding_window=W, interpret=use_interpret(), first_key=first_key,
         ).reshape(S, -1).astype(out_dtype)
     else:
-        ctx = _run_attention_xla(q, kt, vt, slots, first_key, W, out_dtype)
+        ctx = _run_attention_xla(q, kt, vt, slots, first_key, W, out_dtype,
+                                 cfg.sm_scale)
     # the leaf afterwards: slot s holds the newest real position that
     # lives there, from this slice where it has one, else what it held
     last = n_valid - 1
@@ -234,7 +236,7 @@ def window_step(q, kh, vh, cache, ci, pos, live, cfg: ModelConfig, out_dtype):
         ctx, kw, vw = flash_attention_decode(
             q[0], cache["kw"], cache["vw"], ci, pos,
             True if live is None else live,
-            sm_scale=cfg.head_dim ** -0.5, block_k=block,
+            sm_scale=cfg.sm_scale, block_k=block,
             sliding_window=cfg.sliding_window, interpret=use_interpret(),
             k_new=kh[:, 0], v_new=vh[:, 0], wrap=True)
         return ctx[None].astype(out_dtype), dict(cache, kw=kw, vw=vw)

@@ -211,7 +211,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     latent_norms = (("attn_norm", "attn_norm"), ("q_a_norm", "attn_q_a_norm"),
                     ("kv_a_norm", "attn_kv_a_norm"))
     fused_names = _fused_names(
-        [] if by_ffn_kind or cfg.conv_l_cache else None) \
+        [] if by_ffn_kind or cfg.conv_l_cache or cfg.ssm_d_state else None) \
         if fmt == "q4k" else {}
 
     import time as _time
@@ -530,9 +530,100 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 out[kind].append(layer)
         return {k: v for k, v in out.items() if v}
 
+    def ssm_values(p: str) -> dict:
+        """An ssm layer's ``A`` (d_state, d_inner: the channels last, as the
+        scan's kernel lays them) and ``b_dt``.  As STORED (``ssm_a`` holds A
+        itself, negative, as llama.cpp's converter writes it), or, where the
+        file says ``<arch>.ssm.values = init_offsets``, as offsets from
+        Mamba's initialisation: ``A = -exp(log(n + 1) + ssm_a[c, n])`` and
+        ``b_dt = softplus^-1(dt0[c]) + ssm_dt.bias[c]`` with ``dt0`` spread
+        over 1e-3..1e-1 in the logarithm by the channel (a fixed
+        low-discrepancy order).  That is how a file of small random values
+        (the benchmark's writer gives a block no say over values) has the
+        time scales of a trained one: its states neither vanish within a
+        few positions nor grow."""
+        a = np.asarray(gf[p + "ssm_a"].astype_f32(), np.float32)
+        b_dt = np.asarray(gf[p + "ssm_dt.bias"].astype_f32(), np.float32)
+        how = str(gf.hparam("ssm.values", "stored"))
+        if how == "init_offsets":
+            n_c, n_s = a.shape
+            a = -np.exp(np.log(np.arange(1, n_s + 1, dtype=np.float32))[None]
+                        + a)
+            u = (np.arange(n_c, dtype=np.float64) * 0.6180339887498949) % 1.0
+            dt0 = np.exp(np.log(1e-3) + u * (np.log(1e-1) - np.log(1e-3)))
+            b_dt = (dt0 + np.log(-np.expm1(-dt0))).astype(np.float32) + b_dt
+        elif how != "stored":
+            raise ValueError(f"phi4flash: ssm.values {how!r} (stored, "
+                             "init_offsets)")
+        return {"a": jnp.asarray(np.ascontiguousarray(a.T)),
+                "dt_b": jnp.asarray(b_dt)}
+
+    def ssm_mixer_layers() -> dict:
+        """A ``phi4flash`` file (models/phi4flash.py): FIVE stacks, a
+        layer's mixer tensors under its mixer kind (``ssm`` | ``attn``: the
+        window layers and the full one | ``gmu`` | ``cross``, with the
+        layer's ``attn_norm``) and every layer's feed-forward under ``ffn``;
+        a name fuses by the types of ITS kind's layers.  Nothing is
+        requantized: a matrix no fused kernel takes (``ssm_x``: its rows
+        fill no tile) is served bf16, the F32 ``ssm_dt`` float32."""
+        from .config import CROSS, FULL, GMU, SSM, WINDOW
+        from .phi4flash import ATTN, FFN
+
+        mats = {
+            SSM: {"in_proj": "ssm_in", "out_proj": "ssm_out"},
+            ATTN: {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
+                   "wo": "attn_output"},
+            GMU: {"in_proj": "gmu_in", "out_proj": "gmu_out"},
+            CROSS: {"wq": "attn_q", "wo": "attn_output"},
+            FFN: {"w_gate": "ffn_gate", "w_up": "ffn_up",
+                  "w_down": "ffn_down"}}
+        lams = {f"lam_{k}": f"attn_lambda_{k}"
+                for k in ("q1", "k1", "q2", "k2")}
+        norm1 = {"attn_norm": "attn_norm.weight",
+                 "attn_norm_b": "attn_norm.bias"}
+        f32s = {
+            SSM: {**norm1, "conv": "ssm_conv1d.weight",
+                  "conv_b": "ssm_conv1d.bias", "dt_proj": "ssm_dt.weight",
+                  "d": "ssm_d"},
+            ATTN: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
+                   "bq": "attn_q.bias", "bk": "attn_k.bias",
+                   "bv": "attn_v.bias", "bo": "attn_output.bias"},
+            GMU: norm1,
+            CROSS: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
+                    "bq": "attn_q.bias", "bo": "attn_output.bias"},
+            FFN: {"ffn_norm": "ffn_norm.weight",
+                  "ffn_norm_b": "ffn_norm.bias"}}
+        ids = {kind: [i for i, m in enumerate(cfg.mixers) if m in names]
+               for kind, names in ((SSM, (SSM,)), (ATTN, (WINDOW, FULL)),
+                                   (GMU, (GMU,)), (CROSS, (CROSS,)))}
+        ids[FFN] = list(range(cfg.n_layers))
+        out = {}
+        for kind, mine in ids.items():
+            fused = _fused_names(list(mats[kind].values()), mine) \
+                if fmt == "q4k" and mine else {}
+            out[kind] = []
+            for i in mine:
+                p = f"blk.{i}."
+                layer = {}
+                for key, name in mats[kind].items():
+                    if fmt == "q4k" and name not in fused:
+                        layer[key] = {"w": as_bf16(gf[p + name + ".weight"])}
+                    else:
+                        layer[key] = lin(p + name + ".weight", fused)
+                for key, name in f32s[kind].items():
+                    layer[key] = norm(p + name)
+                if kind == SSM:
+                    layer["x_proj"] = {"w": as_bf16(gf[p + "ssm_x.weight"])}
+                    layer.update(ssm_values(p))
+                if overlap:
+                    layer = jax.tree.map(jax.device_put, layer)
+                out[kind].append(layer)
+        return out
+
     layers = []
     t_prep = _time.time()
-    by_kind = mixer_ffn_layers() if cfg.conv_l_cache else \
+    by_kind = ssm_mixer_layers() if cfg.ssm_d_state else \
+        mixer_ffn_layers() if cfg.conv_l_cache else \
         kinds_layers() if cfg.mixers else \
         shortcut_layers() if cfg.attn_sublayers == 2 else \
         ffn_kind_layers() if by_ffn_kind else None
@@ -569,8 +660,25 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         logger.debug("loaded layer %d/%d", i + 1, cfg.n_layers)
     t_head = _time.time()
 
-    emb = as_bf16(gf["token_embd.weight"])
-    if cfg.tie_embeddings or "output.weight" not in gf.tensors:
+    tied_fused = None
+    if cfg.ssm_d_state and fmt == "q4k":
+        # a ``phi4flash`` file's tied Q6_K table: ONE stored tensor, the
+        # head's fused planes, of which the embedding lookup dequantizes the
+        # rows it gathers (models/phi4flash.py ``embed``)
+        from ..gguf.constants import GGMLType
+        from ..ops.pallas.q6matmul import q6k_compatible
+
+        t = gf["token_embd.weight"]
+        if t.ggml_type == GGMLType.Q6_K \
+                and (fused_types is None or GGMLType.Q6_K in fused_types) \
+                and q6k_compatible(t.shape[1], padded_k(t.shape[0])):
+            tied_fused = lin("token_embd.weight",
+                             {"token_embd": GGMLType.Q6_K})
+    emb = tied_fused if tied_fused is not None \
+        else as_bf16(gf["token_embd.weight"])
+    if tied_fused is not None:
+        output = tied_fused
+    elif cfg.tie_embeddings or "output.weight" not in gf.tensors:
         output = {"w": emb}
     elif (cfg.fp32_residual or cfg.fp32_logits) \
             and gf["output.weight"].ggml_type.name in ("F32", "F16", "BF16"):
@@ -604,6 +712,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                          in gf.tensors else "token_embd_norm.weight"),
         "output": output,
         **gate,
+        # (a ``phi4flash`` file's norms are LayerNorms: a bias beside each)
+        **({"out_norm_b": norm("output_norm.bias")} if cfg.ssm_d_state
+           else {}),
     }
 
 

@@ -496,6 +496,55 @@ METRICS: dict[str, Metric] = _register(
     Metric("conv_state_starts_total", GAUGE,
            "prefills that began from zero rows: every prompt's, since the "
            "carried rows cannot be rolled back to a prefix; cumulative"),
+    # -- the state + window + shared-ring cache (models/phi4flash.py) -------
+    # (its window_slots_* are the window + global cache's families above)
+    Metric("ssm_state_updates_total", GAUGE,
+           "selective-scan states stepped in decode steps for a lane that "
+           "holds an unfinished request: one per step, state-space layer "
+           "and such lane (a serial engine: its one sequence), cumulative; "
+           "from host-tracked positions, nothing fetched; exported by a "
+           "file of that cache kind only"),
+    Metric("ssm_state_steps_total", GAUGE,
+           "selective-scan states the decode steps' arithmetic stepped: "
+           "one per step, state-space layer and LANE OF THE BATCH, whether "
+           "it holds a request or not (a lane that holds none keeps its "
+           "state); ssm_state_updates_total over it = the mean share of "
+           "live lanes"),
+    Metric("ssm_state_starts_total", GAUGE,
+           "prefills that began from zero states: every prompt's, since a "
+           "state cannot be rolled back to a prefix; cumulative"),
+    Metric("shared_leaf_reads_total", GAUGE,
+           "reads of the ONE shared K/V leaf (the full-attention layer's) "
+           "by decode steps: one per step, lane with an unfinished request "
+           "and READING layer (the full layer and every cross layer), "
+           "cumulative"),
+    Metric("shared_leaf_steps_total", GAUGE,
+           "decode steps summed over the lanes with an unfinished request: "
+           "shared_leaf_reads_total over it = reads of the leaf a step"),
+    Metric("shared_leaf_slots_read_total", GAUGE,
+           "slots of the shared leaf those reads covered (whole blocks up "
+           "to the lane's position under the decode kernel, up to the "
+           "largest live lane's under the XLA loop), summed over the "
+           "reading layers; ring_slots_read_total is this plus "
+           "window_slots_read_total"),
+    Metric("shared_leaf_slots_live_total", GAUGE,
+           "of those, the slots at or below the sequence's own position"),
+    Metric("prefill_layer_rows_run_total", GAUGE,
+           "(layer, prompt row) pairs the prefill programs ran, padding "
+           "included, cumulative: a slice that holds no prompt's last token "
+           "runs the layers up to the full-attention one, the slice that "
+           "does runs the others on that one row; host arithmetic at each "
+           "dispatch"),
+    Metric("prefill_layer_rows_skipped_total", GAUGE,
+           "(layer, prompt row) pairs no program ran: the layers above the "
+           "full-attention one write no cache, so no later token reads them "
+           "at a prompt position; over this plus "
+           "prefill_layer_rows_run_total = the share of the stack's "
+           "applications a prompt did not pay"),
+    Metric("prefill_programs_total", GAUGE,
+           "prefill programs dispatched, by the part of the stack they run: "
+           "lower = up to the full-attention layer (a slice that holds no "
+           "prompt's last token), whole = every layer", labels=("stack",)),
     # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
     Metric("latent_positions_read_total", GAUGE,
            "cached latent rows the decode steps' attention covered (whole "

@@ -101,17 +101,25 @@ def make_linear_q5k(w: np.ndarray) -> dict:
     return prep_q5k(quant_q5_k(w.reshape(-1)), n_out, k_in)
 
 
-def padded_k(k_in: int, share: int = 4) -> int:
+def padded_k(k_in: int, share: int = 4, above_tile: bool = True) -> int:
     """The K a fused layout of a ``k_in``-wide matrix is stored at: the next
     multiple of the kernels' K tile where that adds at most a quarter
     (11008 -> 12288: the loader fills the last tile up with zero blocks and
     :func:`linear` the activations with zeros), else ``k_in`` itself (so
     narrow a matrix is no fused kernel's shape).  ``share``: the most the
     fill may add, as ``k_in / share`` (the grouped expert kernels take a
-    third: ops/pallas/experts.py ``padded_k``)."""
+    third: ops/pallas/experts.py ``padded_k``).  ``above_tile``: a K
+    between ONE tile and two is filled where that adds at most three fifths
+    (2560 -> 4096, 60 %: ``phi4flash``'s hidden size, which would else
+    leave every matrix that reads the stream to no fused kernel; 2304 stays,
+    a K under one tile, 1536 or 512, stays, and so does every matrix of the
+    other files served, none of which has a K between 2048 and 4096).  What the fill costs is
+    bytes read: ROADMAP B-I 11 has the tile geometry that divides 2560."""
     from .pallas.qmatmul import TK
 
     k_pad = -(-k_in // TK) * TK
+    if above_tile and TK < k_in < 2 * TK and 5 * (k_pad - k_in) <= 3 * k_in:
+        return k_pad
     return k_pad if share * (k_pad - k_in) <= k_in else k_in
 
 

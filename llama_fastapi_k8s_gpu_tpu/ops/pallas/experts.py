@@ -115,7 +115,7 @@ def padded_k(k_in: int) -> int:
     ``ops.linear.padded_k`` with a third for its quarter."""
     from ..linear import padded_k as dense_padded_k
 
-    return dense_padded_k(k_in, share=3)
+    return dense_padded_k(k_in, share=3, above_tile=False)
 
 
 def fold_factor(k_in: int) -> int:

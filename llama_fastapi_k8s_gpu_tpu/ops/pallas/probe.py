@@ -408,6 +408,7 @@ from ...obs.devtime import register_program  # noqa: E402
 
 register_program("probe_flash_attention", site="ops.pallas.probe")
 register_program("probe_lin_state", site="ops.pallas.probe")
+register_program("probe_ssm_scan", site="ops.pallas.probe")
 register_program("probe_latent_decode", site="ops.pallas.probe")
 register_program("probe_latent_prefill", site="ops.pallas.probe")
 register_program("_latent_decode", site="ops.pallas.probe")
@@ -440,3 +441,32 @@ def probe_lin_state() -> str | None:
         return None
     except Exception as e:  # noqa: BLE001
         return _err(e)
+
+
+@_once
+def probe_ssm_scan() -> str | None:
+    """Compile + run the selective scan of a prefill slice
+    (ops/pallas/ssmscan.py) at the published channel tile (two tiles of
+    1024 channels, 16 states; one tile of 128 and 4 states in interpret
+    mode) over two time chunks, into the second layer of a leaf of two.  A
+    failure leaves a ``phi4flash`` file's slices to the plain ``lax.scan``
+    (``models/phi4flash.py selective_scan``); the ring's kernels stay."""
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .ssmscan import TIME_CHUNK, ssm_scan
+
+        itp = use_interpret()
+        C, N, S = (128, 4, 16) if itp else (2048, 16, 2 * TIME_CHUNK)
+        x = jnp.ones((S, C), jnp.float32)
+        state = jnp.zeros((2, N, C // 128, 128), jnp.float32)
+        y, new = jax.jit(lambda x, s: ssm_scan(
+            x, 0.01 * x, x[:, :N], x[:, :N], -jnp.ones((N, C)), x[0], s,
+            jnp.int32(1), jnp.bool_(False), interpret=itp))(x, state)
+        float(y.sum()) + float(new.sum())
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)
+

@@ -252,6 +252,24 @@ def dequant_ref6(w: dict) -> jax.Array:
     return (eff * (q6 - 32.0)).reshape(N, kt * TK)
 
 
+def dequant_rows6(w: dict, rows: jax.Array, k_in: int) -> jax.Array:
+    """Rows ``rows`` of a split-layout Q6_K matrix, dequantized, in the
+    FILE's column order: (len(rows), ``k_in``) f32, the weights the kernels
+    multiply by (:func:`dequant_ref6` on the gathered rows' planes, its
+    columns put back in order, the zero fill past ``k_in`` cut).  What an
+    embedding lookup of a tied Q6_K head reads (models/phi4flash.py): the
+    rows it gathers, not a second table."""
+    if "q4" not in w:
+        raise ValueError("dequant_rows6 reads the split layout (q4, q2, sm6)")
+    p = dequant_ref6({"q4": jnp.take(w["q4"], rows, axis=0),
+                      "q2": jnp.take(w["q2"], rows, axis=0),
+                      "sm6": jnp.take(w["sm6"], rows, axis=1)})
+    S, K = p.shape
+    # permute_x6's inverse: column e * 128 + blk * 16 + sub of a tile
+    x = p.reshape(S, K // TK, 16, 8, 16).transpose(0, 1, 3, 4, 2)
+    return x.reshape(S, K)[:, :k_in]
+
+
 # ---------------------------------------------------------------------------
 # kernel
 # ---------------------------------------------------------------------------

@@ -784,6 +784,132 @@ def write_tiny_lfm2_gguf(path: str, cfg: ModelConfig = TINY_LFM2_CFG,
     return cfg
 
 
+#: a tiny ``phi4flash`` file (models/phi4flash.py) with every layer kind:
+#: two (ssm, window) pairs, the (ssm, full) pair, two (gmu, cross) pairs;
+#: 4 heads on 2 KV heads of 64 (one pair of each), a window of 8 positions
+#: in 16 slots, 512 channels of 4 states, 4 taps, the head tied
+TINY_PHI4FLASH_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=10, n_heads=4, n_kv_heads=2,
+    ffn_dim=512, n_ctx=256, rms_eps=1e-5, head_width=64, sliding_window=8,
+    mixers=("ssm", "window") * 2 + ("ssm", "full") + ("gmu", "cross") * 2,
+    ssm_d_inner=512, ssm_d_state=4, ssm_d_conv=4, ssm_dt_rank=16,
+    tie_embeddings=True,
+)
+
+#: the Q4_K_M mix on a ``phi4flash`` file, as the benchmark writes it
+PHI4FLASH_Q4KM_MIX = {
+    "token_embd": GGMLType.Q6_K, "ssm_in": GGMLType.Q4_K,
+    "ssm_x": GGMLType.Q4_K, "ssm_out": GGMLType.Q4_K,
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "gmu_in": GGMLType.Q4_K, "gmu_out": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+}
+
+
+def write_tiny_phi4flash_gguf(path: str,
+                              cfg: ModelConfig = TINY_PHI4FLASH_CFG,
+                              seed: int = 0, mix: dict | None = None,
+                              values: str = "stored") -> ModelConfig:
+    """Write a random-weight ``phi4flash`` GGUF (llama.cpp's Mamba tensor
+    names, LayerNorms with biases, projection biases, the differential
+    form's lambdas, no ``output.weight``) with the byte-level tokenizer of
+    :func:`write_tiny_llama_gguf`.  ``values``: ``stored`` writes ``ssm_a``
+    as A itself (-(1..d_state) and noise) and ``ssm_dt.bias`` for step
+    sizes of 1e-3..1e-1; ``init_offsets`` small random offsets, as the
+    benchmark's writer would (models/params.py ``ssm_values``)."""
+    tokens, types = byte_vocab_with_specials()
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens)})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**PHI4FLASH_Q4KM_MIX, **(mix or {})}
+    arch = "phi4flash"
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-phi4flash-test",
+                          arch=arch)
+    for key, value in (
+            ("mixer_types", ",".join(cfg.mixers)),
+            ("attention.key_length", cfg.head_dim),
+            ("ssm.conv_kernel", cfg.ssm_d_conv),
+            ("ssm.inner_size", cfg.ssm_d_inner),
+            ("ssm.state_size", cfg.ssm_d_state),
+            ("ssm.time_step_rank", cfg.ssm_dt_rank),
+            ("ssm.values", values)):
+        w.add_metadata(f"{arch}.{key}", value)
+    D, hd, F = cfg.dim, cfg.head_dim, cfg.ffn_dim
+    C, N, L, R = (cfg.ssm_d_inner, cfg.ssm_d_state, cfg.ssm_d_conv,
+                  cfg.ssm_dt_rank)
+    q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
+
+    def t(name, shape, gtype=GGMLType.F32, mul=1.0):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x, gtype)
+
+    def norm(name, n):   # near one, not one; a bias that is skipped shows
+        w.add_tensor(name + ".weight", 1.0 + 0.1 * rng.standard_normal(
+            n).astype(np.float32), GGMLType.F32)
+        w.add_tensor(name + ".bias", 0.1 * rng.standard_normal(n).astype(
+            np.float32), GGMLType.F32)
+
+    def attention(p, cross):
+        t(p + "attn_q.weight", (q_dim, D), mix["attn_q"])
+        t(p + "attn_q.bias", (q_dim,))
+        if not cross:
+            t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"])
+            t(p + "attn_k.bias", (kv_dim,))
+            t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+            t(p + "attn_v.bias", (kv_dim,))
+        for k in ("q1", "k1", "q2", "k2"):     # the published init: N(0, 0.1)
+            w.add_tensor(p + f"attn_lambda_{k}", 0.1 * rng.standard_normal(
+                hd).astype(np.float32), GGMLType.F32)
+        w.add_tensor(p + "attn_sub_norm.weight", 1.0 + 0.1 * rng
+                     .standard_normal(2 * hd).astype(np.float32), GGMLType.F32)
+        t(p + "attn_output.weight", (D, q_dim), mix["attn_output"])
+        t(p + "attn_output.bias", (D,))
+
+    t("token_embd.weight", (cfg.vocab_size, D), mix["token_embd"]
+      if (cfg.vocab_size * D) % 256 == 0 else GGMLType.F16)
+    for i, mixer in enumerate(cfg.mixers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm", D)
+        if mixer == "ssm":
+            t(p + "ssm_in.weight", (2 * C, D), mix["ssm_in"])
+            w.add_tensor(p + "ssm_conv1d.weight", (
+                rng.standard_normal((C, L)) * L ** -0.5).astype(np.float32),
+                GGMLType.F32)
+            t(p + "ssm_conv1d.bias", (C,))
+            t(p + "ssm_x.weight", (R + 2 * N, C), mix["ssm_x"]
+              if C % 256 == 0 else GGMLType.F16, (D / C) ** 0.5)
+            t(p + "ssm_dt.weight", (C, R), mul=(D / R) ** 0.5)
+            noise = 0.1 * rng.standard_normal((C, N)).astype(np.float32)
+            dt_noise = 0.1 * rng.standard_normal(C).astype(np.float32)
+            if values == "stored":
+                a = -np.exp(np.log(np.arange(1, N + 1, dtype=np.float32))[None]
+                            + noise)
+                dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), C))
+                b_dt = (dt0 + np.log(-np.expm1(-dt0))).astype(np.float32)
+            else:
+                a, b_dt = noise, dt_noise
+            w.add_tensor(p + "ssm_a", a.astype(np.float32), GGMLType.F32)
+            w.add_tensor(p + "ssm_dt.bias", b_dt, GGMLType.F32)
+            w.add_tensor(p + "ssm_d", (1.0 + 0.1 * rng.standard_normal(C)
+                                       ).astype(np.float32), GGMLType.F32)
+            t(p + "ssm_out.weight", (D, C), mix["ssm_out"], (D / C) ** 0.5)
+        elif mixer == "gmu":
+            t(p + "gmu_in.weight", (C, D), mix["gmu_in"])
+            t(p + "gmu_out.weight", (D, C), mix["gmu_out"], (D / C) ** 0.5)
+        else:
+            attention(p, mixer == "cross")
+        norm(p + "ffn_norm", D)
+        t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+        t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+        t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+    norm("output_norm", D)
+    w.write()
+    return cfg
+
+
 def synth_bpe_vocab(n_merges: int = 280_000, seed: int = 0,
                     ) -> tuple[list[str], list[str], list[int]]:
     """Deterministic Llama-3-*scale* BPE vocab: 256 byte tokens + specials +

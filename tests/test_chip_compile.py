@@ -1607,3 +1607,138 @@ def test_shortconv_stack_compiles_with_no_ring_sized_copy(
                   sliced.memory_analysis().temp_size_in_bytes)
             assert sliced.memory_analysis().temp_size_in_bytes \
                 < 1024 * 2 ** 20 * rows // 256
+
+
+# Phi-4-mini-flash-reasoning whole (benchmarks/configs/phi4-mini-flash-3.8b-
+# q4km-16lane.json: 32 layers, hidden 2560, 16 lanes of 32768 positions):
+# (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("phi4flash-serial", 0),
+                                        ("phi4flash-16lane", 16)])
+def test_phi4flash_stack_compiles_with_no_ring_sized_copy(
+        one_chip, monkeypatch, name, lanes):
+    """The decode chunk (0 and 16 lanes) and, for both slice widths, BOTH
+    prefill programs (the whole stack, and the one that stops after the
+    full-attention layer) of the ``phi4flash`` stack (models/phi4flash.py)
+    compile for the chip: the scan kernel on the slices, the decode kernel
+    in its ``wrap`` form on the window leaves and per lane on the ONE shared
+    leaf (eight calls a step), the flash kernel on rows of two 64-wide heads
+    side by side, every fused matmul at K 2560 filled up to 4096 and K 5120
+    to 6144, the tied Q6_K head on 200064 rows and the embedding rows
+    dequantized from its planes.  The compiler has put NO copy or transpose
+    of the shared leaf in the decode chunk."""
+    import dataclasses
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import phi4flash
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import (
+        init_cache, ring_write_impl)
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+    from tests.test_phi4flash import published_cfg
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg = dataclasses.replace(published_cfg(), attn_impl="pallas",
+                              ssm_scan_kernel=True)
+    D, V, F, C, N, R, hd = 2560, 200064, 10240, 5120, 16, 160, 64
+    Dk, Ck = 4096, 6144                 # as stored: ops.linear ``padded_k``
+    assert phi4flash.CACHE.decode_kernel_block(cfg) == 128
+    assert ring_write_impl(cfg) == "kernel"
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def norm(L):
+        return {"attn_norm": S(L, D, dtype=f32),
+                "attn_norm_b": S(L, D, dtype=f32)}
+
+    def lams(L):
+        return {**{f"lam_{k}": S(L, hd, dtype=f32)
+                   for k in ("q1", "k1", "q2", "k2")},
+                "sub_norm": S(L, 2 * hd, dtype=f32)}
+
+    head = _planes("q6k", V, Dk)
+    params = place({
+        "tok_emb": head, "output": head, "out_norm": S(D, dtype=f32),
+        "out_norm_b": S(D, dtype=f32),
+        "layers": {
+            "ssm": {**norm(9), "in_proj": _planes("q4k", 2 * C, Dk, 9),
+                    "out_proj": _planes("q4k", D, Ck, 9),
+                    "x_proj": {"w": S(9, R + 2 * N, C)},
+                    "conv": S(9, C, 4, dtype=f32),
+                    "conv_b": S(9, C, dtype=f32),
+                    "dt_proj": S(9, C, R, dtype=f32),
+                    "dt_b": S(9, C, dtype=f32), "a": S(9, N, C, dtype=f32),
+                    "d": S(9, C, dtype=f32)},
+            "attn": {**norm(9), **lams(9),
+                     "wq": _planes("q4k", D, Dk, 9),
+                     "wk": _planes("q4k", D // 2, Dk, 9),
+                     "wv": _planes("q6k", D // 2, Dk, 9),
+                     "wo": _planes("q4k", D, Dk, 9),
+                     "bq": S(9, D, dtype=f32), "bk": S(9, D // 2, dtype=f32),
+                     "bv": S(9, D // 2, dtype=f32), "bo": S(9, D, dtype=f32)},
+            "gmu": {**norm(7), "in_proj": _planes("q4k", C, Dk, 7),
+                    "out_proj": _planes("q4k", D, Ck, 7)},
+            "cross": {**norm(7), **lams(7),
+                      "wq": _planes("q4k", D, Dk, 7),
+                      "wo": _planes("q4k", D, Dk, 7),
+                      "bq": S(7, D, dtype=f32), "bo": S(7, D, dtype=f32)},
+            "ffn": {"ffn_norm": S(32, D, dtype=f32),
+                    "ffn_norm_b": S(32, D, dtype=f32),
+                    "w_gate": _planes("q4k", F, Dk, 32),
+                    "w_up": _planes("q4k", F, Dk, 32),
+                    "w_down": _planes("q6k", D, F, 32)}}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_attention_decode_window" in text
+    assert re.search(r"flash_attention_decode[^_]", text)
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*32768,128\]\S* "
+        r"(copy|transpose|dynamic-update-slice)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    print(name, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 2 ** 20
+    if not lanes:       # the admission slices into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert _slice_widths(cfg) == [256, 1024]
+        lower = phi4flash.CACHE.slice_cfg(cfg, False)
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            for scfg in (cfg, lower):       # the whole stack, and 18 of 32
+                sliced = prefill_chunk_jit.__wrapped__.lower(
+                    params, scfg, place(S(rows, dtype=i32)),
+                    place(S(dtype=i32)), place(S(dtype=i32)), cache).compile()
+                stext = sliced.as_text()
+                assert "ssm_scan" in stext and "flash_attention" in stext
+                print("slice", rows, "lower" if scfg.lower_only else "whole",
+                      "temporaries",
+                      sliced.memory_analysis().temp_size_in_bytes)
+                assert sliced.memory_analysis().temp_size_in_bytes \
+                    < 1536 * 2 ** 20 * rows // 256
+
