@@ -293,10 +293,19 @@ class Engine:
                         experts=bool(self.cfg.n_experts))
             with tl.phase("params") as loading:
                 sub: dict = {}
+                fill: dict = {}
                 self.params = load_params(gf, self.cfg, weight_format,
                                           fused_types=fused_types,
                                           phases_out=sub,
-                                          fused_experts=fused_experts)
+                                          fused_experts=fused_experts,
+                                          fill_out=fill)
+                #: per cent of the resident fused planes' bytes that are
+                #: zero fill (a K filled up to the kernels' tile, rows to
+                #: their N), by the loader's own sum; None where no plane
+                #: is fused.  /health ``engine.weight_fill_share``
+                self.weight_fill_share = round(
+                    100.0 * fill["fill_bytes"] / fill["plane_bytes"], 3) \
+                    if fill.get("plane_bytes") else None
                 loading.children = [Phase(k, t0, t1)
                                     for k, (t0, t1) in sub.items()]
             template = gf.metadata.get("tokenizer.chat_template")

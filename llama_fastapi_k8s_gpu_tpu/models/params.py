@@ -13,9 +13,11 @@ Formats (``ops.linear``):
 - ``q4k`` — fused serving: Q4_K / Q5_K / Q6_K / Q8_0 tensors stay in
   (nearly) their GGUF bit layouts in HBM (~5 / 6 / 7 / 9 bit/weight) and
   are dequantized in-VMEM by their fused Pallas matmuls (ops/pallas/
-  q*matmul.py; a K that their 2048-wide tile does not divide is filled up
-  with zero blocks where that adds at most a quarter: ``ops.linear.
-  padded_k``); anything else falls back to int8.  The v5e serving
+  q*matmul.py; a K that their 2048-wide tile does not divide ends in a
+  narrow tail tile of the Q4_K / Q6_K layouts where filling would add a
+  fifth or more, and is else filled up with zero blocks where that adds at
+  most a quarter: ``ops.linear.padded_k``); anything else falls back to
+  int8.  The v5e serving
   format: lowest decode HBM traffic at file fidelity.  Because per-layer
   tensors are stacked for ``lax.scan``, the choice is per tensor *name*:
   a name fuses only if every layer's tensor of that name shares one
@@ -119,7 +121,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 on_device: bool | None = None,
                 fused_types: frozenset | None = None,
                 phases_out: dict | None = None,
-                fused_experts: bool = True) -> dict:
+                fused_experts: bool = True,
+                fill_out: dict | None = None) -> dict:
     """Dequantize all tensors from ``gf`` into a stacked param pytree.
 
     ``on_device=True`` (default on TPU) routes quantized tensors through the
@@ -132,7 +135,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
     in ONE kernel degrades only that format's tensors to int8.
     ``fused_experts=False`` (the grouped expert kernels failed their probe)
     loads a routed block's experts dequantized.  ``phases_out`` receives
-    {"prep" | "head" | "stack": (start, end)} on ``time.time()``.
+    {"prep" | "head" | "stack": (start, end)} on ``time.time()``,
+    ``fill_out`` {"plane_bytes": the fused planes prepared, "fill_bytes":
+    those of them that are zero fill} (``/health`` ``weight_fill_share``).
     """
     if on_device is None:
         on_device = jax.default_backend() == "tpu"
@@ -155,10 +160,19 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         ``rows``: {name: output rows it is LOADED with} where the loader
         fills a matrix up with zero rows (:func:`padded_rows`)."""
         from ..gguf.constants import GGMLType
-        from ..ops.pallas.qmatmul import q4k_compatible
+        from ..ops.pallas.qmatmul import q4k_compatible, tail_of
 
         def fits_padded(n_out, k_in):
             return q4k_compatible(n_out, padded_k(k_in))
+
+        def tail_types(ts, types):
+            """``types`` where a name's K ends in a tail tile
+            (``ops/pallas/qmatmul.py tail_of``): the two families whose
+            layout has one."""
+            if any(tail_of(t.shape[0]) for t in ts):
+                return [t for t in types
+                        if t in (GGMLType.Q4_K, GGMLType.Q6_K)]
+            return types
 
         fusable = tuple(fused_types) if fused_types is not None \
             else (GGMLType.Q4_K, GGMLType.Q5_K, GGMLType.Q6_K, GGMLType.Q8_0)
@@ -183,7 +197,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 lambda n_out, k_in: experts_compatible(
                     n_out, experts_padded_k(k_in)),
                 [t for t in fusable if t in (GGMLType.Q4_K, GGMLType.Q6_K)]) \
-                if n.endswith("_exps") else (fits_padded, fusable)
+                if n.endswith("_exps") else (
+                    fits_padded, tail_types(ts, fusable))
             if not all(fits((rows or {}).get(n, t.shape[1]), t.shape[0])
                        for t in ts):
                 continue
@@ -197,7 +212,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 if target in allowed:
                     ok[n] = target
         t = gf.tensors.get("output.weight")
-        if t is not None and t.ggml_type in fusable \
+        if t is not None and t.ggml_type in tail_types([t], fusable) \
                 and fits_padded(*reversed(t.shape)):
             ok["output"] = t.ggml_type
         return ok
@@ -215,6 +230,17 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         if fmt == "q4k" else {}
 
     import time as _time
+
+    fill = {"plane_bytes": 0, "fill_bytes": 0}
+
+    def planes(w: dict, real: int, stored: int) -> dict:
+        """``w``, a fused layout's planes as prepared, counted: their bytes
+        and those of them that are zero fill (``stored - real`` of ``stored``
+        weights: a last K tile filled up, whole zero rows)."""
+        size = sum(a.nbytes for a in w.values())
+        fill["plane_bytes"] += size
+        fill["fill_bytes"] += size * (stored - real) // stored
+        return w
 
     # coarse load-phase attribution, logged at the end: prep (host packers /
     # codecs incl. the raw() mmap page-ins they trigger; with
@@ -245,8 +271,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
 
                 maker = {GGMLType.Q5_K: make_linear_q5k,
                          GGMLType.Q6_K: make_linear_q6k}[target]
-                return maker(np.pad(t.astype_f32(),
-                                    ((0, 0), (0, k_pad - k_in))))
+                return planes(maker(np.pad(t.astype_f32(),
+                                           ((0, 0), (0, k_pad - k_in)))),
+                              k_in, k_pad)
             prep = {GGMLType.Q4_K: prep_q4k, GGMLType.Q5_K: prep_q5k,
                     GGMLType.Q6_K: prep_q6k,
                     GGMLType.Q8_0: prep_q8_0}[target]
@@ -256,11 +283,14 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                 # a row's last K tile filled up with all-zero blocks (scale
                 # 0: every weight of them is 0), so that the file's own
                 # blocks serve the fused kernel (``linear`` pads the
-                # activations with zeros to match); whole zero rows alike
+                # activations with zeros to match); whole zero rows alike.
+                # (A K that ends in a tail tile is stored as it is:
+                # ``padded_k`` returns it, and ``prep`` lays the tail out)
                 raw = raw.reshape(n_out, -1)
                 raw = np.pad(raw, ((0, n_fill - n_out), (
                     0, raw.shape[1] * (k_pad - k_in) // k_in))).reshape(-1)
-            return prep(raw, n_fill, k_pad)
+            return planes(prep(raw, n_fill, k_pad), n_out * k_in,
+                          n_fill * k_pad)
         if on_device:
             w = _tensor_to_device(gf[name])
             if base_fmt == "int8":
@@ -296,7 +326,7 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
                     0, raw.shape[1] * (k_pad - k_in) // k_in))).reshape(-1)
             w = prep_experts(raw, n_exp, n_out, k_pad, target)
             if w is not None:
-                return w
+                return planes(w, k_in, k_pad)
         return {"w": as_bf16(t)}
 
     # LFKT_LOAD_OVERLAP=1: enqueue each layer's host→device transfer the
@@ -699,6 +729,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         # loads can't cross-report
         phases_out.update(prep=(t_prep, t_head), head=(t_head, t_stack),
                           stack=(t_stack, t_end))
+    if fill_out is not None:
+        fill_out.update(fill)
     # a looped stack's exit gate: one F32 row and its bias
     gate = {"exit_gate": {
         "w": norm("ut_exit_gate.weight").reshape(cfg.dim),

@@ -109,11 +109,13 @@ def _bind(so_path: str) -> ctypes.CDLL | None:
     try:
         lib.lfkt_prep_q4k.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int,
         ]
         lib.lfkt_prep_q4k.restype = ctypes.c_int
         lib.lfkt_prep_q6k.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
         ]
         lib.lfkt_prep_q6k.restype = ctypes.c_int
@@ -226,52 +228,72 @@ def _bf16_view(u16: np.ndarray) -> np.ndarray:
     return u16.view(ml_dtypes.bfloat16)
 
 
+def _ptr(a: np.ndarray | None):
+    return None if a is None else a.ctypes.data_as(ctypes.c_void_p)
+
+
 def native_prep_q4k(raw: np.ndarray, n_out: int, k_in: int,
                     n_threads: int = 0) -> dict | None:
     """Raw Q4_K block bytes -> {"qs" int8 (n,k/2), "sm" bf16 (k/2048,n,128)}
     numpy arrays in the fused-kernel layout (ops/pallas/qmatmul.py), packed
-    by the threaded C++ path; None -> caller uses the numpy packer."""
+    by the threaded C++ path; where ``k_in`` ends in a tail tile (``k_in %
+    2048``: 512 or 1024), "qs" / "sm" hold the whole tiles and "qs_t" (n,
+    tail/2) / "sm_t" (1,n,128) the tail; None -> caller uses the numpy
+    packer."""
     lib = get_lib()
     if lib is None or not hasattr(lib, "lfkt_prep_q4k"):
         return None
     src = np.ascontiguousarray(raw, dtype=np.uint8).reshape(-1)
     if src.size < (n_out * k_in // 256) * 144:
         return None
-    qs = np.empty((n_out, k_in // 2), dtype=np.int8)
-    sm = np.empty((k_in // 2048, n_out, 128), dtype=np.uint16)
+    tail = k_in % 2048
+    kw = k_in - tail
+    qs = np.empty((n_out, kw // 2), dtype=np.int8)
+    sm = np.empty((kw // 2048, n_out, 128), dtype=np.uint16)
+    qs_t = np.empty((n_out, tail // 2), dtype=np.int8) if tail else None
+    sm_t = np.empty((1, n_out, 128), dtype=np.uint16) if tail else None
     rc = lib.lfkt_prep_q4k(
-        src.ctypes.data_as(ctypes.c_void_p),
-        ctypes.c_int64(n_out), ctypes.c_int64(k_in),
-        qs.ctypes.data_as(ctypes.c_void_p), sm.ctypes.data_as(ctypes.c_void_p),
-        int(n_threads))
+        _ptr(src), ctypes.c_int64(n_out), ctypes.c_int64(k_in),
+        _ptr(qs), _ptr(sm), _ptr(qs_t), _ptr(sm_t), int(n_threads))
     if rc != 0:
         logger.warning("native prep_q4k rc=%d; numpy fallback", rc)
         return None
-    return {"qs": qs, "sm": _bf16_view(sm)}
+    out = {"qs": qs, "sm": _bf16_view(sm)}
+    if tail:
+        out.update(qs_t=qs_t, sm_t=_bf16_view(sm_t))
+    return out
 
 
 def native_prep_q6k(raw: np.ndarray, n_out: int, k_in: int,
                     n_threads: int = 0) -> dict | None:
     """Raw Q6_K block bytes -> {"q4", "q2", "sm6"} numpy arrays in the fused
-    layout (ops/pallas/q6matmul.py); None -> numpy packer."""
+    layout (ops/pallas/q6matmul.py), and {"q4_t", "q2_t", "sm6_t"} for a
+    tail tile as :func:`native_prep_q4k`; None -> numpy packer."""
     lib = get_lib()
     if lib is None or not hasattr(lib, "lfkt_prep_q6k"):
         return None
     src = np.ascontiguousarray(raw, dtype=np.uint8).reshape(-1)
     if src.size < (n_out * k_in // 256) * 210:
         return None
-    q4 = np.empty((n_out, k_in // 2), dtype=np.int8)
-    q2 = np.empty((n_out, k_in // 4), dtype=np.int8)
-    sm6 = np.empty((k_in // 2048, n_out, 128), dtype=np.uint16)
+    tail = k_in % 2048
+    kw = k_in - tail
+    q4 = np.empty((n_out, kw // 2), dtype=np.int8)
+    q2 = np.empty((n_out, kw // 4), dtype=np.int8)
+    sm6 = np.empty((kw // 2048, n_out, 128), dtype=np.uint16)
+    q4_t = np.empty((n_out, tail // 2), dtype=np.int8) if tail else None
+    q2_t = np.empty((n_out, tail // 4), dtype=np.int8) if tail else None
+    sm6_t = np.empty((1, n_out, 128), dtype=np.uint16) if tail else None
     rc = lib.lfkt_prep_q6k(
-        src.ctypes.data_as(ctypes.c_void_p),
-        ctypes.c_int64(n_out), ctypes.c_int64(k_in),
-        q4.ctypes.data_as(ctypes.c_void_p), q2.ctypes.data_as(ctypes.c_void_p),
-        sm6.ctypes.data_as(ctypes.c_void_p), int(n_threads))
+        _ptr(src), ctypes.c_int64(n_out), ctypes.c_int64(k_in),
+        _ptr(q4), _ptr(q2), _ptr(sm6), _ptr(q4_t), _ptr(q2_t), _ptr(sm6_t),
+        int(n_threads))
     if rc != 0:
         logger.warning("native prep_q6k rc=%d; numpy fallback", rc)
         return None
-    return {"q4": q4, "q2": q2, "sm6": _bf16_view(sm6)}
+    out = {"q4": q4, "q2": q2, "sm6": _bf16_view(sm6)}
+    if tail:
+        out.update(q4_t=q4_t, q2_t=q2_t, sm6_t=_bf16_view(sm6_t))
+    return out
 
 
 def native_prep_q5k(raw: np.ndarray, n_out: int, k_in: int,

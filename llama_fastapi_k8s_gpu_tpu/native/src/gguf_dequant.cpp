@@ -266,98 +266,130 @@ inline uint16_t bf16_rne(float f) {
 
 constexpr int64_t TKQ = 2048;  // K elements per kernel tile (= 8 super-blocks)
 
-// Q4_K: src blocks (row-major, 144 B each) -> qs (n, k/2) int8 + sm
-// (k/2048, n, 128) bf16.  Byte b = e*64 + s of a tile packs sub-block s's
-// elements e (lo) and e+16 (hi) as (hi-8)*16 + lo.
-void prep_q4k_row(const uint8_t* src, int64_t n_out, int64_t k_in, int64_t row,
-                  int8_t* qs, uint16_t* sm) {
-  const int64_t nb = k_in / QK_K;
-  const int64_t kt = k_in / TKQ;
-  const uint8_t* rb = src + row * nb * 144;
-  int8_t* qrow = qs + row * (k_in / 2);
-  for (int64_t t = 0; t < kt; t++) {
-    uint16_t* smt = sm + (t * n_out + row) * 128;
-    int8_t* qt = qrow + t * (TKQ / 2);
-    for (int sb = 0; sb < 8; sb++) {
-      const uint8_t* blk = rb + (t * 8 + sb) * 144;
-      const float d = f16(blk);
-      const float dmin = f16(blk + 2);
-      uint8_t sc[8], mn[8];
-      scale_min_k4(blk + 4, sc, mn);
-      for (int j = 0; j < 8; j++) {
-        smt[sb * 8 + j] = bf16_rne(d * static_cast<float>(sc[j]));
-        smt[64 + sb * 8 + j] = bf16_rne(dmin * static_cast<float>(mn[j]));
+// A K that ends in a TAIL tile (ops/pallas/qmatmul.py tail_of: 512 or 1024
+// columns after the whole 2048 tiles) stores that tile in planes of its own,
+// the same layout at its own period: `nsb` super-blocks a tile (8 whole, 2 or
+// 4 a tail), columns element-major over the tile's own sub-blocks, its scales
+// tiled up to the plane's lanes.
+
+// One Q4_K tile of `nsb` super-blocks at `blk0` -> qt (nsb*128 bytes: byte
+// e*S + s, S = nsb*8, packs sub-block s's elements e (lo) and e+16 (hi) as
+// (hi-8)*16 + lo) + smt (128 bf16: the S scales tiled to 64 lanes, then the
+// S mins).
+void prep_q4k_tile(const uint8_t* blk0, int nsb, int8_t* qt, uint16_t* smt) {
+  const int S = nsb * 8;
+  for (int sb = 0; sb < nsb; sb++) {
+    const uint8_t* blk = blk0 + sb * 144;
+    const float d = f16(blk);
+    const float dmin = f16(blk + 2);
+    uint8_t sc[8], mn[8];
+    scale_min_k4(blk + 4, sc, mn);
+    for (int j = 0; j < 8; j++) {
+      const uint16_t es = bf16_rne(d * static_cast<float>(sc[j]));
+      const uint16_t em = bf16_rne(dmin * static_cast<float>(mn[j]));
+      for (int l = sb * 8 + j; l < 64; l += S) {
+        smt[l] = es;
+        smt[64 + l] = em;
       }
-      const uint8_t* fq = blk + 16;  // 128 nibble bytes: g*32+i
-      for (int subp = 0; subp < 4; subp++) {     // sub-block pairs 2g/2g+1
-        const uint8_t* q = fq + 32 * subp;
-        const int s_even = sb * 8 + 2 * subp;
-        const int s_odd = s_even + 1;
-        for (int e = 0; e < 16; e++) {
-          const int lo_e = q[e] & 0x0F, lo_h = q[e + 16] & 0x0F;
-          const int hi_e = q[e] >> 4, hi_h = q[e + 16] >> 4;
-          // byte index e*64 + s pairs nib(s,e) with nib(s,e+16)
-          qt[e * 64 + s_even] =
-              static_cast<int8_t>(((lo_h - 8) << 4) + lo_e);
-          qt[e * 64 + s_odd] =
-              static_cast<int8_t>(((hi_h - 8) << 4) + hi_e);
-        }
+    }
+    const uint8_t* fq = blk + 16;  // 128 nibble bytes: g*32+i
+    for (int subp = 0; subp < 4; subp++) {     // sub-block pairs 2g/2g+1
+      const uint8_t* q = fq + 32 * subp;
+      const int s_even = sb * 8 + 2 * subp;
+      const int s_odd = s_even + 1;
+      for (int e = 0; e < 16; e++) {
+        const int lo_e = q[e] & 0x0F, lo_h = q[e + 16] & 0x0F;
+        const int hi_e = q[e] >> 4, hi_h = q[e + 16] >> 4;
+        // byte index e*S + s pairs nib(s,e) with nib(s,e+16)
+        qt[e * S + s_even] = static_cast<int8_t>(((lo_h - 8) << 4) + lo_e);
+        qt[e * S + s_odd] = static_cast<int8_t>(((hi_h - 8) << 4) + hi_e);
       }
     }
   }
 }
 
-// Q6_K: src blocks (210 B) -> q4 (n, k/2) int8 + q2 (n, k/4) int8 + sm6
-// (k/2048, n, 128) bf16.  Tile columns c = e*128 + s (s = sub-block of 16);
-// q4 byte b = e*128+s (e<8) packs nib(s,e),nib(s,e+8); q2 byte b = e'*128+s
-// (e'<4) packs crumbs of elements e', e'+4, e'+8, e'+12.
-void prep_q6k_row(const uint8_t* src, int64_t n_out, int64_t k_in, int64_t row,
-                  int8_t* q4, int8_t* q2, uint16_t* sm6) {
+// Q4_K: src blocks (row-major, 144 B each) -> qs (n, kw/2) int8 + sm
+// (kw/2048, n, 128) bf16 over the kw columns in whole tiles, and the tail's
+// qs_t (n, tail/2) + sm_t (1, n, 128).
+void prep_q4k_row(const uint8_t* src, int64_t n_out, int64_t k_in, int64_t row,
+                  int8_t* qs, uint16_t* sm, int8_t* qs_t, uint16_t* sm_t) {
   const int64_t nb = k_in / QK_K;
   const int64_t kt = k_in / TKQ;
-  const uint8_t* rb = src + row * nb * 210;
-  int8_t* q4row = q4 + row * (k_in / 2);
-  int8_t* q2row = q2 + row * (k_in / 4);
+  const int64_t tail = k_in % TKQ;
+  const uint8_t* rb = src + row * nb * 144;
+  int8_t* qrow = qs + row * (kt * TKQ / 2);
+  for (int64_t t = 0; t < kt; t++)
+    prep_q4k_tile(rb + t * 8 * 144, 8, qrow + t * (TKQ / 2),
+                  sm + (t * n_out + row) * 128);
+  if (tail)
+    prep_q4k_tile(rb + kt * 8 * 144, static_cast<int>(tail / QK_K),
+                  qs_t + row * (tail / 2), sm_t + row * 128);
+}
+
+// One Q6_K tile of `nsb` super-blocks -> q4t (nsb*128 bytes), q2t (nsb*64),
+// smt (128 bf16: the S = nsb*16 sub-scales tiled to 128 lanes).  Tile
+// columns c = e*S + s (s = sub-block of 16); q4 byte b = e*S+s (e<8) packs
+// nib(s,e),nib(s,e+8); q2 byte b = e'*S+s (e'<4) packs crumbs of elements
+// e', e'+4, e'+8, e'+12.
+void prep_q6k_tile(const uint8_t* blk0, int nsb, int8_t* q4t, int8_t* q2t,
+                   uint16_t* smt) {
+  const int S = nsb * 16;
   uint8_t q6[256];
-  for (int64_t t = 0; t < kt; t++) {
-    uint16_t* smt = sm6 + (t * n_out + row) * 128;
-    int8_t* q4t = q4row + t * (TKQ / 2);
-    int8_t* q2t = q2row + t * (TKQ / 4);
-    for (int sb = 0; sb < 8; sb++) {
-      const uint8_t* blk = rb + (t * 8 + sb) * 210;
-      const int8_t* scales = reinterpret_cast<const int8_t*>(blk + 192);
-      const float d = f16(blk + 208);
-      for (int half = 0; half < 2; half++) {
-        const uint8_t* ql = blk + 64 * half;
-        const uint8_t* qh = blk + 128 + 32 * half;
-        uint8_t* q6h = q6 + 128 * half;
-        for (int l = 0; l < 128; l++) {
-          const int low = (l < 64) ? (ql[l] & 0x0F) : (ql[l - 64] >> 4);
-          const int high = (qh[l & 31] >> (2 * (l >> 5))) & 3;
-          q6h[l] = static_cast<uint8_t>(low | (high << 4));
-        }
+  for (int sb = 0; sb < nsb; sb++) {
+    const uint8_t* blk = blk0 + sb * 210;
+    const int8_t* scales = reinterpret_cast<const int8_t*>(blk + 192);
+    const float d = f16(blk + 208);
+    for (int half = 0; half < 2; half++) {
+      const uint8_t* ql = blk + 64 * half;
+      const uint8_t* qh = blk + 128 + 32 * half;
+      uint8_t* q6h = q6 + 128 * half;
+      for (int l = 0; l < 128; l++) {
+        const int low = (l < 64) ? (ql[l] & 0x0F) : (ql[l - 64] >> 4);
+        const int high = (qh[l & 31] >> (2 * (l >> 5))) & 3;
+        q6h[l] = static_cast<uint8_t>(low | (high << 4));
       }
-      for (int sub = 0; sub < 16; sub++) {
-        const int s = sb * 16 + sub;  // tile-local sub-block column
-        smt[s] = bf16_rne(d * static_cast<float>(scales[sub]));
-        const uint8_t* qe = q6 + sub * 16;  // elements of this sub-block
-        for (int e = 0; e < 8; e++) {
-          const int nib_lo = qe[e] & 0x0F;
-          const int nib_hi = qe[e + 8] & 0x0F;
-          q4t[e * 128 + s] =
-              static_cast<int8_t>(((nib_hi - 8) << 4) + nib_lo);
-        }
-        for (int ep = 0; ep < 4; ep++) {
-          const int c0 = qe[ep] >> 4;
-          const int c1 = qe[ep + 4] >> 4;
-          const int c2 = qe[ep + 8] >> 4;
-          const int c3 = qe[ep + 12] >> 4;
-          q2t[ep * 128 + s] = static_cast<int8_t>(
-              (((c3 * 4 + c2) * 4 + c1) * 4 + c0) - 128);
-        }
+    }
+    for (int sub = 0; sub < 16; sub++) {
+      const int s = sb * 16 + sub;  // tile-local sub-block column
+      const uint16_t eff = bf16_rne(d * static_cast<float>(scales[sub]));
+      for (int l = s; l < 128; l += S) smt[l] = eff;
+      const uint8_t* qe = q6 + sub * 16;  // elements of this sub-block
+      for (int e = 0; e < 8; e++) {
+        const int nib_lo = qe[e] & 0x0F;
+        const int nib_hi = qe[e + 8] & 0x0F;
+        q4t[e * S + s] = static_cast<int8_t>(((nib_hi - 8) << 4) + nib_lo);
+      }
+      for (int ep = 0; ep < 4; ep++) {
+        const int c0 = qe[ep] >> 4;
+        const int c1 = qe[ep + 4] >> 4;
+        const int c2 = qe[ep + 8] >> 4;
+        const int c3 = qe[ep + 12] >> 4;
+        q2t[ep * S + s] = static_cast<int8_t>(
+            (((c3 * 4 + c2) * 4 + c1) * 4 + c0) - 128);
       }
     }
   }
+}
+
+// Q6_K: src blocks (210 B) -> q4 (n, kw/2) int8 + q2 (n, kw/4) int8 + sm6
+// (kw/2048, n, 128) bf16 over the whole tiles, and the tail's q4_t (n,
+// tail/2) + q2_t (n, tail/4) + sm6_t (1, n, 128).
+void prep_q6k_row(const uint8_t* src, int64_t n_out, int64_t k_in, int64_t row,
+                  int8_t* q4, int8_t* q2, uint16_t* sm6, int8_t* q4_t,
+                  int8_t* q2_t, uint16_t* sm6_t) {
+  const int64_t nb = k_in / QK_K;
+  const int64_t kt = k_in / TKQ;
+  const int64_t tail = k_in % TKQ;
+  const uint8_t* rb = src + row * nb * 210;
+  int8_t* q4row = q4 + row * (kt * TKQ / 2);
+  int8_t* q2row = q2 + row * (kt * TKQ / 4);
+  for (int64_t t = 0; t < kt; t++)
+    prep_q6k_tile(rb + t * 8 * 210, 8, q4row + t * (TKQ / 2),
+                  q2row + t * (TKQ / 4), sm6 + (t * n_out + row) * 128);
+  if (tail)
+    prep_q6k_tile(rb + kt * 8 * 210, static_cast<int>(tail / QK_K),
+                  q4_t + row * (tail / 2), q2_t + row * (tail / 4),
+                  sm6_t + row * 128);
 }
 
 // Q5_K: src blocks (176 B) -> q5s (n, k/2) int8 (Q4_K qs layout of the low
@@ -432,6 +464,15 @@ void prep_q8_0_row(const uint8_t* src, int64_t n_out, int64_t k_in,
   }
 }
 
+// Whether the packers lay `k_in` out: whole 2048 tiles, or at least one and
+// a tail tile of 512 or 1024 columns whose planes (`all_given`) are there
+// (which K is STORED so is ops/pallas/qmatmul.py tail_of's to say).
+bool tail_ok(int64_t k_in, bool all_given) {
+  const int64_t tail = k_in % TKQ;
+  return tail == 0 ||
+         (k_in > TKQ && (tail == 512 || tail == 1024) && all_given);
+}
+
 }  // namespace
 
 extern "C" {
@@ -443,30 +484,36 @@ int lfkt_supported(int ggml_type) {
              : 0;
 }
 
-// Fused-layout packers.  rc: 0 ok, -2 bad args.
+// Fused-layout packers.  rc: 0 ok, -2 bad args.  The `_t` planes are the
+// tail tile's (unused, and may be null, where 2048 divides k_in).
 int lfkt_prep_q4k(const uint8_t* src, int64_t n_out, int64_t k_in,
-                  int8_t* qs, uint16_t* sm, int n_threads) {
-  if (!src || !qs || !sm || n_out <= 0 || k_in <= 0 || k_in % TKQ != 0)
-    return -2;
-  if (n_threads <= 0)
-    n_threads = static_cast<int>(std::thread::hardware_concurrency());
-  if (n_threads <= 0) n_threads = 1;
-  run_threads(n_out, n_threads, [&](int64_t lo, int64_t hi) {
-    for (int64_t r = lo; r < hi; r++) prep_q4k_row(src, n_out, k_in, r, qs, sm);
-  });
-  return 0;
-}
-
-int lfkt_prep_q6k(const uint8_t* src, int64_t n_out, int64_t k_in,
-                  int8_t* q4, int8_t* q2, uint16_t* sm6, int n_threads) {
-  if (!src || !q4 || !q2 || !sm6 || n_out <= 0 || k_in <= 0 || k_in % TKQ != 0)
+                  int8_t* qs, uint16_t* sm, int8_t* qs_t, uint16_t* sm_t,
+                  int n_threads) {
+  if (!src || !qs || !sm || n_out <= 0 || k_in <= 0 ||
+      !tail_ok(k_in, qs_t && sm_t))
     return -2;
   if (n_threads <= 0)
     n_threads = static_cast<int>(std::thread::hardware_concurrency());
   if (n_threads <= 0) n_threads = 1;
   run_threads(n_out, n_threads, [&](int64_t lo, int64_t hi) {
     for (int64_t r = lo; r < hi; r++)
-      prep_q6k_row(src, n_out, k_in, r, q4, q2, sm6);
+      prep_q4k_row(src, n_out, k_in, r, qs, sm, qs_t, sm_t);
+  });
+  return 0;
+}
+
+int lfkt_prep_q6k(const uint8_t* src, int64_t n_out, int64_t k_in,
+                  int8_t* q4, int8_t* q2, uint16_t* sm6, int8_t* q4_t,
+                  int8_t* q2_t, uint16_t* sm6_t, int n_threads) {
+  if (!src || !q4 || !q2 || !sm6 || n_out <= 0 || k_in <= 0 ||
+      !tail_ok(k_in, q4_t && q2_t && sm6_t))
+    return -2;
+  if (n_threads <= 0)
+    n_threads = static_cast<int>(std::thread::hardware_concurrency());
+  if (n_threads <= 0) n_threads = 1;
+  run_threads(n_out, n_threads, [&](int64_t lo, int64_t hi) {
+    for (int64_t r = lo; r < hi; r++)
+      prep_q6k_row(src, n_out, k_in, r, q4, q2, sm6, q4_t, q2_t, sm6_t);
   });
   return 0;
 }

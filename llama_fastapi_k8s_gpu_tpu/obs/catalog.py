@@ -326,6 +326,14 @@ METRICS: dict[str, Metric] = _register(
            "scope=pod on replica scrapes, scope=fleet when the router "
            "evaluates the catalog over federated histograms",
            labels=("slo", "window", "scope")),
+    Metric("weight_fill_share", GAUGE,
+           "per cent of the resident fused weight planes' bytes that are "
+           "zero fill: a K the kernels' 2048 tile does not divide and that "
+           "ends in no tail tile is filled up with zero blocks, which every "
+           "step reads (ops/linear.py padded_k, ops/pallas/experts.py "
+           "padded_k); the loader's own sum over the planes it prepared, "
+           "as /health engine.weight_fill_share; absent where no plane is "
+           "fused"),
     # -- routed layers (engine/expert_counters.py; a file with experts) ----
     Metric("expert_layer_steps_total", GAUGE,
            "routed feed-forward layers run by decode chunks: (layer, step) "

@@ -101,26 +101,31 @@ def make_linear_q5k(w: np.ndarray) -> dict:
     return prep_q5k(quant_q5_k(w.reshape(-1)), n_out, k_in)
 
 
-def padded_k(k_in: int, share: int = 4, above_tile: bool = True) -> int:
-    """The K a fused layout of a ``k_in``-wide matrix is stored at: the next
-    multiple of the kernels' K tile where that adds at most a quarter
-    (11008 -> 12288: the loader fills the last tile up with zero blocks and
-    :func:`linear` the activations with zeros), else ``k_in`` itself (so
-    narrow a matrix is no fused kernel's shape).  ``share``: the most the
-    fill may add, as ``k_in / share`` (the grouped expert kernels take a
-    third: ops/pallas/experts.py ``padded_k``).  ``above_tile``: a K
-    between ONE tile and two is filled where that adds at most three fifths
-    (2560 -> 4096, 60 %: ``phi4flash``'s hidden size, which would else
-    leave every matrix that reads the stream to no fused kernel; 2304 stays,
-    a K under one tile, 1536 or 512, stays, and so does every matrix of the
-    other files served, none of which has a K between 2048 and 4096).  What the fill costs is
-    bytes read: ROADMAP B-I 11 has the tile geometry that divides 2560."""
+def filled_k(k_in: int, share: int) -> int:
+    """``k_in`` filled up to the next multiple of the kernels' K tile where
+    that adds at most ``k_in / share``, else ``k_in`` itself."""
     from .pallas.qmatmul import TK
 
     k_pad = -(-k_in // TK) * TK
-    if above_tile and TK < k_in < 2 * TK and 5 * (k_pad - k_in) <= 3 * k_in:
-        return k_pad
     return k_pad if share * (k_pad - k_in) <= k_in else k_in
+
+
+def padded_k(k_in: int) -> int:
+    """The K a fused layout of a ``k_in``-wide dense matrix is stored at, by
+    shape alone.  A multiple of the kernels' K tile: as it is.  A K that
+    ends in a TAIL tile (``ops/pallas/qmatmul.py tail_of``: above one tile,
+    a multiple of 512, where filling would add a fifth of K or more; 2560,
+    3072, 5120): as it is too, its last 512 or 1024 columns a narrow tile of
+    the same layout at its own period, nothing filled.  Else the next
+    multiple of the tile where that adds at most a quarter (7168 -> 8192,
+    11008 -> 12288: the loader fills the last tile up with zero blocks and
+    :func:`linear` the activations with zeros), else ``k_in`` itself (2304,
+    1536, 512: no fused kernel's shape).  The grouped expert kernels keep a
+    rule of their own (ops/pallas/experts.py ``padded_k``: a third, no
+    tail)."""
+    from .pallas.qmatmul import tail_of
+
+    return k_in if tail_of(k_in) else filled_k(k_in, 4)
 
 
 def _pad_k(x: jax.Array) -> jax.Array:

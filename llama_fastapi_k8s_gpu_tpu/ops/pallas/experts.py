@@ -112,10 +112,11 @@ FEW_VMEM = 64 * 2 ** 20  # a few-row call's limit: its output block is all N
 
 def padded_k(k_in: int) -> int:
     """The K an expert matrix of ``k_in`` is stored at (module docstring):
-    ``ops.linear.padded_k`` with a third for its quarter."""
-    from ..linear import padded_k as dense_padded_k
+    ``ops.linear.filled_k`` with a third where the dense matrices take a
+    quarter, and no tail tile (``ops.linear.padded_k``)."""
+    from ..linear import filled_k
 
-    return dense_padded_k(k_in, share=3, above_tile=False)
+    return filled_k(k_in, 3)
 
 
 def fold_factor(k_in: int) -> int:

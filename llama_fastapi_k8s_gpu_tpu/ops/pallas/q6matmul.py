@@ -35,8 +35,22 @@ Layout contract (:func:`prep_q6k`):
 - ``sm6`` (K/2048, N, 128) bf16 — the 128 effective sub-scales ``d·sc`` of
   the tile, block-major.
 
-Shape requirements: ``K % 2048 == 0``, ``N % 128`` == 0 — same classes as
-the Q4_K kernel; ineligible tensors fall back to int8 (models/params.py).
+Shape requirements: ``N % 128`` == 0 and a K of whole 2048 tiles, or of
+whole tiles and one TAIL tile — same classes as the Q4_K kernel
+(``qmatmul.tail_of``); ineligible tensors fall back to int8
+(models/params.py).
+
+A tail of ``T`` columns (512 or 1024; PR 63) is the same layout at its own
+period ``S = T/16`` sub-blocks (32 or 64), in planes beside the whole
+tiles': ``q4_t`` (N, T/2) — byte ``b`` the low nibbles of columns ``b`` and
+``b + T/2``, column ``c = e·S + s``; ``q2_t`` (N, T/4) — byte ``b`` the
+crumbs of columns ``b + j·T/4``; ``sm6_t`` (1, N, 128) — the S sub-scales
+tiled ``128/S`` times.  The activations' tail gains its own 256 correction
+columns.  The float body runs it at width T on the last K step
+(``qmatmul.tail_kernel``); the head's integer body joins the tail's
+quarters to the 512 columns a dot takes (one dot for 512, two for 1024,
+where a whole tile has four); :func:`dequant_rows6` puts its columns back
+in the file's order.
 
 Two bodies build that plane.  The stacked calls (a layer's ``w_down`` /
 ``wv``) run :func:`_q6k_matmul_kernel`, the float form above, and are what
@@ -61,11 +75,13 @@ from ...gguf.constants import GGML_BLOCK_SIZES, GGMLType, QK_K
 from ...obs.devtime import register_program
 from ...gguf.quants import _garbage_tolerant
 from .qmatmul import (
+    _augment_tile,
     batched_rows,
     _env_variant,
     _interpret,
     _lane_repeat,
     _NIB,
+    _permute_tiles,
     _pick_tn,
     kernel_name,
     MANYROW_MAX,
@@ -78,10 +94,13 @@ from .qmatmul import (
     _spec_axis,
     stacked_pallas_call,
     stacked_partitioned,
+    tail_kernel,
+    tail_of,
     TK,
     TM,
     tn_prefs,
     _tn_prefs_for,
+    _with_tail,
 )
 
 # first entry = the env-knob default (ops/pallas/qmatmul.py::_env_variant).
@@ -138,35 +157,15 @@ def _combine_q6p(q4: np.ndarray, q2: np.ndarray, n_out: int,
     return (nib + (crumb << 4).astype(np.int8)).reshape(n_out, k_in)
 
 
-@_garbage_tolerant
-def prep_q6k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
-    """Raw Q6_K block bytes (row-major, ``n_out`` rows of ``k_in`` elements)
-    → the kernel layout dict: {"q4", "q2", "sm6"} (split layout) or
-    {"q6p", "sm6"} under ``LFKT_Q6K_KERNEL=pre`` (see Q6K_VARIANTS).
-
-    Dispatches to the threaded C++ packer (native/src/gguf_dequant.cpp,
-    bit-identical planes — tests/test_native.py) when available; the numpy
-    chain below is the reference implementation and the fallback."""
-    if not q6k_compatible(n_out, k_in):
-        raise ValueError(f"({n_out}, {k_in}) not fused-Q6_K compatible "
-                         f"(need K%{TK}==0, N%128==0)")
-    from ...native import native_prep_q6k
-
-    pre = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS) == "pre"
-    nat = native_prep_q6k(raw, n_out, k_in)
-    if nat is not None:
-        if pre:
-            return {"q6p": jnp.asarray(_combine_q6p(
-                        np.asarray(nat["q4"]), np.asarray(nat["q2"]),
-                        n_out, k_in)),
-                    "sm6": jnp.asarray(nat["sm6"])}
-        return {"q4": jnp.asarray(nat["q4"]), "q2": jnp.asarray(nat["q2"]),
-                "sm6": jnp.asarray(nat["sm6"])}
-    bs = GGML_BLOCK_SIZES[GGMLType.Q6_K][1]           # 210
-    nb = k_in // QK_K
-    kt = k_in // TK
-    blocks = np.ascontiguousarray(raw, dtype=np.uint8)[: n_out * nb * bs]
-    blocks = blocks.reshape(n_out, nb, bs)
+def _q6k_tile_planes(blocks: np.ndarray, width: int):
+    """(N, nb, 210) Q6_K super-blocks -> (q4 (N, nb * 128), q2 (N, nb * 64)
+    int8, sm6 (tiles, N, 128) float32) in tiles of ``width`` columns (a
+    multiple of 256 that divides 2048): the layout contract above at
+    ``width`` = :data:`TK`, a tail's at its own width, where the tile's
+    ``width / 16`` sub-scales are tiled up to the plane's 128 lanes."""
+    n_out, nb, _ = blocks.shape
+    kt = nb * QK_K // width
+    subs = width // 16                                # sub-blocks a tile
     ql = blocks[..., 0:128].reshape(n_out, nb, 2, 64)
     qh = blocks[..., 128:192].reshape(n_out, nb, 2, 32)
     sc = blocks[..., 192:208].view(np.int8).astype(np.float32)  # (N, nb, 16)
@@ -183,34 +182,78 @@ def prep_q6k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
     q6 = (low | (hi << 4)).reshape(n_out, nb, 256)    # elem idx = sub*16 + e
 
     # element-major tile columns: Q[..., e, s], s = blk*16 + sub
-    Q = q6.reshape(n_out, kt, 8, 16, 16).transpose(0, 1, 4, 2, 3)
-    Q = np.ascontiguousarray(Q).reshape(n_out, kt, 16, _SUBS6)
+    Q = q6.reshape(n_out, kt, width // QK_K, 16, 16).transpose(0, 1, 4, 2, 3)
+    Q = np.ascontiguousarray(Q).reshape(n_out, kt, 16, subs)
     nib = Q & 0x0F
     crumb = Q >> 4                                    # ∈ [0, 4)
 
-    lo4 = nib[:, :, :8, :].reshape(n_out, kt, TK // 2)
-    hi4 = nib[:, :, 8:, :].reshape(n_out, kt, TK // 2)
+    lo4 = nib[:, :, :8, :].reshape(n_out, kt, width // 2)
+    hi4 = nib[:, :, 8:, :].reshape(n_out, kt, width // 2)
     v4 = ((hi4.astype(np.int16) - 8) << 4) + lo4
-    q4 = v4.astype(np.int8).reshape(n_out, k_in // 2)
+    q4 = v4.astype(np.int8).reshape(n_out, nb * (QK_K // 2))
 
-    cr = crumb.reshape(n_out, kt, 4, TK // 4).astype(np.int16)
+    cr = crumb.reshape(n_out, kt, 4, width // 4).astype(np.int16)
     v2 = (((cr[:, :, 3] * 4 + cr[:, :, 2]) * 4 + cr[:, :, 1]) * 4
           + cr[:, :, 0]) - 128
-    q2 = v2.astype(np.int8).reshape(n_out, k_in // 4)
+    q2 = v2.astype(np.int8).reshape(n_out, nb * (QK_K // 4))
 
     eff = d[..., None] * sc                           # (N, nb, 16)
-    sm6 = eff.reshape(n_out, kt, _SUBS6).transpose(1, 0, 2)
-    sm6 = jnp.asarray(np.ascontiguousarray(sm6), dtype=jnp.bfloat16)
+    sm6 = np.tile(eff.reshape(n_out, kt, subs), (1, 1, _SUBS6 // subs))
+    return q4, q2, np.ascontiguousarray(sm6.transpose(1, 0, 2))
+
+
+@_garbage_tolerant
+def prep_q6k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
+    """Raw Q6_K block bytes (row-major, ``n_out`` rows of ``k_in`` elements)
+    → the kernel layout dict: {"q4", "q2", "sm6"} (split layout), with
+    {"q4_t", "q2_t", "sm6_t"} beside them where ``k_in`` ends in a tail
+    (``qmatmul.tail_of``), or {"q6p", "sm6"} under ``LFKT_Q6K_KERNEL=pre``
+    (see Q6K_VARIANTS; a K with a tail keeps the split layout there).
+
+    Dispatches to the threaded C++ packer (native/src/gguf_dequant.cpp,
+    bit-identical planes — tests/test_native.py) when available; the numpy
+    chain below is the reference implementation and the fallback."""
+    if not q6k_compatible(n_out, k_in):
+        raise ValueError(f"({n_out}, {k_in}) not fused-Q6_K compatible "
+                         f"(need K%{TK}==0 or a tail, N%128==0)")
+    from ...native import native_prep_q6k
+
+    tail = tail_of(k_in)
+    pre = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS) == "pre" and not tail
+    nat = native_prep_q6k(raw, n_out, k_in)
+    if nat is not None:
+        if pre:
+            return {"q6p": jnp.asarray(_combine_q6p(
+                        np.asarray(nat["q4"]), np.asarray(nat["q2"]),
+                        n_out, k_in)),
+                    "sm6": jnp.asarray(nat["sm6"])}
+        return {key: jnp.asarray(a) for key, a in nat.items()}
+    bs = GGML_BLOCK_SIZES[GGMLType.Q6_K][1]           # 210
+    nb = k_in // QK_K
+    whole = (k_in - tail) // QK_K                     # blocks in whole tiles
+    blocks = np.ascontiguousarray(raw, dtype=np.uint8)[: n_out * nb * bs]
+    blocks = blocks.reshape(n_out, nb, bs)
+    q4, q2, sm6 = _q6k_tile_planes(blocks[:, :whole], TK)
+    sm6 = jnp.asarray(sm6, dtype=jnp.bfloat16)
     if pre:
         return {"q6p": jnp.asarray(_combine_q6p(q4, q2, n_out, k_in)),
                 "sm6": sm6}
-    return {"q4": jnp.asarray(q4), "q2": jnp.asarray(q2), "sm6": sm6}
+    w = {"q4": jnp.asarray(q4), "q2": jnp.asarray(q2), "sm6": sm6}
+    if tail:
+        q4, q2, sm6 = _q6k_tile_planes(blocks[:, whole:], tail)
+        w.update(q4_t=jnp.asarray(q4), q2_t=jnp.asarray(q2),
+                 sm6_t=jnp.asarray(sm6, dtype=jnp.bfloat16))
+    return w
 
 
 def permute_x6(x: jax.Array) -> jax.Array:
     """(..., K) → (..., K): element-major column order (column ``e·128+s`` ←
-    original element ``(s//16)·256 + (s%16)·16 + e``)."""
+    original element ``(s//16)·256 + (s%16)·16 + e``); a tail the same way
+    at its own width (column ``e·(width/16) + s``)."""
     K = x.shape[-1]
+    if tail_of(K):
+        return _with_tail(x, permute_x6,
+                          lambda t, width: _permute_tiles(t, width, 16))
     lead = x.shape[:-1]
     nl = len(lead)
     xb = x.reshape(*lead, K // TK, 8, 16, 16)         # [blk, sub, e]
@@ -221,8 +264,12 @@ def permute_x6(x: jax.Array) -> jax.Array:
 def augment_x6(xp: jax.Array) -> jax.Array:
     """Permuted activations (B, K) → (B, K/TK·TKA6): each tile gains 256
     correction columns [sum per sub-block | sum over the hi-nibble half]
-    dotted against [−32·eff | 8·eff]."""
+    dotted against [−32·eff | 8·eff]; a tail of ``width`` columns its own
+    256 (``width + 256`` in all: ``qmatmul.augment_x``)."""
     B, K = xp.shape
+    if tail_of(K):
+        return _with_tail(xp, augment_x6,
+                          lambda t, width: _augment_tile(t, width, 16, _SUBS6))
     kt = K // TK
     xt = xp.reshape(B, kt, 16, _SUBS6)
     xsum = jnp.sum(xt, axis=2)                        # (B, kt, 128)
@@ -233,23 +280,31 @@ def augment_x6(xp: jax.Array) -> jax.Array:
 
 def dequant_ref6(w: dict) -> jax.Array:
     """(N, K) f32 dequantized weights in **permuted** column order."""
-    N, half = w["q4"].shape
-    kt = half // (TK // 2)
-    v4 = w["q4"].astype(jnp.float32).reshape(N, kt, TK // 2)
-    h = jnp.floor(v4 / 16.0)
-    nib = jnp.concatenate([v4 - 16.0 * h, h + 8.0], axis=2)   # (N, kt, TK)
-    u = w["q2"].astype(jnp.float32).reshape(N, kt, TK // 4) + 128.0
-    c3 = jnp.floor(u / 64.0)
-    r = u - 64.0 * c3
-    c2 = jnp.floor(r / 16.0)
-    r = r - 16.0 * c2
-    c1 = jnp.floor(r / 4.0)
-    c0 = r - 4.0 * c1
-    crumb = jnp.concatenate([c0, c1, c2, c3], axis=2)         # (N, kt, TK)
-    q6 = nib + 16.0 * crumb
-    eff = jnp.transpose(w["sm6"], (1, 0, 2)).astype(jnp.float32)
-    eff = jnp.tile(eff, (1, 1, TK // _SUBS6))
-    return (eff * (q6 - 32.0)).reshape(N, kt * TK)
+    def tiles(q4, q2, sm6, width):
+        N, half = q4.shape
+        kt = half // (width // 2)
+        v4 = q4.astype(jnp.float32).reshape(N, kt, width // 2)
+        h = jnp.floor(v4 / 16.0)
+        nib = jnp.concatenate([v4 - 16.0 * h, h + 8.0], axis=2)
+        u = q2.astype(jnp.float32).reshape(N, kt, width // 4) + 128.0
+        c3 = jnp.floor(u / 64.0)
+        r = u - 64.0 * c3
+        c2 = jnp.floor(r / 16.0)
+        r = r - 16.0 * c2
+        c1 = jnp.floor(r / 4.0)
+        c0 = r - 4.0 * c1
+        crumb = jnp.concatenate([c0, c1, c2, c3], axis=2)     # (N, kt, width)
+        q6 = nib + 16.0 * crumb
+        eff = jnp.transpose(sm6, (1, 0, 2)).astype(jnp.float32)
+        eff = jnp.tile(eff, (1, 1, width // _SUBS6))
+        return (eff * (q6 - 32.0)).reshape(N, kt * width)
+
+    whole = tiles(w["q4"], w["q2"], w["sm6"], TK)
+    if "q4_t" not in w:
+        return whole
+    return jnp.concatenate(
+        [whole, tiles(w["q4_t"], w["q2_t"], w["sm6_t"],
+                      2 * w["q4_t"].shape[1])], axis=1)
 
 
 def dequant_rows6(w: dict, rows: jax.Array, k_in: int) -> jax.Array:
@@ -261,13 +316,16 @@ def dequant_rows6(w: dict, rows: jax.Array, k_in: int) -> jax.Array:
     rows it gathers, not a second table."""
     if "q4" not in w:
         raise ValueError("dequant_rows6 reads the split layout (q4, q2, sm6)")
-    p = dequant_ref6({"q4": jnp.take(w["q4"], rows, axis=0),
-                      "q2": jnp.take(w["q2"], rows, axis=0),
-                      "sm6": jnp.take(w["sm6"], rows, axis=1)})
-    S, K = p.shape
-    # permute_x6's inverse: column e * 128 + blk * 16 + sub of a tile
-    x = p.reshape(S, K // TK, 16, 8, 16).transpose(0, 1, 3, 4, 2)
-    return x.reshape(S, K)[:, :k_in]
+    p = dequant_ref6({key: jnp.take(a, rows, axis=1 if key.startswith("sm6")
+                                    else 0) for key, a in w.items()})
+    K = p.shape[1]
+    n = K // TK * TK
+    # permute_x6's inverse, each tile at its own width: column e * (width /
+    # 16) + s of a tile back to element s * 16 + e
+    cols = [_permute_tiles(p[:, :n], TK, TK // 16)]
+    if n < K:
+        cols.append(_permute_tiles(p[:, n:], K - n, (K - n) // 16))
+    return jnp.concatenate(cols, axis=1)[:, :k_in]
 
 
 # ---------------------------------------------------------------------------
@@ -275,22 +333,26 @@ def dequant_rows6(w: dict, rows: jax.Array, k_in: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
-                       variant="cur"):
-    TN = q4_ref.shape[0]
-    v4 = q4_ref[...].astype(jnp.float32)              # (TN, TK/2)
+                       variant="cur", accum=_q4k_accum):
+    # ``W``: the tile's columns, TK or a tail's own width, read off the block
+    # (``qmatmul._q4k_matmul_kernel``); ``accum``: what folds the tile's
+    # product into the output block
+    TN, W = q4_ref.shape[0], 2 * q4_ref.shape[1]
+    v4 = q4_ref[...].astype(jnp.float32)              # (TN, W/2)
     h = jnp.floor(v4 * 0.0625)
 
-    u = q2_ref[...].astype(jnp.float32) + 128.0       # (TN, TK/4)
+    u = q2_ref[...].astype(jnp.float32) + 128.0       # (TN, W/4)
 
     sm = sm_ref[...].reshape(TN, 128)                 # eff = d·sc
     corr = jnp.concatenate([sm * -32.0, sm * 8.0], axis=1).astype(jnp.bfloat16)
 
     if variant == "vbf32":
-        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret)
+        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret, W,
+                        accum)
         return
 
     l = v4 - h * 16.0
-    nib = jnp.concatenate([l, h], axis=1)             # (TN, TK); hi bias → corr
+    nib = jnp.concatenate([l, h], axis=1)             # (TN, W); hi bias → corr
     if variant == "parfloor":
         # all floors depend only on u (u ≤ 255 integer; /4,/16,/64 are
         # exact power-of-two scalings, so every quantity is an exact f32
@@ -308,24 +370,24 @@ def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
         r = r - 16.0 * c2
         c1 = jnp.floor(r * 0.25)
         c0 = r - 4.0 * c1
-    crumb = jnp.concatenate([c0, c1, c2, c3], axis=1)  # (TN, TK)
+    crumb = jnp.concatenate([c0, c1, c2, c3], axis=1)  # (TN, W)
 
-    eff = _lane_repeat(sm, TK // 128, interpret)
-    eff16 = _lane_repeat(sm * 16.0, TK // 128, interpret)
+    eff = _lane_repeat(sm, W // 128, interpret)
+    eff16 = _lane_repeat(sm * 16.0, W // 128, interpret)
 
     a = (nib * eff + crumb * eff16).astype(jnp.bfloat16)
 
     xpa = xpa_ref[...]
     part = jax.lax.dot_general(
-        xpa[:, :TK], a, (((1,), (1,)), ((), ())),
+        xpa[:, :W], a, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     part += jax.lax.dot_general(
-        xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
+        xpa[:, W:], corr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    _q4k_accum(o_ref, part)
+    accum(o_ref, part)
 
 
-def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
+def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret, W, accum):
     """Activation-side recombination with f32 planes (Q6_K analogue of the
     Q4_K ``vbf32`` variant, ops/pallas/qmatmul.py).
 
@@ -346,21 +408,21 @@ def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
     byte's pair ``b, b+1024`` all share sub-block ``b % 128`` (512 and
     1024 are multiples of 128), so one repeated ``sm`` plane serves every
     term."""
-    eff_h = _lane_repeat(sm, (TK // 2) // 128, interpret)
-    eff_q = _lane_repeat(sm * 16.0, (TK // 4) // 128, interpret)
+    eff_h = _lane_repeat(sm, (W // 2) // 128, interpret)
+    eff_q = _lane_repeat(sm * 16.0, (W // 4) // 128, interpret)
 
     f1 = jnp.floor(u * 0.25)
     f2 = jnp.floor(u * 0.0625)
     c3 = jnp.floor(u * (1.0 / 64.0))
 
     xpa = xpa_ref[...]
-    Q = TK // 4
+    Q = W // 4
     x0 = xpa[:, 0 * Q: 1 * Q].astype(jnp.float32)
     x1 = xpa[:, 1 * Q: 2 * Q].astype(jnp.float32)
     x2 = xpa[:, 2 * Q: 3 * Q].astype(jnp.float32)
     x3 = xpa[:, 3 * Q: 4 * Q].astype(jnp.float32)
-    x_lo = jnp.concatenate([x0, x1], axis=1)          # columns [0, TK/2)
-    x_hi = jnp.concatenate([x2, x3], axis=1)          # columns [TK/2, TK)
+    x_lo = jnp.concatenate([x0, x1], axis=1)          # columns [0, W/2)
+    x_hi = jnp.concatenate([x2, x3], axis=1)          # columns [W/2, W)
 
     # f32-operand dots; Mosaic rejects an explicit precision attr — see the
     # Q4_K vbf32 note (qmatmul.py): the chip microbench's numerics
@@ -374,8 +436,8 @@ def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret):
     part += dot(x1 - 4.0 * x0, f1 * eff_q)
     part += dot(x2 - 4.0 * x1, f2 * eff_q)
     part += dot(x3 - 4.0 * x2, c3 * eff_q)
-    part += dot(xpa[:, TK:], corr)
-    _q4k_accum(o_ref, part)
+    part += dot(xpa[:, W:], corr)
+    accum(o_ref, part)
 
 
 def _q6k_pre_kernel(xpa_ref, q6p_ref, sm_ref, o_ref, *, interpret):
@@ -419,18 +481,24 @@ def _q6k_pre_specs(B: int, TN: int):
 _TN_PREFS_Q6K = (256, 128)  # wider f32 intermediates than Q4_K: smaller TN
 
 
-def _q6k_specs(B: int, TN: int):
+def _q6k_specs(B: int, TN: int, tail: int = 0):
     """Single tiling definition for both the unstacked and stacked calls
-    (see qmatmul._q4k_specs)."""
-    return (
-        [
-            ((B, TKA6), lambda n, k: (0, k)),
-            ((TN, TK // 2), lambda n, k: (n, k)),
-            ((TN, TK // 4), lambda n, k: (n, k)),
-            ((1, TN, 128), lambda n, k: (k, n, 0)),
-        ],
-        ((B, TN), lambda n, k: (0, n)),
-    )
+    (see qmatmul._q4k_specs; ``tail``: the columns of the K's tail tile)."""
+    x = ((B, TKA6), lambda n, k: (0, k))
+    planes = [
+        ((TN, TK // 2), lambda n, k: (n, k)),
+        ((TN, TK // 4), lambda n, k: (n, k)),
+        ((1, TN, 128), lambda n, k: (k, n, 0)),
+    ]
+    out = ((B, TN), lambda n, k: (0, n))
+    if not tail:
+        return [x, *planes], out
+    return [
+        x, ((B, tail + 256), lambda n, k: (0, 0)), *planes,
+        ((TN, tail // 2), lambda n, k: (n, 0)),
+        ((TN, tail // 4), lambda n, k: (n, 0)),
+        ((1, TN, 128), lambda n, k: (0, n, 0)),
+    ], out
 
 
 # ---------------------------------------------------------------------------
@@ -456,7 +524,7 @@ HEAD_KERNEL = "q6k-head"
 _CRUMB = 0x30303030              # a crumb, where ``q6 = nib | crumb << 4`` has it
 
 
-def _q6k_tile_product(q4, q2, sm, xpa, interpret):
+def _q6k_tile_product(q4, q2, sm, xpa, interpret, width: int = TK):
     """One K tile of a Q6_K product: :func:`_q6k_matmul_kernel`'s plane, bit
     for bit, at half its vector operations.  The packed bytes are taken
     apart as INTEGERS, four weight rows a 32-bit word (the int8 planes
@@ -472,10 +540,13 @@ def _q6k_tile_product(q4, q2, sm, xpa, interpret):
     operations and the head's program keeps its text
     (tools/traced_program_hashes.py).  Returns the (B, TN) float32 product.  The bodies that run it:
     the head's (:func:`_q6k_head_kernel`) and the grouped expert calls'
-    (:func:`_q6k_expert_kernel`)."""
+    (:func:`_q6k_expert_kernel`).  ``width``: the tile's columns, a tail's
+    512 or 1024 (q4 (TN, width/2), q2 (TN, width/4), xpa (B, width + 256)):
+    its quarters are joined to the 512 columns a dot takes, one dot or two
+    where a whole tile has four."""
     from jax.experimental.pallas import tpu as pltpu
 
-    Q = TK // 4
+    Q = width // 4
 
     def signed(t, flip):
         # a byte ``16 c + u``, u = (hi - 8) mod 16, to the int8 ``16 c + hi
@@ -495,6 +566,10 @@ def _q6k_tile_product(q4, q2, sm, xpa, interpret):
           lo[:, Q:] | ((w2 << 2) & _CRUMB),       # [512, 1024)
           signed(hi[:, :Q] | (w2 & _CRUMB), 0),   # [1024, 1536)
           signed(hi[:, Q:] | ((w2 >> 2) & _CRUMB), 0x20202020))
+    if width < TK:
+        g, Q = TK // width, TK // 4
+        q6 = tuple(jnp.concatenate(q6[i:i + g], axis=1)
+                   for i in range(0, 4, g))
     sm = sm()                                     # (TN, 128): eff = d·sc
     eff = _lane_repeat(sm, Q // 128, interpret)
     corr = jnp.concatenate([sm * -32.0, sm * 8.0],
@@ -504,7 +579,7 @@ def _q6k_tile_product(q4, q2, sm, xpa, interpret):
     # takes 1.34 ms where this takes 1.14 (153600 x 6144, on the chip): the
     # float32 sums of a K tile are taken in another order than the stacked
     # body's one dot takes them
-    p = dot(xpa[:, TK:], corr)
+    p = dot(xpa[:, width:], corr)
     for c, q in enumerate(q6):
         a = (pltpu.bitcast(q, jnp.int8).astype(jnp.float32) * eff
              ).astype(jnp.bfloat16)               # (TN, 512)
@@ -518,6 +593,16 @@ def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
     grid step.  xpa (tiles, B, TKA6), which is the whole of it, fetched once
     a call, where the step holds all of K; q4 (TN, tiles * TK/2), q2 (TN,
     tiles * TK/4) int8; sm (tiles, TN, 128)."""
+    part = _q6k_head_tiles(xpa_ref, q4_ref, q2_ref, sm_ref, interpret, tiles)
+    if accumulate:
+        _q4k_accum(o_ref, part)
+    else:
+        o_ref[...] = part
+
+
+def _q6k_head_tiles(xpa_ref, q4_ref, q2_ref, sm_ref, interpret, tiles):
+    """The products of a grid step's ``tiles`` whole K tiles, summed in the
+    tiles' order."""
     Q = TK // 4
     part = None
     for j in range(tiles):
@@ -526,10 +611,31 @@ def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
             lambda: q2_ref[:, j * Q:(j + 1) * Q],
             lambda: sm_ref[j], lambda: xpa_ref[j], interpret)
         part = p if part is None else part + p
-    if accumulate:
-        _q4k_accum(o_ref, part)
-    else:
-        o_ref[...] = part
+    return part
+
+
+def _q6k_head_tail_kernel(xpa_ref, xt_ref, q4_ref, q2_ref, sm_ref, q4t_ref,
+                          q2t_ref, smt_ref, o_ref, *, interpret, tiles,
+                          steps):
+    """:func:`_q6k_head_kernel` of a K that ends in a tail tile
+    (``qmatmul.tail_of``): the LAST of the call's ``steps`` K steps adds the
+    tail's product (xt (B, T + 256); q4t (TN, T/2), q2t (TN, T/4); smt (1,
+    TN, 128)), whose blocks arrived with the N tile's first."""
+    part = _q6k_head_tiles(xpa_ref, q4_ref, q2_ref, sm_ref, interpret, tiles)
+
+    def tail():
+        return _q6k_tile_product(
+            lambda: q4t_ref[...], lambda: q2t_ref[...], lambda: smt_ref[0],
+            lambda: xt_ref[...], interpret, 2 * q4t_ref.shape[1])
+
+    if steps == 1:
+        o_ref[...] = part + tail()
+        return
+    _q4k_accum(o_ref, part)
+
+    @pl.when(pl.program_id(1) == steps - 1)
+    def _():
+        o_ref[...] += tail()
 
 
 def _q6k_expert_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
@@ -568,30 +674,49 @@ def _head_tiling(N: int, B: int, kt: int, interpret: bool):
 
 
 def _q6k_2d_raw(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
-                interpret: bool) -> jax.Array:
+                interpret: bool, tail: tuple = ()) -> jax.Array:
+    """``tail``: (q4_t, q2_t, sm6_t), the planes of K's tail tile."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, KA = xpa.shape
     kt = KA // TKA6
     N = q4.shape[0]
     TN, tiles = _head_tiling(N, B, kt, interpret)
+    kernel = functools.partial(_q6k_head_kernel, interpret=interpret,
+                               tiles=tiles, accumulate=tiles < kt)
+    in_specs = [
+        pl.BlockSpec((tiles, B, TKA6), lambda n, k: (k, 0, 0)),
+        pl.BlockSpec((TN, tiles * TK // 2), lambda n, k: (n, k)),
+        pl.BlockSpec((TN, tiles * TK // 4), lambda n, k: (n, k)),
+        pl.BlockSpec((tiles, TN, 128), lambda n, k: (k, n, 0)),
+    ]
+    whole = xpa[:, :kt * TKA6] if tail else xpa
+    operands = (jnp.transpose(whole.reshape(B, kt, TKA6), (1, 0, 2)),
+                q4, q2, sm)
+    if tail:
+        T = 2 * tail[0].shape[1]
+        kernel = functools.partial(
+            _q6k_head_tail_kernel, interpret=interpret, tiles=tiles,
+            steps=kt // tiles)
+        in_specs = [
+            in_specs[0], pl.BlockSpec((B, T + 256), lambda n, k: (0, 0)),
+            *in_specs[1:],
+            pl.BlockSpec((TN, T // 2), lambda n, k: (n, 0)),
+            pl.BlockSpec((TN, T // 4), lambda n, k: (n, 0)),
+            pl.BlockSpec((1, TN, 128), lambda n, k: (0, n, 0)),
+        ]
+        operands = (operands[0], xpa[:, kt * TKA6:], *operands[1:], *tail)
     return pl.pallas_call(
-        functools.partial(_q6k_head_kernel, interpret=interpret,
-                          tiles=tiles, accumulate=tiles < kt),
+        kernel,
         grid=(N // TN, kt // tiles),
-        in_specs=[
-            pl.BlockSpec((tiles, B, TKA6), lambda n, k: (k, 0, 0)),
-            pl.BlockSpec((TN, tiles * TK // 2), lambda n, k: (n, k)),
-            pl.BlockSpec((TN, tiles * TK // 4), lambda n, k: (n, k)),
-            pl.BlockSpec((tiles, TN, 128), lambda n, k: (k, n, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((B, TN), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
         name=kernel_name("q6k", B),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=MANYROW_VMEM if B > TM else HEAD_VMEM),
-    )(jnp.transpose(xpa.reshape(B, kt, TKA6), (1, 0, 2)), q4, q2, sm)
+    )(*operands)
 
 
 def _q6k_pre_2d_raw(xpa: jax.Array, q6p: jax.Array, sm: jax.Array,
@@ -674,30 +799,29 @@ def _q6k_pre_2d_stacked_partitioned(interpret: bool):
 
 
 @functools.lru_cache(maxsize=4)
-def _q6k_2d_partitioned(interpret: bool):
+def _q6k_2d_partitioned(interpret: bool, tail: bool = False):
     """GSPMD rule mirroring the Q4_K kernel's: partition over N (and rows),
-    never over K; tp-sharded weights compute locally."""
+    never over K; tp-sharded weights compute locally.  ``tail``: the call
+    takes a tail tile's three planes after the whole tiles'."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     @custom_partitioning
-    def fn(xpa, q4, q2, sm):
-        return _q6k_2d_raw(xpa, q4, q2, sm, interpret)
+    def fn(xpa, q4, q2, sm, *tail_planes):
+        return _q6k_2d_raw(xpa, q4, q2, sm, interpret, tail_planes)
 
     def partition(mesh, arg_shapes, result_shape):
-        xp_s, q4_s, q2_s, sm_s = (a.sharding for a in arg_shapes)
-        rows = _spec_axis(xp_s, 0)
-        n_ax = _spec_axis(q4_s, 0)
-        arg_shardings = (
-            NamedSharding(mesh, P(rows, None)),
-            NamedSharding(mesh, P(n_ax, None)),
-            NamedSharding(mesh, P(n_ax, None)),
-            NamedSharding(mesh, P(None, n_ax, None)),
-        )
+        rows = _spec_axis(arg_shapes[0].sharding, 0)
+        n_ax = _spec_axis(arg_shapes[1].sharding, 0)
+        planes = (NamedSharding(mesh, P(n_ax, None)),
+                  NamedSharding(mesh, P(n_ax, None)),
+                  NamedSharding(mesh, P(None, n_ax, None)))
+        arg_shardings = (NamedSharding(mesh, P(rows, None)), *planes,
+                         *(planes if tail else ()))
         result_sharding = NamedSharding(mesh, P(rows, n_ax))
 
-        def lower(xpa, q4, q2, sm):
-            return _q6k_2d_raw(xpa, q4, q2, sm, interpret)
+        def lower(xpa, q4, q2, sm, *tail_planes):
+            return _q6k_2d_raw(xpa, q4, q2, sm, interpret, tail_planes)
 
         return mesh, lower, result_sharding, arg_shardings
 
@@ -709,43 +833,60 @@ def _q6k_2d_partitioned(interpret: bool):
     fn.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
-        sharding_rule="b k, n j, n p, t n l -> b n",
+        sharding_rule="b k, n j, n p, t n l, n q, n r, u n s -> b n" if tail
+        else "b k, n j, n p, t n l -> b n",
     )
     return jax.jit(rows_vmappable(fn, xpa_pos=0, bound=MANYROW_MAX))
 
 
 def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
-                        q2: jax.Array, sm: jax.Array,
+                        q2: jax.Array, sm: jax.Array, *tail,
                         interpret: bool, variant: str = "cur") -> jax.Array:
+    """``tail``: (q4_t, q2_t, sm6_t), the stacked planes of K's tail tile."""
     B, KA = xpa.shape
     K = (KA // TKA6) * TK
     N = q4.shape[1]
     TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q6K))
-    in_specs, out_spec = _q6k_specs(B, TN)
+    in_specs, out_spec = _q6k_specs(
+        B, TN, 2 * tail[0].shape[-1] if tail else 0)
+    kernel = functools.partial(_q6k_matmul_kernel, interpret=interpret,
+                               variant=variant)
     call = stacked_pallas_call(
-        functools.partial(_q6k_matmul_kernel, interpret=interpret,
-                          variant=variant),
+        tail_kernel(kernel, K // TK) if tail else kernel,
         grid=(N // TN, K // TK),
         in_specs=in_specs,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
         name=kernel_name("q6k", B),
+        n_act=2 if tail else 1,
     )
+    if tail:
+        return call(idx, xpa, xpa[:, K // TK * TKA6:], q4, q2, sm, *tail)
     return call(idx, xpa, q4, q2, sm)
 
 
-@functools.lru_cache(maxsize=8)
-def _q6k_2d_stacked_partitioned(interpret: bool, variant: str = "cur"):
+@functools.lru_cache(maxsize=16)
+def _q6k_2d_stacked_partitioned(interpret: bool, variant: str = "cur",
+                                tail: bool = False):
     return stacked_partitioned(
         functools.partial(_q6k_2d_stacked_raw, variant=variant),
-        "i, b k, l n j, l n p, l t n m -> b n", interpret, MANYROW_MAX)
+        "i, b k, l n j, l n p, l t n m, l n q, l n r, l u n s -> b n" if tail
+        else "i, b k, l n j, l n p, l t n m -> b n", interpret, MANYROW_MAX)
+
+
+def _q6k_planes(w: dict) -> tuple:
+    """A split-layout weight dict's planes in the calls' order: the whole
+    tiles', then the tail's where the K has one."""
+    return (w["q4"], w["q2"], w["sm6"]) + (
+        (w["q4_t"], w["q2_t"], w["sm6_t"]) if "q4_t" in w else ())
 
 
 def q6k_matmul_stacked(x: jax.Array, w: dict, idx,
                        interpret: bool | None = None) -> jax.Array:
     """x (..., K) → (..., N) against layer ``idx`` of stacked Q6_K weights
-    (``q4`` (L, N, K/2), ``q2`` (L, N, K/4), ``sm6`` (L, K/2048, N, 128);
+    (``q4`` (L, N, K/2), ``q2`` (L, N, K/4), ``sm6`` (L, K/2048, N, 128),
+    with ``q4_t`` / ``q2_t`` / ``sm6_t`` beside them where K ends in a tail;
     or ``q6p`` (L, N, K) + ``sm6`` for the `pre` layout).  The program is
     dispatched on the LAYOUT (plane presence), not the env knob, so
     weights prepped under one variant can never meet the other family's
@@ -761,9 +902,10 @@ def q6k_matmul_stacked(x: jax.Array, w: dict, idx,
     else:
         var = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS)
         fn = _q6k_2d_stacked_partitioned(
-            _interpret(interpret), "cur" if var == "pre" else var)
+            _interpret(interpret), "cur" if var == "pre" else var,
+            "q4_t" in w)
         y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws),
-                         xpa, w["q4"], w["q2"], w["sm6"], bound=MANYROW_MAX)
+                         xpa, *_q6k_planes(w), bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
@@ -780,9 +922,8 @@ def q6k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
     else:
         # the split layout's unstacked call is the head's, whatever
         # LFKT_Q6K_KERNEL says of the stacked bodies
-        fn = _q6k_2d_partitioned(_interpret(interpret))
-        y = batched_rows(fn, xpa, w["q4"], w["q2"], w["sm6"],
-                         bound=MANYROW_MAX)
+        fn = _q6k_2d_partitioned(_interpret(interpret), "q4_t" in w)
+        y = batched_rows(fn, xpa, *_q6k_planes(w), bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 

@@ -56,9 +56,34 @@ Activations are pre-permuted to the same column order by :func:`permute_x`
 (a reshape+transpose fused into the surrounding XLA graph) and augmented
 with the per-sub-block sums by :func:`augment_x`.
 
-Shape requirements: ``K % 2048 == 0`` and ``N % 128 == 0`` (all Llama-3 /
-Mistral linear shapes qualify; loaders fall back to the int8 format
-otherwise — see models/params.py).
+Shape requirements: ``N % 128 == 0`` and a K of whole tiles (``K % 2048 ==
+0``: all Llama-3 / Mistral linear shapes; a K that ``ops/linear.py
+padded_k`` fills up to one with zero blocks: 7168 -> 8192, 11008 -> 12288),
+or of whole tiles and ONE TAIL TILE (:func:`tail_of`: a K above one tile, a
+multiple of 512, that filling would widen by a fifth or more: 2560 = 2048 +
+512, 5120 = 2 x 2048 + 1024).  Loaders fall back to the int8 format
+otherwise — see models/params.py.
+
+The tail tile (PR 63) is the same layout at its own period, in planes of its
+own beside the whole tiles', which stay what they were to the bit:
+
+- ``qs_t`` (N, T/2) int8 for a tail of ``T`` columns (512 or 1024) — byte
+  ``b`` holds the weights of columns ``b`` (lo) and ``b + T/2`` (hi), column
+  ``c = e·S + s`` over the tail's OWN ``S = T/32`` sub-blocks (16 or 32),
+  element ``e = c // S`` ∈ [0,32): element-major with period S, which divides
+  the 64 lanes of a scale vector.
+- ``sm_t`` (1, N, 128) bf16 — the tail's S scales tiled ``64/S`` times, then
+  its S mins tiled alike, so that :func:`_lane_repeat` expands them exactly
+  as it expands a whole tile's.
+- activations: :func:`permute_x` lays the last T columns out with period S,
+  :func:`augment_x` appends the tail's own 128 correction columns (its S
+  sub-block sums, zeros up to 64, twice): ``K + 128 * (K // 2048 + 1)``
+  columns in all, nothing padded.
+- kernels: the grid is the whole tiles' (N tiles x whole K tiles); the tail
+  takes no step of its own, the last K step runs the body a second time on
+  the tail's blocks at width T (:func:`tail_kernel`).  A quarter or a half
+  of a tile's bytes, dequantization and MXU passes where the zero fill cost
+  a whole tile's.
 
 Two bodies read these planes.  :func:`_q4k_matmul_kernel`, the float split
 above with its ``LFKT_Q4K_KERNEL`` variants, is the body of the dense calls
@@ -98,47 +123,51 @@ def _interpret(override: bool | None) -> bool:
     return use_interpret()
 
 
+def tail_of(k_in: int) -> int:
+    """The width of the narrow LAST tile a fused Q4_K / Q6_K layout of a
+    ``k_in``-wide matrix ends in, or 0 where it has none: ``k_in % TK`` of a
+    K above one tile that is a multiple of 512 and that filling up to the
+    next multiple of :data:`TK` would widen by a fifth or more (2560 -> 512,
+    3072 and 5120 -> 1024; 7168, whose fill is 14 %, and 11008, whose rest of
+    768 has no period that divides 128 lanes, have none:
+    ``ops/linear.py padded_k`` fills them).  Only 512 and 1024 ever meet the
+    rule: a rest of 1536 is filled by a quarter of a tile at most."""
+    t = k_in % TK
+    return t if k_in > TK and t % 512 == 0 and 5 * (TK - t) >= k_in else 0
+
+
 def q4k_compatible(n_out: int, k_in: int, for_tpu: bool | None = None) -> bool:
     """Whether (n_out, k_in) can use the fused kernel.  On TPU, N must tile
-    to 128 sublanes; interpret mode (CPU tests) accepts any multiple of 8."""
+    to 128 sublanes; interpret mode (CPU tests) accepts any multiple of 8.
+    K: whole tiles, or whole tiles and a tail (:func:`tail_of`)."""
     if for_tpu is None:
         for_tpu = not _interpret(None)
-    return k_in % TK == 0 and n_out % (128 if for_tpu else 8) == 0
+    return (k_in % TK == 0 or tail_of(k_in) > 0) \
+        and n_out % (128 if for_tpu else 8) == 0
 
 
 # ---------------------------------------------------------------------------
 # host-side weight prep
 # ---------------------------------------------------------------------------
 
-@_garbage_tolerant
-def prep_q4k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
-    """Raw Q4_K block bytes (row-major, ``n_out`` rows of ``k_in`` elements)
-    → the kernel layout dict {"qs", "sm"}.
-
-    Dispatches to the threaded C++ packer (native/src/gguf_dequant.cpp,
-    bit-identical planes — tests/test_native.py) when available; the numpy
-    chain below is the reference implementation and the fallback."""
-    if not q4k_compatible(n_out, k_in):
-        raise ValueError(f"({n_out}, {k_in}) not fused-Q4_K compatible "
-                         f"(need K%{TK}==0, N%128==0)")
-    from ...native import native_prep_q4k
-
-    nat = native_prep_q4k(raw, n_out, k_in)
-    if nat is not None:
-        return {"qs": jnp.asarray(nat["qs"]), "sm": jnp.asarray(nat["sm"])}
-    bs = GGML_BLOCK_SIZES[GGMLType.Q4_K][1]           # 144
-    nb = k_in // QK_K
-    ktiles = k_in // TK
-    blocks = np.ascontiguousarray(raw, dtype=np.uint8)[: n_out * nb * bs]
-    blocks = blocks.reshape(n_out, nb, bs)
+def _q4k_tile_planes(blocks: np.ndarray, width: int):
+    """(N, nb, 144) Q4_K super-blocks -> (qs (N, nb * 128) int8, sm (tiles,
+    N, 128) float32) in tiles of ``width`` columns (a multiple of 256 that
+    divides 2048): the layout contract above at ``width`` = :data:`TK`, a
+    tail's at its own width, where the tile's ``width / 32`` scales (then
+    its mins) are tiled up to the 64 lanes of the plane's half."""
+    n_out, nb, _ = blocks.shape
+    ktiles = nb * QK_K // width
+    subs = width // 32                                # sub-blocks a tile
     d = blocks[..., 0:2].copy().view(np.float16).astype(np.float32)[..., 0]
     dmin = blocks[..., 2:4].copy().view(np.float16).astype(np.float32)[..., 0]
     sc, mn = unpack_scale_min_k4(blocks[..., 4:16])   # (N, nb, 8) uint8
     eff_s = d[..., None] * sc.astype(np.float32)      # (N, nb, 8)
     eff_m = dmin[..., None] * mn.astype(np.float32)
+    rep = (1, 1, _SUBS // subs)
     sm = np.concatenate([
-        eff_s.reshape(n_out, ktiles, _SUBS),          # natural block-major
-        eff_m.reshape(n_out, ktiles, _SUBS),
+        np.tile(eff_s.reshape(n_out, ktiles, subs), rep),   # block-major
+        np.tile(eff_m.reshape(n_out, ktiles, subs), rep),
     ], axis=-1)                                       # (N, ktiles, 128)
     sm = np.ascontiguousarray(sm.transpose(1, 0, 2))  # (ktiles, N, 128)
 
@@ -149,23 +178,74 @@ def prep_q4k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
     q[:, :, 0::2, :] = fqs & 0x0F
     q[:, :, 1::2, :] = (fqs >> 4) & 0x0F
     # tile-local element-major columns: Q[..., e, s], s = sb*8 + sub
-    Q = q.reshape(n_out, ktiles, 8, 8, 32).transpose(0, 1, 4, 2, 3)
-    Q = np.ascontiguousarray(Q).reshape(n_out, ktiles, 32, 64)
-    lo = Q[:, :, :16, :].reshape(n_out, ktiles, TK // 2)
-    hi = Q[:, :, 16:, :].reshape(n_out, ktiles, TK // 2)
+    Q = q.reshape(n_out, ktiles, width // QK_K, 8, 32).transpose(0, 1, 4, 2, 3)
+    Q = np.ascontiguousarray(Q).reshape(n_out, ktiles, 32, subs)
+    lo = Q[:, :, :16, :].reshape(n_out, ktiles, width // 2)
+    hi = Q[:, :, 16:, :].reshape(n_out, ktiles, width // 2)
     v = ((hi.astype(np.int16) - 8) << 4) + lo         # re-biased byte
-    qs = v.astype(np.int8).reshape(n_out, k_in // 2)
-    return {
-        "qs": jnp.asarray(qs),
-        "sm": jnp.asarray(sm, dtype=jnp.bfloat16),
-    }
+    return v.astype(np.int8).reshape(n_out, nb * (QK_K // 2)), sm
+
+
+@_garbage_tolerant
+def prep_q4k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
+    """Raw Q4_K block bytes (row-major, ``n_out`` rows of ``k_in`` elements)
+    → the kernel layout dict {"qs", "sm"}, with {"qs_t", "sm_t"} beside them
+    where ``k_in`` ends in a tail (:func:`tail_of`).
+
+    Dispatches to the threaded C++ packer (native/src/gguf_dequant.cpp,
+    bit-identical planes — tests/test_native.py) when available; the numpy
+    chain below is the reference implementation and the fallback."""
+    if not q4k_compatible(n_out, k_in):
+        raise ValueError(f"({n_out}, {k_in}) not fused-Q4_K compatible "
+                         f"(need K%{TK}==0 or a tail, N%128==0)")
+    from ...native import native_prep_q4k
+
+    nat = native_prep_q4k(raw, n_out, k_in)
+    if nat is not None:
+        return {key: jnp.asarray(a) for key, a in nat.items()}
+    bs = GGML_BLOCK_SIZES[GGMLType.Q4_K][1]           # 144
+    nb = k_in // QK_K
+    tail = tail_of(k_in)
+    whole = (k_in - tail) // QK_K                     # blocks in whole tiles
+    blocks = np.ascontiguousarray(raw, dtype=np.uint8)[: n_out * nb * bs]
+    blocks = blocks.reshape(n_out, nb, bs)
+    qs, sm = _q4k_tile_planes(blocks[:, :whole], TK)
+    w = {"qs": jnp.asarray(qs), "sm": jnp.asarray(sm, dtype=jnp.bfloat16)}
+    if tail:
+        qs, sm = _q4k_tile_planes(blocks[:, whole:], tail)
+        w.update(qs_t=jnp.asarray(qs),
+                 sm_t=jnp.asarray(sm, dtype=jnp.bfloat16))
+    return w
+
+
+def _permute_tiles(x: jax.Array, width: int, subs: int) -> jax.Array:
+    """(..., K) → (..., K), each ``width``-wide tile reordered element-major
+    over its own sub-blocks of ``subs`` elements (``subs`` = 32: column ``e
+    · width/32 + s`` ← the tile's element ``s · 32 + e``)."""
+    K = x.shape[-1]
+    lead = x.shape[:-1]
+    nl = len(lead)
+    xb = x.reshape(*lead, K // width, width // subs, subs)
+    return jnp.swapaxes(xb, nl + 1, nl + 2).reshape(*lead, K)
+
+
+def _with_tail(x: jax.Array, whole, tail_fn) -> jax.Array:
+    """``whole`` on the columns of ``x`` (..., K) in whole tiles and
+    ``tail_fn(columns, width)`` on its tail's, side by side."""
+    n = x.shape[-1] // TK * TK
+    return jnp.concatenate(
+        [whole(x[..., :n]), tail_fn(x[..., n:], x.shape[-1] - n)], axis=-1)
 
 
 def permute_x(x: jax.Array) -> jax.Array:
     """(..., K) → (..., K) with each 2048-element k-tile reordered to the
     kernel's element-major column order (column ``e·64 + s`` ← original
-    element ``(s//8)·256 + (s%8)·32 + e``)."""
+    element ``(s//8)·256 + (s%8)·32 + e``); a tail (:func:`tail_of`) the
+    same way at its own width (column ``e·(width/32) + s``)."""
     K = x.shape[-1]
+    if tail_of(K):
+        return _with_tail(x, permute_x,
+                          lambda t, width: _permute_tiles(t, width, 32))
     lead = x.shape[:-1]
     xb = x.reshape(*lead, K // TK, 8, 8, 32)          # [sb, sub, e]
     xe = jnp.transpose(xb, (*range(len(lead)), len(lead), len(lead) + 3,
@@ -176,8 +256,12 @@ def permute_x(x: jax.Array) -> jax.Array:
 def augment_x(xp: jax.Array) -> jax.Array:
     """Permuted activations (B, K) → (B, K/TK·TKA): each 2048 tile gains
     128 correction columns [per-sub-block sum | per-sub-block hi-half sum]
-    that the kernel dots against [−mn | 8·sc]."""
+    that the kernel dots against [−mn | 8·sc]; a tail of ``width`` columns
+    gains its own 128 (``width + 128`` in all: :func:`_augment_tile`)."""
     B, K = xp.shape
+    if tail_of(K):
+        return _with_tail(xp, augment_x,
+                          lambda t, width: _augment_tile(t, width, 32, _SUBS))
     kt = K // TK
     xt = xp.reshape(B, kt, 32, _SUBS)
     xsum = jnp.sum(xt, axis=2)                        # (B, kt, 64)
@@ -187,20 +271,41 @@ def augment_x(xp: jax.Array) -> jax.Array:
     return xpa.reshape(B, kt * TKA)
 
 
+def _augment_tile(xt: jax.Array, width: int, elems: int,
+                  lanes: int) -> jax.Array:
+    """A permuted tail (B, ``width``) and its 2 x ``lanes`` correction
+    columns: [sum per sub-block | sum over its high half's elements], the
+    tail's ``width / elems`` sub-blocks first and zeros up to ``lanes`` (the
+    plane's scales and mins are tiled there: they meet zeros).  The sums are
+    a zero-filled tile's for the same sub-blocks, to the bit."""
+    r = xt.reshape(xt.shape[0], elems, width // elems)
+    fill = [(0, 0), (0, lanes - width // elems)]
+    return jnp.concatenate(
+        [xt, jnp.pad(jnp.sum(r, axis=1), fill),
+         jnp.pad(jnp.sum(r[:, elems // 2:], axis=1), fill)], axis=-1)
+
+
 def dequant_ref(w: dict) -> jax.Array:
     """(N, K) f32 dequantized weights in **permuted** column order — the
     small-shape oracle the kernel is tested against."""
-    N, half = w["qs"].shape
-    kt = half // (TK // 2)
-    v = w["qs"].astype(jnp.float32).reshape(N, kt, TK // 2)
-    h = jnp.floor(v / 16.0)
-    lo = v - 16.0 * h                                 # low nibble
-    hi = h + 8.0                                      # high nibble
-    q = jnp.concatenate([lo, hi], axis=2)             # (N, kt, TK) elem-major
-    sm = jnp.transpose(w["sm"], (1, 0, 2)).astype(jnp.float32)  # (N, kt, 128)
-    sc = jnp.tile(sm[..., :_SUBS], (1, 1, TK // _SUBS))
-    mn = jnp.tile(sm[..., _SUBS:], (1, 1, TK // _SUBS))
-    return (q * sc - mn).reshape(N, kt * TK)
+    def tiles(qs, sm, width):
+        N, half = qs.shape
+        kt = half // (width // 2)
+        v = qs.astype(jnp.float32).reshape(N, kt, width // 2)
+        h = jnp.floor(v / 16.0)
+        lo = v - 16.0 * h                             # low nibble
+        hi = h + 8.0                                  # high nibble
+        q = jnp.concatenate([lo, hi], axis=2)         # (N, kt, width)
+        sm = jnp.transpose(sm, (1, 0, 2)).astype(jnp.float32)  # (N, kt, 128)
+        sc = jnp.tile(sm[..., :_SUBS], (1, 1, width // _SUBS))
+        mn = jnp.tile(sm[..., _SUBS:], (1, 1, width // _SUBS))
+        return (q * sc - mn).reshape(N, kt * width)
+
+    whole = tiles(w["qs"], w["sm"], TK)
+    if "qs_t" not in w:
+        return whole
+    return jnp.concatenate(
+        [whole, tiles(w["qs_t"], w["sm_t"], 2 * w["qs_t"].shape[1])], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -238,6 +343,8 @@ def _lane_repeat(v, times: int, interpret: bool):
     """Expand a 128-lane per-sub-block vector over a k-tile by vreg tiling
     (f32): ``jnp.tile`` in interpret mode, ``pltpu.repeat`` on TPU.  Shared
     by every fused kernel's scale-plane expansion."""
+    if times == 1:      # (a quarter of a 512-column tail is the 128 lanes)
+        return v.astype(jnp.float32)
     if interpret:
         return jnp.tile(v, (1, times)).astype(jnp.float32)
     from jax.experimental.pallas import tpu as pltpu
@@ -250,14 +357,16 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
     # xpa (B, TKA) bf16 permuted+augmented; qs (TN, TK/2) int8;
     # sm (1, TN, 128) bf16.  ``accum(o_ref, part)`` folds a k-tile's partial
     # product into the output block: :func:`_q4k_accum` on the (n, k) grids
-    # here, the grouped expert grid's own (ops/pallas/experts.py)
+    # here, the grouped expert grid's own (ops/pallas/experts.py).  ``W``:
+    # the tile's columns, TK or a tail's own width (xpa (B, W + 128), qs
+    # (TN, W/2)), read off the block
     accum = accum or _q4k_accum
-    TN = qs_ref.shape[0]
+    TN, W = qs_ref.shape[0], 2 * qs_ref.shape[1]
     v = qs_ref[...].astype(jnp.float32)
     sm = sm_ref[...].reshape(TN, 128)
     sc, mn = sm[:, :_SUBS], sm[:, _SUBS:]
     sc2 = jnp.concatenate([sc, sc], axis=1)           # (TN, 128)
-    sc_exp = _lane_repeat(sc2, TK // 256, interpret)
+    sc_exp = _lane_repeat(sc2, W // 256, interpret)
     h = jnp.floor(v * 0.0625)                         # hi − 8
     corr = jnp.concatenate([-mn, sc * 8.0], axis=1).astype(jnp.bfloat16)
     xpa = xpa_ref[...]
@@ -277,8 +386,8 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
         # gate; the interpret-mode tests pin the algebra either way.
         a_v = v * sc_exp
         a_h = h * sc_exp
-        x_lo = xpa[:, : TK // 2].astype(jnp.float32)
-        x_hi = xpa[:, TK // 2: TK].astype(jnp.float32)
+        x_lo = xpa[:, : W // 2].astype(jnp.float32)
+        x_hi = xpa[:, W // 2: W].astype(jnp.float32)
         part = jax.lax.dot_general(
             x_lo, a_v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -286,7 +395,7 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
             x_hi - 16.0 * x_lo, a_h, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         part += jax.lax.dot_general(
-            xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
+            xpa[:, W:], corr, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         accum(o_ref, part)
         return
@@ -302,31 +411,31 @@ def _q4k_matmul_kernel(xpa_ref, qs_ref, sm_ref, o_ref, *, interpret,
         a_hi = a_hi_f.astype(jnp.bfloat16)
     else:                                             # cur | onedot
         l = v - h * 16.0                              # lo
-        a_lo = (l * sc_exp).astype(jnp.bfloat16)      # (TN, TK/2)
+        a_lo = (l * sc_exp).astype(jnp.bfloat16)      # (TN, W/2)
         a_hi = (h * sc_exp).astype(jnp.bfloat16)
 
     if variant == "onedot":
-        # One concatenated (TN, TK) plane, one MXU dot over the full tile
+        # One concatenated (TN, W) plane, one MXU dot over the full tile
         # (plus the corr dot) — same planes as `cur` bit-for-bit, trading
         # a VMEM concat copy for fewer, larger matmuls.
-        a = jnp.concatenate([a_lo, a_hi], axis=1)     # (TN, TK)
+        a = jnp.concatenate([a_lo, a_hi], axis=1)     # (TN, W)
         part = jax.lax.dot_general(
-            xpa[:, :TK], a, (((1,), (1,)), ((), ())),
+            xpa[:, :W], a, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         part += jax.lax.dot_general(
-            xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
+            xpa[:, W:], corr, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         accum(o_ref, part)
         return
 
     part = jax.lax.dot_general(
-        xpa[:, : TK // 2], a_lo, (((1,), (1,)), ((), ())),
+        xpa[:, : W // 2], a_lo, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     part += jax.lax.dot_general(
-        xpa[:, TK // 2: TK], a_hi, (((1,), (1,)), ((), ())),
+        xpa[:, W // 2: W], a_hi, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     part += jax.lax.dot_general(
-        xpa[:, TK:], corr, (((1,), (1,)), ((), ())),
+        xpa[:, W:], corr, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     accum(o_ref, part)
 
@@ -466,19 +575,54 @@ def _manyrow_kw(B: int) -> dict:
         vmem_limit_bytes=MANYROW_VMEM)}
 
 
-def _q4k_specs(B: int, TN: int):
+def _q4k_specs(B: int, TN: int, tail: int = 0):
     """(in_specs, out_spec) as (block_shape, index_map) pairs — the single
     tiling definition consumed by BOTH the unstacked pallas_call (output
     head) and the stacked scalar-prefetch call (per-layer serving path),
-    so the two can't drift."""
-    return (
-        [
-            ((B, TKA), lambda n, k: (0, k)),
-            ((TN, TK // 2), lambda n, k: (n, k)),
-            ((1, TN, 128), lambda n, k: (k, n, 0)),
-        ],
-        ((B, TN), lambda n, k: (0, n)),
-    )
+    so the two can't drift.  ``tail``: the columns of the K's tail tile
+    (:func:`tail_kernel`): its activations after the whole tiles', its planes
+    after theirs, all fetched once an N tile."""
+    x = ((B, TKA), lambda n, k: (0, k))
+    planes = [
+        ((TN, TK // 2), lambda n, k: (n, k)),
+        ((1, TN, 128), lambda n, k: (k, n, 0)),
+    ]
+    out = ((B, TN), lambda n, k: (0, n))
+    if not tail:
+        return [x, *planes], out
+    return [
+        x, ((B, tail + 128), lambda n, k: (0, 0)), *planes,
+        ((TN, tail // 2), lambda n, k: (n, 0)),
+        ((1, TN, 128), lambda n, k: (0, n, 0)),
+    ], out
+
+
+def tail_kernel(body, kf: int):
+    """The kernel of a call whose K is ``kf`` whole tiles and a tail
+    (:func:`tail_of`), from the family's ``body(xpa_ref, *plane_refs, o_ref,
+    accum=)``: refs (xpa, the tail's xpa, the whole tiles' planes, the
+    tail's, out).  The tail takes no grid step of its own: the LAST whole
+    tile's step runs the body a second time on the tail's blocks (its width
+    is read off them), which arrived with the N tile's first."""
+    def add(o_ref, part):
+        o_ref[...] += part
+
+    def kernel(xpa_ref, xt_ref, *refs):
+        n = len(refs) // 2
+        whole, tail, o_ref = refs[:n], refs[n:2 * n], refs[-1]
+        if kf == 1:     # one step an N tile: nothing to accumulate into
+            parts = []
+            for x, planes in ((xpa_ref, whole), (xt_ref, tail)):
+                body(x, *planes, o_ref, accum=lambda _, p: parts.append(p))
+            o_ref[...] = parts[0] + parts[1]
+            return
+        body(xpa_ref, *whole, o_ref)
+
+        @pl.when(pl.program_id(1) == kf - 1)
+        def _():
+            body(xt_ref, *tail, o_ref, accum=add)
+
+    return kernel
 
 
 def kernel_name(family: str, rows: int) -> str:
@@ -510,15 +654,24 @@ def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
 
 
 def _q4k_2d_raw(xpa: jax.Array, qs: jax.Array, sm: jax.Array,
-                interpret: bool, variant: str = "cur") -> jax.Array:
+                interpret: bool, variant: str = "cur",
+                tail: tuple = ()) -> jax.Array:
     B, KA = xpa.shape
     K = (KA // TKA) * TK
     N = qs.shape[0]
     TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q4K))
+    kernel = functools.partial(_q4k_matmul_kernel, interpret=interpret,
+                               variant=variant)
+    if tail:    # (qs_t, sm_t): the planes of K's tail tile
+        in_specs, out_spec = _q4k_specs(B, TN, 2 * tail[0].shape[-1])
+        return plain_pallas_call(
+            tail_kernel(kernel, K // TK), (N // TN, K // TK), in_specs,
+            out_spec, jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+            kernel_name("q4k", B),
+        )(xpa, xpa[:, K // TK * TKA:], qs, sm, *tail)
     in_specs, out_spec = _q4k_specs(B, TN)
     return plain_pallas_call(
-        functools.partial(_q4k_matmul_kernel, interpret=interpret,
-                          variant=variant),
+        kernel,
         (N // TN, K // TK), in_specs, out_spec,
         jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
         kernel_name("q4k", B),
@@ -532,8 +685,9 @@ def _spec_axis(sharding, dim: int):
     return spec[dim] if dim < len(spec) else None
 
 
-@functools.lru_cache(maxsize=4)
-def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
+@functools.lru_cache(maxsize=8)
+def _q4k_2d_partitioned(interpret: bool, variant: str = "cur",
+                        tail: bool = False):
     """The 2D fused matmul with a GSPMD partitioning rule: tp-sharded
     ``qs``/``sm`` (N dim) compute locally and the output comes back N-sharded
     — no all-gather of the quantized weights (VERDICT r1 #5; previously a
@@ -543,27 +697,27 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
     Contract: partitioning is over the output dim N (and the row/batch dim
     of ``xpa``); the contraction dim K is never split (a caller shards fused
     weights on N for row-parallel layers too — gathering the small
-    activations beats gathering weights)."""
+    activations beats gathering weights).  ``tail``: the call takes a tail
+    tile's two planes after the whole tiles' (:func:`tail_of`)."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     @custom_partitioning
-    def fn(xpa, qs, sm):
-        return _q4k_2d_raw(xpa, qs, sm, interpret, variant)
+    def fn(xpa, qs, sm, *tail_planes):
+        return _q4k_2d_raw(xpa, qs, sm, interpret, variant, tail_planes)
 
     def partition(mesh, arg_shapes, result_shape):
-        xp_s, qs_s, sm_s = (a.sharding for a in arg_shapes)
-        rows = _spec_axis(xp_s, 0)
-        n_ax = _spec_axis(qs_s, 0)
+        rows = _spec_axis(arg_shapes[0].sharding, 0)
+        n_ax = _spec_axis(arg_shapes[1].sharding, 0)
+        planes = (NamedSharding(mesh, P(n_ax, None)),
+                  NamedSharding(mesh, P(None, n_ax, None)))
         arg_shardings = (
             NamedSharding(mesh, P(rows, None)),        # never split K
-            NamedSharding(mesh, P(n_ax, None)),
-            NamedSharding(mesh, P(None, n_ax, None)),
-        )
+            *planes, *(planes if tail else ()))
         result_sharding = NamedSharding(mesh, P(rows, n_ax))
 
-        def lower(xpa, qs, sm):
-            return _q4k_2d_raw(xpa, qs, sm, interpret, variant)
+        def lower(xpa, qs, sm, *tail_planes):
+            return _q4k_2d_raw(xpa, qs, sm, interpret, variant, tail_planes)
 
         return mesh, lower, result_sharding, arg_shardings
 
@@ -577,7 +731,8 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur"):
         infer_sharding_from_operands=infer,
         # shardy factor rule: rows (b) and output (n) propagate; K factors
         # (k, j, t) stay unsplit: a caller shards the planes over N alone
-        sharding_rule="b k, n j, t n l -> b n",
+        sharding_rule="b k, n j, t n l, n p, u n q -> b n" if tail
+        else "b k, n j, t n l -> b n",
     )
     return jax.jit(rows_vmappable(fn, xpa_pos=0, bound=MANYROW_MAX))
 
@@ -619,26 +774,26 @@ class _NoLead:
 
 
 def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
-                        interpret: bool, name: str):
+                        interpret: bool, name: str, n_act: int = 1):
     """Build ``fn(idx, xpa, *stacked_planes)`` running ``kernel`` (an
     unstacked fused kernel ``(xpa_ref, *plane_refs, o_ref)``) against layer
     ``idx[0]`` of weight planes stacked as (L, ...) arrays.
 
     ``in_specs`` are the UNSTACKED (block_shape, index_map) pairs — first
-    the activations, then the weight planes; weight specs get the layer dim
+    the activations (``n_act`` operands: two where K ends in a tail), then
+    the weight planes; weight specs get the layer dim
     prepended and their index_maps extended with the prefetched scalar.
     Interpret mode (CPU tests) runs the same code path — pallas emulates
     scalar prefetch.  ``name``: :func:`kernel_name`."""
     from jax.experimental.pallas import tpu as pltpu
 
-    (x_block, x_map), *w_specs = in_specs
-
     def lift(block, imap):
         return pl.BlockSpec(
             (1, *block), lambda *a, _m=imap: (a[-1][0], *_m(*a[:-1])))
 
-    specs = [pl.BlockSpec(x_block, lambda *a, _m=x_map: _m(*a[:-1]))]
-    specs += [lift(b, m) for b, m in w_specs]
+    specs = [pl.BlockSpec(b, lambda *a, _m=m: _m(*a[:-1]))
+             for b, m in in_specs[:n_act]]
+    specs += [lift(b, m) for b, m in in_specs[n_act:]]
     o_block, o_map = out_spec
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -647,9 +802,10 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
         out_specs=pl.BlockSpec(o_block, lambda *a, _m=o_map: _m(*a[:-1])),
     )
 
-    def wrapped(idx_ref, xpa_ref, *rest):
+    def wrapped(idx_ref, *refs):
         del idx_ref  # consumed by the index_maps
-        kernel(xpa_ref, *(_NoLead(r) for r in rest[:-1]), rest[-1])
+        kernel(*refs[:n_act], *(_NoLead(r) for r in refs[n_act:-1]),
+               refs[-1])
 
     return pl.pallas_call(
         wrapped, grid_spec=gs, out_shape=out_shape, interpret=interpret,
@@ -657,23 +813,29 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
 
 
 def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,
-                        sm: jax.Array, interpret: bool,
+                        sm: jax.Array, *tail, interpret: bool,
                         variant: str = "cur") -> jax.Array:
+    """``tail``: (qs_t, sm_t), the stacked planes of K's tail tile."""
     B, KA = xpa.shape
     K = (KA // TKA) * TK
     N = qs.shape[1]
     TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q4K))
-    in_specs, out_spec = _q4k_specs(B, TN)
+    in_specs, out_spec = _q4k_specs(
+        B, TN, 2 * tail[0].shape[-1] if tail else 0)
+    kernel = functools.partial(_q4k_matmul_kernel, interpret=interpret,
+                               variant=variant)
     call = stacked_pallas_call(
-        functools.partial(_q4k_matmul_kernel, interpret=interpret,
-                          variant=variant),
+        tail_kernel(kernel, K // TK) if tail else kernel,
         grid=(N // TN, K // TK),
         in_specs=in_specs,
         out_spec=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
         name=kernel_name("q4k", B),
+        n_act=2 if tail else 1,
     )
+    if tail:
+        return call(idx, xpa, xpa[:, K // TK * TKA:], qs, sm, *tail)
     return call(idx, xpa, qs, sm)
 
 
@@ -759,25 +921,37 @@ def stacked_partitioned(raw_fn, sharding_rule: str, interpret: bool,
     return jax.jit(rows_vmappable(fn, xpa_pos=1, bound=bound))
 
 
-@functools.lru_cache(maxsize=8)
-def _q4k_2d_stacked_partitioned(interpret: bool, variant: str = "cur"):
+@functools.lru_cache(maxsize=16)
+def _q4k_2d_stacked_partitioned(interpret: bool, variant: str = "cur",
+                                tail: bool = False):
     return stacked_partitioned(
         functools.partial(_q4k_2d_stacked_raw, variant=variant),
-        "i, b k, l n j, l t n m -> b n", interpret, MANYROW_MAX)
+        "i, b k, l n j, l t n m, l n p, l u n q -> b n" if tail
+        else "i, b k, l n j, l t n m -> b n", interpret, MANYROW_MAX)
+
+
+def _q4k_planes(w: dict) -> tuple:
+    """A Q4_K weight dict's planes in the calls' order: the whole tiles',
+    then the tail's where the K has one."""
+    return (w["qs"], w["sm"]) + (
+        (w["qs_t"], w["sm_t"]) if "qs_t" in w else ())
 
 
 def q4k_matmul_stacked(x: jax.Array, w: dict, idx,
                        interpret: bool | None = None) -> jax.Array:
     """x (..., K) → (..., N) against layer ``idx`` of stacked weights
-    (``qs`` (L, N, K/2), ``sm`` (L, K/2048, N, 128)).  The fused path of
+    (``qs`` (L, N, K/2), ``sm`` (L, K/2048, N, 128); with a tail of T
+    columns, ``qs_t`` (L, N, T/2) and ``sm_t`` (L, 1, N, 128) beside the
+    whole tiles').  The fused path of
     ``ops.linear.linear_at`` — no per-layer weight copy under scan."""
     K = x.shape[-1]
     lead = x.shape[:-1]
     xpa = augment_x(permute_x(x).reshape(-1, K).astype(jnp.bfloat16))
     fn = _q4k_2d_stacked_partitioned(
-        _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS))
+        _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS),
+        "qs_t" in w)
     i1 = jnp.asarray(idx, jnp.int32).reshape(1)
-    y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws), xpa, w["qs"], w["sm"],
+    y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws), xpa, *_q4k_planes(w),
                      bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
@@ -813,8 +987,9 @@ def q4k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
     xpa = augment_x(
         permute_x(x).reshape(-1, K).astype(jnp.bfloat16))
     fn = _q4k_2d_partitioned(
-        _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS))
-    y = batched_rows(fn, xpa, w["qs"], w["sm"], bound=MANYROW_MAX)
+        _interpret(interpret), _env_variant("LFKT_Q4K_KERNEL", Q4K_VARIANTS),
+        "qs_t" in w)
+    y = batched_rows(fn, xpa, *_q4k_planes(w), bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 

@@ -1424,6 +1424,12 @@ def create_app(engine=None, settings: Settings | None = None,
             kernel = getattr(eng, "expert_kernel", None)
             if kernel:
                 engine_info["expert_kernel"] = kernel
+            # per cent of the resident fused planes' bytes that are zero
+            # fill (a K filled up to the kernels' 2048 tile: the loader's
+            # own sum); no key where no plane is fused
+            fill = getattr(eng, "weight_fill_share", None)
+            if fill is not None:
+                engine_info["weight_fill_share"] = fill
             # a vocabulary the tokenizer cannot cut at spaces pays the
             # whole-text merge loop on every prompt (tokenizer/spm.py);
             # absent where it can
@@ -1532,6 +1538,9 @@ def create_app(engine=None, settings: Settings | None = None,
             m.set_gauge(base, value, **dict(
                 pair.split("=", 1) for pair in
                 labels.rstrip("}").replace('"', "").split(",") if pair))
+        fill = getattr(app.state.engine, "weight_fill_share", None)
+        if fill is not None:
+            m.set_gauge("weight_fill_share", fill)
         # routed layers (a file with experts): cumulative counters of the
         # decode chunks that have finished, folded here and not on the
         # decode path (engine/expert_counters.py)

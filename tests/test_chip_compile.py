@@ -70,14 +70,27 @@ def S(*shape, dtype=bf16):
 
 
 def _planes(fmt, n, k, layers=None):
+    """A fused layout's planes at K ``k`` as stored: whole 2048 tiles, and
+    for Q4_K / Q6_K the tail tile's planes beside them where ``k`` ends in
+    one (ops/pallas/qmatmul.py ``tail_of``: 2560, 5120)."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import tail_of
+
+    tail = tail_of(k) if fmt in ("q4k", "q6k") else 0
+    k -= tail
     kt = k // 2048
     lead = () if layers is None else (layers,)
     sm = S(*lead, kt, n, 128)
+    sm_t = S(*lead, 1, n, 128)
     if fmt == "q4k":
-        return {"qs": S(*lead, n, k // 2, dtype=i8), "sm": sm}
+        return {"qs": S(*lead, n, k // 2, dtype=i8), "sm": sm,
+                **({"qs_t": S(*lead, n, tail // 2, dtype=i8), "sm_t": sm_t}
+                   if tail else {})}
     if fmt == "q6k":
         return {"q4": S(*lead, n, k // 2, dtype=i8),
-                "q2": S(*lead, n, k // 4, dtype=i8), "sm6": sm}
+                "q2": S(*lead, n, k // 4, dtype=i8), "sm6": sm,
+                **({"q4_t": S(*lead, n, tail // 2, dtype=i8),
+                    "q2_t": S(*lead, n, tail // 4, dtype=i8),
+                    "sm6_t": sm_t} if tail else {})}
     if fmt == "q5k":
         return {"q5s": S(*lead, n, k // 2, dtype=i8),
                 "q5h": S(*lead, n, k // 8, dtype=i8), "sm5": sm}
@@ -1646,7 +1659,7 @@ def test_phi4flash_stack_compiles_with_no_ring_sized_copy(
     cfg = dataclasses.replace(published_cfg(), attn_impl="pallas",
                               ssm_scan_kernel=True)
     D, V, F, C, N, R, hd = 2560, 200064, 10240, 5120, 16, 160, 64
-    Dk, Ck = 4096, 6144                 # as stored: ops.linear ``padded_k``
+    Dk, Ck = D, C       # as stored (ops.linear ``padded_k``): each ends in a tail
     assert phi4flash.CACHE.decode_kernel_block(cfg) == 128
     assert ring_write_impl(cfg) == "kernel"
 

@@ -182,3 +182,40 @@ def test_prep_bit_exact(monkeypatch, kind, raw_kind, n, k):
         else:
             assert np.array_equal(a.view(np.uint16), b.view(np.uint16)), \
                 (kind, key)
+
+
+@pytest.mark.parametrize("raw_kind", ["codec", "random_bytes"])
+@pytest.mark.parametrize("kind", ["q4k", "q6k"])
+@pytest.mark.parametrize("n,k", [(128, 2560), (8, 5120)])
+def test_prep_tail_bit_exact(monkeypatch, kind, raw_kind, n, k):
+    """A K that ends in a TAIL tile (ops/pallas/qmatmul.py ``tail_of``: 2560
+    = 2048 + 512, 5120 = 2 x 2048 + 1024): the C++ packers lay the whole
+    tiles' planes and the tail's out as the numpy chains do, byte for byte,
+    the tiled scales of the tail included."""
+    import llama_fastapi_k8s_gpu_tpu.native as native_mod
+
+    monkeypatch.setenv("LFKT_Q6K_KERNEL", "cur")
+    module, ref_name, nat_name, codec, gtype = _packer_case(kind)
+    rng = np.random.default_rng(hash((kind, raw_kind, n, k)) % 2**32)
+    if raw_kind == "codec":
+        raw = codec((rng.standard_normal(n * k) * 0.05).astype(np.float32))
+    else:
+        block_elems, block_bytes = GGML_BLOCK_SIZES[gtype]
+        raw = rng.integers(0, 256, size=(n * k // block_elems) * block_bytes,
+                           dtype=np.uint8)
+    nat = getattr(native_mod, nat_name)(raw, n, k)
+    assert nat is not None
+    monkeypatch.setattr(native_mod, nat_name, lambda *a, **kw: None)
+    ref = getattr(module, ref_name)(raw, n, k)
+    tail = {"q4k": {"qs_t", "sm_t"}, "q6k": {"q4_t", "q2_t", "sm6_t"}}[kind]
+    assert sorted(nat) == sorted(ref) and tail <= set(nat)
+    assert nat[{"q4k": "qs_t", "q6k": "q4_t"}[kind]].shape == (
+        n, (k % 2048) // 2)
+    for key in nat:
+        a, b = nat[key], np.asarray(ref[key])
+        assert a.shape == b.shape, (kind, key)
+        if a.dtype == np.int8:
+            assert np.array_equal(a, b), (kind, key)
+        else:
+            assert np.array_equal(a.view(np.uint16), b.view(np.uint16)), \
+                (kind, key)

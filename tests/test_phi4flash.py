@@ -514,13 +514,19 @@ def test_the_windows_wrap_and_the_shared_leaf_does_not(loaded, served, want):
 # K = 2560 on the fused kernels; the tied head
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("k,stored", [
-    (2560, 4096), (5120, 6144), (2304, 2304), (1536, 1536), (512, 512),
-    (3072, 4096), (4096, 4096), (7168, 8192), (11008, 12288)])
-def test_the_fill_rule_is_narrow(k, stored):
+@pytest.mark.parametrize("k,stored,tail", [
+    (2560, 2560, 512), (3072, 3072, 1024), (5120, 5120, 1024),
+    (4096, 4096, 0), (7168, 8192, 0), (11008, 12288, 0), (2304, 2304, 0),
+    (1536, 1536, 0), (512, 512, 0)])
+def test_the_fill_rule_is_narrow(k, stored, tail):
+    """By shape alone: a multiple of the tile as it is; a K above one tile,
+    a multiple of 512, that filling would widen by a fifth or more, stored
+    at K and ending in a TAIL tile; anything else as it always was."""
     from llama_fastapi_k8s_gpu_tpu.ops.linear import padded_k
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import tail_of
 
     assert padded_k(k) == stored
+    assert tail_of(k) == tail
 
 
 def test_an_expert_matrix_keeps_its_own_rule():
@@ -547,27 +553,56 @@ def _filled(raw, n, k, k_pad):
                   ).reshape(-1)
 
 
-@pytest.mark.parametrize("rows", [3, 70], ids=["few_rows", "many_rows"])
-@pytest.mark.parametrize("fmt", ["q4k", "q6k"])
-def test_k_2560_on_the_fused_matmuls_against_the_oracle(fmt, rows):
-    """The FILE's own blocks, each row's second K tile filled up with zero
-    blocks, through the fused kernel: the dequantized matrix's product
-    (gguf/quants.py is the oracle), nothing requantized."""
+def _fused_product(fmt, x, w, stacked):
+    """``x`` through the fused call of ``w``'s layout: the unstacked call
+    (for Q6_K the HEAD's integer body) or the stacked one on a stack of two,
+    layer 1."""
     import jax.numpy as jnp
 
-    from llama_fastapi_k8s_gpu_tpu.ops.linear import linear, padded_k
+    from llama_fastapi_k8s_gpu_tpu.ops.linear import linear, linear_at
+
+    if not stacked:
+        return np.asarray(linear(x, w), np.float32)
+    ws = {key: jnp.stack([jnp.zeros_like(a), a]) for key, a in w.items()}
+    return np.asarray(linear_at(x, ws, 1), np.float32)
+
+
+@pytest.mark.parametrize("k", [2560, 5120])
+@pytest.mark.parametrize("stacked", [False, True], ids=["plain", "stacked"])
+@pytest.mark.parametrize("rows", [3, 300], ids=["few_rows", "many_rows"])
+@pytest.mark.parametrize("fmt", ["q4k", "q6k"])
+def test_k_2560_on_the_fused_matmuls_against_the_oracle(fmt, rows, stacked,
+                                                        k):
+    """The FILE's own blocks in the TAIL layout (the whole 2048 tiles as
+    they always were, the last 512 / 1024 columns a narrow tile of the same
+    layout) through the fused kernels, float bodies and the head's integer
+    one: the dequantized matrix's product (gguf/quants.py is the oracle),
+    nothing requantized, and the product of the same blocks with each row's
+    last tile FILLED UP with zero blocks, up to the order of a tile's
+    float32 sums."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.linear import padded_k
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import prep_q4k
 
-    n, k = 256, 2560
+    n, k_fill = 256, -(-k // 2048) * 2048
     raw, deq = _quantized(fmt, n, k)
     prep = {"q4k": prep_q4k, "q6k": prep_q6k}[fmt]
-    w = prep(_filled(raw, n, k, padded_k(k)), n, padded_k(k))
+    assert padded_k(k) == k
+    w = prep(raw, n, k)
+    assert {"q4k": "qs_t", "q6k": "q4_t"}[fmt] in w
+    # (bfloat16 values held as float32: the calls then return their float32
+    # sums unrounded, which is where the two layouts may differ)
     x = jnp.asarray(np.random.default_rng(1).standard_normal((rows, k)),
-                    jnp.bfloat16)
-    got = np.asarray(linear(x, w), np.float32)
-    oracle = np.asarray(x, np.float32) @ deq.T
+                    jnp.bfloat16).astype(jnp.float32)
+    got = _fused_product(fmt, x, w, stacked)
+    oracle = np.asarray(x) @ deq.T
     assert rel(got, oracle) < 1e-2
+    filled = _fused_product(
+        fmt, jnp.pad(x, ((0, 0), (0, k_fill - k))),
+        prep(_filled(raw, n, k, k_fill), n, k_fill), stacked)
+    assert np.abs(got - filled).max() <= 1e-5 * np.abs(filled).max()
 
 
 #: sha256[:12] of the prepared planes at the PARENT commit (f9a3546)
@@ -578,10 +613,10 @@ PLANES = {("q4k", 4096): "2ceb40ca024e", ("q4k", 7168): "9ac847c7e664",
 @pytest.mark.parametrize("fmt,k", [
     ("q4k", 4096), ("q6k", 4096), ("q4k", 7168), ("q6k", 7168)])
 def test_the_planes_of_other_widths_are_what_they_were(fmt, k):
-    """The K = 2560 rule moves no other matrix: the prepared planes of a K
-    = 4096 and a K = 7168 (-> 8192) matrix hash to what the parent's
-    ``prep_*`` gives on the parent's ``padded_k`` (the digests below were
-    taken at the parent commit)."""
+    """The tail tile moves no other matrix: the prepared planes of a K
+    = 4096 and a K = 7168 (-> 8192) matrix hash to what PR 62's parent's
+    ``prep_*`` gave on its ``padded_k`` (the digests below were taken
+    there, and PR 63 left them as they were)."""
     from llama_fastapi_k8s_gpu_tpu.ops.linear import padded_k
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import prep_q6k
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import prep_q4k
@@ -600,9 +635,10 @@ def test_the_planes_of_other_widths_are_what_they_were(fmt, k):
 
 
 def test_the_tied_q6k_head_is_the_dequantized_rows():
-    """ONE stored tensor: the head's fused Q6_K planes (K 2560 filled to
-    4096) give the logits, and the embedding lookup dequantizes the rows it
-    gathers from the same planes: both are the file's matrix."""
+    """ONE stored tensor: the head's fused Q6_K planes (K 2560: a whole
+    tile and a tail of 512) give the logits, and the embedding lookup
+    (``q6k-rows``) dequantizes the rows it gathers from the same planes,
+    tail and all: both are the file's matrix."""
     import jax.numpy as jnp
 
     from llama_fastapi_k8s_gpu_tpu.models.phi4flash import embed
@@ -611,7 +647,9 @@ def test_the_tied_q6k_head_is_the_dequantized_rows():
 
     n, k = 384, 2560
     raw, deq = _quantized("q6k", n, k, seed=3)
-    w = prep_q6k(_filled(raw, n, k, padded_k(k)), n, padded_k(k))
+    assert padded_k(k) == k
+    w = prep_q6k(raw, n, k)
+    assert w["q4_t"].shape == (n, 256) and w["q2_t"].shape == (n, 128)
     params = {"tok_emb": w, "output": w}
     ids = jnp.asarray([0, 5, 383, 5, 77], jnp.int32)
     rows = np.asarray(embed(params, ids, k), np.float32)
@@ -623,6 +661,27 @@ def test_the_tied_q6k_head_is_the_dequantized_rows():
     logits = np.asarray(linear(h, params["output"]), np.float32)
     all_rows = np.asarray(embed(params, jnp.arange(n), k), np.float32)
     assert rel(logits, np.asarray(h, np.float32) @ all_rows.T) < 1e-2
+
+
+@pytest.mark.parametrize("k", [2560, 5120, 4096])
+def test_the_row_lookup_follows_the_layout(k):
+    """``q6k-rows``: the gathered rows of the head's planes, dequantized and
+    put back in the FILE's column order, whole tiles and tail: the file's
+    weights with ``d x sc`` rounded to bfloat16 (2^-9), element by
+    element."""
+    import jax.numpy as jnp
+
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import (
+        dequant_rows6, prep_q6k)
+
+    n = 128
+    raw, deq = _quantized("q6k", n, k, seed=11)
+    w = prep_q6k(raw, n, k)
+    assert ("q4_t" in w) == (k != 4096)
+    ids = np.asarray([127, 0, 64, 0])
+    rows = np.asarray(dequant_rows6(w, jnp.asarray(ids), k))
+    assert rows.shape == (4, k)
+    assert (np.abs(rows - deq[ids]) <= 2.0 ** -8 * np.abs(deq[ids])).all()
 
 
 # ---------------------------------------------------------------------------
@@ -942,3 +1001,61 @@ async def test_the_server_serves_the_file_and_names_the_kind(lane_engine):
                          "window_slots_read_total", "ring_slots_read_total"):
                 assert name in m, name
         await app.router.shutdown()
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("ffn_dim,d_inner,share", [
+    (2560, 2560, "none"), (1792, 2048, "some")], ids=["tail", "filled"])
+async def test_health_names_the_formats_and_the_fill_share(
+        tmp_path, ffn_dim, d_inner, share):
+    """``/health`` of a tiny file whose ``ffn_down`` / ``ssm_out`` /
+    ``gmu_out`` have a K the fused kernels take (2560: a whole tile and a
+    tail, nothing filled; 1792: filled up to 2048): every ``weight_formats``
+    entry the published configuration's ``expect_health`` lists is there,
+    those that fuse here under the names it expects, ``head_kernel`` is
+    reported, and ``weight_fill_share`` is the loader's sum (``/metrics``
+    has the same number)."""
+    import json
+
+    import httpx
+
+    from llama_fastapi_k8s_gpu_tpu.engine import Engine
+    from llama_fastapi_k8s_gpu_tpu.server.app import create_app
+    from llama_fastapi_k8s_gpu_tpu.testing import (
+        PHI4FLASH_Q4KM_MIX, TINY_PHI4FLASH_CFG, write_tiny_phi4flash_gguf)
+    from llama_fastapi_k8s_gpu_tpu.utils.config import Settings
+
+    with open(os.path.join(
+            REPO, "benchmarks", "configs",
+            "phi4-mini-flash-3.8b-q4km-16lane.json")) as fh:
+        expect = json.load(fh)["expect_health"]
+    path = str(tmp_path / "wide.gguf")
+    write_tiny_phi4flash_gguf(
+        path, dataclasses.replace(TINY_PHI4FLASH_CFG, ffn_dim=ffn_dim,
+                                  ssm_d_inner=d_inner),
+        mix=PHI4FLASH_Q4KM_MIX)
+    engine = Engine(path, n_ctx=128, prefill_chunk=16, weight_format="q4k")
+    if share == "none":     # the tail's planes serve a request end to end
+        out = engine.create_chat_completion(MSGS, max_tokens=2,
+                                            temperature=0.0)
+        assert out["usage"]["prompt_tokens"] > 16   # (two slices and more)
+        assert "q4_t" in engine.params["layers"]["ffn"]["w_down"]
+        assert "qs_t" in engine.params["layers"]["ssm"]["out_proj"]
+    app = create_app(engine=engine, settings=Settings())
+    transport = httpx.ASGITransport(app=app)
+    async with transport:
+        await app.router.startup()
+        async with httpx.AsyncClient(transport=transport,
+                                     base_url="http://test") as client:
+            eng = (await client.get("/health")).json()["engine"]
+            metrics = (await client.get("/metrics")).text
+    fmts = eng["weight_formats"]
+    assert set(expect["weight_formats"]) <= set(fmts)
+    for name in ("ffn.w_down", "ssm.out_proj", "gmu.out_proj"):
+        assert fmts[name] == expect["weight_formats"][name]
+    assert eng["head_kernel"] in (expect["head_kernel"], "bf16")
+    fill = eng["weight_fill_share"]
+    assert fill == 0.0 if share == "none" else 5.0 < fill < 12.5
+    line = [ln for ln in metrics.splitlines()
+            if ln.startswith("weight_fill_share ")]
+    assert len(line) == 1 and float(line[0].split()[1]) == fill

@@ -23,6 +23,12 @@ matmuls a prefill slice runs (:func:`rows_sweep`): us a call and us per 256
 rows at 8 / 256 / 512 / 1024 / 2048 rows, as one many-row call and as the
 256-row calls a wider operand was cut into before (docs/PERF.md "Rows of a
 fused matmul call" holds its table).
+
+``python tools/kernel_microbench.py tail`` times the dense calls of a K that
+ends in a TAIL tile (:func:`tail_sweep`: ``phi4flash``'s matrices, K 2560
+and 5120, at 16 and 1024 rows) in the tail layout and with the last tile
+filled up with zero blocks, as it was stored before PR 63: us a call and the
+share of ``stored bytes / 819 GB/s`` (PERF.md section 6, PR 63).
 """
 
 from __future__ import annotations
@@ -150,6 +156,86 @@ def rows_sweep(linear) -> list:
     return rows
 
 
+# the tail sweep: (name, format, N, K, stacked) of ``phi4flash``'s matrices
+# (the head is the one unstacked call: the Q6_K integer body)
+TAIL_SHAPES = [("gate_up", "q4k", 10240, 2560, True),
+               ("ssm_in", "q4k", 10240, 2560, True),
+               ("wq", "q4k", 2560, 2560, True),
+               ("wv", "q6k", 1280, 2560, True),
+               ("head", "q6k", 200064, 2560, False),
+               ("ssm_out", "q4k", 2560, 5120, True)]
+TAIL_ROWS = ((16, 400), (1024, 40))         # (rows, chained calls)
+
+
+def random_planes(fmt: str, n: int, k: int, key) -> dict:
+    """Planes of a (n, k) matrix in ``fmt``'s layout as ``prep_*`` stores a
+    K of ``k`` (whole tiles, and a tail's beside them), of random bytes and
+    small scales: a call's time does not depend on the values, and the
+    codecs would take minutes at these sizes."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import TK, tail_of
+
+    def ints(i, *shape):
+        return jax.random.randint(jax.random.fold_in(key, i), shape, -128,
+                                  128, jnp.int8)
+
+    def scales(i, tiles):
+        return (jax.random.normal(jax.random.fold_in(key, i),
+                                  (tiles, n, 128)) * 1e-3
+                ).astype(jnp.bfloat16)
+
+    tail = tail_of(k)
+    kw = k - tail
+    if fmt == "q4k":
+        w = {"qs": ints(0, n, kw // 2), "sm": scales(1, kw // TK)}
+        if tail:
+            w.update(qs_t=ints(2, n, tail // 2), sm_t=scales(3, 1))
+        return w
+    w = {"q4": ints(0, n, kw // 2), "q2": ints(1, n, kw // 4),
+         "sm6": scales(2, kw // TK)}
+    if tail:
+        w.update(q4_t=ints(3, n, tail // 2), q2_t=ints(4, n, tail // 4),
+                 sm6_t=scales(5, 1))
+    return w
+
+
+def tail_sweep(linear, linear_at) -> list:
+    """us a call in the ``tail`` layout and ``filled`` (the K filled up to
+    the next multiple of 2048, the activations with zeros, as both were
+    before PR 63), with the planes' bytes and their time at 819 GB/s."""
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import TK
+
+    out = []
+    for name, fmt, n, k, stacked in TAIL_SHAPES:
+        k_fill = -(-k // TK) * TK
+        for layout, kk in (("tail", k), ("filled", k_fill)):
+            w = random_planes(fmt, n, kk, jax.random.PRNGKey(n + k))
+            nbytes = sum(a.nbytes for a in w.values())
+            if stacked:
+                w = {key: a[None] for key, a in w.items()}
+
+            def fn(x, w, pad=kk - k):
+                x = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+                return linear_at(x, w, 0) if stacked else linear(x, w)
+
+            for b, iters in TAIL_ROWS:
+                if not stacked and b > 16:
+                    iters = 8       # (a 0.8 GB float32 result a call)
+                row = {"name": name, "fmt": fmt, "n": n, "k": k, "rows": b,
+                       "layout": layout, "MB": round(nbytes / 1e6, 2),
+                       "floor_us": round(nbytes / (HBM_GBPS * 1e3), 1)}
+                try:
+                    dt = timed_chain(fn, w, b, k, n, iters)
+                except Exception as e:  # noqa: BLE001 — what the chip refuses
+                    row["error"] = str(e)[:300]
+                else:
+                    row.update(us=round(dt * 1e6, 1), floor_share=round(
+                        100 * row["floor_us"] / (dt * 1e6), 1))
+                out.append(row)
+                print(json.dumps(row), file=sys.stderr, flush=True)
+            del w
+    return out
+
+
 def timed_chain(linear_fn, w, b: int, k: int, n: int, iters: int) -> float:
     """Mean per-matmul time over an ``iters``-step ON-DEVICE chain.
 
@@ -191,6 +277,11 @@ def main() -> None:
         return linear_at(x, w, 0) if "sm6" in w else linear_(x, w)
 
     dev = jax.devices()[0]
+    if sys.argv[1:] == ["tail"]:
+        print(json.dumps({"device": str(dev), "hbm_gbps": HBM_GBPS,
+                          "rows": tail_sweep(linear_, linear_at)}),
+              flush=True)
+        return
     if sys.argv[1:] == ["rows"]:
         print(json.dumps({"device": str(dev), "iters": ROW_ITERS,
                           "mxu_tflops": MXU_TFLOPS,

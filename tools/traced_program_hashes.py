@@ -6,6 +6,8 @@ five routed configurations' widths (a serial step, the lane engines'
 vmapped step, prefill slices), each with the chip's kernels and in
 interpret mode, and the grouped calls alone, a family each (``grouped.*``:
 a 16-lane decode step's few-row call and a 1024-token slice's many-row one).
+Since PR 63 also the dense calls of a K that ends in a tail tile (``tail.*``:
+``phi4flash``'s widths; a tree without the tail layout leaves them out).
 
     python tools/traced_program_hashes.py <tree> <out.json>     # once a tree
     git archive --prefix=.parent_check/ <parent> | tar x
@@ -51,12 +53,21 @@ def hashes(tree: str | None = None, only=None) -> dict:
         text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(*args)))
         return hashlib.sha256(text.encode()).hexdigest()[:16]
 
-    def planes(fmt, n, k, lead=()):
+    def planes(fmt, n, k, lead=(), t=""):
         sm = S((*lead, k // 2048, n, 128), bf16)
         if fmt == "q4k":
-            return {"qs": S((*lead, n, k // 2), i8), "sm": sm}
-        return {"q4": S((*lead, n, k // 2), i8),
-                "q2": S((*lead, n, k // 4), i8), "sm6": sm}
+            return {"qs" + t: S((*lead, n, k // 2), i8), "sm" + t: sm}
+        return {"q4" + t: S((*lead, n, k // 2), i8),
+                "q2" + t: S((*lead, n, k // 4), i8), "sm6" + t: sm}
+
+    def tail_planes(fmt, n, k, lead=()):
+        """The planes of a K that ends in a tail tile (PR 63): the whole
+        tiles', and the tail's under the ``_t`` keys."""
+        tail = k % 2048
+        w = planes(fmt, n, 2048, lead, "_t")
+        w = {key: S((*a.shape[:-1], a.shape[-1] * tail // 2048), a.dtype)
+             if a.dtype == i8 else a for key, a in w.items()}
+        return {**planes(fmt, n, k - tail, lead), **w}
 
     res = {}
 
@@ -81,6 +92,21 @@ def hashes(tree: str | None = None, only=None) -> dict:
                     put("stacked." + tag, lambda: traced(
                         lambda x, w, i: stacked(x, w, i, interpret=interp),
                         S((rows, k), bf16), planes(fmt, n, k, (2,)),
+                        S((), i32)))
+    # a K that ends in a tail tile (``phi4flash``: gate / up, ``ssm_out``, the
+    # head); a tree without ``qmatmul.tail_of`` has no such programs
+    if hasattr(P.qmatmul, "tail_of"):
+        for fmt, (plain, stacked) in dense.items():
+            for k, n in ((2560, 10240), (5120, 2560),
+                         (2560, 200064 if fmt == "q6k" else 2560)):
+                for rows in (16, 1024):
+                    tag = f"{fmt}.{k}x{n}.r{rows}.tpu"
+                    put("tail.dense." + tag, lambda: traced(
+                        lambda x, w: plain(x, w, interpret=False),
+                        S((rows, k), bf16), tail_planes(fmt, n, k)))
+                    put("tail.stacked." + tag, lambda: traced(
+                        lambda x, w, i: stacked(x, w, i, interpret=False),
+                        S((rows, k), bf16), tail_planes(fmt, n, k, (2,)),
                         S((), i32)))
     # the routed layer: (name, experts held, D, F, picks a token, tokens)
     for name, E, D, F, k, toks in (
