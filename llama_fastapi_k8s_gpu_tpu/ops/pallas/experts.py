@@ -174,9 +174,9 @@ def _few_k_tiles(kt: int, tn: int) -> int:
 
 
 # Each family's kernel is the integer dequantization of its packed bytes
-# under this module's grid; the stacked dense calls keep their float bodies
-# (and the ``LFKT_Q4K_KERNEL`` / ``LFKT_Q6K_KERNEL`` variants, which reach
-# no grouped call).  The Q4_K calls' float32 temporaries of a half plane are
+# under this module's grid; the dense Q4_K calls keep their float bodies
+# (and the ``LFKT_Q4K_KERNEL`` variants, which reach no grouped call), the
+# dense Q6_K calls run this family's body since PR 64.  The Q4_K calls' float32 temporaries of a half plane are
 # (TN, 1024): 4 MB each at a tile of 1024, so the many-row call is given the
 # few-row call's scoped VMEM (the Q6_K calls take a quarter at a time and
 # fit XLA's own limit; their programs keep their text)

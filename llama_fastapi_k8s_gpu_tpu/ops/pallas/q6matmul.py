@@ -46,20 +46,21 @@ tiles': ``q4_t`` (N, T/2) — byte ``b`` the low nibbles of columns ``b`` and
 ``b + T/2``, column ``c = e·S + s``; ``q2_t`` (N, T/4) — byte ``b`` the
 crumbs of columns ``b + j·T/4``; ``sm6_t`` (1, N, 128) — the S sub-scales
 tiled ``128/S`` times.  The activations' tail gains its own 256 correction
-columns.  The float body runs it at width T on the last K step
-(``qmatmul.tail_kernel``); the head's integer body joins the tail's
+columns.  The last K step of a call adds the tail's product
+(:func:`_q6k_head_tail_kernel`): the integer body joins the tail's
 quarters to the 512 columns a dot takes (one dot for 512, two for 1024,
 where a whole tile has four); :func:`dequant_rows6` puts its columns back
 in the file's order.
 
-Two bodies build that plane.  The stacked calls (a layer's ``w_down`` /
-``wv``) run :func:`_q6k_matmul_kernel`, the float form above, and are what
-``LFKT_Q6K_KERNEL`` chooses among.  :func:`_q6k_tile_product` builds the
-same plane bit for bit from integer operations on the packed bytes; it
-serves the ONE unstacked call, the vocabulary head's (:func:`_q6k_2d_raw`,
-:func:`_q6k_head_kernel`: a wide N tile, all of K a grid step, so the
-activations are fetched once), and, since PR 59, the grouped expert calls
+One body builds that plane, :func:`_q6k_tile_product`: bit for bit what the
+float form above gives, from integer operations on the packed bytes.  It
+serves the vocabulary head's call (:func:`_q6k_2d_raw`) and, since PR 64,
+the stacked calls (a layer's ``w_down`` / ``wv``: :func:`_q6k_2d_stacked_raw`)
+through one builder (:func:`_q6k_call`, tiled by the call's rows, N and K:
+:func:`_q6k_tiling`), and since PR 59 the grouped expert calls
 (:func:`_q6k_expert_kernel` under ops/pallas/experts.py's grid and tile).
+``LFKT_Q6K_KERNEL=pre`` is another LAYOUT (one combined plane, its own
+kernel), chosen at load.
 """
 
 from __future__ import annotations
@@ -94,38 +95,30 @@ from .qmatmul import (
     _spec_axis,
     stacked_pallas_call,
     stacked_partitioned,
-    tail_kernel,
     tail_of,
     TK,
     TM,
-    tn_prefs,
     _tn_prefs_for,
     _with_tail,
 )
 
-# first entry = the env-knob default (ops/pallas/qmatmul.py::_env_variant).
-# `cur` and `parfloor` are bit-identical planes (independent exact f32
-# floors vs the serial remainder chain) and trade places inside noise
-# across sessions: the 07-31 engine A/B had parfloor +0.75%, the 08-01
-# microbench has cur -0.1% per-op.  `cur` leads because the 08-01 banked
-# headline A/B (bench_q4km_variant_ab: 72.32 tok/s, the shipped-defaults
-# claim) ran LFKT_Q4K_KERNEL=resplit + LFKT_Q6K_KERNEL=cur — the default
-# tuple ships exactly the measured configuration (and the warm compile
-# cache the driver bench inherits).
+# ``LFKT_Q6K_KERNEL`` chooses a LAYOUT, at load (first entry = the default,
+# ops/pallas/qmatmul.py::_env_variant): every call is dispatched on the
+# planes it is given.  The float bodies the knob used to choose among for
+# the stacked calls (`cur`, `parfloor`, `vbf32`: the 07-31 / 08-01 A/Bs)
+# went in PR 64, when those calls took :func:`_q6k_tile_product`.
 #
-# `pre` is a LAYOUT variant (the others only re-order the kernel body):
-# prep stores one pre-combined int8 plane ``q6p = q6 ∈ [0,64)`` (N, K) at
-# 1 B/weight instead of the packed q4+q2 split at 0.75 B/weight.  The
-# kernel then pays ~3 VPU ops/weight (convert, ·eff, bf16 cast) instead
-# of ~7 (nibble+crumb extraction and recombination) — attacking the
-# measured 200 vs 147 µs gap to the Q4_K kernel at equal MXU tile count
-# (kernel_microbench_2026-08-01; the q4km mix carries ~32% of its
-# weights in Q6_K).  Numerics: ``q6·eff`` is an exact f32 product (6-bit
-# int × bf16 ≤ 14 mantissa bits), so the bf16-cast plane equals the
-# split path's plane; only the +8 hi-nibble bias moves from a separately
-# bf16-rounded corr column into the exact plane — deviation vs `cur` is
-# corr-rounding scale (~1e-3), same class as `onedot`, gated on chip.
-Q6K_VARIANTS = ("cur", "parfloor", "vbf32", "pre")
+# `pre`: prep stores one pre-combined int8 plane ``q6p = q6 ∈ [0,64)``
+# (N, K) at 1 B/weight instead of the packed q4+q2 split at 0.75 B/weight.
+# The kernel then pays ~3 VPU ops/weight (convert, ·eff, bf16 cast): what
+# the integer body pays on the split planes since, at a third more bytes.
+# No cell loads it (kernel_microbench_q6kpre_2026-08-01: a per-op win over
+# the float body, engine-flat).  Numerics: ``q6·eff`` is an exact f32
+# product (6-bit int × bf16 ≤ 14 mantissa bits), so the bf16-cast plane
+# equals the split path's plane; only the +8 hi-nibble bias moves from a
+# separately bf16-rounded corr column into the exact plane — deviation vs
+# the split layout is corr-rounding scale (~1e-3), gated on chip.
+Q6K_LAYOUTS = ("split", "pre")
 
 _SUBS6 = TK // 16    # 128 sub-blocks of 16 per k-tile
 TKA6 = TK + 256      # + [xsum_all(128) | xsum_hi(128)] correction columns
@@ -208,7 +201,7 @@ def prep_q6k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
     → the kernel layout dict: {"q4", "q2", "sm6"} (split layout), with
     {"q4_t", "q2_t", "sm6_t"} beside them where ``k_in`` ends in a tail
     (``qmatmul.tail_of``), or {"q6p", "sm6"} under ``LFKT_Q6K_KERNEL=pre``
-    (see Q6K_VARIANTS; a K with a tail keeps the split layout there).
+    (see Q6K_LAYOUTS; a K with a tail keeps the split layout there).
 
     Dispatches to the threaded C++ packer (native/src/gguf_dequant.cpp,
     bit-identical planes — tests/test_native.py) when available; the numpy
@@ -219,7 +212,7 @@ def prep_q6k(raw: np.ndarray, n_out: int, k_in: int) -> dict:
     from ...native import native_prep_q6k
 
     tail = tail_of(k_in)
-    pre = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS) == "pre" and not tail
+    pre = _env_variant("LFKT_Q6K_KERNEL", Q6K_LAYOUTS) == "pre" and not tail
     nat = native_prep_q6k(raw, n_out, k_in)
     if nat is not None:
         if pre:
@@ -332,114 +325,6 @@ def dequant_rows6(w: dict, rows: jax.Array, k_in: int) -> jax.Array:
 # kernel
 # ---------------------------------------------------------------------------
 
-def _q6k_matmul_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
-                       variant="cur", accum=_q4k_accum):
-    # ``W``: the tile's columns, TK or a tail's own width, read off the block
-    # (``qmatmul._q4k_matmul_kernel``); ``accum``: what folds the tile's
-    # product into the output block
-    TN, W = q4_ref.shape[0], 2 * q4_ref.shape[1]
-    v4 = q4_ref[...].astype(jnp.float32)              # (TN, W/2)
-    h = jnp.floor(v4 * 0.0625)
-
-    u = q2_ref[...].astype(jnp.float32) + 128.0       # (TN, W/4)
-
-    sm = sm_ref[...].reshape(TN, 128)                 # eff = d·sc
-    corr = jnp.concatenate([sm * -32.0, sm * 8.0], axis=1).astype(jnp.bfloat16)
-
-    if variant == "vbf32":
-        _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret, W,
-                        accum)
-        return
-
-    l = v4 - h * 16.0
-    nib = jnp.concatenate([l, h], axis=1)             # (TN, W); hi bias → corr
-    if variant == "parfloor":
-        # all floors depend only on u (u ≤ 255 integer; /4,/16,/64 are
-        # exact power-of-two scalings, so every quantity is an exact f32
-        # integer and the crumbs come out bit-identical to the chain)
-        c3 = jnp.floor(u * (1.0 / 64.0))
-        f2 = jnp.floor(u * 0.0625)
-        f1 = jnp.floor(u * 0.25)
-        c2 = f2 - 4.0 * c3
-        c1 = f1 - 4.0 * f2
-        c0 = u - 4.0 * f1
-    else:
-        c3 = jnp.floor(u * (1.0 / 64.0))
-        r = u - 64.0 * c3
-        c2 = jnp.floor(r * 0.0625)
-        r = r - 16.0 * c2
-        c1 = jnp.floor(r * 0.25)
-        c0 = r - 4.0 * c1
-    crumb = jnp.concatenate([c0, c1, c2, c3], axis=1)  # (TN, W)
-
-    eff = _lane_repeat(sm, W // 128, interpret)
-    eff16 = _lane_repeat(sm * 16.0, W // 128, interpret)
-
-    a = (nib * eff + crumb * eff16).astype(jnp.bfloat16)
-
-    xpa = xpa_ref[...]
-    part = jax.lax.dot_general(
-        xpa[:, :W], a, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    part += jax.lax.dot_general(
-        xpa[:, W:], corr, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    accum(o_ref, part)
-
-
-def _q6k_vbf32_body(xpa_ref, v4, h, u, sm, corr, o_ref, interpret, W, accum):
-    """Activation-side recombination with f32 planes (Q6_K analogue of the
-    Q4_K ``vbf32`` variant, ops/pallas/qmatmul.py).
-
-    Nibbles: ``x_lo·(l·eff) + x_hi·(h·eff)`` rewritten through ``l = v4 −
-    16h`` as ``x_lo·(v4·eff) + (x_hi − 16·x_lo)·(h·eff)`` — no per-weight
-    reconstruction.  Crumbs: with partial floors ``f1 = ⌊u/4⌋``,
-    ``f2 = ⌊u/16⌋``, ``c3 = ⌊u/64⌋`` the base-4 digit sum telescopes,
-    ``Σⱼ xⱼ·cⱼ = x₀·u + (x₁−4x₀)·f1 + (x₂−4x₁)·f2 + (x₃−4x₂)·c3``, so no
-    digit is ever isolated.  Per packed byte: 1 floor + 2 muls (nibbles),
-    3 floors + 4 muls (4 crumbs) — vs the default's per-WEIGHT multiply,
-    add and bf16 cast.  All planes are exact f32 products (≤8-bit int ×
-    bf16 scale needs ≤16 mantissa bits); the dots take f32 operands so the
-    amplified-magnitude cancellations stay at f32 accuracy IF the backend's
-    f32 dot is multi-pass (residual ~64·2⁻²² per term — below the shared
-    bf16 corr path); see the chip-gate note at the dot call below.
-
-    Scale alignment: a crumb byte's four columns ``b+512j`` and a nibble
-    byte's pair ``b, b+1024`` all share sub-block ``b % 128`` (512 and
-    1024 are multiples of 128), so one repeated ``sm`` plane serves every
-    term."""
-    eff_h = _lane_repeat(sm, (W // 2) // 128, interpret)
-    eff_q = _lane_repeat(sm * 16.0, (W // 4) // 128, interpret)
-
-    f1 = jnp.floor(u * 0.25)
-    f2 = jnp.floor(u * 0.0625)
-    c3 = jnp.floor(u * (1.0 / 64.0))
-
-    xpa = xpa_ref[...]
-    Q = W // 4
-    x0 = xpa[:, 0 * Q: 1 * Q].astype(jnp.float32)
-    x1 = xpa[:, 1 * Q: 2 * Q].astype(jnp.float32)
-    x2 = xpa[:, 2 * Q: 3 * Q].astype(jnp.float32)
-    x3 = xpa[:, 3 * Q: 4 * Q].astype(jnp.float32)
-    x_lo = jnp.concatenate([x0, x1], axis=1)          # columns [0, W/2)
-    x_hi = jnp.concatenate([x2, x3], axis=1)          # columns [W/2, W)
-
-    # f32-operand dots; Mosaic rejects an explicit precision attr — see the
-    # Q4_K vbf32 note (qmatmul.py): the chip microbench's numerics
-    # cross-check gates whether its f32 lowering preserves the cancellation
-    dot = functools.partial(
-        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    part = dot(x_lo, v4 * eff_h)
-    part += dot(x_hi - 16.0 * x_lo, h * eff_h)
-    part += dot(x0, u * eff_q)
-    part += dot(x1 - 4.0 * x0, f1 * eff_q)
-    part += dot(x2 - 4.0 * x1, f2 * eff_q)
-    part += dot(x3 - 4.0 * x2, c3 * eff_q)
-    part += dot(xpa[:, W:], corr)
-    accum(o_ref, part)
-
-
 def _q6k_pre_kernel(xpa_ref, q6p_ref, sm_ref, o_ref, *, interpret):
     """`pre` layout body: one combined int8 plane, ~3 VPU ops/weight.
 
@@ -478,55 +363,57 @@ def _q6k_pre_specs(B: int, TN: int):
     )
 
 
-_TN_PREFS_Q6K = (256, 128)  # wider f32 intermediates than Q4_K: smaller TN
-
-
-def _q6k_specs(B: int, TN: int, tail: int = 0):
-    """Single tiling definition for both the unstacked and stacked calls
-    (see qmatmul._q4k_specs; ``tail``: the columns of the K's tail tile)."""
-    x = ((B, TKA6), lambda n, k: (0, k))
-    planes = [
-        ((TN, TK // 2), lambda n, k: (n, k)),
-        ((TN, TK // 4), lambda n, k: (n, k)),
-        ((1, TN, 128), lambda n, k: (k, n, 0)),
-    ]
-    out = ((B, TN), lambda n, k: (0, n))
-    if not tail:
-        return [x, *planes], out
-    return [
-        x, ((B, tail + 256), lambda n, k: (0, 0)), *planes,
-        ((TN, tail // 2), lambda n, k: (n, 0)),
-        ((TN, tail // 4), lambda n, k: (n, 0)),
-        ((1, TN, 128), lambda n, k: (0, n, 0)),
-    ], out
+_TN_PREFS_Q6K = (256, 128)  # the `pre` layout's calls (float32 planes)
 
 
 # ---------------------------------------------------------------------------
-# the head's call: the one unstacked split-layout matmul (``ops/linear.py
-# linear`` is called by the models' ``head`` functions alone)
+# the split layout's calls: the vocabulary head's (the one unstacked fused
+# tensor: ``ops/linear.py linear`` is called by the models' ``head``
+# functions alone) and a layer's stacked ``w_down`` / ``wv``, one body
+# (:func:`_q6k_tile_product`) under one builder (:func:`_q6k_call`), tiled
+# by the call's shape (:func:`_q6k_tiling`)
 # ---------------------------------------------------------------------------
 
-#: the widest N tile of the head's call, as 128-row units: the tile is the
+#: the widest N tile of a few-row call, as 128-row units: the tile is the
 #: largest ``128 * d`` with ``d`` up to this that divides N (128256 = 167 x
 #: 768, 153600 = 150 x 1024).  On the chip at 153600 x 6144, 16 rows, all
 #: of K a step: 1.17 / 1.14 / 1.12 ms at d = 4 / 8 / 16, and 1.62 at the
-#: stacked calls' (256, one K tile), whose 1800 grid steps cost 0.27 us each
+#: float body's (256, one K tile), whose 1800 grid steps cost 0.27 us each
 HEAD_TN_UNITS = 8
 #: the most weights of one grid step's block (N tile x K tiles x 2048)
 HEAD_W_BLOCK = 1024 * 4 * 2048
-#: scoped VMEM of the head's call
+#: scoped VMEM of a few-row call
 HEAD_VMEM = 64 * 2 ** 20
+#: the fewest grid steps a few-row call is tiled into where its shape allows:
+#: the first block's fetch hides behind nothing, so a call of one or two
+#: steps fetches, then computes.  On the chip (docs/PERF.md "Rows of a fused
+#: matmul call", PR 64): ``wv`` at 1024 x 4096, 16 rows, 8.0 us at 4 steps,
+#: 8.3-8.7 at 2 or 8, 10.7 at one, 10.6 at 16; from 8 steps up a ``w_down``
+#: reads the same within the sweep's 2 % ((512, 7) 8 steps, (256, 7) 16,
+#: (1024, 1) 28 at 4096 x 14336: 78-81 % of ``stored bytes / 819 GB/s``)
+MIN_STEPS = 4
+#: the most bytes of a few-row call's activation block (K tiles a step x rows
+#: x 2304 bfloat16): four K tiles of a :data:`TM`-row block, what the head's
+#: widest call takes.  A slice beside live lanes (256 rows) of a K of seven
+#: tiles takes one a step
+X_BLOCK = 4 * TM * TKA6 * 2
 #: what ``/health`` ``engine.head_kernel`` calls :func:`_q6k_2d_raw` as it is
 #: built here (serving/registry.py ``head_kind``): a change of the body the
 #: unstacked call runs changes this name with it
 HEAD_KERNEL = "q6k-head"
+#: what ``/health`` ``engine.q6k_kernel`` calls the body of the STACKED
+#: split-layout calls (serving/registry.py ``stacked_q6k_kind``): the integer
+#: dequantization of :func:`_q6k_tile_product`, as ``q6k-int`` names it for
+#: the grouped expert calls (ops/pallas/experts.py ``FAMILIES``)
+STACKED_KERNEL = "q6k-int"
 
 _CRUMB = 0x30303030              # a crumb, where ``q6 = nib | crumb << 4`` has it
 
 
 def _q6k_tile_product(q4, q2, sm, xpa, interpret, width: int = TK):
-    """One K tile of a Q6_K product: :func:`_q6k_matmul_kernel`'s plane, bit
-    for bit, at half its vector operations.  The packed bytes are taken
+    """One K tile of a Q6_K product: :func:`dequant_ref6`'s plane (the float
+    ``floor`` form of the module's text) bit for bit, at half that form's
+    vector operations.  The packed bytes are taken
     apart as INTEGERS, four weight rows a 32-bit word (the int8 planes
     bitcast in the kernel), and joined to ``q6 = nib | crumb << 4`` before
     the scale, so that a weight pays one conversion, one multiply by ``eff``
@@ -538,8 +425,9 @@ def _q6k_tile_product(q4, q2, sm, xpa, interpret, width: int = TK):
     (TN, TK/2) and ``q2()`` (TN, TK/4) int8, ``sm()`` (TN, 128), ``xpa()``
     (B, TKA6): thunks, so that each read stays where it was among the
     operations and the head's program keeps its text
-    (tools/traced_program_hashes.py).  Returns the (B, TN) float32 product.  The bodies that run it:
-    the head's (:func:`_q6k_head_kernel`) and the grouped expert calls'
+    (tools/traced_program_hashes.py).  Returns the (B, TN) float32 product.
+    The bodies that run it: the head's and the stacked calls'
+    (:func:`_q6k_head_kernel`) and the grouped expert calls'
     (:func:`_q6k_expert_kernel`).  ``width``: the tile's columns, a tail's
     512 or 1024 (q4 (TN, width/2), q2 (TN, width/4), xpa (B, width + 256)):
     its quarters are joined to the 512 columns a dot takes, one dot or two
@@ -589,8 +477,9 @@ def _q6k_tile_product(q4, q2, sm, xpa, interpret, width: int = TK):
 
 def _q6k_head_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
                      tiles, accumulate):
-    """The head's body: :func:`_q6k_tile_product` over ``tiles`` K tiles a
-    grid step.  xpa (tiles, B, TKA6), which is the whole of it, fetched once
+    """The body of the split layout's calls, the head's (whose name it keeps)
+    and the stacked ones: :func:`_q6k_tile_product` over ``tiles`` K tiles a
+    grid step.  xpa (B, tiles * TKA6), which is the whole of it, fetched once
     a call, where the step holds all of K; q4 (TN, tiles * TK/2), q2 (TN,
     tiles * TK/4) int8; sm (tiles, TN, 128)."""
     part = _q6k_head_tiles(xpa_ref, q4_ref, q2_ref, sm_ref, interpret, tiles)
@@ -609,7 +498,8 @@ def _q6k_head_tiles(xpa_ref, q4_ref, q2_ref, sm_ref, interpret, tiles):
         p = _q6k_tile_product(
             lambda: q4_ref[:, j * 2 * Q:(j + 1) * 2 * Q],
             lambda: q2_ref[:, j * Q:(j + 1) * Q],
-            lambda: sm_ref[j], lambda: xpa_ref[j], interpret)
+            lambda: sm_ref[j],
+            lambda: xpa_ref[:, j * TKA6:(j + 1) * TKA6], interpret)
         part = p if part is None else part + p
     return part
 
@@ -651,72 +541,93 @@ def _q6k_expert_kernel(xpa_ref, q4_ref, q2_ref, sm_ref, o_ref, *, interpret,
         interpret))
 
 
+def _n_tiles(N: int, interpret: bool) -> list:
+    """The N tiles ``128 * d``, ``d`` up to :data:`HEAD_TN_UNITS`, that
+    divide N, widest first (a narrower N, in interpret mode only: what
+    divides it)."""
+    return [128 * d for d in range(HEAD_TN_UNITS, 0, -1)
+            if N % (128 * d) == 0] or [_pick_tn(N, interpret, ())]
+
+
 def wide_tn(N: int, interpret: bool) -> int:
-    """The widest N tile ``128 * d``, ``d`` up to :data:`HEAD_TN_UNITS`,
-    that divides N: the head's few-row calls' and the grouped Q6_K expert
-    calls' (a narrower N, in interpret mode only: what divides it)."""
-    return next((128 * d for d in range(HEAD_TN_UNITS, 0, -1)
-                 if N % (128 * d) == 0), None) or _pick_tn(N, interpret, ())
+    """The widest of :func:`_n_tiles`: the N tile of the grouped Q6_K and
+    Q4_K expert calls, and a head's."""
+    return _n_tiles(N, interpret)[0]
 
 
-def _head_tiling(N: int, B: int, kt: int, interpret: bool):
-    """(N tile, K tiles a grid step) of the head's call.  The rows of a
-    decode step or of a slice beside live lanes: the widest ``128 * d``
-    that divides N (:data:`HEAD_TN_UNITS`) and as many K tiles a step as
-    divide the call's and fit :data:`HEAD_W_BLOCK`.  A many-row call keeps
-    its own tiles, a K tile a step."""
+def _q6k_tiling(N: int, B: int, kt: int, interpret: bool):
+    """(N tile, K tiles a grid step) of a split-layout call, from its shape
+    alone: ``B`` rows against (N, ``kt`` K tiles).  The rows of a decode step
+    or of a slice beside live lanes (up to :data:`TM`): of the N tiles ``128
+    * d`` that divide N (:data:`HEAD_TN_UNITS`) and the K tiles a step that
+    divide the call's, fit :data:`HEAD_W_BLOCK` and keep the activations'
+    block inside :data:`X_BLOCK`, the pair of the FEWEST grid steps that
+    still leaves :data:`MIN_STEPS` of them (the fewest of all where none
+    does), and of two such pairs the one with more of K a step (where a step
+    holds all of K the activations are fetched once a call).  The head's N is
+    so tall that this is its widest tile and all the K tiles that fit;
+    ``w_down`` at 4096 x 14336 takes (512, 7): 8 steps where the float body
+    had (256, 1): 112; ``wv`` at 1024 x 4096 (256, 2): 4 for 8.  A many-row
+    call keeps its own tiles, a K tile a step."""
     if B > TM:
         return _pick_tn(N, interpret, prefs=MANYROW_TN), 1
-    tn = wide_tn(N, interpret)
-    tiles = max(t for t in range(1, kt + 1)
-                if kt % t == 0 and (t == 1 or tn * t * TK <= HEAD_W_BLOCK))
-    return tn, tiles
+    pairs = [(tn, t) for tn in _n_tiles(N, interpret)
+             for t in range(1, kt + 1)
+             if kt % t == 0 and (t == 1 or (
+                 tn * t * TK <= HEAD_W_BLOCK and t * B * TKA6 * 2 <= X_BLOCK))]
+
+    def steps(pair):
+        return (N // pair[0]) * (kt // pair[1])
+
+    enough = [p for p in pairs if steps(p) >= MIN_STEPS] or pairs
+    return min(enough, key=lambda p: (steps(p), -p[1], -p[0]))
+
+
+def _q6k_call(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
+              tail: tuple, interpret: bool, idx=None) -> jax.Array:
+    """The split layout's call, the ONE builder of its ``pallas_call``:
+    ``xpa`` (B, kt * TKA6, and a tail's T + 256 columns after them) against
+    the planes of (N, kt whole K tiles) and ``tail`` (q4_t, q2_t, sm6_t; ()
+    for none), unstacked (``idx`` None: the head's) or on layer ``idx`` of
+    stacked planes."""
+    B, N, kt = xpa.shape[0], q4.shape[-2], xpa.shape[1] // TKA6
+    TN, tiles = _q6k_tiling(N, B, kt, interpret)
+    kernel = functools.partial(_q6k_head_kernel, interpret=interpret,
+                               tiles=tiles, accumulate=tiles < kt)
+    acts = [((B, tiles * TKA6), lambda n, k: (0, k))]
+    operands = [xpa]
+    planes = [
+        ((TN, tiles * TK // 2), lambda n, k: (n, k)),
+        ((TN, tiles * TK // 4), lambda n, k: (n, k)),
+        ((tiles, TN, 128), lambda n, k: (k, n, 0)),
+    ]
+    if tail:
+        T = 2 * tail[0].shape[-1]
+        kernel = functools.partial(
+            _q6k_head_tail_kernel, interpret=interpret, tiles=tiles,
+            steps=kt // tiles)
+        acts.append(((B, T + 256), lambda n, k: (0, 0)))
+        operands.append(xpa[:, kt * TKA6:])     # an operand of its own
+        planes += [
+            ((TN, T // 2), lambda n, k: (n, 0)),
+            ((TN, T // 4), lambda n, k: (n, 0)),
+            ((1, TN, 128), lambda n, k: (0, n, 0)),
+        ]
+    args = (kernel, (N // TN, kt // tiles), acts + planes,
+            ((B, TN), lambda n, k: (0, n)),
+            jax.ShapeDtypeStruct((B, N), jnp.float32), interpret,
+            kernel_name("q6k", B))
+    if idx is None:
+        return plain_pallas_call(*args, few_vmem=HEAD_VMEM)(
+            *operands, q4, q2, sm, *tail)
+    return stacked_pallas_call(*args, n_act=len(acts), few_vmem=HEAD_VMEM)(
+        idx, *operands, q4, q2, sm, *tail)
 
 
 def _q6k_2d_raw(xpa: jax.Array, q4: jax.Array, q2: jax.Array, sm: jax.Array,
                 interpret: bool, tail: tuple = ()) -> jax.Array:
     """``tail``: (q4_t, q2_t, sm6_t), the planes of K's tail tile."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, KA = xpa.shape
-    kt = KA // TKA6
-    N = q4.shape[0]
-    TN, tiles = _head_tiling(N, B, kt, interpret)
-    kernel = functools.partial(_q6k_head_kernel, interpret=interpret,
-                               tiles=tiles, accumulate=tiles < kt)
-    in_specs = [
-        pl.BlockSpec((tiles, B, TKA6), lambda n, k: (k, 0, 0)),
-        pl.BlockSpec((TN, tiles * TK // 2), lambda n, k: (n, k)),
-        pl.BlockSpec((TN, tiles * TK // 4), lambda n, k: (n, k)),
-        pl.BlockSpec((tiles, TN, 128), lambda n, k: (k, n, 0)),
-    ]
-    whole = xpa[:, :kt * TKA6] if tail else xpa
-    operands = (jnp.transpose(whole.reshape(B, kt, TKA6), (1, 0, 2)),
-                q4, q2, sm)
-    if tail:
-        T = 2 * tail[0].shape[1]
-        kernel = functools.partial(
-            _q6k_head_tail_kernel, interpret=interpret, tiles=tiles,
-            steps=kt // tiles)
-        in_specs = [
-            in_specs[0], pl.BlockSpec((B, T + 256), lambda n, k: (0, 0)),
-            *in_specs[1:],
-            pl.BlockSpec((TN, T // 2), lambda n, k: (n, 0)),
-            pl.BlockSpec((TN, T // 4), lambda n, k: (n, 0)),
-            pl.BlockSpec((1, TN, 128), lambda n, k: (0, n, 0)),
-        ]
-        operands = (operands[0], xpa[:, kt * TKA6:], *operands[1:], *tail)
-    return pl.pallas_call(
-        kernel,
-        grid=(N // TN, kt // tiles),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, TN), lambda n, k: (0, n)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        interpret=interpret,
-        name=kernel_name("q6k", B),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=MANYROW_VMEM if B > TM else HEAD_VMEM),
-    )(*operands)
+    return _q6k_call(xpa, q4, q2, sm, tail, interpret)
 
 
 def _q6k_pre_2d_raw(xpa: jax.Array, q6p: jax.Array, sm: jax.Array,
@@ -841,36 +752,15 @@ def _q6k_2d_partitioned(interpret: bool, tail: bool = False):
 
 def _q6k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, q4: jax.Array,
                         q2: jax.Array, sm: jax.Array, *tail,
-                        interpret: bool, variant: str = "cur") -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """``tail``: (q4_t, q2_t, sm6_t), the stacked planes of K's tail tile."""
-    B, KA = xpa.shape
-    K = (KA // TKA6) * TK
-    N = q4.shape[1]
-    TN = _pick_tn(N, interpret, prefs=tn_prefs(B, _TN_PREFS_Q6K))
-    in_specs, out_spec = _q6k_specs(
-        B, TN, 2 * tail[0].shape[-1] if tail else 0)
-    kernel = functools.partial(_q6k_matmul_kernel, interpret=interpret,
-                               variant=variant)
-    call = stacked_pallas_call(
-        tail_kernel(kernel, K // TK) if tail else kernel,
-        grid=(N // TN, K // TK),
-        in_specs=in_specs,
-        out_spec=out_spec,
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        interpret=interpret,
-        name=kernel_name("q6k", B),
-        n_act=2 if tail else 1,
-    )
-    if tail:
-        return call(idx, xpa, xpa[:, K // TK * TKA6:], q4, q2, sm, *tail)
-    return call(idx, xpa, q4, q2, sm)
+    return _q6k_call(xpa, q4, q2, sm, tail, interpret, idx)
 
 
-@functools.lru_cache(maxsize=16)
-def _q6k_2d_stacked_partitioned(interpret: bool, variant: str = "cur",
-                                tail: bool = False):
+@functools.lru_cache(maxsize=4)
+def _q6k_2d_stacked_partitioned(interpret: bool, tail: bool = False):
     return stacked_partitioned(
-        functools.partial(_q6k_2d_stacked_raw, variant=variant),
+        _q6k_2d_stacked_raw,
         "i, b k, l n j, l n p, l t n m, l n q, l n r, l u n s -> b n" if tail
         else "i, b k, l n j, l n p, l t n m -> b n", interpret, MANYROW_MAX)
 
@@ -900,10 +790,7 @@ def q6k_matmul_stacked(x: jax.Array, w: dict, idx,
         y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws),
                          xpa, w["q6p"], w["sm6"])
     else:
-        var = _env_variant("LFKT_Q6K_KERNEL", Q6K_VARIANTS)
-        fn = _q6k_2d_stacked_partitioned(
-            _interpret(interpret), "cur" if var == "pre" else var,
-            "q4_t" in w)
+        fn = _q6k_2d_stacked_partitioned(_interpret(interpret), "q4_t" in w)
         y = batched_rows(lambda xp, *ws: fn(i1, xp, *ws),
                          xpa, *_q6k_planes(w), bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)
@@ -920,8 +807,6 @@ def q6k_matmul(x: jax.Array, w: dict, interpret: bool | None = None) -> jax.Arra
         fn = _q6k_pre_2d_partitioned(_interpret(interpret))
         y = batched_rows(fn, xpa, w["q6p"], w["sm6"])
     else:
-        # the split layout's unstacked call is the head's, whatever
-        # LFKT_Q6K_KERNEL says of the stacked bodies
         fn = _q6k_2d_partitioned(_interpret(interpret), "q4_t" in w)
         y = batched_rows(fn, xpa, *_q6k_planes(w), bound=MANYROW_MAX)
     return y.reshape(*lead, -1).astype(x.dtype)

@@ -564,15 +564,17 @@ def tn_prefs(B: int, prefs: tuple) -> tuple:
     return MANYROW_TN if B > TM else _tn_prefs_for(B, prefs)
 
 
-def _manyrow_kw(B: int) -> dict:
-    """pallas_call keywords by the call's rows: none up to :data:`TM` (the
-    call is built as it always was), else the raised VMEM limit."""
-    if B <= TM:
+def _manyrow_kw(B: int, few_vmem: int | None = None) -> dict:
+    """pallas_call keywords by the call's rows: up to :data:`TM` none (the
+    call is built as it always was) or the family's own scoped VMEM
+    (``few_vmem``: the Q6_K calls', whose few-row blocks are wide), else
+    the raised VMEM limit."""
+    if B <= TM and few_vmem is None:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=MANYROW_VMEM)}
+        vmem_limit_bytes=MANYROW_VMEM if B > TM else few_vmem)}
 
 
 def _q4k_specs(B: int, TN: int, tail: int = 0):
@@ -637,9 +639,11 @@ def kernel_name(family: str, rows: int) -> str:
 
 
 def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
-                      interpret: bool, name: str):
+                      interpret: bool, name: str,
+                      few_vmem: int | None = None):
     """pl.pallas_call from the same (block_shape, index_map) pairs
-    :func:`stacked_pallas_call` consumes; ``name``: :func:`kernel_name`."""
+    :func:`stacked_pallas_call` consumes; ``name``: :func:`kernel_name`;
+    ``few_vmem``: :func:`_manyrow_kw`'s."""
     o_block, o_map = out_spec
     return pl.pallas_call(
         kernel,
@@ -649,7 +653,7 @@ def plain_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
         out_shape=out_shape,
         interpret=interpret,
         name=name,
-        **_manyrow_kw(out_shape.shape[0]),
+        **_manyrow_kw(out_shape.shape[0], few_vmem),
     )
 
 
@@ -757,8 +761,9 @@ def _q4k_2d_partitioned(interpret: bool, variant: str = "cur",
 
 class _NoLead:
     """Ref adapter hiding the leading length-1 layer axis of a stacked
-    weight block, so the unstacked kernel bodies run unchanged (they only
-    use ``ref.shape`` and ``ref[...]``)."""
+    weight block, so the unstacked kernel bodies run unchanged (they use
+    ``ref.shape``, ``ref[...]`` and, the Q6_K body of several K tiles a
+    grid step, indices and slices of the block)."""
 
     __slots__ = ("_ref",)
 
@@ -770,11 +775,14 @@ class _NoLead:
         return self._ref.shape[1:]
 
     def __getitem__(self, idx):
-        return self._ref[idx].reshape(self._ref.shape[1:])
+        if idx is Ellipsis:     # as it always read: those programs' text
+            return self._ref[idx].reshape(self._ref.shape[1:])
+        return self._ref[(0, *idx) if isinstance(idx, tuple) else (0, idx)]
 
 
 def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
-                        interpret: bool, name: str, n_act: int = 1):
+                        interpret: bool, name: str, n_act: int = 1,
+                        few_vmem: int | None = None):
     """Build ``fn(idx, xpa, *stacked_planes)`` running ``kernel`` (an
     unstacked fused kernel ``(xpa_ref, *plane_refs, o_ref)``) against layer
     ``idx[0]`` of weight planes stacked as (L, ...) arrays.
@@ -784,7 +792,8 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
     the weight planes; weight specs get the layer dim
     prepended and their index_maps extended with the prefetched scalar.
     Interpret mode (CPU tests) runs the same code path — pallas emulates
-    scalar prefetch.  ``name``: :func:`kernel_name`."""
+    scalar prefetch.  ``name``: :func:`kernel_name`; ``few_vmem``:
+    :func:`_manyrow_kw`'s."""
     from jax.experimental.pallas import tpu as pltpu
 
     def lift(block, imap):
@@ -809,7 +818,7 @@ def stacked_pallas_call(kernel, grid, in_specs, out_spec, out_shape,
 
     return pl.pallas_call(
         wrapped, grid_spec=gs, out_shape=out_shape, interpret=interpret,
-        name=name, **_manyrow_kw(out_shape.shape[0]))
+        name=name, **_manyrow_kw(out_shape.shape[0], few_vmem))
 
 
 def _q4k_2d_stacked_raw(idx: jax.Array, xpa: jax.Array, qs: jax.Array,

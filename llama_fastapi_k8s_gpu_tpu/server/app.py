@@ -1372,6 +1372,8 @@ def create_app(engine=None, settings: Settings | None = None,
                 "attn_impl": getattr(cfg, "attn_impl", None),
                 # what serves the vocabulary head (``_head_kernel``)
                 "head_kernel": _head_kernel(params),
+                # and the layers' stacked Q6_K linears (``_q6k_kernel``)
+                "q6k_kernel": _q6k_kernel(params),
                 # who stores a decode step's K/V row in the ring: the
                 # decode kernel, or XLA (docs/KV_CACHE.md)
                 "ring_write": _ring_write(cfg),
@@ -1848,6 +1850,20 @@ def _head_kernel(params) -> str | None:
 
     leaf = params.get("output") if isinstance(params, dict) else None
     return head_kind(leaf) if isinstance(leaf, dict) else None
+
+
+def _q6k_kernel(params) -> str | None:
+    """``/health`` ``engine.q6k_kernel``: the body the layers' stacked Q6_K
+    linears run (serving/registry.py ``stacked_q6k_kind``: ``q6k-int`` for
+    the split layout, the integer dequantization the head and the grouped
+    expert calls share); None without parameters or without such a tensor."""
+    if not isinstance(params, dict) or "layers" not in params:
+        return None
+    from ..models.params import flat_layers
+    from ..serving.registry import stacked_q6k_kind
+
+    return stacked_q6k_kind(leaf for _, leaf in flat_layers(params["layers"])
+                            if isinstance(leaf, dict))
 
 
 def _ring_write(cfg) -> str | None:

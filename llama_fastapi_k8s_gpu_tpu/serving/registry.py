@@ -83,6 +83,20 @@ def head_kind(leaf: dict) -> str:
     return linear_kind(leaf)
 
 
+def stacked_q6k_kind(leaves) -> str | None:
+    """What runs the layers' stacked Q6_K linears (``leaves``: their leaf
+    dicts), the one place that says which dequantization a pod runs: the
+    split layout's calls run the body named where it is built
+    (ops/pallas/q6matmul.py ``STACKED_KERNEL``), the `pre` layout's its own
+    kernel (:func:`linear_kind`'s name); None where no layer keeps a fused
+    Q6_K tensor."""
+    from ..ops.pallas.q6matmul import STACKED_KERNEL
+
+    kinds = {STACKED_KERNEL if "q4" in leaf else linear_kind(leaf)
+             for leaf in leaves if "q4" in leaf or "q6p" in leaf}
+    return "+".join(sorted(kinds)) or None
+
+
 def _quant_summary(engine) -> str | None:
     """One label for how the model's linear weights are served (e.g.
     ``q4k-fused`` or ``bf16+int8`` when groups differ) — the /health

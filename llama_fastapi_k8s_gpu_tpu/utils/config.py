@@ -519,7 +519,8 @@ KNOBS: dict[str, Knob] = _register(
     Knob("LFKT_Q5K_KERNEL", str, "fused Q5_K kernel variant (A/B)",
          default=""),
     Knob("LFKT_Q6K_KERNEL", str,
-         "fused Q6_K variant of the stacked dense calls (A/B)", default=""),
+         "fused Q6_K LAYOUT a load writes: split (default) | pre",
+         default=""),
 )
 
 

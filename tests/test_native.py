@@ -160,7 +160,7 @@ def test_prep_bit_exact(monkeypatch, kind, raw_kind, n, k):
     # `pre` combined-plane layout on top under its env default (Q5_K since
     # the 2026-08-01 A/B), so pin the split layout for the comparison
     monkeypatch.setenv("LFKT_Q5K_KERNEL", "cur")
-    monkeypatch.setenv("LFKT_Q6K_KERNEL", "cur")
+    monkeypatch.setenv("LFKT_Q6K_KERNEL", "split")
     module, ref_name, nat_name, codec, gtype = _packer_case(kind)
     rng = np.random.default_rng(hash((kind, raw_kind, n, k)) % 2**32)
     if raw_kind == "codec":
@@ -194,7 +194,7 @@ def test_prep_tail_bit_exact(monkeypatch, kind, raw_kind, n, k):
     the tiled scales of the tail included."""
     import llama_fastapi_k8s_gpu_tpu.native as native_mod
 
-    monkeypatch.setenv("LFKT_Q6K_KERNEL", "cur")
+    monkeypatch.setenv("LFKT_Q6K_KERNEL", "split")
     module, ref_name, nat_name, codec, gtype = _packer_case(kind)
     rng = np.random.default_rng(hash((kind, raw_kind, n, k)) % 2**32)
     if raw_kind == "codec":

@@ -181,17 +181,18 @@ def test_fused_matmul_partitioned_matches_unsharded(maker_name, monkeypatch):
 
 def test_shipped_kernel_defaults_are_the_measured_configuration():
     """The tuple heads are a MEASURED decision, not style: the 2026-08-01
-    chip A/B banked 72.32 tok/s with exactly q4k=resplit + q6k=cur
+    chip A/B banked 72.32 tok/s with exactly q4k=resplit (the Q6_K float
+    bodies it ran beside went in PR 64: ``LFKT_Q6K_KERNEL`` names a layout)
     (docs/bench/bench_q4km_variant_ab_2026-08-01.json, confirmed bare-env
     by bench_q4km_postflip_2026-08-01.json).  A reorder silently changes
     the shipped default (_env_variant takes allowed[0]) and detaches the
     headline claim from its artifact — flip only with a new banked A/B."""
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.q5matmul import Q5K_VARIANTS
-    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import Q6K_VARIANTS
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import Q6K_LAYOUTS
     from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import Q4K_VARIANTS
 
     assert Q4K_VARIANTS[0] == "resplit"
-    assert Q6K_VARIANTS[0] == "cur"
+    assert Q6K_LAYOUTS == ("split", "pre")   # the packed planes the cells load
     # q5k=pre since the 2026-08-01 q5km A/B: 63.09 vs 52.27 tok/s
     # (bench_q5km_pre_2026-08-01.json vs bench_q5km_2026-08-01.json,
     # kernel_microbench_q5kpre_2026-08-01.json)

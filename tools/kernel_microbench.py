@@ -5,10 +5,12 @@ shapes, against the int8 control and the HBM-bandwidth roofline, so kernel
 restructurings can be picked on data (VERDICT r3 #2: raise Q4_K from 57% of
 roofline toward the int8 path's 85%).
 
-(The Q6_K variants are bodies of the STACKED call since PR 57: that format's
-rows go through ``linear_at`` on a stack of one.  The unstacked Q6_K call
-is the vocabulary head's, one body whatever the knob says:
-``tools/time_head_call.py`` times it.)
+(``LFKT_Q6K_KERNEL`` names a LAYOUT since PR 64, ``split`` | ``pre``: the
+split layout's calls run one body, the integer dequantization the head has
+had since PR 57, and the float bodies the knob used to choose among are
+gone.  That format's rows go through ``linear_at`` on a stack of one: the
+stacked call, as a layer makes it.  ``tools/time_head_call.py`` times the
+head's call and the stacked one alone, and sweeps their tiling.)
 
 Method: each (fmt, variant, shape, B) cell times a jitted x -> x-chained
 matvec (output reduced back into the input row so nothing hoists), double
@@ -61,13 +63,13 @@ ITERS = 1000
 REL_DEV_GATE = 5e-3
 
 from llama_fastapi_k8s_gpu_tpu.ops.pallas.q5matmul import Q5K_VARIANTS
-from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import Q6K_VARIANTS
+from llama_fastapi_k8s_gpu_tpu.ops.pallas.q6matmul import Q6K_LAYOUTS
 from llama_fastapi_k8s_gpu_tpu.ops.pallas.qmatmul import Q4K_VARIANTS
 
 VARIANTS = {
     "q4k": Q4K_VARIANTS,
     "q5k": Q5K_VARIANTS,
-    "q6k": Q6K_VARIANTS,
+    "q6k": Q6K_LAYOUTS,
     "q8": ("cur",),
     "int8": ("cur",),
 }
@@ -112,7 +114,7 @@ def make_weight(fmt: str, wf: np.ndarray) -> dict:
           "q6k": L.make_linear_q6k, "q8": L.make_linear_q8,
           "int8": L.make_linear_int8}[fmt]
     w = mk(wf)
-    if fmt == "q6k":                # the variants' bodies: the stacked call
+    if fmt == "q6k":                # the stacked call, as a layer makes it
         w = {k: v[None] for k, v in w.items()}
     return jax.device_put(w)
 

@@ -1,30 +1,39 @@
-"""Time the head's Q6_K call alone (run ALONE on the chip).
+"""Time the split-layout Q6_K calls alone (run ALONE on the chip).
 
-The vocabulary head is the one unstacked fused Q6_K tensor of a served
-file (``ops/pallas/q6matmul.py _q6k_2d_raw``).  This times that call at the
-heads of every configuration that serves a fused Q6_K head, at 1 and 16
-rows (``--rows`` for more: a call of up to 256 rows takes the head's tiling,
-a taller one the many-row tiles), beside the call as it was before PR 57
-(the stacked calls' body ``_q6k_matmul_kernel`` under their tiling, rebuilt
-here from the parts that still serve them), and prints for each the milliseconds a
-call and the share of ``bytes / 819 GB/s`` (bytes: the planes as the
-kernel stores them, ``q4`` N x K/2, ``q2`` N x K/4, ``sm6`` N x K/8, with
-K filled up to the K tile: 7168 -> 8192).
+Two callers run ``ops/pallas/q6matmul.py``'s one body under one builder
+(``_q6k_call``): the vocabulary head, the one unstacked fused Q6_K tensor of
+a served file (``_q6k_2d_raw``), and every layer's stacked ``w_down`` /
+``wv`` (``_q6k_2d_stacked_raw``, ``--stacked``: the dense configurations'
+shapes, on layer 1 of a stack of two).  This times the call at 1 and 16 rows
+(``--rows`` for more: a call of up to 256 rows takes the few-row tiling, a
+taller one the many-row tiles), beside the PARENT commit's call where its
+``q6matmul.py`` is at ``--parent`` (``git archive --prefix=.parent_check/
+<parent> | tar x``; its other imports are this tree's), and prints for each
+the milliseconds a call and the share of ``bytes / 819 GB/s`` (bytes: the
+planes as the kernel stores them, ``q4`` N x K/2, ``q2`` N x K/4, ``sm6`` N
+x K/8, with K filled up to the K tile: 7168 -> 8192, or ending in a tail
+tile: 2560).
 
     chiprun -- python tools/time_head_call.py
     chiprun -- python tools/time_head_call.py --only kexaone --tn-units 2,4,8
+    chiprun -- python tools/time_head_call.py --stacked --rows 1,16,256 \
+        --tiling 1024:1,512:7,256:7
+
+``--tiling``: (N tile):(K tiles a step) pairs put in the place of
+``_q6k_tiling``'s choice for the few-row calls (a pair that does not divide
+the shape is left out).
 
 Method: ``calls`` and ``3 x calls`` chained calls inside ONE jit (the
 result folded back into the activations, so nothing hoists), the slope
 ``(t(3n) - t(n)) / 2n`` of the medians of ``reps`` runs.  ``same_bits``:
-the new call's float32 result equals the old one's bit for bit.  One JSON
+the call's float32 result equals the first side's bit for bit.  One JSON
 line a (shape, rows, side); all of them again in ``--out``.
 """
 
 from __future__ import annotations
 
 import argparse
-import functools
+import importlib.util
 import json
 import os
 import sys
@@ -40,6 +49,15 @@ HBM_GBPS = 819.0  # v5e HBM bandwidth (spec)
 SHAPES = [("kexaone", 153600, 6144), ("gigachat", 128256, 7168),
           ("longcat", 131072, 6144), ("llama32k", 32000, 4096),
           ("olmoe", 50304, 2048), ("ouro", 49152, 2048)]
+# the stacked calls of the dense configurations: ``w_down`` of mistral /
+# solar, sala, ouro, evabyte (K 11008 filled to 12288), phi4flash, longcat's
+# dense layers; ``wv`` of mistral / solar, and the widths whose K ends in a
+# tail tile (phi4flash's ``wv`` at K 2560, a K of 5120)
+STACKED = [("down.mistral", 4096, 14336), ("down.sala", 4096, 16384),
+           ("down.ouro", 2048, 5632), ("down.evabyte", 4096, 11008),
+           ("down.phi4flash", 2560, 10240), ("down.longcat", 6144, 12288),
+           ("wv.mistral", 1024, 4096), ("wv.ouro", 2048, 2048),
+           ("wv.phi4flash", 1280, 2560), ("tail.5120", 2560, 5120)]
 
 
 def main() -> int:
@@ -52,6 +70,13 @@ def main() -> int:
                     help="sweep the tiling: comma list of HEAD_TN_UNITS, or "
                     "units:tiles to set HEAD_W_BLOCK to `tiles` K tiles of "
                     "that N tile too (default: as built)")
+    ap.add_argument("--stacked", action="store_true",
+                    help="the stacked calls' shapes in place of the heads'")
+    ap.add_argument("--tiling", default="",
+                    help="comma list of tn:tiles pairs for the few-row calls")
+    ap.add_argument("--parent", default=".parent_check/llama_fastapi_k8s_"
+                    "gpu_tpu/ops/pallas/q6matmul.py",
+                    help="the parent commit's q6matmul.py (side `old`)")
     ap.add_argument("--no-old", action="store_true")
     ap.add_argument("--out", default="chiprun_out/time_head_call.jsonl")
     args = ap.parse_args()
@@ -75,21 +100,21 @@ def main() -> int:
 
     say(device=str(dev), kind=dev.device_kind, hbm_gbps=HBM_GBPS)
 
-    def old_call(xpa, q4, q2, sm):
-        """The unstacked call as it was: the stacked calls' body and
-        tiling, one K tile of activations fetched a grid step."""
-        B, N, K = xpa.shape[0], q4.shape[0], q4.shape[1] * 2
-        TN = Q4._pick_tn(N, False, prefs=Q4.tn_prefs(B, Q6._TN_PREFS_Q6K))
-        in_specs, out_spec = Q6._q6k_specs(B, TN)
-        return Q4.plain_pallas_call(
-            functools.partial(Q6._q6k_matmul_kernel, interpret=False,
-                              variant="cur"),
-            (N // TN, K // Q4.TK), in_specs, out_spec,
-            jax.ShapeDtypeStruct((B, N), jnp.float32), False,
-            Q4.kernel_name("q6k", B))(xpa, q4, q2, sm)
+    parent = None
+    if not args.no_old and os.path.exists(args.parent):
+        spec = importlib.util.spec_from_file_location(
+            Q6.__name__ + "_parent", args.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
 
-    def new_call(xpa, q4, q2, sm):
-        return Q6._q6k_2d_raw(xpa, q4, q2, sm, False)
+    def call_of(mod):
+        """``mod``'s call on the planes as they are stored: the head's, or
+        the stacked one on layer 1 of a stack of two."""
+        if not args.stacked:
+            return lambda xpa, q4, q2, sm, *tail: mod._q6k_2d_raw(
+                xpa, q4, q2, sm, False, tail)
+        return lambda xpa, *planes: mod._q6k_2d_stacked_raw(
+            jnp.ones(1, jnp.int32), xpa, *planes, interpret=False)
 
     def chain_of(call, n):
         def run(xpa, *planes):
@@ -115,46 +140,68 @@ def main() -> int:
 
     only = [s for s in args.only.split(",") if s]
     units = [tuple(int(v) for v in u.split(":"))
-             for u in args.tn_units.split(",") if u] or [None]
-    for name, N, k_file in SHAPES:
-        if only and name not in only:
+             for u in args.tn_units.split(",") if u]
+    tilings = [tuple(int(v) for v in u.split(":"))
+               for u in args.tiling.split(",") if u]
+    built = (Q6.HEAD_TN_UNITS, Q6.HEAD_W_BLOCK, Q6._q6k_tiling)
+    lead = (2,) if args.stacked else ()
+    for name, N, k_file in STACKED if args.stacked else SHAPES:
+        if only and not any(o in name for o in only):
             continue
-        K = padded_k(k_file)
+        T = Q4.tail_of(k_file)
+        K = k_file if T else padded_k(k_file)
+        kt = (K - T) // Q4.TK
         key = jax.random.PRNGKey(N)
-        q4 = jax.random.randint(key, (N, K // 2), -128, 128, jnp.int8)
-        q2 = jax.random.randint(jax.random.fold_in(key, 1), (N, K // 4),
-                                -128, 128, jnp.int8)
-        sm = (jax.random.normal(jax.random.fold_in(key, 2),
-                                (K // Q4.TK, N, 128)) * 1e-3
-              ).astype(jnp.bfloat16)
-        nbytes = q4.size + q2.size + sm.size * 2
+
+        def plane(i, shape, scale=None):
+            k = jax.random.fold_in(key, i)
+            if scale is None:
+                return jax.random.randint(k, lead + shape, -128, 128,
+                                          jnp.int8)
+            return (jax.random.normal(k, lead + shape) * scale
+                    ).astype(jnp.bfloat16)
+
+        planes = [plane(0, (N, (K - T) // 2)), plane(1, (N, (K - T) // 4)),
+                  plane(2, (kt, N, 128), 1e-3)]
+        if T:
+            planes += [plane(4, (N, T // 2)), plane(5, (N, T // 4)),
+                       plane(6, (1, N, 128), 1e-3)]
+        nbytes = sum(p.size * p.dtype.itemsize for p in planes) // (
+            2 if args.stacked else 1)
         floor_ms = nbytes / (HBM_GBPS * 1e6)
         for B in (int(r) for r in args.rows.split(",")):
             x = jax.random.normal(jax.random.fold_in(key, 3), (B, K),
                                   jnp.bfloat16)
             xpa = Q6.augment_x6(Q6.permute_x6(x))
             want = None
-            sides = [] if args.no_old else [("old", None)]
-            sides += [("new", u) for u in units]
+            sides = [("old", None)] if parent is not None else []
+            sides += [("new", None)] + [("new", ("units", *u)) for u in units]
+            if B <= Q4.TM:
+                sides += [("new", ("tiling", *t)) for t in tilings
+                          if N % t[0] == 0 and kt % t[1] == 0]
             for side, u in sides:
-                call = old_call if side == "old" else new_call
-                if u is not None:
-                    Q6.HEAD_TN_UNITS = u[0]
-                    if len(u) > 1:
-                        Q6.HEAD_W_BLOCK = 128 * u[0] * u[1] * Q4.TK
+                Q6.HEAD_TN_UNITS, Q6.HEAD_W_BLOCK, Q6._q6k_tiling = built
+                if u is not None and u[0] == "units":
+                    Q6.HEAD_TN_UNITS = u[1]
+                    if len(u) > 2:
+                        Q6.HEAD_W_BLOCK = 128 * u[1] * u[2] * Q4.TK
+                elif u is not None:
+                    Q6._q6k_tiling = lambda *a, _t=u[1:]: _t
                 row = dict(shape=name, N=N, K=K, rows=B, side=side,
                            MB=round(nbytes / 1e6, 1),
                            floor_ms=round(floor_ms, 4))
                 if side == "new":
-                    row["TN"], row["k_tiles_a_step"] = Q6._head_tiling(
-                        N, B, K // Q4.TK, False)
+                    row["TN"], row["k_tiles_a_step"] = Q6._q6k_tiling(
+                        N, B, kt, False)
+                    row["chosen"] = u is None
+                call = call_of(parent if side == "old" else Q6)
                 try:
-                    ms = slope_ms(call, xpa, q4, q2, sm)
-                    got = np.asarray(jax.jit(call)(xpa, q4, q2, sm))
+                    ms = slope_ms(call, xpa, *planes)
+                    got = np.asarray(jax.jit(call)(xpa, *planes))
                 except Exception as e:  # noqa: BLE001 — a tiling the chip refuses
                     say(**row, error=str(e)[:300])
                     continue
-                row.update(ms=round(ms, 4),
+                row.update(ms=round(ms, 5),
                            floor_share=round(100 * floor_ms / ms, 1))
                 if want is None:
                     want = got
@@ -164,7 +211,8 @@ def main() -> int:
                     row["max_dev"] = float(np.abs(got - want).max()
                                            / (np.abs(want).max() + 1e-30))
                 say(**row)
-        del q4, q2, sm
+        Q6.HEAD_TN_UNITS, Q6.HEAD_W_BLOCK, Q6._q6k_tiling = built
+        del planes
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as fh:
         fh.writelines(json.dumps(row) + "\n" for row in lines)

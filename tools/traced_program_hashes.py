@@ -7,7 +7,9 @@ vmapped step, prefill slices), each with the chip's kernels and in
 interpret mode, and the grouped calls alone, a family each (``grouped.*``:
 a 16-lane decode step's few-row call and a 1024-token slice's many-row one).
 Since PR 63 also the dense calls of a K that ends in a tail tile (``tail.*``:
-``phi4flash``'s widths; a tree without the tail layout leaves them out).
+``phi4flash``'s widths; a tree without the tail layout leaves them out),
+since PR 64 the families no cell loads (``other.*``: Q5_K, Q8_0, the Q6_K
+``pre`` layout).
 
     python tools/traced_program_hashes.py <tree> <out.json>     # once a tree
     git archive --prefix=.parent_check/ <parent> | tar x
@@ -93,6 +95,37 @@ def hashes(tree: str | None = None, only=None) -> dict:
                         lambda x, w, i: stacked(x, w, i, interpret=interp),
                         S((rows, k), bf16), planes(fmt, n, k, (2,)),
                         S((), i32)))
+    # the families no cell of the benchmark loads (Q5_K split and `pre`,
+    # Q8_0, the Q6_K `pre` layout): they share ``qmatmul.py``'s builders
+    # (``plain_pallas_call``, ``stacked_pallas_call``) with the two that do
+    other = {
+        "q5k": (P.q5k_matmul, P.q5k_matmul_stacked, lambda n, k, lead: {
+            "q5s": S((*lead, n, k // 2), i8), "q5h": S((*lead, n, k // 8), i8),
+            "sm5": S((*lead, k // 2048, n, 128), bf16)}),
+        "q5k_pre": (P.q5k_matmul, P.q5k_matmul_stacked, lambda n, k, lead: {
+            "q5p": S((*lead, n, k), i8),
+            "sm5": S((*lead, k // 2048, n, 128), bf16)}),
+        "q8_0": (P.q8_matmul, P.q8_matmul_stacked, lambda n, k, lead: {
+            "q8": S((*lead, n, k), i8),
+            "sm8": S((*lead, k // 2048, n, 128), bf16)}),
+        "q6k_pre": (P.q6k_matmul, P.q6k_matmul_stacked, lambda n, k, lead: {
+            "q6p": S((*lead, n, k), i8),
+            "sm6": S((*lead, k // 2048, n, 128), bf16)}),
+    }
+    for fmt, (plain, stacked, shapes) in other.items():
+        for rows in (1, 16, 256, 1024):
+            for interp in (False, True):
+                if interp and rows != 1:
+                    continue
+                tag = f"{fmt}.4096x4096.r{rows}." + ("interp" if interp
+                                                     else "tpu")
+                put("other.dense." + tag, lambda: traced(
+                    lambda x, w: plain(x, w, interpret=interp),
+                    S((rows, 4096), bf16), shapes(4096, 4096, ())))
+                put("other.stacked." + tag, lambda: traced(
+                    lambda x, w, i: stacked(x, w, i, interpret=interp),
+                    S((rows, 4096), bf16), shapes(4096, 4096, (2,)),
+                    S((), i32)))
     # a K that ends in a tail tile (``phi4flash``: gate / up, ``ssm_out``, the
     # head); a tree without ``qmatmul.tail_of`` has no such programs
     if hasattr(P.qmatmul, "tail_of"):
