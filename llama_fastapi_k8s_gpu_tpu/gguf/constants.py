@@ -260,11 +260,28 @@ GGML_BLOCK_SIZES: dict[GGMLType, tuple[int, int]] = {
 #: ``ssm_a`` and ``ssm_dt.bias`` hold OFFSETS from Mamba's initialisation
 #: (models/params.py ``ssm_values``), which is how a file of random values
 #: gets the time scales a trained one has.
+#: ``jamba`` (llama.cpp's name for the Jamba family as remembered;
+#: models/jamba.py) is a MIXER kind per layer over the eighth cache kind,
+#: Mamba-1 or unrotated GQA, and a dense SwiGLU in every layer; every norm
+#: is an RMSNorm, nothing rotates, and there is no ``output.weight``: the
+#: head is ``token_embd``.  Key ``attention.head_count_kv``: an ARRAY with
+#: one entry a layer, 0 in a scan layer (``lfm2moe``'s device).  Tensors: a
+#: scan layer has the Mamba names above (``ssm_in`` ... ``ssm_out``,
+#: ``ssm_a`` as stored or as ``ssm.values`` says) and the family's three
+#: inner RMSNorms ``ssm_dt_norm.weight`` (time_step_rank), ``ssm_b_norm
+#: .weight`` and ``ssm_c_norm.weight`` (state_size), applied to dt, B and C
+#: between ``ssm_x`` and ``ssm_dt`` / the scan; an attention layer
+#: ``attn_{q,k,v,output}`` without biases; every layer ``attn_norm``,
+#: ``ffn_norm`` and ``ffn_{gate,up,down}``; ``output_norm``.  Keys:
+#: ``ssm.conv_kernel`` / ``inner_size`` / ``state_size`` /
+#: ``time_step_rank``, ``attention.key_length``, ``ssm.values``;
+#: ``expert_count`` above 1 (the family's routed layers) and
+#: ``attention.sliding_window`` are refused by name.
 #: A file of any other architecture is refused by name at load
 #: (gguf/reader.py).
 SERVED_ARCHITECTURES = ("llama", "mistral", "olmoe", "evabyte", "minicpm-sala",
                         "deepseek2", "exaone-moe", "lfm2moe", "longcat-flash",
-                        "ouro", "deepseek32", "phi4flash")
+                        "ouro", "deepseek32", "phi4flash", "jamba")
 
 #: Of those, the architectures whose rotary embedding pairs dimension i
 #: with i + head_dim/2 ("rotate-half", llama.cpp's LLAMA_ROPE_TYPE_NEOX):

@@ -15,7 +15,7 @@ import types
 from typing import Callable, Mapping
 
 from .config import (
-    CONV_RING, LATENT_RING, RING, SSM_WINDOW_SHARED, STATE_RING,
+    CONV_RING, LATENT_RING, RING, SSM_RING, SSM_WINDOW_SHARED, STATE_RING,
     WINDOW_GLOBAL_RING, WINDOW_SUMMARIES, ModelConfig)
 
 #: what an engine can ASK of a cache kind, in the order the asks are
@@ -136,7 +136,8 @@ class CacheKind:
 #: the module that holds each kind's ``CACHE``
 _MODULES = {RING: "llama", WINDOW_SUMMARIES: "eva", STATE_RING: "sala",
             LATENT_RING: "mla", WINDOW_GLOBAL_RING: "hybrid",
-            CONV_RING: "lfm2", SSM_WINDOW_SHARED: "phi4flash"}
+            CONV_RING: "lfm2", SSM_WINDOW_SHARED: "phi4flash",
+            SSM_RING: "jamba"}
 
 
 def cache_of(cfg: ModelConfig) -> CacheKind:

@@ -21,11 +21,13 @@ LATENT_RING = "latent-ring"
 WINDOW_GLOBAL_RING = "window+global-ring"
 CONV_RING = "conv-state+ring"
 SSM_WINDOW_SHARED = "ssm-state+window+shared-ring"
+SSM_RING = "ssm-state+ring"
 
 #: the attention kinds of a layer (``ModelConfig.attn_kinds``)
 WINDOW, GLOBAL = "window", "global"
 
-#: the mixer kinds of a ``lfm2moe`` layer (``ModelConfig.mixers``)
+#: the mixer kinds of a ``lfm2moe`` layer (``ModelConfig.mixers``); a
+#: ``jamba`` layer is :data:`SSM` or :data:`ATTN`
 CONV, ATTN = "conv", "attn"
 
 #: the mixer kinds of a ``phi4flash`` layer beside :data:`WINDOW`: a
@@ -242,11 +244,15 @@ class ModelConfig:
     # ``"window"`` / ``"full"`` (differential attention; the ONE full layer
     # writes the shared K/V leaf), ``"gmu"`` (a gate on the last ssm layer's
     # scan output) or ``"cross"`` (a query on the shared leaf).  Every norm
-    # is a LayerNorm with a bias; nothing rotates.
+    # is a LayerNorm with a bias; nothing rotates.  A ``jamba`` file
+    # (models/jamba.py) names its layers ``"ssm"`` (the same mixer with an
+    # RMSNorm on each of dt, B and C: ``ssm_inner_norms``) or ``"attn"`` (GQA
+    # on a ring, unrotated); its norms are RMSNorms.
     ssm_d_inner: int = 0
     ssm_d_state: int = 0
     ssm_d_conv: int = 0
     ssm_dt_rank: int = 0
+    ssm_inner_norms: bool = False
     # a prefill slice's scan runs the slice kernel (ops/pallas/ssmscan.py).
     # Set by the engine, never by a file or a user: a TPU whose compiler
     # took the kernel's probe
@@ -302,10 +308,11 @@ class ModelConfig:
         (models/sala.py), ``latent-ring`` (models/mla.py) or
         ``window+global-ring`` (models/hybrid.py) or ``conv-state+ring``
         (models/lfm2.py) or ``ssm-state+window+shared-ring``
-        (models/phi4flash.py); models/cache.py ``cache_of`` maps it to the
+        (models/phi4flash.py) or ``ssm-state+ring`` (models/jamba.py);
+        models/cache.py ``cache_of`` maps it to the
         kind's object, nothing else tests it."""
         if self.ssm_d_state:
-            return SSM_WINDOW_SHARED
+            return SSM_WINDOW_SHARED if FULL in self.mixers else SSM_RING
         if self.conv_l_cache:
             return CONV_RING
         if self.mixers:
@@ -324,9 +331,12 @@ class ModelConfig:
         """About how many weights the layers' matrices hold, every expert
         included: what the ``weight_format="auto"`` size test weighs."""
         if self.ssm_d_state:
+            # (attention layers of either block: about 3 dim^2 each)
+            n_attn = self.n_layers_of(WINDOW) + self.n_layers_of(FULL) \
+                + self.n_layers_of(ATTN)
             return self.n_layers * 3 * self.dim * self.ffn_dim \
                 + self.n_layers_of(SSM) * 3 * self.dim * self.ssm_d_inner \
-                + (self.n_layers_of(WINDOW) + 1) * 3 * self.dim * self.dim
+                + n_attn * 3 * self.dim * self.dim
         if self.kv_lora_rank or self.attn_kinds or self.conv_l_cache:
             routed = 3 * self.dim * self.expert_ffn_dim * (
                 self.n_held + self.n_shared_experts)
@@ -352,6 +362,9 @@ class ModelConfig:
         mla = {}
         if arch == "lfm2moe":   # one entry a layer, 0 in a conv layer
             mla = _lfm2moe_fields(h, n_heads, n_kv_heads)
+            n_kv_heads = max(n_kv_heads)
+        if arch == "jamba":     # one entry a layer, 0 in a scan layer
+            mla = _jamba_fields(h, n_heads, n_kv_heads)
             n_kv_heads = max(n_kv_heads)
         n_kv_heads = int(n_kv_heads)
         eva = {}
@@ -600,20 +613,29 @@ def _routed_fields(h, arch: str) -> dict:
         experts_first=first, experts_held=held)
 
 
+def _check_kv_head_array(arch: str, kv_heads, n_layers: int, n_heads: int,
+                         other: str) -> None:
+    """``attention.head_count_kv`` of a file whose mixer kind is the
+    layer's: an array with one entry a layer, 0 in a layer of the ``other``
+    kind, ONE count in the attention layers; a ValueError naming what is
+    not."""
+    if not isinstance(kv_heads, (list, tuple)) or len(kv_heads) != n_layers:
+        raise ValueError(
+            f"{arch}: attention.head_count_kv must be an array with one "
+            f"entry for each of the {n_layers} layers (0: a {other} layer)")
+    counts = {int(n) for n in kv_heads} - {0}
+    if len(counts) != 1 or n_heads % max(counts):
+        raise ValueError(
+            f"{arch}: the attention layers' KV heads {sorted(counts)} must "
+            f"be one count that divides the {n_heads} heads")
+
+
 def _lfm2moe_fields(h, n_heads: int, kv_heads) -> dict:
     """The ``lfm2moe`` keys (gguf/constants.py) as ``ModelConfig`` fields; a
     ValueError naming what the block here cannot compute."""
     n_layers = int(h("block_count"))
     taps = int(h("shortconv.l_cache", 0) or 0)
-    if not isinstance(kv_heads, (list, tuple)) or len(kv_heads) != n_layers:
-        raise ValueError(
-            "lfm2moe: attention.head_count_kv must be an array with one "
-            f"entry for each of the {n_layers} layers (0: a conv layer)")
-    counts = {int(n) for n in kv_heads} - {0}
-    if len(counts) != 1 or n_heads % max(counts):
-        raise ValueError(
-            f"lfm2moe: the attention layers' KV heads {sorted(counts)} must "
-            f"be one count that divides the {n_heads} heads")
+    _check_kv_head_array("lfm2moe", kv_heads, n_layers, n_heads, "conv")
     if taps < 2:
         raise ValueError(
             f"lfm2moe: shortconv.l_cache {taps}: a conv layer has two taps "
@@ -631,6 +653,42 @@ def _lfm2moe_fields(h, n_heads: int, kv_heads) -> dict:
                 head_width=int(h("attention.key_length", 0) or 0),
                 # the family's router divides by the picked scores' sum + 1e-6
                 expert_weights_eps=1e-6, **routed)
+
+
+def _ssm_sizes(h, arch: str) -> dict:
+    """llama.cpp's ``ssm.*`` keys as the ``ssm_d_*`` fields; a ValueError
+    naming the ones a file lacks."""
+    d_inner, d_state, d_conv, dt_rank = (
+        int(h(f"ssm.{key}", 0) or 0) for key in
+        ("inner_size", "state_size", "conv_kernel", "time_step_rank"))
+    if min(d_inner, d_state, dt_rank) < 1 or d_conv < 2:
+        raise ValueError(
+            f"{arch}: the file lacks <arch>.ssm.inner_size / state_size / "
+            "time_step_rank, or ssm.conv_kernel is under 2 taps")
+    return dict(ssm_d_inner=d_inner, ssm_d_state=d_state, ssm_d_conv=d_conv,
+                ssm_dt_rank=dt_rank)
+
+
+def _jamba_fields(h, n_heads: int, kv_heads) -> dict:
+    """The ``jamba`` keys (gguf/constants.py) as ``ModelConfig`` fields; a
+    ValueError naming what the block here cannot compute."""
+    arch = "jamba"
+    n_layers = int(h("block_count"))
+    _check_kv_head_array(arch, kv_heads, n_layers, n_heads, "scan")
+    if all(int(n) for n in kv_heads):
+        raise ValueError(
+            f"{arch}: attention.head_count_kv names no scan layer (a 0)")
+    if int(h("expert_count", 0) or 0) > 1:
+        raise ValueError(
+            f"{arch}: expert_count {h('expert_count')}: the block here has "
+            "the dense feed-forward in every layer (the family's routed "
+            "layers are ROADMAP B-I 14)")
+    if int(h("attention.sliding_window", 0) or 0):
+        raise ValueError(f"{arch}: attention.sliding_window is not served: "
+                         "the attention layers here are causal over all")
+    return dict(mixers=tuple(ATTN if int(n) else SSM for n in kv_heads),
+                head_width=int(h("attention.key_length", 0) or 0),
+                ssm_inner_norms=True, **_ssm_sizes(h, arch))
 
 
 def _phi4flash_fields(h, n_heads: int, n_kv: int, window: int) -> dict:
@@ -653,13 +711,7 @@ def _phi4flash_fields(h, n_heads: int, n_kv: int, window: int) -> dict:
         raise ValueError(
             f"{arch}: mixer_types {listed!r}: the block here is (ssm, "
             "window) pairs, one (ssm, full) pair, then (gmu, cross) pairs")
-    d_inner, d_state, d_conv, dt_rank = (
-        int(h(f"ssm.{key}", 0) or 0) for key in
-        ("inner_size", "state_size", "conv_kernel", "time_step_rank"))
-    if min(d_inner, d_state, dt_rank) < 1 or d_conv < 2:
-        raise ValueError(
-            f"{arch}: the file lacks <arch>.ssm.inner_size / state_size / "
-            "time_step_rank, or ssm.conv_kernel is under 2 taps")
+    sizes = _ssm_sizes(h, arch)
     d_k = int(h("attention.key_length", 0) or 0) or dim // n_heads
     if n_heads % 2 or n_kv % 2 or (n_heads // 2) % (n_kv // 2) \
             or 2 * d_k != 128:
@@ -669,9 +721,7 @@ def _phi4flash_fields(h, n_heads: int, n_kv: int, window: int) -> dict:
             "here lays a pair's two 64-wide keys side by side in one row")
     if window < 1:
         raise ValueError(f"{arch}: attention.sliding_window {window}")
-    return dict(mixers=tuple(listed), ssm_d_inner=d_inner,
-                ssm_d_state=d_state, ssm_d_conv=d_conv, ssm_dt_rank=dt_rank,
-                head_width=d_k)
+    return dict(mixers=tuple(listed), head_width=d_k, **sizes)
 
 
 def _exaone_moe_fields(h, n_heads: int, window: int) -> dict:

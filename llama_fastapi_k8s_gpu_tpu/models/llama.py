@@ -50,6 +50,20 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return ((x32 * inv) * w.astype(jnp.float32)).astype(x.dtype)
 
 
+def embed(params: dict, tokens, dim: int):
+    """The tokens' rows of the embedding, bf16: of a float table its rows, of
+    the tied Q6_K head's planes (models/params.py: ONE stored tensor, a
+    ``phi4flash`` or ``jamba`` file's) the gathered rows dequantized
+    (ops/pallas/q6matmul.py ``dequant_rows6``)."""
+    emb = params["tok_emb"]
+    if isinstance(emb, dict):
+        from ..ops.pallas.q6matmul import dequant_rows6
+
+        with jax.named_scope("embed_rows"):
+            return dequant_rows6(emb, tokens, dim).astype(jnp.bfloat16)
+    return jnp.take(emb, tokens, axis=0).astype(jnp.bfloat16)
+
+
 def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: (S, H, hd); rotate pairs (2i, 2i+1) by pos * theta^(-2i/hd)."""
     hd = x.shape[-1]

@@ -583,49 +583,63 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
             dt0 = np.exp(np.log(1e-3) + u * (np.log(1e-1) - np.log(1e-3)))
             b_dt = (dt0 + np.log(-np.expm1(-dt0))).astype(np.float32) + b_dt
         elif how != "stored":
-            raise ValueError(f"phi4flash: ssm.values {how!r} (stored, "
-                             "init_offsets)")
+            raise ValueError(f"ssm.values {how!r} (stored, init_offsets)")
         return {"a": jnp.asarray(np.ascontiguousarray(a.T)),
                 "dt_b": jnp.asarray(b_dt)}
 
     def ssm_mixer_layers() -> dict:
-        """A ``phi4flash`` file (models/phi4flash.py): FIVE stacks, a
-        layer's mixer tensors under its mixer kind (``ssm`` | ``attn``: the
-        window layers and the full one | ``gmu`` | ``cross``, with the
-        layer's ``attn_norm``) and every layer's feed-forward under ``ffn``;
-        a name fuses by the types of ITS kind's layers.  Nothing is
-        requantized: a matrix no fused kernel takes (``ssm_x``: its rows
-        fill no tile) is served bf16, the F32 ``ssm_dt`` float32."""
-        from .config import CROSS, FULL, GMU, SSM, WINDOW
-        from .phi4flash import ATTN, FFN
+        """A file with Mamba layers: stacks by kind, a layer's mixer tensors
+        under its mixer kind (with the layer's ``attn_norm``) and every
+        layer's feed-forward under ``ffn``; a name fuses by the types of ITS
+        kind's layers.  A ``phi4flash`` file (models/phi4flash.py) has FIVE
+        (``ssm`` | ``attn``: the window layers and the full one | ``gmu`` |
+        ``cross`` | ``ffn``), its norms LayerNorms with a bias; a ``jamba``
+        file (models/jamba.py) THREE (``ssm`` with its three inner norms |
+        ``attn`` | ``ffn``), RMSNorms.  Nothing is requantized: a matrix no
+        fused kernel takes (``ssm_x``: its rows fill no tile) is served
+        bf16, the F32 ``ssm_dt`` float32."""
+        from .config import ATTN, CROSS, FULL, GMU, SSM, WINDOW
+        from .mamba import FFN
 
-        mats = {
-            SSM: {"in_proj": "ssm_in", "out_proj": "ssm_out"},
-            ATTN: {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
-                   "wo": "attn_output"},
-            GMU: {"in_proj": "gmu_in", "out_proj": "gmu_out"},
-            CROSS: {"wq": "attn_q", "wo": "attn_output"},
-            FFN: {"w_gate": "ffn_gate", "w_up": "ffn_up",
-                  "w_down": "ffn_down"}}
-        lams = {f"lam_{k}": f"attn_lambda_{k}"
-                for k in ("q1", "k1", "q2", "k2")}
-        norm1 = {"attn_norm": "attn_norm.weight",
-                 "attn_norm_b": "attn_norm.bias"}
-        f32s = {
-            SSM: {**norm1, "conv": "ssm_conv1d.weight",
-                  "conv_b": "ssm_conv1d.bias", "dt_proj": "ssm_dt.weight",
-                  "d": "ssm_d"},
-            ATTN: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
-                   "bq": "attn_q.bias", "bk": "attn_k.bias",
-                   "bv": "attn_v.bias", "bo": "attn_output.bias"},
-            GMU: norm1,
-            CROSS: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
-                    "bq": "attn_q.bias", "bo": "attn_output.bias"},
-            FFN: {"ffn_norm": "ffn_norm.weight",
-                  "ffn_norm_b": "ffn_norm.bias"}}
+        ssm_mats = {"in_proj": "ssm_in", "out_proj": "ssm_out"}
+        ssm_f32s = {"conv": "ssm_conv1d.weight", "conv_b": "ssm_conv1d.bias",
+                    "dt_proj": "ssm_dt.weight", "d": "ssm_d"}
+        qkvo = {"wq": "attn_q", "wk": "attn_k", "wv": "attn_v",
+                "wo": "attn_output"}
+        ffn = {"w_gate": "ffn_gate", "w_up": "ffn_up", "w_down": "ffn_down"}
+        if cfg.ssm_inner_norms:          # jamba
+            mats = {SSM: ssm_mats, ATTN: qkvo, FFN: ffn}
+            norm1 = {"attn_norm": "attn_norm.weight"}
+            f32s = {
+                SSM: {**norm1, **ssm_f32s, "dt_norm": "ssm_dt_norm.weight",
+                      "b_norm": "ssm_b_norm.weight",
+                      "c_norm": "ssm_c_norm.weight"},
+                ATTN: norm1,
+                FFN: {"ffn_norm": "ffn_norm.weight"}}
+            kinds = ((SSM, (SSM,)), (ATTN, (ATTN,)))
+        else:                            # phi4flash
+            mats = {
+                SSM: ssm_mats, ATTN: qkvo,
+                GMU: {"in_proj": "gmu_in", "out_proj": "gmu_out"},
+                CROSS: {"wq": "attn_q", "wo": "attn_output"}, FFN: ffn}
+            lams = {f"lam_{k}": f"attn_lambda_{k}"
+                    for k in ("q1", "k1", "q2", "k2")}
+            norm1 = {"attn_norm": "attn_norm.weight",
+                     "attn_norm_b": "attn_norm.bias"}
+            f32s = {
+                SSM: {**norm1, **ssm_f32s},
+                ATTN: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
+                       "bq": "attn_q.bias", "bk": "attn_k.bias",
+                       "bv": "attn_v.bias", "bo": "attn_output.bias"},
+                GMU: norm1,
+                CROSS: {**norm1, **lams, "sub_norm": "attn_sub_norm.weight",
+                        "bq": "attn_q.bias", "bo": "attn_output.bias"},
+                FFN: {"ffn_norm": "ffn_norm.weight",
+                      "ffn_norm_b": "ffn_norm.bias"}}
+            kinds = ((SSM, (SSM,)), (ATTN, (WINDOW, FULL)), (GMU, (GMU,)),
+                     (CROSS, (CROSS,)))
         ids = {kind: [i for i, m in enumerate(cfg.mixers) if m in names]
-               for kind, names in ((SSM, (SSM,)), (ATTN, (WINDOW, FULL)),
-                                   (GMU, (GMU,)), (CROSS, (CROSS,)))}
+               for kind, names in kinds}
         ids[FFN] = list(range(cfg.n_layers))
         out = {}
         for kind, mine in ids.items():
@@ -692,9 +706,9 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
 
     tied_fused = None
     if cfg.ssm_d_state and fmt == "q4k":
-        # a ``phi4flash`` file's tied Q6_K table: ONE stored tensor, the
-        # head's fused planes, of which the embedding lookup dequantizes the
-        # rows it gathers (models/phi4flash.py ``embed``)
+        # a ``phi4flash`` or ``jamba`` file's tied Q6_K table: ONE stored
+        # tensor, the head's fused planes, of which the embedding lookup
+        # dequantizes the rows it gathers (models/llama.py ``embed``)
         from ..gguf.constants import GGMLType
         from ..ops.pallas.q6matmul import q6k_compatible
 
@@ -745,8 +759,8 @@ def load_params(gf: GGUFFile, cfg: ModelConfig, fmt: str = "bf16",
         "output": output,
         **gate,
         # (a ``phi4flash`` file's norms are LayerNorms: a bias beside each)
-        **({"out_norm_b": norm("output_norm.bias")} if cfg.ssm_d_state
-           else {}),
+        **({"out_norm_b": norm("output_norm.bias")}
+           if "output_norm.bias" in gf.tensors else {}),
     }
 
 

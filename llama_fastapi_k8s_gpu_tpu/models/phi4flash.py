@@ -6,24 +6,10 @@ K/V leaf that the whole upper half of the stack reads
 Every layer is ``h += mixer(LN1(h)); h += W_down(silu(g) * u)`` with a
 LayerNorm that has a bias; nothing rotates.  The mixer is the layer's:
 
-- ``"ssm"`` (Mamba-1): ``[x, z] = W_in hn``; ``x = silu(conv(x) + b)`` over
-  ``cfg.ssm_d_conv`` causal depthwise taps; ``[dt, B, C] = W_x x``; ``dt =
-  softplus(W_dt dt + b_dt)``; per channel and state ``s_t = exp(dt_t A)
-  s_(t-1) + dt_t B_t x_t``, ``y_t = C_t . s_t + D x_t``; the branch is
-  ``W_out (y * silu(z))``.  The LAST ssm layer also hands ``m = y`` (before
-  the gate) to the layers above it.  A sequence carries the float32 states,
-  leaf ``state`` (ssm layers, d_state, d_inner / 128, 128: the channels on
-  a tile's lanes, models the kernel's layout), and the last ``d_conv - 1``
-  inputs of the taps, leaf ``conv`` (as models/lfm2.py's).  The state
-  INTEGRATES what it is fed: a row of padding past the prompt's end reaches
-  neither (``dt = 0`` there keeps the state to the bit), **the pass that
-  starts at position 0 starts from zero**, a lane that holds no request
-  keeps both as they were, and neither can be rolled back to an earlier
-  position: prefix reuse and lane claims are off.  A prefill slice's scan
-  is ops/pallas/ssmscan.py where the engine's probe passed
-  (``cfg.ssm_scan_kernel``), else :func:`selective_scan`, the plain
-  ``lax.scan`` tier-1 holds the kernel to; a decode step is one step of the
-  recurrence in XLA over the lanes.
+- ``"ssm"`` (Mamba-1; models/mamba.py, the mixer this block shares with
+  models/jamba.py): the leaves ``state`` and ``conv``, the slice kernel
+  behind ``cfg.ssm_scan_kernel``.  The LAST ssm layer also hands ``m = y``
+  (the scan's output before the gate) to the layers above it.
 - ``"window"`` / ``"full"``: DIFFERENTIAL attention.  Heads in pairs by even
   and odd: ``a1 = softmax(q1 k1^T) [v1 | v2]``, ``a2 = softmax(q2 k2^T) [v1
   | v2]``, ``a = RMSNorm(a1 - lam a2) (1 - lam0)``.  A pair's ``[k1 | k2]``
@@ -62,15 +48,15 @@ from .config import (
     CROSS, FULL, GMU, SSM, SSM_WINDOW_SHARED, WINDOW, ModelConfig)
 from .hybrid import chunk_counts as window_chunk_counts
 from .hybrid import window_slice, window_step
-from .lfm2 import conv_mix
 from .llama import (
-    _kernel_decode, _ring_attention, decode_read_slots, ring_kernel_block,
-    ring_step_bound, rms_norm)
+    _kernel_decode, _ring_attention, decode_read_slots, embed,
+    ring_kernel_block, ring_step_bound, rms_norm)
+from .mamba import (     # noqa: F401 — tier-1 reads the scan from here too
+    FFN, engine_health as _engine_health, init_leaves, probe_scan_kernel,
+    selective_scan, ssm_mixer, state_nbytes, state_shape)
 from .routed import swiglu
 
 _LANES = 128
-#: the stack of every layer's feed-forward
-FFN = "ffn"
 #: the attention stack: the window layers, then the full one
 ATTN = "attn"
 
@@ -99,11 +85,6 @@ def depths(cfg: ModelConfig, *kinds: str) -> np.ndarray:
                       np.int32)
 
 
-def state_shape(cfg: ModelConfig) -> tuple:
-    return (cfg.n_layers_of(SSM), cfg.ssm_d_state,
-            cfg.ssm_d_inner // _LANES, _LANES)
-
-
 def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     if cfg.kv_dtype not in ("bf16", "bfloat16"):
         raise ValueError(
@@ -113,22 +94,13 @@ def init_cache(cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     g = ring_view(cfg)
     n_w = cfg.n_layers_of(WINDOW)
     return {
-        "state": jnp.zeros(state_shape(cfg), jnp.float32),
-        "conv": jnp.zeros((cfg.n_layers_of(SSM), cfg.ssm_d_conv - 1,
-                           cfg.ssm_d_inner), dtype),
+        **init_leaves(cfg, dtype),
         "kw": jnp.zeros((n_w, g.n_kv_heads, cfg.window_slots, g.head_dim),
                         dtype),
         "vw": jnp.zeros((n_w, g.n_kv_heads, cfg.window_slots, g.head_dim),
                         dtype),
         "k": jnp.zeros((1, g.n_kv_heads, cfg.n_ctx, g.head_dim), dtype),
         "v": jnp.zeros((1, g.n_kv_heads, cfg.n_ctx, g.head_dim), dtype)}
-
-
-def state_nbytes(cfg: ModelConfig) -> int:
-    """The float32 states and the carried conv rows of one sequence."""
-    n = cfg.n_layers_of(SSM)
-    return n * cfg.ssm_d_inner * (cfg.ssm_d_state * 4
-                                  + (cfg.ssm_d_conv - 1) * 2)
 
 
 def cache_nbytes(cfg: ModelConfig) -> int:
@@ -178,84 +150,13 @@ def differential(ctx, w, i, depth, cfg: ModelConfig, out_dtype):
     return d.reshape(S, -1).astype(out_dtype)
 
 
-def selective_scan(x, dt, b, c, a, d, s0):
-    """The recurrence as a plain ``lax.scan``: what the slice kernel
-    (ops/pallas/ssmscan.py) computes, and the form of the CPU.  ``x`` / ``dt``
-    (S, C) f32 (``dt`` 0 in a row past the prompt's end), ``b`` / ``c`` (S, N),
-    ``a`` (N, C), ``d`` (C,), ``s0`` (N, C).  Returns (y (S, C), the state
-    after the last row)."""
-    def step(s, row):
-        xt, dtt, bt, ct = row
-        s = jnp.exp(dtt[None, :] * a) * s + bt[:, None] * (dtt * xt)[None, :]
-        return s, jnp.sum(ct[:, None] * s, axis=0) + d * xt
-
-    s, y = jax.lax.scan(step, s0, (x, dt, b, c))
-    return y, s
-
-
 def _ssm(h, w, mi, cache, pos_offset, n_valid, cfg: ModelConfig, live):
-    """One ssm layer's mixer branch: a prefill slice and a decode step
-    alike.  ``mi``: the layer within the ssm layers' weights and leaves.
-    Returns (h + branch, cache, y (S, d_inner) f32: the scan's output
-    before the gate)."""
-    S = h.shape[0]
-    C, N, R = cfg.ssm_d_inner, cfg.ssm_d_state, cfg.ssm_dt_rank
-    f32 = jnp.float32
-
-    def lin(x, name):
-        with jax.named_scope(name):
-            return linear_at(x, w[name], mi)
-
+    """One ssm layer's mixer branch (models/mamba.py ``ssm_mixer`` behind the
+    block's LayerNorm).  Returns (h + branch, cache, y (S, d_inner) f32: the
+    scan's output before the gate)."""
     hn = layer_norm(h, w["attn_norm"][mi], w["attn_norm_b"][mi], cfg.rms_eps)
-    with jax.named_scope("ssm"):
-        xz = lin(hn, "in_proj")
-        x, z = xz[:, :C], xz[:, C:]
-        fresh = pos_offset == 0     # the pass that starts its sequence
-        with jax.named_scope("conv"):
-            held = jax.lax.dynamic_index_in_dim(cache["conv"], mi, 0,
-                                                keepdims=False)
-            carried = jnp.where(fresh, jnp.zeros((), held.dtype), held)
-            v, carry_on = conv_mix(x, w["conv"][mi], carried, n_valid)
-            if live is not None:     # a lane that holds no request
-                carry_on = jnp.where(live, carry_on, held)
-            cache = dict(cache, conv=jax.lax.dynamic_update_slice(
-                cache["conv"], carry_on[None].astype(held.dtype), (mi, 0, 0)))
-            xc = jax.nn.silu(v + w["conv_b"][mi])                  # f32
-        with jax.named_scope("x_proj"):
-            # (never a fused layout: its rows are no multiple of a tile)
-            dbc = jax.lax.dot_general(
-                xc.astype(jnp.bfloat16), w["x_proj"]["w"][mi],
-                (((1,), (1,)), ((), ())), preferred_element_type=f32)
-        with jax.named_scope("dt_proj"):
-            # float32 at full precision: a step size, not an activation
-            dt = jax.nn.softplus(jnp.dot(
-                dbc[:, :R], w["dt_proj"][mi].T,
-                precision=jax.lax.Precision.HIGHEST) + w["dt_b"][mi])
-        # a row of padding past the prompt's end leaves the state as it is
-        dt = jnp.where((jnp.arange(S) < n_valid)[:, None], dt, 0.0)
-        b, c = dbc[:, R:R + N], dbc[:, R + N:]
-        with jax.named_scope("scan"):
-            if S > 1 and cfg.ssm_scan_kernel:
-                from ..ops.pallas import use_interpret
-                from ..ops.pallas.ssmscan import ssm_scan
-
-                y, state = ssm_scan(xc, dt, b, c, w["a"][mi], w["d"][mi],
-                                    cache["state"], mi, fresh,
-                                    interpret=use_interpret())
-            else:
-                kept = jax.lax.dynamic_index_in_dim(cache["state"], mi, 0,
-                                                    keepdims=False)
-                s0 = jnp.where(fresh, 0.0, kept).reshape(N, C)
-                y, s = selective_scan(xc, dt, b, c, w["a"][mi], w["d"][mi],
-                                      s0)
-                s = s.reshape(kept.shape)
-                if live is not None:
-                    s = jnp.where(live, s, kept)
-                state = jax.lax.dynamic_update_slice(
-                    cache["state"], s[None], (mi, 0, 0, 0))
-            cache = dict(cache, state=state)
-        gated = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype)
-        out = lin(gated, "out_proj")
+    out, cache, y = ssm_mixer(hn, w, mi, cache, pos_offset, n_valid, cfg,
+                              live)
     return h + out, cache, y
 
 
@@ -349,19 +250,6 @@ def _gmu(h, w, gi, m, cfg: ModelConfig):
 def _ffn(h, w, fi, cfg: ModelConfig):
     hn = layer_norm(h, w["ffn_norm"][fi], w["ffn_norm_b"][fi], cfg.rms_eps)
     return h + swiglu(hn, w, fi, "w_gate", "w_up", "w_down")
-
-
-def embed(params: dict, tokens, dim: int):
-    """The tokens' rows of the embedding, bf16: of a float table its rows, of
-    the tied Q6_K head's planes (models/params.py: ONE stored tensor) the
-    gathered rows dequantized (ops/pallas/q6matmul.py ``dequant_rows6``)."""
-    emb = params["tok_emb"]
-    if isinstance(emb, dict):
-        from ..ops.pallas.q6matmul import dequant_rows6
-
-        with jax.named_scope("embed_rows"):
-            return dequant_rows6(emb, tokens, dim).astype(jnp.bfloat16)
-    return jnp.take(emb, tokens, axis=0).astype(jnp.bfloat16)
 
 
 #: where the comparison with the reference reads the stream after the full
@@ -487,10 +375,6 @@ def _health(cfg: ModelConfig, engine) -> dict:
         "kv_paged": "refused at start"}
 
 
-def _engine_health(cfg: ModelConfig) -> dict:
-    return {"ssm_scan": "pallas" if cfg.ssm_scan_kernel else "xla"}
-
-
 def _note_decode(counts: dict, cfg: ModelConfig, wanted: list, n_steps: int,
                  live: list | None = None) -> None:
     dispatched = wanted if live is None else live
@@ -567,25 +451,8 @@ def _attn_impl(cfg: ModelConfig, asked: str) -> str:
 
 
 def _probe_kernels(cfg: ModelConfig, asked: str, attn_impl: str, probed):
-    """The slice's scan is a kernel of the kind's own
-    (ops/pallas/ssmscan.py): where its probe passes, ``cfg.ssm_scan_kernel``;
-    where it fails, the plain ``lax.scan`` (and the ring's kernels stay)."""
-    import logging
-
-    from ..ops.pallas.ssmscan import scan_compatible
-
-    if attn_impl == "pallas" and scan_compatible(cfg.ssm_d_inner):
-        from ..ops.pallas.probe import probe_ssm_scan
-
-        probed.append("ssm_scan")
-        err = probe_ssm_scan()
-        if err is None:
-            cfg = dataclasses.replace(cfg, ssm_scan_kernel=True)
-        else:
-            logging.getLogger(__name__).error(
-                "pallas selective scan failed its compile probe; the "
-                "slices' scans run as lax.scan: %s", err)
-    return cfg, attn_impl
+    """The slice's scan is a kernel of its own (ops/pallas/ssmscan.py)."""
+    return probe_scan_kernel(cfg, attn_impl, probed), attn_impl
 
 
 CACHE = CacheKind(

@@ -553,6 +553,23 @@ METRICS: dict[str, Metric] = _register(
            "prefill programs dispatched, by the part of the stack they run: "
            "lower = up to the full-attention layer (a slice that holds no "
            "prompt's last token), whole = every layer", labels=("stack",)),
+    # -- the ssm-state + ring cache (models/jamba.py; ``jamba``) -------------
+    # (its ssm_state_* are the three above; its ring_slots_* are summed over
+    # the attention layers)
+    Metric("prefill_ring_blocks_live_total", GAUGE,
+           "fused key blocks the prefill attention kernel's calls NEEDED: "
+           "those up to each slice's last row's position, once a row tile "
+           "and attention layer, summed over a prompt's slices at its "
+           "admission (host arithmetic on the slice plan and the kernel's "
+           "static block sizes: ops/pallas/attention.py flash_plan), "
+           "cumulative; 0 where the slices' attention is no kernel; exported "
+           "by a file of that cache kind only"),
+    Metric("prefill_ring_blocks_walked_total", GAUGE,
+           "fused key blocks those calls' grids WALKED: the same where the "
+           "key axis ends at the slice's end (a ring of more than "
+           "WALK_WHOLE_STEPS fused blocks), the whole ring's where it is "
+           "walked whole; live over walked = the share of the walk a slice "
+           "needed"),
     # -- the latent ring (models/mla.py; ``deepseek2``) ----------------------
     Metric("latent_positions_read_total", GAUGE,
            "cached latent rows the decode steps' attention covered (whole "

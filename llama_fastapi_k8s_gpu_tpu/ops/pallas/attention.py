@@ -78,6 +78,7 @@ def _attn_kernel(
     sliding_window: int,
     quantized: bool = False,
     key_floor: bool = False,   # pos_ref is (2,): [position, first real key]
+    bounded: bool = False,     # pos_ref's LAST: the key steps the grid walks
 ):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -197,7 +198,12 @@ def _attn_kernel(
     for u in range(kv_unroll):
         _sub_block(u)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    # (a bounded walk reads its last step from the scalar the grid's extent
+    # was set from, not from the grid)
+    last = (pos_ref[2 if key_floor else 1] if bounded
+            else pl.num_programs(2)) - 1
+
+    @pl.when(kb == last)
     def _finish():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)            # fully-masked (padded) rows
@@ -222,6 +228,51 @@ def _env_kv_unroll() -> int:
     if u < 1:
         raise ValueError(f"LFKT_FLASH_KV_UNROLL must be >= 1, got {u}")
     return u
+
+
+#: fused key blocks a ring holds from which the prefill kernel's walk ENDS
+#: at the slice's own end: a ring of up to this many is walked whole, every
+#: step past the slice classified and skipped in the kernel (its K/V blocks
+#: are fetched all the same); a longer one (131072 slots and more at the
+#: default blocks) would spend a slice's time on steps that compute nothing,
+#: so the key axis of its grid is a TRACED extent, ``ceil((pos + S) /
+#: fused block)``.  Every ring up to 32768 slots keeps the program it had.
+WALK_WHOLE_STEPS = 8
+
+
+def flash_plan(S: int, n_heads: int, n_kv: int, n_ctx: int,
+               block_q: int = 512, block_k: int = 1024,
+               kv_unroll: int | None = None) -> dict:
+    """The static plan of :func:`flash_attention` for these shapes: ``bq``
+    rows a row tile, ``bk`` keys a sub-block, ``unroll`` sub-blocks and
+    ``bkf`` keys a grid step, ``row_tiles`` and ``key_steps`` (the whole
+    ring's), and ``bounded``: whether the key axis ends at the slice's end
+    (:data:`WALK_WHOLE_STEPS`).  The kernel's builder and the counters of a
+    cache kind (models/jamba.py ``prefill_walk``) read the same numbers."""
+    gs = (n_heads // n_kv) * S
+    bq = _pick_block(gs, block_q)
+    bk = _pick_block(n_ctx, block_k)
+    if kv_unroll is None:
+        kv_unroll = _env_kv_unroll()
+    # largest unroll <= requested whose fused block divides the ring
+    u = max(1, min(int(kv_unroll), n_ctx // bk))
+    while u > 1 and n_ctx % (bk * u):
+        u -= 1
+    bkf = bk * u                                   # fused K/V block
+    steps = n_ctx // bkf
+    return {"bq": bq, "bk": bk, "unroll": u, "bkf": bkf,
+            "row_tiles": gs // bq, "key_steps": steps,
+            "bounded": steps > WALK_WHOLE_STEPS}
+
+
+def flash_steps_walked(plan: dict, end: int) -> int:
+    """Key steps a call whose last query sits at position ``end - 1`` walks
+    under ``plan``: up to the slice's end where the walk is bounded, else the
+    ring's.  (Host arithmetic and traced alike.)"""
+    if not plan["bounded"]:
+        return plan["key_steps"]
+    least = min if isinstance(end, int) else jnp.minimum
+    return least((end + plan["bkf"] - 1) // plan["bkf"], plan["key_steps"])
 
 
 @functools.partial(
@@ -265,6 +316,13 @@ def flash_attention(
     block-DMA setup for.  Clamped so the fused block still divides
     ``n_ctx`` (tiny rings degrade gracefully to the plain grid).
 
+    A ring of more than :data:`WALK_WHOLE_STEPS` fused blocks is walked up
+    to the block that holds the slice's LAST query and no further: the key
+    axis of the grid has a traced extent (:func:`flash_steps_walked`), so no
+    step, and no K/V fetch, is spent past the slice.  Causality makes the
+    result the same to the bit: every key past the slice is masked for
+    every query of it.
+
     ``first_key``: the keys are no ring of ``n_ctx`` positions but a run
     that starts before the sequence does (models/hybrid.py: a window
     layer's last window of cached rows, in position order, then the slice's
@@ -278,22 +336,21 @@ def flash_attention(
     gs = group * S
     quantized = k_scale is not None
 
-    bq = _pick_block(gs, block_q)
-    bk = _pick_block(n_ctx, block_k)
-    if kv_unroll is None:
-        kv_unroll = _env_kv_unroll()
-    # largest unroll <= requested whose fused block divides the ring
-    u = max(1, min(int(kv_unroll), n_ctx // bk))
-    while u > 1 and n_ctx % (bk * u):
-        u -= 1
-    bkf = bk * u                                   # fused K/V block
+    plan = flash_plan(S, n_heads, n_kv, n_ctx, block_q, block_k, kv_unroll)
+    bq, bk, u, bkf = (plan[key] for key in ("bq", "bk", "unroll", "bkf"))
+    # (a window layer's run of keys is short, and starts before position 0)
+    bounded = plan["bounded"] and first_key is None
 
     # (S, n_kv, group, hd) → (n_kv, group*S, hd): row = g*S + s
     qg = q.reshape(S, n_kv, group, hd).transpose(1, 2, 0, 3).reshape(n_kv, gs, hd)
     kk = k                                         # (n_kv, n_ctx, hd)
     vv = v
 
-    grid = (n_kv, gs // bq, n_ctx // bkf)
+    scalars = jnp.atleast_1d(pos_offset.astype(jnp.int32))
+    key_steps = n_ctx // bkf
+    if bounded:
+        key_steps = flash_steps_walked(plan, scalars[0] + S)
+    grid = (n_kv, gs // bq, key_steps)
     kernel = functools.partial(
         _attn_kernel,
         seq_len=S,
@@ -304,11 +361,13 @@ def flash_attention(
         sliding_window=sliding_window,
         quantized=quantized,
         key_floor=first_key is not None,
+        bounded=bounded,
     )
-    scalars = jnp.atleast_1d(pos_offset.astype(jnp.int32))
     if first_key is not None:
         scalars = jnp.concatenate(
             [scalars, jnp.atleast_1d(jnp.asarray(first_key, jnp.int32))])
+    if bounded:
+        scalars = jnp.concatenate([scalars, jnp.atleast_1d(key_steps)])
     in_specs = [
         pl.BlockSpec((1, bq, hd), lambda h, qb, kb, *_: (h, qb, 0)),
         pl.BlockSpec((1, bkf, hd), lambda h, qb, kb, *_: (h, kb, 0)),
@@ -351,9 +410,10 @@ def flash_attention(
 # the decode step (S = 1): every lane walks its own blocks of the ring
 # ---------------------------------------------------------------------------
 
-#: rows a lane's queries of one KV head are padded to: the bf16 tile's
-#: sublanes, so the (rows, hd) x (hd, T) score product is one aligned MXU
-#: pass whatever the group (4 in GQA 32/8, 1 in MHA)
+#: rows a lane's queries of one KV head are padded to a multiple of: the
+#: bf16 tile's sublanes, so the (rows, hd) x (hd, T) score product is an
+#: aligned MXU pass whatever the group (4 in GQA 32/8, 1 in MHA: 16 rows;
+#: 20 heads on one KV head: 32)
 _DECODE_ROWS = 16
 
 #: rows of the ring the kernel copies back around the row it stores: one
@@ -622,7 +682,7 @@ def _decode_lanes(q, i, pos, live, *arrays, block_k: int, sm_scale: float,
     if store and block_k % _ROW_TILE:
         raise ValueError(f"the decode kernel's block of {block_k} slots is "
                          f"no multiple of the {_ROW_TILE} rows it stores by")
-    n_rows = max(_DECODE_ROWS, group)
+    n_rows = -(-group // _DECODE_ROWS) * _DECODE_ROWS
     out_w = v_width or hd
     qg = jnp.pad(q.reshape(B, n_kv, group, hd),
                  ((0, 0), (0, 0), (0, n_rows - group), (0, 0)))

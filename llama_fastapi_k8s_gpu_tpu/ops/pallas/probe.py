@@ -409,6 +409,7 @@ from ...obs.devtime import register_program  # noqa: E402
 register_program("probe_flash_attention", site="ops.pallas.probe")
 register_program("probe_lin_state", site="ops.pallas.probe")
 register_program("probe_ssm_scan", site="ops.pallas.probe")
+register_program("probe_ring_wide_group", site="ops.pallas.probe")
 register_program("probe_latent_decode", site="ops.pallas.probe")
 register_program("probe_latent_prefill", site="ops.pallas.probe")
 register_program("_latent_decode", site="ops.pallas.probe")
@@ -470,3 +471,42 @@ def probe_ssm_scan() -> str | None:
     except Exception as e:  # noqa: BLE001
         return _err(e)
 
+
+
+@_once
+def probe_ring_wide_group(group: int) -> str | None:
+    """Compile + run the ring's two kernels at ``group`` query heads on ONE
+    KV head of 128 (a ``jamba`` file's attention layers: 20), the forms
+    :func:`probe_flash_attention` does not reach: the decode kernel with a
+    lane's rows padded past one tile, the flash kernel with one step on its
+    head axis and, on a ring of more fused blocks than it walks whole
+    (ops/pallas/attention.py ``WALK_WHOLE_STEPS``), a key axis that ends at
+    the slice's end.  A failure degrades such a file to ``attn_impl=xla``."""
+    try:
+        import jax
+        import jax.numpy as jnp
+
+        from . import use_interpret
+        from .attention import (
+            WALK_WHOLE_STEPS, flash_attention, flash_attention_decode)
+
+        itp = use_interpret()
+        S, HD, BK = (8, 128, 16) if itp else (128, 128, 128)
+        CTX = BK * (WALK_WHOLE_STEPS + 2)
+
+        def both(q):
+            ring = jnp.ones((2, 1, CTX, HD), jnp.bfloat16)
+            y = flash_attention(q, ring[0], ring[1], jnp.int32(BK),
+                                sm_scale=HD ** -0.5, block_k=BK, kv_unroll=1,
+                                interpret=itp)
+            row = jnp.ones((1, HD), jnp.bfloat16)
+            ctx, _, _ = flash_attention_decode(
+                q[0], ring, ring, jnp.int32(1), jnp.int32(CTX - 1), True,
+                sm_scale=HD ** -0.5, block_k=BK, interpret=itp, k_new=row,
+                v_new=row)
+            return y.astype(jnp.float32).sum() + ctx.astype(jnp.float32).sum()
+
+        float(jax.jit(both)(jnp.ones((S, group, HD), jnp.bfloat16)))
+        return None
+    except Exception as e:  # noqa: BLE001
+        return _err(e)

@@ -784,6 +784,53 @@ def write_tiny_lfm2_gguf(path: str, cfg: ModelConfig = TINY_LFM2_CFG,
     return cfg
 
 
+def _write_ssm_tensors(w: GGUFWriter, rng, p: str, cfg: ModelConfig,
+                       mix: dict, values: str, inner_norms: bool = False,
+                       dt_mul: float = 1.0) -> None:
+    """One Mamba-1 layer's tensors under llama.cpp's names (``blk.N.`` =
+    ``p``), as :func:`write_tiny_phi4flash_gguf` and
+    :func:`write_tiny_jamba_gguf` write them (``values``: see the former).
+    ``inner_norms``: the ``jamba`` family's RMSNorms on dt, B and C, drawn
+    with a WIDE spread (one that is skipped, or applied with another's
+    weight, moves the branch); ``dt_mul`` scales ``ssm_dt.weight`` (dt is
+    unit-size after its norm)."""
+    D, scale = cfg.dim, cfg.dim ** -0.5
+    C, N, L, R = (cfg.ssm_d_inner, cfg.ssm_d_state, cfg.ssm_d_conv,
+                  cfg.ssm_dt_rank)
+
+    def t(name, shape, gtype=GGMLType.F32, mul=1.0):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x, gtype)
+
+    t(p + "ssm_in.weight", (2 * C, D), mix["ssm_in"])
+    w.add_tensor(p + "ssm_conv1d.weight", (
+        rng.standard_normal((C, L)) * L ** -0.5).astype(np.float32),
+        GGMLType.F32)
+    t(p + "ssm_conv1d.bias", (C,))
+    t(p + "ssm_x.weight", (R + 2 * N, C), mix["ssm_x"]
+      if C % 256 == 0 else GGMLType.F16, (D / C) ** 0.5)
+    if inner_norms:
+        for name, n in (("dt", R), ("b", N), ("c", N)):
+            w.add_tensor(p + f"ssm_{name}_norm.weight",
+                         1.0 + 0.3 * rng.standard_normal(n).astype(
+                             np.float32), GGMLType.F32)
+    t(p + "ssm_dt.weight", (C, R), mul=(D / R) ** 0.5 * dt_mul)
+    noise = 0.1 * rng.standard_normal((C, N)).astype(np.float32)
+    dt_noise = 0.1 * rng.standard_normal(C).astype(np.float32)
+    if values == "stored":
+        a = -np.exp(np.log(np.arange(1, N + 1, dtype=np.float32))[None]
+                    + noise)
+        dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), C))
+        b_dt = (dt0 + np.log(-np.expm1(-dt0))).astype(np.float32)
+    else:
+        a, b_dt = noise, dt_noise
+    w.add_tensor(p + "ssm_a", a.astype(np.float32), GGMLType.F32)
+    w.add_tensor(p + "ssm_dt.bias", b_dt, GGMLType.F32)
+    w.add_tensor(p + "ssm_d", (1.0 + 0.1 * rng.standard_normal(C)
+                               ).astype(np.float32), GGMLType.F32)
+    t(p + "ssm_out.weight", (D, C), mix["ssm_out"], (D / C) ** 0.5)
+
+
 #: a tiny ``phi4flash`` file (models/phi4flash.py) with every layer kind:
 #: two (ssm, window) pairs, the (ssm, full) pair, two (gmu, cross) pairs;
 #: 4 heads on 2 KV heads of 64 (one pair of each), a window of 8 positions
@@ -838,8 +885,7 @@ def write_tiny_phi4flash_gguf(path: str,
             ("ssm.values", values)):
         w.add_metadata(f"{arch}.{key}", value)
     D, hd, F = cfg.dim, cfg.head_dim, cfg.ffn_dim
-    C, N, L, R = (cfg.ssm_d_inner, cfg.ssm_d_state, cfg.ssm_d_conv,
-                  cfg.ssm_dt_rank)
+    C = cfg.ssm_d_inner
     q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
 
     def t(name, shape, gtype=GGMLType.F32, mul=1.0):
@@ -874,33 +920,101 @@ def write_tiny_phi4flash_gguf(path: str,
         p = f"blk.{i}."
         norm(p + "attn_norm", D)
         if mixer == "ssm":
-            t(p + "ssm_in.weight", (2 * C, D), mix["ssm_in"])
-            w.add_tensor(p + "ssm_conv1d.weight", (
-                rng.standard_normal((C, L)) * L ** -0.5).astype(np.float32),
-                GGMLType.F32)
-            t(p + "ssm_conv1d.bias", (C,))
-            t(p + "ssm_x.weight", (R + 2 * N, C), mix["ssm_x"]
-              if C % 256 == 0 else GGMLType.F16, (D / C) ** 0.5)
-            t(p + "ssm_dt.weight", (C, R), mul=(D / R) ** 0.5)
-            noise = 0.1 * rng.standard_normal((C, N)).astype(np.float32)
-            dt_noise = 0.1 * rng.standard_normal(C).astype(np.float32)
-            if values == "stored":
-                a = -np.exp(np.log(np.arange(1, N + 1, dtype=np.float32))[None]
-                            + noise)
-                dt0 = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), C))
-                b_dt = (dt0 + np.log(-np.expm1(-dt0))).astype(np.float32)
-            else:
-                a, b_dt = noise, dt_noise
-            w.add_tensor(p + "ssm_a", a.astype(np.float32), GGMLType.F32)
-            w.add_tensor(p + "ssm_dt.bias", b_dt, GGMLType.F32)
-            w.add_tensor(p + "ssm_d", (1.0 + 0.1 * rng.standard_normal(C)
-                                       ).astype(np.float32), GGMLType.F32)
-            t(p + "ssm_out.weight", (D, C), mix["ssm_out"], (D / C) ** 0.5)
+            _write_ssm_tensors(w, rng, p, cfg, mix, values)
         elif mixer == "gmu":
             t(p + "gmu_in.weight", (C, D), mix["gmu_in"])
             t(p + "gmu_out.weight", (D, C), mix["gmu_out"], (D / C) ** 0.5)
         else:
             attention(p, mixer == "cross")
+        norm(p + "ffn_norm", D)
+        t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
+        t(p + "ffn_up.weight", (F, D), mix["ffn_up"])
+        t(p + "ffn_down.weight", (D, F), mix["ffn_down"])
+    norm("output_norm", D)
+    w.write()
+    return cfg
+
+#: a tiny ``jamba`` file (models/jamba.py): the period cut to 5 (offset 2), so
+#: that scan runs lie on both sides of an attention layer and between two
+#: (ssm ssm attn ssm ssm | ssm ssm attn ssm); 5 query heads of 128 on ONE KV
+#: head (a group that is no multiple of 8), 512 channels of 4 states, 4 taps,
+#: a dt rank of 16, the head tied
+TINY_JAMBA_CFG = ModelConfig(
+    vocab_size=256 + 7, dim=256, n_layers=9, n_heads=5, n_kv_heads=1,
+    ffn_dim=512, n_ctx=256, rms_eps=1e-6, head_width=128,
+    mixers=("ssm", "ssm", "attn", "ssm", "ssm") + ("ssm", "ssm", "attn",
+                                                    "ssm"),
+    ssm_d_inner=512, ssm_d_state=4, ssm_d_conv=4, ssm_dt_rank=16,
+    ssm_inner_norms=True, tie_embeddings=True,
+)
+
+#: the Q4_K_M mix on a ``jamba`` file, as the benchmark writes it
+JAMBA_Q4KM_MIX = {
+    "token_embd": GGMLType.Q6_K, "ssm_in": GGMLType.Q4_K,
+    "ssm_x": GGMLType.Q4_K, "ssm_out": GGMLType.Q4_K,
+    "attn_q": GGMLType.Q4_K, "attn_k": GGMLType.Q4_K,
+    "attn_v": GGMLType.Q6_K, "attn_output": GGMLType.Q4_K,
+    "ffn_gate": GGMLType.Q4_K, "ffn_up": GGMLType.Q4_K,
+    "ffn_down": GGMLType.Q6_K,
+}
+
+
+def write_tiny_jamba_gguf(path: str, cfg: ModelConfig = TINY_JAMBA_CFG,
+                          seed: int = 0, mix: dict | None = None,
+                          values: str = "stored") -> ModelConfig:
+    """Write a random-weight ``jamba`` GGUF (llama.cpp's Mamba tensor names
+    and the family's ``ssm_{dt,b,c}_norm``, RMSNorms near one, the per-layer
+    KV-head array with 0 in a scan layer, no biases but the conv's and
+    ``ssm_dt``'s, no ``output.weight``) with the byte-level tokenizer of
+    :func:`write_tiny_llama_gguf`.  ``values`` as
+    :func:`write_tiny_phi4flash_gguf`'s."""
+    tokens, types = byte_vocab_with_specials()
+    cfg = ModelConfig(**{**cfg.__dict__, "vocab_size": len(tokens)})
+    rng = np.random.default_rng(seed)
+    scale = cfg.dim ** -0.5
+    mix = {**JAMBA_Q4KM_MIX, **(mix or {})}
+    arch = "jamba"
+    w = GGUFWriter(path)
+    write_llama_gguf_meta(w, cfg, tokens, types, name="tiny-jamba-test",
+                          arch=arch)
+    key = f"{arch}.attention.head_count_kv"
+    w.metadata = [m for m in w.metadata if m[0] != key]
+    w.add_metadata(key, [cfg.n_kv_heads if m == "attn" else 0
+                         for m in cfg.mixers])
+    for key, value in (
+            ("attention.key_length", cfg.head_dim),
+            ("attention.value_length", cfg.head_dim),
+            ("ssm.conv_kernel", cfg.ssm_d_conv),
+            ("ssm.inner_size", cfg.ssm_d_inner),
+            ("ssm.state_size", cfg.ssm_d_state),
+            ("ssm.time_step_rank", cfg.ssm_dt_rank),
+            ("ssm.values", values)):
+        w.add_metadata(f"{arch}.{key}", value)
+    D, hd, F = cfg.dim, cfg.head_dim, cfg.ffn_dim
+    q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
+
+    def t(name, shape, gtype=GGMLType.F32, mul=1.0):
+        x = rng.standard_normal(shape).astype(np.float32) * scale * mul
+        w.add_tensor(name, x, gtype)
+
+    def norm(name, n):   # near one, not one: a norm that is skipped shows
+        w.add_tensor(name + ".weight", 1.0 + 0.1 * rng.standard_normal(
+            n).astype(np.float32), GGMLType.F32)
+
+    t("token_embd.weight", (cfg.vocab_size, D), mix["token_embd"]
+      if (cfg.vocab_size * D) % 256 == 0 else GGMLType.F16)
+    for i, mixer in enumerate(cfg.mixers):
+        p = f"blk.{i}."
+        norm(p + "attn_norm", D)
+        if mixer == "ssm":
+            _write_ssm_tensors(w, rng, p, cfg, mix, values, inner_norms=True,
+                               dt_mul=0.5)
+        else:
+            t(p + "attn_q.weight", (q_dim, D), mix["attn_q"])
+            t(p + "attn_k.weight", (kv_dim, D), mix["attn_k"])
+            t(p + "attn_v.weight", (kv_dim, D), mix["attn_v"])
+            t(p + "attn_output.weight", (D, q_dim), mix["attn_output"],
+              (D / q_dim) ** 0.5)
         norm(p + "ffn_norm", D)
         t(p + "ffn_gate.weight", (F, D), mix["ffn_gate"])
         t(p + "ffn_up.weight", (F, D), mix["ffn_up"])

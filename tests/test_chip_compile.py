@@ -1755,3 +1755,128 @@ def test_phi4flash_stack_compiles_with_no_ring_sized_copy(
                 assert sliced.memory_analysis().temp_size_in_bytes \
                     < 1536 * 2 ** 20 * rows // 256
 
+
+
+def jamba_published_cfg():
+    from llama_fastapi_k8s_gpu_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        vocab_size=65536, dim=2560, n_layers=28, n_heads=20, n_kv_heads=1,
+        ffn_dim=8192, n_ctx=262144, rms_eps=1e-6, head_width=128,
+        mixers=tuple("attn" if i % 14 == 7 else "ssm" for i in range(28)),
+        ssm_d_inner=5120, ssm_d_state=16, ssm_d_conv=4, ssm_dt_rank=160,
+        ssm_inner_norms=True, tie_embeddings=True)
+
+
+# AI21-Jamba2-3B whole (benchmarks/configs/jamba2-3b-q4km-16lane.json: 28
+# layers, hidden 2560, 16 lanes of 262144 positions): (name, lanes)
+@pytest.mark.parametrize("name,lanes", [("jamba-serial", 0),
+                                        ("jamba-16lane", 16)])
+def test_jamba_stack_compiles_with_no_ring_sized_copy(
+        one_chip, monkeypatch, name, lanes):
+    """The decode chunk (0 and 16 lanes) and both slice widths' prefill
+    program of the ``jamba`` stack (models/jamba.py) compile for the chip:
+    the scan kernel on the slices of 26 layers, the decode kernel per lane
+    at 20 query rows (padded to 32) on ONE K/V head, the flash kernel with
+    one step on its head axis and a key axis that ENDS at the slice's end (a
+    traced grid extent on the ring of 262144 slots), every fused matmul at
+    K 2560 / 5120 with a tail tile and K 8192 whole, the tied Q6_K head on
+    65536 rows and the embedding rows dequantized from its planes.  The
+    compiler has put NO copy or transpose of a ring in the decode chunk."""
+    import dataclasses
+    import re
+
+    from llama_fastapi_k8s_gpu_tpu.models import jamba
+    from llama_fastapi_k8s_gpu_tpu.models.generate import (
+        generate_chunk_jit, init_state, prefill_chunk_jit)
+    from llama_fastapi_k8s_gpu_tpu.models.llama import (
+        init_cache, ring_write_impl)
+    from llama_fastapi_k8s_gpu_tpu.ops import pallas as pallas_ops
+    from llama_fastapi_k8s_gpu_tpu.ops.pallas.attention import flash_plan
+    from llama_fastapi_k8s_gpu_tpu.parallel.batched import (
+        batched_generate_chunk_perlane_jit, init_batched_state,
+        init_lane_left)
+    from llama_fastapi_k8s_gpu_tpu.sampling.sample import (
+        SamplingParams, sampling_tensors)
+
+    monkeypatch.setattr(pallas_ops, "use_interpret", lambda: False)
+    cfg = dataclasses.replace(jamba_published_cfg(), attn_impl="pallas",
+                              ssm_scan_kernel=True)
+    D, V, F, C, N, R = 2560, 65536, 8192, 5120, 16, 160
+    assert jamba.CACHE.decode_kernel_block(cfg) == 512
+    assert ring_write_impl(cfg) == "kernel"
+    assert flash_plan(1024, 20, 1, cfg.n_ctx)["bounded"]
+    assert jamba.cache_nbytes(cfg) == 277753856
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    head = _planes("q6k", V, D)
+    params = place({
+        "tok_emb": head, "output": head, "out_norm": S(D, dtype=f32),
+        "layers": {
+            "ssm": {"attn_norm": S(26, D, dtype=f32),
+                    "in_proj": _planes("q4k", 2 * C, D, 26),
+                    "out_proj": _planes("q4k", D, C, 26),
+                    "x_proj": {"w": S(26, R + 2 * N, C)},
+                    "conv": S(26, C, 4, dtype=f32),
+                    "conv_b": S(26, C, dtype=f32),
+                    "dt_norm": S(26, R, dtype=f32),
+                    "b_norm": S(26, N, dtype=f32),
+                    "c_norm": S(26, N, dtype=f32),
+                    "dt_proj": S(26, C, R, dtype=f32),
+                    "dt_b": S(26, C, dtype=f32), "a": S(26, N, C, dtype=f32),
+                    "d": S(26, C, dtype=f32)},
+            "attn": {"attn_norm": S(2, D, dtype=f32),
+                     "wq": _planes("q4k", D, D, 2),
+                     "wk": _planes("q4k", 128, D, 2),
+                     "wv": _planes("q6k", 128, D, 2),
+                     "wo": _planes("q4k", D, D, 2)},
+            "ffn": {"ffn_norm": S(28, D, dtype=f32),
+                    "w_gate": _planes("q4k", F, D, 28),
+                    "w_up": _planes("q4k", F, D, 28),
+                    "w_down": _planes("q6k", D, F, 28)}}})
+    st = sampling_tensors(SamplingParams())
+    if lanes:
+        state = place(jax.eval_shape(lambda: init_batched_state(cfg, lanes)))
+        st = place(jax.eval_shape(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (lanes,)), st)))
+        left = place(jax.eval_shape(lambda: init_lane_left(lanes)))
+        lowered = batched_generate_chunk_perlane_jit.__wrapped__.lower(
+            params, cfg, state, st, left, n_steps=8, top_k=40,
+            live=place(S(lanes, dtype=jnp.bool_)), stop_ids=(2,))
+    else:
+        state = place(jax.eval_shape(lambda: init_state(cfg)))
+        lowered = generate_chunk_jit.__wrapped__.lower(
+            params, cfg, state, place(jax.eval_shape(lambda: st)),
+            n_steps=8, top_k=40)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert re.search(r"flash_attention_decode[^_]", text)
+    leaf_op = re.compile(
+        r"^\s*(ROOT )?%\S+ = bf16\[(\d+,)*262144,128\]\S* "
+        r"(copy|transpose|dynamic-update-slice)\(")
+    fused = re.compile(r"^%fused_computation")
+    found, in_fusion = [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):
+            in_fusion = bool(fused.match(ln))
+        if not in_fusion and leaf_op.search(ln):
+            found.append(ln.strip()[:120])
+    assert not found, found[:4]
+    print(name, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 2 ** 20
+    if not lanes:       # the admission slices into the scratch cache
+        cache = place(jax.eval_shape(lambda: init_cache(cfg)))
+        assert _slice_widths(cfg) == [256, 1024]
+        for rows in _slice_widths(cfg):     # narrow, and the wide slice
+            sliced = prefill_chunk_jit.__wrapped__.lower(
+                params, cfg, place(S(rows, dtype=i32)), place(S(dtype=i32)),
+                place(S(dtype=i32)), cache).compile()
+            stext = sliced.as_text()
+            assert "ssm_scan" in stext and "flash_attention" in stext
+            print("slice", rows, "temporaries",
+                  sliced.memory_analysis().temp_size_in_bytes)
+            assert sliced.memory_analysis().temp_size_in_bytes \
+                < 1536 * 2 ** 20 * rows // 256
